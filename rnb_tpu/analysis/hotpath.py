@@ -1,8 +1,8 @@
 """Hot-path AST lint: host-sync and import hygiene, statically.
 
-The runtime's throughput ceiling is the single host core (RESULTS.md),
-so the per-request code paths have hard rules the tree learned the
-expensive way — PR 2 measured per-emission ``import`` machinery and
+The runtime's throughput ceiling in round 5 was the single host core
+(2026-07, previous transport, not reproduced), so the per-request
+code paths have hard rules the tree learned the expensive way — PR 2 measured per-emission ``import`` machinery and
 ``np.zeros`` staging as whole percentage points of the core. This
 module encodes those rules over the AST so they hold by construction
 instead of by review.

@@ -3,9 +3,11 @@
 The native library is the performance path for .y4m decode — a fused
 probe/decode/convert/resize in C++ with an internal worker pool, the
 TPU-native replacement for the role NVVL's GPU decoder played in the
-reference (SURVEY.md §2.2 N2).  Everything degrades gracefully: if the
-shared library has not been built (``make -C native``) the pure-numpy
-:class:`~rnb_tpu.decode.Y4MDecoder` carries the same contract.
+reference (SURVEY.md §2.2 N2).  If the shared library has not been
+built (``make -C native``) the pure-numpy
+:class:`~rnb_tpu.decode.Y4MDecoder` carries the same contract on the
+CPU harness; a loader placed on a TPU refuses to start without it
+(:func:`require_native`).
 """
 
 from __future__ import annotations
@@ -129,6 +131,22 @@ def load_native():
 
 def native_available() -> bool:
     return load_native() is not None
+
+
+def require_native(platform: str) -> None:
+    """Raise when a loader on ``platform`` would decode without the
+    native library nobody turned off. On the CPU harness the numpy/PIL
+    decoders carry the same contract and tests rely on that; on a TPU
+    they are references, and a run that quietly measured them is worse
+    than one that stops (``RNB_DISABLE_NATIVE=1`` is the explicit way
+    to ask for them)."""
+    if (platform == "tpu" and not os.environ.get("RNB_DISABLE_NATIVE")
+            and not native_available()):
+        raise RuntimeError(
+            "the native decode library %s is missing or does not load; "
+            "build it (make -C native) before serving from a TPU, or "
+            "set RNB_DISABLE_NATIVE=1 to run the Python decoders on "
+            "purpose" % _lib_path())
 
 
 def default_decode_threads() -> int:

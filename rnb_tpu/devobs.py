@@ -51,6 +51,7 @@ import os
 import re
 import threading
 import time
+import traceback
 from typing import Dict, List, Optional, Tuple
 
 #: the active per-job plane, installed/cleared by rnb_tpu.benchmark
@@ -316,10 +317,8 @@ class DevObsPlane:
 
     UNGUARDED_OK = {
         "_worker": "controller-thread lifecycle (start/stop)",
-        "_peak_tflops": "idempotent memo — a racing duplicate probe "
-                        "computes the same value",
-        "_peak_resolved": "guards only the memo above; same "
-                          "idempotence argument",
+        "_worker_error": "written once by the worker thread, read by "
+                         "stop() after joining it",
     }
 
     def __init__(self, settings: DevObsSettings,
@@ -349,8 +348,16 @@ class DevObsPlane:
         self._worker: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._run_started = threading.Event()
-        self._peak_tflops: Optional[float] = None
-        self._peak_resolved = False
+        self._worker_error: Optional[BaseException] = None
+        import jax
+
+        from rnb_tpu.models.r2p1d.flops import peak_tflops_for
+        first = jax.devices()[0]
+        #: the MFU denominator: None off the chip (mfu is then
+        #: reported absent, mfu_e4 = -1); a TPU whose kind is not in
+        #: the peaks table raises here, at launch
+        self._peak_tflops: Optional[float] = peak_tflops_for(
+            first.device_kind, first.platform)
 
     # -- stage registration -------------------------------------------
 
@@ -520,20 +527,21 @@ class DevObsPlane:
             self.request_capture("forced")
         if self.settings.capture_window_ms > 0:
             self.request_capture("window")
-        while not self._stop.wait(timeout=period):
-            try:
+        try:
+            while not self._stop.wait(timeout=period):
                 self.ledger.sample()
                 self._service_captures()
-            except Exception:
-                continue  # the worker must outlive any bad probe
-        # drain any still-armed capture with the stop flag set: the
-        # window wait returns immediately, so this is cheap and the
-        # forced-capture contract (env set => artifact exists) holds
-        # even for very short runs
-        try:
+            # drain any still-armed capture with the stop flag set:
+            # the window wait returns immediately, so this is cheap
+            # and the forced-capture contract (env set => artifact
+            # exists) holds even for very short runs
             self._service_captures()
-        except Exception:
-            pass
+        except Exception as exc:  # noqa: BLE001 — re-raised by stop()
+            # the thread ends here; the controller hears of it at
+            # teardown instead of finding a run with no capture and
+            # no reason
+            traceback.print_exc()
+            self._worker_error = exc
 
     def _service_captures(self) -> None:
         while True:
@@ -549,26 +557,20 @@ class DevObsPlane:
                     self._captures_inflight -= 1
 
     def stop(self, timeout: float = 30.0) -> None:
+        """Stop the worker; raise what killed it, if anything did — a
+        ledger probe or a profiler capture that cannot start is a
+        failed run, not a quiet one."""
         self._stop.set()
         self._run_started.set()
         if self._worker is not None:
             self._worker.join(timeout=timeout)
             self._worker = None
+        if self._worker_error is not None:
+            raise RuntimeError(
+                "the devobs worker died: %r" % (self._worker_error,)
+            ) from self._worker_error
 
     # -- metrics bridge -----------------------------------------------
-
-    def _peak(self) -> Optional[float]:
-        if not self._peak_resolved:
-            self._peak_resolved = True
-            try:
-                import jax
-
-                from rnb_tpu.models.r2p1d.flops import peak_tflops_for
-                self._peak_tflops = peak_tflops_for(
-                    jax.devices()[0].device_kind)
-            except Exception:
-                self._peak_tflops = None
-        return self._peak_tflops
 
     def metrics_poll(self) -> List[Tuple[str, str, float]]:
         """Registry poll source (rnb_tpu.metrics): ``compute.*``
@@ -577,7 +579,7 @@ class DevObsPlane:
         tracking is at least as fine as the metrics interval."""
         from rnb_tpu import metrics
         out: List[Tuple[str, str, float]] = []
-        peak = self._peak()
+        peak = self._peak_tflops
         with self._lock:
             meters = list(self.meters.values())
         for meter in meters:
@@ -688,7 +690,7 @@ class DevObsPlane:
         with self._lock:
             meters = sorted(self.meters.values(),
                             key=lambda m: m.step_idx)
-        peak = self._peak()
+        peak = self._peak_tflops
         stage_detail: Dict[str, dict] = {}
         flops_total = 0
         dispatches_total = 0

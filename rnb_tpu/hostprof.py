@@ -115,8 +115,7 @@ def totals(prefix: str, role: str = None) -> Tuple[float, int]:
     role (exact thread name), answering "how much of this section ran
     on THAT thread" — the question the role-less sum cannot. The
     staging acceptance comparison (executor-thread ``loader.device_put``
-    + emit alloc/copy share, RESULTS.md round 5) is a prefix sum like
-    this."""
+    + emit alloc/copy share) is a prefix sum like this."""
     with _lock:
         total_s, calls = 0.0, 0
         for (name, r), (secs, n) in _acc.items():

@@ -246,31 +246,9 @@ class MemLedger:
 
     @staticmethod
     def _live_backend_bytes() -> int:
-        """Total bytes of the backend's own live array list, or 0 when
-        the introspection API is unavailable."""
-        try:
-            import jax
-        except Exception:
-            return 0
-        arrays = None
-        for attr in ("live_arrays", "live_buffers"):
-            fn = getattr(jax, attr, None)
-            if fn is None:
-                continue
-            try:
-                arrays = fn()
-                break
-            except Exception:
-                continue
-        if arrays is None:
-            return 0
-        total = 0
-        for arr in arrays:
-            try:
-                total += int(arr.nbytes)
-            except Exception:
-                continue
-        return total
+        """Total bytes of the backend's own live array list."""
+        import jax
+        return sum(int(arr.nbytes) for arr in jax.live_arrays())
 
     def reconcile(self) -> Tuple[int, bool]:
         """-> ``(live_bytes, ok)``: the backend's live-buffer byte

@@ -43,13 +43,21 @@ TPU_PEAK_TFLOPS = {
 }
 
 
-def peak_tflops_for(device_kind: str):
-    """Peak lookup for a ``jax.Device.device_kind`` string; None when
-    the platform is unknown (mfu is then unreported rather than wrong).
-    Exact match only — a prefix fallback would hand e.g. a 'TPU v4
-    lite' variant the full v4 peak and silently corrupt the published
-    MFU; unknown kinds belong in the table, not guessed."""
-    return TPU_PEAK_TFLOPS.get(device_kind.strip())
+def peak_tflops_for(device_kind: str, platform: str = ""):
+    """Peak lookup for a ``jax.Device.device_kind`` string. Exact match
+    only — a prefix fallback would hand e.g. a 'TPU v4 lite' variant
+    the full v4 peak and silently corrupt the published MFU; unknown
+    kinds belong in the table, not guessed. Off the chip path an
+    unknown kind gives None (mfu is then unreported rather than
+    wrong); with ``platform == "tpu"`` it raises — a measurement on a
+    chip whose peak nobody wrote down must stop, not print null."""
+    peak = TPU_PEAK_TFLOPS.get(device_kind.strip())
+    if peak is None and platform == "tpu":
+        raise KeyError(
+            "device_kind %r is not in TPU_PEAK_TFLOPS (%s); add its "
+            "spec-sheet bf16 peak before measuring on it"
+            % (device_kind, __file__))
+    return peak
 
 
 def _conv_out(extent: int, kernel: int, stride: int, pad: int) -> int:

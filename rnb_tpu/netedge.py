@@ -1096,13 +1096,28 @@ class NetEdgePeer:
                     pass
 
 
+def peer_env() -> dict:
+    """The environment the spawned peer runs in: the parent's
+    (XLA_FLAGS, RNB_FAULT_PLAN — both sides must resolve the same
+    fault plan) with JAX pinned to the CPU platform. The peer is a
+    host decode process; an accelerator belongs to one process at a
+    time, and the parent that spawns it already holds the chip, so a
+    peer that opened the default backend would fail or hang there.
+    PYTHONPATH leads with this package's checkout, so the peer runs
+    the parent's code whatever directory the parent was started in."""
+    from rnb_tpu.benchmark import REPO_DIR
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (REPO_DIR, env.get("PYTHONPATH")) if p)
+    return env
+
+
 def spawn_peer(config_path: str, settings: NetEdgeSettings,
                seed: int = 0, timeout_s: float = 60.0):
     """Launch the ingest peer as a real second process (same config
     file the main process runs) and wait for its bound port. Returns
-    ``(proc, "host:port")``; the caller owns termination. The child
-    inherits the environment (XLA_FLAGS, RNB_FAULT_PLAN) so both
-    sides resolve the same fault plan."""
+    ``(proc, "host:port")``; the caller owns termination."""
     listen = settings.listen or "127.0.0.1:0"
     host, _ = parse_addr(listen)
     tmpdir = tempfile.mkdtemp(prefix="rnb-netedge-")
@@ -1110,7 +1125,7 @@ def spawn_peer(config_path: str, settings: NetEdgeSettings,
     cmd = [sys.executable, "-m", "rnb_tpu.netedge", "--serve",
            "--config", config_path, "--listen", listen,
            "--port-file", port_file, "--seed", str(int(seed))]
-    proc = subprocess.Popen(cmd, env=dict(os.environ))
+    proc = subprocess.Popen(cmd, env=peer_env())
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
         if proc.poll() is not None:
@@ -1143,7 +1158,9 @@ def main(argv=None) -> int:
                         help="write the bound port here once serving")
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(argv)
+    from rnb_tpu.benchmark import enable_compilation_cache
     from rnb_tpu.config import load_config
+    enable_compilation_cache()
     config = load_config(args.config)
     peer = NetEdgePeer(config, args.listen, seed=args.seed)
     peer.build_model()

@@ -266,47 +266,56 @@ def unpack_dct_rows(x, height: int, width: int):
 
 # -- the fused frame conversion (shared by kernel, twin, interpret) ---
 
-def _frame_rgb_normalized(cy, cu, cv, ly, lyt, lcr, lcct, dtype):
-    """Block-tiled coefficient planes ``(..., H, W)`` -> normalized
-    ``(..., H, W, 3)``. The SINGLE function both the Pallas kernel
-    body (one 2-D frame per grid program) and the jnp twin (all
-    frames batched over the leading dims — ``jnp.matmul`` broadcasts)
-    call, so the two are structurally identical op for op; the
-    bit-parity contract tier-1 asserts batched-vs-per-frame matmul
-    rounding agreement on this backend.
+def _frame_rgb_planes(cy, cu, cv, ly, lyt, lcr, lcct, dtype):
+    """Block-tiled coefficient planes ``(..., H, W)`` -> the three
+    normalized colour planes ``(r, g, b)``, each ``(..., H, W)``. The
+    SINGLE function both the Pallas kernel body (one 2-D frame per
+    grid program) and the jnp twin (all frames batched over the
+    leading dims — ``jnp.matmul`` broadcasts) call, so the two are
+    structurally identical op for op; the bit-parity contract tier-1
+    asserts batched-vs-per-frame matmul rounding agreement on this
+    backend.
 
     Stages mirror the host pixel pipeline exactly: IDCT (+128 level
     shift), per-plane round-half-up u8 quantize (native Idct8x8's
     ``ClipByte(px + 0.5)``), BT.601 in the same op order as
     rnb_tpu/ops/yuv.py, clip, truncate to u8, then the FMA-proof
     normalize formulation of ops/preprocess.normalize_u8_reference.
+
+    The IDCT matmuls ask for ``Precision.HIGHEST``: coefficients reach
+    +-2^11, and a TPU's default single-pass bf16 product would move
+    reconstructed pixels by whole levels, outside the +-1 LSB the
+    module promises against the host decoder. The CPU backend
+    multiplies f32 in full either way, so the pinned numerics there
+    are unchanged.
     """
+    import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
+    highest = jax.lax.Precision.HIGHEST
 
     def plane(coef, left, right):
         c = coef.astype(jnp.int32).astype(f32)
-        p = jnp.matmul(left, jnp.matmul(c, right,
+        p = jnp.matmul(left, jnp.matmul(c, right, precision=highest,
                                         preferred_element_type=f32),
-                       preferred_element_type=f32)
+                       precision=highest, preferred_element_type=f32)
         # level shift + the host decoder's round-half-up u8 quantize
         return jnp.clip(jnp.floor(p + (128.0 + 0.5)), 0.0, 255.0)
 
     y = plane(cy, ly, lyt)
-    u = plane(cu, lcr, lcct)
-    v = plane(cv, lcr, lcct)
-    uf = u - 128.0
-    vf = v - 128.0
-    rgb = jnp.stack([
-        y + 1.402 * vf,
-        y - 0.344136 * uf - 0.714136 * vf,
-        y + 1.772 * uf,
-    ], axis=-1)
-    # the yuv420 path's u8 quantization step (clip + truncate), kept in
-    # f32, then the single-rounding normalize
-    rgbq = jnp.floor(jnp.clip(rgb, 0.0, 255.0))
-    return ((rgbq * 2.0 - 255.0) * f32(1.0 / 255.0)).astype(dtype)
+    uf = plane(cu, lcr, lcct) - 128.0
+    vf = plane(cv, lcr, lcct) - 128.0
+
+    def normalized(channel):
+        # the yuv420 path's u8 quantization step (clip + truncate),
+        # kept in f32, then the single-rounding normalize
+        q = jnp.floor(jnp.clip(channel, 0.0, 255.0))
+        return ((q * 2.0 - 255.0) * f32(1.0 / 255.0)).astype(dtype)
+
+    return (normalized(y + 1.402 * vf),
+            normalized(y - 0.344136 * uf - 0.714136 * vf),
+            normalized(y + 1.772 * uf))
 
 
 def _dct_kernel(rows_valid_ref, cy_ref, cu_ref, cv_ref, ly_ref,
@@ -314,7 +323,10 @@ def _dct_kernel(rows_valid_ref, cy_ref, cu_ref, cv_ref, ly_ref,
     """One (pool-row, frame) program: full fused conversion when the
     row is valid, a zero store otherwise — pad programs run no
     IDCT/convert arithmetic (the ``pl.when`` predicate skips the whole
-    body, rnb_tpu/ops/ragged.py discipline)."""
+    body, rnb_tpu/ops/ragged.py discipline). The output block is
+    channel-planar ``(1, 1, 3, H, W)``: W sits on the lanes, where a
+    channel-minor ``(H, W, 3)`` block would pad its 3-wide minor
+    dimension to a full 128-lane tile."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -322,10 +334,11 @@ def _dct_kernel(rows_valid_ref, cy_ref, cu_ref, cv_ref, ly_ref,
 
     @pl.when(row < rows_valid_ref[0])
     def _valid():
-        out = _frame_rgb_normalized(
+        planes = _frame_rgb_planes(
             cy_ref[0, 0], cu_ref[0, 0], cv_ref[0, 0], ly_ref[:],
             lyt_ref[:], lcr_ref[:], lcct_ref[:], o_ref.dtype)
-        o_ref[:] = out[None, None]
+        for channel, value in enumerate(planes):
+            o_ref[0, 0, channel] = value
 
     @pl.when(row >= rows_valid_ref[0])
     def _pad():
@@ -337,7 +350,14 @@ def _dct_convert_pallas(ycoef, ucoef, vcoef, rows_valid, height: int,
     """Pallas dispatch over (pool rows, frames): ``rows_valid`` is
     scalar-prefetched so every program's predicate resolves before its
     body; the IDCT bases ride as whole-array inputs every program
-    reads."""
+    reads. Per program the VMEM blocks are one int32 luma plane, two
+    quarter planes, the four f32 bases and the planar output — about
+    0.4 MB at 112x112 with lanes padded to 128, double-buffered far
+    under the 16 MiB default scoped limit, so no other limit is
+    stated. (The channel-minor output this replaced needed 16.3 MB and
+    was refused by the compiler on a v5e.) The kernel's planar result is moved to
+    the network's NDHWC layout by XLA outside it (a pure transpose:
+    the values are untouched)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -359,19 +379,19 @@ def _dct_convert_pallas(ycoef, ucoef, vcoef, rows_valid, height: int,
             const(ly.shape), const(lyt.shape), const(lcr.shape),
             const(lcct.shape),
         ],
-        out_specs=pl.BlockSpec((1, 1, height, width, 3),
+        out_specs=pl.BlockSpec((1, 1, 3, height, width),
                                lambda i, j, rv: (i, j, 0, 0, 0)),
     )
     out = pl.pallas_call(
         _dct_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (rows, frames, height, width, 3), dtype),
+            (rows, frames, 3, height, width), dtype),
         interpret=interpret,
     )(jnp.asarray(rows_valid, jnp.int32).reshape(1), ycoef, ucoef,
       vcoef, jnp.asarray(ly), jnp.asarray(lyt), jnp.asarray(lcr),
       jnp.asarray(lcct))
-    return out
+    return jnp.moveaxis(out, 2, -1)
 
 
 def _dct_convert_jnp(ycoef, ucoef, vcoef, height: int, width: int,
@@ -384,17 +404,40 @@ def _dct_convert_jnp(ycoef, ucoef, vcoef, height: int, width: int,
     import jax.numpy as jnp
 
     ly, lyt, lcr, lcct = _plane_bases(height, width)
-    return _frame_rgb_normalized(
+    return jnp.stack(_frame_rgb_planes(
         ycoef, ucoef, vcoef, jnp.asarray(ly), jnp.asarray(lyt),
-        jnp.asarray(lcr), jnp.asarray(lcct), dtype)
+        jnp.asarray(lcr), jnp.asarray(lcct), dtype), axis=-1)
 
 
-def _on_tpu() -> bool:
+def _dct_convert(ycoef, ucoef, vcoef, rows_valid, height: int,
+                 width: int, dtype, interpret: bool):
+    """Coefficient planes -> normalized NDHWC frames with rows
+    ``>= rows_valid`` exactly zero: the Pallas kernel where the
+    computation is compiled for a TPU (or under ``interpret``
+    anywhere), the masked jnp twin elsewhere — chosen at lowering time
+    by ``lax.platform_dependent``, i.e. by the device the operands live
+    on. A kernel Mosaic refuses raises; it never gives way to the
+    twin."""
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    import jax.numpy as jnp
+
+    if interpret:
+        return _dct_convert_pallas(ycoef, ucoef, vcoef, rows_valid,
+                                   height, width, dtype, True)
+
+    def kernel(ycoef, ucoef, vcoef, rows_valid):
+        return _dct_convert_pallas(ycoef, ucoef, vcoef, rows_valid,
+                                   height, width, dtype, False)
+
+    def twin(ycoef, ucoef, vcoef, rows_valid):
+        out = _dct_convert_jnp(ycoef, ucoef, vcoef, height, width, dtype)
+        rows = out.shape[0]
+        mask = jnp.arange(rows).reshape((rows, 1, 1, 1, 1)) < rows_valid
+        return jnp.where(mask, out, jnp.zeros((), out.dtype))
+
+    return jax.lax.platform_dependent(
+        ycoef, ucoef, vcoef, jnp.asarray(rows_valid, jnp.int32),
+        tpu=kernel, default=twin)
 
 
 def normalize_dct(pool, height: int, width: int, dtype=None,
@@ -410,12 +453,8 @@ def normalize_dct(pool, height: int, width: int, dtype=None,
 
     if dtype is None:
         dtype = jnp.bfloat16
-    ycoef, ucoef, vcoef = unpack_dct_rows(pool, height, width)
-    if interpret or _on_tpu():
-        return _dct_convert_pallas(ycoef, ucoef, vcoef,
-                                   pool.shape[0], height, width,
-                                   dtype, interpret)
-    return _dct_convert_jnp(ycoef, ucoef, vcoef, height, width, dtype)
+    return _dct_convert(*unpack_dct_rows(pool, height, width),
+                        pool.shape[0], height, width, dtype, interpret)
 
 
 def ragged_normalize_dct(pool, rows_valid, height: int, width: int,
@@ -432,14 +471,8 @@ def ragged_normalize_dct(pool, rows_valid, height: int, width: int,
 
     if dtype is None:
         dtype = jnp.bfloat16
-    ycoef, ucoef, vcoef = unpack_dct_rows(pool, height, width)
-    if interpret or _on_tpu():
-        return _dct_convert_pallas(ycoef, ucoef, vcoef, rows_valid,
-                                   height, width, dtype, interpret)
-    out = _dct_convert_jnp(ycoef, ucoef, vcoef, height, width, dtype)
-    rows = pool.shape[0]
-    mask = jnp.arange(rows).reshape((rows, 1, 1, 1, 1)) < rows_valid
-    return jnp.where(mask, out, jnp.zeros((), out.dtype))
+    return _dct_convert(*unpack_dct_rows(pool, height, width),
+                        rows_valid, height, width, dtype, interpret)
 
 
 # -- numpy oracle (tests only) ----------------------------------------

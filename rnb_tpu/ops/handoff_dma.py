@@ -38,10 +38,12 @@ ever materializing host memory. This module owns the *how*:
   representable values (tests/test_handoff.py), never used where
   bit-parity against an unsharded forward is claimed.
 
-Kernel lineage: the Pallas distributed right-permute exemplar
-(SNIPPETS.md [1]/[3]; jax.dev pallas/tpu/distributed) — semaphore
-pair in scratch, ``memory_space=ANY`` refs, ``DeviceIdType.MESH``
-neighbor addressing.
+Kernel lineage: the right-permute example of the public Pallas guide
+"Distributed Computing in Pallas for TPUs"
+(docs.jax.dev/en/latest/pallas/tpu/distributed.html) — semaphore
+pair in scratch, ``memory_space=pl.ANY`` refs, ``DeviceIdType.MESH``
+neighbor addressing, and that guide's neighbor barrier ahead of the
+first DMA.
 """
 
 from __future__ import annotations
@@ -54,12 +56,13 @@ from rnb_tpu.utils.lazy_jax import jax_numpy as _jax_numpy
 def dma_available() -> bool:
     """Is the Pallas remote-DMA path usable? Real TPU backends only —
     interpret mode cannot emulate cross-device semaphores, and the
-    CPU twin exists precisely so everything else stays testable."""
+    CPU twin exists precisely so everything else stays testable. The
+    ring collectives move mesh-sharded operands, and meshes are built
+    from the default backend's devices, so the default backend is the
+    platform they run on; a backend that fails to initialize raises
+    here, it does not answer "use the twin"."""
     jax, _ = _jax_numpy()
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _mesh_axis(mesh) -> Optional[str]:
@@ -101,25 +104,45 @@ def ring_shift_amount(src_sharding, dst_sharding) -> Optional[int]:
     return None
 
 
-def _pallas_shift_body(axis_name: str, n: int, shift: int):
+def _pallas_shift_body(axis_name: str, n: int, shift: int,
+                       collective_id: int = 0):
     """The Pallas remote-copy body for one core: DMA the whole local
     shard into the neighbor ``shift`` positions along the ring. Gated
-    to real TPU by the caller (``dma_available``)."""
+    to real TPU by the caller (``dma_available``).
+
+    ``collective_id`` names the barrier semaphore the kernel's entry
+    handshake uses. Back-to-back kernels of one multi-hop collective
+    take different ids: with a shared semaphore, a core already in hop
+    k+1 would signal a neighbor still waiting in hop k, which could
+    then start writing into a third core that has not entered hop k.
+    """
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from jax import lax
 
+    mesh_id = pltpu.DeviceIdType.MESH
+
     def kernel(input_ref, output_ref, send_sem, recv_sem):
         my_id = lax.axis_index(axis_name)
-        neighbor = lax.rem(my_id + shift, n)
+        receiver = lax.rem(my_id + shift, n)
+        sender = lax.rem(my_id - shift + n, n)
+        # Neither core may write into the other's output buffer before
+        # that core has entered the kernel and owns it: signal the core
+        # this one writes to and the core that writes here, then wait
+        # for both of their signals.
+        barrier = pltpu.get_barrier_semaphore()
+        for peer in (sender, receiver):
+            pltpu.semaphore_signal(barrier, inc=1, device_id=(peer,),
+                                   device_id_type=mesh_id)
+        pltpu.semaphore_wait(barrier, 2)
         copy = pltpu.make_async_remote_copy(
             src_ref=input_ref,
             dst_ref=output_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
-            device_id=(neighbor,),
-            device_id_type=pltpu.DeviceIdType.MESH,
+            device_id=(receiver,),
+            device_id_type=mesh_id,
         )
         copy.start()
         copy.wait()
@@ -127,14 +150,16 @@ def _pallas_shift_body(axis_name: str, n: int, shift: int):
     def body(x_shard):
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
         )
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct(x_shard.shape, x_shard.dtype),
             grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                collective_id=collective_id),
         )(x_shard)
 
     return body
@@ -155,11 +180,16 @@ def _ppermute_shift_body(axis_name: str, n: int, shift: int):
 
 
 def _one_step_shift_body(axis_name: str, n: int, use_pallas: bool):
-    """The shared ring primitive both collectives below ride: move
-    every core's buffer to its +1 neighbor — the Pallas remote-DMA
-    kernel on real TPU, the ppermute twin everywhere else."""
-    return (_pallas_shift_body(axis_name, n, 1) if use_pallas
-            else _ppermute_shift_body(axis_name, n, 1))
+    """The shared ring primitive both collectives below ride:
+    ``shift(buf, hop)`` moves every core's buffer to its +1 neighbor —
+    the Pallas remote-DMA kernel on real TPU (hop ``k`` of a
+    collective on barrier semaphore ``k``), the ppermute twin
+    everywhere else."""
+    if not use_pallas:
+        twin = _ppermute_shift_body(axis_name, n, 1)
+        return lambda buf, hop: twin(buf)
+    return lambda buf, hop: _pallas_shift_body(
+        axis_name, n, 1, collective_id=hop)(buf)
 
 
 def ring_all_gather_body(axis_name: str, n: int, axis: int = -1,
@@ -187,7 +217,7 @@ def ring_all_gather_body(axis_name: str, n: int, axis: int = -1,
             axis=ax)
         buf = x_shard
         for s in range(1, n):
-            buf = shift(buf)
+            buf = shift(buf, s - 1)
             # after s hops this core holds the shard that started on
             # core (idx - s) mod n — place it at that chunk's offset
             src = lax.rem(idx - s + n, n)
@@ -230,7 +260,7 @@ def ring_psum_scatter_body(axis_name: str, n: int, axis: int = -1,
         # at hop s adds chunk (j-1-s) mod n to the partial it received
         acc = piece(lax.rem(idx - 1 + n, n))
         for s in range(1, n):
-            acc = shift(acc)
+            acc = shift(acc, s - 1)
             acc = acc + piece(lax.rem(idx - 1 - s + 2 * n, n))
         return acc
 
@@ -244,10 +274,6 @@ def ring_all_gather(x, mesh, axis_name: Optional[str] = None,
     the unsharded array). ``use_pallas`` defaults to
     :func:`dma_available`."""
     jax, _ = _jax_numpy()
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        shard_map = jax.shard_map
     from jax.sharding import PartitionSpec
 
     if axis_name is None:
@@ -265,10 +291,11 @@ def ring_all_gather(x, mesh, axis_name: Optional[str] = None,
         use_pallas = dma_available()
     in_spec = [None] * x.ndim
     in_spec[ax] = axis_name
-    fn = shard_map(ring_all_gather_body(axis_name, n, axis=ax,
-                                        use_pallas=use_pallas),
-                   mesh=mesh, in_specs=PartitionSpec(*in_spec),
-                   out_specs=PartitionSpec(), check_rep=False)
+    fn = jax.shard_map(
+        ring_all_gather_body(axis_name, n, axis=ax,
+                             use_pallas=use_pallas),
+        mesh=mesh, in_specs=PartitionSpec(*in_spec),
+        out_specs=PartitionSpec(), check_vma=False)
     return jax.jit(fn)(x)
 
 
@@ -282,10 +309,6 @@ def ring_psum_scatter(x, mesh, axis_name: Optional[str] = None,
     The returned global array is the concatenation of those chunks
     (== the full sum)."""
     jax, _ = _jax_numpy()
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        shard_map = jax.shard_map
     from jax.sharding import PartitionSpec
 
     if axis_name is None:
@@ -313,9 +336,10 @@ def ring_psum_scatter(x, mesh, axis_name: Optional[str] = None,
 
     out_spec = [None] * (x.ndim - 1)
     out_spec[op_axis - 1] = axis_name
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=PartitionSpec(axis_name),
-                   out_specs=PartitionSpec(*out_spec), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=PartitionSpec(axis_name),
+                       out_specs=PartitionSpec(*out_spec),
+                       check_vma=False)
     return jax.jit(fn)(x)
 
 
@@ -327,10 +351,6 @@ def ring_shift(x, mesh, axis_name: Optional[str] = None, shift: int = 1,
     to :func:`dma_available` — the remote-DMA kernel on real TPU, the
     ppermute twin everywhere else."""
     jax, _ = _jax_numpy()
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax spells it jax.shard_map
-        shard_map = jax.shard_map
     from jax.sharding import PartitionSpec
 
     if axis_name is None:
@@ -347,8 +367,8 @@ def ring_shift(x, mesh, axis_name: Optional[str] = None, shift: int = 1,
     body = (_pallas_shift_body(axis_name, n, shift) if use_pallas
             else _ppermute_shift_body(axis_name, n, shift))
     spec = PartitionSpec(axis_name)
-    fn = shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
     return jax.jit(fn)(x)
 
 

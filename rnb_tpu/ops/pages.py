@@ -20,7 +20,7 @@ memcpy:
     BlockSpec's index_map picks each program's source page block from
     it (clamped for sentinel rows), and ``pl.when`` selects
     slab-vs-passthrough so sentinel programs never read the slab;
-  - **CPU / fallback**: a masked ``jnp`` formulation
+  - **any other platform**: a masked ``jnp`` formulation
     (:func:`gather_rows_reference`) with the identical contract;
   - **interpret mode**: the Pallas body runs on CPU via
     ``interpret=True`` and tests assert it matches the reference
@@ -51,14 +51,6 @@ from rnb_tpu.ops.ragged import LANES
 BLOCK_SUBLANES = 512
 
 
-def _on_tpu() -> bool:
-    import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 # -- reference (masked jnp) -------------------------------------------
 #
 # jax imports stay inside the functions: rnb-lint and config parsing
@@ -82,12 +74,35 @@ def gather_rows_reference(pool, slab, src_rows):
 
 
 @functools.lru_cache(maxsize=None)
-def _gather_reference_jit():
+def _gather_jit():
+    """The one jitted gather: the Pallas kernel where it is compiled
+    for a TPU and the rows are lane-divisible, the masked-jnp
+    reference elsewhere — decided at lowering time
+    (``lax.platform_dependent``) by the platform of the device the
+    pool lives on, so a kernel Mosaic refuses raises instead of
+    giving way to the twin."""
     import jax
-    return jax.jit(gather_rows_reference)
+
+    def gather(pool, slab, src_rows):
+        if _lane_divisible(pool):
+            return jax.lax.platform_dependent(
+                pool, slab, src_rows,
+                tpu=functools.partial(_gather_rows_pallas,
+                                      interpret=False),
+                default=gather_rows_reference)
+        return gather_rows_reference(pool, slab, src_rows)
+
+    return jax.jit(gather)
 
 
 # -- Pallas kernel -----------------------------------------------------
+
+def _lane_divisible(pool) -> bool:
+    """Can the kernel tile this pool's rows to (sublanes, LANES)?"""
+    import numpy as np
+    per_row = int(np.prod(pool.shape[1:])) if pool.ndim > 1 else 0
+    return per_row > 0 and per_row % LANES == 0
+
 
 def _gather_rows_kernel(src_ref, pool_ref, slab_ref, o_ref):
     """One (pool-row, sublane-chunk) program: copy the prefetched
@@ -158,19 +173,16 @@ def gather_rows(pool, slab, src_rows, interpret: bool = False):
     ``src_rows`` int32 ``(pool_rows,)`` with ``-1`` sentinels. The
     fixed-length source table is the signature discipline: every
     gather of a given (pool, slab) pair dispatches through one
-    compiled executable regardless of how many rows hit. Dispatches to
-    the Pallas kernel on TPU (or under ``interpret=True`` anywhere,
-    for tests) when the row byte count is lane-divisible; the jitted
-    masked-jnp reference otherwise.
+    compiled executable regardless of how many rows hit
+    (:func:`_gather_jit`; ``interpret=True`` runs the kernel body on
+    any platform, for tests).
     """
     import numpy as np
 
-    per_row = int(np.prod(pool.shape[1:])) if pool.ndim > 1 else 0
-    if (per_row > 0 and per_row % LANES == 0
-            and (interpret or _on_tpu())):
-        return _gather_rows_pallas(pool, slab, src_rows, interpret)
-    return _gather_reference_jit()(pool, slab,
-                                   np.asarray(src_rows, np.int32))
+    src_rows = np.asarray(src_rows, np.int32)
+    if interpret and _lane_divisible(pool):
+        return _gather_rows_pallas(pool, slab, src_rows, True)
+    return _gather_jit()(pool, slab, src_rows)
 
 
 # -- page writes -------------------------------------------------------

@@ -70,21 +70,20 @@ def _normalize_u8_pallas(x, dtype=jnp.bfloat16):
     return out.reshape(x.shape)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 def normalize_u8(x, dtype=jnp.bfloat16):
     """uint8 [0,255] frames -> ``dtype`` in [-1, 1].
 
     The single normalization every ingest path shares (pipeline loader
-    preprocess, sharded mesh step). Dispatches to the Pallas kernel on
-    TPU when the element count is lane-divisible, else to jnp.
+    preprocess, sharded mesh step). When the element count is
+    lane-divisible the choice between the Pallas kernel and jnp is made
+    at lowering time, by the platform the computation is compiled for
+    (``lax.platform_dependent``) — the device the operand lives on, not
+    the process default: a host-placed stage of a TPU process gets the
+    jnp form, and a TPU gets the kernel or, if Mosaic refuses it, the
+    compiler's error — never the twin.
     """
-    if x.dtype == jnp.uint8 and x.size > 0 and x.size % LANES == 0 \
-            and _on_tpu():
-        return _normalize_u8_pallas(x, dtype=dtype)
+    if x.dtype == jnp.uint8 and x.size > 0 and x.size % LANES == 0:
+        return jax.lax.platform_dependent(
+            x, tpu=functools.partial(_normalize_u8_pallas, dtype=dtype),
+            default=functools.partial(normalize_u8_reference, dtype=dtype))
     return normalize_u8_reference(x, dtype=dtype)

@@ -16,7 +16,7 @@ primitive masks/skips rows past ``rows_valid``:
   ``rows_valid`` is scalar-prefetched into SMEM and the grid's row
   programs use ``pl.when(row < rows_valid)`` so pad-row blocks execute
   a zero-store only, no arithmetic — zero padding FLOPs;
-* **CPU / fallback**: a masked ``jnp`` formulation with the identical
+* **any other platform**: a masked ``jnp`` formulation with the identical
   contract (valid rows bit-identical to the bucketed path's
   ``normalize_u8``; pad rows exactly zero), so the tier-1 harness
   exercises the same semantics the TPU kernel compiles;
@@ -208,14 +208,6 @@ def _ragged_normalize_pallas(pool, rows_valid, dtype, interpret: bool):
     return out.reshape(pool.shape)
 
 
-def _on_tpu() -> bool:
-    import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 def ragged_normalize_u8(pool, rows_valid, dtype=None,
                         interpret: bool = False):
     """uint8 row pool -> normalized ``dtype`` pool; pad rows zeroed.
@@ -223,10 +215,13 @@ def ragged_normalize_u8(pool, rows_valid, dtype=None,
     The ragged twin of ``ops.preprocess.normalize_u8``: valid rows are
     bit-identical to the bucketed preprocess applied to the same rows
     (same FMA-proof formulation); rows ``>= rows_valid`` come out
-    exactly zero without being read by any arithmetic. Dispatches to
-    the Pallas grid-skip kernel on TPU (or under ``interpret=True``
-    anywhere, for tests); the masked jnp formulation otherwise.
+    exactly zero without being read by any arithmetic. The Pallas
+    grid-skip kernel where the computation is compiled for a TPU (or
+    under ``interpret=True`` anywhere, for tests), the masked jnp
+    formulation elsewhere — chosen at lowering time by
+    ``lax.platform_dependent``, like ops.preprocess.normalize_u8.
     """
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -234,14 +229,22 @@ def ragged_normalize_u8(pool, rows_valid, dtype=None,
 
     if dtype is None:
         dtype = jnp.bfloat16
+
+    def masked(pool, rows_valid):
+        return jnp.where(_row_mask(pool, rows_valid),
+                         normalize_u8_reference(pool, dtype=dtype),
+                         jnp.zeros((), dtype))
+
     per_row = int(np.prod(pool.shape[1:])) if pool.ndim > 1 else 0
-    if (pool.dtype == jnp.uint8 and per_row > 0
-            and per_row % LANES == 0 and (interpret or _on_tpu())):
-        return _ragged_normalize_pallas(pool, rows_valid, dtype,
-                                        interpret)
-    return jnp.where(_row_mask(pool, rows_valid),
-                     normalize_u8_reference(pool, dtype=dtype),
-                     jnp.zeros((), dtype))
+    if not (pool.dtype == jnp.uint8 and per_row > 0
+            and per_row % LANES == 0):
+        return masked(pool, rows_valid)
+    if interpret:
+        return _ragged_normalize_pallas(pool, rows_valid, dtype, True)
+    return jax.lax.platform_dependent(
+        pool, jnp.asarray(rows_valid, jnp.int32),
+        tpu=lambda p, rv: _ragged_normalize_pallas(p, rv, dtype, False),
+        default=masked)
 
 
 def ragged_normalize_yuv420(pool, rows_valid, height: int, width: int,

@@ -1,9 +1,9 @@
 """On-device 4:2:0 ingest: packed YUV planes -> normalized bfloat16.
 
 The ``yuv420`` pixel path moves the per-pixel colourspace work off the
-host (the benchmark host's single CPU core is the throughput ceiling —
-see RESULTS.md) and onto the accelerator, where it fuses with the
-ingest normalization into one XLA kernel:
+host (round 5's single host core was the throughput ceiling: 2026-07,
+previous transport, not reproduced) and onto the accelerator, where it
+fuses with the ingest normalization into one XLA kernel:
 
     host:   y4m payload --pure byte gathers--> packed 4:2:0 planes
     wire:   1.5 bytes/pixel  (vs 3 for RGB u8, 6 for bf16 frames)

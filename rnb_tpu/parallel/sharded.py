@@ -117,11 +117,6 @@ class ShardedInference:
             self.batch_sharding = NamedSharding(mesh, P(dp_axis))
         self.logit_sharding = NamedSharding(mesh, P(dp_axis))
 
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
-
         hw = self.frame_hw
 
         def step(variables, vids, mask):
@@ -142,10 +137,13 @@ class ShardedInference:
             per_video = (logits * mask[..., None]).sum(axis=1)
             return jax.lax.psum(per_video, sp_axis)
 
-        sharded = shard_map(
+        # check_vma=False: the rgb ingest is a Pallas kernel where this
+        # compiles for a TPU, and pallas_call declares no varying-axes
+        # type for its output
+        sharded = jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P(dp_axis, sp_axis), P(dp_axis, sp_axis)),
-            out_specs=P(dp_axis))
+            out_specs=P(dp_axis), check_vma=False)
         if clip_pad == 0:
             self._run = jax.jit(sharded)
         else:

@@ -228,19 +228,19 @@ def make_sharded_apply(start: int, end: int, num_classes: int,
                        factored_shortcut: bool = False,
                        pixel_path: str = "rgb", ragged: bool = False,
                        axis_name: str = "tp"):
-    """The sharded twin of model._shared_apply: ONE jit whose ingest
-    (identical HLO to the unsharded applier's) runs replicated, then a
-    ``shard_map`` network body over the ring. A head range returns
+    """The sharded twin of model._shared_apply: ONE jitted
+    ``shard_map`` body over the ring — the ingest (identical HLO to
+    the unsharded applier's) on every member's replicated copy of the
+    input, then the sharded network. The ingest sits INSIDE the body
+    because it holds a Pallas kernel where this compiles for a TPU,
+    and the partitioner refuses a Mosaic kernel outside a shard_map
+    ("cannot be automatically partitioned"). A head range returns
     logits still CHANNEL-SHARDED on the class axis (merge them with
     :func:`make_merge` — the host-timed collective); a mid-pipeline
     range's output is already full-width (the last temporal gather
     reassembled it) and comes back replicated."""
     import jax
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        shard_map = jax.shard_map
     from rnb_tpu.models.r2p1d.network import (NUM_LAYERS,
                                               R2Plus1DClassifier)
 
@@ -286,23 +286,20 @@ def make_sharded_apply(start: int, end: int, num_classes: int,
             del rows_valid
             return x
 
-    def network(variables, xin):
-        return model.apply(variables, xin, train=False)
+    if ragged:
+        def network(variables, x, rows_valid):
+            return model.apply(variables, ingest(x, rows_valid),
+                               train=False)
+    else:
+        def network(variables, x):
+            return model.apply(variables, ingest(x, None), train=False)
 
     def build(variables_specs):
-        body = shard_map(
+        return jax.jit(jax.shard_map(
             network, mesh=mesh,
-            in_specs=(variables_specs, P()),
+            in_specs=(variables_specs,) + (P(),) * (2 if ragged else 1),
             out_specs=(P(None, axis_name) if head else P()),
-            check_rep=False)
-
-        if ragged:
-            def apply(variables, x, rows_valid):
-                return body(variables, ingest(x, rows_valid))
-        else:
-            def apply(variables, x):
-                return body(variables, ingest(x, None))
-        return jax.jit(apply)
+            check_vma=False))
 
     def applier_for(variables):
         return build(shard_param_specs(variables, axis_name))
@@ -319,14 +316,11 @@ def make_merge(mesh, axis_name: str = "tp"):
     source whatif's ``shard_degree`` vocabulary scales from."""
     import jax
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:
-        shard_map = jax.shard_map
     from rnb_tpu.ops.handoff_dma import ring_all_gather_body
 
     degree = int(mesh.shape[axis_name])
-    fn = shard_map(ring_all_gather_body(axis_name, degree, axis=-1),
-                   mesh=mesh, in_specs=P(None, axis_name),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(
+        ring_all_gather_body(axis_name, degree, axis=-1),
+        mesh=mesh, in_specs=P(None, axis_name), out_specs=P(),
+        check_vma=False)
     return jax.jit(fn)

@@ -3,8 +3,8 @@
 Round 5 measured the single bench-host core at 98% saturation with the
 two dominant terms being raw byte movement: ``device_put`` staging
 (49.3% of the window) and decode-output assembly + decode wait (22.1%)
-— see RESULTS.md round 5 and the motivation in ``rnb_tpu/cache.py``.
-The clip cache removes those terms for popularity-skewed *hits*; this
+— 2026-07, previous transport, not reproduced; see the motivation in
+``rnb_tpu/cache.py``. The clip cache removes those terms for popularity-skewed *hits*; this
 module removes them for the miss/uniform hot path itself:
 
 * **StagingPool** — per-(loader, bucket-shape) sets of pre-allocated

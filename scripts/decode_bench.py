@@ -1,8 +1,8 @@
 """Single-caller decode micro-benchmark for the native backend.
 
 Measures raw frames/s of the C++ decoder (native/decode.cpp) outside
-the pipeline — the number RESULTS.md quotes when attributing matrix-
-cell throughput to the host codec (the role NVDEC benchmarks filled
+the pipeline — the number to quote when attributing a cell's
+throughput to the host codec (the role NVDEC benchmarks filled
 for the reference's NVVL loader, reference README.md:42-110). Decodes
 every video in a dataset tree sequentially on the calling thread (no
 pool fan-out) so the figure is per-core codec speed, not concurrency.

@@ -46,7 +46,7 @@ def test_bench_prints_one_json_line(tmp_path):
     # this forced-CPU run must refuse the comparison and say why
     assert payload["platform"] == "cpu"
     assert payload["vs_baseline"] is None
-    assert "not the TPU plugin" in payload["note"]
+    assert "not a TPU" in payload["note"]
     assert payload["num_devices"] >= 1
     assert payload["num_videos"] == 6
     assert payload["config"].endswith("r2p1d-tiny.json")

@@ -235,7 +235,6 @@ def test_meter_mfu_against_hand_computed_dispatches():
 
 def test_compute_summary_cross_foots_bench_arithmetic():
     plane = DevObsPlane(DevObsSettings())
-    plane._peak_resolved = True
     plane._peak_tflops = 100.0  # pretend-device peak
     meter = StageComputeMeter(1, flops_per_row=1_000_000_000)
     meter.note(4, 2.0)
@@ -257,7 +256,6 @@ def test_compute_summary_cross_foots_bench_arithmetic():
 
 def test_compute_summary_without_peak_reports_sentinel():
     plane = DevObsPlane(DevObsSettings())
-    plane._peak_resolved = True
     plane._peak_tflops = None  # the CPU harness: no known peak
     meter = StageComputeMeter(0, flops_per_row=10)
     meter.note(1, 0.1)
@@ -268,7 +266,6 @@ def test_compute_summary_without_peak_reports_sentinel():
     # no meters at all: the record still exists (zero flops) so the
     # captures counter stays checkable on flops-less pipelines
     empty = DevObsPlane(DevObsSettings())
-    empty._peak_resolved = True
     empty._peak_tflops = None
     summary = empty.compute_summary(1.0, 1)
     assert summary["stages"] == 0 and summary["flops_total"] == 0

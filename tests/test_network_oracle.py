@@ -157,11 +157,13 @@ def test_golden_logits_fixture():
     with scripts/make_golden_logits.py when the architecture changes
     on purpose.
 
-    Provenance: regenerated 2026-08-04 for this image's flax/jax —
-    the prior fixture's logits were UNCORRELATED with the current
-    init at identical seeds (corr ~0.02, so flax changed how it
-    folds the init RNG, not the math; a precision drift would keep
-    the draws correlated). The network arithmetic itself is pinned
+    Provenance: regenerated in PR 21 (2026-09-26) on the CPU backend
+    under jax 0.9.0 / jaxlib 0.9.0 / flax 0.12.3, the installation the
+    sandbox and the chip machine share. The fixture depends on how
+    flax folds the init RNG, so it is tied to that installation: the
+    one it replaced, made 2026-08-04 under another flax, failed at
+    identical seeds with logits uncorrelated to these (corr 0.02:
+    other draws, not a precision drift). The network arithmetic itself is pinned
     independently of init by the numpy-oracle tests above, which
     feed IDENTICAL parameter arrays to both implementations."""
     golden = np.load(GOLDEN_PATH)
