@@ -1,0 +1,21 @@
+"""% of the classified stretch in which the chip ran no operation while the
+network's executor sat in `exec{K}.model_call` or `.device_sync`: a program was
+handed to the runtime and had not started, or had ended and the host had not
+woken yet (the time in the two spans less the chip's busy time).
+
+The stretch is what `benchmarks/hostspans.py` classifies: the first
+`exec{K}.model_call` to the last `exec{K}.device_sync` of the capture, not the
+window `device_idle_pct` divides by. The three `idle_*_pct` add up to the chip's
+idle share of it. None where the host spans fail the clock check."""
+
+NAME = "idle_launch_pct.bulk"
+UNIT = "%"
+BETTER = "lower"
+SOURCE = "program_span"
+LAYER = "device"
+MOVES = "videos_per_s"
+
+
+def read(facts):
+    from benchmarks import hostspans
+    return hostspans.idle_pct(facts, hostspans.LAUNCH)

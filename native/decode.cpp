@@ -31,6 +31,7 @@
 #include <sys/stat.h>
 
 #include <atomic>
+#include <chrono>
 #include <cmath>
 #include <condition_variable>
 #include <cstdint>
@@ -1229,6 +1230,10 @@ struct Pool {
   std::condition_variable cv_job, cv_done;
   long long next_ticket = 1;
   bool stopping = false;
+  // what the workers did since the pool was made (rnb_pool_stats):
+  // nanoseconds inside DecodeClips summed over the workers, and the
+  // frames of the jobs that succeeded
+  std::atomic<long long> busy_ns{0}, frames_done{0};
 
   explicit Pool(int n) {
     for (int i = 0; i < n; ++i)
@@ -1245,11 +1250,18 @@ struct Pool {
         job = std::move(jobs.front());
         jobs.pop_front();
       }
+      const auto t0 = std::chrono::steady_clock::now();
       const int rc = DecodeClips(
           job.path.c_str(), job.starts.data(),
           static_cast<int>(job.starts.size()), job.consecutive,
           job.out_w, job.out_h, job.out, job.pixfmt,
           job.dct_capacity);
+      busy_ns += std::chrono::duration_cast<std::chrono::nanoseconds>(
+                     std::chrono::steady_clock::now() - t0)
+                     .count();
+      if (rc == 0)
+        frames_done +=
+            static_cast<long long>(job.starts.size()) * job.consecutive;
       {
         std::lock_guard<std::mutex> lk(mu);
         done[job.ticket] = rc;
@@ -1430,6 +1442,15 @@ int rnb_pool_wait(void* pool, long long ticket) {
 int rnb_pool_peek(void* pool, long long ticket) {
   if (!pool || ticket <= 0) return kErrArg;
   return static_cast<Pool*>(pool)->Peek(ticket) ? 1 : 0;
+}
+
+// Totals since the pool was made; a reader takes two and subtracts.
+int rnb_pool_stats(void* pool, long long* busy_ns, long long* frames) {
+  if (!pool) return kErrArg;
+  Pool* p = static_cast<Pool*>(pool);
+  if (busy_ns) *busy_ns = p->busy_ns.load();
+  if (frames) *frames = p->frames_done.load();
+  return 0;
 }
 
 }  // extern "C"

@@ -35,7 +35,7 @@ RNB-C002
     shared attribute. Roles come from the existing seams: hotpath's
     executor roots (``HOT_ROOT_METHODS`` -> role ``hot``) and
     ``threading.Thread(target=self.x, name="...")`` entry points
-    (role = the thread-name prefix, the trace/hostprof convention),
+    (role = the thread-name prefix, the trace convention),
     propagated through self-method calls.
 RNB-C003
     A lock-owning class mutates attributes after ``__init__`` without
@@ -177,8 +177,8 @@ class _ClassContract:
 
 
 def _thread_role(name_literal: Optional[str]) -> str:
-    """Thread role from the ``name=`` literal the trace/hostprof seams
-    key on: the prefix before any per-instance numbering
+    """Thread role from the ``name=`` literal the trace seams key
+    on: the prefix before any per-instance numbering
     (``rnb-decode_3`` -> ``rnb-decode``)."""
     if not name_literal:
         return "worker"

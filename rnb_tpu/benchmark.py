@@ -79,6 +79,12 @@ class BenchmarkResult:
     #: pool) over the measured window; / total_time_s ~ host-core
     #: saturation on a 1-core host
     host_cpu_s: float = 0.0
+    #: the shared native decode pool over the measured window
+    #: (rnb_tpu.decode.native.DecodePool.stats): seconds its workers
+    #: spent inside the decoder summed over the workers, and frames
+    #: decoded. Both zero when the native pool decoded nothing.
+    decode_busy_s: float = 0.0
+    decode_frames: int = 0
     #: fault-containment accounting (rnb_tpu.faults): requests
     #: dead-lettered with a permanent failure, dropped by the "shed"
     #: overload policy, and transient retry attempts. Successfully
@@ -1050,12 +1056,7 @@ def run_benchmark(config_path: str,
         jax.block_until_ready(_marker(_marker_arg))
     import resource
 
-    from rnb_tpu import hostprof
-    if hostprof.ENABLED:
-        # scope the section accumulator to THIS measured window — a
-        # multi-run process (config sweep) must not fold earlier runs'
-        # totals (or this run's warmup) into this run's report
-        hostprof.reset()
+    from rnb_tpu.decode.native import DecodePool
     if tracer is not None:
         # occupancy sampling covers the measured window (plus the
         # short drain); started here so warm-up/compile never lands
@@ -1073,6 +1074,7 @@ def run_benchmark(config_path: str,
         devobs_plane.start()
     sta_bar.wait()
     ru_start = resource.getrusage(resource.RUSAGE_SELF)
+    decode_start = DecodePool.shared_stats()
     if devobs_plane is not None:
         devobs_plane.note_run_started()
     time_start = time.time()
@@ -1098,6 +1100,7 @@ def run_benchmark(config_path: str,
     ru_end = resource.getrusage(resource.RUSAGE_SELF)
     host_cpu_s = ((ru_end.ru_utime + ru_end.ru_stime)
                   - (ru_start.ru_utime + ru_start.ru_stime))
+    decode_end = DecodePool.shared_stats()
     total_time = time_end - time_start
     if xprof:
         jax.block_until_ready(_marker(_marker_arg))  # end-of-window mark
@@ -1968,17 +1971,6 @@ def run_benchmark(config_path: str,
               % (stacks_summary["samples"], stacks_summary["threads"],
                  stacks_summary["folded"], stacks_summary["total"]))
 
-    if hostprof.ENABLED:
-        lines = hostprof.report_lines(total_time)
-        with open(os.path.join(logroot(job_id, base=log_base),
-                               "hostprof.txt"), "w") as f:
-            f.write("# wall_s %.3f host_cpu_s %.3f host_cpu_frac %.3f\n"
-                    % (total_time, host_cpu_s,
-                       host_cpu_s / total_time if total_time else 0.0))
-            f.write("\n".join(lines) + "\n")
-        if print_progress:
-            print("\n".join(lines))
-
     return BenchmarkResult(
         job_id=job_id,
         total_time_s=total_time,
@@ -1994,6 +1986,8 @@ def run_benchmark(config_path: str,
         p99_latency_ms=p99,
         clips_completed=clips_completed,
         host_cpu_s=host_cpu_s,
+        decode_busy_s=decode_end["busy_s"] - decode_start["busy_s"],
+        decode_frames=decode_end["frames"] - decode_start["frames"],
         num_completed=num_completed,
         num_failed=num_failed,
         num_shed=num_shed,

@@ -297,8 +297,7 @@ class ClipCache:
         Used by the fusing loader, whose misses cross the wire inside a
         fused batch — there is no standalone padded device array to
         reuse, so the insert pays one extra transfer the first time a
-        video is seen (amortized away by every later hit; the
-        ``loader.cache_insert`` hostprof section accounts for it).
+        video is seen (amortized away by every later hit).
 
         Staging contract (rnb_tpu.staging): ``clips`` may be a view
         into a staging slot whose buffer is recycled after the fused

@@ -102,7 +102,7 @@ def _client(video_path_iterator_path: str, filename_queue: "queue.Queue",
                         + deadline_budget_s
                 # flow anchor for the request's cross-stage trace
                 # chain + an event-driven arrival-rate counter track
-                # (rnb_tpu.trace; one None test each when tracing off)
+                # (rnb_tpu.trace)
                 trace.instant("client.enqueue", rid=video_count)
                 trace.counter("client.enqueued", video_count + 1)
                 # live-metrics arrival feed (rnb_tpu.metrics): the

@@ -33,6 +33,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Tuple
 
+from rnb_tpu import trace
+
 
 def signature_of(*arrays) -> tuple:
     """The jit-entry signature of a positional array argument list:
@@ -47,6 +49,14 @@ def signature_of(*arrays) -> tuple:
         else:
             sig.append((tuple(int(d) for d in shape), str(dtype)))
     return tuple(sig)
+
+
+def _signature_text(sig: tuple) -> str:
+    """``8x32x18816:uint8 scalar:float32``-style text of a signature:
+    no comma, ``#`` or ``=``, which delimit a profiler annotation's
+    stats."""
+    return " ".join(("x".join(map(str, part[0])) or "scalar") + ":" + part[1]
+                    if len(part) == 2 else part[0] for part in sig)
 
 
 class SignatureTracker:
@@ -82,11 +92,15 @@ class SignatureTracker:
                 self._warmup.add(sig)
                 return
             self._steady_calls += 1
-            if sig not in self._warmup:
-                # a signature warmup never saw: this dispatch is (or
-                # would be, modulo the persistent cache) a mid-run
-                # compile
-                self._steady_new.add(sig)
+            if sig in self._warmup or sig in self._steady_new:
+                return
+            # a signature warmup never saw: this dispatch is (or
+            # would be, modulo the persistent cache) a mid-run
+            # compile
+            self._steady_new.add(sig)
+        # a traced run shows which step recompiled and when: the
+        # instant lies inside that step's model_call span
+        trace.instant("compile.steady", signature=_signature_text(sig))
 
     def freeze(self) -> None:
         """The measured window opened: signatures from here on must

@@ -1258,9 +1258,9 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                 "remote path bypasses the runner's segment split")
         _expect(not (isinstance(trace, dict)
                      and trace.get("enabled", True)),
-                "'netedge' cannot be combined with 'trace': remote "
-                "emissions lack the trace-mode decode stamps, so the "
-                "per-request timing tables would mix two schemas")
+                "'netedge' cannot be combined with 'trace': the peer "
+                "process has no Tracer, so the job's trace.json would "
+                "lack the remote requests' spans")
         _expect(not (isinstance(ragged, dict)
                      and ragged.get("enabled", True)),
                 "'netedge' cannot be combined with 'ragged': the "

@@ -8,7 +8,7 @@ is still a human scrolling Perfetto. This module recovers, for every
 completed request, the **blocking chain**: the unique sequence of
 segments that actually gated its completion, derived from the same
 TimeCard stamps the phase attribution walks (so it works on any past
-log directory) and refined by the trace-mode stamps where present.
+log directory) and refined by the loader's phase stamps where present.
 Segments carry both a *class* — ``queue_wait`` (starved behind a
 queue), ``decode``, ``hold`` (batch-fill wait), ``transfer``,
 ``service``, ``drain`` (publish/pickup) — and the *pipeline step* they

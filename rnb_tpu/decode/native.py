@@ -77,7 +77,8 @@ def load_native():
                     "rnb_pool_destroy", "rnb_pool_submit",
                     "rnb_pool_submit_fmt", "rnb_pool_wait",
                     "rnb_pool_peek", "rnb_video_probe",
-                    "rnb_y4m_decode_clips_dct", "rnb_pool_submit_dct"):
+                    "rnb_y4m_decode_clips_dct", "rnb_pool_submit_dct",
+                    "rnb_pool_stats"):
             if not hasattr(lib, sym):
                 return None
         lib.rnb_y4m_probe.restype = ctypes.c_int
@@ -104,6 +105,9 @@ def load_native():
         lib.rnb_pool_wait.argtypes = [ctypes.c_void_p, ctypes.c_longlong]
         lib.rnb_pool_peek.restype = ctypes.c_int
         lib.rnb_pool_peek.argtypes = [ctypes.c_void_p, ctypes.c_longlong]
+        lib.rnb_pool_stats.restype = ctypes.c_int
+        lib.rnb_pool_stats.argtypes = [
+            ctypes.c_void_p] + [ctypes.POINTER(ctypes.c_longlong)] * 2
         lib.rnb_y4m_decode_clips_fmt.restype = ctypes.c_int
         lib.rnb_y4m_decode_clips_fmt.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_longlong),
@@ -311,6 +315,27 @@ class DecodePool:
         # `buffers` pins (out, starts) until the native workers finish
         _check(self._lib.rnb_pool_wait(self._pool, ticket), path)
         del buffers
+
+    def stats(self) -> dict:
+        """What the workers did since the pool was made:
+        ``busy_s`` (seconds inside the decoder, summed over the
+        workers) and ``frames`` of the jobs that succeeded. A reader
+        takes two and subtracts."""
+        busy, frames = ctypes.c_longlong(), ctypes.c_longlong()
+        _check(self._lib.rnb_pool_stats(
+            self._pool, ctypes.byref(busy), ctypes.byref(frames)),
+            "<pool stats>")
+        return {"busy_s": busy.value / 1e9, "frames": frames.value}
+
+    @classmethod
+    def shared_stats(cls) -> dict:
+        """:meth:`stats` of the shared pool; zeros while the process
+        has made none (no native library, or no decode yet)."""
+        with cls._shared_lock:
+            pool = cls._shared
+        if pool is None:
+            return {"busy_s": 0.0, "frames": 0}
+        return pool.stats()
 
     def close(self) -> None:
         if self._pool:

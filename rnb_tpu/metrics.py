@@ -43,9 +43,8 @@ live*, in three pieces:
   metric window around the trigger embedded, so the PR 10 chaos
   incidents leave a black-box postmortem, not just counters.
 
-Cost discipline: like :mod:`rnb_tpu.trace` and :mod:`rnb_tpu.hostprof`,
-the disabled path of every module-level hook is one module-global
-``None`` test and no allocation (rnb-lint hot-path enforced). With the
+Cost discipline: the disabled path of every module-level hook is one
+module-global ``None`` test and no allocation (rnb-lint hot-path enforced). With the
 ``metrics`` root key absent nothing is installed, no new log-meta line
 is written, and every artifact stays byte-identical to the pre-metrics
 schema.
@@ -377,8 +376,9 @@ class SpanBridge:
         #: so this lands in the dump's dropped_events count
         self.ring_evicted = 0
 
-    def span(self, event_name: str, rid: Optional[int] = None):
-        return trace_mod._Span(self, event_name, rid)
+    def span(self, event_name: str, rid: Optional[int] = None,
+             counts: Optional[dict] = None):
+        return trace_mod._Span(self, event_name, rid, counts)
 
     def add_event(self, event_name: str, ph: str, t0: float,
                   dur: float, rid: Optional[int],

@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from rnb_tpu import hostprof, trace
+from rnb_tpu import trace
 from rnb_tpu.autotune import BatchController
 from rnb_tpu.cache import content_key
 from rnb_tpu.compilestats import SignatureTracker
@@ -517,11 +517,12 @@ class R2P1DLoader(StageModel):
                 raise ValueError("fallback_decode_threads must be >= 1, "
                                  "got %r" % (fallback_decode_threads,))
         self._starts_cache = {}  # video -> clip starts (see _sample_starts)
-        #: pipeline-step index when the job traces (rnb_tpu.trace):
-        #: set via enable_trace(), gates the phase-refinement stamps
-        #: (decode{step}_done / transfer{step}_start/_done) so
-        #: trace-off runs keep the pre-trace stamp schema byte-stable
-        self._trace_step: Optional[int] = None
+        #: this stage's pipeline-step index, handed over by the
+        #: executor (bind_step): names the phase-refinement stamps
+        #: (decode{step}_done / transfer{step}_start/_done) every
+        #: request carries. None only for a loader driven without an
+        #: executor (unit tests), which then writes none
+        self._stamp_step: Optional[int] = None
         # Zero-copy decode staging (rnb_tpu.staging): pre-allocated
         # host slots the native decoder writes straight into, removing
         # the per-request/per-emission bucket-shaped allocation and
@@ -687,24 +688,27 @@ class R2P1DLoader(StageModel):
                 print("[rnb-tpu] WARNING: decode warm-up skipped %s: %s"
                       % (path, e))
 
+    def bind_step(self, step_idx: int) -> None:
+        """Executor protocol (rnb_tpu.runner): the stage learns its
+        step index, which names the per-request phase-refinement
+        stamps. Called on every run."""
+        self._stamp_step = int(step_idx)
+
     def enable_trace(self, tracer, step_idx: int) -> None:
-        """Executor protocol (rnb_tpu.runner): turn on the per-request
-        phase-refinement stamps and register this stage's sampled
-        occupancy sources with the job tracer. Called only on
-        trace-enabled runs."""
-        self._trace_step = int(step_idx)
+        """Executor protocol (rnb_tpu.runner): register this stage's
+        sampled occupancy sources with the job tracer. Called only on
+        runs with the ``trace`` config key."""
         if self.staging is not None:
             tracer.add_counter_source(
                 trace.name("staging.s%d.free", step_idx),
                 self.staging.available)
 
     def _stamp_decode_done(self, time_card) -> None:
-        """Phase-refinement: this request's decode completed (trace
-        mode only — one None test otherwise)."""
-        if self._trace_step is None:
+        """Phase-refinement: this request's decode completed."""
+        if self._stamp_step is None:
             return
         _record_clamped(time_card,
-                        "decode%d_done" % self._trace_step, time.time())
+                        "decode%d_done" % self._stamp_step, time.time())
         trace.instant("loader.decode_ready", rid=time_card.id)
 
     def _staging_default_slots(self) -> int:
@@ -929,7 +933,7 @@ class R2P1DLoader(StageModel):
         file's frame count is fixed, so a repeated id re-derives
         identical starts; before caching, the probe+sample path cost
         ~200 us/request = 20% of the host core at ~1k videos/s
-        (hostprof, round 5). A file replaced on disk mid-run keeps its
+        (host profile, round 5). A file replaced on disk mid-run keeps its
         cached starts — benchmark semantics, same as the native
         decoder's per-video metadata caches."""
         starts = self._starts_cache.get(video)
@@ -944,7 +948,7 @@ class R2P1DLoader(StageModel):
 
     def _cache_lookup(self, video: str, key=None):
         """(key, entry) for one request — (None, None) when caching is
-        off. Counted and hostprof-sectioned: the lookup (one stat + one
+        off. Counted: the lookup (one stat + one
         dict probe) is the only cost a cache-enabled miss adds. Under
         a paged cache the hit value is a pinned GatherPlan
         (rnb_tpu.cache.ClipCache.acquire), not a blob entry. ``key``
@@ -952,13 +956,12 @@ class R2P1DLoader(StageModel):
         computed it (the feature-page probe)."""
         if self.cache is None:
             return None, None
-        with hostprof.section("loader.cache_lookup"):
-            if key is None:
-                key = content_key(video, self._cache_cfg)
-            if self.cache.paged:
-                entry = self.cache.acquire(key)
-            else:
-                entry = self.cache.lookup(key)
+        if key is None:
+            key = content_key(video, self._cache_cfg)
+        if self.cache.paged:
+            entry = self.cache.acquire(key)
+        else:
+            entry = self.cache.lookup(key)
         return key, entry
 
     def _feature_probe(self, video: str):
@@ -999,19 +1002,19 @@ class R2P1DLoader(StageModel):
         if self.ragged:
             if self.ragged_stats is not None:
                 self.ragged_stats["cache_hit_rows"] += entry.valid
-            if self._trace_step is not None:
+            if self._stamp_step is not None:
                 _record_clamped(time_card, "decode%d_done"
-                                % self._trace_step, time.time())
+                                % self._stamp_step, time.time())
             if self.cache.paged:
                 return self._materialize_pages(entry, time_card)
             return self._materialize(entry.batch, entry.valid,
                                      time_card)
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             # a hit pays no decode/hold/transfer: zero-length phases
             # keep every card's key sequence identical per instance
             # (TimeCardSummary asserts one schema per run)
             now = time.time()
-            step = self._trace_step
+            step = self._stamp_step
             _record_clamped(time_card, "decode%d_done" % step, now)
             _record_clamped(time_card, "transfer%d_start" % step, now)
             _record_clamped(time_card, "transfer%d_done" % step, now)
@@ -1030,18 +1033,17 @@ class R2P1DLoader(StageModel):
         The gather feeds the identical normalize dispatch a miss
         feeds, so hit/miss logits stay bit-identical."""
         n = plan.valid
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             # no transfer happens: zero-length phases keep the card's
             # key sequence identical to a miss (TimeCardSummary
             # asserts one schema per step instance)
             now = time.time()
-            step = self._trace_step
+            step = self._stamp_step
             _record_clamped(time_card, "transfer%d_start" % step, now)
             _record_clamped(time_card, "transfer%d_done" % step, now)
         src = np.full((self.pool_rows,), -1, np.int32)
         src[:n] = plan.src_rows
-        with hostprof.section("loader.cache_gather"):
-            device_u8 = self._clip_arena.gather(self._zero_pool, src)
+        device_u8 = self._clip_arena.gather(self._zero_pool, src)
         plan.release()
         if self.staging is not None:
             self.staging.note_bypassed()
@@ -1061,9 +1063,9 @@ class R2P1DLoader(StageModel):
         time_card.num_clips = n
         time_card.feature_hit = True
         time_card.feature_plan = plan
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             now = time.time()
-            step = self._trace_step
+            step = self._stamp_step
             _record_clamped(time_card, "decode%d_done" % step, now)
             _record_clamped(time_card, "transfer%d_start" % step, now)
             _record_clamped(time_card, "transfer%d_done" % step, now)
@@ -1127,13 +1129,11 @@ class R2P1DLoader(StageModel):
         """The raw async-decode kickoff behind :meth:`submit` — no
         cache interaction (the fusing loader runs its own lookup and
         coalescing around this)."""
-        with hostprof.section("loader.probe+sample"):
-            decoder = get_decoder(video)
-            starts = self._sample_starts(decoder, video)
+        decoder = get_decoder(video)
+        starts = self._sample_starts(decoder, video)
         n = len(starts)
         time_card.num_clips = n
-        # flow anchor: decode kicked off for this request (one None
-        # test when tracing is off, rnb_tpu.trace)
+        # flow anchor: decode kicked off for this request
         trace.instant("loader.decode_submit", rid=time_card.id)
         # trust the backend get_decoder() chose: a .y4m path whose file
         # vanished resolves to SyntheticDecoder there, and submitting it
@@ -1146,13 +1146,12 @@ class R2P1DLoader(StageModel):
             pool = DecodePool.shared()
             tickets = []
             try:
-                with hostprof.section("loader.pool_submit"):
-                    for lo in range(0, n, self.POOL_CHUNK_CLIPS):
-                        hi = min(lo + self.POOL_CHUNK_CLIPS, n)
-                        tickets.append(pool.submit_into(
-                            video, starts[lo:hi], self.consecutive_frames,
-                            out[lo:hi], pixfmt=pixfmt, width=FRAME_HW,
-                            height=FRAME_HW))
+                for lo in range(0, n, self.POOL_CHUNK_CLIPS):
+                    hi = min(lo + self.POOL_CHUNK_CLIPS, n)
+                    tickets.append(pool.submit_into(
+                        video, starts[lo:hi], self.consecutive_frames,
+                        out[lo:hi], pixfmt=pixfmt, width=FRAME_HW,
+                        height=FRAME_HW))
             except Exception:
                 # a partial submit must not leak the earlier tickets —
                 # un-waited tickets pin the batch buffer in the pool's
@@ -1217,32 +1216,29 @@ class R2P1DLoader(StageModel):
             # ragged entries are host row extents (exactly n rows,
             # no pool padding) — copied out here, before the transfer,
             # while the decode buffer is live
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_rows(cache_key, clips, n)
-        if self._trace_step is not None:
+            self.cache.insert_rows(cache_key, clips, n)
+        if self._stamp_step is not None:
             _record_clamped(time_card,
-                            "transfer%d_start" % self._trace_step,
+                            "transfer%d_start" % self._stamp_step,
                             time.time())
         with trace.span("loader.transfer", time_card.id):
             device_u8 = jax.device_put(padded, self._jax_device)
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             _record_clamped(time_card,
-                            "transfer%d_done" % self._trace_step,
+                            "transfer%d_done" % self._stamp_step,
                             time.time())
         if cache_key is not None and self.cache is not None \
                 and self.ragged and self.cache.paged:
             # paged insert is post-transfer DEVICE work (insert-after-
             # success and zero extra host copies): pool rows [0, n)
             # publish into pages by donated on-device writes
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_pages(cache_key, device_u8, 0, n)
+            self.cache.insert_pages(cache_key, device_u8, 0, n)
             self._stamp_feature_insert(time_card, cache_key, 0, n)
         if cache_key is not None and self.cache is not None \
                 and not self.ragged:
             # zero-copy insert: the padded device array IS the cached
             # value (immutable jax.Array) — no extra transfer
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_device(cache_key, device_u8, n)
+            self.cache.insert_device(cache_key, device_u8, n)
         self._note_emission_padding(n, int(target[0]), [time_card])
         batch = self._normalize_emission(device_u8, n)
         return (self._wrap_batch(batch, n),), None, time_card
@@ -1269,36 +1265,32 @@ class R2P1DLoader(StageModel):
                 and self.ragged and not self.cache.paged:
             # ragged entries are host row extents, copied out of the
             # slot while its rows are still live (pre-handoff)
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_rows(cache_key, slot.buf, n)
+            self.cache.insert_rows(cache_key, slot.buf, n)
         self.staging.begin_transfer(slot)
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             _record_clamped(time_card,
-                            "transfer%d_start" % self._trace_step,
+                            "transfer%d_start" % self._stamp_step,
                             time.time())
-        with hostprof.section("loader.device_put"), \
-                trace.span("loader.transfer", time_card.id):
+        with trace.span("loader.transfer", time_card.id):
             device_u8 = jax.device_put(slot.buf, self._jax_device)
         self.staging.finish_transfer(slot, device_u8)
         self.staging.note_staged()
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             _record_clamped(time_card,
-                            "transfer%d_done" % self._trace_step,
+                            "transfer%d_done" % self._stamp_step,
                             time.time())
         self._release_handle_slot(handle)
         if cache_key is not None and self.cache is not None \
                 and self.ragged and self.cache.paged:
             # paged insert, post-transfer (see _materialize)
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_pages(cache_key, device_u8, 0, n)
+            self.cache.insert_pages(cache_key, device_u8, 0, n)
             self._stamp_feature_insert(time_card, cache_key, 0, n)
         if cache_key is not None and self.cache is not None \
                 and not self.ragged:
             # still zero-copy: the cached device array owns its bytes
             # once the transfer is confirmed; the slot recycle gate
             # (and the alias probe behind it) guarantees exactly that
-            with hostprof.section("loader.cache_insert"):
-                self.cache.insert_device(cache_key, device_u8, n)
+            self.cache.insert_device(cache_key, device_u8, n)
         self._note_emission_padding(n, int(device_u8.shape[0]),
                                     [time_card])
         return (self._wrap_batch(self._normalize_emission(device_u8, n),
@@ -1580,8 +1572,8 @@ class R2P1DFusingLoader(R2P1DLoader):
         return self.autotune
 
     def enable_trace(self, tracer, step_idx: int) -> None:
-        """On top of the base wiring (refinement stamps + staging
-        occupancy): sample this stage's decode window — decodes in
+        """On top of the base wiring (staging occupancy): sample
+        this stage's decode window — decodes in
         flight plus decoded-but-unemitted requests (deque len reads
         are GIL-atomic, safe from the sampler thread)."""
         super().enable_trace(tracer, step_idx)
@@ -1754,8 +1746,8 @@ class R2P1DFusingLoader(R2P1DLoader):
             return self._emit_take()
 
     def _emit_take(self) -> bool:
-        """:meth:`_emit` body (split out so the traced path can wrap
-        the whole take/assemble/handoff in one timeline span)."""
+        """:meth:`_emit` body (split out so that one span wraps the
+        whole take/assemble/handoff)."""
         cap = self.max_clips
         take, rows = [], 0
         while self._ready and len(take) < self.fuse:
@@ -1775,14 +1767,6 @@ class R2P1DFusingLoader(R2P1DLoader):
         # max_clips); a silent min() here would mask clip loss instead
         # of surfacing the broken invariant
         assert rows <= cap, (rows, cap)
-        if hostprof.ENABLED:
-            # batch-hold accounting: how long the oldest taken request
-            # sat ready waiting for batchmates — the fill-wait half of
-            # the latency/throughput trade, split out of emit_wait so
-            # hostprof tables distinguish "holding for a batch" from
-            # "waiting on decode"
-            hostprof.add("loader.hold_wait",
-                         max(0.0, time.monotonic() - take[0].t_ready))
         for rec in take:
             if rec.handle.slot is not None \
                     and rec.handle.slot is self._open_slot:
@@ -1792,7 +1776,7 @@ class R2P1DFusingLoader(R2P1DLoader):
                 self._open_slot = None
                 break
         ok = []
-        with hostprof.section("loader.emit_wait"):
+        with trace.span("loader.emit_wait"):
             for rec in take:
                 if self._wait_contained(rec):
                     ok.append(rec)
@@ -1823,7 +1807,7 @@ class R2P1DFusingLoader(R2P1DLoader):
         # decide() budgets against slo_ms alongside the residual-fill
         # wait
         t_close = time.monotonic()
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             # phase-refinement stamps for every card shipping in this
             # emission: its decode ended at the record's harvest
             # instant (epoch-converted from the monotonic t_ready, and
@@ -1832,7 +1816,7 @@ class R2P1DFusingLoader(R2P1DLoader):
             # batch just closed and the transfer path begins
             now_epoch = time.time()
             now_mono = time.monotonic()
-            step = self._trace_step
+            step = self._stamp_step
             for rec in ok:
                 decoded_at = now_epoch - max(0.0, now_mono - rec.t_ready)
                 for tc in rec.cards:
@@ -1871,18 +1855,17 @@ class R2P1DFusingLoader(R2P1DLoader):
             # rows, no bucket padding, no insert-time device_put —
             # hits re-enter the pool fill); bucketed entries stay the
             # padded device batch hits serve zero-copy.
-            with hostprof.section("loader.cache_insert"):
-                for rec in ok:
-                    if rec.key is not None:
-                        n = rec.handle.n
-                        if self.ragged:
-                            self.cache.insert_rows(rec.key,
-                                                   rec.handle.out, n)
-                        else:
-                            self.cache.insert_host(
-                                rec.key, rec.handle.out, n,
-                                self._batch_shape(self._bucket_for(n)),
-                                dtype=self._wire_dtype)
+            for rec in ok:
+                if rec.key is not None:
+                    n = rec.handle.n
+                    if self.ragged:
+                        self.cache.insert_rows(rec.key,
+                                               rec.handle.out, n)
+                    else:
+                        self.cache.insert_host(
+                            rec.key, rec.handle.out, n,
+                            self._batch_shape(self._bucket_for(n)),
+                            dtype=self._wire_dtype)
         cards = []
         for rec in ok:
             cards.extend(rec.cards)
@@ -1955,28 +1938,25 @@ class R2P1DFusingLoader(R2P1DLoader):
                 staged = False
             if staged:
                 if bucket > rows and not self.ragged:
-                    with hostprof.section("loader.emit_copy"):
-                        # seed byte parity: padding rows stay zeroed.
-                        # Under ragged the consumer's kernel masks the
-                        # pool tail, so the memset is skipped
-                        slot.buf[rows:bucket] = 0
+                    # seed byte parity: padding rows stay zeroed.
+                    # Under ragged the consumer's kernel masks the
+                    # pool tail, so the memset is skipped
+                    slot.buf[rows:bucket] = 0
                 self.staging.note_staged()
                 return slot.buf[:bucket], slot
-        with hostprof.section("loader.emit_alloc"):
-            # copy fallback (RNB-H007 baselined): rows [0, rows) are
-            # overwritten below; only the padding tail needs zeroing
-            out = np.empty(self._batch_shape(bucket),
-                           dtype=self._wire_dtype)
+        # copy fallback (RNB-H007 baselined): rows [0, rows) are
+        # overwritten below; only the padding tail needs zeroing
+        out = np.empty(self._batch_shape(bucket),
+                       dtype=self._wire_dtype)
         row = 0
-        with hostprof.section("loader.emit_copy"):
-            for rec in ok:
-                n = rec.handle.n
-                out[row:row + n] = rec.handle.out[:n]
-                row += n
-            if row < out.shape[0] and not self.ragged:
-                # ragged consumers mask the pool tail in-jit; only the
-                # bucketed path needs zeroed padding bytes
-                out[row:] = 0
+        for rec in ok:
+            n = rec.handle.n
+            out[row:row + n] = rec.handle.out[:n]
+            row += n
+        if row < out.shape[0] and not self.ragged:
+            # ragged consumers mask the pool tail in-jit; only the
+            # bucketed path needs zeroed padding bytes
+            out[row:] = 0
         for rec in ok:
             # rows copied out: slot references retire immediately
             self._release_handle_slot(rec.handle)
@@ -1996,16 +1976,14 @@ class R2P1DFusingLoader(R2P1DLoader):
             src = np.full((int(batch.shape[0]),), -1, np.int32)
             for row0, plan in gather_plans:
                 src[row0:row0 + plan.valid] = plan.src_rows
-            with hostprof.section("loader.cache_gather"):
-                batch = self._clip_arena.gather(batch, src)
+            batch = self._clip_arena.gather(batch, src)
             for _, plan in gather_plans:
                 # dispatched: the gather captured the slab value, so
                 # the pins can release (rnb_tpu.pager limbo rule)
                 plan.release()
         if insert_jobs:
-            with hostprof.section("loader.cache_insert"):
-                for key, row0, n in insert_jobs:
-                    self.cache.insert_pages(key, batch, row0, n)
+            for key, row0, n in insert_jobs:
+                self.cache.insert_pages(key, batch, row0, n)
         return batch
 
     def _transfer_sync(self, out, slot, rows: int, cards,
@@ -2017,23 +1995,21 @@ class R2P1DFusingLoader(R2P1DLoader):
         confirmed lazily at the slot's next acquire, so the executor
         still never blocks on transfer completion."""
         jax, _ = _jax_numpy()
-        with hostprof.section("loader.device_put"), \
-                trace.span("loader.transfer"):
+        with trace.span("loader.transfer"):
             batch = jax.device_put(out, self._jax_device)
         if slot is not None:
             self.staging.finish_transfer(slot, batch)
         if gather_plans is not None or insert_jobs is not None:
             batch = self._overlay_pages(batch, gather_plans,
                                         insert_jobs)
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             at = time.time()
             for tc in cards:
-                _record_clamped(tc, "transfer%d_done" % self._trace_step,
+                _record_clamped(tc, "transfer%d_done" % self._stamp_step,
                                 at)
         if self._preprocess is not None or \
                 self._preprocess_ragged is not None:
-            with hostprof.section("loader.preprocess_dispatch"):
-                batch = self._normalize_emission(batch, rows)
+            batch = self._normalize_emission(batch, rows)
         self._push_ready(((self._wrap_batch(batch, rows, offsets),),
                           None, TimeCardList(cards)),
                          bucket, time.monotonic() - t_close)
@@ -2047,24 +2023,21 @@ class R2P1DFusingLoader(R2P1DLoader):
         confirm completion (alias-probed) before releasing the slot's
         transfer hold. Runs off the executor thread."""
         jax, _ = _jax_numpy()
-        with hostprof.section("transfer.device_put"), \
-                trace.span("loader.transfer"):
+        with trace.span("loader.transfer"):
             batch = jax.device_put(out, self._jax_device)
         if slot is not None:
-            with hostprof.section("transfer.confirm"):
-                self.staging.confirm_now(slot, batch)
+            self.staging.confirm_now(slot, batch)
         if gather_plans is not None or insert_jobs is not None:
             batch = self._overlay_pages(batch, gather_plans,
                                         insert_jobs)
-        if self._trace_step is not None:
+        if self._stamp_step is not None:
             at = time.time()
             for tc in cards:
-                _record_clamped(tc, "transfer%d_done" % self._trace_step,
+                _record_clamped(tc, "transfer%d_done" % self._stamp_step,
                                 at)
         if self._preprocess is not None or \
                 self._preprocess_ragged is not None:
-            with hostprof.section("transfer.preprocess_dispatch"):
-                batch = self._normalize_emission(batch, rows)
+            batch = self._normalize_emission(batch, rows)
         self._push_ready(((self._wrap_batch(batch, rows, offsets),),
                           None, TimeCardList(cards)),
                          bucket, time.monotonic() - t_close)
@@ -2230,10 +2203,8 @@ class R2P1DFusingLoader(R2P1DLoader):
                 handle.gather_plan = entry
             else:
                 # blob hit: the decode is skipped; the memcpy into
-                # the slot slice is the whole cost (its own hostprof
-                # section, split from the lookup above)
-                with hostprof.section("loader.cache_gather"):
-                    np.copyto(target, entry.batch[:n])
+                # the slot slice is the whole cost
+                np.copyto(target, entry.batch[:n])
                 handle = _DecodeHandle(target, n, slot=hit_slot,
                                        row0=hit_row0)
             self._stamp_decode_done(time_card)
@@ -2640,9 +2611,8 @@ class R2P1DRunner(StageModel):
                        sh_bytes / 2**20, pool_bytes / 2**20,
                        feasible if feasible is not None else "none"))
         #: set by the executor's bind_shard_step() so the merge
-        #: collective's hostprof section / trace span carry the step
-        #: index even on trace-disabled runs
-        self._sec_collective = None
+        #: collective's trace span carries the step index even on
+        #: trace-disabled runs
         self._tr_collective = None
         #: jit-entry signature accounting (rnb_tpu.compilestats):
         #: distinct applier input signatures == executables this stage
@@ -2680,13 +2650,12 @@ class R2P1DRunner(StageModel):
     def bind_shard_step(self, step_idx: int) -> None:
         """Executor protocol (rnb_tpu.runner): hand the stage its step
         index so the merge collective can be host-timed under the
-        ``exec{i}.collective`` hostprof section / trace span. Called
-        unconditionally (unlike enable_trace) because the collective
-        tax must reach hostprof and the Shard: accounting even on
-        trace-disabled runs; a no-op for unsharded stages."""
+        ``exec{i}.collective`` trace span. Called unconditionally
+        (unlike enable_trace) because the collective tax must reach
+        the span and the Shard: accounting even on trace-disabled
+        runs; a no-op for unsharded stages."""
         if self._merge is None:
             return
-        self._sec_collective = "exec%d.collective" % int(step_idx)
         self._tr_collective = trace.name("exec%d.collective",
                                          int(step_idx))
 
@@ -2939,9 +2908,8 @@ class R2P1DRunner(StageModel):
             jax.block_until_ready(out)
             rid = getattr(time_card, "id", None)
             t0 = time.perf_counter()
-            if self._sec_collective is not None:
-                with hostprof.section(self._sec_collective), \
-                        trace.span(self._tr_collective, rid):
+            if self._tr_collective is not None:
+                with trace.span(self._tr_collective, rid):
                     out = self._merge(out)
                     jax.block_until_ready(out)
             else:
@@ -3008,10 +2976,15 @@ class R2P1DSingleStep(StageModel):
                                dct_coeffs_per_frame=kwargs.get(
                                    "dct_coeffs_per_frame"))
 
-    def enable_trace(self, tracer, step_idx: int) -> None:
+    def bind_step(self, step_idx: int) -> None:
         """Forward to the embedded loader: its phase-refinement
-        stamps and occupancy sources apply to this fused step's
-        index (rnb_tpu.runner executor protocol)."""
+        stamps carry this fused step's index (rnb_tpu.runner
+        executor protocol)."""
+        self.loader.bind_step(step_idx)
+
+    def enable_trace(self, tracer, step_idx: int) -> None:
+        """Forward to the embedded loader: its occupancy sources
+        apply to this fused step's index."""
         self.loader.enable_trace(tracer, step_idx)
 
     def compute_profile(self):

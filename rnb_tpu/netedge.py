@@ -885,6 +885,11 @@ class NetEdgePeer:
         model_class = load_class(self.step.model)
         self.model = model_class(self.device,
                                  **self.step.kwargs_for_group(0))
+        if hasattr(self.model, "bind_step"):
+            # the executor protocol's step binding: remote emissions
+            # carry the same phase-refinement stamps as local ones, so
+            # the timing tables keep one schema
+            self.model.bind_step(0)
 
     # -- framing helpers ----------------------------------------------
 

@@ -10,9 +10,8 @@ memcpy:
   device**: ``out[i] = slab[src_rows[i]]`` where ``src_rows[i] >= 0``,
   ``out[i] = pool[i]`` otherwise. This runs once per emission, after
   the pool's transfer and before the normalize dispatch, so hit rows
-  never exist as host bytes at all (the before/after is visible as the
-  ``loader.cache_gather`` hostprof section: a row memcpy in the blob
-  arm, a dispatch in the paged arm). Following the house kernel
+  never exist as host bytes at all (a row memcpy in the blob arm, a
+  dispatch in the paged arm). Following the house kernel
   pattern (rnb_tpu/ops/ragged.py):
 
   - **TPU**: a Pallas kernel over a ``PrefetchScalarGridSpec`` — the

@@ -53,7 +53,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-from rnb_tpu import devobs, hostprof, metrics, trace
+from rnb_tpu import devobs, metrics, trace
 from rnb_tpu.control import (NUM_EXIT_MARKERS, BufferRing, EdgeTracker,
                              FaultStats, InferenceCounter, Signal,
                              TerminationFlag, TerminationState,
@@ -84,7 +84,7 @@ def poll_plan(model):
     the stage's own next deadline (hold-timeout expiry / harvest tick
     — under autotune, the controller's next deadline), clamped to
     [MIN_POLL_S, QUEUE_POLL_S], plus whether the stage is actually
-    holding work (drives the exec*.hold_wait/queue_get hostprof
+    holding work (drives the exec*.hold_wait/queue_get trace
     split: waiting to fill a batch is not starvation). Stages without
     deadlines poll at the coarse default. The round-5 frontier
     measured the fixed 50 ms poll as the light-load p99 floor
@@ -188,10 +188,12 @@ class RunnerContext:
     #: here (BenchmarkResult shard_* + `Shard:`/`Shard steps:` lines)
     shard_sink: Optional[List] = None
     #: per-job rnb_tpu.trace.Tracer when the config's `trace` key
-    #: enabled tracing, else None. The executor emits hot-loop spans
-    #: through the module-level trace hooks (one None test when off),
-    #: calls model.enable_trace(tracer, step_idx) on stages that
-    #: refine phase stamps / register occupancy sources, and opts the
+    #: enabled tracing, else None. The executor's hot-loop spans go
+    #: through the module-level trace hooks either way (profiler
+    #: annotations under a profiler session; the Tracer's buffer as
+    #: well when one is set); with
+    #: a Tracer it also calls model.enable_trace(tracer, step_idx) on
+    #: stages that register occupancy sources, and opts the
     #: final-step summary into `# phases` trailers.
     tracer: Optional[Any] = None
     #: device-resident handoff (root 'handoff' config key,
@@ -254,6 +256,18 @@ class RunnerContext:
     #: lines are the launcher's aggregation of the same rows) —
     #: False keeps reports byte-stable with the earlier schema
     critpath: bool = False
+
+
+def _dispatch_counts(tensors, device: DeviceSpec) -> dict:
+    """What a batched dispatch's ``model_call`` span carries: the rows
+    shipped (the bucket or pool), the valid ones among them, and the
+    id of the device they run on. Empty for a stage that takes no
+    batch (a loader)."""
+    if tensors and isinstance(tensors[0], PaddedBatch):
+        head = tensors[0]
+        return {"rows": head.max_rows, "rows_valid": int(head.valid),
+                "device": int(device.resolve().id)}
+    return {}
 
 
 def split_segments(payload, num_segments: int):
@@ -734,17 +748,21 @@ def runner(ctx: RunnerContext) -> None:
             # register with the memory ledger here, pre-barrier, so
             # every Memory:/Pages: sample covers the full page pool
             model.enable_pager(ctx.pager)
+        if hasattr(model, "bind_step"):
+            # the stage learns its step index: loaders name the
+            # per-request phase stamps (decode/hold/transfer) by it,
+            # on every run
+            model.bind_step(ctx.step_idx)
         if ctx.tracer is not None and hasattr(model, "enable_trace"):
-            # unified tracing (rnb_tpu.trace): stages that refine the
-            # per-request phase stamps (decode/hold/transfer) and own
-            # sampled occupancy sources wire themselves up here; the
-            # executor's own spans need no stage support
+            # the `trace` config key's Tracer (rnb_tpu.trace): stages
+            # that own sampled occupancy sources register them here;
+            # the executor's own spans need no stage support
             model.enable_trace(ctx.tracer, ctx.step_idx)
         if hasattr(model, "bind_shard_step"):
             # intra-stage sharding (rnb_tpu.parallel.shardplan): the
             # stage host-times its merge collective as
             # exec{i}.collective — unconditional (unlike enable_trace)
-            # because hostprof and the Shard: accounting need the
+            # because the span and the Shard: accounting need the
             # step index even on trace-disabled runs
             model.bind_shard_step(ctx.step_idx)
         # live-metrics plane (rnb_tpu.metrics): stage-owned subsystems
@@ -807,28 +825,20 @@ def runner(ctx: RunnerContext) -> None:
         # the executor path's first-attempt abort.
         model.fault_retry_budget = (ctx.max_retries, ctx.retry_backoff_ms)
     old_counter_value = 0
-    # loop-invariant hostprof section names, formatted once
-    sec_queue_get = "exec%d.queue_get" % ctx.step_idx
-    sec_hold_wait = "exec%d.hold_wait" % ctx.step_idx
-    sec_model_call = "exec%d.model_call" % ctx.step_idx
-    sec_device_sync = "exec%d.device_sync" % ctx.step_idx
-    sec_ring_publish = "exec%d.ring_publish" % ctx.step_idx
-    sec_bookkeeping = "exec%d.bookkeeping" % ctx.step_idx
-    sec_enqueue = "exec%d.route+enqueue" % ctx.step_idx
-    sec_handoff = "exec%d.handoff" % ctx.step_idx
     # loop-invariant stamp keys the autotune service feed reads (these
     # are lookups of stamps the record() sites below write, not new
     # stamp sites)
     key_inf_start = "inference%d_start" % ctx.step_idx
     key_inf_finish = "inference%d_finish" % ctx.step_idx
     # loop-invariant trace event names (rnb_tpu.trace): formatted once
-    # here so the hot loop's disabled path stays one None test with no
-    # allocation (the trace.name literals are what RNB-T008 checks)
+    # here so the hot loop pays no formatting per event (the
+    # trace.name literals are what RNB-T008 checks)
     tr_queue_get = trace.name("exec%d.queue_get", ctx.step_idx)
     tr_hold_wait = trace.name("exec%d.hold_wait", ctx.step_idx)
     tr_swallow = trace.name("exec%d.swallow", ctx.step_idx)
     tr_model_call = trace.name("exec%d.model_call", ctx.step_idx)
     tr_device_sync = trace.name("exec%d.device_sync", ctx.step_idx)
+    tr_finish = trace.name("exec%d.finish", ctx.step_idx)
     tr_publish = trace.name("exec%d.publish", ctx.step_idx)
     tr_handoff = trace.name("exec%d.handoff", ctx.step_idx)
     # devobs compute meter (rnb_tpu.devobs): resolved once — None when
@@ -965,8 +975,7 @@ def runner(ctx: RunnerContext) -> None:
                 else:
                     try:
                         if idle_poll is None:
-                            with hostprof.section(sec_queue_get), \
-                                    trace.span(tr_queue_get):
+                            with trace.span(tr_queue_get):
                                 item = ctx.in_queue.get(
                                     timeout=QUEUE_POLL_S)
                         else:
@@ -975,13 +984,10 @@ def runner(ctx: RunnerContext) -> None:
                             # (under autotune, the controller's), and
                             # time spent blocked while the stage HOLDS
                             # work is batch-fill wait, not queue
-                            # starvation — hostprof splits the two
+                            # starvation — two span names split them
                             timeout, holding = poll_plan(model)
-                            with hostprof.section(
-                                    sec_hold_wait if holding
-                                    else sec_queue_get), \
-                                    trace.span(tr_hold_wait if holding
-                                               else tr_queue_get):
+                            with trace.span(tr_hold_wait if holding
+                                            else tr_queue_get):
                                 item = ctx.in_queue.get(timeout=timeout)
                     except queue.Empty:
                         if marker_noted \
@@ -1103,8 +1109,7 @@ def runner(ctx: RunnerContext) -> None:
                             # onto this consumer — and account the
                             # move, so "zero host-hop bytes" is a
                             # log fact, not a claim
-                            with hostprof.section(sec_handoff), \
-                                    trace.span(tr_handoff):
+                            with trace.span(tr_handoff):
                                 tensors = handoff.take(tensors)
 
                 if flushed is not None:
@@ -1128,6 +1133,7 @@ def runner(ctx: RunnerContext) -> None:
                         if stall > 0:
                             time.sleep(stall / 1000.0)
                     time_card.record("inference%d_start" % ctx.step_idx)
+                    call_counts = _dispatch_counts(tensors, ctx.device)
                     attempt = 0
                     failed_reason = None
                     lane_death = None
@@ -1136,10 +1142,10 @@ def runner(ctx: RunnerContext) -> None:
                                else None)
                     while True:
                         try:
-                            with hostprof.section(sec_model_call), \
-                                    trace.span(tr_model_call,
-                                               getattr(in_card, "id",
-                                                       None)):
+                            with trace.span(tr_model_call,
+                                            getattr(in_card, "id",
+                                                    None),
+                                            **call_counts):
                                 if ctx.fault_plan is not None:
                                     # inside the model_call span:
                                     # injected 'latency' is emulated
@@ -1260,138 +1266,136 @@ def runner(ctx: RunnerContext) -> None:
                     t_sync0 = (time.monotonic()
                                if ctx.placement_sink is not None
                                else None)
-                    with hostprof.section(sec_device_sync), \
-                            trace.span(tr_device_sync):
+                    with trace.span(tr_device_sync):
                         _block_on(tensors_out)
                     if t_sync0 is not None:
                         stage_busy_s += time.monotonic() - t_sync0
-                time_card.record("inference%d_finish" % ctx.step_idx)
-                if ctx.placement_sink is not None:
-                    stage_dispatches += 1
-                if ctx.in_hedges is not None \
-                        and _hedge_lost(ctx, time_card):
-                    # first completion wins: a sibling copy already
-                    # resolved this hedged dispatch — discard this
-                    # result (service time lands in hedges_wasted_ms,
-                    # nothing publishes, nothing double-counts)
-                    continue
-                if devobs_meter is not None and flushed is None:
-                    # per-dispatch achieved-FLOPs feed — AFTER the
-                    # hedge-lost discard above, so a loser copy's rows
-                    # never inflate the meter (the same reason the
-                    # autotune service feed sits past that check):
-                    # valid rows are the constituents' num_clips
-                    # stamps with coalesced followers counted 0 — the
-                    # device-work rule clip_counts applies
-                    # (telemetry.TimeCardSummary) — so the Compute:
-                    # line cross-foots bench.py's clips_completed-
-                    # based MFU exactly. The busy span is
-                    # inference_start -> inference_finish (model call
-                    # + device sync), the service-time semantics the
-                    # autotune estimator uses.
-                    cards_dv = _cards_of(time_card)
-                    t_fin_dv = cards_dv[0].timings.get(key_inf_finish)
-                    if t_fin_dv is not None:
-                        # LAST constituent's start, like the autotune
-                        # estimator: an accumulating stage's earlier
-                        # members carry stale starts whose gap is
-                        # batch-fill wait, not device busy time
-                        t_sta_dv = max(
-                            tc_dv.timings.get(key_inf_start, t_fin_dv)
-                            for tc_dv in cards_dv)
-                        rows_dv = 0
-                        for tc_dv in cards_dv:
-                            # coalesced rows share another request's
-                            # dispatch and feature-hit rows skipped
-                            # the forward entirely — neither ran
-                            # FLOPs, so both count 0 (honesty policy:
-                            # hits must never inflate MFU)
-                            if not getattr(tc_dv, "cache_coalesced",
-                                           False) \
-                                    and not getattr(tc_dv,
-                                                    "feature_hit",
-                                                    False):
-                                rows_dv += int(getattr(tc_dv,
-                                                       "num_clips", 0))
-                        devobs_meter.note(rows_dv,
-                                          t_fin_dv - t_sta_dv)
-                if controller is not None and tensors_out \
-                        and flushed is None \
-                        and not getattr(model, "AUTOTUNE_SELF_SERVICE",
-                                        False):
-                    # service-time estimator, per emitted row bucket:
-                    # the LAST-swallowed constituent's start -> the
-                    # emission finish. Accurate for stages where
-                    # swallow and emit happen in the same call (the
-                    # Batcher — earlier constituents' spans include
-                    # their accumulate hold, which must not read as
-                    # service). Stages whose emissions complete
-                    # asynchronously (the fusing loader under
-                    # transfer_async, where every emission surfaces
-                    # via take_ready and `flushed` is never None)
-                    # self-report their close->ready span instead and
-                    # opt out via AUTOTUNE_SELF_SERVICE.
-                    # Arrival-triggered dispatches only: on `flushed`
-                    # emissions (idle-tick hold expiry, EOS flush,
-                    # async-transfer drains) the last start predates
-                    # the dispatch by up to the hold/poll gap, and
-                    # feeding that span would inflate the EWMA until
-                    # the controller stopped holding at all
-                    cards = _cards_of(time_card)
-                    t_fin = cards[0].timings.get(key_inf_finish)
-                    if t_fin is not None:
-                        t_sta = max(tc.timings.get(key_inf_start, t_fin)
-                                    for tc in cards)
-                        out_pb = tensors_out[0]
-                        # ragged emissions always ship the pool shape;
-                        # the controller's continuous candidates are
-                        # keyed by the VALID rows the dispatch carried
-                        rows_key = (out_pb.valid
-                                    if isinstance(out_pb, RaggedBatch)
-                                    else int(out_pb.data.shape[0]))
-                        controller.observe_service(
-                            rows_key, max(0.0, t_fin - t_sta))
-
-                out_queue = None
-                if ctx.out_queues is not None:
-                    if _sheddable_expired(ctx, time_card):
-                        # pre-ring-write expiry shed: the computed
-                        # output is already too late — drop it before
-                        # it occupies a ring slot or downstream queue
-                        _shed_deadline(ctx, time_card,
-                                       "step%d_publish" % ctx.step_idx,
-                                       summary)
+                with trace.span(tr_finish):
+                    time_card.record("inference%d_finish" % ctx.step_idx)
+                    if ctx.placement_sink is not None:
+                        stage_dispatches += 1
+                    if ctx.in_hedges is not None \
+                            and _hedge_lost(ctx, time_card):
+                        # first completion wins: a sibling copy already
+                        # resolved this hedged dispatch — discard this
+                        # result (service time lands in hedges_wasted_ms,
+                        # nothing publishes, nothing double-counts)
                         continue
-                    # route BEFORE the ring publish so a shed decision
-                    # can drop the item while no ring slot holds it (a
-                    # written-but-never-signalled slot would deadlock
-                    # the producer on the next wrap-around)
-                    with hostprof.section(sec_enqueue):
+                    if devobs_meter is not None and flushed is None:
+                        # per-dispatch achieved-FLOPs feed — AFTER the
+                        # hedge-lost discard above, so a loser copy's rows
+                        # never inflate the meter (the same reason the
+                        # autotune service feed sits past that check):
+                        # valid rows are the constituents' num_clips
+                        # stamps with coalesced followers counted 0 — the
+                        # device-work rule clip_counts applies
+                        # (telemetry.TimeCardSummary) — so the Compute:
+                        # line cross-foots bench.py's clips_completed-
+                        # based MFU exactly. The busy span is
+                        # inference_start -> inference_finish (model call
+                        # + device sync), the service-time semantics the
+                        # autotune estimator uses.
+                        cards_dv = _cards_of(time_card)
+                        t_fin_dv = cards_dv[0].timings.get(key_inf_finish)
+                        if t_fin_dv is not None:
+                            # LAST constituent's start, like the autotune
+                            # estimator: an accumulating stage's earlier
+                            # members carry stale starts whose gap is
+                            # batch-fill wait, not device busy time
+                            t_sta_dv = max(
+                                tc_dv.timings.get(key_inf_start, t_fin_dv)
+                                for tc_dv in cards_dv)
+                            rows_dv = 0
+                            for tc_dv in cards_dv:
+                                # coalesced rows share another request's
+                                # dispatch and feature-hit rows skipped
+                                # the forward entirely — neither ran
+                                # FLOPs, so both count 0 (honesty policy:
+                                # hits must never inflate MFU)
+                                if not getattr(tc_dv, "cache_coalesced",
+                                               False) \
+                                        and not getattr(tc_dv,
+                                                        "feature_hit",
+                                                        False):
+                                    rows_dv += int(getattr(tc_dv,
+                                                           "num_clips", 0))
+                            devobs_meter.note(rows_dv,
+                                              t_fin_dv - t_sta_dv)
+                    if controller is not None and tensors_out \
+                            and flushed is None \
+                            and not getattr(model, "AUTOTUNE_SELF_SERVICE",
+                                            False):
+                        # service-time estimator, per emitted row bucket:
+                        # the LAST-swallowed constituent's start -> the
+                        # emission finish. Accurate for stages where
+                        # swallow and emit happen in the same call (the
+                        # Batcher — earlier constituents' spans include
+                        # their accumulate hold, which must not read as
+                        # service). Stages whose emissions complete
+                        # asynchronously (the fusing loader under
+                        # transfer_async, where every emission surfaces
+                        # via take_ready and `flushed` is never None)
+                        # self-report their close->ready span instead and
+                        # opt out via AUTOTUNE_SELF_SERVICE.
+                        # Arrival-triggered dispatches only: on `flushed`
+                        # emissions (idle-tick hold expiry, EOS flush,
+                        # async-transfer drains) the last start predates
+                        # the dispatch by up to the hold/poll gap, and
+                        # feeding that span would inflate the EWMA until
+                        # the controller stopped holding at all
+                        cards = _cards_of(time_card)
+                        t_fin = cards[0].timings.get(key_inf_finish)
+                        if t_fin is not None:
+                            t_sta = max(tc.timings.get(key_inf_start, t_fin)
+                                        for tc in cards)
+                            out_pb = tensors_out[0]
+                            # ragged emissions always ship the pool shape;
+                            # the controller's continuous candidates are
+                            # keyed by the VALID rows the dispatch carried
+                            rows_key = (out_pb.valid
+                                        if isinstance(out_pb, RaggedBatch)
+                                        else int(out_pb.data.shape[0]))
+                            controller.observe_service(
+                                rows_key, max(0.0, t_fin - t_sta))
+
+                    out_queue = None
+                    if ctx.out_queues is not None:
+                        if _sheddable_expired(ctx, time_card):
+                            # pre-ring-write expiry shed: the computed
+                            # output is already too late — drop it before
+                            # it occupies a ring slot or downstream queue
+                            _shed_deadline(ctx, time_card,
+                                           "step%d_publish" % ctx.step_idx,
+                                           summary)
+                            continue
+                        # route BEFORE the ring publish so a shed decision
+                        # can drop the item while no ring slot holds it (a
+                        # written-but-never-signalled slot would deadlock
+                        # the producer on the next wrap-around)
                         out_idx = selector.select(tensors_out,
                                                   non_tensors_out,
                                                   time_card)
-                    out_queue = ctx.out_queues[out_idx]
-                    # forked segment cards are never shed (dropping one
-                    # segment would strand its siblings in the
-                    # aggregator and double-count the request): they
-                    # fall through to the blocking-put backpressure path
-                    if (ctx.overload_policy == "shed"
-                            and out_queue.maxsize > 0
-                            and getattr(time_card, "sub_id", None) is None
-                            and out_queue.qsize() + ctx.num_segments
-                            > out_queue.maxsize):
-                        # on a replica-expanded edge the shed site is
-                        # per-LANE: which lane's queue filled up is
-                        # the signal (satellite of the health layer)
-                        _shed_item(ctx, time_card, summary,
-                                   lane=(ctx.out_queue_indices[out_idx]
-                                         if ctx.out_depths is not None
-                                         else None))
-                        continue
+                        out_queue = ctx.out_queues[out_idx]
+                        # forked segment cards are never shed (dropping one
+                        # segment would strand its siblings in the
+                        # aggregator and double-count the request): they
+                        # fall through to the blocking-put backpressure path
+                        if (ctx.overload_policy == "shed"
+                                and out_queue.maxsize > 0
+                                and getattr(time_card, "sub_id", None) is None
+                                and out_queue.qsize() + ctx.num_segments
+                                > out_queue.maxsize):
+                            # on a replica-expanded edge the shed site is
+                            # per-LANE: which lane's queue filled up is
+                            # the signal (satellite of the health layer)
+                            _shed_item(ctx, time_card, summary,
+                                       lane=(ctx.out_queue_indices[out_idx]
+                                             if ctx.out_depths is not None
+                                             else None))
+                            continue
 
                 if ctx.output_ring is not None:
-                    with hostprof.section(sec_ring_publish), \
-                            trace.span(tr_publish):
+                    with trace.span(tr_publish):
                         segments = split_segments(tensors_out,
                                                   ctx.num_segments)
                         for seg_idx, seg_payload in enumerate(segments):
@@ -1413,7 +1417,7 @@ def runner(ctx: RunnerContext) -> None:
                     # the flag while this one was mid-inference — the
                     # reference registered every completed record
                     # (reference runner.py:176-202)
-                    with hostprof.section(sec_bookkeeping):
+                    with trace.span(tr_finish):
                         n = len(time_card) if isinstance(time_card,
                                                          TimeCardList) \
                             else 1
@@ -1439,8 +1443,7 @@ def runner(ctx: RunnerContext) -> None:
                             break  # someone else already hit the target
                 else:
                     try:
-                        with hostprof.section(sec_enqueue), \
-                                trace.span(tr_publish):
+                        with trace.span(tr_publish):
                             for seg_idx in range(ctx.num_segments):
                                 forked = time_card.fork(seg_idx) \
                                     if ctx.num_segments > 1 else time_card

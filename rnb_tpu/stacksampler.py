@@ -1,8 +1,7 @@
 """Continuous wall-clock stack sampling over the pipeline threads.
 
-hostprof answers "how much wall time did section X cost" — but only
-for the sections somebody instrumented, and only as end-of-run sums.
-This module is the always-on complement: a low-rate background sampler
+Spans answer "how much wall time did section X cost" — but only for
+the sections somebody instrumented. This module is the complement: a low-rate background sampler
 over ``sys._current_frames()`` that records *where each named pipeline
 thread actually is* at every tick, with zero per-sample cooperation
 from the sampled code. Three surfaces come out of one sample stream:

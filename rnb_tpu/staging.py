@@ -224,9 +224,7 @@ class StagingPool:
         shape = tuple(int(d) for d in shape)
         with self._lock:
             self.num_acquire_waits += 1
-        from rnb_tpu import hostprof
-        with hostprof.section("staging.acquire_wait"), \
-                trace.span("staging.acquire_wait"):
+        with trace.span("staging.acquire_wait"):
             while True:
                 pending = None
                 with self._available:

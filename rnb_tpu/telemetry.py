@@ -147,21 +147,21 @@ STAMP_REGISTRY = (
               "model call (or prefetched-decode completion) began"),
     StampSpec("inference{step}_finish", "rnb_tpu/runner.py",
               "stage output ready (device-synced unless async_dispatch)"),
-    # -- phase-refinement stamps (rnb_tpu.trace): recorded ONLY when
-    # the job's `trace` config key enables tracing, so trace-off runs
-    # stay byte-stable with the pre-trace schema. They split the
-    # loader's inference{step} span into decode/hold/transfer/drain
-    # for per-request attribution (parse_utils --attribute).
+    # -- phase-refinement stamps (rnb_tpu.trace): recorded for every
+    # request a loader serves under an executor (PR 24; before, only
+    # under the `trace` config key). They split the loader's
+    # inference{step} span into decode/hold/transfer/drain for
+    # per-request attribution (parse_utils --attribute, the
+    # benchmark's phase_*_ms metrics).
     StampSpec("decode{step}_done", "rnb_tpu/models/r2p1d/model.py",
-              "this request's clip decode completed (trace mode only; "
-              "a cache hit records a zero-length decode phase)"),
+              "this request's clip decode completed (a cache hit "
+              "records a zero-length decode phase)"),
     StampSpec("transfer{step}_start", "rnb_tpu/models/r2p1d/model.py",
               "the emission holding this request closed and its "
-              "host->device transfer began (trace mode only)"),
+              "host->device transfer began"),
     StampSpec("transfer{step}_done", "rnb_tpu/models/r2p1d/model.py",
               "host->device transfer dispatched/confirmed; the gap to "
-              "inference{step}_finish is publish drain (trace mode "
-              "only)"),
+              "inference{step}_finish is publish drain"),
 )
 
 #: every ``<Prefix>:``-keyed line rnb_tpu/benchmark.py may write into
@@ -422,10 +422,17 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("exec{step}.swallow", "rnb_tpu/runner.py",
               "instant: one request admitted into the stage"),
     StampSpec("exec{step}.model_call", "rnb_tpu/runner.py",
-              "span: the stage model call for one dispatch"),
+              "span: the stage model call for one dispatch (a batched "
+              "dispatch carries rows = rows shipped, rows_valid, "
+              "device = the device's id)"),
     StampSpec("exec{step}.device_sync", "rnb_tpu/runner.py",
               "span: blocking on device output readiness "
               "(sync_outputs)"),
+    StampSpec("exec{step}.finish", "rnb_tpu/runner.py",
+              "span: the executor's own work on a finished dispatch, "
+              "from the end of device_sync to the start of publish: "
+              "finish stamp, meters, routing; on the final step also "
+              "the completion bookkeeping (a second span)"),
     StampSpec("exec{step}.publish", "rnb_tpu/runner.py",
               "span: route + ring write + downstream enqueue"),
     StampSpec("exec{step}.handoff", "rnb_tpu/runner.py",
@@ -456,9 +463,12 @@ TRACE_EVENT_REGISTRY = (
               "instant: one request's decode observed complete"),
     StampSpec("loader.emit", "rnb_tpu/models/r2p1d/model.py",
               "span: fused-batch take/assemble/handoff"),
+    StampSpec("loader.emit_wait", "rnb_tpu/models/r2p1d/model.py",
+              "span: the emission blocked on decodes of its take that "
+              "were not done yet (inside loader.emit)"),
     StampSpec("loader.transfer", "rnb_tpu/models/r2p1d/model.py",
-              "span: host->device device_put (+ confirm/preprocess "
-              "dispatch) — executor thread or transfer worker"),
+              "span: the host->device device_put of one batch — "
+              "executor thread or transfer worker"),
     StampSpec("loader.s{step}.inflight", "rnb_tpu/models/r2p1d/model.py",
               "counter (sampled): decodes in flight + decoded-but-"
               "unemitted requests held by the loader"),
@@ -476,6 +486,11 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("autotune.decision", "rnb_tpu/autotune.py",
               "instant: one BatchController decision (args: verdict, "
               "target_rows, hold_ms)"),
+    StampSpec("compile.steady", "rnb_tpu/compilestats.py",
+              "instant: a jitted applier met an entry signature its "
+              "warm-up never saw — a compilation inside the run "
+              "(signature = the shapes and dtypes), on the thread "
+              "that dispatched it"),
     StampSpec("queue.filename.depth", "rnb_tpu/benchmark.py",
               "counter (sampled): client filename queue depth"),
     StampSpec("queue.e{step}.depth", "rnb_tpu/benchmark.py",

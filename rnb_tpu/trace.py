@@ -1,8 +1,7 @@
 """Unified pipeline tracing: spans, counters, Perfetto export, phases.
 
 PRs 1-5 left the runtime with rich but fragmented telemetry: TimeCard
-stamps answer "when did request N pass milestone X", hostprof prefix
-sums answer "which section eats the host core", and the log-meta
+stamps answer "when did request N pass milestone X" and the log-meta
 counter lines answer "how many". None of them can answer "where did
 request #417's 9 ms go" or "what was the staging pool doing while the
 executor starved". This module unifies the signals into two artifacts:
@@ -18,18 +17,34 @@ executor starved". This module unifies the signals into two artifacts:
   deterministic decomposition of each request's end-to-end latency
   into named phases — ``client_queue -> decode -> hold -> transfer ->
   inference{i} -> inter_stage_queue -> drain`` — derived from TimeCard
-  stamps alone, so it works on any past log directory (coarser there:
-  without the trace-mode refinement stamps the loader span reports as
-  one ``decode`` phase). Phases partition [first stamp, last stamp] by
-  construction, so they always sum to the end-to-end latency.
+  stamps alone, so it works on any past log directory (coarser on logs
+  from before PR 24's always-on refinement stamps: there the loader
+  span reports as one ``decode`` phase). Phases partition [first
+  stamp, last stamp] by construction, so they always sum to the end-to-end latency.
 
-Cost discipline: like :mod:`rnb_tpu.hostprof`, the disabled path of
-every instrumentation call is one module-global ``None`` test and no
-allocation — ``trace.span(name)`` returns a shared no-op context
-manager when no tracer is active. Event names are DECLARED in
-``rnb_tpu.telemetry.TRACE_EVENT_REGISTRY`` and cross-checked by the
-static schema checker (rnb_tpu.analysis.schema, RNB-T008): an
-undeclared event name is a tier-1 lint failure.
+One span system (PR 24): under a profiler session :func:`span` and
+:func:`instant` open a ``jax.profiler.TraceAnnotation``, so the
+program's spans land in the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the chip's operations, with their
+``rid`` and counts readable as event stats (the benchmark's
+``benchmarks/hostspans.py`` reads them). The planes agree up to a
+constant of about a millisecond that differs from capture to capture
+(PERF.md, "The clock"): good for laying a span of tens of milliseconds
+beside the chip's work, not for ordering two events a millisecond
+apart. The profiler session is the switch: with none running (one
+``TraceAnnotation.is_enabled()``, some 0.1 us on the CPU harness) and
+no :class:`Tracer`, a span is the shared no-op and allocates nothing.
+An earlier form opened an annotation at every site regardless and
+read -0.6% videos/s in the saturated cell over nine pairs of untraced
+runs against the parent; gated, eight pairs read +0.1% (my chip runs,
+PR 24). When a
+:class:`Tracer` is installed (root key ``trace``) the event goes to
+its buffer as well. A profiler line is named after the OS thread,
+not the Python one, so the role lives in the span's name
+(``exec{step}.*``, ``loader.*``, ``transfer.*``). Event names are
+DECLARED in ``rnb_tpu.telemetry.TRACE_EVENT_REGISTRY`` and
+cross-checked by the static schema checker (rnb_tpu.analysis.schema,
+RNB-T008): an undeclared event name is a tier-1 lint failure.
 """
 
 from __future__ import annotations
@@ -41,8 +56,8 @@ import time
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 #: the active per-job tracer, installed/cleared by rnb_tpu.benchmark
-#: around the measured run (module-global like hostprof's accumulator:
-#: jobs run one at a time per process)
+#: around the measured run (module-global: jobs run one at a time per
+#: process)
 ACTIVE: Optional["Tracer"] = None
 
 #: default background counter-sampling rate (Hz); 0 disables the
@@ -54,8 +69,8 @@ DEFAULT_MAX_EVENTS = 200000
 
 
 class _NullSpan:
-    """Shared no-op context manager: the disabled path costs one
-    function call, one global read, and no allocation."""
+    """Shared no-op context manager: with no profiler session and no
+    Tracer a span costs two calls and no allocation."""
 
     __slots__ = ()
 
@@ -68,6 +83,24 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, resolved by the first span (this
+#: module is also imported by offline tools that never start JAX)
+_ANNOTATION = None
+
+
+def _session() -> bool:
+    """Whether a profiler session collects host events right now."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION.is_enabled()
+
+
+def _stats(rid: Optional[int], counts: dict) -> dict:
+    """An annotation's stats: the counts, and the request id if any."""
+    return dict(counts, rid=rid) if rid is not None else counts
+
 
 def name(pattern: str, *args) -> str:
     """Format a registered event-name pattern once, ahead of a hot
@@ -79,25 +112,37 @@ def name(pattern: str, *args) -> str:
     return pattern % args if args else pattern
 
 
-def span(event_name: str, rid: Optional[int] = None):
+def span(event_name: str, rid: Optional[int] = None, **counts):
     """Context manager timing one named span on the current thread.
 
-    ``rid`` correlates the span with a request id: the exporter chains
-    all events of one rid into a Perfetto flow. Disabled path: shared
-    no-op, no allocation."""
+    ``rid`` correlates the span with a request id (the Tracer's
+    exporter chains all events of one rid into a Perfetto flow);
+    ``counts`` are numbers or short strings known when the span opens
+    (``rows=48``). Both travel as the annotation's stats."""
     t = ACTIVE
-    if t is None:
+    if t is not None:
+        return t.span(event_name, rid, counts)
+    if not _session():
         return _NULL
-    return t.span(event_name, rid)
+    return _ANNOTATION(event_name, **_stats(rid, counts))
 
 
 def instant(event_name: str, rid: Optional[int] = None,
-            args: Optional[dict] = None) -> None:
-    """A zero-duration event on the current thread's track."""
+            args: Optional[dict] = None, **counts) -> None:
+    """A zero-duration event on the current thread's track (``args``:
+    counts whose names are no Python identifiers)."""
     t = ACTIVE
-    if t is None:
+    session = _session()
+    if t is None and not session:
         return
-    t.add_event(event_name, "i", time.time(), 0.0, rid, args)
+    if args:
+        counts.update(args)
+    if session:
+        with _ANNOTATION(event_name, **_stats(rid, counts)):
+            pass
+    if t is not None:
+        t.add_event(event_name, "i", time.time(), 0.0, rid,
+                    counts or None)
 
 
 def counter(event_name: str, value) -> None:
@@ -136,24 +181,31 @@ class TraceSettings:
 
 
 class _Span:
-    """One live enabled-mode span (allocated only while tracing)."""
+    """One live span while a Tracer (or the metrics bridge) collects:
+    the profiler annotation plus an event in the collector's
+    buffer."""
 
-    __slots__ = ("tracer", "name", "rid", "t0")
+    __slots__ = ("tracer", "name", "rid", "counts", "annotation", "t0")
 
-    def __init__(self, tracer: "Tracer", event_name: str,
-                 rid: Optional[int]):
+    def __init__(self, tracer, event_name: str, rid: Optional[int],
+                 counts: Optional[dict] = None):
         self.tracer = tracer
         self.name = event_name
         self.rid = rid
-        self.t0 = time.time()
+        self.counts = counts
+        self.annotation = _ANNOTATION(
+            event_name, **_stats(rid, counts or {})) if _session() else _NULL
 
     def __enter__(self):
+        self.annotation.__enter__()
+        self.t0 = time.time()
         return self
 
     def __exit__(self, *exc):
         t1 = time.time()
+        self.annotation.__exit__(*exc)
         self.tracer.add_event(self.name, "X", self.t0, t1 - self.t0,
-                              self.rid, None)
+                              self.rid, self.counts or None)
         return False
 
 
@@ -190,8 +242,9 @@ class Tracer:
 
     # -- collection ---------------------------------------------------
 
-    def span(self, event_name: str, rid: Optional[int] = None) -> _Span:
-        return _Span(self, event_name, rid)
+    def span(self, event_name: str, rid: Optional[int] = None,
+             counts: Optional[dict] = None) -> _Span:
+        return _Span(self, event_name, rid, counts)
 
     def add_event(self, event_name: str, ph: str, t0: float,
                   dur: float, rid: Optional[int],

@@ -166,6 +166,33 @@ def test_pool_concurrent_decodes(tmp_path, native):
         pool.close()
 
 
+def test_pool_stats_count_frames_and_busy_time(tmp_path, native):
+    from rnb_tpu.decode.native import DecodePool
+    p = tmp_path / "stats.y4m"
+    _write_video(p, n=12, seed=11)
+    pool = DecodePool(num_threads=2)
+    try:
+        assert pool.stats() == {"busy_s": 0.0, "frames": 0}
+        tickets = [pool.submit(str(p), [0, 4, 8], 3, 16, 16)[0]
+                   for _ in range(4)]
+        for ticket in tickets:
+            pool.wait(ticket, str(p))
+        stats = pool.stats()
+        # 4 jobs of 3 clips of 3 frames; the workers' own clock ran
+        assert stats["frames"] == 4 * 3 * 3
+        assert 0.0 < stats["busy_s"] < 60.0
+        # a job that fails is timed and decodes no frame
+        bad = tmp_path / "gone.y4m"
+        ticket, _ = pool.submit(str(bad), [0], 2, 16, 16)
+        with pytest.raises(ValueError):
+            pool.wait(ticket, str(bad))
+        after = pool.stats()
+        assert after["frames"] == stats["frames"]
+        assert after["busy_s"] >= stats["busy_s"]
+    finally:
+        pool.close()
+
+
 def test_pool_double_wait_fails_fast(tmp_path, native):
     from rnb_tpu.decode.native import DecodePool
     p = tmp_path / "dw.y4m"

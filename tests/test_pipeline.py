@@ -68,13 +68,10 @@ def test_bulk_end_to_end(tmp_path):
     assert "Termination flag: 0" in meta
 
 
-def test_host_profile_and_cpu_accounting(tmp_path, monkeypatch):
-    """RNB_HOST_PROFILE writes the per-section host breakdown, and the
-    rusage window (always on) lands in the result — the evidence pair
-    behind any host-ceiling claim (VERDICT r4 item 1)."""
-    from rnb_tpu import hostprof
-    monkeypatch.setattr(hostprof, "ENABLED", True)
-    hostprof.reset()
+def test_cpu_accounting_and_no_span_artifacts(tmp_path):
+    """The rusage window (always on) lands in the result, and a run
+    with no `trace` key and no profiler session leaves no span
+    artifact behind: the spans are profiler annotations only."""
     cfg = _write_config(tmp_path, _two_step())
     # enough videos that the measured window exceeds the kernel's
     # CPU-time accounting granularity: with every jit cache warm from
@@ -85,17 +82,12 @@ def test_host_profile_and_cpu_accounting(tmp_path, monkeypatch):
                         print_progress=False)
     assert res.termination_flag == TerminationFlag.TARGET_NUM_VIDEOS_REACHED
     assert res.host_cpu_s > 0
-    prof_path = os.path.join(res.log_dir, "hostprof.txt")
-    with open(prof_path) as f:
-        text = f.read()
-    assert "host_cpu_frac" in text
-    assert "exec0.model_call" in text
-    assert "exec1.queue_get" in text
-    snap = hostprof.snapshot()
-    assert snap["exec0.model_call"][1] >= 25  # one call per request
-    hostprof.reset()
-    assert hostprof.snapshot() == {}
-
+    files = os.listdir(res.log_dir)
+    assert sorted(f for f in files if "group" not in f) \
+        == ["log-meta.txt", "pipeline.json"]
+    assert res.trace_events == 0
+    # the tiny pipeline decodes nothing through the native pool
+    assert res.decode_busy_s == 0 and res.decode_frames == 0
 
 def test_poisson_end_to_end_replicated(tmp_path):
     cfg = _write_config(tmp_path, _two_step(devices_a=(0, 1),

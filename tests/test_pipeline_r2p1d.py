@@ -9,6 +9,7 @@ is one compile for the whole test session.
 import json
 import os
 
+import pytest
 
 from rnb_tpu.benchmark import run_benchmark
 from rnb_tpu.control import TerminationFlag
@@ -43,9 +44,30 @@ def test_r2p1d_whole_pipeline(tmp_path):
     with open(os.path.join(res.log_dir, reports[0])) as f:
         lines = f.read().strip().split("\n")
     header = lines[0].split()
-    assert "inference0_finish" in header  # loader stage timed
-    assert "inference1_finish" in header  # net stage timed
-    assert len(lines) - 1 >= 4
+    # the loader's phase-refinement stamps are on every request, with
+    # no `trace` key: the table has their three columns, in order
+    assert header == ["enqueue_filename", "runner0_start",
+                      "inference0_start", "decode0_done",
+                      "transfer0_start", "transfer0_done",
+                      "inference0_finish", "runner1_start",
+                      "inference1_start", "inference1_finish",
+                      "device0", "device1"]
+    rows = [line.split() for line in lines[1:]
+            if line and not line.startswith("#")]
+    assert len(rows) >= 4
+    # ... and attribute_phases partitions every row: the phases sum
+    # to the request's latency, decode/hold/transfer among them
+    from rnb_tpu.trace import attribute_phases
+    for row in rows:
+        stamps = {k: float(v) for k, v in zip(header[:10], row)}
+        phases = attribute_phases(stamps)
+        assert {"decode", "hold", "transfer", "inference1"} <= set(phases)
+        latency = (stamps["inference1_finish"]
+                   - stamps["enqueue_filename"]) * 1000.0
+        assert sum(phases.values()) == pytest.approx(latency, abs=1e-6)
+        assert all(ms >= 0.0 for ms in phases.values())
+    # no Tracer, no profiler session: the spans left nothing behind
+    assert "trace.json" not in os.listdir(res.log_dir)
 
 
 def test_r2p1d_layer_split_pipeline(tmp_path):

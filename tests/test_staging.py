@@ -156,21 +156,6 @@ def test_plain_loader_without_prefetch_builds_no_pool():
     assert loader.staging is None
 
 
-def test_hostprof_totals_prefix_sum():
-    from rnb_tpu import hostprof
-    hostprof.reset()
-    try:
-        hostprof.add("loader.emit_copy", 0.25)
-        hostprof.add("loader.emit_wait", 0.5)
-        hostprof.add("loader.emit_wait", 0.5)
-        hostprof.add("transfer.device_put", 2.0)
-        assert hostprof.totals("loader.emit") == (1.25, 3)
-        assert hostprof.totals("transfer.") == (2.0, 1)
-        assert hostprof.totals("nothing.") == (0.0, 0)
-    finally:
-        hostprof.reset()
-
-
 def test_aggregate_snapshots_sums():
     agg = aggregate_snapshots([
         {"slots": 3, "slot_bytes": 10, "acquires": 5, "acquire_waits": 1,

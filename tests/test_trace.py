@@ -1,6 +1,5 @@
 """Unified pipeline tracing (rnb_tpu.trace): spans/counters/export,
-deterministic phase attribution, trace-off byte-stability, and the
-hostprof thread-role dimension.
+deterministic phase attribution, trace-off byte-stability.
 
 Unit coverage runs without JAX; the e2e cases drive the tiny test
 pipeline (tests.pipeline_helpers) through run_benchmark with the root
@@ -82,13 +81,23 @@ def test_config_rejects_bad_trace_key(bad):
 
 # -- collector + export -----------------------------------------------
 
-def test_disabled_module_hooks_are_noops():
-    # no tracer installed: span returns the shared null context, the
-    # instant/counter hooks return without recording anything
-    with trace.span("exec0.queue_get") as s:
-        assert s is None
+def test_without_tracer_or_session_a_span_is_the_shared_noop():
+    # no Tracer installed and no profiler session: span hands back the
+    # one shared no-op (nothing allocated, no annotation opened), the
+    # instant/counter hooks return, and nothing is retained anywhere
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
+    probe = Tracer(TraceSettings(sample_hz=0))  # never installed
+    with trace.span("exec0.queue_get"):
+        pass
+    span = trace.span("exec1.model_call", rid=3, rows=8, rows_valid=5)
+    assert span is trace._NULL
+    with span:
+        pass
     trace.instant("client.enqueue", rid=1)
+    trace.instant("compile.steady", signature="8x4:uint8")
     trace.counter("client.enqueued", 1)
+    assert trace.ACTIVE is None and probe.num_events() == 0
 
 
 def test_tracer_export_valid_and_flow_linked(tmp_path):
@@ -374,48 +383,106 @@ def test_trace_overhead_is_bounded(tmp_path):
     assert time.monotonic() - t0 < 60.0
 
 
-# -- hostprof thread-role dimension (satellite) ------------------------
+# -- the profiler's host plane (PR 24) ---------------------------------
 
-def test_hostprof_role_split_and_rollup():
-    from rnb_tpu import hostprof
-    hostprof.reset()
+def _host_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                found += [(e.name, e.duration_ns, dict(e.stats))
+                          for e in line.events
+                          if e.name.split(".")[0] in ("exec1", "compile")]
+    return found
+
+
+def test_worker_thread_span_and_instant_land_in_the_xplane(tmp_path):
+    import jax
+
+    def work():
+        with trace.span("exec1.model_call", rid=7, rows=48,
+                        rows_valid=41, device=0):
+            pass
+        trace.instant("compile.steady", signature="8x2:uint8")
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # as benchmarks/run.py traces
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        hostprof.add("loader.cache_insert", 0.5, role="runner-s0-g0-i0")
-        hostprof.add("loader.cache_insert", 0.25, role="rnb-transfer")
-        hostprof.add("loader.cache_insert", 0.25, role="rnb-transfer")
-        hostprof.add("exec0.queue_get", 1.0, role="runner-s0-g0-i0")
-        # role-less view folds roles per section (historical schema)
-        snap = hostprof.snapshot()
-        assert snap["loader.cache_insert"] == (1.0, 3)
-        by_role = hostprof.snapshot_by_role()
-        assert by_role[("loader.cache_insert", "rnb-transfer")] \
-            == (0.5, 2)
-        assert hostprof.totals("loader.") == (1.0, 3)
-        assert hostprof.totals("loader.", role="rnb-transfer") \
-            == (0.5, 2)
-        lines = hostprof.report_lines(10.0)
-        text = "\n".join(lines)
-        # the multi-role section gets per-role breakdown rows; the
-        # single-role one does not
-        assert "loader.cache_insert @rnb-transfer" in text
-        assert "exec0.queue_get @" not in text
-    finally:
-        hostprof.reset()
-
-
-def test_hostprof_add_defaults_to_current_thread_name():
-    from rnb_tpu import hostprof
-    hostprof.reset()
-    try:
-        result = {}
-
-        def work():
-            hostprof.add("loader.emit_wait", 0.125)
-
-        t = threading.Thread(target=work, name="runner-s9-g0-i0")
+        t = threading.Thread(target=work, name="runner-s1-g0-i0")
         t.start()
-        t.join()
-        assert hostprof.snapshot_by_role()[
-            ("loader.emit_wait", "runner-s9-g0-i0")] == (0.125, 1)
+        t.join(timeout=30)
+        assert not t.is_alive()
     finally:
-        hostprof.reset()
+        jax.profiler.stop_trace()
+    events = {name: (dur, stats)
+              for name, dur, stats in _host_events(str(tmp_path))}
+    dur, stats = events["exec1.model_call"]
+    assert dur > 0
+    assert {k: int(stats[k]) for k in ("rid", "rows", "rows_valid",
+                                       "device")} \
+        == {"rid": 7, "rows": 48, "rows_valid": 41, "device": 0}
+    assert events["compile.steady"][1]["signature"] == "8x2:uint8"
+
+
+def test_tracer_gets_the_counts_and_instants_stay_instants():
+    tracer = Tracer(TraceSettings(sample_hz=0))
+    trace.ACTIVE = tracer
+    with trace.span("exec1.model_call", rid=3, rows=16, rows_valid=9,
+                    device=2):
+        pass
+    with trace.span("loader.transfer"):
+        pass
+    trace.instant("loader.decode_ready", rid=3)
+    trace.instant("health.lane_state", args={"from": "a", "to": "b"})
+    events = tracer.snapshot_events()
+    assert [(e[0], e[1], e[5], e[6]) for e in events] == [
+        ("exec1.model_call", "X", 3,
+         {"rows": 16, "rows_valid": 9, "device": 2}),
+        ("loader.transfer", "X", None, None),
+        ("loader.decode_ready", "i", 3, None),
+        ("health.lane_state", "i", None, {"from": "a", "to": "b"})]
+
+
+def test_compile_steady_instant_once_per_new_signature():
+    import numpy as np
+
+    from rnb_tpu.compilestats import SignatureTracker
+    tracer = Tracer(TraceSettings(sample_hz=0))
+    trace.ACTIVE = tracer
+    tracker = SignatureTracker()
+    warm = np.zeros((8, 2), np.uint8)
+    tracker.observe(warm)
+    tracker.freeze()
+    tracker.observe(warm)                        # warmed: no instant
+    tracker.observe(np.zeros((16, 2), np.uint8))  # a compile in the run
+    tracker.observe(np.zeros((16, 2), np.uint8))  # the same one again
+    assert tracker.snapshot() == {"warmup": 1, "steady_new": 1,
+                                  "steady_calls": 3}
+    assert [(e[0], e[1], e[6]) for e in tracer.snapshot_events()] == [
+        ("compile.steady", "i", {"signature": "16x2:uint8"})]
+
+
+def test_traced_run_dispatch_spans_carry_rows_and_device(tmp_path):
+    res = _run(tmp_path, "rows", {"enabled": True, "sample_hz": 0},
+               videos=12, interval_ms=0)
+    assert res.termination_flag == 0
+    with open(os.path.join(res.log_dir, "trace.json")) as f:
+        events = json.load(f)["traceEvents"]
+    calls = [e for e in events if e["name"] == "exec1.model_call"]
+    assert len(calls) >= 12
+    for e in calls:
+        # TinyLoader ships 2 valid rows in a 4-row batch to device 1
+        assert (e["args"]["rows"], e["args"]["rows_valid"]) == (4, 2)
+        assert isinstance(e["args"]["device"], int)
+    # a loader takes no batch: its call spans carry no counts
+    assert all("rows" not in e.get("args", {}) for e in events
+               if e["name"] == "exec0.model_call")
+    names = {e["name"] for e in events}
+    assert {"exec0.finish", "exec1.finish", "exec0.publish"} <= names
