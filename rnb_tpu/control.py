@@ -368,6 +368,15 @@ class BufferRing:
                 return False
         return True
 
+    def would_block(self, slot_idx: int, count: int = 1) -> bool:
+        """Whether a publish writing ``count`` slots from ``slot_idx``
+        on would have to wait for a consumer right now (a peek for the
+        producer's emission policy; it takes nothing)."""
+        n = len(self.slots)
+        return count > n or any(
+            not self.slots[(slot_idx + k) % n].free.is_set()
+            for k in range(count))
+
     def release_all(self) -> None:
         """Free every slot so blocked producers wake during teardown
         (reference runner.py:247-253)."""

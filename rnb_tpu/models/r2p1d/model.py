@@ -1407,12 +1407,25 @@ class R2P1DFusingLoader(R2P1DLoader):
     transport, not reproduced).
 
     Emission policy (adaptive, unlike the fixed-k Batcher):
-      * emit when ``fuse`` requests are ready or their combined clip
-        rows reach the ring's max shape;
-      * emit a partial batch when nothing is left in flight, so light
-        Poisson load pays no batch-fill latency;
-      * emit when the oldest ready request has waited longer than
-        ``max_hold_ms`` (bounds p99 at mid load);
+      * the batch is full — ``fuse`` requests are ready or their
+        combined clip rows reach the ring's max shape (under autotune,
+        the controller's target): emit, whatever the ring's state;
+        ``publish`` then blocks while the ring is full, so the ring
+        still bounds what is in flight;
+      * latency rules, which fire only while the executor's publish
+        probe (:meth:`bind_publish_probe`) reads a free ring slot:
+        emit a partial batch when nothing is left in flight, so light
+        Poisson load pays no batch-fill latency, and emit when the
+        oldest ready request has waited longer than ``max_hold_ms``
+        (bounds p99 at mid load). While the ring is full a batch
+        could not reach it anyway, so the loader keeps filling and
+        looks again within ``HARVEST_TICK_S``; no probe bound (unit
+        tests, no output ring) reads free;
+      * a take made while the ring is full closes on a bucket
+        boundary (:meth:`_plan_take`): the leftover stays at the head
+        of the ready list and rides the next batch, which is due as
+        soon as a slot frees; with a free slot the take is the
+        longest that fits, padded to its bucket — latency first;
       * block on the oldest in-flight decode only once ``depth``
         requests are pending (backpressure toward the client queue).
 
@@ -1465,6 +1478,9 @@ class R2P1DFusingLoader(R2P1DLoader):
         "_failed": "executor-thread confined (see _ready)",
         "_stage_retries": "executor-thread confined (see _ready)",
         "_deadline_shed": "executor-thread confined (see _ready)",
+        "_deferred": "executor-thread confined (see _ready)",
+        "_publish_probe": "bound once by the executor thread before "
+                          "its loop; read on that thread only",
         "autotune": "executor-thread confined (see _ready)",
         "ragged_stats": "executor-thread confined (see _ready)",
     }
@@ -1517,6 +1533,29 @@ class R2P1DFusingLoader(R2P1DLoader):
         #: before emission (rnb_tpu.health), parked for the
         #: executor's take_shed() drain — inert without deadlines
         self._deadline_shed = []
+        #: the executor's peek at the output ring, ``probe(ahead) ->
+        #: would a publish behind `ahead` unpublished emissions block
+        #: now?`` (bind_publish_probe); None reads "free"
+        self._publish_probe = None
+        #: a latency rule was already held back for the batch now
+        #: filling (loader.emit_deferred fires once per batch)
+        self._deferred = False
+
+    def bind_publish_probe(self, probe) -> None:
+        """Executor protocol (rnb_tpu.runner): downstream
+        back-pressure as the emission policy observes it."""
+        self._publish_probe = probe
+
+    def _ring_full(self) -> bool:
+        """Whether an emission made now would wait for a ring slot."""
+        probe = self._publish_probe
+        if probe is None:
+            return False
+        with self._out_lock:
+            ahead = len(self._out_ready)
+        if self._worker is not None:
+            ahead += self._worker.outstanding()
+        return probe(ahead)
 
     def take_shed(self):
         """Executor hook (rnb_tpu.runner): requests this stage shed
@@ -1731,7 +1770,28 @@ class R2P1DFusingLoader(R2P1DLoader):
         n, self._stage_retries = self._stage_retries, 0
         return n
 
-    def _emit(self) -> bool:
+    def _plan_take(self, blocked: bool):
+        """``(requests, rows)`` of the next take: the longest in-order
+        prefix of the ready list within ``fuse`` requests and the row
+        cap. ``blocked`` (the ring is full, so what is left behind
+        loses nothing: the next batch is due when a slot frees) closes
+        instead at the longest such prefix whose rows are exactly a
+        row bucket, where there is one. Ragged stages ship one shape
+        and have no boundary to close on."""
+        cap = self.max_clips
+        count = rows = 0
+        on_bucket = None
+        for rec in self._ready:
+            n = rec.handle.n
+            if count >= self.fuse or (count and rows + n > cap):
+                break
+            count += 1
+            rows += n
+            if blocked and rows in self.row_buckets:
+                on_bucket = (count, rows)
+        return on_bucket or (count, rows)
+
+    def _emit(self, reason: str = "drain") -> bool:
         """Fuse ready requests (up to ``fuse`` / the ring max rows)
         into one padded batch + TimeCardList and ship it — zero-copy
         straight from the staging slot when the take is the slot's
@@ -1741,32 +1801,39 @@ class R2P1DFusingLoader(R2P1DLoader):
         worker under ``transfer_async``. Returns True when ready
         records were consumed (progress), False when nothing was
         takeable; a take whose every decode failed still returns True
-        (the failures are on the take_failed() queue)."""
-        with trace.span("loader.emit"):
-            return self._emit_take()
+        (the failures are on the take_failed() queue). ``reason``
+        names the rule that fired (``full``, ``hold``, ``idle``; every
+        forced path — flush, staging exhaustion, ``depth`` — is
+        ``drain``) for the span's stats."""
+        count, rows = self._plan_take(
+            not self.ragged and self._ring_full())
+        if not count:
+            return False
+        self._deferred = False
+        with trace.span(
+                "loader.emit", reason=reason, rows=rows,
+                bucket=self.pool_rows if self.ragged
+                else self._bucket_for(rows),
+                left=sum(rec.handle.n for rec in self._ready) - rows):
+            return self._emit_take(count)
 
-    def _emit_take(self) -> bool:
+    def _emit_take(self, count: int) -> bool:
         """:meth:`_emit` body (split out so that one span wraps the
-        whole take/assemble/handoff)."""
-        cap = self.max_clips
-        take, rows = [], 0
-        while self._ready and len(take) < self.fuse:
-            handle = self._ready[0].handle
-            if take and rows + handle.n > cap:
-                break
+        whole take/assemble/handoff): ship the first ``count`` ready
+        requests."""
+        take = []
+        for _ in range(count):
             rec = self._ready.popleft()
             # finalizing: close the coalescing window now — by the time
             # a later same-key request arrives, the successful decode is
             # in the cache (inserted below, same call)
             self._drop_coalesce(rec)
             take.append(rec)
-            rows += handle.n
-        if not take:
-            return False
+        rows = sum(rec.handle.n for rec in take)
         # the take loop guarantees this (submit caps each request at
         # max_clips); a silent min() here would mask clip loss instead
         # of surfacing the broken invariant
-        assert rows <= cap, (rows, cap)
+        assert rows <= self.max_clips, (rows, self.max_clips)
         for rec in take:
             if rec.handle.slot is not None \
                     and rec.handle.slot is self._open_slot:
@@ -2104,6 +2171,11 @@ class R2P1DFusingLoader(R2P1DLoader):
                 return 0.0  # a completed emission awaits publishing
         self._harvest()  # peek-only: fresh view of completed decodes
         if self._ready:
+            if self._ring_full():
+                # the latency rules wait for a slot: look again within
+                # a tick (a full batch emits on the arrival that fills
+                # it, not on this clock)
+                return self.HARVEST_TICK_S
             if not self._inflight:
                 return 0.0  # nothing else can fuse: emit now
             waited = time.monotonic() - self._ready[0].t_ready
@@ -2154,20 +2226,28 @@ class R2P1DFusingLoader(R2P1DLoader):
             # capped by the static fuse/row ceilings
             dec = self.autotune.decide(len(self._ready), rows_ready,
                                        waited_s)
-            should_emit = (len(self._ready) >= self.fuse
-                           or rows_ready >= self.max_clips
-                           or rows_ready >= dec.target_rows
-                           or not self._inflight
-                           or waited_s >= dec.hold_s)
+            full = rows_ready >= dec.target_rows
+            held_out = waited_s >= dec.hold_s
         else:
-            should_emit = (len(self._ready) >= self.fuse
-                           or rows_ready >= self.max_clips
-                           or not self._inflight
-                           or waited_s * 1000.0 > self.max_hold_ms)
-        if should_emit:
-            self._emit()
-            return self._pop_ready()
-        return None
+            full = False
+            held_out = waited_s * 1000.0 > self.max_hold_ms
+        if (full or len(self._ready) >= self.fuse
+                or rows_ready >= self.max_clips):
+            reason = "full"
+        elif not held_out and self._inflight:
+            return None
+        elif self._ring_full():
+            # a latency rule fired, and emitting could buy no latency:
+            # the batch cannot reach the ring before a slot frees, so
+            # it keeps filling (next_deadline_s looks again in a tick)
+            if not self._deferred:
+                self._deferred = True
+                trace.instant("loader.emit_deferred")
+            return None
+        else:
+            reason = "hold" if held_out else "idle"
+        self._emit(reason)
+        return self._pop_ready()
 
     def __call__(self, tensors, non_tensors, time_card):
         video = str(non_tensors)

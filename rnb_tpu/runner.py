@@ -803,6 +803,15 @@ def runner(ctx: RunnerContext) -> None:
             progress_bar = None
 
     ring_counter = 0  # next output slot (reference runner.py:60-61)
+    if ctx.output_ring is not None \
+            and hasattr(model, "bind_publish_probe"):
+        # downstream back-pressure, observed: "would publishing one
+        # more emission, behind `ahead` emitted and not yet published
+        # ones, block now?" — a peek at the slots the publish path
+        # below writes next. A stage with no probe bound reads "free"
+        model.bind_publish_probe(
+            lambda ahead=0: ctx.output_ring.would_block(
+                ring_counter, (ahead + 1) * ctx.num_segments))
     # accumulator stages expose poll() for the idle tick; resolve once
     idle_poll = getattr(model, "poll", None)
     # stages with intra-stage batching surface internally-contained

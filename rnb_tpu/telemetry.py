@@ -462,7 +462,14 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("loader.decode_ready", "rnb_tpu/models/r2p1d/model.py",
               "instant: one request's decode observed complete"),
     StampSpec("loader.emit", "rnb_tpu/models/r2p1d/model.py",
-              "span: fused-batch take/assemble/handoff"),
+              "span: fused-batch take/assemble/handoff (reason = the "
+              "rule that fired: full, hold, idle, or drain for a "
+              "forced emission; rows and bucket of the take; left = "
+              "ready rows it left behind)"),
+    StampSpec("loader.emit_deferred", "rnb_tpu/models/r2p1d/model.py",
+              "instant: a latency rule (hold expired, nothing in "
+              "flight) was held back because the output ring is full "
+              "— once per batch, the first time"),
     StampSpec("loader.emit_wait", "rnb_tpu/models/r2p1d/model.py",
               "span: the emission blocked on decodes of its take that "
               "were not done yet (inside loader.emit)"),

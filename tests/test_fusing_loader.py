@@ -351,3 +351,385 @@ def test_flush_take_hits_exact_buckets(tmp_path):
         # fuse=8: takes of 8, 7(=15-8) requests x 3 clips + remainder
         # rows 24->bucket 24 (NOT 36), 21->24, 9->15
         assert got == want, (fuse, got)
+
+
+# -- emission under downstream back-pressure (the publish probe) -------
+#
+# Counts only. Decodes are stood in for by completed handles (no
+# tickets, no future: ready at once, copy-path assembly) and by handles
+# on a future nobody completes (a decode still in flight), so every
+# scenario is deterministic: which rule fires, how many rows a take
+# closes at, what it leaves behind.
+
+BUCKETS = [8, 16, 24, 32, 40, 48]
+
+
+class _Probe:
+    """The executor's publish probe, by hand."""
+
+    def __init__(self, full=True):
+        self.full = full
+        self.asked = []
+
+    def __call__(self, ahead=0):
+        self.asked.append(ahead)
+        return self.full
+
+
+def _wide_loader(probe=None, **kw):
+    kw.setdefault("fuse", 64)
+    kw.setdefault("depth", 1000)
+    kw.setdefault("max_hold_ms", 1e9)
+    kw.setdefault("max_clips", 48)
+    if not kw.get("ragged"):
+        kw.setdefault("row_buckets", BUCKETS)
+    kw.setdefault("consecutive_frames", 2)
+    kw.setdefault("pixel_path", "yuv420")
+    loader = _loader(**kw)
+    if probe is not None:
+        loader.bind_publish_probe(probe)
+    return loader
+
+
+def _decoded(loader, rows, first_id=0):
+    """Put completed decodes of the given row counts in flight; the
+    next harvest finds them ready. Row r of request i holds i + 1."""
+    from rnb_tpu.models.r2p1d.model import _DecodeHandle, _FuseRecord
+    for i, n in enumerate(rows, first_id):
+        tc = TimeCard(i)
+        tc.num_clips = n
+        out = np.full(loader._batch_shape(n), (i + 1) % 251,
+                      loader._wire_dtype)
+        loader._inflight.append(
+            _FuseRecord(_DecodeHandle(out, n), "v%d" % i, tc))
+
+
+def _still_decoding(loader, req_id=999):
+    """One request whose decode never completes."""
+    from concurrent.futures import Future
+
+    from rnb_tpu.models.r2p1d.model import _DecodeHandle, _FuseRecord
+    tc = TimeCard(req_id)
+    tc.num_clips = 1
+    handle = _DecodeHandle(np.zeros(loader._batch_shape(1),
+                                    loader._wire_dtype), 1,
+                           future=Future())
+    loader._inflight.append(_FuseRecord(handle, "pending", tc))
+    return handle
+
+
+def _abort(loader):
+    """The executor's abort path, once the stand-in decode has landed
+    (discard waits for every decode it retires)."""
+    for rec in loader._inflight:
+        if rec.handle.future is not None:
+            rec.handle.future.set_result(None)
+    loader.discard_pending()
+    assert not loader._ready and not loader._inflight
+
+
+def _shape(out):
+    """(valid rows, rows shipped, request ids) of one emission."""
+    (batch,), _, cards = out
+    return (batch.valid, int(batch.data.shape[0]),
+            [tc.id for tc in cards.time_cards])
+
+
+def _parent_take(ready, fuse, cap, buckets):
+    """The parent commit's take rule, as a loop, over ``[(id, rows)]``:
+    requests in order until ``fuse`` or until the next one would pass
+    the cap, padded to the smallest bucket that fits
+    -> ((valid, shipped, ids), what is left)."""
+    n = valid = 0
+    while n < len(ready) and n < fuse \
+            and (n == 0 or valid + ready[n][1] <= cap):
+        valid += ready[n][1]
+        n += 1
+    return ((valid, next(b for b in buckets if b >= valid),
+             [i for i, _ in ready[:n]]), ready[n:])
+
+
+@pytest.mark.parametrize("rule", ["hold", "idle"])
+def test_full_ring_holds_back_the_latency_rules(rule):
+    """Hold expired / nothing in flight: with a probe that reads full,
+    neither emits — the batch could not reach the ring anyway."""
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe,
+                          max_hold_ms=0.0 if rule == "hold" else 1e9)
+    _decoded(loader, [1, 9, 1])
+    if rule == "hold":
+        _still_decoding(loader)
+    for _ in range(3):
+        assert loader.poll() is None
+    assert probe.asked and set(probe.asked) == {0}
+    assert [rec.handle.n for rec in loader._ready] == [1, 9, 1]
+    _abort(loader)
+
+
+@pytest.mark.parametrize("fuse,rows,ships", [
+    (4, [1, 1, 5, 1], 8),                     # `fuse` requests ready
+    (64, [9, 9, 9, 9, 9, 1, 1, 1], 48),       # max_clips rows ready
+], ids=["fuse", "max_clips"])
+def test_full_ring_does_not_hold_back_a_full_batch(fuse, rows, ships):
+    """The rules that mean "the batch is full" fire whatever the probe
+    reads: publish then blocks, and the ring bounds what is in flight."""
+    loader = _wide_loader(_Probe(full=True), fuse=fuse)
+    _decoded(loader, rows)
+    _still_decoding(loader)
+    out = loader.poll()
+    assert out is not None
+    valid, shipped, ids = _shape(out)
+    assert valid == shipped == ships
+    assert ids == list(range(len(ids)))
+    _abort(loader)
+
+
+def test_probe_turning_free_releases_the_next_poll():
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe, max_hold_ms=0.0)
+    _decoded(loader, [1, 9, 1])
+    _still_decoding(loader)
+    assert loader.poll() is None
+    probe.full = False
+    # with a free slot the take is the parent's: everything that fits,
+    # padded to its bucket
+    assert _shape(loader.poll()) == (11, 16, [0, 1, 2])
+    _abort(loader)
+
+
+def test_probe_counts_the_emissions_still_ahead_of_it():
+    """What the executor binds: a peek at the slots its next publishes
+    write. An emission made but not yet published (the transfer worker
+    holds it, or it waits for take_ready) will take the first free
+    slot, so the stage asks about the one behind it."""
+    from rnb_tpu.control import BufferRing
+    ring = BufferRing(3, None, ())
+    assert not ring.would_block(0) and not ring.would_block(2, 3)
+    assert ring.would_block(0, 4)          # more than the ring holds
+    ring.slots[1].write(("batch",))
+    assert not ring.would_block(0) and ring.would_block(1)
+    assert ring.would_block(0, 2) and not ring.would_block(2, 2)  # wraps
+    ring.slots[1].release()
+    assert not ring.would_block(1)
+
+    probe = _Probe(full=False)
+    loader = _wide_loader(probe, max_hold_ms=0.0)
+    _decoded(loader, [1, 1])
+    _still_decoding(loader)
+    loader._push_ready("an emission awaiting take_ready")
+    assert loader.next_deadline_s() == 0.0 and probe.asked == []
+    assert loader._ring_full() is False and probe.asked == [1]
+    assert loader.take_ready() == "an emission awaiting take_ready"
+    assert loader._ring_full() is False and probe.asked == [1, 0]
+    _abort(loader)
+
+
+@pytest.mark.parametrize("inflight", [True, False])
+def test_next_deadline_is_a_tick_while_held_back(inflight):
+    """Held back for a slot, the stage asks to be looked at again
+    within HARVEST_TICK_S — not at once (no spin at the executor's
+    1 ms floor), and not after the 50 ms poll."""
+    from rnb_tpu.runner import poll_plan
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe, max_hold_ms=0.0)
+    _decoded(loader, [1, 1])
+    if inflight:
+        _still_decoding(loader)
+    assert loader.next_deadline_s() == loader.HARVEST_TICK_S
+    assert poll_plan(loader) == (loader.HARVEST_TICK_S, True)
+    probe.full = False
+    assert loader.next_deadline_s() == 0.0  # the parent's answer
+    _abort(loader)
+
+
+_PARENT_SCENARIOS = {
+    # name: (loader kwargs, ready rows, a decode still in flight)
+    "idle-partial": ({}, [1, 9, 1], False),
+    "hold-expired": ({"max_hold_ms": 0.0}, [1] * 5 + [9], True),
+    "fuse-reached": ({"fuse": 3}, [1, 1, 1, 1, 1], True),
+    "cap-stops-before-long-video": ({}, [1] * 41 + [9] + [1] * 5, True),
+    "cap-reached-exactly": ({}, [1] * 39 + [9] + [1] * 5, True),
+    "all-long": ({}, [9] * 6, True),
+    "nothing-fires": ({}, [1, 1, 1], True),
+    "wide-caps-of-the-file": ({"fuse": 12, "max_clips": 36,
+                               "row_buckets": [6, 15, 24, 36]},
+                              [3] * 15, False),
+}
+
+
+@pytest.mark.parametrize("probe", [None, "free"])
+@pytest.mark.parametrize("name", sorted(_PARENT_SCENARIOS))
+def test_without_back_pressure_the_emissions_are_the_parents(name, probe):
+    """No probe bound, or one that reads free: poll() fires the
+    parent's rules and every take is the parent's (the loop above),
+    through to the drain."""
+    kw, rows, inflight = _PARENT_SCENARIOS[name]
+    loader = _wide_loader(_Probe(full=False) if probe else None, **kw)
+    _decoded(loader, rows)
+    if inflight:
+        pending = _still_decoding(loader)
+    fuse, cap = loader.fuse, loader.max_clips
+    fires = (len(rows) >= fuse or sum(rows) >= cap or not inflight
+             or loader.max_hold_ms == 0.0)
+    got = []
+    out = loader.poll()
+    assert (out is not None) == fires
+    if out is not None:
+        got.append(_shape(out))
+    if inflight:
+        pending.future.set_result(None)
+    while True:
+        out = loader.flush()
+        if out is None:
+            break
+        got.append(_shape(out))
+    want, ready = [], list(enumerate(rows))
+    if fires:      # the poll sees the decoded requests only
+        take, ready = _parent_take(ready, fuse, cap, loader.row_buckets)
+        want.append(take)
+    if inflight:
+        ready.append((999, 1))
+    while ready:
+        take, ready = _parent_take(ready, fuse, cap, loader.row_buckets)
+        want.append(take)
+    assert got == want
+
+
+@pytest.mark.parametrize("rows,closes_at,left", [
+    ([1] * 41 + [9] + [1] * 5, 40, [1, 9, 1, 1, 1, 1, 1]),
+    ([1] * 39 + [9], 48, []),
+    # ISSUE 26's second example as written: 39 + 9 is 48, a boundary
+    ([1] * 39 + [9] + [1] * 5, 48, [1] * 5),
+    # the longest prefix ON a boundary, though a longer one fits (43,
+    # padded to 48): the three long videos ride the next batch
+    ([1] * 7 + [9] * 5, 16, [9, 9, 9, 9]),
+    ([3] * 17, 48, [3]),
+], ids=["stops-at-40-before-a-long-video", "exactly-48",
+        "48-then-five-left", "short-boundary-over-padded-fit", "three-clip-videos"])
+def test_blocked_take_closes_on_a_bucket_boundary(rows, closes_at, left):
+    """Ring full at the take: close at the longest in-order prefix on a
+    row bucket, no pad rows; what is left stays at the head, in order,
+    and ships first next time."""
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe)
+    _decoded(loader, rows)
+    _still_decoding(loader)
+    valid, shipped, ids = _shape(loader.poll())  # rows >= max_clips
+    assert valid == shipped == closes_at
+    assert ids == list(range(len(ids)))
+    assert [rec.handle.n for rec in loader._ready] == left
+    assert loader.padding.pad_rows == 0
+    if left:
+        probe.full = False
+        loader._inflight.clear()    # nothing in flight: the idle rule
+        valid, _shipped, more = _shape(loader.poll())
+        assert more == list(range(len(ids), len(rows)))
+        assert valid == sum(left)
+
+
+def test_blocked_take_without_a_boundary_takes_the_longest_fit():
+    """No prefix lands on a bucket: the parent's take, padding and all
+    (nothing is held back for a boundary that may never come)."""
+    loader = _wide_loader(_Probe(full=True))
+    _decoded(loader, [9] * 6)
+    _still_decoding(loader)
+    assert _shape(loader.poll()) == (45, 48, [0, 1, 2, 3, 4])
+    assert [rec.handle.n for rec in loader._ready] == [9]
+    _abort(loader)
+
+
+def test_blocked_take_keeps_the_rows_of_each_request():
+    """A leftover rides the next batch through the assembly copy: the
+    rows that ship are each request's own, in order."""
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe, raw_output=True, row_buckets=None,
+                          max_clips=16)
+    # one bucket (16): boundary only at the cap
+    _decoded(loader, [1] * 7 + [9] + [1] * 3)
+    _still_decoding(loader)
+    (first,), _, cards = loader.poll()
+    assert first.valid == 16 and len(cards) == 8
+    probe.full = False
+    loader._inflight.clear()
+    (second,), _, cards = loader.poll()
+    assert second.valid == 3
+    assert [tc.id for tc in cards.time_cards] == [8, 9, 10]
+    rows = np.asarray(second.data)[:3].reshape(3, -1)
+    assert [int(r[0]) for r in rows] == [9, 10, 11]
+    assert (rows == rows[:, :1]).all()
+
+
+def test_ragged_take_is_unchanged_under_a_full_ring():
+    """One pool shape, explicit valid rows: nothing to close on. The
+    take under a full ring is the longest fit, as with a free one."""
+    got = []
+    for full in (True, False):
+        loader = _wide_loader(_Probe(full=full), ragged=True,
+                              max_clips=48)
+        _decoded(loader, [1] * 41 + [9] + [1] * 5)
+        _still_decoding(loader)
+        (batch,), _, cards = loader.poll()
+        got.append((batch.valid, len(cards)))
+        _abort(loader)
+    assert got == [(41, 41), (41, 41)]
+
+
+def test_flush_and_termination_drain_a_stage_that_is_held_back():
+    """End of stream and the abort path take no notice of the probe's
+    answer: flush ships everything, discard_pending leaves nothing."""
+    loader = _wide_loader(_Probe(full=True), max_hold_ms=0.0)
+    _decoded(loader, [1, 9, 1, 1])
+    assert loader.poll() is None     # held back: the ring is full
+    seen = []
+    while True:
+        out = loader.flush()
+        if out is None:
+            break
+        seen += _shape(out)[2]
+    assert seen == [0, 1, 2, 3]
+    assert loader.next_deadline_s() is None
+    _decoded(loader, [1, 1], first_id=4)
+    assert loader.poll() is None
+    _abort(loader)
+    assert loader.next_deadline_s() is None
+
+
+def test_emit_deferred_fires_once_a_batch_and_emit_says_why():
+    """The counter that says how often the mechanism engages: one
+    registered instant the first time a batch's latency rule is held
+    back, and reason / rows / bucket / left on the loader.emit span."""
+    from rnb_tpu import trace
+    from rnb_tpu.analysis.schema import check_trace_events, \
+        package_py_files
+    from rnb_tpu.telemetry import TRACE_EVENT_REGISTRY
+    assert "loader.emit_deferred" in {
+        spec.pattern for spec in TRACE_EVENT_REGISTRY}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert check_trace_events(
+        package_py_files(os.path.join(root, "rnb_tpu")), root) == []
+
+    probe = _Probe(full=True)
+    loader = _wide_loader(probe)
+    tracer = trace.Tracer()
+    trace.ACTIVE = tracer
+    try:
+        _decoded(loader, [1] * 30)
+        for _ in range(3):           # nothing in flight, ring full
+            assert loader.poll() is None
+        _decoded(loader, [1] * 11 + [9] + [1] * 5, first_id=30)
+        assert loader.poll() is not None   # 55 rows: full; closes at 40
+        for _ in range(2):           # the leftover's own batch
+            assert loader.poll() is None
+        probe.full = False
+        assert loader.poll() is not None   # idle rule, free slot
+    finally:
+        trace.ACTIVE = None
+    events = tracer.snapshot_events()
+    assert [e[0] for e in events if e[0].startswith("loader.emit")
+            and e[0] != "loader.emit_wait"] == [
+        "loader.emit_deferred", "loader.emit",
+        "loader.emit_deferred", "loader.emit"]
+    stats = [e[6] for e in events if e[0] == "loader.emit"]
+    assert stats == [
+        {"reason": "full", "rows": 40, "bucket": 40, "left": 15},
+        {"reason": "idle", "rows": 15, "bucket": 16, "left": 0}]

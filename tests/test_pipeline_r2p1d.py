@@ -150,3 +150,86 @@ def test_split_range_logits_match_whole_range(tmp_path):
                                np.asarray(whole_logits.data),
                                rtol=0, atol=0.05)
     assert split_logits.valid == whole_logits.valid == 2
+
+
+def test_fused_loader_keeps_filling_while_the_ring_is_full(tmp_path):
+    """Emission under back-pressure, through the real runtime: a
+    backlog of one-clip videos -> R2P1DFusingLoader (hold 0 ms, 8-row
+    cap, a ring of 2) -> a stage that takes 300 ms a dispatch. Once
+    the ring is full the expired hold no longer ships what happens to
+    be decoded: the loader keeps filling, and every batch from the
+    first deferral on, but the drain's last, is a whole bucket with no
+    pad row. Counts only (the CPU harness)."""
+    num_videos = 60    # some seven 8-row batches and a tail
+    cfg = {
+        "video_path_iterator":
+            "rnb_tpu.models.r2p1d.model.R2P1DVideoPathIterator",
+        "trace": {"enabled": True, "sample_hz": 0},
+        "pipeline": [
+            {"model": "rnb_tpu.models.r2p1d.model.R2P1DFusingLoader",
+             "queue_groups": [{"devices": [0], "out_queues": [0]}],
+             "num_shared_tensors": 2,
+             "fuse": 64, "max_clips": 8, "row_buckets": [4, 8],
+             "max_hold_ms": 0.0, "consecutive_frames": 2,
+             "num_clips_population": [1], "weights": [1],
+             "num_warmups": 1},
+            {"model": "tests.pipeline_helpers.TinySlowSink",
+             "queue_groups": [{"devices": [1], "in_queue": 0}],
+             "delay_s": 0.3},
+        ],
+    }
+    path = os.path.join(str(tmp_path), "backpressure.json")
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    res = run_benchmark(path, mean_interval_ms=0, num_videos=num_videos,
+                        queue_size=100, log_base=str(tmp_path / "logs"),
+                        print_progress=False)
+    assert res.termination_flag == TerminationFlag.TARGET_NUM_VIDEOS_REACHED
+    assert res.clips_completed == num_videos   # every request, one clip
+    assert res.num_failed == 0 and res.num_shed == 0
+
+    with open(os.path.join(res.log_dir, "trace.json")) as f:
+        events = sorted(json.load(f)["traceEvents"],
+                        key=lambda e: e.get("ts", 0.0))
+    emits = [e for e in events if e["name"] == "loader.emit"]
+    deferred = [e["ts"] for e in events
+                if e["name"] == "loader.emit_deferred"]
+    # the span's stats foot to the whole-run Padding: counters
+    assert sum(e["args"]["rows"] for e in emits) == num_videos
+    assert sum(e["args"]["bucket"] for e in emits) == res.total_rows
+    assert res.pad_rows == res.total_rows - num_videos
+    with open(os.path.join(res.log_dir, "log-meta.txt")) as f:
+        assert "Padding: pad_rows=%d total_rows=%d" % (
+            res.pad_rows, res.total_rows) in f.read()
+    # the mechanism engaged, and from then on no latency rule shipped
+    # a part-filled bucket into the full ring: every batch but the
+    # drain's last is a whole bucket, no pad row (a full batch under
+    # back-pressure, or the drain closing on a boundary)
+    assert deferred
+    held = [e["args"] for e in emits if e["ts"] > deferred[0]]
+    assert len(held) >= 5
+    assert {a["reason"] for a in held} <= {"full", "drain"}
+    assert [a["bucket"] - a["rows"] for a in held[:-1]] \
+        == [0] * (len(held) - 1)
+    assert any(a["reason"] == "full" for a in held)
+    # at most one deferral a batch
+    assert len(deferred) <= len(emits)
+
+    # the phases still partition every request's latency
+    from rnb_tpu.trace import attribute_phases
+    reports = [f for f in os.listdir(res.log_dir) if "group" in f]
+    with open(os.path.join(res.log_dir, reports[0])) as f:
+        lines = f.read().strip().split("\n")
+    header = lines[0].split()
+    stamps_n = header.index("device0")
+    rows = [line.split() for line in lines[1:]
+            if line and not line.startswith("#")]
+    assert rows
+    for row in rows:
+        stamps = {k: float(v) for k, v in zip(header[:stamps_n], row)}
+        phases = attribute_phases(stamps)
+        assert {"decode", "hold", "transfer"} <= set(phases)
+        latency = (stamps[header[stamps_n - 1]]
+                   - stamps["enqueue_filename"]) * 1000.0
+        assert sum(phases.values()) == pytest.approx(latency, abs=1e-6)
+        assert all(ms >= 0.0 for ms in phases.values())
