@@ -2,8 +2,10 @@
 
 A :class:`RunFacts` holds the schedule with its stamps, each request's
 finish, the program's counters (``BenchmarkResult``), the process CPU
-seconds over the window, the device's peak memory and, in a traced
-run, the trace's reduction. End-to-end metrics and the shared
+seconds over the window, the device's peak memory, the configuration
+as run with its family's module (``config``, ``family``: a reader that
+wants a kernel's operations or bytes asks the family for them) and, in
+a traced run, the trace's reduction. End-to-end metrics and the shared
 arithmetic of the per-layer readers are methods here, so that a reader
 file is a description and a line or two.
 """
@@ -19,10 +21,10 @@ from benchmarks import stamps
 
 class RunFacts:
     def __init__(self, *, schedule, finish, instance, result, chips: int,
-                 device_kind: str, platform: str, flops_per_clip: int,
-                 peak_flops_per_s: Optional[float], window_cpu_s: float,
-                 memory_peak_bytes: int, frame_bytes_per_row: int,
-                 trace=None):
+                 device_kind: str, platform: str, config: dict, family,
+                 flops_per_row: int, peak_flops_per_s: Optional[float],
+                 window_cpu_s: float, memory_peak_bytes: int,
+                 wire_bytes_per_row: int, trace=None):
         self.schedule = schedule
         self.finish = finish
         self.instance = instance
@@ -30,11 +32,13 @@ class RunFacts:
         self.chips = int(chips)
         self.device_kind = device_kind
         self.platform = platform
-        self.flops_per_clip = int(flops_per_clip)
+        self.config = config
+        self.family = family
+        self.flops_per_row = int(flops_per_row)
         self.peak_flops_per_s = peak_flops_per_s
         self.window_cpu_s = float(window_cpu_s)
         self.memory_peak_bytes = int(memory_peak_bytes)
-        self.frame_bytes_per_row = int(frame_bytes_per_row)
+        self.wire_bytes_per_row = int(wire_bytes_per_row)
         self.trace = trace
         self.window = schedule.window
         self.seconds = schedule.seconds
@@ -98,7 +102,7 @@ class RunFacts:
     def net_flops_util_pct(self) -> Optional[float]:
         if self.peak_flops_per_s is None:
             return None  # a CPU dry run has no peak to stand against
-        return 100.0 * self.clips_per_s() * self.flops_per_clip \
+        return 100.0 * self.clips_per_s() * self.flops_per_row \
             / (self.chips * self.peak_flops_per_s)
 
     def per_instance_in_window(self) -> Dict[str, int]:
@@ -117,7 +121,7 @@ class RunFacts:
 
     def rehomed_byte_pct(self) -> Optional[float]:
         r = self.result
-        put = r.total_rows * self.frame_bytes_per_row
+        put = r.total_rows * self.wire_bytes_per_row
         if not getattr(r, "handoff_edges", 0) or not put:
             return None
         return 100.0 * r.handoff_d2d_bytes / put
@@ -154,7 +158,7 @@ class RunFacts:
             return None
         span = self.trace.host_span
         done = stamps.in_window(self.finish, span)
-        flops = float(self.schedule.clips[done].sum()) * self.flops_per_clip
+        flops = float(self.schedule.clips[done].sum()) * self.flops_per_row
         op_s = sum(self.trace.self_s.values())  # over every chip used
         return 100.0 * flops / (op_s * self.peak_flops_per_s) \
             if op_s else None

@@ -540,6 +540,16 @@ def of(facts) -> Optional[HostSpans]:
     return cached
 
 
+def notes_of(facts) -> Optional[dict]:
+    """What the reduction said of this run (its summary without the
+    span table: the notes, the clock check's counts and constant), for
+    the result line's ``notes``; None where no reader asked for one."""
+    cached = getattr(facts, "_hostspans", None)
+    if cached is None:
+        return None
+    return {k: v for k, v in cached.summary().items() if k != "spans"}
+
+
 def idle_pct(facts, state: str) -> Optional[float]:
     spans = of(facts)
     return spans.idle_pct(state) if spans is not None else None

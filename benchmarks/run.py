@@ -2,16 +2,21 @@
 --seed <n> --seconds <s> --trace <0|1>``.
 
 Set-up (counted in ``setup_s``, from this process's start to the start
-of the measured window): the native build (``make -C native``, a no-op
-once built), JAX under the configuration's ``runtime_env``, the cell's
-dataset (made once per checkout), weights
-from ``--seed`` through the program's initialiser, the run's pipeline
+of the measured window): the family's build (``benchmarks/families/``;
+for R(2+1)D ``make -C native``, a no-op once built), JAX under the
+configuration's ``runtime_env``, the cell's request files (made once
+per checkout), weights from ``--seed``, the run's pipeline
 configuration, the program's own warm-up of the cell's buckets inside
 ``run_benchmark``, and the mix's ramp. Then the window of ``--seconds``:
 requests are released by :mod:`benchmarks.traffic`, the program serves
 them, and everything reported is computed from stamps and counters
 that fall inside the window. After it: the drain, the peak memory, and
-the serving applier's logits against the float32 reference.
+the family's check of the serving applier's logits against its float32
+reference.
+
+What is particular to a family of models (its inputs, weights,
+reference, operation count) is in the family file the configuration
+names; nothing here knows a model.
 
 The last line of standard output is the result object. Without an
 accelerator (or with fewer chips than the cell asks for) the run exits
@@ -29,7 +34,6 @@ T_PROCESS = time.time()  # set-up is counted from here
 import argparse  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import subprocess  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 
@@ -111,59 +115,6 @@ def derive_pipeline_config(config: dict, ckpt_path: str, out_dir: str):
     return path, pipeline
 
 
-def check_logits(config: dict, pipeline: dict, variables, ckpt_path: str,
-                 seed: int, sample_path: str, clips_starts) -> dict:
-    """The serving applier of the run (same jitted function, same
-    device weights, smallest warmed bucket) against the float32
-    reference on a seeded sample, outside the window."""
-    import jax
-    import numpy as np
-
-    from benchmarks import reference
-    from rnb_tpu.models.r2p1d import model as stage
-    step = pipeline["pipeline"][config["weights_steps"][0]]
-    sizes = tuple(step["layer_sizes"])
-    frames = int(step["consecutive_frames"])
-    pixel_path = step["pixel_path"]
-    rows = int(min(step["row_buckets"]))
-    hw = stage.FRAME_HW
-    device = jax.devices()[0]
-    apply = stage._shared_apply(step["start_index"], step["end_index"],
-                                config["model"]["num_classes"], sizes,
-                                pixel_path=pixel_path)
-    params = stage._shared_params(step["start_index"], step["end_index"],
-                                  config["model"]["num_classes"], sizes,
-                                  ckpt_path, device)
-    checked = min(2, rows)
-    rng = np.random.default_rng([seed % 2 ** 63, 7])
-    if pixel_path == "yuv420":
-        wire = rng.integers(0, 256, (rows, frames, hw * hw * 3 // 2),
-                            dtype=np.uint8)
-        ref_in = reference.normalize_yuv420(wire[:checked], hw, hw)
-    elif pixel_path == "dct":
-        # real files: the program's decoder makes the coefficient rows,
-        # its float64 numpy oracle the reference's pixels (the one part
-        # of the reference that is the program's own: PERF.md)
-        from rnb_tpu.decode import get_decoder
-        from rnb_tpu.ops import dct
-        decoded = get_decoder(sample_path).decode_clips_dct(
-            sample_path, list(clips_starts)[:checked], frames, width=hw,
-            height=hw, coeffs=dct.default_dct_coeffs(hw, hw))
-        wire = np.zeros((rows,) + tuple(decoded.shape[1:]), decoded.dtype)
-        wire[:checked] = decoded[:checked]
-        ref_in = reference.normalize_rgb_u8(
-            dct.dct_rows_to_rgb_numpy(wire[:checked], hw, hw))
-    else:
-        raise ValueError("no reference ingest for pixel_path %r"
-                         % (pixel_path,))
-    got = np.asarray(apply(params, jax.device_put(wire, device)),
-                     np.float32)[:checked]
-    with jax.default_matmul_precision("highest"):
-        ref = np.asarray(jax.jit(
-            lambda v, x: reference.forward(v, x, sizes))(variables, ref_in))
-    return reference.compare(got, ref)
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--workload", required=True)
@@ -188,10 +139,11 @@ def main(argv=None) -> int:
     chips = int(cell["chips"])
     config = manifest_mod.load_config_file(manifest, cell["config"],
                                            bench_root)
+    family = manifest_mod.load_family(
+        config["family"], manifest_mod.subdir(bench_root, "families"))
 
-    # children that never touch JAX come first: the native build
-    subprocess.run(["make", "-C", os.path.join(REPO, "native")],
-                   check=True, stdout=subprocess.DEVNULL)
+    # children that never touch JAX come first
+    family.build(REPO)
     if args.platform == "cpu" and chips > 1:
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=%d" % chips)
@@ -222,13 +174,8 @@ def main(argv=None) -> int:
     if found == "tpu":
         peak = peaks.peak_for(device_kind)["bf16_flops_per_s"]
 
-    from benchmarks import dataset, facts as facts_mod, stamps, traffic
+    from benchmarks import facts as facts_mod, stamps, traffic
     from rnb_tpu.benchmark import enable_compilation_cache, run_benchmark
-    from rnb_tpu.decode.native import load_native
-    from rnb_tpu.models.r2p1d import checkpoint
-    from rnb_tpu.models.r2p1d.sampler import R2P1DSampler
-    if load_native() is None:
-        raise RuntimeError("native/build/librnb_decode.so does not load")
     cache_dir = enable_compilation_cache()
     say("platform=%s kind=%s devices=%d compile_cache=%s"
         % (found, device_kind, len(devices), cache_dir))
@@ -236,24 +183,16 @@ def main(argv=None) -> int:
     out_dir = os.path.abspath(args.out or os.path.join(
         REPO, "logs", "benchmarks", args.workload))
     os.makedirs(out_dir, exist_ok=True)
-    loader = config["pipeline_config"]["pipeline"][0]
-    frames = int(loader["consecutive_frames"])
-    sampler = R2P1DSampler(consecutive_frames=frames)
-    shorts, longs, clips_of = dataset.prepare(
-        config["dataset"], os.path.join(REPO, "data", "benchmarks"),
-        sampler, int(loader["max_clips"]))
-    data_root = os.path.dirname(os.path.dirname(shorts[0]))
-    os.environ["RNB_TPU_DATA_ROOT"] = data_root
-    say("dataset %s: %d short, %d long names" % (data_root, len(shorts),
-                                                 len(longs)))
+    inputs = family.prepare_inputs(
+        config, os.path.join(REPO, "data", "benchmarks"))
+    shorts, longs = inputs["short_files"], inputs["long_files"]
+    os.environ["RNB_TPU_DATA_ROOT"] = inputs["data_root"]
+    say("dataset %s: %d short, %d long names"
+        % (inputs["data_root"], len(shorts), len(longs)))
 
-    model = config["model"]
-    variables = checkpoint.init_variables(
-        seed=args.seed % 2 ** 31, layer_sizes=tuple(model["layer_sizes"]),
-        num_classes=model["num_classes"])
-    ckpt_path = os.path.join(REPO, "checkpoints", "benchmarks",
-                             cell["config"] + ".msgpack")
-    checkpoint.save_checkpoint(ckpt_path, variables)
+    ckpt_path, weights = family.make_weights(
+        config, args.seed, os.path.join(REPO, "checkpoints", "benchmarks",
+                                        cell["config"]))
     config_path, pipeline = derive_pipeline_config(config, ckpt_path,
                                                    out_dir)
     say("weights from seed -> %s" % ckpt_path)
@@ -261,7 +200,8 @@ def main(argv=None) -> int:
     mix = traffic.load_mix(cell["traffic"],
                            manifest_mod.subdir(bench_root, "traffic"))
     schedule = traffic.build_schedule(
-        mix, args.seed, args.seconds, chips, shorts, longs, clips_of,
+        mix, args.seed, args.seconds, chips, shorts, longs,
+        inputs["rows_of"],
         capacity_hint=config.get("capacity_videos_per_chip_s"))
     traffic.ACTIVE = schedule
     trace_dir = os.path.join(out_dir, "xplane") if args.trace else None
@@ -299,21 +239,15 @@ def main(argv=None) -> int:
         trace_facts = xplane.TraceFacts(xplane.find_xplane(trace_dir),
                                         window_s=t1 - t0)
         trace_facts.host_span = (t0, t1)
-    from rnb_tpu.models.r2p1d.model import R2P1DRunner
-    row_bytes = int(np.prod(R2P1DRunner.input_shape_for(
-        start_index=1, max_rows=1, consecutive_frames=frames,
-        pixel_path=loader["pixel_path"])[0])) * np.dtype(
-            R2P1DRunner.input_dtype_for(
-                start_index=1, pixel_path=loader["pixel_path"])).itemsize
     facts = facts_mod.RunFacts(
         schedule=schedule, finish=finish, instance=instance, result=result,
         chips=chips, device_kind=device_kind, platform=found,
-        flops_per_clip=peaks.r2p1d_flops_per_clip(
-            model["layer_sizes"], model["consecutive_frames"],
-            model["frame_hw"], model["num_classes"]),
+        config=config, family=family,
+        flops_per_row=family.flops_per_row(config),
         peak_flops_per_s=peak,
         window_cpu_s=watcher.cpu[1] - watcher.cpu[0],
-        memory_peak_bytes=memory_peak, frame_bytes_per_row=row_bytes,
+        memory_peak_bytes=memory_peak,
+        wire_bytes_per_row=family.wire_bytes_per_row(config, pipeline),
         trace=trace_facts)
 
     # -- correct ---------------------------------------------------------
@@ -334,10 +268,8 @@ def main(argv=None) -> int:
             problems.append("the backlog emptied: %.1f%% of the requests "
                             "were left when the window closed, under "
                             "%.1f%%" % (100 * left, 100 * need))
-    sample = longs[0]
-    logits = check_logits(
-        config, pipeline, variables, ckpt_path, args.seed, sample,
-        sampler.sample(int(config["dataset"]["frames"]), video_id=sample))
+    logits = family.check_outputs(config, pipeline, weights, ckpt_path,
+                                  args.seed, inputs, devices, result)
     if not logits["ok"]:
         problems.append("logits against the float32 reference: %s" % logits)
     say("logits vs reference: %s" % logits)
@@ -396,6 +328,11 @@ def main(argv=None) -> int:
                      "run_total_s": result.total_time_s,
                      "warmup_s": result.warmup_s,
                      "wall_s": time.time() - T_PROCESS}
+    if args.trace:
+        from benchmarks import hostspans
+        reduced = hostspans.notes_of(facts)
+        if reduced is not None:
+            line["notes"]["hostspans"] = reduced
     print(json.dumps(line), flush=True)
     return 0
 

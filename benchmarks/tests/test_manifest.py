@@ -104,6 +104,7 @@ def check_config_file(path, reduced, tmp_path):
     with open(os.path.join(mm.REPO, path)) as f:
         config = json.load(f)
     assert config["reduced"] == reduced and config["assumed"]
+    assert config["family"] == "r2p1d"
     parsed = parse_config(config["pipeline_config"])
     assert parsed.video_path_iterator \
         == "benchmarks.traffic.ScheduledPathIterator"
@@ -128,7 +129,7 @@ def check_config_file(path, reduced, tmp_path):
                                           ((3, 4, 6, 3), 8)])
 def test_flop_count_equals_the_programs(sizes, frames):
     from rnb_tpu.models.r2p1d.flops import range_flops_per_clip
-    assert peaks.r2p1d_flops_per_clip(sizes, frames) \
+    assert mm.load_family("r2p1d").flops_per_clip(sizes, frames) \
         == range_flops_per_clip(1, 5, layer_sizes=sizes,
                                 consecutive_frames=frames)
 

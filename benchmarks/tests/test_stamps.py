@@ -48,9 +48,9 @@ def run_facts(tmp_path, process):
         total_rows, pad_rows, pad_emissions = 30, 6, 3
     return finish, instance, RunFacts(
         schedule=s, finish=finish, instance=instance, result=Result(),
-        chips=2, device_kind="cpu", platform="cpu", flops_per_clip=10,
-        peak_flops_per_s=None, window_cpu_s=10.0, memory_peak_bytes=0,
-        frame_bytes_per_row=1)
+        chips=2, device_kind="cpu", platform="cpu", config={}, family=None,
+        flops_per_row=10, peak_flops_per_s=None, window_cpu_s=10.0,
+        memory_peak_bytes=0, wire_bytes_per_row=1)
 
 
 def test_rows_match_requests_by_enqueue_stamp(tmp_path):

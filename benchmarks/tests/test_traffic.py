@@ -6,7 +6,8 @@ import os
 import numpy as np
 import pytest
 
-from benchmarks import dataset, traffic
+from benchmarks import manifest as mm
+from benchmarks import traffic
 
 MIXES = sorted(f[:-5] for f in os.listdir(traffic.TRAFFIC_DIR)
                if f.endswith(".json"))
@@ -115,7 +116,7 @@ def test_names_pin_the_mix_wherever_the_checkout_lies(tmp_path):
             "frames": 70, "labels": 1, "videos_per_label": 2, "seed": 0}
     sampler = R2P1DSampler(consecutive_frames=32)
     for sub in ("x", "some/other/place"):
-        shorts, longs, clips = dataset.prepare(
+        shorts, longs, clips = mm.load_family("r2p1d").prepare(
             spec, str(tmp_path / sub), sampler, 48)
         assert [clips[p] for p in shorts] == [1, 1]
         assert [clips[p] for p in longs] == [2, 2]  # 70 frames hold two
