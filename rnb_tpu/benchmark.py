@@ -167,6 +167,19 @@ class BenchmarkResult:
     pad_rows: int = 0
     total_rows: int = 0
     pad_emissions: int = 0
+    #: token accounting of stages whose rows are blocks of tokens
+    #: (rnb_tpu.models.nemotron_h): valid tokens / tokens shipped
+    #: (rows x tokens a row) over every dispatch served; 0 elsewhere
+    tokens_valid: int = 0
+    tokens_shipped: int = 0
+    #: sparse-expert accounting of a stage that holds a share of each
+    #: layer's experts: (valid token, chosen expert) pairs routed /
+    #: those whose expert is held here / the most and the mean that one
+    #: held expert of one layer served; 0 without such a stage
+    experts_assignments: int = 0
+    experts_held: int = 0
+    experts_max_per_expert: int = 0
+    experts_mean_per_expert: float = 0.0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all
@@ -486,6 +499,7 @@ def run_benchmark(config_path: str,
     compile_sink: list = []
     pad_sink: list = []
     ragged_sink: list = []
+    stage_counter_sink: list = []
     shard_sink: list = []
     fault_stats = FaultStats()
     # load-adaptive batching (rnb_tpu.autotune): one validated settings
@@ -972,6 +986,7 @@ def run_benchmark(config_path: str,
                     compile_sink=compile_sink,
                     pad_sink=pad_sink,
                     ragged_sink=ragged_sink,
+                    stage_counter_sink=stage_counter_sink,
                     shard_sink=shard_sink,
                     tracer=tracer,
                     handoff_settings=handoff_settings,
@@ -1279,6 +1294,12 @@ def run_benchmark(config_path: str,
                         "cache_hit_rows"):
                 ragged_stats[key] += int(snap.get(key, 0))
 
+    token_stats = expert_stats = None
+    if stage_counter_sink:
+        from rnb_tpu.telemetry import aggregate_stage_counters
+        token_stats, expert_stats = aggregate_stage_counters(
+            stage_counter_sink)
+
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
     # at teardown; replica lanes of the same step sum, the static
@@ -1521,6 +1542,15 @@ def run_benchmark(config_path: str,
                     "pad_emissions=%d\n"
                     % (pad_stats["pad_rows"], pad_stats["total_rows"],
                        pad_stats["emissions"]))
+        if token_stats is not None:
+            f.write("Tokens: valid=%d shipped=%d\n"
+                    % (token_stats["valid"], token_stats["shipped"]))
+        if expert_stats is not None:
+            f.write("Experts: assignments=%d held=%d max_per_expert=%d "
+                    "mean_per_expert=%.3f\n"
+                    % (expert_stats["assignments"], expert_stats["held"],
+                       expert_stats["max_per_expert"],
+                       expert_stats["mean_per_expert"]))
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -2037,6 +2067,15 @@ def run_benchmark(config_path: str,
         pad_rows=pad_stats["pad_rows"] if pad_stats else 0,
         total_rows=pad_stats["total_rows"] if pad_stats else 0,
         pad_emissions=pad_stats["emissions"] if pad_stats else 0,
+        tokens_valid=token_stats["valid"] if token_stats else 0,
+        tokens_shipped=token_stats["shipped"] if token_stats else 0,
+        experts_assignments=(expert_stats["assignments"]
+                             if expert_stats else 0),
+        experts_held=expert_stats["held"] if expert_stats else 0,
+        experts_max_per_expert=(expert_stats["max_per_expert"]
+                                if expert_stats else 0),
+        experts_mean_per_expert=(expert_stats["mean_per_expert"]
+                                 if expert_stats else 0.0),
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

@@ -68,7 +68,8 @@ from rnb_tpu.health import expired as _deadline_expired
 from rnb_tpu.ops.ragged import check_segment_offsets
 from rnb_tpu.placement import CostRecord
 from rnb_tpu.stage import PaddedBatch, RaggedBatch
-from rnb_tpu.telemetry import TimeCardList, TimeCardSummary, logname
+from rnb_tpu.telemetry import (TimeCardList, TimeCardSummary, logname,
+                               logroot)
 from rnb_tpu.utils.class_utils import load_class
 from rnb_tpu.utils.lazy_jax import jax_numpy as _jax_numpy
 
@@ -180,6 +181,10 @@ class RunnerContext:
     #: batching stages append their PadCounter snapshot here
     #: (BenchmarkResult pad_rows/total_rows + log-meta `Padding:` line)
     pad_sink: Optional[List] = None
+    #: stages with counters of their own (``stage_counters()``: valid
+    #: and shipped tokens, assignments served by each held expert)
+    #: append them here at teardown (the `Tokens:` / `Experts:` lines)
+    stage_counter_sink: Optional[List] = None
     #: ragged stages (root 'ragged' config key) append their
     #: ragged_stats here (BenchmarkResult ragged_* + `Ragged:` line)
     ragged_sink: Optional[List] = None
@@ -258,15 +263,24 @@ class RunnerContext:
     critpath: bool = False
 
 
-def _dispatch_counts(tensors, device: DeviceSpec) -> dict:
+def _dispatch_counts(tensors, device: DeviceSpec, card=None) -> dict:
     """What a batched dispatch's ``model_call`` span carries: the rows
     shipped (the bucket or pool), the valid ones among them, and the
-    id of the device they run on. Empty for a stage that takes no
-    batch (a loader)."""
+    id of the device they run on; for a batch that carries its segment
+    table, the requests packed into it and, where their cards say how
+    many tokens each holds, the valid tokens. Empty for a stage that
+    takes no batch (a loader)."""
     if tensors and isinstance(tensors[0], PaddedBatch):
         head = tensors[0]
-        return {"rows": head.max_rows, "rows_valid": int(head.valid),
-                "device": int(device.resolve().id)}
+        counts = {"rows": head.max_rows, "rows_valid": int(head.valid),
+                  "device": int(device.resolve().id)}
+        if isinstance(head, RaggedBatch):
+            counts["segments"] = head.num_segments
+            tokens = [getattr(tc, "num_tokens", None)
+                      for tc in _cards_of(card)] if card is not None else []
+            if tokens and None not in tokens:
+                counts["tokens_valid"] = int(sum(tokens))
+        return counts
     return {}
 
 
@@ -753,6 +767,10 @@ def runner(ctx: RunnerContext) -> None:
             # per-request phase stamps (decode/hold/transfer) by it,
             # on every run
             model.bind_step(ctx.step_idx)
+        if hasattr(model, "bind_log_dir"):
+            # a stage that keeps samples of what it served (what a
+            # run's check compares) writes them beside the job's logs
+            model.bind_log_dir(logroot(ctx.job_id, ctx.log_base))
         if ctx.tracer is not None and hasattr(model, "enable_trace"):
             # the `trace` config key's Tracer (rnb_tpu.trace): stages
             # that own sampled occupancy sources register them here;
@@ -1142,7 +1160,8 @@ def runner(ctx: RunnerContext) -> None:
                         if stall > 0:
                             time.sleep(stall / 1000.0)
                     time_card.record("inference%d_start" % ctx.step_idx)
-                    call_counts = _dispatch_counts(tensors, ctx.device)
+                    call_counts = _dispatch_counts(tensors, ctx.device,
+                                                   in_card)
                     attempt = 0
                     failed_reason = None
                     lane_death = None
@@ -1631,6 +1650,13 @@ def runner(ctx: RunnerContext) -> None:
                 and getattr(model, "padding", None) is not None):
             try:
                 ctx.pad_sink.append(model.padding.snapshot())
+            except Exception:
+                traceback.print_exc()
+        # a stage's own counters (tokens, expert assignments)
+        if (ctx.stage_counter_sink is not None
+                and hasattr(model, "stage_counters")):
+            try:
+                ctx.stage_counter_sink.append(model.stage_counters())
             except Exception:
                 traceback.print_exc()
         if (ctx.ragged_sink is not None

@@ -128,8 +128,10 @@ class RaggedBatch(PaddedBatch):
     the stage's one compiled shape, plus the per-request segment table
     (rnb_tpu.ops.ragged).
 
-    ``data`` always has exactly the pool shape — never a bucket —
-    so every dispatch hits the same XLA executable; ``valid`` is the
+    Under the ``ragged`` root key ``data`` always has exactly the
+    pool shape — never a bucket — so every dispatch hits the same XLA
+    executable (a ``Batcher`` with ``segments`` ships a bucket with its
+    segment table instead: packed sequences); ``valid`` is the
     scalar ``rows_valid`` the ragged forward primitive masks against;
     ``segment_offsets`` partitions ``[0, valid)`` per constituent
     request (request i owns rows ``[offsets[i], offsets[i+1])``),

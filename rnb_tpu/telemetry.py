@@ -97,7 +97,11 @@ CONTENT_STAMPS = ("num_clips", "cache_hit", "cache_coalesced",
                   # the SAME copy must not claim again (it owns the
                   # rid's terminal outcome; a re-claim would consume
                   # the sibling copy's LOSER slot)
-                  "hedge_resolved")
+                  "hedge_resolved",
+                  # valid tokens of a request whose rows are blocks of
+                  # tokens (rnb_tpu.models.nemotron_h): the fuse and
+                  # model_call spans sum it over a packed dispatch
+                  "num_tokens")
 
 #: card-riding carriers that are DELIBERATELY not content stamps: they
 #: must NOT survive fork/merge. Each holds single-owner live state —
@@ -219,6 +223,15 @@ META_LINE_REGISTRY = (
     StampSpec("Padding:", "rnb_tpu/benchmark.py",
               "bucketed-path padding waste: pad rows / total shipped "
               "rows / emissions summed over batching stages"),
+    StampSpec("Tokens:", "rnb_tpu/benchmark.py",
+              "token accounting of stages whose rows are blocks of "
+              "tokens: valid tokens / tokens shipped (rows x tokens a "
+              "row) over every dispatch (such stages only)"),
+    StampSpec("Experts:", "rnb_tpu/benchmark.py",
+              "sparse-expert accounting of a stage holding a share of "
+              "each layer's experts: pairs routed, pairs whose expert "
+              "is held here, most and mean served by one held expert "
+              "of one layer (such stages only)"),
     StampSpec("Compiles:", "rnb_tpu/benchmark.py",
               "JSON per-step jit-entry signature counts "
               "{step: {warmup, steady_new, steady_calls}} — "
@@ -424,7 +437,8 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("exec{step}.model_call", "rnb_tpu/runner.py",
               "span: the stage model call for one dispatch (a batched "
               "dispatch carries rows = rows shipped, rows_valid, "
-              "device = the device's id)"),
+              "device = the device's id; a packed one also segments "
+              "and tokens_valid)"),
     StampSpec("exec{step}.device_sync", "rnb_tpu/runner.py",
               "span: blocking on device output readiness "
               "(sync_outputs)"),
@@ -487,6 +501,15 @@ TRACE_EVENT_REGISTRY = (
               "backpressure)"),
     StampSpec("transfer.job", "rnb_tpu/staging.py",
               "span: one queued job on the transfer worker thread"),
+    StampSpec("batcher.fuse", "rnb_tpu/batcher.py",
+              "span: concatenating the pending requests' valid rows "
+              "into one batch (rows, segments = requests; tokens_valid "
+              "where the cards carry num_tokens)"),
+    StampSpec("tokens.read", "rnb_tpu/models/nemotron_h/stages.py",
+              "span: reading one request's prompt file"),
+    StampSpec("tokens.pack", "rnb_tpu/models/nemotron_h/stages.py",
+              "span: one prompt into its rows of tokens (rows, "
+              "tokens_valid, segments = 1)"),
     StampSpec("batcher.emit", "rnb_tpu/batcher.py",
               "instant: the Batcher fused + emitted one batch "
               "(args: requests, rows)"),
@@ -1232,3 +1255,31 @@ class TimeCardSummary:
         critpath = self.critpath_line()
         if critpath is not None:
             fp.write(critpath + "\n")
+
+
+def aggregate_stage_counters(snapshots):
+    """(token stats, expert stats) summed over the ``stage_counters()``
+    snapshots of a run's stage instances: the numbers of the
+    ``Tokens:`` and ``Experts:`` log-meta lines. Either is None where
+    no stage counts it. The per-expert counts are summed over the
+    instances before the most loaded one is taken (replicas of one
+    stage hold the same experts)."""
+    import numpy as np
+    tokens = experts = served = None
+    for snap in snapshots:
+        if "tokens_valid" in snap:
+            tokens = tokens or {"valid": 0, "shipped": 0}
+            tokens["valid"] += int(snap["tokens_valid"])
+            tokens["shipped"] += int(snap["tokens_shipped"])
+        if snap.get("expert_served") is not None:
+            part = np.asarray(snap["expert_served"], np.int64)
+            served = part if served is None or served.shape != part.shape \
+                else served + part
+            experts = experts or {"assignments": 0}
+            experts["assignments"] += int(snap["tokens_valid"]) \
+                * int(snap["experts_per_token"]) * part.shape[0]
+    if experts is not None:
+        experts.update(held=int(served.sum()),
+                       max_per_expert=int(served.max()),
+                       mean_per_expert=float(served.mean()))
+    return tokens, experts

@@ -133,6 +133,20 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta[key] = int(val)
+        elif line.startswith("Tokens:"):
+            # "Tokens: valid=V shipped=S" — token accounting of stages
+            # whose rows are blocks of tokens
+            for part in line.split(":", 1)[1].split():
+                key, _, val = part.partition("=")
+                meta["tokens_" + key] = int(val)
+        elif line.startswith("Experts:"):
+            # "Experts: assignments=A held=H max_per_expert=M
+            #  mean_per_expert=F" — sparse-expert accounting of a stage
+            # holding a share of each layer's experts
+            for part in line.split(":", 1)[1].split():
+                key, _, val = part.partition("=")
+                meta["experts_" + key] = float(val) if "." in val \
+                    else int(val)
         elif line.startswith("Compiles:"):
             # JSON {step: {warmup, steady_new, steady_calls}} —
             # jit-entry signature accounting (rnb_tpu.compilestats);

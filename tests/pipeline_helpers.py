@@ -93,6 +93,42 @@ class TinySlowSink(StageModel):
         return None, non_tensors, time_card
 
 
+class BackpressureSink(StageModel):
+    """Final stage that holds each dispatch until the loader upstream
+    has closed ``ahead`` further batches — enough to fill the ring
+    behind it and have one more assembled and waiting for a slot — or
+    has emitted its last row. Back-pressure by condition, not by the
+    clock: however slow the host decodes, a slot never frees before a
+    whole batch waits for it. Reads the active tracer's ``loader.emit``
+    spans (the run needs ``trace`` enabled)."""
+
+    def __init__(self, device, ahead=3, total_rows=0, timeout_s=120.0,
+                 **kwargs):
+        super().__init__(device)
+        self.ahead, self.total_rows = int(ahead), int(total_rows)
+        self.timeout_s = float(timeout_s)
+        self._taken = 0
+
+    @staticmethod
+    def output_shape():
+        return None
+
+    def __call__(self, tensors, non_tensors, time_card):
+        import time
+
+        from rnb_tpu import trace
+        self._taken += 1
+        deadline = time.monotonic() + self.timeout_s
+        while time.monotonic() < deadline:
+            rows = [e[6]["rows"] for e in trace.ACTIVE.snapshot_events()
+                    if e[0] == "loader.emit"]
+            if len(rows) >= self._taken + self.ahead \
+                    or sum(rows) >= self.total_rows:
+                break
+            time.sleep(0.002)
+        return None, non_tensors, time_card
+
+
 class HoardingSink(StageModel):
     """Final stage that swallows EVERY item and releases them only at
     end-of-stream, one per flush() call — a deterministic stand-in for

@@ -1,0 +1,539 @@
+"""The Nemotron-H family against its plain reference, at a toy size on
+the CPU with weights from a seed: every mixer alone, the blocked scan
+against the recurrence, packing, routing under skew, the expert share
+against the uncut layer, the whole 14-block pattern through the one
+benchmark command, the recipe, and the lower-precision control that
+must fail. Then the real configuration: its pipeline through the
+program's own checks and, where a v5e can be described, the compile of
+its largest bucket (one file, the topology inside a fixture)."""
+
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmarks import manifest as mm  # noqa: E402
+from benchmarks.references import compare  # noqa: E402
+from benchmarks.references import nemotron_h as reference  # noqa: E402
+
+REAL = "benchmarks/configs/nemotron3-nano-l14-ep2.json"
+CELL = "nemotron3-nano.bulk"
+SEED = 3_000_000_123
+
+#: the published pattern's first 14 blocks at toy widths
+TOY = {
+    "hybrid_override_pattern":
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    "num_hidden_layers": 14, "hidden_size": 64, "vocab_size": 256,
+    "chunk_size": 16, "mamba_num_heads": 8, "mamba_head_dim": 8,
+    "n_groups": 2, "ssm_state_size": 16, "conv_kernel": 4,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "n_routed_experts": 4, "num_experts_per_tok": 2,
+    "published": {"n_routed_experts": 8, "num_hidden_layers": 52},
+    "moe_intermediate_size": 32,
+    "moe_shared_expert_intermediate_size": 64,
+    "routed_scaling_factor": 2.5, "layer_norm_epsilon": 1e-5,
+    "time_step_min": 0.001, "time_step_max": 0.1,
+    "time_step_floor": 1e-4}
+HELD = (0, 1, 2, 3)
+Q = TOY["chunk_size"]
+
+
+@pytest.fixture(scope="module")
+def toy():
+    import jax
+
+    from rnb_tpu.models.nemotron_h import checkpoint, network
+    cfg = network.NemotronHConfig.from_published(TOY)
+    device = jax.devices()[0]
+    return {"cfg": cfg, "device": device,
+            "params": checkpoint.make_params(cfg, SEED, HELD, device),
+            "slots": network.held_slots(cfg, HELD),
+            "read": checkpoint.reference_reader(cfg, SEED, device),
+            "reference": reference.Reference(TOY)}
+
+
+def prompts_of(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n).astype(np.int32)
+            for n in lengths]
+
+
+def pack(prompts, rows):
+    """-> (tokens (rows, Q), meta, offsets): the prompts packed in
+    order, as the loader and the Batcher pack them."""
+    from rnb_tpu.models.nemotron_h import stages
+    tokens = np.zeros((rows, Q), np.int32)
+    per_row = np.zeros(rows, np.int32)
+    offsets, row = [0], 0
+    for prompt in prompts:
+        n = stages.rows_of_tokens(len(prompt), Q)
+        tokens.reshape(-1)[row * Q:row * Q + len(prompt)] = prompt
+        per_row[row:row + n] = Q
+        per_row[row + n - 1] = len(prompt) - (n - 1) * Q
+        row += n
+        offsets.append(row)
+    return tokens, stages.dispatch_meta(offsets, per_row, rows, Q), offsets
+
+
+def run_program(toy, prompts, rows, **kwargs):
+    import jax
+
+    from rnb_tpu.models.nemotron_h import network
+    tokens, meta, offsets = pack(prompts, rows)
+    logits, chosen, served = jax.jit(
+        lambda p, s, t, m: network.forward(
+            toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True,
+            **kwargs))(
+        toy["params"], toy["slots"], tokens, meta)
+    chosen = np.asarray(chosen)
+    per_prompt = [chosen[:, o * Q:o * Q + len(p)]
+                  for o, p in zip(offsets, prompts)]
+    return np.asarray(logits)[:len(prompts)], per_prompt, np.asarray(served)
+
+
+def run_reference(toy, prompt, forced=None, held=HELD):
+    import jax
+    with jax.default_matmul_precision("highest"):
+        return toy["reference"].forward(toy["read"], prompt, held=held,
+                                        forced=forced)
+
+
+# -- every mixer alone ------------------------------------------------------
+
+
+def block_inputs(toy, length, kind):
+    """A normed activation (rows, Q, hidden) for one prompt of
+    ``length`` tokens, the first block of ``kind`` and its weights for
+    program and reference."""
+    import jax.numpy as jnp
+    cfg = toy["cfg"]
+    index = cfg.blocks_of(kind)[0]
+    rng = np.random.default_rng(length)
+    rows = -(-length // Q)
+    h = np.zeros((rows * Q, cfg.hidden_size), np.float32)
+    h[:length] = rng.standard_normal((length, cfg.hidden_size))
+    h = jnp.asarray(h, jnp.bfloat16)
+    weights = {t: toy["read"]("b%d.%s" % (index, t),
+                              HELD if t in ("up", "down") else None)
+               for t in reference.TENSORS[kind]}
+    return h, rows, toy["params"]["b%d" % index], weights
+
+
+@pytest.mark.parametrize("length", [5, 16, 37, 100])
+def test_mamba_mixer_and_its_blocked_scan_match_the_recurrence(toy, length):
+    """Lengths that are no multiple of the chunk: the blocked scan
+    (rows of 16, states carried between them) against the plain
+    recurrence over t."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import network
+    h, rows, params, weights = block_inputs(toy, length, "M")
+    first = jnp.arange(rows) == 0
+    got = network.mamba_mixer(toy["cfg"], params,
+                              h.reshape(rows, Q, -1), first)
+    with jax.default_matmul_precision("highest"):
+        want = reference.mamba(TOY, weights, h.astype(jnp.float32)[:length])
+    got = np.asarray(got).reshape(rows * Q, -1)[:length]
+    assert compare(got, np.asarray(want), 0.03)["ok"]
+
+
+def test_attention_mixer_matches_one_masked_softmax(toy):
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import network
+    length = 45
+    h, rows, params, weights = block_inputs(toy, length, "*")
+    got = network.attention_mixer(
+        toy["cfg"], params, h.reshape(rows, Q, -1),
+        jnp.zeros(rows, jnp.int32), interpret=True)
+    with jax.default_matmul_precision("highest"):
+        want = reference.attention(TOY, weights,
+                                   h.astype(jnp.float32)[:length])
+    got = np.asarray(got).reshape(rows * Q, -1)[:length]
+    assert compare(got, np.asarray(want), 0.03)["ok"]
+
+
+def experts_both(toy, length, held, b_corr=None):
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import checkpoint, network
+    cfg = toy["cfg"]
+    index = cfg.blocks_of("E")[0]
+    h, rows, _, weights = block_inputs(toy, length, "E")
+    params = checkpoint.make_params(cfg, SEED, held, toy["device"],
+                                    groups=["b%d" % index])["b%d" % index]
+    weights = dict(weights)
+    for t in ("up", "down"):
+        weights[t] = toy["read"]("b%d.%s" % (index, t), held)
+    if b_corr is not None:
+        params = dict(params, b_corr=jnp.asarray(b_corr, jnp.float32))
+        weights["b_corr"] = jnp.asarray(b_corr, jnp.float32)
+    ok = jnp.arange(rows * Q).reshape(rows, Q) < length
+    got, ids, counts = network.experts_mixer(
+        cfg, params, h.reshape(rows, Q, -1), ok,
+        network.held_slots(cfg, held), interpret=True)
+    with jax.default_matmul_precision("highest"):
+        want, _, shortfall = reference.experts(
+            TOY, weights, h.astype(jnp.float32)[:length],
+            jnp.asarray(held, jnp.int32), forced=ids[:length])
+    assert float(shortfall.max()) < 0.02
+    return (np.asarray(got).reshape(rows * Q, -1)[:length],
+            np.asarray(want), np.asarray(counts), weights)
+
+
+def test_experts_mixer_matches_the_loop_over_chosen_experts(toy):
+    got, want, counts, _ = experts_both(toy, 50, HELD)
+    assert compare(got, want, 0.03)["ok"]
+    assert 0 < counts.sum() < 50 * TOY["num_experts_per_tok"]
+
+
+def test_routing_drops_nothing_under_a_skewed_router(toy):
+    """Every token chooses experts 0 and 1: two held experts serve
+    every pair, the others none, and nothing is dropped."""
+    skew = np.zeros(8, np.float32)
+    skew[:2] = 10.0
+    got, want, counts, _ = experts_both(toy, 61, HELD, b_corr=skew)
+    assert counts.tolist() == [61, 61, 0, 0]
+    assert compare(got, want, 0.03)["ok"]
+
+
+def test_the_share_ties_to_the_model(toy):
+    """Experts 0-3 here plus experts 4-7 on the other chip, the shared
+    expert counted once, are the uncut reference's E block."""
+    import jax
+    import jax.numpy as jnp
+    length = 50
+    low, _, low_n, weights = experts_both(toy, length, (0, 1, 2, 3))
+    high, _, high_n, _ = experts_both(toy, length, (4, 5, 6, 7))
+    assert low_n.sum() + high_n.sum() \
+        == length * TOY["num_experts_per_tok"]
+    index = toy["cfg"].blocks_of("E")[0]
+    h = block_inputs(toy, length, "E")[0].astype(jnp.float32)[:length]
+    every = tuple(range(8))
+    whole = dict(weights)
+    for t in ("up", "down"):
+        whole[t] = toy["read"]("b%d.%s" % (index, t), every)
+    with jax.default_matmul_precision("highest"):
+        uncut, _, _ = reference.experts(TOY, whole, h,
+                                        jnp.asarray(every, jnp.int32))
+        shared = jnp.square(jax.nn.relu(h @ whole["shared_up"])) \
+            @ whole["shared_down"]
+    assert compare(low + high - np.asarray(shared), np.asarray(uncut),
+                   0.03)["ok"]
+
+
+# -- the whole pattern ------------------------------------------------------
+
+
+def fuse(rows_of_requests):
+    """The Batcher's emissions for requests of so many rows, driven as
+    the executor drives it: ``take_ready`` ahead of each arrival,
+    ``flush`` until dry at the end. -> [(request ids, valid rows,
+    bucket rows, segment offsets)]."""
+    from rnb_tpu.batcher import Batcher
+    from rnb_tpu.stage import PaddedBatch
+    from rnb_tpu.telemetry import TimeCard
+    b = Batcher("host", batch=64, segments=True, shapes=[[8, Q], [8]],
+                row_buckets=[4, 8])
+    out = []
+
+    def note(emission):
+        if emission is not None and emission[2] is not None:
+            ids, lens = emission[0]
+            assert ids.max_rows == lens.max_rows
+            # each request's rows carry its id: the table cuts them
+            offsets = list(ids.segment_offsets)
+            cards = [tc.id for tc in emission[2].time_cards]
+            for card, lo, hi in zip(cards, offsets, offsets[1:]):
+                assert (np.asarray(ids.data)[lo:hi] == card).all()
+            out.append((cards, ids.valid, ids.max_rows, offsets))
+
+    for rid, rows in enumerate(rows_of_requests):
+        note(b.take_ready())
+        tokens = np.full((rows, Q), rid, np.int32)
+        note(b((PaddedBatch(tokens, rows),
+                PaddedBatch(np.full((rows,), Q, np.int32), rows)),
+               None, TimeCard(rid)))
+    while True:
+        last = b.flush()
+        if last is None:
+            break
+        note(last)
+    return out
+
+
+@pytest.mark.parametrize("rows,want,shipped", [
+    # a request that does not fit waits aside while those behind it
+    # fill the batch, and opens the next one (first in, first out
+    # would ship 32 rows: [0], [1], [2, 3], [4, 5])
+    ([5, 6, 3, 2, 7, 1], [[0, 2], [1, 3], [4, 5]], 24),
+    # more than four aside: the batch goes as it is, and the five open
+    # the next ones in their order, each with what still fits
+    ([5, 6, 7, 6, 5, 4, 3, 2], [[0], [1], [2], [3, 7], [4, 6], [5]], 44),
+    # requests from aside fill a batch: it goes ahead of the next
+    # arrival
+    ([5, 4, 4, 3, 8], [[0, 3], [1, 2], [4]], 24),
+])
+def test_a_packed_batch_fills_past_a_request_that_does_not_fit(
+        rows, want, shipped):
+    got = fuse(rows)
+    assert [cards for cards, _, _, _ in got] == want
+    # every request once, whole, at a bucket that holds it
+    assert sorted(r for cards, _, _, _ in got for r in cards) \
+        == list(range(len(rows)))
+    for cards, valid, bucket, offsets in got:
+        assert valid == sum(rows[r] for r in cards) <= bucket <= 8
+        assert offsets == list(np.cumsum([0] + [rows[r] for r in cards]))
+    assert sum(bucket for _, _, bucket, _ in got) == shipped
+
+
+def test_scope_table_reads_an_instruction_that_spans_lines():
+    """A Pallas kernel's attributes hold line breaks: its scope is on
+    the instruction's last line."""
+    from rnb_tpu.models.nemotron_h import stages
+    text = """  %fusion.1 = f32[8,4]{1,0} fusion(%p), kind=kLoop, metadata={op_name="jit(f)/ssd/mul"}
+  %kernel.2 = (f32[2,8]{1,0}, bf16[2,16]{1,0}) custom-call(%a), frontend_attributes={kernel_metadata={
+"xprof_metadata":"{}"
+}}, metadata={op_name="jit(f)/attn/vmap(k)/pallas_call" stack_frame_id=3}
+  %copy.3 = f32[8,4]{1,0} copy(%fusion.1)
+  ROOT %add.4 = f32[8,4]{1,0} add(%copy.3, %p), metadata={op_name="jit(f)/head/add"}"""
+    assert stages.scopes_of_hlo(text) == {
+        "%fusion.1 f32[8,4]": "jit(f)/ssd/mul",
+        "%kernel.2 f32[2,8]": "jit(f)/attn/vmap(k)/pallas_call",
+        "%add.4 f32[8,4]": "jit(f)/head/add"}
+
+
+def test_packing_is_invisible(toy):
+    """A prompt's logits depend neither on what shares its dispatch
+    nor on the bucket."""
+    a, b, c, d = prompts_of([37, 5, 64, 20])
+    alone, chosen, _ = run_program(toy, [a], 4)
+    packed, _, _ = run_program(toy, [b, c, a, d], 16)
+    other, _, _ = run_program(toy, [d, a], 8)
+    want = run_reference(toy, a, forced=chosen[0])
+    spread = float(np.asarray(want["logits"]).std())
+    for got in (packed[2], other[1]):
+        # the same arithmetic on the same rows: far inside the
+        # comparison's tolerance
+        assert np.abs(got - alone[0]).max() < 0.005 * spread
+    assert compare(alone[0], np.asarray(want["logits"]), 0.03)["ok"]
+
+
+def test_full_pattern_matches_the_reference_and_the_control_fails(toy):
+    import jax.numpy as jnp
+    family = mm.load_family("nemotron_h")
+    prompts = prompts_of([5, 16, 37, 64, 20, 70], seed=4)
+    logits, chosen, served = run_program(toy, prompts, 16)
+    refs = [run_reference(toy, p, forced=c)
+            for p, c in zip(prompts, chosen)]
+    want = np.stack([np.asarray(r["logits"]) for r in refs])
+    verdict = compare(logits, want, family.SHARE_OF_SPREAD)
+    assert verdict["ok"], verdict
+    assert max(float(r["shortfall"].max()) for r in refs) \
+        < family.ROUTE_SLACK
+    valid = sum(len(p) for p in prompts)
+    assert served.sum(axis=1).max() <= valid * TOY["num_experts_per_tok"]
+    # the reference's own free choice agrees almost everywhere
+    free = run_reference(toy, prompts[3])
+    agree = (np.sort(np.asarray(free["chosen"]), -1)
+             == np.sort(chosen[3], -1)).all(-1).mean()
+    assert agree > 0.9
+    # the control: the experts' matrices through float8, and the
+    # scan's states carried in bfloat16, each outside the tolerance
+    fp8 = run_program(
+        toy, prompts, 16,
+        expert_cast=lambda w: w.astype(jnp.float8_e4m3fn)
+        .astype(jnp.bfloat16))
+    refs8 = np.stack([np.asarray(run_reference(toy, p, forced=c)["logits"])
+                      for p, c in zip(prompts, fp8[1])])
+    assert not compare(fp8[0], refs8, family.SHARE_OF_SPREAD)["ok"]
+
+
+def test_recipe_gives_program_and_reference_the_same_values(toy):
+    from rnb_tpu.models.nemotron_h import checkpoint
+    params, read = toy["params"], toy["read"]
+    for name, tensor in (("b0.in_proj", params["b0"]["in_proj"]),
+                         ("top.embed", params["embed"]),
+                         ("b1.b_corr", params["b1"]["b_corr"])):
+        assert np.array_equal(np.asarray(tensor, np.float32),
+                              np.asarray(read(name)))
+    # an expert is a function of its global id, whoever holds it
+    up = np.asarray(params["b1"]["up"], np.float32)
+    assert np.array_equal(up[[3, 1]], np.asarray(read("b1.up", (3, 1))))
+    other = checkpoint.make_params(toy["cfg"], SEED, (2, 3, 4, 5),
+                                   toy["device"], groups=["b1"])
+    assert np.array_equal(np.asarray(other["b1"]["up"], np.float32)[:2],
+                          up[2:])
+    assert not np.array_equal(
+        np.asarray(read("b1.up", (0,))),
+        np.asarray(checkpoint.reference_reader(
+            toy["cfg"], SEED + 1, toy["device"])("b1.up", (0,))))
+
+
+def test_operation_counts_agree_with_the_family_file(toy):
+    from rnb_tpu.models.nemotron_h import flops, network
+    family = mm.load_family("nemotron_h")
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.NemotronHConfig.from_published(config)
+    assert flops.flops_per_token(cfg, 512.0, 3.0) \
+        == family.flops_per_token(config, 512.0, 3.0)
+    assert family.flops_per_row(config) == config["chunk_size"] \
+        * flops.flops_per_token(cfg, family.mean_context(config), 3.0)
+
+
+# -- through the one benchmark command --------------------------------------
+
+
+def toy_tree(tmp_path):
+    """The real manifest's new cell over a toy-width copy of its
+    configuration: the same family, stages, mix and readers."""
+    manifest = mm.load()
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    config.update(TOY)
+    config["experts_held"] = {"first": 0, "count": 4}
+    config["dataset"] = {"seed": 0, "long_every": 11,
+                         "short": {"count": 6, "median": 24, "sigma": 0.8,
+                                   "min": 4, "max": 60},
+                         "long": {"count": 2, "min": 64, "max": 128}}
+    config["capacity_videos_per_chip_s"] = 500
+    config["share_of_spread"] = 0.06
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    loader.update(max_rows=8, chunk=16)
+    batcher.update(batch=8, shapes=[[8, 16], [8]], row_buckets=[4, 8])
+    prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
+                   sample_every=5, samples=8)
+    os.makedirs(tmp_path / "benchmarks" / "configs")
+    with open(tmp_path / REAL, "w") as f:
+        json.dump(config, f)
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(manifest, f)
+    return str(tmp_path / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_full_pattern_through_the_benchmark_command(trace, tmp_path):
+    out = tmp_path / "out"
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+         "--manifest", toy_tree(tmp_path), "--workload", CELL,
+         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
+         "--platform", "cpu", "--out", str(out)],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, \
+        done.stderr[-3000:]
+    assert line["attempted"] > 0
+    meta = (out / "run" / "log-meta.txt").read_text()
+    assert "Tokens: valid=" in meta and "Experts: assignments=" in meta
+    assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
+    metrics = line["metrics"]
+    if trace:
+        assert metrics["tokens_per_s.bulk"]["value"] > 0
+        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
+        assert 30 < metrics["held_assignment_pct.bulk"]["value"] < 70
+        assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
+        assert metrics["rows_per_dispatch.bulk"]["value"] > 0
+        # what stands against the chip's peak does not come from a CPU
+        assert not any("roofline" in n or "util" in n for n in metrics)
+    else:
+        assert metrics["videos_per_s"]["value"] > 0
+        assert metrics["setup_s"]["value"] > 0
+
+
+# -- the real configuration -------------------------------------------------
+
+
+def test_real_configuration_keeps_the_published_sizes():
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    entry = mm.config_entry(mm.load(), "nemotron3-nano-l14-ep2")
+    assert entry["reduced"] == config["reduced"] \
+        == ["num_hidden_layers", "n_routed_experts"]
+    assert config["family"] == "nemotron_h" and config["assumed"]
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["source_url"] == entry["source"])
+        differ = sorted(k for k, v in row["config"].items()
+                        if config.get(k) != v)
+        assert differ == sorted(config["reduced"])
+        assert config["published"] == {
+            k: row["config"][k] for k in config["reduced"]}
+    assert len(config["hybrid_override_pattern"]) == 52
+
+
+def test_real_pipeline_passes_the_programs_own_checks(tmp_path):
+    from rnb_tpu.config import parse_config
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    parsed = parse_config(config["pipeline_config"])
+    assert parsed.video_path_iterator \
+        == "benchmarks.traffic.ScheduledPathIterator"
+    path = tmp_path / "pipeline.json"
+    path.write_text(json.dumps(config["pipeline_config"]))
+    lint = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "rnb_lint.py"),
+         "--config", str(path)], capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert lint.returncode == 0, lint.stdout + lint.stderr
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_largest_bucket_fits_the_chip_and_clears_the_floor(one_chip):
+    """The real stage program at 64 rows, compiled for a described v5e
+    (nothing runs): weights and temporaries between 4 and 14 GiB."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import checkpoint, network
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.NemotronHConfig.from_published(config)
+    step = config["pipeline_config"]["pipeline"][-1]
+    rows, held = max(step["row_buckets"]), config["experts_held"]["count"]
+    params = {}
+    for group, tensors in checkpoint.tensor_specs(cfg, held).items():
+        made = {name: jax.ShapeDtypeStruct(
+            spec.shape, getattr(jnp, spec.dtype), sharding=one_chip)
+            for name, spec in tensors.items()}
+        params.update(made if group == "top" else {group: made})
+
+    def of(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    memory = jax.jit(lambda p, s, t, m: network.forward(
+        cfg, p, s, t, m[0], m[1], m[2])).lower(
+        params, of((cfg.router_experts,)), of((rows, cfg.chunk_size)),
+        of((3, rows))).compile().memory_analysis()
+    projected = memory.temp_size_in_bytes + memory.argument_size_in_bytes
+    assert 4 * 2 ** 30 <= projected <= 14 * 2 ** 30, projected / 2 ** 30

@@ -155,7 +155,10 @@ def test_split_range_logits_match_whole_range(tmp_path):
 def test_fused_loader_keeps_filling_while_the_ring_is_full(tmp_path):
     """Emission under back-pressure, through the real runtime: a
     backlog of one-clip videos -> R2P1DFusingLoader (hold 0 ms, 8-row
-    cap, a ring of 2) -> a stage that takes 300 ms a dispatch. Once
+    cap, a ring of 2) -> a stage that holds each dispatch until the
+    loader has filled the ring behind it and closed one batch more
+    (a condition, not a delay: decode speed under a loaded host does
+    not decide the counts). Once
     the ring is full the expired hold no longer ships what happens to
     be decoded: the loader keeps filling, and every batch from the
     first deferral on, but the drain's last, is a whole bucket with no
@@ -173,9 +176,9 @@ def test_fused_loader_keeps_filling_while_the_ring_is_full(tmp_path):
              "max_hold_ms": 0.0, "consecutive_frames": 2,
              "num_clips_population": [1], "weights": [1],
              "num_warmups": 1},
-            {"model": "tests.pipeline_helpers.TinySlowSink",
+            {"model": "tests.pipeline_helpers.BackpressureSink",
              "queue_groups": [{"devices": [1], "in_queue": 0}],
-             "delay_s": 0.3},
+             "ahead": 3, "total_rows": num_videos},
         ],
     }
     path = os.path.join(str(tmp_path), "backpressure.json")
