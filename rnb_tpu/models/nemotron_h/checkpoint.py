@@ -10,6 +10,14 @@ the device, in float32, and rounds to its stored dtype once;
 :func:`reference_reader` hands the plain reference those same stored
 values, upcast to float32, one tensor at a time.
 
+A tensor is drawn in its published orientation and may be *stored* in
+another (``TensorSpec.transposed``): the routed experts' first matrix
+is published ``[experts, hidden, inner]`` and lies on the device as
+``[held, inner, hidden]``, the orientation the grouped product reads
+without a relayout (``ops/moe.py``). The draw and the reader's values
+do not know of it; the transpose is made once, inside the jit that
+draws the tensor.
+
 Initial scales (all of them this repo's assumption: the published
 checkpoint is trained, not initialised): embedding N(0, 1) so the
 residual stream starts at a spread of one; every projection into a
@@ -50,6 +58,9 @@ class TensorSpec:
     kind: str           # normal | ones | a_log | dt_bias | uniform
     scale: float = 1.0
     per_expert: bool = False   # leading axis = routed experts
+    #: ``shape`` is the stored one: the published orientation, in which
+    #: the tensor is drawn and read, has the last two axes swapped
+    transposed: bool = False
 
 
 def tensor_specs(cfg: NemotronHConfig, num_held: int
@@ -94,8 +105,9 @@ def tensor_specs(cfg: NemotronHConfig, num_held: int
                 "router": lin(d, cfg.router_experts),
                 "b_corr": TensorSpec((cfg.router_experts,), f32, "normal",
                                      B_CORR_STD),
-                "up": TensorSpec((num_held, d, inner), bf, "normal",
-                                 1.0 / math.sqrt(d), per_expert=True),
+                "up": TensorSpec((num_held, inner, d), bf, "normal",
+                                 1.0 / math.sqrt(d), per_expert=True,
+                                 transposed=True),
                 "down": TensorSpec((num_held, inner, d), bf, "normal",
                                    back / math.sqrt(inner),
                                    per_expert=True),
@@ -116,11 +128,14 @@ def _key(seed: int, name: str):
 
 @functools.lru_cache(maxsize=None)
 def _drawer(cfg_steps: Tuple[float, float, float], spec: TensorSpec):
-    """The jitted draw of one spec: (key, expert ids) -> tensor."""
+    """The jitted draw of one spec: (key, expert ids) -> the tensor as
+    stored."""
     import jax
     import jax.numpy as jnp
     dtype = getattr(jnp, spec.dtype)
     shape = spec.shape[1:] if spec.per_expert else spec.shape
+    if spec.transposed:
+        shape = shape[:-2] + (shape[-1], shape[-2])
     t_min, t_max, t_floor = cfg_steps
 
     def one(key):
@@ -142,7 +157,8 @@ def _drawer(cfg_steps: Tuple[float, float, float], spec: TensorSpec):
             x = dt + jnp.log(-jnp.expm1(-dt))      # inverse softplus
         else:
             raise ValueError("tensor kind %r" % (spec.kind,))
-        return x.astype(dtype)
+        x = x.astype(dtype)
+        return jnp.swapaxes(x, -1, -2) if spec.transposed else x
 
     if spec.per_expert:
         return jax.jit(lambda key, ids: jax.vmap(
@@ -153,8 +169,8 @@ def _drawer(cfg_steps: Tuple[float, float, float], spec: TensorSpec):
 def make_tensor(cfg: NemotronHConfig, seed: int, name: str,
                 spec: TensorSpec, expert_ids: Sequence[int], device):
     """The tensor ``name`` of the model ``seed`` names, on ``device``,
-    in its stored dtype. ``expert_ids`` are the global ids of the
-    experts a per-expert stack holds, in its order."""
+    in its stored dtype and orientation. ``expert_ids`` are the global
+    ids of the experts a per-expert stack holds, in its order."""
     import jax
     with jax.default_device(device):
         ids = np.asarray(expert_ids, np.int32)
@@ -186,17 +202,20 @@ def make_params(cfg: NemotronHConfig, seed: int, held: Sequence[int],
 
 def reference_reader(cfg: NemotronHConfig, seed: int, device):
     """``read(name, expert_ids=None)`` -> the stored values of tensor
-    ``name`` (``top.embed``, ``b3.in_proj``, ...) as float32; for a
-    per-expert stack, of the experts named. What the plain reference
-    reads its weights through, one tensor at a time."""
+    ``name`` (``top.embed``, ``b3.in_proj``, ...) as float32, in the
+    published orientation; for a per-expert stack, of the experts
+    named. What the plain reference reads its weights through, one
+    tensor at a time."""
     import jax.numpy as jnp
     specs = tensor_specs(cfg, 1)
 
     def read(name: str, expert_ids: Optional[Sequence[int]] = None):
         group, tensor = name.split(".", 1)
-        return make_tensor(cfg, seed, name, specs[group][tensor],
-                           expert_ids if expert_ids is not None else (),
-                           device).astype(jnp.float32)
+        spec = specs[group][tensor]
+        stored = make_tensor(cfg, seed, name, spec,
+                             expert_ids if expert_ids is not None else (),
+                             device).astype(jnp.float32)
+        return jnp.swapaxes(stored, -1, -2) if spec.transposed else stored
     return read
 
 

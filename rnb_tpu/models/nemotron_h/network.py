@@ -167,7 +167,10 @@ def experts_mixer(cfg, p, h, token_ok, slots, expert_cast=None,
                   interpret=False):
     """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,)).
     ``expert_cast`` rounds the experts' weights through a lower
-    precision: the tests' control, never the program;
+    precision: the tests' control on the CPU, never the program (the
+    v5e's compiler fuses such a round trip in front of the grouped
+    product and keeps the excess precision: on the chip
+    ``scripts/nemotron_control.py`` rounds the stored weights);
     ``interpret`` runs the grouped product's kernel in interpret mode
     (a device that is no TPU)."""
     rows, q, hidden = h.shape

@@ -16,6 +16,18 @@ behind the last group, belong to no group and cost no product. On one
 chip there is no exchange: what the absent experts would add is left
 out.
 
+The pairs go into expert order once and come back once. In: one
+gather of the tokens' rows by the sorted order. Back: the inverse of
+that order (a scatter of 49,152 int32, not of rows), one gather of the
+second product's rows, and one fused pass that masks, weights and sums
+a token's ``top_k`` rows in float32. The mask is a ``where`` on the
+*rows*: the kernel leaves whatever it finds behind the last group, and
+a zero weight would turn a NaN there into a NaN in the sum. (Scaling
+and masking every row, scattering the rows onto zeros and summing them
+cost 57 ms of a 215 ms dispatch on the v5e, where this costs 33: XLA
+sorts such a scatter's indices again and makes two passes over the
+array; my chip runs, PR 29.)
+
 The grouped product is JAX's Pallas kernel
 (``jax.experimental.pallas.ops.tpu.megablox.gmm``: one grid step a
 (row tile, group) pair that holds rows, float32 accumulator in VMEM).
@@ -23,6 +35,19 @@ The first traced run on the v5e (PR 28) read ``lax.ragged_dot`` at 47%
 of the device's time and 12 to 25 TFLOP/s of useful work; the kernel
 with the tiles below read 35 to 64 on the same shapes. Off the TPU the
 same kernel runs in Pallas's interpret mode.
+
+The weights lie in memory as the kernel reads them. Its operands must
+be row-major, and the device keeps an array whose last axis is no
+multiple of 128 lanes with the axis before it innermost: the first
+matrix as published, ``[held, 2688, 1856]`` (1856 = 14.5 x 128), was
+stored ``{1,2,0}`` and copied to ``{2,1,0}`` in front of the kernel,
+638 MB read and written every E block of every dispatch (2.0 ms, of
+which 1.6 had hidden the wait for the gather in front of it: without
+the copy that gather reads 2.1 ms for 0.4; my chip runs, PR 29). It is
+therefore stored ``[held, inner, hidden]`` (2688 = 21 x 128 innermost,
+like the second matrix) and the kernel contracts it over its last
+axis (``transpose_rhs``); ``checkpoint.py`` draws it as published and
+transposes it once, at set-up.
 """
 
 from __future__ import annotations
@@ -48,21 +73,36 @@ def _tile(extent: int, whole_under: int, cap: int, lane: int = 128) -> int:
     return cap
 
 
-def grouped_matmul(rows, weights, counts, interpret: bool):
-    """``rows`` (M, K), sorted by group; ``weights`` (G, K, N);
-    ``counts`` (G,) int32 rows of each group, in order. -> float32
-    (M, N); what lies behind the last group's rows is unspecified.
+def grouped_matmul(rows, weights, counts, interpret: bool,
+                   transposed: bool = False):
+    """``rows`` (M, K), sorted by group; ``weights`` (G, K, N), or
+    (G, N, K) where ``transposed``; ``counts`` (G,) int32 rows of each
+    group, in order. -> float32 (M, N); what lies behind the last
+    group's rows is unspecified.
 
-    Tiles (m, k, n), read on the v5e at M 49,152 (PR 28, my chip
-    runs): K 2688 -> N 1856 with (512, 896, 1024) 7.0 ms, K 1856 ->
-    N 2688 with (512, 1856, 896) 3.8 ms, against 20.5 and 18.6 ms of
-    ``lax.ragged_dot``; 1,024 rows a tile run out of VMEM."""
+    Tiles (m, k, n), read on the v5e at M 49,152 (my chip runs): K 2688
+    -> N 1856 with (512, 896, 1024), K 1856 -> N 2688 with (512, 1856,
+    896): 3.5 and 3.0 ms a call in the cell's trace, against 20.5 and
+    18.6 ms of ``lax.ragged_dot`` (PR 28). Re-read for the first
+    product's weights as (G, N, K), the kernel alone under a real
+    dispatch's group sizes (half the pairs held, load max/mean 2.6 and
+    4.2; a jitted call by the host's clock, 0.6 ms of group metadata
+    in it; PR 29): 4.97 ms, and 6.99 ms with (G, K, N) weights, 2.0 of
+    them the relayout in front, so the kernel costs the same either
+    way. A whole N with 384 rows or more, and a whole K with 256 x
+    1,024 or 512 x 640 of the result, run out of VMEM. Smaller row
+    tiles waste less where a group ends (a group has 384 rows on
+    average): (128, 2688, 1024) 3.82 and (256, 896, 1856) 4.15 ms for
+    the first product, (128, 1856, 896) 3.28 ms against 3.81 for the
+    second; not taken here (PERF.md section 7: the cell's backlog has
+    to grow first)."""
     m, k = rows.shape
-    n = weights.shape[2]
+    n = weights.shape[1] if transposed else weights.shape[2]
     tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
     tiling = (tm, _tile(k, 2048, 1024), _tile(n, 1024, 1024))
     return gmm(rows, weights, counts, preferred_element_type=jnp.float32,
-               tiling=tiling, interpret=interpret)
+               tiling=tiling, transpose_rhs=transposed,
+               interpret=interpret)
 
 
 def route(x, w_router, b_corr, top_k: int, scaling: float):
@@ -87,30 +127,32 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     ``x`` (T, hidden); ``ids``/``weights`` (T, k) from :func:`route`;
     ``token_ok`` (T,) bool, False on padding; ``held_slot`` (E,) int32:
     an expert's position in the stacks, or -1 where it is held
-    elsewhere; ``up`` (held, hidden, inner), ``down`` (held, inner,
+    elsewhere; ``up`` (held, inner, hidden), ``down`` (held, inner,
     hidden); ``interpret``: run the grouped product's kernel in
     Pallas's interpret mode (off the TPU). -> (out (T, hidden)
     float32, counts (held,) int32: the pairs each held expert served)."""
     tokens, k = ids.shape
     held = up.shape[0]
     slot = held_slot[ids]                               # (T, k)
-    slot = jnp.where(token_ok[:, None] & (slot >= 0), slot, held)
-    flat_slot = slot.reshape(-1)
+    here = token_ok[:, None] & (slot >= 0)              # served on this chip
+    flat_slot = jnp.where(here, slot, held).reshape(-1)
     order = jnp.argsort(flat_slot, stable=True)
     counts = jnp.bincount(flat_slot, length=held + 1)[:held] \
         .astype(jnp.int32)
     rows = x[order // k]                                # (T*k, hidden)
-    hidden = grouped_matmul(rows, up, counts, interpret)
+    hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
     hidden = relu2(hidden).astype(x.dtype)
     out = grouped_matmul(hidden, down, counts, interpret)
-    # rows behind the last group are no expert's: their product is
-    # whatever the grouped kernel leaves there, so they are zeroed
-    served = jnp.arange(tokens * k) < counts.sum()
-    w = weights.reshape(-1)[order]
-    out = jnp.where(served[:, None], out * w[:, None], 0.0)
-    back = jnp.zeros((tokens * k, out.shape[1]), jnp.float32) \
-        .at[order].set(out)
-    return back.reshape(tokens, k, -1).sum(axis=1), counts
+    # the way back: where each pair lies in expert order (the inverse
+    # of ``order``), and one gather of the product's rows
+    place = jnp.zeros_like(order).at[order].set(
+        jnp.arange(tokens * k, dtype=order.dtype), unique_indices=True)
+    back = out[place].reshape(tokens, k, -1)
+    # a pair that is not served here lies behind the last group: its
+    # row is whatever the kernel left there (0 x NaN is NaN), so the
+    # mask is on the rows and not a zero weight
+    back = jnp.where(here[:, :, None], back * weights[:, :, None], 0.0)
+    return back.sum(axis=1), counts
 
 
 def dense_expert(x, up, down):

@@ -1,16 +1,18 @@
 """The Nemotron-H family against its plain reference, at a toy size on
 the CPU with weights from a seed: every mixer alone, the blocked scan
 against the recurrence, packing, routing under skew, the expert share
-against the uncut layer, the whole 14-block pattern through the one
-benchmark command, the recipe, and the lower-precision control that
-must fail. Then the real configuration: its pipeline through the
-program's own checks and, where a v5e can be described, the compile of
-its largest bucket (one file, the topology inside a fixture)."""
+against the uncut layer, the experts' way back against the scatter
+form it replaced, the whole 14-block pattern through the one benchmark
+command, the recipe, and the lower-precision control that must fail.
+Then the real configuration: its pipeline through the program's own
+checks and, where a v5e can be described, the compile of its largest
+bucket and of one E block (one file, the topology inside a fixture)."""
 
 import json
 import os
 import subprocess
 import sys
+import zlib
 
 import numpy as np
 import pytest
@@ -232,6 +234,86 @@ def test_the_share_ties_to_the_model(toy):
                    0.03)["ok"]
 
 
+def held_experts_by_scatter(x, ids, weights, token_ok, held_slot, up, down):
+    """The oracle of the way back, as the program ran it until PR 29:
+    every row of the second product times its weight under a mask,
+    scattered onto zeros in (token, k) order, summed over k."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import moe
+    tokens, k = ids.shape
+    held = up.shape[0]
+    slot = held_slot[ids]
+    slot = jnp.where(token_ok[:, None] & (slot >= 0), slot, held)
+    flat_slot = slot.reshape(-1)
+    order = jnp.argsort(flat_slot, stable=True)
+    counts = jnp.bincount(flat_slot, length=held + 1)[:held] \
+        .astype(jnp.int32)
+    hidden = moe.grouped_matmul(x[order // k], up, counts, True,
+                                transposed=True)
+    hidden = moe.relu2(hidden).astype(x.dtype)
+    out = moe.grouped_matmul(hidden, down, counts, True)
+    served = jnp.arange(tokens * k) < counts.sum()
+    w = weights.reshape(-1)[order]
+    out = jnp.where(served[:, None], out * w[:, None], 0.0)
+    back = jnp.zeros((tokens * k, out.shape[1]), jnp.float32) \
+        .at[order].set(out)
+    return back.reshape(tokens, k, -1).sum(axis=1), counts
+
+
+@pytest.mark.parametrize("case", ["mixed", "no_pair_held", "one_expert",
+                                  "padding", "nan_behind_the_last_group"])
+def test_the_way_back_is_the_scatter_forms(toy, case, monkeypatch):
+    """One gather by the inverse permutation and a masked weighted sum
+    give what the scatter onto zeros gave, whatever the kernel leaves
+    behind the last group."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import network
+    from rnb_tpu.ops import moe
+    cfg = toy["cfg"]
+    tokens, k = 48, cfg.num_experts_per_tok
+    rng = np.random.default_rng(29)
+    x = jnp.asarray(rng.standard_normal((tokens, cfg.hidden_size)),
+                    jnp.bfloat16)
+    ids = np.argsort(rng.random((tokens, cfg.router_experts)))[:, :k]
+    ok = np.ones(tokens, bool)
+    if case == "no_pair_held":
+        ids = 4 + ids % 4
+    elif case == "one_expert":
+        ids[:] = 2
+    elif case == "padding":
+        ok[[3, 17]] = False
+        ok[31:] = False
+    weights = jnp.asarray(rng.random((tokens, k)) + 0.1, jnp.float32)
+    block = toy["params"]["b%d" % cfg.blocks_of("E")[0]]
+    if case == "nan_behind_the_last_group":
+        product = moe.grouped_matmul
+
+        def planted(rows, stack, counts, interpret, transposed=False):
+            out = product(rows, stack, counts, interpret, transposed)
+            behind = jnp.arange(out.shape[0]) >= counts.sum()
+            return jnp.where(behind[:, None], jnp.nan, out)
+        monkeypatch.setattr(moe, "grouped_matmul", planted)
+    args = (x, jnp.asarray(ids, jnp.int32), weights, jnp.asarray(ok),
+            network.held_slots(cfg, HELD), block["up"], block["down"])
+    got, counts = moe.held_experts(*args, interpret=True)
+    want, want_counts = held_experts_by_scatter(*args)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(np.asarray(counts), np.asarray(want_counts))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    on_held = (ids < 4) & ok[:, None]
+    assert int(np.asarray(counts).sum()) == int(on_held.sum())
+    assert not got[~on_held.any(axis=1)].any()      # exact zeros
+    if case == "no_pair_held":
+        assert not got.any()
+    elif case == "one_expert":
+        assert np.asarray(counts).tolist() == [0, 0, tokens * k, 0]
+    else:
+        assert np.abs(got).max() > 0
+
+
 # -- the whole pattern ------------------------------------------------------
 
 
@@ -367,9 +449,23 @@ def test_recipe_gives_program_and_reference_the_same_values(toy):
                          ("b1.b_corr", params["b1"]["b_corr"])):
         assert np.array_equal(np.asarray(tensor, np.float32),
                               np.asarray(read(name)))
-    # an expert is a function of its global id, whoever holds it
+    # an expert is a function of its global id, whoever holds it; its
+    # first matrix is stored transposed ([held, inner, hidden]: what the
+    # grouped product reads in place) and read as published
     up = np.asarray(params["b1"]["up"], np.float32)
-    assert np.array_equal(up[[3, 1]], np.asarray(read("b1.up", (3, 1))))
+    down = np.asarray(params["b1"]["down"], np.float32)
+    inner, hidden = TOY["moe_intermediate_size"], TOY["hidden_size"]
+    assert up.shape == down.shape == (len(HELD), inner, hidden)
+    read_up = np.asarray(read("b1.up", (3, 1)))
+    read_down = np.asarray(read("b1.down", (3, 1)))
+    assert read_up.shape == (2, hidden, inner)
+    assert np.array_equal(up[[3, 1]], read_up.transpose(0, 2, 1))
+    assert np.array_equal(down[[3, 1]], read_down)
+    # the reader's values are the ones it gave before the stored
+    # orientation changed (checksums taken on PR 28's tree)
+    assert read_up.dtype == read_down.dtype == np.float32
+    assert zlib.crc32(read_up.tobytes()) == 1547041046
+    assert zlib.crc32(read_down.tobytes()) == 502981089
     other = checkpoint.make_params(toy["cfg"], SEED, (2, 3, 4, 5),
                                    toy["device"], groups=["b1"])
     assert np.array_equal(np.asarray(other["b1"]["up"], np.float32)[:2],
@@ -537,3 +633,68 @@ def test_largest_bucket_fits_the_chip_and_clears_the_floor(one_chip):
         of((3, rows))).compile().memory_analysis()
     projected = memory.temp_size_in_bytes + memory.argument_size_in_bytes
     assert 4 * 2 ** 30 <= projected <= 14 * 2 ** 30, projected / 2 ** 30
+
+
+def test_an_expert_block_moves_its_pairs_once_each_way(one_chip):
+    """One E block of the real configuration at 64 rows, compiled for
+    the described v5e (nothing runs): the grouped product reads both
+    expert stacks where they lie (no instruction but a parameter has a
+    stack's shape: no relayout in front of the kernel), nothing
+    scatters a [tokens x k, hidden] float32 array, and the kernel is
+    called twice, under the scope its readers find it by."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import checkpoint, network
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.NemotronHConfig.from_published(config)
+    step = config["pipeline_config"]["pipeline"][-1]
+    rows, held = max(step["row_buckets"]), config["experts_held"]["count"]
+    specs = checkpoint.tensor_specs(cfg, held)[
+        "b%d" % cfg.blocks_of(network.EXPERTS)[0]]
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def block(p, slots, x, token_ok):
+        with jax.named_scope("experts"):
+            h = network.rms_norm(x, p["norm"], cfg.eps, x.dtype)
+            out, ids, counts = network.experts_mixer(cfg, p, h, token_ok,
+                                                     slots)
+            return (x.astype(jnp.float32) + out).astype(x.dtype), ids, counts
+    text = jax.jit(block).lower(
+        {name: of(spec.shape, getattr(jnp, spec.dtype))
+         for name, spec in specs.items()},
+        of((cfg.router_experts,), jnp.int32),
+        of((rows, cfg.chunk_size, cfg.hidden_size), jnp.bfloat16),
+        of((rows, cfg.chunk_size), jnp.bool_)).compile().as_text()
+
+    d, inner = cfg.hidden_size, cfg.moe_intermediate_size
+    pairs = rows * cfg.chunk_size * cfg.num_experts_per_tok
+    stacks = {"bf16[%d,%d,%d]" % (held, a, b)
+              for a, b in ((d, inner), (inner, d))}
+    assert {"bf16[%s]" % ",".join(map(str, specs[t].shape))
+            for t in ("up", "down")} <= stacks
+    instruction = re.compile(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+\[[\d,]*\])\S* ([a-z\-]+)\(")
+    kernels = []
+    for line in text.splitlines():
+        found = instruction.match(line)
+        if not found:
+            continue
+        name, shape, opcode = found.groups()
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        if shape in stacks:
+            assert opcode == "parameter", line[:200]
+        if shape == "f32[%d,%d]" % (pairs, d):
+            assert not op_name.endswith("scatter"), line[:200]
+        if re.fullmatch(r"%gmm(\.\d+)?", name):
+            assert opcode == "custom-call" and "/experts/" in op_name, \
+                line[:200]
+            kernels.append(shape)
+    assert sorted(kernels) == ["f32[%d,%d]" % (pairs, inner),
+                               "f32[%d,%d]" % (pairs, d)]
