@@ -55,7 +55,7 @@ import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-#: the procedural y4m population bench.py measures (4:2:0, 128 source
+#: the procedural y4m population the benchmark measures (4:2:0, 128 source
 #: frames so the sampler can place 15 clips, 192x256 so decode+resize
 #: does real work) and a 112x112 MJPEG set for the dct pixel path, which
 #: ships coefficients at source geometry. Quality 60: over all 16 clips

@@ -26,9 +26,8 @@ Rules
   or trailer kind ``parse_utils`` never checks for.
 * ``RNB-T006`` result-field-drift: a ``key=value`` counter written to
   the Faults:/Cache:/Staging:/Autotune:/Trace:/Ragged:/Handoff:/
-  Padding:/Compute:/Memory:/Critpath:/Whatif:/Operator:/Stacks:
-  log-meta lines with no matching ``BenchmarkResult`` field (or vice
-  versa for those counter families; dict-valued fields — bucket
+  Padding: log-meta lines with no matching ``BenchmarkResult`` field
+  (or vice versa for those counter families; dict-valued fields — bucket
   counts, per-edge overflows, compile signatures, warmup seconds —
   ride their own JSON meta lines and are exempt).
 * ``RNB-T007`` unregistered-content-stamp: an attribute stamped onto a
@@ -41,13 +40,6 @@ Rules
   ``trace.instant`` / ``trace.counter`` / ``trace.name`` site emits an
   event name ``TRACE_EVENT_REGISTRY`` does not declare (the reverse —
   a registered event no site emits — is an RNB-T003 dead entry).
-* ``RNB-T009`` unregistered-metric: a ``metrics.counter`` /
-  ``metrics.gauge`` / ``metrics.observe`` / ``metrics.mark`` /
-  ``metrics.name`` site emits a series name ``METRIC_REGISTRY`` does
-  not declare (mirror of RNB-T008 for the live-metrics plane; the
-  reverse — a ``site``-sourced registry entry with no remaining call
-  site — is an RNB-T003 dead entry; ``bridge``/``poll``/``derived``
-  entries have no call sites by design and are exempt).
 """
 
 from __future__ import annotations
@@ -60,8 +52,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 from rnb_tpu.analysis.findings import (Finding, package_py_files,
                                        parse_py)
 from rnb_tpu.telemetry import (CONTENT_STAMPS, META_LINE_REGISTRY,
-                               METRIC_REGISTRY, STAMP_REGISTRY,
-                               TABLE_TRAILER_REGISTRY,
+                               STAMP_REGISTRY, TABLE_TRAILER_REGISTRY,
                                TRACE_EVENT_REGISTRY, TRANSIENT_STAMPS)
 
 #: core TimeCard attributes (assignments to these are state, not
@@ -84,13 +75,6 @@ TRACE_MODULE_NAMES = {"trace", "trace_mod"}
 
 #: rnb_tpu.trace entry points that take an event name first
 TRACE_CALL_ATTRS = {"span", "instant", "counter", "name"}
-
-#: modules whose counter/gauge/observe/mark/name calls emit live
-#: metrics (rnb_tpu.metrics imported as either name)
-METRIC_MODULE_NAMES = {"metrics", "metrics_mod"}
-
-#: rnb_tpu.metrics entry points that take a series name first
-METRIC_CALL_ATTRS = {"counter", "gauge", "observe", "mark", "name"}
 
 _FMT_PLACEHOLDER = re.compile(r"%[0-9.]*[sdf]")
 
@@ -259,14 +243,6 @@ COUNTER_LINE_PREFIXES = {"Faults:": "", "Cache:": "cache_",
                          "Health:": "health_",
                          "Deadline:": "deadline_",
                          "Hedge:": "hedges_",
-                         "Metrics:": "metrics_",
-                         "Slo:": "slo_",
-                         "Compute:": "compute_",
-                         "Memory:": "memory_",
-                         "Critpath:": "critpath_",
-                         "Whatif:": "whatif_",
-                         "Operator:": "operator_",
-                         "Stacks:": "stacks_",
                          "Net:": "net_",
                          "Net errors:": "net_err_",
                          "Locks:": "locks_"}
@@ -295,29 +271,6 @@ def extract_meta_counter_keys(benchmark_path: str) -> Dict[str, Set[str]]:
                     keys.setdefault(prefix, set()).update(
                         key_re.findall(literal))
     return keys
-
-
-def extract_metric_names(py_paths: Sequence[str], root: str = "."
-                         ) -> List[Tuple[str, int, str]]:
-    """Every literal series name passed to a live-metrics entry point
-    (``metrics.counter(...)`` / ``.gauge`` / ``.observe`` / ``.mark``
-    / ``.name``): -> [(relpath, line, pattern)]. Prebuilt names
-    flowing through variables are covered at their ``metrics.name``
-    build site, exactly like the trace extractor."""
-    out = []
-    for path in py_paths:
-        rel = _rel(path, root)
-        for node in ast.walk(_parse(path)):
-            if isinstance(node, ast.Call) \
-                    and isinstance(node.func, ast.Attribute) \
-                    and node.func.attr in METRIC_CALL_ATTRS \
-                    and isinstance(node.func.value, ast.Name) \
-                    and node.func.value.id in METRIC_MODULE_NAMES \
-                    and node.args:
-                literal = _fmt_string(node.args[0])
-                if literal is not None:
-                    out.append((rel, node.lineno, _pattern_of(literal)))
-    return out
 
 
 def extract_trace_events(py_paths: Sequence[str], root: str = "."
@@ -472,37 +425,6 @@ def check_trace_events(py_paths: Sequence[str], root: str = ".",
     return findings
 
 
-def check_metric_names(py_paths: Sequence[str], root: str = ".",
-                       registry=METRIC_REGISTRY) -> List[Finding]:
-    """RNB-T009 both ways: every series name a
-    ``metrics.counter/gauge/observe/mark/name`` site emits must be
-    declared in ``telemetry.METRIC_REGISTRY``, and every declared
-    ``site``-sourced series must still have an emitting site (else
-    RNB-T003). ``bridge``/``poll``/``derived`` entries are fed from
-    trace events, snapshot polls or registry internals — no call site
-    exists by design, so only the forward direction applies to them
-    (the runtime registry separately rejects undeclared names)."""
-    findings: List[Finding] = []
-    sites = extract_metric_names(py_paths, root)
-    registered = {spec.pattern for spec in registry}
-    for rel, line, pattern in sites:
-        if pattern not in registered:
-            findings.append(Finding(
-                "RNB-T009", rel, line, pattern,
-                "metric %r is not declared in "
-                "telemetry.METRIC_REGISTRY — register it (with its "
-                "kind and source) or remove the call site" % pattern))
-    produced = {pattern for _, _, pattern in sites}
-    for spec in registry:
-        if getattr(spec, "source", "site") == "site" \
-                and spec.pattern not in produced:
-            findings.append(Finding(
-                "RNB-T003", "rnb_tpu/telemetry.py", 0, spec.pattern,
-                "registered site-sourced metric %r has no remaining "
-                "call site" % spec.pattern))
-    return findings
-
-
 def check_benchmark_result(benchmark_path: str, root: str = "."
                            ) -> List[Finding]:
     """Every counter written to the Faults:/Cache: log-meta lines must
@@ -547,14 +469,6 @@ def check_benchmark_result(benchmark_path: str, root: str = "."
                 or field.startswith("health_") \
                 or field.startswith("deadline_") \
                 or field.startswith("hedges_") \
-                or field.startswith("metrics_") \
-                or field.startswith("slo_") \
-                or field.startswith("compute_") \
-                or field.startswith("memory_") \
-                or field.startswith("critpath_") \
-                or field.startswith("whatif_") \
-                or field.startswith("operator_") \
-                or field.startswith("stacks_") \
                 or field.startswith("net_") \
                 or field.startswith("locks_"):
             if field not in mapped:
@@ -581,6 +495,5 @@ def check_repo(root: str = ".") -> List[Finding]:
     findings.extend(check_meta_lines(benchmark, parse_src, root))
     findings.extend(check_trailers(telemetry, parse_src, root))
     findings.extend(check_trace_events(py_files, root))
-    findings.extend(check_metric_names(py_files, root))
     findings.extend(check_benchmark_result(benchmark, root))
     return findings

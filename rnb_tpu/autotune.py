@@ -66,7 +66,7 @@ import dataclasses
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from rnb_tpu import metrics, trace
+from rnb_tpu import trace
 
 #: defaults for the optional keys of the ``autotune`` root config
 AUTOTUNE_DEFAULTS = {
@@ -324,15 +324,6 @@ class BatchController:
                 "verdict": "immediate" if dec.immediate else "held",
                 "target_rows": dec.target_rows,
                 "hold_ms": dec.hold_s * 1000.0})
-        if metrics.ACTIVE is not None:
-            # live controller state (rnb_tpu.metrics): the arrival-
-            # rate estimate and chosen target stream so an operator
-            # (and the future elastic-serving controller, ROADMAP
-            # item 5) can watch the adaptive loop act — still no
-            # clock reads or RNG on the decision path
-            metrics.gauge("autotune.arrival_hz",
-                          1.0 / self._ia_s if self._ia_s else 0.0)
-            metrics.gauge("autotune.target_rows", dec.target_rows)
         self._decisions += 1
         self._decided_since_emit = True
         if dec.immediate:

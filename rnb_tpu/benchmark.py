@@ -206,7 +206,7 @@ class BenchmarkResult:
     shard_rows: int = 0
     #: per-step shard detail (the `Shard steps:` JSON meta line):
     #: degree/axis, merge-gather counters, projected vs budget MiB,
-    #: and the memledger-projected min feasible degree
+    #: and the projected min feasible degree
     shard_step_detail: Dict[str, Any] = field(default_factory=dict)
     #: paged device-memory accounting (rnb_tpu.pager, root `pager`
     #: config key) — the `Pages:` meta line verbatim: page
@@ -281,111 +281,6 @@ class BenchmarkResult:
     hedges_won: int = 0
     hedges_lost: int = 0
     hedges_wasted_ms: int = 0
-    #: live-metrics plane accounting (rnb_tpu.metrics, root `metrics`
-    #: config key): interval snapshots appended to metrics.jsonl,
-    #: distinct series at teardown, flight-recorder dumps written and
-    #: triggers observed — all zero without the key. --check holds
-    #: the final snapshot's counters to the ledger lines exactly.
-    metrics_snapshots: int = 0
-    metrics_series: int = 0
-    metrics_dumps: int = 0
-    metrics_triggers: int = 0
-    #: live SLO-layer accounting (same gating): completions tracked /
-    #: within deadline / missed, plus the run's peak burn rate in
-    #: milli-units (1000 = consuming the error budget exactly)
-    slo_tracked: int = 0
-    slo_within: int = 0
-    slo_missed: int = 0
-    slo_burn_max_milli: int = 0
-    #: device compute plane accounting (rnb_tpu.devobs, root `devobs`
-    #: config key): flops-bearing stages metered, dispatches/valid
-    #: rows observed, total achieved FLOPs (per-row counts x rows),
-    #: the measured window in microseconds, the job-level achieved
-    #: TFLOP/s and MFU in bench.py's exact rounding (milli-tflops /
-    #: 1e-4 mfu units; mfu_e4 == -1 when the platform has no known
-    #: peak), and bounded capture windows taken — all zero without
-    #: the key. --check cross-foots flops_total against the per-stage
-    #: detail and the demo gate holds tflops/mfu to bench.py's
-    #: evidence line to the digit.
-    compute_stages: int = 0
-    compute_dispatches: int = 0
-    compute_rows: int = 0
-    compute_flops_total: int = 0
-    compute_window_us: int = 0
-    compute_tflops_milli: int = 0
-    compute_mfu_e4: int = 0
-    compute_captures: int = 0
-    #: per-stage roofline detail (the `Compute stages:` JSON meta
-    #: line): rows, dispatches, flops_per_row, busy_us, tflops_busy,
-    #: mfu_busy, ai_flops_per_byte
-    compute_stage_detail: Dict[str, Any] = field(default_factory=dict)
-    #: HBM footprint ledger accounting (rnb_tpu.memledger, same
-    #: gating): declared owners and devices seen, final/peak resident
-    #: bytes, the watermark threshold and below->above crossings, the
-    #: backend's live-buffer byte total, and whether the ledger's
-    #: live-backed claims reconciled against it (1 = checked and
-    #: consistent; 0 = backend exposes no live list OR the check
-    #: failed — --check flags the latter)
-    memory_owners: int = 0
-    memory_devices: int = 0
-    memory_total_bytes: int = 0
-    memory_peak_bytes: int = 0
-    memory_watermark_bytes: int = 0
-    memory_watermark_hits: int = 0
-    memory_live_bytes: int = 0
-    memory_reconciled: int = 0
-    #: per-owner footprint detail (the `Memory owners:` JSON meta
-    #: line): {owner: {bytes, peak_bytes}}
-    memory_owner_detail: Dict[str, Any] = field(default_factory=dict)
-    #: critical-path extraction accounting (rnb_tpu.critpath, root
-    #: `critpath` config key): completed requests whose blocking
-    #: chain was recovered, total chain segments, the worst
-    #: per-request partition residual (microseconds — --check holds
-    #: it under 1000), hedge-won and redispatched completions, and
-    #: the binding stage's critical-path throughput bound — all zero
-    #: without the key.
-    critpath_requests: int = 0
-    critpath_segments: int = 0
-    critpath_residual_us_max: int = 0
-    critpath_hedged: int = 0
-    critpath_redispatched: int = 0
-    critpath_bound_step: int = 0
-    critpath_bound_vps_milli: int = 0
-    #: per-stage blocking attribution (the `Critpath stages:` JSON
-    #: meta line): lanes, per-class blocked totals, occupied ms,
-    #: bound_vps
-    critpath_stage_detail: Dict[str, Any] = field(default_factory=dict)
-    #: calibrated queueing what-if engine accounting (rnb_tpu.whatif,
-    #: root `whatif` config key — requires `metrics`): stages the
-    #: model calibrated from the final metrics snapshot, whether
-    #: calibration succeeded, the model's self-predicted throughput
-    #: (milli-vps) and its predicted bottleneck step (-1 when
-    #: uncalibrated) — all zero/-1 without the key. --check
-    #: recomputes the prediction offline from metrics.jsonl + the
-    #: config copy and holds it to +-1 milli-vps.
-    whatif_stages: int = 0
-    whatif_calibrated: int = 0
-    whatif_pred_vps_milli: int = 0
-    whatif_bottleneck_step: int = 0
-    #: operator-plane request ledger (rnb_tpu.statusz, root `operator`
-    #: config key): GET requests served (scrapes), POST actions
-    #: accepted, POST actions denied by the allow_actions gate, and
-    #: request errors (bad route / unavailable backing plane) — all
-    #: zero without the key. --check holds the Operator: line to the
-    #: operator.json artifact's presence both ways.
-    operator_scrapes: int = 0
-    operator_actions: int = 0
-    operator_denied: int = 0
-    operator_errors: int = 0
-    #: wall-clock stack sampler ledger (rnb_tpu.stacksampler, gated on
-    #: `operator.sample_hz` > 0): sampling ticks, distinct thread
-    #: roles, distinct folded stacks, total per-thread samples — the
-    #: stacks.folded artifact's counts sum to stacks_total exactly and
-    #: ticks track sample_hz x wall within --check's tolerance.
-    stacks_samples: int = 0
-    stacks_threads: int = 0
-    stacks_folded: int = 0
-    stacks_total: int = 0
     # netedge transport ledger (root 'netedge' key; rnb_tpu.netedge)
     net_frames_sent: int = 0
     net_frames_acked: int = 0
@@ -430,7 +325,8 @@ def run_benchmark(config_path: str,
                   seed: Optional[int] = None,
                   job_id: Optional[str] = None,
                   xprof: bool = False) -> BenchmarkResult:
-    """Programmatic entry used by the CLI, tests and bench.py."""
+    """Programmatic entry used by the CLI, the tests and the benchmark
+    (``benchmarks/run.py``)."""
     enable_compilation_cache()
     # multi-host: honor RNB_TPU_COORDINATOR/_NUM_PROCESSES/_PROCESS_ID
     # before the first backend touch — jax.distributed must initialize
@@ -440,9 +336,6 @@ def run_benchmark(config_path: str,
     from rnb_tpu.parallel.distributed import maybe_initialize
     keep_host_backend()
     maybe_initialize()
-    from rnb_tpu import devobs as devobs_mod
-    from rnb_tpu import memledger as memledger_mod
-    from rnb_tpu import metrics as metrics_mod
     from rnb_tpu import trace as trace_mod
     from rnb_tpu.client import bulk_client, poisson_client
     from rnb_tpu.config import load_config
@@ -455,11 +348,8 @@ def run_benchmark(config_path: str,
     # defensive: a previous run that died mid-trace must not leave its
     # tracer active — this run's instrumentation would otherwise write
     # into a dead collector (and un-traced runs would stop being
-    # byte-stable); same for the live-metrics registry
+    # byte-stable)
     trace_mod.ACTIVE = None
-    metrics_mod.ACTIVE = None
-    devobs_mod.ACTIVE = None
-    memledger_mod.ACTIVE = None
 
     config = load_config(config_path)
     config.check_devices()
@@ -708,27 +598,6 @@ def run_benchmark(config_path: str,
             inject_queue=fabric.get_queues(0, 0)[1][0],
             num_markers=fabric.filename_num_markers,
             seed=seed or 0)
-    # one queue-occupancy probe list — (series name, qsize fn,
-    # capacity) per edge in step-major enumeration order — shared by
-    # the metrics gauge sources and the operator server's /statusz so
-    # their edge naming can never diverge. The trace block below
-    # keeps its own enumeration of the SAME edges in the SAME order
-    # only because RNB-T008/T009 each require literal trace.name/
-    # metrics.name call sites for their registries — any change to
-    # this walk must be mirrored there
-    queue_probes = [(metrics_mod.name("queue.filename.depth"),
-                     fabric.get_filename_queue().qsize,
-                     effective_queue_size)]
-    _edge_idx = 0
-    for _step_queues in fabric.queues:
-        # edge ordinal in step-major enumeration order (queue indices
-        # may legally repeat across steps, so the ordinal — not the
-        # config's queue index — keys the series)
-        for _q_idx in sorted(_step_queues):
-            queue_probes.append(
-                (metrics_mod.name("queue.e%d.depth", _edge_idx),
-                 _step_queues[_q_idx].qsize, effective_queue_size))
-            _edge_idx += 1
 
     # unified pipeline tracing (rnb_tpu.trace, root 'trace' config
     # key): one per-job collector every thread role records spans
@@ -739,9 +608,6 @@ def run_benchmark(config_path: str,
     trace_settings = trace_mod.TraceSettings.from_config(config.trace)
     if trace_settings is not None:
         tracer = trace_mod.Tracer(trace_settings)
-        # mirrors the shared queue_probes walk above (same edges, same
-        # step-major ordinal naming); kept as explicit trace.name
-        # sites because RNB-T008 requires the literals here
         tracer.add_counter_source(
             trace_mod.name("queue.filename.depth"),
             fabric.get_filename_queue().qsize)
@@ -756,152 +622,6 @@ def run_benchmark(config_path: str,
                     step_queues[q_idx].qsize)
                 edge_idx += 1
         trace_mod.ACTIVE = tracer
-
-    # live metrics plane (rnb_tpu.metrics, root 'metrics' config key):
-    # a time-series registry + background flusher streaming interval
-    # snapshots to logs/<job>/metrics.jsonl while the run is live. It
-    # BRIDGES existing signals instead of re-measuring: a SpanBridge
-    # installs as the trace collector (forwarding to the real tracer
-    # when tracing is also on) so the hot-loop spans feed latency
-    # histograms and the flight-recorder ring, and the shared ledgers
-    # (faults, deadline, hedge, health) + queue depths become poll
-    # sources read each tick. Stage-owned subsystems register in the
-    # runner (metrics.register_stage).
-    metrics_registry = None
-    metrics_settings = metrics_mod.MetricsSettings.from_config(
-        config.metrics)
-    if metrics_settings is not None:
-        slo_budget = None
-        if deadline_settings is not None:
-            slo_budget = deadline_settings.budget_ms
-        elif autotune_settings is not None:
-            slo_budget = autotune_settings.slo_ms
-        metrics_registry = metrics_mod.MetricsRegistry(
-            metrics_settings, job_dir=logroot(job_id, base=log_base),
-            job_id=job_id, slo_budget_ms=slo_budget)
-        for probe_name, probe_fn, probe_cap in queue_probes:
-            metrics_registry.add_gauge_source(probe_name, probe_fn,
-                                              capacity=probe_cap)
-        metrics_registry.add_poll(metrics_mod.snapshot_poll(
-            "faults", fault_stats.snapshot,
-            counters=("num_failed", "num_shed", "num_retries")))
-        if deadline_stats is not None:
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "deadline", deadline_stats.snapshot,
-                counters=("expired",)))
-        for gov in governors_by_step.values():
-            # live_counters, NOT snapshot(): the teardown snapshot
-            # resolves leftover hedges, and a per-tick poll must
-            # never perturb the claim ledger
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "hedge", gov.live_counters,
-                counters=("fired", "won", "lost")))
-        for board in boards_by_step.values():
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "health", board.snapshot,
-                counters=("transitions", "opens", "evictions",
-                          "probes", "redispatches")))
-        if netedge_stats is not None:
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "net", netedge_stats.snapshot,
-                counters=("frames_sent", "frames_acked", "resends",
-                          "beats", "reconnects", "remote", "local",
-                          "dedup_drops", "dup_arrivals", "wire_bytes",
-                          "frame_bytes", "err_total"),
-                gauges=("peer_depth",)))
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "health", netedge_board.snapshot,
-                counters=("transitions", "opens", "evictions",
-                          "probes", "redispatches")))
-        if pager is not None:
-            metrics_registry.add_poll(metrics_mod.snapshot_poll(
-                "pages", pager.snapshot,
-                counters=("allocs", "frees", "alloc_fails", "gathers",
-                          "gather_rows", "feature_lookups",
-                          "feature_hits", "feature_inserts",
-                          "feature_evictions", "feature_gathers",
-                          "feature_gather_rows",
-                          "feature_bytes_saved"),
-                gauges=("live", "limbo", "bytes")))
-        bridge = metrics_mod.SpanBridge(
-            metrics_registry, forward=tracer,
-            ring_events=(metrics_settings.ring_events
-                         if metrics_settings.flight_enabled else 0))
-        metrics_registry.bridge = bridge
-        trace_mod.ACTIVE = bridge
-        metrics_mod.ACTIVE = metrics_registry
-
-    # device observability plane (rnb_tpu.devobs, root 'devobs' config
-    # key): bounded jax.profiler capture windows (config window /
-    # RNB_DEVOBS_FORCE env / flight-recorder triggers via the metrics
-    # registry's trigger hooks) merged into the trace export as device
-    # tracks, per-stage compute meters behind the Compute: line and
-    # compute.* series, and the HBM footprint ledger
-    # (rnb_tpu.memledger) behind the Memory: line and memory.* gauges.
-    # Stages register their meters/byte sources in the runner
-    # (devobs.register_stage) before the start barrier.
-    devobs_plane = None
-    devobs_settings = devobs_mod.DevObsSettings.from_config(
-        config.devobs)
-    if devobs_settings is not None:
-        devobs_plane = devobs_mod.DevObsPlane(
-            devobs_settings, job_dir=logroot(job_id, base=log_base),
-            job_id=job_id)
-        devobs_mod.ACTIVE = devobs_plane
-        memledger_mod.ACTIVE = devobs_plane.ledger
-        if metrics_registry is not None:
-            metrics_registry.add_poll(devobs_plane.metrics_poll)
-            metrics_registry.trigger_hooks.append(
-                devobs_plane.on_trigger)
-
-    # the explanation plane (rnb_tpu.critpath / rnb_tpu.whatif):
-    # blocking-chain extraction over completed requests' stamps, and
-    # the calibrated queueing what-if model built from the metrics
-    # plane at teardown — both fully off (byte-stable logs) without
-    # their root config keys
-    from rnb_tpu.critpath import CritpathSettings
-    from rnb_tpu.whatif import WhatifSettings
-    critpath_settings = CritpathSettings.from_config(config.critpath)
-    whatif_settings = WhatifSettings.from_config(config.whatif)
-
-    # the operator plane (rnb_tpu.statusz / rnb_tpu.stacksampler, root
-    # 'operator' config key): a threaded loopback HTTP server over the
-    # registries built above — /healthz (lane boards), /metrics (the
-    # live Prometheus exposition), /statusz, /whatif (the calibrated
-    # counterfactual, live), /stacks, and allow_actions-gated POST
-    # /flight and /capture — plus a continuous wall-clock stack
-    # sampler over the named pipeline threads (sample_hz > 0). Bound
-    # address lands in logs/<job>/operator.json; nothing here measures
-    # anything new, it only serves what the planes already hold.
-    from rnb_tpu.statusz import OperatorServer, OperatorSettings
-    operator_settings = OperatorSettings.from_config(config.operator)
-    operator_server = None
-    stack_sampler = None
-    operator_window: Dict[str, Any] = {"t0": None}
-    if operator_settings is not None:
-        if operator_settings.sample_hz > 0:
-            from rnb_tpu.stacksampler import StackSampler
-            stack_sampler = StackSampler(operator_settings.sample_hz)
-        topology = {"steps": [
-            {"step": step_idx, "model": step.model,
-             "groups": len(step.groups),
-             "instances": sum(len(g.devices) for g in step.groups),
-             "replica_lanes": list(step.replica_queues or [])}
-            for step_idx, step in enumerate(config.steps)]}
-        operator_server = OperatorServer(
-            operator_settings, job_dir=logroot(job_id, base=log_base),
-            job_id=job_id, metrics_registry=metrics_registry,
-            boards=boards_by_step, devobs_plane=devobs_plane,
-            config_raw=config.raw, topology=topology,
-            queue_probes=queue_probes, termination=termination,
-            window=operator_window, sampler=stack_sampler)
-        operator_server.start()
-        if print_progress:
-            print("[rnb-tpu] operator server on http://127.0.0.1:%d "
-                  "(actions %s)"
-                  % (operator_server.port,
-                     "enabled" if operator_settings.allow_actions
-                     else "disabled"))
 
     threads = []
     client_kwargs = dict(overload_policy=config.overload_policy,
@@ -1021,7 +741,6 @@ def run_benchmark(config_path: str,
                                if step.replica_queues
                                and group.in_queue
                                in step.replica_queues else None),
-                    critpath=critpath_settings is not None,
                 )
                 threads.append(threading.Thread(
                     target=runner, args=(ctx,),
@@ -1077,31 +796,10 @@ def run_benchmark(config_path: str,
         # short drain); started here so warm-up/compile never lands
         # in the timeline
         tracer.start_sampler()
-    if metrics_registry is not None:
-        # the flusher covers the measured window: every poll source
-        # is registered by now (runner registration happens before
-        # the start barrier)
-        metrics_registry.start()
-    if devobs_plane is not None:
-        # worker up before the barrier (sources are all registered),
-        # but capture windows stay armed until note_run_started below
-        # so warmup compile never lands in a capture
-        devobs_plane.start()
     sta_bar.wait()
     ru_start = resource.getrusage(resource.RUSAGE_SELF)
     decode_start = DecodePool.shared_stats()
-    if devobs_plane is not None:
-        devobs_plane.note_run_started()
     time_start = time.time()
-    # the operator server's measured-window clock (/whatif wall_s,
-    # /statusz) starts ticking with the window itself
-    operator_window["t0"] = time_start
-    if stack_sampler is not None:
-        # the wall-clock sampler covers the measured window (plus the
-        # short drain to thread join) — started AFTER the barrier so
-        # multi-minute warmup compiles never land in the folded
-        # stacks and the samples ~ sample_hz x wall invariant holds
-        stack_sampler.start()
     if print_progress:
         print("START! %f" % time_start)
 
@@ -1134,7 +832,7 @@ def run_benchmark(config_path: str,
             # per-plane clock bases differ (XLine timestamps have no
             # shared origin across host/device planes), so the plane
             # is part of the record: busy-time aggregation is only
-            # valid within one plane (scripts/device_busy.py groups).
+            # valid within one plane.
             f.write("# t0_ns t1_ns plane op_name\n")
             # The capture starts before the barrier and the device
             # clock has no host-epoch origin, so the measured window
@@ -1176,41 +874,6 @@ def run_benchmark(config_path: str,
         except Exception:
             netedge_peer.kill()
 
-    if metrics_registry is not None:
-        # stop bridging the trace hooks (the tracer export below
-        # reads its own buffer, not the module hook); the registry
-        # itself keeps running until the final footing flush after
-        # every ledger snapshot settled
-        trace_mod.ACTIVE = None
-
-    if devobs_plane is not None:
-        # stop the capture worker (any still-armed capture is drained
-        # with a zero-length window first) and clear the module hooks,
-        # then merge the captured device-op intervals into the tracer
-        # as device:<plane> tracks — rid-correlated to the model_call
-        # spans so the exporter's flow chains draw the host->device
-        # arrows — BEFORE the export below writes trace.json
-        devobs_mod.ACTIVE = None
-        memledger_mod.ACTIVE = None
-        devobs_plane.stop()
-        if tracer is not None:
-            tracer.extend(devobs_plane.device_events(
-                devobs_mod.model_call_spans(tracer.snapshot_events())))
-
-    # wall-clock stack sampler: stop, write the flamegraph-folded
-    # artifact, and merge the per-role top-frame timeline into the
-    # tracer as stacks:<role> tracks BEFORE the export below writes
-    # trace.json (the devobs device-track pattern)
-    stacks_summary = None
-    if stack_sampler is not None:
-        stack_sampler.stop()
-        stack_sampler.write_folded(
-            os.path.join(logroot(job_id, base=log_base),
-                         "stacks.folded"))
-        if tracer is not None:
-            tracer.extend(stack_sampler.trace_events())
-        stacks_summary = stack_sampler.summary()
-
     # trace export: every thread is drained, so the event set is
     # final; clear the module hook BEFORE exporting so a later run in
     # this process can never write into this job's collector
@@ -1239,21 +902,6 @@ def run_benchmark(config_path: str,
                     NUM_SUMMARY_SKIPS).items():
                 merged.setdefault(phase, []).extend(vals)
         phases_stats = phase_stats(merged) or None
-
-    # critical-path extraction (rnb_tpu.critpath): the blocking-chain
-    # aggregation over every final instance's steady completions —
-    # stamps only, so it costs nothing on the hot path; hedge/
-    # redispatch content stamps ride along from the summaries
-    critpath_report = None
-    if critpath_settings is not None and summary_sink:
-        from rnb_tpu.critpath import aggregate as critpath_aggregate
-        lanes_by_step = {
-            step_idx: sum(len(g.devices) for g in step.groups)
-            for step_idx, step in enumerate(config.steps)}
-        critpath_report = critpath_aggregate(
-            (row for s in summary_sink
-             for row in s.steady_rows(NUM_SUMMARY_SKIPS)),
-            lanes_by_step)
 
     # decoded-clip cache accounting: cache-owning stages appended
     # their final snapshots before the finish barrier (rnb_tpu.runner)
@@ -1368,58 +1016,6 @@ def run_benchmark(config_path: str,
         placement_report = build_report(placement_sink, total_time,
                                         len(jax.devices()),
                                         placement_settings.mode)
-
-    metrics_summary = None
-    if metrics_registry is not None:
-        # the FINAL footing flush: every pipeline thread joined and
-        # every ledger snapshot above settled (the hedge snapshot
-        # resolves leftover unresolved hedges), so this last
-        # metrics.jsonl record's counters must equal the log-meta
-        # ledgers exactly — parse_utils --check asserts it. Also
-        # services the forced-dump env hook and writes metrics.prom.
-        metrics_registry.stop()
-        metrics_mod.ACTIVE = None
-        metrics_summary = metrics_registry.summary()
-
-    operator_summary = None
-    if operator_server is not None:
-        # the server outlives the pipeline into teardown (a live
-        # scraper may still read the settling /metrics state), and
-        # stops before the log-meta write so the Operator: ledger
-        # below is final
-        operator_server.stop()
-        operator_summary = operator_server.summary()
-
-    # what-if engine calibration (rnb_tpu.whatif): built from the
-    # FINAL metrics snapshot — the same dict metrics.jsonl holds as
-    # its last record, so parse_utils --check can recompute the
-    # Whatif: line from the artifacts alone and hold the two equal
-    whatif_counters = None
-    if whatif_settings is not None:
-        from rnb_tpu import whatif as whatif_mod
-        whatif_model = None
-        if metrics_registry is not None:
-            final_snap = metrics_registry.final_snapshot()
-            if final_snap is not None:
-                whatif_model = whatif_mod.calibrate_from_snapshot(
-                    final_snap,
-                    whatif_mod.steps_info_from_config(config.raw),
-                    wall_s=total_time,
-                    arrival_hz=whatif_mod.arrival_hz_from_snapshot(
-                        final_snap))
-        whatif_counters = whatif_mod.summary_counters(whatif_model)
-
-    compute_summary = None
-    memory_summary = None
-    if devobs_plane is not None:
-        # job-level tflops/mfu use bench.py's exact arithmetic over
-        # the SAME measured window, so the Compute: line cross-foots
-        # the bench evidence line to the digit on a clean run; the
-        # memory snapshot re-samples after every thread joined, so
-        # owner rows reflect the settled end-of-run state
-        compute_summary = devobs_plane.compute_summary(
-            total_time, devobs_mod.devices_used(config.raw))
-        memory_summary = devobs_plane.memory_summary()
 
     # paged-memory ledger (rnb_tpu.pager): every pipeline thread
     # joined, so live/limbo occupancy is settled and the teardown
@@ -1658,113 +1254,6 @@ def run_benchmark(config_path: str,
             # per request (parse_utils --check asserts it)
             f.write("Phases: %s\n"
                     % json.dumps(phases_stats, sort_keys=True))
-        if metrics_summary is not None:
-            # only metrics-enabled runs carry the lines, keeping
-            # metrics-off logs byte-stable with the earlier schema;
-            # --check cross-foots metrics.jsonl's final snapshot
-            # against the ledger lines above and validates every
-            # flight dump per validate_trace
-            f.write("Metrics: snapshots=%d series=%d dumps=%d "
-                    "triggers=%d\n"
-                    % (metrics_summary["snapshots"],
-                       metrics_summary["series"],
-                       metrics_summary["dumps"],
-                       metrics_summary["triggers"]))
-            f.write("Slo: tracked=%d within=%d missed=%d "
-                    "burn_max_milli=%d\n"
-                    % (metrics_summary["slo_tracked"],
-                       metrics_summary["slo_within"],
-                       metrics_summary["slo_missed"],
-                       metrics_summary["burn_max_milli"]))
-        if compute_summary is not None:
-            # every devobs run carries the line (zero-flops when no
-            # stage declares a compute profile — the captures counter
-            # must stay checkable), devobs-off logs stay byte-stable;
-            # --check cross-foots flops_total against the per-stage
-            # detail, recomputes tflops_milli from the integer
-            # fields, and bounds the mfu
-            f.write("Compute: stages=%d dispatches=%d rows=%d "
-                    "flops_total=%d window_us=%d tflops_milli=%d "
-                    "mfu_e4=%d captures=%d\n"
-                    % (compute_summary["stages"],
-                       compute_summary["dispatches"],
-                       compute_summary["rows"],
-                       compute_summary["flops_total"],
-                       compute_summary["window_us"],
-                       compute_summary["tflops_milli"],
-                       compute_summary["mfu_e4"],
-                       compute_summary["captures"]))
-            f.write("Compute stages: %s\n"
-                    % json.dumps(compute_summary["stage_detail"],
-                                 sort_keys=True))
-        if memory_summary is not None:
-            # owner rows MUST sum to total_bytes and peak >= final —
-            # the --check footing invariants; reconciled=1 means the
-            # ledger's live-backed claims fit inside the backend's
-            # own live-buffer total
-            f.write("Memory: owners=%d devices=%d total_bytes=%d "
-                    "peak_bytes=%d watermark_bytes=%d "
-                    "watermark_hits=%d live_bytes=%d reconciled=%d\n"
-                    % (len(memory_summary["owners"]),
-                       len(memory_summary["devices"]),
-                       memory_summary["total_bytes"],
-                       memory_summary["peak_bytes"],
-                       memory_summary["watermark_bytes"],
-                       memory_summary["watermark_hits"],
-                       memory_summary["live_bytes"],
-                       memory_summary["reconciled"]))
-            if memory_summary["owners"]:
-                f.write("Memory owners: %s\n"
-                        % json.dumps(memory_summary["owners"],
-                                     sort_keys=True))
-        if critpath_report is not None:
-            # only critpath-enabled runs carry the lines, keeping
-            # earlier logs byte-stable; --check re-derives every
-            # field from the timing tables and holds the partition
-            # residual under 1 ms per request
-            f.write("Critpath: requests=%d segments=%d "
-                    "residual_us_max=%d hedged=%d redispatched=%d "
-                    "bound_step=%d bound_vps_milli=%d\n"
-                    % (critpath_report["requests"],
-                       critpath_report["segments"],
-                       critpath_report["residual_us_max"],
-                       critpath_report["hedged"],
-                       critpath_report["redispatched"],
-                       critpath_report["bound_step"],
-                       critpath_report["bound_vps_milli"]))
-            f.write("Critpath stages: %s\n"
-                    % json.dumps(critpath_report["stage_detail"],
-                                 sort_keys=True))
-        if whatif_counters is not None:
-            # only whatif-enabled runs carry the line; --check
-            # recomputes the prediction from metrics.jsonl + the
-            # config copy alone and holds it to +-1 milli-vps
-            f.write("Whatif: stages=%d calibrated=%d "
-                    "pred_vps_milli=%d bottleneck_step=%d\n"
-                    % (whatif_counters["stages"],
-                       whatif_counters["calibrated"],
-                       whatif_counters["pred_vps_milli"],
-                       whatif_counters["bottleneck_step"]))
-        if operator_summary is not None:
-            # only operator-enabled runs carry the line (logs stay
-            # byte-stable otherwise); --check holds it to the
-            # operator.json artifact both ways
-            f.write("Operator: scrapes=%d actions=%d denied=%d "
-                    "errors=%d\n"
-                    % (operator_summary["scrapes"],
-                       operator_summary["actions"],
-                       operator_summary["denied"],
-                       operator_summary["errors"]))
-        if stacks_summary is not None:
-            # operator runs with sample_hz > 0 only; the stacks.folded
-            # counts sum to total and samples track sample_hz x wall
-            # (--check invariants)
-            f.write("Stacks: samples=%d threads=%d folded=%d "
-                    "total=%d\n"
-                    % (stacks_summary["samples"],
-                       stacks_summary["threads"],
-                       stacks_summary["folded"],
-                       stacks_summary["total"]))
         if net_snap is not None:
             # the edge's exactly-once ledger, cross-footed by --check:
             # frames_sent == frames_acked + resent_pending, dedup
@@ -1895,40 +1384,6 @@ def run_benchmark(config_path: str,
                  deadline_snap["expired"],
                  ", ".join("%s=%d" % kv for kv in sorted(
                      deadline_snap["sites"].items())) or "-"))
-    if metrics_summary is not None and print_progress:
-        print("Metrics: %d snapshot(s) over %d series -> "
-              "metrics.jsonl, %d flight dump(s) from %d trigger(s); "
-              "SLO %d/%d within (peak burn %.3f)"
-              % (metrics_summary["snapshots"],
-                 metrics_summary["series"],
-                 metrics_summary["dumps"],
-                 metrics_summary["triggers"],
-                 metrics_summary["slo_within"],
-                 metrics_summary["slo_tracked"],
-                 metrics_summary["burn_max_milli"] / 1000.0))
-    if compute_summary is not None and print_progress:
-        print("Compute: %d stage(s), %d dispatch(es), %d row(s), "
-              "%.3f achieved TFLOP/s over the window (mfu %s), "
-              "%d capture(s)"
-              % (compute_summary["stages"],
-                 compute_summary["dispatches"],
-                 compute_summary["rows"],
-                 compute_summary["tflops_milli"] / 1000.0,
-                 ("%.4f" % (compute_summary["mfu_e4"] / 10000.0)
-                  if compute_summary["mfu_e4"] >= 0
-                  else "n/a: unknown device peak"),
-                 compute_summary["captures"]))
-    if memory_summary is not None and print_progress:
-        print("Memory: %.2f MiB resident (peak %.2f MiB) across %d "
-              "owner(s); live-buffer reconcile: %s"
-              % (memory_summary["total_bytes"] / (1 << 20),
-                 memory_summary["peak_bytes"] / (1 << 20),
-                 len(memory_summary["owners"]),
-                 "ok" if memory_summary["reconciled"]
-                 else ("%.2f MiB live"
-                       % (memory_summary["live_bytes"] / (1 << 20))
-                       if memory_summary["live_bytes"]
-                       else "unavailable")))
     if hedge_stats is not None and print_progress:
         print("Hedge: %d fired, %d won by the hedge / %d by the "
               "original, %d ms of loser service wasted"
@@ -1971,35 +1426,6 @@ def run_benchmark(config_path: str,
             s = phases_stats[phase]
             print("  %-18s %8.3f / %8.3f  (n=%d)"
                   % (phase, s["mean_ms"], s["p99_ms"], s["count"]))
-    if critpath_report is not None and print_progress:
-        from rnb_tpu.critpath import ranking as critpath_ranking
-        ranked = critpath_ranking(critpath_report["stage_detail"])
-        print("Critpath: %d request(s), top blockers %s; bound "
-              "step%d at %.3f videos/s"
-              % (critpath_report["requests"],
-                 ", ".join("%s %.1f ms" % (seg, total)
-                           for seg, total, _mean in ranked[:3]),
-                 critpath_report["bound_step"],
-                 critpath_report["bound_vps_milli"] / 1000.0))
-    if whatif_counters is not None and print_progress:
-        print("Whatif: %d stage(s) calibrated=%d, self-predicted "
-              "%.3f videos/s (bottleneck step %d)"
-              % (whatif_counters["stages"],
-                 whatif_counters["calibrated"],
-                 whatif_counters["pred_vps_milli"] / 1000.0,
-                 whatif_counters["bottleneck_step"]))
-    if operator_summary is not None and print_progress:
-        print("Operator: %d scrape(s), %d action(s), %d denied, "
-              "%d error(s)"
-              % (operator_summary["scrapes"],
-                 operator_summary["actions"],
-                 operator_summary["denied"],
-                 operator_summary["errors"]))
-    if stacks_summary is not None and print_progress:
-        print("Stacks: %d tick(s) over %d role(s) -> %d folded "
-              "stack(s) (%d samples) in stacks.folded"
-              % (stacks_summary["samples"], stacks_summary["threads"],
-                 stacks_summary["folded"], stacks_summary["total"]))
 
     return BenchmarkResult(
         job_id=job_id,
@@ -2133,97 +1559,6 @@ def run_benchmark(config_path: str,
         hedges_lost=hedge_stats["lost"] if hedge_stats else 0,
         hedges_wasted_ms=(hedge_stats["wasted_ms"]
                           if hedge_stats else 0),
-        metrics_snapshots=(metrics_summary["snapshots"]
-                           if metrics_summary else 0),
-        metrics_series=(metrics_summary["series"]
-                        if metrics_summary else 0),
-        metrics_dumps=(metrics_summary["dumps"]
-                       if metrics_summary else 0),
-        metrics_triggers=(metrics_summary["triggers"]
-                          if metrics_summary else 0),
-        slo_tracked=(metrics_summary["slo_tracked"]
-                     if metrics_summary else 0),
-        slo_within=(metrics_summary["slo_within"]
-                    if metrics_summary else 0),
-        slo_missed=(metrics_summary["slo_missed"]
-                    if metrics_summary else 0),
-        slo_burn_max_milli=(metrics_summary["burn_max_milli"]
-                            if metrics_summary else 0),
-        compute_stages=(compute_summary["stages"]
-                        if compute_summary else 0),
-        compute_dispatches=(compute_summary["dispatches"]
-                            if compute_summary else 0),
-        compute_rows=compute_summary["rows"] if compute_summary else 0,
-        compute_flops_total=(compute_summary["flops_total"]
-                             if compute_summary else 0),
-        compute_window_us=(compute_summary["window_us"]
-                           if compute_summary else 0),
-        compute_tflops_milli=(compute_summary["tflops_milli"]
-                              if compute_summary else 0),
-        compute_mfu_e4=(compute_summary["mfu_e4"]
-                        if compute_summary else 0),
-        compute_captures=(compute_summary["captures"]
-                          if compute_summary else 0),
-        compute_stage_detail=(dict(compute_summary["stage_detail"])
-                              if compute_summary else {}),
-        memory_owners=(len(memory_summary["owners"])
-                       if memory_summary else 0),
-        memory_devices=(len(memory_summary["devices"])
-                        if memory_summary else 0),
-        memory_total_bytes=(memory_summary["total_bytes"]
-                            if memory_summary else 0),
-        memory_peak_bytes=(memory_summary["peak_bytes"]
-                           if memory_summary else 0),
-        memory_watermark_bytes=(memory_summary["watermark_bytes"]
-                                if memory_summary else 0),
-        memory_watermark_hits=(memory_summary["watermark_hits"]
-                               if memory_summary else 0),
-        memory_live_bytes=(memory_summary["live_bytes"]
-                           if memory_summary else 0),
-        memory_reconciled=(memory_summary["reconciled"]
-                           if memory_summary else 0),
-        memory_owner_detail=(dict(memory_summary["owners"])
-                             if memory_summary else {}),
-        critpath_requests=(critpath_report["requests"]
-                           if critpath_report else 0),
-        critpath_segments=(critpath_report["segments"]
-                           if critpath_report else 0),
-        critpath_residual_us_max=(critpath_report["residual_us_max"]
-                                  if critpath_report else 0),
-        critpath_hedged=(critpath_report["hedged"]
-                         if critpath_report else 0),
-        critpath_redispatched=(critpath_report["redispatched"]
-                               if critpath_report else 0),
-        critpath_bound_step=(critpath_report["bound_step"]
-                             if critpath_report else 0),
-        critpath_bound_vps_milli=(critpath_report["bound_vps_milli"]
-                                  if critpath_report else 0),
-        critpath_stage_detail=(dict(critpath_report["stage_detail"])
-                               if critpath_report else {}),
-        whatif_stages=(whatif_counters["stages"]
-                       if whatif_counters else 0),
-        whatif_calibrated=(whatif_counters["calibrated"]
-                           if whatif_counters else 0),
-        whatif_pred_vps_milli=(whatif_counters["pred_vps_milli"]
-                               if whatif_counters else 0),
-        whatif_bottleneck_step=(whatif_counters["bottleneck_step"]
-                                if whatif_counters else 0),
-        operator_scrapes=(operator_summary["scrapes"]
-                          if operator_summary else 0),
-        operator_actions=(operator_summary["actions"]
-                          if operator_summary else 0),
-        operator_denied=(operator_summary["denied"]
-                         if operator_summary else 0),
-        operator_errors=(operator_summary["errors"]
-                         if operator_summary else 0),
-        stacks_samples=(stacks_summary["samples"]
-                        if stacks_summary else 0),
-        stacks_threads=(stacks_summary["threads"]
-                        if stacks_summary else 0),
-        stacks_folded=(stacks_summary["folded"]
-                       if stacks_summary else 0),
-        stacks_total=(stacks_summary["total"]
-                      if stacks_summary else 0),
         net_frames_sent=(net_snap["frames_sent"] if net_snap else 0),
         net_frames_acked=(net_snap["frames_acked"] if net_snap else 0),
         net_resent_pending=(net_snap["resent_pending"]
@@ -2348,20 +1683,6 @@ def main(argv=None) -> int:
         print("trace: %s"
               % (json.dumps(cfg.trace, sort_keys=True)
                  if cfg.trace else "none"))
-        print("metrics: %s"
-              % (json.dumps(cfg.metrics, sort_keys=True)
-                 if cfg.metrics else "none"))
-        print("devobs: %s"
-              % (json.dumps(cfg.devobs, sort_keys=True)
-                 if cfg.devobs else "none"))
-        print("critpath: %s; whatif: %s"
-              % (json.dumps(cfg.critpath, sort_keys=True)
-                 if cfg.critpath else "none",
-                 json.dumps(cfg.whatif, sort_keys=True)
-                 if cfg.whatif else "none"))
-        print("operator: %s"
-              % (json.dumps(cfg.operator, sort_keys=True)
-                 if cfg.operator else "none"))
         hedged = {"step%d" % i: s.hedge_ms
                   for i, s in enumerate(cfg.steps)
                   if s.hedge_ms is not None}

@@ -27,7 +27,7 @@ from typing import Optional
 
 import numpy as np
 
-from rnb_tpu import metrics, trace
+from rnb_tpu import trace
 from rnb_tpu.control import NUM_EXIT_MARKERS, FaultStats, \
     InferenceCounter, TerminationFlag, TerminationState, \
     dispose_requests, send_exit_markers
@@ -105,11 +105,6 @@ def _client(video_path_iterator_path: str, filename_queue: "queue.Queue",
                 # (rnb_tpu.trace)
                 trace.instant("client.enqueue", rid=video_count)
                 trace.counter("client.enqueued", video_count + 1)
-                # live-metrics arrival feed (rnb_tpu.metrics): the
-                # windowed arrival rate the future cross-host ingest
-                # tier schedules on; one None test each when off
-                metrics.counter("client.requests")
-                metrics.mark("client.arrivals")
                 try:
                     filename_queue.put_nowait((None, video_path, time_card))
                 except queue.Full:
@@ -119,7 +114,6 @@ def _client(video_path_iterator_path: str, filename_queue: "queue.Queue",
                         # id and counts toward the run target — the
                         # pipeline owes it no further work)
                         trace.instant("client.shed", rid=video_count)
-                        metrics.counter("client.shed")
                         time_card.mark_shed(SHED_SITE)
                         if fault_stats is not None:
                             fault_stats.record_shed(SHED_SITE)

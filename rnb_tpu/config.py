@@ -42,9 +42,7 @@ ROOT_KEYWORDS = [
     "video_path_iterator", "pipeline", "overload_policy",
     "fault_containment", "fault_plan", "popularity", "autotune",
     "trace", "ragged", "pager", "handoff", "placement", "health",
-    "deadline",
-    "metrics", "devobs", "critpath", "whatif", "operator", "netedge",
-    "lint",
+    "deadline", "netedge", "lint",
     "_comment",
 ]
 
@@ -76,28 +74,6 @@ HEALTH_KEYWORDS = ["enabled", "suspect_after_ms", "open_after_ms",
 
 #: keys a root 'deadline' object may carry (rnb_tpu.health)
 DEADLINE_KEYWORDS = ["enabled", "budget_ms"]
-
-#: keys a root 'metrics' object may carry (rnb_tpu.metrics)
-METRICS_KEYWORDS = ["enabled", "interval_ms", "flight_recorder"]
-
-#: keys a 'metrics.flight_recorder' object may carry
-FLIGHT_RECORDER_KEYWORDS = ["enabled", "ring_events", "max_dumps",
-                            "burn_threshold", "shed_spike_per_s",
-                            "queue_saturation", "cooldown_s"]
-
-#: keys a root 'devobs' object may carry (rnb_tpu.devobs)
-DEVOBS_KEYWORDS = ["enabled", "capture_window_ms", "capture_on_trigger",
-                   "max_captures", "capture_max_ops", "watermark_mb",
-                   "sample_hz"]
-
-#: keys a root 'critpath' object may carry (rnb_tpu.critpath)
-CRITPATH_KEYWORDS = ["enabled"]
-
-#: keys a root 'whatif' object may carry (rnb_tpu.whatif)
-WHATIF_KEYWORDS = ["enabled"]
-
-#: keys a root 'operator' object may carry (rnb_tpu.statusz)
-OPERATOR_KEYWORDS = ["enabled", "port", "allow_actions", "sample_hz"]
 
 #: keys a root 'lint' object may carry (runtime arms of the
 #: rnb-lint analyzers; today just the RNB-C lock-order witness)
@@ -272,48 +248,6 @@ class PipelineConfig:
     #: expired requests (shed reason deadline_expired) instead of
     #: computing doomed work — rnb_tpu.health
     deadline: Optional[Dict[str, Any]] = None
-    #: validated live-metrics spec ({"enabled": .., "interval_ms": ..,
-    #: "flight_recorder": {..}}), or None; when enabled the launcher
-    #: builds an rnb_tpu.metrics.MetricsRegistry + background flusher
-    #: (metrics.jsonl / metrics.prom / flight-<n>.json in the job
-    #: dir) and log-meta gains the Metrics:/Slo: lines. Absent => no
-    #: registry, byte-stable logs.
-    metrics: Optional[Dict[str, Any]] = None
-    #: validated device-observability spec ({"enabled": ..,
-    #: "capture_window_ms": .., "capture_on_trigger": ..,
-    #: "max_captures": .., "capture_max_ops": .., "watermark_mb": ..,
-    #: "sample_hz": ..}), or None; when enabled the launcher builds an
-    #: rnb_tpu.devobs.DevObsPlane (bounded jax.profiler capture
-    #: windows merged into trace.json as device tracks, per-stage
-    #: compute meters feeding the Compute: line and compute.* series,
-    #: and the rnb_tpu.memledger HBM footprint ledger behind the
-    #: Memory: line and memory.* gauges). Absent => no plane,
-    #: byte-stable logs.
-    devobs: Optional[Dict[str, Any]] = None
-    #: validated critical-path extraction spec ({"enabled": ..}), or
-    #: None; when enabled the launcher recovers every completed
-    #: request's blocking chain from its TimeCard stamps
-    #: (rnb_tpu.critpath) and log-meta gains the Critpath:/Critpath
-    #: stages: lines plus a `# critpath` table trailer. Absent =>
-    #: byte-stable logs.
-    critpath: Optional[Dict[str, Any]] = None
-    #: validated what-if engine spec ({"enabled": ..}), or None; when
-    #: enabled (requires `metrics` — the service histograms ARE the
-    #: calibration data) the launcher calibrates a per-stage queueing
-    #: model at teardown (rnb_tpu.whatif) and log-meta gains the
-    #: Whatif: line. Absent => byte-stable logs.
-    whatif: Optional[Dict[str, Any]] = None
-    #: validated operator-plane spec ({"enabled": .., "port": ..,
-    #: "allow_actions": .., "sample_hz": ..}), or None; when enabled
-    #: the launcher binds the rnb_tpu.statusz introspection/control
-    #: HTTP server on loopback (port 0 = ephemeral; bound address
-    #: written to logs/<job>/operator.json) and — with sample_hz > 0 —
-    #: runs the rnb_tpu.stacksampler wall-clock stack sampler
-    #: (stacks.folded artifact, sampler tracks in trace.json, Stacks:
-    #: line). POST actions (/flight, /capture) stay 403 unless
-    #: allow_actions is true. Absent => no server, no sampler,
-    #: byte-stable logs.
-    operator: Optional[Dict[str, Any]] = None
     #: validated cross-host ingest-edge spec ({"enabled": ..,
     #: "listen": .., "connect": .., "beat_ms": ..,
     #: "io_timeout_ms": .., "max_retries": .., "backoff_ms": ..,
@@ -854,144 +788,6 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                 "(defaults to autotune.slo_ms when autotune is "
                 "configured), got %r" % (budget,))
 
-    metrics = raw.get("metrics")
-    if metrics is not None:
-        _expect(isinstance(metrics, dict), "'metrics' must be an object")
-        unknown_m = sorted(set(metrics) - set(METRICS_KEYWORDS))
-        _expect(not unknown_m,
-                "'metrics' has unknown key(s) %s — keys are %s"
-                % (unknown_m, METRICS_KEYWORDS))
-        _expect(isinstance(metrics.get("enabled", True), bool),
-                "'metrics.enabled' must be a boolean")
-        interval = metrics.get("interval_ms", 250.0)
-        _expect(isinstance(interval, (int, float))
-                and not isinstance(interval, bool) and interval > 0,
-                "'metrics.interval_ms' must be a positive number, "
-                "got %r" % (interval,))
-        fr = metrics.get("flight_recorder")
-        if fr is not None and not isinstance(fr, bool):
-            _expect(isinstance(fr, dict),
-                    "'metrics.flight_recorder' must be a boolean or "
-                    "an object")
-            unknown_fr = sorted(set(fr) - set(FLIGHT_RECORDER_KEYWORDS))
-            _expect(not unknown_fr,
-                    "'metrics.flight_recorder' has unknown key(s) %s "
-                    "— keys are %s" % (unknown_fr,
-                                       FLIGHT_RECORDER_KEYWORDS))
-            _expect(isinstance(fr.get("enabled", True), bool),
-                    "'metrics.flight_recorder.enabled' must be a "
-                    "boolean")
-            for key in ("ring_events", "max_dumps"):
-                val = fr.get(key)
-                _expect(val is None
-                        or (isinstance(val, int)
-                            and not isinstance(val, bool) and val >= 1),
-                        "'metrics.flight_recorder.%s' must be a "
-                        "positive integer, got %r" % (key, val))
-            for key in ("burn_threshold", "shed_spike_per_s",
-                        "cooldown_s"):
-                val = fr.get(key)
-                _expect(val is None
-                        or (isinstance(val, (int, float))
-                            and not isinstance(val, bool) and val > 0),
-                        "'metrics.flight_recorder.%s' must be a "
-                        "positive number, got %r" % (key, val))
-            sat = fr.get("queue_saturation")
-            _expect(sat is None
-                    or (isinstance(sat, (int, float))
-                        and not isinstance(sat, bool)
-                        and 0 < sat <= 1),
-                    "'metrics.flight_recorder.queue_saturation' must "
-                    "be a fraction in (0, 1], got %r" % (sat,))
-
-    devobs = raw.get("devobs")
-    if devobs is not None:
-        _expect(isinstance(devobs, dict), "'devobs' must be an object")
-        unknown_do = sorted(set(devobs) - set(DEVOBS_KEYWORDS))
-        _expect(not unknown_do,
-                "'devobs' has unknown key(s) %s — keys are %s"
-                % (unknown_do, DEVOBS_KEYWORDS))
-        _expect(isinstance(devobs.get("enabled", True), bool),
-                "'devobs.enabled' must be a boolean")
-        _expect(isinstance(devobs.get("capture_on_trigger", True),
-                           bool),
-                "'devobs.capture_on_trigger' must be a boolean")
-        window = devobs.get("capture_window_ms")
-        _expect(window is None
-                or (isinstance(window, (int, float))
-                    and not isinstance(window, bool) and window >= 0),
-                "'devobs.capture_window_ms' must be a non-negative "
-                "number (0 disables the configured window; forced/"
-                "trigger captures still run), got %r" % (window,))
-        for key in ("max_captures", "capture_max_ops"):
-            val = devobs.get(key)
-            _expect(val is None
-                    or (isinstance(val, int)
-                        and not isinstance(val, bool) and val >= 1),
-                    "'devobs.%s' must be a positive integer, got %r"
-                    % (key, val))
-        for key in ("watermark_mb", "sample_hz"):
-            val = devobs.get(key)
-            _expect(val is None
-                    or (isinstance(val, (int, float))
-                        and not isinstance(val, bool) and val > 0),
-                    "'devobs.%s' must be a positive number, got %r"
-                    % (key, val))
-
-    critpath = raw.get("critpath")
-    if critpath is not None:
-        _expect(isinstance(critpath, dict),
-                "'critpath' must be an object")
-        unknown_cp = sorted(set(critpath) - set(CRITPATH_KEYWORDS))
-        _expect(not unknown_cp,
-                "'critpath' has unknown key(s) %s — keys are %s"
-                % (unknown_cp, CRITPATH_KEYWORDS))
-        _expect(isinstance(critpath.get("enabled", True), bool),
-                "'critpath.enabled' must be a boolean")
-
-    whatif = raw.get("whatif")
-    if whatif is not None:
-        _expect(isinstance(whatif, dict), "'whatif' must be an object")
-        unknown_wi = sorted(set(whatif) - set(WHATIF_KEYWORDS))
-        _expect(not unknown_wi,
-                "'whatif' has unknown key(s) %s — keys are %s"
-                % (unknown_wi, WHATIF_KEYWORDS))
-        _expect(isinstance(whatif.get("enabled", True), bool),
-                "'whatif.enabled' must be a boolean")
-        if whatif.get("enabled", True):
-            _expect(isinstance(metrics, dict)
-                    and metrics.get("enabled", True),
-                    "'whatif' requires an enabled root 'metrics' key "
-                    "— the per-stage service histograms streamed to "
-                    "metrics.jsonl are the calibration data")
-
-    operator = raw.get("operator")
-    if operator is not None:
-        _expect(isinstance(operator, dict),
-                "'operator' must be an object")
-        unknown_op = sorted(set(operator) - set(OPERATOR_KEYWORDS))
-        _expect(not unknown_op,
-                "'operator' has unknown key(s) %s — keys are %s"
-                % (unknown_op, OPERATOR_KEYWORDS))
-        _expect(isinstance(operator.get("enabled", True), bool),
-                "'operator.enabled' must be a boolean")
-        _expect(isinstance(operator.get("allow_actions", False), bool),
-                "'operator.allow_actions' must be a boolean (false "
-                "keeps POST /flight and /capture 403-gated)")
-        port = operator.get("port", 0)
-        _expect(isinstance(port, int) and not isinstance(port, bool)
-                and 0 <= port <= 65535,
-                "'operator.port' must be an integer in [0, 65535] "
-                "(0 binds an ephemeral port, recorded in "
-                "operator.json), got %r" % (port,))
-        op_hz = operator.get("sample_hz")
-        _expect(op_hz is None
-                or (isinstance(op_hz, (int, float))
-                    and not isinstance(op_hz, bool) and op_hz >= 0),
-                "'operator.sample_hz' must be a non-negative number "
-                "(0 disables the wall-clock stack sampler), got %r"
-                % (op_hz,))
-
     netedge = raw.get("netedge")
     if netedge is not None:
         _expect(isinstance(netedge, dict),
@@ -1284,11 +1080,6 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                           placement=placement,
                           health=health,
                           deadline=deadline,
-                          critpath=critpath,
-                          whatif=whatif,
-                          metrics=metrics,
-                          devobs=devobs,
-                          operator=operator,
                           netedge=netedge,
                           lint=lint,
                           trace=trace)

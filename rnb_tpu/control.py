@@ -35,7 +35,6 @@ import threading
 from collections import namedtuple
 from typing import Dict, List, Optional, Tuple
 
-from rnb_tpu import metrics
 from rnb_tpu.config import (  # DEFAULT_... re-exported for back-compat
     DEFAULT_NUM_SHARED_TENSORS, ConfigError, PipelineConfig)
 from rnb_tpu.devices import DeviceSpec
@@ -137,19 +136,11 @@ class FaultStats:
             for rid in request_ids:
                 if len(self.dead_letters) < self.MAX_DEAD_LETTERS:
                     self.dead_letters.append((rid, step_idx, reason))
-        # live SLO feed (rnb_tpu.metrics): a dead-lettered request is
-        # an SLO violation the burn-rate window must see NOW, not at
-        # exit (one None test when metrics are off; outside the ledger
-        # lock so the two locks never nest)
-        metrics.mark("slo.miss", len(request_ids))
 
     def record_shed(self, site: str, n: int = 1) -> None:
         with self._lock:
             self.num_shed += n
             self.shed_sites[site] = self.shed_sites.get(site, 0) + n
-        # shed-spike flight trigger + SLO burn both window on these
-        metrics.mark("faults.sheds", n)
-        metrics.mark("slo.miss", n)
 
     def record_retries(self, n: int = 1) -> None:
         with self._lock:

@@ -50,8 +50,8 @@ def keep_host_backend() -> None:
 
 def require_platform(wanted: str = "tpu") -> list:
     """-> ``jax.devices()``, raising unless the default backend is
-    ``wanted``. The measurement entry points (bench.py, chip_smoke.py,
-    the benchmark CLI) call this before any work: JAX falls back to
+    ``wanted``. The measurement entry points (chip_smoke.py, the
+    benchmark CLI) call this before any work: JAX falls back to
     the CPU when no accelerator initializes, and a run that completed
     on the wrong device must not look like a result."""
     import jax

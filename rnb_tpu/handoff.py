@@ -107,16 +107,9 @@ class EdgeHandoff:
     """
 
     def __init__(self, settings: HandoffSettings, device,
-                 edge: str, model=None, external_owner=None):
+                 edge: str, model=None):
         self.mode = settings.mode
         self.edge = str(edge)
-        #: predicate over committed arrays whose bytes another ledger
-        #: owner already foots (the pager's shared zero/stub pools,
-        #: rnb_tpu.pager.Pager.owns): the SAME persistent array rides
-        #: every feature-hit take, so counting it into this edge's
-        #: residency would double-claim bytes the `page_pool` owner
-        #: holds and break the Memory owners reconciliation
-        self._external_owner = external_owner
         self._device = (device.resolve() if hasattr(device, "resolve")
                         else device)
         # stages homed on a mesh declare the sharding their inputs
@@ -132,11 +125,6 @@ class EdgeHandoff:
         self.host_edges = 0
         self.d2d_bytes = 0
         self.host_bytes = 0
-        #: payload bytes resident from the most recent take — what
-        #: this edge's adoptions currently pin on the consumer side
-        #: (the HBM-ledger "handoff" owner, rnb_tpu.memledger; a
-        #: single-threaded int the ledger probe reads without a lock)
-        self.resident_bytes = 0
 
     # -- the take -----------------------------------------------------
 
@@ -177,20 +165,7 @@ class EdgeHandoff:
             out.append(self._rewrap(pb, rehomed))
         self.d2d_edges += 1
         self.d2d_bytes += moved
-        self.resident_bytes = self._residency(out)
         return tuple(out)
-
-    def _residency(self, out) -> int:
-        """Bytes this take pins on the consumer side, excluding arrays
-        an external ledger owner (the pager) already foots."""
-        total = 0
-        for pb in out:
-            data = pb.data
-            if self._external_owner is not None \
-                    and self._external_owner(data):
-                continue
-            total += int(getattr(data, "nbytes", 0))
-        return total
 
     def _is_resident(self, data) -> bool:
         """Is this committed array already where the consumer wants
@@ -219,7 +194,6 @@ class EdgeHandoff:
                 pb, jax.device_put(host, self._device)))
         self.host_edges += 1
         self.host_bytes += moved
-        self.resident_bytes = self._residency(out)
         return tuple(out)
 
     # -- reporting ----------------------------------------------------

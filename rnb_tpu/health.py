@@ -57,7 +57,7 @@ import time
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
-from rnb_tpu import lockwitness, metrics, trace
+from rnb_tpu import lockwitness, trace
 
 # -- lane states -------------------------------------------------------
 
@@ -320,13 +320,6 @@ class LaneHealthBoard:
         if trace.ACTIVE is not None:
             trace.instant("health.lane_state", args={
                 "lane": queue_idx, "from": frm, "to": to, "why": why})
-        if to == OPEN:
-            # the flight recorder's circuit-open trigger
-            # (rnb_tpu.metrics): arm a black-box dump of the ring
-            # around this exact incident — the recorder's flusher
-            # does the IO, never this (board-lock-holding) thread
-            metrics.trigger(metrics.TRIGGER_CIRCUIT_OPEN,
-                            {"lane": queue_idx, "why": why})
 
     def _evaluate_locked(self, now: float) -> None:
         if now - self._last_eval < self.EVAL_INTERVAL_S:
@@ -927,17 +920,6 @@ class HedgeGovernor:
             self.wasted_ms += waste
 
     # -- reporting ----------------------------------------------------
-
-    def live_counters(self) -> Dict[str, int]:
-        """Read-only counter view for the live-metrics poll
-        (rnb_tpu.metrics) — unlike :meth:`snapshot` it does NOT
-        resolve unresolved hedges, so it can be read every flusher
-        tick without perturbing the claim ledger. The final metric
-        snapshot is taken AFTER :meth:`snapshot` ran at teardown, so
-        it foots with the Hedge: log-meta line exactly."""
-        with self._lock:
-            return {"fired": self.fired, "won": self.won,
-                    "lost": self.lost}
 
     def snapshot(self) -> Dict[str, object]:
         """Final counters; hedges still unresolved at teardown (the

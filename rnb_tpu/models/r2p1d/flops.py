@@ -7,8 +7,8 @@ MFU convention. Elementwise work (BatchNorm, ReLU, residual adds,
 pooling) is excluded — on any matmul-class accelerator it is bandwidth,
 not FLOPs, and XLA fuses it into the convs anyway.
 
-The numbers feed the benchmark's ``tflops``/``mfu`` line (bench.py) and
-are cross-checked in tests against XLA's own ``cost_analysis()`` of the
+The benchmark's own count (``benchmarks/families/r2p1d.py``) is held
+to these numbers by its tests, and they are cross-checked in tests against XLA's own ``cost_analysis()`` of the
 compiled program, so the analytic walk cannot silently drift from the
 network it claims to describe.
 

@@ -835,8 +835,6 @@ class R2P1DLoader(StageModel):
         zeros = np.zeros(self._batch_shape(self.pool_rows),
                          dtype=self._wire_dtype)
         self._zero_pool = jax.device_put(zeros, self._jax_device)
-        pager.adopt_shared("loader-zero-pool", self._zero_pool,
-                           device_label=str(self._jax_device))
         # feature hits ship a stub emission downstream (the consumer
         # gathers its own output rows and never reads the payload);
         # the stub must still BE the declared wire value — normalized
@@ -845,8 +843,6 @@ class R2P1DLoader(StageModel):
         if stub is not self._zero_pool:
             import jax as _jax
             _jax.block_until_ready(stub)
-            pager.adopt_shared("loader-feature-stub", stub,
-                               device_label=str(self._jax_device))
         self._feature_stub = stub
 
     def _decode_sync(self, decoder, video, starts):
@@ -2540,10 +2536,9 @@ class R2P1DRunner(StageModel):
                 self.ragged_chunk_rows = 0
         layer_sizes = tuple(layer_sizes)
         self._jax_device = _resolve(device)
-        #: the exact network-shape arguments the analytic FLOP walk
-        #: needs (rnb_tpu/models/r2p1d/flops.py) — kept verbatim so
-        #: the devobs compute seam below can never drift from the
-        #: network this stage actually compiled
+        #: the network-shape arguments this stage compiled — the
+        #: feature cache's fingerprint, and what the analytic FLOP walk
+        #: (rnb_tpu/models/r2p1d/flops.py) takes
         self._flops_args = dict(
             consecutive_frames=int(consecutive_frames),
             num_classes=int(num_classes),
@@ -2637,9 +2632,7 @@ class R2P1DRunner(StageModel):
         # (shardplan.projected_device_mb) and — when hbm_budget_mb is
         # armed — REJECTS the launch when the projection does not fit.
         # This is the honest "this stage does not fit at this degree"
-        # failure the headline shard config demonstrates at degree 1;
-        # memledger owns the live accounting once a feasible launch
-        # runs.
+        # failure the headline shard config demonstrates at degree 1.
         self.shard_stats = None
         if self.shard_declared:
             from rnb_tpu.parallel.shardplan import (
@@ -2784,8 +2777,6 @@ class R2P1DRunner(StageModel):
         pager.feature.attach(self._feature_arena, fingerprint)
         zeros = np.zeros((self.pool_rows, num_classes), np.float32)
         self._logit_pool = jax.device_put(zeros, self._jax_device)
-        pager.adopt_shared("runner-logit-pool", self._logit_pool,
-                           device_label=str(self._jax_device))
 
     def _take_feature_plan(self, time_card):
         """The pinned feature-page plan riding this dispatch's card,
@@ -2820,67 +2811,6 @@ class R2P1DRunner(StageModel):
                 tc.feature_insert = None
                 key, row0, n = job
                 feature.insert(key, out, row0, n)
-
-    def _cost_bytes_per_row(self):
-        """Per-row "bytes accessed" from XLA's own cost model of the
-        compiled steady-shape applier — the arithmetic-intensity
-        denominator of the Compute stages: roofline detail. None when
-        the backend exposes no cost analysis (the figure is then
-        unreported rather than guessed). Called only on devobs-enabled
-        runs, pre-barrier, where the warmed signature makes the
-        lower/compile a cache hit."""
-        try:
-            import jax
-            import jax.numpy as jnp
-            arg = jax.ShapeDtypeStruct(self._steady_shape,
-                                       self._warm_dtype)
-            if self.ragged:
-                lowered = self._apply.lower(
-                    self._variables, arg,
-                    jax.ShapeDtypeStruct((), jnp.int32))
-            else:
-                lowered = self._apply.lower(self._variables, arg)
-            analysis = lowered.compile().cost_analysis()
-            if isinstance(analysis, (list, tuple)):
-                analysis = analysis[0] if analysis else {}
-            nbytes = float(analysis.get("bytes accessed", 0.0))
-        except Exception:
-            return None
-        rows = int(self._steady_shape[0])
-        if nbytes <= 0.0 or rows <= 0:
-            return None
-        return nbytes / rows
-
-    def compute_profile(self):
-        """The devobs compute/memory seam (rnb_tpu.devobs): declared
-        per-row FLOPs from the analytic walk this stage's exact
-        network shape feeds, the shared parameter copy's footprint
-        (keyed by object identity, so replicas sharing one
-        ``_shared_params`` copy dedupe in the ledger), and — under
-        ragged dispatch — the one pool-shaped input's bytes."""
-        import jax
-
-        from rnb_tpu.models.r2p1d.flops import range_flops_per_clip
-        flops_per_row = range_flops_per_clip(
-            self.start_index, self.end_index, **self._flops_args)
-        params_bytes = int(jax.tree_util.tree_reduce(
-            lambda acc, leaf: acc + int(getattr(leaf, "nbytes", 0)),
-            self._variables, 0))
-        pool_bytes = 0
-        if self.ragged:
-            per_row = 1
-            for extent in self._steady_shape[1:]:
-                per_row *= int(extent)
-            pool_bytes = (int(self.pool_rows) * per_row
-                          * int(np.dtype(self._warm_dtype).itemsize))
-        return {
-            "flops_per_row": int(flops_per_row),
-            "devices": 1,
-            "bytes_per_row": self._cost_bytes_per_row(),
-            "params_key": ("params", id(self._variables)),
-            "params_bytes": params_bytes,
-            "pool_bytes": pool_bytes,
-        }
 
     @classmethod
     def input_shape_for(cls, start_index: int = 1,
@@ -3067,12 +2997,6 @@ class R2P1DSingleStep(StageModel):
         apply to this fused step's index."""
         self.loader.enable_trace(tracer, step_idx)
 
-    def compute_profile(self):
-        """devobs seam: the embedded network's profile IS this fused
-        step's (the loader contributes bytes via its own cache/staging
-        attributes, not FLOPs)."""
-        return self.net.compute_profile()
-
     def input_shape(self):
         return None
 
@@ -3168,15 +3092,6 @@ class R2P1DMeshRunner(StageModel):
             ckpt_path=ckpt_path, factored_shortcut=factored_shortcut,
             pixel_path=pixel_path)
         self.pixel_path = pixel_path
-        #: devobs compute seam inputs (see compute_profile): the mesh
-        #: covers len(mesh_devices) devices and every row costs the
-        #: full [1..5] network
-        self._mesh_size = len(devs)
-        self._flops_args = dict(
-            consecutive_frames=self.consecutive_frames,
-            num_classes=int(num_classes),
-            layer_sizes=tuple(layer_sizes),
-            factored_shortcut=bool(factored_shortcut))
         self._acc = []            # (PaddedBatch, TimeCard) awaiting dp fill
         self._inflight = deque()  # unretired device prediction arrays
         dummy = np.zeros(self._si.batch_shape(self.dp), np.uint8)
@@ -3218,28 +3133,6 @@ class R2P1DMeshRunner(StageModel):
         # consumes the loader's raw_output uint8 batches in either
         # pixel path (the sharded program owns normalize/ingest)
         return "uint8"
-
-    def compute_profile(self):
-        """devobs seam: full-range per-row FLOPs over the whole
-        sub-mesh (the MFU denominator counts every core the shard_map
-        spans); the replicated parameter copy's bytes are counted once
-        per mesh (keyed by the shared variables object)."""
-        import jax
-
-        from rnb_tpu.models.r2p1d.flops import range_flops_per_clip
-        flops_per_row = range_flops_per_clip(1, NUM_LAYERS,
-                                             **self._flops_args)
-        params_bytes = int(jax.tree_util.tree_reduce(
-            lambda acc, leaf: acc + int(getattr(leaf, "nbytes", 0)),
-            self._si.variables, 0))
-        return {
-            "flops_per_row": int(flops_per_row),
-            "devices": self._mesh_size,
-            "bytes_per_row": None,
-            "params_key": ("params", id(self._si.variables)),
-            "params_bytes": params_bytes,
-            "pool_bytes": 0,
-        }
 
     @staticmethod
     def output_shape():

@@ -1,7 +1,7 @@
 """The network edge: a crash-tolerant cross-host ingest transport.
 
-ROADMAP item 2 calls for splitting ingest (decode + staging) from
-inference behind a real transport. This module is that edge: the main
+Ingest (decode + staging) split from inference behind a real
+transport (ROADMAP D8 holds the case against keeping it): the main
 process keeps the client, the local step-0 fallback path and every
 downstream inference stage, while a *peer* process (``python -m
 rnb_tpu.netedge --serve``) runs a second copy of the step-0 stage and
@@ -182,8 +182,8 @@ class NetEdgeSettings:
 
 class NetStats:
     """Thread-safe edge counters — the ``Net:`` / ``Net errors:``
-    log-meta lines, the ``net.*`` metrics poll, and the BenchmarkResult
-    ``net_*`` fields all read one :meth:`snapshot`."""
+    log-meta lines and the BenchmarkResult ``net_*`` fields both read
+    one :meth:`snapshot`."""
 
     COUNTERS = ("frames_sent", "frames_acked", "resent_pending",
                 "resends", "beats", "reconnects", "remote", "local",

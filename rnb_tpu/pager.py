@@ -36,22 +36,18 @@ layer:
   computed (bit-identical by construction). Insert-after-success only:
   the runner inserts strictly after its forward returned, so contained
   failures and deadline sheds never populate feature pages.
-* **Accounting**: registered under the declared ``page_pool`` owner in
-  rnb_tpu.memledger (slabs are live-backed persistent arrays); exact
-  counters (allocs/frees/live pages, gathers, gather rows, feature
-  lookups/hits/bytes saved) surfaced end-to-end — the ``Pages:``
-  log-meta line, the ``pages.*`` metric family, and the
-  ``parse_utils --check`` invariants (pages allocated == freed + live
-  at teardown; feature hits <= lookups; gather rows foot with cache
-  hit rows).
+* **Accounting**: exact counters (allocs/frees/live pages, gathers,
+  gather rows, feature lookups/hits/bytes saved) surfaced end-to-end —
+  the ``Pages:`` log-meta line and the ``parse_utils --check``
+  invariants (pages allocated == freed + live at teardown; feature
+  hits <= lookups; gather rows foot with cache hit rows).
 
 Sizing: ``pager.pool_mb`` is the explicit per-arena page budget; when
-absent, the arena is sized from the ledger's cache-owner data — the
-loader passes its clip-cache byte budget (the bytes the blob cache
-would have owned), and the feature arena inherits the same figure via
+absent, the arena is sized from the clip cache — the loader passes
+its clip-cache byte budget (the bytes the blob cache would have
+owned), and the feature arena inherits the same figure via
 :meth:`Pager.size_hint` (its rows are orders of magnitude smaller, so
-this is a generous ceiling, bounded and visible in ``Memory owners:``
-either way).
+this is a generous ceiling, and bounded either way).
 """
 
 from __future__ import annotations
@@ -63,7 +59,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from rnb_tpu import lockwitness, memledger
+from rnb_tpu import lockwitness
 
 #: fallback arena budget when neither ``pool_mb`` nor a cache-derived
 #: size hint exists (a bare pager on a cache-less config)
@@ -168,18 +164,11 @@ class Arena:
         if device is not None:
             slab = jax.device_put(slab, device)
         self._slab = slab
-        self.device_label = str(device) if device is not None \
-            else str(getattr(slab, "device", "device0"))
         #: LIFO free list: recently-freed pages are re-alloc'd first
         #: (their slab rows are warm)
         self._free: List[int] = list(range(self.num_pages))
         self._pins: Dict[int, int] = {}
         self._limbo: set = set()
-        # one ledger probe per arena under the declared page_pool
-        # owner; live=True — the slab is a persistent device array
-        memledger.register("page_pool", self.device_label,
-                           ("pager", self.name, id(self)),
-                           self.nbytes, live=True)
 
     @property
     def nbytes(self) -> int:
@@ -420,7 +409,6 @@ class Pager:
         "counters": "lock",
         "_arenas": "lock",
         "_size_hint_bytes": "lock",
-        "_owned_ids": "lock",
     }
 
     def __init__(self, settings: PagerSettings):
@@ -430,16 +418,14 @@ class Pager:
                                          for k in self.COUNTER_KEYS}
         self._arenas: List[Arena] = []
         self._size_hint_bytes: Optional[int] = None
-        self._owned_ids: Dict[int, object] = {}
         self.feature: Optional[FeatureCache] = \
             FeatureCache(self) if settings.feature_cache else None
 
     # -- sizing --------------------------------------------------------
 
     def size_hint(self, nbytes: int) -> None:
-        """Feed the ledger-derived sizing figure (the loader's clip
-        cache budget — the bytes the cache owner would claim); later
-        arenas without an explicit ``pool_mb`` inherit it."""
+        """Feed the sizing figure (the loader's clip cache budget);
+        later arenas without an explicit ``pool_mb`` inherit it."""
         with self.lock:
             if nbytes and nbytes > 0:
                 self._size_hint_bytes = int(nbytes)
@@ -470,27 +456,6 @@ class Pager:
         with self.lock:
             self._arenas.append(arena)
         return arena
-
-    # -- shared-object accounting -------------------------------------
-
-    def adopt_shared(self, name: str, arr, device_label=None) -> None:
-        """Account a pager-machinery device array (the loaders' zero
-        pools feature hits dispatch with) under the page_pool owner,
-        and mark it so the handoff edge's residency accounting can
-        exclude it (rnb_tpu.handoff ``external_owner`` — the bytes are
-        already footed here, and the same array is adopted on every
-        feature-hit take)."""
-        with self.lock:
-            self._owned_ids[id(arr)] = arr
-        memledger.register(
-            "page_pool",
-            str(device_label) if device_label is not None
-            else str(getattr(arr, "device", "device0")),
-            ("pager-shared", name), int(arr.nbytes), live=True)
-
-    def owns(self, arr) -> bool:
-        with self.lock:
-            return id(arr) in self._owned_ids
 
     # -- counters ------------------------------------------------------
 

@@ -1,11 +1,11 @@
 """Intra-stage tensor parallelism: shard one R(2+1)D stage over a ring.
 
 PR 9's scale-out replicates whole stages, so a stage can never exceed
-one device's HBM or FLOPs. This module is the other axis (ROADMAP item
-4): partition the stage's *channel* dimensions over a ``shard_degree``-
-sized mesh axis via ``shard_map``, the Gemma-on-TPU serving protocol
-(PAPERS.md) applied to the R(2+1)D backbone — shard the filter axes,
-keep ONE executable, measure the collective tax honestly.
+one device's HBM or FLOPs. This module is the other axis: partition
+the stage's *channel* dimensions over a ``shard_degree``-sized mesh
+axis via ``shard_map``, the Gemma-on-TPU serving protocol (PAPERS.md)
+applied to the R(2+1)D backbone — shard the filter axes, keep ONE
+executable, measure the collective tax honestly.
 
 What is sharded (and why the result is bit-identical):
 
@@ -51,7 +51,7 @@ launch-time feasibility gate: a projected per-device footprint
 (replicated params + sharded params / degree + the ragged pool) over
 budget REJECTS the launch — the honest "this stage does not fit at
 this degree" failure the headline shard config demonstrates at degree
-1 (memledger owns the live accounting; this gate owns the projection).
+1.
 """
 
 from __future__ import annotations
@@ -312,8 +312,7 @@ def make_merge(mesh, axis_name: str = "tp"):
     the full-width value, replicated, via the ring all-gather. Jitted
     separately from the forward ON PURPOSE: the stage host-times this
     call as ``exec{i}.collective``, so the collective tax is a span in
-    the trace and a histogram in metrics.jsonl — the calibration
-    source whatif's ``shard_degree`` vocabulary scales from."""
+    the trace."""
     import jax
     from jax.sharding import PartitionSpec as P
     from rnb_tpu.ops.handoff_dma import ring_all_gather_body

@@ -51,9 +51,7 @@ degree. The corrected model, per step:
   ring-hop factor ``g(k) = (k-1)/k``, *calibrated from the measured
   collective fraction, never assumed*. With no measured collective
   (executed degree 1) there is nothing to calibrate from, so the
-  planner refuses to extrapolate a degree>1 service — that
-  counterfactual belongs to `whatif`'s ``shard_degree_step<i>``
-  vocabulary, validated against an executed shard arm.
+  planner refuses to extrapolate a degree>1 service.
 
 Joint recommendation (:func:`recommend_joint`): degree is bought for
 per-device HBM feasibility, never for speed — on this cost model a
@@ -184,8 +182,7 @@ def service_at_degree(service_s: float, collective_s: float,
     (weight-gathered sharding), the collective slice scales by
     ``g(degree)/g(degree0)``. Returns None when ``degree0 <= 1`` and
     ``degree > 1`` — a degree-1 run measured NO collective, and this
-    module refuses to invent one (whatif documents the same limit on
-    its ``shard_degree_step<i>`` vocabulary)."""
+    module refuses to invent one."""
     degree0, degree = int(degree0), int(degree)
     if degree == degree0:
         return float(service_s)

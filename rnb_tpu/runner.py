@@ -53,7 +53,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-from rnb_tpu import devobs, metrics, trace
+from rnb_tpu import trace
 from rnb_tpu.control import (NUM_EXIT_MARKERS, BufferRing, EdgeTracker,
                              FaultStats, InferenceCounter, Signal,
                              TerminationFlag, TerminationState,
@@ -255,12 +255,6 @@ class RunnerContext:
     #: replica step itself
     out_hedges: Optional[Any] = None
     in_hedges: Optional[Any] = None
-    #: critical-path extraction (root 'critpath' config key,
-    #: rnb_tpu.critpath): when True, final-instance summaries opt
-    #: into the `# critpath` table trailer (the job-wide Critpath:
-    #: lines are the launcher's aggregation of the same rows) —
-    #: False keeps reports byte-stable with the earlier schema
-    critpath: bool = False
 
 
 def _dispatch_counts(tensors, device: DeviceSpec, card=None) -> dict:
@@ -679,12 +673,6 @@ def runner(ctx: RunnerContext) -> None:
         # Phases: line); trace-off reports stay byte-stable
         summary.track_phases = True
         summary.phase_num_skips = NUM_SUMMARY_SKIPS
-    if summary is not None and ctx.critpath:
-        # critpath-enabled runs opt the report into the `# critpath`
-        # trailer (same steady-state skip as the job-wide Critpath:
-        # lines); critpath-off reports stay byte-stable
-        summary.track_critpath = True
-        summary.critpath_num_skips = NUM_SUMMARY_SKIPS
     progress_bar = None
     declared_shapes = None
     controller = None
@@ -743,12 +731,7 @@ def runner(ctx: RunnerContext) -> None:
             from rnb_tpu.handoff import EdgeHandoff
             handoff = EdgeHandoff(
                 ctx.handoff_settings, ctx.device, ctx.handoff_edge,
-                model,
-                # pager-owned shared pools (feature-hit stubs) are
-                # footed under the page_pool ledger owner — exclude
-                # them from this edge's residency claim
-                external_owner=(ctx.pager.owns
-                                if ctx.pager is not None else None))
+                model)
         if ctx.autotune is not None \
                 and getattr(model, "SUPPORTS_AUTOTUNE", False):
             # load-adaptive batching (rnb_tpu.autotune): the stage
@@ -783,19 +766,6 @@ def runner(ctx: RunnerContext) -> None:
             # because the span and the Shard: accounting need the
             # step index even on trace-disabled runs
             model.bind_shard_step(ctx.step_idx)
-        # live-metrics plane (rnb_tpu.metrics): stage-owned subsystems
-        # (clip cache, staging pool, handoff edge) become poll sources
-        # of the active registry — registered before the start barrier
-        # so every flusher tick sees the full source set (no-op when
-        # metrics are off)
-        metrics.register_stage(model, handoff)
-        # device observability plane (rnb_tpu.devobs): the stage's
-        # declared compute profile becomes a per-step MFU meter and
-        # its byte-owning subsystems (params, cache, staging, ragged
-        # pool, handoff adoptions) become HBM-ledger sources — all
-        # pre-barrier, so every sample covers the full source set
-        # (no-op when devobs is off)
-        devobs.register_stage(model, ctx.step_idx, ctx.device, handoff)
     except Exception:
         traceback.print_exc()
         ctx.termination.raise_flag(TerminationFlag.INTERNAL_ERROR)
@@ -868,10 +838,6 @@ def runner(ctx: RunnerContext) -> None:
     tr_finish = trace.name("exec%d.finish", ctx.step_idx)
     tr_publish = trace.name("exec%d.publish", ctx.step_idx)
     tr_handoff = trace.name("exec%d.handoff", ctx.step_idx)
-    # devobs compute meter (rnb_tpu.devobs): resolved once — None when
-    # devobs is off or this stage declares no compute profile, so the
-    # per-dispatch cost of the disabled path is one None test
-    devobs_meter = devobs.meter_for(ctx.step_idx)
 
     # Prefetch (NVVL parity, reference README.md:46-110): a signal-free
     # first stage exposing submit()/complete() gets its next requests'
@@ -1309,46 +1275,6 @@ def runner(ctx: RunnerContext) -> None:
                         # result (service time lands in hedges_wasted_ms,
                         # nothing publishes, nothing double-counts)
                         continue
-                    if devobs_meter is not None and flushed is None:
-                        # per-dispatch achieved-FLOPs feed — AFTER the
-                        # hedge-lost discard above, so a loser copy's rows
-                        # never inflate the meter (the same reason the
-                        # autotune service feed sits past that check):
-                        # valid rows are the constituents' num_clips
-                        # stamps with coalesced followers counted 0 — the
-                        # device-work rule clip_counts applies
-                        # (telemetry.TimeCardSummary) — so the Compute:
-                        # line cross-foots bench.py's clips_completed-
-                        # based MFU exactly. The busy span is
-                        # inference_start -> inference_finish (model call
-                        # + device sync), the service-time semantics the
-                        # autotune estimator uses.
-                        cards_dv = _cards_of(time_card)
-                        t_fin_dv = cards_dv[0].timings.get(key_inf_finish)
-                        if t_fin_dv is not None:
-                            # LAST constituent's start, like the autotune
-                            # estimator: an accumulating stage's earlier
-                            # members carry stale starts whose gap is
-                            # batch-fill wait, not device busy time
-                            t_sta_dv = max(
-                                tc_dv.timings.get(key_inf_start, t_fin_dv)
-                                for tc_dv in cards_dv)
-                            rows_dv = 0
-                            for tc_dv in cards_dv:
-                                # coalesced rows share another request's
-                                # dispatch and feature-hit rows skipped
-                                # the forward entirely — neither ran
-                                # FLOPs, so both count 0 (honesty policy:
-                                # hits must never inflate MFU)
-                                if not getattr(tc_dv, "cache_coalesced",
-                                               False) \
-                                        and not getattr(tc_dv,
-                                                        "feature_hit",
-                                                        False):
-                                    rows_dv += int(getattr(tc_dv,
-                                                           "num_clips", 0))
-                            devobs_meter.note(rows_dv,
-                                              t_fin_dv - t_sta_dv)
                     if controller is not None and tensors_out \
                             and flushed is None \
                             and not getattr(model, "AUTOTUNE_SELF_SERVICE",
@@ -1458,11 +1384,6 @@ def runner(ctx: RunnerContext) -> None:
                             time_card, TimeCardList) else [time_card]
                         for tc in cards:
                             summary.register(tc)
-                        # live SLO feed (rnb_tpu.metrics): the same
-                        # completions the summary registers stream
-                        # into the windowed goodput/burn gauges (one
-                        # None test when metrics are off)
-                        metrics.completions(cards)
                     if new >= ctx.num_videos:
                         if old < ctx.num_videos:
                             ctx.termination.raise_flag(

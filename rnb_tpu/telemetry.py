@@ -281,85 +281,10 @@ META_LINE_REGISTRY = (
               "trace-export counters: events written to trace.json, "
               "events dropped at the max_events cap "
               "(trace-enabled runs only)"),
-    StampSpec("Metrics:", "rnb_tpu/benchmark.py",
-              "live-metrics plane counters: interval snapshots "
-              "appended to metrics.jsonl, distinct series, flight-"
-              "recorder dumps written and triggers observed "
-              "(metrics-enabled runs only; --check holds the final "
-              "snapshot's counters to the Faults:/Cache:/Deadline:/"
-              "Hedge: ledgers exactly)"),
-    StampSpec("Slo:", "rnb_tpu/benchmark.py",
-              "live SLO-layer counters: completions tracked / within "
-              "deadline / missed, plus the run's peak burn rate in "
-              "milli-units (burn 1000 = consuming the error budget "
-              "exactly; metrics-enabled runs only)"),
     StampSpec("Phases:", "rnb_tpu/benchmark.py",
               "JSON per-phase latency attribution "
               "{phase: {mean_ms, p99_ms, count}} over steady-state "
               "completions (trace-enabled runs only)"),
-    StampSpec("Compute:", "rnb_tpu/benchmark.py",
-              "device-compute plane counters (rnb_tpu.devobs): "
-              "flops-bearing stages, dispatches, valid rows, total "
-              "achieved FLOPs, measured window, job tflops/mfu in "
-              "bench.py's exact rounding (tflops_milli / mfu_e4; "
-              "mfu_e4=-1 when the device peak is unknown), capture "
-              "windows taken (devobs-enabled runs only; --check "
-              "cross-foots flops against per-row counts x rows, "
-              "recomputes tflops_milli, and bounds the mfu)"),
-    StampSpec("Compute stages:", "rnb_tpu/benchmark.py",
-              "JSON per-stage roofline detail: rows, dispatches, "
-              "flops_per_row, busy_us, achieved tflops_busy, "
-              "mfu_busy vs the device peak, arithmetic intensity "
-              "from XLA cost_analysis bytes "
-              "(devobs-enabled runs only)"),
-    StampSpec("Memory:", "rnb_tpu/benchmark.py",
-              "HBM footprint ledger totals (rnb_tpu.memledger): "
-              "declared owners, devices, resident/peak bytes, "
-              "watermark threshold and crossings, backend "
-              "live-buffer bytes and the reconciliation verdict "
-              "(devobs-enabled runs only; --check asserts owner "
-              "rows sum to the total and peak >= final)"),
-    StampSpec("Memory owners:", "rnb_tpu/benchmark.py",
-              "JSON per-owner footprint detail {owner: {bytes, "
-              "peak_bytes}} — owners are declared in "
-              "memledger.MEM_OWNER_REGISTRY "
-              "(devobs-enabled runs only)"),
-    StampSpec("Critpath:", "rnb_tpu/benchmark.py",
-              "critical-path extraction counters (rnb_tpu.critpath): "
-              "requests whose blocking chain was recovered, chain "
-              "segments, worst per-request partition residual in "
-              "microseconds, hedge-won and redispatched completions, "
-              "and the binding stage's critical-path throughput "
-              "bound (bound_step / bound_vps_milli) "
-              "(critpath-enabled runs only; --check re-derives every "
-              "field from the timing tables and holds the partition "
-              "residual under 1 ms per request)"),
-    StampSpec("Critpath stages:", "rnb_tpu/benchmark.py",
-              "JSON per-stage blocking attribution: lanes, per-"
-              "(class) blocked totals/means over steady completions, "
-              "occupied ms and the stage's critical-path throughput "
-              "bound (critpath-enabled runs only)"),
-    StampSpec("Whatif:", "rnb_tpu/benchmark.py",
-              "calibrated queueing-model counters (rnb_tpu.whatif): "
-              "stages calibrated from the metrics plane, whether "
-              "calibration succeeded, the model's self-predicted "
-              "throughput in milli-vps and its bottleneck step "
-              "(whatif-enabled runs only; --check recomputes the "
-              "prediction from metrics.jsonl + the config copy alone "
-              "and holds it to +-1 milli-vps)"),
-    StampSpec("Operator:", "rnb_tpu/benchmark.py",
-              "operator-plane request ledger (rnb_tpu.statusz): GET "
-              "scrapes served, POST actions accepted, POST actions "
-              "denied by the allow_actions gate, request errors "
-              "(operator-enabled runs only; --check holds the line "
-              "to the logs/<job>/operator.json artifact both ways)"),
-    StampSpec("Stacks:", "rnb_tpu/benchmark.py",
-              "wall-clock stack sampler counters "
-              "(rnb_tpu.stacksampler): sampling ticks, distinct "
-              "thread roles, distinct folded stacks, total per-"
-              "thread samples (operator runs with sample_hz > 0 "
-              "only; --check re-sums stacks.folded to total and "
-              "holds ticks to sample_hz x wall within tolerance)"),
     StampSpec("Net:", "rnb_tpu/benchmark.py",
               "cross-host ingest edge counters (rnb_tpu.netedge): "
               "frames sent/acked, resends + resent_pending at "
@@ -404,10 +329,6 @@ TABLE_TRAILER_REGISTRY = (
     StampSpec("padding", "rnb_tpu/telemetry.py",
               "per-instance pad rows shipped with completed requests "
               "(0 under ragged dispatch)"),
-    StampSpec("critpath", "rnb_tpu/telemetry.py",
-              "per-instance blocking-chain totals: microseconds "
-              "blocked per (class, step) segment over steady "
-              "completions (critpath-enabled runs only)"),
 )
 
 
@@ -526,247 +447,6 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("queue.e{step}.depth", "rnb_tpu/benchmark.py",
               "counter (sampled): inter-stage queue depth, keyed by "
               "queue index"),
-)
-
-
-#: one declared live-metric series (rnb_tpu.metrics): ``pattern`` uses
-#: ``{step}`` like the other registries; ``kind`` is the series type
-#: (counter | gauge | rate | histogram); ``source`` says where samples
-#: come from — ``site`` (a ``metrics.counter/gauge/observe/mark/name``
-#: call site, which rnb-lint RNB-T009 requires to exist), ``bridge``
-#: (fed from same-named rnb_tpu.trace events through the SpanBridge —
-#: no metrics call site exists by design), ``poll`` (read from a
-#: subsystem's snapshot() each flusher tick) or ``derived`` (computed
-#: inside the registry, e.g. the SLO burn gauge).
-MetricSpec = namedtuple("MetricSpec",
-                        ("pattern", "kind", "source", "description"))
-
-#: every live-metric series name the tree may emit
-#: (``logs/<job>/metrics.jsonl`` + the Prometheus exposition file) —
-#: rnb-lint RNB-T009 cross-checks call sites against this, and the
-#: runtime registry rejects undeclared names outright
-METRIC_REGISTRY = (
-    # -- client (site-sourced) ----------------------------------------
-    MetricSpec("client.arrivals", "rate", "site",
-               "windowed request arrival rate at the client"),
-    MetricSpec("client.requests", "counter", "site",
-               "requests the client has created"),
-    MetricSpec("client.shed", "counter", "site",
-               "requests the client dropped at the full filename "
-               "queue"),
-    # -- executor hot loop (bridged from trace spans) -----------------
-    MetricSpec("exec{step}.queue_get", "histogram", "bridge",
-               "executor input-queue starvation wait (ms)"),
-    MetricSpec("exec{step}.hold_wait", "histogram", "bridge",
-               "executor batch-fill hold wait (ms)"),
-    MetricSpec("exec{step}.model_call", "histogram", "bridge",
-               "stage model-call service time (ms)"),
-    MetricSpec("exec{step}.device_sync", "histogram", "bridge",
-               "device output readiness wait (ms)"),
-    MetricSpec("exec{step}.publish", "histogram", "bridge",
-               "route + ring write + downstream enqueue (ms)"),
-    MetricSpec("exec{step}.collective", "histogram", "bridge",
-               "sharded-stage cross-shard logits merge gather (ms)"),
-    MetricSpec("loader.emit", "histogram", "bridge",
-               "fused-batch take/assemble/handoff (ms)"),
-    MetricSpec("loader.transfer", "histogram", "bridge",
-               "host->device transfer span (ms)"),
-    MetricSpec("staging.acquire_wait", "histogram", "bridge",
-               "staging-slot exhaustion backpressure wait (ms)"),
-    MetricSpec("batcher.emit", "counter", "bridge",
-               "Batcher fused emissions"),
-    MetricSpec("autotune.decision", "counter", "bridge",
-               "BatchController decisions"),
-    MetricSpec("health.lane_state", "counter", "bridge",
-               "lane health state transitions"),
-    # -- queue occupancy (probed each flusher tick) -------------------
-    MetricSpec("queue.filename.depth", "gauge", "site",
-               "client filename queue depth (saturation-armed)"),
-    MetricSpec("queue.e{step}.depth", "gauge", "site",
-               "inter-stage queue depth by edge ordinal "
-               "(saturation-armed)"),
-    # -- autotune controller (site-sourced gauges) --------------------
-    MetricSpec("autotune.arrival_hz", "gauge", "site",
-               "controller arrival-rate EWMA at the last decision"),
-    MetricSpec("autotune.target_rows", "gauge", "site",
-               "controller target row count at the last decision"),
-    # -- ledgers (polled from the shared stats objects) ---------------
-    MetricSpec("faults.num_failed", "counter", "poll",
-               "dead-lettered requests (FaultStats ledger)"),
-    MetricSpec("faults.num_shed", "counter", "poll",
-               "shed requests (FaultStats ledger)"),
-    MetricSpec("faults.num_retries", "counter", "poll",
-               "transient retry attempts (FaultStats ledger)"),
-    MetricSpec("faults.sheds", "rate", "site",
-               "windowed shed rate (shed-spike flight trigger)"),
-    MetricSpec("deadline.expired", "counter", "poll",
-               "requests shed as deadline_expired (DeadlineStats "
-               "ledger)"),
-    MetricSpec("hedge.fired", "counter", "poll",
-               "hedged re-dispatches fired (HedgeGovernor ledger)"),
-    MetricSpec("hedge.won", "counter", "poll",
-               "hedges the clone copy won"),
-    MetricSpec("hedge.lost", "counter", "poll",
-               "hedges the original copy won"),
-    MetricSpec("health.transitions", "counter", "poll",
-               "lane state-machine hops (LaneHealthBoard)"),
-    MetricSpec("health.opens", "counter", "poll",
-               "lane circuit opens"),
-    MetricSpec("health.evictions", "counter", "poll",
-               "permanently dead lanes"),
-    MetricSpec("health.probes", "counter", "poll",
-               "half-open recovery probes"),
-    MetricSpec("health.redispatches", "counter", "poll",
-               "items drained off evicted lanes onto siblings"),
-    MetricSpec("net.frames_sent", "counter", "poll",
-               "REQ frames shipped across the ingest edge"),
-    MetricSpec("net.frames_acked", "counter", "poll",
-               "REQ frames the peer acknowledged (unique seqs)"),
-    MetricSpec("net.resends", "counter", "poll",
-               "REQ frames re-shipped after reconnect or ack loss"),
-    MetricSpec("net.beats", "counter", "poll",
-               "peer heartbeat frames received"),
-    MetricSpec("net.reconnects", "counter", "poll",
-               "successful re-dials after a connection died"),
-    MetricSpec("net.remote", "counter", "poll",
-               "requests dispatched across the wire"),
-    MetricSpec("net.local", "counter", "poll",
-               "requests routed to the in-process fallback"),
-    MetricSpec("net.dedup_drops", "counter", "poll",
-               "duplicate DATA/DISPOSE frames dropped by the "
-               "receiver-side ledger (exactly-once guard)"),
-    MetricSpec("net.dup_arrivals", "counter", "poll",
-               "frames that arrived for an already-settled seq"),
-    MetricSpec("net.wire_bytes", "counter", "poll",
-               "total bytes received off the wire"),
-    MetricSpec("net.frame_bytes", "counter", "poll",
-               "DATA row-payload bytes received (valid rows only)"),
-    MetricSpec("net.err_total", "counter", "poll",
-               "classified network faults observed (all classes)"),
-    MetricSpec("net.peer_depth", "gauge", "poll",
-               "peer-reported in-flight depth (piggybacked on "
-               "every ack/beat frame)"),
-    # -- stage-owned subsystems (polled via metrics.register_stage) ---
-    MetricSpec("cache.hits", "counter", "poll",
-               "clip-cache lookup hits"),
-    MetricSpec("cache.misses", "counter", "poll",
-               "clip-cache lookup misses"),
-    MetricSpec("cache.inserts", "counter", "poll",
-               "clip-cache inserts"),
-    MetricSpec("cache.evictions", "counter", "poll",
-               "clip-cache LRU evictions"),
-    MetricSpec("cache.coalesced", "counter", "poll",
-               "requests that shared an in-flight decode"),
-    MetricSpec("cache.oversize", "counter", "poll",
-               "entries skipped as larger than the whole budget"),
-    MetricSpec("cache.bytes_resident", "gauge", "poll",
-               "resident cache bytes (shrinks on eviction)"),
-    MetricSpec("cache.entries", "gauge", "poll",
-               "resident cache entries"),
-    MetricSpec("staging.acquires", "counter", "poll",
-               "staging-slot acquires"),
-    MetricSpec("staging.acquire_waits", "counter", "poll",
-               "staging-slot exhaustion waits"),
-    MetricSpec("staging.staged_batches", "counter", "poll",
-               "zero-copy staged emissions"),
-    MetricSpec("staging.copied_batches", "counter", "poll",
-               "copy-fallback emissions"),
-    MetricSpec("staging.reallocs", "counter", "poll",
-               "alias-forced slot-buffer replacements"),
-    MetricSpec("pages.allocs", "counter", "poll",
-               "pages popped off arena free lists (rnb_tpu.pager)"),
-    MetricSpec("pages.frees", "counter", "poll",
-               "pages returned to arena free lists (incl. limbo "
-               "releases at unpin)"),
-    MetricSpec("pages.alloc_fails", "counter", "poll",
-               "page allocations refused for lack of free pages "
-               "(the caller evicts-and-retries or skips)"),
-    MetricSpec("pages.gathers", "counter", "poll",
-               "clip-arena gather kernels dispatched (one per "
-               "emission with paged hit rows)"),
-    MetricSpec("pages.gather_rows", "counter", "poll",
-               "rows overlaid from clip pages onto emission pools "
-               "(zero host bytes each)"),
-    MetricSpec("pages.feature_lookups", "counter", "poll",
-               "feature-cache probes at request admission"),
-    MetricSpec("pages.feature_hits", "counter", "poll",
-               "feature-cache hits (the request skips decode, "
-               "transfer and the stage forward)"),
-    MetricSpec("pages.feature_inserts", "counter", "poll",
-               "feature entries written after a successful forward "
-               "(insert-after-success only)"),
-    MetricSpec("pages.feature_evictions", "counter", "poll",
-               "LRU feature entries evicted to fit an insert"),
-    MetricSpec("pages.feature_gathers", "counter", "poll",
-               "feature-arena gather kernels dispatched (one per "
-               "feature-hit emission)"),
-    MetricSpec("pages.feature_gather_rows", "counter", "poll",
-               "output rows gathered from feature pages"),
-    MetricSpec("pages.feature_bytes_saved", "counter", "poll",
-               "wire bytes feature hits did not ship host->device"),
-    MetricSpec("pages.live", "gauge", "poll",
-               "pages off the free lists (entry-held + limbo) across "
-               "arenas"),
-    MetricSpec("pages.limbo", "gauge", "poll",
-               "evicted-but-still-pinned pages awaiting unpin"),
-    MetricSpec("pages.bytes", "gauge", "poll",
-               "total arena slab bytes (the page_pool HBM claim)"),
-    MetricSpec("staging.slots", "gauge", "poll",
-               "allocated staging slots"),
-    MetricSpec("handoff.d2d_edges", "counter", "poll",
-               "device-resident edge takes"),
-    MetricSpec("handoff.host_edges", "counter", "poll",
-               "host-round-trip edge takes"),
-    MetricSpec("handoff.d2d_bytes", "counter", "poll",
-               "bytes adopted/resharded on-device"),
-    MetricSpec("handoff.host_bytes", "counter", "poll",
-               "bytes moved through host memory"),
-    # -- device observability plane (polled from rnb_tpu.devobs) ------
-    MetricSpec("compute.s{step}.rows", "counter", "poll",
-               "valid rows a flops-bearing stage dispatched"),
-    MetricSpec("compute.s{step}.dispatches", "counter", "poll",
-               "model-call dispatches the compute meter observed"),
-    MetricSpec("compute.s{step}.tflops", "gauge", "poll",
-               "achieved TFLOP/s over the stage's busy time "
-               "(declared per-row FLOPs x rows / busy seconds)"),
-    MetricSpec("compute.s{step}.mfu", "gauge", "poll",
-               "busy-time MFU vs the device peak (absent when the "
-               "platform has no known peak — never guessed)"),
-    MetricSpec("memory.total_bytes", "gauge", "poll",
-               "HBM footprint ledger total across declared owners"),
-    MetricSpec("memory.peak_bytes", "gauge", "poll",
-               "ledger high-water mark (monotone)"),
-    MetricSpec("memory.params_bytes", "gauge", "poll",
-               "device-resident network parameter bytes (deduped "
-               "across replicas sharing one copy)"),
-    MetricSpec("memory.cache_bytes", "gauge", "poll",
-               "clip-cache resident bytes as a ledger owner"),
-    MetricSpec("memory.staging_bytes", "gauge", "poll",
-               "staging-slot slab bytes as a ledger owner"),
-    MetricSpec("memory.ragged_pool_bytes", "gauge", "poll",
-               "ragged pool dispatch-shape bytes as a ledger owner"),
-    MetricSpec("memory.page_pool_bytes", "gauge", "poll",
-               "page-allocator arena slab + shared-pool bytes "
-               "(memledger page_pool owner, rnb_tpu.pager)"),
-    MetricSpec("memory.handoff_bytes", "gauge", "poll",
-               "bytes resident from the latest edge adoptions"),
-    # -- the live SLO layer (derived inside the registry) -------------
-    MetricSpec("slo.good", "rate", "derived",
-               "windowed within-deadline completions"),
-    MetricSpec("slo.miss", "rate", "site",
-               "windowed SLO violations: late completions + "
-               "shed/failed requests"),
-    MetricSpec("slo.tracked", "counter", "derived",
-               "completions the SLO layer observed"),
-    MetricSpec("slo.within", "counter", "derived",
-               "completions inside their deadline/budget"),
-    MetricSpec("slo.missed", "counter", "derived",
-               "completions outside their deadline/budget"),
-    MetricSpec("slo.goodput_vps", "gauge", "derived",
-               "windowed within-deadline goodput (completions/s)"),
-    MetricSpec("slo.burn_rate", "gauge", "derived",
-               "windowed miss fraction / error budget (1.0 = "
-               "consuming the budget exactly)"),
 )
 
 
@@ -953,7 +633,7 @@ class TimeCardSummary:
         self.keys: List[str] = []
         self.devices_per_inference: List[List[tuple]] = []
         # per-record clip counts (0 when the pipeline never stamped
-        # num_clips) — feeds clips/sec and MFU accounting in bench.py
+        # num_clips) — feeds clips/sec and MFU accounting
         self.clip_counts: List[int] = []
         # fault accounting (rnb_tpu.runner containment): failed/shed
         # requests never enter the columnar timing data, so latency
@@ -987,17 +667,6 @@ class TimeCardSummary:
         # trace-off reports stay byte-stable with the earlier schema
         self.track_phases: bool = False
         self.phase_num_skips: int = 0
-        # blocking-chain extraction (rnb_tpu.critpath): the hedge/
-        # redispatch content stamps are captured per completion
-        # unconditionally (cheap ints, like clip_counts) so the
-        # chain aggregation stays hedge-aware, but the `# critpath`
-        # trailer is written only when the executor opts this summary
-        # in (root 'critpath' config key) — earlier reports stay
-        # byte-stable
-        self.track_critpath: bool = False
-        self.critpath_num_skips: int = 0
-        self.hedge_flags: List[bool] = []
-        self.redispatch_counts: List[int] = []
 
     def note_failure(self, reason: str, n: int = 1) -> None:
         """Count a contained permanent failure (excluded from timings)."""
@@ -1043,13 +712,6 @@ class TimeCardSummary:
         if pad is not None:
             self.num_pad_tracked += 1
             self.num_pad_rows += int(pad)
-        # claim-ledger stamps (rnb_tpu.health): did the hedge clone
-        # win this completion, and how often was it drained off an
-        # evicted lane — the critical-path aggregation reports both
-        self.hedge_flags.append(
-            bool(getattr(time_card, "hedge_copy", False)))
-        self.redispatch_counts.append(
-            int(getattr(time_card, "redispatched", 0)))
 
     def total_clips(self) -> int:
         """Sum of registered records' ``num_clips`` stamps."""
@@ -1139,40 +801,6 @@ class TimeCardSummary:
         return ("# padding pad_rows=%d num_tracked=%d"
                 % (self.num_pad_rows, self.num_pad_tracked))
 
-    def steady_rows(self, num_skips: int = 0):
-        """Yield ``(timings, hedged, redispatched)`` per record after
-        ``num_skips`` — the critical-path aggregation's input
-        (rnb_tpu.critpath.aggregate): each row's stamp mapping plus
-        the claim-ledger content stamps captured at register()."""
-        if not self.keys or len(self.keys) < 2:
-            return
-        columns = [self.summary[key][num_skips:] for key in self.keys]
-        hedges = self.hedge_flags[num_skips:]
-        redisps = self.redispatch_counts[num_skips:]
-        for idx, row in enumerate(zip(*columns)):
-            yield (dict(zip(self.keys, row)),
-                   hedges[idx] if idx < len(hedges) else False,
-                   redisps[idx] if idx < len(redisps) else 0)
-
-    def critpath_line(self) -> Optional[str]:
-        """The ``# critpath ...`` trailer, or None when extraction is
-        off (critpath-disabled runs keep the earlier byte-stable
-        schema) or no steady record decomposed. Microsecond integer
-        totals per ``<class><step>`` segment so the generic
-        ``key=value`` trailer parser reads it unchanged."""
-        if not self.track_critpath:
-            return None
-        from rnb_tpu.critpath import trailer_totals
-        n, totals = trailer_totals(
-            timings for timings, _h, _r
-            in self.steady_rows(self.critpath_num_skips))
-        if not n:
-            return None
-        parts = ["# critpath n=%d" % n]
-        parts.extend("%s_us=%d" % (key, totals[key])
-                     for key in sorted(totals))
-        return " ".join(parts)
-
     def phase_samples(self, num_skips: int = 0):
         """{phase: [per-request milliseconds]} over records after
         ``num_skips`` — the deterministic stamp-only decomposition
@@ -1252,9 +880,6 @@ class TimeCardSummary:
         phases = self.phases_line()
         if phases is not None:
             fp.write(phases + "\n")
-        critpath = self.critpath_line()
-        if critpath is not None:
-            fp.write(critpath + "\n")
 
 
 def aggregate_stage_counters(snapshots):

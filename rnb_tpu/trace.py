@@ -181,9 +181,8 @@ class TraceSettings:
 
 
 class _Span:
-    """One live span while a Tracer (or the metrics bridge) collects:
-    the profiler annotation plus an event in the collector's
-    buffer."""
+    """One live span while a Tracer collects: the profiler annotation
+    plus an event in the collector's buffer."""
 
     __slots__ = ("tracer", "name", "rid", "counts", "annotation", "t0")
 
@@ -262,27 +261,9 @@ class Tracer:
             return len(self._events)
 
     def snapshot_events(self) -> List[Tuple]:
-        """Copy of the collected event tuples — the devobs merge reads
-        the ``model_call`` spans here to rid-correlate device ops."""
+        """Copy of the collected event tuples."""
         with self._lock:
             return list(self._events)
-
-    def extend(self, events: List[Tuple]) -> int:
-        """Append externally-built event tuples (``(name, ph, t0,
-        dur_s, thread_name, rid, args)`` — the collection schema) with
-        the same ``max_events`` bound as live collection; returns how
-        many were admitted. Used by rnb_tpu.devobs to merge captured
-        device-op intervals as ``device:<plane>`` tracks after the run
-        drained (never on the hot path)."""
-        added = 0
-        with self._lock:
-            for event in events:
-                if len(self._events) >= self.settings.max_events:
-                    self.dropped += 1
-                    continue
-                self._events.append(tuple(event))
-                added += 1
-        return added
 
     # -- background occupancy sampler ---------------------------------
 
@@ -332,15 +313,10 @@ class Tracer:
 
 
 def export_events(events: List[Tuple], dropped: int, path: str,
-                  job_id: str = "",
-                  extra: Optional[dict] = None) -> int:
+                  job_id: str = "") -> int:
     """Export one event list — ``(name, ph, t0, dur_s, thread_name,
     rid, args)`` tuples, the :class:`Tracer` collection schema — as
-    Chrome-trace JSON. Shared by :meth:`Tracer.export` and the flight
-    recorder (rnb_tpu.metrics), whose bounded ring dumps MUST render
-    in Perfetto and pass :func:`validate_trace` exactly like a full
-    trace; ``extra`` keys land in ``otherData`` (the flight dump
-    carries its trigger + metric window there)."""
+    Chrome-trace JSON."""
     events = sorted(events, key=lambda e: e[2])
     t_base = events[0][2] if events else 0.0
     tids: Dict[str, int] = {}
@@ -459,8 +435,6 @@ def export_events(events: List[Tuple], dropped: int, path: str,
              "num_flows": num_flows,
              "dropped_events": dropped,
              "t_base_epoch_s": t_base}
-    if extra:
-        other.update(extra)
     doc = {"traceEvents": meta + out,
            "displayTimeUnit": "ms",
            "otherData": other}

@@ -2,7 +2,7 @@
 """``make benchdiff``: CI-able perf-trajectory check over the matrix.
 
 The per-config throughput matrix (``MULTICHIP_CONFIGS.json``, written
-by ``scripts/multichip_demo.py`` / ``scripts/bench_matrix.py`` runs)
+by ``scripts/multichip_demo.py`` / ``scripts/run_shipped_configs.py`` runs)
 has so far been eyeballed across ``BENCH_*.json`` snapshots — a
 regression in one cell is invisible until someone reads the numbers.
 This script makes the trajectory a checked artifact: it diffs the
@@ -25,16 +25,6 @@ Rules (per config row, joined on the ``config`` key):
 ``--update`` rewrites the baseline from the current matrix (the
 reviewed way to ratify a new floor). Exit: 0 clean, 1 regression(s),
 2 unreadable inputs.
-
-``--explain`` wires in the run-diff attribution (scripts/rnb_diff.py):
-matrix rows MAY carry an ``evidence_logs`` key naming the repo-
-relative job log directory the cell was measured from (the evidence-
-log convention, documented in README "Explanation plane"); when a
-cell regresses and BOTH its baseline and current rows point at
-existing evidence dirs, the ranked per-phase delta table is appended
-under the regression line — every red cell ships with its
-explanation. Rows without evidence (or with vanished dirs) degrade
-gracefully to a one-line note; nothing new can fail the gate.
 """
 
 from __future__ import annotations
@@ -68,43 +58,7 @@ def row_ok(row: dict) -> bool:
         "termination_flag", 0) or 0) == 0
 
 
-def explain_cell(base: dict, cur: dict):
-    """The run-diff attribution lines for one regressed cell, from
-    the rows' ``evidence_logs`` job dirs — or a one-line note when
-    either side carries no (existing) evidence. Never raises: an
-    explanation failure must not mask the regression it explains."""
-    base_dir = base.get("evidence_logs")
-    cur_dir = cur.get("evidence_logs")
-    if not base_dir or not cur_dir:
-        missing = "baseline" if not base_dir else "current"
-        return ["    (no explanation: the %s row names no "
-                "evidence_logs dir)" % missing]
-    if str(base_dir) == str(cur_dir):
-        # a regenerated current row carries the baseline's pointer
-        # forward until an operator attaches the regressed run's own
-        # logs — diffing a dir against itself would print an
-        # all-zero "attribution" under a real red cell
-        return ["    (no explanation: baseline and current rows "
-                "share the same evidence dir %s — attach the "
-                "regressed run's own logs to the current row)"
-                % base_dir]
-    base_path = os.path.join(REPO, str(base_dir))
-    cur_path = os.path.join(REPO, str(cur_dir))
-    for side, path in (("baseline", base_path), ("current", cur_path)):
-        if not os.path.isdir(path):
-            return ["    (no explanation: %s evidence dir %s does "
-                    "not exist)" % (side, path)]
-    try:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        import rnb_diff
-        report = rnb_diff.diff_jobs(base_path, cur_path)
-        return ["    " + line for line in rnb_diff.report_lines(report)]
-    except Exception as e:  # noqa: BLE001 — degraded, never fatal
-        return ["    (no explanation: rnb_diff failed: %s)" % e]
-
-
-def diff(baseline: dict, current: dict, tolerance: float,
-         explain: bool = False):
+def diff(baseline: dict, current: dict, tolerance: float):
     """-> (report lines, regression count). Pure so tests drive it."""
     lines = []
     regressions = 0
@@ -130,8 +84,6 @@ def diff(baseline: dict, current: dict, tolerance: float,
                          "(ok=%s flag=%s)"
                          % (key, cur.get("ok"),
                             cur.get("termination_flag")))
-            if explain:
-                lines.extend(explain_cell(base, cur))
             continue
         floor = base_vps * (1.0 - tolerance)
         if row_ok(base) and cur_vps < floor:
@@ -140,8 +92,6 @@ def diff(baseline: dict, current: dict, tolerance: float,
                          "(baseline %.3f, tolerance %d%%)"
                          % (key, cur_vps, floor, base_vps,
                             round(tolerance * 100)))
-            if explain:
-                lines.extend(explain_cell(base, cur))
         elif base_vps > 0:
             lines.append("  ok         %-44s %.3f v/s vs baseline "
                          "%.3f (%+.0f%%)"
@@ -170,11 +120,6 @@ def main(argv=None) -> int:
     parser.add_argument("--update", action="store_true",
                         help="ratify the current matrix as the new "
                              "baseline instead of checking")
-    parser.add_argument("--explain", action="store_true",
-                        help="append the rnb_diff per-phase delta "
-                             "attribution under every regressed cell "
-                             "whose rows carry evidence_logs dirs "
-                             "(graceful no-op otherwise)")
     args = parser.parse_args(argv)
 
     try:
@@ -203,8 +148,7 @@ def main(argv=None) -> int:
               "(run --update once to ratify a floor)"
               % (args.baseline, e))
         return 2
-    lines, regressions = diff(baseline, current, args.tolerance,
-                              explain=args.explain)
+    lines, regressions = diff(baseline, current, args.tolerance)
     print("bench_diff: %s vs %s (tolerance %d%%)"
           % (os.path.relpath(args.current, REPO),
              os.path.relpath(args.baseline, REPO),

@@ -167,97 +167,6 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta["trace_" + key] = int(val)
-        elif line.startswith("Metrics:"):
-            # "Metrics: snapshots=S series=K dumps=D triggers=T" —
-            # live-metrics plane accounting (rnb_tpu.metrics), written
-            # only by metrics-enabled runs; --check cross-foots the
-            # final metrics.jsonl snapshot against the ledger lines
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["metrics_" + key] = int(val)
-        elif line.startswith("Slo:"):
-            # "Slo: tracked=T within=W missed=M burn_max_milli=B" —
-            # the live SLO layer's final ledger (rnb_tpu.metrics),
-            # metrics-enabled runs only
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["slo_" + key] = int(val)
-        elif line.startswith("Compute stages:"):
-            # JSON per-stage roofline detail (rnb_tpu.devobs) — must
-            # be matched before the "Compute:" prefix below;
-            # devobs-enabled runs only
-            import json
-            meta["compute_stage_detail"] = json.loads(
-                line.split(":", 1)[1])
-        elif line.startswith("Compute:"):
-            # "Compute: stages=S dispatches=D rows=R flops_total=F
-            #  window_us=W tflops_milli=T mfu_e4=M captures=C" —
-            # device compute plane accounting (rnb_tpu.devobs),
-            # devobs-enabled runs only; --check cross-foots the
-            # per-stage detail, recomputes tflops_milli, and bounds
-            # the mfu (mfu_e4 == -1 means no known device peak)
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["compute_" + key] = int(val)
-        elif line.startswith("Memory owners:"):
-            # JSON per-owner footprint detail {owner: {bytes,
-            # peak_bytes}} — must be matched before the "Memory:"
-            # prefix below; devobs-enabled runs only
-            import json
-            meta["memory_owner_detail"] = json.loads(
-                line.split(":", 1)[1])
-        elif line.startswith("Memory:"):
-            # "Memory: owners=O devices=D total_bytes=B peak_bytes=P
-            #  watermark_bytes=W watermark_hits=H live_bytes=L
-            #  reconciled=R" — HBM footprint ledger totals
-            # (rnb_tpu.memledger), devobs-enabled runs only; owner
-            # rows must sum to total_bytes and peak >= final
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["memory_" + key] = int(val)
-        elif line.startswith("Critpath stages:"):
-            # JSON per-stage blocking attribution (rnb_tpu.critpath)
-            # — must be matched before the "Critpath:" prefix below;
-            # critpath-enabled runs only
-            import json
-            meta["critpath_stage_detail"] = json.loads(
-                line.split(":", 1)[1])
-        elif line.startswith("Critpath:"):
-            # "Critpath: requests=N segments=S residual_us_max=R
-            #  hedged=H redispatched=D bound_step=B
-            #  bound_vps_milli=V" — blocking-chain extraction
-            # counters (rnb_tpu.critpath), critpath-enabled runs
-            # only; --check re-derives every field from the timing
-            # tables and holds the partition residual under 1 ms
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["critpath_" + key] = int(val)
-        elif line.startswith("Whatif:"):
-            # "Whatif: stages=N calibrated=C pred_vps_milli=P
-            #  bottleneck_step=B" — calibrated queueing-model
-            # counters (rnb_tpu.whatif), whatif-enabled runs only;
-            # --check recomputes the prediction from metrics.jsonl +
-            # the config copy alone
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["whatif_" + key] = int(val)
-        elif line.startswith("Operator:"):
-            # "Operator: scrapes=S actions=A denied=D errors=E" — the
-            # operator-plane HTTP server's request ledger
-            # (rnb_tpu.statusz), operator-enabled runs only; --check
-            # holds the line to the operator.json artifact both ways
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["operator_" + key] = int(val)
-        elif line.startswith("Stacks:"):
-            # "Stacks: samples=S threads=T folded=F total=N" — the
-            # wall-clock stack sampler ledger (rnb_tpu.stacksampler),
-            # operator runs with sample_hz > 0 only; --check re-sums
-            # the stacks.folded artifact to total and holds samples
-            # to sample_hz x wall within tolerance
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["stacks_" + key] = int(val)
         elif line.startswith("Net errors:"):
             # "Net errors: total=T refused=R reset=S timeout=O
             #  partial_frame=P corrupt=C" — per-class network fault
@@ -723,86 +632,6 @@ def print_attribution(job_dir: str, out=None) -> int:
     return 0 if worst <= 1.0 else 1
 
 
-# -- critical-path explanation (CLI: --explain <job_dir>) --------------
-
-def print_explanation(job_dir: str, out=None) -> int:
-    """``--explain``: the per-request blocking-chain ranking, the
-    per-stage critical-path throughput bounds, and (when the job
-    streamed metrics) the calibrated what-if counterfactuals — all
-    recomputed from the artifacts alone, so it works on any job dir.
-    Returns 0 on success, 1 when the partition invariant fails or
-    nothing decomposes."""
-    import sys as _sys
-    out = out or _sys.stdout
-    critpath = _rnb_critpath()
-    num_skips = _summary_skips()
-    tables = _timing_tables(job_dir)
-    report = _recompute_critpath(job_dir, tables, num_skips)
-    if report is None:
-        # short runs (fewer rows than the steady skip) still explain
-        # — over every completed row, flagged as such
-        report = _recompute_critpath(job_dir, tables, 0)
-        if report is None:
-            out.write("%s: no completed request decomposes into a "
-                      "blocking chain\n" % job_dir)
-            return 1
-        out.write("%s: fewer rows than the steady-state skip — "
-                  "explaining over every completed request\n"
-                  % job_dir)
-    out.write("%s: blocking-chain attribution over %d request(s)\n"
-              % (job_dir, report["requests"]))
-    out.write("  ranked blocked time (segment = <class><step>):\n")
-    ranked = critpath.ranking(report["stage_detail"])
-    total_all = sum(total for _seg, total, _mean in ranked) or 1.0
-    for seg, total, mean in ranked:
-        out.write("    %-18s %10.2f ms total  %8.3f ms/req  (%4.1f%%)\n"
-                  % (seg, total, mean, 100.0 * total / total_all))
-    out.write("  per-stage critical-path throughput bound "
-              "(lanes x requests / occupied s):\n")
-    for step_key in sorted(report["stage_detail"]):
-        entry = report["stage_detail"][step_key]
-        out.write("    %-8s lanes=%d occupied=%.1f ms  bound=%.3f "
-                  "videos/s%s\n"
-                  % (step_key, entry["lanes"], entry["occupied_ms"],
-                     entry["bound_vps"],
-                     "  <- binding" if ("step%d"
-                                        % report["bound_step"])
-                     == step_key else ""))
-    out.write("  partition residual: worst %d us per request "
-              "(must stay <= 1000)\n" % report["residual_us_max"])
-    # cross-foot the log-meta line when the run wrote one
-    meta = parse_meta(job_dir)
-    status = 0
-    if "critpath_requests" in meta \
-            and meta.get("critpath_requests") != report["requests"]:
-        out.write("  WARNING: log-meta 'Critpath:' counts %s "
-                  "request(s) but the tables recompute %d\n"
-                  % (meta.get("critpath_requests"),
-                     report["requests"]))
-        status = 1
-    # the what-if face: calibrate from the artifacts when present
-    _rnb_trace()
-    from rnb_tpu import whatif as whatif_mod
-    model = whatif_mod.calibrate_job(job_dir)
-    if model is not None and model.calibrated:
-        vps, bottleneck = model.predict_throughput()
-        out.write("  what-if (calibrated from metrics.jsonl + config "
-                  "copy):\n")
-        out.write("    self-predicted %.3f videos/s, bottleneck "
-                  "step%d\n" % (vps, bottleneck))
-        for label, spec in (
-                ("replicas+1 on the bottleneck",
-                 {"replicas": {bottleneck: "+1"}}),
-                ("service x0.5 on the bottleneck",
-                 {"service_scale": {bottleneck: 0.5}}),
-                ("arrival x1.5", {"arrival_scale": 1.5})):
-            answer = model.query(spec)
-            out.write("    %-32s -> %.3f videos/s (%.2fx)\n"
-                      % (label, answer["pred_vps"],
-                         answer["vps_ratio"]))
-    return max(status, 0 if report["residual_us_max"] <= 1000 else 1)
-
-
 # -- consistency checking (CLI: parse_utils.py --check <job_dir>) ------
 
 def check_job(job_dir: str) -> List[str]:
@@ -1165,30 +994,6 @@ def check_job_detail(job_dir: str) -> Tuple[List[str], bool]:
     # trace.json actually holds, and the artifact must be structurally
     # valid (every event stamped, every flow resolving)
     problems.extend(_check_trace_artifact(job_dir, meta))
-    # live-metrics plane (rnb_tpu.metrics): counters monotone across
-    # snapshots, histogram bucket sums equal to counts, the FINAL
-    # snapshot footing the Faults:/Cache:/Deadline:/Hedge:/Slo:
-    # ledgers exactly, and every flight dump structurally valid
-    problems.extend(_check_metrics(job_dir, meta))
-    # device observability plane (rnb_tpu.devobs / rnb_tpu.memledger):
-    # per-stage flops must equal per-row counts x rows and sum to the
-    # total, MFU <= 1 wherever a peak is known, memory owner rows must
-    # sum to the ledger total with peak >= final, and every capture
-    # artifact must exist and parse
-    problems.extend(_check_devobs(job_dir, meta))
-    # explanation plane (rnb_tpu.critpath / rnb_tpu.whatif): blocking
-    # chains must partition every request's end-to-end span (<= 1 ms
-    # residual, every row of every table), the Critpath: lines and
-    # `# critpath` trailers must re-derive from the tables, and the
-    # Whatif: prediction must recompute from metrics.jsonl + the
-    # config copy alone
-    problems.extend(_check_critpath(job_dir, meta, tables))
-    problems.extend(_check_whatif(job_dir, meta))
-    # operator plane (rnb_tpu.statusz / rnb_tpu.stacksampler): the
-    # Operator: ledger and the operator.json artifact must agree both
-    # ways, the stacks.folded counts must re-sum to the Stacks: total,
-    # and the sampler's tick count must track sample_hz x wall
-    problems.extend(_check_operator(job_dir, meta))
     # cross-host ingest edge (rnb_tpu.netedge): the send/ack/resend
     # ledger must foot at teardown, per-class error counts must re-sum
     # to the total, every duplicate arrival must have been dropped by
@@ -1868,830 +1673,6 @@ def _check_trace_artifact(job_dir: str,
     return problems
 
 
-def load_metrics(job_dir: str) -> List[Dict[str, object]]:
-    """One job's ``metrics.jsonl`` -> list of snapshot dicts (empty
-    when the file is absent — metrics-off runs write nothing)."""
-    import json
-    path = os.path.join(job_dir, "metrics.jsonl")
-    if not os.path.isfile(path):
-        return []
-    out = []
-    with open(path) as f:
-        for line in f:
-            if line.strip():
-                out.append(json.loads(line))
-    return out
-
-
-#: (final-snapshot counter name, log-meta key) pairs the metrics
-#: footing check holds equal whenever the meta key is present — the
-#: "metrics are checked, not trusted" rule: the live plane must agree
-#: with the end-of-run ledgers EXACTLY at the final snapshot
-_METRICS_FOOTING = (
-    ("faults.num_failed", "num_failed"),
-    ("faults.num_shed", "num_shed"),
-    ("faults.num_retries", "num_retries"),
-    ("cache.hits", "cache_hits"),
-    ("cache.misses", "cache_misses"),
-    ("cache.inserts", "cache_inserts"),
-    ("cache.evictions", "cache_evictions"),
-    ("cache.coalesced", "cache_coalesced"),
-    ("cache.oversize", "cache_oversize"),
-    ("staging.acquires", "staging_acquires"),
-    ("staging.acquire_waits", "staging_acquire_waits"),
-    ("staging.staged_batches", "staging_staged_batches"),
-    ("staging.copied_batches", "staging_copied_batches"),
-    ("staging.reallocs", "staging_reallocs"),
-    ("deadline.expired", "deadline_expired"),
-    ("hedge.fired", "hedges_fired"),
-    ("hedge.won", "hedges_won"),
-    ("hedge.lost", "hedges_lost"),
-    ("health.transitions", "health_transitions"),
-    ("health.opens", "health_opens"),
-    ("health.evictions", "health_evictions"),
-    ("health.probes", "health_probes"),
-    ("health.redispatches", "health_redispatches"),
-    ("handoff.d2d_edges", "handoff_d2d_edges"),
-    ("handoff.host_edges", "handoff_host_edges"),
-    ("handoff.d2d_bytes", "handoff_d2d_bytes"),
-    ("handoff.host_bytes", "handoff_host_bytes"),
-    ("slo.tracked", "slo_tracked"),
-    ("slo.within", "slo_within"),
-    ("slo.missed", "slo_missed"),
-)
-
-
-def _check_metrics(job_dir: str,
-                   meta: Dict[str, object]) -> List[str]:
-    """Live-metrics invariants (rnb_tpu.metrics): see
-    :data:`_METRICS_FOOTING` plus snapshot monotonicity, histogram
-    internal consistency, and flight-dump validity."""
-    problems: List[str] = []
-    jsonl = os.path.join(job_dir, "metrics.jsonl")
-    flights = sorted(
-        name_ for name_ in os.listdir(job_dir)
-        if re.fullmatch(r"flight-\d+\.json", name_))
-    if "metrics_snapshots" not in meta:
-        if os.path.isfile(jsonl):
-            problems.append("metrics.jsonl present but log-meta has "
-                            "no 'Metrics:' line")
-        if flights:
-            problems.append("flight dump(s) %s present but log-meta "
-                            "has no 'Metrics:' line" % flights)
-        return problems
-    snapshots = load_metrics(job_dir)
-    if not snapshots:
-        return ["log-meta carries a 'Metrics:' line but "
-                "metrics.jsonl is missing or empty"]
-    if len(snapshots) != meta["metrics_snapshots"]:
-        problems.append(
-            "'Metrics:' line says snapshots=%s but metrics.jsonl "
-            "holds %d" % (meta["metrics_snapshots"], len(snapshots)))
-    if "slo_tracked" not in meta:
-        problems.append("log-meta carries a 'Metrics:' line but no "
-                        "'Slo:' line (the two ship together)")
-    last_seq = 0
-    prev_counters: Dict[str, object] = {}
-    for idx, snap in enumerate(snapshots):
-        seq = int(snap.get("seq", 0))
-        if seq <= last_seq:
-            problems.append(
-                "metrics.jsonl snapshot %d: seq %d is not increasing "
-                "(previous %d)" % (idx, seq, last_seq))
-        last_seq = seq
-        counters = dict(snap.get("counters", {}))
-        for key, value in counters.items():
-            if int(value) < int(prev_counters.get(key, 0)):
-                problems.append(
-                    "metrics.jsonl snapshot %d: counter %r decreased "
-                    "%s -> %s (counters must be monotone)"
-                    % (idx, key, prev_counters.get(key), value))
-        prev_counters = counters
-        for hname, hist in dict(snap.get("histograms", {})).items():
-            hist = dict(hist)
-            bucket_sum = sum(int(b) for b in hist.get("buckets", []))
-            if bucket_sum != int(hist.get("count", -1)):
-                problems.append(
-                    "metrics.jsonl snapshot %d: histogram %r bucket "
-                    "sum %d != count %s" % (idx, hname, bucket_sum,
-                                            hist.get("count")))
-    final = dict(snapshots[-1].get("counters", {}))
-    for counter_name, meta_key in _METRICS_FOOTING:
-        if meta_key not in meta:
-            continue
-        if counter_name not in final:
-            problems.append(
-                "final metrics snapshot is missing %r (log-meta "
-                "carries %s=%s)" % (counter_name, meta_key,
-                                    meta[meta_key]))
-        elif int(final[counter_name]) != int(meta[meta_key]):
-            problems.append(
-                "final metrics snapshot %s=%s does not foot log-meta "
-                "%s=%s (metrics are checked, not trusted)"
-                % (counter_name, final[counter_name], meta_key,
-                   meta[meta_key]))
-    if len(flights) != meta.get("metrics_dumps", 0):
-        problems.append(
-            "'Metrics:' line says dumps=%s but the job dir holds %d "
-            "flight dump(s): %s" % (meta.get("metrics_dumps"),
-                                    len(flights), flights))
-    if meta.get("metrics_dumps", 0) > meta.get("metrics_triggers", 0):
-        problems.append(
-            "metrics_dumps=%s exceeds metrics_triggers=%s (every "
-            "dump needs a trigger)" % (meta.get("metrics_dumps"),
-                                       meta.get("metrics_triggers")))
-    trace = _rnb_trace()
-    import json
-    for name_ in flights:
-        path = os.path.join(job_dir, name_)
-        for issue in trace.validate_trace(path)[:3]:
-            problems.append("%s: %s" % (name_, issue))
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except ValueError:
-            continue  # validate_trace already reported it
-        if not doc.get("otherData", {}).get("flight_trigger"):
-            problems.append("%s: otherData names no flight_trigger"
-                            % name_)
-    if not os.path.isfile(os.path.join(job_dir, "metrics.prom")):
-        problems.append("metrics-enabled run wrote no metrics.prom "
-                        "exposition file")
-    # the Slo: ledger must partition: within + missed == tracked
-    if "slo_tracked" in meta \
-            and meta.get("slo_within", 0) + meta.get("slo_missed", 0) \
-            != meta["slo_tracked"]:
-        problems.append(
-            "slo_within=%s + slo_missed=%s != slo_tracked=%s (every "
-            "tracked completion has exactly one verdict)"
-            % (meta.get("slo_within"), meta.get("slo_missed"),
-               meta["slo_tracked"]))
-    return problems
-
-
-def _devobs_captures(job_dir: str) -> List[str]:
-    return sorted(name for name in os.listdir(job_dir)
-                  if re.fullmatch(r"devobs-capture-\d+\.txt", name))
-
-
-def _check_capture_artifact(path: str) -> List[str]:
-    """Light structural validation of one devobs capture: the
-    xprof-ops 4-column header, an ops_written bound honored by the
-    data rows, and every data row parsing as two integer timestamps
-    (t1 >= t0) plus plane + op name."""
-    base = os.path.basename(path)
-    problems: List[str] = []
-    ops_written = None
-    rows = 0
-    with open(path) as f:
-        first = f.readline()
-        if not first.startswith("# t0_ns t1_ns plane op_name"):
-            return ["%s: missing the '# t0_ns t1_ns plane op_name' "
-                    "header" % base]
-        for line in f:
-            if line.startswith("#"):
-                parts = line.split()
-                if "ops_written" in parts:
-                    ops_written = int(
-                        parts[parts.index("ops_written") + 1])
-                continue
-            rows += 1
-            parts = line.rstrip("\n").split(" ", 3)
-            if len(parts) != 4:
-                problems.append("%s: malformed data row %r"
-                                % (base, line.strip()[:60]))
-                break
-            try:
-                t0, t1 = int(parts[0]), int(parts[1])
-            except ValueError:
-                problems.append("%s: non-integer timestamps in %r"
-                                % (base, line.strip()[:60]))
-                break
-            if t1 < t0:
-                problems.append("%s: interval ends before it starts "
-                                "(%d > %d)" % (base, t0, t1))
-                break
-    if ops_written is None:
-        problems.append("%s: missing the ops_total/ops_written bound "
-                        "header" % base)
-    elif rows != ops_written:
-        problems.append("%s: header says ops_written=%d but the file "
-                        "holds %d row(s)" % (base, ops_written, rows))
-    return problems
-
-
-def _check_devobs(job_dir: str, meta: Dict[str, object]) -> List[str]:
-    """Device-observability invariants (rnb_tpu.devobs /
-    rnb_tpu.memledger): the Compute: line's integer fields must
-    recompute from the per-stage detail (tflops_milli included), MFU
-    stays <= 1 wherever a peak is known, Memory: owner rows sum to
-    the ledger total with peak >= final, and capture artifacts match
-    their counter and parse. Malformed detail values (the adversarial
-    case the tamper tests simulate) surface as findings, never as a
-    checker crash."""
-    try:
-        return _check_devobs_inner(job_dir, meta)
-    except (ValueError, TypeError, KeyError) as e:
-        return ["devobs Compute:/Memory: lines are malformed "
-                "(%s: %s) — the detail JSON does not match the "
-                "declared schema" % (type(e).__name__, e)]
-
-
-def _check_devobs_inner(job_dir: str,
-                        meta: Dict[str, object]) -> List[str]:
-    problems: List[str] = []
-    captures = _devobs_captures(job_dir)
-    if "compute_stages" not in meta and "memory_total_bytes" not in meta:
-        if captures:
-            problems.append("devobs capture artifact(s) %s present "
-                            "but log-meta has no 'Compute:'/'Memory:' "
-                            "line" % captures)
-        return problems
-    if "compute_stages" in meta and "memory_total_bytes" not in meta:
-        problems.append("log-meta carries a 'Compute:' line but no "
-                        "'Memory:' line (the devobs plane writes the "
-                        "ledger totals on every enabled run)")
-    # -- Compute: footing ---------------------------------------------
-    if "compute_stages" in meta:
-        detail = {key: dict(val) for key, val
-                  in dict(meta.get("compute_stage_detail", {})).items()}
-        if len(detail) != meta.get("compute_stages", 0):
-            problems.append(
-                "'Compute stages:' names %d stage(s) but the "
-                "'Compute:' line says stages=%d"
-                % (len(detail), meta.get("compute_stages", 0)))
-        for key in ("compute_dispatches", "compute_rows",
-                    "compute_flops_total", "compute_window_us",
-                    "compute_captures"):
-            if meta.get(key, 0) < 0:
-                problems.append("negative %s" % key)
-        flops_sum = dispatches_sum = 0
-        last_step = None
-        for key, entry in sorted(detail.items()):
-            rows = int(entry.get("rows", 0))
-            per_row = int(entry.get("flops_per_row", 0))
-            flops = int(entry.get("flops", 0))
-            if flops != per_row * rows:
-                problems.append(
-                    "'Compute stages:' %s: flops=%d != flops_per_row"
-                    "=%d x rows=%d (achieved FLOPs are per-row counts "
-                    "times the rows actually dispatched)"
-                    % (key, flops, per_row, rows))
-            if min(rows, per_row, int(entry.get("dispatches", 0)),
-                   int(entry.get("busy_us", 0))) < 0:
-                problems.append("'Compute stages:' %s carries a "
-                                "negative counter" % key)
-            mfu_busy = entry.get("mfu_busy")
-            if mfu_busy is not None and float(mfu_busy) > 1.0001:
-                problems.append(
-                    "'Compute stages:' %s: mfu_busy=%s exceeds 1 — a "
-                    "stage cannot beat the device's peak; the "
-                    "declared FLOPs or the peak table is wrong"
-                    % (key, mfu_busy))
-            flops_sum += flops
-            dispatches_sum += int(entry.get("dispatches", 0))
-            step = int(key[4:])
-            if last_step is None or step > last_step:
-                last_step = step
-                last_rows = rows
-        if flops_sum != meta.get("compute_flops_total", 0):
-            problems.append(
-                "'Compute stages:' flops sum to %d but the 'Compute:' "
-                "line says flops_total=%d" % (
-                    flops_sum, meta.get("compute_flops_total", 0)))
-        if dispatches_sum != meta.get("compute_dispatches", 0):
-            problems.append(
-                "'Compute stages:' dispatches sum to %d but the "
-                "'Compute:' line says dispatches=%d" % (
-                    dispatches_sum, meta.get("compute_dispatches", 0)))
-        if detail and last_rows != meta.get("compute_rows", 0):
-            problems.append(
-                "'Compute:' rows=%d but the last flops-bearing stage "
-                "dispatched %d row(s) (the job row count is the final "
-                "stage's — the completed clips)"
-                % (meta.get("compute_rows", 0), last_rows))
-        if meta.get("compute_mfu_e4", 0) > 10000:
-            problems.append(
-                "compute_mfu_e4=%d exceeds 10000 (MFU > 1: the job "
-                "cannot beat the device peak)"
-                % meta.get("compute_mfu_e4", 0))
-        window_s = meta.get("compute_window_us", 0) / 1e6
-        if detail and window_s > 0:
-            # tflops_milli is fully derivable offline: rows/s x the
-            # summed per-row FLOPs, in the writer's exact expression
-            # order and rounding (±1 milli absorbs the window_us
-            # integer rounding) — a cooked headline number cannot
-            # survive the check
-            flops_per_clip = float(sum(
-                int(entry.get("flops_per_row", 0))
-                for entry in detail.values()))
-            tflops = (meta.get("compute_rows", 0) / window_s) \
-                * flops_per_clip / 1e12
-            want_milli = int(round(round(tflops, 3) * 1000))
-            if abs(int(meta.get("compute_tflops_milli", 0))
-                   - want_milli) > 1:
-                problems.append(
-                    "'Compute:' tflops_milli=%s but rows/window x "
-                    "per-row flops recompute to %d"
-                    % (meta.get("compute_tflops_milli"), want_milli))
-        if "wall_time_s" in meta \
-                and abs(meta.get("compute_window_us", 0) / 1e6
-                        - float(meta["wall_time_s"])) > 0.01:
-            problems.append(
-                "'Compute:' window_us=%d disagrees with the measured "
-                "wall time %.6f s (the compute window IS the measured "
-                "window)" % (meta.get("compute_window_us", 0),
-                             meta["wall_time_s"]))
-        if len(captures) != meta.get("compute_captures", 0):
-            problems.append(
-                "'Compute:' line says captures=%d but the job dir "
-                "holds %d capture artifact(s): %s"
-                % (meta.get("compute_captures", 0), len(captures),
-                   captures))
-    # -- Memory: footing ----------------------------------------------
-    if "memory_total_bytes" in meta:
-        detail = {key: dict(val) for key, val
-                  in dict(meta.get("memory_owner_detail", {})).items()}
-        if len(detail) != meta.get("memory_owners", 0):
-            problems.append(
-                "'Memory owners:' names %d owner(s) but the 'Memory:' "
-                "line says owners=%d"
-                % (len(detail), meta.get("memory_owners", 0)))
-        _rnb_trace()  # side effect: repo checkout on sys.path
-        from rnb_tpu.memledger import MEM_OWNERS
-        rogue = sorted(set(detail) - set(MEM_OWNERS))
-        if rogue:
-            problems.append(
-                "'Memory owners:' names undeclared owner(s) %s — "
-                "owners are declared in memledger.MEM_OWNER_REGISTRY"
-                % rogue)
-        owner_sum = 0
-        for owner, entry in sorted(detail.items()):
-            nbytes = int(entry.get("bytes", 0))
-            peak = int(entry.get("peak_bytes", 0))
-            if nbytes < 0 or peak < 0:
-                problems.append("'Memory owners:' %s carries negative "
-                                "bytes" % owner)
-            if peak < nbytes:
-                problems.append(
-                    "'Memory owners:' %s: peak_bytes=%d below final "
-                    "bytes=%d (the high-water mark covers every "
-                    "sample, the final one included)"
-                    % (owner, peak, nbytes))
-            owner_sum += nbytes
-        if owner_sum != meta.get("memory_total_bytes", 0):
-            problems.append(
-                "'Memory owners:' bytes sum to %d but the 'Memory:' "
-                "line says total_bytes=%d (owner rows must foot to "
-                "the ledger total)"
-                % (owner_sum, meta.get("memory_total_bytes", 0)))
-        if meta.get("memory_peak_bytes", 0) \
-                < meta.get("memory_total_bytes", 0):
-            problems.append(
-                "memory_peak_bytes=%d below memory_total_bytes=%d "
-                "(peak >= final by construction)"
-                % (meta.get("memory_peak_bytes", 0),
-                   meta.get("memory_total_bytes", 0)))
-        if meta.get("memory_watermark_hits", 0) > 0:
-            if meta.get("memory_watermark_bytes", 0) <= 0:
-                problems.append(
-                    "memory_watermark_hits=%d with no configured "
-                    "watermark" % meta["memory_watermark_hits"])
-            elif meta.get("memory_peak_bytes", 0) \
-                    < meta.get("memory_watermark_bytes", 0):
-                problems.append(
-                    "memory_watermark_hits=%d but the peak %d never "
-                    "reached the %d-byte watermark"
-                    % (meta["memory_watermark_hits"],
-                       meta.get("memory_peak_bytes", 0),
-                       meta.get("memory_watermark_bytes", 0)))
-        if meta.get("memory_reconciled", 0) not in (0, 1):
-            problems.append("memory_reconciled must be 0 or 1, got %s"
-                            % meta.get("memory_reconciled"))
-        if meta.get("memory_reconciled", 0) == 1 \
-                and meta.get("memory_live_bytes", 0) <= 0:
-            problems.append(
-                "memory_reconciled=1 with live_bytes=0 (a reconcile "
-                "verdict needs the backend's live-buffer total)")
-        if meta.get("memory_live_bytes", 0) > 0 \
-                and meta.get("memory_reconciled", 0) != 1:
-            problems.append(
-                "live_bytes=%d but reconciled=0 — the ledger's "
-                "live-backed claims exceed the backend's own live "
-                "buffers (the ledger is lying about device memory)"
-                % meta.get("memory_live_bytes", 0))
-    for name_ in captures:
-        problems.extend(
-            _check_capture_artifact(os.path.join(job_dir, name_)))
-    return problems
-
-
-def _rnb_critpath():
-    """Import :mod:`rnb_tpu.critpath` from the repo checkout this
-    script sits in (same rule as :func:`_rnb_trace`: the chain rules
-    live next to the runtime so online and offline can never
-    diverge)."""
-    _rnb_trace()
-    from rnb_tpu import critpath
-    return critpath
-
-
-def _config_lanes(job_dir: str) -> Dict[int, int]:
-    """{step: executor instances} from the config copy benchmark.py
-    drops into the job dir — delegated to rnb_tpu.whatif's config
-    reader + per-step lane rule so the critpath bound recompute and
-    the what-if calibration can never count lanes differently; {}
-    when no config copy is found."""
-    _rnb_trace()
-    from rnb_tpu import whatif as whatif_mod
-    raw = whatif_mod.job_config(job_dir)
-    if raw is None:
-        return {}
-    return {step: int(info["lanes"]) for step, info
-            in whatif_mod.steps_info_from_config(raw).items()}
-
-
-def _parsed_tables(tables: List[str]):
-    """[(path, DataFrame)] for the tables that parse — the shared
-    one-parse input of the critpath recompute + partition loop."""
-    out = []
-    for path in tables:
-        try:
-            out.append((path, parse_timing_table(path)))
-        except (OSError, ValueError):
-            continue
-    return out
-
-
-def _recompute_critpath(job_dir: str, tables: List[str],
-                        num_skips: int, parsed=None):
-    """The offline twin of the launcher's Critpath: aggregation:
-    blocking chains over every table's steady rows (hedge/redispatch
-    content stamps are not persisted in tables, so those two counters
-    stay run-side-only). ``parsed`` reuses already-parsed frames
-    (one parse per table in the composed --check path). -> aggregate
-    report or None."""
-    critpath = _rnb_critpath()
-    if parsed is None:
-        parsed = _parsed_tables(tables)
-
-    def rows():
-        for _path, df in parsed:
-            time_cols = _table_time_cols(df)
-            for row in df.iloc[num_skips:][time_cols].itertuples(
-                    index=False):
-                timings = {k: t for k, t in zip(time_cols, row)
-                           if t == t}
-                if len(timings) >= 2:
-                    yield (timings, False, 0)
-
-    return critpath.aggregate(rows(), _config_lanes(job_dir))
-
-
-def _check_critpath(job_dir: str, meta: Dict[str, object],
-                    tables: List[str]) -> List[str]:
-    problems: List[str] = []
-    try:
-        critpath = _rnb_critpath()
-        num_skips = _summary_skips()
-    except Exception as e:  # noqa: BLE001 — surfaced, not hidden
-        return ["critpath check unavailable (rnb_tpu unimportable): "
-                "%s" % e]
-    # partition invariant over EVERY row of every table (warm records
-    # included), on ANY job dir: the blocking chain must sum to the
-    # end-to-end span within 1 ms. Like the phases twin above, this
-    # guards the EXTRACTOR, not the data — the sum telescopes only
-    # while blocking_chain keeps every adjacent gap, so a future
-    # classifier change that drops/filters segments fails here on
-    # every existing log instead of silently under-attributing
-    saw_critpath_trailer = False
-    parsed = _parsed_tables(tables)  # unparsable: reported above
-    for path, df in parsed:
-        base = os.path.basename(path)
-        time_cols = _table_time_cols(df)
-        for row in df[time_cols].itertuples(index=False):
-            timings = {k: t for k, t in zip(time_cols, row) if t == t}
-            if len(timings) < 2:
-                continue
-            chain = critpath.blocking_chain(timings)
-            e2e_ms = (max(timings.values())
-                      - min(timings.values())) * 1e3
-            total = sum(ms for _c, _s, ms in chain)
-            if abs(total - e2e_ms) > 1.0:
-                problems.append(
-                    "%s: a request's blocking chain sums to %.3f ms "
-                    "but its end-to-end latency is %.3f ms (chain "
-                    "segments must partition the span)"
-                    % (base, total, e2e_ms))
-                break  # one report per table is enough
-        trailer = parse_table_trailers(path).get("critpath")
-        if trailer is None:
-            continue
-        saw_critpath_trailer = True
-        n, totals = critpath.trailer_totals(
-            {k: t for k, t in zip(time_cols, row) if t == t}
-            for row in df.iloc[num_skips:][time_cols].itertuples(
-                index=False))
-        if trailer.get("n") != n:
-            problems.append(
-                "%s: '# critpath' trailer says n=%s but the table "
-                "holds %d steady decomposable row(s)"
-                % (base, trailer.get("n"), n))
-        for key, want in sorted(totals.items()):
-            got = trailer.get("%s_us" % key)
-            if got is None or abs(got - want) > 1000:
-                problems.append(
-                    "%s: '# critpath' trailer %s_us=%s but the "
-                    "table's rows recompute to %d"
-                    % (base, key, got, want))
-    if "critpath_requests" not in meta:
-        if "critpath_stage_detail" in meta:
-            problems.append("log-meta carries a 'Critpath stages:' "
-                            "line but no 'Critpath:' totals line")
-        if saw_critpath_trailer:
-            problems.append("tables carry a '# critpath' trailer but "
-                            "log-meta has no 'Critpath:' line")
-        return problems
-    if not saw_critpath_trailer and tables:
-        problems.append("log-meta carries a 'Critpath:' line but no "
-                        "table carries a '# critpath' trailer")
-    for key in ("critpath_requests", "critpath_segments",
-                "critpath_hedged", "critpath_redispatched",
-                "critpath_bound_vps_milli"):
-        if meta.get(key, 0) < 0:
-            problems.append("negative %s" % key)
-    if meta.get("critpath_residual_us_max", 0) > 1000:
-        problems.append(
-            "critpath_residual_us_max=%d exceeds 1000 us — a "
-            "request's blocking chain failed to partition its "
-            "end-to-end span" % meta["critpath_residual_us_max"])
-    if meta.get("critpath_hedged", 0) > meta.get("critpath_requests",
-                                                 0):
-        problems.append(
-            "critpath_hedged=%d exceeds critpath_requests=%d (a "
-            "hedge-won completion is still one completion)"
-            % (meta["critpath_hedged"], meta["critpath_requests"]))
-    recomputed = _recompute_critpath(job_dir, tables, num_skips,
-                                     parsed=parsed)
-    if recomputed is None:
-        problems.append("log-meta carries a 'Critpath:' line but no "
-                        "table row decomposes into a blocking chain")
-        return problems
-    for key in ("requests", "segments", "bound_step"):
-        if meta.get("critpath_" + key) != recomputed[key]:
-            problems.append(
-                "'Critpath:' %s=%s but the tables recompute %s"
-                % (key, meta.get("critpath_" + key), recomputed[key]))
-    if abs(meta.get("critpath_bound_vps_milli", 0)
-           - recomputed["bound_vps_milli"]) > 1:
-        problems.append(
-            "'Critpath:' bound_vps_milli=%s but the tables recompute "
-            "%d" % (meta.get("critpath_bound_vps_milli"),
-                    recomputed["bound_vps_milli"]))
-    detail = {key: dict(val) for key, val
-              in dict(meta.get("critpath_stage_detail", {})).items()}
-    want_detail = recomputed["stage_detail"]
-    if set(detail) != set(want_detail):
-        problems.append(
-            "'Critpath stages:' names %s but the tables recompute %s"
-            % (sorted(detail), sorted(want_detail)))
-        return problems
-    for step_key in sorted(detail):
-        got, want = detail[step_key], want_detail[step_key]
-        got_classes = dict(got.get("classes", {}))
-        want_classes = dict(want.get("classes", {}))
-        if set(got_classes) != set(want_classes):
-            problems.append(
-                "'Critpath stages:' %s classes %s but the tables "
-                "recompute %s" % (step_key, sorted(got_classes),
-                                  sorted(want_classes)))
-            continue
-        for cls in sorted(want_classes):
-            for stat in ("total_ms", "mean_ms"):
-                got_v = dict(got_classes[cls]).get(stat)
-                want_v = dict(want_classes[cls])[stat]
-                if got_v is None or abs(float(got_v)
-                                        - float(want_v)) > 0.005:
-                    problems.append(
-                        "'Critpath stages:' %s %s %s=%s but the "
-                        "tables recompute %.3f"
-                        % (step_key, cls, stat, got_v, want_v))
-    return problems
-
-
-def _check_whatif(job_dir: str, meta: Dict[str, object]) -> List[str]:
-    problems: List[str] = []
-    if "whatif_stages" not in meta:
-        return problems
-    if meta.get("whatif_calibrated") not in (0, 1):
-        problems.append("whatif_calibrated must be 0 or 1, got %s"
-                        % meta.get("whatif_calibrated"))
-    if "metrics_snapshots" not in meta:
-        problems.append("log-meta carries a 'Whatif:' line but no "
-                        "'Metrics:' line — the what-if engine "
-                        "calibrates from the metrics plane")
-        return problems
-    if meta.get("whatif_calibrated") != 1:
-        if meta.get("whatif_pred_vps_milli", 0) != 0:
-            problems.append(
-                "whatif_pred_vps_milli=%s with calibrated=0 (an "
-                "uncalibrated model must not predict)"
-                % meta.get("whatif_pred_vps_milli"))
-        return problems
-    # reproducibility: the line must recompute from the artifacts
-    # alone (metrics.jsonl final snapshot + config copy)
-    _rnb_trace()
-    from rnb_tpu import whatif as whatif_mod
-    model = whatif_mod.calibrate_job(job_dir)
-    recomputed = whatif_mod.summary_counters(model)
-    for key in ("stages", "calibrated", "bottleneck_step"):
-        if meta.get("whatif_" + key) != recomputed[key]:
-            problems.append(
-                "'Whatif:' %s=%s but metrics.jsonl + the config copy "
-                "recompute %s (the explanation must be reproducible "
-                "from the artifacts)" % (key, meta.get("whatif_" + key),
-                                         recomputed[key]))
-    if abs(meta.get("whatif_pred_vps_milli", 0)
-           - recomputed["pred_vps_milli"]) > 1:
-        problems.append(
-            "'Whatif:' pred_vps_milli=%s but metrics.jsonl + the "
-            "config copy recompute %d"
-            % (meta.get("whatif_pred_vps_milli"),
-               recomputed["pred_vps_milli"]))
-    return problems
-
-
-#: sampler-cadence tolerance: the tick count of a wait()-paced loop
-#: can never exceed sample_hz x elapsed by much (slack for the short
-#: post-window drain to thread join), and on a loaded 1-core host the
-#: GIL can stretch individual waits — the lower bound is deliberately
-#: loose
-_STACKS_UPPER_SLACK = 1.5
-_STACKS_LOWER_FRAC = 0.2
-_STACKS_ABS_SLACK = 25
-
-
-def _config_operator(job_dir: str):
-    """The job's declared ``operator`` spec from the config copy
-    benchmark.py drops into the job dir, or None when no config copy
-    declares an enabled one."""
-    import json
-    for name in sorted(os.listdir(job_dir)):
-        if not name.endswith(".json"):
-            continue
-        try:
-            with open(os.path.join(job_dir, name)) as f:
-                raw = json.load(f)
-        except (OSError, ValueError):
-            continue
-        if not isinstance(raw, dict) or "pipeline" not in raw:
-            continue
-        operator = raw.get("operator")
-        if isinstance(operator, dict) \
-                and operator.get("enabled", True):
-            return operator
-        return None
-    return None
-
-
-def _check_operator(job_dir: str,
-                    meta: Dict[str, object]) -> List[str]:
-    """Operator-plane invariants (rnb_tpu.statusz /
-    rnb_tpu.stacksampler): the request ledger agrees with the
-    operator.json artifact both ways, the folded-stack artifact
-    re-sums to the Stacks: total, and the sampler cadence tracks
-    sample_hz x wall."""
-    import json
-    problems: List[str] = []
-    op_path = os.path.join(job_dir, "operator.json")
-    folded_path = os.path.join(job_dir, "stacks.folded")
-    if "operator_scrapes" not in meta:
-        if os.path.isfile(op_path):
-            problems.append("operator.json present but log-meta has "
-                            "no 'Operator:' line")
-        if "stacks_samples" in meta:
-            problems.append("log-meta carries a 'Stacks:' line but no "
-                            "'Operator:' line (the sampler rides the "
-                            "operator key)")
-        if os.path.isfile(folded_path):
-            problems.append("stacks.folded present but log-meta has "
-                            "no 'Stacks:' line")
-        return problems
-    for key in ("operator_scrapes", "operator_actions",
-                "operator_denied", "operator_errors"):
-        if int(meta.get(key, 0)) < 0:
-            problems.append("negative %s" % key)
-    if not os.path.isfile(op_path):
-        problems.append("log-meta carries an 'Operator:' line but "
-                        "operator.json is missing — the bound address "
-                        "record must ship with the ledger")
-    else:
-        try:
-            with open(op_path) as f:
-                record = json.load(f)
-        except (OSError, ValueError) as e:
-            problems.append("operator.json unreadable: %s" % e)
-            record = None
-        if record is not None:
-            port = record.get("port")
-            if not isinstance(port, int) or not 1 <= port <= 65535:
-                problems.append("operator.json carries no bound port "
-                                "(got %r) — port 0 must be resolved "
-                                "to the ephemeral port at bind time"
-                                % (port,))
-            if not record.get("host"):
-                problems.append("operator.json names no host")
-    # -- the stack sampler ---------------------------------------------
-    if "stacks_samples" not in meta:
-        if os.path.isfile(folded_path):
-            problems.append("stacks.folded present but log-meta has "
-                            "no 'Stacks:' line")
-        return problems
-    for key in ("stacks_samples", "stacks_threads", "stacks_folded",
-                "stacks_total"):
-        if int(meta.get(key, 0)) < 0:
-            problems.append("negative %s" % key)
-    if not os.path.isfile(folded_path):
-        problems.append("log-meta carries a 'Stacks:' line but "
-                        "stacks.folded is missing")
-    else:
-        total = 0
-        stacks = 0
-        roles = set()
-        bad_lines = 0
-        with open(folded_path) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                stack, _, count = line.rpartition(" ")
-                if not stack or not count.lstrip("-").isdigit():
-                    bad_lines += 1
-                    continue
-                stacks += 1
-                total += int(count)
-                roles.add(stack.split(";", 1)[0])
-        if bad_lines:
-            problems.append("stacks.folded holds %d unparsable "
-                            "line(s) (want 'role;frame;... count')"
-                            % bad_lines)
-        if stacks != meta.get("stacks_folded"):
-            problems.append(
-                "stacks.folded holds %d folded stack(s) but the "
-                "'Stacks:' line says folded=%s"
-                % (stacks, meta.get("stacks_folded")))
-        if total != meta.get("stacks_total"):
-            problems.append(
-                "stacks.folded counts sum to %d but the 'Stacks:' "
-                "line says total=%s (every sample must fold exactly "
-                "once)" % (total, meta.get("stacks_total")))
-        if len(roles) != meta.get("stacks_threads"):
-            problems.append(
-                "stacks.folded names %d role(s) but the 'Stacks:' "
-                "line says threads=%s"
-                % (len(roles), meta.get("stacks_threads")))
-    # every folded stack was observed at least once (counts >= 1), so
-    # the distinct-stack count can never exceed the sample total.
-    # (total vs samples x threads is deliberately NOT bounded: several
-    # pool workers collapse onto one role — rnb-decode, rnb-transfer —
-    # so one tick may legally contribute many samples to one role.)
-    samples = int(meta.get("stacks_samples", 0))
-    if int(meta.get("stacks_folded", 0)) \
-            > int(meta.get("stacks_total", 0)):
-        problems.append(
-            "stacks_folded=%s exceeds stacks_total=%s (every distinct "
-            "stack was sampled at least once)"
-            % (meta.get("stacks_folded"), meta.get("stacks_total")))
-    # cadence: samples ~ sample_hz x measured wall within tolerance
-    operator = _config_operator(job_dir)
-    wall = meta.get("wall_time_s")
-    if operator is not None and isinstance(wall, float) and wall > 0:
-        hz = operator.get("sample_hz")
-        if hz is None:
-            _rnb_trace()  # ensure the repo checkout is importable
-            from rnb_tpu.stacksampler import DEFAULT_SAMPLE_HZ
-            hz = DEFAULT_SAMPLE_HZ
-        hz = float(hz)
-        if hz > 0:
-            expected = hz * wall
-            upper = expected * _STACKS_UPPER_SLACK + _STACKS_ABS_SLACK
-            lower = max(0.0, expected * _STACKS_LOWER_FRAC
-                        - _STACKS_ABS_SLACK)
-            if samples > upper:
-                problems.append(
-                    "stacks_samples=%d far exceeds sample_hz x wall "
-                    "= %.1f (upper tolerance %.1f) — the sampler "
-                    "cannot tick faster than its wait loop"
-                    % (samples, expected, upper))
-            if samples < lower:
-                problems.append(
-                    "stacks_samples=%d falls far below sample_hz x "
-                    "wall = %.1f (lower tolerance %.1f) — the "
-                    "sampler stalled or died mid-run"
-                    % (samples, expected, lower))
-    return problems
-
-
 def _configured_buckets(job_dir: str) -> set:
     """Every row count the job's config could legally warm: the union
     of ``row_buckets`` / ``max_clips`` / ``max_rows`` values across
@@ -2747,9 +1728,7 @@ def print_stamp_registry(out=None) -> None:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in _sys.path:
         _sys.path.insert(0, repo)
-    from rnb_tpu.memledger import MEM_OWNER_REGISTRY
-    from rnb_tpu.telemetry import (META_LINE_REGISTRY, METRIC_REGISTRY,
-                                   STAMP_REGISTRY,
+    from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAMP_REGISTRY,
                                    TABLE_TRAILER_REGISTRY,
                                    TRACE_EVENT_REGISTRY, CONTENT_STAMPS)
     out.write("# Telemetry schema reference (generated by "
@@ -2779,19 +1758,6 @@ def print_stamp_registry(out=None) -> None:
     for spec in TRACE_EVENT_REGISTRY:
         out.write("%-26s %-22s %s\n" % (spec.pattern, spec.producer,
                                         spec.description))
-    out.write("\n## Live-metric series (logs/<job>/metrics.jsonl + "
-              "metrics.prom,\n## metrics-enabled runs only; kind/"
-              "source per rnb_tpu.telemetry.MetricSpec)\n")
-    for spec in METRIC_REGISTRY:
-        out.write("%-26s %-10s %-7s %s\n"
-                  % (spec.pattern, spec.kind, spec.source,
-                     spec.description))
-    out.write("\n## HBM-ledger owners (the 'Memory owners:' line's "
-              "keys,\n## devobs-enabled runs only; declared in "
-              "rnb_tpu.memledger)\n")
-    for spec in MEM_OWNER_REGISTRY:
-        out.write("%-26s %-22s %s\n" % (spec.name, spec.producer,
-                                        spec.description))
 
 
 def main(argv=None) -> int:
@@ -2811,13 +1777,6 @@ def main(argv=None) -> int:
                              "per-phase mean/p99 table derived from "
                              "TimeCard stamps alone and verify phases "
                              "sum to end-to-end latency")
-    parser.add_argument("--explain", action="store_true",
-                        help="blocking-chain explanation: ranked "
-                             "blocked time per (class, step) segment, "
-                             "per-stage critical-path throughput "
-                             "bounds, and calibrated what-if "
-                             "counterfactuals when the job streamed "
-                             "metrics")
     args = parser.parse_args(argv)
     if args.stamps:
         print_stamp_registry()
@@ -2826,12 +1785,9 @@ def main(argv=None) -> int:
         parser.error("job_dirs required unless --stamps is given")
     status = 0
     for job_dir in args.job_dirs:
-        # --attribute/--explain/--check compose: all run, worst
-        # status wins
+        # --attribute/--check compose: both run, worst status wins
         if args.attribute:
             status = max(status, print_attribution(job_dir))
-        if args.explain:
-            status = max(status, print_explanation(job_dir))
         if args.check:
             # exit discipline matches the rnb-lint CLI: 2 = the
             # artifacts could not be parsed (the check never ran), 1 =
@@ -2844,7 +1800,7 @@ def main(argv=None) -> int:
                     print("  - %s" % problem)
             else:
                 print("%s: OK" % job_dir)
-        if not args.attribute and not args.explain and not args.check:
+        if not args.attribute and not args.check:
             meta, df = get_data(job_dir)
             print("%s: %d requests" % (job_dir, len(df)))
             for key in sorted(meta):

@@ -75,21 +75,6 @@ def main():
               % (name, row["ok"], row["wall_s"],
                  row.get("error", "")), flush=True)
 
-    # evidence-log pointers (the bench_diff --explain convention) are
-    # curated by hand on committed rows, never produced by a sweep —
-    # carry them over from the existing artifact so a regeneration
-    # cannot silently disable the regression-attribution wiring
-    evidence = {}
-    if os.path.exists(OUT_PATH):
-        with open(OUT_PATH) as f:
-            prior = json.load(f)
-        evidence = {r["config"]: r["evidence_logs"]
-                    for r in prior.get("configs", [])
-                    if r.get("config") and r.get("evidence_logs")}
-    for row in rows:
-        if row["config"] in evidence:
-            row["evidence_logs"] = evidence[row["config"]]
-
     if args.only is not None and os.path.exists(OUT_PATH):
         # merge a partial sweep into the existing artifact by config
         # name (e.g. one newly added config without re-running all);

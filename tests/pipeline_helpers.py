@@ -154,49 +154,6 @@ class HoardingSink(StageModel):
         return None, non_tensors, time_card
 
 
-class TinyComputeSink(StageModel):
-    """Final stage with a tiny jitted matmul plus the devobs compute/
-    memory seam (compute_profile): the declared per-row FLOPs are the
-    matmul's 2*F*F MACs, the 'params' footprint is the weight matrix,
-    so test_devobs can check MFU/ledger arithmetic against hand
-    computation while the jit guarantees a capture window sees XLA
-    ops."""
-
-    FLOPS_PER_ROW = 2 * SHAPE[1] * SHAPE[1]
-
-    def __init__(self, device, **kwargs):
-        super().__init__(device)
-        import jax
-        self._w = jax.device_put(
-            np.eye(SHAPE[1], dtype=np.float32))
-        self._apply = jax.jit(lambda x, w: x @ w)
-        jax.block_until_ready(
-            self._apply(np.zeros(SHAPE, np.float32), self._w))
-        self.seen = []
-
-    def compute_profile(self):
-        return {
-            "flops_per_row": self.FLOPS_PER_ROW,
-            "devices": 1,
-            "bytes_per_row": float(SHAPE[1] * 4 * 2),
-            "params_key": ("tiny-w", SHAPE[1]),
-            "params_bytes": int(self._w.nbytes),
-            "pool_bytes": 0,
-        }
-
-    @staticmethod
-    def output_shape():
-        return None
-
-    def __call__(self, tensors, non_tensors, time_card):
-        if tensors is not None:
-            import jax
-            out = self._apply(
-                np.asarray(tensors[0].data, np.float32), self._w)
-            self.seen.append(np.asarray(jax.block_until_ready(out)))
-        return None, non_tensors, time_card
-
-
 class CountingPathIterator(VideoPathIterator):
     """Yields synthetic request ids forever: video-0, video-1, ..."""
 

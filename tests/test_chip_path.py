@@ -99,14 +99,6 @@ def test_chip_smoke_result_line_holds_exactly_the_contract_keys():
     assert len(prints) == 2
 
 
-def test_bench_refuses_the_cpu_unless_asked():
-    proc = _run(["bench.py"])
-    assert proc.returncode == 1
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["value"] is None
-    assert "platform 'cpu'" in line["error"]
-
-
 def test_benchmark_cli_refuses_the_cpu_unless_asked():
     proc = _run(["-m", "rnb_tpu.benchmark", "-c",
                  "configs/r2p1d-tiny.json", "-v", "2"])
@@ -228,22 +220,6 @@ def test_missing_native_library_stops_a_tpu_loader(monkeypatch):
     native.require_native("tpu")  # asked for by name
 
 
-def test_devobs_worker_failure_fails_the_run(monkeypatch):
-    """A capture that cannot start used to leave a run that exited 0
-    with no capture and no reason."""
-    from rnb_tpu import profiler
-    from rnb_tpu.devobs import DevObsPlane, DevObsSettings
-
-    def no_profiler(trace_dir=None):
-        raise OSError("profiler service unavailable")
-    monkeypatch.setattr(profiler, "initialize", no_profiler)
-    plane = DevObsPlane(DevObsSettings(capture_window_ms=5))
-    plane.start()
-    plane.note_run_started()
-    with pytest.raises(RuntimeError, match="devobs worker died"):
-        plane.stop()
-
-
 # -- one process per chip ----------------------------------------------
 
 def test_netedge_peer_environment_pins_the_cpu(monkeypatch):
@@ -254,15 +230,6 @@ def test_netedge_peer_environment_pins_the_cpu(monkeypatch):
     assert env["JAX_PLATFORMS"] == "cpu"
     assert env["RNB_FAULT_PLAN"] == "{}"  # both sides, one fault plan
     assert env["PYTHONPATH"].split(os.pathsep)[0] == REPO
-
-
-def test_orchestrating_parents_stay_off_jax():
-    """bench_matrix and latency_frontier hand the chip to one bench.py
-    child at a time; importing them must not import JAX."""
-    code = ("import sys; sys.path.insert(0, 'scripts'); "
-            "import bench_matrix, latency_frontier; "
-            "sys.exit('jax' in sys.modules)")
-    assert _run(["-c", code]).returncode == 0
 
 
 def test_host_stages_survive_an_accelerator_only_platform_list():
@@ -332,7 +299,7 @@ def test_code_is_written_for_the_installed_jax():
     gone = ("jax.experimental." "shard_map", "check_" "rep=",
             "pltpu." "ANY", "RNB_COMPILE_" "CACHE_DIR")
     hits = []
-    for root in ("rnb_tpu", "scripts", "bench.py", "chip_smoke.py",
+    for root in ("rnb_tpu", "scripts", "chip_smoke.py",
                  "__graft_entry__.py"):
         root = os.path.join(REPO, root)
         paths = [root] if os.path.isfile(root) else [

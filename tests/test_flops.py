@@ -1,8 +1,9 @@
 """The analytic FLOP counter must track the network it describes.
 
 Cross-checks rnb_tpu/models/r2p1d/flops.py against XLA's own
-``cost_analysis()`` of the compiled program so the MFU numbers bench.py
-publishes cannot silently drift from the real compute.
+``cost_analysis()`` of the compiled program so the count the
+benchmark's harness is held to (``benchmarks/tests/test_manifest.py``)
+cannot silently drift from the real compute.
 
 Counting conventions differ at the margins: the analytic walk counts
 2 FLOPs per MAC over every conv window position (that is the work the

@@ -293,89 +293,6 @@ def test_dead_trace_registry_entry():
         == {("RNB-T003", "ghost.event")}
 
 
-_T009_REGISTRY = None
-
-
-def _t009_registry():
-    from rnb_tpu.telemetry import MetricSpec
-    return (MetricSpec("good.requests", "counter", "site", "f"),
-            MetricSpec("good.depth", "gauge", "site", "f"),
-            MetricSpec("good.latency", "histogram", "site", "f"),
-            MetricSpec("good.arrivals", "rate", "site", "f"),
-            MetricSpec("good.e{step}.depth", "gauge", "site", "f"))
-
-
-def test_metric_fixture_is_clean():
-    from rnb_tpu.analysis.schema import check_metric_names
-    findings = check_metric_names([_fixture("good_t009_metrics.py")],
-                                  root=FIXTURES,
-                                  registry=_t009_registry())
-    assert findings == [], [f.render() for f in findings]
-
-
-def test_unregistered_metric_triggers_t009():
-    from rnb_tpu.analysis.schema import check_metric_names
-    findings = check_metric_names([_fixture("bad_t009_metrics.py")],
-                                  root=FIXTURES,
-                                  registry=_t009_registry())
-    assert {(f.rule, f.anchor) for f in findings} \
-        == {("RNB-T009", "mystery.series")}
-
-
-def test_dead_site_metric_registry_entry():
-    # a registered SITE-sourced metric no call site emits is an
-    # RNB-T003 dead entry; bridge/poll/derived entries have no call
-    # sites by design and must NOT be flagged
-    from rnb_tpu.analysis.schema import check_metric_names
-    from rnb_tpu.telemetry import MetricSpec
-    registry = _t009_registry() + (
-        MetricSpec("ghost.series", "counter", "site", "never emitted"),
-        MetricSpec("bridged.series", "histogram", "bridge", "no site"),
-        MetricSpec("polled.series", "counter", "poll", "no site"),
-        MetricSpec("derived.series", "gauge", "derived", "no site"))
-    findings = check_metric_names([_fixture("good_t009_metrics.py")],
-                                  root=FIXTURES, registry=registry)
-    assert {(f.rule, f.anchor) for f in findings} \
-        == {("RNB-T003", "ghost.series")}
-
-
-def _devobs_metric_registry():
-    from rnb_tpu.telemetry import MetricSpec
-    return (MetricSpec("compute.s{step}.tflops", "gauge", "poll", "f"),
-            MetricSpec("compute.s{step}.rows", "counter", "poll", "f"),
-            MetricSpec("memory.total_bytes", "gauge", "poll", "f"),
-            MetricSpec("memory.cache_bytes", "gauge", "poll", "f"))
-
-
-def test_devobs_metric_fixture_is_clean():
-    # the RNB-T009 family covers the compute.*/memory.* vocabulary:
-    # the good fixture emits exactly the declared devobs series
-    from rnb_tpu.analysis.schema import check_metric_names
-    findings = check_metric_names([_fixture("good_t009_devobs.py")],
-                                  root=FIXTURES,
-                                  registry=_devobs_metric_registry())
-    assert findings == [], [f.render() for f in findings]
-
-
-def test_unregistered_devobs_metric_triggers_t009():
-    from rnb_tpu.analysis.schema import check_metric_names
-    findings = check_metric_names([_fixture("bad_t009_devobs.py")],
-                                  root=FIXTURES,
-                                  registry=_devobs_metric_registry())
-    assert {(f.rule, f.anchor) for f in findings} \
-        == {("RNB-T009", "compute.s0.mystery")}
-
-
-def test_repo_metric_names_all_registered():
-    # the real tree: every emitted metric series name is declared and
-    # every declared site-sourced name is still emitted somewhere
-    from rnb_tpu.analysis.findings import package_py_files
-    from rnb_tpu.analysis.schema import check_metric_names
-    findings = check_metric_names(
-        package_py_files(os.path.join(REPO, "rnb_tpu")), root=REPO)
-    assert findings == [], [f.render() for f in findings]
-
-
 def test_repo_trace_events_all_registered():
     # the real tree: every emitted trace event name is declared and
     # every declared name is still emitted somewhere
@@ -429,17 +346,6 @@ def test_unregistered_meta_line_triggers_t004(tmp_path):
                      'f.write("Hedge: fired=%d\\n" % hg)\n'
                      'f.write("Compiles: %s\\n" % c)\n'
                      'f.write("Warmup: %s\\n" % w)\n'
-                     'f.write("Metrics: snapshots=%d\\n" % ms)\n'
-                     'f.write("Slo: tracked=%d\\n" % sl)\n'
-                     'f.write("Compute: stages=%d\\n" % cp)\n'
-                     'f.write("Compute stages: %s\\n" % cs)\n'
-                     'f.write("Memory: owners=%d\\n" % mb)\n'
-                     'f.write("Memory owners: %s\\n" % mo)\n'
-                     'f.write("Critpath: requests=%d\\n" % cr)\n'
-                     'f.write("Critpath stages: %s\\n" % ct)\n'
-                     'f.write("Whatif: stages=%d\\n" % wi)\n'
-                     'f.write("Operator: scrapes=%d\\n" % op)\n'
-                     'f.write("Stacks: samples=%d\\n" % st)\n'
                      'f.write("Net: frames_sent=%d\\n" % nt)\n'
                      'f.write("Net errors: total=%d\\n" % ne)\n'
                      'f.write("Pages: allocs=%d\\n" % pg)\n'
@@ -467,7 +373,7 @@ def test_unparsed_meta_line_triggers_t005(tmp_path):
 
 
 #: every key=value counter family a benchmark-like module writes,
-#: shared by the RNB-T006 tests below (the devobs lines ride on top)
+#: shared by the RNB-T006 tests below
 REPO_BENCH_LIKE = (
         'f.write("Faults: num_failed=%d num_shed=%d num_retries=%d '
         '\\n" % x)\n'
@@ -492,25 +398,6 @@ REPO_BENCH_LIKE = (
         'f.write("Deadline: budget_ms=%d expired=%d\\n" % dl)\n'
         'f.write("Hedge: fired=%d won=%d lost=%d wasted_ms=%d\\n" '
         '% hg)\n'
-        'f.write("Metrics: snapshots=%d series=%d dumps=%d '
-        'triggers=%d\\n" % ms)\n'
-        'f.write("Slo: tracked=%d within=%d missed=%d '
-        'burn_max_milli=%d\\n" % sl)\n'
-        'f.write("Compute: stages=%d dispatches=%d rows=%d '
-        'flops_total=%d window_us=%d tflops_milli=%d mfu_e4=%d '
-        'captures=%d\\n" % cp)\n'
-        'f.write("Memory: owners=%d devices=%d total_bytes=%d '
-        'peak_bytes=%d watermark_bytes=%d watermark_hits=%d '
-        'live_bytes=%d reconciled=%d\\n" % mm)\n'
-        'f.write("Critpath: requests=%d segments=%d '
-        'residual_us_max=%d hedged=%d redispatched=%d bound_step=%d '
-        'bound_vps_milli=%d\\n" % cr)\n'
-        'f.write("Whatif: stages=%d calibrated=%d pred_vps_milli=%d '
-        'bottleneck_step=%d\\n" % wi)\n'
-        'f.write("Operator: scrapes=%d actions=%d denied=%d '
-        'errors=%d\\n" % op)\n'
-        'f.write("Stacks: samples=%d threads=%d folded=%d '
-        'total=%d\\n" % st)\n'
         'f.write("Net: frames_sent=%d frames_acked=%d '
         'resent_pending=%d resends=%d beats=%d reconnects=%d '
         'remote=%d local=%d dedup_drops=%d dup_arrivals=%d '
@@ -534,65 +421,27 @@ def test_benchmark_result_counter_drift_triggers_t006(tmp_path):
         == {("RNB-T006", "num_bogus")}
 
 
-def test_compute_memory_counter_drift_triggers_t006(tmp_path):
-    """The RNB-T006 family covers the devobs lines: a Compute:/Memory:
-    counter with no BenchmarkResult twin is drift, and a compute_/
-    memory_ result field nothing writes is invisible offline."""
-    from rnb_tpu.analysis.schema import check_benchmark_result
-    bench = tmp_path / "bench_like.py"
-    # bogus keys added to both devobs lines on top of the complete
-    # legitimate families, so exactly the two bogus fields surface
-    src = (REPO_BENCH_LIKE
-           .replace('captures=%d\\n', 'captures=%d bogus_flops=%d\\n')
-           .replace('reconciled=%d\\n',
-                    'reconciled=%d bogus_bytes=%d\\n'))
-    bench.write_text(src)
-    findings = check_benchmark_result(str(bench), root=str(tmp_path))
-    anchors = {f.anchor for f in findings if f.rule == "RNB-T006"}
-    assert "compute_bogus_flops" in anchors
-    assert "memory_bogus_bytes" in anchors
-
-
-def test_critpath_whatif_counter_drift_triggers_t006(tmp_path):
-    """The RNB-T006 family covers the explanation-plane lines: the
-    good fixture (REPO_BENCH_LIKE, which writes the full Critpath:/
-    Whatif: counter sets) is clean, and a bogus counter on either
-    line surfaces as exactly its drifted field."""
+@pytest.mark.parametrize("line_end,prefix", [
+    ('host_bytes=%d\\n', "handoff_"),
+    ('routes_after_open=%d\\n', "health_"),
+    ('wasted_ms=%d\\n', "hedges_"),
+])
+def test_counter_family_drift_triggers_t006(tmp_path, line_end, prefix):
+    """The RNB-T006 family covers the Handoff:/Health:/Hedge: lines:
+    the good fixture (REPO_BENCH_LIKE, which writes their full counter
+    sets) is clean, and a bogus counter on a line surfaces as exactly
+    its drifted field."""
     from rnb_tpu.analysis.schema import check_benchmark_result
     good = tmp_path / "good_bench_like.py"
     good.write_text(REPO_BENCH_LIKE)
     assert check_benchmark_result(str(good), root=str(tmp_path)) == []
     bad = tmp_path / "bad_bench_like.py"
-    bad.write_text(REPO_BENCH_LIKE
-                   .replace('bound_vps_milli=%d\\n',
-                            'bound_vps_milli=%d bogus_chain=%d\\n')
-                   .replace('bottleneck_step=%d\\n',
-                            'bottleneck_step=%d bogus_pred=%d\\n'))
+    assert line_end in REPO_BENCH_LIKE
+    bad.write_text(REPO_BENCH_LIKE.replace(
+        line_end, line_end[:-2] + ' bogus=%d\\n'))
     findings = check_benchmark_result(str(bad), root=str(tmp_path))
-    anchors = {f.anchor for f in findings if f.rule == "RNB-T006"}
-    assert "critpath_bogus_chain" in anchors
-    assert "whatif_bogus_pred" in anchors
-
-
-def test_operator_stacks_counter_drift_triggers_t006(tmp_path):
-    """The RNB-T006 family covers the operator-plane lines: the good
-    fixture (REPO_BENCH_LIKE, which writes the full Operator:/Stacks:
-    counter sets) is clean, and a bogus counter on either line
-    surfaces as exactly its drifted field."""
-    from rnb_tpu.analysis.schema import check_benchmark_result
-    good = tmp_path / "good_bench_like.py"
-    good.write_text(REPO_BENCH_LIKE)
-    assert check_benchmark_result(str(good), root=str(tmp_path)) == []
-    bad = tmp_path / "bad_bench_like.py"
-    bad.write_text(REPO_BENCH_LIKE
-                   .replace('errors=%d\\n',
-                            'errors=%d bogus_gets=%d\\n')
-                   .replace('total=%d\\n',
-                            'total=%d bogus_ticks=%d\\n'))
-    findings = check_benchmark_result(str(bad), root=str(tmp_path))
-    anchors = {f.anchor for f in findings if f.rule == "RNB-T006"}
-    assert "operator_bogus_gets" in anchors
-    assert "stacks_bogus_ticks" in anchors
+    assert {(f.rule, f.anchor) for f in findings} \
+        == {("RNB-T006", prefix + "bogus")}
 
 
 def test_net_counter_drift_triggers_t006(tmp_path):
@@ -685,7 +534,7 @@ def test_contract_registry_names_the_core_classes():
     from rnb_tpu.analysis.concurrency import contract_registry
     classes = {cls for _, cls, _, _ in contract_registry()}
     for expected in ("ClipCache", "StagingPool", "HedgeGovernor",
-                     "LaneHealthBoard", "Pager", "MetricsRegistry"):
+                     "LaneHealthBoard", "Pager", "Tracer"):
         assert expected in classes, expected
 
 

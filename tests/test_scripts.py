@@ -177,182 +177,10 @@ def test_latency_summary_cli_empty(tmp_path):
     assert latency_summary.main(["--log-base", str(tmp_path)]) == 1
 
 
-def test_bench_matrix_unparseable_cell_is_contained(monkeypatch,
-                                                    tmp_path):
-    """A cell whose bench.py prints garbage costs that cell only."""
-    import importlib
-    import subprocess as _sp
-
-    bench_matrix = importlib.import_module("bench_matrix")
-
-    class FakeProc:
-        returncode = 0
-        stdout = "not json at all\n"
-        stderr = ""
-
-    monkeypatch.setattr(_sp, "run", lambda *a, **k: FakeProc())
-    row = bench_matrix.run_cell("configs/x.json", 0, 4)
-    assert "unparseable" in row["error"]
-
-
-def test_device_busy_union_and_filter(tmp_path):
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    trace.write_text(
-        "0 100 fusion.1\n"
-        "50 150 convolution.2\n"          # overlaps fusion.1
-        "300 400 copy.3\n"
-        "0 1000 $threading.py:323 wait\n"  # host row: filtered out
-        "0 900 Thread #7\n")
-    planes = device_busy.load_intervals(str(trace))
-    # legacy 3-column format: everything lands under one plane
-    assert set(planes) == {"(all)"}
-    ivals = planes["(all)"]
-    assert len(ivals) == 3
-    # union: [0,150) + [300,400) = 250 ns busy; the span denominator
-    # comes from the UNFILTERED trace (the host row spans [0,1000)) so
-    # device idle at the window's edges is not hidden
-    stats = device_busy.summarize(ivals, span_bounds=(0, 1000))
-    assert stats["busy_ms"] == 250 / 1e6
-    assert stats["span_ms"] == 1000 / 1e6
-    assert abs(stats["busy_fraction"] - 0.25) < 1e-9
-    # host rows kept on demand
-    all_planes = device_busy.load_intervals(str(trace),
-                                            device_only=False)
-    assert len(all_planes["(all)"]) == 5
-    assert device_busy.main([str(trace)]) == 0
-
-
-def test_device_busy_groups_planes(tmp_path, capsys):
-    """4-column traces: busy fractions are computed per plane — XLine
-    clock bases differ across planes, so a cross-plane union would
-    conflate clocks (a 6 s capture once reported a 54 s 'span')."""
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    trace.write_text(
-        "# t0_ns t1_ns plane op_name\n"
-        "0 100 /device:TPU:0 fusion.1\n"
-        "50 150 /device:TPU:0 convolution.2\n"
-        "1000000 1000400 /host:CPU jit_apply(42)\n"  # other clock base
-        "0 1000 /host:CPU $threading.py:1 wait\n")
-    planes = device_busy.load_intervals(str(trace))
-    assert set(planes) == {"/device:TPU:0", "/host:CPU"}
-    # per-plane union, never merged across planes
-    dev = device_busy.summarize(planes["/device:TPU:0"],
-                                span_bounds=(0, 150))
-    assert dev["busy_ms"] == 150 / 1e6
-    assert abs(dev["busy_fraction"] - 1.0) < 1e-9
-    # default report: named /device: planes ARE the device ops — host
-    # planes are excluded wholesale (the jit_apply row is a host-side
-    # dispatch span even though its name passes the legacy heuristic)
-    assert device_busy.main([str(trace)]) == 0
-    out = capsys.readouterr().out
-    assert "/device:TPU:0" in out and "/host:CPU" not in out
-    assert device_busy.main([str(trace), "--include-host"]) == 0
-    out = capsys.readouterr().out
-    assert "/device:TPU:0" in out and "/host:CPU" in out
-
-
-def test_device_busy_window_mapping(tmp_path, capsys):
-    """The measured-window cross-check: host-epoch window from the
-    header is mapped onto the device timeline by anchoring flush_epoch
-    to the plane's max t1, and busy is reported within that window
-    only (the remote capture contains the whole device session, so the
-    full-span fraction under-reports steady-state utilization)."""
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    # device timeline: ops at [0,1e9), [2e9,3e9), [9e9,10e9).
-    # flush at epoch 110.0 anchors device t=10e9; window epoch
-    # [101.0, 110.0] -> device [1e9, 10e9): clips the first op out
-    # entirely except nothing (op1 ends at 1e9), keeps [2e9,3e9) and
-    # [9e9,10e9) -> busy 2e9 of a 9e9 window.
-    trace.write_text(
-        "# t0_ns t1_ns plane op_name\n"
-        "# window_epoch 101.0 110.0 flush_epoch 110.0\n"
-        "0 1000000000 /device:TPU:0 fusion.1\n"
-        "2000000000 3000000000 /device:TPU:0 fusion.2\n"
-        "9000000000 10000000000 /device:TPU:0 fusion.3\n")
-    assert device_busy.load_window(str(trace)) == (101.0, 110.0, 110.0)
-    planes = device_busy.load_intervals(str(trace))
-    clipped, (w0, w1) = device_busy.clip_to_window(
-        planes["/device:TPU:0"], (101.0, 110.0, 110.0),
-        anchor_t1_ns=10_000_000_000)
-    assert (w0, w1) == (1_000_000_000, 10_000_000_000)
-    assert [(t0, t1) for t0, t1, _ in clipped] == [
-        (2_000_000_000, 3_000_000_000),
-        (9_000_000_000, 10_000_000_000)]
-    assert device_busy.main([str(trace)]) == 0
-    out = capsys.readouterr().out
-    assert "measured window" in out
-    # 2e9 busy / 9e9 window = 22.2%
-    assert "(22.2% of window)" in out
-
-
-def test_device_busy_no_window_header_is_fine(tmp_path, capsys):
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    trace.write_text("# t0_ns t1_ns plane op_name\n"
-                     "0 100 /device:TPU:0 fusion.1\n")
-    assert device_busy.load_window(str(trace)) is None
-    assert device_busy.main([str(trace)]) == 0
-    assert "measured window" not in capsys.readouterr().out
-
-
-def test_device_busy_marker_window(tmp_path, capsys):
-    """Marker-delimited window: busy is computed between the first
-    marker's end and the last marker's start, markers excluded."""
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    trace.write_text(
-        "# t0_ns t1_ns plane op_name\n"
-        "# window_epoch 1.0 2.0 flush_epoch 2.0\n"  # marker wins over this
-        "0 100 /device:TPU:0 jit_rnb_window_marker(1)\n"
-        "500 600 /device:TPU:0 fusion.pre\n"        # before... no: inside
-        "1000 3000 /device:TPU:0 fusion.in\n"
-        "9000 9100 /device:TPU:0 jit_rnb_window_marker(2)\n"
-        "9500 9900 /device:TPU:0 fusion.post\n")
-    planes = device_busy.load_intervals(str(trace))
-    assert device_busy.marker_window(planes["/device:TPU:0"]) == (100,
-                                                                  9000)
-    assert device_busy.main([str(trace)]) == 0
-    out = capsys.readouterr().out
-    # window [100, 9000): fusion.pre (100) + fusion.in (2000) busy of
-    # 8900 -> 23.6%; fusion.post lies outside and is excluded
-    assert "marker-delimited window (23.6%" in out
-
-
-def test_device_busy_headerless_four_col_sniffed(tmp_path, capsys):
-    """A 4-column file whose header line was stripped must still be
-    parsed per-plane (sniffed from the first data row), not folded
-    into '(all)' with the plane token glued onto the op name."""
-    import device_busy
-
-    trace = tmp_path / "xprof-ops.txt"
-    trace.write_text("0 100 /device:TPU:0 fusion.1\n"
-                     "50 150 /host:CPU cpu_thing\n")
-    planes = device_busy.load_intervals(str(trace), device_only=False)
-    assert set(planes) == {"/device:TPU:0", "/host:CPU"}
-    assert planes["/device:TPU:0"] == [(0, 100, "fusion.1")]
-    assert device_busy.main([str(trace)]) == 0
-    capsys.readouterr()
-    # a retained window_epoch comment must not defeat the sniff: the
-    # format decision comes from the first DATA row
-    trace.write_text("# window_epoch 100.0 102.0 flush_epoch 102.0\n"
-                     "0 100 /device:TPU:0 fusion.1\n")
-    planes = device_busy.load_intervals(str(trace), device_only=False)
-    assert set(planes) == {"/device:TPU:0"}
-
-
 def test_decode_bench_smoke(tmp_path):
     """scripts/decode_bench.py: decodes a tiny dataset tree with the
     native backend and reports a frame count matching every frame
-    decoded exactly once (the micro-benchmark behind the frames/s
-    rates quoted in MATRIX.md)."""
+    decoded exactly once."""
     import json as _json
     import subprocess as _sp
 
@@ -455,40 +283,3 @@ def test_bench_diff_cli_detects_regression(tmp_path):
                             "--tolerance", "0.95"]) == 0
     assert bench_diff.main(["--baseline", str(tmp_path / "nope.json"),
                             "--current", str(cpath)]) == 2
-
-
-def test_device_busy_job_dir_reads_ledger_and_captures(tmp_path,
-                                                       capsys):
-    """Job-dir mode: the devobs ledger lines print first, every
-    capture artifact is analyzed, and an idle capture is a report,
-    not an error."""
-    import device_busy
-
-    job = tmp_path / "job"
-    job.mkdir()
-    (job / "log-meta.txt").write_text(
-        "Args: Namespace()\n"
-        "Compute: stages=1 dispatches=2 rows=3 flops_total=30 "
-        "window_us=1000 tflops_milli=0 mfu_e4=-1 captures=1\n"
-        "Memory: owners=1 devices=1 total_bytes=16 peak_bytes=16 "
-        "watermark_bytes=0 watermark_hits=0 live_bytes=0 "
-        "reconciled=0\n")
-    (job / "devobs-capture-0.txt").write_text(
-        "# t0_ns t1_ns plane op_name\n"
-        "# window_epoch 0.0 1.0 flush_epoch 1.0\n"
-        "# trigger window ops_total 1 ops_written 1\n"
-        "100 200 /device:TPU:0 fusion.1\n")
-    assert device_busy.main([str(job)]) == 0
-    out = capsys.readouterr().out
-    assert "Compute: stages=1" in out
-    assert "Memory: owners=1" in out
-    assert "devobs-capture-0.txt" in out
-    # an idle (empty) capture must not fail the report
-    (job / "devobs-capture-1.txt").write_text(
-        "# t0_ns t1_ns plane op_name\n"
-        "# trigger forced ops_total 0 ops_written 0\n")
-    assert device_busy.main([str(job)]) == 0
-    # a dir with neither ledger nor artifacts is an error
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert device_busy.main([str(empty)]) == 1

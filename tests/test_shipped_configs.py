@@ -127,81 +127,6 @@ def test_chaos_arm_ships_executed_with_the_full_healing_layer():
         "'rnb-scaleout-r4-chaos.json'")
 
 
-def test_metrics_arm_ships_executed_with_overhead_in_the_noise():
-    """The live-metrics headline cell (PR 11) must land in BOTH
-    configs/ and the matrix with an ok execution row, must actually
-    declare the root ``metrics`` key over the same topology as
-    rnb-fused-yuv-staged, and the committed pair must back the
-    overhead claim: the metrics arm's videos/s within the noise of
-    the staged baseline (>= 0.85x). A re-sweep that drops below the
-    floor invalidates the 'overhead in the noise' headline and must
-    fail here, not silently rot in the artifact."""
-    rel = "configs/rnb-fused-yuv-metrics.json"
-    base = "configs/rnb-fused-yuv-staged.json"
-    path = os.path.join(REPO, rel)
-    assert os.path.exists(path), rel
-    from rnb_tpu.config import load_config
-    cfg = load_config(path)
-    assert cfg.metrics is not None and cfg.metrics.get("enabled", True)
-    base_cfg = load_config(os.path.join(REPO, base))
-    # same topology as the staged baseline: the pair differs by the
-    # metrics key alone, so the committed ratio IS the overhead
-    assert [s.model for s in cfg.steps] \
-        == [s.model for s in base_cfg.steps]
-    with open(ARTIFACT) as f:
-        rows = {r["config"]: r for r in json.load(f)["configs"]}
-    assert rel in rows and rows[rel].get("ok"), (
-        "the metrics arm has no ok execution row — run "
-        "scripts/run_shipped_configs.py --only "
-        "'rnb-fused-yuv-metrics.json'")
-    ratio = rows[rel]["videos_per_sec"] / rows[base]["videos_per_sec"]
-    assert ratio >= 0.85, (
-        "metrics arm runs at %.2fx the staged baseline — the live "
-        "plane's overhead is no longer in the noise; profile the "
-        "flusher/bridge before re-executing the row" % ratio)
-
-
-def test_operator_arm_ships_executed_with_overhead_in_the_noise():
-    """The operator-plane headline cell (PR 15) must land in BOTH
-    configs/ and the matrix with an ok execution row, must declare the
-    root ``operator`` key (actions gated OFF per the honesty policy,
-    sampler ON) over the same topology as rnb-fused-yuv-metrics, and
-    the committed pair must back the overhead claim: serving the
-    operator server + continuous stack sampler costs videos/s within
-    the noise of the metrics baseline (>= 0.85x)."""
-    rel = "configs/rnb-fused-yuv-operator.json"
-    base = "configs/rnb-fused-yuv-metrics.json"
-    path = os.path.join(REPO, rel)
-    assert os.path.exists(path), rel
-    from rnb_tpu.config import load_config
-    cfg = load_config(path)
-    assert cfg.operator is not None \
-        and cfg.operator.get("enabled", True)
-    assert cfg.operator.get("allow_actions") is False, (
-        "the shipped operator arm must keep actuation opt-in "
-        "(allow_actions false) — introspection ships, control does "
-        "not")
-    assert cfg.operator.get("sample_hz", 1) > 0, (
-        "the shipped arm carries the always-on sampler (the overhead "
-        "claim covers it)")
-    base_cfg = load_config(os.path.join(REPO, base))
-    # same topology as the metrics baseline: the pair differs by the
-    # operator key alone, so the committed ratio IS the overhead
-    assert [s.model for s in cfg.steps] \
-        == [s.model for s in base_cfg.steps]
-    with open(ARTIFACT) as f:
-        rows = {r["config"]: r for r in json.load(f)["configs"]}
-    assert rel in rows and rows[rel].get("ok"), (
-        "the operator arm has no ok execution row — run "
-        "scripts/run_shipped_configs.py --only "
-        "'rnb-fused-yuv-operator.json'")
-    ratio = rows[rel]["videos_per_sec"] / rows[base]["videos_per_sec"]
-    assert ratio >= 0.85, (
-        "operator arm runs at %.2fx the metrics baseline — the "
-        "server/sampler overhead is no longer in the noise; profile "
-        "the sampler cadence before re-executing the row" % ratio)
-
-
 def test_dct_arm_ships_executed_with_half_the_wire_bytes():
     """The DCT-domain ingest headline cell (PR 12) must land in BOTH
     configs/ and the matrix with an ok execution row, must be the
