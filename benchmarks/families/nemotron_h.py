@@ -202,6 +202,70 @@ def check_outputs(config: dict, pipeline: dict, weights, ckpt_path: str,
     return verdict
 
 
+def check_config(config: dict) -> List[str]:
+    """What has to hold between the parts of one of this family's
+    configuration files, beyond what the program's own parser and lint
+    check: -> the problems, none for a sound file."""
+    from rnb_tpu.models.nemotron_h import network
+    problems = []
+    cfg = network.NemotronHConfig.from_published(published_keys(config))
+    if cfg.pattern != config["model"]["blocks"] \
+            or len(cfg.pattern) != config["num_hidden_layers"]:
+        problems.append("blocks held: the model's %r, the pattern's first "
+                        "%d %r" % (config["model"]["blocks"],
+                                   config["num_hidden_layers"], cfg.pattern))
+    for key in config["reduced"]:
+        if config["published"].get(key) in (None, config[key]):
+            problems.append("reduced key %s: \"published\" has to hold "
+                            "the source's value, which differs" % key)
+    if config["experts_held"]["count"] != config["n_routed_experts"]:
+        problems.append("experts_held.count is not n_routed_experts")
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    if not loader["max_rows"] == batcher["batch"] == prefill["max_rows"] \
+            == max(prefill["row_buckets"]):
+        problems.append("the three stages disagree on the row cap")
+    if batcher["row_buckets"] != prefill["row_buckets"]:
+        problems.append("the batcher packs buckets the final stage has "
+                        "not compiled")
+    if not loader["chunk"] == prefill["chunk"] == config["chunk_size"]:
+        problems.append("a row is chunk_size tokens in every stage")
+    return problems
+
+
+def project_memory(config: dict, sharding) -> dict:
+    """Bytes the largest row bucket takes on the device of ``sharding``
+    (a described chip: the real stage program is compiled and nothing
+    runs): the program's ``temporaries`` and ``arguments`` (the weights
+    held and one packed batch) and the batches that may be ``waiting``
+    on the device, one a slot of the ring in front of the stage."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.nemotron_h import checkpoint, network
+    cfg = network.NemotronHConfig.from_published(published_keys(config))
+    batcher, step = config["pipeline_config"]["pipeline"][-2:]
+    rows = max(step["row_buckets"])
+    params = {}
+    for group, tensors in checkpoint.tensor_specs(
+            cfg, config["experts_held"]["count"]).items():
+        made = {name: jax.ShapeDtypeStruct(
+            spec.shape, getattr(jnp, spec.dtype), sharding=sharding)
+            for name, spec in tensors.items()}
+        params.update(made if group == "top" else {group: made})
+
+    def of(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+    memory = jax.jit(lambda p, s, t, m: network.forward(
+        cfg, p, s, t, m[0], m[1], m[2])).lower(
+        params, of((cfg.router_experts,)), of((rows, cfg.chunk_size)),
+        of((3, rows))).compile().memory_analysis()
+    return {"rows": rows,
+            "temporaries": memory.temp_size_in_bytes,
+            "arguments": memory.argument_size_in_bytes,
+            "waiting": batcher["num_shared_tensors"]
+            * wire_bytes_per_row(config, config["pipeline_config"]) * rows}
+
+
 # -- operations and bytes -------------------------------------------------
 
 

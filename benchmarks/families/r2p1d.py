@@ -197,8 +197,14 @@ def make_weights(config: dict, seed: int, ckpt_base: str):
 def check_outputs(config: dict, pipeline: dict, weights, ckpt_path: str,
                   seed: int, inputs: dict, devices, result) -> dict:
     """The serving applier of the run (same jitted function, same
-    device weights, smallest warmed bucket) against the float32
-    reference on a seeded sample, outside the window."""
+    device weights) against the float32 reference on a seeded sample,
+    outside the window: at the smallest warmed bucket and at the
+    largest, the rows most of a backlog's dispatches ship. The compiler
+    chooses the layouts around the ingest kernels by the row count, so
+    what holds at 8 rows is not shown at 48 (PERF.md section 7 g).
+    Both programs are warmed; nothing compiles. The verdict is the
+    worse bucket's, with every bucket's numbers beside it; the
+    reference runs once."""
     import jax
     import numpy as np
 
@@ -209,7 +215,6 @@ def check_outputs(config: dict, pipeline: dict, weights, ckpt_path: str,
     sizes = tuple(step["layer_sizes"])
     frames = int(step["consecutive_frames"])
     pixel_path = step["pixel_path"]
-    rows = int(min(step["row_buckets"]))
     hw = stage.FRAME_HW
     device = devices[0]
     apply = stage._shared_apply(step["start_index"], step["end_index"],
@@ -218,10 +223,15 @@ def check_outputs(config: dict, pipeline: dict, weights, ckpt_path: str,
     params = stage._shared_params(step["start_index"], step["end_index"],
                                   config["model"]["num_classes"], sizes,
                                   ckpt_path, device)
-    checked = min(2, rows)
+    buckets = sorted({int(min(step["row_buckets"])),
+                      int(max(step["row_buckets"]))})
+    checked = min(2, buckets[0])
+    # one input at the largest bucket, its first rows the smaller one's
+    # (the generator fills row by row), so every bucket is held to the
+    # same reference rows
     rng = np.random.default_rng([seed % 2 ** 63, 7])
     if pixel_path == "yuv420":
-        wire = rng.integers(0, 256, (rows, frames, hw * hw * 3 // 2),
+        wire = rng.integers(0, 256, (buckets[-1], frames, hw * hw * 3 // 2),
                             dtype=np.uint8)
         ref_in = reference.normalize_yuv420(wire[:checked], hw, hw)
     elif pixel_path == "dct":
@@ -233,19 +243,78 @@ def check_outputs(config: dict, pipeline: dict, weights, ckpt_path: str,
         decoded = get_decoder(sample_path).decode_clips_dct(
             sample_path, list(clips_starts)[:checked], frames, width=hw,
             height=hw, coeffs=dct.default_dct_coeffs(hw, hw))
-        wire = np.zeros((rows,) + tuple(decoded.shape[1:]), decoded.dtype)
+        wire = np.zeros((buckets[-1],) + tuple(decoded.shape[1:]),
+                        decoded.dtype)
         wire[:checked] = decoded[:checked]
         ref_in = reference.normalize_rgb_u8(
             dct.dct_rows_to_rgb_numpy(wire[:checked], hw, hw))
     else:
         raise ValueError("no reference ingest for pixel_path %r"
                          % (pixel_path,))
-    got = np.asarray(apply(params, jax.device_put(wire, device)),
-                     np.float32)[:checked]
     with jax.default_matmul_precision("highest"):
         ref = np.asarray(jax.jit(
             lambda v, x: reference.forward(v, x, sizes))(weights, ref_in))
-    return compare(got, ref)
+    by_rows = {rows: compare(np.asarray(
+        apply(params, jax.device_put(wire[:rows], device)),
+        np.float32)[:checked], ref) for rows in buckets}
+    worst = max(buckets, key=lambda rows: (
+        not by_rows[rows]["ok"], by_rows[rows].get("share_of_spread") or 0))
+    return dict(by_rows[worst], rows=worst,
+                by_rows={str(rows): by_rows[rows] for rows in buckets})
+
+
+def check_config(config: dict) -> List[str]:
+    """What has to hold between the parts of one of this family's
+    configuration files, beyond what the program's own parser and lint
+    check: -> the problems, none for a sound file."""
+    model = config["model"]
+    runner = config["pipeline_config"]["pipeline"][-1]
+    problems = []
+    for key, published in (("layer_sizes", [3, 4, 6, 3]),
+                           ("consecutive_frames", 32)):
+        if not runner[key] == model[key] == published:
+            problems.append("%s: the stage's %r, the model's %r, the "
+                            "paper's %r" % (key, runner[key], model[key],
+                                            published))
+    return problems
+
+
+def project_memory(config: dict, sharding) -> dict:
+    """Bytes the largest row bucket takes on the device of ``sharding``
+    (a described chip: the real stage program, ingest included, is
+    compiled and nothing runs): the program's ``temporaries`` and
+    ``arguments`` (weights and one input batch) and the batches that
+    may be ``waiting`` on the device, one a ring slot."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.r2p1d import model as stage
+    from rnb_tpu.models.r2p1d.network import R2Plus1DClassifier
+    pipeline = config["pipeline_config"]["pipeline"]
+    loader, step = pipeline[0], pipeline[-1]
+    sizes = tuple(step["layer_sizes"])
+    rows, frames = max(step["row_buckets"]), step["consecutive_frames"]
+    apply = stage._shared_apply(step["start_index"], step["end_index"],
+                                config["model"]["num_classes"], sizes,
+                                pixel_path=step["pixel_path"])
+    shapes = jax.eval_shape(
+        lambda k: R2Plus1DClassifier(layer_sizes=sizes).init(
+            k, np.zeros((1, 2, 14, 14, 3), np.float32), train=False),
+        jax.random.key(0))
+    variables = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding), shapes)
+    shape = stage.R2P1DRunner.input_shape_for(
+        start_index=step["start_index"], max_rows=rows,
+        consecutive_frames=frames, pixel_path=step["pixel_path"])[0]
+    dtype = getattr(jnp, stage.R2P1DRunner.input_dtype_for(
+        start_index=step["start_index"], pixel_path=step["pixel_path"]))
+    memory = apply.lower(variables, jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)).compile().memory_analysis()
+    return {"rows": rows,
+            "temporaries": memory.temp_size_in_bytes,
+            "arguments": memory.argument_size_in_bytes,
+            "waiting": loader["num_shared_tensors"] * int(np.prod(shape))
+            * np.dtype(dtype).itemsize}
 
 
 def flops_per_row(config: dict) -> int:

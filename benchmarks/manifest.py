@@ -118,6 +118,14 @@ def load_family(name: str, directory: str = FAMILIES_DIR):
     ``flops_per_row(config)``, ``wire_bytes_per_row(config, pipeline)``
         the operations one row needs and the bytes it ships.
 
+    The harness's own tests ask a family whose configuration is in
+    ``BENCHMARK.json`` for two more, so that no test names a model:
+    ``check_config(config) -> [problems]`` (what has to hold between
+    the parts of the configuration's file) and ``project_memory(config,
+    sharding) -> {rows, temporaries, arguments, waiting}`` (bytes of
+    the largest bucket's stage program compiled for a described chip,
+    held against the file's ``size_record``).
+
     A tree that brings its own ``families`` directory (a test's copy)
     need not repeat this checkout's files: a name it lacks is looked
     for here."""

@@ -261,13 +261,16 @@ def main(argv=None) -> int:
     if np.isnan(schedule.sent).any():
         problems.append("%d scheduled request(s) were never sent"
                         % int(np.isnan(schedule.sent).sum()))
+    backlog = None
     if schedule.process == "backlog":
-        left = float((~(finish < schedule.window[1])).sum()) / len(schedule)
-        need = float(mix["arrivals"].get("min_left_share", 0.05))
-        if left < need:
-            problems.append("the backlog emptied: %.1f%% of the requests "
-                            "were left when the window closed, under "
-                            "%.1f%%" % (100 * left, 100 * need))
+        backlog = traffic.backlog_room(
+            len(schedule), int((finish < schedule.window[1]).sum()),
+            float(mix["arrivals"].get("min_left_share", 0.05)),
+            facts.videos_per_s())
+        say("backlog: %s" % backlog)
+        emptied = traffic.backlog_problem(backlog)
+        if emptied:
+            problems.append(emptied)
     logits = family.check_outputs(config, pipeline, weights, ckpt_path,
                                   args.seed, inputs, devices, result)
     if not logits["ok"]:
@@ -328,6 +331,8 @@ def main(argv=None) -> int:
                      "run_total_s": result.total_time_s,
                      "warmup_s": result.warmup_s,
                      "wall_s": time.time() - T_PROCESS}
+    if backlog is not None:
+        line["notes"]["backlog"] = backlog
     if args.trace:
         from benchmarks import hostspans
         reduced = hostspans.notes_of(facts)

@@ -32,8 +32,11 @@ def run(manifest, workload, *extra, trace=0, seconds=3):
                                             ("tiny.poisson", 1),
                                             ("tiny-r4.bulk", 1)])
 def test_result_line(workload, trace, tmp_path):
+    # a backlog's window ends on a boundary of the notes' 5 s bins
+    seconds = 5 if workload.endswith(".bulk") else 3
     done = run(os.path.join(FIXTURE, "BENCHMARK.json"), workload,
-               "--platform", "cpu", "--out", str(tmp_path), trace=trace)
+               "--platform", "cpu", "--out", str(tmp_path), trace=trace,
+               seconds=seconds)
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert {"correct", "attempted", "failed", "metrics", "device"} \
@@ -52,6 +55,19 @@ def test_result_line(workload, trace, tmp_path):
         assert line["metrics"]["setup_s"]["value"] > 0
         if workload == "tiny.poisson":
             assert line["correct"] is True
+    notes = line["notes"]
+    if workload.endswith(".bulk"):
+        # how far the run was from emptying its backlog, in every line
+        room = notes["backlog"]
+        assert set(room) == {"requests", "left_share", "min_left_share",
+                             "empties_at_videos_per_s"}
+        assert room["requests"] == notes["requests"]
+        by_the_close = notes["finished_by_5s"][0]
+        assert room["left_share"] == pytest.approx(
+            1.0 - by_the_close / notes["requests"])
+        assert room["min_left_share"] == 0.05
+    else:
+        assert "backlog" not in notes
 
 
 def test_no_accelerator_no_result(tmp_path):

@@ -8,6 +8,7 @@ import pytest
 
 from benchmarks import manifest as mm
 from benchmarks import peaks
+from benchmarks import traffic
 
 MANIFEST = mm.load()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -78,9 +79,9 @@ def test_cell_reports_setup_another_end_to_end_and_a_layer(cell):
 
 @pytest.mark.parametrize("entry", MANIFEST["configs"],
                          ids=lambda c: c["name"])
-def test_config_file_loads_through_the_programs_own_checks(entry, tmp_path):
-    from rnb_tpu.config import parse_config
-    assert entry["file"].startswith("benchmarks/") and entry["reduced"] == []
+def test_config_file_passes_the_programs_and_its_familys_checks(entry,
+                                                                tmp_path):
+    assert entry["file"].startswith("benchmarks/")
     assert any(w["config"] == entry["name"] for w in MANIFEST["workloads"])
     check_config_file(entry["file"], entry["reduced"], tmp_path)
 
@@ -100,19 +101,19 @@ def test_prepared_config_file_loads_too(path, tmp_path):
 
 
 def check_config_file(path, reduced, tmp_path):
+    """The manifest's and the file's ``reduced`` agree; what the file's
+    family holds its parts to (``check_config`` of the family file: the
+    model-specific lines live there) finds nothing; the program's own
+    parser and the static graph checks of rnb_lint (shapes, buckets,
+    dtypes) take its pipeline."""
     from rnb_tpu.config import parse_config
     with open(os.path.join(mm.REPO, path)) as f:
         config = json.load(f)
     assert config["reduced"] == reduced and config["assumed"]
-    assert config["family"] == "r2p1d"
+    assert mm.load_family(config["family"]).check_config(config) == []
     parsed = parse_config(config["pipeline_config"])
     assert parsed.video_path_iterator \
         == "benchmarks.traffic.ScheduledPathIterator"
-    model = config["model"]
-    runner = config["pipeline_config"]["pipeline"][-1]
-    assert runner["layer_sizes"] == model["layer_sizes"] == [3, 4, 6, 3]
-    assert runner["consecutive_frames"] == model["consecutive_frames"] == 32
-    # the static graph checks of rnb_lint: shapes, buckets, dtypes
     path = tmp_path / "pipeline.json"
     path.write_text(json.dumps(config["pipeline_config"]))
     import subprocess
@@ -122,6 +123,24 @@ def check_config_file(path, reduced, tmp_path):
          "--config", str(path)], capture_output=True, text=True,
         env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=mm.REPO))
     assert lint.returncode == 0, lint.stdout + lint.stderr
+
+
+BACKLOG_CELLS = [w for w in MANIFEST["workloads"]
+                 if traffic.load_mix(w["traffic"])["arrivals"]["process"]
+                 == "backlog"]
+
+
+@pytest.mark.parametrize("cell", BACKLOG_CELLS, ids=lambda w: w["name"])
+def test_a_backlog_cell_has_room_to_show_half_again_its_anchor(cell):
+    """benchmarks/traffic.py's rule: the backlog is sized from the
+    rate of the ledger line the configuration's capacity key names,
+    with room for at least +50% before ``correct`` refuses the run."""
+    arrivals = traffic.load_mix(cell["traffic"])["arrivals"]
+    assert arrivals["backlog_factor"] \
+        * (1.0 - arrivals["min_left_share"]) >= 1.5
+    config = mm.load_config_file(MANIFEST, cell["config"])
+    assert config["capacity_videos_per_chip_s"] > 0
+    assert re.search(r"ledger, PR \d+", config["capacity_why"])
 
 
 @pytest.mark.parametrize("sizes,frames", [((3, 4, 6, 3), 32),

@@ -121,3 +121,37 @@ def test_names_pin_the_mix_wherever_the_checkout_lies(tmp_path):
         assert [clips[p] for p in shorts] == [1, 1]
         assert [clips[p] for p in longs] == [2, 2]  # 70 frames hold two
         assert all(os.path.exists(p) for p in shorts + longs)
+
+
+@pytest.mark.parametrize("finished,rate,emptied", [
+    # ledger and chiprun_out/p30, PR 30: r34-yuv.bulk on 1.35 x 128
+    (4663, 138.37, False),
+    # PR 29's (k, T) form of nemotron3-nano.bulk on 1.35 x 29.9: 4.6%
+    # of 1,375 left, the refusal ISSUE 31 is about
+    (1312, 38.63, True)])
+def test_backlog_room_and_the_sentence_that_refuses_a_run(finished, rate,
+                                                          emptied):
+    requests = 5885 if finished == 4663 else 1375
+    room = traffic.backlog_room(requests, finished, 0.05, rate)
+    assert set(room) == {"requests", "left_share", "min_left_share",
+                         "empties_at_videos_per_s"}
+    assert room["left_share"] == pytest.approx(1 - finished / requests)
+    # the run's own rate x the finishes 5% left allows / those it had
+    assert room["empties_at_videos_per_s"] == pytest.approx(
+        rate * 0.95 * requests / finished)
+    sentence = traffic.backlog_problem(room)
+    if not emptied:
+        assert sentence is None
+        assert 165 < room["empties_at_videos_per_s"] < 167
+    else:
+        assert "the backlog emptied: 4.6% of the 1375 requests" in sentence
+        assert "at most %.1f requests/s" \
+            % room["empties_at_videos_per_s"] in sentence
+        assert room["empties_at_videos_per_s"] < rate
+
+
+def test_backlog_room_of_a_run_that_finished_nothing():
+    room = traffic.backlog_room(22, 0, 0.05, 0.0)
+    assert room["left_share"] == 1.0
+    assert room["empties_at_videos_per_s"] is None
+    assert traffic.backlog_problem(room) is None

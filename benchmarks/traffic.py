@@ -22,7 +22,18 @@ Mix file keys:
     configuration's ``capacity_videos_per_chip_s`` (its measured rate,
     kept in the configuration's file) x chips x (``ramp_s`` + seconds)
     requests are offered: enough that the queue outlasts the window,
-    few enough that the drain after it is short.
+    few enough that the drain after it is short. A run that leaves
+    under ``min_left_share`` of them unfinished when the window closes
+    is not correct, so the cell can show a rate of ``backlog_factor`` x
+    (1 - ``min_left_share``) x the capacity key and no more. **The
+    rule** (PR 31): the key is the cell's rate on the newest accepted
+    ledger line when it was anchored (``capacity_why`` names the line),
+    the factor leaves room for at least +50% over it, and the first
+    ``benchmark`` PR after a bulk cell's ``level`` has risen by a
+    quarter since the anchor re-anchors the key. Every run's
+    ``notes.backlog`` (:func:`backlog_room`) says at what rate it would
+    have emptied: whoever asks a bulk cell for a gain reckons that
+    first.
     ``"poisson"``: open loop at ``rate_per_s`` (a fixed number; the
     knee it was taken from and the sweep's readings sit beside it).
     Optional ``burst`` ``{"period_s", "on_s", "factor"}``: the rate is
@@ -215,6 +226,42 @@ def build_schedule(mix: dict, seed: int, seconds: float, chips: int,
     clips = np.array([clips_of[p] for p in paths], dtype=np.int64)
     return Schedule(due, paths, clips, ramp_s, seconds,
                     arrivals["process"])
+
+
+def backlog_room(requests: int, finished: int, min_left_share: float,
+                 videos_per_s: float) -> dict:
+    """How far a backlog run was from emptying its queue: the share of
+    the ``requests`` left when the window closed (``finished`` of them
+    were done by then, the ramp's among them), and the rate at which
+    the run would have read ``min_left_share`` — its own
+    ``videos_per_s`` x the finishes that share allows / the finishes it
+    had: ramp and window speed up together. None where nothing
+    finished inside the window."""
+    allowed = requests * (1.0 - min_left_share)
+    return {"requests": int(requests),
+            "left_share": (requests - finished) / requests,
+            "min_left_share": min_left_share,
+            "empties_at_videos_per_s":
+                videos_per_s * allowed / finished
+                if finished and videos_per_s else None}
+
+
+def backlog_problem(room: dict):
+    """The sentence ``correct`` is refused with where the backlog
+    emptied, None where enough of it was left."""
+    if room["left_share"] >= room["min_left_share"]:
+        return None
+    sentence = ("the backlog emptied: %.1f%% of the %d requests were left "
+                "when the window closed, under %.1f%%"
+                % (100 * room["left_share"], room["requests"],
+                   100 * room["min_left_share"]))
+    rate = room["empties_at_videos_per_s"]
+    if rate is not None:
+        sentence += ("; this cell can show at most %.1f requests/s (its "
+                     "mix's backlog_factor x its configuration's "
+                     "capacity_videos_per_chip_s: a benchmark PR re-anchors "
+                     "them)" % rate)
+    return sentence
 
 
 #: the schedule of the run in progress: set by benchmarks/run.py before
