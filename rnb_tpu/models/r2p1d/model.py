@@ -115,8 +115,9 @@ def _shared_apply(start: int, end: int, num_classes: int,
 
     ``pixel_path="yuv420"`` (layer-1 stages only) prepends the fused
     ingest — packed 4:2:0 planes -> chroma upsample -> BT.601 ->
-    normalize (rnb_tpu/ops/yuv.py) — inside the same jit, so XLA fuses
-    the colourspace math with the first convolution's input pipeline.
+    normalize (rnb_tpu/ops/yuv.py) — inside the same jit, all of it
+    plain jnp, so XLA makes one producer of it and lays its result out
+    for the first convolution (no Pallas kernel stands between them).
 
     ``ragged`` swaps the contract for the ragged row-pool one
     (rnb_tpu/ops/ragged.py): the applier takes the flat pool plus a
@@ -132,7 +133,9 @@ def _shared_apply(start: int, end: int, num_classes: int,
     size produce the same per-row outputs; asserted in
     tests/test_ragged.py). ``ragged_chunk=0`` applies the whole pool
     in one call (preferable on real TPUs, where the MXU wants the
-    large batch and the Pallas ingest already skips pad arithmetic).
+    large batch; the RGB loader's ragged Pallas preprocess and the
+    dct ingest's kernel skip pad arithmetic, the yuv420 ingest, plain
+    jnp inside this jit, masks the pads at the u8 level).
     """
     key = (start, end, num_classes, layer_sizes, factored_shortcut,
            pixel_path, bool(ragged), int(ragged_chunk))

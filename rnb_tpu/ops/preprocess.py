@@ -1,16 +1,31 @@
 """Ingest preprocess kernel: uint8 frames -> normalized bfloat16.
 
-This is the one op every video batch crosses on its way from the host
-decoder into the network (the TPU-native analog of the reference's
-post-NVVL ``.float()`` cast, reference models/r2p1d/model.py:149-151):
+This is the op a batch of RGB u8 frames crosses on its way from the
+host decoder into the network (the TPU-native analog of the
+reference's post-NVVL ``.float()`` cast, reference
+models/r2p1d/model.py:149-151):
 
     y = x.astype(bf16) * (2/255) - 1        # [0,255] -> [-1,1]
 
-XLA would fuse this into the consuming conv when it can; the Pallas
-kernel makes the ingest cost explicit and keeps the uint8->bf16
-widening on the VPU with lane-aligned tiles, independent of what the
-consumer looks like (it may live behind a ``device_put`` boundary in
-the pipelined runtime, where there is no consumer to fuse into).
+Two forms of it, and which caller takes which:
+
+* ``normalize_u8`` — on a TPU a Pallas kernel — is the stand-alone
+  preprocess program's (the RGB pixel path's loader,
+  models/r2p1d/model.py ``_shared_preprocess``): its u8 batch arrives
+  from the host and its result crosses a ``device_put`` / ring
+  boundary, so there is no consumer in the program to fuse into, and
+  the kernel keeps the uint8->bf16 widening on the VPU with
+  lane-aligned tiles. (The rgb branch of the mesh step,
+  parallel/sharded.py, also calls it, in front of its network inside
+  one jit: no cell measures that branch, and it is left as it was.)
+* ``normalize_u8_reference`` — plain jnp, the numerics contract the
+  kernel was written against — is what an ingest calls that computes
+  its u8 frames *inside its consumer's jit* (ops/yuv.py; ops/dct.py
+  writes the same formulation into its own conversion): there XLA
+  fuses the normalization with its producer and lays the result out
+  for the first convolution, and an opaque kernel over a flat
+  ``(M, 128)`` view between the two costs two relayouts and a
+  3-channel clip padded to 128 lanes (PERF.md section 6, PR 32).
 
 Layout strategy: the logical clip shape ``(N, F, H, W, 3)`` is
 irrelevant to an elementwise op, so the wrapper flattens to
@@ -73,8 +88,11 @@ def _normalize_u8_pallas(x, dtype=jnp.bfloat16):
 def normalize_u8(x, dtype=jnp.bfloat16):
     """uint8 [0,255] frames -> ``dtype`` in [-1, 1].
 
-    The single normalization every ingest path shares (pipeline loader
-    preprocess, sharded mesh step). When the element count is
+    The stand-alone normalization of a u8 batch that came from the
+    host (the RGB loader's preprocess program; also the rgb branch of
+    the sharded mesh step). An ingest that computes its u8 frames in
+    the same jit as their consumer calls ``normalize_u8_reference``
+    instead (module docstring). When the element count is
     lane-divisible the choice between the Pallas kernel and jnp is made
     at lowering time, by the platform the computation is compiled for
     (``lax.platform_dependent``) — the device the operand lives on, not

@@ -3,7 +3,8 @@
 The ``yuv420`` pixel path moves the per-pixel colourspace work off the
 host (round 5's single host core was the throughput ceiling: 2026-07,
 previous transport, not reproduced) and onto the accelerator, where it
-fuses with the ingest normalization into one XLA kernel:
+fuses with the ingest normalization into one XLA producer for the
+network's first convolution:
 
     host:   y4m payload --pure byte gathers--> packed 4:2:0 planes
     wire:   1.5 bytes/pixel  (vs 3 for RGB u8, 6 for bf16 frames)
@@ -14,6 +15,19 @@ The reference did this balance the opposite way — NVVL's NVDEC decoded
 on the GPU *because the GPU had a video ASIC* (reference
 README.md:42-110). A TPU has none, so the split that minimizes host
 work and wire bytes is: gather on host, arithmetic on device.
+
+The normalization here is the plain jnp form
+(``ops.preprocess.normalize_u8_reference``), called directly and not
+through ``normalize_u8``: on a TPU that one is a Pallas kernel over a
+flat ``(M, 128)`` view, an opaque call the compiler cannot fuse
+through. It is for a u8 batch that arrives from the host with no
+consumer in its program. Here the u8 frames are computed in the same
+jit as the convolution that reads them, and the kernel between the two
+cost two relayouts and a 3-channel clip padded to 128 lanes twice a
+dispatch (PERF.md section 6, PR 32). Every caller of this module — the
+bucketed and the ragged stage program, the mesh step, the sharded
+ring — normalizes inside its consumer's jit, so the choice needs no
+knob.
 
 Packed layout per frame (geometry must be even): ``Y`` (H*W bytes),
 then ``U`` and ``V`` ((H/2)*(W/2) bytes each) — ``packed_frame_bytes``
@@ -34,7 +48,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from rnb_tpu.ops.preprocess import normalize_u8
+from rnb_tpu.ops.preprocess import normalize_u8_reference
 
 
 def packed_frame_bytes(height: int, width: int) -> int:
@@ -76,9 +90,11 @@ def normalize_yuv420(x, height: int = 112, width: int = 112,
     The u8 quantization step between conversion and normalization is
     kept deliberately: it makes the network's input identical to what
     a host-side converter would have produced, so accuracy is a
-    property of the pixel path, not of where it runs.
+    property of the pixel path, not of where it runs. The jnp
+    normalization, not the Pallas one: see the module docstring.
     """
-    return normalize_u8(yuv420_to_rgb_u8(x, height, width), dtype=dtype)
+    return normalize_u8_reference(yuv420_to_rgb_u8(x, height, width),
+                                  dtype=dtype)
 
 
 def yuv420_to_rgb_numpy(x: np.ndarray, height: int,

@@ -231,10 +231,12 @@ def make_sharded_apply(start: int, end: int, num_classes: int,
     """The sharded twin of model._shared_apply: ONE jitted
     ``shard_map`` body over the ring — the ingest (identical HLO to
     the unsharded applier's) on every member's replicated copy of the
-    input, then the sharded network. The ingest sits INSIDE the body
-    because it holds a Pallas kernel where this compiles for a TPU,
-    and the partitioner refuses a Mosaic kernel outside a shard_map
-    ("cannot be automatically partitioned"). A head range returns
+    input, then the sharded network. The ingest sits INSIDE the body:
+    the yuv420 one is plain jnp and fuses with the first convolution
+    there as it does unsharded; the dct one holds a Pallas kernel
+    where this compiles for a TPU, and the partitioner refuses a
+    Mosaic kernel outside a shard_map ("cannot be automatically
+    partitioned"). A head range returns
     logits still CHANNEL-SHARDED on the class axis (merge them with
     :func:`make_merge` — the host-timed collective); a mid-pipeline
     range's output is already full-width (the last temporal gather

@@ -160,12 +160,14 @@ def test_kernels_dispatch_on_the_platform_they_are_compiled_for():
 @pytest.mark.parametrize("pixel_path", ["yuv420", "dct"])
 @pytest.mark.parametrize("ragged", [False, True])
 def test_sharded_stage_lowers_for_a_tpu(pixel_path, ragged):
-    """The weight-sharded stage's applier, compiled for a TPU, holds a
-    Mosaic ingest kernel; outside a shard_map the partitioner refuses
-    one ("cannot be automatically partitioned" — what stopped
-    rnb-shard-d2 on its first four-chip run), so the ingest lives in
-    the body. Lowering for the TPU from here catches a regression
-    without a chip."""
+    """The weight-sharded stage's applier lowers for a TPU with its
+    ingest in the shard_map's body. The dct ingest is a Mosaic kernel
+    there; outside a shard_map the partitioner refuses one ("cannot
+    be automatically partitioned" — what stopped rnb-shard-d2 on its
+    first four-chip run). The yuv420 ingest is plain jnp (it
+    normalizes inside its consumer's jit, ops/yuv.py) and the program
+    holds no kernel at all. Lowering for the TPU from here catches a
+    regression of either without a chip."""
     import jax
     import jax.numpy as jnp
 
@@ -181,7 +183,7 @@ def test_sharded_stage_lowers_for_a_tpu(pixel_path, ragged):
         args += (jax.ShapeDtypeStruct((), jnp.int32),)
     text = stage._apply.trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == (pixel_path == "dct")
 
 
 def test_ring_dispatch_does_not_swallow_backend_errors(monkeypatch):

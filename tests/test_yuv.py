@@ -7,7 +7,10 @@ Contract under test (rnb_tpu/ops/yuv.py docstring):
     may contract mul+add into FMA);
   * luma is bit-exact with the RGB pixel path (same index map);
   * the loader's yuv420 mode ships packed u8 and the network stage's
-    fused ingest produces the same predictions as the rgb path.
+    fused ingest produces the same predictions as the rgb path;
+  * ``normalize_yuv420`` is the reference composition to the bit, and
+    the stage program compiled for a TPU holds no Pallas kernel and
+    no flat ``(M, 128)`` view of the clip between planes and stem.
 """
 
 import os
@@ -199,6 +202,111 @@ def test_normalize_yuv420_range():
     assert out.dtype == jnp.bfloat16
     f = np.asarray(out, dtype=np.float32)
     assert f.min() >= -1.0 and f.max() <= 1.0
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("lead,hw", [((2, 4), (112, 112)),
+                                     ((1, 3), (6, 10))])
+def test_normalize_yuv420_is_the_reference_composition(lead, hw, dtype):
+    """Jitted whole, the ingest equals conversion to u8 and the jnp
+    normalization run one after the other, to the bit: at the
+    production geometry and at one whose element count (540) is no
+    multiple of 128, the case the Pallas form never took."""
+    import jax
+    import jax.numpy as jnp
+    from rnb_tpu.ops.preprocess import normalize_u8_reference
+    from rnb_tpu.ops.yuv import normalize_yuv420
+    h, w = hw
+    dtype = getattr(jnp, dtype)
+    packed = np.random.default_rng(3).integers(
+        0, 256, lead + (packed_frame_bytes(h, w),), dtype=np.uint8)
+    rgb = jax.jit(lambda x: yuv420_to_rgb_u8(x, h, w))(packed)
+    assert rgb.dtype == jnp.uint8 and (rgb.size % 128 == 0) == (h == 112)
+    want = normalize_u8_reference(rgb, dtype=dtype)
+    got = jax.jit(lambda x: normalize_yuv420(x, h, w, dtype))(packed)
+    assert got.dtype == dtype and got.shape == lead + (h, w, 3)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_ragged_normalize_yuv420_zeroes_the_tail_and_matches_bucketed():
+    """Rows past ``rows_valid`` enter the converter as zero bytes
+    whatever the pool's tail held; the valid rows are the bucketed
+    ingest's, to the bit, for every ``rows_valid`` of one program."""
+    import jax
+    from rnb_tpu.ops.ragged import ragged_normalize_yuv420
+    from rnb_tpu.ops.yuv import normalize_yuv420
+    pool = np.random.default_rng(5).integers(
+        0, 256, (4, 2, packed_frame_bytes(112, 112)), dtype=np.uint8)
+    ragged = jax.jit(lambda x, n: ragged_normalize_yuv420(x, n, 112, 112))
+    bucketed = np.asarray(jax.jit(
+        lambda x: normalize_yuv420(x, 112, 112))(pool), np.float32)
+    pad = np.asarray(normalize_yuv420(np.zeros_like(pool[:1]), 112, 112),
+                     np.float32)
+    for valid in (0, 1, 3, 4):
+        out = np.asarray(ragged(pool, valid), np.float32)
+        np.testing.assert_array_equal(out[:valid], bucketed[:valid])
+        np.testing.assert_array_equal(
+            out[valid:], np.broadcast_to(pad, out[valid:].shape))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    # what is compiled for a described chip is written to the
+    # persistent cache and cannot be read back without one: off for
+    # these tests, and on again for whatever this worker runs next
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("rows", [8, 48])
+def test_stage_program_has_no_kernel_between_planes_and_stem(rows,
+                                                             one_chip):
+    """The bucketed yuv420 stage program of R(2+1)D-34 on 32-frame
+    clips, compiled for a described v5e (nothing runs): no Mosaic
+    custom call, and no instruction that views the clip as a flat
+    ``u8[M, 128]`` or ``bf16[M, 128]`` — the relayouts into and out of
+    the Pallas normalization, which cost a sixth of the device's time
+    (PERF.md section 6, PR 32). At the smallest and the largest
+    bucket: the compiler lays the ingest out by the row count."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.r2p1d import model as stage
+    from rnb_tpu.models.r2p1d.network import R2Plus1DClassifier
+    sizes, frames, hw = (3, 4, 6, 3), 32, stage.FRAME_HW
+    apply = stage._shared_apply(1, 5, 400, sizes, pixel_path="yuv420")
+    shapes = jax.eval_shape(
+        lambda k: R2Plus1DClassifier(layer_sizes=sizes).init(
+            k, np.zeros((1, 2, 14, 14, 3), np.float32), train=False),
+        jax.random.key(0))
+    variables = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), shapes)
+    text = apply.lower(variables, jax.ShapeDtypeStruct(
+        (rows, frames, packed_frame_bytes(hw, hw)), jnp.uint8,
+        sharding=one_chip)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    flat = rows * frames * hw * hw * 3 // 128
+    views = re.findall(r"= (?:u8|bf16)\[%d,128\]" % flat, text)
+    assert not views, views
+    # the stem's convolution is there, and reads a clip of 3 colours
+    assert re.search(r"bf16\[%d,%d,%d,%d,3\]" % (rows, frames, hw, hw),
+                     text)
 
 
 def test_loader_yuv_output_shape_and_pipeline_parity(tmp_path):
