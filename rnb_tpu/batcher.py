@@ -46,7 +46,7 @@ class Batcher(StageModel):
 
     ``segments`` (optional) is for a consumer whose rows are not
     independent: consecutive rows of one request are one sequence
-    (packed token rows, rnb_tpu.models.nemotron_h). The bucketed
+    (packed token rows, rnb_tpu.models.token_stages). The bucketed
     emission then carries its segment table (a RaggedBatch at the
     bucket's shape, validated on publish), and fusing runs up to the
     row cap: a batch that has reached the declared max rows is emitted

@@ -168,7 +168,7 @@ class BenchmarkResult:
     total_rows: int = 0
     pad_emissions: int = 0
     #: token accounting of stages whose rows are blocks of tokens
-    #: (rnb_tpu.models.nemotron_h): valid tokens / tokens shipped
+    #: (rnb_tpu.models.token_stages): valid tokens / tokens shipped
     #: (rows x tokens a row) over every dispatch served; 0 elsewhere
     tokens_valid: int = 0
     tokens_shipped: int = 0
@@ -180,6 +180,10 @@ class BenchmarkResult:
     experts_held: int = 0
     experts_max_per_expert: int = 0
     experts_mean_per_expert: float = 0.0
+    #: where the router chooses among groups of experts: the valid
+    #: tokens, summed over the expert layers, that sent the held
+    #: experts anything (``group_tokens=`` on the Experts: line)
+    experts_group_tokens: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all
@@ -1143,10 +1147,12 @@ def run_benchmark(config_path: str,
                     % (token_stats["valid"], token_stats["shipped"]))
         if expert_stats is not None:
             f.write("Experts: assignments=%d held=%d max_per_expert=%d "
-                    "mean_per_expert=%.3f\n"
+                    "mean_per_expert=%.3f%s\n"
                     % (expert_stats["assignments"], expert_stats["held"],
                        expert_stats["max_per_expert"],
-                       expert_stats["mean_per_expert"]))
+                       expert_stats["mean_per_expert"],
+                       " group_tokens=%d" % expert_stats["group_tokens"]
+                       if "group_tokens" in expert_stats else ""))
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -1502,6 +1508,8 @@ def run_benchmark(config_path: str,
                                 if expert_stats else 0),
         experts_mean_per_expert=(expert_stats["mean_per_expert"]
                                  if expert_stats else 0.0),
+        experts_group_tokens=(expert_stats.get("group_tokens", 0)
+                              if expert_stats else 0),
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

@@ -1,0 +1,272 @@
+"""The forward pass of a DeepSeek-V2 stack over a packed pool of rows.
+
+Every layer is ``x += attn(RMSNorm(x))``, ``x += ffn(RMSNorm(x))``: the
+attention is latent (MLA: queries and keys-values through low-rank
+latents, 64 rotary key columns shared by all heads), the feed-forward a
+gated MLP in the first ``first_k_dense_replace`` layers and sparse
+experts (softmax router, group-limited greedy choice, gated experts,
+shared experts every token visits) in the rest. After the last layer: a
+final RMSNorm and an untied head, on each request's last valid token.
+
+MLA runs in its *expanded* form: per head ``q = [q_nope | q_pe]`` and
+``k = [k_nope | k_pe]`` of 128 + 64 columns, values of 128, through the
+pool's flash kernel (``ops/segattn.py``) as 128 heads of their own.
+The *folded* form (``W_UK`` into the query, one latent key of 512 + 64
+and one latent value of 512 for all heads, ``W_UV`` behind the kernel)
+is the same mathematics and the decode path's; in prefill on the v5e it
+lost (PERF.md section 6, PR 33) and is not in the tree:
+``tests/test_deepseek_v2.py`` keeps its algebra against the reference.
+
+A *row* is ``chunk_size`` tokens; a request is a run of consecutive
+rows with its tail padded; a token's rotary position is its index
+inside its request (``ops/rope.py``). Weights and activations are
+bfloat16; the router's scores, the softmax, the norms' statistics, the
+rotary angles and every product's accumulation are float32. The
+rotary projections' columns are stored de-interleaved
+(``checkpoint.py``), which the published code does at run time.
+
+The named scopes are the ones the benchmark's reduction knows:
+``embed``, ``attn``, ``experts`` (a layer's feed-forward, the dense
+first layer's too), ``head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Mapping, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from rnb_tpu.ops import moe, rope, segattn
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV2Config:
+    """The sizes of one stack, under the published config's names."""
+
+    num_hidden_layers: int          # held here: the model's first so many
+    published_layers: int
+    first_k_dense_replace: int
+    hidden_size: int
+    vocab_size: int
+    chunk_size: int                 # tokens a row: the pipeline's, not the model's
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_shared_experts: int
+    router_experts: int
+    n_group: int
+    topk_group: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    scoring_func: str
+    rope_theta: float
+    rope_factor: float
+    rope_original: int
+    rope_beta_fast: float
+    rope_beta_slow: float
+    rope_mscale: float
+    rope_mscale_all_dim: float
+    eps: float
+
+    @staticmethod
+    def from_published(config: Mapping) -> "DeepseekV2Config":
+        """From a configuration file's keys: the published ones, with
+        ``num_hidden_layers`` the layers held here and
+        ``published.n_routed_experts`` the width of the router."""
+        published = config.get("published", {})
+        layers = int(config["num_hidden_layers"])
+        yarn = config["rope_scaling"]
+        if yarn.get("type") != "yarn" or config["moe_layer_freq"] != 1 \
+                or config["topk_method"] != "group_limited_greedy":
+            raise ValueError("rope_scaling.type, moe_layer_freq or "
+                             "topk_method: not the DeepSeek-V2 this "
+                             "network implements")
+        return DeepseekV2Config(
+            num_hidden_layers=layers,
+            published_layers=int(published.get("num_hidden_layers",
+                                               layers)),
+            first_k_dense_replace=int(config["first_k_dense_replace"]),
+            hidden_size=int(config["hidden_size"]),
+            vocab_size=int(config["vocab_size"]),
+            chunk_size=int(config["chunk_size"]),
+            num_attention_heads=int(config["num_attention_heads"]),
+            q_lora_rank=int(config["q_lora_rank"]),
+            kv_lora_rank=int(config["kv_lora_rank"]),
+            qk_nope_head_dim=int(config["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(config["qk_rope_head_dim"]),
+            v_head_dim=int(config["v_head_dim"]),
+            intermediate_size=int(config["intermediate_size"]),
+            moe_intermediate_size=int(config["moe_intermediate_size"]),
+            n_shared_experts=int(config["n_shared_experts"]),
+            router_experts=int(published.get(
+                "n_routed_experts", config["n_routed_experts"])),
+            n_group=int(config["n_group"]),
+            topk_group=int(config["topk_group"]),
+            num_experts_per_tok=int(config["num_experts_per_tok"]),
+            routed_scaling_factor=float(config["routed_scaling_factor"]),
+            norm_topk_prob=bool(config["norm_topk_prob"]),
+            scoring_func=str(config["scoring_func"]),
+            rope_theta=float(config["rope_theta"]),
+            rope_factor=float(yarn["factor"]),
+            rope_original=int(yarn["original_max_position_embeddings"]),
+            rope_beta_fast=float(yarn["beta_fast"]),
+            rope_beta_slow=float(yarn["beta_slow"]),
+            rope_mscale=float(yarn["mscale"]),
+            rope_mscale_all_dim=float(yarn["mscale_all_dim"]),
+            eps=float(config["rms_norm_eps"]))
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def num_expert_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def shared_intermediate_size(self) -> int:
+        return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_head_dim ** -0.5`` times YaRN's temperature, squared."""
+        return self.qk_head_dim ** -0.5 * rope.yarn_mscale(
+            self.rope_factor, self.rope_mscale_all_dim) ** 2
+
+    @property
+    def rotary_mscale(self) -> float:
+        """What the published code multiplies cos and sin by: 1 here."""
+        return rope.yarn_mscale(self.rope_factor, self.rope_mscale) \
+            / rope.yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+
+    def inv_freq(self):
+        return rope.yarn_inv_freq(
+            self.qk_rope_head_dim, self.rope_theta, self.rope_factor,
+            self.rope_original, self.rope_beta_fast, self.rope_beta_slow)
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+
+def held_slots(cfg: DeepseekV2Config, held: Sequence[int]):
+    """``ops/moe.held_slots`` over the router's experts."""
+    return moe.held_slots(cfg.router_experts, held)
+
+
+def rms_norm(x, weight, eps: float, out_dtype):
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (xf * weight.astype(jnp.float32)).astype(out_dtype)
+
+
+def _proj(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def latent_attention(cfg, p, h, row_start, positions, interpret=False):
+    """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
+    rows, q, _ = h.shape
+    act = h.dtype
+    heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    nope, value = cfg.qk_nope_head_dim, cfg.v_head_dim
+    inv_freq, scale = cfg.inv_freq(), cfg.softmax_scale
+    mscale = cfg.rotary_mscale
+    c_q = rms_norm(_proj(h, p["q_a"]), p["q_a_norm"], cfg.eps, act)
+    qs = _proj(c_q, p["q_b"]).reshape(rows, q, heads, cfg.qk_head_dim)
+    down = _proj(h, p["kv_a"])
+    c_kv = rms_norm(down[..., :rank], p["kv_a_norm"], cfg.eps, act)
+    kv = _proj(c_kv, p["kv_b"]).astype(act) \
+        .reshape(rows, q, heads, nope + value)
+    # the scores' scale (and the rotation) go onto the float32 queries,
+    # before their one rounding to the activations' dtype
+    q_pe = rope.rotate(qs[..., nope:], positions, inv_freq) * mscale
+    query = (jnp.concatenate([qs[..., :nope], q_pe], -1) * scale) \
+        .astype(act)
+    k_pe = (rope.rotate(down[..., rank:], positions, inv_freq) * mscale) \
+        .astype(act)
+    key = jnp.concatenate([
+        kv[..., :nope],
+        jnp.broadcast_to(k_pe[:, :, None, :],
+                         (rows, q, heads, cfg.qk_rope_head_dim))], -1)
+    out = segattn.packed_attention(query, key, kv[..., nope:], row_start,
+                                   interpret)
+    return _proj(out.reshape(rows, q, heads * value), p["o"])
+
+
+def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
+    """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,), the
+    valid tokens that sent the held experts anything)."""
+    rows, q, hidden = h.shape
+    flat = h.reshape(rows * q, hidden)
+    ok = token_ok.reshape(-1)
+    # the published code scales the chosen scores where it does not
+    # renormalise them, and the other way round
+    ids, weights = moe.route(
+        flat, p["router"], None, cfg.num_experts_per_tok,
+        1.0 if cfg.norm_topk_prob else cfg.routed_scaling_factor,
+        score=cfg.scoring_func, n_group=cfg.n_group,
+        topk_group=cfg.topk_group, renormalise=cfg.norm_topk_prob)
+    routed, counts = moe.held_experts(
+        flat, ids, weights, ok, slots, p["up"], p["down"],
+        interpret=interpret, gate=p["gate"])
+    out = routed + moe.dense_expert(flat, p["shared_up"], p["shared_down"],
+                                    p["shared_gate"])
+    sent = ((slots[ids] >= 0).any(-1) & ok).sum().astype(jnp.int32)
+    return out.reshape(rows, q, hidden), ids, counts, sent
+
+
+def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
+            row_start, last_idx, *, interpret=False):
+    """One packed dispatch.
+
+    ``tokens`` (rows, Q) int32; ``row_tokens`` (rows,) the valid tokens
+    of each row (0 on a pad row); ``row_start`` (rows,) the first row of
+    each row's request (its own index on a pad row); ``last_idx``
+    (rows,) the flat index of request i's last valid token (0 past the
+    last request); ``interpret`` runs the Pallas kernels in interpret
+    mode (a device that is no TPU).
+
+    -> (logits (rows, vocab) float32, one line a request; the router's
+    choices (expert layers, tokens, k) int32; assignments served by
+    each held expert (expert layers, held) int32, valid tokens only;
+    valid tokens of each expert layer that sent the held group anything
+    (expert layers,) int32).
+    """
+    rows, q = tokens.shape
+    token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
+    positions = rope.pool_positions(row_start, q)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+    act = x.dtype
+    chosen, served, sent = [], [], []
+    for i in range(cfg.num_hidden_layers):
+        p = params["l%d" % i]
+        with jax.named_scope("attn"):
+            h = rms_norm(x, p["attn_norm"], cfg.eps, act)
+            out = latent_attention(cfg, p, h, row_start, positions,
+                                   interpret)
+            x = (x.astype(jnp.float32) + out).astype(act)
+        with jax.named_scope("experts"):
+            h = rms_norm(x, p["ffn_norm"], cfg.eps, act)
+            if cfg.is_dense(i):
+                out = moe.dense_expert(h, p["up"], p["down"], p["gate"])
+            else:
+                out, ids, counts, tokens_sent = experts_ffn(
+                    cfg, p, h, token_ok, slots, interpret)
+                chosen.append(ids)
+                served.append(counts)
+                sent.append(tokens_sent)
+            x = (x.astype(jnp.float32) + out).astype(act)
+    with jax.named_scope("head"):
+        last = x.reshape(rows * q, -1)[last_idx]
+        last = rms_norm(last, params["final_norm"], cfg.eps, act)
+        logits = _proj(last, params["head"])
+    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent)

@@ -99,14 +99,14 @@ class NemotronHConfig:
     def blocks_of(self, kind: str) -> Tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.pattern) if k == kind)
 
+    @property
+    def num_expert_layers(self) -> int:
+        return self.pattern.count(EXPERTS)
+
 
 def held_slots(cfg: NemotronHConfig, held: Sequence[int]):
-    """(router_experts,) int32: an expert's position among the held
-    stacks, -1 where another chip holds it."""
-    slots = [-1] * cfg.router_experts
-    for pos, expert in enumerate(held):
-        slots[int(expert)] = pos
-    return jnp.asarray(slots, jnp.int32)
+    """``ops/moe.held_slots`` over the router's experts."""
+    return moe.held_slots(cfg.router_experts, held)
 
 
 def rms_norm(x, weight, eps: float, out_dtype):
@@ -170,7 +170,7 @@ def experts_mixer(cfg, p, h, token_ok, slots, expert_cast=None,
     precision: the tests' control on the CPU, never the program (the
     v5e's compiler fuses such a round trip in front of the grouped
     product and keeps the excess precision: on the chip
-    ``scripts/nemotron_control.py`` rounds the stored weights);
+    ``scripts/prefill_control.py`` rounds the stored weights);
     ``interpret`` runs the grouped product's kernel in interpret mode
     (a device that is no TPU)."""
     rows, q, hidden = h.shape

@@ -1,295 +1,9 @@
-"""The pipeline stages of the Nemotron-H family: a first stage whose
-request is a prompt file, and a final stage that runs the stack over a
-packed pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
-(``segments: true``), which fuses requests into row buckets up to the
-row cap and carries the segment table.
+"""The family's pipeline stages are the token families' shared ones
+(``rnb_tpu/models/token_stages.py``); the final stage learns the
+family from the recipe it is pointed at. The names below are the ones
+configuration files written before the move give."""
 
-The batch unit is the *row*: ``chunk_size`` tokens. A prompt of L
-tokens is ceil(L / chunk) consecutive rows, the last one's tail
-padded; two tensors travel: the token ids ``(rows, chunk)`` and the
-valid tokens of each row ``(rows,)``.
-"""
-
-from __future__ import annotations
-
-import json
-import os
-import re
-from typing import Optional
-
-import numpy as np
-
-from rnb_tpu import trace
-from rnb_tpu.compilestats import SignatureTracker
-from rnb_tpu.health import cards_of
-from rnb_tpu.stage import (PaddedBatch, StageModel,
-                           normalize_row_buckets)
-
-MAX_ROWS = 64
-CHUNK = 128
-
-
-def rows_of_tokens(num_tokens: int, chunk: int = CHUNK) -> int:
-    return -(-int(num_tokens) // int(chunk))
-
-
-class NemotronTokenLoader(StageModel):
-    """First stage: reads a request's prompt (a ``.npy`` of int32 token
-    ids) and emits its rows."""
-
-    def __init__(self, device, max_rows: int = MAX_ROWS,
-                 chunk: int = CHUNK, **kwargs):
-        super().__init__(device)
-        self.max_rows = int(max_rows)
-        self.chunk = int(chunk)
-
-    @classmethod
-    def output_shape_for(cls, max_rows: int = MAX_ROWS,
-                         chunk: int = CHUNK, **_kwargs):
-        return ((int(max_rows), int(chunk)), (int(max_rows),))
-
-    @staticmethod
-    def output_shape():
-        return ((MAX_ROWS, CHUNK), (MAX_ROWS,))
-
-    @classmethod
-    def output_dtype_for(cls, **_kwargs):
-        return "int32"
-
-    def __call__(self, tensors, non_tensors, time_card):
-        rid = getattr(time_card, "id", None)
-        with trace.span("tokens.read", rid):
-            ids = np.load(str(non_tensors)).astype(np.int32, copy=False)
-        count = int(ids.shape[0])
-        rows = rows_of_tokens(count, self.chunk)
-        if not 0 < rows <= self.max_rows:
-            raise ValueError("%s holds %d tokens: a request is 1 to %d "
-                             "rows of %d" % (non_tensors, count,
-                                             self.max_rows, self.chunk))
-        with trace.span("tokens.pack", rid, rows=rows, tokens_valid=count,
-                        segments=1):
-            packed = np.zeros((rows, self.chunk), np.int32)
-            packed.reshape(-1)[:count] = ids
-            row_tokens = np.full((rows,), self.chunk, np.int32)
-            row_tokens[-1] = count - (rows - 1) * self.chunk
-        time_card.num_clips = rows
-        time_card.num_tokens = count
-        return (PaddedBatch(packed, rows), PaddedBatch(row_tokens, rows)), \
-            non_tensors, time_card
-
-
-def dispatch_meta(offsets, row_tokens, rows: int, chunk: int):
-    """The (3, rows) int32 table one packed dispatch carries beside its
-    tokens: each row's valid tokens, the first row of its request (its
-    own index on a pad row), and the flat index of request i's last
-    valid token."""
-    offsets = np.asarray(offsets, np.int64)
-    valid = int(offsets[-1])
-    meta = np.zeros((3, rows), np.int32)
-    meta[0, :valid] = np.asarray(row_tokens[:valid], np.int32)
-    meta[1] = np.arange(rows)
-    spans = np.diff(offsets)
-    meta[1, :valid] = np.repeat(offsets[:-1], spans)
-    last_rows = offsets[1:] - 1
-    keep = spans > 0
-    meta[2, :len(spans)][keep] = \
-        last_rows[keep] * chunk + meta[0, last_rows[keep]] - 1
-    return meta
-
-
-class NemotronPrefill(StageModel):
-    """Final stage: embedding -> the blocks -> final norm -> head on
-    each request's last valid token, one jitted program a row bucket.
-    Weights are made on the device from the recipe at ``ckpt_path``.
-
-    It emits one value a request (the executor waits on it); the
-    logits stay on the device. While it serves, it keeps what the run's
-    check compares: the logits (and the router's choices) of ``samples``
-    requests, every ``sample_every``-th it serves, written under the
-    run's log directory when the stage ends."""
-
-    def __init__(self, device, ckpt_path: Optional[str] = None,
-                 max_rows: int = MAX_ROWS, chunk: int = CHUNK,
-                 row_buckets=None, num_warmups: int = 1,
-                 sample_every: int = 50, samples: int = 8, **kwargs):
-        super().__init__(device)
-        import jax
-
-        from rnb_tpu.models.nemotron_h import checkpoint, network
-        if ckpt_path is None:
-            raise ValueError("NemotronPrefill needs ckpt_path: the "
-                             "recipe its weights are made from")
-        self.cfg, seed, held = checkpoint.load_recipe(ckpt_path)
-        self.max_rows, self.chunk = int(max_rows), int(chunk)
-        if self.chunk != self.cfg.chunk_size:
-            raise ValueError("a row is chunk_size=%d tokens, not %d"
-                             % (self.cfg.chunk_size, self.chunk))
-        self.row_buckets = normalize_row_buckets(row_buckets,
-                                                 self.max_rows, "max_rows")
-        self._jax_device = device.resolve()
-        self._params = checkpoint.make_params(self.cfg, seed, held,
-                                              self._jax_device)
-        self._slots = jax.device_put(network.held_slots(self.cfg, held),
-                                     self._jax_device)
-        cfg = self.cfg
-        interpret = self._jax_device.platform != "tpu"
-
-        def apply(params, slots, tokens, meta):
-            logits, chosen, served = network.forward(
-                cfg, params, slots, tokens, meta[0], meta[1], meta[2],
-                interpret=interpret)
-            # one value a request goes back to the executor, which
-            # waits on it as on any stage's output
-            return logits[:, 0], logits, chosen, served
-        # one program a row bucket, compiled ahead: its text says which
-        # named scope each instruction came from (hlo_scopes)
-        self._programs = {}
-        self.hlo_scopes = {}
-        self.compiles = SignatureTracker()
-        for rows in self.row_buckets:
-            tokens = np.zeros((rows, self.chunk), np.int32)
-            meta = dispatch_meta((0, rows), np.full(rows, self.chunk),
-                                 rows, self.chunk)
-            self.compiles.observe(tokens)
-            program = jax.jit(apply).lower(
-                self._params, self._slots, tokens, meta).compile()
-            self.hlo_scopes.update(scopes_of_hlo(program.as_text()))
-            self._programs[rows] = program
-            for _ in range(int(num_warmups)):
-                jax.block_until_ready(program(
-                    self._params, self._slots, tokens, meta))
-        num_e = len(self.cfg.blocks_of(network.EXPERTS))
-        #: counters of the dispatches served (the Tokens: and Experts:
-        #: lines): valid and shipped tokens, and the assignments each
-        #: held expert of each E block served
-        self.tokens_valid = 0
-        self.tokens_shipped = 0
-        self.expert_served = np.zeros((num_e, len(held)), np.int64)
-        self._sample_every = max(1, int(sample_every))
-        self._samples_wanted = int(samples)
-        self._samples = []
-        self._served = 0
-        self._log_dir = None
-        #: (served, meta, rows) of the last dispatch: its counters are
-        #: read when the next call starts, after the executor's wait
-        self._pending = None
-
-    def bind_log_dir(self, log_dir: str) -> None:
-        self._log_dir = log_dir
-
-    @classmethod
-    def input_shape_for(cls, max_rows: int = MAX_ROWS, chunk: int = CHUNK,
-                        **_kwargs):
-        return ((int(max_rows), int(chunk)), (int(max_rows),))
-
-    def input_shape(self):
-        return self.input_shape_for(max_rows=self.max_rows,
-                                    chunk=self.chunk)
-
-    @classmethod
-    def input_dtype_for(cls, **_kwargs):
-        return "int32"
-
-    @classmethod
-    def output_shape_for(cls, max_rows: int = MAX_ROWS, **_kwargs):
-        return ((int(max_rows),),)
-
-    @staticmethod
-    def output_shape():
-        return ((MAX_ROWS,),)
-
-    @classmethod
-    def output_dtype_for(cls, **_kwargs):
-        return "float32"
-
-    def stage_counters(self) -> dict:
-        self._count_pending()
-        return {"tokens_valid": int(self.tokens_valid),
-                "tokens_shipped": int(self.tokens_shipped),
-                "expert_served": self.expert_served.copy(),
-                "experts_per_token": int(self.cfg.num_experts_per_tok)}
-
-    def _count_pending(self) -> None:
-        """The counters the last dispatch brought back with its logits
-        (the executor has waited for it: no extra synchronisation)."""
-        if self._pending is not None:
-            served, valid, rows = self._pending
-            self._pending = None
-            self.expert_served += np.asarray(served)
-            self.tokens_valid += valid
-            self.tokens_shipped += rows * self.chunk
-
-    def __call__(self, tensors, non_tensors, time_card):
-        self._count_pending()
-        pb, per_row = tensors
-        rows = pb.max_rows
-        offsets = getattr(pb, "segment_offsets", (0, int(pb.valid)))
-        tokens = np.asarray(pb.data, np.int32)
-        meta = dispatch_meta(offsets, np.asarray(per_row.data), rows,
-                             self.chunk)
-        self.compiles.observe(tokens)
-        done, logits, chosen, served = self._programs[rows](
-            self._params, self._slots, tokens, meta)
-        self._pending = (served, int(meta[0].sum()), rows)
-        cards = cards_of(time_card)
-        for seg, card in enumerate(cards):
-            self._served += 1
-            if self._served % self._sample_every == 0 \
-                    and len(self._samples) < self._samples_wanted \
-                    and len(cards) == len(offsets) - 1:
-                self._keep_sample(seg, card, offsets, tokens, meta, logits,
-                                  chosen, rows)
-        return (PaddedBatch(done, len(offsets) - 1),), non_tensors, \
-            time_card
-
-    def _keep_sample(self, seg, card, offsets, tokens, meta, logits,
-                     chosen, rows) -> None:
-        first = int(offsets[seg]) * self.chunk
-        count = int(meta[2, seg]) + 1 - first
-        self._samples.append({
-            "rid": int(card.id), "rows": rows,
-            "segments": len(offsets) - 1,
-            "tokens": tokens.reshape(-1)[first:first + count].copy(),
-            "logits": np.asarray(logits)[seg].astype(np.float32),
-            "chosen": np.asarray(chosen)[:, first:first + count].copy()})
-
-    def finalize(self) -> None:
-        """The stage has drained: write the samples and the scopes of
-        its programs' instructions, free the weights."""
-        self._count_pending()
-        if self._log_dir is not None:
-            for k, sample in enumerate(self._samples):
-                np.savez(os.path.join(self._log_dir,
-                                      "prefill-sample-%d.npz" % k),
-                         **sample)
-            with open(os.path.join(self._log_dir, "hlo-scopes.json"),
-                      "w") as f:
-                json.dump(self.hlo_scopes, f)
-        self._params = None
-        self._programs = None
-
-
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+\[[\d,]*\])")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-
-
-def scopes_of_hlo(text: str) -> dict:
-    """{"<instruction> <result shape>": op_name} for every instruction
-    of a compiled module's text that carries an ``op_name``: the path
-    of ``jax.named_scope``s it was traced under. The profiler names a
-    device operation by its instruction and not by its scope; this is
-    the table that joins the two (the same instruction name recurs in
-    each bucket's program with another shape)."""
-    out = {}
-    open_head = None
-    for line in text.splitlines():
-        head = _INSTRUCTION.match(line)
-        if head:
-            open_head = "%s %s" % head.groups()
-        # a Pallas kernel's attributes hold line breaks: its op_name
-        # follows on a later line of the same instruction
-        found = _OP_NAME.search(line)
-        if found and open_head is not None:
-            out[open_head] = found.group(1)
-            open_head = None
-    return out
+from rnb_tpu.models.token_stages import (  # noqa: F401
+    CHUNK, MAX_ROWS, TokenLoader as NemotronTokenLoader,
+    PackedPrefill as NemotronPrefill, dispatch_meta, rows_of_tokens,
+    scopes_of_hlo)

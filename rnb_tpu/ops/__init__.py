@@ -13,13 +13,16 @@ Every op exposes one public entry point that dispatches to the Pallas
 kernel on TPU backends and to an identical jnp formulation elsewhere
 (CPU tests, interpret mode), so numerics are defined once.
 
-The token-sequence family (rnb_tpu.models.nemotron_h) adds three
-mechanisms, each over a packed pool of rows with state confined to
-requests: ``ssd`` (the blocked Mamba-2 scan and its convolution, in
-plain jnp/lax), ``segattn`` (causal attention inside requests: JAX's
-Pallas splash kernel over the pool) and ``moe`` (routing over all
-experts and the held experts' part, whose grouped product is JAX's
-Pallas megablox kernel).
+The token-sequence families (rnb_tpu.models.nemotron_h,
+rnb_tpu.models.deepseek_v2) add four mechanisms, each over a packed
+pool of rows with state confined to requests: ``ssd`` (the blocked
+Mamba-2 scan and its convolution, in plain jnp/lax), ``segattn``
+(causal attention inside requests: JAX's Pallas splash kernel over the
+pool; values may be narrower than keys, latent attention's expanded
+form), ``rope`` (rotary positions that restart at each request, YaRN's
+frequencies) and ``moe`` (routing over all experts by the family's rule
+and the held experts' part, plain or gated, whose grouped product is
+JAX's Pallas megablox kernel).
 """
 
 from rnb_tpu.ops.preprocess import normalize_u8  # noqa: F401

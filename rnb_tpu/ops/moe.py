@@ -2,16 +2,20 @@
 over every expert the model has, and the held experts' part of the
 result as one grouped matrix product.
 
-The router scores all ``num_experts`` in float32 (``sigmoid``), picks
-the ``top_k`` largest of score + correction bias, and weights the
-chosen by their scores over the sum of the chosen, times a scaling
-factor — over all the chosen, held here or not. The layer is told
-which experts it holds (``held``: their ids, in the order of the
-stacked weights). Every (token, chosen expert) pair whose expert is
-held is computed, whatever the imbalance: pairs are sorted by held
-expert, the tokens gathered in that order, and the two projections of
-``relu(x U_e)^2 D_e`` run as grouped matrix products over the groups;
-pairs whose expert is held elsewhere (or whose token is padding) sort
+The router scores all ``num_experts`` in float32 and picks ``top_k``
+by the family's rule, which is :func:`route`'s arguments (Nemotron-H:
+``sigmoid``, the largest of score + correction bias, weights = the
+chosen scores over their sum; DeepSeek-V2: ``softmax``, the best
+groups of experts first, weights = the chosen scores as they are),
+times a scaling factor — over all the chosen, held here or not. An
+expert is two matrices with ``relu^2`` between them or three, gated
+(``gate``). The layer is told which experts it holds (``held``: their
+ids, in the order of the stacked weights). Every (token, chosen
+expert) pair whose expert is held is computed, whatever the
+imbalance: pairs are sorted by held expert, the tokens gathered in
+that order, and the projections of ``relu(x U_e)^2 D_e`` (or of
+``(silu(x G_e) * (x U_e)) D_e``) run as grouped matrix products over
+the groups; pairs whose expert is held elsewhere (or whose token is padding) sort
 behind the last group, belong to no group and cost no product. On one
 chip there is no exchange: what the absent experts would add is left
 out.
@@ -105,15 +109,52 @@ def grouped_matmul(rows, weights, counts, interpret: bool,
                interpret=interpret)
 
 
-def route(x, w_router, b_corr, top_k: int, scaling: float):
+def route(x, w_router, b_corr, top_k: int, scaling: float, *,
+          score: str = "sigmoid", n_group: int = 1, topk_group: int = 1,
+          renormalise: bool = True):
     """-> (ids (T, top_k) int32, weights (T, top_k) float32). ``x``
-    (T, hidden); ``w_router`` (hidden, E)."""
+    (T, hidden); ``w_router`` (hidden, E).
+
+    The rule is the family's: ``score`` (``sigmoid`` or ``softmax``
+    over the E logits, float32); ``b_corr`` (E,) a correction bias
+    added for the choice alone, or None; ``n_group`` > 1: the experts
+    lie in that many groups of E / n_group, a group's score is its
+    best expert's, the best ``topk_group`` groups stay and the rest
+    are masked to 0 before the ``top_k`` (group-limited greedy
+    routing: a token's experts lie on ``topk_group`` devices at most);
+    ``renormalise``: the chosen scores over their sum. Times
+    ``scaling``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=_HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, ids = lax.top_k(scores + b_corr.astype(jnp.float32), top_k)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError("router score %r" % (score,))
+    choice = scores if b_corr is None \
+        else scores + b_corr.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choice.reshape(choice.shape[0], n_group, -1)
+        _, best = lax.top_k(grouped.max(-1), topk_group)
+        kept = (best[:, :, None] == jnp.arange(n_group)).any(1)
+        choice = jnp.where(kept[:, :, None], grouped, 0.0) \
+            .reshape(choice.shape)
+    _, ids = lax.top_k(choice, top_k)
     picked = jnp.take_along_axis(scores, ids, axis=-1)
-    return ids, picked / picked.sum(-1, keepdims=True) * scaling
+    if renormalise:
+        picked = picked / picked.sum(-1, keepdims=True)
+    return ids, picked * scaling
+
+
+def held_slots(num_experts: int, held):
+    """(num_experts,) int32: an expert's position among the held
+    stacks (``held``: their ids, in the stacks' order), -1 where
+    another chip holds it."""
+    slots = [-1] * int(num_experts)
+    for pos, expert in enumerate(held):
+        slots[int(expert)] = pos
+    return jnp.asarray(slots, jnp.int32)
 
 
 def relu2(x):
@@ -121,8 +162,10 @@ def relu2(x):
 
 
 def held_experts(x, ids, weights, token_ok, held_slot, up, down,
-                 interpret: bool = False):
-    """The held experts' part of the layer's result.
+                 interpret: bool = False, gate=None):
+    """The held experts' part of the layer's result: ``relu(x U_e)^2
+    D_e`` or, where ``gate`` is given (stacked and stored like ``up``),
+    the gated form ``(silu(x G_e) * (x U_e)) D_e``.
 
     ``x`` (T, hidden); ``ids``/``weights`` (T, k) from :func:`route`;
     ``token_ok`` (T,) bool, False on padding; ``held_slot`` (E,) int32:
@@ -141,8 +184,12 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
         .astype(jnp.int32)
     rows = x[order // k]                                # (T*k, hidden)
     hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
-    hidden = relu2(hidden).astype(x.dtype)
-    out = grouped_matmul(hidden, down, counts, interpret)
+    if gate is None:
+        hidden = relu2(hidden)
+    else:
+        hidden = jax.nn.silu(grouped_matmul(
+            rows, gate, counts, interpret, transposed=True)) * hidden
+    out = grouped_matmul(hidden.astype(x.dtype), down, counts, interpret)
     # the way back: where each pair lies in expert order (the inverse
     # of ``order``), and one gather of the product's rows
     place = jnp.zeros_like(order).at[order].set(
@@ -155,9 +202,16 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     return back.sum(axis=1), counts
 
 
-def dense_expert(x, up, down):
-    """``relu(x U)^2 D`` for one expert every token visits (the shared
-    one): inputs in their dtype, float32 accumulation. -> float32."""
+def dense_expert(x, up, down, gate=None):
+    """``relu(x U)^2 D``, or ``(silu(x G) * (x U)) D`` where ``gate`` is
+    given, for one expert every token visits (the shared ones, a dense
+    layer's feed-forward): inputs in their dtype, float32
+    accumulation. -> float32."""
     hidden = jnp.dot(x, up, preferred_element_type=jnp.float32)
-    return jnp.dot(relu2(hidden).astype(x.dtype), down,
+    if gate is None:
+        hidden = relu2(hidden)
+    else:
+        hidden = jax.nn.silu(jnp.dot(
+            x, gate, preferred_element_type=jnp.float32)) * hidden
+    return jnp.dot(hidden.astype(x.dtype), down,
                    preferred_element_type=jnp.float32)

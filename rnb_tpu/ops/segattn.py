@@ -1,5 +1,8 @@
 """Causal grouped-query attention over a packed pool of rows, confined
-to requests, as one flash kernel over the whole pool.
+to requests, as one flash kernel over the whole pool. Multi-head
+attention is the case of one query head a key-value head, and latent
+attention (MLA) in its expanded form the case of values narrower than
+queries and keys.
 
 The pool holds ``rows`` of ``Q`` tokens; a request is a run of
 consecutive rows and ``row_start[r]`` is the first row of row r's
@@ -38,25 +41,28 @@ def _round_up(n: int, to: int) -> int:
 
 
 def packed_attention(q, k, v, row_start, interpret: bool = False):
-    """``q`` (rows, Q, Hq, D), already scaled by ``D ** -0.5``; ``k``,
-    ``v`` (rows, Q, Hk, D), each kv head serving Hq // Hk query heads;
-    ``row_start`` (rows,) int32. -> (rows, Q, Hq, D) in q's dtype.
+    """``q`` (rows, Q, Hq, D), already scaled (``D ** -0.5``, or the
+    family's own); ``k`` (rows, Q, Hk, D) and ``v`` (rows, Q, Hk, Dv),
+    each kv head serving Hq // Hk query heads (Dv may differ from D:
+    latent attention's 192 / 128); ``row_start`` (rows,) int32.
+    -> (rows, Q, Hq, Dv) in q's dtype.
 
-    The kernel wants whole blocks of tokens and whole lanes of D: a
-    pool or a head narrower than that (the tests' sizes) is padded
-    with tokens that are requests of their own and zero columns."""
-    rows, qlen, hq, dim = q.shape
-    hk = k.shape[2]
+    The kernel wants whole blocks of tokens and whole lanes of D and
+    Dv: a pool or a head narrower than that (the tests' sizes, a
+    192-wide q and k) is padded with tokens that are requests of their
+    own and zero columns."""
+    rows, qlen, hq, _ = q.shape
+    hk, dim_v = k.shape[2], v.shape[3]
     per = hq // hk
     tokens = rows * qlen
     block = min(_BLOCK, _round_up(tokens, _LANES))
     padded = _round_up(tokens, block)
-    lanes = _round_up(dim, _LANES)
 
     def heads_first(x, heads):
+        dim = x.shape[-1]
         x = x.reshape((tokens,) + heads + (dim,))
         x = jnp.pad(x, ((0, padded - tokens),) + ((0, 0),) * len(heads)
-                    + ((0, lanes - dim),))
+                    + ((0, _round_up(dim, _LANES) - dim),))
         return jnp.moveaxis(x, 0, -2)
 
     segment = jnp.concatenate([
@@ -71,5 +77,5 @@ def packed_attention(q, k, v, row_start, interpret: bool = False):
     out = jax.vmap(kernel, in_axes=(0, 0, 0, None))(
         heads_first(q, (hk, per)), heads_first(k, (hk,)),
         heads_first(v, (hk,)), splash.SegmentIds(segment, segment))
-    out = jnp.moveaxis(out, -2, 0)[:tokens, ..., :dim]
-    return out.reshape(rows, qlen, hq, dim)
+    out = jnp.moveaxis(out, -2, 0)[:tokens, ..., :dim_v]
+    return out.reshape(rows, qlen, hq, dim_v)

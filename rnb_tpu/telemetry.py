@@ -99,7 +99,7 @@ CONTENT_STAMPS = ("num_clips", "cache_hit", "cache_coalesced",
                   # the sibling copy's LOSER slot)
                   "hedge_resolved",
                   # valid tokens of a request whose rows are blocks of
-                  # tokens (rnb_tpu.models.nemotron_h): the fuse and
+                  # tokens (rnb_tpu.models.token_stages): the fuse and
                   # model_call spans sum it over a packed dispatch
                   "num_tokens")
 
@@ -426,9 +426,9 @@ TRACE_EVENT_REGISTRY = (
               "span: concatenating the pending requests' valid rows "
               "into one batch (rows, segments = requests; tokens_valid "
               "where the cards carry num_tokens)"),
-    StampSpec("tokens.read", "rnb_tpu/models/nemotron_h/stages.py",
+    StampSpec("tokens.read", "rnb_tpu/models/token_stages.py",
               "span: reading one request's prompt file"),
-    StampSpec("tokens.pack", "rnb_tpu/models/nemotron_h/stages.py",
+    StampSpec("tokens.pack", "rnb_tpu/models/token_stages.py",
               "span: one prompt into its rows of tokens (rows, "
               "tokens_valid, segments = 1)"),
     StampSpec("batcher.emit", "rnb_tpu/batcher.py",
@@ -888,7 +888,10 @@ def aggregate_stage_counters(snapshots):
     ``Tokens:`` and ``Experts:`` log-meta lines. Either is None where
     no stage counts it. The per-expert counts are summed over the
     instances before the most loaded one is taken (replicas of one
-    stage hold the same experts)."""
+    stage hold the same experts). ``group_tokens`` is there where a
+    stage counts it (a router that chooses among groups of experts):
+    the valid tokens, summed over the expert layers, that sent the
+    held experts anything."""
     import numpy as np
     tokens = experts = served = None
     for snap in snapshots:
@@ -903,6 +906,9 @@ def aggregate_stage_counters(snapshots):
             experts = experts or {"assignments": 0}
             experts["assignments"] += int(snap["tokens_valid"]) \
                 * int(snap["experts_per_token"]) * part.shape[0]
+            if "group_tokens" in snap:
+                experts["group_tokens"] = experts.get("group_tokens", 0) \
+                    + int(snap["group_tokens"])
     if experts is not None:
         experts.update(held=int(served.sum()),
                        max_per_expert=int(served.max()),

@@ -141,8 +141,9 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
                 meta["tokens_" + key] = int(val)
         elif line.startswith("Experts:"):
             # "Experts: assignments=A held=H max_per_expert=M
-            #  mean_per_expert=F" — sparse-expert accounting of a stage
-            # holding a share of each layer's experts
+            #  mean_per_expert=F [group_tokens=G]" — sparse-expert
+            # accounting of a stage holding a share of each layer's
+            # experts (G: tokens that sent the held group anything)
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta["experts_" + key] = float(val) if "." in val \

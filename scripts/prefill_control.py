@@ -1,0 +1,133 @@
+"""The lower-precision control of a token family's comparison, on the
+device it runs on: a few prompts packed into one dispatch at the
+configuration's real widths, compared with the family's float32
+reference (the router's choices given) once as the configuration states
+its precision and once an arm of its family below. The first must lie
+inside the family file's tolerance and a control outside it; the last
+stdout line is one JSON object with each arm's share of the spread.
+
+    python3 scripts/prefill_control.py [--config <file>] [--seed N]
+    chiprun -- python3 scripts/prefill_control.py --config \\
+        benchmarks/configs/deepseek-v2-ep8.json
+
+The configuration's file names the family. An arm is keyword arguments
+of the family's ``network.forward`` or a set of stored matrices rounded
+through float8 (e4m3) where they lie, each conversion a program of its
+own: inside the dispatch's program the v5e's compiler fuses bf16 ->
+float8 -> bf16 in front of the product and keeps the excess precision,
+and the arm then read the stated precision's logits bit for bit (PR 29,
+my chip run). Rounded weights stay rounded: such arms come last, the
+narrower set first.
+"""
+
+import argparse
+import importlib
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+_FFN = ("gate", "up", "down", "shared_gate", "shared_up", "shared_down")
+
+
+def arms_of(family: str):
+    """[(arm, forward's keyword arguments, which (group, tensor) to
+    round through float8 or None)], in the order they run."""
+    import jax.numpy as jnp
+    if family == "nemotron_h":
+        return [("as_stated", {}, None),
+                ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
+                ("experts_float8", {}, lambda group, name: name in (
+                    "up", "down", "shared_up", "shared_down"))]
+    if family == "deepseek_v2":
+        return [("as_stated", {}, None),
+                ("experts_float8", {}, lambda group, name: name in _FFN),
+                ("layers_float8", {}, lambda group, name: True)]
+    raise ValueError("no control arms for family %r" % (family,))
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--config", default=os.path.join(
+        REPO, "benchmarks", "configs", "nemotron3-nano-l14-ep2.json"))
+    parser.add_argument("--seed", type=int, default=2_500_000_017)
+    parser.add_argument("--lengths", default="300,1190,700,2400")
+    args = parser.parse_args(argv)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import manifest
+    from benchmarks.references import compare
+    from rnb_tpu.models import token_stages as stages
+    with open(args.config) as f:
+        config = json.load(f)
+    name = config["family"]
+    family = manifest.load_family(name)
+    reference = importlib.import_module("benchmarks.references." + name)
+    checkpoint, network = (
+        importlib.import_module("rnb_tpu.models.%s.%s" % (name, part))
+        for part in ("checkpoint", "network"))
+    published = family.published_keys(config)
+    recipe = os.path.join(REPO, "checkpoints", "control.recipe.json")
+    checkpoint.save_recipe(recipe, published, args.seed,
+                           family.held_experts(config))
+    cfg, _, held = checkpoint.load_recipe(recipe)
+    limit = float(config.get("share_of_spread", family.SHARE_OF_SPREAD))
+    device = jax.devices()[0]
+    params = checkpoint.make_params(cfg, args.seed, held, device)
+    slots = network.held_slots(cfg, held)
+    rng = np.random.default_rng(args.seed)
+    prompts = [rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32)
+               for n in args.lengths.split(",")]
+    chunk = cfg.chunk_size
+    rows = -(-sum(stages.rows_of_tokens(len(p), chunk)
+                  for p in prompts) // 16) * 16
+    tokens, meta, offsets = stages.pack_prompts(prompts, rows, chunk)
+    read = checkpoint.reference_reader(cfg, args.seed, device)
+    ref_model = reference.Reference(published)
+    out = {"device": device.device_kind, "family": name, "limit": limit,
+           "rows": rows, "lengths": [len(p) for p in prompts]}
+    arms = arms_of(name)
+    for arm, kwargs, rounded in arms:
+        if rounded is not None:
+            for group, block in params.items():
+                if isinstance(block, dict):
+                    for tensor, w in block.items():
+                        if w.ndim >= 2 and rounded(group, tensor):
+                            block[tensor] = w.astype(
+                                jnp.float8_e4m3fn).astype(w.dtype)
+        logits, chosen, *_ = jax.jit(
+            lambda p, s, t, m: network.forward(
+                cfg, p, s, t, m[0], m[1], m[2],
+                interpret=device.platform != "tpu", **kwargs))(
+            params, slots, tokens, meta)
+        chosen = np.asarray(chosen)
+        want, short = [], 0.0
+        with jax.default_matmul_precision("highest"):
+            for prompt, first in zip(prompts, offsets):
+                ref = ref_model.forward(
+                    read, prompt, held=held,
+                    forced=chosen[:, first * chunk:
+                                  first * chunk + len(prompt)])
+                want.append(np.asarray(ref["logits"]))
+                short = max([short] + [
+                    float(ref[key].max())
+                    for key in ("shortfall", "group_shortfall")
+                    if key in ref])
+        verdict = compare(np.asarray(logits)[:len(prompts)],
+                          np.stack(want), limit)
+        out[arm] = {"share_of_spread": verdict["share_of_spread"],
+                    "ok": verdict["ok"], "route_shortfall_max": short}
+        print("[control] %s %s" % (arm, out[arm]), file=sys.stderr,
+              flush=True)
+    out["ok"] = out["as_stated"]["ok"] and not all(
+        out[arm]["ok"] for arm, _, _ in arms[1:])
+    print(json.dumps(out))
+    return 0 if out["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
