@@ -32,6 +32,27 @@ cost 57 ms of a 215 ms dispatch on the v5e, where this costs 33: XLA
 sorts such a scatter's indices again and makes two passes over the
 array; my chip runs, PR 29.)
 
+The pairs lie expert-choice-major, ``(k, T)``: pair ``j * T + t`` is
+token ``t``'s ``j``-th chosen expert, and every array with a pair axis
+keeps the ``top_k`` choices as its *leading* axis. The device tiles an
+array's second-minor axis by 8 sublanes. Token-major, ``(T, k)``, the
+way back ended in ``out[place].reshape(T, k, hidden)``: float32 with
+k = 6 on that axis, so the reshape was a real copy into a padded
+``[8192, 8, hidden]`` (704 MB written at Nemotron's 2688, 1.34 GB at
+DeepSeek-V2's 5120: 1.92 and 3.65 ms an expert layer) and the masked
+sum read the padded form. As ``[k, 8192, hidden]`` the two minor axes
+are whole tiles, the reshape is a bitcast and the sum runs over the
+leading axis at the memory's speed: the experts' part of a 64-row
+dispatch 109.4 -> 88.0 ms and 34.2 -> 38.6 requests/s for Nemotron,
+118.8 -> 91.4 ms and 15.1 -> 16.1 for DeepSeek-V2 (my chip runs, PR
+34; 8 and 10 ms of that are the compiler's, which now keeps the source
+of the gather in in its fast memory: PERF.md section 6). Within a group the rows lie sorted by (j, t) and not by (t, j): a
+row's product does not depend on its neighbours. The sum adds the rows
+j = 0 ... k-1 in that order (read on the chip, to the bit); over the
+padded sublanes it had been ``((r0 + r4) + r2) + ((r1 + r5) + r3)``,
+so a result may differ from the older form's in its last bit. Callers
+pass and get ``(T, k)`` and ``(T, hidden)`` as before.
+
 The grouped product is JAX's Pallas kernel
 (``jax.experimental.pallas.ops.tpu.megablox.gmm``: one grid step a
 (row tile, group) pair that holds rows, float32 accumulator in VMEM).
@@ -176,13 +197,15 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     float32, counts (held,) int32: the pairs each held expert served)."""
     tokens, k = ids.shape
     held = up.shape[0]
-    slot = held_slot[ids]                               # (T, k)
-    here = token_ok[:, None] & (slot >= 0)              # served on this chip
+    # every pair axis below is (k, T): pair j*T + t is token t's j-th
+    # chosen expert (the module's docstring says why)
+    slot = held_slot[ids.T]                             # (k, T)
+    here = token_ok[None, :] & (slot >= 0)              # served on this chip
     flat_slot = jnp.where(here, slot, held).reshape(-1)
     order = jnp.argsort(flat_slot, stable=True)
     counts = jnp.bincount(flat_slot, length=held + 1)[:held] \
         .astype(jnp.int32)
-    rows = x[order // k]                                # (T*k, hidden)
+    rows = x[order % tokens]                            # (k*T, hidden)
     hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
     if gate is None:
         hidden = relu2(hidden)
@@ -194,12 +217,12 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     # of ``order``), and one gather of the product's rows
     place = jnp.zeros_like(order).at[order].set(
         jnp.arange(tokens * k, dtype=order.dtype), unique_indices=True)
-    back = out[place].reshape(tokens, k, -1)
+    back = out[place].reshape(k, tokens, -1)
     # a pair that is not served here lies behind the last group: its
     # row is whatever the kernel left there (0 x NaN is NaN), so the
     # mask is on the rows and not a zero weight
-    back = jnp.where(here[:, :, None], back * weights[:, :, None], 0.0)
-    return back.sum(axis=1), counts
+    back = jnp.where(here[:, :, None], back * weights.T[:, :, None], 0.0)
+    return back.sum(axis=0), counts
 
 
 def dense_expert(x, up, down, gate=None):

@@ -704,3 +704,113 @@ def test_real_configuration_keeps_the_published_sizes():
                for spec in tensors.values())
     assert abs(held / 1e9 - config["model"]["params_billions_held"]) < 0.01
     assert abs(2 * held / 2 ** 30 - config["model"]["weights_gib"]) < 0.01
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    # what is compiled for a described chip is written to the
+    # persistent cache and cannot be read back without one: off for
+    # this test, and on again for whatever this worker runs next
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def real_sizes():
+    """-> (the real configuration, its largest row bucket, the experts
+    this chip holds)."""
+    from rnb_tpu.models.deepseek_v2 import network
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.DeepseekV2Config.from_published(
+        mm.load_family(config["family"]).published_keys(config))
+    step = config["pipeline_config"]["pipeline"][-1]
+    return cfg, max(step["row_buckets"]), config["experts_held"]["count"]
+
+
+def test_a_gated_expert_layer_moves_its_pairs_once_each_way(one_chip):
+    """One expert layer's feed-forward of the real configuration at 64
+    rows, compiled for the described v5e (nothing runs): what
+    ``check_pair_buffers`` reads of the three stacks and the pair
+    buffers, and the kernel is called three times."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.deepseek_v2 import checkpoint, network
+    from tests.compiled_experts import check_pair_buffers
+    cfg, rows, held = real_sizes()
+    layer = next(i for i in range(cfg.num_hidden_layers)
+                 if not cfg.is_dense(i))
+    specs = checkpoint.tensor_specs(cfg, held)["l%d" % layer]
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def feed_forward(p, slots, x, token_ok):
+        with jax.named_scope("experts"):
+            h = network.rms_norm(x, p["ffn_norm"], cfg.eps, x.dtype)
+            out, ids, counts, sent = network.experts_ffn(
+                cfg, p, h, token_ok, slots)
+            return (x.astype(jnp.float32) + out).astype(x.dtype), \
+                ids, counts, sent
+    text = jax.jit(feed_forward).lower(
+        {name: of(specs[name].shape, getattr(jnp, specs[name].dtype))
+         for name in ("ffn_norm", "router", "up", "gate", "down",
+                      "shared_up", "shared_gate", "shared_down")},
+        of((cfg.router_experts,), jnp.int32),
+        of((rows, cfg.chunk_size, cfg.hidden_size), jnp.bfloat16),
+        of((rows, cfg.chunk_size), jnp.bool_)).compile().as_text()
+
+    d, inner = cfg.hidden_size, cfg.moe_intermediate_size
+    tokens, k = rows * cfg.chunk_size, cfg.num_experts_per_tok
+    stacks = {"bf16[%d,%d,%d]" % (held, a, b)
+              for a, b in ((d, inner), (inner, d))}
+    assert {"bf16[%s]" % ",".join(map(str, specs[t].shape))
+            for t in ("up", "gate", "down")} <= stacks
+    assert (tokens, k, d) == (8192, 6, 5120)
+    assert check_pair_buffers(text, tokens, k, d, stacks) \
+        == ["f32[%d,%d]" % (tokens * k, inner)] * 2 \
+        + ["f32[%d,%d]" % (tokens * k, d)]
+
+
+def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
+    """The real stage program at 64 rows, compiled for the described
+    v5e (nothing runs). A reading kept, not a mechanism of
+    ``held_experts``: with the pairs (k, T) the compiler's memory space
+    assignment keeps the tokens' rows in its fast memory for the
+    gather into expert order in all four expert layers, and that gather
+    takes 0.77 ms for the 3.73 it took from HBM in the (T, k) form (my
+    chip runs, PR 34). A change that moves this count has moved 3 ms a
+    layer of the dispatch, and says so."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.deepseek_v2 import checkpoint, network
+    from tests.compiled_experts import gather_in_sources
+    cfg, rows, held = real_sizes()
+    params = {}
+    for group, tensors in checkpoint.tensor_specs(cfg, held).items():
+        made = {name: jax.ShapeDtypeStruct(
+            spec.shape, getattr(jnp, spec.dtype), sharding=one_chip)
+            for name, spec in tensors.items()}
+        params.update(made if group == "top" else {group: made})
+
+    def of(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda p, s, t, m: network.forward(
+        cfg, p, s, t, m[0], m[1], m[2])).lower(
+        params, of((cfg.router_experts,)), of((rows, cfg.chunk_size)),
+        of((3, rows))).compile().as_text()
+    assert gather_in_sources(text, 8192, 6, 5120) == [True] * 4
