@@ -184,6 +184,15 @@ class BenchmarkResult:
     #: tokens, summed over the expert layers, that sent the held
     #: experts anything (``group_tokens=`` on the Experts: line)
     experts_group_tokens: int = 0
+    #: block-selected attention accounting of a stage whose stack
+    #: chooses key blocks (rnb_tpu.ops.blocksparse), over (valid query,
+    #: key-value head) pairs of every sparse layer: the pairs / those of
+    #: requests that select / the causal keys those could read / the
+    #: keys of the blocks they chose; 0 without such a stage
+    sparse_queries: int = 0
+    sparse_selecting: int = 0
+    sparse_causal_keys: int = 0
+    sparse_chosen_keys: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all
@@ -946,11 +955,14 @@ def run_benchmark(config_path: str,
                         "cache_hit_rows"):
                 ragged_stats[key] += int(snap.get(key, 0))
 
-    token_stats = expert_stats = None
+    token_stats = expert_stats = sparse_stats = None
     if stage_counter_sink:
-        from rnb_tpu.telemetry import aggregate_stage_counters
+        from rnb_tpu.telemetry import (SPARSE_COUNTS,
+                                       aggregate_sparse_counters,
+                                       aggregate_stage_counters)
         token_stats, expert_stats = aggregate_stage_counters(
             stage_counter_sink)
+        sparse_stats = aggregate_sparse_counters(stage_counter_sink)
 
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
@@ -1153,6 +1165,9 @@ def run_benchmark(config_path: str,
                        expert_stats["mean_per_expert"],
                        " group_tokens=%d" % expert_stats["group_tokens"]
                        if "group_tokens" in expert_stats else ""))
+        if sparse_stats is not None:
+            f.write("Sparse: %s\n" % " ".join(
+                "%s=%d" % (key, sparse_stats[key]) for key in SPARSE_COUNTS))
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -1510,6 +1525,8 @@ def run_benchmark(config_path: str,
                                  if expert_stats else 0.0),
         experts_group_tokens=(expert_stats.get("group_tokens", 0)
                               if expert_stats else 0),
+        **{"sparse_" + key: count
+           for key, count in (sparse_stats or {}).items()},
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

@@ -40,6 +40,10 @@ import jax.numpy as jnp
 
 from rnb_tpu.ops import moe, rope, segattn
 
+#: what ``forward`` returns behind the logits and the router's choices
+#: (``models/token_stages.py``)
+COUNTERS = ("expert_served", "group_tokens")
+
 
 @dataclasses.dataclass(frozen=True)
 class DeepseekV2Config:

@@ -24,6 +24,9 @@ import jax.numpy as jnp
 from rnb_tpu.ops import moe, segattn, ssd
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+#: what ``forward`` returns behind the logits and the router's choices
+#: (``models/token_stages.py``)
+COUNTERS = ("expert_served",)
 
 
 @dataclasses.dataclass(frozen=True)

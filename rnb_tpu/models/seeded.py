@@ -43,7 +43,7 @@ import numpy as np
 class TensorSpec:
     shape: Tuple[int, ...]
     dtype: str          # "bfloat16" | "float32"
-    kind: str           # normal | ones | a_log | dt_bias | uniform
+    kind: str           # normal | ones (x scale) | a_log | dt_bias | uniform
     scale: float = 1.0
     per_expert: bool = False   # leading axis = routed experts
     #: ``shape`` is the stored one: the published orientation, in which
@@ -93,7 +93,7 @@ def _drawer(spec: TensorSpec):
             x = jax.random.uniform(key, shape, jnp.float32,
                                    -spec.scale, spec.scale)
         elif spec.kind == "ones":
-            x = jnp.ones(shape, jnp.float32)
+            x = jnp.full(shape, spec.scale, jnp.float32)
         elif spec.kind == "a_log":
             x = jnp.log(jax.random.uniform(key, shape, jnp.float32,
                                            1.0, 16.0))

@@ -1,18 +1,39 @@
 """The pipeline stages of the token families (``nemotron_h``,
-``deepseek_v2``): a first stage whose request is a prompt file, and a
-final stage that runs a family's stack over a packed pool of rows.
-Between them stands ``rnb_tpu.batcher.Batcher`` (``segments: true``),
-which fuses requests into row buckets up to the row cap and carries the
-segment table.
+``deepseek_v2``, ``minicpm_sala``): a first stage whose request is a
+prompt file, and a final stage that runs a family's stack over a packed
+pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
+(``segments: true``), which fuses requests into row buckets up to the
+row cap and carries the segment table.
 
 A family is a package ``rnb_tpu.models.<family>`` that brings
 ``checkpoint.load_recipe(path) -> (cfg, seed, held)``,
-``checkpoint.make_params(cfg, seed, held, device)``,
-``network.held_slots(cfg, held)`` and ``network.forward(cfg, params,
-slots, tokens, row_tokens, row_start, last_idx, interpret=...) ->
-(logits, chosen, served[, group_tokens])``; its ``cfg`` says
-``chunk_size``, ``num_experts_per_tok`` and ``num_expert_layers``. The
-recipe the final stage is pointed at names the family.
+``checkpoint.make_params(cfg, seed, held, device)`` and
+``network.forward(cfg, params, slots, tokens, row_tokens, row_start,
+last_idx, interpret=...) -> (logits, chosen, *counts)``; its ``cfg``
+says ``chunk_size``. ``chosen`` is what the stack chose for each token
+and the run's check hands its reference (a router's experts, an
+attention's key blocks), token axis second; ``network.COUNTERS`` names
+the ``counts``, device counters of one dispatch, which the stage sums
+over the dispatches it serves and hands on by those names:
+
+``expert_served`` (expert layers, held): the assignments each held
+expert served; with it ``network.held_slots(cfg, held)`` makes
+``slots``, ``cfg.num_experts_per_tok`` is reported beside it, and
+``group_tokens`` (expert layers,) may follow: the valid tokens that
+sent the held experts anything (the ``Experts:`` line);
+
+``sparse`` (sparse layers, 4): (valid query, key-value head) pairs,
+those of requests that select key blocks, the causal keys those could
+read, the keys of the blocks they chose (the ``Sparse:`` line).
+
+A family without experts has no ``held_slots`` (its ``slots`` is
+None), counts no ``expert_served`` and gets no ``Experts:`` line; one
+that chooses no key blocks gets no ``Sparse:`` line. A family whose
+choices are not one row a token brings ``network.request_choices(cfg,
+chosen, first, count)``: what a sample keeps of ``chosen`` for the
+request of ``count`` tokens from flat token ``first``. The recipe the
+final stage is pointed at names the family. ``MAX_ROWS`` is the
+default row cap; a configuration's pipeline states its own.
 
 The batch unit is the *row*: ``chunk_size`` tokens. A prompt of L
 tokens is ceil(L / chunk) consecutive rows, the last one's tail
@@ -134,9 +155,10 @@ class PackedPrefill(StageModel):
 
     It emits one value a request (the executor waits on it); the
     logits stay on the device. While it serves, it keeps what the run's
-    check compares: the logits (and the router's choices) of ``samples``
-    requests, every ``sample_every``-th it serves, written under the
-    run's log directory when the stage ends."""
+    check compares: the logits (and the stack's choices: a router's
+    experts, an attention's key blocks) of ``samples`` requests, every
+    ``sample_every``-th it serves, written under the run's log directory
+    when the stage ends."""
 
     def __init__(self, device, ckpt_path: Optional[str] = None,
                  max_rows: int = MAX_ROWS, chunk: int = CHUNK,
@@ -168,8 +190,11 @@ class PackedPrefill(StageModel):
         self._jax_device = device.resolve()
         self._params = checkpoint.make_params(self.cfg, seed, held,
                                               self._jax_device)
-        self._slots = jax.device_put(network.held_slots(self.cfg, held),
-                                     self._jax_device)
+        self._slots = None
+        if "expert_served" in network.COUNTERS:
+            self._slots = jax.device_put(
+                network.held_slots(self.cfg, held), self._jax_device)
+        self._request_choices = getattr(network, "request_choices", None)
         cfg = self.cfg
         interpret = self._jax_device.platform != "tpu"
 
@@ -197,16 +222,13 @@ class PackedPrefill(StageModel):
             for _ in range(int(num_warmups)):
                 jax.block_until_ready(program(
                     self._params, self._slots, tokens, meta))
-        #: counters of the dispatches served (the Tokens: and Experts:
-        #: lines): valid and shipped tokens, the assignments each held
-        #: expert of each expert layer served and, where the family's
-        #: router chooses among groups of experts, the valid tokens of
-        #: each expert layer that sent the held experts anything
+        #: counters of the dispatches served: valid and shipped tokens
+        #: (the Tokens: line) and the family's own, by the names of
+        #: ``network.COUNTERS``, summed as they come back
         self.tokens_valid = 0
         self.tokens_shipped = 0
-        self.expert_served = np.zeros(
-            (self.cfg.num_expert_layers, len(held)), np.int64)
-        self.group_tokens = None
+        self._counter_names = tuple(network.COUNTERS)
+        self._counted = {}
         self._sample_every = max(1, int(sample_every))
         self._samples_wanted = int(samples)
         self._samples = []
@@ -245,25 +267,34 @@ class PackedPrefill(StageModel):
         return "float32"
 
     def stage_counters(self) -> dict:
+        """What ``telemetry.aggregate_stage_counters`` sums over a
+        run's stages: the tokens, and the family's counters under their
+        names (a family without experts reports no ``expert_served``,
+        one that chooses no key blocks no ``sparse``; none before the
+        first dispatch)."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
-                    "tokens_shipped": int(self.tokens_shipped),
-                    "expert_served": self.expert_served.copy(),
-                    "experts_per_token": int(self.cfg.num_experts_per_tok)}
-        if self.group_tokens is not None:
-            counters["group_tokens"] = int(self.group_tokens)
+                    "tokens_shipped": int(self.tokens_shipped)}
+        counted = self._counted
+        if "expert_served" in counted:
+            counters["expert_served"] = counted["expert_served"].copy()
+            counters["experts_per_token"] = int(
+                self.cfg.num_experts_per_tok)
+        if "group_tokens" in counted:
+            counters["group_tokens"] = int(counted["group_tokens"].sum())
+        if "sparse" in counted:
+            counters["sparse"] = counted["sparse"].sum(axis=0)
         return counters
 
     def _count_pending(self) -> None:
         """The counters the last dispatch brought back with its logits
         (the executor has waited for it: no extra synchronisation)."""
         if self._pending is not None:
-            (served, *group), valid, rows = self._pending
+            counts, valid, rows = self._pending
             self._pending = None
-            self.expert_served += np.asarray(served)
-            if group:
-                self.group_tokens = (self.group_tokens or 0) \
-                    + int(np.asarray(group[0]).sum())
+            for name, count in zip(self._counter_names, counts):
+                self._counted[name] = self._counted.get(name, 0) \
+                    + np.asarray(count, np.int64)
             self.tokens_valid += valid
             self.tokens_shipped += rows * self.chunk
 
@@ -294,12 +325,16 @@ class PackedPrefill(StageModel):
                      chosen, rows) -> None:
         first = int(offsets[seg]) * self.chunk
         count = int(meta[2, seg]) + 1 - first
+        if self._request_choices is None:
+            kept = np.asarray(chosen)[:, first:first + count].copy()
+        else:
+            kept = self._request_choices(self.cfg, chosen, first, count)
         self._samples.append({
             "rid": int(card.id), "rows": rows,
             "segments": len(offsets) - 1,
             "tokens": tokens.reshape(-1)[first:first + count].copy(),
             "logits": np.asarray(logits)[seg].astype(np.float32),
-            "chosen": np.asarray(chosen)[:, first:first + count].copy()})
+            "chosen": kept})
 
     def finalize(self) -> None:
         """The stage has drained: write the samples and the scopes of

@@ -14,9 +14,13 @@ kernel on TPU backends and to an identical jnp formulation elsewhere
 (CPU tests, interpret mode), so numerics are defined once.
 
 The token-sequence families (rnb_tpu.models.nemotron_h,
-rnb_tpu.models.deepseek_v2) add four mechanisms, each over a packed
-pool of rows with state confined to requests: ``ssd`` (the blocked
-Mamba-2 scan and its convolution, in plain jnp/lax), ``segattn``
+rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala) add five
+mechanisms, each over a packed pool of rows with state confined to
+requests: ``ssd`` (the blocked Mamba-2 scan and its convolution, in
+plain jnp/lax; lightning linear attention is its case of unit steps),
+``blocksparse`` (every query's own top-k blocks of keys from
+mean-compressed keys, and a Pallas flash kernel under that block
+mask), ``segattn``
 (causal attention inside requests: JAX's Pallas splash kernel over the
 pool; values may be narrower than keys, latent attention's expanded
 form), ``rope`` (rotary positions that restart at each request, YaRN's

@@ -1,5 +1,5 @@
 """The Mamba-2 state-space scan over a packed pool of rows, in its
-blocked (SSD) form, and the causal depthwise convolution in front of
+blocked (SSD) form (lightning linear attention is a case of it), and the causal depthwise convolution in front of
 it — both with the state reset where a request's first row starts.
 
 A *row* is one chunk of ``Q`` consecutive tokens (the configuration's
@@ -9,6 +9,13 @@ one of its own). Per head, with state ``S`` (``P`` x ``N``)::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t xs_t (x) B_t
     y_t = S_t C_t + D xs_t
+
+Linear attention with a constant decay a head (lightning attention:
+``S_t = lambda S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t``) is the same
+recurrence with unit steps and no skip term: ``dt = None``, ``A = log
+lambda``, ``xs = v``, ``B = k``, ``C = q`` (scaled by the caller), ``D
+= None``, one group a head. Its decays do not depend on the row, so
+they are computed once a head and not once a row.
 
 The blocked form computes each row's own tokens as one masked
 ``Q x Q`` product (the decays between two tokens of a row are
@@ -54,12 +61,13 @@ def segment_conv1d(x, weight, bias, row_first):
 
 
 def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
-    """The scan of one Mamba-2 block over a packed pool.
+    """The scan of one block over a packed pool.
 
     ``xs`` (rows, Q, H, P); ``dt`` (rows, Q, H) float32, after its
-    softplus; ``a`` (H,) float32, negative; ``b``, ``c`` (rows, Q, G,
-    N), head h reading group h // (H // G); ``d`` (H,) float32;
-    ``row_first`` (rows,) bool. -> float32 (rows, Q, H, P).
+    softplus, or None for unit steps; ``a`` (H,) float32, negative;
+    ``b``, ``c`` (rows, Q, G, N), head h reading group h // (H // G);
+    ``d`` (H,) float32 or None for no skip term; ``row_first`` (rows,)
+    bool. -> float32 (rows, Q, H, P).
 
     ``state_dtype`` is the precision the states are carried in
     between rows: float32 in the program; the lower-precision control
@@ -68,26 +76,31 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
     groups = b.shape[2]
     per = heads // groups
     xg = xs.reshape(rows, q, groups, per, p)
-    la = dt * a                                    # log decay a token
-    cs = jnp.cumsum(la, axis=1)                    # (rows, Q, H)
-    csg = cs.reshape(rows, q, groups, per)
-    dtg = dt.reshape(rows, q, groups, per)
+    # log decay a token: with unit steps the same in every row, and
+    # everything made of it keeps a leading axis of one
+    la = jnp.broadcast_to(a, (1, q, heads)) if dt is None else dt * a
+    cs = jnp.cumsum(la, axis=1)                    # (rows | 1, Q, H)
+    lead = cs.shape[0]
+    csg = cs.reshape(lead, q, groups, per)
 
     # a row's own tokens: (C_i . B_j) exp(cs_i - cs_j) dt_j, j <= i;
     # the two token axes are the minor ones, one Q x Q tile a head
     cb = jnp.einsum("rign,rjgn->rgij", c, b,
                     preferred_element_type=jnp.float32)
-    csh = csg.transpose(0, 2, 3, 1)                # (rows, G, per, Q)
-    dth = dtg.transpose(0, 2, 3, 1)
+    csh = csg.transpose(0, 2, 3, 1)                # (rows | 1, G, per, Q)
     tril = jnp.tril(jnp.ones((q, q), bool))
     decay = jnp.exp(jnp.where(
         tril, csh[..., :, None] - csh[..., None, :], -jnp.inf))
-    scores = cb[:, :, None] * decay * dth[..., None, :]
+    scores = cb[:, :, None] * decay
+    to_end = jnp.exp(csg[:, -1:, :, :] - csg)
+    if dt is not None:
+        dtg = dt.reshape(rows, q, groups, per)
+        scores = scores * dtg.transpose(0, 2, 3, 1)[..., None, :]
+        to_end = to_end * dtg
     y = jnp.einsum("rghij,rjghp->righp", scores.astype(xs.dtype), xg,
                    preferred_element_type=jnp.float32)
 
     # each row's end state from its own tokens
-    to_end = jnp.exp(csg[:, -1:, :, :] - csg) * dtg
     xw = xg.astype(jnp.float32) * to_end[..., None]
     state = jnp.einsum("rjghp,rjgn->rghpn", xw, b.astype(jnp.float32),
                        precision=_HIGHEST)         # (rows, G, per, P, N)
@@ -95,7 +108,7 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
     # states carried across the rows of a request: row r receives
     # sum over earlier rows q of its request of
     # exp(sum of the row decays strictly between) * state_q
-    row_decay = cs[:, -1, :]                       # (rows, H)
+    row_decay = jnp.broadcast_to(cs[:, -1, :], (rows, heads))
     cum = jnp.cumsum(row_decay, axis=0)
     seg = jnp.cumsum(row_first.astype(jnp.int32))
     idx = jnp.arange(rows)
@@ -113,7 +126,7 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
     y_in = jnp.einsum("rign,rghpn->righp", c.astype(jnp.float32),
                       incoming, precision=_HIGHEST)
     y = y + y_in * jnp.exp(csg)[..., None]
-    y = y + xg.astype(jnp.float32) \
-        * d.reshape(groups, per)[None, None, :, :, None]
+    if d is not None:
+        y = y + xg.astype(jnp.float32) \
+            * d.reshape(groups, per)[None, None, :, :, None]
     return y.reshape(rows, q, heads, p)
-

@@ -232,6 +232,13 @@ META_LINE_REGISTRY = (
               "each layer's experts: pairs routed, pairs whose expert "
               "is held here, most and mean served by one held expert "
               "of one layer (such stages only)"),
+    StampSpec("Sparse:", "rnb_tpu/benchmark.py",
+              "block-selected attention accounting of a stage whose "
+              "stack chooses key blocks, over (valid query, key-value "
+              "head) pairs of every sparse layer: the pairs, those of "
+              "requests that select, the causal keys those could "
+              "read, the keys of the blocks they chose (such stages "
+              "only)"),
     StampSpec("Compiles:", "rnb_tpu/benchmark.py",
               "JSON per-step jit-entry signature counts "
               "{step: {warmup, steady_new, steady_calls}} — "
@@ -914,3 +921,21 @@ def aggregate_stage_counters(snapshots):
                        max_per_expert=int(served.max()),
                        mean_per_expert=float(served.mean()))
     return tokens, experts
+
+
+#: the four counts of a ``sparse`` stage counter, in order
+SPARSE_COUNTS = ("queries", "selecting", "causal_keys", "chosen_keys")
+
+
+def aggregate_sparse_counters(snapshots):
+    """The numbers of the ``Sparse:`` log-meta line, summed over the
+    ``stage_counters()`` snapshots of a run's stage instances
+    (``rnb_tpu.ops.blocksparse`` says what each counts), or None where
+    no stage chooses key blocks."""
+    sparse = None
+    for snap in snapshots:
+        if snap.get("sparse") is not None:
+            sparse = sparse or dict.fromkeys(SPARSE_COUNTS, 0)
+            for name, count in zip(SPARSE_COUNTS, snap["sparse"]):
+                sparse[name] += int(count)
+    return sparse

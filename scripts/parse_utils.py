@@ -148,6 +148,13 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
                 key, _, val = part.partition("=")
                 meta["experts_" + key] = float(val) if "." in val \
                     else int(val)
+        elif line.startswith("Sparse:"):
+            # "Sparse: queries=N selecting=S causal_keys=C
+            #  chosen_keys=K" — block-selected attention accounting
+            # over (valid query, key-value head) pairs
+            for part in line.split(":", 1)[1].split():
+                key, _, val = part.partition("=")
+                meta["sparse_" + key] = int(val)
         elif line.startswith("Compiles:"):
             # JSON {step: {warmup, steady_new, steady_calls}} —
             # jit-entry signature accounting (rnb_tpu.compilestats);

@@ -10,8 +10,10 @@ stdout line is one JSON object with each arm's share of the spread.
     chiprun -- python3 scripts/prefill_control.py --config \\
         benchmarks/configs/deepseek-v2-ep8.json
 
-The configuration's file names the family. An arm is keyword arguments
-of the family's ``network.forward`` or a set of stored matrices rounded
+The configuration's family file writes the recipe, and the recipe names
+the family whose program, reference and arms are used: one script for
+every token family. An arm is keyword arguments of the family's
+``network.forward`` or a set of stored matrices rounded
 through float8 (e4m3) where they lie, each conversion a program of its
 own: inside the dispatch's program the v5e's compiler fuses bf16 ->
 float8 -> bf16 in front of the product and keeps the excess precision,
@@ -45,6 +47,10 @@ def arms_of(family: str):
         return [("as_stated", {}, None),
                 ("experts_float8", {}, lambda group, name: name in _FFN),
                 ("layers_float8", {}, lambda group, name: True)]
+    if family == "minicpm_sala":
+        return [("as_stated", {}, None),
+                ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
+                ("layers_float8", {}, lambda group, name: True)]
     raise ValueError("no control arms for family %r" % (family,))
 
 
@@ -53,7 +59,9 @@ def main(argv=None) -> int:
     parser.add_argument("--config", default=os.path.join(
         REPO, "benchmarks", "configs", "nemotron3-nano-l14-ep2.json"))
     parser.add_argument("--seed", type=int, default=2_500_000_017)
-    parser.add_argument("--lengths", default="300,1190,700,2400")
+    parser.add_argument("--lengths", default=None, help="prompt lengths, "
+                        "comma-separated (default: the family file's "
+                        "CONTROL_LENGTHS, else 300,1190,700,2400)")
     args = parser.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -61,27 +69,30 @@ def main(argv=None) -> int:
 
     from benchmarks import manifest
     from benchmarks.references import compare
-    from rnb_tpu.models import token_stages as stages
+    from rnb_tpu.models import seeded, token_stages as stages
     with open(args.config) as f:
         config = json.load(f)
-    name = config["family"]
-    family = manifest.load_family(name)
+    family = manifest.load_family(config["family"])
+    recipe, _ = family.make_weights(
+        config, args.seed, os.path.join(REPO, "checkpoints", "control"))
+    name = seeded.read_recipe(recipe)["family"]
     reference = importlib.import_module("benchmarks.references." + name)
     checkpoint, network = (
         importlib.import_module("rnb_tpu.models.%s.%s" % (name, part))
         for part in ("checkpoint", "network"))
     published = family.published_keys(config)
-    recipe = os.path.join(REPO, "checkpoints", "control.recipe.json")
-    checkpoint.save_recipe(recipe, published, args.seed,
-                           family.held_experts(config))
     cfg, _, held = checkpoint.load_recipe(recipe)
     limit = float(config.get("share_of_spread", family.SHARE_OF_SPREAD))
     device = jax.devices()[0]
     params = checkpoint.make_params(cfg, args.seed, held, device)
-    slots = network.held_slots(cfg, held)
+    # a family without experts has no slots (models/token_stages.py)
+    slots = network.held_slots(cfg, held) \
+        if "expert_served" in network.COUNTERS else None
+    lengths = args.lengths.split(",") if args.lengths else getattr(
+        family, "CONTROL_LENGTHS", (300, 1190, 700, 2400))
     rng = np.random.default_rng(args.seed)
     prompts = [rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32)
-               for n in args.lengths.split(",")]
+               for n in lengths]
     chunk = cfg.chunk_size
     rows = -(-sum(stages.rows_of_tokens(len(p), chunk)
                   for p in prompts) // 16) * 16
@@ -108,10 +119,16 @@ def main(argv=None) -> int:
         want, short = [], 0.0
         with jax.default_matmul_precision("highest"):
             for prompt, first in zip(prompts, offsets):
-                ref = ref_model.forward(
-                    read, prompt, held=held,
-                    forced=chosen[:, first * chunk:
-                                  first * chunk + len(prompt)])
+                # the request's own choices, as a sample keeps them
+                # (models/token_stages.py) and the run's check reads them
+                keep = getattr(network, "request_choices", None)
+                forced = chosen[:, first * chunk:
+                                first * chunk + len(prompt)] \
+                    if keep is None else family.unpack_choices(
+                        config, keep(cfg, chosen, first * chunk,
+                                     len(prompt)), len(prompt))
+                ref = ref_model.forward(read, prompt, held=held,
+                                        forced=forced)
                 want.append(np.asarray(ref["logits"]))
                 short = max([short] + [
                     float(ref[key].max())

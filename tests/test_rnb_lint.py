@@ -336,6 +336,7 @@ def test_unregistered_meta_line_triggers_t004(tmp_path):
                      'f.write("Padding: pad_rows=%d\\n" % pd)\n'
                      'f.write("Tokens: valid=%d\\n" % tk)\n'
                      'f.write("Experts: assignments=%d\\n" % ex)\n'
+                     'f.write("Sparse: queries=%d\\n" % sq)\n'
                      'f.write("Handoff: edges=%d\\n" % ho)\n'
                      'f.write("Handoff edges: %s\\n" % he)\n'
                      'f.write("Placement: %s\\n" % pl)\n'
