@@ -193,6 +193,12 @@ class BenchmarkResult:
     sparse_selecting: int = 0
     sparse_causal_keys: int = 0
     sparse_chosen_keys: int = 0
+    #: packed flash attention accounting of a stage whose stack runs
+    #: it (rnb_tpu.ops.segattn), over every attention layer of every
+    #: dispatch: the tiles the dispatch's block table let the kernel
+    #: run / the tiles on or under the diagonal; 0 without such a stage
+    attention_tiles_visited: int = 0
+    attention_tiles_causal: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all
@@ -955,14 +961,17 @@ def run_benchmark(config_path: str,
                         "cache_hit_rows"):
                 ragged_stats[key] += int(snap.get(key, 0))
 
-    token_stats = expert_stats = sparse_stats = None
+    token_stats = expert_stats = sparse_stats = attention_stats = None
     if stage_counter_sink:
-        from rnb_tpu.telemetry import (SPARSE_COUNTS,
-                                       aggregate_sparse_counters,
+        from rnb_tpu.telemetry import (ATTENTION_COUNTS, SPARSE_COUNTS,
+                                       aggregate_counts,
                                        aggregate_stage_counters)
         token_stats, expert_stats = aggregate_stage_counters(
             stage_counter_sink)
-        sparse_stats = aggregate_sparse_counters(stage_counter_sink)
+        sparse_stats = aggregate_counts(stage_counter_sink, "sparse",
+                                        SPARSE_COUNTS)
+        attention_stats = aggregate_counts(stage_counter_sink,
+                                           "attn_tiles", ATTENTION_COUNTS)
 
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
@@ -1168,6 +1177,10 @@ def run_benchmark(config_path: str,
         if sparse_stats is not None:
             f.write("Sparse: %s\n" % " ".join(
                 "%s=%d" % (key, sparse_stats[key]) for key in SPARSE_COUNTS))
+        if attention_stats is not None:
+            f.write("Attention: %s\n" % " ".join(
+                "%s=%d" % (key, attention_stats[key])
+                for key in ATTENTION_COUNTS))
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -1527,6 +1540,8 @@ def run_benchmark(config_path: str,
                               if expert_stats else 0),
         **{"sparse_" + key: count
            for key, count in (sparse_stats or {}).items()},
+        **{"attention_" + key: count
+           for key, count in (attention_stats or {}).items()},
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

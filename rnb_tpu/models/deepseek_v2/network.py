@@ -42,7 +42,7 @@ from rnb_tpu.ops import moe, rope, segattn
 
 #: what ``forward`` returns behind the logits and the router's choices
 #: (``models/token_stages.py``)
-COUNTERS = ("expert_served", "group_tokens")
+COUNTERS = ("expert_served", "group_tokens", "attn_tiles")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,7 +176,8 @@ def _proj(x, w):
 
 
 def latent_attention(cfg, p, h, row_start, positions, interpret=False):
-    """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
+    """``h`` (rows, Q, hidden), normed -> (float32 (rows, Q, hidden),
+    the flash kernel's tiles: run, and on or under the diagonal)."""
     rows, q, _ = h.shape
     act = h.dtype
     heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
@@ -200,9 +201,9 @@ def latent_attention(cfg, p, h, row_start, positions, interpret=False):
         kv[..., :nope],
         jnp.broadcast_to(k_pe[:, :, None, :],
                          (rows, q, heads, cfg.qk_rope_head_dim))], -1)
-    out = segattn.packed_attention(query, key, kv[..., nope:], row_start,
-                                   interpret)
-    return _proj(out.reshape(rows, q, heads * value), p["o"])
+    out, tiles = segattn.packed_attention(query, key, kv[..., nope:],
+                                          row_start, interpret)
+    return _proj(out.reshape(rows, q, heads * value), p["o"]), tiles
 
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
@@ -242,7 +243,9 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
     choices (expert layers, tokens, k) int32; assignments served by
     each held expert (expert layers, held) int32, valid tokens only;
     valid tokens of each expert layer that sent the held group anything
-    (expert layers,) int32).
+    (expert layers,) int32; the flash kernel's tiles (layers, 2) int32:
+    those this dispatch's block table let run, and those on or under
+    the diagonal).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
@@ -250,14 +253,15 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, served, sent = [], [], []
+    chosen, served, sent, tiles = [], [], [], []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         with jax.named_scope("attn"):
             h = rms_norm(x, p["attn_norm"], cfg.eps, act)
-            out = latent_attention(cfg, p, h, row_start, positions,
-                                   interpret)
+            out, ran = latent_attention(cfg, p, h, row_start, positions,
+                                        interpret)
             x = (x.astype(jnp.float32) + out).astype(act)
+            tiles.append(ran)
         with jax.named_scope("experts"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)
             if cfg.is_dense(i):
@@ -273,4 +277,5 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
         last = x.reshape(rows * q, -1)[last_idx]
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
-    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent)
+    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent), \
+        jnp.stack(tiles)

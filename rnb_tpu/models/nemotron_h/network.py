@@ -26,7 +26,7 @@ from rnb_tpu.ops import moe, segattn, ssd
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 #: what ``forward`` returns behind the logits and the router's choices
 #: (``models/token_stages.py``)
-COUNTERS = ("expert_served",)
+COUNTERS = ("expert_served", "attn_tiles")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,6 +152,8 @@ def mamba_mixer(cfg, p, h, row_first, state_dtype=jnp.float32):
 
 
 def attention_mixer(cfg, p, h, row_start, interpret=False):
+    """-> (float32 (rows, Q, hidden), the flash kernel's tiles: run, and
+    on or under the diagonal)."""
     rows, q, _ = h.shape
     act = h.dtype
     hq, hk, dim = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -162,8 +164,8 @@ def attention_mixer(cfg, p, h, row_start, interpret=False):
         .reshape(rows, q, hq, dim)
     ks = _proj(h, p["k"]).astype(act).reshape(rows, q, hk, dim)
     vs = _proj(h, p["v"]).astype(act).reshape(rows, q, hk, dim)
-    out = segattn.packed_attention(qs, ks, vs, row_start, interpret)
-    return _proj(out.reshape(rows, q, hq * dim), p["o"])
+    out, tiles = segattn.packed_attention(qs, ks, vs, row_start, interpret)
+    return _proj(out.reshape(rows, q, hq * dim), p["o"]), tiles
 
 
 def experts_mixer(cfg, p, h, token_ok, slots, expert_cast=None,
@@ -207,7 +209,9 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
 
     -> (logits (rows, vocab) float32, one line a request; the router's
     choices (E blocks, tokens, k) int32; assignments served by each
-    held expert (E blocks, held) int32, valid tokens only).
+    held expert (E blocks, held) int32, valid tokens only; the flash
+    kernel's tiles (attention blocks, 2) int32: those this dispatch's
+    block table let run, and those on or under the diagonal).
     """
     rows, q = tokens.shape
     row_first = row_start == jnp.arange(rows)
@@ -215,7 +219,7 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, served = [], []
+    chosen, served, tiles = [], [], []
     for i, kind in enumerate(cfg.pattern):
         p = params["b%d" % i]
         if kind == MAMBA:
@@ -226,8 +230,9 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
         elif kind == ATTENTION:
             with jax.named_scope("attn"):
                 h = rms_norm(x, p["norm"], cfg.eps, act)
-                out = attention_mixer(cfg, p, h, row_start, interpret)
+                out, ran = attention_mixer(cfg, p, h, row_start, interpret)
                 x = (x.astype(jnp.float32) + out).astype(act)
+                tiles.append(ran)
         elif kind == EXPERTS:
             with jax.named_scope("experts"):
                 h = rms_norm(x, p["norm"], cfg.eps, act)
@@ -242,4 +247,4 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
         last = x.reshape(rows * q, -1)[last_idx]
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
-    return logits, jnp.stack(chosen), jnp.stack(served)
+    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(tiles)

@@ -24,11 +24,17 @@ sent the held experts anything (the ``Experts:`` line);
 
 ``sparse`` (sparse layers, 4): (valid query, key-value head) pairs,
 those of requests that select key blocks, the causal keys those could
-read, the keys of the blocks they chose (the ``Sparse:`` line).
+read, the keys of the blocks they chose (the ``Sparse:`` line);
+
+``attn_tiles`` (attention layers, 2): the (query block, key block)
+tiles of the packed flash kernel (``ops/segattn.py``) that the
+dispatch's block table let run, and those on or under the diagonal
+(the ``Attention:`` line).
 
 A family without experts has no ``held_slots`` (its ``slots`` is
 None), counts no ``expert_served`` and gets no ``Experts:`` line; one
-that chooses no key blocks gets no ``Sparse:`` line. A family whose
+that chooses no key blocks gets no ``Sparse:`` line, one that runs no
+packed flash kernel no ``Attention:`` line. A family whose
 choices are not one row a token brings ``network.request_choices(cfg,
 chosen, first, count)``: what a sample keeps of ``chosen`` for the
 request of ``count`` tokens from flat token ``first``. The recipe the
@@ -270,8 +276,9 @@ class PackedPrefill(StageModel):
         """What ``telemetry.aggregate_stage_counters`` sums over a
         run's stages: the tokens, and the family's counters under their
         names (a family without experts reports no ``expert_served``,
-        one that chooses no key blocks no ``sparse``; none before the
-        first dispatch)."""
+        one that chooses no key blocks no ``sparse``, one without the
+        packed flash kernel no ``attn_tiles``; none before the first
+        dispatch)."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
                     "tokens_shipped": int(self.tokens_shipped)}
@@ -282,8 +289,9 @@ class PackedPrefill(StageModel):
                 self.cfg.num_experts_per_tok)
         if "group_tokens" in counted:
             counters["group_tokens"] = int(counted["group_tokens"].sum())
-        if "sparse" in counted:
-            counters["sparse"] = counted["sparse"].sum(axis=0)
+        for name in ("sparse", "attn_tiles"):
+            if name in counted:
+                counters[name] = counted[name].sum(axis=0)
         return counters
 
     def _count_pending(self) -> None:

@@ -239,6 +239,12 @@ META_LINE_REGISTRY = (
               "requests that select, the causal keys those could "
               "read, the keys of the blocks they chose (such stages "
               "only)"),
+    StampSpec("Attention:", "rnb_tpu/benchmark.py",
+              "packed flash attention accounting of a stage whose "
+              "stack runs it, over every attention layer of every "
+              "dispatch: the (query block, key block) tiles the "
+              "dispatch's block table let the kernel run, and the "
+              "tiles on or under the diagonal (such stages only)"),
     StampSpec("Compiles:", "rnb_tpu/benchmark.py",
               "JSON per-step jit-entry signature counts "
               "{step: {warmup, steady_new, steady_calls}} — "
@@ -924,18 +930,22 @@ def aggregate_stage_counters(snapshots):
 
 
 #: the four counts of a ``sparse`` stage counter, in order
+#: (``rnb_tpu.ops.blocksparse`` says what each counts: the ``Sparse:``
+#: line), and the two of ``attn_tiles`` (``rnb_tpu.ops.segattn``: the
+#: ``Attention:`` line)
 SPARSE_COUNTS = ("queries", "selecting", "causal_keys", "chosen_keys")
+ATTENTION_COUNTS = ("tiles_visited", "tiles_causal")
 
 
-def aggregate_sparse_counters(snapshots):
-    """The numbers of the ``Sparse:`` log-meta line, summed over the
-    ``stage_counters()`` snapshots of a run's stage instances
-    (``rnb_tpu.ops.blocksparse`` says what each counts), or None where
-    no stage chooses key blocks."""
-    sparse = None
+def aggregate_counts(snapshots, counter, names):
+    """{name: count} of the stage counter ``counter``, a vector of
+    ``names``, summed over the ``stage_counters()`` snapshots of a
+    run's stage instances: the numbers of its log-meta line, or None
+    where no stage counts it."""
+    total = None
     for snap in snapshots:
-        if snap.get("sparse") is not None:
-            sparse = sparse or dict.fromkeys(SPARSE_COUNTS, 0)
-            for name, count in zip(SPARSE_COUNTS, snap["sparse"]):
-                sparse[name] += int(count)
-    return sparse
+        if snap.get(counter) is not None:
+            total = total or dict.fromkeys(names, 0)
+            for name, count in zip(names, snap[counter]):
+                total[name] += int(count)
+    return total

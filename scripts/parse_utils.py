@@ -148,13 +148,16 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
                 key, _, val = part.partition("=")
                 meta["experts_" + key] = float(val) if "." in val \
                     else int(val)
-        elif line.startswith("Sparse:"):
+        elif line.startswith(("Sparse:", "Attention:")):
             # "Sparse: queries=N selecting=S causal_keys=C
             #  chosen_keys=K" — block-selected attention accounting
-            # over (valid query, key-value head) pairs
-            for part in line.split(":", 1)[1].split():
+            # over (valid query, key-value head) pairs;
+            # "Attention: tiles_visited=V tiles_causal=C" — the packed
+            # flash kernel's tiles, run and on or under the diagonal
+            name, counts = line.split(":", 1)
+            for part in counts.split():
                 key, _, val = part.partition("=")
-                meta["sparse_" + key] = int(val)
+                meta["%s_%s" % (name.lower(), key)] = int(val)
         elif line.startswith("Compiles:"):
             # JSON {step: {warmup, steady_new, steady_calls}} —
             # jit-entry signature accounting (rnb_tpu.compilestats);

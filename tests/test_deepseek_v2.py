@@ -85,7 +85,7 @@ def run_program(toy, prompts, rows, params=None):
 
     from rnb_tpu.models.deepseek_v2 import network
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, served, sent = jax.jit(
+    logits, chosen, served, sent, _ = jax.jit(
         lambda p, s, t, m: network.forward(
             toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True))(
         toy["params"] if params is None else params, toy["slots"], tokens,
@@ -443,10 +443,12 @@ def test_latent_attention_matches_one_masked_softmax(toy):
     h = jnp.asarray(rng.standard_normal((4, Q, cfg.hidden_size)),
                     jnp.bfloat16)
     row_start = jnp.asarray([0, 0, 0, 3], jnp.int32)
-    got = np.asarray(jax.jit(
+    got, tiles = jax.jit(
         lambda p, h, s: network.latent_attention(
             cfg, p, h, s, rope.pool_positions(s, Q), interpret=True))(
-        toy["params"]["l0"], h, row_start)).reshape(4 * Q, -1)
+        toy["params"]["l0"], h, row_start)
+    got = np.asarray(got).reshape(4 * Q, -1)
+    assert np.asarray(tiles).tolist() == [1, 1]
     w = {t: toy["read"]("l0.%s" % t) for t in reference.ATTENTION}
     flat = h.reshape(4 * Q, -1).astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -539,8 +541,34 @@ def test_one_prefill_stage_serves_both_families(tmp_path):
     assert counters["expert_served"].shape == (2, 4)
     assert 0 < counters["group_tokens"] <= 2 * valid
     assert counters["expert_served"].sum() >= counters["group_tokens"]
+    # three layers, one tile each: visited, and on or under the diagonal
+    assert counters["attn_tiles"].tolist() == [3, 3]
     assert any("/attn/" in name for name in stage.hlo_scopes.values())
     assert any("/experts/" in name for name in stage.hlo_scopes.values())
+
+
+def test_every_layer_counts_the_tiles_its_dispatch_ran(toy, monkeypatch):
+    """Three requests and two pad rows over 3 x 3 tiles of 128 tokens:
+    each layer's counter is the block table's own sums."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.deepseek_v2 import network
+    from rnb_tpu.ops import segattn
+    for name in ("_BLOCK_Q", "_BLOCK_KV", "_BLOCK_COMPUTE"):
+        monkeypatch.setattr(segattn, name, 128)
+    tokens, meta, offsets = pack(prompts_of([9 * Q, 6 * Q - 3, 7 * Q]), 24)
+    assert offsets == [0, 9, 15, 22]
+    *_, tiles = jax.jit(lambda p, s, t, m: network.forward(
+        toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True))(
+        toy["params"], toy["slots"], tokens, meta)
+    # query block 1 (rows 8-15) opens inside request 0, block 2 (rows
+    # 16-23) inside request 2, which begins in key block 1
+    run, _, causal = segattn.block_table(
+        jnp.asarray([0, 0, 15 * Q]), 128, 128)
+    assert np.asarray(run).tolist() == [[1, 0, 0], [1, 1, 0], [0, 1, 1]]
+    assert np.asarray(tiles).tolist() \
+        == [[5, causal]] * TOY["num_hidden_layers"] == [[5, 6]] * 3
 
 
 def test_the_experts_line_carries_the_group_tokens():
@@ -629,9 +657,12 @@ def test_the_cell_through_the_benchmark_command(trace, tmp_path):
     meta = (out / "run" / "log-meta.txt").read_text()
     assert "Tokens: valid=" in meta and "Experts: assignments=" in meta
     assert " group_tokens=" in meta
+    assert "Attention: tiles_visited=" in meta
     assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
     metrics = line["metrics"]
     if trace:
+        # a toy pool is one tile: the counter comes through the result
+        assert metrics["flash_tile_visit_pct.bulk"]["value"] == 100
         assert metrics["tokens_per_s.bulk"]["value"] > 0
         assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
         # one group of four held: a quarter of the pairs under even
@@ -814,3 +845,10 @@ def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
         params, of((cfg.router_experts,)), of((rows, cfg.chunk_size)),
         of((3, rows))).compile().as_text()
     assert gather_in_sources(text, 8192, 6, 5120) == [True] * 4
+    # the flash kernel, a layer, keeps the name and the scope that two
+    # readers of benchmarks/ find it by, its block table traced data
+    from rnb_tpu.models import token_stages
+    flash = [scope for head, scope in token_stages.scopes_of_hlo(text).items()
+             if head.startswith("%splash_mqa_fwd_segmented_no_residuals")]
+    assert len(flash) == cfg.num_hidden_layers == 5
+    assert all("/attn/" in scope for scope in flash)

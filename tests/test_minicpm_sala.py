@@ -581,7 +581,7 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
     from rnb_tpu.models import token_stages
     from rnb_tpu.models.minicpm_sala import checkpoint
     from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import (aggregate_sparse_counters,
+    from rnb_tpu.telemetry import (SPARSE_COUNTS, aggregate_counts,
                                    aggregate_stage_counters)
     family = mm.load_family("minicpm_sala")
     recipe = str(tmp_path / "toy.recipe.json")
@@ -618,10 +618,12 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
     tokens_line, experts_line = aggregate_stage_counters([counters])
     assert tokens_line == {"valid": valid, "shipped": 8 * Q}
     assert experts_line is None
-    sparse_line = aggregate_sparse_counters([counters, counters])
+    sparse_line = aggregate_counts([counters, counters], "sparse",
+                                   SPARSE_COUNTS)
     assert sparse_line["queries"] == 4 * valid
     assert sparse_line["chosen_keys"] < sparse_line["causal_keys"]
-    assert aggregate_sparse_counters([{"tokens_valid": 1}]) is None
+    assert aggregate_counts([{"tokens_valid": 1}], "sparse",
+                            SPARSE_COUNTS) is None
     for scope in ("/attn/", "/attn/select/", "/ssd/", "/mlp/", "/head/",
                   "/embed/"):
         assert any(scope in name + "/"

@@ -89,7 +89,7 @@ def run_program(toy, prompts, rows, **kwargs):
 
     from rnb_tpu.models.nemotron_h import network
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, served = jax.jit(
+    logits, chosen, served, _ = jax.jit(
         lambda p, s, t, m: network.forward(
             toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True,
             **kwargs))(
@@ -154,9 +154,10 @@ def test_attention_mixer_matches_one_masked_softmax(toy):
     from rnb_tpu.models.nemotron_h import network
     length = 45
     h, rows, params, weights = block_inputs(toy, length, "*")
-    got = network.attention_mixer(
+    got, tiles = network.attention_mixer(
         toy["cfg"], params, h.reshape(rows, Q, -1),
         jnp.zeros(rows, jnp.int32), interpret=True)
+    assert np.asarray(tiles).tolist() == [1, 1]
     with jax.default_matmul_precision("highest"):
         want = reference.attention(TOY, weights,
                                    h.astype(jnp.float32)[:length])
@@ -552,9 +553,12 @@ def test_full_pattern_through_the_benchmark_command(trace, tmp_path):
     assert line["attempted"] > 0
     meta = (out / "run" / "log-meta.txt").read_text()
     assert "Tokens: valid=" in meta and "Experts: assignments=" in meta
+    assert "Attention: tiles_visited=" in meta
     assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
     metrics = line["metrics"]
     if trace:
+        # a toy pool is one tile: the counter comes through the result
+        assert metrics["flash_tile_visit_pct.bulk"]["value"] == 100
         assert metrics["tokens_per_s.bulk"]["value"] > 0
         assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
         assert 30 < metrics["held_assignment_pct.bulk"]["value"] < 70

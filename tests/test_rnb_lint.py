@@ -337,6 +337,7 @@ def test_unregistered_meta_line_triggers_t004(tmp_path):
                      'f.write("Tokens: valid=%d\\n" % tk)\n'
                      'f.write("Experts: assignments=%d\\n" % ex)\n'
                      'f.write("Sparse: queries=%d\\n" % sq)\n'
+                     'f.write("Attention: tiles_visited=%d\\n" % at)\n'
                      'f.write("Handoff: edges=%d\\n" % ho)\n'
                      'f.write("Handoff edges: %s\\n" % he)\n'
                      'f.write("Placement: %s\\n" % pl)\n'
