@@ -49,13 +49,22 @@ def enable_compilation_cache() -> str:
     Otherwise it is ``<checkout>/.jax_cache`` — a fixed path, because
     the path is part of the cache key and a directory that moves never
     hits. The one function every process of a job calls: the launcher,
-    chip_smoke.py and the netedge peer."""
+    chip_smoke.py and the netedge peer.
+
+    An executable's metadata is part of its key here. JAX leaves it
+    out by default, and a program then gets back whatever executable
+    was cached first for the same arithmetic, with *that* program's
+    ``op_name``s: the final stages write their scope table from the
+    executable's text (rnb_tpu.hloscopes), so a cache shared with a
+    checkout that names its scopes otherwise would hand them its
+    names."""
     import jax
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(REPO_DIR, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir
 
 
@@ -342,8 +351,7 @@ def run_benchmark(config_path: str,
                   log_base: str = "logs",
                   print_progress: bool = True,
                   seed: Optional[int] = None,
-                  job_id: Optional[str] = None,
-                  xprof: bool = False) -> BenchmarkResult:
+                  job_id: Optional[str] = None) -> BenchmarkResult:
     """Programmatic entry used by the CLI, the tests and the benchmark
     (``benchmarks/run.py``)."""
     enable_compilation_cache()
@@ -774,39 +782,6 @@ def run_benchmark(config_path: str,
         # transport threads, not stages: they never join the barriers
         netedge_client.start()
 
-    if xprof:
-        # device-op tracing of the measured window only: wait until
-        # every other participant is parked on the start barrier (model
-        # compile/warm-up happens in the runner ctors BEFORE they reach
-        # it) so the trace contains no warm-up ops, then start capture
-        # before releasing the barrier so neither the trace nor
-        # time_start is skewed by profiler setup. The reference left its
-        # CUPTI bridge unwired from the runner (SURVEY.md §5 tracing);
-        # here the same three-call contract covers the job.
-        from rnb_tpu import profiler
-        deadline = time.time() + BARRIER_TIMEOUT_S
-        while sta_bar.n_waiting < bar_total - 1:
-            if time.time() > deadline:
-                break  # let sta_bar.wait() raise the real timeout
-            time.sleep(0.01)
-        # Window markers: a uniquely named jitted no-op dispatched at
-        # window start and end. Its module name lands in the device
-        # trace ON THE DEVICE'S OWN TIMELINE, which counts from the
-        # start of the capture, not from the host epoch — so the
-        # markers delimit the measured window with no clock mapping.
-        # Compiled here, BEFORE capture starts, so no compile lands in
-        # the trace.
-        import jax
-
-        def rnb_window_marker(x):
-            return x + 1
-
-        _marker = jax.jit(rnb_window_marker)
-        _marker_arg = jax.numpy.zeros((3, 91), jax.numpy.float32)
-        jax.block_until_ready(_marker(_marker_arg))
-        profiler.initialize(os.path.join(logroot(job_id, base=log_base),
-                                         "xprof"))
-        jax.block_until_ready(_marker(_marker_arg))
     import resource
 
     from rnb_tpu.decode.native import DecodePool
@@ -834,39 +809,6 @@ def run_benchmark(config_path: str,
                   - (ru_start.ru_utime + ru_start.ru_stime))
     decode_end = DecodePool.shared_stats()
     total_time = time_end - time_start
-    if xprof:
-        jax.block_until_ready(_marker(_marker_arg))  # end-of-window mark
-        # anchor BEFORE stop_trace: stopping writes the whole trace
-        # out, which for a long window takes seconds, so an
-        # after-the-fact stamp would place the device timeline's end
-        # well past the last captured op. Taken here, the stamp
-        # coincides with the device's last ops up to the short
-        # post-window drain (EOS flush dispatches), which biases the
-        # mapped window late by at most that drain.
-        flush_epoch = time.time()
-        profiler.flush()
-        ops = profiler.report(keep_trace=True, include_plane=True)
-        with open(os.path.join(logroot(job_id, base=log_base),
-                               "xprof-ops.txt"), "w") as f:
-            # per-plane clock bases differ (XLine timestamps have no
-            # shared origin across host/device planes), so the plane
-            # is part of the record: busy-time aggregation is only
-            # valid within one plane.
-            f.write("# t0_ns t1_ns plane op_name\n")
-            # The capture starts before the barrier and the device
-            # clock has no host-epoch origin, so the measured window
-            # is also recorded in host epoch; for traces without
-            # markers the analyzer maps it into device time by
-            # anchoring flush_epoch to the last device timestamp.
-            f.write("# window_epoch %f %f flush_epoch %f\n"
-                    % (time_start, time_end, flush_epoch))
-            for name, t0, t1, plane in ops:
-                f.write("%d %d %s %s\n"
-                        % (t0, t1, plane.replace(" ", "_") or "-",
-                           name))
-        if print_progress:
-            print("xprof: %d device-op intervals -> xprof-ops.txt"
-                  % len(ops))
     if print_progress:
         print("FINISH! %f" % time_end)
         print("Time: %f sec" % total_time)
@@ -1659,9 +1601,6 @@ def main(argv=None) -> int:
                              "to be asked for: 'cpu'")
     parser.add_argument("--log-base", type=str, default="logs")
     parser.add_argument("--seed", type=int, default=None)
-    parser.add_argument("--xprof", action="store_true",
-                        help="Capture device-op timelines for the "
-                             "measured window into <logdir>/xprof-ops.txt")
     args = parser.parse_args(argv)
 
     if args.platform == "cpu":
@@ -1750,7 +1689,6 @@ def main(argv=None) -> int:
         queue_size=args.queue_size,
         log_base=args.log_base,
         seed=args.seed,
-        xprof=args.xprof,
     )
     print("Throughput: %.3f videos/s" % result.throughput_vps)
     print("Logs: %s" % result.log_dir)

@@ -5,5 +5,4 @@ configuration files written before the move give."""
 
 from rnb_tpu.models.token_stages import (  # noqa: F401
     CHUNK, MAX_ROWS, TokenLoader as NemotronTokenLoader,
-    PackedPrefill as NemotronPrefill, dispatch_meta, rows_of_tokens,
-    scopes_of_hlo)
+    PackedPrefill as NemotronPrefill, dispatch_meta, rows_of_tokens)

@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
-from rnb_tpu import trace
+from rnb_tpu import hloscopes, trace
 from rnb_tpu.autotune import BatchController
 from rnb_tpu.cache import content_key
 from rnb_tpu.compilestats import SignatureTracker
@@ -118,6 +118,9 @@ def _shared_apply(start: int, end: int, num_classes: int,
     normalize (rnb_tpu/ops/yuv.py) — inside the same jit, all of it
     plain jnp, so XLA makes one producer of it and lays its result out
     for the first convolution (no Pallas kernel stands between them).
+    Whatever stands in front of layer 1 runs under the named scope
+    ``ingest``; the network opens its own (``stem``, ``stage2`` ...
+    ``stage5``, ``head``: network.R2Plus1DNet).
 
     ``ragged`` swaps the contract for the ragged row-pool one
     (rnb_tpu/ops/ragged.py): the applier takes the flat pool plus a
@@ -173,7 +176,8 @@ def _shared_apply(start: int, end: int, num_classes: int,
                 def apply(variables, x, rows_valid):
                     import jax.numpy as jnp
                     from jax import lax
-                    xin = ingest(x, rows_valid)
+                    with jax.named_scope("ingest"):
+                        xin = ingest(x, rows_valid)
                     if chunk <= 0 or chunk >= xin.shape[0]:
                         return model.apply(variables, xin, train=False)
 
@@ -202,14 +206,16 @@ def _shared_apply(start: int, end: int, num_classes: int,
                 from rnb_tpu.ops.yuv import normalize_yuv420
 
                 def apply(variables, x):
-                    return model.apply(variables, normalize_yuv420(
-                        x, FRAME_HW, FRAME_HW), train=False)
+                    with jax.named_scope("ingest"):
+                        x = normalize_yuv420(x, FRAME_HW, FRAME_HW)
+                    return model.apply(variables, x, train=False)
             elif pixel_path == "dct":
                 from rnb_tpu.ops.dct import normalize_dct
 
                 def apply(variables, x):
-                    return model.apply(variables, normalize_dct(
-                        x, FRAME_HW, FRAME_HW), train=False)
+                    with jax.named_scope("ingest"):
+                        x = normalize_dct(x, FRAME_HW, FRAME_HW)
+                    return model.apply(variables, x, train=False)
             else:
                 def apply(variables, x):
                     return model.apply(variables, x, train=False)
@@ -264,7 +270,8 @@ def _shared_ragged_preprocess(device):
             from rnb_tpu.ops.ragged import ragged_normalize_u8
 
             def preprocess(pool, rows_valid):
-                return ragged_normalize_u8(pool, rows_valid)
+                with jax.named_scope("ingest"):
+                    return ragged_normalize_u8(pool, rows_valid)
 
             fn = jax.jit(preprocess)
             _preprocess_cache[key] = fn
@@ -2694,6 +2701,11 @@ class R2P1DRunner(StageModel):
         #: distinct applier input signatures == executables this stage
         #: requires; frozen by the executor at measured-window start
         self.compiles = SignatureTracker()
+        #: the executables warm-up compiled, one a warmed row count, of
+        #: a stage that ends the network: their text is the scope table
+        #: the stage writes when it has drained (rnb_tpu.hloscopes)
+        self._warmed_programs = []
+        self._log_dir = None
         for rows in warm_rows:
             host = np.zeros((rows,) + self._steady_shape[1:],
                             warm_dtype)
@@ -2708,20 +2720,44 @@ class R2P1DRunner(StageModel):
                     dummy = jax.device_put(host, self._input_sharding)
                 else:
                     dummy = jax.device_put(host, self._jax_device)
+                args = (self._variables, dummy) + (
+                    (np.int32(rows),) if self.ragged else ())
                 for _ in range(num_warmups):
-                    if self.ragged:
-                        out = self._apply(self._variables, dummy,
-                                          np.int32(rows))
-                    else:
-                        out = self._apply(self._variables, dummy)
+                    out = self._apply(*args)
                     jax.block_until_ready(out)
                     if self._merge is not None:
                         # warm the merge collective too: its compile
                         # must not land inside the measured window
                         jax.block_until_ready(self._merge(out))
+                if self.end_index == NUM_LAYERS:
+                    # not a second compile: the same arguments find the
+                    # executable the call above made in jit's cache
+                    # (tests/test_r2p1d_scopes.py counts the backend's
+                    # compilations)
+                    self._warmed_programs.append(
+                        self._apply.lower(*args).compile())
 
     def input_shape(self):
         return (self._steady_shape,)
+
+    def bind_log_dir(self, log_dir: str) -> None:
+        self._log_dir = log_dir
+
+    def scope_table(self) -> dict:
+        """{"<instruction> <result shape>": op_name} over the programs
+        warm-up compiled: which named scope (``ingest``, ``stem``,
+        ``stage2`` ... ``stage5``, ``head``) each instruction of each
+        row bucket's program came from."""
+        table = {}
+        for program in self._warmed_programs:
+            table.update(hloscopes.scopes_of_hlo(program.as_text()))
+        return table
+
+    def finalize(self) -> None:
+        """The stage has drained: a stage that ends the network writes
+        the scope table of its programs beside the run's logs."""
+        if self._log_dir is not None and self._warmed_programs:
+            hloscopes.write_table(self._log_dir, self.scope_table())
 
     def bind_shard_step(self, step_idx: int) -> None:
         """Executor protocol (rnb_tpu.runner): hand the stage its step

@@ -106,7 +106,8 @@ def normalize_u8(x, dtype=jnp.bfloat16):
     sharded mesh step) must share. Pallas kernel on TPU, jnp
     elsewhere (rnb_tpu.ops.preprocess)."""
     from rnb_tpu.ops.preprocess import normalize_u8 as _impl
-    return _impl(x, dtype=dtype)
+    with jax.named_scope("ingest"):
+        return _impl(x, dtype=dtype)
 
 
 def factored_channels(in_features: int, out_features: int,
@@ -273,6 +274,15 @@ class R2Plus1DNet(nn.Module):
     flatten to (rows, 512); the classification head lives in
     :class:`R2Plus1DClassifier`. Equivalent capability to the
     reference's R2Plus1DLayerNet (models/r2p1d/network.py:9-41).
+
+    Each layer the range holds runs under a ``jax.named_scope`` that
+    says what it does, not where its parameters live: ``stem`` (layer
+    1), ``stage2`` ... ``stage5`` (the residual stages), ``head`` (the
+    pool here, the linear layer in the classifier); the ingest in front
+    of layer 1 opens ``ingest`` where it is called. A scope is metadata
+    of the compiled instructions (their ``op_name``), which
+    ``rnb_tpu.hloscopes`` tables for the trace's readers; module names,
+    and so the parameter tree, do not carry it.
     """
 
     start: int = 1
@@ -293,24 +303,29 @@ class R2Plus1DNet(nn.Module):
     def __call__(self, x, train: bool = False):
         for layer in range(self.start, self.end + 1):
             if layer == 1:
-                x = SpatioTemporalConv(64, kernel=(3, 7), stride=(1, 2),
-                                       dtype=self.dtype, shards=self.shards,
-                                       shard_axis=self.shard_axis,
-                                       name="conv1")(x, train)
-                x = nn.BatchNorm(use_running_average=not train,
-                                 dtype=self.dtype, name="stem_bn")(x)
-                x = nn.relu(x)
+                with jax.named_scope("stem"):
+                    x = SpatioTemporalConv(64, kernel=(3, 7), stride=(1, 2),
+                                           dtype=self.dtype,
+                                           shards=self.shards,
+                                           shard_axis=self.shard_axis,
+                                           name="conv1")(x, train)
+                    x = nn.BatchNorm(use_running_average=not train,
+                                     dtype=self.dtype, name="stem_bn")(x)
+                    x = nn.relu(x)
             else:
-                x = SpatioTemporalResLayer(
-                    LAYER_FEATURES[layer],
-                    num_blocks=self.layer_sizes[layer - 2],
-                    downsample=(layer >= 3),
-                    factored_shortcut=self.factored_shortcut,
-                    dtype=self.dtype, shards=self.shards,
-                    shard_axis=self.shard_axis,
-                    name="conv%d" % layer)(x, train)
+                with jax.named_scope("stage%d" % layer):
+                    x = SpatioTemporalResLayer(
+                        LAYER_FEATURES[layer],
+                        num_blocks=self.layer_sizes[layer - 2],
+                        downsample=(layer >= 3),
+                        factored_shortcut=self.factored_shortcut,
+                        dtype=self.dtype, shards=self.shards,
+                        shard_axis=self.shard_axis,
+                        name="conv%d" % layer)(x, train)
         if self.end == NUM_LAYERS:
-            x = jnp.mean(x, axis=(1, 2, 3))  # global spatiotemporal pool
+            with jax.named_scope("head"):
+                # global spatiotemporal pool
+                x = jnp.mean(x, axis=(1, 2, 3))
         return x
 
 
@@ -358,14 +373,15 @@ class R2Plus1DClassifier(nn.Module):
                     trans_in_fn=_gather_shard_params(self.shard_axis,
                                                      self.shards),
                     mutable=False)
-            x = Dense(self.num_classes, dtype=self.dtype,
-                      name="linear")(x)
-            if self.shards > 1:
-                # keep only this member's column block: the slice is
-                # pure movement, so the merge gather reassembles the
-                # full-width logits bit-exactly
-                local = self.num_classes // self.shards
-                idx = lax.axis_index(self.shard_axis)
-                x = lax.dynamic_slice_in_dim(x, idx * local, local,
-                                             axis=-1)
+            with jax.named_scope("head"):
+                x = Dense(self.num_classes, dtype=self.dtype,
+                          name="linear")(x)
+                if self.shards > 1:
+                    # keep only this member's column block: the slice
+                    # is pure movement, so the merge gather reassembles
+                    # the full-width logits bit-exactly
+                    local = self.num_classes // self.shards
+                    idx = lax.axis_index(self.shard_axis)
+                    x = lax.dynamic_slice_in_dim(x, idx * local, local,
+                                                 axis=-1)
         return x.astype(jnp.float32)

@@ -50,14 +50,12 @@ valid tokens of each row ``(rows,)``.
 from __future__ import annotations
 
 import importlib
-import json
 import os
-import re
 from typing import Optional
 
 import numpy as np
 
-from rnb_tpu import trace
+from rnb_tpu import hloscopes, trace
 from rnb_tpu.compilestats import SignatureTracker
 from rnb_tpu.health import cards_of
 from rnb_tpu.stage import (PaddedBatch, StageModel,
@@ -223,7 +221,8 @@ class PackedPrefill(StageModel):
             self.compiles.observe(tokens)
             program = jax.jit(apply).lower(
                 self._params, self._slots, tokens, meta).compile()
-            self.hlo_scopes.update(scopes_of_hlo(program.as_text()))
+            self.hlo_scopes.update(
+                hloscopes.scopes_of_hlo(program.as_text()))
             self._programs[rows] = program
             for _ in range(int(num_warmups)):
                 jax.block_until_ready(program(
@@ -353,34 +352,7 @@ class PackedPrefill(StageModel):
                 np.savez(os.path.join(self._log_dir,
                                       "prefill-sample-%d.npz" % k),
                          **sample)
-            with open(os.path.join(self._log_dir, "hlo-scopes.json"),
-                      "w") as f:
-                json.dump(self.hlo_scopes, f)
+            hloscopes.write_table(self._log_dir, self.hlo_scopes)
         self._params = None
         self._programs = None
 
-
-_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+\[[\d,]*\])")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-
-
-def scopes_of_hlo(text: str) -> dict:
-    """{"<instruction> <result shape>": op_name} for every instruction
-    of a compiled module's text that carries an ``op_name``: the path
-    of ``jax.named_scope``s it was traced under. The profiler names a
-    device operation by its instruction and not by its scope; this is
-    the table that joins the two (the same instruction name recurs in
-    each bucket's program with another shape)."""
-    out = {}
-    open_head = None
-    for line in text.splitlines():
-        head = _INSTRUCTION.match(line)
-        if head:
-            open_head = "%s %s" % head.groups()
-        # a Pallas kernel's attributes hold line breaks: its op_name
-        # follows on a later line of the same instruction
-        found = _OP_NAME.search(line)
-        if found and open_head is not None:
-            out[open_head] = found.group(1)
-            open_head = None
-    return out

@@ -129,7 +129,8 @@ class ShardedInference:
                 # network stage runs (rnb_tpu/ops/yuv.py), here inside
                 # the sharded program so it shards with the clip axis
                 from rnb_tpu.ops.yuv import normalize_yuv420
-                x = normalize_yuv420(flat, hw, hw, dtype)
+                with jax.named_scope("ingest"):
+                    x = normalize_yuv420(flat, hw, hw, dtype)
             else:
                 x = normalize_u8(flat, dtype)
             logits = model.apply(variables, x, train=False)

@@ -290,11 +290,14 @@ def make_sharded_apply(start: int, end: int, num_classes: int,
 
     if ragged:
         def network(variables, x, rows_valid):
-            return model.apply(variables, ingest(x, rows_valid),
-                               train=False)
+            with jax.named_scope("ingest"):
+                x = ingest(x, rows_valid)
+            return model.apply(variables, x, train=False)
     else:
         def network(variables, x):
-            return model.apply(variables, ingest(x, None), train=False)
+            with jax.named_scope("ingest"):
+                x = ingest(x, None)
+            return model.apply(variables, x, train=False)
 
     def build(variables_specs):
         return jax.jit(jax.shard_map(

@@ -14,7 +14,7 @@ def instructions(text):
     """-> [(name, result shape, opcode, op_name, top-level, line)] of
     every instruction; top-level: it is the entry computation's own,
     one operation on the device, and not a line inside a fusion."""
-    from rnb_tpu.models.token_stages import scopes_of_hlo
+    from rnb_tpu.hloscopes import scopes_of_hlo
     scopes = scopes_of_hlo(text)
     found, entry = [], False
     for line in text.splitlines():

@@ -547,6 +547,54 @@ def test_one_prefill_stage_serves_both_families(tmp_path):
     assert any("/experts/" in name for name in stage.hlo_scopes.values())
 
 
+def scopes_of_hlo_before_the_move(text):
+    """``scopes_of_hlo`` as ``models/token_stages.py`` had it before
+    ``rnb_tpu/hloscopes.py`` took it over (PR 37), kept word for word
+    as the reference the moved one is held to."""
+    import re
+    instruction = re.compile(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?(\w+\[[\d,]*\])")
+    op_name = re.compile(r'op_name="([^"]*)"')
+    out = {}
+    open_head = None
+    for line in text.splitlines():
+        head = instruction.match(line)
+        if head:
+            open_head = "%s %s" % head.groups()
+        found = op_name.search(line)
+        if found and open_head is not None:
+            out[open_head] = found.group(1)
+            open_head = None
+    return out
+
+
+def test_the_prefill_stage_writes_the_table_it_always_wrote(tmp_path):
+    """Through the helper it now shares with the R(2+1)D stage: the
+    file's bytes are ``json.dump`` of the old function's table over the
+    same programs' text, bucket by bucket."""
+    from rnb_tpu import hloscopes
+    from rnb_tpu.devices import DeviceSpec
+    from rnb_tpu.models import token_stages
+    from rnb_tpu.models.deepseek_v2 import checkpoint
+    recipe = str(tmp_path / "toy.recipe.json")
+    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
+    stage = token_stages.PackedPrefill(
+        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
+        row_buckets=[4, 8], num_warmups=0)
+    expected = {}
+    for rows in (4, 8):
+        expected.update(scopes_of_hlo_before_the_move(
+            stage._programs[rows].as_text()))
+    assert len(expected) > 100
+    logs = tmp_path / "logs"
+    logs.mkdir()
+    stage.bind_log_dir(str(logs))
+    stage.finalize()
+    assert os.listdir(str(logs)) == [hloscopes.TABLE_FILE]
+    with open(str(logs / hloscopes.TABLE_FILE)) as f:
+        assert f.read() == json.dumps(expected)
+
+
 def test_every_layer_counts_the_tiles_its_dispatch_ran(toy, monkeypatch):
     """Three requests and two pad rows over 3 x 3 tiles of 128 tokens:
     each layer's counter is the block table's own sums."""
@@ -847,8 +895,8 @@ def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
     assert gather_in_sources(text, 8192, 6, 5120) == [True] * 4
     # the flash kernel, a layer, keeps the name and the scope that two
     # readers of benchmarks/ find it by, its block table traced data
-    from rnb_tpu.models import token_stages
-    flash = [scope for head, scope in token_stages.scopes_of_hlo(text).items()
+    from rnb_tpu import hloscopes
+    flash = [scope for head, scope in hloscopes.scopes_of_hlo(text).items()
              if head.startswith("%splash_mqa_fwd_segmented_no_residuals")]
     assert len(flash) == cfg.num_hidden_layers == 5
     assert all("/attn/" in scope for scope in flash)

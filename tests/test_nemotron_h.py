@@ -400,7 +400,7 @@ def test_a_packed_batch_fills_past_a_request_that_does_not_fit(
 def test_scope_table_reads_an_instruction_that_spans_lines():
     """A Pallas kernel's attributes hold line breaks: its scope is on
     the instruction's last line."""
-    from rnb_tpu.models.nemotron_h import stages
+    from rnb_tpu import hloscopes as stages
     text = """  %fusion.1 = f32[8,4]{1,0} fusion(%p), kind=kLoop, metadata={op_name="jit(f)/ssd/mul"}
   %kernel.2 = (f32[2,8]{1,0}, bf16[2,16]{1,0}) custom-call(%a), frontend_attributes={kernel_metadata={
 "xprof_metadata":"{}"

@@ -116,69 +116,3 @@ def test_report_drains_trace(tmp_path):
     first = profiler.report()
     assert profiler.report() == []
     assert isinstance(first, list)
-
-
-def test_benchmark_xprof_end_to_end(tmp_path):
-    """run_benchmark(xprof=True) through the real runtime on the CPU
-    backend: xprof-ops.txt carries the 4-column header, the epoch
-    window line, and at least two window-marker events."""
-    import json
-
-    import numpy as np
-
-    from rnb_tpu.benchmark import run_benchmark
-    from rnb_tpu.control import TerminationFlag
-    from rnb_tpu.decode import write_y4m
-    from rnb_tpu.models.r2p1d import checkpoint as ckpt
-
-    root = os.path.join(str(tmp_path), "data")
-    os.makedirs(os.path.join(root, "label0"))
-    rng = np.random.default_rng(0)
-    for i in range(3):
-        write_y4m(os.path.join(root, "label0", "v%d.y4m" % i),
-                  rng.integers(0, 256, (30, 64, 64, 3), dtype=np.uint8))
-    os.environ["RNB_TPU_DATA_ROOT"] = root
-    try:
-        ckpt_path = os.path.join(str(tmp_path), "tiny.msgpack")
-        ckpt.save_checkpoint(ckpt_path, ckpt.init_variables(
-            seed=1, num_classes=8, layer_sizes=(1, 1, 1, 1)))
-        cfg = {
-            "video_path_iterator":
-                "rnb_tpu.models.r2p1d.model.R2P1DVideoPathIterator",
-            "pipeline": [
-                {"model":
-                    "rnb_tpu.models.r2p1d.model.R2P1DFusingLoader",
-                 "queue_groups": [{"devices": [0], "out_queues": [0]}],
-                 "num_shared_tensors": 10,
-                 "fuse": 2, "max_clips": 4,
-                 "num_clips_population": [2], "weights": [1],
-                 "consecutive_frames": 2, "num_warmups": 0,
-                 "pixel_path": "yuv420"},
-                {"model": "rnb_tpu.models.r2p1d.model.R2P1DRunner",
-                 "queue_groups": [{"devices": [0], "in_queue": 0}],
-                 "start_index": 1, "end_index": 5, "num_classes": 8,
-                 "layer_sizes": [1, 1, 1, 1], "max_rows": 4,
-                 "consecutive_frames": 2, "num_warmups": 0,
-                 "ckpt_path": ckpt_path, "pixel_path": "yuv420"},
-            ],
-        }
-        cfg_path = os.path.join(str(tmp_path), "fused.json")
-        with open(cfg_path, "w") as f:
-            json.dump(cfg, f)
-        log_base = os.path.join(str(tmp_path), "logs")
-        res = run_benchmark(cfg_path, mean_interval_ms=0, num_videos=6,
-                            log_base=log_base, print_progress=False,
-                            xprof=True)
-        assert res.termination_flag == \
-            TerminationFlag.TARGET_NUM_VIDEOS_REACHED
-        job = os.listdir(log_base)[0]
-        trace = os.path.join(log_base, job, "xprof-ops.txt")
-        with open(trace) as f:
-            head = [f.readline(), f.readline()]
-        assert head[0].startswith("# t0_ns t1_ns plane op_name")
-        assert "window_epoch" in head[1] and "flush_epoch" in head[1]
-        with open(trace) as f:
-            n_markers = sum("rnb_window_marker" in line for line in f)
-        assert n_markers >= 2, n_markers
-    finally:
-        os.environ.pop("RNB_TPU_DATA_ROOT", None)
