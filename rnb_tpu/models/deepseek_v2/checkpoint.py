@@ -8,8 +8,14 @@ set-up: a routed expert's first two matrices (``gate``, ``up``) lie
 without a relayout (``ops/moe.py``); the rotary columns of ``q_b`` (64
 of each head's 192) and of ``kv_a`` (its last 64) are stored evens
 first, then odds: the de-interleaving the published code makes at run
-time before it rotates halves (``ops/rope.py``). ``kv_b``'s columns
-are a head's ``[k_nope | v]``, as published.
+time before it rotates halves (``ops/rope.py``). ``q_b`` lies
+heads-first, ``[heads, latent, columns]``, a head's columns the whole
+lanes that ``ops/mla.py`` writes as the flash kernel's queries:
+``[q_nope | q_pe | q_pe turned | 0]`` (the two rotary halves ``[x1 |
+x2]`` once more as ``[-x2 | x1]``, so that the product itself brings
+what the rotation multiplies by the sines), to the next 128 columns (at
+the published sizes 128 + 64 + 64: the pad is the turned columns).
+``kv_b``'s columns are a head's ``[k_nope | v]``, as published.
 
 Initial scales (all of them this repo's assumption: the published
 checkpoint is trained, not initialised): embedding N(0, 1) so the
@@ -29,8 +35,19 @@ from typing import Dict, Optional, Sequence
 from rnb_tpu.models import seeded
 from rnb_tpu.models.deepseek_v2.network import DeepseekV2Config
 from rnb_tpu.models.seeded import TensorSpec
+from rnb_tpu.ops import mla
 
 FAMILY = "deepseek_v2"
+
+
+def query_columns(cfg: DeepseekV2Config):
+    """``q_b``'s stored columns of one head (``TensorSpec.heads_first``)."""
+    nope, half = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim // 2
+    order = list(range(cfg.qk_head_dim)) \
+        + [-1 - (nope + half + i) for i in range(half)] \
+        + [nope + i for i in range(half)]
+    return tuple(order) + (None,) * (mla.query_lanes(
+        nope, cfg.qk_rope_head_dim) - len(order))
 
 
 def tensor_specs(cfg: DeepseekV2Config, num_held: int
@@ -40,6 +57,7 @@ def tensor_specs(cfg: DeepseekV2Config, num_held: int
     back = 1.0 / math.sqrt(2 * cfg.published_layers)
     heads = cfg.num_attention_heads
     rank, rotary = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    columns = query_columns(cfg)
 
     def lin(fan_in, fan_out, scale=1.0, halves=None):
         return TensorSpec((fan_in, fan_out), bf, "normal",
@@ -57,9 +75,11 @@ def tensor_specs(cfg: DeepseekV2Config, num_held: int
             "attn_norm": ones(d), "ffn_norm": ones(d),
             "q_a": lin(d, cfg.q_lora_rank),
             "q_a_norm": ones(cfg.q_lora_rank),
-            "q_b": lin(cfg.q_lora_rank, heads * cfg.qk_head_dim,
-                       halves=(cfg.qk_head_dim, cfg.qk_nope_head_dim,
-                               rotary)),
+            "q_b": TensorSpec(
+                (heads, cfg.q_lora_rank, len(columns)), bf, "normal",
+                1.0 / math.sqrt(cfg.q_lora_rank),
+                halves=(cfg.qk_head_dim, cfg.qk_nope_head_dim, rotary),
+                heads_first=(cfg.qk_head_dim, columns)),
             "kv_a": lin(d, rank + rotary,
                         halves=(rank + rotary, rank, rotary)),
             "kv_a_norm": ones(rank),

@@ -10,7 +10,9 @@ final RMSNorm and an untied head, on each request's last valid token.
 
 MLA runs in its *expanded* form: per head ``q = [q_nope | q_pe]`` and
 ``k = [k_nope | k_pe]`` of 128 + 64 columns, values of 128, through the
-pool's flash kernel (``ops/segattn.py``) as 128 heads of their own.
+pool's flash kernel (``ops/segattn.py``) as 128 heads of their own;
+the queries' up-projection (``ops/mla.py``) writes its operand
+heads-first and whole lanes wide, rotated, scaled and rounded once.
 The *folded* form (``W_UK`` into the query, one latent key of 512 + 64
 and one latent value of 512 for all heads, ``W_UV`` behind the kernel)
 is the same mathematics and the decode path's; in prefill on the v5e it
@@ -38,7 +40,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
-from rnb_tpu.ops import moe, rope, segattn
+from rnb_tpu.ops import mla, moe, rope, segattn
 
 #: what ``forward`` returns behind the logits and the router's choices
 #: (``models/token_stages.py``)
@@ -177,33 +179,46 @@ def _proj(x, w):
 
 def latent_attention(cfg, p, h, row_start, positions, interpret=False):
     """``h`` (rows, Q, hidden), normed -> (float32 (rows, Q, hidden),
-    the flash kernel's tiles: run, and on or under the diagonal)."""
-    rows, q, _ = h.shape
+    the flash kernel's tiles: run, and on or under the diagonal).
+
+    The queries' up-projection writes the kernel's operand itself
+    (``ops/mla.py``: heads first, whole lanes; the rotation and the
+    scores' scale on the float32 queries, before their one rounding to
+    the activations' dtype), and ``o`` contracts over (head, value
+    column) from the kernel's result as it lies. Keys and values are
+    still laid out behind their product (``segattn.heads_first``)."""
+    rows, q, hidden = h.shape
     act = h.dtype
     heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
     nope, value = cfg.qk_nope_head_dim, cfg.v_head_dim
-    inv_freq, scale = cfg.inv_freq(), cfg.softmax_scale
-    mscale = cfg.rotary_mscale
-    c_q = rms_norm(_proj(h, p["q_a"]), p["q_a_norm"], cfg.eps, act)
-    qs = _proj(c_q, p["q_b"]).reshape(rows, q, heads, cfg.qk_head_dim)
-    down = _proj(h, p["kv_a"])
-    c_kv = rms_norm(down[..., :rank], p["kv_a_norm"], cfg.eps, act)
-    kv = _proj(c_kv, p["kv_b"]).astype(act) \
-        .reshape(rows, q, heads, nope + value)
-    # the scores' scale (and the rotation) go onto the float32 queries,
-    # before their one rounding to the activations' dtype
-    q_pe = rope.rotate(qs[..., nope:], positions, inv_freq) * mscale
-    query = (jnp.concatenate([qs[..., :nope], q_pe], -1) * scale) \
-        .astype(act)
-    k_pe = (rope.rotate(down[..., rank:], positions, inv_freq) * mscale) \
-        .astype(act)
+    inv_freq, mscale = cfg.inv_freq(), cfg.rotary_mscale
+    tokens = rows * q
+    # a pool narrower than the kernel's blocks (the tests' sizes) gets
+    # its pad tokens here, where a token is still one hidden row
+    flat = jnp.pad(h.reshape(tokens, hidden),
+                   ((0, segattn.pool_tokens(tokens) - tokens), (0, 0)))
+    pool = flat.shape[0]
+    at = jnp.pad(positions.reshape(tokens), (0, pool - tokens))
+    c_q = rms_norm(_proj(flat, p["q_a"]), p["q_a_norm"], cfg.eps, act)
+    query = mla.queries(c_q, p["q_b"], at, inv_freq, nope,
+                        cfg.softmax_scale, mscale, interpret)
+    down = _proj(flat, p["kv_a"])
+    c_kv = rms_norm(down[:, :rank], p["kv_a_norm"], cfg.eps, act)
+    kv = _proj(c_kv, p["kv_b"]).astype(act).reshape(pool, heads,
+                                                    nope + value)
+    k_pe = (rope.rotate(down[None, :, rank:], at[None], inv_freq)[0]
+            * mscale).astype(act)
     key = jnp.concatenate([
         kv[..., :nope],
-        jnp.broadcast_to(k_pe[:, :, None, :],
-                         (rows, q, heads, cfg.qk_rope_head_dim))], -1)
-    out, tiles = segattn.packed_attention(query, key, kv[..., nope:],
-                                          row_start, interpret)
-    return _proj(out.reshape(rows, q, heads * value), p["o"]), tiles
+        jnp.broadcast_to(k_pe[:, None, :],
+                         (pool, heads, cfg.qk_rope_head_dim))], -1)
+    out, tiles = segattn.heads_first_attention(
+        query[:, None], segattn.heads_first(key, query.shape[-1]),
+        segattn.heads_first(kv[..., nope:]), row_start, q, interpret)
+    out = jnp.einsum("htv,hvd->td", out[:, 0, :tokens, :value],
+                     p["o"].reshape(heads, value, hidden),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(rows, q, hidden), tiles
 
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):

@@ -23,7 +23,13 @@ reader's values do not know of it:
 - ``TensorSpec.halves``: ``(head, first, width)``: the last axis is
   heads of ``head`` columns, and in each the ``width`` columns from
   ``first`` are stored evens first, then odds (a rotary projection's
-  interleaved pairs as the two halves ``ops/rope.py`` rotates).
+  interleaved pairs as the two halves ``ops/rope.py`` rotates);
+- ``TensorSpec.heads_first``: ``(head, order)``: a matrix whose
+  columns are heads of ``head`` lies ``[heads, rows, len(order)]``, a
+  head's stored column j holding its column ``order[j]`` (after
+  ``halves``), the negative of column ``-1 - order[j]``, or zero
+  (``None``): the operand a product writes heads-first and whole
+  lanes wide, its pad columns part of the weight (``ops/mla.py``).
 """
 
 from __future__ import annotations
@@ -51,21 +57,44 @@ class TensorSpec:
     transposed: bool = False
     #: (head, first, width): columns stored evens first, then odds
     halves: Optional[Tuple[int, int, int]] = None
+    #: (head, order): ``shape`` is the stored [heads, rows, len(order)]
+    heads_first: Optional[Tuple[int, Tuple[Optional[int], ...]]] = None
     #: a ``dt_bias`` draw's (min, max, floor) of the time step
     steps: Tuple[float, ...] = ()
+
+
+def published_shape(spec: TensorSpec) -> Tuple[int, ...]:
+    """The shape ``spec`` is drawn and read in."""
+    shape = spec.shape
+    if spec.heads_first is not None:
+        heads, rows, _ = shape
+        return (rows, heads * spec.heads_first[0])
+    if spec.transposed:
+        return shape[:-2] + (shape[-1], shape[-2])
+    return shape
 
 
 def halves_order(spec: TensorSpec, inverse: bool = False) -> np.ndarray:
     """The stored position -> published column of ``spec.halves`` (or
     its inverse: published column -> stored position)."""
     head, first, width = spec.halves
-    last = spec.shape[-2] if spec.transposed else spec.shape[-1]
+    last = published_shape(spec)[-1]
     order = np.arange(last).reshape(last // head, head)
     pairs = order[:, first:first + width].copy()
     order[:, first:first + width] = np.concatenate(
         [pairs[:, 0::2], pairs[:, 1::2]], axis=1)
     order = order.reshape(-1)
     return np.argsort(order) if inverse else order
+
+
+def _heads_first_columns(order) -> Tuple[np.ndarray, np.ndarray]:
+    """-> (the head's column each stored column reads, its sign: 0 for
+    a column of zeros) of a ``heads_first`` order."""
+    source = np.array([0 if j is None else j if j >= 0 else -1 - j
+                       for j in order])
+    sign = np.array([0 if j is None else 1 if j >= 0 else -1
+                     for j in order], np.int8)
+    return source, sign
 
 
 def _key(seed: int, name: str):
@@ -82,9 +111,9 @@ def _drawer(spec: TensorSpec):
     import jax
     import jax.numpy as jnp
     dtype = getattr(jnp, spec.dtype)
-    shape = spec.shape[1:] if spec.per_expert else spec.shape
-    if spec.transposed:
-        shape = shape[:-2] + (shape[-1], shape[-2])
+    shape = published_shape(spec)
+    if spec.per_expert:
+        shape = shape[1:]
 
     def one(key):
         if spec.kind == "normal":
@@ -109,6 +138,11 @@ def _drawer(spec: TensorSpec):
         x = x.astype(dtype)
         if spec.halves is not None:
             x = x[..., halves_order(spec)]
+        if spec.heads_first is not None:
+            head, order = spec.heads_first
+            source, sign = _heads_first_columns(order)
+            x = x.reshape(shape[0], -1, head)[..., source]
+            return jnp.swapaxes(x * sign.astype(dtype), 0, 1)
         return jnp.swapaxes(x, -1, -2) if spec.transposed else x
 
     if spec.per_expert:
@@ -167,7 +201,12 @@ def reference_reader(specs: Specs, seed: int, device) -> Callable:
         stored = make_tensor(seed, name, spec,
                              expert_ids if expert_ids is not None else (),
                              device).astype(jnp.float32)
-        if spec.transposed:
+        if spec.heads_first is not None:
+            head, order = spec.heads_first
+            at = np.array([order.index(j) for j in range(head)])
+            stored = jnp.swapaxes(stored, 0, 1)[..., at]
+            stored = stored.reshape(stored.shape[0], -1)
+        elif spec.transposed:
             stored = jnp.swapaxes(stored, -1, -2)
         if spec.halves is not None:
             stored = stored[..., halves_order(spec, inverse=True)]

@@ -1,0 +1,94 @@
+"""The queries' up-projection of latent attention (MLA) in its expanded
+form, as a product that writes the operand the pool's flash kernel
+reads (``ops/segattn.py``: ``heads_first_attention``), once:
+heads-first, ``(heads, tokens, columns)``, the columns whole lanes.
+
+One Pallas kernel, a step a (block of tokens, head): the latents'
+block stays in VMEM while the heads' weights stream past it, the
+product of one step is a float32 ``(tokens, columns)`` that never
+leaves VMEM, and what the float32 product needed before its one
+rounding happens there: the rotary columns are turned, the softmax
+scale applied. The weight's columns of a head are ``[q_nope | q_pe |
+q_pe turned | 0]`` (``models/deepseek_v2/checkpoint.py``): with the
+halves ``[x1 | x2]`` the product brings ``[-x2 | x1]`` beside them, a
+lane roll lays it under the halves, and ``x cos + turned sin`` is the
+half-split rotation of ``ops/rope.py`` in the same float32 numbers.
+
+On the v5e, 8,192 tokens, 128 heads of 128 + 64 + 64 columns from a
+latent of 1536 (my chip runs, PR 38): 4.43 ms at 2,048 tokens a step,
+4.54 at 1,024, 4.75 at 512; the matrix unit's peak allows 4.19. (XLA's
+own product of the published 192 columns a head, tokens-first and
+rounded to bfloat16, nothing else: 3.28.)
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from rnb_tpu.ops import rope
+
+_LANES = 128
+#: tokens a step
+_TOKENS = 2048
+KERNEL_NAME = "mla_queries"
+
+
+def query_lanes(nope: int, rotary: int) -> int:
+    """The columns of a head's queries and keys as the kernel reads
+    them: whole lanes with room for the rotary columns twice."""
+    return -(-(nope + 2 * rotary) // _LANES) * _LANES
+
+
+def _kernel(x_ref, w_ref, cos_ref, sin_ref, o_ref, *, whole, turn, scale):
+    acc = jnp.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
+    if whole:
+        o_ref[0, :, :whole] = (acc[:, :whole] * scale).astype(o_ref.dtype)
+    last = acc[:, whole:]
+    last = last * cos_ref[...] + pltpu.roll(last, turn, 1) * sin_ref[...]
+    o_ref[0, :, whole:] = (last * scale).astype(o_ref.dtype)
+
+
+def queries(latent, weight, positions, inv_freq, nope: int, scale: float,
+            mscale: float = 1.0, interpret: bool = False):
+    """``latent`` (tokens, rank), normed; ``weight`` (heads, rank,
+    ``query_lanes``) in the stored order; ``positions`` (tokens,).
+    -> (heads, tokens, ``query_lanes``) in the latent's dtype: a head's
+    ``[q_nope | q_pe rotated | 0]`` times ``scale``, the rotation (its
+    cos and sin times ``mscale``) and the scale on the float32
+    product."""
+    rotary = 2 * len(inv_freq)
+    columns = weight.shape[2]
+    # the lane tiles in front of the one the rotary columns begin in
+    # are scaled and no more; the rest is rolled as one
+    whole = nope // _LANES * _LANES
+    first, width = nope - whole, columns - whole
+    if first + 2 * rotary > width:
+        raise ValueError("no room for %d rotary columns twice behind %d "
+                         "in %d" % (rotary, nope, columns))
+    cos, sin = rope.turn_tables(positions, inv_freq, first, width, mscale)
+    tokens, rank = latent.shape
+    heads = weight.shape[0]
+    step = min(_TOKENS, tokens)
+    if tokens % step:
+        raise ValueError("%d tokens in steps of %d" % (tokens, step))
+    return pl.pallas_call(
+        functools.partial(_kernel, whole=whole, turn=width - rotary,
+                          scale=scale),
+        grid=(tokens // step, heads),
+        in_specs=[pl.BlockSpec((step, rank), lambda i, h: (i, 0)),
+                  pl.BlockSpec((1, rank, columns), lambda i, h: (h, 0, 0)),
+                  pl.BlockSpec((step, width), lambda i, h: (i, 0)),
+                  pl.BlockSpec((step, width), lambda i, h: (i, 0))],
+        out_specs=pl.BlockSpec((1, step, columns), lambda i, h: (h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, tokens, columns),
+                                       latent.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret, name=KERNEL_NAME,
+    )(latent, weight, cos, sin)

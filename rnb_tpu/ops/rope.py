@@ -82,3 +82,23 @@ def rotate(x, positions, inv_freq):
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def turn_tables(positions, inv_freq, first: int, width: int,
+                mscale: float = 1.0):
+    """:func:`rotate` as two products a column, for a product that
+    brings the turned halves ``[-x2 | x1]`` itself (``ops/mla.py``):
+    ``rotate(x) * mscale == x * cos + turned * sin``, in the same
+    float32 numbers where ``mscale`` is 1. ``positions`` (tokens,);
+    the rotary columns are the ``2 * len(inv_freq)`` from ``first`` of
+    ``width``. -> (cos, sin) float32 (tokens, width): the cosines
+    and sines under both halves, 1 and 0 in front of them (columns
+    that pass as they are), 0 and 0 behind."""
+    angles = positions.astype(jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    tokens, half = angles.shape
+    cos, sin = jnp.cos(angles) * mscale, jnp.sin(angles) * mscale
+    front = jnp.zeros((tokens, first), jnp.float32)
+    behind = jnp.zeros((tokens, width - first - 2 * half), jnp.float32)
+    return (jnp.concatenate([front + 1.0, cos, cos, behind], 1),
+            jnp.concatenate([front, sin, sin, behind], 1))

@@ -100,33 +100,45 @@ def block_table(first, block_q: int, block_kv: int):
     return run.astype(jnp.int32), fetch, int((j <= hi).sum())
 
 
-def packed_attention(q, k, v, row_start, interpret: bool = False):
-    """``q`` (rows, Q, Hq, D), already scaled (``D ** -0.5``, or the
-    family's own); ``k`` (rows, Q, Hk, D) and ``v`` (rows, Q, Hk, Dv),
-    each kv head serving Hq // Hk query heads (Dv may differ from D:
-    latent attention's 192 / 128); ``row_start`` (rows,) int32.
-    -> ((rows, Q, Hq, Dv) in q's dtype, int32 (2,): the tiles the
-    kernel ran a head, and the tiles on or under the diagonal).
-
-    The kernel wants whole blocks of tokens and whole lanes of D and
-    Dv: a pool or a head narrower than that (the tests' sizes, a
-    192-wide q and k) is padded with tokens that are requests of their
-    own and zero columns."""
-    rows, qlen, hq, _ = q.shape
-    hk, dim_v = k.shape[2], v.shape[3]
-    per = hq // hk
-    tokens = rows * qlen
+def _blocks(tokens: int):
+    """(queries a tile, keys a tile) for a pool of ``tokens``."""
     whole = _round_up(tokens, _LANES)
-    block_q, block_kv = min(_BLOCK_Q, whole), min(_BLOCK_KV, whole)
-    padded = _round_up(tokens, math.lcm(block_q, block_kv))
+    return min(_BLOCK_Q, whole), min(_BLOCK_KV, whole)
 
-    def heads_first(x, heads):
-        dim = x.shape[-1]
-        x = x.reshape((tokens,) + heads + (dim,))
-        x = jnp.pad(x, ((0, padded - tokens),) + ((0, 0),) * len(heads)
-                    + ((0, _round_up(dim, _LANES) - dim),))
-        return jnp.moveaxis(x, 0, -2)
 
+def pool_tokens(tokens: int) -> int:
+    """The tokens of a pool as the kernel walks it: whole blocks of
+    queries and of keys (the pad tokens are requests of their own)."""
+    return _round_up(tokens, math.lcm(*_blocks(tokens)))
+
+
+def heads_first(x, columns=None):
+    """``x`` (tokens, ..., dim), a token's heads behind it -> (...,
+    ``pool_tokens(tokens)``, columns): the form the kernel reads, by a
+    pad (tokens that are requests of their own, zero columns up to
+    ``columns``: by default dim's next whole lanes) and a transpose."""
+    tokens, dim = x.shape[0], x.shape[-1]
+    columns = _round_up(dim, _LANES) if columns is None else columns
+    x = jnp.pad(x, ((0, pool_tokens(tokens) - tokens),)
+                + ((0, 0),) * (x.ndim - 2) + ((0, columns - dim),))
+    return jnp.moveaxis(x, 0, -2)
+
+
+def heads_first_attention(q, k, v, row_start, qlen: int,
+                          interpret: bool = False):
+    """The kernel's own form: ``q`` (Hk, Hq // Hk, P, D), already
+    scaled; ``k`` (Hk, P, D); ``v`` (Hk, P, Dv); P the
+    ``pool_tokens`` of the ``len(row_start) * qlen`` the pool holds, D
+    and Dv whole lanes (what lies past a head's own columns is zero in
+    ``k`` and ``v``); ``row_start`` (rows,) int32.
+    -> ((Hk, Hq // Hk, P, Dv) in q's dtype, int32 (2,): the tiles the
+    kernel ran a head, and the tiles on or under the diagonal)."""
+    per, padded = q.shape[1:3]
+    tokens = row_start.shape[0] * qlen
+    block_q, block_kv = _blocks(tokens)
+    if padded != pool_tokens(tokens):
+        raise ValueError("%d tokens laid out as %d, not %d"
+                         % (tokens, padded, pool_tokens(tokens)))
     # a token's segment id is the first token of its request
     segment = jnp.concatenate([
         jnp.repeat(row_start.astype(jnp.int32) * qlen, qlen),
@@ -147,8 +159,29 @@ def packed_attention(q, k, v, row_start, interpret: bool = False):
             data_next=fetch[None].astype(info.data_next.dtype)),
         None, None, **kernel.kwargs)
     out = jax.vmap(kernel, in_axes=(0, 0, 0, None))(
-        heads_first(q, (hk, per)), heads_first(k, (hk,)),
-        heads_first(v, (hk,)), splash.SegmentIds(segment, segment))
+        q, k, v, splash.SegmentIds(segment, segment))
+    return out, jnp.stack([run.sum(), jnp.int32(causal)])
+
+
+def packed_attention(q, k, v, row_start, interpret: bool = False):
+    """``q`` (rows, Q, Hq, D), already scaled (``D ** -0.5``, or the
+    family's own); ``k`` (rows, Q, Hk, D) and ``v`` (rows, Q, Hk, Dv),
+    each kv head serving Hq // Hk query heads (Dv may differ from D:
+    latent attention's 192 / 128); ``row_start`` (rows,) int32.
+    -> ((rows, Q, Hq, Dv) in q's dtype, int32 (2,): the tiles the
+    kernel ran a head, and the tiles on or under the diagonal).
+
+    Lays the operands out as :func:`heads_first_attention` reads them
+    (:func:`heads_first`), and its result back: the kernel wants heads
+    first, whole blocks of tokens and whole lanes of D and Dv. A caller
+    whose products write that form calls the kernel's own entry."""
+    rows, qlen, hq, dim = q.shape
+    hk, dim_v = k.shape[2], v.shape[3]
+    tokens = rows * qlen
+    out, tiles = heads_first_attention(
+        heads_first(q.reshape(tokens, hk, hq // hk, dim)),
+        heads_first(k.reshape(tokens, hk, dim)),
+        heads_first(v.reshape(tokens, hk, dim_v)), row_start, qlen,
+        interpret)
     out = jnp.moveaxis(out, -2, 0)[:tokens, ..., :dim_v]
-    return out.reshape(rows, qlen, hq, dim_v), \
-        jnp.stack([run.sum(), jnp.int32(causal)])
+    return out.reshape(rows, qlen, hq, dim_v), tiles

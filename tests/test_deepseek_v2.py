@@ -458,6 +458,88 @@ def test_latent_attention_matches_one_masked_softmax(toy):
     assert np.abs(got - want).max() < 0.03 * want.std()
 
 
+def plain_latent_attention(cfg, p, h, row_start, positions):
+    """Latent attention as the program ran it before its queries left
+    their product as the kernel's operand (PR 37's tree): every product
+    tokens-first, the queries' kept in float32, sliced, rotated,
+    concatenated, scaled, rounded, and all three operands laid out by
+    ``packed_attention``. ``p["q_b"]`` is the stored tensor: its first
+    128 + 64 columns a head are that tree's. -> (out, tiles, the
+    queries before the layout)."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.deepseek_v2.network import _proj, rms_norm
+    from rnb_tpu.ops import rope, segattn
+    rows, q, _ = h.shape
+    act = h.dtype
+    heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+    nope, value = cfg.qk_nope_head_dim, cfg.v_head_dim
+    inv_freq, scale = cfg.inv_freq(), cfg.softmax_scale
+    mscale = cfg.rotary_mscale
+    q_b = jnp.swapaxes(p["q_b"][..., :cfg.qk_head_dim], 0, 1) \
+        .reshape(cfg.q_lora_rank, heads * cfg.qk_head_dim)
+    c_q = rms_norm(_proj(h, p["q_a"]), p["q_a_norm"], cfg.eps, act)
+    qs = _proj(c_q, q_b).reshape(rows, q, heads, cfg.qk_head_dim)
+    down = _proj(h, p["kv_a"])
+    c_kv = rms_norm(down[..., :rank], p["kv_a_norm"], cfg.eps, act)
+    kv = _proj(c_kv, p["kv_b"]).astype(act) \
+        .reshape(rows, q, heads, nope + value)
+    q_pe = rope.rotate(qs[..., nope:], positions, inv_freq) * mscale
+    query = (jnp.concatenate([qs[..., :nope], q_pe], -1) * scale) \
+        .astype(act)
+    k_pe = (rope.rotate(down[..., rank:], positions, inv_freq) * mscale) \
+        .astype(act)
+    key = jnp.concatenate([
+        kv[..., :nope],
+        jnp.broadcast_to(k_pe[:, :, None, :],
+                         (rows, q, heads, cfg.qk_rope_head_dim))], -1)
+    out, tiles = segattn.packed_attention(query, key, kv[..., nope:],
+                                          row_start, True)
+    return _proj(out.reshape(rows, q, heads * value), p["o"]), tiles, query
+
+
+def test_latent_attention_is_the_plain_form_with_its_passes_gone(toy):
+    """Two requests and a pad row in one pool: the program's latent
+    attention against the form it had, kept above. The products'
+    shapes changed (a head's 24 columns among 128, heads a batch), so
+    their sums may round otherwise; the rotation, the scale and the
+    rounding may not: the queries' operand is the plain form's, laid
+    out, to the last bit on this backend."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.deepseek_v2 import network
+    from rnb_tpu.ops import mla, rope, segattn
+    cfg = toy["cfg"]
+    rng = np.random.default_rng(11)
+    h = jnp.asarray(rng.standard_normal((6, Q, cfg.hidden_size)),
+                    jnp.bfloat16)
+    row_start = jnp.asarray([0, 0, 0, 3, 3, 5], jnp.int32)
+    p = toy["params"]["l0"]
+    positions = rope.pool_positions(row_start, Q)
+    got, tiles = jax.jit(lambda p, h, s: network.latent_attention(
+        cfg, p, h, s, rope.pool_positions(s, Q), interpret=True))(
+        p, h, row_start)
+    want, plain_tiles, plain_query = jax.jit(
+        lambda p, h, s: plain_latent_attention(
+            cfg, p, h, s, rope.pool_positions(s, Q)))(p, h, row_start)
+    assert np.array_equal(np.asarray(tiles), np.asarray(plain_tiles))
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.abs(got - want).max() < 0.03 * want.std()
+    flat = h.reshape(6 * Q, -1)
+    c_q = network.rms_norm(network._proj(flat, p["q_a"]), p["q_a_norm"],
+                           cfg.eps, h.dtype)
+    query = mla.queries(c_q, p["q_b"], positions.reshape(-1),
+                        cfg.inv_freq(), cfg.qk_nope_head_dim,
+                        cfg.softmax_scale, cfg.rotary_mscale,
+                        interpret=True)
+    laid = segattn.heads_first(
+        plain_query.reshape(6 * Q, cfg.num_attention_heads, -1))
+    assert query.shape == (cfg.num_attention_heads, 6 * Q, 128)
+    assert np.array_equal(np.asarray(query, np.float32),
+                          np.asarray(laid[:, :6 * Q], np.float32))
+
+
 # -- the recipe, the stages, the counters -------------------------------------
 
 
@@ -478,11 +560,18 @@ def test_recipe_gives_program_and_reference_the_same_values(toy):
     assert np.array_equal(stored[:, :16], published[:, :16])
     assert np.array_equal(stored[:, 16:20], published[:, 16:24:2])
     assert np.array_equal(stored[:, 20:24], published[:, 17:24:2])
-    stored = np.asarray(params["l0"]["q_b"], np.float32).reshape(32, 4, 24)
+    # q_b lies heads-first, a head's 24 columns whole lanes wide: the
+    # rotary halves [x1 | x2] once more as [-x2 | x1], then zeros
+    stored = np.asarray(params["l0"]["q_b"], np.float32)
+    assert stored.shape == (4, 32, 128)
+    stored = stored.transpose(1, 0, 2)
     published = np.asarray(read("l0.q_b")).reshape(32, 4, 24)
     assert np.array_equal(stored[..., :16], published[..., :16])
     assert np.array_equal(stored[..., 16:20], published[..., 16:24:2])
     assert np.array_equal(stored[..., 20:24], published[..., 17:24:2])
+    assert np.array_equal(stored[..., 24:28], -published[..., 17:24:2])
+    assert np.array_equal(stored[..., 28:32], published[..., 16:24:2])
+    assert not stored[..., 32:].any()
     spec = checkpoint.tensor_specs(toy["cfg"], 4)["l0"]["q_b"]
     order = seeded.halves_order(spec)
     assert np.array_equal(order[seeded.halves_order(spec, inverse=True)],
@@ -501,6 +590,69 @@ def test_recipe_gives_program_and_reference_the_same_values(toy):
                           gate[2:])
     assert not np.array_equal(
         np.asarray(read("l1.up", (4,))), np.asarray(read("l1.gate", (4,))))
+
+
+def test_the_stored_orders_read_back_as_the_published_draw(toy):
+    """``read`` hands the reference what a plain spec of the published
+    shape draws under the same name, whatever order the program's
+    tensor is stored in."""
+    import dataclasses
+
+    from rnb_tpu.models import seeded
+    from rnb_tpu.models.deepseek_v2 import checkpoint
+    specs = checkpoint.tensor_specs(toy["cfg"], len(HELD))["l0"]
+    assert specs["q_b"].heads_first is not None
+    for name in ("q_b", "kv_b", "kv_a", "o"):
+        spec = specs[name]
+        plain = dataclasses.replace(
+            spec, shape=seeded.published_shape(spec), halves=None,
+            heads_first=None)
+        drawn = seeded.make_tensor(SEED, "l0." + name, plain, (),
+                                   toy["device"])
+        assert np.array_equal(np.asarray(drawn, np.float32),
+                              np.asarray(toy["read"]("l0." + name))), name
+    assert seeded.published_shape(specs["q_b"]) == (32, 4 * 24)
+    assert seeded.published_shape(
+        checkpoint.tensor_specs(toy["cfg"], 4)["l1"]["gate"]) \
+        == (4, TOY["hidden_size"], TOY["moe_intermediate_size"])
+
+
+def tree_hash(params):
+    import hashlib
+
+    import jax
+    digest = hashlib.sha256()
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    for path, leaf in sorted(leaves,
+                             key=lambda kv: jax.tree_util.keystr(kv[0])):
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(("%s%s" % (leaf.dtype, leaf.shape)).encode())
+        digest.update(np.asarray(leaf.astype("float32")).tobytes())
+    return digest.hexdigest()
+
+
+@pytest.mark.parametrize("family,want", [
+    ("nemotron_h", "1c1f2c5c241b48406811eafcaefa62ab"
+                   "eb7d2ef2455a206bceb55085db0c889c"),
+    ("minicpm_sala", "aff7174d3a935a9b82fd4a76167a07a1"
+                     "12baa6f147efc864815d96a8c473a926")])
+def test_the_other_families_seeded_trees_are_the_parents(family, want):
+    """A stored order is a spec's own: the families that name none draw
+    the trees they drew before ``TensorSpec.heads_first`` (the hashes
+    are the parent's, PR 37's tree, over the tests' toy sizes)."""
+    import importlib
+
+    import jax
+    toy_of = importlib.import_module("tests.test_" + family)
+    package = "rnb_tpu.models.%s." % family
+    network = importlib.import_module(package + "network")
+    checkpoint = importlib.import_module(package + "checkpoint")
+    config, = [c for c in vars(network).values() if isinstance(c, type)
+               and hasattr(c, "from_published")]
+    params = checkpoint.make_params(
+        config.from_published(toy_of.TOY), toy_of.SEED,
+        getattr(toy_of, "HELD", ()), jax.devices()[0])
+    assert tree_hash(params) == want
 
 
 def test_one_prefill_stage_serves_both_families(tmp_path):
@@ -775,10 +927,12 @@ def test_real_configuration_keeps_the_published_sizes():
     family = mm.load_family(config["family"])
     assert family.check_config(config) == []
     # the weights the file states, from the tensor list
+    from rnb_tpu.models import seeded
     from rnb_tpu.models.deepseek_v2 import checkpoint, network
     cfg = network.DeepseekV2Config.from_published(
         family.published_keys(config))
-    held = sum(int(np.prod(spec.shape)) for tensors in
+    # q_b's stored pad columns are no parameters of the model
+    held = sum(int(np.prod(seeded.published_shape(spec))) for tensors in
                checkpoint.tensor_specs(cfg, 20).values()
                for spec in tensors.values())
     assert abs(held / 1e9 - config["model"]["params_billions_held"]) < 0.01
@@ -864,20 +1018,14 @@ def test_a_gated_expert_layer_moves_its_pairs_once_each_way(one_chip):
         + ["f32[%d,%d]" % (tokens * k, d)]
 
 
-def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
-    """The real stage program at 64 rows, compiled for the described
-    v5e (nothing runs). A reading kept, not a mechanism of
-    ``held_experts``: with the pairs (k, T) the compiler's memory space
-    assignment keeps the tokens' rows in its fast memory for the
-    gather into expert order in all four expert layers, and that gather
-    takes 0.77 ms for the 3.73 it took from HBM in the (T, k) form (my
-    chip runs, PR 34). A change that moves this count has moved 3 ms a
-    layer of the dispatch, and says so."""
+@pytest.fixture(scope="module")
+def stage_program(one_chip):
+    """-> (the real configuration, the text of its stage program at 64
+    rows compiled for the described v5e; nothing runs)."""
     import jax
     import jax.numpy as jnp
 
     from rnb_tpu.models.deepseek_v2 import checkpoint, network
-    from tests.compiled_experts import gather_in_sources
     cfg, rows, held = real_sizes()
     params = {}
     for group, tensors in checkpoint.tensor_specs(cfg, held).items():
@@ -888,10 +1036,23 @@ def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
 
     def of(shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    text = jax.jit(lambda p, s, t, m: network.forward(
+    return cfg, jax.jit(lambda p, s, t, m: network.forward(
         cfg, p, s, t, m[0], m[1], m[2])).lower(
         params, of((cfg.router_experts,)), of((rows, cfg.chunk_size)),
         of((3, rows))).compile().as_text()
+
+
+def test_the_gather_into_expert_order_reads_the_fast_memory(stage_program):
+    """The real stage program at 64 rows, compiled for the described
+    v5e (nothing runs). A reading kept, not a mechanism of
+    ``held_experts``: with the pairs (k, T) the compiler's memory space
+    assignment keeps the tokens' rows in its fast memory for the
+    gather into expert order in all four expert layers, and that gather
+    takes 0.77 ms for the 3.73 it took from HBM in the (T, k) form (my
+    chip runs, PR 34). A change that moves this count has moved 3 ms a
+    layer of the dispatch, and says so."""
+    from tests.compiled_experts import gather_in_sources
+    cfg, text = stage_program
     assert gather_in_sources(text, 8192, 6, 5120) == [True] * 4
     # the flash kernel, a layer, keeps the name and the scope that two
     # readers of benchmarks/ find it by, its block table traced data
@@ -900,3 +1061,60 @@ def test_the_gather_into_expert_order_reads_the_fast_memory(one_chip):
              if head.startswith("%splash_mqa_fwd_segmented_no_residuals")]
     assert len(flash) == cfg.num_hidden_layers == 5
     assert all("/attn/" in scope for scope in flash)
+
+
+def test_the_queries_reach_the_kernel_from_their_product(stage_program):
+    """The same program: what lies under ``attn`` between the products
+    and the flash kernel, counted over the arrays of a whole (tokens x
+    heads) operand (64 columns a head or more) that an instruction of
+    the program's own writes to memory.
+
+    No float32 array of tokens x heads x 192 elements is written: the
+    queries' float32 product stays inside ``mla_queries``, which
+    writes the kernel's operand ``bf16[128,8192,256]`` itself, a layer.
+    Every other such array that neither a product nor a kernel writes
+    is a layout pass. PR 37's tree had 12 a layer, 60 in all (the
+    float32 queries sliced and copied, rotated and concatenated,
+    padded and transposed; the rotary key's broadcast, the keys'
+    concatenate, pad and transpose, the values' copy and transpose,
+    three copies of the keys-values product, the result's transpose
+    and reshape in front of ``o``), and the float32 ``[64,128,24576]``
+    besides. The budget this tree ends with is **5 a layer, 25**, all
+    on the keys' and values' side, which still goes through
+    ``segattn.heads_first``: the rotary key's broadcast, a copy of the
+    keys-values product, the keys' and the values' copies, the keys'
+    pad and transpose. ``o`` reads the kernel's result as it lies."""
+    import re
+
+    from rnb_tpu import hloscopes
+    from rnb_tpu.ops import mla
+    cfg, text = stage_program
+    tokens, heads = 64 * cfg.chunk_size, cfg.num_attention_heads
+    scopes = hloscopes.scopes_of_hlo(text)
+    head = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \(?((\w+)\[([\d,]*)\])"
+                      r"\S* ([a-z\-]+)\(")
+    passes, queries = [], []
+    # the entry computation is the module's last: every line behind
+    # its head that reads as an instruction is one of the program's own
+    for line in text[text.index("\nENTRY "):].splitlines():
+        found = head.match(line)
+        if not found:
+            continue
+        name, shape, dtype, dims, opcode = found.groups()
+        scope = scopes.get("%s %s" % (name, shape), "")
+        elements = int(np.prod([int(d) for d in dims.split(",") if d]))
+        # a copy the compiler's layout assignment put in carries no scope
+        if elements < tokens * heads * 64 or not (
+                "/attn/" in scope or not scope) \
+                or opcode in ("parameter", "bitcast", "get-tuple-element"):
+            continue
+        assert not (dtype == "f32"
+                    and elements >= tokens * heads * cfg.qk_head_dim), line
+        if opcode == "custom-call":
+            if mla.KERNEL_NAME in scope:
+                queries.append(shape)
+        elif "kind=kOutput" not in line:        # not a product
+            passes.append(name)
+    assert queries == ["bf16[%d,%d,%d]" % (heads, tokens, mla.query_lanes(
+        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim))] * 5
+    assert len(passes) <= 25, passes

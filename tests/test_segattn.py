@@ -172,6 +172,63 @@ def test_the_kernel_under_the_table_equals_every_causal_tile_bit_for_bit(
     assert (np.abs(got - want) <= 2.0 ** -7 * np.abs(want) + 2.0 ** -9).all()
 
 
+#: the two callers' real head counts and widths, over a pool of two
+#: blocks: Nemotron-H's grouped queries, DeepSeek-V2's latent attention
+ENTRIES = {
+    "gqa-32-over-2-of-128": dict(hq=32, hk=2, dim=128, dim_v=128),
+    "mla-128-of-192-and-128": dict(hq=128, hk=128, dim=192, dim_v=128),
+}
+
+
+@pytest.mark.parametrize("form", sorted(ENTRIES))
+def test_the_upper_entry_is_the_kernels_own_behind_a_layout(form,
+                                                            monkeypatch):
+    """``packed_attention`` (tokens first in) against
+    ``heads_first_attention`` fed operands laid out by hand in numpy:
+    heads first, the pool's 176 tokens padded to two blocks of 128,
+    192 columns to 256 with zeros. Bit for bit, the tiles too."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import segattn
+    for name in ("_BLOCK_Q", "_BLOCK_KV", "_BLOCK_COMPUTE"):
+        monkeypatch.setattr(segattn, name, 128)
+    shape = ENTRIES[form]
+    rows, tokens, padded = 11, 11 * Q, 256
+    assert segattn.pool_tokens(tokens) == padded
+    start = jnp.asarray(row_starts([5, 4], rows))
+    rng = np.random.default_rng(len(form))
+    q, k, v = (jnp.asarray(rng.standard_normal((rows, Q, heads, dim)),
+                           jnp.bfloat16)
+               for heads, dim in ((shape["hq"], shape["dim"]),
+                                  (shape["hk"], shape["dim"]),
+                                  (shape["hk"], shape["dim_v"])))
+    q = q * shape["dim"] ** -0.5
+    got, tiles = jax.jit(lambda *a: segattn.packed_attention(
+        *a, interpret=True))(q, k, v, start)
+
+    def by_hand(x, heads):
+        x = np.asarray(x, np.float32).reshape((tokens,) + heads + (-1,))
+        laid = np.zeros(heads + (padded, -(-x.shape[-1] // 128) * 128),
+                        np.float32)
+        laid[..., :tokens, :x.shape[-1]] = np.moveaxis(x, 0, -2)
+        return jnp.asarray(laid, jnp.bfloat16)
+    hk, per = shape["hk"], shape["hq"] // shape["hk"]
+    out, own_tiles = jax.jit(lambda *a: segattn.heads_first_attention(
+        *a, Q, interpret=True))(
+        by_hand(q, (hk, per)), by_hand(k, (hk,)), by_hand(v, (hk,)), start)
+    assert out.shape == (hk, per, padded, shape["dim_v"])
+    assert np.array_equal(np.asarray(tiles), np.asarray(own_tiles))
+    back = np.moveaxis(np.asarray(out, np.float32), -2, 0)[:tokens]
+    assert np.array_equal(
+        np.asarray(got, np.float32),
+        back.reshape(rows, Q, shape["hq"], shape["dim_v"]))
+    with pytest.raises(ValueError, match="laid out as"):
+        segattn.heads_first_attention(
+            by_hand(q, (hk, per))[..., :128, :], by_hand(k, (hk,)),
+            by_hand(v, (hk,)), start, Q, interpret=True)
+
+
 def test_the_attention_line_is_declared_summed_and_parsed(tmp_path):
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     import parse_utils
