@@ -1,5 +1,5 @@
 """The pipeline stages of the token families (``nemotron_h``,
-``deepseek_v2``, ``minicpm_sala``): a first stage whose request is a
+``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``): a first stage whose request is a
 prompt file, and a final stage that runs a family's stack over a packed
 pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
 (``segments: true``), which fuses requests into row buckets up to the

@@ -14,10 +14,13 @@ kernel on TPU backends and to an identical jnp formulation elsewhere
 (CPU tests, interpret mode), so numerics are defined once.
 
 The token-sequence families (rnb_tpu.models.nemotron_h,
-rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala) add five
-mechanisms, each over a packed pool of rows with state confined to
-requests: ``ssd`` (the blocked Mamba-2 scan and its convolution, in
-plain jnp/lax; lightning linear attention is its case of unit steps),
+rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
+rnb_tpu.models.qwen3_next) add six mechanisms, each over a packed pool
+of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
+scan and its convolution, in plain jnp/lax; lightning linear attention
+is its case of unit steps), ``deltanet`` (the gated delta rule, whose
+transition is a matrix: a triangular solve inside a row and a scan over
+the rows, in plain jnp/lax),
 ``blocksparse`` (every query's own top-k blocks of keys from
 mean-compressed keys, and a Pallas flash kernel under that block
 mask), ``segattn``

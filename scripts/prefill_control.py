@@ -47,7 +47,7 @@ def arms_of(family: str):
         return [("as_stated", {}, None),
                 ("experts_float8", {}, lambda group, name: name in _FFN),
                 ("layers_float8", {}, lambda group, name: True)]
-    if family == "minicpm_sala":
+    if family in ("minicpm_sala", "qwen3_next"):
         return [("as_stated", {}, None),
                 ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
                 ("layers_float8", {}, lambda group, name: True)]

@@ -1,0 +1,785 @@
+"""The Qwen3-Next family against its plain reference, at a toy size on
+the CPU with weights from a seed: the packed prefill through dispatches
+with several requests, a pad row and a request that ends inside a row,
+the lower-precision controls, the gated delta rule through
+``ops/deltanet`` against the token-by-token recurrence with state and
+convolution history reset at every request's first row, the triangular
+solve alone, the shares of the experts adding up to the uncut layer,
+partial rotary, the stages and their counters, the operation counts,
+the cell through the one benchmark command, the four new readers on a
+run without their scope, the real configuration against the catalog's
+row, and the shared code's StableHLO for the three older families.
+Nothing here needs the native decode library or a chip."""
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmarks import manifest as mm  # noqa: E402
+from benchmarks.references import compare  # noqa: E402
+from benchmarks.references import qwen3_next as reference  # noqa: E402
+
+REAL = "benchmarks/configs/qwen3-next-l4-ep2.json"
+CELL = "qwen3-next.bulk"
+SEED = 3_000_000_123
+
+#: one period of the published pattern at toy widths: 2 key / 4 value
+#: heads of 16 in the DeltaNet layers, 4 / 2 heads of 32 with rotary on
+#: 8 columns in the attention layer, 16 experts top-4 of which 8 held
+TOY = {
+    "num_hidden_layers": 4, "full_attention_interval": 4,
+    "hidden_size": 64, "vocab_size": 256, "chunk_size": 16,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
+    "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 16, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "num_experts": 8,
+    "num_experts_per_tok": 4, "moe_intermediate_size": 32,
+    "shared_expert_intermediate_size": 32, "rms_norm_eps": 1e-6,
+    "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "norm_topk_prob": True, "rope_scaling": None, "hidden_act": "silu",
+    "published": {"num_hidden_layers": 48, "num_experts": 16}}
+HELD = tuple(range(8))
+OTHER = tuple(range(8, 16))
+Q = TOY["chunk_size"]
+#: the comparison's limit at the toy widths: narrow sums average less
+#: rounding away than the real ones (the real limit is the family
+#: file's SHARE_OF_SPREAD); the toy reads 2.2 to 2.7% over two seeds
+#: of weights and its float8 control 11 to 19%
+TOY_LIMIT = 0.04
+
+
+@pytest.fixture(scope="module")
+def toy():
+    import jax
+
+    from rnb_tpu.models.qwen3_next import checkpoint, network
+    cfg = network.Qwen3NextConfig.from_published(TOY)
+    device = jax.devices()[0]
+    return {"cfg": cfg, "device": device,
+            "params": checkpoint.make_params(cfg, SEED, HELD, device),
+            "slots": network.held_slots(cfg, HELD),
+            "read": checkpoint.reference_reader(cfg, SEED, device),
+            "reference": reference.Reference(TOY)}
+
+
+def prompts_of(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n).astype(np.int32)
+            for n in lengths]
+
+
+def pack(prompts, rows):
+    from rnb_tpu.models import token_stages
+    return token_stages.pack_prompts(prompts, rows, Q)
+
+
+def run_program(toy, prompts, rows, params=None, **kwargs):
+    """-> (logits a prompt, each prompt's router choices (layers,
+    tokens, k), the counters)."""
+    import jax
+
+    from rnb_tpu.models.qwen3_next import network
+    tokens, meta, offsets = pack(prompts, rows)
+    logits, chosen, *counts = jax.jit(
+        lambda p, t, m: network.forward(
+            toy["cfg"], p, toy["slots"], t, m[0], m[1], m[2],
+            interpret=True, **kwargs))(
+        toy["params"] if params is None else params, tokens, meta)
+    chosen = np.asarray(chosen)
+    per_prompt = [chosen[:, o * Q:o * Q + len(p)]
+                  for o, p in zip(offsets, prompts)]
+    return np.asarray(logits)[:len(prompts)], per_prompt, \
+        [np.asarray(c) for c in counts]
+
+
+def run_reference(toy, prompt, forced=None):
+    import jax
+    with jax.default_matmul_precision("highest"):
+        return toy["reference"].forward(toy["read"], prompt, held=HELD,
+                                        forced=forced)
+
+
+def through_float8(params):
+    """The stored matrices of every layer rounded through float8 (e4m3):
+    the nearest precision below the one the configuration states."""
+    import jax.numpy as jnp
+    out = dict(params)
+    for group, tensors in params.items():
+        if isinstance(tensors, dict):
+            out[group] = {
+                name: (w.astype(jnp.float8_e4m3fn).astype(w.dtype)
+                       if w.ndim >= 2 else w)
+                for name, w in tensors.items()}
+    return out
+
+
+# -- the whole stack ----------------------------------------------------------
+
+#: dispatches of 16 rows: several requests, one that ends inside a row,
+#: one that fills its rows, pad rows behind
+DISPATCHES = {"three": [120, 37, 70], "whole_rows": [16, 96, 5, 64],
+              "one_long": [250]}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCHES))
+def test_packed_prefill_matches_the_reference(toy, case):
+    family = mm.load_family("qwen3_next")
+    prompts = prompts_of(DISPATCHES[case], seed=4)
+    logits, chosen, counts = run_program(toy, prompts, 16)
+    refs = [run_reference(toy, p, forced=c)
+            for p, c in zip(prompts, chosen)]
+    want = np.stack([np.asarray(r["logits"]) for r in refs])
+    verdict = compare(logits, want, TOY_LIMIT)
+    assert verdict["ok"], verdict
+    assert max(float(np.asarray(r["shortfall"]).max()) for r in refs) \
+        < family.ROUTE_SLACK
+    # the reference's own free choice agrees almost everywhere
+    for prompt, mine in zip(prompts, chosen):
+        free = np.asarray(run_reference(toy, prompt)["chosen"])
+        assert (np.sort(free, -1) == np.sort(mine, -1)).all(-1).mean() > 0.9
+    # the counters: served pairs and sending tokens of the valid tokens
+    served, sent, tiles = counts
+    valid = sum(DISPATCHES[case])
+    assert served.shape == (4, 8) and sent.shape == (4,)
+    assert served.sum() == sum(int(np.isin(c, HELD).sum()) for c in chosen)
+    assert (sent <= valid).all() and sent.min() > 0
+    assert tiles.shape == (1, 2) and tiles[0, 0] <= tiles[0, 1]
+
+
+def test_the_lower_precision_controls(toy):
+    """The stored matrices through float8 lie outside the stated
+    tolerance, with the rule's states through bfloat16 and without. The
+    states through bfloat16 alone are reported by the control script on
+    the chip; here they read like the stated precision."""
+    import jax.numpy as jnp
+    prompts = prompts_of([120, 37, 70], seed=4)
+
+    def reading(**how):
+        logits, chosen, _ = run_program(toy, prompts, 16, **how)
+        want = np.stack([np.asarray(run_reference(toy, p, forced=c)
+                                    ["logits"])
+                         for p, c in zip(prompts, chosen)])
+        return compare(logits, want, TOY_LIMIT)
+    assert reading()["ok"]
+    assert not reading(params=through_float8(toy["params"]))["ok"]
+    assert not reading(params=through_float8(toy["params"]),
+                       state_dtype=jnp.bfloat16)["ok"]
+    assert reading(state_dtype=jnp.bfloat16)["share_of_spread"] < 0.2
+
+
+def test_packing_is_invisible_and_state_and_positions_restart(toy):
+    """A prompt's logits and choices depend neither on what shares its
+    dispatch, nor on where in the pool it lies, nor on the bucket."""
+    a, b, c, d = prompts_of([100, 5, 70, 20])
+    alone, chosen, _ = run_program(toy, [a], 8)
+    packed, packed_chosen, _ = run_program(toy, [b, c, a, d], 16)
+    other, other_chosen, _ = run_program(toy, [d, a], 16)
+    want = run_reference(toy, a, forced=chosen[0])
+    spread = float(np.asarray(want["logits"]).std())
+    for got in (packed[2], other[1]):
+        assert np.abs(got - alone[0]).max() < 0.005 * spread
+    assert np.array_equal(packed_chosen[2], chosen[0])
+    assert np.array_equal(other_chosen[1], chosen[0])
+    assert compare(alone[0], np.asarray(want["logits"]), TOY_LIMIT)["ok"]
+
+
+# -- the delta rule alone -------------------------------------------------------
+
+
+def rule_inputs(rows, qlen, hk=2, hv=4, dk=8, dv=8, seed=1):
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k = n(rows, qlen, hk, dk), n(rows, qlen, hk, dk)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return (q, k, n(rows, qlen, hv, dv),
+            -0.3 * jnp.exp(n(rows, qlen, hv)),
+            jax.nn.sigmoid(n(rows, qlen, hv)))
+
+
+@pytest.mark.parametrize("qlen,firsts", [
+    (16, (0,)), (16, (0, 3, 4)), (16, (0, 1, 2, 3, 4, 5)),
+    (64, (0, 2)), (128, (0, 1))])
+def test_the_blocked_rule_matches_the_recurrence(qlen, firsts):
+    """``gated_delta_rule`` over a pool of six rows (three at 128)
+    against the plain reference's recurrence, token by token, a request
+    at a time: the
+    state starts at zero at every request's first row. Rows of 64 and
+    128 tokens go through the solve's merged levels."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    rows = 3 if qlen == 128 else 6
+    inputs = rule_inputs(rows, qlen)
+    row_first = np.zeros(rows, bool)
+    row_first[list(firsts)] = True
+    out = np.asarray(deltanet.gated_delta_rule(
+        *inputs, jnp.asarray(row_first)))
+    bounds = list(firsts) + [rows]
+    for lo, hi in zip(bounds, bounds[1:]):
+        q, k, v, log_alpha, beta = (
+            x[lo:hi].reshape((-1,) + x.shape[2:]) for x in inputs)
+        want = np.asarray(reference.delta_rule(
+            jnp.repeat(q, 2, axis=1), jnp.repeat(k, 2, axis=1), v,
+            jnp.exp(log_alpha), beta))
+        got = out[lo:hi].reshape(want.shape)
+        assert np.abs(got - want).max() < 2e-5 * max(
+            1.0, np.abs(want).max())
+    # a state that did not restart would show: the second request's
+    # first token reads only its own write
+    if len(firsts) > 1:
+        lo = firsts[1]
+        q, k, v, _, beta = (np.asarray(x, np.float64) for x in inputs)
+        alone = beta[lo, 0, :, None] * v[lo, 0] * np.repeat(
+            (k[lo, 0] * q[lo, 0]).sum(-1), 2)[:, None]
+        assert np.abs(out[lo, 0] - alone).max() < 1e-5
+
+
+@pytest.mark.parametrize("size", [8, 16, 32, 128])
+def test_the_triangular_solve_is_the_inverse(size):
+    from rnb_tpu.ops import deltanet
+    rng = np.random.default_rng(size)
+    lower = np.tril(0.2 * rng.normal(size=(5, size, size)), -1) \
+        .astype(np.float32)
+    got = np.asarray(deltanet.unit_lower_inverse(lower))
+    want = np.linalg.inv(np.eye(size) + lower.astype(np.float64))
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+    assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+
+def test_keys_alike_do_not_break_the_solve():
+    """Neighbouring keys that are all but equal with steps near one: the
+    Neumann series' terms would grow to 1e30 over a row of 128; the
+    substitution stays exact."""
+    from rnb_tpu.ops import deltanet
+    lower = np.tril(np.full((1, 128, 128), 0.99, np.float32), -1)
+    got = np.asarray(deltanet.unit_lower_inverse(lower))
+    want = np.linalg.inv(np.eye(128) + lower[0].astype(np.float64))
+    assert np.abs(got[0] - want).max() < 1e-5
+
+
+def test_the_mixer_restarts_state_and_convolution_history(toy):
+    """The DeltaNet mixer on a packed pool against each request alone:
+    the convolution's history and the rule's state are zero at every
+    request's first row, wherever it lies."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.qwen3_next import network
+    cfg, p = toy["cfg"], toy["params"]["l0"]
+    rng = np.random.default_rng(5)
+    h = jnp.asarray(rng.normal(size=(6, Q, 64)), jnp.bfloat16)
+    first = jnp.asarray([True, False, False, True, True, False])
+    packed = np.asarray(network.deltanet_mixer(cfg, p, h, first))
+    for lo, hi in ((0, 3), (3, 4), (4, 6)):
+        alone = np.asarray(network.deltanet_mixer(
+            cfg, p, h[lo:hi], jnp.arange(hi - lo) == 0))
+        assert np.abs(packed[lo:hi] - alone).max() \
+            < 1e-5 * np.abs(alone).max()
+    # and against the plain reference's mixer, token by token
+    w = {t: toy["read"]("l0." + t) for t in reference.DELTANET}
+    import jax
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(reference.deltanet(
+            TOY, w, h[:3].reshape(3 * Q, 64).astype(jnp.float32)))
+    assert np.abs(packed[:3].reshape(want.shape) - want).max() \
+        < 0.03 * want.std()
+
+
+# -- the experts' shares ----------------------------------------------------------
+
+
+def test_the_shares_add_up_to_the_uncut_layer(toy):
+    """Experts 0-7 held here and 8-15 on the other chip: the two shares'
+    routed parts plus the shared expert, which both chips compute alike,
+    once, are the uncut reference's expert layer. In the reference, and
+    in the program with the slots of each share."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.qwen3_next import checkpoint, network
+    cfg, model, read = toy["cfg"], toy["reference"], toy["read"]
+    rng = np.random.default_rng(7)
+    hb = jnp.asarray(rng.normal(size=(3, Q, 64)), jnp.bfloat16)
+    h = hb.reshape(3 * Q, 64).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole, ids, _, _, shared = model.experts(read, 1, h, range(16))
+        here = model.experts(read, 1, h, HELD)
+        there = model.experts(read, 1, h, OTHER)
+    whole, shared = np.asarray(whole), np.asarray(shared)
+    summed = np.asarray(here[3]) + np.asarray(there[3]) + shared
+    assert np.abs(summed - whole).max() < 1e-5 * np.abs(whole).max()
+    assert np.abs(np.asarray(here[0]) + np.asarray(there[0]) - shared
+                  - whole).max() < 1e-5 * np.abs(whole).max()
+    # the tokens' choices are spread over both shares
+    assert np.isin(np.asarray(ids), HELD).any() \
+        and np.isin(np.asarray(ids), OTHER).any()
+    # the program: each share's layer from its own stacks and slots
+    ok = jnp.ones((3, Q), bool)
+    outs = []
+    for share in (HELD, OTHER):
+        p = checkpoint.make_params(cfg, SEED, share, toy["device"],
+                                   groups=["l1"])["l1"]
+        out, chose, counts, _ = network.experts_ffn(
+            cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
+        assert int(counts.sum()) == int(np.isin(np.asarray(chose),
+                                                share).sum())
+        outs.append(np.asarray(out).reshape(3 * Q, 64))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(model.experts(
+            read, 1, h, range(16), forced=jnp.asarray(chose))[0])
+    got = outs[0] + outs[1] - shared
+    assert np.abs(got - want).max() < 0.03 * want.std()
+
+
+# -- partial rotary ---------------------------------------------------------------
+
+
+def test_partial_rotary_leaves_the_rest_of_a_head_untouched(toy):
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.qwen3_next import network
+    from rnb_tpu.ops import rope
+    cfg = toy["cfg"]
+    assert cfg.rotary_dim == 8 and len(cfg.inv_freq()) == 4
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(4, Q, 2, 32)), jnp.float32)
+    # rows 0-1 one request, row 2 the next, row 3 a pad row
+    positions = rope.pool_positions(jnp.asarray([0, 0, 2, 3]), Q)
+    out = np.asarray(network.rotate_front(cfg, x, positions))
+    assert np.array_equal(out[..., 8:], np.asarray(x)[..., 8:])
+    # position 0 turns nothing; any other turns the first columns
+    assert np.allclose(out[0, 0], np.asarray(x)[0, 0], atol=1e-6)
+    assert np.allclose(out[2, 0], np.asarray(x)[2, 0], atol=1e-6)
+    assert np.abs(out[1, 5, :, :8] - np.asarray(x)[1, 5, :, :8]).max() > 0.1
+    # the plain reference's rotary on the first request
+    want = np.asarray(reference.rotary(TOY, x[:2].reshape(2 * Q, 2, 32)))
+    assert np.abs(out[:2].reshape(want.shape) - want).max() < 1e-5
+    # the real sizes: 64 of 256 columns, theta 1e7
+    with open(os.path.join(REPO, REAL)) as f:
+        real = network.Qwen3NextConfig.from_published(
+            mm.load_family("qwen3_next").published_keys(json.load(f)))
+    assert real.rotary_dim == 64
+    assert np.allclose(real.inv_freq()[[0, -1]], [1.0, 1e7 ** (-62 / 64)])
+
+
+# -- the recipe and the stages ------------------------------------------------------
+
+
+def test_recipe_gives_program_and_reference_the_same_values(toy):
+    from rnb_tpu.models.qwen3_next import checkpoint
+    params, read = toy["params"], toy["read"]
+    for name, tensor in (("l0.in_qkvz", params["l0"]["in_qkvz"]),
+                         ("top.embed", params["embed"]),
+                         ("l3.q", params["l3"]["q"]),
+                         ("l2.a_log", params["l2"]["a_log"]),
+                         ("l1.shared_w", params["l1"]["shared_w"])):
+        assert np.array_equal(np.asarray(tensor, np.float32),
+                              np.asarray(read(name)))
+    # a routed expert's first matrices lie [held, inner, hidden]; the
+    # reference reads them as published, by global id
+    assert params["l0"]["gate"].shape == (8, 32, 64)
+    assert np.array_equal(
+        np.asarray(params["l0"]["gate"][3], np.float32).T,
+        np.asarray(read("l0.gate", [3]))[0])
+    assert np.asarray(read("l3.q_norm")).tolist() \
+        == [checkpoint.QK_NORM] * 32
+    assert np.abs(np.asarray(read("l0.mixer_norm"))).max() <= 0.1
+    assert np.asarray(read("l0.o_norm")).tolist() == [1.0] * 16
+    assert "q" not in params["l0"] and "in_qkvz" not in params["l3"]
+    assert params["l0"]["in_qkvz"].shape == (64, 2 * 32 + 2 * 64)
+    assert params["l3"]["q"].shape == (64, 4 * 2 * 32)
+
+
+def test_the_prefill_stage_serves_the_family(tmp_path):
+    """The final stage learns the family from the recipe, counts the
+    held experts' assignments and the flash kernel's tiles, names the
+    scopes the readers look for and keeps the router's choices."""
+    from rnb_tpu.devices import DeviceSpec
+    from rnb_tpu.models import token_stages
+    from rnb_tpu.models.qwen3_next import checkpoint
+    from rnb_tpu.stage import PaddedBatch
+    from rnb_tpu.telemetry import aggregate_stage_counters
+    recipe = str(tmp_path / "toy.recipe.json")
+    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
+    stage = token_stages.PackedPrefill(
+        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
+        row_buckets=[4, 8], family="qwen3_next", sample_every=1, samples=2)
+    assert stage.family == "qwen3_next" and stage._slots is not None
+    prompts = prompts_of([80, 9, 30], seed=2)
+    tokens, meta, offsets = pack(prompts, 8)
+    batch = PaddedBatch(tokens, offsets[-1])
+    batch.segment_offsets = tuple(offsets)
+
+    class Card:
+        def __init__(self, rid):
+            self.id = rid
+
+    class Cards:
+        time_cards = [Card(0), Card(1), Card(2)]
+    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, Cards())
+    counters = stage.stage_counters()
+    valid = sum(len(p) for p in prompts)
+    assert counters["tokens_valid"] == valid
+    assert counters["tokens_shipped"] == 8 * Q
+    assert counters["experts_per_token"] == 4
+    assert counters["expert_served"].shape == (4, 8)
+    assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
+    assert 0 < counters["group_tokens"] <= 4 * valid
+    assert counters["attn_tiles"].tolist() == [1, 1]
+    tokens_line, experts_line = aggregate_stage_counters([counters])
+    assert tokens_line == {"valid": valid, "shipped": 8 * Q}
+    assert experts_line is not None
+    for scope in ("/deltanet/", "/deltanet/rule/", "/attn/", "/experts/",
+                  "/head/", "/embed/"):
+        assert any(scope in name + "/"
+                   for name in stage.hlo_scopes.values()), scope
+    assert len(stage._samples) == 2
+    first = stage._samples[0]
+    assert first["tokens"].tolist() == prompts[0].tolist()
+    assert first["chosen"].shape == (4, 80, 4)
+
+
+def test_operation_counts_agree_with_the_family_file():
+    from rnb_tpu.models.qwen3_next import flops, network
+    family = mm.load_family("qwen3_next")
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.Qwen3NextConfig.from_published(
+        family.published_keys(config))
+    assert flops.flops_per_token(cfg, 3000.0, 5.0) \
+        == family.flops_per_token(config, 3000.0, 5.0)
+    assert flops.deltanet_flops_per_token(cfg) \
+        == family.deltanet_flops_per_token(config)
+    assert flops.delta_rule_flops_per_token(cfg) == 7 * 32 * 128 * 128 \
+        == family.delta_rule_flops_per_token(config)
+    assert family.flops_per_row(config) == 128 * flops.flops_per_token(
+        cfg, family.mean_context(config), 5.0)
+    # ISSUE 39's arithmetic: some 0.54 GFLOP a token, of which the one
+    # attention layer's scores some 90 MFLOP at the mix's mean context
+    per_token = family.flops_per_row(config) / 128
+    assert 0.45e9 < per_token < 0.62e9
+    assert 70e6 < flops.attention_score_flops_per_token(
+        cfg, family.mean_context(config)) < 110e6
+    # both callers: scopes.py passes the held assignments, subscopes.py
+    # does not
+    ops, nbytes = family.mechanism_work(config, "deltarule", 1e6, 80.0)
+    assert ops == 3 * 1e6 * 7 * 32 * 128 * 128
+    assert nbytes == 3 * 1e6 * (2 * 8192 + 8 * 32 + 4 * 4096)
+    assert family.mechanism_work(config, "deltanet", 1e6, 5e6, 80.0) \
+        == family.mechanism_work(config, "deltanet", 1e6, 80.0)
+    gmm_ops, _ = family.mechanism_work(config, "gmm", 1e6, 5e6, 80.0)
+    assert gmm_ops == 5e6 * flops.expert_flops(cfg)
+    flash_ops, _ = family.mechanism_work(config, "flash", 1e6, 5e6, 80.0)
+    assert flash_ops == 1e6 * 4 * family.mean_context(config) * 4096
+    experts_ops, experts_bytes = family.mechanism_work(
+        config, "experts", 1e6, 5e6, 80.0)
+    assert experts_ops > gmm_ops and experts_bytes > 80 * 4 * 2 * 805e6
+
+
+# -- through the one benchmark command ------------------------------------------
+
+
+def toy_config():
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    config.update(TOY)
+    config["experts_held"] = {"first": 0, "count": 8}
+    config["dataset"] = {"seed": 0, "long_every": 11,
+                         "short": {"count": 6, "median": 60, "sigma": 0.5,
+                                   "min": 20, "max": 100},
+                         "long": {"count": 2, "min": 100, "max": 128}}
+    config["capacity_videos_per_chip_s"] = 300
+    config["share_of_spread"] = TOY_LIMIT
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    loader.update(max_rows=8, chunk=16)
+    batcher.update(batch=8, shapes=[[8, 16], [8]], row_buckets=[4, 8])
+    prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
+                   sample_every=3, samples=8)
+    return config
+
+
+def toy_tree(tmp_path):
+    """The real manifest's new cell over a toy-width copy of its
+    configuration: the same family, stages, mix and readers."""
+    os.makedirs(tmp_path / "benchmarks" / "configs")
+    with open(tmp_path / REAL, "w") as f:
+        json.dump(toy_config(), f)
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(mm.load(), f)
+    return str(tmp_path / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_the_cell_through_the_benchmark_command(trace, tmp_path):
+    out = tmp_path / "out"
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+         "--manifest", toy_tree(tmp_path), "--workload", CELL,
+         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
+         "--platform", "cpu", "--out", str(out)],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, \
+        done.stderr[-3000:]
+    assert line["attempted"] > 0
+    meta = (out / "run" / "log-meta.txt").read_text()
+    for name in ("Tokens: valid=", "Experts:", "Attention:"):
+        assert name in meta, name
+    assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
+    with open(out / "run" / "hlo-scopes.json") as f:
+        assert any("/deltanet/rule/" in name + "/"
+                   for name in json.load(f).values())
+    metrics = line["metrics"]
+    if trace:
+        assert metrics["tokens_per_s.bulk"]["value"] > 0
+        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
+        assert 0 < metrics["held_assignment_pct.bulk"]["value"] < 100
+        assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
+        assert 0 < metrics["flash_tile_visit_pct.bulk"]["value"] <= 100
+        # what stands against the chip's peak, or comes from the
+        # device's trace, does not come from a CPU
+        assert not any("roofline" in n or "util" in n or "deltarule" in n
+                       or "busy_pct" in n for n in metrics)
+    else:
+        assert metrics["videos_per_s"]["value"] > 0
+        assert metrics["setup_s"]["value"] > 0
+
+
+def test_the_parent_fails_on_the_cell_before_jax_starts(tmp_path):
+    """A checkout whose program lacks the family (the parent of PR 39,
+    given this PR's benchmark files): the family file's ``build`` says
+    so and exits, no result line."""
+    family = mm.load_family("qwen3_next")
+    os.makedirs(tmp_path / "rnb_tpu" / "models")
+    with pytest.raises(SystemExit, match="qwen3_next"):
+        family.build(str(tmp_path))
+    family.build(REPO)
+
+
+def test_the_control_script_takes_the_family_from_the_recipe(tmp_path):
+    """``scripts/prefill_control.py`` over a toy-width copy of the
+    configuration's file: as stated inside the limit, the float8 arm
+    outside it, the bfloat16 states reported."""
+    path = tmp_path / "toy.json"
+    path.write_text(json.dumps(toy_config()))
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
+         "--config", str(path), "--lengths", "37,120,70"],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    assert out["family"] == "qwen3_next" and out["ok"]
+    assert out["as_stated"]["ok"] and not out["layers_float8"]["ok"]
+    assert "share_of_spread" in out["state_bfloat16"]
+
+
+# -- the four new readers -------------------------------------------------------------
+
+NEW_READERS = ("deltanet_busy_pct.bulk", "deltanet_roofline_pct.bulk",
+               "deltarule_roofline_pct.bulk", "deltarule_ms_per_dispatch.bulk")
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
+        name, tmp_path):
+    """No trace, and a trace whose run wrote no scope table (the parent's
+    program has none of these scopes): None, not a raise."""
+    module = mm.load_layer_metric(name)
+    entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
+    assert entry and entry[0]["workloads"] == [CELL]
+    assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
+    assert module.LAYER == "gated delta rule"
+
+    class Result:
+        log_dir = str(tmp_path)
+        tokens_valid = 100
+        pad_emissions = 2
+
+    class Facts:
+        trace = None
+        result = Result
+        family = mm.load_family("qwen3_next")
+        config = {}
+        peak_flops_per_s = 1.97e14
+        device_kind = "TPU v5 lite"
+    assert module.read(Facts) is None
+
+    class Trace:
+        path = str(tmp_path / "none.xplane.pb")
+        host_span = (0.0, 1.0)
+    from benchmarks import subscopes
+    subscopes._CACHE[Trace.path] = {"%fusion.1 f32[8,8]": 0.5}
+    Facts.trace = Trace
+    try:
+        assert subscopes.seconds_under(Facts, "deltanet") is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]": "jit(apply)/jit(main)/experts/dot"}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, "deltanet") is None
+        assert subscopes.seconds_under(Facts, "deltanet/rule") is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]":
+             "jit(apply)/jit(main)/deltanet/rule/dot"}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, "deltanet") == 0.5
+        assert subscopes.seconds_under(Facts, "deltanet/rule") == 0.5
+    finally:
+        del subscopes._CACHE[Trace.path]
+        subscopes._op_names.cache_clear()
+
+
+# -- the real configuration -----------------------------------------------------
+
+
+def catalog_row():
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        for line in f:
+            row = json.loads(line)
+            if row["name"] == "Qwen3-Next-80B-A3B-Instruct":
+                return row
+    return None
+
+
+#: the catalog's ``config`` of Qwen3-Next-80B-A3B-Instruct
+PUBLISHED = {
+    "decoder_sparse_step": 1, "full_attention_interval": 4,
+    "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+    "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+    "linear_key_head_dim": 128, "linear_num_key_heads": 16,
+    "linear_num_value_heads": 32, "linear_value_head_dim": 128,
+    "max_position_embeddings": 262144, "mlp_only_layers": [],
+    "model_type": "qwen3_next", "moe_intermediate_size": 512,
+    "norm_topk_prob": True, "num_attention_heads": 16, "num_experts": 512,
+    "num_experts_per_tok": 10, "num_hidden_layers": 48,
+    "num_key_value_heads": 2, "partial_rotary_factor": 0.25,
+    "rms_norm_eps": 1e-06, "rope_scaling": None, "rope_theta": 10000000,
+    "shared_expert_intermediate_size": 512, "tie_word_embeddings": False,
+    "use_sliding_window": False, "vocab_size": 151936}
+
+
+def test_real_configuration_keeps_the_published_sizes():
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    entry = mm.config_entry(mm.load(), "qwen3-next-l4-ep2")
+    assert entry["reduced"] == config["reduced"] \
+        == ["num_hidden_layers", "num_experts"]
+    assert entry["source"] == config["source"]
+    for key, value in PUBLISHED.items():
+        if key in config["reduced"]:
+            assert config["published"][key] == value
+        else:
+            assert config[key] == value, key
+    assert config["num_hidden_layers"] == 4 and config["num_experts"] == 256
+    row = catalog_row()
+    if row is not None:
+        assert row["source_url"] == config["source"]
+        assert row["config"] == PUBLISHED
+    for key in ("chunk_size", "column_order", "norms", "rotary", "decay",
+                "mtp", "weights", "precision", "prompts", "batch"):
+        assert config["assumed"][key], key
+    assert "two chips share each layer" in config["deployment"]
+    assert config["size_record"]["projected_gib"] >= 4
+    assert config["capacity_why"] and config["capacity_videos_per_chip_s"]
+    family = mm.load_family(config["family"])
+    assert family.check_config(config) == []
+    cell = mm.cell(mm.load(), CELL)
+    assert cell["config"] == "qwen3-next-l4-ep2" and cell["chips"] == 1 \
+        and cell["traffic"] == "bulk"
+    # the weights the file states, from the tensor list: ISSUE 39's
+    # 33.72 M and 27.26 M of mixers, 805.3 M of held experts a layer,
+    # 622.3 M of embedding and head
+    from rnb_tpu.models.qwen3_next import checkpoint, network
+    cfg = network.Qwen3NextConfig.from_published(
+        family.published_keys(config))
+    specs = checkpoint.tensor_specs(cfg, 256)
+    sizes = {group: sum(int(np.prod(spec.shape)) for spec in tensors.values())
+             for group, tensors in specs.items()}
+    assert abs(sizes["top"] / 1e6 - 622.3) < 0.1
+    assert abs(sizes["l0"] / 1e6 - 843.2) < 0.1
+    assert abs(sizes["l3"] / 1e6 - 836.8) < 0.1
+    held = sum(sizes.values())
+    assert abs(held / 1e9 - config["model"]["params_billions_held"]) < 0.01
+    assert abs(2 * held / 2 ** 30 - config["model"]["weights_gib"]) < 0.01
+    # the prompts the issue states, as minicpm-sala-l4 draws them
+    with open(os.path.join(
+            REPO, "benchmarks/configs/minicpm-sala-l4.json")) as f:
+        assert json.load(f)["dataset"] == config["dataset"]
+    lengths = family.prompt_lengths(config)
+    assert min(lengths.values()) == 4096 and max(lengths.values()) <= 16384
+    # a held expert's tokens a full dispatch
+    assert 128 * 128 * config["num_experts_per_tok"] \
+        // config["published"]["num_experts"] == 320
+
+
+# -- the shared code, for the three older families -------------------------------------
+
+
+def stack_text(family: str) -> str:
+    """The StableHLO text of a toy stack's ``forward`` over one 8-row
+    dispatch, from the family's own test module's toy sizes."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    toys = importlib.import_module("test_" + family)
+    checkpoint, network = (
+        importlib.import_module("rnb_tpu.models.%s.%s" % (family, part))
+        for part in ("checkpoint", "network"))
+    cfg = getattr(network, [n for n in dir(network)
+                            if n.endswith("Config")
+                            and n != "SparseConfig"][0]) \
+        .from_published(toys.TOY)
+    held = getattr(toys, "HELD", None)
+    if held is None:
+        specs, slots = checkpoint.tensor_specs(cfg), None
+    else:
+        specs = checkpoint.tensor_specs(cfg, len(held))
+        slots = jax.ShapeDtypeStruct((cfg.router_experts,), jnp.int32)
+    params = {}
+    for group, tensors in specs.items():
+        made = {name: jax.ShapeDtypeStruct(spec.shape,
+                                           getattr(jnp, spec.dtype))
+                for name, spec in tensors.items()}
+        params.update(made if group == "top" else {group: made})
+    return jax.jit(lambda p, s, t, m: network.forward(
+        cfg, p, s, t, m[0], m[1], m[2], interpret=True)).lower(
+        params, slots, jax.ShapeDtypeStruct((8, cfg.chunk_size), jnp.int32),
+        jax.ShapeDtypeStruct((3, 8), jnp.int32)).as_text()
+
+
+@pytest.mark.parametrize("family", ["nemotron_h", "deepseek_v2",
+                                    "minicpm_sala"])
+def test_the_older_families_lower_to_the_text_the_parent_gave(family):
+    """PR 39 left ``ops/moe.py``, ``ops/segattn.py``, ``ops/rope.py`` and
+    ``ops/ssd.py`` as they were: each older family's toy stack lowers to
+    the StableHLO text PR 38's tree gave (its SHA-256, recorded from a
+    ``git archive`` of that commit under ``tests/recorded``). A PR that
+    moves one of them on purpose records the new text and shows those
+    cells on the chip."""
+    with open(os.path.join(REPO, "tests", "recorded",
+                           "toy_stack_stablehlo.json")) as f:
+        recorded = json.load(f)
+    import jax
+    if recorded["jax"] != jax.__version__:
+        pytest.skip("recorded under jax %s" % recorded["jax"])
+    assert hashlib.sha256(stack_text(family).encode()).hexdigest() \
+        == recorded[family]
