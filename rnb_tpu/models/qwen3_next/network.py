@@ -15,9 +15,13 @@ heads of ``Dk`` twice, ``Hv`` value heads of ``Dv`` twice), one gives
 (``ops/ssd.segment_conv1d``), SiLU; ``q``, ``k`` L2-normalised a head,
 ``q`` scaled by ``Dk ** -0.5``; ``beta = sigmoid(b)``, ``log alpha =
 -exp(A_log) softplus(a + dt_bias)``; the gated delta rule
-(``ops/deltanet.py``), value head h reading key head ``h // (Hv //
-Hk)``; an RMSNorm over each head's ``Dv`` columns times ``silu(z)``;
-the output product.
+(``ops/deltanet.py``: one Pallas kernel a layer, its grid (head group,
+row) with the rows innermost and in order; a grid step holds one row's
+scores, decay triangle, solve and updates and the head group's states
+in VMEM and carries the states in their sequential form, ``S <- exp(g_Q)
+S + (exp(g_Q - g) k)^T v_new``, zeroed where a request opens), value
+head h reading key head ``h // (Hv // Hk)``; an RMSNorm over each
+head's ``Dv`` columns times ``silu(z)``; the output product.
 
 *Gated attention*: one product gives every head's ``[query | gate]``,
 one each keys and values; an RMSNorm over each head's columns on
@@ -39,8 +43,9 @@ decays, steps and states, the rotary angles and every product's
 accumulation are float32.
 
 The named scopes are ``embed``, ``deltanet`` (a DeltaNet layer's mixer
-whole, the rule alone under ``deltanet/rule``), ``attn``, ``experts``
-and ``head``.
+whole, the rule alone under ``deltanet/rule``: the kernel's custom call
+and the running sums in front of it), ``attn``, ``experts`` and
+``head``.
 """
 
 from __future__ import annotations
@@ -178,7 +183,8 @@ def _proj(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
-def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32):
+def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
+                   interpret=False):
     """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
     rows, q, _ = h.shape
     act = h.dtype
@@ -201,7 +207,7 @@ def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32):
     with jax.named_scope("rule"):
         out = deltanet.gated_delta_rule(
             qs.astype(act), ks.astype(act), vs.astype(act), log_alpha, beta,
-            row_first, state_dtype=state_dtype)
+            row_first, state_dtype=state_dtype, interpret=interpret)
     out = rms_norm(out, p["o_norm"], cfg.eps, jnp.float32, centred=False) \
         .reshape(rows, q, cfg.value_dim)
     return _proj((out * jax.nn.silu(z)).astype(act), p["o"])
@@ -297,7 +303,8 @@ def forward(cfg: Qwen3NextConfig, params, slots, tokens, row_tokens,
         else:
             with jax.named_scope("deltanet"):
                 h = rms_norm(x, p["mixer_norm"], cfg.eps, act)
-                out = deltanet_mixer(cfg, p, h, row_first, state_dtype)
+                out = deltanet_mixer(cfg, p, h, row_first, state_dtype,
+                                     interpret)
                 x = (x.astype(jnp.float32) + out).astype(act)
         with jax.named_scope("experts"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)

@@ -19,8 +19,8 @@ rnb_tpu.models.qwen3_next) add six mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan and its convolution, in plain jnp/lax; lightning linear attention
 is its case of unit steps), ``deltanet`` (the gated delta rule, whose
-transition is a matrix: a triangular solve inside a row and a scan over
-the rows, in plain jnp/lax),
+transition is a matrix: one Pallas kernel that walks the rows with a
+head group's states in VMEM, a triangular solve inside each row),
 ``blocksparse`` (every query's own top-k blocks of keys from
 mean-compressed keys, and a Pallas flash kernel under that block
 mask), ``segattn``

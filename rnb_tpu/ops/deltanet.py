@@ -1,5 +1,7 @@
 """The gated delta rule over a packed pool of rows, in its blocked (WY /
-UT) form, with the state reset where a request's first row starts.
+UT) form, as one Pallas TPU kernel that keeps a row's arrays and the
+carried state in VMEM; the state is reset where a request's first row
+starts.
 
 A *row* is one chunk of ``Q`` consecutive tokens; a request occupies
 consecutive rows of the pool and ``row_first[r]`` says that row ``r``
@@ -27,103 +29,179 @@ updates are one unit-triangular system, ``T = (I + L)^-1``::
     S_out = exp(g_Q) S_in + (exp(g_Q - g) k)^T v_new
 
 ``T`` is computed by forward substitution (:func:`unit_lower_inverse`):
-row by row inside diagonal blocks of 16, and block by block above them
+row by row inside diagonal blocks of 32, and block by block above them
 (the inverse of ``[[A, 0], [B, D]]`` is ``[[A^-1, 0], [-D^-1 B A^-1,
-D^-1]]``: a level of the doubling is two batched products). It is
-exact, and not the Neumann series, whose terms grow where neighbouring
-keys are alike.
+D^-1]]``: a level of the doubling is two whole ``Q x Q`` products). It
+is exact, and not the Neumann series, whose terms grow where
+neighbouring keys are alike.
 
-Across rows a row maps its incoming state affinely, ``S_out = M S_in +
-B`` with ``M = exp(g_Q) I - (exp(g_Q - g) k)^T W`` and ``B = (exp(g_Q -
-g) k)^T U``, both computed for every row at once; what is sequential is
-one ``lax.scan`` over the rows that applies them (one batched ``Dk x
-Dk`` by ``Dk x Dv`` product a step, the state zeroed where
-``row_first``) and hands every row its incoming state; ``v_new`` and
-``o`` are again computed for all rows at once.
+*The kernel.* The grid is (head group, row): a grid step takes one row
+of ``_KEY_HEADS`` key heads with the value heads that read them; the
+row axis is innermost and sequential, so a head group walks the pool's
+rows in order with its states in a VMEM scratch that lives from one
+grid step to the next. ``row_first`` is a scalar-prefetch operand: a
+step whose row opens a request zeroes the scratch before it reads it.
+A step reads its heads' ``q``, ``k``, ``v`` (a key head once for its
+value heads), the running sums and the steps, forms ``k . k``, ``q .
+k``, the decay triangle, ``L``, ``T``, ``U``, ``W`` in VMEM, applies the
+equations above against the state at hand, the carry in its sequential
+form (the last line: one product), and writes ``o``. Nothing of ``Q x
+Q`` or ``Q x Dk`` a head reaches HBM. The heads of a step are
+independent chains of products, unrolled side by side so that the
+matrix unit has another head's product to run while one waits on its
+own result. Only the running sum ``g`` (a few bytes a token and head)
+is formed outside, in both the orientations the kernel reads it.
 
 Decays, steps, ``T`` and states are float32; every product that reads
-or builds ``T`` or a state runs at ``highest`` precision, so that none
-is rounded to bfloat16 on its way through the matrix unit: seventeen
-batched 128 x 128 products a layer, 0.9 ms each on the v5e (``high``
-read the same time: my chip runs, PR 39). The two
-score products (``k . k``, ``q . k``) take their inputs in the
-activations' dtype and accumulate in float32.
+or builds ``T`` or a state takes float32 operands at ``highest``
+precision, so that none is rounded to bfloat16 on its way through the
+matrix unit. The two score products (``k . k``, ``q . k``) take their
+inputs in the activations' dtype and accumulate in float32.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HIGHEST = lax.Precision.HIGHEST
 
+#: the kernel's name in the device's trace and in the scope table
+KERNEL_NAME = "gated_delta_rule"
 
 #: the block the triangular solve substitutes row by row; larger blocks
-#: are merged from their halves
-_SOLVE_BASE = 16
+#: are merged from their halves. On the v5e 31 columns on the vector
+#: unit cost less than the level of merges (two whole products) they
+#: replace, 63 cost more (PERF.md section 6, PR 41)
+_SOLVE_BASE = 32
+
+#: key heads a grid step (with the value heads that read them): the
+#: body is unrolled a head, and past two the kernel gains 1-3% for a
+#: compile time that doubles with the heads (the same readings)
+_KEY_HEADS = 2
 
 
-def _substitute(lower, size: int):
-    """``(I + lower)^-1`` by forward substitution, unrolled: row i of
-    the inverse is ``e_i - sum_{j<i} lower[i, j] row_j``. ``lower`` (c *
-    c, B): entry (i, j) is row ``i * c + j``, the batch on the minor
-    axis, so that every term is a multiply-add of (c, B) arrays over
-    whole lanes; c small. -> (c, c, B).
+def _dot(a, b):
+    return jnp.dot(a, b, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
 
-    (With the batch in front, ``lower[:, i, j]`` lowered to 120 gathers
-    a layer, 28 ms; as rank-1 updates of a (c, c, B) array the compiler
-    laid the two c's innermost and every step moved the array padded
-    eightfold, 17 ms: my chip runs, PR 39.)"""
-    eye = jnp.eye(size, dtype=lower.dtype)
-    rows = []
-    for i in range(size):
-        row = jnp.broadcast_to(eye[i][:, None], (size, lower.shape[1]))
-        for j in range(i):
-            at = i * size + j
-            row = row - lower[at:at + 1, :] * rows[j]
-        rows.append(row)
-    return jnp.stack(rows)
+
+def _scores(a, b):
+    """``a b^T``, operands as they come, float32 accumulation."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _spread_columns(packed):
+    """``packed`` (b, C), C // b groups of ``b`` lanes -> b arrays (b,
+    C): in array ``j`` every lane of a group holds the group's lane
+    ``j``. A tree over the bits of ``j``, highest first: a level halves
+    the lanes a value may still come from, by one rotation to each side
+    and a select (2 b - 2 rotations in all, on the lanes' own unit)."""
+    base, size = packed.shape
+    lane = lax.broadcasted_iota(jnp.int32, packed.shape, 1)
+    level = [packed]
+    bit = base // 2
+    while bit:
+        high = (lane & bit) != 0
+        level = [half for x in level for half in (
+            # bit clear: the lanes that have it set read ``bit`` below
+            jnp.where(high, pltpu.roll(x, bit, 1), x),
+            jnp.where(high, x, pltpu.roll(x, size - bit, 1)))]
+        bit //= 2
+    return level
 
 
 def unit_lower_inverse(lower):
-    """``(I + lower)^-1`` for ``lower`` (B, C, C) float32, strictly
-    lower triangular (what lies on or over the diagonal is not read);
-    C a power of two. -> (B, C, C), unit lower triangular.
+    """``(I + lower)^-1`` for ``lower`` (C, C) float32, strictly lower
+    triangular (zero on and over the diagonal); C a power of two. ->
+    (C, C), unit lower triangular. Written for the kernel's body: whole
+    arrays in VMEM, the lanes the minor axis.
 
-    The diagonal blocks of ``_SOLVE_BASE`` row by row; then, with the
-    inverses of the diagonal blocks of b in ``x`` (block-diagonal), the
-    blocks of 2b: ``x - x (lower * under_b) x``, where ``under_b`` keeps
-    each pair's lower-left block. Whole ``C x C`` products and not
-    products of the blocks: batched products of 32 and 64 columns ran
-    at a fortieth of the matrix unit's rate on the v5e (my chip runs,
-    PR 39: 45 ms a level a layer, where a whole product takes 0.9)."""
-    batch, size = lower.shape[:2]
+    The diagonal blocks of ``_SOLVE_BASE`` row by row, all of them at
+    once in a packed form (b, C): row ``a`` holds row ``a`` of every
+    block, a block's columns where they lie. Column ``j`` of the
+    substitution takes ``lower[a, j] * row_j`` off every row ``a > j``:
+    one multiply-add of the packed array, the column spread over its
+    block's lanes. Then, with the inverses of the diagonal blocks of b
+    in ``x`` (block-diagonal), the blocks of 2b: ``x - x (lower *
+    under_b) x``, where ``under_b`` keeps each pair's lower-left block:
+    whole ``C x C`` products and not products of the blocks."""
+    size = lower.shape[0]
     base = min(size, _SOLVE_BASE)
     blocks = size // base
-    diagonal = jnp.stack([
-        lower[:, i * base:(i + 1) * base, i * base:(i + 1) * base]
-        for i in range(blocks)])                     # (blocks, B, b, b)
-    solved = _substitute(diagonal.transpose(2, 3, 0, 1).reshape(
-        base * base, blocks * batch), base)
-    solved = solved.reshape(base, base, blocks, batch).transpose(2, 3, 0, 1)
-    # block-diagonal: block i's rows, zeros on both sides of it
-    x = jnp.concatenate([
-        jnp.pad(solved[i], ((0, 0), (0, 0),
-                            (i * base, size - (i + 1) * base)))
-        for i in range(blocks)], axis=1)
-    at = jnp.arange(size)
+    lane = lax.broadcasted_iota(jnp.int32, (base, size), 1)
+    packed = jnp.zeros((base, size), lower.dtype)
+    for i in range(blocks):
+        packed = packed + jnp.where(
+            lane // base == i, lower[i * base:(i + 1) * base], 0.0)
+    row = lax.broadcasted_iota(jnp.int32, (base, size), 0)
+    solved = jnp.where(lane % base == row, 1.0, 0.0).astype(lower.dtype)
+    for j, column in enumerate(_spread_columns(packed)[:-1]):
+        solved = solved - column * solved[j:j + 1]
+    at = lax.broadcasted_iota(jnp.int32, (size, size), 0) // base
+    to = lax.broadcasted_iota(jnp.int32, (size, size), 1) // base
+    x = jnp.where(at == to, jnp.concatenate([solved] * blocks, axis=0), 0.0)
     while base < size:
-        under = (at[:, None] // base == at[None, :] // base + 1) \
-            & (at[:, None] // base % 2 == 1)
-        x = x - jnp.matmul(
-            jnp.matmul(x, jnp.where(under, lower, 0.0), precision=_HIGHEST),
-            x, precision=_HIGHEST)
+        under = (at == to + 1) & (at % 2 == 1)
+        x = x - _dot(_dot(x, jnp.where(under, lower, 0.0)), x)
+        at, to = at // 2, to // 2
         base *= 2
     return x
 
 
+def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, o_ref,
+            state_ref, *, per: int, dk: int, dv: int, state_dtype):
+    """One row of one head group. ``q_ref``, ``k_ref`` (Q, heads *
+    Dk), ``v_ref`` (Q, heads * per * Dv); ``g_ref``, ``b_ref`` (Q,
+    value heads) the running sums and the steps, a token a sublane;
+    ``g_row_ref`` (value heads, Q) the sums again, a token a lane;
+    ``state_ref`` (value heads, Dk, Dv) float32, carried."""
+    f32 = jnp.float32
+    qlen = q_ref.shape[0]
+
+    @pl.when(first_ref[pl.program_id(1)] != 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
+    other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
+    for head in range(k_ref.shape[1] // dk):
+        k = k_ref[:, head * dk:(head + 1) * dk]
+        q = q_ref[:, head * dk:(head + 1) * dk]
+        kk, qk = _scores(k, k), _scores(q, k)
+        kf, qf = k.astype(f32), q.astype(f32)
+        for j in range(head * per, (head + 1) * per):
+            g = g_ref[:, j:j + 1]                            # (Q, 1)
+            b = b_ref[:, j:j + 1]
+            g_row = g_row_ref[j:j + 1, :]                    # (1, Q)
+            end = g[qlen - 1:qlen]                           # (1, 1)
+            decay = jnp.exp(jnp.where(token >= other, g - g_row, -jnp.inf))
+            t = unit_lower_inverse(
+                jnp.where(token > other, b * kk * decay, 0.0))
+            u = _dot(t, b * v_ref[:, j * dv:(j + 1) * dv].astype(f32))
+            w = _dot(t, (b * jnp.exp(g)) * kf)
+            state = state_ref[j]
+            v_new = u - _dot(w, state)
+            o_ref[:, j * dv:(j + 1) * dv] = _dot(qf * jnp.exp(g), state) \
+                + _dot(qk * decay, v_new)
+            state = jnp.exp(jnp.broadcast_to(end, (1, dv))) * state \
+                + lax.dot_general(
+                    kf * jnp.exp(end - g), v_new, (((0,), (0,)), ((), ())),
+                    precision=_HIGHEST, preferred_element_type=f32)
+            # inside the kernel the pair of conversions is Mosaic's to
+            # lower, and it keeps both (XLA on the v5e drops such a pair
+            # and keeps the excess precision: my chip run, PR 39)
+            state_ref[j] = state.astype(state_dtype).astype(f32)
+
+
 def gated_delta_rule(q, k, v, log_alpha, beta, row_first,
-                     state_dtype=jnp.float32):
+                     state_dtype=jnp.float32, interpret: bool = False):
     """The rule of one layer over a packed pool.
 
     ``q``, ``k`` (rows, Q, Hk, Dk), as the rule reads them (normalised,
@@ -134,63 +212,41 @@ def gated_delta_rule(q, k, v, log_alpha, beta, row_first,
 
     ``state_dtype`` is the precision the states are carried in between
     rows: float32 in the program; the lower-precision control passes
-    bfloat16."""
+    bfloat16. ``interpret`` runs the kernel in interpret mode (a device
+    that is no TPU)."""
     rows, qlen, hk, dk = k.shape
     hv, dv = v.shape[2:]
     per = hv // hk
+    heads = min(_KEY_HEADS, hk)
+    groups, values = hk // heads, heads * per
     f32 = jnp.float32
-    # heads first, a head's tokens and columns the two minor axes
-    qh = q.transpose(0, 2, 1, 3)                     # (rows, Hk, Q, Dk)
-    kh = k.transpose(0, 2, 1, 3)
-    vh = v.reshape(rows, qlen, hk, per, dv).transpose(0, 2, 3, 1, 4)
+    # a head group's value heads side by side: (rows, groups, Q, values)
+    g = jnp.cumsum(log_alpha.astype(f32), axis=1) \
+        .reshape(rows, qlen, groups, values).transpose(0, 2, 1, 3)
+    b = beta.astype(f32).reshape(rows, qlen, groups, values) \
+        .transpose(0, 2, 1, 3)
 
-    def heads_first(x):                              # (rows, Hk, per, Q)
-        return x.astype(f32).reshape(rows, qlen, hk, per) \
-            .transpose(0, 2, 3, 1)
-    g = jnp.cumsum(heads_first(log_alpha), axis=-1)
-    b = heads_first(beta)
-    on_or_under = jnp.tril(jnp.ones((qlen, qlen), bool))
-    decay = jnp.exp(jnp.where(on_or_under,
-                              g[..., :, None] - g[..., None, :], -jnp.inf))
-    kk = jnp.einsum("rhid,rhjd->rhij", kh, kh, preferred_element_type=f32)
-    qk = jnp.einsum("rhid,rhjd->rhij", qh, kh, preferred_element_type=f32)
-    under = jnp.tril(jnp.ones((qlen, qlen), bool), -1)
-    lower = jnp.where(under, b[..., :, None] * kk[:, :, None] * decay, 0.0)
-    t = unit_lower_inverse(lower.reshape(-1, qlen, qlen)) \
-        .reshape(lower.shape)
+    def columns(width):
+        return pl.BlockSpec((None, qlen, width), lambda i, r, _: (r, 0, i))
 
-    kf = kh.astype(f32)[:, :, None]                  # (rows, Hk, 1, Q, Dk)
-    u = jnp.matmul(t, b[..., None] * vh.astype(f32), precision=_HIGHEST)
-    w = jnp.matmul(t, (b * jnp.exp(g))[..., None] * kf,
-                   precision=_HIGHEST)               # (rows, Hk, per, Q, Dk)
-    to_end = kf * jnp.exp(g[..., -1:] - g)[..., None]
-    end = jnp.exp(g[..., -1])                        # (rows, Hk, per)
-    carry_m = end[..., None, None] * jnp.eye(dk, dtype=f32) - jnp.einsum(
-        "rhpik,rhpil->rhpkl", to_end, w, precision=_HIGHEST)
-    carry_b = jnp.einsum("rhpik,rhpiv->rhpkv", to_end, u,
-                         precision=_HIGHEST)
-
-    def through(x):
-        # reduce_precision and not a pair of conversions: the v5e's
-        # compiler drops the pair and keeps the excess precision (the
-        # arm then read the float32 logits bit for bit: my chip run,
-        # PR 39)
-        if state_dtype == f32:
-            return x
-        info = jnp.finfo(state_dtype)
-        return lax.reduce_precision(x, info.nexp, info.nmant)
-
-    def step(state, row):
-        first, m, add = row
-        state = jnp.where(first, 0.0, state)
-        return through(jnp.matmul(m, state, precision=_HIGHEST) + add), \
-            state
-    _, incoming = lax.scan(step, jnp.zeros((hk, per, dk, dv), f32),
-                           (row_first, carry_m, carry_b))
-
-    v_new = u - jnp.matmul(w, incoming, precision=_HIGHEST)
-    out = jnp.matmul(qh.astype(f32)[:, :, None] * jnp.exp(g)[..., None],
-                     incoming, precision=_HIGHEST) \
-        + jnp.matmul(qk[:, :, None] * decay, v_new, precision=_HIGHEST)
-    # (rows, Hk, per, Q, Dv) -> (rows, Q, Hv, Dv)
-    return out.transpose(0, 3, 1, 2, 4).reshape(rows, qlen, hv, dv)
+    def of_group(*block):
+        return pl.BlockSpec((None, None) + block,
+                            lambda i, r, _: (r, i, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, per=per, dk=dk, dv=dv,
+                          state_dtype=state_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(groups, rows),
+            in_specs=[columns(heads * dk), columns(heads * dk),
+                      columns(values * dv), of_group(qlen, values),
+                      of_group(qlen, values), of_group(values, qlen)],
+            out_specs=columns(values * dv),
+            scratch_shapes=[pltpu.VMEM((values, dk, dv), f32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, qlen, hv * dv), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name=KERNEL_NAME,
+    )(row_first.astype(jnp.int32), q.reshape(rows, qlen, hk * dk),
+      k.reshape(rows, qlen, hk * dk), v.reshape(rows, qlen, hv * dv),
+      g, b, g.transpose(0, 1, 3, 2))
+    return out.reshape(rows, qlen, hv, dv)

@@ -1,14 +1,16 @@
 """The Qwen3-Next family against its plain reference, at a toy size on
 the CPU with weights from a seed: the packed prefill through dispatches
 with several requests, a pad row and a request that ends inside a row,
-the lower-precision controls, the gated delta rule through
-``ops/deltanet`` against the token-by-token recurrence with state and
-convolution history reset at every request's first row, the triangular
-solve alone, the shares of the experts adding up to the uncut layer,
-partial rotary, the stages and their counters, the operation counts,
+the lower-precision controls, the gated delta rule's kernel
+(``ops/deltanet``, interpreted) against the token-by-token recurrence
+with state and convolution history reset at every request's first row,
+its states through bfloat16, the triangular solve alone, the shares of
+the experts adding up to the uncut layer, partial rotary, the stages and their counters, the operation counts,
 the cell through the one benchmark command, the four new readers on a
 run without their scope, the real configuration against the catalog's
-row, and the shared code's StableHLO for the three older families.
+row, what the rule's kernel keeps out of the lowered program, the kernel
+compiled at the published widths for a described v5e, and the shared
+code's StableHLO for the three older families.
 Nothing here needs the native decode library or a chip."""
 
 import hashlib
@@ -210,24 +212,34 @@ def rule_inputs(rows, qlen, hk=2, hv=4, dk=8, dv=8, seed=1):
             jax.nn.sigmoid(n(rows, qlen, hv)))
 
 
-@pytest.mark.parametrize("qlen,firsts", [
-    (16, (0,)), (16, (0, 3, 4)), (16, (0, 1, 2, 3, 4, 5)),
-    (64, (0, 2)), (128, (0, 1))])
-def test_the_blocked_rule_matches_the_recurrence(qlen, firsts):
-    """``gated_delta_rule`` over a pool of six rows (three at 128)
-    against the plain reference's recurrence, token by token, a request
-    at a time: the
-    state starts at zero at every request's first row. Rows of 64 and
-    128 tokens go through the solve's merged levels."""
+#: (tokens a row, the rows that open a request, key heads): the first
+#: five are the blocked form's own cases; then what only a kernel that
+#: walks the rows with its states at hand has to face - every row of two
+#: head groups' walks opens a request (the state zeroed each step), one
+#: request spans the whole pool, a pad row (a request of its own) lies
+#: between two requests
+RULE_CASES = [
+    (16, (0,), 2), (16, (0, 3, 4), 2), (16, (0, 1, 2, 3, 4, 5), 2),
+    (64, (0, 2), 2), (128, (0, 1), 2),
+    (64, (0, 1, 2, 3, 4, 5), 4), (128, (0,), 2), (32, (0, 3, 4), 4)]
+
+
+@pytest.mark.parametrize("qlen,firsts,hk", RULE_CASES)
+def test_the_blocked_rule_matches_the_recurrence(qlen, firsts, hk):
+    """``gated_delta_rule`` (the kernel, interpreted) over a pool of six
+    rows (three at 128) against the plain reference's recurrence, token
+    by token, a request at a time: the state starts at zero at every
+    request's first row. Rows of 32, 64 and 128 tokens go through the
+    solve's merged levels; four key heads are two head groups."""
     import jax.numpy as jnp
 
     from rnb_tpu.ops import deltanet
     rows = 3 if qlen == 128 else 6
-    inputs = rule_inputs(rows, qlen)
+    inputs = rule_inputs(rows, qlen, hk=hk, hv=2 * hk)
     row_first = np.zeros(rows, bool)
     row_first[list(firsts)] = True
     out = np.asarray(deltanet.gated_delta_rule(
-        *inputs, jnp.asarray(row_first)))
+        *inputs, jnp.asarray(row_first), interpret=True))
     bounds = list(firsts) + [rows]
     for lo, hi in zip(bounds, bounds[1:]):
         q, k, v, log_alpha, beta = (
@@ -248,13 +260,51 @@ def test_the_blocked_rule_matches_the_recurrence(qlen, firsts):
         assert np.abs(out[lo, 0] - alone).max() < 1e-5
 
 
+def test_bfloat16_states_differ_by_one_rounding_a_row():
+    """The control's ``state_dtype``: a request's first row reads no
+    carried state, so it is the float32 rule's bit for bit; every later
+    row reads a state rounded once more, and differs (a rounding that
+    was dropped would read zero) by no more than its roundings, each
+    2^-9 of the state, allow."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    inputs = rule_inputs(6, 16)
+
+    def rule(firsts, **how):
+        row_first = np.zeros(6, bool)
+        row_first[list(firsts)] = True
+        return np.asarray(deltanet.gated_delta_rule(
+            *inputs, jnp.asarray(row_first), interpret=True, **how))
+    exact, rounded = rule((0, 4)), rule((0, 4), state_dtype=jnp.bfloat16)
+    scale = np.abs(exact).max()
+    for row in (0, 4):
+        assert np.array_equal(rounded[row], exact[row])
+    for row, roundings in ((1, 1), (2, 2), (3, 3), (5, 1)):
+        off = np.abs(rounded[row] - exact[row]).max()
+        assert 0 < off < roundings * 2.0 ** -7 * scale, (row, off)
+    # requests of one row each: nothing rounded is ever read
+    alone = range(6)
+    assert np.array_equal(rule(alone, state_dtype=jnp.bfloat16), rule(alone))
+
+
+def solve(lower):
+    """``deltanet.unit_lower_inverse`` over a batch, as the kernel's
+    body calls it: one matrix at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    return np.asarray(jax.jit(lambda batch: jnp.stack(
+        [deltanet.unit_lower_inverse(x) for x in batch]))(lower))
+
+
 @pytest.mark.parametrize("size", [8, 16, 32, 128])
 def test_the_triangular_solve_is_the_inverse(size):
-    from rnb_tpu.ops import deltanet
     rng = np.random.default_rng(size)
     lower = np.tril(0.2 * rng.normal(size=(5, size, size)), -1) \
         .astype(np.float32)
-    got = np.asarray(deltanet.unit_lower_inverse(lower))
+    got = solve(lower)
     want = np.linalg.inv(np.eye(size) + lower.astype(np.float64))
     assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
     assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
@@ -263,12 +313,29 @@ def test_the_triangular_solve_is_the_inverse(size):
 def test_keys_alike_do_not_break_the_solve():
     """Neighbouring keys that are all but equal with steps near one: the
     Neumann series' terms would grow to 1e30 over a row of 128; the
-    substitution stays exact."""
+    substitution stays exact. Once on the matrix alone, and once through
+    the kernel on a row whose keys and steps produce it."""
+    import jax.numpy as jnp
+
     from rnb_tpu.ops import deltanet
     lower = np.tril(np.full((1, 128, 128), 0.99, np.float32), -1)
-    got = np.asarray(deltanet.unit_lower_inverse(lower))
+    got = solve(lower)
     want = np.linalg.inv(np.eye(128) + lower[0].astype(np.float64))
     assert np.abs(got[0] - want).max() < 1e-5
+    # one key for every token, steps of 0.99, no decay: ``lower`` above.
+    # The first row reads no state, so o = tril(q . k) T (beta v)
+    rng = np.random.default_rng(0)
+    key = rng.normal(size=8)
+    k = np.broadcast_to(key / np.linalg.norm(key), (1, 128, 1, 8))
+    q = rng.normal(size=(1, 128, 1, 8))
+    v = rng.normal(size=(1, 128, 1, 8))
+    out = np.asarray(deltanet.gated_delta_rule(
+        *(jnp.asarray(x, jnp.float32) for x in (
+            q, k, v, np.zeros((1, 128, 1)), np.full((1, 128, 1), 0.99))),
+        jnp.asarray([True]), interpret=True))
+    scores = np.tril(q[0, :, 0] @ k[0, :, 0].T)
+    want = scores @ want @ (0.99 * v[0, :, 0])
+    assert np.abs(out[0, :, 0] - want).max() < 1e-4 * np.abs(want).max()
 
 
 def test_the_mixer_restarts_state_and_convolution_history(toy):
@@ -282,10 +349,11 @@ def test_the_mixer_restarts_state_and_convolution_history(toy):
     rng = np.random.default_rng(5)
     h = jnp.asarray(rng.normal(size=(6, Q, 64)), jnp.bfloat16)
     first = jnp.asarray([True, False, False, True, True, False])
-    packed = np.asarray(network.deltanet_mixer(cfg, p, h, first))
+    packed = np.asarray(network.deltanet_mixer(cfg, p, h, first,
+                                               interpret=True))
     for lo, hi in ((0, 3), (3, 4), (4, 6)):
         alone = np.asarray(network.deltanet_mixer(
-            cfg, p, h[lo:hi], jnp.arange(hi - lo) == 0))
+            cfg, p, h[lo:hi], jnp.arange(hi - lo) == 0, interpret=True))
         assert np.abs(packed[lo:hi] - alone).max() \
             < 1e-5 * np.abs(alone).max()
     # and against the plain reference's mixer, token by token
@@ -730,25 +798,86 @@ def test_real_configuration_keeps_the_published_sizes():
         // config["published"]["num_experts"] == 320
 
 
+# -- what the kernel keeps off the chip's memory ---------------------------------
+
+
+def test_the_rule_leaves_no_row_by_row_arrays_outside_the_kernel():
+    """The lowered program of a toy stack (rows of 32 tokens, so that no
+    other array has these shapes) holds no float32 array of ``(rows, Hv,
+    Q, Q)`` - the decay, the scores' triangle, the solve - nor of
+    ``(rows, Hk, per, Q, Dk)`` - ``w``, ``to_end`` - which the plain
+    ``jnp`` rule wrote to the chip's memory one after another: inside
+    the kernel they are a grid step's, of one row."""
+    from rnb_tpu.models.qwen3_next import checkpoint, network
+    cfg = network.Qwen3NextConfig.from_published(dict(TOY, chunk_size=32))
+    rows, q = 8, cfg.chunk_size
+    text = lowered_text(checkpoint, network, cfg, HELD, rows)
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    # the rule's result, which does leave the kernel, is in the text
+    assert "tensor<%dx%dx%dxf32>" % (
+        rows, q, hv * cfg.linear_value_head_dim) in text
+    for shape in ((rows, hv, q, q), (rows * hv, q, q),
+                  (rows, hk, hv // hk, q, q),
+                  (rows, hk, hv // hk, q, cfg.linear_key_head_dim)):
+        assert "tensor<%sxf32>" % "x".join(map(str, shape)) not in text, \
+            shape
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_kernel_compiles_at_the_published_widths(one_chip):
+    """The rule of one layer over the largest row bucket, compiled for a
+    described v5e (nothing runs): one custom call, and what the program
+    holds beside its operands and result is the running sums and steps
+    in the kernel's two orientations, not a ``Q x Q`` array a head."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    rows = max(config["pipeline_config"]["pipeline"][-1]["row_buckets"])
+    q, hk, hv = config["chunk_size"], config["linear_num_key_heads"], \
+        config["linear_num_value_heads"]
+    dk, dv = config["linear_key_head_dim"], config["linear_value_head_dim"]
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(deltanet.gated_delta_rule).lower(
+        of((rows, q, hk, dk), jnp.bfloat16),
+        of((rows, q, hk, dk), jnp.bfloat16),
+        of((rows, q, hv, dv), jnp.bfloat16), of((rows, q, hv), jnp.float32),
+        of((rows, q, hv), jnp.float32), of((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert deltanet.KERNEL_NAME in text
+    assert "f32[%d,%d,%d,%d]" % (rows, hv, q, q) not in text
+
+
 # -- the shared code, for the three older families -------------------------------------
 
 
-def stack_text(family: str) -> str:
-    """The StableHLO text of a toy stack's ``forward`` over one 8-row
-    dispatch, from the family's own test module's toy sizes."""
-    import importlib
-
+def lowered_text(checkpoint, network, cfg, held, rows=8) -> str:
+    """The StableHLO text of a family's ``forward`` over one dispatch of
+    ``rows`` rows, its Pallas kernels interpreted; ``held``: the experts
+    held here, None for a family without experts."""
     import jax
     import jax.numpy as jnp
-    toys = importlib.import_module("test_" + family)
-    checkpoint, network = (
-        importlib.import_module("rnb_tpu.models.%s.%s" % (family, part))
-        for part in ("checkpoint", "network"))
-    cfg = getattr(network, [n for n in dir(network)
-                            if n.endswith("Config")
-                            and n != "SparseConfig"][0]) \
-        .from_published(toys.TOY)
-    held = getattr(toys, "HELD", None)
     if held is None:
         specs, slots = checkpoint.tensor_specs(cfg), None
     else:
@@ -762,8 +891,25 @@ def stack_text(family: str) -> str:
         params.update(made if group == "top" else {group: made})
     return jax.jit(lambda p, s, t, m: network.forward(
         cfg, p, s, t, m[0], m[1], m[2], interpret=True)).lower(
-        params, slots, jax.ShapeDtypeStruct((8, cfg.chunk_size), jnp.int32),
-        jax.ShapeDtypeStruct((3, 8), jnp.int32)).as_text()
+        params, slots,
+        jax.ShapeDtypeStruct((rows, cfg.chunk_size), jnp.int32),
+        jax.ShapeDtypeStruct((3, rows), jnp.int32)).as_text()
+
+
+def stack_text(family: str) -> str:
+    """The StableHLO text of a toy stack's ``forward`` over one 8-row
+    dispatch, from the family's own test module's toy sizes."""
+    import importlib
+    toys = importlib.import_module("test_" + family)
+    checkpoint, network = (
+        importlib.import_module("rnb_tpu.models.%s.%s" % (family, part))
+        for part in ("checkpoint", "network"))
+    cfg = getattr(network, [n for n in dir(network)
+                            if n.endswith("Config")
+                            and n != "SparseConfig"][0]) \
+        .from_published(toys.TOY)
+    return lowered_text(checkpoint, network, cfg,
+                        getattr(toys, "HELD", None))
 
 
 @pytest.mark.parametrize("family", ["nemotron_h", "deepseek_v2",
