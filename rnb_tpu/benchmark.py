@@ -208,6 +208,11 @@ class BenchmarkResult:
     #: run / the tiles on or under the diagonal; 0 without such a stage
     attention_tiles_visited: int = 0
     attention_tiles_causal: int = 0
+    #: the same pair of the stack's layers with a window, at their own
+    #: tile sizes (the pair above is then the full layers' alone); 0
+    #: without such layers
+    window_tiles_visited: int = 0
+    window_tiles_causal: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all
@@ -904,6 +909,7 @@ def run_benchmark(config_path: str,
                 ragged_stats[key] += int(snap.get(key, 0))
 
     token_stats = expert_stats = sparse_stats = attention_stats = None
+    window_stats = None
     if stage_counter_sink:
         from rnb_tpu.telemetry import (ATTENTION_COUNTS, SPARSE_COUNTS,
                                        aggregate_counts,
@@ -914,6 +920,8 @@ def run_benchmark(config_path: str,
                                         SPARSE_COUNTS)
         attention_stats = aggregate_counts(stage_counter_sink,
                                            "attn_tiles", ATTENTION_COUNTS)
+        window_stats = aggregate_counts(stage_counter_sink,
+                                        "window_tiles", ATTENTION_COUNTS)
 
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
@@ -1121,8 +1129,10 @@ def run_benchmark(config_path: str,
                 "%s=%d" % (key, sparse_stats[key]) for key in SPARSE_COUNTS))
         if attention_stats is not None:
             f.write("Attention: %s\n" % " ".join(
-                "%s=%d" % (key, attention_stats[key])
-                for key in ATTENTION_COUNTS))
+                ["%s=%d" % (key, attention_stats[key])
+                 for key in ATTENTION_COUNTS]
+                + ["window_%s=%d" % (key, window_stats[key])
+                   for key in ATTENTION_COUNTS if window_stats]))
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -1484,6 +1494,8 @@ def run_benchmark(config_path: str,
            for key, count in (sparse_stats or {}).items()},
         **{"attention_" + key: count
            for key, count in (attention_stats or {}).items()},
+        **{"window_" + key: count
+           for key, count in (window_stats or {}).items()},
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

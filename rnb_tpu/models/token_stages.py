@@ -1,6 +1,6 @@
 """The pipeline stages of the token families (``nemotron_h``,
-``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``): a first stage whose request is a
-prompt file, and a final stage that runs a family's stack over a packed
+``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``, ``exaone_moe``): a
+first stage whose request is a prompt file, and a final stage that runs a family's stack over a packed
 pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
 (``segments: true``), which fuses requests into row buckets up to the
 row cap and carries the segment table.
@@ -29,7 +29,10 @@ read, the keys of the blocks they chose (the ``Sparse:`` line);
 ``attn_tiles`` (attention layers, 2): the (query block, key block)
 tiles of the packed flash kernel (``ops/segattn.py``) that the
 dispatch's block table let run, and those on or under the diagonal
-(the ``Attention:`` line).
+(the ``Attention:`` line); a stack that has layers with a window counts
+those layers' tiles apart, at their own tile sizes, as
+``window_tiles`` (the same line's ``window_`` pair), and its
+``attn_tiles`` are the full layers' alone.
 
 A family without experts has no ``held_slots`` (its ``slots`` is
 None), counts no ``expert_served`` and gets no ``Experts:`` line; one
@@ -276,8 +279,8 @@ class PackedPrefill(StageModel):
         run's stages: the tokens, and the family's counters under their
         names (a family without experts reports no ``expert_served``,
         one that chooses no key blocks no ``sparse``, one without the
-        packed flash kernel no ``attn_tiles``; none before the first
-        dispatch)."""
+        packed flash kernel no ``attn_tiles``, one without a window no
+        ``window_tiles``; none before the first dispatch)."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
                     "tokens_shipped": int(self.tokens_shipped)}
@@ -288,7 +291,7 @@ class PackedPrefill(StageModel):
                 self.cfg.num_experts_per_tok)
         if "group_tokens" in counted:
             counters["group_tokens"] = int(counted["group_tokens"].sum())
-        for name in ("sparse", "attn_tiles"):
+        for name in ("sparse", "attn_tiles", "window_tiles"):
             if name in counted:
                 counters[name] = counted[name].sum(axis=0)
         return counters

@@ -15,7 +15,7 @@ kernel on TPU backends and to an identical jnp formulation elsewhere
 
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
-rnb_tpu.models.qwen3_next) add six mechanisms, each over a packed pool
+rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe) add six mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan and its convolution, in plain jnp/lax; lightning linear attention
 is its case of unit steps), ``deltanet`` (the gated delta rule, whose
@@ -26,7 +26,8 @@ mean-compressed keys, and a Pallas flash kernel under that block
 mask), ``segattn``
 (causal attention inside requests: JAX's Pallas splash kernel over the
 pool; values may be narrower than keys, latent attention's expanded
-form), ``rope`` (rotary positions that restart at each request, YaRN's
+form; with a window, a query reads the last so many keys of its request
+and the kernel walks a band of tiles, K-EXAONE's sliding layers), ``rope`` (rotary positions that restart at each request, YaRN's
 frequencies) and ``moe`` (routing over all experts by the family's rule
 and the held experts' part, plain or gated, whose grouped product is
 JAX's Pallas megablox kernel).

@@ -99,11 +99,12 @@ def _tile(extent: int, whole_under: int, cap: int, lane: int = 128) -> int:
 
 
 def grouped_matmul(rows, weights, counts, interpret: bool,
-                   transposed: bool = False):
+                   transposed: bool = False, tiling=None):
     """``rows`` (M, K), sorted by group; ``weights`` (G, K, N), or
     (G, N, K) where ``transposed``; ``counts`` (G,) int32 rows of each
-    group, in order. -> float32 (M, N); what lies behind the last
-    group's rows is unspecified.
+    group, in order; ``tiling``: the caller's own (m, k, n), where the
+    tiles below do not fit its widths. -> float32 (M, N); what lies
+    behind the last group's rows is unspecified.
 
     Tiles (m, k, n), read on the v5e at M 49,152 (my chip runs): K 2688
     -> N 1856 with (512, 896, 1024), K 1856 -> N 2688 with (512, 1856,
@@ -120,11 +121,16 @@ def grouped_matmul(rows, weights, counts, interpret: bool,
     average): (128, 2688, 1024) 3.82 and (256, 896, 1856) 4.15 ms for
     the first product, (128, 1856, 896) 3.28 ms against 3.81 for the
     second; not taken here (PERF.md section 7: the cell's backlog has
-    to grow first)."""
+    to grow first). K 2048 -> N 6144 (K-EXAONE's second product) runs
+    out of VMEM with these, a whole K of 2,048 against 1,024 columns of
+    a 512-row tile: 18 MB of the 16; that caller brings its own
+    (``models/exaone_moe/network.py``)."""
     m, k = rows.shape
     n = weights.shape[1] if transposed else weights.shape[2]
-    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
-    tiling = (tm, _tile(k, 2048, 1024), _tile(n, 1024, 1024))
+    if tiling is None:
+        tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1)
+                  if m % t == 0)
+        tiling = (tm, _tile(k, 2048, 1024), _tile(n, 1024, 1024))
     return gmm(rows, weights, counts, preferred_element_type=jnp.float32,
                tiling=tiling, transpose_rhs=transposed,
                interpret=interpret)
@@ -183,7 +189,7 @@ def relu2(x):
 
 
 def held_experts(x, ids, weights, token_ok, held_slot, up, down,
-                 interpret: bool = False, gate=None):
+                 interpret: bool = False, gate=None, down_tiling=None):
     """The held experts' part of the layer's result: ``relu(x U_e)^2
     D_e`` or, where ``gate`` is given (stacked and stored like ``up``),
     the gated form ``(silu(x G_e) * (x U_e)) D_e``.
@@ -193,7 +199,9 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     an expert's position in the stacks, or -1 where it is held
     elsewhere; ``up`` (held, inner, hidden), ``down`` (held, inner,
     hidden); ``interpret``: run the grouped product's kernel in
-    Pallas's interpret mode (off the TPU). -> (out (T, hidden)
+    Pallas's interpret mode (off the TPU); ``down_tiling``: the second
+    product's (m, k, n) where :func:`grouped_matmul`'s own do not fit
+    the family's widths. -> (out (T, hidden)
     float32, counts (held,) int32: the pairs each held expert served)."""
     tokens, k = ids.shape
     held = up.shape[0]
@@ -212,7 +220,8 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     else:
         hidden = jax.nn.silu(grouped_matmul(
             rows, gate, counts, interpret, transposed=True)) * hidden
-    out = grouped_matmul(hidden.astype(x.dtype), down, counts, interpret)
+    out = grouped_matmul(hidden.astype(x.dtype), down, counts, interpret,
+                         tiling=down_tiling)
     # the way back: where each pair lies in expert order (the inverse
     # of ``order``), and one gather of the product's rows
     place = jnp.zeros_like(order).at[order].set(

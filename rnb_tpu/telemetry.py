@@ -244,7 +244,9 @@ META_LINE_REGISTRY = (
               "stack runs it, over every attention layer of every "
               "dispatch: the (query block, key block) tiles the "
               "dispatch's block table let the kernel run, and the "
-              "tiles on or under the diagonal (such stages only)"),
+              "tiles on or under the diagonal; where the stack has "
+              "layers with a window, their pair apart as window_* and "
+              "the first pair the full layers' alone (such stages only)"),
     StampSpec("Compiles:", "rnb_tpu/benchmark.py",
               "JSON per-step jit-entry signature counts "
               "{step: {warmup, steady_new, steady_calls}} — "
@@ -932,7 +934,8 @@ def aggregate_stage_counters(snapshots):
 #: the four counts of a ``sparse`` stage counter, in order
 #: (``rnb_tpu.ops.blocksparse`` says what each counts: the ``Sparse:``
 #: line), and the two of ``attn_tiles`` (``rnb_tpu.ops.segattn``: the
-#: ``Attention:`` line)
+#: ``Attention:`` line; the ``window_tiles`` of a stack's layers with a
+#: window are the same two, written behind them as ``window_*``)
 SPARSE_COUNTS = ("queries", "selecting", "causal_keys", "chosen_keys")
 ATTENTION_COUNTS = ("tiles_visited", "tiles_causal")
 

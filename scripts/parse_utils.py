@@ -152,8 +152,11 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             # "Sparse: queries=N selecting=S causal_keys=C
             #  chosen_keys=K" — block-selected attention accounting
             # over (valid query, key-value head) pairs;
-            # "Attention: tiles_visited=V tiles_causal=C" — the packed
-            # flash kernel's tiles, run and on or under the diagonal
+            # "Attention: tiles_visited=V tiles_causal=C
+            #  [window_tiles_visited=W window_tiles_causal=X]" — the
+            # packed flash kernel's tiles, run and on or under the
+            # diagonal (the layers with a window apart, where a stack
+            # has them)
             name, counts = line.split(":", 1)
             for part in counts.split():
                 key, _, val = part.partition("=")

@@ -43,7 +43,7 @@ def arms_of(family: str):
                 ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
                 ("experts_float8", {}, lambda group, name: name in (
                     "up", "down", "shared_up", "shared_down"))]
-    if family == "deepseek_v2":
+    if family in ("deepseek_v2", "exaone_moe"):
         return [("as_stated", {}, None),
                 ("experts_float8", {}, lambda group, name: name in _FFN),
                 ("layers_float8", {}, lambda group, name: True)]

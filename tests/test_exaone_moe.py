@@ -1,0 +1,960 @@
+"""The K-EXAONE family (``exaone_moe``) against its plain reference, at a
+toy size on the CPU with weights from a seed: the packed prefill through
+dispatches with requests of unequal length, a pad row, a request longer
+than the window and one shorter; the lower-precision control; packing
+invisible; the eight shares of the experts adding up to the uncut layer;
+the window by itself (``ops/segattn`` with a window against an explicit
+mask, for windows under, at and over a tile) and its table never closing
+a tile that holds a permitted pair; rotary on the sliding layers alone;
+the stages and their counters; the operation counts against a count by
+hand; the cell through the one benchmark command; the four new readers
+on a run without their scope; the real configuration against the
+catalog's row; the window kernel compiled at the published widths for a
+described v5e; and the shared code's StableHLO for the newest older
+family and for this one.
+Nothing here needs the native decode library or a chip."""
+
+import hashlib
+import json
+import os
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmarks import manifest as mm  # noqa: E402
+from benchmarks.references import compare  # noqa: E402
+from benchmarks.references import exaone_moe as reference  # noqa: E402
+
+REAL = "benchmarks/configs/k-exaone-l5-ep8.json"
+CELL = "k-exaone.bulk"
+SEED = 3_000_000_123
+S, F = "sliding_attention", "full_attention"
+
+#: the dense layer and one period behind it at toy widths: 4 / 2 heads of
+#: 32, a window of 24 keys over rows of 16 tokens, 16 experts top-4 of
+#: which 2 held (eight shares), one shared expert
+TOY = {
+    "num_hidden_layers": 5, "layer_types": [S, S, S, F] * 2,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 7, "sliding_window": 24,
+    "first_k_dense_replace": 1, "hidden_size": 64, "vocab_size": 256,
+    "chunk_size": 16, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 32,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_shared_experts": 1, "num_experts": 2, "n_group": 1,
+    "topk_group": 1, "num_experts_per_tok": 4,
+    "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "rms_norm_eps": 1e-5, "hidden_act": "silu",
+    "tie_word_embeddings": False,
+    "published": {"num_hidden_layers": 48, "num_experts": 16,
+                  "vocab_size": 2048}}
+HELD = (0, 1)
+SHARES = [(2 * i, 2 * i + 1) for i in range(8)]
+Q = TOY["chunk_size"]
+#: the comparison's limit at the toy widths: narrow sums average less
+#: rounding away than the real ones (the real limit is the family
+#: file's SHARE_OF_SPREAD); the toy reads 1.8 to 3.1% over the
+#: dispatches below and two seeds of weights, its float8 control FLOAT8
+TOY_LIMIT = 0.045
+
+
+@pytest.fixture(scope="module")
+def toy():
+    import jax
+
+    from rnb_tpu.models.exaone_moe import checkpoint, network
+    cfg = network.ExaoneMoeConfig.from_published(TOY)
+    device = jax.devices()[0]
+    return {"cfg": cfg, "device": device,
+            "params": checkpoint.make_params(cfg, SEED, HELD, device),
+            "slots": network.held_slots(cfg, HELD),
+            "read": checkpoint.reference_reader(cfg, SEED, device),
+            "reference": reference.Reference(TOY)}
+
+
+def prompts_of(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n).astype(np.int32)
+            for n in lengths]
+
+
+def pack(prompts, rows):
+    from rnb_tpu.models import token_stages
+    return token_stages.pack_prompts(prompts, rows, Q)
+
+
+def run_program(toy, prompts, rows, params=None):
+    """-> (logits a prompt, each prompt's router choices (expert layers,
+    tokens, k), the counters)."""
+    import jax
+
+    from rnb_tpu.models.exaone_moe import network
+    tokens, meta, offsets = pack(prompts, rows)
+    logits, chosen, *counts = jax.jit(
+        lambda p, t, m: network.forward(
+            toy["cfg"], p, toy["slots"], t, m[0], m[1], m[2],
+            interpret=True))(
+        toy["params"] if params is None else params, tokens, meta)
+    chosen = np.asarray(chosen)
+    per_prompt = [chosen[:, o * Q:o * Q + len(p)]
+                  for o, p in zip(offsets, prompts)]
+    return np.asarray(logits)[:len(prompts)], per_prompt, \
+        [np.asarray(c) for c in counts]
+
+
+def run_reference(toy, prompt, forced=None):
+    import jax
+    with jax.default_matmul_precision("highest"):
+        return toy["reference"].forward(toy["read"], prompt, held=HELD,
+                                        forced=forced)
+
+
+def through_float8(params):
+    """The stored matrices of every layer rounded through float8 (e4m3):
+    the nearest precision below the one the configuration states."""
+    import jax.numpy as jnp
+    out = dict(params)
+    for group, tensors in params.items():
+        if isinstance(tensors, dict):
+            out[group] = {
+                name: (w.astype(jnp.float8_e4m3fn).astype(w.dtype)
+                       if w.ndim >= 2 else w)
+                for name, w in tensors.items()}
+    return out
+
+
+# -- the whole stack ----------------------------------------------------------
+
+#: dispatches of 16 rows: requests of unequal length, longer than the
+#: window of 24 and shorter (5, 16), one that ends inside a row, one that
+#: fills its rows, pad rows behind
+DISPATCHES = {"three": [120, 37, 70], "short_and_long": [16, 96, 5, 64],
+              "one_long": [250]}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCHES))
+def test_packed_prefill_matches_the_reference(toy, case):
+    family = mm.load_family("exaone_moe")
+    prompts = prompts_of(DISPATCHES[case], seed=4)
+    logits, chosen, counts = run_program(toy, prompts, 16)
+    refs = [run_reference(toy, p, forced=c)
+            for p, c in zip(prompts, chosen)]
+    want = np.stack([np.asarray(r["logits"]) for r in refs])
+    # bfloat16 weights and activations through five post-norm layers at
+    # widths of 64: TOY_LIMIT's reason
+    verdict = compare(logits, want, TOY_LIMIT)
+    assert verdict["ok"], verdict
+    assert max(float(np.asarray(r["shortfall"]).max()) for r in refs) \
+        < family.ROUTE_SLACK
+    # the reference's own free choice agrees almost everywhere
+    for prompt, mine in zip(prompts, chosen):
+        free = np.asarray(run_reference(toy, prompt)["chosen"])
+        assert (np.sort(free, -1) == np.sort(mine, -1)).all(-1).mean() > 0.9
+    # the counters: served pairs and sending tokens of the valid tokens,
+    # the full layer's tiles and the four sliding layers' apart
+    served, sent, tiles, window_tiles = counts
+    valid = sum(DISPATCHES[case])
+    assert served.shape == (4, 2) and sent.shape == (4,)
+    assert served.sum() == sum(int(np.isin(c, HELD).sum()) for c in chosen)
+    assert (sent <= valid).all() and sent.min() > 0
+    assert tiles.shape == (1, 2) and window_tiles.shape == (4, 2)
+    assert (tiles[:, 0] <= tiles[:, 1]).all()
+    assert (window_tiles[:, 0] <= window_tiles[:, 1]).all()
+
+
+def test_the_lower_precision_control(toy):
+    """Every stored matrix through float8 lies outside the tolerance the
+    stated precision lies inside."""
+    prompts = prompts_of([120, 37, 70], seed=4)
+
+    def reading(**how):
+        logits, chosen, _ = run_program(toy, prompts, 16, **how)
+        want = np.stack([np.asarray(run_reference(toy, p, forced=c)
+                                    ["logits"])
+                         for p, c in zip(prompts, chosen)])
+        return compare(logits, want, TOY_LIMIT)
+    assert reading()["ok"]
+    low = reading(params=through_float8(toy["params"]))
+    assert not low["ok"] and low["share_of_spread"] > 2 * TOY_LIMIT
+
+
+def test_packing_is_invisible_and_positions_restart(toy):
+    """A prompt's logits and choices depend neither on what shares its
+    dispatch, nor on where in the pool it lies, nor on the bucket: the
+    window and the rotary positions are the request's own."""
+    a, b, c, d = prompts_of([100, 5, 70, 20])
+    alone, chosen, _ = run_program(toy, [a], 8)
+    packed, packed_chosen, _ = run_program(toy, [b, c, a, d], 16)
+    other, other_chosen, _ = run_program(toy, [d, a], 16)
+    want = run_reference(toy, a, forced=chosen[0])
+    spread = float(np.asarray(want["logits"]).std())
+    # the same bfloat16 program on the same numbers; what differs is the
+    # tiles' order of summation, and a rounding of the stream that falls
+    # the other way once is carried through the norms behind it (1.3% of
+    # the spread read; the reference stands 2-3% off either)
+    for got in (packed[2], other[1]):
+        assert np.abs(got - alone[0]).max() < 0.02 * spread
+    # and a near-tie in a router may then fall the other way
+    for mine in (packed_chosen[2], other_chosen[1]):
+        assert (np.sort(mine, -1) == np.sort(chosen[0], -1)).all(-1).mean() \
+            > 0.97
+    assert compare(alone[0], np.asarray(want["logits"]), TOY_LIMIT)["ok"]
+
+
+# -- the share tied to the model ------------------------------------------------
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer(toy):
+    """Experts 2i and 2i + 1 on chip i of eight: the eight shares' routed
+    parts plus the shared expert, which every chip computes alike, once,
+    are the uncut reference's expert layer. In the reference, and in the
+    program with the slots of each share."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import checkpoint, network
+    cfg, model, read = toy["cfg"], toy["reference"], toy["read"]
+    rng = np.random.default_rng(7)
+    hb = jnp.asarray(rng.normal(size=(3, Q, 64)), jnp.bfloat16)
+    h = hb.reshape(3 * Q, 64).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole, ids, _, _, shared = model.experts(read, 1, h, range(16))
+        parts = [model.experts(read, 1, h, share) for share in SHARES]
+    whole, shared = np.asarray(whole), np.asarray(shared)
+    summed = sum(np.asarray(part[3]) for part in parts) + shared
+    # float32 sums in another order
+    assert np.abs(summed - whole).max() < 1e-5 * np.abs(whole).max()
+    assert np.abs(sum(np.asarray(part[0]) for part in parts)
+                  - 7 * shared - whole).max() < 1e-5 * np.abs(whole).max()
+    # the tokens' choices are spread over the shares
+    assert all(np.isin(np.asarray(ids), share).any() for share in SHARES)
+    # the program: each share's layer from its own stacks and slots
+    ok = jnp.ones((3, Q), bool)
+    outs = []
+    for share in SHARES:
+        p = checkpoint.make_params(cfg, SEED, share, toy["device"],
+                                   groups=["l1"])["l1"]
+        out, chose, counts, _ = network.experts_ffn(
+            cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
+        assert int(counts.sum()) == int(np.isin(np.asarray(chose),
+                                                share).sum())
+        outs.append(np.asarray(out).reshape(3 * Q, 64))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(model.experts(
+            read, 1, h, range(16), forced=jnp.asarray(chose))[0])
+    got = sum(outs) - 7 * shared
+    # bfloat16 weights and products against float32: a few percent of
+    # the layer's spread, eight shares' roundings added (4.6% read)
+    assert np.abs(got - want).max() < 0.06 * want.std()
+
+
+# -- the window by itself ---------------------------------------------------------
+
+
+def explicit(q, k, v, row_start, window):
+    """Softmax attention under an explicit (T, T) mask: a key of the
+    query's request, at or before it, inside its window."""
+    rows, qlen, hq, dim = q.shape
+    hk, tokens = k.shape[2], rows * qlen
+    qf = np.asarray(q, np.float64).reshape(tokens, hq, dim)
+    kf = np.asarray(k, np.float64).reshape(tokens, hk, dim)
+    vf = np.asarray(v, np.float64).reshape(tokens, hk, -1)
+    seg, at = np.repeat(np.asarray(row_start), qlen), np.arange(tokens)
+    ok = (seg[:, None] == seg[None, :]) & (at[None, :] <= at[:, None])
+    if window is not None:
+        ok &= at[None, :] > at[:, None] - window
+    out = np.zeros((tokens, hq, vf.shape[-1]))
+    for h in range(hq):
+        s = np.where(ok, qf[:, h] @ kf[:, h // (hq // hk)].T, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, h] = (p / p.sum(-1, keepdims=True)) @ vf[:, h // (hq // hk)]
+    return out.reshape(rows, qlen, hq, -1), ok
+
+
+#: (rows of 128 tokens, each row's request, the window): a pool of
+#: lane-wide tiles (3 rows) and one of the module's window tiles (16
+#: rows: 512 keys a tile), windows under, at and over a tile, requests
+#: that open inside a tile and a pad row
+WINDOWS = [
+    (3, [0, 0, 2], 40), (3, [0, 0, 2], 128), (3, [0, 0, 0], 200),
+    (16, [0] * 9 + [9] * 6 + [15], 100),
+    (16, [0] * 9 + [9] * 6 + [15], 512),
+    (16, [0] * 16, 600), (16, [0] * 5 + [5] * 11, None)]
+
+
+@pytest.mark.parametrize("rows,starts,window", WINDOWS)
+def test_the_windowed_kernel_equals_the_explicit_mask(rows, starts, window):
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import segattn
+    rng = np.random.default_rng(rows + (window or 0))
+    hq, hk, dim = 4, 2, 128
+    q = jnp.asarray(rng.normal(size=(rows, 128, hq, dim)) * dim ** -0.5,
+                    jnp.float32)
+    k = jnp.asarray(rng.normal(size=(rows, 128, hk, dim)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(rows, 128, hk, dim)), jnp.float32)
+    out, tiles = segattn.packed_attention(
+        q, k, v, jnp.asarray(starts, jnp.int32), True, window)
+    want, _ = explicit(q, k, v, starts, window)
+    # float32 operands, the kernel's running softmax against one pass
+    assert np.abs(np.asarray(out) - want).max() < 5e-6
+    ran, causal = (int(n) for n in np.asarray(tiles))
+    assert 0 < ran <= causal
+
+
+@pytest.mark.parametrize("block_q,block_kv,window", [
+    (128, 128, 128), (256, 256, 128), (256, 128, 128), (512, 256, 128),
+    (128, 256, 100), (256, 256, 300), (1024, 256, 128), (256, 512, 700)])
+def test_the_window_table_closes_no_tile_with_a_permitted_pair(
+        block_q, block_kv, window):
+    """Over random segment tables: every (query, key) pair the mask
+    permits lies in a tile the table runs, the step that runs it fetches
+    that tile's own key block, a step that does not run fetches a block
+    some row runs (no copy of keys nobody reads), and the table is as
+    wide as the band, not the row."""
+    from rnb_tpu.ops import segattn
+    tokens = 4096
+    rng = np.random.default_rng(block_q + block_kv + window)
+    for _ in range(5):
+        cuts = np.sort(rng.choice(np.arange(1, tokens // 128), 5,
+                                  replace=False)) * 128
+        segment = np.zeros(tokens, np.int32)
+        for cut in cuts:
+            segment[cut:] = cut
+        run, fetch, causal = segattn.block_table(
+            segment[::block_q], block_q, block_kv, window)
+        run, fetch = np.asarray(run), np.asarray(fetch)
+        nq, steps = run.shape
+        assert steps <= (block_q + window - 2) // block_kv + 2
+        assert causal == sum(((i + 1) * block_q - 1) // block_kv + 1
+                             for i in range(nq))
+        open_tiles = {(i, int(fetch[i, s])) for i in range(nq)
+                      for s in range(steps) if run[i, s]}
+        assert len(open_tiles) == int(run.sum())
+        at = np.arange(tokens)
+        ok = (segment[:, None] == segment[None, :]) \
+            & (at[None, :] <= at[:, None]) \
+            & (at[None, :] > at[:, None] - window)
+        needed = {(int(i), int(j)) for i, j in zip(
+            *np.nonzero(ok.reshape(nq, block_q, tokens // block_kv,
+                                   block_kv).any(axis=(1, 3))))}
+        assert needed <= open_tiles
+        fetched = {int(j) for j in fetch.reshape(-1)}
+        assert fetched <= {j for _, j in open_tiles}
+
+
+# -- rotary on the sliding layers alone ---------------------------------------------
+
+
+def test_rotary_turns_the_sliding_layers_and_not_the_full_one(toy):
+    """A full layer's mixer does not see positions at all; a sliding
+    layer's result moves with them. And the reference agrees on both
+    kinds, layer by layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import network
+    from rnb_tpu.ops import rope
+    cfg = toy["cfg"]
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(4, Q, 64)), jnp.bfloat16)
+    start = jnp.zeros(4, jnp.int32)
+    here = rope.pool_positions(start, Q)
+    for layer, sliding in ((3, False), (2, True)):
+        assert cfg.is_sliding(layer) == sliding
+        p = toy["params"]["l%d" % layer]
+        out, _ = network.attention_mixer(cfg, p, x, start, here, sliding,
+                                         interpret=True)
+        moved, _ = network.attention_mixer(cfg, p, x, start, here + 7,
+                                           sliding, interpret=True)
+        # a shift of every position is invisible to rotary scores too:
+        # turn the keys' and the queries' positions apart
+        apart, _ = network.attention_mixer(cfg, p, x, start, here * 2,
+                                           sliding, interpret=True)
+        assert np.array_equal(np.asarray(out), np.asarray(moved)) \
+            or sliding
+        assert np.array_equal(np.asarray(out), np.asarray(apart)) \
+            != sliding
+        with jax.default_matmul_precision("highest"):
+            want = reference.attention(
+                TOY, {t: toy["read"]("l%d.%s" % (layer, t))
+                      for t in reference.ATTENTION},
+                x.reshape(4 * Q, 64).astype(jnp.float32), sliding)
+        # bfloat16 products against float32
+        assert np.abs(np.asarray(out).reshape(4 * Q, 64)
+                      - np.asarray(want)).max() < 0.05 * float(want.std())
+
+
+def test_recipe_gives_program_and_reference_the_same_values(toy):
+    """Every tensor the reference reads is the program's stored value,
+    upcast: the routed experts by their global ids."""
+    from rnb_tpu.models.exaone_moe import checkpoint
+    params, read = toy["params"], toy["read"]
+    specs = checkpoint.tensor_specs(toy["cfg"], len(HELD))
+    for group, tensors in specs.items():
+        for name, spec in tensors.items():
+            stored = np.asarray(
+                (params if group == "top" else params[group])[name],
+                np.float32)
+            got = np.asarray(read("%s.%s" % (group, name),
+                                  HELD if spec.per_expert else None))
+            if spec.transposed:
+                stored = np.swapaxes(stored, -1, -2)
+            assert np.array_equal(stored, got), (group, name)
+    # the bias that only chooses is the router's, one a routed expert
+    assert np.asarray(read("l1.b_corr")).shape == (16,)
+    other = checkpoint.make_params(toy["cfg"], SEED, (2, 3), toy["device"],
+                                   groups=["l1"])["l1"]
+    assert not np.array_equal(np.asarray(other["up"]),
+                              np.asarray(params["l1"]["up"]))
+    assert np.array_equal(np.asarray(other["router"]),
+                          np.asarray(params["l1"]["router"]))
+
+
+# -- the stages -----------------------------------------------------------------
+
+
+def test_the_prefill_stage_serves_the_family(tmp_path):
+    """The final stage learns the family from the recipe, counts the
+    held experts' assignments and both kinds of layer's tiles, names the
+    scopes the readers look for and keeps the router's choices."""
+    from rnb_tpu.devices import DeviceSpec
+    from rnb_tpu.models import token_stages
+    from rnb_tpu.models.exaone_moe import checkpoint
+    from rnb_tpu.stage import PaddedBatch
+    from rnb_tpu.telemetry import (ATTENTION_COUNTS, aggregate_counts,
+                                   aggregate_stage_counters)
+    recipe = str(tmp_path / "toy.recipe.json")
+    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
+    stage = token_stages.PackedPrefill(
+        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
+        row_buckets=[4, 8], family="exaone_moe", sample_every=1, samples=2)
+    assert stage.family == "exaone_moe" and stage._slots is not None
+    prompts = prompts_of([80, 9, 30], seed=2)
+    tokens, meta, offsets = pack(prompts, 8)
+    batch = PaddedBatch(tokens, offsets[-1])
+    batch.segment_offsets = tuple(offsets)
+
+    class Card:
+        def __init__(self, rid):
+            self.id = rid
+
+    class Cards:
+        time_cards = [Card(0), Card(1), Card(2)]
+    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, Cards())
+    counters = stage.stage_counters()
+    valid = sum(len(p) for p in prompts)
+    assert counters["tokens_valid"] == valid
+    assert counters["tokens_shipped"] == 8 * Q
+    assert counters["experts_per_token"] == 4
+    assert counters["expert_served"].shape == (4, 2)
+    assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
+    assert 0 < counters["group_tokens"] <= 4 * valid
+    # one full layer and four sliding ones, a pool of one tile
+    assert counters["attn_tiles"].tolist() == [1, 1]
+    assert counters["window_tiles"].tolist() == [4, 4]
+    assert aggregate_counts([counters, counters], "window_tiles",
+                            ATTENTION_COUNTS) \
+        == {"tiles_visited": 8, "tiles_causal": 8}
+    tokens_line, experts_line = aggregate_stage_counters([counters])
+    assert tokens_line == {"valid": valid, "shipped": 8 * Q}
+    assert experts_line is not None
+    for scope in ("/attn/", "/attn/window/", "/attn/full/",
+                  "/attn/window/kernel/", "/attn/full/kernel/",
+                  "/experts/", "/head/", "/embed/"):
+        assert any(scope in name + "/"
+                   for name in stage.hlo_scopes.values()), scope
+    assert len(stage._samples) == 2
+    first = stage._samples[0]
+    assert first["tokens"].tolist() == prompts[0].tolist()
+    assert first["chosen"].shape == (4, 80, 4)
+
+
+def test_the_attention_line_carries_the_window_pair(tmp_path):
+    """Written behind the full layers' pair, parsed under its own names,
+    and a result without sliding layers keeps the default."""
+    sys.path.insert(0, os.path.join(REPO, "scripts"))
+    import parse_utils
+    from rnb_tpu.benchmark import BenchmarkResult
+    fields = BenchmarkResult.__dataclass_fields__
+    assert fields["window_tiles_visited"].default == 0
+    assert fields["window_tiles_causal"].default == 0
+    (tmp_path / "log-meta.txt").write_text(
+        "Tokens: valid=10 shipped=16\n"
+        "Attention: tiles_visited=46 tiles_causal=72 "
+        "window_tiles_visited=12 window_tiles_causal=208\n")
+    meta = parse_utils.parse_meta(str(tmp_path))
+    assert meta["attention_tiles_visited"] == 46
+    assert meta["attention_window_tiles_visited"] == 12
+    assert meta["attention_window_tiles_causal"] == 208
+    reader = mm.load_layer_metric("window_tile_visit_pct.bulk")
+    assert reader.read(types.SimpleNamespace(
+        result=types.SimpleNamespace())) is None
+    assert reader.read(types.SimpleNamespace(result=types.SimpleNamespace(
+        window_tiles_visited=0, window_tiles_causal=0))) is None
+    assert reader.read(types.SimpleNamespace(result=types.SimpleNamespace(
+        window_tiles_visited=12, window_tiles_causal=208))) \
+        == pytest.approx(100 * 12 / 208)
+
+
+# -- the operation counts ---------------------------------------------------------
+
+
+def real_config():
+    with open(os.path.join(REPO, REAL)) as f:
+        return json.load(f)
+
+
+def test_operation_counts_agree_with_a_count_by_hand():
+    from rnb_tpu.models.exaone_moe import flops, network
+    family = mm.load_family("exaone_moe")
+    config = real_config()
+    cfg = network.ExaoneMoeConfig.from_published(
+        family.published_keys(config))
+    # by hand, from the published widths: four products of 6144 x (8192 +
+    # 1024 + 1024) and 8192 x 6144; a gated MLP is three matrices
+    proj = 2 * (6144 * 8192 * 2 + 6144 * 1024 * 2)
+    assert flops.attention_proj_flops_per_token(cfg) == proj \
+        == 2 * family.attention_params(config)
+    assert flops.mlp_flops(cfg, 18432) == 6 * 6144 * 18432
+    assert flops.expert_flops(cfg) == 6 * 6144 * 2048 \
+        == family.expert_flops(config)
+    assert flops.experts_flops_per_token(cfg, 1.0) \
+        == 2 * 6144 * 128 + 2 * 6 * 6144 * 2048
+    assert flops.attention_score_flops_per_token(cfg, 128) \
+        == 4 * 128 * 64 * 128
+    by_hand = 5 * proj + 4 * 5000 * 8192 + 4 * 4 * 120 * 8192 \
+        + 6 * 6144 * 18432 \
+        + 4 * (2 * 6144 * 128 + 6 * 6144 * 2048 * (1 + 1.0))
+    assert flops.flops_per_token(cfg, 5000.0, 120.0, 1.0) == by_hand \
+        == family.flops_per_token(config, 5000.0, 120.0, 1.0)
+    # a sliding layer's queries read 128 keys but for a prompt's first
+    # 127: a little under the window at the mix's lengths
+    keys = family.mean_window_keys(config)
+    assert 126.5 < keys < 128
+    assert family.flops_per_row(config) == 128 * flops.flops_per_token(
+        cfg, family.mean_context(config), keys, 1.0)
+    # ISSUE 42's arithmetic: some 2.9 GFLOP a token as the sliding layers
+    # ran at 1,024-tiles; by their own work (0.004 each, not 0.07) 2.65
+    per_token = family.flops_per_row(config) / 128
+    assert 2.5e9 < per_token < 2.8e9
+    assert 0.15e9 < flops.attention_score_flops_per_token(
+        cfg, family.mean_context(config)) < 0.21e9
+    # both callers: scopes.py passes the held assignments, subscopes.py
+    # does not
+    ops, nbytes = family.mechanism_work(config, "window_attn", 1e6, 80.0)
+    assert ops == 4 * 1e6 * 4 * keys * 8192
+    assert nbytes == 4 * 1e6 * 2 * (2 * 8192 + 2 * 1024)
+    assert family.mechanism_work(config, "window_attn", 1e6, 5e6, 80.0) \
+        == (ops, nbytes)
+    flash_ops, flash_bytes = family.mechanism_work(config, "flash", 1e6,
+                                                   5e6, 80.0)
+    assert flash_ops == ops + 1e6 * 4 * family.mean_context(config) * 8192
+    assert flash_bytes == nbytes * 5 / 4
+    gmm_ops, _ = family.mechanism_work(config, "gmm", 1e6, 5e6, 80.0)
+    assert gmm_ops == 5e6 * flops.expert_flops(cfg)
+    experts_ops, experts_bytes = family.mechanism_work(
+        config, "experts", 1e6, 5e6, 80.0)
+    assert experts_ops > gmm_ops + 1e6 * 6 * 6144 * 18432
+    assert experts_bytes > 80 * 2 * (3 * 6144 * 18432
+                                     + 4 * 16 * 3 * 6144 * 2048)
+
+
+# -- through the one benchmark command ------------------------------------------
+
+
+def toy_config():
+    config = real_config()
+    config.update(TOY)
+    config["experts_held"] = {"first": 0, "count": 2}
+    config["dataset"] = {"seed": 0, "long_every": 11,
+                         "short": {"count": 6, "median": 60, "sigma": 0.5,
+                                   "min": 20, "max": 100},
+                         "long": {"count": 2, "min": 100, "max": 128}}
+    config["capacity_videos_per_chip_s"] = 300
+    config["share_of_spread"] = TOY_LIMIT
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    loader.update(max_rows=8, chunk=16)
+    batcher.update(batch=8, shapes=[[8, 16], [8]], row_buckets=[4, 8])
+    prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
+                   sample_every=3, samples=8)
+    return config
+
+
+def toy_tree(tmp_path):
+    """The real manifest's new cell over a toy-width copy of its
+    configuration: the same family, stages, mix and readers."""
+    os.makedirs(tmp_path / "benchmarks" / "configs")
+    with open(tmp_path / REAL, "w") as f:
+        json.dump(toy_config(), f)
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(mm.load(), f)
+    return str(tmp_path / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_the_cell_through_the_benchmark_command(trace, tmp_path):
+    out = tmp_path / "out"
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+         "--manifest", toy_tree(tmp_path), "--workload", CELL,
+         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
+         "--platform", "cpu", "--out", str(out)],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0, \
+        done.stderr[-3000:]
+    assert line["attempted"] > 0
+    meta = (out / "run" / "log-meta.txt").read_text()
+    for name in ("Tokens: valid=", "Experts:", "Attention: tiles_visited=",
+                 " window_tiles_visited="):
+        assert name in meta, name
+    assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
+    with open(out / "run" / "hlo-scopes.json") as f:
+        scopes = list(json.load(f).values())
+    for scope in ("/attn/window/kernel/", "/attn/full/kernel/"):
+        assert any(scope in name + "/" for name in scopes), scope
+    metrics = line["metrics"]
+    if trace:
+        assert metrics["tokens_per_s.bulk"]["value"] > 0
+        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
+        assert 0 < metrics["held_assignment_pct.bulk"]["value"] < 100
+        assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
+        assert 0 < metrics["flash_tile_visit_pct.bulk"]["value"] <= 100
+        assert 0 < metrics["window_tile_visit_pct.bulk"]["value"] <= 100
+        # what stands against the chip's peak, or comes from the
+        # device's trace, does not come from a CPU
+        assert not any("roofline" in n or "util" in n or "_ms_per_" in n
+                       or "busy_pct" in n for n in metrics)
+    else:
+        assert metrics["videos_per_s"]["value"] > 0
+        assert metrics["setup_s"]["value"] > 0
+
+
+def test_the_parent_fails_on_the_cell_before_jax_starts(tmp_path):
+    """A checkout whose program lacks the family (the parent of PR 42,
+    given this PR's benchmark files): the family file's ``build`` says
+    so and exits, no result line."""
+    family = mm.load_family("exaone_moe")
+    os.makedirs(tmp_path / "rnb_tpu" / "models")
+    with pytest.raises(SystemExit, match="exaone_moe"):
+        family.build(str(tmp_path))
+    family.build(REPO)
+
+
+def test_the_control_script_takes_the_family_from_the_recipe(tmp_path):
+    """``scripts/prefill_control.py`` over a toy-width copy of the
+    configuration's file: as stated inside the limit, the float8 arms
+    outside it."""
+    path = tmp_path / "toy.json"
+    path.write_text(json.dumps(toy_config()))
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
+         "--config", str(path), "--lengths", "37,120,70"],
+        capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-3000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    assert out["family"] == "exaone_moe" and out["ok"]
+    assert out["as_stated"]["ok"] and not out["layers_float8"]["ok"]
+    assert "share_of_spread" in out["experts_float8"]
+
+
+# -- the four new readers -------------------------------------------------------------
+
+NEW_READERS = {"window_attn_ms_per_dispatch.bulk": "attn/window",
+               "full_attn_ms_per_dispatch.bulk": "attn/full",
+               "window_attn_roofline_pct.bulk": "attn/window/kernel",
+               "window_tile_visit_pct.bulk": None}
+
+
+@pytest.mark.parametrize("name", sorted(NEW_READERS))
+def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
+        name, tmp_path):
+    """No trace, and a trace whose run wrote no scope table or none of
+    these scopes (the parent's program): None, not a raise. With the
+    scope: the seconds under it."""
+    module = mm.load_layer_metric(name)
+    entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
+    assert entry and entry[0]["workloads"] == [CELL]
+    assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
+    assert module.LAYER == "packed attention"
+
+    class Result:
+        log_dir = str(tmp_path)
+        tokens_valid = 100
+        pad_emissions = 2
+
+    class Facts:
+        trace = None
+        result = Result
+        family = mm.load_family("exaone_moe")
+        config = {}
+        peak_flops_per_s = 1.97e14
+        device_kind = "TPU v5 lite"
+    assert module.read(Facts) is None
+    path = NEW_READERS[name]
+    if path is None:
+        return
+
+    class Trace:
+        path = str(tmp_path / "none.xplane.pb")
+        host_span = (0.0, 1.0)
+    from benchmarks import subscopes
+    subscopes._CACHE[Trace.path] = {"%fusion.1 f32[8,8]": 0.5}
+    Facts.trace = Trace
+    try:
+        assert subscopes.seconds_under(Facts, path) is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]": "jit(apply)/jit(main)/attn/dot"}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, path) is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]":
+             "jit(apply)/jit(main)/%s/pallas_call" % path}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, path) == 0.5
+        assert subscopes.seconds_under(Facts, "attn") == 0.5
+    finally:
+        del subscopes._CACHE[Trace.path]
+        subscopes._op_names.cache_clear()
+
+
+# -- the real configuration -----------------------------------------------------
+
+
+def catalog_row():
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        for line in f:
+            row = json.loads(line)
+            if row["name"] == "K-EXAONE-236B-A23B":
+                return row
+    return None
+
+
+#: the catalog's ``config`` of K-EXAONE-236B-A23B, but for its four lists
+#: (``layer_types`` and ``sliding_windows`` the pattern below twelve
+#: times, ``mlp_layer_types`` dense once and sparse 47 times, the
+#: prediction module's)
+PUBLISHED = {
+    "first_k_dense_replace": 1, "head_dim": 128, "hidden_act": "silu",
+    "hidden_size": 6144, "intermediate_size": 18432,
+    "max_position_embeddings": 262144, "model_type": "exaone_moe",
+    "moe_intermediate_size": 2048, "n_group": 1, "norm_topk_prob": True,
+    "num_attention_heads": 64, "num_experts": 128,
+    "num_experts_per_tok": 8, "num_hidden_layers": 48,
+    "num_key_value_heads": 8, "num_nextn_predict_layers": 1,
+    "num_shared_experts": 1, "rms_norm_eps": 1e-05,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+    "sliding_window": 128, "sliding_window_pattern": "LLLG",
+    "tie_word_embeddings": False, "topk_group": 1, "vocab_size": 153600,
+    "layer_types": [S, S, S, F] * 12,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 47,
+    "sliding_windows": [128, 128, 128, 0] * 12,
+    "mtp_layer_types": [F], "mtp_sliding_windows": [0]}
+
+
+def test_from_published_reads_the_catalog_row():
+    """The row's own ``config``, every layer held and every expert: the
+    network's sizes are the published ones. And a configuration the
+    network does not implement is refused by name."""
+    from rnb_tpu.models.exaone_moe import network
+    row = catalog_row()
+    published = dict(PUBLISHED if row is None else row["config"],
+                     chunk_size=128)
+    if row is not None:
+        assert row["config"] == PUBLISHED
+    cfg = network.ExaoneMoeConfig.from_published(published)
+    assert cfg.num_hidden_layers == 48 and cfg.sliding_layers == 36
+    assert cfg.num_expert_layers == 47 and cfg.is_dense(0)
+    assert [cfg.is_sliding(i) for i in range(5)] \
+        == [True, True, True, False, True]
+    assert (cfg.hidden_size, cfg.num_attention_heads,
+            cfg.num_key_value_heads, cfg.head_dim) == (6144, 64, 8, 128)
+    assert (cfg.sliding_window, cfg.intermediate_size,
+            cfg.moe_intermediate_size) == (128, 18432, 2048)
+    assert (cfg.router_experts, cfg.num_experts_per_tok,
+            cfg.routed_scaling_factor, cfg.scoring_func) \
+        == (128, 8, 2.5, "sigmoid")
+    assert cfg.inv_freq().shape == (64,) and cfg.inv_freq()[0] == 1.0
+    for key, value in (("hidden_act", "gelu"),
+                       ("tie_word_embeddings", True),
+                       ("layer_types", ["chunked_attention"] * 48),
+                       ("mlp_layer_types", ["sparse"] * 48)):
+        with pytest.raises(ValueError, match="not the K-EXAONE"):
+            network.ExaoneMoeConfig.from_published(
+                dict(published, **{key: value}))
+
+
+def test_real_configuration_keeps_the_published_sizes():
+    config = real_config()
+    entry = mm.config_entry(mm.load(), "k-exaone-l5-ep8")
+    assert entry["reduced"] == config["reduced"] \
+        == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert entry["source"] == config["source"]
+    for key, value in PUBLISHED.items():
+        if key in config["reduced"]:
+            assert config["published"][key] == value
+        else:
+            assert config[key] == value, key
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (5, 16, 19200)
+    row = catalog_row()
+    if row is not None:
+        assert row["source_url"] == config["source"]
+    for key in ("chunk_size", "norms", "rotary", "window", "router", "mtp",
+                "weights", "precision", "prompts", "batch"):
+        assert config["assumed"][key], key
+    assert "eight chips share each layer" in config["deployment"]
+    assert config["size_record"]["projected_gib"] >= 4
+    assert config["capacity_why"] and config["capacity_videos_per_chip_s"]
+    family = mm.load_family(config["family"])
+    assert family.check_config(config) == []
+    for key, value in (("num_hidden_layers", 4), ("vocab_size", 19199)):
+        assert family.check_config(dict(config, **{
+            key: value, "model": dict(config["model"], layers=value)}))
+    cell = mm.cell(mm.load(), CELL)
+    assert cell["config"] == "k-exaone-l5-ep8" and cell["chips"] == 1 \
+        and cell["traffic"] == "bulk"
+    # the weights the file states, from the tensor list: ISSUE 42's
+    # 113.25 M of attention a layer, 453.0 M the dense layer, 755.8 M a
+    # sparse one with 16 experts, 235.9 M of embedding and head
+    from rnb_tpu.models.exaone_moe import checkpoint, network
+    cfg = network.ExaoneMoeConfig.from_published(
+        family.published_keys(config))
+    specs = checkpoint.tensor_specs(cfg, 16)
+    sizes = {group: sum(int(np.prod(spec.shape)) for spec in tensors.values())
+             for group, tensors in specs.items()}
+    assert abs(sizes["top"] / 1e6 - 235.9) < 0.1
+    assert abs(sizes["l0"] / 1e6 - 453.0) < 0.1
+    assert abs(sizes["l3"] / 1e6 - 755.8) < 0.1
+    held = sum(sizes.values())
+    assert abs(held / 1e9 - config["model"]["params_billions_held"]) < 0.01
+    assert abs(2 * held / 2 ** 30 - config["model"]["weights_gib"]) < 0.01
+    # the prompts of the two sibling cells (ISSUE 42: 32 short, 8 long,
+    # one long in eleven), length for length: three configurations on
+    # one traffic
+    with open(os.path.join(
+            REPO, "benchmarks/configs/qwen3-next-l4-ep2.json")) as f:
+        sibling = json.load(f)
+    assert config["dataset"] == sibling["dataset"]
+    assert (config["dataset"]["short"]["count"],
+            config["dataset"]["long"]["count"],
+            config["dataset"]["long_every"]) == (32, 8, 11)
+    lengths = family.prompt_lengths(config)
+    assert lengths == mm.load_family("qwen3_next").prompt_lengths(sibling)
+    assert len(lengths) == 40
+    assert min(lengths.values()) == 4096 and max(lengths.values()) <= 16384
+    # a held expert's tokens a full dispatch
+    assert 128 * 128 * config["num_experts_per_tok"] \
+        // config["published"]["num_experts"] == 1024
+
+
+# -- compiled for the chip -----------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("window", [128, None])
+def test_the_kernel_compiles_at_the_published_widths(window, one_chip):
+    """Both kinds of layer's kernel over the largest row bucket, compiled
+    for a described v5e (nothing runs): one custom call a key-value head
+    batch, and a sliding layer's tables are a band of two steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import segattn
+    config = real_config()
+    rows = max(config["pipeline_config"]["pipeline"][-1]["row_buckets"])
+    q, hq, hk, dim = (config["chunk_size"], config["num_attention_heads"],
+                      config["num_key_value_heads"], config["head_dim"])
+    pool = segattn.pool_tokens(rows * q)
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, b, c, s: segattn.heads_first_attention(
+            a, b, c, s, q, False, window)).lower(
+        of((hk, hq // hk, pool, dim)), of((hk, pool, dim)),
+        of((hk, pool, dim)), of((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "splash_mqa_fwd_segmented_no_residuals" in text
+    if window is not None:
+        block = segattn._WINDOW_BLOCK_Q
+        assert "s32[%d,2]" % (pool // block) in text \
+            or "s8[1,%d,2]" % (pool // block) in text
+
+
+def test_the_second_grouped_product_compiles_at_the_published_widths(
+        one_chip):
+    """``ops/moe.py``'s own tiles run out of VMEM at K 2048 -> N 6144 (a
+    whole contraction against 1,024 columns of a 512-row tile); the
+    family's own fit."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import network
+    from rnb_tpu.ops import moe
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    operands = (of((131072, 2048)), of((16, 2048, 6144)),
+                of((16,), jnp.int32))
+    jax.jit(lambda x, w, c: moe.grouped_matmul(
+        x, w, c, False, tiling=network._DOWN_TILING)).lower(
+        *operands).compile()
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(lambda x, w, c: moe.grouped_matmul(x, w, c, False)).lower(
+            *operands).compile()
+
+
+# -- the shared code's StableHLO ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("family", ["qwen3_next", "exaone_moe"])
+def test_the_toy_stacks_lower_to_the_recorded_text(family):
+    """PR 42 gave ``ops/segattn.py`` a window and ``ops/moe.py`` a
+    tiling argument; a caller that passes neither lowers to the parent's
+    program: ``qwen3_next``'s toy stack to the StableHLO text PR 41's
+    tree gave (its SHA-256, recorded from a ``git archive`` of that
+    commit; the three older families' are held by
+    ``test_qwen3_next.py``), and this family's to the text of the tree
+    that brought it, for the next PR to hold. A PR that moves one of them
+    on purpose records the new text and shows those cells on the chip."""
+    import test_qwen3_next
+    with open(os.path.join(REPO, "tests", "recorded",
+                           "toy_stack_stablehlo.json")) as f:
+        recorded = json.load(f)
+    import jax
+    if recorded["jax"] != jax.__version__:
+        pytest.skip("recorded under jax %s" % recorded["jax"])
+    text = test_qwen3_next.stack_text(family)
+    assert hashlib.sha256(text.encode()).hexdigest() == recorded[family]

@@ -308,8 +308,9 @@ def test_the_way_back_is_the_scatter_forms(toy, case, monkeypatch):
     if case == "nan_behind_the_last_group":
         product = moe.grouped_matmul
 
-        def planted(rows, stack, counts, interpret, transposed=False):
-            out = product(rows, stack, counts, interpret, transposed)
+        def planted(rows, stack, counts, interpret, transposed=False,
+                    tiling=None):
+            out = product(rows, stack, counts, interpret, transposed, tiling)
             behind = jnp.arange(out.shape[0]) >= counts.sum()
             return jnp.where(behind[:, None], jnp.nan, out)
         monkeypatch.setattr(moe, "grouped_matmul", planted)
