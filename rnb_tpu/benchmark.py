@@ -193,6 +193,12 @@ class BenchmarkResult:
     #: tokens, summed over the expert layers, that sent the held
     #: experts anything (``group_tokens=`` on the Experts: line)
     experts_group_tokens: int = 0
+    #: where the stack sizes the held experts' pair buffers by the share
+    #: held (rnb_tpu.ops.moe.pair_capacity): the pair rows the buffers
+    #: held / the tokens x k of those layers, over every expert layer of
+    #: every dispatch (``pair_rows_*=`` on the Experts: line)
+    experts_pair_rows_moved: int = 0
+    experts_pair_rows_all: int = 0
     #: block-selected attention accounting of a stage whose stack
     #: chooses key blocks (rnb_tpu.ops.blocksparse), over (valid query,
     #: key-value head) pairs of every sparse layer: the pairs / those of
@@ -346,6 +352,24 @@ class BenchmarkResult:
     locks_acquires: int = 0
     locks_edges: int = 0
     locks_violations: int = 0
+
+
+def experts_counts(expert_stats, pair_row_stats=None) -> str:
+    """What the ``Experts:`` log-meta line says of
+    ``aggregate_stage_counters``' expert stats; ``group_tokens=`` where
+    a stage counts it, and the ``pair_rows_`` pair where a stack sizes
+    its held experts' buffers (a stack that does neither keeps the line
+    it had)."""
+    counts = ("assignments=%d held=%d max_per_expert=%d "
+              "mean_per_expert=%.3f"
+              % (expert_stats["assignments"], expert_stats["held"],
+                 expert_stats["max_per_expert"],
+                 expert_stats["mean_per_expert"]))
+    if "group_tokens" in expert_stats:
+        counts += " group_tokens=%d" % expert_stats["group_tokens"]
+    for key, count in (pair_row_stats or {}).items():
+        counts += " %s=%d" % (key, count)
+    return counts
 
 
 def run_benchmark(config_path: str,
@@ -909,10 +933,10 @@ def run_benchmark(config_path: str,
                 ragged_stats[key] += int(snap.get(key, 0))
 
     token_stats = expert_stats = sparse_stats = attention_stats = None
-    window_stats = None
+    window_stats = pair_row_stats = None
     if stage_counter_sink:
-        from rnb_tpu.telemetry import (ATTENTION_COUNTS, SPARSE_COUNTS,
-                                       aggregate_counts,
+        from rnb_tpu.telemetry import (ATTENTION_COUNTS, PAIR_ROW_COUNTS,
+                                       SPARSE_COUNTS, aggregate_counts,
                                        aggregate_stage_counters)
         token_stats, expert_stats = aggregate_stage_counters(
             stage_counter_sink)
@@ -922,6 +946,8 @@ def run_benchmark(config_path: str,
                                            "attn_tiles", ATTENTION_COUNTS)
         window_stats = aggregate_counts(stage_counter_sink,
                                         "window_tiles", ATTENTION_COUNTS)
+        pair_row_stats = aggregate_counts(stage_counter_sink,
+                                          "pair_rows", PAIR_ROW_COUNTS)
 
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
@@ -1117,13 +1143,8 @@ def run_benchmark(config_path: str,
             f.write("Tokens: valid=%d shipped=%d\n"
                     % (token_stats["valid"], token_stats["shipped"]))
         if expert_stats is not None:
-            f.write("Experts: assignments=%d held=%d max_per_expert=%d "
-                    "mean_per_expert=%.3f%s\n"
-                    % (expert_stats["assignments"], expert_stats["held"],
-                       expert_stats["max_per_expert"],
-                       expert_stats["mean_per_expert"],
-                       " group_tokens=%d" % expert_stats["group_tokens"]
-                       if "group_tokens" in expert_stats else ""))
+            f.write("Experts: %s\n"
+                    % experts_counts(expert_stats, pair_row_stats))
         if sparse_stats is not None:
             f.write("Sparse: %s\n" % " ".join(
                 "%s=%d" % (key, sparse_stats[key]) for key in SPARSE_COUNTS))
@@ -1496,6 +1517,8 @@ def run_benchmark(config_path: str,
            for key, count in (attention_stats or {}).items()},
         **{"window_" + key: count
            for key, count in (window_stats or {}).items()},
+        **{"experts_" + key: count
+           for key, count in (pair_row_stats or {}).items()},
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]

@@ -55,8 +55,11 @@ from rnb_tpu.ops import moe, rope, segattn
 
 #: what ``forward`` returns behind the logits and the router's choices
 #: (``models/token_stages.py``): ``attn_tiles`` counts the full layers,
-#: ``window_tiles`` the sliding ones, at their own tile sizes
-COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "window_tiles")
+#: ``window_tiles`` the sliding ones, at their own tile sizes;
+#: ``pair_rows`` the pair rows ``held_experts``' buffers held and the
+#: tokens x k a layer has
+COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "window_tiles",
+            "pair_rows")
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: the grouped product's (rows, contraction, columns) a tile for an
@@ -219,23 +222,35 @@ def attention_mixer(cfg, p, x, row_start, positions, sliding: bool,
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
     """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,), the
-    valid tokens that sent the held experts anything)."""
+    valid tokens that sent the held experts anything, the pair rows the
+    held experts' buffers held and the T k they would hold unsized).
+
+    The buffers are sized by the share of experts held
+    (``ops/moe.pair_capacity``: 32,768 of 131,072 pair rows at 128 rows
+    of 128 tokens, 16 of 128 experts), whatever the router does: pairs
+    over the size take further passes."""
     rows, q, hidden = h.shape
     flat = h.reshape(rows * q, hidden)
     ok = token_ok.reshape(-1)
+    pairs = rows * q * cfg.num_experts_per_tok
+    capacity = moe.pair_capacity(rows * q, cfg.num_experts_per_tok,
+                                 p["up"].shape[0], cfg.router_experts)
     ids, weights = moe.route(
         flat, p["router"], p["b_corr"], cfg.num_experts_per_tok,
         cfg.routed_scaling_factor, score=cfg.scoring_func,
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         renormalise=cfg.norm_topk_prob)
-    routed, counts = moe.held_experts(
+    routed, counts, *moved = moe.held_experts(
         flat, ids, weights, ok, slots, p["up"], p["down"],
         interpret=interpret, gate=p["gate"],
-        down_tiling=_down_tiling(cfg, rows * q * cfg.num_experts_per_tok))
+        down_tiling=_down_tiling(cfg, capacity or pairs), capacity=capacity)
     out = routed + moe.dense_expert(flat, p["shared_up"], p["shared_down"],
                                     p["shared_gate"])
     sent = ((slots[ids] >= 0).any(-1) & ok).sum().astype(jnp.int32)
-    return out.reshape(rows, q, hidden), ids, counts, sent
+    # without a capacity (a dispatch too small for one) all pairs move
+    pair_rows = jnp.stack([moved[0] if moved else jnp.int32(pairs),
+                           jnp.int32(pairs)])
+    return out.reshape(rows, q, hidden), ids, counts, sent, pair_rows
 
 
 def _down_tiling(cfg, pairs: int):
@@ -265,7 +280,9 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
     anything (expert layers,) int32; the flash kernel's tiles in the
     full layers (full layers, 2) int32: those this dispatch's block
     table let run, and those on or under the diagonal; the same of the
-    sliding layers (sliding layers, 2), at their own tile sizes).
+    sliding layers (sliding layers, 2), at their own tile sizes; the pair
+    rows the held experts' buffers held and tokens x k (expert layers,
+    2)).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
@@ -274,6 +291,7 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
     chosen, served, sent, full_tiles, window_tiles = [], [], [], [], []
+    pair_rows = []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         sliding = cfg.is_sliding(i)
@@ -288,11 +306,12 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
             if cfg.is_dense(i):
                 out = moe.dense_expert(x, p["up"], p["down"], p["gate"])
             else:
-                out, ids, counts, tokens_sent = experts_ffn(
+                out, ids, counts, tokens_sent, moved = experts_ffn(
                     cfg, p, x, token_ok, slots, interpret)
                 chosen.append(ids)
                 served.append(counts)
                 sent.append(tokens_sent)
+                pair_rows.append(moved)
             out = rms_norm(out, p["ffn_norm"], cfg.eps, jnp.float32)
             x = (x.astype(jnp.float32) + out).astype(act)
     with jax.named_scope("head"):
@@ -300,4 +319,5 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
     return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent), \
-        jnp.stack(full_tiles), jnp.stack(window_tiles)
+        jnp.stack(full_tiles), jnp.stack(window_tiles), \
+        jnp.stack(pair_rows)

@@ -32,7 +32,12 @@ dispatch's block table let run, and those on or under the diagonal
 (the ``Attention:`` line); a stack that has layers with a window counts
 those layers' tiles apart, at their own tile sizes, as
 ``window_tiles`` (the same line's ``window_`` pair), and its
-``attn_tiles`` are the full layers' alone.
+``attn_tiles`` are the full layers' alone;
+
+``pair_rows`` (expert layers, 2): the pair rows the held experts'
+buffers held, where a stack sizes them by the share held
+(``ops/moe.pair_capacity``), and the tokens x k they would hold unsized
+(the ``Experts:`` line's ``pair_rows_`` pair).
 
 A family without experts has no ``held_slots`` (its ``slots`` is
 None), counts no ``expert_served`` and gets no ``Experts:`` line; one
@@ -280,7 +285,8 @@ class PackedPrefill(StageModel):
         names (a family without experts reports no ``expert_served``,
         one that chooses no key blocks no ``sparse``, one without the
         packed flash kernel no ``attn_tiles``, one without a window no
-        ``window_tiles``; none before the first dispatch)."""
+        ``window_tiles``, one that does not size its experts' buffers no
+        ``pair_rows``; none before the first dispatch)."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
                     "tokens_shipped": int(self.tokens_shipped)}
@@ -291,7 +297,7 @@ class PackedPrefill(StageModel):
                 self.cfg.num_experts_per_tok)
         if "group_tokens" in counted:
             counters["group_tokens"] = int(counted["group_tokens"].sum())
-        for name in ("sparse", "attn_tiles", "window_tiles"):
+        for name in ("sparse", "attn_tiles", "window_tiles", "pair_rows"):
             if name in counted:
                 counters[name] = counted[name].sum(axis=0)
         return counters

@@ -32,6 +32,39 @@ cost 57 ms of a 215 ms dispatch on the v5e, where this costs 33: XLA
 sorts such a scatter's indices again and makes two passes over the
 array; my chip runs, PR 29.)
 
+How many pair rows move is the caller's to size (``capacity``; PR 43).
+Without one, every array with a pair axis has all T k rows, held or
+not. A layer that holds an eighth of the experts then gathers in,
+passes over and gathers back eight rows for each one a product reads:
+K-EXAONE's 131,072 rows of 6,144 cost 34 ms a layer around 10 ms of
+products (my chip runs, PR 43). With a capacity C
+(:func:`pair_capacity`: twice the pairs a uniform router sends the held
+experts, from the shapes alone; None at half the experts or more, where
+twice the share is everything) the held pairs — the first
+``counts.sum()`` of the sorted order — go through in passes of C rows,
+a loop that runs while held pairs are left, so nothing is dropped or
+deferred whatever the router does (every pair held: four passes, 100
+ms against 95). A pass gathers C tokens' rows,
+runs the products at M = C over its share of each group (the clipped
+differences of ``cumsum(counts)``) and adds the rows to the tokens'
+sums. Pointing the unheld pairs of the old way back at a zero row
+would not do: its gather would still write and re-read T k rows of
+float32, the largest part of the loss. So the way back is a kernel
+(:func:`combine_pairs`) that walks the served pairs, sorted by (token,
+choice), copies each one's row out of the product's result and adds it
+times its weight to its token's sum — product, then sum, j = 0 ... k-1,
+as the masked sum does: one pass gives the sums of all T k rows to the
+bit (read on the chip). A copy moves whole (8, 128) tiles and a row of
+a float32 ``[C, hidden]`` array is one sublane of each of its tiles
+(Mosaic refuses the slice), so the result first goes into ``[C, 8,
+hidden / 8]``, a row as eight sublanes, in one pass of XLA's, and the
+sums come back from that form in another: 2.5 and 1.2 ms a layer around
+a kernel of 2.0 (16 thousand copies of 24 KB, 64 in flight, and the
+read of the loop's zero carry; a first form that tested all T k pairs
+in its scalar loop took 6.5). XLA's
+``zeros.at[token].add(rows)`` over the C rows read 8.4 ms and 3.5 for
+its index sort, and does not fix the order of a token's additions.
+
 The pairs lie expert-choice-major, ``(k, T)``: pair ``j * T + t`` is
 token ``t``'s ``j``-th chosen expert, and every array with a pair axis
 keeps the ``top_k`` choices as its *leading* axis. The device tiles an
@@ -46,8 +79,9 @@ leading axis at the memory's speed: the experts' part of a 64-row
 dispatch 109.4 -> 88.0 ms and 34.2 -> 38.6 requests/s for Nemotron,
 118.8 -> 91.4 ms and 15.1 -> 16.1 for DeepSeek-V2 (my chip runs, PR
 34; 8 and 10 ms of that are the compiler's, which now keeps the source
-of the gather in in its fast memory: PERF.md section 6). Within a group the rows lie sorted by (j, t) and not by (t, j): a
-row's product does not depend on its neighbours. The sum adds the rows
+of the gather in its fast memory: PERF.md section 6). Within a group
+the rows lie sorted by (j, t) and not by (t, j): a row's product does
+not depend on its neighbours. The sum adds the rows
 j = 0 ... k-1 in that order (read on the chip, to the bit); over the
 padded sublanes it had been ``((r0 + r4) + r2) + ((r1 + r5) + r3)``,
 so a result may differ from the older form's in its last bit. Callers
@@ -77,9 +111,13 @@ transposes it once, at set-up.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -188,8 +226,35 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def pair_capacity(tokens: int, k: int, held: int, num_experts: int):
+    """The pair rows :func:`held_experts`' buffers hold at a time, from
+    the shapes alone: the smallest multiple of 512 (the grouped
+    product's row tile) that is at least twice the pairs a uniform
+    router sends the held experts, ``2 tokens k held / num_experts``;
+    None, the buffers of all ``tokens k`` pairs, where that is not under
+    half of them (at a half share twice the share is everything)."""
+    pairs = tokens * k
+    capacity = -(-2 * pairs * held // (num_experts * 512)) * 512
+    return capacity if 2 * capacity < pairs else None
+
+
+def _expert_products(rows, counts, up, down, gate, interpret, down_tiling):
+    """``rows`` (M, hidden) sorted by held expert, ``counts`` the rows
+    of each -> float32 (M, hidden): the expert's two or three products
+    as grouped ones."""
+    hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
+    if gate is None:
+        hidden = relu2(hidden)
+    else:
+        hidden = jax.nn.silu(grouped_matmul(
+            rows, gate, counts, interpret, transposed=True)) * hidden
+    return grouped_matmul(hidden.astype(rows.dtype), down, counts,
+                          interpret, tiling=down_tiling)
+
+
 def held_experts(x, ids, weights, token_ok, held_slot, up, down,
-                 interpret: bool = False, gate=None, down_tiling=None):
+                 interpret: bool = False, gate=None, down_tiling=None,
+                 capacity=None):
     """The held experts' part of the layer's result: ``relu(x U_e)^2
     D_e`` or, where ``gate`` is given (stacked and stored like ``up``),
     the gated form ``(silu(x G_e) * (x U_e)) D_e``.
@@ -198,11 +263,14 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     ``token_ok`` (T,) bool, False on padding; ``held_slot`` (E,) int32:
     an expert's position in the stacks, or -1 where it is held
     elsewhere; ``up`` (held, inner, hidden), ``down`` (held, inner,
-    hidden); ``interpret``: run the grouped product's kernel in
-    Pallas's interpret mode (off the TPU); ``down_tiling``: the second
-    product's (m, k, n) where :func:`grouped_matmul`'s own do not fit
-    the family's widths. -> (out (T, hidden)
-    float32, counts (held,) int32: the pairs each held expert served)."""
+    hidden); ``interpret``: run the kernels in Pallas's interpret mode
+    (off the TPU); ``down_tiling``: the second product's (m, k, n) where
+    :func:`grouped_matmul`'s own do not fit the family's widths;
+    ``capacity``: the pair rows the buffers hold at a time
+    (:func:`pair_capacity`), None for all T k of them. -> (out (T,
+    hidden) float32, counts (held,) int32: the pairs each held expert
+    served) and, with a capacity, the pair rows the buffers held (int32:
+    the capacity times the passes the held pairs took)."""
     tokens, k = ids.shape
     held = up.shape[0]
     # every pair axis below is (k, T): pair j*T + t is token t's j-th
@@ -213,15 +281,15 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     order = jnp.argsort(flat_slot, stable=True)
     counts = jnp.bincount(flat_slot, length=held + 1)[:held] \
         .astype(jnp.int32)
+    if capacity is not None:
+        return _held_by_capacity(
+            x, weights, order, counts, capacity,
+            lambda rows, sizes: _expert_products(
+                rows, sizes, up, down, gate, interpret, down_tiling),
+            interpret)
     rows = x[order % tokens]                            # (k*T, hidden)
-    hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
-    if gate is None:
-        hidden = relu2(hidden)
-    else:
-        hidden = jax.nn.silu(grouped_matmul(
-            rows, gate, counts, interpret, transposed=True)) * hidden
-    out = grouped_matmul(hidden.astype(x.dtype), down, counts, interpret,
-                         tiling=down_tiling)
+    out = _expert_products(rows, counts, up, down, gate, interpret,
+                           down_tiling)
     # the way back: where each pair lies in expert order (the inverse
     # of ``order``), and one gather of the product's rows
     place = jnp.zeros_like(order).at[order].set(
@@ -232,6 +300,123 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     # mask is on the rows and not a zero weight
     back = jnp.where(here[:, :, None], back * weights.T[:, :, None], 0.0)
     return back.sum(axis=0), counts
+
+
+def _held_by_capacity(x, weights, order, counts, capacity, products,
+                      interpret):
+    """:func:`held_experts` with buffers of ``capacity`` pair rows: the
+    held pairs are the first ``counts.sum()`` of ``order``; a pass takes
+    the next ``capacity`` of them through ``products`` and adds each
+    token's weighted rows to its sum, in a loop that runs while held
+    pairs are left (once, where the router keeps to twice its mean). One
+    body for the first pass and the others: the stage program is traced,
+    lowered and compiled a row bucket at every start, and a pass is
+    three grouped products and the way back."""
+    tokens, k = weights.shape
+    pairs = k * tokens
+    order = jnp.pad(order, (0, -(-pairs // capacity) * capacity - pairs))
+    ends = jnp.cumsum(counts)
+    weights = weights.T.reshape(-1)                     # by pair, (k, T)
+
+    def one_pass(carry):
+        first, acc = carry
+        chosen = lax.dynamic_slice(order, (first,), (capacity,))
+        sizes = jnp.clip(ends - first, 0, capacity) \
+            - jnp.clip(ends - counts - first, 0, capacity)
+        token = chosen % tokens
+        out = products(x[token], sizes)
+        # the rows that hold a pair, in the order (token, choice): a
+        # token's rows lie together, j = 0 ... k-1
+        rows = jnp.arange(capacity, dtype=jnp.int32)
+        key = jnp.where(rows < ends[-1] - first,
+                        token * k + chosen // tokens, pairs)
+        key, rows, weight = lax.sort((key, rows, weights[chosen]),
+                                     num_keys=1)
+        return first + capacity, combine_pairs(out, rows, key // k, weight,
+                                               acc, interpret)
+
+    first, acc = lax.while_loop(
+        lambda carry: carry[0] < ends[-1], one_pass,
+        (jnp.int32(0), jnp.zeros((tokens, 8, x.shape[1] // 8), jnp.float32)))
+    return acc.reshape(tokens, -1), counts, first
+
+
+#: row copies in flight in :func:`combine_pairs`' kernel
+_COPIES = 64
+
+
+def _combine_kernel(starts_ref, rows_ref, tokens_ref, weights_ref, out_hbm,
+                    *rest, tile: int):
+    """One grid step: ``tile`` tokens' sums. In SMEM: ``starts_ref``
+    where each tile's entries begin in the three lists, ``rows_ref`` a
+    pair's row of ``out_hbm``, ``tokens_ref`` its token, ``weights_ref``
+    its weight; then the sums so far, the sums, ``_COPIES`` rows of
+    buffer and their DMA semaphores."""
+    acc_ref, sum_ref, buf, sems = rest
+    step = pl.program_id(0)
+    first = starts_ref[step]
+    entries = starts_ref[step + 1] - first
+    sum_ref[...] = acc_ref[...]
+
+    def copy(entry):
+        slot = entry % _COPIES
+        return pltpu.make_async_copy(out_hbm.at[rows_ref[first + entry]],
+                                     buf.at[slot], sems.at[slot])
+
+    def start(entry, carry):
+        copy(entry).start()
+        return carry
+    lax.fori_loop(0, jnp.minimum(entries, _COPIES), start, 0)
+
+    def add(entry, carry):
+        copy(entry).wait()
+        token = tokens_ref[first + entry] - step * tile
+        # a token's entries follow each other, j = 0 ... k-1
+        sum_ref[token] += buf[entry % _COPIES] * weights_ref[first + entry]
+
+        @pl.when(entry + _COPIES < entries)
+        def _():
+            copy(entry + _COPIES).start()
+        return carry
+    lax.fori_loop(0, entries, add, 0)
+
+
+def combine_pairs(out, rows, token, weight, acc, interpret: bool = False):
+    """``acc`` and each token's sum of its served pairs' rows of ``out``
+    times their weights, in float32, as one Pallas kernel that copies a
+    served pair's row and touches no other.
+
+    ``out`` (C, hidden) float32; the served pairs as lists of C, sorted
+    by token and a token's in the order they are to be added: ``rows``
+    int32 a pair's row of ``out``, ``token`` its token, ``weight``
+    float32; behind the last served pair ``token`` says T and the other
+    two are not read; ``acc`` float32 (T, 8, hidden / 8), the sums so
+    far, and so the result: a row as eight sublanes, which is how one
+    row can be copied (a copy moves whole (8, 128) tiles; ``out`` goes
+    into that form in one pass of XLA's in front of the kernel)."""
+    tokens, *row = acc.shape
+    row = tuple(row)
+    tile = next(t for t in (128, 64, 32, 16, 8, 4, 2, 1) if tokens % t == 0)
+    starts = jnp.searchsorted(
+        token, jnp.arange(tokens // tile + 1, dtype=jnp.int32) * tile) \
+        .astype(jnp.int32)
+    sums = pl.BlockSpec((tile,) + row, lambda i, *_: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, tile=tile),
+        out_shape=jax.ShapeDtypeStruct((tokens,) + row, jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(tokens // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), sums],
+            out_specs=sums,
+            scratch_shapes=[pltpu.VMEM((_COPIES,) + row, jnp.float32),
+                            pltpu.SemaphoreType.DMA((_COPIES,))]),
+        input_output_aliases={5: 0},
+        # two blocks of sums in and two out, 3 MiB each at 128 tokens of
+        # 6,144, and the rows in flight: the default 16 MiB is too tight
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret, name="combine_pairs",
+    )(starts, rows, token, weight, out.reshape((out.shape[0],) + row), acc)
 
 
 def dense_expert(x, up, down, gate=None):

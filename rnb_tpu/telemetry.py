@@ -231,7 +231,9 @@ META_LINE_REGISTRY = (
               "sparse-expert accounting of a stage holding a share of "
               "each layer's experts: pairs routed, pairs whose expert "
               "is held here, most and mean served by one held expert "
-              "of one layer (such stages only)"),
+              "of one layer; where the stack sizes the held experts' "
+              "pair buffers, the pair rows they held and the tokens x "
+              "k of those layers as pair_rows_* (such stages only)"),
     StampSpec("Sparse:", "rnb_tpu/benchmark.py",
               "block-selected attention accounting of a stage whose "
               "stack chooses key blocks, over (valid query, key-value "
@@ -938,6 +940,9 @@ def aggregate_stage_counters(snapshots):
 #: window are the same two, written behind them as ``window_*``)
 SPARSE_COUNTS = ("queries", "selecting", "causal_keys", "chosen_keys")
 ATTENTION_COUNTS = ("tiles_visited", "tiles_causal")
+#: the two of ``pair_rows`` (``rnb_tpu.ops.moe.held_experts`` with a
+#: capacity: the ``Experts:`` line's last pair)
+PAIR_ROW_COUNTS = ("pair_rows_moved", "pair_rows_all")
 
 
 def aggregate_counts(snapshots, counter, names):

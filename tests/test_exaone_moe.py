@@ -135,14 +135,18 @@ def through_float8(params):
 #: window of 24 and shorter (5, 16), one that ends inside a row, one that
 #: fills its rows, pad rows behind
 DISPATCHES = {"three": [120, 37, 70], "short_and_long": [16, 96, 5, 64],
-              "one_long": [250]}
+              "one_long": [250], "sized_buffers": [250, 120, 100]}
+#: ... of 32 rows: 2,048 pairs of which an eighth is held, so the held
+#: experts' buffers are sized (512 pair rows) and not all pairs move
+ROWS = {"sized_buffers": 32}
 
 
 @pytest.mark.parametrize("case", sorted(DISPATCHES))
 def test_packed_prefill_matches_the_reference(toy, case):
     family = mm.load_family("exaone_moe")
     prompts = prompts_of(DISPATCHES[case], seed=4)
-    logits, chosen, counts = run_program(toy, prompts, 16)
+    rows = ROWS.get(case, 16)
+    logits, chosen, counts = run_program(toy, prompts, rows)
     refs = [run_reference(toy, p, forced=c)
             for p, c in zip(prompts, chosen)]
     want = np.stack([np.asarray(r["logits"]) for r in refs])
@@ -157,8 +161,9 @@ def test_packed_prefill_matches_the_reference(toy, case):
         free = np.asarray(run_reference(toy, prompt)["chosen"])
         assert (np.sort(free, -1) == np.sort(mine, -1)).all(-1).mean() > 0.9
     # the counters: served pairs and sending tokens of the valid tokens,
-    # the full layer's tiles and the four sliding layers' apart
-    served, sent, tiles, window_tiles = counts
+    # the full layer's tiles and the four sliding layers' apart, the
+    # pair rows the held experts' buffers held
+    served, sent, tiles, window_tiles, pair_rows = counts
     valid = sum(DISPATCHES[case])
     assert served.shape == (4, 2) and sent.shape == (4,)
     assert served.sum() == sum(int(np.isin(c, HELD).sum()) for c in chosen)
@@ -166,6 +171,8 @@ def test_packed_prefill_matches_the_reference(toy, case):
     assert tiles.shape == (1, 2) and window_tiles.shape == (4, 2)
     assert (tiles[:, 0] <= tiles[:, 1]).all()
     assert (window_tiles[:, 0] <= window_tiles[:, 1]).all()
+    pairs = rows * Q * 4
+    assert pair_rows.tolist() == [[512 if rows == 32 else pairs, pairs]] * 4
 
 
 def test_the_lower_precision_control(toy):
@@ -240,7 +247,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(toy):
     for share in SHARES:
         p = checkpoint.make_params(cfg, SEED, share, toy["device"],
                                    groups=["l1"])["l1"]
-        out, chose, counts, _ = network.experts_ffn(
+        out, chose, counts, _, _ = network.experts_ffn(
             cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
         assert int(counts.sum()) == int(np.isin(np.asarray(chose),
                                                 share).sum())
@@ -428,7 +435,8 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     from rnb_tpu.models import token_stages
     from rnb_tpu.models.exaone_moe import checkpoint
     from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import (ATTENTION_COUNTS, aggregate_counts,
+    from rnb_tpu.telemetry import (ATTENTION_COUNTS, PAIR_ROW_COUNTS,
+                                   aggregate_counts,
                                    aggregate_stage_counters)
     recipe = str(tmp_path / "toy.recipe.json")
     checkpoint.save_recipe(recipe, TOY, SEED, HELD)
@@ -462,6 +470,12 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert aggregate_counts([counters, counters], "window_tiles",
                             ATTENTION_COUNTS) \
         == {"tiles_visited": 8, "tiles_causal": 8}
+    # four expert layers of 8 rows x 16 tokens x 4 choices: too few
+    # pairs for a capacity, so all of them move
+    assert counters["pair_rows"].tolist() == [2048, 2048]
+    assert aggregate_counts([counters, counters], "pair_rows",
+                            PAIR_ROW_COUNTS) \
+        == {"pair_rows_moved": 4096, "pair_rows_all": 4096}
     tokens_line, experts_line = aggregate_stage_counters([counters])
     assert tokens_line == {"valid": valid, "shipped": 8 * Q}
     assert experts_line is not None
@@ -615,7 +629,7 @@ def test_the_cell_through_the_benchmark_command(trace, tmp_path):
     assert line["attempted"] > 0
     meta = (out / "run" / "log-meta.txt").read_text()
     for name in ("Tokens: valid=", "Experts:", "Attention: tiles_visited=",
-                 " window_tiles_visited="):
+                 " window_tiles_visited=", " pair_rows_moved="):
         assert name in meta, name
     assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
     with open(out / "run" / "hlo-scopes.json") as f:
@@ -630,6 +644,8 @@ def test_the_cell_through_the_benchmark_command(trace, tmp_path):
         assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
         assert 0 < metrics["flash_tile_visit_pct.bulk"]["value"] <= 100
         assert 0 < metrics["window_tile_visit_pct.bulk"]["value"] <= 100
+        # 8 rows of 16 tokens: no capacity, all pairs move
+        assert metrics["pair_rows_moved_pct.bulk"]["value"] == 100
         # what stands against the chip's peak, or comes from the
         # device's trace, does not come from a CPU
         assert not any("roofline" in n or "util" in n or "_ms_per_" in n
@@ -948,7 +964,10 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     commit; the three older families' are held by
     ``test_qwen3_next.py``), and this family's to the text of the tree
     that brought it, for the next PR to hold. A PR that moves one of them
-    on purpose records the new text and shows those cells on the chip."""
+    on purpose records the new text and shows those cells on the chip:
+    PR 43 recorded this family's again (``forward`` returns the pair
+    rows its held experts' buffers held; at the toy's 8 rows the
+    buffers have no capacity and the rest is the text PR 42 gave)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
@@ -958,3 +977,70 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
         pytest.skip("recorded under jax %s" % recorded["jax"])
     text = test_qwen3_next.stack_text(family)
     assert hashlib.sha256(text.encode()).hexdigest() == recorded[family]
+
+
+# -- the held experts' buffers in the compiled program -------------------------------
+
+
+def test_a_sparse_layer_moves_the_pairs_it_holds(one_chip):
+    """One sparse layer's feed-forward of the real configuration at 128
+    rows, compiled for the described v5e (nothing runs), through
+    ``tests/compiled_experts.py``'s reader: no float array has the
+    131,072 rows of all (token, choice) pairs — the loop over passes of
+    32,768 keeps none for an overflow either — the three grouped
+    products run at the capacity's rows under ``experts``, nothing
+    scatters rows, and the second product's result reaches the tokens'
+    sums through one relayout (a row as eight sublanes) and the
+    ``combine_pairs`` kernel, each once: the loop's body serves the
+    first pass and the others."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import checkpoint, network
+    from rnb_tpu.ops import moe
+    from tests.compiled_experts import instructions
+    config = real_config()
+    cfg = network.ExaoneMoeConfig.from_published(
+        mm.load_family(config["family"]).published_keys(config))
+    held = config["experts_held"]["count"]
+    rows = max(config["pipeline_config"]["pipeline"][-1]["row_buckets"])
+    specs = checkpoint.tensor_specs(cfg, held)["l1"]
+    tokens, k, d, inner = (rows * cfg.chunk_size, cfg.num_experts_per_tok,
+                           cfg.hidden_size, cfg.moe_intermediate_size)
+    capacity = moe.pair_capacity(tokens, k, held, cfg.router_experts)
+    assert (tokens * k, capacity) == (131072, 32768)
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def feed_forward(p, slots, x, token_ok):
+        with jax.named_scope("experts"):
+            out, ids, counts, sent, moved = network.experts_ffn(
+                cfg, p, x, token_ok, slots)
+            out = network.rms_norm(out, p["ffn_norm"], cfg.eps, jnp.float32)
+            return (x.astype(jnp.float32) + out).astype(x.dtype), \
+                ids, counts, sent, moved
+    text = jax.jit(feed_forward).lower(
+        {name: of(specs[name].shape, getattr(jnp, specs[name].dtype))
+         for name in ("ffn_norm", "router", "b_corr", "up", "gate", "down",
+                      "shared_up", "shared_gate", "shared_down")},
+        of((cfg.router_experts,), jnp.int32),
+        of((rows, cfg.chunk_size, d), jnp.bfloat16),
+        of((rows, cfg.chunk_size), jnp.bool_)).compile().as_text()
+    found = instructions(text)
+    kernels, combines = [], []
+    for name, shape, opcode, op_name, top, line in found:
+        kind, _, dims = shape.partition("[")
+        if kind in ("f32", "bf16") and dims.startswith("%d," % (tokens * k)):
+            raise AssertionError(line)
+        if shape == "f32[%d,%d]" % (capacity, d):
+            assert not op_name.endswith(("scatter", "scatter-add")), line
+        if name.startswith("%gmm"):
+            assert opcode == "custom-call" and "/experts/" in op_name, line
+            kernels.append(shape)
+        if name.startswith("%combine_pairs"):
+            assert opcode == "custom-call" and "/experts/" in op_name, line
+            combines.append(shape)
+    assert sorted(kernels) == ["f32[%d,%d]" % (capacity, inner)] * 2 \
+        + ["f32[%d,%d]" % (capacity, d)]
+    assert combines == ["f32[%d,8,%d]" % (tokens, d // 8)]
