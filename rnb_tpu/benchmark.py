@@ -199,6 +199,11 @@ class BenchmarkResult:
     #: every dispatch (``pair_rows_*=`` on the Experts: line)
     experts_pair_rows_moved: int = 0
     experts_pair_rows_all: int = 0
+    #: the rows the first grouped product's grid steps multiplied for
+    #: the ``experts_held`` pairs (rnb_tpu.ops.moe.gmm_visits), over
+    #: every expert layer of every dispatch of a stack that counts them
+    #: (``gmm_rows=`` on the Experts: line)
+    experts_gmm_rows: int = 0
     #: block-selected attention accounting of a stage whose stack
     #: chooses key blocks (rnb_tpu.ops.blocksparse), over (valid query,
     #: key-value head) pairs of every sparse layer: the pairs / those of
@@ -357,9 +362,9 @@ class BenchmarkResult:
 def experts_counts(expert_stats, pair_row_stats=None) -> str:
     """What the ``Experts:`` log-meta line says of
     ``aggregate_stage_counters``' expert stats; ``group_tokens=`` where
-    a stage counts it, and the ``pair_rows_`` pair where a stack sizes
-    its held experts' buffers (a stack that does neither keeps the line
-    it had)."""
+    a stage counts it, the ``pair_rows_`` pair where a stack sizes its
+    held experts' buffers, ``gmm_rows=`` where it counts the grouped
+    product's rows (a stack that does none keeps the line it had)."""
     counts = ("assignments=%d held=%d max_per_expert=%d "
               "mean_per_expert=%.3f"
               % (expert_stats["assignments"], expert_stats["held"],
@@ -369,6 +374,8 @@ def experts_counts(expert_stats, pair_row_stats=None) -> str:
         counts += " group_tokens=%d" % expert_stats["group_tokens"]
     for key, count in (pair_row_stats or {}).items():
         counts += " %s=%d" % (key, count)
+    if "gmm_rows" in expert_stats:
+        counts += " gmm_rows=%d" % expert_stats["gmm_rows"]
     return counts
 
 
@@ -1511,6 +1518,8 @@ def run_benchmark(config_path: str,
                                  if expert_stats else 0.0),
         experts_group_tokens=(expert_stats.get("group_tokens", 0)
                               if expert_stats else 0),
+        experts_gmm_rows=(expert_stats.get("gmm_rows", 0)
+                          if expert_stats else 0),
         **{"sparse_" + key: count
            for key, count in (sparse_stats or {}).items()},
         **{"attention_" + key: count

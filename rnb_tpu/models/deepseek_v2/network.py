@@ -43,8 +43,9 @@ import jax.numpy as jnp
 from rnb_tpu.ops import mla, moe, rope, segattn
 
 #: what ``forward`` returns behind the logits and the router's choices
-#: (``models/token_stages.py``)
-COUNTERS = ("expert_served", "group_tokens", "attn_tiles")
+#: (``models/token_stages.py``); ``gmm_rows``: the rows the first
+#: grouped product multiplied for the pairs the held experts served
+COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "gmm_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,7 +224,8 @@ def latent_attention(cfg, p, h, row_start, positions, interpret=False):
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
     """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,), the
-    valid tokens that sent the held experts anything)."""
+    valid tokens that sent the held experts anything, the rows the
+    first grouped product multiplied)."""
     rows, q, hidden = h.shape
     flat = h.reshape(rows * q, hidden)
     ok = token_ok.reshape(-1)
@@ -234,13 +236,13 @@ def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
         1.0 if cfg.norm_topk_prob else cfg.routed_scaling_factor,
         score=cfg.scoring_func, n_group=cfg.n_group,
         topk_group=cfg.topk_group, renormalise=cfg.norm_topk_prob)
-    routed, counts = moe.held_experts(
+    routed, counts, gmm_rows = moe.held_experts(
         flat, ids, weights, ok, slots, p["up"], p["down"],
         interpret=interpret, gate=p["gate"])
     out = routed + moe.dense_expert(flat, p["shared_up"], p["shared_down"],
                                     p["shared_gate"])
     sent = ((slots[ids] >= 0).any(-1) & ok).sum().astype(jnp.int32)
-    return out.reshape(rows, q, hidden), ids, counts, sent
+    return out.reshape(rows, q, hidden), ids, counts, sent, gmm_rows
 
 
 def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
@@ -260,7 +262,8 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
     valid tokens of each expert layer that sent the held group anything
     (expert layers,) int32; the flash kernel's tiles (layers, 2) int32:
     those this dispatch's block table let run, and those on or under
-    the diagonal).
+    the diagonal; the rows the first grouped product multiplied (expert
+    layers,) int32).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
@@ -268,7 +271,7 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, served, sent, tiles = [], [], [], []
+    chosen, served, sent, tiles, gmm_rows = [], [], [], [], []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         with jax.named_scope("attn"):
@@ -282,15 +285,16 @@ def forward(cfg: DeepseekV2Config, params, slots, tokens, row_tokens,
             if cfg.is_dense(i):
                 out = moe.dense_expert(h, p["up"], p["down"], p["gate"])
             else:
-                out, ids, counts, tokens_sent = experts_ffn(
+                out, ids, counts, tokens_sent, multiplied = experts_ffn(
                     cfg, p, h, token_ok, slots, interpret)
                 chosen.append(ids)
                 served.append(counts)
                 sent.append(tokens_sent)
+                gmm_rows.append(multiplied)
             x = (x.astype(jnp.float32) + out).astype(act)
     with jax.named_scope("head"):
         last = x.reshape(rows * q, -1)[last_idx]
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
     return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent), \
-        jnp.stack(tiles)
+        jnp.stack(tiles), jnp.stack(gmm_rows)

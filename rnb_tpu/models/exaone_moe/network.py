@@ -63,8 +63,9 @@ COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "window_tiles",
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: the grouped product's (rows, contraction, columns) a tile for an
-#: expert's second matrix, K 2,048 -> N 6,144: ``ops/moe.py``'s own
-#: (512, 2048, 1024) run out of VMEM there. Read on the v5e, 131,072
+#: expert's second matrix, K 2,048 -> N 6,144: ``ops/moe.py``'s wide
+#: (512, 2048, 1024) run out of VMEM there (its (128, 2048, 1024), PR
+#: 44's, fit and were not read against these). Read on the v5e, 131,072
 #: pair rows of which 16,384 in 16 groups (my chip runs, PR 42): (256,
 #: 2048, 1024) 3.66 ms; (512, 2048, 768) 4.01; (512, 2048, 512) 4.08;
 #: (512, 1024, 1024) 4.38; (512, 512, 1024) 4.73; (256, 1024, 1024)
@@ -240,7 +241,10 @@ def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
         cfg.routed_scaling_factor, score=cfg.scoring_func,
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         renormalise=cfg.norm_topk_prob)
-    routed, counts, *moved = moe.held_experts(
+    # the rows the first product multiplied are not counted here: a
+    # sixth counter a dispatch is one more fetch in front of the next
+    # program, and this stack's tiles are the wide ones in every bucket
+    routed, counts, _, *moved = moe.held_experts(
         flat, ids, weights, ok, slots, p["up"], p["down"],
         interpret=interpret, gate=p["gate"],
         down_tiling=_down_tiling(cfg, capacity or pairs), capacity=capacity)

@@ -25,8 +25,9 @@ from rnb_tpu.ops import moe, segattn, ssd
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
 #: what ``forward`` returns behind the logits and the router's choices
-#: (``models/token_stages.py``)
-COUNTERS = ("expert_served", "attn_tiles")
+#: (``models/token_stages.py``); ``gmm_rows``: the rows the first
+#: grouped product multiplied for the pairs the held experts served
+COUNTERS = ("expert_served", "attn_tiles", "gmm_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,7 +171,8 @@ def attention_mixer(cfg, p, h, row_start, interpret=False):
 
 def experts_mixer(cfg, p, h, token_ok, slots, expert_cast=None,
                   interpret=False):
-    """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,)).
+    """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,), the
+    rows the first grouped product multiplied).
     ``expert_cast`` rounds the experts' weights through a lower
     precision: the tests' control on the CPU, never the program (the
     v5e's compiler fuses such a round trip in front of the grouped
@@ -188,11 +190,11 @@ def experts_mixer(cfg, p, h, token_ok, slots, expert_cast=None,
     if expert_cast is not None:
         up, down, s_up, s_down = (expert_cast(w)
                                   for w in (up, down, s_up, s_down))
-    routed, counts = moe.held_experts(flat, ids, weights,
-                                      token_ok.reshape(-1), slots, up, down,
-                                      interpret=interpret)
+    routed, counts, gmm_rows = moe.held_experts(
+        flat, ids, weights, token_ok.reshape(-1), slots, up, down,
+        interpret=interpret)
     out = routed + moe.dense_expert(flat, s_up, s_down)
-    return out.reshape(rows, q, hidden), ids, counts
+    return out.reshape(rows, q, hidden), ids, counts, gmm_rows
 
 
 def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
@@ -211,7 +213,8 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
     choices (E blocks, tokens, k) int32; assignments served by each
     held expert (E blocks, held) int32, valid tokens only; the flash
     kernel's tiles (attention blocks, 2) int32: those this dispatch's
-    block table let run, and those on or under the diagonal).
+    block table let run, and those on or under the diagonal; the rows
+    the first grouped product multiplied (E blocks,) int32).
     """
     rows, q = tokens.shape
     row_first = row_start == jnp.arange(rows)
@@ -219,7 +222,7 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, served, tiles = [], [], []
+    chosen, served, tiles, gmm_rows = [], [], [], []
     for i, kind in enumerate(cfg.pattern):
         p = params["b%d" % i]
         if kind == MAMBA:
@@ -236,15 +239,17 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
         elif kind == EXPERTS:
             with jax.named_scope("experts"):
                 h = rms_norm(x, p["norm"], cfg.eps, act)
-                out, ids, counts = experts_mixer(
+                out, ids, counts, multiplied = experts_mixer(
                     cfg, p, h, token_ok, slots, expert_cast, interpret)
                 x = (x.astype(jnp.float32) + out).astype(act)
                 chosen.append(ids)
                 served.append(counts)
+                gmm_rows.append(multiplied)
         else:
             raise ValueError("block kind %r" % (kind,))
     with jax.named_scope("head"):
         last = x.reshape(rows * q, -1)[last_idx]
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
-    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(tiles)
+    return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(tiles), \
+        jnp.stack(gmm_rows)

@@ -60,8 +60,9 @@ import numpy as np
 from rnb_tpu.ops import deltanet, moe, rope, segattn, ssd
 
 #: what ``forward`` returns behind the logits and the router's choices
-#: (``models/token_stages.py``)
-COUNTERS = ("expert_served", "group_tokens", "attn_tiles")
+#: (``models/token_stages.py``); ``gmm_rows``: the rows the first
+#: grouped product multiplied for the pairs the held experts served
+COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "gmm_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,20 +248,21 @@ def attention_mixer(cfg, p, h, row_start, positions, interpret=False):
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
     """-> (float32 (rows, Q, hidden), ids (T, k), counts (held,), the
-    valid tokens that sent the held experts anything)."""
+    valid tokens that sent the held experts anything, the rows the
+    first grouped product multiplied)."""
     rows, q, hidden = h.shape
     flat = h.reshape(rows * q, hidden)
     ok = token_ok.reshape(-1)
     ids, weights = moe.route(flat, p["router"], None,
                              cfg.num_experts_per_tok, 1.0, score="softmax")
-    routed, counts = moe.held_experts(
+    routed, counts, gmm_rows = moe.held_experts(
         flat, ids, weights, ok, slots, p["up"], p["down"],
         interpret=interpret, gate=p["gate"])
     shared = moe.dense_expert(flat, p["shared_up"], p["shared_down"],
                               p["shared_gate"])
     out = routed + jax.nn.sigmoid(_proj(flat, p["shared_w"])) * shared
     sent = ((slots[ids] >= 0).any(-1) & ok).sum().astype(jnp.int32)
-    return out.reshape(rows, q, hidden), ids, counts, sent
+    return out.reshape(rows, q, hidden), ids, counts, sent, gmm_rows
 
 
 def forward(cfg: Qwen3NextConfig, params, slots, tokens, row_tokens,
@@ -281,7 +283,8 @@ def forward(cfg: Qwen3NextConfig, params, slots, tokens, row_tokens,
     expert (layers, held) int32, valid tokens only; valid tokens of each
     layer that sent the held experts anything (layers,) int32; the flash
     kernel's tiles (attention layers, 2) int32: those this dispatch's
-    block table let run, and those on or under the diagonal).
+    block table let run, and those on or under the diagonal; the rows
+    the first grouped product multiplied (layers,) int32).
     """
     rows, q = tokens.shape
     row_first = row_start == jnp.arange(rows)
@@ -290,7 +293,7 @@ def forward(cfg: Qwen3NextConfig, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, served, sent, tiles = [], [], [], []
+    chosen, served, sent, tiles, gmm_rows = [], [], [], [], []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         if cfg.is_attention(i):
@@ -308,15 +311,16 @@ def forward(cfg: Qwen3NextConfig, params, slots, tokens, row_tokens,
                 x = (x.astype(jnp.float32) + out).astype(act)
         with jax.named_scope("experts"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)
-            out, ids, counts, tokens_sent = experts_ffn(
+            out, ids, counts, tokens_sent, multiplied = experts_ffn(
                 cfg, p, h, token_ok, slots, interpret)
             x = (x.astype(jnp.float32) + out).astype(act)
             chosen.append(ids)
             served.append(counts)
             sent.append(tokens_sent)
+            gmm_rows.append(multiplied)
     with jax.named_scope("head"):
         last = x.reshape(rows * q, -1)[last_idx]
         last = rms_norm(last, params["final_norm"], cfg.eps, act)
         logits = _proj(last, params["head"])
     return logits, jnp.stack(chosen), jnp.stack(served), jnp.stack(sent), \
-        jnp.stack(tiles)
+        jnp.stack(tiles), jnp.stack(gmm_rows)

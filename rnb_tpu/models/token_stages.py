@@ -37,7 +37,12 @@ those layers' tiles apart, at their own tile sizes, as
 ``pair_rows`` (expert layers, 2): the pair rows the held experts'
 buffers held, where a stack sizes them by the share held
 (``ops/moe.pair_capacity``), and the tokens x k they would hold unsized
-(the ``Experts:`` line's ``pair_rows_`` pair).
+(the ``Experts:`` line's ``pair_rows_`` pair);
+
+``gmm_rows`` (expert layers,): the rows the first grouped product's grid
+steps multiplied for the pairs the held experts served
+(``ops/moe.gmm_visits`` times the row tile in use; ``gmm_rows=`` on the
+``Experts:`` line, whose ``held=`` is the pairs it kept).
 
 A family without experts has no ``held_slots`` (its ``slots`` is
 None), counts no ``expert_served`` and gets no ``Experts:`` line; one
@@ -286,7 +291,8 @@ class PackedPrefill(StageModel):
         one that chooses no key blocks no ``sparse``, one without the
         packed flash kernel no ``attn_tiles``, one without a window no
         ``window_tiles``, one that does not size its experts' buffers no
-        ``pair_rows``; none before the first dispatch)."""
+        ``pair_rows``, one that does not count the grouped product's
+        rows no ``gmm_rows``; none before the first dispatch)."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
                     "tokens_shipped": int(self.tokens_shipped)}
@@ -295,8 +301,9 @@ class PackedPrefill(StageModel):
             counters["expert_served"] = counted["expert_served"].copy()
             counters["experts_per_token"] = int(
                 self.cfg.num_experts_per_tok)
-        if "group_tokens" in counted:
-            counters["group_tokens"] = int(counted["group_tokens"].sum())
+        for name in ("group_tokens", "gmm_rows"):
+            if name in counted:
+                counters[name] = int(counted[name].sum())
         for name in ("sparse", "attn_tiles", "window_tiles", "pair_rows"):
             if name in counted:
                 counters[name] = counted[name].sum(axis=0)

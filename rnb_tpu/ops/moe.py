@@ -92,8 +92,14 @@ The grouped product is JAX's Pallas kernel
 (row tile, group) pair that holds rows, float32 accumulator in VMEM).
 The first traced run on the v5e (PR 28) read ``lax.ragged_dot`` at 47%
 of the device's time and 12 to 25 TFLOP/s of useful work; the kernel
-with the tiles below read 35 to 64 on the same shapes. Off the TPU the
-same kernel runs in Pallas's interpret mode.
+with :func:`gmm_tiling`'s wide tiles read 35 to 64 on the same shapes.
+A grid step multiplies its whole row tile and stores the group's rows
+alone, so the row tile is 128 wherever a whole K fits beside it
+(:func:`gmm_tiling`; PR 44), and :func:`held_experts` returns beside
+the pairs each held expert served the rows the first product's steps
+multiplied for them (:func:`gmm_visits`): the ``gmm_rows`` counter of a
+family's ``network.COUNTERS``. Off the TPU the same kernel runs in
+Pallas's interpret mode.
 
 The weights lie in memory as the kernel reads them. Its operands must
 be row-major, and the device keeps an array whose last axis is no
@@ -136,42 +142,154 @@ def _tile(extent: int, whole_under: int, cap: int, lane: int = 128) -> int:
     return cap
 
 
+def gmm_visits(counts, tm: int):
+    """The grid steps the grouped product takes a column tile at a row
+    tile of ``tm`` over groups of ``counts`` rows (int32, in order; rows
+    behind the last group belong to none): one for every (row tile,
+    group) pair that holds rows, so a group that starts inside a tile
+    visits that tile again. Each step multiplies the whole ``tm`` rows
+    and keeps the group's (megablox's ``make_group_metadata`` counts the
+    same, ``tests/test_moe_tiles.py``)."""
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    return jnp.where(counts > 0, (ends + tm - 1) // tm - starts // tm,
+                     0).sum()
+
+
+#: what :func:`gmm_tiling` lets a grid step's blocks take of the 16 MiB
+#: of VMEM a kernel may use. Conservative on purpose: the compiler's own
+#: limit lies between (512, 1856, 896), 15.96 MB by :func:`_tile_bytes`,
+#: which compiles, and (256, 2688, 1024), 16.9, which does not; the
+#: account is not the compiler's, so it keeps 2 MiB of room under them
+_VMEM = 14 * 2 ** 20
+
+
+def _tile_bytes(tm: int, tk: int, tn: int) -> int:
+    """What a grid step of the grouped product holds in VMEM: both
+    bfloat16 operand blocks and the float32 result's twice (the
+    pipeline's two buffers) and the float32 accumulator."""
+    return 4 * (tm * tk + tk * tn) + 12 * tm * tn
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """The grouped product's tiles (m, k, n) for ``rows`` (M, K) times
+    (K, N) a group, from the shapes alone: a 128-row tile beside a whole
+    K wherever that fits in VMEM (*narrow*), else PR 28's *wide* tiles.
+
+    *Wide* tiles: the largest row tile that divides M (512), K whole up
+    to 2,048 and N up to 1,024, else cut (:func:`_tile`).
+    Read on the v5e at M 49,152 (my chip runs): K 2688 -> N 1856 with
+    (512, 896, 1024), K 1856 -> N 2688 with (512, 1856, 896): 3.5 and
+    3.0 ms a call in the cell's trace, against 20.5 and 18.6 ms of
+    ``lax.ragged_dot`` (PR 28); the first product's weights as
+    (G, N, K) 4.97 ms a jitted call by the host's clock, 0.6 ms of
+    group metadata in it, and 6.99 ms as (G, K, N), 2.0 of them the
+    relayout in front (PR 29). A whole N with 384 rows or more, and a
+    whole K with 256 x 1,024 or 512 x 640 of the result, run out of
+    VMEM. K 2048 -> N 6144 (K-EXAONE's second product) runs out of VMEM
+    with these, a whole K of 2,048 against 1,024 columns of a 512-row
+    tile: 18 MB of the 16; that caller brings its own (256, 2048, 1024)
+    (``models/exaone_moe/network.py``; PR 42's sweep), which the narrow
+    (128, 2048, 1024) below was not read against.
+
+    *Narrow* tiles (PR 44): the kernel takes a grid step for every (row
+    tile, group) pair that holds rows and multiplies the whole tile in
+    each (:func:`gmm_visits`), so a 512-row tile over groups of 320 rows
+    multiplies two and a half rows for each one it keeps. A 128-row
+    tile beside a *whole* K: consecutive steps inside a group then keep
+    the group's weight block where it is, while at a cut K every step
+    fetches it again and the smaller tile loses. Columns: all of N where
+    that fits in VMEM beside the whole K, else the widest even split of
+    N into whole lanes up to 1,024, else 1,024 with a ragged last tile
+    (:data:`_VMEM`); where not even 512 columns fit beside the whole K
+    (K-EXAONE's 6,144), or 128 does not divide M, the wide tiles stay.
+    The sweep (``scripts/gmm_sweep.py``), the kernel alone
+    under a real dispatch's group sizes (the pairs each held expert
+    served in one full dispatch of the cell's prompts through the stack
+    at its real widths, first and last expert layer: max over mean 1.8
+    and 2.0, 1.4 and 1.5, 1.2 and 1.1; a jitted call by the host's clock,
+    the group metadata in it; ms a call, the mean of both layers; my
+    chip runs, PR 44; *: the wide tiles, >: what this rule returns):
+
+    ==================  =========================  ====================
+    product, M, groups  tiles: ms                  rows kept, %
+    ==================  =========================  ====================
+    Nemotron-H first,   * (512, 896, 1024) 4.82;   41.6 at 512, 59.2 at
+    K 2688 -> N 1856,   (256, 896, 1024) 4.42;     256, 74.6 at 128
+    49,152, 64 of 384   (128, 896, 1024) 5.36;
+    rows                (384, 896, 1024) 4.43;
+                        (256, 896, 1856) 4.05;
+                        > (128, 2688, 1024) 3.78;
+                        (128, 2688, 896) 4.30;
+                        (128, 2688, 640) 3.72;
+                        (256, 2688, 640) 3.70;
+                        (256, 2688, 512) 3.84
+    Nemotron-H second,  * (512, 1856, 896) 3.70;   as above
+    K 1856 -> N 2688    (384, 1856, 896) 3.33;
+                        (256, 1856, 896) 3.23;
+                        > (128, 1856, 896) 3.14;
+                        (256, 1856, 1024) 3.43;
+                        (128, 1856, 1024) 3.35
+    Qwen3-Next first,   * (512, 2048, 512) 2.47;   38.2 at 512, 55.3 at
+    K 2048 -> N 512,    (320, 2048, 512) 2.15;     256, 71.5 at 128,
+    163,840, 256 of     (256, 2048, 512) 2.11;     83.5 at 64
+    320 rows            > (128, 2048, 512) 2.09;
+                        (64, 2048, 512) 2.28
+    Qwen3-Next second,  * (512, 512, 1024) 3.27;   as above
+    K 512 -> N 2048     (256, 512, 1024) 3.10;
+                        (128, 512, 1024) 2.97;
+                        (256, 512, 2048) 2.89;
+                        > (128, 512, 2048) 2.60;
+                        (64, 512, 2048) 2.60
+    DeepSeek-V2 first,  * (512, 1024, 768) 1.51;   36.2 at 512, 53.4 at
+    K 5120 -> N 1536,   (256, 1024, 768) 1.34;     256, 69.5 at 128
+    49,152, 20 of 307   (128, 1024, 768) 1.73;
+    rows                (256, 2560, 768) 1.33;
+                        (128, 2560, 768) 1.73;
+                        (256, 1024, 1536) 1.19;
+                        > (128, 5120, 512) 1.06;
+                        (128, 5120, 384) 1.08;
+                        (256, 5120, 384) 1.09;
+                        (256, 5120, 256) 1.13
+    DeepSeek-V2         * (512, 1536, 1024) 1.42;  as above
+    second, K 1536 ->   (384, 1536, 1024) 1.28;
+    N 5120              (256, 1536, 1024) 1.24;
+                        > (128, 1536, 1024) 1.18;
+                        (256, 1536, 1280) 1.22;
+                        (128, 1536, 1280) 1.25
+    ==================  =========================  ====================
+
+    A whole K changes how the float32 partial sums associate: 1.4e-6 and
+    2.4e-6 of results of order one against the cut K's, a smaller row
+    tile nothing (equal to the bit). K-EXAONE's products were not swept,
+    so no 128-row tile was read against groups of 1,024 rows: its first
+    products' K of 6,144 fits whole beside 256 columns at most and its
+    second brings its own tiles, so all five row buckets keep the wide
+    tiles (``tests/test_moe_tiles.py``)."""
+    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1) if m % t == 0)
+    columns = [n] + [n // parts for parts in range(2, n // 512 + 1)
+                     if n % (128 * parts) == 0 and n // parts <= 1024]
+    if n > 1024:
+        columns.append(1024)
+    if tm >= 128:
+        for tn in columns:
+            if _tile_bytes(128, k, tn) <= _VMEM:
+                return (128, k, tn)
+    return (tm, _tile(k, 2048, 1024), _tile(n, 1024, 1024))
+
+
 def grouped_matmul(rows, weights, counts, interpret: bool,
                    transposed: bool = False, tiling=None):
     """``rows`` (M, K), sorted by group; ``weights`` (G, K, N), or
     (G, N, K) where ``transposed``; ``counts`` (G,) int32 rows of each
-    group, in order; ``tiling``: the caller's own (m, k, n), where the
-    tiles below do not fit its widths. -> float32 (M, N); what lies
-    behind the last group's rows is unspecified.
-
-    Tiles (m, k, n), read on the v5e at M 49,152 (my chip runs): K 2688
-    -> N 1856 with (512, 896, 1024), K 1856 -> N 2688 with (512, 1856,
-    896): 3.5 and 3.0 ms a call in the cell's trace, against 20.5 and
-    18.6 ms of ``lax.ragged_dot`` (PR 28). Re-read for the first
-    product's weights as (G, N, K), the kernel alone under a real
-    dispatch's group sizes (half the pairs held, load max/mean 2.6 and
-    4.2; a jitted call by the host's clock, 0.6 ms of group metadata
-    in it; PR 29): 4.97 ms, and 6.99 ms with (G, K, N) weights, 2.0 of
-    them the relayout in front, so the kernel costs the same either
-    way. A whole N with 384 rows or more, and a whole K with 256 x
-    1,024 or 512 x 640 of the result, run out of VMEM. Smaller row
-    tiles waste less where a group ends (a group has 384 rows on
-    average): (128, 2688, 1024) 3.82 and (256, 896, 1856) 4.15 ms for
-    the first product, (128, 1856, 896) 3.28 ms against 3.81 for the
-    second; not taken here (PERF.md section 7: the cell's backlog has
-    to grow first). K 2048 -> N 6144 (K-EXAONE's second product) runs
-    out of VMEM with these, a whole K of 2,048 against 1,024 columns of
-    a 512-row tile: 18 MB of the 16; that caller brings its own
-    (``models/exaone_moe/network.py``)."""
+    group, in order; ``tiling``: the caller's own (m, k, n), where
+    :func:`gmm_tiling`'s do not fit its widths. -> float32 (M, N); what
+    lies behind the last group's rows is unspecified."""
     m, k = rows.shape
     n = weights.shape[1] if transposed else weights.shape[2]
-    if tiling is None:
-        tm = next(t for t in (512, 256, 128, 64, 32, 16, 8, 1)
-                  if m % t == 0)
-        tiling = (tm, _tile(k, 2048, 1024), _tile(n, 1024, 1024))
     return gmm(rows, weights, counts, preferred_element_type=jnp.float32,
-               tiling=tiling, transpose_rhs=transposed,
-               interpret=interpret)
+               tiling=tiling or gmm_tiling(m, k, n),
+               transpose_rhs=transposed, interpret=interpret)
 
 
 def route(x, w_router, b_corr, top_k: int, scaling: float, *,
@@ -229,7 +347,7 @@ def relu2(x):
 def pair_capacity(tokens: int, k: int, held: int, num_experts: int):
     """The pair rows :func:`held_experts`' buffers hold at a time, from
     the shapes alone: the smallest multiple of 512 (the grouped
-    product's row tile) that is at least twice the pairs a uniform
+    product's widest row tile) that is at least twice the pairs a uniform
     router sends the held experts, ``2 tokens k held / num_experts``;
     None, the buffers of all ``tokens k`` pairs, where that is not under
     half of them (at a half share twice the share is everything)."""
@@ -240,16 +358,22 @@ def pair_capacity(tokens: int, k: int, held: int, num_experts: int):
 
 def _expert_products(rows, counts, up, down, gate, interpret, down_tiling):
     """``rows`` (M, hidden) sorted by held expert, ``counts`` the rows
-    of each -> float32 (M, hidden): the expert's two or three products
-    as grouped ones."""
-    hidden = grouped_matmul(rows, up, counts, interpret, transposed=True)
+    of each -> (float32 (M, hidden): the expert's two or three products
+    as grouped ones; int32: the rows the first product's grid steps
+    multiplied, :func:`gmm_visits` at its row tile)."""
+    tiling = gmm_tiling(*rows.shape, up.shape[1])
+
+    def first(weights):
+        return grouped_matmul(rows, weights, counts, interpret,
+                              transposed=True, tiling=tiling)
+    hidden = first(up)
     if gate is None:
         hidden = relu2(hidden)
     else:
-        hidden = jax.nn.silu(grouped_matmul(
-            rows, gate, counts, interpret, transposed=True)) * hidden
+        hidden = jax.nn.silu(first(gate)) * hidden
     return grouped_matmul(hidden.astype(rows.dtype), down, counts,
-                          interpret, tiling=down_tiling)
+                          interpret, tiling=down_tiling), \
+        gmm_visits(counts, tiling[0]) * tiling[0]
 
 
 def held_experts(x, ids, weights, token_ok, held_slot, up, down,
@@ -265,14 +389,19 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     elsewhere; ``up`` (held, inner, hidden), ``down`` (held, inner,
     hidden); ``interpret``: run the kernels in Pallas's interpret mode
     (off the TPU); ``down_tiling``: the second product's (m, k, n) where
-    :func:`grouped_matmul`'s own do not fit the family's widths;
+    :func:`gmm_tiling`'s own do not fit the family's widths;
     ``capacity``: the pair rows the buffers hold at a time
     (:func:`pair_capacity`), None for all T k of them. -> (out (T,
     hidden) float32, counts (held,) int32: the pairs each held expert
-    served) and, with a capacity, the pair rows the buffers held (int32:
-    the capacity times the passes the held pairs took)."""
+    served, int32: the rows the first product's grid steps multiplied
+    for them, :func:`gmm_visits`) and, with a capacity, the pair rows
+    the buffers held (int32: the capacity times the passes the held
+    pairs took)."""
     tokens, k = ids.shape
     held = up.shape[0]
+    products = functools.partial(
+        _expert_products, up=up, down=down, gate=gate, interpret=interpret,
+        down_tiling=down_tiling)
     # every pair axis below is (k, T): pair j*T + t is token t's j-th
     # chosen expert (the module's docstring says why)
     slot = held_slot[ids.T]                             # (k, T)
@@ -282,14 +411,10 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     counts = jnp.bincount(flat_slot, length=held + 1)[:held] \
         .astype(jnp.int32)
     if capacity is not None:
-        return _held_by_capacity(
-            x, weights, order, counts, capacity,
-            lambda rows, sizes: _expert_products(
-                rows, sizes, up, down, gate, interpret, down_tiling),
-            interpret)
+        return _held_by_capacity(x, weights, order, counts, capacity,
+                                 products, interpret)
     rows = x[order % tokens]                            # (k*T, hidden)
-    out = _expert_products(rows, counts, up, down, gate, interpret,
-                           down_tiling)
+    out, multiplied = products(rows, counts)
     # the way back: where each pair lies in expert order (the inverse
     # of ``order``), and one gather of the product's rows
     place = jnp.zeros_like(order).at[order].set(
@@ -299,7 +424,7 @@ def held_experts(x, ids, weights, token_ok, held_slot, up, down,
     # row is whatever the kernel left there (0 x NaN is NaN), so the
     # mask is on the rows and not a zero weight
     back = jnp.where(here[:, :, None], back * weights.T[:, :, None], 0.0)
-    return back.sum(axis=0), counts
+    return back.sum(axis=0), counts, multiplied
 
 
 def _held_by_capacity(x, weights, order, counts, capacity, products,
@@ -319,12 +444,12 @@ def _held_by_capacity(x, weights, order, counts, capacity, products,
     weights = weights.T.reshape(-1)                     # by pair, (k, T)
 
     def one_pass(carry):
-        first, acc = carry
+        first, acc, multiplied = carry
         chosen = lax.dynamic_slice(order, (first,), (capacity,))
         sizes = jnp.clip(ends - first, 0, capacity) \
             - jnp.clip(ends - counts - first, 0, capacity)
         token = chosen % tokens
-        out = products(x[token], sizes)
+        out, more = products(x[token], sizes)
         # the rows that hold a pair, in the order (token, choice): a
         # token's rows lie together, j = 0 ... k-1
         rows = jnp.arange(capacity, dtype=jnp.int32)
@@ -332,13 +457,14 @@ def _held_by_capacity(x, weights, order, counts, capacity, products,
                         token * k + chosen // tokens, pairs)
         key, rows, weight = lax.sort((key, rows, weights[chosen]),
                                      num_keys=1)
-        return first + capacity, combine_pairs(out, rows, key // k, weight,
-                                               acc, interpret)
+        return first + capacity, combine_pairs(
+            out, rows, key // k, weight, acc, interpret), multiplied + more
 
-    first, acc = lax.while_loop(
+    first, acc, multiplied = lax.while_loop(
         lambda carry: carry[0] < ends[-1], one_pass,
-        (jnp.int32(0), jnp.zeros((tokens, 8, x.shape[1] // 8), jnp.float32)))
-    return acc.reshape(tokens, -1), counts, first
+        (jnp.int32(0), jnp.zeros((tokens, 8, x.shape[1] // 8), jnp.float32),
+         jnp.int32(0)))
+    return acc.reshape(tokens, -1), counts, multiplied, first
 
 
 #: row copies in flight in :func:`combine_pairs`' kernel

@@ -233,7 +233,10 @@ META_LINE_REGISTRY = (
               "is held here, most and mean served by one held expert "
               "of one layer; where the stack sizes the held experts' "
               "pair buffers, the pair rows they held and the tokens x "
-              "k of those layers as pair_rows_* (such stages only)"),
+              "k of those layers as pair_rows_*; where it counts them, "
+              "the rows the first grouped product's grid steps "
+              "multiplied for the held pairs as gmm_rows (such stages "
+              "only)"),
     StampSpec("Sparse:", "rnb_tpu/benchmark.py",
               "block-selected attention accounting of a stage whose "
               "stack chooses key blocks, over (valid query, key-value "
@@ -908,7 +911,8 @@ def aggregate_stage_counters(snapshots):
     stage hold the same experts). ``group_tokens`` is there where a
     stage counts it (a router that chooses among groups of experts):
     the valid tokens, summed over the expert layers, that sent the
-    held experts anything."""
+    held experts anything; ``gmm_rows`` likewise: the rows the first
+    grouped product multiplied for the held pairs."""
     import numpy as np
     tokens = experts = served = None
     for snap in snapshots:
@@ -923,9 +927,9 @@ def aggregate_stage_counters(snapshots):
             experts = experts or {"assignments": 0}
             experts["assignments"] += int(snap["tokens_valid"]) \
                 * int(snap["experts_per_token"]) * part.shape[0]
-            if "group_tokens" in snap:
-                experts["group_tokens"] = experts.get("group_tokens", 0) \
-                    + int(snap["group_tokens"])
+            for name in ("group_tokens", "gmm_rows"):
+                if name in snap:
+                    experts[name] = experts.get(name, 0) + int(snap[name])
     if experts is not None:
         experts.update(held=int(served.sum()),
                        max_per_expert=int(served.max()),

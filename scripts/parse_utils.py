@@ -142,11 +142,12 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
         elif line.startswith("Experts:"):
             # "Experts: assignments=A held=H max_per_expert=M
             #  mean_per_expert=F [group_tokens=G] [pair_rows_moved=R
-            #  pair_rows_all=P]" — sparse-expert accounting of a stage
-            # holding a share of each layer's experts (G: tokens that
-            # sent the held group anything; R of P: the pair rows the
-            # held experts' buffers held, of tokens x k, where the
-            # stack sizes them)
+            #  pair_rows_all=P] [gmm_rows=X]" — sparse-expert accounting
+            # of a stage holding a share of each layer's experts (G:
+            # tokens that sent the held group anything; R of P: the
+            # pair rows the held experts' buffers held, of tokens x k,
+            # where the stack sizes them; X: the rows the first grouped
+            # product multiplied for the H pairs, where it counts them)
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta["experts_" + key] = float(val) if "." in val \

@@ -85,7 +85,7 @@ def run_program(toy, prompts, rows, params=None):
 
     from rnb_tpu.models.deepseek_v2 import network
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, served, sent, _ = jax.jit(
+    logits, chosen, served, sent, *_ = jax.jit(
         lambda p, s, t, m: network.forward(
             toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True))(
         toy["params"] if params is None else params, toy["slots"], tokens,
@@ -364,7 +364,7 @@ def test_the_share_ties_to_the_model(toy):
         held = tuple(range(4 * group, 4 * group + 4))
         p = checkpoint.make_params(cfg, SEED, held, toy["device"],
                                    groups=["l%d" % layer])["l%d" % layer]
-        out, ids, counts, sent = jax.jit(
+        out, ids, counts, sent, _ = jax.jit(
             lambda p, h, ok, s: network.experts_ffn(
                 cfg, p, h, ok, s, interpret=True))(
             p, h, token_ok, network.held_slots(cfg, held))
@@ -759,7 +759,7 @@ def test_every_layer_counts_the_tiles_its_dispatch_ran(toy, monkeypatch):
         monkeypatch.setattr(segattn, name, 128)
     tokens, meta, offsets = pack(prompts_of([9 * Q, 6 * Q - 3, 7 * Q]), 24)
     assert offsets == [0, 9, 15, 22]
-    *_, tiles = jax.jit(lambda p, s, t, m: network.forward(
+    *_, tiles, _ = jax.jit(lambda p, s, t, m: network.forward(
         toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True))(
         toy["params"], toy["slots"], tokens, meta)
     # query block 1 (rows 8-15) opens inside request 0, block 2 (rows
@@ -994,10 +994,8 @@ def test_a_gated_expert_layer_moves_its_pairs_once_each_way(one_chip):
     def feed_forward(p, slots, x, token_ok):
         with jax.named_scope("experts"):
             h = network.rms_norm(x, p["ffn_norm"], cfg.eps, x.dtype)
-            out, ids, counts, sent = network.experts_ffn(
-                cfg, p, h, token_ok, slots)
-            return (x.astype(jnp.float32) + out).astype(x.dtype), \
-                ids, counts, sent
+            out, *counted = network.experts_ffn(cfg, p, h, token_ok, slots)
+            return (x.astype(jnp.float32) + out).astype(x.dtype), *counted
     text = jax.jit(feed_forward).lower(
         {name: of(specs[name].shape, getattr(jnp, specs[name].dtype))
          for name in ("ffn_norm", "router", "up", "gate", "down",

@@ -247,7 +247,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(toy):
     for share in SHARES:
         p = checkpoint.make_params(cfg, SEED, share, toy["device"],
                                    groups=["l1"])["l1"]
-        out, chose, counts, _, _ = network.experts_ffn(
+        out, chose, counts, *_ = network.experts_ffn(
             cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
         assert int(counts.sum()) == int(np.isin(np.asarray(chose),
                                                 share).sum())
@@ -931,9 +931,10 @@ def test_the_kernel_compiles_at_the_published_widths(window, one_chip):
 
 def test_the_second_grouped_product_compiles_at_the_published_widths(
         one_chip):
-    """``ops/moe.py``'s own tiles run out of VMEM at K 2048 -> N 6144 (a
+    """``ops/moe.py``'s wide tiles run out of VMEM at K 2048 -> N 6144 (a
     whole contraction against 1,024 columns of a 512-row tile); the
-    family's own fit."""
+    family's own fit, and win over the rule's (128 rows since PR 44,
+    which fit too)."""
     import jax
     import jax.numpy as jnp
 
@@ -947,8 +948,12 @@ def test_the_second_grouped_product_compiles_at_the_published_widths(
     jax.jit(lambda x, w, c: moe.grouped_matmul(
         x, w, c, False, tiling=network._DOWN_TILING)).lower(
         *operands).compile()
+    assert moe.gmm_tiling(131072, 2048, 6144) == (128, 2048, 1024)
+    jax.jit(lambda x, w, c: moe.grouped_matmul(x, w, c, False)).lower(
+        *operands).compile()
     with pytest.raises(Exception, match="vmem"):
-        jax.jit(lambda x, w, c: moe.grouped_matmul(x, w, c, False)).lower(
+        jax.jit(lambda x, w, c: moe.grouped_matmul(
+            x, w, c, False, tiling=(512, 2048, 1024))).lower(
             *operands).compile()
 
 
@@ -967,7 +972,12 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     on purpose records the new text and shows those cells on the chip:
     PR 43 recorded this family's again (``forward`` returns the pair
     rows its held experts' buffers held; at the toy's 8 rows the
-    buffers have no capacity and the rest is the text PR 42 gave)."""
+    buffers have no capacity and the rest is the text PR 42 gave); PR 44
+    recorded both again (Qwen3-Next's ``forward`` returns ``gmm_rows``,
+    the rows the first grouped product multiplied; this family counts
+    none, and its text moved with the toy's tiles, 128 rows beside a
+    whole K of 64 where the real widths keep the wide ones, and with
+    the numbers of the functions traced in front)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
@@ -1015,11 +1025,9 @@ def test_a_sparse_layer_moves_the_pairs_it_holds(one_chip):
 
     def feed_forward(p, slots, x, token_ok):
         with jax.named_scope("experts"):
-            out, ids, counts, sent, moved = network.experts_ffn(
-                cfg, p, x, token_ok, slots)
+            out, *counted = network.experts_ffn(cfg, p, x, token_ok, slots)
             out = network.rms_norm(out, p["ffn_norm"], cfg.eps, jnp.float32)
-            return (x.astype(jnp.float32) + out).astype(x.dtype), \
-                ids, counts, sent, moved
+            return (x.astype(jnp.float32) + out).astype(x.dtype), *counted
     text = jax.jit(feed_forward).lower(
         {name: of(specs[name].shape, getattr(jnp, specs[name].dtype))
          for name in ("ffn_norm", "router", "b_corr", "up", "gate", "down",

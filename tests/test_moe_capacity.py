@@ -65,8 +65,9 @@ def layer(experts, held, routing, exact_weights, seed=0):
 
 
 def both(args, capacity, gated=True):
-    """-> ((sums, counts) of all T k pairs, (sums, counts, pair rows
-    moved) under ``capacity``)."""
+    """-> ((sums, counts, the rows the first grouped product
+    multiplied) of all T k pairs, (the same and the pair rows moved)
+    under ``capacity``)."""
     import jax
 
     from rnb_tpu.ops import moe
@@ -113,9 +114,13 @@ def test_sized_buffers_give_the_sums_of_all_pairs(case):
     n_here = held_pairs(args)
     if isinstance(routing, int):
         assert n_here == routing
-    (want, counts), (got, counts_sized, moved) = both(
-        args, capacity, gated="relu2" not in case)
+    (want, counts, gmm_rows), (got, counts_sized, gmm_rows_sized, moved) = \
+        both(args, capacity, gated="relu2" not in case)
     assert counts.tolist() == counts_sized.tolist()
+    # the rows the first product multiplied for the held pairs: a
+    # pass's grid steps over its share of each group
+    assert (n_here == 0) == (gmm_rows_sized == 0) == (gmm_rows == 0)
+    assert gmm_rows >= n_here and gmm_rows_sized >= n_here
     assert counts.sum() == n_here
     assert (n_here == 0) == (routing == "none_held")
     assert (n_here == (TOKENS - PAD) * K) == (routing == "all_held")
@@ -207,49 +212,68 @@ def test_k_exaones_row_buckets_are_the_tables():
 
 # -- the counter's line and its reader ------------------------------------------------
 
+#: the line -> (pair_rows_moved_pct.bulk, gmm_row_fill_pct.bulk) its
+#: readers give; the first four are the parent's lines (PR 44's program
+#: writes ``gmm_rows=`` for the three families that count it)
 EXPERTS_LINES = {
     "nemotron_h": ("Experts: assignments=900 held=450 max_per_expert=40 "
-                   "mean_per_expert=28.125\n", None),
+                   "mean_per_expert=28.125\n", None, None),
     "deepseek_v2": ("Experts: assignments=900 held=120 max_per_expert=40 "
-                    "mean_per_expert=7.500 group_tokens=95\n", None),
+                    "mean_per_expert=7.500 group_tokens=95\n", None, None),
     "exaone_moe": ("Experts: assignments=900 held=110 max_per_expert=40 "
                    "mean_per_expert=6.875 group_tokens=95 "
-                   "pair_rows_moved=512 pair_rows_all=2048\n", 25.0),
+                   "pair_rows_moved=512 pair_rows_all=2048\n", 25.0, None),
     "exaone_moe_overflowing": (
         "Experts: assignments=900 held=880 max_per_expert=400 "
         "mean_per_expert=55.000 group_tokens=150 "
-        "pair_rows_moved=2048 pair_rows_all=2048\n", 100.0),
+        "pair_rows_moved=2048 pair_rows_all=2048\n", 100.0, None),
+    "nemotron_h_rows": (
+        "Experts: assignments=900 held=450 max_per_expert=40 "
+        "mean_per_expert=28.125 gmm_rows=1024\n", None, 100.0 * 450 / 1024),
+    "sized_and_counting_rows": (
+        "Experts: assignments=900 held=110 max_per_expert=40 "
+        "mean_per_expert=6.875 group_tokens=95 pair_rows_moved=512 "
+        "pair_rows_all=2048 gmm_rows=512\n", 25.0, 100.0 * 110 / 512),
+    "no_pair_held_rows": (
+        "Experts: assignments=900 held=0 max_per_expert=0 "
+        "mean_per_expert=0.000 gmm_rows=0\n", None, None),
 }
 
 
 @pytest.mark.parametrize("family", sorted(EXPERTS_LINES))
-def test_the_experts_line_with_and_without_the_pair(family, tmp_path):
+def test_the_experts_line_with_and_without_the_rows(family, tmp_path):
     """The line as ``rnb_tpu.benchmark`` writes it for each family — the
-    older ones' byte for byte what they were — parses, and the reader
-    gives the share of pair rows moved, or None where the program counts
-    none (the parent's)."""
+    parent's byte for byte what they were — parses, and each reader
+    gives its share (the pair rows moved; the multiplied rows kept), or
+    None where the program counts none or the count is 0."""
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     import parse_utils
     from rnb_tpu.benchmark import BenchmarkResult, experts_counts
     from rnb_tpu.telemetry import (PAIR_ROW_COUNTS, aggregate_counts,
                                    aggregate_stage_counters)
-    line, share = EXPERTS_LINES[family]
+    line, moved_share, fill_share = EXPERTS_LINES[family]
     (tmp_path / "log-meta.txt").write_text("Tokens: valid=10 shipped=16\n"
                                            + line)
     meta = parse_utils.parse_meta(str(tmp_path))
     assert meta["experts_assignments"] == 900
-    assert ("experts_pair_rows_all" in meta) == (share is not None)
+    assert ("experts_pair_rows_all" in meta) == (moved_share is not None)
+    assert ("experts_gmm_rows" in meta) == ("gmm_rows" in line)
     fields = BenchmarkResult.__dataclass_fields__
-    assert fields["experts_pair_rows_moved"].default == 0
-    assert fields["experts_pair_rows_all"].default == 0
+    assert all(fields["experts_" + key].default == 0
+               for key in PAIR_ROW_COUNTS + ("gmm_rows",))
     result = types.SimpleNamespace(**{
-        key: meta[key] for key in meta if key.startswith("experts_pair_")})
-    reader = mm.load_layer_metric("pair_rows_moved_pct.bulk")
-    assert reader.read(types.SimpleNamespace(result=result)) == share
+        key: meta[key] for key in meta
+        if key.startswith(("experts_pair_", "experts_gmm_",
+                           "experts_held"))})
+    for reader, share in (("pair_rows_moved_pct.bulk", moved_share),
+                          ("gmm_row_fill_pct.bulk", fill_share)):
+        got = mm.load_layer_metric(reader).read(
+            types.SimpleNamespace(result=result))
+        assert got == (None if share is None else pytest.approx(share))
     # the writer gives that line back from the parsed numbers
     stats = {key: meta["experts_" + key]
              for key in ("assignments", "held", "max_per_expert",
-                         "mean_per_expert", "group_tokens")
+                         "mean_per_expert", "group_tokens", "gmm_rows")
              if "experts_" + key in meta}
     pair = {key: meta["experts_" + key] for key in PAIR_ROW_COUNTS
             if "experts_" + key in meta}
@@ -260,10 +284,14 @@ def test_the_experts_line_with_and_without_the_pair(family, tmp_path):
             "expert_served": np.full((2, 4), 5, np.int64)}
     if "group_tokens" in line:
         snap["group_tokens"] = 7
-    if share is not None:
+    if "gmm_rows" in line:
+        snap["gmm_rows"] = meta["experts_gmm_rows"]
+    if moved_share is not None:
         snap["pair_rows"] = np.array([pair[key] for key in PAIR_ROW_COUNTS])
     _, experts = aggregate_stage_counters([snap, snap])
     assert ("group_tokens" in experts) == ("group_tokens" in line)
+    assert experts.get("gmm_rows") == (
+        2 * meta["experts_gmm_rows"] if "gmm_rows" in line else None)
     summed = aggregate_counts([snap, snap], "pair_rows", PAIR_ROW_COUNTS)
     assert summed == ({key: 2 * count for key, count in pair.items()}
                       or None)
@@ -271,8 +299,8 @@ def test_the_experts_line_with_and_without_the_pair(family, tmp_path):
 
 def test_the_readers_entry_in_the_manifest():
     module = mm.load_layer_metric("pair_rows_moved_pct.bulk")
-    entry = mm.load()["per_layer"][-1]
-    assert entry["name"] == "pair_rows_moved_pct.bulk"
+    entry = next(e for e in mm.load()["per_layer"]
+                 if e["name"] == "pair_rows_moved_pct.bulk")
     assert entry["workloads"] == ["k-exaone.bulk"]
     assert mm.describe(module) == {k: entry[k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == "sparse experts" and module.BETTER == "lower"

@@ -89,7 +89,7 @@ def run_program(toy, prompts, rows, **kwargs):
 
     from rnb_tpu.models.nemotron_h import network
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, served, _ = jax.jit(
+    logits, chosen, served, *_ = jax.jit(
         lambda p, s, t, m: network.forward(
             toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True,
             **kwargs))(
@@ -182,7 +182,7 @@ def experts_both(toy, length, held, b_corr=None):
         params = dict(params, b_corr=jnp.asarray(b_corr, jnp.float32))
         weights["b_corr"] = jnp.asarray(b_corr, jnp.float32)
     ok = jnp.arange(rows * Q).reshape(rows, Q) < length
-    got, ids, counts = network.experts_mixer(
+    got, ids, counts, _ = network.experts_mixer(
         cfg, params, h.reshape(rows, Q, -1), ok,
         network.held_slots(cfg, held), interpret=True)
     with jax.default_matmul_precision("highest"):
@@ -316,7 +316,9 @@ def test_the_way_back_is_the_scatter_forms(toy, case, monkeypatch):
         monkeypatch.setattr(moe, "grouped_matmul", planted)
     args = (x, jnp.asarray(ids, jnp.int32), weights, jnp.asarray(ok),
             network.held_slots(cfg, HELD), block["up"], block["down"])
-    got, counts = moe.held_experts(*args, interpret=True, gate=gate)
+    got, counts, gmm_rows = moe.held_experts(*args, interpret=True,
+                                             gate=gate)
+    assert int(counts.sum()) <= int(gmm_rows)
     want, want_counts = held_experts_by_scatter(*args, gate=gate)
     got, want = np.asarray(got), np.asarray(want)
     assert np.array_equal(np.asarray(counts), np.asarray(want_counts))
@@ -700,9 +702,10 @@ def test_an_expert_block_moves_its_pairs_once_each_way(one_chip):
     def block(p, slots, x, token_ok):
         with jax.named_scope("experts"):
             h = network.rms_norm(x, p["norm"], cfg.eps, x.dtype)
-            out, ids, counts = network.experts_mixer(cfg, p, h, token_ok,
-                                                     slots)
-            return (x.astype(jnp.float32) + out).astype(x.dtype), ids, counts
+            out, ids, counts, gmm_rows = network.experts_mixer(
+                cfg, p, h, token_ok, slots)
+            return (x.astype(jnp.float32) + out).astype(x.dtype), ids, \
+                counts, gmm_rows
     text = jax.jit(block).lower(
         {name: of(spec.shape, getattr(jnp, spec.dtype))
          for name, spec in specs.items()},

@@ -148,8 +148,10 @@ def test_packed_prefill_matches_the_reference(toy, case):
     for prompt, mine in zip(prompts, chosen):
         free = np.asarray(run_reference(toy, prompt)["chosen"])
         assert (np.sort(free, -1) == np.sort(mine, -1)).all(-1).mean() > 0.9
-    # the counters: served pairs and sending tokens of the valid tokens
-    served, sent, tiles = counts
+    # the counters: served pairs and sending tokens of the valid
+    # tokens, the rows the first product multiplied for those pairs
+    served, sent, tiles, gmm_rows = counts
+    assert gmm_rows.shape == (4,) and (served.sum(-1) <= gmm_rows).all()
     valid = sum(DISPATCHES[case])
     assert served.shape == (4, 8) and sent.shape == (4,)
     assert served.sum() == sum(int(np.isin(c, HELD).sum()) for c in chosen)
@@ -400,7 +402,7 @@ def test_the_shares_add_up_to_the_uncut_layer(toy):
     for share in (HELD, OTHER):
         p = checkpoint.make_params(cfg, SEED, share, toy["device"],
                                    groups=["l1"])["l1"]
-        out, chose, counts, _ = network.experts_ffn(
+        out, chose, counts, _, _ = network.experts_ffn(
             cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
         assert int(counts.sum()) == int(np.isin(np.asarray(chose),
                                                 share).sum())
@@ -920,7 +922,10 @@ def test_the_older_families_lower_to_the_text_the_parent_gave(family):
     the StableHLO text PR 38's tree gave (its SHA-256, recorded from a
     ``git archive`` of that commit under ``tests/recorded``). A PR that
     moves one of them on purpose records the new text and shows those
-    cells on the chip."""
+    cells on the chip: PR 44 recorded the two expert families' again
+    (``forward`` returns ``gmm_rows``, the rows the first grouped
+    product multiplied; ``minicpm_sala``'s, a stack without experts, is
+    the text PR 38's tree gave)."""
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
         recorded = json.load(f)
