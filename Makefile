@@ -3,7 +3,7 @@
 
 PYTHON ?= python
 
-.PHONY: lint test native stamps trace ragged multichip chaos netchaos \
+.PHONY: lint test native stamps trace ragged multichip chaos \
 	dct benchdiff pages races shard
 
 # Static analysis: pipeline graph checker over every shipped config,
@@ -64,17 +64,6 @@ shard:
 # Health:/Deadline:/Hedge: invariants. Exit 0 = containment holds.
 chaos:
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/chaos_demo.py
-
-# Network chaos gate (README "Disaggregated ingest"): seeded network
-# faults against the cross-host netedge transport on the shipped
-# chaos arm — a mid-stream peer RST (recovered by reconnect+resend), a
-# silent 3 s wedge (the beat-staleness circuit must open BEFORE the
-# 2.5 s io timeout classifies it), and a fatal peer kill (refused
-# dials -> eviction -> local fallback) — asserting every request
-# terminates exactly once and parse_utils --check green including the
-# Net: wire-ledger footing. Exit 0 = containment holds.
-netchaos:
-	JAX_PLATFORMS=cpu $(PYTHON) scripts/netchaos_demo.py
 
 # DCT-domain ingest gate (README "DCT-domain ingest"): same-seed
 # yuv420-vs-dct A/B over a generated 112x112 MJPEG dataset, asserting

@@ -52,7 +52,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 from rnb_tpu.analysis.findings import (Finding, package_py_files,
                                        parse_py)
 from rnb_tpu.telemetry import (CONTENT_STAMPS, META_LINE_REGISTRY,
-                               STAMP_REGISTRY, TABLE_TRAILER_REGISTRY,
+                               STAGE_COUNTERS, STAMP_REGISTRY,
+                               TABLE_TRAILER_REGISTRY,
                                TRACE_EVENT_REGISTRY, TRANSIENT_STAMPS)
 
 #: core TimeCard attributes (assignments to these are state, not
@@ -243,8 +244,6 @@ COUNTER_LINE_PREFIXES = {"Faults:": "", "Cache:": "cache_",
                          "Health:": "health_",
                          "Deadline:": "deadline_",
                          "Hedge:": "hedges_",
-                         "Net:": "net_",
-                         "Net errors:": "net_err_",
                          "Locks:": "locks_"}
 
 #: verbatim-named counter fields (prefix "") the reverse RNB-T006
@@ -355,7 +354,10 @@ def check_meta_lines(benchmark_path: str, parse_utils_src: str,
                 "RNB-T004", rel, line, prefix,
                 "log-meta line %r is not declared in "
                 "telemetry.META_LINE_REGISTRY" % prefix))
-    produced = {p for _, _, p in written}
+    # the stages' counter lines are written whole by
+    # telemetry.stage_counter_report, from its table
+    produced = {p for _, _, p in written} \
+        | {row.line for row in STAGE_COUNTERS}
     literals = _code_literals(parse_utils_src)
     for spec in registry:
         if spec.pattern not in produced:
@@ -469,7 +471,6 @@ def check_benchmark_result(benchmark_path: str, root: str = "."
                 or field.startswith("health_") \
                 or field.startswith("deadline_") \
                 or field.startswith("hedges_") \
-                or field.startswith("net_") \
                 or field.startswith("locks_"):
             if field not in mapped:
                 findings.append(Finding(

@@ -48,8 +48,8 @@ def enable_compilation_cache() -> str:
     (a chip host that keeps one between runs) must be the one used.
     Otherwise it is ``<checkout>/.jax_cache`` — a fixed path, because
     the path is part of the cache key and a directory that moves never
-    hits. The one function every process of a job calls: the launcher,
-    chip_smoke.py and the netedge peer.
+    hits. The one function every process of a job calls: the launcher
+    and chip_smoke.py.
 
     An executable's metadata is part of its key here. JAX leaves it
     out by default, and a program then gets back whatever executable
@@ -176,52 +176,28 @@ class BenchmarkResult:
     pad_rows: int = 0
     total_rows: int = 0
     pad_emissions: int = 0
-    #: token accounting of stages whose rows are blocks of tokens
-    #: (rnb_tpu.models.token_stages): valid tokens / tokens shipped
-    #: (rows x tokens a row) over every dispatch served; 0 elsewhere
+    #: a stage's own counters (``stage_counters()``; the token
+    #: families' final stage, rnb_tpu.models.token_stages), summed over
+    #: the run's stage instances: rnb_tpu.telemetry.STAGE_COUNTERS says
+    #: what each counts and which log-meta line and key carries it
+    #: (Tokens: / Experts: / Sparse: / Attention:); 0 where no stage
+    #: counts it
     tokens_valid: int = 0
     tokens_shipped: int = 0
-    #: sparse-expert accounting of a stage that holds a share of each
-    #: layer's experts: (valid token, chosen expert) pairs routed /
-    #: those whose expert is held here / the most and the mean that one
-    #: held expert of one layer served; 0 without such a stage
     experts_assignments: int = 0
     experts_held: int = 0
     experts_max_per_expert: int = 0
     experts_mean_per_expert: float = 0.0
-    #: where the router chooses among groups of experts: the valid
-    #: tokens, summed over the expert layers, that sent the held
-    #: experts anything (``group_tokens=`` on the Experts: line)
     experts_group_tokens: int = 0
-    #: where the stack sizes the held experts' pair buffers by the share
-    #: held (rnb_tpu.ops.moe.pair_capacity): the pair rows the buffers
-    #: held / the tokens x k of those layers, over every expert layer of
-    #: every dispatch (``pair_rows_*=`` on the Experts: line)
     experts_pair_rows_moved: int = 0
     experts_pair_rows_all: int = 0
-    #: the rows the first grouped product's grid steps multiplied for
-    #: the ``experts_held`` pairs (rnb_tpu.ops.moe.gmm_visits), over
-    #: every expert layer of every dispatch of a stack that counts them
-    #: (``gmm_rows=`` on the Experts: line)
     experts_gmm_rows: int = 0
-    #: block-selected attention accounting of a stage whose stack
-    #: chooses key blocks (rnb_tpu.ops.blocksparse), over (valid query,
-    #: key-value head) pairs of every sparse layer: the pairs / those of
-    #: requests that select / the causal keys those could read / the
-    #: keys of the blocks they chose; 0 without such a stage
     sparse_queries: int = 0
     sparse_selecting: int = 0
     sparse_causal_keys: int = 0
     sparse_chosen_keys: int = 0
-    #: packed flash attention accounting of a stage whose stack runs
-    #: it (rnb_tpu.ops.segattn), over every attention layer of every
-    #: dispatch: the tiles the dispatch's block table let the kernel
-    #: run / the tiles on or under the diagonal; 0 without such a stage
     attention_tiles_visited: int = 0
     attention_tiles_causal: int = 0
-    #: the same pair of the stack's layers with a window, at their own
-    #: tile sizes (the pair above is then the full layers' alone); 0
-    #: without such layers
     window_tiles_visited: int = 0
     window_tiles_causal: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
@@ -325,27 +301,6 @@ class BenchmarkResult:
     hedges_won: int = 0
     hedges_lost: int = 0
     hedges_wasted_ms: int = 0
-    # netedge transport ledger (root 'netedge' key; rnb_tpu.netedge)
-    net_frames_sent: int = 0
-    net_frames_acked: int = 0
-    net_resent_pending: int = 0
-    net_resends: int = 0
-    net_beats: int = 0
-    net_reconnects: int = 0
-    net_remote: int = 0
-    net_local: int = 0
-    net_dedup_drops: int = 0
-    net_dup_arrivals: int = 0
-    net_wire_bytes: int = 0
-    net_frame_bytes: int = 0
-    net_window_stranded: int = 0
-    net_open_before_timeout: int = 0
-    net_err_total: int = 0
-    net_err_refused: int = 0
-    net_err_reset: int = 0
-    net_err_timeout: int = 0
-    net_err_partial_frame: int = 0
-    net_err_corrupt: int = 0
     #: lock-order witness ledger (rnb_tpu.lockwitness, root `lint`
     #: config key with lock_witness true): witnessed locks, total
     #: acquisitions, distinct acquisition-order edges, discipline
@@ -357,26 +312,6 @@ class BenchmarkResult:
     locks_acquires: int = 0
     locks_edges: int = 0
     locks_violations: int = 0
-
-
-def experts_counts(expert_stats, pair_row_stats=None) -> str:
-    """What the ``Experts:`` log-meta line says of
-    ``aggregate_stage_counters``' expert stats; ``group_tokens=`` where
-    a stage counts it, the ``pair_rows_`` pair where a stack sizes its
-    held experts' buffers, ``gmm_rows=`` where it counts the grouped
-    product's rows (a stack that does none keeps the line it had)."""
-    counts = ("assignments=%d held=%d max_per_expert=%d "
-              "mean_per_expert=%.3f"
-              % (expert_stats["assignments"], expert_stats["held"],
-                 expert_stats["max_per_expert"],
-                 expert_stats["mean_per_expert"]))
-    if "group_tokens" in expert_stats:
-        counts += " group_tokens=%d" % expert_stats["group_tokens"]
-    for key, count in (pair_row_stats or {}).items():
-        counts += " %s=%d" % (key, count)
-    if "gmm_rows" in expert_stats:
-        counts += " gmm_rows=%d" % expert_stats["gmm_rows"]
-    return counts
 
 
 def run_benchmark(config_path: str,
@@ -557,10 +492,7 @@ def run_benchmark(config_path: str,
                                       health_settings)
             for step_idx, step in enumerate(config.steps)
             if step.replica_queues}
-        if not boards_by_step and not (config.netedge
-                                       or {}).get("enabled"):
-            # a netedge run has no replica lanes but DOES have a lane
-            # to circuit-break — the remote peer's board
+        if not boards_by_step:
             print("[rnb-tpu] WARNING: health is enabled but no step "
                   "declares replica lanes — there is nothing to "
                   "circuit-break and no Health: telemetry will be "
@@ -592,21 +524,6 @@ def run_benchmark(config_path: str,
                 "but the config has no enabled root 'health' key (or "
                 "no replica lanes) — lane deaths need the health "
                 "layer's eviction/drain machinery to stay contained")
-    # cross-host ingest edge (rnb_tpu.netedge, root 'netedge' key):
-    # a peer process serves step 0 over the wire with a local
-    # fallback path behind a dedicated LaneHealthBoard
-    from rnb_tpu.netedge import (NET_LANE, NetEdgeClient,
-                                 NetEdgeSettings, NetStats, spawn_peer)
-    netedge_settings = NetEdgeSettings.from_config(config.netedge)
-    if fault_plan is not None and fault_plan.has_net_faults() \
-            and netedge_settings is None:
-        # same loud-typo posture as LANE_KINDS without replicas: a
-        # net fault with no edge never fires, and the chaos run would
-        # read 'containment verified' with zero injections
-        raise ValueError(
-            "the fault plan injects net_* faults but the config has "
-            "no enabled root 'netedge' key — there is no network "
-            "edge to address")
     if fault_plan is not None and print_progress:
         print("[rnb-tpu] fault plan active: %s" % fault_plan.describe())
 
@@ -627,41 +544,6 @@ def run_benchmark(config_path: str,
         effective_queue_size = (num_videos * seg_factor + num_runners
                                 + max(NUM_EXIT_MARKERS, num_runners) + 1)
     fabric = ChannelFabric(config, effective_queue_size)
-    # netedge interposition: the dispatcher becomes the filename
-    # queue's sole consumer; step-0 executors read this local queue
-    # instead (same capacity, same item/marker protocol), and the
-    # receiver injects remote emissions straight into step 0's first
-    # out-queue as DirectPayload items
-    netedge_client = None
-    netedge_stats = None
-    netedge_board = None
-    netedge_peer = None
-    netedge_local_q = None
-    if netedge_settings is not None:
-        if netedge_settings.spawn:
-            netedge_peer, peer_addr = spawn_peer(
-                config_path, netedge_settings, seed=seed or 0)
-            netedge_settings.connect = peer_addr
-        netedge_board = LaneHealthBoard(
-            (NET_LANE,), health_settings or HealthSettings())
-        netedge_stats = NetStats()
-        netedge_local_q = queue.Queue(maxsize=effective_queue_size)
-        netedge_client = NetEdgeClient(
-            netedge_settings,
-            board=netedge_board,
-            stats=netedge_stats,
-            fault_plan=fault_plan,
-            fault_stats=fault_stats,
-            deadline_stats=deadline_stats,
-            counter=counter,
-            num_videos=num_videos,
-            termination=termination,
-            filename_queue=fabric.get_filename_queue(),
-            local_queue=netedge_local_q,
-            inject_queue=fabric.get_queues(0, 0)[1][0],
-            num_markers=fabric.filename_num_markers,
-            seed=seed or 0)
-
     # unified pipeline tracing (rnb_tpu.trace, root 'trace' config
     # key): one per-job collector every thread role records spans
     # into, plus a low-rate background sampler over the inter-stage
@@ -721,11 +603,6 @@ def run_benchmark(config_path: str,
             for instance_idx, device in enumerate(group.devices):
                 in_queue, out_queues = fabric.get_queues(step_idx,
                                                          group_idx)
-                if netedge_local_q is not None and step_idx == 0:
-                    # netedge: the dispatcher owns the filename
-                    # queue; local step-0 executors serve the
-                    # fallback path off the interposed local queue
-                    in_queue = netedge_local_q
                 ctx = RunnerContext(
                     in_queue=in_queue,
                     out_queues=out_queues,
@@ -814,10 +691,6 @@ def run_benchmark(config_path: str,
     for t in threads:
         t.start()
 
-    if netedge_client is not None:
-        # transport threads, not stages: they never join the barriers
-        netedge_client.start()
-
     import resource
 
     from rnb_tpu.decode.native import DecodePool
@@ -852,24 +725,6 @@ def run_benchmark(config_path: str,
 
     for t in threads:
         t.join(timeout=60)
-
-    if netedge_client is not None:
-        # after the stage joins: the window is drained (or rerouted),
-        # so teardown counters are final. Remote cards carry the
-        # peer loader's pad_rows stamps but the peer's PadCounter
-        # dies with the peer — the receiver's re-count of shipped
-        # emissions keeps the Padding: ledger covering them (--check
-        # holds per-request trailer pads <= the meta counter)
-        netedge_client.stop()
-        netedge_pads = netedge_client.pad_snapshot()
-        if netedge_pads["emissions"]:
-            pad_sink.append(netedge_pads)
-    if netedge_peer is not None:
-        netedge_peer.terminate()
-        try:
-            netedge_peer.wait(timeout=10)
-        except Exception:
-            netedge_peer.kill()
 
     # trace export: every thread is drained, so the event set is
     # final; clear the module hook BEFORE exporting so a later run in
@@ -939,22 +794,11 @@ def run_benchmark(config_path: str,
                         "cache_hit_rows"):
                 ragged_stats[key] += int(snap.get(key, 0))
 
-    token_stats = expert_stats = sparse_stats = attention_stats = None
-    window_stats = pair_row_stats = None
-    if stage_counter_sink:
-        from rnb_tpu.telemetry import (ATTENTION_COUNTS, PAIR_ROW_COUNTS,
-                                       SPARSE_COUNTS, aggregate_counts,
-                                       aggregate_stage_counters)
-        token_stats, expert_stats = aggregate_stage_counters(
-            stage_counter_sink)
-        sparse_stats = aggregate_counts(stage_counter_sink, "sparse",
-                                        SPARSE_COUNTS)
-        attention_stats = aggregate_counts(stage_counter_sink,
-                                           "attn_tiles", ATTENTION_COUNTS)
-        window_stats = aggregate_counts(stage_counter_sink,
-                                        "window_tiles", ATTENTION_COUNTS)
-        pair_row_stats = aggregate_counts(stage_counter_sink,
-                                          "pair_rows", PAIR_ROW_COUNTS)
+    # the stages' own counters (tokens, experts, attention tiles): the
+    # lines to write and the result's fields, by telemetry's table
+    from rnb_tpu.telemetry import stage_counter_report
+    counter_lines, counter_fields = stage_counter_report(
+        stage_counter_sink)
 
     # intra-stage shard accounting (rnb_tpu.parallel.shardplan):
     # declared-degree stages snapshot their merge-collective counters
@@ -997,16 +841,12 @@ def run_benchmark(config_path: str,
     # self-healing accounting (rnb_tpu.health): boards/governors are
     # shared objects, stable once every thread joined above
     health_stats = None
-    if boards_by_step or netedge_board is not None:
+    if boards_by_step:
         from rnb_tpu.health import aggregate_board_snapshots
-        snapshots = [b.snapshot() for b in boards_by_step.values()]
-        if netedge_board is not None:
-            snapshots.append(netedge_board.snapshot())
-        health_stats = aggregate_board_snapshots(snapshots)
+        health_stats = aggregate_board_snapshots(
+            [b.snapshot() for b in boards_by_step.values()])
     deadline_snap = (deadline_stats.snapshot()
                      if deadline_stats is not None else None)
-    net_snap = (netedge_stats.snapshot()
-                if netedge_stats is not None else None)
     # final witness ledger: every pipeline thread joined above, so the
     # edge set and violation list are settled (config-armed runs only
     # — an externally enabled witness, e.g. the test harness, keeps
@@ -1146,21 +986,8 @@ def run_benchmark(config_path: str,
                     "pad_emissions=%d\n"
                     % (pad_stats["pad_rows"], pad_stats["total_rows"],
                        pad_stats["emissions"]))
-        if token_stats is not None:
-            f.write("Tokens: valid=%d shipped=%d\n"
-                    % (token_stats["valid"], token_stats["shipped"]))
-        if expert_stats is not None:
-            f.write("Experts: %s\n"
-                    % experts_counts(expert_stats, pair_row_stats))
-        if sparse_stats is not None:
-            f.write("Sparse: %s\n" % " ".join(
-                "%s=%d" % (key, sparse_stats[key]) for key in SPARSE_COUNTS))
-        if attention_stats is not None:
-            f.write("Attention: %s\n" % " ".join(
-                ["%s=%d" % (key, attention_stats[key])
-                 for key in ATTENTION_COUNTS]
-                + ["window_%s=%d" % (key, window_stats[key])
-                   for key in ATTENTION_COUNTS if window_stats]))
+        for line in counter_lines:
+            f.write(line + "\n")
         if ragged_stats is not None:
             # only ragged-enabled runs carry the line, keeping bucketed
             # logs byte-stable with the earlier schema
@@ -1268,32 +1095,6 @@ def run_benchmark(config_path: str,
             # per request (parse_utils --check asserts it)
             f.write("Phases: %s\n"
                     % json.dumps(phases_stats, sort_keys=True))
-        if net_snap is not None:
-            # the edge's exactly-once ledger, cross-footed by --check:
-            # frames_sent == frames_acked + resent_pending, dedup
-            # drops == dup arrivals, zero stranded on target-reached
-            f.write("Net: frames_sent=%d frames_acked=%d "
-                    "resent_pending=%d resends=%d beats=%d "
-                    "reconnects=%d remote=%d local=%d dedup_drops=%d "
-                    "dup_arrivals=%d wire_bytes=%d frame_bytes=%d "
-                    "window_stranded=%d open_before_timeout=%d\n"
-                    % (net_snap["frames_sent"],
-                       net_snap["frames_acked"],
-                       net_snap["resent_pending"],
-                       net_snap["resends"], net_snap["beats"],
-                       net_snap["reconnects"], net_snap["remote"],
-                       net_snap["local"], net_snap["dedup_drops"],
-                       net_snap["dup_arrivals"],
-                       net_snap["wire_bytes"],
-                       net_snap["frame_bytes"],
-                       net_snap["window_stranded"],
-                       net_snap["open_before_timeout"]))
-            f.write("Net errors: total=%d refused=%d reset=%d "
-                    "timeout=%d partial_frame=%d corrupt=%d\n"
-                    % (net_snap["err_total"], net_snap["err_refused"],
-                       net_snap["err_reset"], net_snap["err_timeout"],
-                       net_snap["err_partial_frame"],
-                       net_snap["err_corrupt"]))
         if lock_snap is not None:
             # witness-armed runs only; --check holds violations to
             # zero, the Lock edges: detail to these counts, and every
@@ -1403,14 +1204,6 @@ def run_benchmark(config_path: str,
               "original, %d ms of loser service wasted"
               % (hedge_stats["fired"], hedge_stats["won"],
                  hedge_stats["lost"], hedge_stats["wasted_ms"]))
-    if net_snap is not None and print_progress:
-        print("Net: %d frame(s) sent / %d acked, %d resend(s), "
-              "%d reconnect(s), %d remote / %d local route(s), "
-              "%d error(s)"
-              % (net_snap["frames_sent"], net_snap["frames_acked"],
-                 net_snap["resends"], net_snap["reconnects"],
-                 net_snap["remote"], net_snap["local"],
-                 net_snap["err_total"]))
     if lock_snap is not None and print_progress:
         print("Locks: %d witnessed lock(s), %d acquisition(s), "
               "%d order edge(s), %d violation(s)"
@@ -1507,27 +1300,7 @@ def run_benchmark(config_path: str,
         pad_rows=pad_stats["pad_rows"] if pad_stats else 0,
         total_rows=pad_stats["total_rows"] if pad_stats else 0,
         pad_emissions=pad_stats["emissions"] if pad_stats else 0,
-        tokens_valid=token_stats["valid"] if token_stats else 0,
-        tokens_shipped=token_stats["shipped"] if token_stats else 0,
-        experts_assignments=(expert_stats["assignments"]
-                             if expert_stats else 0),
-        experts_held=expert_stats["held"] if expert_stats else 0,
-        experts_max_per_expert=(expert_stats["max_per_expert"]
-                                if expert_stats else 0),
-        experts_mean_per_expert=(expert_stats["mean_per_expert"]
-                                 if expert_stats else 0.0),
-        experts_group_tokens=(expert_stats.get("group_tokens", 0)
-                              if expert_stats else 0),
-        experts_gmm_rows=(expert_stats.get("gmm_rows", 0)
-                          if expert_stats else 0),
-        **{"sparse_" + key: count
-           for key, count in (sparse_stats or {}).items()},
-        **{"attention_" + key: count
-           for key, count in (attention_stats or {}).items()},
-        **{"window_" + key: count
-           for key, count in (window_stats or {}).items()},
-        **{"experts_" + key: count
-           for key, count in (pair_row_stats or {}).items()},
+        **counter_fields,
         ragged_pool_rows=(ragged_stats["pool_rows"]
                           if ragged_stats else 0),
         ragged_emissions=(ragged_stats["emissions"]
@@ -1585,35 +1358,11 @@ def run_benchmark(config_path: str,
         hedges_lost=hedge_stats["lost"] if hedge_stats else 0,
         hedges_wasted_ms=(hedge_stats["wasted_ms"]
                           if hedge_stats else 0),
-        net_frames_sent=(net_snap["frames_sent"] if net_snap else 0),
-        net_frames_acked=(net_snap["frames_acked"] if net_snap else 0),
-        net_resent_pending=(net_snap["resent_pending"]
-                            if net_snap else 0),
-        net_resends=(net_snap["resends"] if net_snap else 0),
-        net_beats=(net_snap["beats"] if net_snap else 0),
-        net_reconnects=(net_snap["reconnects"] if net_snap else 0),
-        net_remote=(net_snap["remote"] if net_snap else 0),
-        net_local=(net_snap["local"] if net_snap else 0),
-        net_dedup_drops=(net_snap["dedup_drops"] if net_snap else 0),
-        net_dup_arrivals=(net_snap["dup_arrivals"] if net_snap else 0),
-        net_wire_bytes=(net_snap["wire_bytes"] if net_snap else 0),
-        net_frame_bytes=(net_snap["frame_bytes"] if net_snap else 0),
-        net_window_stranded=(net_snap["window_stranded"]
-                             if net_snap else 0),
-        net_open_before_timeout=(net_snap["open_before_timeout"]
-                                 if net_snap else 0),
         locks_tracked=(lock_snap["locks"] if lock_snap else 0),
         locks_acquires=(lock_snap["acquires"] if lock_snap else 0),
         locks_edges=(len(lock_snap["edges"]) if lock_snap else 0),
         locks_violations=(len(lock_snap["violations"])
                           if lock_snap else 0),
-        net_err_total=(net_snap["err_total"] if net_snap else 0),
-        net_err_refused=(net_snap["err_refused"] if net_snap else 0),
-        net_err_reset=(net_snap["err_reset"] if net_snap else 0),
-        net_err_timeout=(net_snap["err_timeout"] if net_snap else 0),
-        net_err_partial_frame=(net_snap["err_partial_frame"]
-                               if net_snap else 0),
-        net_err_corrupt=(net_snap["err_corrupt"] if net_snap else 0),
     )
 
 

@@ -42,7 +42,7 @@ ROOT_KEYWORDS = [
     "video_path_iterator", "pipeline", "overload_policy",
     "fault_containment", "fault_plan", "popularity", "autotune",
     "trace", "ragged", "pager", "handoff", "placement", "health",
-    "deadline", "netedge", "lint",
+    "deadline", "lint",
     "_comment",
 ]
 
@@ -78,11 +78,6 @@ DEADLINE_KEYWORDS = ["enabled", "budget_ms"]
 #: keys a root 'lint' object may carry (runtime arms of the
 #: rnb-lint analyzers; today just the RNB-C lock-order witness)
 LINT_KEYWORDS = ["lock_witness"]
-
-#: keys a root 'netedge' object may carry (rnb_tpu.netedge)
-NETEDGE_KEYWORDS = ["enabled", "listen", "connect", "beat_ms",
-                    "io_timeout_ms", "max_retries", "backoff_ms",
-                    "resend_window", "spawn"]
 
 #: Ring slots per stage instance when a step omits 'num_shared_tensors'
 #: (reference control.py:8). Lives here (not control.py) so validation
@@ -248,17 +243,6 @@ class PipelineConfig:
     #: expired requests (shed reason deadline_expired) instead of
     #: computing doomed work — rnb_tpu.health
     deadline: Optional[Dict[str, Any]] = None
-    #: validated cross-host ingest-edge spec ({"enabled": ..,
-    #: "listen": .., "connect": .., "beat_ms": ..,
-    #: "io_timeout_ms": .., "max_retries": .., "backoff_ms": ..,
-    #: "resend_window": .., "spawn": ..}), or None; when enabled the
-    #: launcher interposes the rnb_tpu.netedge transport between the
-    #: client and step 0: requests route over a checksummed TCP frame
-    #: protocol to an ingest peer process (spawn: true launches it)
-    #: with a local fallback path behind a LaneHealthBoard, and
-    #: log-meta gains the Net:/Net errors: lines. Absent => in-process
-    #: queues, byte-stable logs.
-    netedge: Optional[Dict[str, Any]] = None
     #: validated lint-runtime spec ({"lock_witness": ..}), or None;
     #: with lock_witness true the launcher enables the
     #: rnb_tpu.lockwitness lock-order witness BEFORE pipeline
@@ -788,47 +772,6 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                 "(defaults to autotune.slo_ms when autotune is "
                 "configured), got %r" % (budget,))
 
-    netedge = raw.get("netedge")
-    if netedge is not None:
-        _expect(isinstance(netedge, dict),
-                "'netedge' must be an object")
-        unknown_ne = sorted(set(netedge) - set(NETEDGE_KEYWORDS))
-        _expect(not unknown_ne,
-                "'netedge' has unknown key(s) %s — keys are %s"
-                % (unknown_ne, NETEDGE_KEYWORDS))
-        _expect(isinstance(netedge.get("enabled", True), bool),
-                "'netedge.enabled' must be a boolean")
-        _expect(isinstance(netedge.get("spawn", False), bool),
-                "'netedge.spawn' must be a boolean")
-        for key in ("listen", "connect"):
-            val = netedge.get(key)
-            _expect(val is None or isinstance(val, str),
-                    "'netedge.%s' must be a host:port string, got %r"
-                    % (key, val))
-        for key in ("beat_ms", "io_timeout_ms", "backoff_ms"):
-            val = netedge.get(key)
-            _expect(val is None
-                    or (isinstance(val, (int, float))
-                        and not isinstance(val, bool) and val >= 0),
-                    "'netedge.%s' must be a non-negative number, "
-                    "got %r" % (key, val))
-        for key in ("max_retries", "resend_window"):
-            val = netedge.get(key)
-            _expect(val is None
-                    or (isinstance(val, int)
-                        and not isinstance(val, bool) and val >= 1),
-                    "'netedge.%s' must be a positive integer, got %r"
-                    % (key, val))
-        if netedge.get("enabled", True):
-            # the same defaulting the runtime applies — a timeout
-            # shorter than the heartbeat, or neither connect nor
-            # spawn, must fail at parse time, not at launch
-            try:
-                from rnb_tpu.netedge import NetEdgeSettings
-                NetEdgeSettings.from_config(netedge)
-            except ValueError as e:
-                raise ConfigError("invalid 'netedge': %s" % e) from e
-
     lint = raw.get("lint")
     if lint is not None:
         _expect(isinstance(lint, dict), "'lint' must be an object")
@@ -1039,34 +982,6 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                                     step_idx),
                                 hedge_ms=hedge_ms))
 
-    if netedge is not None and netedge.get("enabled", True):
-        # the remote peer serves step 0 and the receiver injects its
-        # outputs into step 0's out-queue — both need a downstream
-        # step to exist and the local/remote emission paths to be
-        # interchangeable; features that break that symmetry are
-        # rejected loudly rather than silently mis-accounted
-        _expect(len(steps) >= 2,
-                "'netedge' needs at least 2 pipeline steps: the peer "
-                "serves step 0 remotely and injects into step 1's "
-                "input edge")
-        _expect(steps[0].num_segments == 1,
-                "'netedge' cannot serve a segmented step 0: the "
-                "remote path bypasses the runner's segment split")
-        _expect(not (isinstance(trace, dict)
-                     and trace.get("enabled", True)),
-                "'netedge' cannot be combined with 'trace': the peer "
-                "process has no Tracer, so the job's trace.json would "
-                "lack the remote requests' spans")
-        _expect(not (isinstance(ragged, dict)
-                     and ragged.get("enabled", True)),
-                "'netedge' cannot be combined with 'ragged': the "
-                "peer's row-pool accounting dies with the peer")
-        _expect(all(s.replica_queues is None for s in steps),
-                "'netedge' cannot be combined with replica-expanded "
-                "steps (or hedging/apply-mode placement): injected "
-                "remote emissions bypass the replica in-flight depth "
-                "accounting")
-
     return PipelineConfig(video_path_iterator=raw["video_path_iterator"],
                           steps=steps, raw=raw,
                           overload_policy=overload_policy,
@@ -1080,6 +995,5 @@ def parse_config(raw: Dict[str, Any]) -> PipelineConfig:
                           placement=placement,
                           health=health,
                           deadline=deadline,
-                          netedge=netedge,
                           lint=lint,
                           trace=trace)

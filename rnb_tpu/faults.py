@@ -72,45 +72,6 @@ class InjectedPermanentError(PermanentError):
     """Raised by a :class:`FaultPlan` 'permanent' fault."""
 
 
-class NetRefusedError(TransientError):
-    """The peer actively refused the dial (nothing listening yet, or
-    the listener's backlog is gone). Transient: the peer may come up —
-    the sender retries the dial under its capped backoff schedule."""
-    fault_reason = "net_refused"
-
-
-class NetResetError(TransientError):
-    """The established connection died mid-stream (RST / broken pipe /
-    EOF inside a frame boundary). Transient: the sender reconnects and
-    resends every non-terminal window entry; the receiver's dedup
-    ledger keeps the resend from double-dispatching."""
-    fault_reason = "net_reset"
-
-
-class NetTimeoutError(TransientError):
-    """A configured socket timeout (``netedge.io_timeout_ms``) expired
-    waiting on the peer — wedged, not dead. Transient: the sender
-    resends the oldest unacked frame / reconnects; the health board
-    has usually opened the circuit from beat staleness well before
-    this fires (that ordering is asserted by ``make netchaos``)."""
-    fault_reason = "net_timeout"
-
-
-class NetPartialFrameError(TransientError):
-    """The stream ended inside a length-prefixed frame (short read).
-    Transient: framing is lost so the connection is torn down and
-    re-dialed; unacked frames are resent on the fresh connection."""
-    fault_reason = "net_partial_frame"
-
-
-class NetCorruptFrameError(PermanentError):
-    """A frame arrived complete but its CRC32 did not match. Permanent
-    for the REQUEST it carried: retrying cannot un-corrupt recorded
-    bytes, so the request is dead-lettered with reason ``net_corrupt``
-    — but framing stayed in sync, so the connection survives."""
-    fault_reason = "net_corrupt"
-
-
 class LaneDeathError(Exception):
     """A replica lane's executor is dead (chaos 'replica_crash' /
     'replica_stall' fault kinds).
@@ -181,26 +142,13 @@ def fault_reason(exc: BaseException) -> str:
     return type(exc).__name__.lower()
 
 
-#: kinds that address the cross-host ingest EDGE (rnb_tpu.netedge)
-#: instead of a pipeline step: net_refused fires at the sender's dial,
-#: the other four fire on the peer while serving a matched request.
-#: net_corrupt is the one permanent member (a recorded-bytes verdict);
-#: the rest are transient per the PR 1 taxonomy.
-NET_KINDS = ("net_refused", "net_reset", "net_timeout",
-             "net_partial_frame", "net_corrupt")
-
 VALID_KINDS = ("transient", "permanent", "latency", "stall",
-               "replica_crash", "replica_stall") + NET_KINDS
+               "replica_crash", "replica_stall")
 
 #: kinds that kill a replica LANE rather than fail a request — they
 #: carry an optional 'lane' (queue index) address and fire exactly once
 #: per matching (step, lane) executor
 LANE_KINDS = ("replica_crash", "replica_stall")
-
-#: the one edge-addressed site key used in the deterministic draw for
-#: NET_KINDS faults (there is exactly one edge, and it is not a step)
-NET_SITE = -1
-
 
 def validate_plan(spec: Any) -> Dict[str, Any]:
     """Validate a fault-plan dict; returns it. Raises ValueError with a
@@ -240,34 +188,7 @@ def validate_plan(spec: Any) -> Dict[str, Any]:
         if prob is not None and not (isinstance(prob, (int, float))
                                      and 0.0 <= prob <= 1.0):
             raise ValueError("%s: 'probability' must be in [0, 1]" % where)
-        fatal = f.get("fatal")
-        if fatal is not None:
-            if kind != "net_reset":
-                raise ValueError("%s: 'fatal' only applies to net_reset "
-                                 "faults (it kills the peer process)"
-                                 % where)
-            if not isinstance(fatal, bool):
-                raise ValueError("%s: 'fatal' must be a boolean" % where)
-        if kind in NET_KINDS:
-            if step is not None:
-                raise ValueError("%s: net faults address the edge; "
-                                 "'step' is not allowed" % where)
-            if f.get("lane") is not None:
-                raise ValueError("%s: net faults address the edge; "
-                                 "'lane' is not allowed" % where)
-            if "times" in f:
-                raise ValueError("%s: 'times' only applies to "
-                                 "transient/permanent faults" % where)
-            if kind == "net_timeout":
-                ms = f.get("ms")
-                if not (isinstance(ms, (int, float)) and ms >= 0):
-                    raise ValueError("%s: net_timeout faults need a "
-                                     "non-negative 'ms' (peer wedge "
-                                     "duration)" % where)
-            elif "ms" in f:
-                raise ValueError("%s: among net faults only net_timeout "
-                                 "takes 'ms'" % where)
-        elif kind in ("latency", "stall", "replica_stall"):
+        if kind in ("latency", "stall", "replica_stall"):
             ms = f.get("ms")
             if not (isinstance(ms, (int, float)) and ms >= 0):
                 raise ValueError("%s: %r faults need a non-negative 'ms'"
@@ -309,7 +230,7 @@ def validate_plan(spec: Any) -> Dict[str, Any]:
         if reason is not None and not isinstance(reason, str):
             raise ValueError("%s: 'reason' must be a string" % where)
         unknown = set(f) - {"kind", "step", "request_ids", "probability",
-                            "ms", "times", "reason", "lane", "fatal"}
+                            "ms", "times", "reason", "lane"}
         if unknown:
             raise ValueError("%s has unknown keys %s"
                              % (where, sorted(unknown)))
@@ -503,36 +424,6 @@ class FaultPlan:
             if reason:
                 exc.fault_reason = reason
             raise exc
-
-    def has_net_faults(self) -> bool:
-        """True if any fault addresses the network edge — the launcher
-        rejects such a plan when ``netedge`` is off, the same loud-typo
-        posture as LANE_KINDS without replicas (the chaos run would
-        otherwise read 'containment verified' with zero injections)."""
-        return any(f["kind"] in NET_KINDS for f in self.faults)
-
-    def net_fault(self, kind: str, request_id: int
-                  ) -> Optional[tuple]:
-        """First matching edge fault of ``kind`` for one request id, as
-        ``(fault_idx, fault_dict)``, or None.
-
-        Net faults draw at the edge site (:data:`NET_SITE`), not a
-        step. The plan stays stateless (same thread-safety contract as
-        :meth:`fire`), so the CALLER keeps a fired ledger keyed by the
-        returned ``fault_idx`` + request id — a resend of the same
-        request must re-match here without re-firing there, otherwise
-        a net_reset would reset every resend of its victim forever.
-        ``net_refused`` is consulted at dial time where no request is
-        in scope: the sender passes its dial counter as the id, which
-        keeps the draw deterministic per attempt.
-        """
-        request_ids = self._as_ids(request_id)
-        for idx, f in enumerate(self.faults):
-            if f["kind"] != kind:
-                continue
-            if self._matches(idx, f, NET_SITE, request_ids) is not None:
-                return idx, f
-        return None
 
     def describe(self) -> str:
         """One-line summary for --check output and logs."""

@@ -430,10 +430,10 @@ class LaneHealthBoard:
         between the caller's check and its note — which would count a
         ``routes_after_open`` violation against a dispatch that was
         decided while the lane was still routable. A caller with a
-        single candidate lane and a fallback path (the netedge
-        dispatcher) uses this instead: the decision and the
-        accounting share one lock acquisition, so a route claimed
-        here is by construction never a containment violation.
+        single candidate lane and a fallback path uses this instead:
+        the decision and the accounting share one lock acquisition,
+        so a route claimed here is by construction never a
+        containment violation.
         Healthy/suspect route; a half-open lane grants exactly one
         probe (the claimer must dispatch it); open/evicted refuse.
         """

@@ -13,46 +13,20 @@ last_idx, interpret=...) -> (logits, chosen, *counts)``; its ``cfg``
 says ``chunk_size``. ``chosen`` is what the stack chose for each token
 and the run's check hands its reference (a router's experts, an
 attention's key blocks), token axis second; ``network.COUNTERS`` names
-the ``counts``, device counters of one dispatch, which the stage sums
-over the dispatches it serves and hands on by those names:
-
-``expert_served`` (expert layers, held): the assignments each held
-expert served; with it ``network.held_slots(cfg, held)`` makes
-``slots``, ``cfg.num_experts_per_tok`` is reported beside it, and
-``group_tokens`` (expert layers,) may follow: the valid tokens that
-sent the held experts anything (the ``Experts:`` line);
-
-``sparse`` (sparse layers, 4): (valid query, key-value head) pairs,
-those of requests that select key blocks, the causal keys those could
-read, the keys of the blocks they chose (the ``Sparse:`` line);
-
-``attn_tiles`` (attention layers, 2): the (query block, key block)
-tiles of the packed flash kernel (``ops/segattn.py``) that the
-dispatch's block table let run, and those on or under the diagonal
-(the ``Attention:`` line); a stack that has layers with a window counts
-those layers' tiles apart, at their own tile sizes, as
-``window_tiles`` (the same line's ``window_`` pair), and its
-``attn_tiles`` are the full layers' alone;
-
-``pair_rows`` (expert layers, 2): the pair rows the held experts'
-buffers held, where a stack sizes them by the share held
-(``ops/moe.pair_capacity``), and the tokens x k they would hold unsized
-(the ``Experts:`` line's ``pair_rows_`` pair);
-
-``gmm_rows`` (expert layers,): the rows the first grouped product's grid
-steps multiplied for the pairs the held experts served
-(``ops/moe.gmm_visits`` times the row tile in use; ``gmm_rows=`` on the
-``Experts:`` line, whose ``held=`` is the pairs it kept).
-
-A family without experts has no ``held_slots`` (its ``slots`` is
-None), counts no ``expert_served`` and gets no ``Experts:`` line; one
-that chooses no key blocks gets no ``Sparse:`` line, one that runs no
-packed flash kernel no ``Attention:`` line. A family whose
-choices are not one row a token brings ``network.request_choices(cfg,
-chosen, first, count)``: what a sample keeps of ``chosen`` for the
-request of ``count`` tokens from flat token ``first``. The recipe the
-final stage is pointed at names the family. ``MAX_ROWS`` is the
-default row cap; a configuration's pipeline states its own.
+the ``counts``, device counters of one dispatch with the layers that
+count first, which the stage sums over the dispatches it serves and
+hands on by those names (``stage_counters()``).
+``rnb_tpu.telemetry.STAGE_COUNTERS`` is where a counter is described:
+what it counts, its shape, the log-meta line and the keys it is written
+under. A family with ``expert_served`` among them holds a share of each
+layer's experts: ``network.held_slots(cfg, held)`` makes its ``slots``
+and ``cfg.num_experts_per_tok`` is reported beside the counter; one
+without has ``slots`` None. A family whose choices are not one row a
+token brings ``network.request_choices(cfg, chosen, first, count)``:
+what a sample keeps of ``chosen`` for the request of ``count`` tokens
+from flat token ``first``. The recipe the final stage is pointed at
+names the family. ``MAX_ROWS`` is the default row cap; a
+configuration's pipeline states its own.
 
 The batch unit is the *row*: ``chunk_size`` tokens. A prompt of L
 tokens is ceil(L / chunk) consecutive rows, the last one's tail
@@ -73,6 +47,7 @@ from rnb_tpu.compilestats import SignatureTracker
 from rnb_tpu.health import cards_of
 from rnb_tpu.stage import (PaddedBatch, StageModel,
                            normalize_row_buckets)
+from rnb_tpu.telemetry import STAGE_COUNTERS
 
 MAX_ROWS = 64
 CHUNK = 128
@@ -285,28 +260,24 @@ class PackedPrefill(StageModel):
         return "float32"
 
     def stage_counters(self) -> dict:
-        """What ``telemetry.aggregate_stage_counters`` sums over a
-        run's stages: the tokens, and the family's counters under their
-        names (a family without experts reports no ``expert_served``,
-        one that chooses no key blocks no ``sparse``, one without the
-        packed flash kernel no ``attn_tiles``, one without a window no
-        ``window_tiles``, one that does not size its experts' buffers no
-        ``pair_rows``, one that does not count the grouped product's
-        rows no ``gmm_rows``; none before the first dispatch)."""
+        """What ``telemetry.stage_counter_report`` reads of a run's
+        stages: the tokens, and the family's counters under their
+        names, each summed over its layers to the keys of its row in
+        ``telemetry.STAGE_COUNTERS`` (a row with a reduction of its own
+        whole); only what the family counts, and none before the first
+        dispatch."""
         self._count_pending()
         counters = {"tokens_valid": int(self.tokens_valid),
                     "tokens_shipped": int(self.tokens_shipped)}
-        counted = self._counted
-        if "expert_served" in counted:
-            counters["expert_served"] = counted["expert_served"].copy()
+        for row in STAGE_COUNTERS:
+            count = self._counted.get(row.counter)
+            if count is None:
+                continue
+            counters[row.counter] = count.copy() if row.reduce \
+                else count.reshape(-1, len(row.keys)).sum(axis=0)
+        if "expert_served" in counters:
             counters["experts_per_token"] = int(
                 self.cfg.num_experts_per_tok)
-        for name in ("group_tokens", "gmm_rows"):
-            if name in counted:
-                counters[name] = int(counted[name].sum())
-        for name in ("sparse", "attn_tiles", "window_tiles", "pair_rows"):
-            if name in counted:
-                counters[name] = counted[name].sum(axis=0)
         return counters
 
     def _count_pending(self) -> None:

@@ -2,16 +2,16 @@
 
 The compute path of this framework is almost entirely XLA-compiled
 Flax/jnp code — XLA already fuses elementwise work into the conv/matmul
-HLOs that dominate R(2+1)D. The ops package holds the few hand-written
-Pallas kernels for boundaries XLA cannot see across, currently the
-host->device ingest preprocess (uint8 decode output -> normalized
-bfloat16 activations) that every video batch crosses exactly once
-(reference analog: the uint8->float cast + permute after NVVL decode,
-reference models/r2p1d/model.py:149-151).
+HLOs that dominate R(2+1)D, the ingest normalization among them
+(``preprocess.normalize_u8``, plain jnp: uint8 decode output ->
+normalized bfloat16 activations; reference analog: the uint8->float
+cast + permute after NVVL decode, reference
+models/r2p1d/model.py:149-151). The ops package holds the hand-written
+Pallas kernels for what XLA cannot do as well alone.
 
-Every op exposes one public entry point that dispatches to the Pallas
-kernel on TPU backends and to an identical jnp formulation elsewhere
-(CPU tests, interpret mode), so numerics are defined once.
+An op with a kernel exposes one public entry point that dispatches to
+the Pallas kernel on TPU backends and to an identical jnp formulation
+elsewhere (CPU tests, interpret mode), so numerics are defined once.
 
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,

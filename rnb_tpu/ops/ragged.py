@@ -219,20 +219,20 @@ def ragged_normalize_u8(pool, rows_valid, dtype=None,
     grid-skip kernel where the computation is compiled for a TPU (or
     under ``interpret=True`` anywhere, for tests), the masked jnp
     formulation elsewhere — chosen at lowering time by
-    ``lax.platform_dependent``, like ops.preprocess.normalize_u8.
+    ``lax.platform_dependent``.
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from rnb_tpu.ops.preprocess import normalize_u8_reference
+    from rnb_tpu.ops.preprocess import normalize_u8
 
     if dtype is None:
         dtype = jnp.bfloat16
 
     def masked(pool, rows_valid):
         return jnp.where(_row_mask(pool, rows_valid),
-                         normalize_u8_reference(pool, dtype=dtype),
+                         normalize_u8(pool, dtype=dtype),
                          jnp.zeros((), dtype))
 
     per_row = int(np.prod(pool.shape[1:])) if pool.ndim > 1 else 0

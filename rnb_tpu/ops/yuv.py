@@ -17,17 +17,14 @@ README.md:42-110). A TPU has none, so the split that minimizes host
 work and wire bytes is: gather on host, arithmetic on device.
 
 The normalization here is the plain jnp form
-(``ops.preprocess.normalize_u8_reference``), called directly and not
-through ``normalize_u8``: on a TPU that one is a Pallas kernel over a
-flat ``(M, 128)`` view, an opaque call the compiler cannot fuse
-through. It is for a u8 batch that arrives from the host with no
-consumer in its program. Here the u8 frames are computed in the same
-jit as the convolution that reads them, and the kernel between the two
-cost two relayouts and a 3-channel clip padded to 128 lanes twice a
-dispatch (PERF.md section 6, PR 32). Every caller of this module — the
-bucketed and the ragged stage program, the mesh step, the sharded
-ring — normalizes inside its consumer's jit, so the choice needs no
-knob.
+(``ops.preprocess.normalize_u8``), the only one since PR 45. The u8
+frames are computed in the same jit as the convolution that reads
+them, so XLA fuses the normalization into its producer; the Pallas
+kernel that once stood between the two cost two relayouts and a
+3-channel clip padded to 128 lanes twice a dispatch (PERF.md section
+6, PR 32). Every caller of this module — the bucketed and the ragged
+stage program, the mesh step, the sharded ring — normalizes inside its
+consumer's jit.
 
 Packed layout per frame (geometry must be even): ``Y`` (H*W bytes),
 then ``U`` and ``V`` ((H/2)*(W/2) bytes each) — ``packed_frame_bytes``
@@ -48,7 +45,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from rnb_tpu.ops.preprocess import normalize_u8_reference
+from rnb_tpu.ops.preprocess import normalize_u8
 
 
 def packed_frame_bytes(height: int, width: int) -> int:
@@ -90,11 +87,9 @@ def normalize_yuv420(x, height: int = 112, width: int = 112,
     The u8 quantization step between conversion and normalization is
     kept deliberately: it makes the network's input identical to what
     a host-side converter would have produced, so accuracy is a
-    property of the pixel path, not of where it runs. The jnp
-    normalization, not the Pallas one: see the module docstring.
+    property of the pixel path, not of where it runs.
     """
-    return normalize_u8_reference(yuv420_to_rgb_u8(x, height, width),
-                                  dtype=dtype)
+    return normalize_u8(yuv420_to_rgb_u8(x, height, width), dtype=dtype)
 
 
 def yuv420_to_rgb_numpy(x: np.ndarray, height: int,

@@ -19,7 +19,8 @@ from __future__ import annotations
 import os
 import time
 from collections import OrderedDict, namedtuple
-from typing import IO, Iterable, List, Optional, Sequence
+from typing import (IO, Callable, Iterable, List, NamedTuple, Optional,
+                    Sequence)
 
 
 def logroot(job_id: str, base: str = "logs") -> str:
@@ -223,35 +224,20 @@ META_LINE_REGISTRY = (
     StampSpec("Padding:", "rnb_tpu/benchmark.py",
               "bucketed-path padding waste: pad rows / total shipped "
               "rows / emissions summed over batching stages"),
-    StampSpec("Tokens:", "rnb_tpu/benchmark.py",
+    # -- a stage's own counters: STAGE_COUNTERS (below) says which
+    # counter stands on which of these four, under which keys
+    StampSpec("Tokens:", "rnb_tpu/telemetry.py",
               "token accounting of stages whose rows are blocks of "
-              "tokens: valid tokens / tokens shipped (rows x tokens a "
-              "row) over every dispatch (such stages only)"),
-    StampSpec("Experts:", "rnb_tpu/benchmark.py",
+              "tokens (such stages only)"),
+    StampSpec("Experts:", "rnb_tpu/telemetry.py",
               "sparse-expert accounting of a stage holding a share of "
-              "each layer's experts: pairs routed, pairs whose expert "
-              "is held here, most and mean served by one held expert "
-              "of one layer; where the stack sizes the held experts' "
-              "pair buffers, the pair rows they held and the tokens x "
-              "k of those layers as pair_rows_*; where it counts them, "
-              "the rows the first grouped product's grid steps "
-              "multiplied for the held pairs as gmm_rows (such stages "
-              "only)"),
-    StampSpec("Sparse:", "rnb_tpu/benchmark.py",
+              "each layer's experts (such stages only)"),
+    StampSpec("Sparse:", "rnb_tpu/telemetry.py",
               "block-selected attention accounting of a stage whose "
-              "stack chooses key blocks, over (valid query, key-value "
-              "head) pairs of every sparse layer: the pairs, those of "
-              "requests that select, the causal keys those could "
-              "read, the keys of the blocks they chose (such stages "
-              "only)"),
-    StampSpec("Attention:", "rnb_tpu/benchmark.py",
+              "stack chooses key blocks (such stages only)"),
+    StampSpec("Attention:", "rnb_tpu/telemetry.py",
               "packed flash attention accounting of a stage whose "
-              "stack runs it, over every attention layer of every "
-              "dispatch: the (query block, key block) tiles the "
-              "dispatch's block table let the kernel run, and the "
-              "tiles on or under the diagonal; where the stack has "
-              "layers with a window, their pair apart as window_* and "
-              "the first pair the full layers' alone (such stages only)"),
+              "stack runs it (such stages only)"),
     StampSpec("Compiles:", "rnb_tpu/benchmark.py",
               "JSON per-step jit-entry signature counts "
               "{step: {warmup, steady_new, steady_calls}} — "
@@ -305,20 +291,6 @@ META_LINE_REGISTRY = (
               "JSON per-phase latency attribution "
               "{phase: {mean_ms, p99_ms, count}} over steady-state "
               "completions (trace-enabled runs only)"),
-    StampSpec("Net:", "rnb_tpu/benchmark.py",
-              "cross-host ingest edge counters (rnb_tpu.netedge): "
-              "frames sent/acked, resends + resent_pending at "
-              "teardown, heartbeats seen, reconnect cycles, "
-              "remote vs local-fallback dispatch split, dedup drops "
-              "vs duplicate arrivals, wire/frame byte totals, "
-              "window strands, opened-before-timeout flag (netedge-"
-              "enabled runs only; --check holds "
-              "frames_sent == frames_acked + resent_pending and "
-              "dedup_drops == dup_arrivals)"),
-    StampSpec("Net errors:", "rnb_tpu/benchmark.py",
-              "per-class network fault counts off the PR 1 taxonomy "
-              "(refused/reset/timeout/partial_frame/corrupt); "
-              "--check re-sums the classes to total"),
     StampSpec("Locks:", "rnb_tpu/benchmark.py",
               "lock-order witness ledger (rnb_tpu.lockwitness, root "
               "`lint.lock_witness` config key): witnessed locks, "
@@ -902,62 +874,137 @@ class TimeCardSummary:
             fp.write(phases + "\n")
 
 
-def aggregate_stage_counters(snapshots):
-    """(token stats, expert stats) summed over the ``stage_counters()``
-    snapshots of a run's stage instances: the numbers of the
-    ``Tokens:`` and ``Experts:`` log-meta lines. Either is None where
-    no stage counts it. The per-expert counts are summed over the
-    instances before the most loaded one is taken (replicas of one
-    stage hold the same experts). ``group_tokens`` is there where a
-    stage counts it (a router that chooses among groups of experts):
-    the valid tokens, summed over the expert layers, that sent the
-    held experts anything; ``gmm_rows`` likewise: the rows the first
-    grouped product multiplied for the held pairs."""
+def _token_totals(snapshots, row):
+    """The ``tokens`` row: a stage reports its own tokens beside the
+    family's counters, a number a key (``tokens_valid``, ...)."""
+    counted = [snap for snap in snapshots if "tokens_valid" in snap]
+    if not counted:
+        return None
+    return [sum(int(snap[field]) for snap in counted)
+            for field in row.fields]
+
+
+def _expert_loads(snapshots, row):
+    """The ``expert_served`` row: (valid token, chosen expert) pairs
+    routed — tokens x experts a token x expert layers — then, of the
+    (expert layers, held) counts summed over the instances (replicas of
+    one stage hold the same experts), the pairs served here and the
+    most and the mean that one held expert of one layer served."""
     import numpy as np
-    tokens = experts = served = None
+    served, assignments = None, 0
     for snap in snapshots:
-        if "tokens_valid" in snap:
-            tokens = tokens or {"valid": 0, "shipped": 0}
-            tokens["valid"] += int(snap["tokens_valid"])
-            tokens["shipped"] += int(snap["tokens_shipped"])
-        if snap.get("expert_served") is not None:
-            part = np.asarray(snap["expert_served"], np.int64)
+        if snap.get(row.counter) is not None:
+            part = np.asarray(snap[row.counter], np.int64)
             served = part if served is None or served.shape != part.shape \
                 else served + part
-            experts = experts or {"assignments": 0}
-            experts["assignments"] += int(snap["tokens_valid"]) \
+            assignments += int(snap["tokens_valid"]) \
                 * int(snap["experts_per_token"]) * part.shape[0]
-            for name in ("group_tokens", "gmm_rows"):
-                if name in snap:
-                    experts[name] = experts.get(name, 0) + int(snap[name])
-    if experts is not None:
-        experts.update(held=int(served.sum()),
-                       max_per_expert=int(served.max()),
-                       mean_per_expert=float(served.mean()))
-    return tokens, experts
+    if served is None:
+        return None
+    return [assignments, int(served.sum()), int(served.max()),
+            float(served.mean())]
 
 
-#: the four counts of a ``sparse`` stage counter, in order
-#: (``rnb_tpu.ops.blocksparse`` says what each counts: the ``Sparse:``
-#: line), and the two of ``attn_tiles`` (``rnb_tpu.ops.segattn``: the
-#: ``Attention:`` line; the ``window_tiles`` of a stack's layers with a
-#: window are the same two, written behind them as ``window_*``)
-SPARSE_COUNTS = ("queries", "selecting", "causal_keys", "chosen_keys")
-ATTENTION_COUNTS = ("tiles_visited", "tiles_causal")
-#: the two of ``pair_rows`` (``rnb_tpu.ops.moe.held_experts`` with a
-#: capacity: the ``Experts:`` line's last pair)
-PAIR_ROW_COUNTS = ("pair_rows_moved", "pair_rows_all")
+class StageCounter(NamedTuple):
+    """What one counter of a stage is: its name in the family's
+    ``network.COUNTERS`` (``tokens`` is the stage's own), the log-meta
+    line its numbers stand on, the ``key=`` names in the order they
+    stand there, and ``prefix`` + key the ``BenchmarkResult`` field of
+    each. ``reduce`` None: a vector of ``len(keys)`` a snapshot, summed
+    over the snapshots; else the row's own ``reduce(snapshots, row)``
+    over the counter as the stage counted it, whole."""
+    counter: str
+    line: str
+    prefix: str
+    keys: Sequence[str]
+    doc: str
+    reduce: Optional[Callable] = None
+
+    @property
+    def fields(self):
+        return tuple(self.prefix + key for key in self.keys)
 
 
-def aggregate_counts(snapshots, counter, names):
-    """{name: count} of the stage counter ``counter``, a vector of
-    ``names``, summed over the ``stage_counters()`` snapshots of a
-    run's stage instances: the numbers of its log-meta line, or None
-    where no stage counts it."""
-    total = None
-    for snap in snapshots:
-        if snap.get(counter) is not None:
-            total = total or dict.fromkeys(names, 0)
-            for name, count in zip(names, snap[counter]):
-                total[name] += int(count)
-    return total
+#: every counter a stage may hand the launcher through
+#: ``stage_counters()``, in the order of the lines and of the keys on a
+#: line. A line is written where a stage counted one of its rows, a row's
+#: keys only where a stage counted that row. A new counter is its
+#: family's ``network.py``, a row here and its ``BenchmarkResult``
+#: field(s): the stage (``models/token_stages.py``) reduces by this and
+#: the launcher writes what :func:`stage_counter_report` hands it;
+#: ``scripts/parse_utils.py`` reads any of the four lines back by one
+#: rule (a key behind the line's name in lower case).
+STAGE_COUNTERS = (
+    StageCounter(
+        "tokens", "Tokens:", "tokens_", ("valid", "shipped"),
+        "valid tokens / tokens shipped (rows x tokens a row) over every "
+        "dispatch a stage of token rows served", _token_totals),
+    StageCounter(
+        "expert_served", "Experts:", "experts_",
+        ("assignments", "held", "max_per_expert", "mean_per_expert"),
+        "(expert layers, held): the assignments each held expert "
+        "served, of a stage holding a share of each layer's experts "
+        "(``network.held_slots`` makes its slots, ``experts_per_token`` "
+        "stands beside it in the snapshot)", _expert_loads),
+    StageCounter(
+        "group_tokens", "Experts:", "experts_", ("group_tokens",),
+        "(expert layers,): the valid tokens that sent the held experts "
+        "anything, where the router chooses among groups of experts"),
+    StageCounter(
+        "pair_rows", "Experts:", "experts_",
+        ("pair_rows_moved", "pair_rows_all"),
+        "(expert layers, 2): the pair rows the held experts' buffers "
+        "held, where a stack sizes them by the share held "
+        "(``ops/moe.pair_capacity``), and the tokens x k they would "
+        "hold unsized"),
+    StageCounter(
+        "gmm_rows", "Experts:", "experts_", ("gmm_rows",),
+        "(expert layers,): the rows the first grouped product's grid "
+        "steps multiplied for the pairs the held experts served "
+        "(``ops/moe.gmm_visits`` times the row tile in use)"),
+    StageCounter(
+        "sparse", "Sparse:", "sparse_",
+        ("queries", "selecting", "causal_keys", "chosen_keys"),
+        "(sparse layers, 4), block-selected attention "
+        "(``ops/blocksparse.py``): (valid query, key-value head) pairs, "
+        "those of requests that select key blocks, the causal keys "
+        "those could read, the keys of the blocks they chose"),
+    StageCounter(
+        "attn_tiles", "Attention:", "attention_",
+        ("tiles_visited", "tiles_causal"),
+        "(attention layers, 2), the packed flash kernel "
+        "(``ops/segattn.py``): the (query block, key block) tiles the "
+        "dispatch's block table let run, and those on or under the "
+        "diagonal; the full layers' alone where a stack has layers "
+        "with a window"),
+    StageCounter(
+        "window_tiles", "Attention:", "",
+        ("window_tiles_visited", "window_tiles_causal"),
+        "(window layers, 2): the same pair of a stack's layers with a "
+        "window, at their own tile sizes"),
+)
+
+
+def stage_counter_report(snapshots):
+    """(lines, fields) of the ``stage_counters()`` snapshots of a run's
+    stage instances, by :data:`STAGE_COUNTERS`: the log-meta lines to
+    write, whole and in order, and ``BenchmarkResult``'s fields by
+    name. Nothing of a counter that no stage counted."""
+    import numpy as np
+    parts, fields = {}, {}
+    for row in STAGE_COUNTERS:
+        if row.reduce is not None:
+            values = row.reduce(snapshots, row)
+        else:
+            counted = [np.asarray(snap[row.counter], np.int64).reshape(-1)
+                       for snap in snapshots
+                       if snap.get(row.counter) is not None]
+            values = [int(v) for v in sum(counted)] if counted else None
+        if values is None:
+            continue
+        fields.update(zip(row.fields, values))
+        parts.setdefault(row.line, []).extend(
+            ("%s=%.3f" if isinstance(value, float) else "%s=%d")
+            % (key, value) for key, value in zip(row.keys, values))
+    return (["%s %s" % (line, " ".join(counts))
+             for line, counts in parts.items()], fields)

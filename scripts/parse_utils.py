@@ -133,38 +133,18 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta[key] = int(val)
-        elif line.startswith("Tokens:"):
-            # "Tokens: valid=V shipped=S" — token accounting of stages
-            # whose rows are blocks of tokens
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["tokens_" + key] = int(val)
-        elif line.startswith("Experts:"):
-            # "Experts: assignments=A held=H max_per_expert=M
-            #  mean_per_expert=F [group_tokens=G] [pair_rows_moved=R
-            #  pair_rows_all=P] [gmm_rows=X]" — sparse-expert accounting
-            # of a stage holding a share of each layer's experts (G:
-            # tokens that sent the held group anything; R of P: the
-            # pair rows the held experts' buffers held, of tokens x k,
-            # where the stack sizes them; X: the rows the first grouped
-            # product multiplied for the H pairs, where it counts them)
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["experts_" + key] = float(val) if "." in val \
-                    else int(val)
-        elif line.startswith(("Sparse:", "Attention:")):
-            # "Sparse: queries=N selecting=S causal_keys=C
-            #  chosen_keys=K" — block-selected attention accounting
-            # over (valid query, key-value head) pairs;
-            # "Attention: tiles_visited=V tiles_causal=C
-            #  [window_tiles_visited=W window_tiles_causal=X]" — the
-            # packed flash kernel's tiles, run and on or under the
-            # diagonal (the layers with a window apart, where a stack
-            # has them)
+        elif line.startswith(("Tokens:", "Experts:", "Sparse:",
+                              "Attention:")):
+            # a stage's own counters, "<Line>: key=N ..." under the
+            # keys of rnb_tpu.telemetry.STAGE_COUNTERS (which says what
+            # each counts, and which are there only where a stage
+            # counts them): the key behind the line's name in lower
+            # case; a value with a point is a float
             name, counts = line.split(":", 1)
             for part in counts.split():
                 key, _, val = part.partition("=")
-                meta["%s_%s" % (name.lower(), key)] = int(val)
+                meta["%s_%s" % (name.lower(), key)] = float(val) \
+                    if "." in val else int(val)
         elif line.startswith("Compiles:"):
             # JSON {step: {warmup, steady_new, steady_calls}} —
             # jit-entry signature accounting (rnb_tpu.compilestats);
@@ -185,26 +165,6 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             for part in line.split(":", 1)[1].split():
                 key, _, val = part.partition("=")
                 meta["trace_" + key] = int(val)
-        elif line.startswith("Net errors:"):
-            # "Net errors: total=T refused=R reset=S timeout=O
-            #  partial_frame=P corrupt=C" — per-class network fault
-            # counts off the PR 1 taxonomy (rnb_tpu.netedge); must be
-            # matched before the "Net:" prefix below; netedge-enabled
-            # runs only; --check re-sums the classes to total
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["net_err_" + key] = int(val)
-        elif line.startswith("Net:"):
-            # "Net: frames_sent=A frames_acked=B resent_pending=C
-            #  resends=D beats=E reconnects=F remote=G local=H
-            #  dedup_drops=I dup_arrivals=J wire_bytes=K frame_bytes=L
-            #  window_stranded=M open_before_timeout=N" — cross-host
-            # ingest edge ledger (rnb_tpu.netedge), netedge-enabled
-            # runs only; --check holds the send/ack/resend and dedup
-            # identities and the zero-strand invariant
-            for part in line.split(":", 1)[1].split():
-                key, _, val = part.partition("=")
-                meta["net_" + key] = int(val)
         elif line.startswith("Lock edges:"):
             # JSON {"edges": [[a, b], ...], "violations": [...]} —
             # the lock-order witness's observed acquisition-order
@@ -1012,12 +972,6 @@ def check_job_detail(job_dir: str) -> Tuple[List[str], bool]:
     # trace.json actually holds, and the artifact must be structurally
     # valid (every event stamped, every flow resolving)
     problems.extend(_check_trace_artifact(job_dir, meta))
-    # cross-host ingest edge (rnb_tpu.netedge): the send/ack/resend
-    # ledger must foot at teardown, per-class error counts must re-sum
-    # to the total, every duplicate arrival must have been dropped by
-    # the dedup ledger (exactly-once), and a target-reached run may
-    # strand nothing in the resend window
-    problems.extend(_check_netedge(meta))
     problems.extend(_check_locks(meta))
     return problems, parse_failed
 
@@ -1176,75 +1130,6 @@ def _check_locks(meta: Dict[str, object]) -> List[str]:
                         "observed lock-order edge %s -> %s is not in "
                         "the static RNB-C lock-order graph — an "
                         "undeclared runtime lock dependency" % (a, b))
-    return problems
-
-
-def _check_netedge(meta: Dict[str, object]) -> List[str]:
-    """Cross-host ingest edge invariants (rnb_tpu.netedge): the 'Net:'
-    and 'Net errors:' ledgers must be internally consistent — sends
-    foot against acks plus the unacked remainder, error classes re-sum
-    to the total, duplicates and dedup drops pair 1:1 (the exactly-
-    once guarantee made visible), and a target-reached run strands
-    nothing in the resend window."""
-    problems: List[str] = []
-    if "net_frames_sent" not in meta:
-        if "net_err_total" in meta:
-            problems.append("log-meta carries a 'Net errors:' line "
-                            "but no 'Net:' totals line")
-        return problems
-    if "net_err_total" not in meta:
-        problems.append("log-meta carries a 'Net:' line but no "
-                        "'Net errors:' line")
-        return problems
-    for key in ("net_frames_sent", "net_frames_acked",
-                "net_resent_pending", "net_resends", "net_beats",
-                "net_reconnects", "net_remote", "net_local",
-                "net_dedup_drops", "net_dup_arrivals",
-                "net_wire_bytes", "net_frame_bytes",
-                "net_window_stranded", "net_open_before_timeout",
-                "net_err_total", "net_err_refused", "net_err_reset",
-                "net_err_timeout", "net_err_partial_frame",
-                "net_err_corrupt"):
-        if meta.get(key, 0) < 0:
-            problems.append("negative %s" % key)
-    sent = meta.get("net_frames_sent", 0)
-    acked = meta.get("net_frames_acked", 0)
-    pending = meta.get("net_resent_pending", 0)
-    if sent != acked + pending:
-        problems.append(
-            "net_frames_sent=%d != net_frames_acked=%d + "
-            "net_resent_pending=%d — the send/ack ledger does not "
-            "foot at teardown" % (sent, acked, pending))
-    class_sum = sum(meta.get(k, 0) for k in
-                    ("net_err_refused", "net_err_reset",
-                     "net_err_timeout", "net_err_partial_frame",
-                     "net_err_corrupt"))
-    if class_sum != meta.get("net_err_total", 0):
-        problems.append(
-            "per-class net error counts sum to %d but the 'Net "
-            "errors:' line says total=%d — a fault class escaped "
-            "classification" % (class_sum, meta.get("net_err_total",
-                                                    0)))
-    if meta.get("net_dedup_drops", 0) != meta.get("net_dup_arrivals",
-                                                  0):
-        problems.append(
-            "net_dedup_drops=%d != net_dup_arrivals=%d — a duplicate "
-            "arrival escaped the receiver-side dedup ledger (exactly-"
-            "once violated)" % (meta.get("net_dedup_drops", 0),
-                                meta.get("net_dup_arrivals", 0)))
-    if meta.get("net_frames_sent", 0) \
-            < meta.get("net_remote", 0):
-        problems.append(
-            "net_remote=%d exceeds net_frames_sent=%d — a remote "
-            "dispatch that never produced a REQ frame"
-            % (meta.get("net_remote", 0), meta.get("net_frames_sent",
-                                                   0)))
-    if meta.get("termination_flag") == 0 \
-            and meta.get("net_window_stranded", 0) != 0:
-        problems.append(
-            "net_window_stranded=%d on a target-reached run — "
-            "requests left in the resend window were neither "
-            "rerouted nor settled" % meta["net_window_stranded"])
     return problems
 
 

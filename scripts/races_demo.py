@@ -6,7 +6,7 @@ nastiest concurrency workload in the tree: 4 replica lanes, hedged
 re-dispatch, a seeded mid-stream lane wedge-then-kill, eviction and
 queue redispatch all racing one another) and re-runs it with the
 runtime lock-order witness armed (``lint: {lock_witness: true}``), so
-every core lock (cache, pager, staging, health, hedge, netedge) is a
+every core lock (cache, pager, staging, health, hedge) is a
 recording WitnessLock. Then asserts the discipline the static
 RNB-C analyzer declares:
 

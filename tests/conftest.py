@@ -31,7 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 @pytest.fixture(autouse=True)
 def _lock_witness():
     """Run every test under the runtime lock-order witness: any pool,
-    cache, pager, health or netedge object a test constructs gets
+    cache, pager or health object a test constructs gets
     witnessed locks, so lock-order inversions and ``*_locked``
     convention breaches surface as recorded violations wherever a test
     (or the races gate) chooses to assert on them. The fixture itself

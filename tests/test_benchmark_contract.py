@@ -57,7 +57,8 @@ META_LINES = [("Padding:", "pad_emissions"),
               ("Compiles:", "compile_signatures"),
               ("Handoff:", "handoff_edges"), ("Faults:", "num_failed")]
 
-REMOVED_ROOT_KEYS = ["metrics", "devobs", "critpath", "whatif", "operator"]
+REMOVED_ROOT_KEYS = ["metrics", "devobs", "critpath", "whatif", "operator",
+                     "netedge"]
 
 
 @pytest.fixture(scope="module")

@@ -772,19 +772,22 @@ def test_every_layer_counts_the_tiles_its_dispatch_ran(toy, monkeypatch):
 
 
 def test_the_experts_line_carries_the_group_tokens():
-    from rnb_tpu.telemetry import aggregate_stage_counters
+    from rnb_tpu.telemetry import stage_counter_report
     served = np.array([[3, 1], [2, 2]])
     with_groups = {"tokens_valid": 10, "tokens_shipped": 16,
                    "expert_served": served, "experts_per_token": 3,
                    "group_tokens": 7}
-    tokens, experts = aggregate_stage_counters([with_groups, with_groups])
-    assert tokens == {"valid": 20, "shipped": 32}
-    assert experts["assignments"] == 2 * 10 * 3 * 2
-    assert experts["held"] == 16 and experts["group_tokens"] == 14
+    lines, fields = stage_counter_report([with_groups, with_groups])
+    assert (fields["tokens_valid"], fields["tokens_shipped"]) == (20, 32)
+    assert fields["experts_assignments"] == 2 * 10 * 3 * 2
+    assert fields["experts_held"] == 16
+    assert fields["experts_group_tokens"] == 14
+    assert lines[1].endswith(" group_tokens=14")
     without = dict(with_groups)
     del without["group_tokens"]
-    _, experts = aggregate_stage_counters([without])
-    assert "group_tokens" not in experts
+    lines, fields = stage_counter_report([without])
+    assert "experts_group_tokens" not in fields
+    assert "group_tokens" not in lines[1]
 
 
 def test_operation_counts_agree_with_the_family_file():

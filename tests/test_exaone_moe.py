@@ -435,9 +435,7 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     from rnb_tpu.models import token_stages
     from rnb_tpu.models.exaone_moe import checkpoint
     from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import (ATTENTION_COUNTS, PAIR_ROW_COUNTS,
-                                   aggregate_counts,
-                                   aggregate_stage_counters)
+    from rnb_tpu.telemetry import stage_counter_report
     recipe = str(tmp_path / "toy.recipe.json")
     checkpoint.save_recipe(recipe, TOY, SEED, HELD)
     stage = token_stages.PackedPrefill(
@@ -467,18 +465,17 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     # one full layer and four sliding ones, a pool of one tile
     assert counters["attn_tiles"].tolist() == [1, 1]
     assert counters["window_tiles"].tolist() == [4, 4]
-    assert aggregate_counts([counters, counters], "window_tiles",
-                            ATTENTION_COUNTS) \
-        == {"tiles_visited": 8, "tiles_causal": 8}
+    _, twice = stage_counter_report([counters, counters])
+    assert (twice["window_tiles_visited"],
+            twice["window_tiles_causal"]) == (8, 8)
     # four expert layers of 8 rows x 16 tokens x 4 choices: too few
     # pairs for a capacity, so all of them move
     assert counters["pair_rows"].tolist() == [2048, 2048]
-    assert aggregate_counts([counters, counters], "pair_rows",
-                            PAIR_ROW_COUNTS) \
-        == {"pair_rows_moved": 4096, "pair_rows_all": 4096}
-    tokens_line, experts_line = aggregate_stage_counters([counters])
-    assert tokens_line == {"valid": valid, "shipped": 8 * Q}
-    assert experts_line is not None
+    assert (twice["experts_pair_rows_moved"],
+            twice["experts_pair_rows_all"]) == (4096, 4096)
+    lines, _ = stage_counter_report([counters])
+    assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
+    assert lines[1].startswith("Experts: ")
     for scope in ("/attn/", "/attn/window/", "/attn/full/",
                   "/attn/window/kernel/", "/attn/full/kernel/",
                   "/experts/", "/head/", "/embed/"):

@@ -115,6 +115,16 @@ def test_validate_plan_rejects_malformed():
     ]})
 
 
+@pytest.mark.parametrize("kind", ["net_refused", "net_reset",
+                                  "net_timeout", "net_partial_frame",
+                                  "net_corrupt"])
+def test_validate_plan_refuses_the_removed_net_kinds_by_name(kind):
+    """The transport they addressed is gone (PR 45): a plan that still
+    names one is refused as any unknown kind is, by name."""
+    with pytest.raises(ValueError, match=repr(kind)):
+        validate_plan({"faults": [{"kind": kind, "request_ids": [1]}]})
+
+
 def test_plan_fire_and_determinism():
     spec = {"seed": 11, "faults": [
         {"step": 0, "kind": "transient", "request_ids": [4], "times": 2},

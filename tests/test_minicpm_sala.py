@@ -581,8 +581,7 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
     from rnb_tpu.models import token_stages
     from rnb_tpu.models.minicpm_sala import checkpoint
     from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import (SPARSE_COUNTS, aggregate_counts,
-                                   aggregate_stage_counters)
+    from rnb_tpu.telemetry import stage_counter_report
     family = mm.load_family("minicpm_sala")
     recipe = str(tmp_path / "toy.recipe.json")
     checkpoint.save_recipe(recipe, TOY, SEED)
@@ -615,15 +614,16 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
     assert "expert_served" not in counters \
         and "experts_per_token" not in counters
     assert counters["sparse"].tolist()[:2] == [2 * valid, 2 * 80]
-    tokens_line, experts_line = aggregate_stage_counters([counters])
-    assert tokens_line == {"valid": valid, "shipped": 8 * Q}
-    assert experts_line is None
-    sparse_line = aggregate_counts([counters, counters], "sparse",
-                                   SPARSE_COUNTS)
-    assert sparse_line["queries"] == 4 * valid
-    assert sparse_line["chosen_keys"] < sparse_line["causal_keys"]
-    assert aggregate_counts([{"tokens_valid": 1}], "sparse",
-                            SPARSE_COUNTS) is None
+    lines, _ = stage_counter_report([counters])
+    assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
+    assert [line.split(":")[0] for line in lines] == ["Tokens", "Sparse"]
+    _, sparse_line = stage_counter_report([counters, counters])
+    assert sparse_line["sparse_queries"] == 4 * valid
+    assert sparse_line["sparse_chosen_keys"] \
+        < sparse_line["sparse_causal_keys"]
+    assert stage_counter_report([{"tokens_valid": 1,
+                                  "tokens_shipped": 1}])[0] \
+        == ["Tokens: valid=1 shipped=1"]
     for scope in ("/attn/", "/attn/select/", "/ssd/", "/mlp/", "/head/",
                   "/embed/"):
         assert any(scope in name + "/"
@@ -645,14 +645,17 @@ def test_the_sparse_line_is_declared_and_parsed(tmp_path):
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     import parse_utils
     from rnb_tpu import telemetry
-    assert telemetry.SPARSE_COUNTS == ("queries", "selecting",
-                                       "causal_keys", "chosen_keys")
+    sparse = [row for row in telemetry.STAGE_COUNTERS
+              if row.line == "Sparse:"]
+    assert [(row.counter, row.keys) for row in sparse] == [
+        ("sparse", ("queries", "selecting", "causal_keys",
+                    "chosen_keys"))]
     (tmp_path / "log-meta.txt").write_text(
         "Tokens: valid=10 shipped=16\n"
         "Sparse: queries=20 selecting=12 causal_keys=90 chosen_keys=60\n")
     meta = parse_utils.parse_meta(str(tmp_path))
     assert meta["tokens_valid"] == 10
-    assert [meta["sparse_" + key] for key in telemetry.SPARSE_COUNTS] \
+    assert [meta[field] for field in sparse[0].fields] \
         == [20, 12, 90, 60]
 
 

@@ -248,9 +248,10 @@ def test_the_experts_line_with_and_without_the_rows(family, tmp_path):
     None where the program counts none or the count is 0."""
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     import parse_utils
-    from rnb_tpu.benchmark import BenchmarkResult, experts_counts
-    from rnb_tpu.telemetry import (PAIR_ROW_COUNTS, aggregate_counts,
-                                   aggregate_stage_counters)
+    from rnb_tpu.benchmark import BenchmarkResult
+    from rnb_tpu.telemetry import STAGE_COUNTERS, stage_counter_report
+    PAIR_ROW_COUNTS, = [row.keys for row in STAGE_COUNTERS
+                        if row.counter == "pair_rows"]
     line, moved_share, fill_share = EXPERTS_LINES[family]
     (tmp_path / "log-meta.txt").write_text("Tokens: valid=10 shipped=16\n"
                                            + line)
@@ -270,31 +271,34 @@ def test_the_experts_line_with_and_without_the_rows(family, tmp_path):
         got = mm.load_layer_metric(reader).read(
             types.SimpleNamespace(result=result))
         assert got == (None if share is None else pytest.approx(share))
-    # the writer gives that line back from the parsed numbers
-    stats = {key: meta["experts_" + key]
-             for key in ("assignments", "held", "max_per_expert",
-                         "mean_per_expert", "group_tokens", "gmm_rows")
-             if "experts_" + key in meta}
+    # the writer gives that line back from a stage's counters with the
+    # parsed numbers: 150 tokens x 3 choices x 2 expert layers routed,
+    # 16 (layer, held expert) loads that sum to held= under max=
     pair = {key: meta["experts_" + key] for key in PAIR_ROW_COUNTS
             if "experts_" + key in meta}
-    assert "Experts: %s\n" % experts_counts(stats, pair or None) == line
-    # and the stages' counters sum to its numbers
-    snap = {"tokens_valid": 10, "tokens_shipped": 16,
+    loads, left = [], meta["experts_held"]
+    for _ in range(16):
+        loads.append(min(left, meta["experts_max_per_expert"]))
+        left -= loads[-1]
+    snap = {"tokens_valid": 150, "tokens_shipped": 256,
             "experts_per_token": 3,
-            "expert_served": np.full((2, 4), 5, np.int64)}
+            "expert_served": np.array(loads, np.int64).reshape(2, 8)}
     if "group_tokens" in line:
-        snap["group_tokens"] = 7
+        snap["group_tokens"] = meta["experts_group_tokens"]
     if "gmm_rows" in line:
         snap["gmm_rows"] = meta["experts_gmm_rows"]
     if moved_share is not None:
         snap["pair_rows"] = np.array([pair[key] for key in PAIR_ROW_COUNTS])
-    _, experts = aggregate_stage_counters([snap, snap])
-    assert ("group_tokens" in experts) == ("group_tokens" in line)
-    assert experts.get("gmm_rows") == (
+    lines, _ = stage_counter_report([snap])
+    assert lines == ["Tokens: valid=150 shipped=256", line[:-1]]
+    # and two stages' counters sum
+    _, experts = stage_counter_report([snap, snap])
+    assert ("experts_group_tokens" in experts) == ("group_tokens" in line)
+    assert experts.get("experts_gmm_rows") == (
         2 * meta["experts_gmm_rows"] if "gmm_rows" in line else None)
-    summed = aggregate_counts([snap, snap], "pair_rows", PAIR_ROW_COUNTS)
-    assert summed == ({key: 2 * count for key, count in pair.items()}
-                      or None)
+    summed = {key: experts["experts_" + key] for key in PAIR_ROW_COUNTS
+              if "experts_" + key in experts}
+    assert summed == {key: 2 * count for key, count in pair.items()}
 
 
 def test_the_readers_entry_in_the_manifest():

@@ -481,7 +481,7 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     from rnb_tpu.models import token_stages
     from rnb_tpu.models.qwen3_next import checkpoint
     from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import aggregate_stage_counters
+    from rnb_tpu.telemetry import stage_counter_report
     recipe = str(tmp_path / "toy.recipe.json")
     checkpoint.save_recipe(recipe, TOY, SEED, HELD)
     stage = token_stages.PackedPrefill(
@@ -509,9 +509,9 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
     assert 0 < counters["group_tokens"] <= 4 * valid
     assert counters["attn_tiles"].tolist() == [1, 1]
-    tokens_line, experts_line = aggregate_stage_counters([counters])
-    assert tokens_line == {"valid": valid, "shipped": 8 * Q}
-    assert experts_line is not None
+    lines, _ = stage_counter_report([counters])
+    assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
+    assert lines[1].startswith("Experts: ")
     for scope in ("/deltanet/", "/deltanet/rule/", "/attn/", "/experts/",
                   "/head/", "/embed/"):
         assert any(scope in name + "/"

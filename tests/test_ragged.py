@@ -25,13 +25,13 @@ LS = (1, 1, 1, 1)  # minimal layer sizes: fast compile, full topology
 
 def test_ragged_normalize_matches_bucketed_and_zeroes_pads():
     import jax.numpy as jnp
-    from rnb_tpu.ops.preprocess import normalize_u8_reference
+    from rnb_tpu.ops.preprocess import normalize_u8
     from rnb_tpu.ops.ragged import ragged_normalize_u8
     pool = np.random.RandomState(0).randint(
         0, 256, (4, 2, 8, 8, 3), np.uint8)
     out = np.asarray(ragged_normalize_u8(jnp.asarray(pool), 2,
                                          dtype=jnp.float32))
-    ref = np.asarray(normalize_u8_reference(pool[:2], dtype=jnp.float32))
+    ref = np.asarray(normalize_u8(pool[:2], dtype=jnp.float32))
     assert np.array_equal(out[:2], ref)
     assert not out[2:].any()
 
@@ -220,8 +220,8 @@ def test_golden_logit_parity_rgb():
         # pool; the bucketed loader normalizes the padded bucket
         pool = jnp.asarray(ragged_normalize_u8(
             jnp.asarray(pool_u8), valid, dtype=jnp.bfloat16))
-        from rnb_tpu.ops.preprocess import normalize_u8_reference
-        bucket = jnp.asarray(normalize_u8_reference(
+        from rnb_tpu.ops.preprocess import normalize_u8
+        bucket = jnp.asarray(normalize_u8(
             np.where(np.arange(4)[:, None, None, None, None] < valid,
                      pool_u8, 0), dtype=jnp.bfloat16))
         (rg,), _, _ = ragged(

@@ -348,8 +348,6 @@ def test_unregistered_meta_line_triggers_t004(tmp_path):
                      'f.write("Hedge: fired=%d\\n" % hg)\n'
                      'f.write("Compiles: %s\\n" % c)\n'
                      'f.write("Warmup: %s\\n" % w)\n'
-                     'f.write("Net: frames_sent=%d\\n" % nt)\n'
-                     'f.write("Net errors: total=%d\\n" % ne)\n'
                      'f.write("Pages: allocs=%d\\n" % pg)\n'
                      'f.write("Shard: steps=%d\\n" % sh)\n'
                      'f.write("Shard steps: %s\\n" % ss)\n'
@@ -400,13 +398,6 @@ REPO_BENCH_LIKE = (
         'f.write("Deadline: budget_ms=%d expired=%d\\n" % dl)\n'
         'f.write("Hedge: fired=%d won=%d lost=%d wasted_ms=%d\\n" '
         '% hg)\n'
-        'f.write("Net: frames_sent=%d frames_acked=%d '
-        'resent_pending=%d resends=%d beats=%d reconnects=%d '
-        'remote=%d local=%d dedup_drops=%d dup_arrivals=%d '
-        'wire_bytes=%d frame_bytes=%d window_stranded=%d '
-        'open_before_timeout=%d\\n" % nt)\n'
-        'f.write("Net errors: total=%d refused=%d reset=%d '
-        'timeout=%d partial_frame=%d corrupt=%d\\n" % ne)\n'
         'f.write("Shard: steps=%d max_degree=%d gathers=%d '
         'collective_us=%d rows=%d\\n" % sh)\n'
         'f.write("Locks: tracked=%d acquires=%d edges=%d '
@@ -444,31 +435,6 @@ def test_counter_family_drift_triggers_t006(tmp_path, line_end, prefix):
     findings = check_benchmark_result(str(bad), root=str(tmp_path))
     assert {(f.rule, f.anchor) for f in findings} \
         == {("RNB-T006", prefix + "bogus")}
-
-
-def test_net_counter_drift_triggers_t006(tmp_path):
-    """The RNB-T006 family covers the cross-host ingest lines: the
-    good fixture (REPO_BENCH_LIKE, which writes the full Net:/Net
-    errors: counter sets) is clean — which is also the reverse
-    direction, since every net_* BenchmarkResult field must map to a
-    written counter for that assert to hold — and a bogus counter on
-    either line surfaces as exactly its drifted field."""
-    from rnb_tpu.analysis.schema import check_benchmark_result
-    good = tmp_path / "good_bench_like.py"
-    good.write_text(REPO_BENCH_LIKE)
-    assert check_benchmark_result(str(good), root=str(tmp_path)) == []
-    bad = tmp_path / "bad_bench_like.py"
-    bad.write_text(REPO_BENCH_LIKE
-                   .replace('open_before_timeout=%d\\n',
-                            'open_before_timeout=%d bogus_frames=%d'
-                            '\\n')
-                   .replace('partial_frame=%d corrupt=%d\\n',
-                            'partial_frame=%d corrupt=%d '
-                            'bogus_class=%d\\n'))
-    findings = check_benchmark_result(str(bad), root=str(tmp_path))
-    anchors = {f.anchor for f in findings if f.rule == "RNB-T006"}
-    assert "net_bogus_frames" in anchors
-    assert "net_err_bogus_class" in anchors
 
 
 def test_schema_checker_clean_on_repo():
@@ -511,7 +477,7 @@ def test_bad_concurrency_fixture_triggers_exactly_its_rule(name, rule):
 
 def test_concurrency_checker_clean_on_repo_modulo_baseline():
     """The analyzer over the real package yields nothing beyond the
-    justified baseline (the health/hedge/pager/staging/netedge sweep
+    justified baseline (the health/hedge/pager/staging sweep
     is fixed or documented, not ignored)."""
     from rnb_tpu.analysis.concurrency import check_package
     from rnb_tpu.analysis.findings import Baseline, apply_baseline

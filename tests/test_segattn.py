@@ -258,23 +258,26 @@ def test_the_attention_line_is_declared_summed_and_parsed(tmp_path):
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     import parse_utils
     from rnb_tpu import telemetry
-    assert telemetry.ATTENTION_COUNTS == ("tiles_visited", "tiles_causal")
+    attention = [row for row in telemetry.STAGE_COUNTERS
+                 if row.counter == "attn_tiles"]
+    assert [(row.line, row.keys) for row in attention] == [
+        ("Attention:", ("tiles_visited", "tiles_causal"))]
     assert any(spec.pattern == "Attention:"
                for spec in telemetry.META_LINE_REGISTRY)
-    stage = {"tokens_valid": 10, "attn_tiles": np.array([23, 36])}
-    assert telemetry.aggregate_counts(
-        [stage, {"tokens_valid": 3}, stage], "attn_tiles",
-        telemetry.ATTENTION_COUNTS) \
-        == {"tiles_visited": 46, "tiles_causal": 72}
-    assert telemetry.aggregate_counts(
-        [{"tokens_valid": 3}], "attn_tiles",
-        telemetry.ATTENTION_COUNTS) is None
+    stage = {"tokens_valid": 10, "tokens_shipped": 16,
+             "attn_tiles": np.array([23, 36])}
+    idle = {"tokens_valid": 3, "tokens_shipped": 16}
+    lines, fields = telemetry.stage_counter_report([stage, idle, stage])
+    assert lines[-1] == "Attention: tiles_visited=46 tiles_causal=72"
+    assert (fields["attention_tiles_visited"],
+            fields["attention_tiles_causal"]) == (46, 72)
+    lines, fields = telemetry.stage_counter_report([idle])
+    assert len(lines) == 1 and "attention_tiles_visited" not in fields
     (tmp_path / "log-meta.txt").write_text(
         "Tokens: valid=10 shipped=16\n"
         "Attention: tiles_visited=46 tiles_causal=72\n")
     meta = parse_utils.parse_meta(str(tmp_path))
-    assert [meta["attention_" + key] for key in telemetry.ATTENTION_COUNTS] \
-        == [46, 72]
+    assert [meta[field] for field in attention[0].fields] == [46, 72]
 
 
 def test_the_reader_is_silent_without_the_counter():

@@ -176,99 +176,6 @@ def test_dct_arm_ships_executed_with_half_the_wire_bytes():
         "the row" % ratio)
 
 
-def test_netedge_arms_ship_executed_with_loopback_near_in_process():
-    """The disaggregated-ingest cells (PR 16) must land in BOTH
-    configs/ and the matrix with ok execution rows. The loopback
-    headline cell serves the DCT loader from a real second process
-    over the netedge wire: its frame payload must be the PR 12 packed
-    row exactly (9408 B/frame at the default budget, computed from
-    the loader's own declarations — the wire ships valid rows only,
-    so bytes/frame IS the packed row size), and its committed
-    throughput must hold >= 0.85x its in-process twin
-    rnb-netedge-off (byte-identical pipeline, netedge disabled,
-    executed back-to-back by the same sweep) — the only variable
-    between the two rows is the process boundary, so the committed
-    ratio IS the wire overhead. The honesty policy forbids comparing
-    either netedge cell against the fused/ragged rows: fusing is
-    unavailable over the wire by construction (single-request
-    emissions keep the dedup ledger's exactly-once claim sound), so
-    those rows measure a different workload. The chaos arm must
-    declare the full network fault surface `make netchaos` exercises
-    — a non-fatal reset, a silent wedge, a fatal peer kill — against
-    a liveness circuit tight enough to beat its io timeout."""
-    rel = "configs/rnb-netedge-loopback.json"
-    base = "configs/rnb-netedge-off.json"
-    chaos = "configs/rnb-netedge-chaos.json"
-    from rnb_tpu.config import load_config
-    from rnb_tpu.utils.class_utils import load_class
-    for p in (rel, base, chaos):
-        assert os.path.exists(os.path.join(REPO, p)), p
-    cfg = load_config(os.path.join(REPO, rel))
-    assert cfg.netedge is not None and cfg.netedge.get("enabled")
-    assert cfg.netedge.get("spawn"), (
-        "the shipped loopback cell must dial a REAL spawned peer "
-        "process — an in-process shortcut would not measure the wire")
-    # the wire carries single-request emissions only (seq <-> request
-    # 1:1 is what keeps the dedup ledger's exactly-once claim sound),
-    # so the disaggregated arm is the plain non-fusing twin: same
-    # pixel path as the dct headline arm, no ragged pooling
-    kw = cfg.steps[0].kwargs_for_group(0)
-    assert kw["pixel_path"] == "dct"
-    assert cfg.ragged is None
-    loader_cls = load_class(cfg.steps[0].model)
-    frame_bytes = loader_cls.output_shape_for(**kw)[0][-1] * 2
-    assert loader_cls.output_dtype_for(**kw) == "int16"
-    assert frame_bytes == 9408, (
-        "the loopback cell's wire row is %d B/frame — the PR 12 "
-        "packed-DCT pin is 9408 (dct_rows_per_frame x budget x "
-        "int16); a drifted row size silently changes the headline's "
-        "meaning" % frame_bytes)
-    # the denominator must stay the loopback cell's true twin: same
-    # pipeline verbatim, netedge block differing ONLY in the enabled
-    # switch — otherwise the committed ratio stops meaning "the wire"
-    with open(os.path.join(REPO, rel)) as f:
-        rel_raw = json.load(f)
-    with open(os.path.join(REPO, base)) as f:
-        base_raw = json.load(f)
-    assert not base_raw["netedge"]["enabled"]
-    assert dict(base_raw["netedge"], enabled=True) == rel_raw["netedge"]
-    assert base_raw["pipeline"] == rel_raw["pipeline"], (
-        "rnb-netedge-off.json drifted from the loopback pipeline — "
-        "the wire-cost ratio is only honest between byte-identical "
-        "twins")
-    chaos_cfg = load_config(os.path.join(REPO, chaos))
-    assert chaos_cfg.netedge is not None \
-        and chaos_cfg.netedge.get("enabled")
-    assert chaos_cfg.health is not None
-    kinds = [f["kind"] for f in chaos_cfg.fault_plan["faults"]]
-    assert "net_reset" in kinds and "net_timeout" in kinds, (
-        "the net chaos arm must stage both a reset and a silent "
-        "wedge, got %s" % sorted(set(kinds)))
-    assert any(f.get("fatal") for f in chaos_cfg.fault_plan["faults"]
-               if f["kind"] == "net_reset"), (
-        "the net chaos arm must kill the peer process outright "
-        "(fatal net_reset) — eviction + local fallback is the "
-        "scenario win")
-    # the open-before-timeout claim needs the circuit strictly
-    # tighter than the io timeout
-    assert chaos_cfg.health["open_after_ms"] \
-        < chaos_cfg.netedge["io_timeout_ms"]
-    with open(ARTIFACT) as f:
-        rows = {r["config"]: r for r in json.load(f)["configs"]}
-    for p in (rel, base, chaos):
-        assert p in rows and rows[p].get("ok"), (
-            "%s has no ok execution row — run "
-            "scripts/run_shipped_configs.py --only '%s'"
-            % (p, os.path.basename(p)))
-    ratio = rows[rel]["videos_per_sec"] / rows[base]["videos_per_sec"]
-    assert ratio >= 0.85, (
-        "loopback netedge cell runs at %.2fx its in-process twin "
-        "(rnb-netedge-off) — crossing a process boundary should cost "
-        "noise (serialization + loopback memcpy), not throughput; "
-        "profile the wire before re-executing the rows back-to-back"
-        % ratio)
-
-
 def test_paged_zipf_arm_ships_executed_and_beats_its_blob_twin():
     """The paged-memory headline pair (PR 17) must land in BOTH
     configs/ and the matrix with ok execution rows, and the committed

@@ -214,7 +214,7 @@ def test_normalize_yuv420_is_the_reference_composition(lead, hw, dtype):
     multiple of 128, the case the Pallas form never took."""
     import jax
     import jax.numpy as jnp
-    from rnb_tpu.ops.preprocess import normalize_u8_reference
+    from rnb_tpu.ops.preprocess import normalize_u8
     from rnb_tpu.ops.yuv import normalize_yuv420
     h, w = hw
     dtype = getattr(jnp, dtype)
@@ -222,7 +222,7 @@ def test_normalize_yuv420_is_the_reference_composition(lead, hw, dtype):
         0, 256, lead + (packed_frame_bytes(h, w),), dtype=np.uint8)
     rgb = jax.jit(lambda x: yuv420_to_rgb_u8(x, h, w))(packed)
     assert rgb.dtype == jnp.uint8 and (rgb.size % 128 == 0) == (h == 112)
-    want = normalize_u8_reference(rgb, dtype=dtype)
+    want = normalize_u8(rgb, dtype=dtype)
     got = jax.jit(lambda x: normalize_yuv420(x, h, w, dtype))(packed)
     assert got.dtype == dtype and got.shape == lead + (h, w, 3)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
