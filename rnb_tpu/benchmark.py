@@ -196,6 +196,8 @@ class BenchmarkResult:
     sparse_selecting: int = 0
     sparse_causal_keys: int = 0
     sparse_chosen_keys: int = 0
+    sparse_tiles_chosen: int = 0
+    sparse_tiles_causal: int = 0
     attention_tiles_visited: int = 0
     attention_tiles_causal: int = 0
     window_tiles_visited: int = 0

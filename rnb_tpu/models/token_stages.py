@@ -1,5 +1,6 @@
 """The pipeline stages of the token families (``nemotron_h``,
-``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``, ``exaone_moe``): a
+``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``, ``exaone_moe``,
+``keye_vl2``): a
 first stage whose request is a prompt file, and a final stage that runs a family's stack over a packed
 pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
 (``segments: true``), which fuses requests into row buckets up to the
@@ -22,9 +23,11 @@ under. A family with ``expert_served`` among them holds a share of each
 layer's experts: ``network.held_slots(cfg, held)`` makes its ``slots``
 and ``cfg.num_experts_per_tok`` is reported beside the counter; one
 without has ``slots`` None. A family whose choices are not one row a
-token brings ``network.request_choices(cfg, chosen, first, count)``:
-what a sample keeps of ``chosen`` for the request of ``count`` tokens
-from flat token ``first``. The recipe the final stage is pointed at
+token, or of more kinds than one (a router's experts and an attention's
+keys: ``chosen`` is then a tuple), brings ``network.request_choices(cfg,
+chosen, first, count)``: what a sample keeps of ``chosen`` for the
+request of ``count`` tokens from flat token ``first``, an array (the
+sample's ``chosen``) or a dict of them, a name each. The recipe the final stage is pointed at
 names the family. ``MAX_ROWS`` is the default row cap; a
 configuration's pipeline states its own.
 
@@ -38,6 +41,7 @@ from __future__ import annotations
 
 import importlib
 import os
+import threading
 from typing import Optional
 
 import numpy as np
@@ -148,9 +152,13 @@ class PackedPrefill(StageModel):
     It emits one value a request (the executor waits on it); the
     logits stay on the device. While it serves, it keeps what the run's
     check compares: the logits (and the stack's choices: a router's
-    experts, an attention's key blocks) of ``samples`` requests, every
-    ``sample_every``-th it serves, written under the run's log directory
-    when the stage ends."""
+    experts, an attention's key blocks or keys) of ``samples`` requests,
+    every ``sample_every``-th it serves, written under the run's log
+    directory when the stage ends. What it reads of a dispatch on the
+    host it reads behind a later launch, so that the device does not
+    idle for the host's reading: its counters once the *next* dispatch
+    is launched; a sampled one's arrays are fetched from then on by a
+    thread of their own."""
 
     def __init__(self, device, ckpt_path: Optional[str] = None,
                  max_rows: int = MAX_ROWS, chunk: int = CHUNK,
@@ -225,10 +233,17 @@ class PackedPrefill(StageModel):
         self._sample_every = max(1, int(sample_every))
         self._samples_wanted = int(samples)
         self._samples = []
+        #: samples taken from the last dispatch, the threads that fetch
+        #: the earlier ones' arrays, and what one of them raised
+        self._sampled = []
+        self._fetching = []
+        self._sample_errors = []
+        self._samples_taken = 0
         self._served = 0
         self._log_dir = None
         #: (counts, valid tokens, rows) of the last dispatch: its counters
-        #: are read when the next call starts, after the executor's wait
+        #: are read once the next dispatch is launched (the executor has
+        #: waited for this one by then)
         self._pending = None
 
     def bind_log_dir(self, log_dir: str) -> None:
@@ -283,17 +298,19 @@ class PackedPrefill(StageModel):
     def _count_pending(self) -> None:
         """The counters the last dispatch brought back with its logits
         (the executor has waited for it: no extra synchronisation)."""
-        if self._pending is not None:
-            counts, valid, rows = self._pending
-            self._pending = None
-            for name, count in zip(self._counter_names, counts):
-                self._counted[name] = self._counted.get(name, 0) \
-                    + np.asarray(count, np.int64)
-            self.tokens_valid += valid
-            self.tokens_shipped += rows * self.chunk
+        pending, self._pending = self._pending, None
+        if pending is not None:
+            self._count(pending)
+
+    def _count(self, pending) -> None:
+        counts, valid, rows = pending
+        for name, count in zip(self._counter_names, counts):
+            self._counted[name] = self._counted.get(name, 0) \
+                + np.asarray(count, np.int64)
+        self.tokens_valid += valid
+        self.tokens_shipped += rows * self.chunk
 
     def __call__(self, tensors, non_tensors, time_card):
-        self._count_pending()
         pb, per_row = tensors
         rows = pb.max_rows
         offsets = getattr(pb, "segment_offsets", (0, int(pb.valid)))
@@ -303,37 +320,88 @@ class PackedPrefill(StageModel):
         self.compiles.observe(tokens)
         done, logits, chosen, counts = self._programs[rows](
             self._params, self._slots, tokens, meta)
-        self._pending = (counts, int(meta[0].sum()), rows)
+        # what the dispatch before this one left to read — its counters
+        # and, where it was sampled, its arrays, which have reached the
+        # host while the device ran — is read now that this one is under
+        # way: the device does not wait for the host's reading
+        before, self._pending = self._pending, \
+            (counts, int(meta[0].sum()), rows)
+        if before is not None:
+            self._count(before)
+        self._send_samples()
         cards = cards_of(time_card)
         for seg, card in enumerate(cards):
             self._served += 1
             if self._served % self._sample_every == 0 \
-                    and len(self._samples) < self._samples_wanted \
+                    and self._samples_taken < self._samples_wanted \
                     and len(cards) == len(offsets) - 1:
-                self._keep_sample(seg, card, offsets, tokens, meta, logits,
-                                  chosen, rows)
+                self._start_sample(seg, card, offsets, tokens, meta, logits,
+                                   chosen, rows)
         return (PaddedBatch(done, len(offsets) - 1),), non_tensors, \
             time_card
 
-    def _keep_sample(self, seg, card, offsets, tokens, meta, logits,
-                     chosen, rows) -> None:
+    def _start_sample(self, seg, card, offsets, tokens, meta, logits,
+                      chosen, rows) -> None:
+        """A sample of request ``seg`` of the dispatch just launched:
+        its arrays are held on the device until the next dispatch is
+        launched (:meth:`_send_samples`)."""
         first = int(offsets[seg]) * self.chunk
         count = int(meta[2, seg]) + 1 - first
-        if self._request_choices is None:
-            kept = np.asarray(chosen)[:, first:first + count].copy()
-        else:
-            kept = self._request_choices(self.cfg, chosen, first, count)
-        self._samples.append({
+        self._samples_taken += 1
+        self._sampled.append(({
             "rid": int(card.id), "rows": rows,
             "segments": len(offsets) - 1,
-            "tokens": tokens.reshape(-1)[first:first + count].copy(),
-            "logits": np.asarray(logits)[seg].astype(np.float32),
-            "chosen": kept})
+            "tokens": tokens.reshape(-1)[first:first + count].copy()},
+            seg, first, count, logits, chosen))
+
+    def _send_samples(self) -> None:
+        """The samples taken from the dispatch before the one just
+        launched go to a thread each, which fetches their arrays and
+        keeps the request's part. Not sooner, and not on this thread:
+        behind its own launch such a copy (hundreds of MB where a sample
+        keeps every query's keys) stood in front of the *next*
+        dispatch's inputs on the way to the device, and read on the
+        stage's thread it held the launch after that back: the chip
+        idled 0.25 to 0.55 s a sampled dispatch (my chip runs, PR 46)."""
+        for entry in self._sampled:
+            reader = threading.Thread(target=self._fetch_sample, args=entry,
+                                      name="prefill-sample", daemon=True)
+            reader.start()
+            self._fetching.append(reader)
+        self._sampled = []
+
+    def _fetch_sample(self, sample, seg, first, count, logits, chosen):
+        """What a sample keeps: the request's line of the logits and
+        its own slice of the stack's choices (``chosen``, or what the
+        family's ``request_choices`` names)."""
+        try:
+            sample["logits"] = np.asarray(logits)[seg].astype(np.float32)
+            if self._request_choices is None:
+                kept = np.asarray(chosen)[:, first:first + count].copy()
+            else:
+                kept = self._request_choices(self.cfg, chosen, first, count)
+            sample.update(kept if isinstance(kept, dict)
+                          else {"chosen": kept})
+            self._samples.append(sample)
+        except BaseException as e:     # raised where the stage ends
+            self._sample_errors.append(e)
+
+    def _collect_samples(self) -> None:
+        """Waits for the samples under way (the stage's end; the
+        tests)."""
+        for reader in self._fetching:
+            reader.join()
+        self._fetching = []
+        if self._sample_errors:
+            raise self._sample_errors[0]
+        self._samples.sort(key=lambda sample: sample["rid"])
 
     def finalize(self) -> None:
         """The stage has drained: write the samples and the scopes of
         its programs' instructions, free the weights."""
         self._count_pending()
+        self._send_samples()
+        self._collect_samples()
         if self._log_dir is not None:
             for k, sample in enumerate(self._samples):
                 np.savez(os.path.join(self._log_dir,

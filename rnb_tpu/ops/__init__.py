@@ -15,7 +15,8 @@ elsewhere (CPU tests, interpret mode), so numerics are defined once.
 
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
-rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe) add six mechanisms, each over a packed pool
+rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
+rnb_tpu.models.keye_vl2) add seven mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan and its convolution, in plain jnp/lax; lightning linear attention
 is its case of unit steps), ``deltanet`` (the gated delta rule, whose
@@ -23,7 +24,9 @@ transition is a matrix: one Pallas kernel that walks the rows with a
 head group's states in VMEM, a triangular solve inside each row),
 ``blocksparse`` (every query's own top-k blocks of keys from
 mean-compressed keys, and a Pallas flash kernel under that block
-mask), ``segattn``
+mask), ``indexed`` (every query's own top-k *keys* by a learned
+indexer's scores: the scores as sort keys, a threshold a query found bit
+by bit, and a Pallas flash kernel under the sets), ``segattn``
 (causal attention inside requests: JAX's Pallas splash kernel over the
 pool; values may be narrower than keys, latent attention's expanded
 form; with a window, a query reads the last so many keys of its request

@@ -1,0 +1,455 @@
+"""Attention under a *learned* choice of keys, over a packed pool of rows
+(DeepSeek-Sparse-Attention's lightning indexer, arXiv:2512.02556 /
+DeepSeek-V3.2-Exp): a small scoring network beside the attention gives
+every (query, key) pair of a request a score, a query keeps the ``topk``
+keys of its own request at or before it with the largest scores (all of
+them while it has ``topk`` or fewer; a tie goes to the lower key), and
+every head attends, causally, to those keys only. Queries that choose
+and queries that read everything share one pool and the same three
+kernels. Nothing is approximated: every query gets exactly its own set.
+
+The pool holds ``rows`` of ``Q`` tokens; a request is a run of
+consecutive rows (``row_start[r]``: the first row of row r's request; a
+pad row is a request of its own). T = rows x Q.
+
+**Scores** (:func:`index_keys`, the Pallas kernel ``index_scores``):
+``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])`` over the indexer's
+heads j, float32 from bfloat16 operands, a (query tile, key tile) at a
+time with the heads unrolled inside it; tiles over the diagonal or
+wholly of earlier requests are not computed. What it writes is not the
+float but its *sort key*: the int32 whose signed order is the float's
+(``-0.0`` folded into ``0.0`` first), and the smallest int32 on every
+pair a query may not read (the future, another request). (T, T) int32:
+1 GiB at 16,384 tokens, written once and read three times a layer.
+
+**The choice** (:func:`thresholds`, the kernels ``index_threshold`` and
+``index_tie_cutoff``) is a threshold a query, not a sort: the largest
+``tau[t]`` with at least ``topk`` keys at or over it, built bit by bit
+from the top (32 counts over the query's row of sort keys, which lies in
+VMEM: a sort of 16,384 floats a query is 105 compare-exchange passes,
+and a rank count 2.7 x 10^8 comparisons), and, where more keys than
+``topk`` lie at or over it — equal scores at the cut —, the position
+``cut[t]`` up to which the keys that *equal* ``tau[t]`` are kept, found
+the same way over the positions' bits in the tiles that hold such a
+query and nowhere else. Query t's set is then ``{s : key[t, s] > tau[t]
+or (key[t, s] == tau[t] and s <= cut[t])}`` among the keys it may read:
+exactly ``min(t + 1, topk)`` of them. A query with ``topk`` keys or
+fewer has ``tau`` the smallest int32: everything.
+
+**Attention** (:func:`masked_attention`, the kernel
+``indexed_attention``) is a flash kernel: a tile of queries times the
+query heads of one key-value head against a tile of keys, scores and
+running maximum and sum in VMEM in float32; the tile's mask is three
+integer comparisons of the sort keys with ``tau`` and ``cut``, the same
+for every head. A query tile walks the key tiles from its first
+request's first to the diagonal's (two numbers a query tile in scalar
+memory); inside them it skips nothing: under seeded random weights a
+query's 2,048 keys lie all over its request and no causal tile of a
+request is ever free of them (:func:`count_sets` counts the tiles that
+hold a chosen key, for the day that changes).
+
+**What a sample keeps** is the kernel's second result: the sets as bits,
+(T, keys a tile) uint32, key tile b being bit b of a word — bit b of
+word w of query t stands for key ``b * (keys a tile) + w`` — written
+once a query tile while the first head group walks its key tiles (two
+operations an element and no pass of its own: as two passes of XLA's
+over the matrix, bits and tiles took 6.3 ms a layer, my chip runs, PR
+46); :func:`unpack_sets` is its inverse on the host.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+#: the indexer's scores: queries and keys a tile
+_SCORE_TILE_Q, _SCORE_TILE_K = 512, 1024
+#: the thresholds: queries a step (their whole rows of sort keys lie in
+#: VMEM: 2 MiB at 16,384 keys), and the keys a count looks at together
+#: (a chunk wholly over the step's diagonal is not counted)
+_SELECT_TILE_Q, _SELECT_CHUNK = 32, 2048
+#: the attention kernel: queries and keys a tile (the query tile times
+#: the heads of a group is the matrix's rows: 2,048 at 8 heads)
+_TILE_Q, _TILE_K = 256, 512
+_MASKED = -1e30
+LOWEST = np.iinfo(np.int32).min
+_VMEM_LIMIT = 64 * 2 ** 20
+SCORES_KERNEL = "index_scores"
+THRESHOLD_KERNEL = "index_threshold"
+TIE_KERNEL = "index_tie_cutoff"
+ATTENTION_KERNEL = "indexed_attention"
+
+
+def token_table(row_start, row_tokens, qlen: int):
+    """Per token of the pool: (the first token of its request (T,)
+    int32, whether it is a valid token (T,) bool)."""
+    start = jnp.repeat(row_start.astype(jnp.int32) * qlen, qlen)
+    valid = (jnp.arange(qlen)[None, :] < row_tokens[:, None]).reshape(-1)
+    return start, valid
+
+
+def sort_key(x):
+    """float32 -> the int32 whose signed order is the float's; ``-0.0``
+    and ``0.0`` give one key."""
+    bits = lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _tile(want: int, tokens: int) -> int:
+    tile = min(want, tokens)
+    if tokens % tile:
+        raise ValueError("%d tokens are no whole tiles of %d"
+                         % (tokens, tile))
+    return tile
+
+
+# -- the scores -----------------------------------------------------------
+
+
+def _scores_kernel(first_ref, q_ref, k_ref, w_ref, start_ref, o_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+    tile_q, tile_k = o_ref.shape
+    heads = q_ref.shape[0]
+    # the tile holds a pair a query may read: not over the diagonal,
+    # and its keys end after the first of the tile's requests begins
+    runs = (j * tile_k <= i * tile_q + tile_q - 1) \
+        & ((j + 1) * tile_k > first_ref[i])
+
+    @pl.when(runs)
+    def _():
+        keys = k_ref[...]                                  # (D, tk)
+        acc = jnp.zeros((tile_q, tile_k), jnp.float32)
+        for h in range(heads):
+            s = jnp.dot(q_ref[h], keys,
+                        preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w_ref[:, h:h + 1]
+        q_at = i * tile_q + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 0)
+        k_at = j * tile_k + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 1)
+        mine = (k_at <= q_at) & (k_at >= start_ref[...])
+        o_ref[...] = jnp.where(mine, sort_key(acc), LOWEST)
+
+    @pl.when(jnp.logical_not(runs))
+    def _():
+        o_ref[...] = jnp.full(o_ref.shape, LOWEST, jnp.int32)
+
+
+def index_keys(q, k, w, start, interpret: bool = False):
+    """``q`` (T, heads, D) and ``k`` (T, D) in the operands' dtype, the
+    indexer's queries and its one key head; ``w`` (T, heads) float32,
+    the heads' weights with every scale folded in; ``start`` (T,) int32.
+    -> (T, T) int32: :func:`sort_key` of ``I[t, s]`` where query t may
+    read key s, the smallest int32 elsewhere."""
+    tokens, heads, dim = q.shape
+    tile_q, tile_k = _tile(_SCORE_TILE_Q, tokens), \
+        _tile(_SCORE_TILE_K, tokens)
+    return pl.pallas_call(
+        _scores_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tokens // tile_q, tokens // tile_k),
+            in_specs=[
+                pl.BlockSpec((heads, tile_q, dim),
+                             lambda i, j, _: (0, i, 0)),
+                pl.BlockSpec((dim, tile_k), lambda i, j, _: (0, j)),
+                pl.BlockSpec((tile_q, heads), lambda i, j, _: (i, 0)),
+                pl.BlockSpec((tile_q, 1), lambda i, j, _: (i, 0))],
+            out_specs=pl.BlockSpec((tile_q, tile_k),
+                                   lambda i, j, _: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((tokens, tokens), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=SCORES_KERNEL,
+    )(start[::tile_q], q.transpose(1, 0, 2), k.T, w.astype(jnp.float32),
+      start[:, None])
+
+
+# -- the choice -----------------------------------------------------------
+
+
+def _count(keys_ref, test, last):
+    """(tile, 1) int32: over a step's rows of sort keys, the keys with
+    ``test(keys, their positions)``, a chunk at a time; a chunk that
+    begins behind position ``last`` holds nothing a query may read."""
+    tile, tokens = keys_ref.shape
+    chunk = min(_SELECT_CHUNK, tokens)
+    total = jnp.zeros((tile, 1), jnp.int32)
+    for lo in range(0, tokens, chunk):
+        def some(lo=lo):
+            at = lo + lax.broadcasted_iota(jnp.int32, (tile, chunk), 1)
+            hit = test(keys_ref[:, lo:lo + chunk], at)
+            return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+        total = total + lax.cond(lo <= last, some,
+                                 lambda: jnp.zeros((tile, 1), jnp.int32))
+    return total
+
+
+def _threshold_kernel(keys_ref, tau_ref, over_ref, reach_ref, *, topk: int):
+    tile = keys_ref.shape[0]
+    last = pl.program_id(0) * tile + tile - 1
+
+    def at_least(cand):
+        return _count(keys_ref, lambda keys, _: keys >= cand, last)
+    # the sign first, then the 31 bits under it from the top: the
+    # largest value that topk keys reach
+    tau = jnp.where(at_least(jnp.zeros((tile, 1), jnp.int32)) >= topk,
+                    0, LOWEST).astype(jnp.int32)
+
+    def step(n, tau):
+        cand = tau | (jnp.int32(1) << (30 - n))
+        return jnp.where(at_least(cand) >= topk, cand, tau)
+    tau = lax.fori_loop(0, 31, step, tau)
+    tau_ref[...] = tau
+    over_ref[...] = _count(keys_ref, lambda keys, _: keys > tau, last)
+    reach_ref[...] = at_least(tau)
+
+
+def _tie_kernel(fetch_ref, tied_ref, keys_ref, tau_ref, want_ref, cut_ref,
+                *, bits: int):
+    i = pl.program_id(0)
+    tile, tokens = keys_ref.shape
+    last = i * tile + tile - 1
+    cut_ref[...] = jnp.full((tile, 1), tokens, jnp.int32)
+
+    @pl.when(tied_ref[i] != 0)
+    def _():
+        tau, want = tau_ref[...], want_ref[...]
+
+        def step(n, cut):
+            cand = cut | (jnp.int32(1) << (bits - 1 - n))
+            before = _count(keys_ref, lambda keys, at:
+                            (keys == tau) & (at < cand), last)
+            return jnp.where(before < want, cand, cut)
+        # the largest position with fewer than ``want`` equal keys in
+        # front of it: where the want-th of them lies
+        cut_ref[...] = lax.fori_loop(
+            0, bits, step, jnp.zeros((tile, 1), jnp.int32))
+
+
+def thresholds(keys, position, topk: int, interpret: bool = False):
+    """``keys`` (T, T) int32 from :func:`index_keys`; ``position`` (T,)
+    int32, a token's index inside its request. -> (tau, cut), (T,) int32
+    each: query t's set is the keys it may read with ``key > tau[t]``,
+    or ``key == tau[t]`` at a pool position ``<= cut[t]``; exactly
+    ``min(position + 1, topk)`` keys."""
+    tokens = keys.shape[0]
+    tile = _tile(_SELECT_TILE_Q, tokens)
+    steps = tokens // tile
+    one = pl.BlockSpec((tile, 1), lambda i, *_: (i, 0))
+    column = jax.ShapeDtypeStruct((tokens, 1), jnp.int32)
+    tau, over, reach = pl.pallas_call(
+        functools.partial(_threshold_kernel, topk=topk),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((tile, tokens), lambda i: (i, 0))],
+        out_specs=[one, one, one], out_shape=[column, column, column],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=THRESHOLD_KERNEL)(keys)
+    # a query that chooses, with more keys at or over tau than topk:
+    # equal scores at the cut. Its set takes the first ``want`` of the
+    # keys that equal tau: topk less those over it
+    tied = (position[:, None] + 1 > topk) & (reach > topk)
+    tile_tied = tied.reshape(steps, tile).any(axis=1)
+    # a step with no such query moves no rows: it names the block the
+    # step before it held
+    fetch = lax.cummax(jnp.where(tile_tied, jnp.arange(steps), 0), axis=0)
+    cut = pl.pallas_call(
+        functools.partial(_tie_kernel,
+                          bits=max(1, int(tokens - 1).bit_length())),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(steps,),
+            in_specs=[pl.BlockSpec((tile, tokens),
+                                   lambda i, fetch, _: (fetch[i], 0)),
+                      one, one],
+            out_specs=one),
+        out_shape=column,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=TIE_KERNEL,
+    )(fetch.astype(jnp.int32), tile_tied.astype(jnp.int32), keys, tau,
+      topk - over)
+    return tau[:, 0], jnp.where(tied, cut, tokens)[:, 0]
+
+
+# -- the sets, as a mask and as bits --------------------------------------
+
+
+def chosen_mask(keys, tau, cut, start):
+    """Bool (T, T): whether query t's set holds key s (plain
+    ``jax.numpy`` over the whole matrix: the tests' and the tools')."""
+    tokens = keys.shape[0]
+    at = jnp.arange(tokens, dtype=jnp.int32)
+    return ((keys > tau[:, None])
+            | ((keys == tau[:, None]) & (at[None, :] <= cut[:, None]))) \
+        & (at[None, :] <= at[:, None]) & (at[None, :] >= start[:, None])
+
+
+def unpack_sets(packed):
+    """The sets as bits, on the host: ``packed`` (..., count, words)
+    uint32, bit b of word w standing for the pool's key ``b * words + w``
+    -> bool (..., count, 32 * words): the keys by pool position (those
+    past the pool's end never set)."""
+    packed = np.asarray(packed)
+    bits = (packed[..., None, :] >> np.arange(32, dtype=np.uint32)
+            .reshape(32, 1)) & np.uint32(1)
+    return bits.reshape(packed.shape[:-1] + (-1,)).astype(bool)
+
+
+def count_sets(packed, tile_q: int):
+    """From the sets as bits (T, words): (int32 (T,): the keys each
+    query chose; int32: the (query tile, key tile) pairs, ``tile_q``
+    queries by ``words`` keys, in which any query chose any key)."""
+    tokens, words = packed.shape
+    chose = lax.population_count(packed).sum(axis=1).astype(jnp.int32)
+    # a key tile is a bit: the tiles a query tile reaches are the bits
+    # set in any word of any of its rows
+    reached = lax.reduce(packed.reshape(tokens // tile_q, tile_q * words),
+                         np.uint32(0), lax.bitwise_or, (1,))
+    return chose, lax.population_count(reached).sum().astype(jnp.int32)
+
+
+# -- attention under the sets ---------------------------------------------
+
+
+def _attention_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, keys_ref, tau_ref,
+                      cut_ref, start_ref, o_ref, sets_ref, m_ref, l_ref,
+                      acc_ref, *, per: int):
+    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    steps = pl.num_programs(2)
+    tile_q, tile_k = keys_ref.shape
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when((j == 0) & (g == 0))
+    def _():
+        sets_ref[...] = jnp.zeros(sets_ref.shape, jnp.int32)
+
+    # a tile that holds a pair a query may read: from its first
+    # request's first key block to the diagonal's
+    @pl.when((j >= lo_ref[i]) & (j <= hi_ref[i]))
+    def _():
+        s = lax.dot_general(q_ref[0, 0], k_ref[0],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        keys, tau = keys_ref[...], tau_ref[...]
+        q_at = i * tile_q + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 0)
+        k_at = j * tile_k + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 1)
+        chosen = ((keys > tau) | ((keys == tau) & (k_at <= cut_ref[...]))) \
+            & (k_at <= q_at) & (k_at >= start_ref[...])
+
+        # the sets as bits, once (the heads share them): key tile j is
+        # bit j of a word
+        @pl.when(g == 0)
+        def _():
+            sets_ref[...] = sets_ref[...] | (chosen.astype(jnp.int32) << j)
+        # rows are (head, query): the same set for every head
+        s = jnp.where(jnp.concatenate([chosen] * per, axis=0), s, _MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        # a row that has met no chosen key yet holds sums of exp(0);
+        # the first chosen key's maximum wipes them (alpha = 0)
+        p = jnp.exp(s - m_next)
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_next
+
+    @pl.when(j == steps - 1)
+    def _():
+        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def attention_tiles(tokens: int):
+    """(queries a tile, keys a tile) of the attention kernel for a pool
+    of ``tokens``; a key tile is a bit of the sets' words, so a pool is
+    32 of them at most."""
+    tile_q, tile_k = _tile(_TILE_Q, tokens), _tile(_TILE_K, tokens)
+    if tokens > 32 * tile_k:
+        raise ValueError("%d tokens are more than 32 key tiles of %d"
+                         % (tokens, tile_k))
+    return tile_q, tile_k
+
+
+def causal_tiles(tokens: int) -> int:
+    """The attention kernel's (query tile, key tile) pairs on or under
+    the diagonal of a pool of ``tokens``."""
+    tile_q, tile_k = attention_tiles(tokens)
+    return int(((np.arange(tokens // tile_q) * tile_q + tile_q - 1)
+                // tile_k + 1).sum())
+
+
+def masked_attention(q, k, v, keys, tau, cut, start,
+                     interpret: bool = False):
+    """``q`` (T, Hk, per, D) scaled; ``k``, ``v`` (T, Hk, D); ``keys``,
+    ``tau``, ``cut`` the sets (:func:`index_keys`, :func:`thresholds`).
+    -> ((T, Hk, per, D) in q's dtype: softmax attention of each query
+    over the keys of its set; the sets as bits (T, keys a tile) uint32,
+    bit b of word w standing for key ``b * (keys a tile) + w``:
+    :func:`unpack_sets` reads them, :func:`count_sets` counts them)."""
+    tokens, groups, per, dim = q.shape
+    tile_q, tile_k = attention_tiles(tokens)
+    nq, nk = tokens // tile_q, tokens // tile_k
+    rows = per * tile_q
+    # a query tile as one matrix, rows (head, query)
+    q_tiles = q.reshape(nq, tile_q, groups, per, dim) \
+        .transpose(2, 0, 3, 1, 4).reshape(groups, nq, rows, dim)
+    # the key tiles a query tile walks: from the block that holds the
+    # first key of its first query's request to the diagonal's. A step
+    # outside them names the nearest of them and moves nothing
+    lo = (start[::tile_q] // tile_k).astype(jnp.int32)
+    hi = jnp.asarray((np.arange(nq) * tile_q + tile_q - 1) // tile_k,
+                     jnp.int32)
+
+    def walked(j, i, lo, hi):
+        return jnp.clip(j, lo[i], hi[i])
+    one = pl.BlockSpec((tile_q, 1), lambda i, g, j, *_: (i, 0))
+    out, sets = pl.pallas_call(
+        functools.partial(_attention_kernel, per=per),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nq, groups, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, rows, dim),
+                             lambda i, g, j, *_: (g, i, 0, 0)),
+                pl.BlockSpec((1, tile_k, dim), lambda i, g, j, lo, hi:
+                             (g, walked(j, i, lo, hi), 0)),
+                pl.BlockSpec((1, tile_k, dim), lambda i, g, j, lo, hi:
+                             (g, walked(j, i, lo, hi), 0)),
+                pl.BlockSpec((tile_q, tile_k), lambda i, g, j, lo, hi:
+                             (i, walked(j, i, lo, hi))),
+                one, one, one],
+            out_specs=[pl.BlockSpec((1, 1, rows, dim),
+                                    lambda i, g, j, *_: (g, i, 0, 0)),
+                       pl.BlockSpec((tile_q, tile_k),
+                                    lambda i, g, j, *_: (i, 0))],
+            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, dim), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((groups, nq, rows, dim), q.dtype),
+                   jax.ShapeDtypeStruct((tokens, tile_k), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=ATTENTION_KERNEL,
+    )(lo, hi, q_tiles, k.transpose(1, 0, 2), v.transpose(1, 0, 2), keys,
+      tau[:, None], cut[:, None], start[:, None])
+    out = out.reshape(groups, nq, per, tile_q, dim) \
+        .transpose(1, 3, 0, 2, 4).reshape(tokens, groups, per, dim)
+    return out, lax.bitcast_convert_type(sets, jnp.uint32)

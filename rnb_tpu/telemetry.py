@@ -233,8 +233,9 @@ META_LINE_REGISTRY = (
               "sparse-expert accounting of a stage holding a share of "
               "each layer's experts (such stages only)"),
     StampSpec("Sparse:", "rnb_tpu/telemetry.py",
-              "block-selected attention accounting of a stage whose "
-              "stack chooses key blocks (such stages only)"),
+              "sparse attention accounting of a stage whose stack "
+              "chooses key blocks or, by a learned indexer, keys (such "
+              "stages only)"),
     StampSpec("Attention:", "rnb_tpu/telemetry.py",
               "packed flash attention accounting of a stage whose "
               "stack runs it (such stages only)"),
@@ -968,7 +969,18 @@ STAGE_COUNTERS = (
         "(sparse layers, 4), block-selected attention "
         "(``ops/blocksparse.py``): (valid query, key-value head) pairs, "
         "those of requests that select key blocks, the causal keys "
-        "those could read, the keys of the blocks they chose"),
+        "those could read, the keys of the blocks they chose; under a "
+        "learned indexer (``ops/indexed.py``) the same four of valid "
+        "queries, one set a query for all heads: those with more keys "
+        "to read than ``topk``, the keys those could read, the keys "
+        "they chose"),
+    StageCounter(
+        "index_tiles", "Sparse:", "sparse_",
+        ("tiles_chosen", "tiles_causal"),
+        "(sparse layers, 2), attention under an indexer's sets "
+        "(``ops/indexed.py``): the attention kernel's (query tile, key "
+        "tile) pairs in which any query chose any key, and those on or "
+        "under the diagonal, at its own tile sizes"),
     StageCounter(
         "attn_tiles", "Attention:", "attention_",
         ("tiles_visited", "tiles_causal"),

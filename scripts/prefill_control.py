@@ -19,7 +19,11 @@ own: inside the dispatch's program the v5e's compiler fuses bf16 ->
 float8 -> bf16 in front of the product and keeps the excess precision,
 and the arm then read the stated precision's logits bit for bit (PR 29,
 my chip run). Rounded weights stay rounded: such arms come last, the
-narrower set first.
+narrower set first. A family whose attention reads a learned indexer's
+sets (``keye_vl2``) has two arms more, each of which must read over the
+limit against the reference's own sets: every causal key in their place,
+and the latest ``topk``; and one that must fail the key slack with the
+program's sets given: the indexer's operands through float8.
 """
 
 import argparse
@@ -50,6 +54,13 @@ def arms_of(family: str):
     if family in ("minicpm_sala", "qwen3_next"):
         return [("as_stated", {}, None),
                 ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
+                ("layers_float8", {}, lambda group, name: True)]
+    if family == "keye_vl2":
+        from rnb_tpu.models.keye_vl2.network import FLOAT8_BITS
+        return [("as_stated", {}, None),
+                ("index_float8", {"index_bits": FLOAT8_BITS}, None),
+                ("all_causal_keys", {"select": "causal"}, None),
+                ("recent_keys", {"select": "recent"}, None),
                 ("layers_float8", {}, lambda group, name: True)]
     raise ValueError("no control arms for family %r" % (family,))
 
@@ -115,8 +126,8 @@ def main(argv=None) -> int:
                 cfg, p, s, t, m[0], m[1], m[2],
                 interpret=device.platform != "tpu", **kwargs))(
             params, slots, tokens, meta)
-        chosen = np.asarray(chosen)
-        want, short = [], 0.0
+        chosen = jax.tree.map(np.asarray, chosen)
+        want, short, key_short = [], 0.0, None
         with jax.default_matmul_precision("highest"):
             for prompt, first in zip(prompts, offsets):
                 # the request's own choices, as a sample keeps them
@@ -127,20 +138,41 @@ def main(argv=None) -> int:
                     if keep is None else family.unpack_choices(
                         config, keep(cfg, chosen, first * chunk,
                                      len(prompt)), len(prompt))
+                given = {}
+                if isinstance(forced, tuple):
+                    # two kinds of choice: the router's and the keys
+                    # every query read; an arm that reads other keys
+                    # than the indexer's is held to the reference's own
+                    forced, sets, strays = forced
+                    if "select" not in kwargs:
+                        given = {"forced_sets": sets}
                 ref = ref_model.forward(read, prompt, held=held,
-                                        forced=forced)
+                                        forced=forced, **given)
                 want.append(np.asarray(ref["logits"]))
                 short = max([short] + [
                     float(ref[key].max())
                     for key in ("shortfall", "group_shortfall")
                     if key in ref])
+                if given:
+                    key_short = max(
+                        key_short or 0.0,
+                        float("inf") if strays else 0.0,
+                        float(np.asarray(ref["key_shortfall"]).max()))
         verdict = compare(np.asarray(logits)[:len(prompts)],
                           np.stack(want), limit)
         out[arm] = {"share_of_spread": verdict["share_of_spread"],
                     "ok": verdict["ok"], "route_shortfall_max": short}
+        if key_short is not None:
+            out[arm]["key_shortfall_max"] = key_short
+            out[arm]["ok"] = bool(verdict["ok"]
+                                  and key_short <= float(config.get(
+                                      "key_slack", family.KEY_SLACK)))
         print("[control] %s %s" % (arm, out[arm]), file=sys.stderr,
               flush=True)
-    out["ok"] = out["as_stated"]["ok"] and not all(
+    # a family that says so holds every control to a failure, the
+    # others at least one
+    fails = any if getattr(family, "EVERY_CONTROL_FAILS", False) else all
+    out["ok"] = out["as_stated"]["ok"] and not fails(
         out[arm]["ok"] for arm, _, _ in arms[1:])
     print(json.dumps(out))
     return 0 if out["ok"] else 1

@@ -481,6 +481,11 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
                   "/experts/", "/head/", "/embed/"):
         assert any(scope in name + "/"
                    for name in stage.hlo_scopes.values()), scope
+    # a sample's arrays start for the host behind the next launch and
+    # are read behind the one after
+    assert len(stage._sampled) == 2 and not stage._samples
+    stage._send_samples()
+    stage._collect_samples()
     assert len(stage._samples) == 2
     first = stage._samples[0]
     assert first["tokens"].tolist() == prompts[0].tolist()

@@ -629,6 +629,11 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
         assert any(scope in name + "/"
                    for name in stage.hlo_scopes.values()), scope
     # the samples: the first two requests, each with its own blocks
+    # a sample's arrays start for the host behind the next launch and
+    # are read behind the one after
+    assert len(stage._sampled) == 2 and not stage._samples
+    stage._send_samples()
+    stage._collect_samples()
     assert len(stage._samples) == 2
     first = stage._samples[0]
     assert first["tokens"].tolist() == prompts[0].tolist()
@@ -647,9 +652,12 @@ def test_the_sparse_line_is_declared_and_parsed(tmp_path):
     from rnb_tpu import telemetry
     sparse = [row for row in telemetry.STAGE_COUNTERS
               if row.line == "Sparse:"]
+    # behind the four: the tiles of a learned indexer's kernel (PR 46),
+    # which this family does not count
     assert [(row.counter, row.keys) for row in sparse] == [
         ("sparse", ("queries", "selecting", "causal_keys",
-                    "chosen_keys"))]
+                    "chosen_keys")),
+        ("index_tiles", ("tiles_chosen", "tiles_causal"))]
     (tmp_path / "log-meta.txt").write_text(
         "Tokens: valid=10 shipped=16\n"
         "Sparse: queries=20 selecting=12 causal_keys=90 chosen_keys=60\n")

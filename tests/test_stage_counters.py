@@ -21,7 +21,7 @@ from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAGE_COUNTERS,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
-            "exaone_moe")
+            "exaone_moe", "keye_vl2")
 
 #: what the dispatches of one stage summed to, as ``network.forward``
 #: hands each counter back: the layers that count first
@@ -33,6 +33,7 @@ RAW = {
     "pair_rows": [[10, 40], [12, 40]],
     "gmm_rows": [128, 256],
     "sparse": [[20, 12, 90, 60], [20, 8, 70, 50]],
+    "index_tiles": [[3, 4], [2, 4]],
 }
 
 TOKENS = "Tokens: valid=10 shipped=16"
@@ -51,6 +52,9 @@ GOLDEN = {
                                      "pair_rows_all=80",
                    ATTENTION + " window_tiles_visited=3 "
                                "window_tiles_causal=4"],
+    "keye_vl2": [TOKENS, EXPERTS + " gmm_rows=384",
+                 "Sparse: queries=40 selecting=20 causal_keys=160 "
+                 "chosen_keys=110 tiles_chosen=5 tiles_causal=8"],
 }
 
 
@@ -105,9 +109,10 @@ def test_a_familys_counters_give_the_lines_the_launcher_wrote(family,
       "experts_max_per_expert": 5, "experts_mean_per_expert": 2.125,
       "experts_group_tokens": 13, "experts_pair_rows_moved": 22,
       "experts_pair_rows_all": 80, "experts_gmm_rows": 384}),
-    (GOLDEN["minicpm_sala"][1],
+    (GOLDEN["keye_vl2"][2],
      {"sparse_queries": 40, "sparse_selecting": 20,
-      "sparse_causal_keys": 160, "sparse_chosen_keys": 110}),
+      "sparse_causal_keys": 160, "sparse_chosen_keys": 110,
+      "sparse_tiles_chosen": 5, "sparse_tiles_causal": 8}),
     (GOLDEN["exaone_moe"][2],
      {"attention_tiles_visited": 9, "attention_tiles_causal": 15,
       "attention_window_tiles_visited": 3,
@@ -178,7 +183,8 @@ def test_the_most_loaded_expert_is_taken_after_the_sum():
     (("sparse",), ("Experts:", "Attention:")),
     (("expert_served", "attn_tiles"),
      ("Sparse:", "group_tokens", "pair_rows", "gmm_rows", "window_")),
-], ids=["tokens-only", "no-experts", "no-tails"])
+    (("sparse",), ("tiles_chosen",)),
+], ids=["tokens-only", "no-experts", "no-tails", "no-index-tiles"])
 def test_what_no_stage_counts_is_not_written(names, absent):
     lines, fields = stage_counter_report([snapshot_of(names)])
     text = "\n".join(lines)
