@@ -175,7 +175,7 @@ def sparse_mixer(cfg, p, h, row_start, row_tokens, interpret=False):
 
 
 def lightning_mixer(cfg, p, h, row_first, positions,
-                    state_dtype=jnp.float32):
+                    state_dtype=jnp.float32, interpret=False):
     """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
     rows, q, _ = h.shape
     act = h.dtype
@@ -190,7 +190,8 @@ def lightning_mixer(cfg, p, h, row_first, positions,
     # one group a head, no skip term
     out = ssd.ssd_scan(vs, None, jnp.asarray(cfg.log_decay()),
                        ks.astype(act), (qs * dim ** -0.5).astype(act), None,
-                       row_first, state_dtype=state_dtype)
+                       row_first, state_dtype=state_dtype,
+                       interpret=interpret)
     out = rms_norm(out.reshape(rows, q, heads * dim), p["o_norm"], cfg.eps,
                    jnp.float32)
     out = (out * jax.nn.sigmoid(_proj(h, p["gate"]))).astype(act)
@@ -239,7 +240,7 @@ def forward(cfg: MinicpmSalaConfig, params, slots, tokens, row_tokens,
             with jax.named_scope("ssd"):
                 h = rms_norm(x, p["attn_norm"], cfg.eps, act)
                 out = lightning_mixer(cfg, p, h, row_first, positions,
-                                      state_dtype)
+                                      state_dtype, interpret)
                 x = (x.astype(jnp.float32) + scale * out).astype(act)
         with jax.named_scope("mlp"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)

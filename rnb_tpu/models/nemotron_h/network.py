@@ -123,16 +123,24 @@ def _proj(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
-def mamba_mixer(cfg, p, h, row_first, state_dtype=jnp.float32):
+def mamba_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
+                interpret=False):
     """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
     rows, q, _ = h.shape
     act = h.dtype
     heads, hd = cfg.mamba_num_heads, cfg.mamba_head_dim
     groups, n = cfg.n_groups, cfg.ssm_state_size
-    zxbcdt = _proj(h, p["in_proj"])
-    z = zxbcdt[..., :cfg.d_inner]
-    xbc = zxbcdt[..., cfg.d_inner:cfg.d_inner + cfg.conv_dim].astype(act)
-    dt = zxbcdt[..., cfg.d_inner + cfg.conv_dim:]
+    # in_proj's columns as three products, z, xBC and dt: one result of
+    # 10,304 columns (80.5 lane tiles) the v5e's compiler lays out with
+    # the tokens minor, and the convolution, the scan's kernel and the
+    # gate, which read rows, each pay a transposing copy of it (PERF.md
+    # section 6, PR 47); 4,096 and 6,144 columns it lays out in rows,
+    # and z need not live through the convolution
+    edges = (0, cfg.d_inner, cfg.d_inner + cfg.conv_dim,
+             p["in_proj"].shape[1])
+    z, xbc, dt = (_proj(h, p["in_proj"][:, lo:hi])
+                  for lo, hi in zip(edges, edges[1:]))
+    xbc = xbc.astype(act)
     xbc = jax.nn.silu(ssd.segment_conv1d(
         xbc, p["conv_w"], p["conv_b"], row_first)).astype(act)
     xs = xbc[..., :cfg.d_inner].reshape(rows, q, heads, hd)
@@ -141,14 +149,13 @@ def mamba_mixer(cfg, p, h, row_first, state_dtype=jnp.float32):
     c = xbc[..., cfg.d_inner + groups * n:].reshape(rows, q, groups, n)
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    # the gate and the gated norm (RMS over each of the n_groups groups,
+    # one weight) are the kernel's last lines: a group's columns are the
+    # columns a step of the scan holds
     y = ssd.ssd_scan(xs, dt, a, b, c, p["d"].astype(jnp.float32),
-                     row_first, state_dtype=state_dtype)
-    y = y.reshape(rows, q, cfg.d_inner) * jax.nn.silu(z)
-    # the gated norm: RMS over each of the n_groups groups, one weight
-    yg = y.reshape(rows, q, groups, cfg.d_inner // groups)
-    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + cfg.eps)
-    y = (yg.reshape(rows, q, cfg.d_inner)
-         * p["gnorm"].astype(jnp.float32)).astype(act)
+                     row_first, state_dtype=state_dtype, interpret=interpret,
+                     gated_norm=(z, p["gnorm"], cfg.eps))
+    y = y.reshape(rows, q, cfg.d_inner)
     return _proj(y, p["out_proj"])
 
 
@@ -228,7 +235,8 @@ def forward(cfg: NemotronHConfig, params, slots, tokens, row_tokens,
         if kind == MAMBA:
             with jax.named_scope("ssd"):
                 h = rms_norm(x, p["norm"], cfg.eps, act)
-                out = mamba_mixer(cfg, p, h, row_first, state_dtype)
+                out = mamba_mixer(cfg, p, h, row_first, state_dtype,
+                                  interpret)
                 x = (x.astype(jnp.float32) + out).astype(act)
         elif kind == ATTENTION:
             with jax.named_scope("attn"):

@@ -18,8 +18,9 @@ rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
 rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
 rnb_tpu.models.keye_vl2) add seven mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
-scan and its convolution, in plain jnp/lax; lightning linear attention
-is its case of unit steps), ``deltanet`` (the gated delta rule, whose
+scan — one Pallas kernel that walks the rows with a step's states in
+VMEM; lightning linear attention is its case of unit steps — and its
+convolution, in plain jnp/lax), ``deltanet`` (the gated delta rule, whose
 transition is a matrix: one Pallas kernel that walks the rows with a
 head group's states in VMEM, a triangular solve inside each row),
 ``blocksparse`` (every query's own top-k blocks of keys from

@@ -1,6 +1,8 @@
 """The Mamba-2 state-space scan over a packed pool of rows, in its
-blocked (SSD) form (lightning linear attention is a case of it), and the causal depthwise convolution in front of
-it — both with the state reset where a request's first row starts.
+blocked (SSD) form, as one Pallas TPU kernel that keeps a row's ``Q x
+Q`` arrays and the carried state in VMEM (lightning linear attention is
+a case of it), and the causal depthwise convolution in front of it —
+both with the state reset where a request's first row starts.
 
 A *row* is one chunk of ``Q`` consecutive tokens (the configuration's
 ``chunk_size``); a request occupies consecutive rows of the pool and
@@ -14,27 +16,104 @@ Linear attention with a constant decay a head (lightning attention:
 ``S_t = lambda S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t``) is the same
 recurrence with unit steps and no skip term: ``dt = None``, ``A = log
 lambda``, ``xs = v``, ``B = k``, ``C = q`` (scaled by the caller), ``D
-= None``, one group a head. Its decays do not depend on the row, so
-they are computed once a head and not once a row.
+= None``, one group a head.
 
-The blocked form computes each row's own tokens as one masked
-``Q x Q`` product (the decays between two tokens of a row are
-``exp`` of a difference of cumulative sums), each row's end state as
-one product, carries states across the rows of a request by one
-``rows x rows`` matrix of decays per head, and adds what the incoming
-state gives each token. Decays, cumulative sums and states are
-float32; the products that read or build a state run at ``highest``
-precision, so that a state is never rounded to bfloat16 on its way
-through the matrix unit. The within-row products take their inputs
-in the activations' dtype and accumulate in float32.
+Inside a row, with ``cs`` the running sum of the log decays ``dt A``
+over the row's tokens and ``S_in`` the state the row receives::
+
+    y_i   = sum_{j <= i} (C_i . B_j) exp(cs_i - cs_j) dt_j xs_j
+            + exp(cs_i) C_i S_in^T + D xs_i
+    S_out = exp(cs_Q) S_in + sum_j exp(cs_Q - cs_j) dt_j xs_j (x) B_j
+
+*The kernel.* The grid is (head group, row): a grid step takes one row
+of ``_STEP_LANES`` lanes of ``xs`` — whole groups, a group's ``per = H
+/ G`` heads sharing its ``C . B^T`` — and the row axis is innermost and
+sequential, so a step's heads walk the pool's rows in order with their
+states (float32, transposed: ``N`` x a group's ``per P`` lanes) in a
+VMEM scratch that lives from one grid step to the next. ``row_first``
+is a scalar-prefetch operand: a step whose row opens a request zeroes
+the scratch before it reads it. A step reads its heads' ``xs`` and its
+groups' ``B``, ``C`` in the activations' dtype as they lie in the pool
+(a token a sublane, the heads side by side along the lanes), and in
+float32 the running sums — formed outside, a few bytes a token and
+head, in both orientations the kernel reads them, with a head's sum at
+the row's end over its lanes — and, where given, the steps and ``D``.
+In VMEM it forms ``C . B^T`` once a group, a head's decay triangle, the
+scores times ``dt_j`` rounded to the activations' dtype, their product
+with ``xs``, what the incoming state gives each token and the skip
+term, writes ``y`` once, and carries the state: one multiply-add of the
+state a row (the ``rows x rows`` matrix of decays a head that carried
+the states before PR 47 was the same recurrence in another association,
+26 GFLOP of ``highest`` product a block, with every row's state in
+HBM). Nothing of ``Q x Q`` a head, and no state, reaches HBM.
+
+Lanes: heads narrower than 128 lanes (Nemotron-H's 64) share a lane
+tile, and a head's scores multiply the tile with the other heads' lanes
+zeroed — the matrix unit's columns are 128 either way — so every slice
+of ``xs``, ``y`` and the state starts at a multiple of 128 and nothing
+is shifted along the lanes; a head's per-token factors reach the tile's
+lanes by a broadcast and a select. With unit steps the running sums are
+one block a step, fetched once, and the triangle is formed in registers
+each step (the exponentials are the transcendental unit's, beside a
+step whose time is the matrix unit's and the memory's).
+
+*The gated norm* (``gated_norm``, Nemotron-H's M block): ``y silu(z)``,
+RMS-normed over each group's ``per P`` columns, times the norm's weight
+— the lines that follow the scan in a Mamba-2 block — run on ``y`` while
+a step holds it: a norm group's columns are the columns of one of the
+step's groups. The kernel reads the gate in float32 and writes the
+activations' dtype; as XLA's
+fusions behind a kernel that wrote float32 the same lines cost four
+passes over a float32 (rows, Q, H P) array and a transposing copy of it
+(11.3 ms of a 146 ms dispatch; my chip run, PR 47).
+
+Decays, cumulative sums and states are float32; the products that read
+or build a state take float32 operands at ``highest`` precision, so
+that a state is never rounded to bfloat16 on its way through the matrix
+unit. The within-row products take their inputs in the activations'
+dtype and accumulate in float32.
+
+Alone on the v5e (``scripts/ssd_sweep.py``, the device's time; my chip
+run, PR 47): Nemotron-H's block (64 rows, 64 heads of 64 in 8 groups)
+2.159 ms as XLA's fusions -> 0.893 ms, 0.758 of it the kernel and the
+rest the running sums; a lightning layer (128 rows, 32 heads of 128)
+7.291 -> 1.338 ms.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HIGHEST = lax.Precision.HIGHEST
+
+#: the kernel's name in the device's trace and in the scope table
+KERNEL_NAME = "ssd_scan"
+
+#: lanes of ``xs`` a grid step takes (heads x P): two of Nemotron-H's
+#: groups (16 heads of 64), eight of lightning's heads of 128. The
+#: device's time a call on the v5e (``scripts/ssd_sweep.py``; my chip run,
+#: PR 47), Nemotron-H's shapes | lightning's: 512 lanes 0.982 | 1.488 ms,
+#: 1024 0.893 | 1.338, 2048 0.868 | 1.293, 4096 0.846 | 1.272 — past 1024
+#: the kernel gains 3-5% for a compile time that doubles with the lanes
+#: (the body is unrolled a head: 0.9 s, 1.5 s, 3.2 s here)
+_STEP_LANES = 1024
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _scores(a, b):
+    """``a b^T``, operands as they come, float32 accumulation."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 def segment_conv1d(x, weight, bias, row_first):
@@ -60,7 +139,134 @@ def segment_conv1d(x, weight, bias, row_first):
     return out.reshape(rows, q, c)
 
 
-def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
+def _lane_tile(per: int, p: int):
+    """(heads, lanes) of one lane tile of a group's ``per * p`` lanes of
+    ``xs``: as many whole heads as 128 lanes hold (two of Nemotron-H's
+    64, one of lightning's 128), so that every slice the kernel takes of
+    ``xs``, of the state and of ``y`` starts at a multiple of the tile
+    and none is shifted along the lanes."""
+    heads = max(1, min(per, 128 // p))
+    while per % heads:
+        heads -= 1
+    return heads, heads * p
+
+
+def _groups_a_step(groups: int, per: int, p: int) -> int:
+    """Groups a grid step: a group's heads are one step's at least (they
+    share ``c . b^T``); groups of fewer lanes than ``_STEP_LANES`` go
+    side by side, independent chains of products."""
+    want = max(1, _STEP_LANES // (per * p))
+    return max(g for g in range(1, groups + 1)
+               if groups % g == 0 and g <= want)
+
+
+def _of_tile(columns, first: int, count: int, p: int, shape):
+    """The lane tile's factor: head ``first + k``'s (Q, 1) or (1, 1)
+    entry of ``columns`` over the tile's lanes ``[k p, (k + 1) p)``."""
+    out = jnp.broadcast_to(columns[first], shape)
+    if count > 1:
+        lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+        for k in range(1, count):
+            out = jnp.where(lane >= k * p, columns[first + k], out)
+    return out
+
+
+def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
+            skip: bool, eps, state_dtype):
+    """One row of one step's groups. ``x_ref`` (Q, heads * P); ``b_ref``,
+    ``c_ref`` (Q, groups * N); ``cs_ref`` (Q, heads) the running sums of
+    the log decays, a token a sublane, ``cs_row_ref`` (heads, Q) the
+    same, a token a lane, ``end_ref`` (1, heads * P) a head's sum at the
+    row's last token over its lanes; with steps ``dt_ref``, ``dt_row_ref``
+    likewise; with a skip term ``d_ref`` (1, heads * P), a head's ``D``
+    over its lanes; with a gated norm (``eps`` not None) ``z_ref`` (Q,
+    heads * P) float32 and ``w_ref`` (1, heads * P) the norm's weight;
+    ``state_ref`` (groups, N, per * P) float32, carried: a head's state
+    transposed, ``S^T``."""
+    refs = iter(refs)
+    x_ref, b_ref, c_ref, cs_ref, cs_row_ref, end_ref = (
+        next(refs) for _ in range(6))
+    dt_ref, dt_row_ref = (next(refs), next(refs)) if steps_dt \
+        else (None, None)
+    d_ref = next(refs) if skip else None
+    z_ref, w_ref = (next(refs), next(refs)) if eps is not None \
+        else (None, None)
+    o_ref, state_ref = refs
+    f32 = jnp.float32
+    qlen = x_ref.shape[0]
+    act = x_ref.dtype
+    row = pl.program_id(1)
+
+    @pl.when((row == 0) | (first_ref[row] != 0))
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    tile_heads, lanes = _lane_tile(per, p)
+    token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
+    other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
+    own = lax.broadcasted_iota(jnp.int32, (qlen, lanes), 1) // p
+
+    def side_by_side(parts):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
+
+    for group in range(b_ref.shape[1] // n):
+        b = b_ref[:, group * n:(group + 1) * n]
+        c = c_ref[:, group * n:(group + 1) * n]
+        cb = _scores(c, b)                                   # (Q, Q)
+        heads = range(group * per, (group + 1) * per)
+        tiles = range(heads.start, heads.stop, tile_heads)
+        of_group = slice(heads.start * p, heads.stop * p)
+        x = x_ref[:, of_group]                               # (Q, per P)
+        cs = {j: cs_ref[:, j:j + 1] for j in heads}          # (Q, 1)
+        # a row's own tokens: (C_i . B_j) exp(cs_i - cs_j) dt_j, j <= i,
+        # rounded to the activations' dtype, times x; a lane tile's
+        # heads each against the tile with the others' lanes zeroed
+        ys = []
+        for at in tiles:
+            xt = x[:, (at - heads.start) * p:(at - heads.start) * p + lanes]
+            y = None
+            for k in range(tile_heads):
+                scores = cb * jnp.exp(jnp.where(
+                    token >= other, cs[at + k] - cs_row_ref[at + k:at + k + 1],
+                    -jnp.inf))
+                if steps_dt:
+                    scores = scores * dt_row_ref[at + k:at + k + 1]
+                mine = xt if tile_heads == 1 else jnp.where(
+                    own == k, xt, jnp.zeros_like(xt))
+                part = jnp.dot(scores.astype(act), mine,
+                               preferred_element_type=f32)
+                y = part if y is None else y + part
+            ys.append(y)
+        xf = x.astype(f32)
+        state = state_ref[group]                             # (N, per P)
+        since = side_by_side([_of_tile(cs, at, tile_heads, p, (qlen, lanes))
+                              for at in tiles])
+        last = end_ref[:, of_group]                          # (1, per P)
+        # what the incoming state gives each token of the row
+        y = side_by_side(ys) + _dot(c.astype(f32), state) * jnp.exp(since)
+        if skip:
+            y = y + xf * d_ref[:, of_group]
+        if eps is not None:
+            z = z_ref[:, of_group]
+            y = y * (z * jax.nn.sigmoid(z))
+            y = y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps) \
+                * w_ref[:, of_group]
+        o_ref[:, of_group] = y.astype(o_ref.dtype)
+        # the carry: one multiply-add of the state a row
+        to_end = jnp.exp(last - since)
+        if steps_dt:
+            dts = {j: dt_ref[:, j:j + 1] for j in heads}
+            to_end = to_end * side_by_side([
+                _of_tile(dts, at, tile_heads, p, (qlen, lanes))
+                for at in tiles])
+        state = jnp.exp(last) * state + _dot(b.astype(f32).T, xf * to_end)
+        # inside the kernel the pair of conversions is Mosaic's to lower,
+        # and it keeps both (``ops/deltanet.py``)
+        state_ref[group] = state.astype(state_dtype).astype(f32)
+
+
+def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
+             interpret: bool = False, gated_norm=None):
     """The scan of one block over a packed pool.
 
     ``xs`` (rows, Q, H, P); ``dt`` (rows, Q, H) float32, after its
@@ -69,64 +275,74 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32):
     ``d`` (H,) float32 or None for no skip term; ``row_first`` (rows,)
     bool. -> float32 (rows, Q, H, P).
 
+    ``gated_norm``: None, or (``z``, ``weight``, ``eps``), the Mamba-2
+    block's gated RMS norm as the kernel's last lines, on ``y`` while
+    it is in VMEM: ``g = y silu(z)``, ``g rsqrt(mean(g^2) + eps) weight``
+    with the mean over each group's ``H / G * P`` columns, in float32,
+    -> ``xs``'s dtype. ``z`` (rows, Q, H P) float32; ``weight`` (H P,).
+
     ``state_dtype`` is the precision the states are carried in
     between rows: float32 in the program; the lower-precision control
-    of the tests passes bfloat16."""
+    of the tests passes bfloat16. ``interpret`` runs the kernel in
+    interpret mode (a device that is no TPU)."""
     rows, q, heads, p = xs.shape
-    groups = b.shape[2]
+    groups, n = b.shape[2:]
     per = heads // groups
-    xg = xs.reshape(rows, q, groups, per, p)
-    # log decay a token: with unit steps the same in every row, and
-    # everything made of it keeps a leading axis of one
-    la = jnp.broadcast_to(a, (1, q, heads)) if dt is None else dt * a
-    cs = jnp.cumsum(la, axis=1)                    # (rows | 1, Q, H)
-    lead = cs.shape[0]
-    csg = cs.reshape(lead, q, groups, per)
+    f32 = jnp.float32
+    with jax.named_scope("scan"):
+        together = _groups_a_step(groups, per, p)
+        steps, mine = groups // together, together * per
 
-    # a row's own tokens: (C_i . B_j) exp(cs_i - cs_j) dt_j, j <= i;
-    # the two token axes are the minor ones, one Q x Q tile a head
-    cb = jnp.einsum("rign,rjgn->rgij", c, b,
-                    preferred_element_type=jnp.float32)
-    csh = csg.transpose(0, 2, 3, 1)                # (rows | 1, G, per, Q)
-    tril = jnp.tril(jnp.ones((q, q), bool))
-    decay = jnp.exp(jnp.where(
-        tril, csh[..., :, None] - csh[..., None, :], -jnp.inf))
-    scores = cb[:, :, None] * decay
-    to_end = jnp.exp(csg[:, -1:, :, :] - csg)
-    if dt is not None:
-        dtg = dt.reshape(rows, q, groups, per)
-        scores = scores * dtg.transpose(0, 2, 3, 1)[..., None, :]
-        to_end = to_end * dtg
-    y = jnp.einsum("rghij,rjghp->righp", scores.astype(xs.dtype), xg,
-                   preferred_element_type=jnp.float32)
+        def of_step(x):
+            """(lead, Q, H) -> (lead, steps, Q, a step's heads) and its
+            transpose: the two orientations the kernel reads."""
+            x = x.reshape(x.shape[0], q, steps, mine).transpose(0, 2, 1, 3)
+            return x, x.transpose(0, 1, 3, 2)
 
-    # each row's end state from its own tokens
-    xw = xg.astype(jnp.float32) * to_end[..., None]
-    state = jnp.einsum("rjghp,rjgn->rghpn", xw, b.astype(jnp.float32),
-                       precision=_HIGHEST)         # (rows, G, per, P, N)
+        def columns(width):
+            return pl.BlockSpec((None, q, width), lambda i, r, _: (r, 0, i))
 
-    # states carried across the rows of a request: row r receives
-    # sum over earlier rows q of its request of
-    # exp(sum of the row decays strictly between) * state_q
-    row_decay = jnp.broadcast_to(cs[:, -1, :], (rows, heads))
-    cum = jnp.cumsum(row_decay, axis=0)
-    seg = jnp.cumsum(row_first.astype(jnp.int32))
-    idx = jnp.arange(rows)
-    carry_ok = (idx[:, None] > idx[None, :]) \
-        & (seg[:, None] == seg[None, :])           # (rows r, rows q)
-    log_m = (cum - row_decay)[:, None, :] - cum[None, :, :]
-    m = jnp.exp(jnp.where(carry_ok[:, :, None], log_m, -jnp.inf))
-    state = state.astype(state_dtype).astype(jnp.float32)
-    incoming = jnp.einsum(
-        "rqgh,qghpn->rghpn", m.reshape(rows, rows, groups, per), state,
-        precision=_HIGHEST)
-    incoming = incoming.astype(state_dtype).astype(jnp.float32)
-
-    # what the incoming state gives each token of the row
-    y_in = jnp.einsum("rign,rghpn->righp", c.astype(jnp.float32),
-                      incoming, precision=_HIGHEST)
-    y = y + y_in * jnp.exp(csg)[..., None]
-    if d is not None:
-        y = y + xg.astype(jnp.float32) \
-            * d.reshape(groups, per)[None, None, :, :, None]
-    return y.reshape(rows, q, heads, p)
+        def small(*block, row=lambda r: r):
+            return pl.BlockSpec((None, None) + block,
+                                lambda i, r, _: (row(r), i, 0, 0))
+        # log decay a token; with unit steps the same in every row: one
+        # block a step, which the pipeline fetches once
+        la = jnp.broadcast_to(a.astype(f32), (1, q, heads)) if dt is None \
+            else dt.astype(f32) * a.astype(f32)
+        cs = jnp.cumsum(la, axis=1)
+        lead = (lambda r: r) if dt is not None else (lambda r: 0)
+        operands = [xs.reshape(rows, q, heads * p),
+                    b.reshape(rows, q, groups * n),
+                    c.reshape(rows, q, groups * n), *of_step(cs),
+                    jnp.repeat(cs[:, -1, :], p, axis=1)
+                    .reshape(-1, steps, 1, mine * p)]
+        specs = [columns(mine * p), columns(together * n),
+                 columns(together * n), small(q, mine, row=lead),
+                 small(mine, q, row=lead), small(1, mine * p, row=lead)]
+        if dt is not None:
+            operands += of_step(dt.astype(f32))
+            specs += [small(q, mine), small(mine, q)]
+        of_heads = pl.BlockSpec((1, mine * p), lambda i, r, _: (0, i))
+        if d is not None:
+            operands.append(jnp.repeat(d.astype(f32), p)[None, :])
+            specs.append(of_heads)
+        eps = None
+        if gated_norm is not None:
+            z, weight, eps = gated_norm
+            operands += [z, weight.astype(f32)[None, :]]
+            specs += [columns(mine * p), of_heads]
+        out = pl.pallas_call(
+            functools.partial(_kernel, per=per, p=p, n=n,
+                              steps_dt=dt is not None, skip=d is not None,
+                              eps=eps, state_dtype=state_dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(steps, rows),
+                in_specs=specs, out_specs=columns(mine * p),
+                scratch_shapes=[pltpu.VMEM((together, n, per * p), f32)]),
+            out_shape=jax.ShapeDtypeStruct(
+                (rows, q, heads * p), f32 if eps is None else xs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret, name=KERNEL_NAME,
+        )(row_first.astype(jnp.int32), *operands)
+    return out.reshape(rows, q, heads, p)

@@ -1,0 +1,155 @@
+"""``rnb_tpu.ops.ssd.ssd_scan`` alone, on the chip, at both callers'
+shapes (Nemotron-H's M block: 64 rows, 64 heads of 64 in 8 groups, N
+128, steps and a skip term; MiniCPM-SALA's lightning layer: 128 rows, 32
+heads of 128, a group a head, unit steps): a check of the kernel against
+the blocked ``jax.numpy`` form it replaced (``tests/test_ssd_kernel.py``
+keeps it) on a pool of three requests and a pad row, then the time of
+that form and of the kernel at each count of lanes a grid step
+(``ssd._STEP_LANES``), and of Nemotron-H's form with its gated norm as
+the kernel's last lines: the device's own time from a profiler trace of
+``REPEATS`` calls (the kernel's custom call, and every operation of the
+jitted scan: the running sums and their transposes are XLA's), and the
+host's clock around the calls, which at these sizes is mostly the launch.
+Lines go to stdout and to ``chiprun_out/ssd_sweep/sweep.jsonl``.
+
+    chiprun -- python3 scripts/ssd_sweep.py [--rows=N] [--lanes=512,1024]
+
+Off the TPU the kernel runs in Pallas's interpret mode, which at these
+sizes is of no use (``--rows=4`` is a dry run of the control flow).
+"""
+import json
+import os
+import sys
+import tempfile
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "tests"))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from benchmarks import xplane  # noqa: E402
+from rnb_tpu.ops import ssd  # noqa: E402
+
+OUT = os.path.join(REPO, "chiprun_out", "ssd_sweep")
+DEVICE = jax.devices()[0]
+INTERPRET = DEVICE.platform != "tpu"
+REPEATS = 10
+QLEN = 128
+
+
+def option(name, default):
+    given = [a.split("=")[1] for a in sys.argv if a.startswith(name + "=")]
+    return given[0] if given else default
+
+
+#: caller -> (rows, heads, groups, P, N, steps and a skip term?)
+CALLERS = {"nemotron_h": (64, 64, 8, 64, 128, True),
+           "lightning": (128, 32, 32, 128, 128, False)}
+LANES = [int(v) for v in option("--lanes", "512,1024,2048,4096").split(",")]
+
+
+def say(line):
+    print(json.dumps(line), flush=True)
+    os.makedirs(OUT, exist_ok=True)
+    with open(os.path.join(OUT, "sweep.jsonl"), "a") as f:
+        f.write(json.dumps(line) + "\n")
+
+
+def timed(f, *args):
+    """-> (the result, {host_ms: median by the host's clock, device_ms:
+    every operation's time a call in the device's trace, kernel_ms: the
+    kernel's custom call alone})."""
+    out = jax.block_until_ready(f(*args))
+    took = []
+    trace_dir = tempfile.mkdtemp()
+    with jax.profiler.trace(trace_dir):
+        for _ in range(REPEATS):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(*args))
+            took.append(1e3 * (time.perf_counter() - t0))
+    times = {"host_ms": round(float(np.median(took)), 3)}
+    if not INTERPRET:
+        ops = [op for plane in xplane.device_ops(
+            xplane.find_xplane(trace_dir)).values() for op in plane]
+        for key, mine in (("device_ms", ops), ("kernel_ms", [
+                op for op in ops if ssd.KERNEL_NAME in op[2]])):
+            times[key] = round(sum(end - start for start, end, _ in mine)
+                               / REPEATS / 1e6, 4)
+    return out, times
+
+
+def operands(caller, rows, seed):
+    """The heads' lanes side by side, as the callers hold them."""
+    _, heads, groups, p, n, full = CALLERS[caller]
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    first = np.zeros(rows, bool)
+    first[[0, rows // 3, max(rows - 2, 0), rows - 1]] = True
+    a = -jnp.asarray(rng.uniform(0.01, 0.3, heads), jnp.float32)
+    dt = d = None
+    if full:
+        dt = jnp.asarray(rng.uniform(0.005, 0.1, (rows, QLEN, heads)),
+                         jnp.float32)
+        d = jnp.asarray(rng.standard_normal(heads), jnp.float32)
+    return (draw(rows, QLEN, heads * p), dt, a, draw(rows, QLEN, groups * n),
+            draw(rows, QLEN, groups * n), d, jnp.asarray(first))
+
+
+def scan_of(caller, scan, **kwargs):
+    _, heads, groups, p, n, _ = CALLERS[caller]
+
+    def run(xs, dt, a, b, c, d, first):
+        rows = xs.shape[0]
+        return scan(xs.reshape(rows, QLEN, heads, p), dt, a,
+                    b.reshape(rows, QLEN, groups, n),
+                    c.reshape(rows, QLEN, groups, n), d, first,
+                    **kwargs).reshape(rows, QLEN, heads * p)
+    return jax.jit(run)
+
+
+def main():
+    from test_ssd_kernel import blocked
+    say({"device": DEVICE.device_kind, "platform": DEVICE.platform})
+    chosen = ssd._STEP_LANES
+    for caller, shape in CALLERS.items():
+        rows = int(option("--rows", shape[0]))
+        args = operands(caller, rows, 47)
+        want, times = timed(scan_of(caller, blocked), *args)
+        say({"caller": caller, "rows": rows, "form": "blocked", **times})
+        want = np.asarray(want)
+        for lanes in LANES:
+            ssd._STEP_LANES = lanes
+            got, times = timed(
+                scan_of(caller, ssd.ssd_scan, interpret=INTERPRET), *args)
+            worst = float(np.abs(np.asarray(got) - want).max()
+                          / (1.0 + np.abs(want).max()))
+            say({"caller": caller, "rows": rows, "form": "kernel",
+                 "step_lanes": lanes,
+                 "groups_a_step": ssd._groups_a_step(shape[2],
+                                                     shape[1] // shape[2],
+                                                     shape[3]),
+                 **times, "worst_vs_blocked": worst})
+            assert worst < 5e-3, worst
+        ssd._STEP_LANES = chosen
+        if shape[5]:
+            rng = np.random.default_rng(48)
+            width = shape[1] * shape[3]
+            gated_norm = (
+                jnp.asarray(rng.standard_normal((rows, QLEN, width)),
+                            jnp.float32),
+                jnp.asarray(rng.uniform(0.5, 1.5, width), jnp.bfloat16), 1e-5)
+            _, times = timed(scan_of(caller, ssd.ssd_scan, interpret=INTERPRET,
+                                     gated_norm=gated_norm), *args)
+            say({"caller": caller, "rows": rows,
+                 "form": "kernel with the gated norm", "step_lanes": chosen,
+                 **times})
+
+
+if __name__ == "__main__":
+    main()

@@ -1127,7 +1127,9 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     the five older families' toy stacks lowers to the StableHLO text the
     parent's tree gave (its SHA-256 under ``tests/recorded``, as PR 45
     left it), and this family's to the text of the tree that brought it,
-    for the next PR to hold."""
+    for the next PR to hold. PR 47 recorded ``nemotron_h``'s and
+    ``minicpm_sala``'s again (``ops/ssd.ssd_scan`` became one Pallas
+    kernel); the other four are the texts their PRs left."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

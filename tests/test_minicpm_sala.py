@@ -429,7 +429,7 @@ def test_lightning_through_the_scan_matches_the_recurrence(toy, lengths):
     got = network.lightning_mixer(
         toy["cfg"], toy["params"]["l1"], h,
         jnp.asarray(row_start) == jnp.arange(rows),
-        rope.pool_positions(jnp.asarray(row_start), Q))
+        rope.pool_positions(jnp.asarray(row_start), Q), interpret=True)
     got = np.asarray(got).reshape(rows * Q, -1)
     flat = h.reshape(rows * Q, -1).astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -448,68 +448,36 @@ def test_the_decays_are_the_stated_slopes(toy):
     assert cfg.logit_scale == pytest.approx(16 / 64)
 
 
-def ssd_scan_before_pr35(xs, dt, a, b, c, d, row_first):
-    """``ops/ssd.ssd_scan`` as it stood before it took unit steps (PR
-    34's tree), copied."""
-    import jax.numpy as jnp
-    from jax import lax
-    highest = lax.Precision.HIGHEST
-    rows, q, heads, p = xs.shape
-    groups = b.shape[2]
-    per = heads // groups
-    xg = xs.reshape(rows, q, groups, per, p)
-    la = dt * a
-    cs = jnp.cumsum(la, axis=1)
-    csg = cs.reshape(rows, q, groups, per)
-    dtg = dt.reshape(rows, q, groups, per)
-    cb = jnp.einsum("rign,rjgn->rgij", c, b,
-                    preferred_element_type=jnp.float32)
-    csh = csg.transpose(0, 2, 3, 1)
-    dth = dtg.transpose(0, 2, 3, 1)
-    tril = jnp.tril(jnp.ones((q, q), bool))
-    decay = jnp.exp(jnp.where(
-        tril, csh[..., :, None] - csh[..., None, :], -jnp.inf))
-    scores = cb[:, :, None] * decay * dth[..., None, :]
-    y = jnp.einsum("rghij,rjghp->righp", scores.astype(xs.dtype), xg,
-                   preferred_element_type=jnp.float32)
-    to_end = jnp.exp(csg[:, -1:, :, :] - csg) * dtg
-    xw = xg.astype(jnp.float32) * to_end[..., None]
-    state = jnp.einsum("rjghp,rjgn->rghpn", xw, b.astype(jnp.float32),
-                       precision=highest)
-    row_decay = cs[:, -1, :]
-    cum = jnp.cumsum(row_decay, axis=0)
-    seg = jnp.cumsum(row_first.astype(jnp.int32))
-    idx = jnp.arange(rows)
-    carry_ok = (idx[:, None] > idx[None, :]) \
-        & (seg[:, None] == seg[None, :])
-    log_m = (cum - row_decay)[:, None, :] - cum[None, :, :]
-    m = jnp.exp(jnp.where(carry_ok[:, :, None], log_m, -jnp.inf))
-    incoming = jnp.einsum(
-        "rqgh,qghpn->rghpn", m.reshape(rows, rows, groups, per), state,
-        precision=highest)
-    y_in = jnp.einsum("rign,rghpn->righp", c.astype(jnp.float32),
-                      incoming, precision=highest)
-    y = y + y_in * jnp.exp(csg)[..., None]
-    y = y + xg.astype(jnp.float32) \
-        * d.reshape(groups, per)[None, None, :, :, None]
-    return y.reshape(rows, q, heads, p)
-
-
 def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
     """One scan, two callers. Nemotron-H's toy M block over a pool of
-    five requests gives bit-equal output through ``ssd_scan`` as it is
-    and as it stood before it took unit steps (copied above), and the
-    array PR 34's tree gave on the same inputs, recorded under
-    ``tests/recorded``, is that output (to the last bits: a recorded
-    array crosses machines, whose compilers may fuse differently). The
-    lightning form — unit steps, a constant decay a head, no skip term
-    — equals the recurrence written out token by token."""
+    five requests gives the same output through ``ssd_scan`` as it is
+    (one Pallas kernel since PR 47: the carry one multiply-add a row,
+    the gate and the gated norm its last lines) and through the blocked
+    ``jax.numpy`` form it replaced (``test_ssd_kernel.blocked``, which
+    PR 35 showed bit-equal to the form before unit steps) with the gate
+    and the norm as the mixer wrote them, float32's rounding apart, and
+    the array PR 34's tree gave on the same inputs, recorded under
+    ``tests/recorded``, is that output.
+    The lightning form — unit steps, a constant decay a head, no skip
+    term — equals the recurrence written out token by token."""
     import jax
     import jax.numpy as jnp
 
     import test_nemotron_h as nemotron
     from rnb_tpu.models.nemotron_h import checkpoint, network
     from rnb_tpu.ops import ssd
+    from test_ssd_kernel import blocked
+
+    def blocked_with_the_norm(xs, dt, a, b, c, d, first, state_dtype=None,
+                              interpret=False, gated_norm=None):
+        z, weight, eps = gated_norm
+        rows, q, heads, p = xs.shape
+        y = blocked(xs, dt, a, b, c, d, first).reshape(rows, q, -1) \
+            * jax.nn.silu(z)
+        yg = y.reshape(rows, q, b.shape[2], -1)
+        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
+        return (yg.reshape(rows, q, -1) * weight.astype(jnp.float32)) \
+            .astype(xs.dtype).reshape(rows, q, heads, p)
     cfg = network.NemotronHConfig.from_published(nemotron.TOY)
     block = checkpoint.make_params(cfg, nemotron.SEED, nemotron.HELD,
                                    jax.devices()[0], groups=["b0"])["b0"]
@@ -517,16 +485,19 @@ def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
     h = jnp.asarray(rng.standard_normal((8, nemotron.Q, cfg.hidden_size)),
                     jnp.bfloat16)
     row_first = jnp.asarray([1, 0, 0, 1, 1, 0, 1, 1], bool)
-    now = np.asarray(network.mamba_mixer(cfg, block, h, row_first))
-    monkeypatch.setattr(
-        ssd, "ssd_scan", lambda *args, state_dtype=None:
-        ssd_scan_before_pr35(*args))
+    now = np.asarray(network.mamba_mixer(cfg, block, h, row_first,
+                                         interpret=True))
+    monkeypatch.setattr(ssd, "ssd_scan", blocked_with_the_norm)
     before = np.asarray(network.mamba_mixer(cfg, block, h, row_first))
     monkeypatch.undo()
-    assert np.array_equal(now, before)
+    # the mixer's output is rounded to bfloat16 on its way into
+    # ``out_proj``: an element of the scan that falls the other side of a
+    # rounding boundary moves the product by that element's last bit
+    assert np.abs(now - before).max() <= 2e-3 * np.abs(before).max()
+    assert (now != before).mean() < 0.05
     recorded = np.load(os.path.join(REPO, "tests", "recorded",
                                     "nemotron_toy_mamba_mixer.npy"))
-    assert np.abs(now - recorded).max() <= 1e-6 * np.abs(recorded).max()
+    assert np.abs(now - recorded).max() <= 2e-3 * np.abs(recorded).max()
 
     # the lightning form against the recurrence, the scan alone
     heads, dim, rows = 4, 16, 6
@@ -534,7 +505,8 @@ def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
                            jnp.float32) for _ in range(3))
     log_decay = jnp.asarray(toy["cfg"].log_decay())
     first = jnp.asarray([1, 0, 0, 1, 0, 1], bool)
-    got = np.asarray(ssd.ssd_scan(v, None, log_decay, k, q, None, first))
+    got = np.asarray(ssd.ssd_scan(v, None, log_decay, k, q, None, first,
+                                  interpret=True))
     state = np.zeros((heads, dim, dim))
     lam = np.exp(np.asarray(log_decay, np.float64))[:, None, None]
     for r in range(rows):

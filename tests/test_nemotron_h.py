@@ -131,8 +131,8 @@ def block_inputs(toy, length, kind):
 @pytest.mark.parametrize("length", [5, 16, 37, 100])
 def test_mamba_mixer_and_its_blocked_scan_match_the_recurrence(toy, length):
     """Lengths that are no multiple of the chunk: the blocked scan
-    (rows of 16, states carried between them) against the plain
-    recurrence over t."""
+    (rows of 16 in the kernel of ``ops/ssd.py``, states carried between
+    them) against the plain recurrence over t."""
     import jax
     import jax.numpy as jnp
 
@@ -140,7 +140,7 @@ def test_mamba_mixer_and_its_blocked_scan_match_the_recurrence(toy, length):
     h, rows, params, weights = block_inputs(toy, length, "M")
     first = jnp.arange(rows) == 0
     got = network.mamba_mixer(toy["cfg"], params,
-                              h.reshape(rows, Q, -1), first)
+                              h.reshape(rows, Q, -1), first, interpret=True)
     with jax.default_matmul_precision("highest"):
         want = reference.mamba(TOY, weights, h.astype(jnp.float32)[:length])
     got = np.asarray(got).reshape(rows * Q, -1)[:length]

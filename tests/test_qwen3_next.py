@@ -929,8 +929,9 @@ def test_the_older_families_lower_to_the_text_the_parent_gave(family):
     moves one of them on purpose records the new text and shows those
     cells on the chip: PR 44 recorded the two expert families' again
     (``forward`` returns ``gmm_rows``, the rows the first grouped
-    product multiplied; ``minicpm_sala``'s, a stack without experts, is
-    the text PR 38's tree gave)."""
+    product multiplied); PR 47 recorded ``nemotron_h``'s and
+    ``minicpm_sala``'s again (``ops/ssd.ssd_scan`` is one Pallas kernel,
+    interpreted here; ``deepseek_v2``'s is the text PR 44 recorded)."""
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
         recorded = json.load(f)

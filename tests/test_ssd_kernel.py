@@ -1,0 +1,313 @@
+"""``ops/ssd.ssd_scan``, the state-space scan as one Pallas kernel, in
+interpret mode at tiny shapes against the recurrence written out token
+by token: both callers' forms (Nemotron-H's: steps and a skip term,
+eight heads of 64 a group; lightning's: unit steps, a group a head of
+128), pools whose requests span rows, share a pool and are parted by a
+pad row, packing, the carried state's precision, the blocked
+``jax.numpy`` form the kernel replaced (kept here as an oracle), and
+the M block's gated norm as the kernel's last lines. Then,
+where a v5e can be described, the compile of the kernel at both
+callers' real shapes (the topology inside a fixture)."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+Q, N = 16, 16
+
+#: name -> (heads, groups, P, steps and skip term?)
+FORMS = {"mamba": (8, 1, 64, True), "mamba_two_groups": (16, 2, 64, True),
+         "lightning": (2, 2, 128, False), "narrow_heads": (8, 2, 8, True)}
+
+#: name -> the rows that open a request (a pad row opens its own)
+POOLS = {"one_row": [1], "a_request_over_rows": [1, 0, 0, 0],
+         "two_requests": [1, 0, 0, 1, 0], "a_pad_row_between": [1, 0, 1, 1, 0],
+         "every_row_its_own": [1, 1, 1]}
+
+
+def inputs(form, rows, seed=0, dtype="bfloat16"):
+    """-> (xs, dt | None, a, b, c, d | None) as ``ssd_scan`` takes them."""
+    import jax.numpy as jnp
+    heads, groups, p, full = FORMS[form]
+    rng = np.random.default_rng(seed)
+    xs, b, c = (jnp.asarray(rng.standard_normal(shape), dtype)
+                for shape in ((rows, Q, heads, p), (rows, Q, groups, N),
+                              (rows, Q, groups, N)))
+    a = jnp.asarray(-rng.uniform(0.05, 1.0, heads), jnp.float32)
+    if not full:
+        return xs, None, a, b, c, None
+    dt = jnp.asarray(rng.uniform(0.01, 0.5, (rows, Q, heads)), jnp.float32)
+    return xs, dt, a, b, c, jnp.asarray(rng.standard_normal(heads),
+                                        jnp.float32)
+
+
+def recurrence(xs, dt, a, b, c, d, first):
+    """``S_t = exp(dt_t A) S_{t-1} + dt_t xs_t (x) B_t``, ``y_t = S_t C_t
+    + D xs_t`` in float64, the state zero where ``first`` says so."""
+    xs, b, c = (np.asarray(v.astype("float32"), np.float64)
+                for v in (xs, b, c))
+    rows, q, heads, p = xs.shape
+    per = heads // b.shape[2]
+    a = np.asarray(a, np.float64)
+    dt = np.ones((rows, q, heads)) if dt is None else np.asarray(dt,
+                                                                  np.float64)
+    out = np.zeros(xs.shape)
+    state = np.zeros((heads, p, b.shape[3]))
+    for r in range(rows):
+        if first[r]:
+            state[:] = 0.0
+        for t in range(q):
+            bt, ct = (np.repeat(v[r, t], per, axis=0) for v in (b, c))
+            state = np.exp(dt[r, t] * a)[:, None, None] * state \
+                + (dt[r, t][:, None] * xs[r, t])[:, :, None] * bt[:, None, :]
+            out[r, t] = np.einsum("hpn,hn->hp", state, ct)
+            if d is not None:
+                out[r, t] += np.asarray(d, np.float64)[:, None] * xs[r, t]
+    return out
+
+
+def blocked(xs, dt, a, b, c, d, row_first, state_dtype=None):
+    """The blocked ``jax.numpy`` form that was ``ops/ssd.ssd_scan`` until
+    PR 47: a row's tokens as one masked ``Q x Q`` product, the states
+    carried by one ``rows x rows`` matrix of decays a head."""
+    import jax.numpy as jnp
+    from jax import lax
+    state_dtype = state_dtype or jnp.float32
+    highest = lax.Precision.HIGHEST
+    rows, q, heads, p = xs.shape
+    groups = b.shape[2]
+    per = heads // groups
+    xg = xs.reshape(rows, q, groups, per, p)
+    la = jnp.broadcast_to(a, (1, q, heads)) if dt is None else dt * a
+    cs = jnp.cumsum(la, axis=1)
+    csg = cs.reshape(cs.shape[0], q, groups, per)
+    cb = jnp.einsum("rign,rjgn->rgij", c, b,
+                    preferred_element_type=jnp.float32)
+    csh = csg.transpose(0, 2, 3, 1)
+    tril = jnp.tril(jnp.ones((q, q), bool))
+    decay = jnp.exp(jnp.where(
+        tril, csh[..., :, None] - csh[..., None, :], -jnp.inf))
+    scores = cb[:, :, None] * decay
+    to_end = jnp.exp(csg[:, -1:, :, :] - csg)
+    if dt is not None:
+        dtg = dt.reshape(rows, q, groups, per)
+        scores = scores * dtg.transpose(0, 2, 3, 1)[..., None, :]
+        to_end = to_end * dtg
+    y = jnp.einsum("rghij,rjghp->righp", scores.astype(xs.dtype), xg,
+                   preferred_element_type=jnp.float32)
+    xw = xg.astype(jnp.float32) * to_end[..., None]
+    state = jnp.einsum("rjghp,rjgn->rghpn", xw, b.astype(jnp.float32),
+                       precision=highest)
+    row_decay = jnp.broadcast_to(cs[:, -1, :], (rows, heads))
+    cum = jnp.cumsum(row_decay, axis=0)
+    seg = jnp.cumsum(row_first.astype(jnp.int32))
+    idx = jnp.arange(rows)
+    carry_ok = (idx[:, None] > idx[None, :]) \
+        & (seg[:, None] == seg[None, :])
+    log_m = (cum - row_decay)[:, None, :] - cum[None, :, :]
+    m = jnp.exp(jnp.where(carry_ok[:, :, None], log_m, -jnp.inf))
+    state = state.astype(state_dtype).astype(jnp.float32)
+    incoming = jnp.einsum(
+        "rqgh,qghpn->rghpn", m.reshape(rows, rows, groups, per), state,
+        precision=highest)
+    incoming = incoming.astype(state_dtype).astype(jnp.float32)
+    y_in = jnp.einsum("rign,rghpn->righp", c.astype(jnp.float32),
+                      incoming, precision=highest)
+    y = y + y_in * jnp.exp(csg)[..., None]
+    if d is not None:
+        y = y + xg.astype(jnp.float32) \
+            * d.reshape(groups, per)[None, None, :, :, None]
+    return y.reshape(rows, q, heads, p)
+
+
+def scan(args, first, **kwargs):
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import ssd
+    return np.asarray(ssd.ssd_scan(*args, jnp.asarray(first, bool),
+                                   interpret=True, **kwargs))
+
+
+def worst(got, want):
+    return np.abs(got - want).max() / (1.0 + np.abs(want).max())
+
+
+# -- the kernel against the recurrence ------------------------------------------
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_kernel_is_the_recurrence(form, pool):
+    """The state is reset at ``row_first`` and nowhere else: a request
+    that spans rows carries it, the next request and a pad row start
+    from zero. The scores of a row are rounded to bfloat16 once, as the
+    configuration states: 2e-2 of the largest output."""
+    first = POOLS[pool]
+    args = inputs(form, len(first), seed=len(first))
+    got = scan(args, first)
+    assert got.dtype == np.float32 and got.shape == args[0].shape
+    assert worst(got, recurrence(*args, first)) < 2e-2
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_float32_inputs_meet_the_recurrence_closely(form):
+    """With float32 activations nothing is rounded to bfloat16 but the
+    within-row products' passes on the CPU's default precision are
+    float32 too: the kernel's algebra, to float32's last digits."""
+    first = POOLS["two_requests"]
+    args = inputs(form, len(first), seed=7, dtype="float32")
+    assert worst(scan(args, first), recurrence(*args, first)) < 2e-5
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_packing_is_invisible(form):
+    """A request alone equals the same request packed behind another
+    and a pad row, bit for bit: nothing crosses ``row_first``."""
+    alone = inputs(form, 2, seed=11)
+    ahead = inputs(form, 3, seed=12)
+    packed = tuple(
+        None if mine is None else mine if mine.ndim == 1
+        else np.concatenate([np.asarray(other.astype("float32")),
+                             np.asarray(mine.astype("float32"))])
+        .astype(mine.dtype)
+        for other, mine in zip(ahead, alone))
+    import jax.numpy as jnp
+    packed = tuple(v if v is None else jnp.asarray(v) for v in packed)
+    got = scan(packed, [1, 0, 1, 1, 0])[3:]
+    assert np.array_equal(got, scan(alone, [1, 0]))
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_first_row_starts_from_zero_whatever_it_says(form):
+    """Row 0 has nothing before it: a pool whose first row does not say
+    it opens a request reads no state another step's heads left."""
+    args = inputs(form, 3, seed=5)
+    assert np.array_equal(scan(args, [0, 0, 1]), scan(args, [1, 0, 1]))
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_a_bfloat16_state_moves_the_result(form):
+    """``state_dtype`` still rounds the carried state: the lower
+    precision control has something to fail on, and only where a state
+    was carried."""
+    import jax.numpy as jnp
+    first = [1, 0, 0, 1]
+    args = inputs(form, len(first), seed=3)
+    exact = scan(args, first)
+    rounded = scan(args, first, state_dtype=jnp.bfloat16)
+    for row in (0, 3):
+        assert np.array_equal(exact[row], rounded[row])
+    moved = worst(rounded[1:3], exact[1:3])
+    assert 1e-4 < moved < 5e-2, moved
+    # with float32 activations the carried state's rounding is the
+    # whole of the distance to the recurrence
+    args = inputs(form, len(first), seed=3, dtype="float32")
+    want = recurrence(*args, first)
+    assert worst(scan(args, first, state_dtype=jnp.bfloat16), want) \
+        > 100 * worst(scan(args, first), want)
+
+
+@pytest.mark.parametrize("pool", ["a_request_over_rows", "a_pad_row_between"])
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_kernel_meets_the_blocked_form_it_replaced(form, pool):
+    """The same recurrence in another association (the carry one
+    multiply-add a row, not a ``rows x rows`` matrix of decays): float32
+    rounding apart, the outputs agree."""
+    import jax.numpy as jnp
+    first = POOLS[pool]
+    args = inputs(form, len(first), seed=21)
+    want = np.asarray(blocked(*args, jnp.asarray(first, bool)))
+    assert worst(scan(args, first), want) < 1e-5
+
+
+@pytest.mark.parametrize("pool", ["one_row", "two_requests"])
+@pytest.mark.parametrize("form", ["mamba", "mamba_two_groups",
+                                  "narrow_heads"])
+def test_the_gated_norm_is_the_kernels_last_lines(form, pool):
+    """``gated_norm``: ``y silu(z)``, RMS-normed over each group's
+    columns, times the weight, written in the activations' dtype — the
+    same float32 lines on the scan's float32 output."""
+    import jax
+    import jax.numpy as jnp
+    first = POOLS[pool]
+    args = inputs(form, len(first), seed=9)
+    rows, q, heads, p = args[0].shape
+    groups = args[3].shape[2]
+    rng = np.random.default_rng(1)
+    z = jnp.asarray(rng.standard_normal((rows, q, heads * p)), jnp.float32)
+    weight = jnp.asarray(rng.uniform(0.5, 1.5, heads * p), jnp.bfloat16)
+    got = scan(args, first, gated_norm=(z, weight, 1e-5))
+    assert got.dtype == args[0].dtype and got.shape == args[0].shape
+    y = scan(args, first).reshape(rows, q, heads * p) \
+        * np.asarray(jax.nn.silu(z))
+    yg = y.reshape(rows, q, groups, -1)
+    yg = yg / np.sqrt(np.mean(yg * yg, -1, keepdims=True) + 1e-5)
+    want = yg.reshape(rows, q, heads * p) \
+        * np.asarray(weight.astype(jnp.float32))
+    got = np.asarray(got.astype(jnp.float32)).reshape(want.shape)
+    # one rounding to bfloat16: 2^-8 of an element
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max() + 1e-6
+
+
+# -- the real shapes, compiled for a described v5e ------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+#: name -> (rows, Q, heads, groups, P, N, steps and skip term?)
+REAL = {"nemotron_h": (64, 128, 64, 8, 64, 128, True),
+        "lightning": (128, 128, 32, 32, 128, 128, False)}
+
+
+@pytest.mark.parametrize("caller", sorted(REAL))
+def test_the_kernel_compiles_at_a_callers_shapes(one_chip, caller):
+    """Mosaic takes the kernel at the real widths (nothing runs), and
+    nothing of ``Q x Q`` a head or of a state is left for XLA: the
+    program's temporaries are the running sums' few bytes a token."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import ssd
+    rows, q, heads, groups, p, n, full = REAL[caller]
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    per_token = of((rows, q, heads), jnp.float32)
+    per_head = of((heads,), jnp.float32)
+    # the heads' lanes side by side on both sides, as the callers hold
+    # them: the reshapes are the compiler's to cancel
+    # (Nemotron-H's with its gated norm)
+    compiled = jax.jit(
+        lambda xs, dt, a, b, c, d, first, z, w: ssd.ssd_scan(
+            xs.reshape(rows, q, heads, p), dt, a,
+            b.reshape(rows, q, groups, n), c.reshape(rows, q, groups, n),
+            d, first, gated_norm=(z, w, 1e-5) if full else None)
+        .reshape(rows, q, heads * p)).lower(
+        of((rows, q, heads * p)), per_token if full else None, per_head,
+        of((rows, q, groups * n)), of((rows, q, groups * n)),
+        per_head if full else None, of((rows,), jnp.bool_),
+        of((rows, q, heads * p), jnp.float32) if full else None,
+        of((heads * p,)) if full else None).compile()
+    assert ssd.KERNEL_NAME in compiled.as_text()
+    out_bytes = rows * q * heads * p * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < out_bytes // 8
