@@ -141,12 +141,15 @@ def mamba_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
     z, xbc, dt = (_proj(h, p["in_proj"][:, lo:hi])
                   for lo, hi in zip(edges, edges[1:]))
     xbc = xbc.astype(act)
-    xbc = jax.nn.silu(ssd.segment_conv1d(
-        xbc, p["conv_w"], p["conv_b"], row_first)).astype(act)
-    xs = xbc[..., :cfg.d_inner].reshape(rows, q, heads, hd)
-    b = xbc[..., cfg.d_inner:cfg.d_inner + groups * n] \
-        .reshape(rows, q, groups, n)
-    c = xbc[..., cfg.d_inner + groups * n:].reshape(rows, q, groups, n)
+    # xs, B and C each leave the convolution's kernel as an array of
+    # their own: the scan's kernel reads them so, and a slice of the
+    # convolution's result would be a copy in HBM
+    xs, b, c = ssd.segment_conv1d(
+        xbc, p["conv_w"], p["conv_b"], row_first, activation="silu",
+        out_dtype=act, interpret=interpret,
+        split=(cfg.d_inner, groups * n, groups * n))
+    xs = xs.reshape(rows, q, heads, hd)
+    b, c = b.reshape(rows, q, groups, n), c.reshape(rows, q, groups, n)
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
     # the gate and the gated norm (RMS over each of the n_groups groups,

@@ -191,23 +191,29 @@ def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
     act = h.dtype
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    qkvz = _proj(h, p["in_qkvz"])
+    # in_qkvz's columns as two products, qkv and z: of one float32 result
+    # of 12,288 columns the convolution's kernel would read a rounded
+    # slice, a pass of its own over them (PERF.md section 6, PR 48); its
+    # own product rounds qkv as it writes
+    qkv = _proj(h, p["in_qkvz"][:, :cfg.conv_dim]).astype(act)
+    z = _proj(h, p["in_qkvz"][:, cfg.conv_dim:])
     ba = _proj(h, p["in_ba"])
-    z = qkvz[..., cfg.conv_dim:]
-    qkv = jax.nn.silu(ssd.segment_conv1d(
-        qkvz[..., :cfg.conv_dim].astype(act), p["conv_w"],
-        jnp.zeros((cfg.conv_dim,), jnp.float32), row_first))
-    qs = l2_norm(qkv[..., :cfg.key_dim].reshape(rows, q, hk, dk)) \
+    # no bias; q and k are normalised in float32 behind the SiLU, so the
+    # kernel writes them in float32; v is rounded at once, by the kernel
+    qk, vs = ssd.segment_conv1d(
+        qkv, p["conv_w"], None, row_first, activation="silu",
+        out_dtype=(jnp.float32, act), interpret=interpret,
+        split=(2 * cfg.key_dim, cfg.value_dim))
+    qs = l2_norm(qk[..., :cfg.key_dim].reshape(rows, q, hk, dk)) \
         * dk ** -0.5
-    ks = l2_norm(qkv[..., cfg.key_dim:2 * cfg.key_dim]
-                 .reshape(rows, q, hk, dk))
-    vs = qkv[..., 2 * cfg.key_dim:].reshape(rows, q, hv, dv)
+    ks = l2_norm(qk[..., cfg.key_dim:].reshape(rows, q, hk, dk))
+    vs = vs.reshape(rows, q, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv])
     log_alpha = -jnp.exp(p["a_log"].astype(jnp.float32)) \
         * jax.nn.softplus(ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
     with jax.named_scope("rule"):
         out = deltanet.gated_delta_rule(
-            qs.astype(act), ks.astype(act), vs.astype(act), log_alpha, beta,
+            qs.astype(act), ks.astype(act), vs, log_alpha, beta,
             row_first, state_dtype=state_dtype, interpret=interpret)
     out = rms_norm(out, p["o_norm"], cfg.eps, jnp.float32, centred=False) \
         .reshape(rows, q, cfg.value_dim)

@@ -19,8 +19,9 @@ rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
 rnb_tpu.models.keye_vl2) add seven mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan — one Pallas kernel that walks the rows with a step's states in
-VMEM; lightning linear attention is its case of unit steps — and its
-convolution, in plain jnp/lax), ``deltanet`` (the gated delta rule, whose
+VMEM; lightning linear attention is its case of unit steps — and the
+causal convolution in front of it, a second Pallas kernel: the taps, the
+bias and the SiLU in one pass over the activations), ``deltanet`` (the gated delta rule, whose
 transition is a matrix: one Pallas kernel that walks the rows with a
 head group's states in VMEM, a triangular solve inside each row),
 ``blocksparse`` (every query's own top-k blocks of keys from

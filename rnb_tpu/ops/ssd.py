@@ -1,8 +1,9 @@
 """The Mamba-2 state-space scan over a packed pool of rows, in its
 blocked (SSD) form, as one Pallas TPU kernel that keeps a row's ``Q x
 Q`` arrays and the carried state in VMEM (lightning linear attention is
-a case of it), and the causal depthwise convolution in front of it —
-both with the state reset where a request's first row starts.
+a case of it), and the causal depthwise convolution in front of it as
+a second kernel — both with the state reset where a request's first row
+starts.
 
 A *row* is one chunk of ``Q`` consecutive tokens (the configuration's
 ``chunk_size``); a request occupies consecutive rows of the pool and
@@ -73,6 +74,34 @@ that a state is never rounded to bfloat16 on its way through the matrix
 unit. The within-row products take their inputs in the activations'
 dtype and accumulate in float32.
 
+*The convolution* (``segment_conv1d``; in front of Nemotron-H's scan
+and of Qwen3-Next's delta rule): K taps a channel, a bias, the SiLU. One
+kernel reads the activations once in their own dtype, forms the taps,
+the bias and the activation in float32 in VMEM and writes once, each of
+the arrays its caller takes (``split``: Nemotron-H's xs, B and C;
+Qwen3-Next's q with k in float32 and v in the activations' dtype) as an
+array of its own in the dtype the caller rounds it to; as XLA's fusions
+the same lines were four float32 passes over the (tokens, channels)
+array with three shifted copies of it, and the caller's SiLU, slices
+and rounding passes of their own. The grid is (step of ``_CONV_ROWS``
+whole rows, lane tile); the kernel's body takes a row of ``_CONV_CHUNK``
+lanes at a time — 16 float32 registers of ``x``, the row before's last
+sublane tile on top — and a tap is a sublane roll of that. Alone on the
+v5e (``scripts/ssd_sweep.py``, the device's time; my chip runs, PR 48),
+Nemotron-H's block (64 rows of 6,144 channels; its bytes are 0.25 ms) |
+Qwen3-Next's layer (128 rows of 8,192; 0.82 ms): the passes with the
+slices behind them 2.333 | 8.084 ms; the kernel at 16 rows x 512 lanes
+a step 0.413 | 1.121; over rows a step 4 / 8 / 16 at 512 lanes 0.437 /
+0.417 / 0.413 | 1.239 / 1.147 / 1.121, over lanes 512 / 1,024 / 2,048
+at 16 rows 0.413 / 0.444 / 0.444 | 1.121 / 1.148 / -; the body over 128
+/ 256 / 512 lanes at once, at 8 rows x 1,024 lanes, 0.439 / 0.428 /
+0.478 | 1.152 / 1.166 / 1.242 (a row of 1,024 lanes whole, the first
+form: the vector unit's registers spill). A call a part, each over its
+own channels of ``x``, ran 0.345 | 1.050 at 8 x 1,024 (the store behind
+a branch on the part costs the body its straight line) and set a stage
+up 3 s slower, warm, for 1.5 s as one call: a stage's set-up grows with
+the kernel bodies its programs hold, three a block or one.
+
 Alone on the v5e (``scripts/ssd_sweep.py``, the device's time; my chip
 run, PR 47): Nemotron-H's block (64 rows, 64 heads of 64 in 8 groups)
 2.159 ms as XLA's fusions -> 0.893 ms, 0.758 of it the kernel and the
@@ -83,6 +112,7 @@ rest the running sums; a lightning layer (128 rows, 32 heads of 128)
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +134,16 @@ KERNEL_NAME = "ssd_scan"
 #: (the body is unrolled a head: 0.9 s, 1.5 s, 3.2 s here)
 _STEP_LANES = 1024
 
+#: the convolution's kernel in the device's trace
+CONV_KERNEL_NAME = "segment_conv1d"
+
+#: rows and lanes of ``x`` a grid step of the convolution takes
+_CONV_ROWS = 16
+_CONV_LANES = 512
+#: lanes of a row the kernel's body holds at once: a row's 128 tokens of
+#: 128 lanes are 16 float32 registers
+_CONV_CHUNK = 128
+
 
 def _dot(a, b):
     return jnp.dot(a, b, precision=_HIGHEST,
@@ -116,27 +156,147 @@ def _scores(a, b):
                            preferred_element_type=jnp.float32)
 
 
-def segment_conv1d(x, weight, bias, row_first):
-    """Causal depthwise convolution along the packed token axis.
+def _conv_kernel(first_ref, x_ref, before_ref, w_ref, *refs, activation,
+                 edges):
+    """One step's rows of one lane tile. ``x_ref`` (R, Q, lanes);
+    ``before_ref`` (history, lanes), the last tokens of the row before
+    the step's first; ``w_ref`` (K, lanes) float32, ``w_ref[K-1]`` on
+    the current token; with a bias ``b_ref`` (1, lanes) float32; an
+    output a part, the part's lane tiles ``[edges[p], edges[p + 1])`` of
+    the grid's: a step writes the one its tile lies in."""
+    b_ref = refs[0] if len(refs) == len(edges) else None
+    o_refs = refs[len(refs) - len(edges) + 1:]
+    f32 = jnp.float32
+    rows, qlen, width = x_ref.shape
+    history, taps = before_ref.shape[0], w_ref.shape[0]
+    step, tile = pl.program_id(0), pl.program_id(1)
+    chunk = width if width % _CONV_CHUNK else _CONV_CHUNK
+
+    def one_row(r, _):
+        row = step * rows + r
+        # the tokens before the row's first: none where the row opens a
+        # request (the pool's first row has none whatever it says)
+        opens = (row == 0) | (first_ref[row] != 0)
+        for at in range(0, width, chunk):
+            lanes = slice(at, at + chunk)
+            x = x_ref[r, :, lanes].astype(f32)
+            before = jnp.where(
+                r == 0, before_ref[:, lanes],
+                x_ref[jnp.maximum(r - 1, 0), qlen - history:, lanes])
+            before = jnp.where(opens, 0.0, before.astype(f32))
+            both = jnp.concatenate([before, x], axis=0)
+            out = x * w_ref[taps - 1:taps, lanes]
+            if b_ref is not None:
+                out = out + b_ref[:, lanes]
+            for k in range(1, taps):
+                out = out + pltpu.roll(both, k, 0)[history:] \
+                    * w_ref[taps - 1 - k:taps - k, lanes]
+            if activation == "silu":
+                out = jax.nn.silu(out)
+            for lo, hi, o_ref in zip(edges, edges[1:], o_refs):
+                @pl.when((tile >= lo) & (tile < hi))
+                def _(o_ref=o_ref):
+                    o_ref[r, :, lanes] = out.astype(o_ref.dtype)
+        return 0
+
+    lax.fori_loop(0, rows, one_row, 0)
+
+
+def _conv_tiles(rows: int, q: int, c: int, itemsize: int):
+    """(rows a grid step, lanes a grid step, tokens of history): the
+    largest divisor of the pool's rows up to ``_CONV_ROWS``; the widest
+    halving of ``_CONV_LANES`` that divides the channels ``c`` (of
+    several parts, their common divisor), else all of them; the input's
+    last sublane tile of a row (8 tokens of 32 bits, 16 of 16), else the
+    row whole."""
+    step_rows = max(r for r in range(1, min(rows, _CONV_ROWS) + 1)
+                    if rows % r == 0)
+    lanes = _CONV_LANES
+    while lanes > 128 and c % lanes:
+        lanes //= 2
+    history = 32 // itemsize
+    return (step_rows, lanes if c % lanes == 0 else c,
+            history if q % history == 0 else q)
+
+
+def segment_conv1d(x, weight, bias, row_first, activation=None,
+                   out_dtype=jnp.float32, interpret: bool = False,
+                   split=None):
+    """Causal depthwise convolution along the packed token axis, as one
+    kernel: one read of ``x`` in its own dtype, the taps, the bias and
+    the activation in float32 in VMEM, one write in ``out_dtype``.
 
     ``x`` (rows, Q, C); ``weight`` (C, K) with ``weight[:, K-1]`` on
     the current token (the cross-correlation a ``Conv1d`` with left
-    padding K-1 computes); ``bias`` (C,). The K-1 tokens of history
-    are zero at the start of every request. -> float32 (rows, Q, C)."""
+    padding K-1 computes); ``bias`` (C,) or None. The K-1 tokens of
+    history are zero at the start of every request. ``activation``:
+    None or "silu", on the float32 sum. -> ``out_dtype`` (rows, Q, C).
+
+    ``split``: None, or the channel counts of the arrays the caller
+    takes, side by side in ``x`` — the kernel writes each as an array of
+    its own, in its own ``out_dtype`` where that is a tuple (nothing is
+    sliced or rounded in HBM behind the kernel). -> a tuple of arrays.
+
+    The grid is (step of whole rows, lane tile): a step's tokens are
+    sublanes and its channels lanes, as the pool lies; the tokens before
+    a step's first row come from a second view of ``x`` (the last
+    sublane tile of the row before), and a row that opens a request
+    (``row_first``, scalar prefetch) reads zeros for them. A tap is a
+    sublane roll of the row with that history on top. A part's output
+    block stays in VMEM over the lane tiles of the other parts (the lane
+    tiles are the grid's inner, sequential axis) and goes to HBM once."""
+    assert activation in (None, "silu"), activation
+    parts = (weight.shape[0],) if split is None else tuple(split)
+    assert sum(parts) == weight.shape[0] == x.shape[2], (parts, x.shape)
+    dtypes = out_dtype if isinstance(out_dtype, tuple) \
+        else (out_dtype,) * len(parts)
+    with jax.named_scope("conv"):
+        outs = _conv_call(x, weight, bias, row_first, activation=activation,
+                          parts=parts, interpret=interpret,
+                          out_dtypes=tuple(jnp.dtype(d) for d in dtypes))
+    return outs[0] if split is None else tuple(outs)
+
+
+# a function under ``jit`` of its own: a stack's blocks call it with the
+# same shapes, and the kernel is traced and lowered once for all of them
+@functools.partial(jax.jit, static_argnames=(
+    "activation", "parts", "out_dtypes", "interpret"))
+def _conv_call(x, weight, bias, row_first, *, activation, parts, out_dtypes,
+               interpret):
     rows, q, c = x.shape
-    k_taps = weight.shape[1]
-    flat = x.reshape(rows * q, c).astype(jnp.float32)
-    w = weight.astype(jnp.float32)
-    out = flat * w[:, k_taps - 1] + bias.astype(jnp.float32)
-    col = jnp.arange(q)
-    for k in range(1, k_taps):
-        shifted = jnp.pad(flat, ((k, 0), (0, 0)))[:rows * q]
-        # the k-th token back lies before the request's first token
-        # for the first k tokens of the request's first row
-        live = ~(row_first[:, None] & (col[None, :] < k))
-        out = out + jnp.where(live.reshape(-1, 1), shifted, 0.0) \
-            * w[:, k_taps - 1 - k]
-    return out.reshape(rows, q, c)
+    taps = weight.shape[1]
+    step_rows, lanes, history = _conv_tiles(
+        rows, q, math.gcd(*parts), x.dtype.itemsize)
+    assert taps - 1 <= history, (taps, history)
+    f32 = jnp.float32
+    edges = tuple(sum(parts[:p]) // lanes for p in range(len(parts) + 1))
+    operands = [x, x, weight.astype(f32).T]
+    specs = [
+        pl.BlockSpec((step_rows, q, lanes), lambda s, i, _: (s, 0, i)),
+        pl.BlockSpec((None, history, lanes), lambda s, i, _: (
+            jnp.maximum(s * step_rows - 1, 0), q // history - 1, i)),
+        pl.BlockSpec((taps, lanes), lambda s, i, _: (0, i))]
+    if bias is not None:
+        operands.append(bias.astype(f32)[None, :])
+        specs.append(pl.BlockSpec((1, lanes), lambda s, i, _: (0, i)))
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, activation=activation, edges=edges),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows // step_rows, c // lanes),
+            in_specs=specs,
+            # a part's block while the grid walks the other parts' lane
+            # tiles: its first before them, its last behind them
+            out_specs=[pl.BlockSpec(
+                (step_rows, q, lanes), lambda s, i, _, lo=lo, hi=hi: (
+                    s, 0, jnp.clip(i - lo, 0, hi - lo - 1)))
+                for lo, hi in zip(edges, edges[1:])]),
+        out_shape=[jax.ShapeDtypeStruct((rows, q, width), dtype)
+                   for width, dtype in zip(parts, out_dtypes)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret, name=CONV_KERNEL_NAME,
+    )(row_first.astype(jnp.int32), *operands)
 
 
 def _lane_tile(per: int, p: int):

@@ -10,13 +10,24 @@ the kernel's last lines: the device's own time from a profiler trace of
 ``REPEATS`` calls (the kernel's custom call, and every operation of the
 jitted scan: the running sums and their transposes are XLA's), and the
 host's clock around the calls, which at these sizes is mostly the launch.
+Then ``rnb_tpu.ops.ssd.segment_conv1d`` alone at its two callers' shapes
+(Nemotron-H's M block: 64 rows of 6,144 channels, a bias, xs, B and C
+as three bfloat16 arrays; Qwen3-Next's DeltaNet layer: 128 rows of
+8,192, no bias, q with k in float32 and v in bfloat16; both four taps
+and the SiLU): the ``jax.numpy`` passes it replaced
+(``tests/test_segment_conv.py`` keeps them) with the caller's SiLU,
+slices and rounding behind them, against the kernel at each count of
+rows and of lanes a grid step and of lanes the body holds at once (``ssd._CONV_ROWS``, ``ssd._CONV_LANES``, ``ssd._CONV_CHUNK``).
 Lines go to stdout and to ``chiprun_out/ssd_sweep/sweep.jsonl``.
 
-    chiprun -- python3 scripts/ssd_sweep.py [--rows=N] [--lanes=512,1024]
+    chiprun -- python3 scripts/ssd_sweep.py [--only=scan|conv] [--rows=N]
+        [--lanes=512,1024] [--conv-rows=4,8] [--conv-lanes=512,1024]
+        [--conv-chunk=128,256]
 
 Off the TPU the kernel runs in Pallas's interpret mode, which at these
 sizes is of no use (``--rows=4`` is a dry run of the control flow).
 """
+import itertools
 import json
 import os
 import sys
@@ -51,6 +62,11 @@ CALLERS = {"nemotron_h": (64, 64, 8, 64, 128, True),
            "lightning": (128, 32, 32, 128, 128, False)}
 LANES = [int(v) for v in option("--lanes", "512,1024,2048,4096").split(",")]
 
+CONV_ROWS = [int(v) for v in option("--conv-rows", "1,2,4,8,16").split(",")]
+CONV_LANES = [int(v) for v in
+              option("--conv-lanes", "512,1024,2048").split(",")]
+CONV_CHUNKS = [int(v) for v in option("--conv-chunk", "128").split(",")]
+
 
 def say(line):
     print(json.dumps(line), flush=True)
@@ -59,10 +75,10 @@ def say(line):
         f.write(json.dumps(line) + "\n")
 
 
-def timed(f, *args):
+def timed(f, *args, kernel=ssd.KERNEL_NAME):
     """-> (the result, {host_ms: median by the host's clock, device_ms:
     every operation's time a call in the device's trace, kernel_ms: the
-    kernel's custom call alone})."""
+    custom call named ``kernel`` alone})."""
     out = jax.block_until_ready(f(*args))
     took = []
     trace_dir = tempfile.mkdtemp()
@@ -76,7 +92,7 @@ def timed(f, *args):
         ops = [op for plane in xplane.device_ops(
             xplane.find_xplane(trace_dir)).values() for op in plane]
         for key, mine in (("device_ms", ops), ("kernel_ms", [
-                op for op in ops if ssd.KERNEL_NAME in op[2]])):
+                op for op in ops if kernel in op[2]])):
             times[key] = round(sum(end - start for start, end, _ in mine)
                                / REPEATS / 1e6, 4)
     return out, times
@@ -113,9 +129,73 @@ def scan_of(caller, scan, **kwargs):
     return jax.jit(run)
 
 
+def conv_sweep():
+    from test_segment_conv import CALLERS as channels_of, REAL_ROWS, \
+        SPLITS, passes
+    for caller, rows in REAL_ROWS.items():
+        channels, biased, _ = channels_of[caller]
+        parts = SPLITS[caller][2]
+        rows = int(option("--rows", rows))
+        rng = np.random.default_rng(48)
+        x = jnp.asarray(rng.standard_normal((rows, QLEN, channels)),
+                        jnp.bfloat16)
+        weight = jnp.asarray(rng.standard_normal((channels, 4)) * 0.5,
+                             jnp.bfloat16)
+        bias = jnp.asarray(rng.standard_normal(channels), jnp.bfloat16)
+        first = np.zeros(rows, bool)
+        first[[0, rows // 3, max(rows - 2, 0), rows - 1]] = True
+        args = (x, weight, bias, jnp.asarray(first))
+
+        def as_passes(x, w, b, f):
+            out = jax.nn.silu(passes(x, w, b if biased else jnp.zeros_like(b),
+                                     f))
+            edges = np.cumsum([0] + [count for count, _ in parts])
+            return [out[..., lo:hi].astype(dtype)
+                    for lo, hi, (_, dtype) in zip(edges, edges[1:], parts)]
+
+        def as_kernel(x, w, b, f):
+            return ssd.segment_conv1d(
+                x, w, b if biased else None, f, activation="silu",
+                out_dtype=tuple(dtype for _, dtype in parts),
+                interpret=INTERPRET, split=tuple(c for c, _ in parts))
+
+        def whole(outs):
+            return np.concatenate([np.asarray(o.astype(jnp.float32))
+                                   for o in outs], axis=-1)
+        want, times = timed(jax.jit(as_passes), *args)
+        say({"caller": caller, "rows": rows, "form": "conv passes", **times})
+        want = whole(want)
+        chosen = ssd._CONV_ROWS, ssd._CONV_LANES, ssd._CONV_CHUNK
+        for tiles in itertools.product(CONV_ROWS, CONV_LANES, CONV_CHUNKS):
+            ssd._CONV_ROWS, ssd._CONV_LANES, ssd._CONV_CHUNK = tiles
+            # the tiles are read while the call traces, and jit keeps a
+            # trace a function
+            ssd._conv_call.clear_cache()
+            got, times = timed(jax.jit(lambda *args: as_kernel(*args)),
+                               *args, kernel=ssd.CONV_KERNEL_NAME)
+            worst = float(np.abs(whole(got) - want).max()
+                          / (1.0 + np.abs(want).max()))
+            say({"caller": caller, "rows": rows, "form": "conv kernel",
+                 "step_rows": ssd._CONV_ROWS,
+                 "step_lanes": ssd._CONV_LANES,
+                 "chunk_lanes": ssd._CONV_CHUNK, **times,
+                 "worst_vs_passes": worst})
+            assert worst < 5e-3, worst
+        ssd._CONV_ROWS, ssd._CONV_LANES, ssd._CONV_CHUNK = chosen
+        ssd._conv_call.clear_cache()
+
+
 def main():
-    from test_ssd_kernel import blocked
     say({"device": DEVICE.device_kind, "platform": DEVICE.platform})
+    only = option("--only", "")
+    if only != "conv":
+        scan_sweep()
+    if only != "scan":
+        conv_sweep()
+
+
+def scan_sweep():
+    from test_ssd_kernel import blocked
     chosen = ssd._STEP_LANES
     for caller, shape in CALLERS.items():
         rows = int(option("--rows", shape[0]))

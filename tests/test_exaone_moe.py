@@ -979,7 +979,12 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     the rows the first grouped product multiplied; this family counts
     none, and its text moved with the toy's tiles, 128 rows beside a
     whole K of 64 where the real widths keep the wide ones, and with
-    the numbers of the functions traced in front)."""
+    the numbers of the functions traced in front); PR 48 recorded
+    ``qwen3_next``'s again (the convolution in front of its delta rule,
+    ``ops/ssd.segment_conv1d``, is one Pallas kernel with the SiLU
+    inside, interpreted here, called once for q with k and once for v,
+    and ``in_qkvz``'s columns are two products; this family runs no such
+    convolution and its text is the one PR 44 recorded)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

@@ -1129,7 +1129,10 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     left it), and this family's to the text of the tree that brought it,
     for the next PR to hold. PR 47 recorded ``nemotron_h``'s and
     ``minicpm_sala``'s again (``ops/ssd.ssd_scan`` became one Pallas
-    kernel); the other four are the texts their PRs left."""
+    kernel) and PR 48 ``nemotron_h``'s and ``qwen3_next``'s
+    (``ops/ssd.segment_conv1d`` became one, with the callers' SiLU
+    inside); the other four — the families whose cells bypass that
+    convolution — are the texts their PRs left."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

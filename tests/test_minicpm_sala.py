@@ -488,7 +488,9 @@ def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
     now = np.asarray(network.mamba_mixer(cfg, block, h, row_first,
                                          interpret=True))
     monkeypatch.setattr(ssd, "ssd_scan", blocked_with_the_norm)
-    before = np.asarray(network.mamba_mixer(cfg, block, h, row_first))
+    # the convolution in front of it is a kernel too since PR 48
+    before = np.asarray(network.mamba_mixer(cfg, block, h, row_first,
+                                            interpret=True))
     monkeypatch.undo()
     # the mixer's output is rounded to bfloat16 on its way into
     # ``out_proj``: an element of the scan that falls the other side of a

@@ -931,7 +931,11 @@ def test_the_older_families_lower_to_the_text_the_parent_gave(family):
     (``forward`` returns ``gmm_rows``, the rows the first grouped
     product multiplied); PR 47 recorded ``nemotron_h``'s and
     ``minicpm_sala``'s again (``ops/ssd.ssd_scan`` is one Pallas kernel,
-    interpreted here; ``deepseek_v2``'s is the text PR 44 recorded)."""
+    interpreted here; ``deepseek_v2``'s is the text PR 44 recorded); PR
+    48 recorded ``nemotron_h``'s again (``ops/ssd.segment_conv1d`` is one
+    Pallas kernel with the SiLU and the rounding inside, interpreted
+    here; ``minicpm_sala``, which calls ``ssd_scan`` alone, and
+    ``deepseek_v2`` lower to the texts they had)."""
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
         recorded = json.load(f)
