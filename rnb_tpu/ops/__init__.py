@@ -16,14 +16,19 @@ elsewhere (CPU tests, interpret mode), so numerics are defined once.
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
 rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
-rnb_tpu.models.keye_vl2) add seven mechanisms, each over a packed pool
+rnb_tpu.models.keye_vl2, rnb_tpu.models.kimi_linear: seven) add seven
+mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan — one Pallas kernel that walks the rows with a step's states in
 VMEM; lightning linear attention is its case of unit steps — and the
 causal convolution in front of it, a second Pallas kernel: the taps, the
 bias and the SiLU in one pass over the activations), ``deltanet`` (the gated delta rule, whose
 transition is a matrix: one Pallas kernel that walks the rows with a
-head group's states in VMEM, a triangular solve inside each row),
+head group's states in VMEM, a triangular solve inside each row; and
+its form under a *vector* gate, one decay a key channel — Kimi Delta
+Attention — a second kernel of the module, in which the decay stands
+inside the two score products and every exponent is kept at or under
+zero),
 ``blocksparse`` (every query's own top-k blocks of keys from
 mean-compressed keys, and a Pallas flash kernel under that block
 mask), ``indexed`` (every query's own top-k *keys* by a learned
@@ -31,7 +36,8 @@ indexer's scores: the scores as sort keys, a threshold a query found bit
 by bit, and a Pallas flash kernel under the sets), ``segattn``
 (causal attention inside requests: JAX's Pallas splash kernel over the
 pool; values may be narrower than keys, latent attention's expanded
-form; with a window, a query reads the last so many keys of its request
+form, with rotary keys (DeepSeek-V2) or with no positions at all
+(Kimi-Linear); with a window, a query reads the last so many keys of its request
 and the kernel walks a band of tiles, K-EXAONE's sliding layers), ``rope`` (rotary positions that restart at each request, YaRN's
 frequencies) and ``moe`` (routing over all experts by the family's rule
 and the held experts' part, plain or gated, whose grouped product is

@@ -1,7 +1,27 @@
-"""The gated delta rule over a packed pool of rows, in its blocked (WY /
-UT) form, as one Pallas TPU kernel that keeps a row's arrays and the
-carried state in VMEM; the state is reset where a request's first row
-starts.
+"""The delta rule over a packed pool of rows in its blocked (WY / UT)
+form, under two gates: a *scalar* a value head a token
+(:func:`gated_delta_rule`: Gated DeltaNet, Qwen3-Next) and a *vector*,
+one decay a key channel (:func:`channel_gated_delta_rule`: Kimi Delta
+Attention, Kimi-Linear). Each is one Pallas TPU kernel that keeps a
+row's arrays and the carried state in VMEM; the state is reset where a
+request's first row starts.
+
+*What the two share*: the row (``Q`` tokens, a power of two) as the
+block, the unit-triangular system and its solve
+(:func:`unit_lower_inverse`), the grid (head group, row) with the rows
+innermost and in order, the head group's states in a VMEM scratch that
+lives from one grid step to the next, ``row_first`` as a scalar-prefetch
+operand that zeroes the scratch where a request opens, float32 decays,
+steps, solve and states at ``highest`` precision, ``state_dtype`` for
+the control arm, and ``interpret`` for a device that is no TPU. *Where
+they part*: the scalar rule multiplies a ``Q x Q`` decay triangle onto
+``k k^T`` and ``q k^T`` *after* the products, and two value heads share
+one key head's scores; under the vector gate the decay stands *inside*
+the sum over the key channels, every head has its own ``q`` and ``k``,
+the running sums are ``(Q, Dk)`` a head and not ``(Q, 1)``, and the
+scores are assembled from pieces whose exponents are all at or under
+zero (below). The scalar rule's kernel and its callers are as PR 41
+left them.
 
 A *row* is one chunk of ``Q`` consecutive tokens; a request occupies
 consecutive rows of the pool and ``row_first[r]`` says that row ``r``
@@ -16,6 +36,10 @@ state ``S`` (``Dk`` x ``Dv``, zero at a request's first token), a decay
 *matrix*, ``alpha_t (I - beta_t k_t k_t^T)``: transitions do not
 commute, so the closed form ``ops/ssd.py`` carries its states with (one
 ``rows x rows`` matrix of scalar decays a head) does not exist here.
+Under the vector gate ``alpha_t`` is ``Diag(alpha_t)``, one decay a key
+channel (Kimi Linear, arXiv:2510.26692)::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
 
 Inside a row the rule is blocked as the paper blocks it. With ``g`` the
 running sum of ``log alpha`` inside the row and ``L`` the strictly lower
@@ -27,6 +51,10 @@ updates are one unit-triangular system, ``T = (I + L)^-1``::
     v_new = U - W S_in
     o     = (exp(g) q) S_in + tril((q . k) exp(g_i - g_j)) v_new
     S_out = exp(g_Q) S_in + (exp(g_Q - g) k)^T v_new
+
+(under the vector gate ``g`` is ``(Q, Dk)``, ``exp(g) k`` a product a
+channel, and the pair's decay lies inside the dot: ``sum_d k_id k_jd
+exp(g_id - g_jd)``.)
 
 ``T`` is computed by forward substitution (:func:`unit_lower_inverse`):
 row by row inside diagonal blocks of 32, and block by block above them
@@ -57,6 +85,27 @@ or builds ``T`` or a state takes float32 operands at ``highest``
 precision, so that none is rounded to bfloat16 on its way through the
 matrix unit. The two score products (``k . k``, ``q . k``) take their
 inputs in the activations' dtype and accumulate in float32.
+
+*The vector gate's scores.* ``exp(g_i - g_j)`` a channel cannot be
+split as ``exp(g_i) exp(-g_j)``: where a channel fades in a few tokens
+``exp(-g_j)`` overflows float32 inside one row. Written as ``(k_i
+exp(g_i - g_n)) . (k_j exp(g_n - g_j))`` it is exact, and both factors
+are at most one, wherever the reference point ``n`` lies between the
+pair (``j < n <= i``). The kernel takes the pairs of a row level by
+level of a binary cut: at the level of blocks of ``B`` tokens (``Q / 2``
+down to ``_PAIR_BASE``) the pairs with ``i`` in the second and ``j`` in
+the first half of one block of ``2 B`` take ``n`` at the second half's
+first token, *every* such block at once in one whole ``Q x Dk x Q``
+product (a token's factor is clamped to one where it is on the wrong
+side, and the pairs the level does not own are masked away); the pairs
+inside a block of ``_PAIR_BASE`` are formed offset by offset on the
+vector unit, ``sum_d k_id k_(i-s)d exp(g_id - g_(i-s)d)`` for ``s`` = 1
+.. ``_PAIR_BASE`` - 1 by a sublane roll, in float32. The running sums
+themselves are one product with a triangle of ones inside the kernel
+(the caller hands over ``log alpha`` as it is: ``(tokens, heads x Dk)``
+float32 read once, and no ``g`` in HBM), and the state is kept
+transposed (``Dv x Dk``), so that a channel's decay over the row
+scales a lane and needs ``g_Q`` in one orientation only.
 """
 
 from __future__ import annotations
@@ -79,6 +128,9 @@ KERNEL_NAME = "gated_delta_rule"
 #: unit cost less than the level of merges (two whole products) they
 #: replace, 63 cost more (PERF.md section 6, PR 41)
 _SOLVE_BASE = 32
+
+#: the vector gate's kernel in the device's trace and in the scope table
+KDA_KERNEL_NAME = "channel_gated_delta_rule"
 
 #: key heads a grid step (with the value heads that read them): the
 #: body is unrolled a head, and past two the kernel gains 1-3% for a
@@ -250,3 +302,156 @@ def gated_delta_rule(q, k, v, log_alpha, beta, row_first,
       k.reshape(rows, qlen, hk * dk), v.reshape(rows, qlen, hv * dv),
       g, b, g.transpose(0, 1, 3, 2))
     return out.reshape(rows, qlen, hv, dv)
+
+
+# -- the gate a channel (Kimi Delta Attention) ----------------------------
+
+#: the block inside which the vector gate's pairs are formed offset by
+#: offset on the vector unit; larger blocks pair through the matrix
+#: unit, a level a whole product (PERF.md section 6, PR 49)
+_PAIR_BASE = 8
+
+#: heads a grid step of the vector gate's kernel (every head has its
+#: own keys)
+_KDA_HEADS = 2
+
+
+def _dot_nt(a, b):
+    """``a b^T``, float32 operands at ``highest``."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           precision=_HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def channel_scores(qf, kf, g, score_dtype):
+    """``sum_d x_id k_jd exp(g_id - g_jd)`` for ``x`` = ``k`` (``i > j``)
+    and ``x`` = ``q`` (``i >= j``), zero elsewhere: -> two (Q, Q)
+    float32. ``qf``, ``kf``, ``g`` (Q, Dk) float32, ``g`` the running
+    sums of ``log alpha`` (non-increasing down the tokens). No exponent
+    is over zero (the module's docstring). The levels' products take
+    their operands in ``score_dtype`` (the activations') and accumulate
+    in float32, as the scalar rule's two score products do."""
+    qlen, dk = g.shape
+    base = min(qlen, _PAIR_BASE)
+    # float32 operands (the tests') at ``highest``
+    product = _dot_nt if score_dtype == jnp.float32 else _scores
+    token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
+    other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
+    kk = jnp.zeros((qlen, qlen), jnp.float32)
+    qk = jnp.where(token == other, jnp.sum(qf * kf, -1, keepdims=True), 0.0)
+    block = qlen // 2
+    while block >= base:
+        # every token's reference: its block of 2 B's second half's first
+        ref = jnp.concatenate([
+            jnp.broadcast_to(g[n:n + 1], (2 * block, dk))
+            for n in range(block, qlen, 2 * block)], axis=0)
+        late = jnp.exp(jnp.minimum(g - ref, 0.0))
+        early = (kf * jnp.exp(jnp.minimum(ref - g, 0.0))) \
+            .astype(score_dtype)
+        owns = (token // block == other // block + 1) \
+            & ((token // block) % 2 == 1)
+        kk = kk + jnp.where(
+            owns, product((kf * late).astype(score_dtype), early), 0.0)
+        qk = qk + jnp.where(
+            owns, product((qf * late).astype(score_dtype), early), 0.0)
+        block //= 2
+    inside = lax.broadcasted_iota(jnp.int32, (qlen, 1), 0) % base
+    for s in range(1, base):
+        # token i against token i - s of its own block of ``base``
+        past = pltpu.roll(kf, s, 0) \
+            * jnp.exp(jnp.minimum(g - pltpu.roll(g, s, 0), 0.0))
+        here = (token - other == s) & (inside >= s)
+        kk = kk + jnp.where(here, jnp.sum(kf * past, -1, keepdims=True), 0.0)
+        qk = qk + jnp.where(here, jnp.sum(qf * past, -1, keepdims=True), 0.0)
+    return kk, qk
+
+
+def _kda_kernel(first_ref, q_ref, k_ref, v_ref, a_ref, b_ref, o_ref,
+                state_ref, *, dk: int, dv: int, state_dtype):
+    """One row of one head group. ``q_ref``, ``k_ref`` (Q, heads * Dk),
+    ``v_ref`` (Q, heads * Dv), ``a_ref`` (Q, heads * Dk) float32 the
+    ``log alpha`` of every channel, ``b_ref`` (Q, heads) the steps;
+    ``state_ref`` (heads, Dv, Dk) float32, carried, a head's state
+    transposed."""
+    f32 = jnp.float32
+    qlen = q_ref.shape[0]
+
+    @pl.when(first_ref[pl.program_id(1)] != 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
+    other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
+    ones = jnp.where(token >= other, 1.0, 0.0).astype(f32)
+    for head in range(k_ref.shape[1] // dk):
+        k = k_ref[:, head * dk:(head + 1) * dk]
+        kf = k.astype(f32)
+        qf = q_ref[:, head * dk:(head + 1) * dk].astype(f32)
+        g = _dot(ones, a_ref[:, head * dk:(head + 1) * dk])    # (Q, Dk)
+        b = b_ref[:, head:head + 1]                            # (Q, 1)
+        end = g[qlen - 1:qlen]                                 # (1, Dk)
+        kk, qk = channel_scores(qf, kf, g, k.dtype)
+        t = unit_lower_inverse(b * kk)
+        u = _dot(t, b * v_ref[:, head * dv:(head + 1) * dv].astype(f32))
+        w = _dot(t, (b * jnp.exp(g)) * kf)
+        state = state_ref[head]                                # (Dv, Dk)
+        v_new = u - _dot_nt(w, state)
+        o_ref[:, head * dv:(head + 1) * dv] = \
+            _dot_nt(qf * jnp.exp(g), state) + _dot(qk, v_new)
+        state = state * jnp.exp(end) + lax.dot_general(
+            v_new, kf * jnp.exp(end - g), (((0,), (0,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=f32)
+        state_ref[head] = state.astype(state_dtype).astype(f32)
+
+
+def channel_gated_delta_rule(q, k, v, log_alpha, beta, row_first,
+                             state_dtype=jnp.float32,
+                             interpret: bool = False):
+    """The rule of one layer over a packed pool, the gate a channel.
+
+    ``q``, ``k`` (rows, Q, H, Dk), as the rule reads them (normalised,
+    ``q`` scaled); ``v`` (rows, Q, H, Dv); ``log_alpha`` (rows, Q, H,
+    Dk) float32, <= 0, a token's own (not summed); ``beta`` (rows, Q,
+    H) float32; ``row_first`` (rows,) bool; Q a power of two.
+    -> float32 (rows, Q, H, Dv).
+
+    ``state_dtype`` and ``interpret`` as :func:`gated_delta_rule` takes
+    them."""
+    return _kda_call(q, k, v, log_alpha, beta, row_first,
+                     state_dtype=jnp.dtype(state_dtype), interpret=interpret)
+
+
+# a function under ``jit`` of its own: a stack's layers call it with the
+# same shapes, and the kernel is traced and lowered once for all of them
+@functools.partial(jax.jit, static_argnames=("state_dtype", "interpret"))
+def _kda_call(q, k, v, log_alpha, beta, row_first, *, state_dtype,
+              interpret):
+    rows, qlen, heads, dk = k.shape
+    dv = v.shape[3]
+    step = min(_KDA_HEADS, heads)
+    groups = heads // step
+    f32 = jnp.float32
+    b = beta.astype(f32).reshape(rows, qlen, groups, step) \
+        .transpose(0, 2, 1, 3)
+
+    def columns(width):
+        return pl.BlockSpec((None, qlen, width), lambda i, r, _: (r, 0, i))
+    out = pl.pallas_call(
+        functools.partial(_kda_kernel, dk=dk, dv=dv,
+                          state_dtype=state_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(groups, rows),
+            in_specs=[columns(step * dk), columns(step * dk),
+                      columns(step * dv), columns(step * dk),
+                      pl.BlockSpec((None, None, qlen, step),
+                                   lambda i, r, _: (r, i, 0, 0))],
+            out_specs=columns(step * dv),
+            scratch_shapes=[pltpu.VMEM((step, dv, dk), f32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, qlen, heads * dv), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name=KDA_KERNEL_NAME,
+    )(row_first.astype(jnp.int32), q.reshape(rows, qlen, heads * dk),
+      k.reshape(rows, qlen, heads * dk), v.reshape(rows, qlen, heads * dv),
+      log_alpha.astype(f32).reshape(rows, qlen, heads * dk), b)
+    return out.reshape(rows, qlen, heads, dv)

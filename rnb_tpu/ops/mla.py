@@ -14,6 +14,16 @@ halves ``[x1 | x2]`` the product brings ``[-x2 | x1]`` beside them, a
 lane roll lays it under the halves, and ``x cos + turned sin`` is the
 half-split rotation of ``ops/rope.py`` in the same float32 numbers.
 
+*Which callers rotate.* ``models/deepseek_v2/network.py`` is the one
+caller: its queries come from a latent (``q_lora_rank``) and 64 of a
+head's 192 columns are rotary, so the kernel requires room for them
+twice. ``models/kimi_linear/network.py`` runs the same expanded form
+**without** a query latent and **without** positions
+(``mla_use_nope``): nothing is turned, so it does not call this kernel;
+it stores its query weight heads-first and whole lanes wide too and one
+batched product writes the flash kernel's operand
+(``heads_first_attention``, which both share, knows no rotation).
+
 On the v5e, 8,192 tokens, 128 heads of 128 + 64 + 64 columns from a
 latent of 1536 (my chip runs, PR 38): 4.43 ms at 2,048 tokens a step,
 4.54 at 1,024, 4.75 at 512; the matrix unit's peak allows 4.19. (XLA's

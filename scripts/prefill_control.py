@@ -23,7 +23,12 @@ narrower set first. A family whose attention reads a learned indexer's
 sets (``keye_vl2``) has two arms more, each of which must read over the
 limit against the reference's own sets: every causal key in their place,
 and the latest ``topk``; and one that must fail the key slack with the
-program's sets given: the indexer's operands through float8.
+program's sets given: the indexer's operands through float8. A family
+whose delta rule is gated a channel and whose latent attention turns
+nothing (``kimi_linear``) has an arm for each: a head's mean ``log alpha``
+in the place of its channels', and rotary turned on; both must fail, as
+must float8, and the carried states through bfloat16 are recorded
+whether they do or not (the family file's ``CONTROL_MAY_PASS``).
 """
 
 import argparse
@@ -61,6 +66,12 @@ def arms_of(family: str):
                 ("index_float8", {"index_bits": FLOAT8_BITS}, None),
                 ("all_causal_keys", {"select": "causal"}, None),
                 ("recent_keys", {"select": "recent"}, None),
+                ("layers_float8", {}, lambda group, name: True)]
+    if family == "kimi_linear":
+        return [("as_stated", {}, None),
+                ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
+                ("scalar_gate", {"gate": "scalar"}, None),
+                ("rotary_on", {"rotary": True}, None),
                 ("layers_float8", {}, lambda group, name: True)]
     raise ValueError("no control arms for family %r" % (family,))
 
@@ -169,11 +180,15 @@ def main(argv=None) -> int:
                                       "key_slack", family.KEY_SLACK)))
         print("[control] %s %s" % (arm, out[arm]), file=sys.stderr,
               flush=True)
-    # a family that says so holds every control to a failure, the
-    # others at least one
-    fails = any if getattr(family, "EVERY_CONTROL_FAILS", False) else all
+    # a family that says so holds every control to a failure (or every
+    # one but those it names as recorded either way), the others at
+    # least one
+    may_pass = getattr(family, "CONTROL_MAY_PASS", None)
+    fails = any if may_pass is not None \
+        or getattr(family, "EVERY_CONTROL_FAILS", False) else all
     out["ok"] = out["as_stated"]["ok"] and not fails(
-        out[arm]["ok"] for arm, _, _ in arms[1:])
+        out[arm]["ok"] for arm, _, _ in arms[1:]
+        if arm not in (may_pass or ()))
     print(json.dumps(out))
     return 0 if out["ok"] else 1
 

@@ -1,0 +1,683 @@
+"""The Kimi-Linear family against its plain reference, at a toy size on
+the CPU with weights from a seed: the vector gate's kernel
+(``ops/deltanet.channel_gated_delta_rule``, interpreted) against the
+token-by-token recurrence over packed pools at a mild and at a harsh
+draw of the decays, against the scalar rule under a gate that is the
+same in every channel, its states through bfloat16; the shares of the experts adding
+up to the uncut layer; latent attention that rotates nothing and reads
+no other request's keys; the recipe, the operation counts, the real
+configuration against the catalog's row, and the kernel compiled at the
+published widths for a described v5e. The packed prefill and the stage
+are ``test_kimi_linear_stack.py``'s, the cell through the benchmark
+command and the readers ``test_kimi_linear_cell.py``'s (one file is one
+worker's under ``--dist loadfile``, and each stays under two minutes).
+Nothing here needs the native decode library or a chip."""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmarks import manifest as mm  # noqa: E402
+from benchmarks.references import compare  # noqa: E402
+from benchmarks.references import kimi_linear as reference  # noqa: E402
+
+REAL = "benchmarks/configs/kimi-linear-l5-ep2.json"
+CELL = "kimi-linear.bulk"
+SEED = 3_000_000_123
+
+#: the published layers 1-5 at toy widths: 4 KDA heads of 16 behind
+#: 4-tap convolutions, latent attention of 4 heads (a latent of 32, keys
+#: of 16 + 8 shared columns, values of 16), a dense MLP of 128, 16
+#: sigmoid-routed experts top-4 of which 8 held
+TOY = {
+    "num_hidden_layers": 5, "first_k_dense_replace": 1,
+    "hidden_size": 64, "vocab_size": 256, "chunk_size": 16,
+    "linear_attn_config": {
+        "kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+        "num_heads": 4, "head_dim": 16, "short_conv_kernel_size": 4},
+    "num_attention_heads": 4, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "q_lora_rank": None,
+    "mla_use_nope": True, "rope_theta": 10000, "intermediate_size": 128,
+    "moe_intermediate_size": 32, "num_shared_experts": 1,
+    "num_experts": 8, "num_experts_per_token": 4,
+    "routed_scaling_factor": 2.446, "moe_layer_freq": 1,
+    "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+    "num_expert_group": 1, "topk_group": 1, "hidden_act": "silu",
+    "rms_norm_eps": 1e-5,
+    "published": {"num_hidden_layers": 27, "num_experts": 16}}
+HELD = tuple(range(8))
+OTHER = tuple(range(8, 16))
+Q = TOY["chunk_size"]
+#: the comparison's limit at the toy widths: narrow sums average less
+#: rounding away than the real ones (the real limit is the family
+#: file's SHARE_OF_SPREAD); the toy reads 3.4% and its float8 control
+#: 18%
+TOY_LIMIT = 0.05
+
+
+@pytest.fixture(scope="module")
+def toy():
+    import jax
+
+    from rnb_tpu.models.kimi_linear import checkpoint, network
+    cfg = network.KimiLinearConfig.from_published(TOY)
+    device = jax.devices()[0]
+    return {"cfg": cfg, "device": device,
+            "params": checkpoint.make_params(cfg, SEED, HELD, device),
+            "slots": network.held_slots(cfg, HELD),
+            "read": checkpoint.reference_reader(cfg, SEED, device),
+            "reference": reference.Reference(TOY)}
+
+
+def prompts_of(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TOY["vocab_size"], n).astype(np.int32)
+            for n in lengths]
+
+
+def pack(prompts, rows):
+    from rnb_tpu.models import token_stages
+    return token_stages.pack_prompts(prompts, rows, Q)
+
+
+_PROGRAMS = {}
+
+
+def run_program(toy, prompts, rows, params=None, cfg=None, **kwargs):
+    """-> (logits a prompt, each prompt's router choices (expert layers,
+    tokens, k), the counters)."""
+    import jax
+
+    from rnb_tpu.models.kimi_linear import network
+    cfg = toy["cfg"] if cfg is None else cfg
+    tokens, meta, offsets = pack(prompts, rows)
+    key = (cfg, rows, tuple(sorted((k, str(v)) for k, v in kwargs.items())))
+    if key not in _PROGRAMS:
+        _PROGRAMS[key] = jax.jit(
+            lambda p, t, m: network.forward(
+                cfg, p, toy["slots"], t, m[0], m[1], m[2],
+                interpret=True, **kwargs))
+    logits, chosen, *counts = _PROGRAMS[key](
+        toy["params"] if params is None else params, tokens, meta)
+    chosen = np.asarray(chosen)
+    per_prompt = [chosen[:, o * Q:o * Q + len(p)]
+                  for o, p in zip(offsets, prompts)]
+    return np.asarray(logits)[:len(prompts)], per_prompt, \
+        [np.asarray(c) for c in counts]
+
+
+#: the reference runs every prompt padded to this many tokens behind its
+#: last (every mixer is causal), so that it compiles one length
+REF_LENGTH = 256
+
+
+def run_reference(toy, prompt, forced=None, model=None, read=None):
+    """-> the reference's logits at the prompt's last token, its choices
+    and shortfalls over the prompt's own tokens."""
+    import jax
+    count, pad = len(prompt), REF_LENGTH - len(prompt)
+    if forced is not None:
+        layers, _, k = forced.shape
+        forced = np.concatenate([forced, np.broadcast_to(
+            np.arange(k, dtype=forced.dtype), (layers, pad, k))], axis=1)
+    with jax.default_matmul_precision("highest"):
+        out = (model or toy["reference"]).forward(
+            read or toy["read"], np.pad(prompt, (0, pad)), held=HELD,
+            forced=forced, position=count - 1)
+    return {"logits": out["logits"], "chosen": out["chosen"][:, :count],
+            "shortfall": out["shortfall"][:, :count]}
+
+
+def through_float8(params):
+    """The stored matrices of every layer rounded through float8 (e4m3):
+    the nearest precision below the one the configuration states."""
+    import jax.numpy as jnp
+    out = dict(params)
+    for group, tensors in params.items():
+        if isinstance(tensors, dict):
+            out[group] = {
+                name: (w.astype(jnp.float8_e4m3fn).astype(w.dtype)
+                       if w.ndim >= 2 else w)
+                for name, w in tensors.items()}
+    return out
+
+
+# -- the vector gate's kernel alone ---------------------------------------
+
+
+def rule_inputs(rows, qlen, heads=2, dk=16, dv=16, harsh=False, seed=1,
+                dtype=None):
+    """Operands as the mixer hands them over. ``mild``: a channel's
+    ``log alpha`` is -0.0003 to -0.03 a token, so that it fades over
+    hundreds to thousands of tokens; ``harsh``: down to -25 a token, a
+    head's channels two orders apart, so that ``exp(-g)`` alone would
+    overflow float32 inside a row of 8 already."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+
+    def n(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, k = n(rows, qlen, heads, dk), n(rows, qlen, heads, dk)
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    rate = jnp.asarray(rng.uniform(0.01, 1.0, size=(heads, dk)),
+                       jnp.float32)
+    token = jnp.asarray(rng.uniform(0.03, 1.0, size=(rows, qlen, heads, dk)),
+                        jnp.float32)
+    log_alpha = -(25.0 if harsh else 0.03) * rate * token
+    out = (q, k, n(rows, qlen, heads, dv), log_alpha,
+           jax.nn.sigmoid(n(rows, qlen, heads)))
+    if dtype is not None:
+        out = tuple(x.astype(dtype) for x in out[:3]) + out[3:]
+    return out
+
+
+def recurrence(inputs, lo, hi):
+    """The plain reference's rule over rows ``lo`` to ``hi``, a request
+    of its own."""
+    import jax
+    import jax.numpy as jnp
+    q, k, v, log_alpha, beta = (
+        x[lo:hi].reshape((-1,) + x.shape[2:]).astype(jnp.float32)
+        for x in inputs)
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(reference.delta_rule(q, k, v, jnp.exp(log_alpha),
+                                               beta))
+
+
+#: (tokens a row, rows, the rows that open a request, heads, harsh): a
+#: request that starts mid-pool, spans several rows and ends in pad rows
+#: (requests of their own); every row a request; one request the whole
+#: pool; rows of 8 (pairs on the vector unit alone), of 16 to 64 (one
+#: to three levels through the matrix unit) and of 128 (four levels,
+#: the solve's merges); four heads are two head groups
+RULE_CASES = [
+    (16, 6, (0, 2, 5), 2, False), (16, 6, (0, 2, 5), 2, True),
+    (8, 6, (0, 3, 4), 2, True), (32, 6, (0, 1, 2, 3, 4, 5), 4, True),
+    (64, 6, (0,), 2, False), (64, 6, (0, 2), 4, True),
+    (128, 3, (0, 1), 2, False), (128, 3, (0, 2), 2, True)]
+
+
+@pytest.mark.parametrize("qlen,rows,firsts,heads,harsh", RULE_CASES)
+def test_the_kernel_matches_the_recurrence(qlen, rows, firsts, heads, harsh):
+    """``channel_gated_delta_rule`` (interpreted) over a packed pool
+    against the recurrence token by token, a request at a time: finite
+    everywhere and within 2e-5 of the result's largest entry, float32
+    operands. At the harsh draw a channel's running sum passes -10 a
+    token: under -160 inside a row of 16 and -1,280 inside one of 128,
+    where float32 ends at exp(88)."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    inputs = rule_inputs(rows, qlen, heads, harsh=harsh)
+    row_first = np.zeros(rows, bool)
+    row_first[list(firsts)] = True
+    out = np.asarray(deltanet.channel_gated_delta_rule(
+        *inputs, jnp.asarray(row_first), interpret=True))
+    assert np.isfinite(out).all()
+    if harsh:
+        assert float(np.asarray(inputs[3]).sum(1).min()) < -10.0 * qlen
+    bounds = list(firsts) + [rows]
+    for lo, hi in zip(bounds, bounds[1:]):
+        want = recurrence(inputs, lo, hi)
+        got = out[lo:hi].reshape(want.shape)
+        assert np.abs(got - want).max() < 2e-5 * max(
+            1.0, np.abs(want).max())
+    # a state that did not restart would show: the second request's
+    # first token reads only its own write
+    lo = firsts[1] if len(firsts) > 1 else 0
+    q, k, v, _, beta = (np.asarray(x, np.float64) for x in inputs)
+    alone = beta[lo, 0, :, None] * v[lo, 0] \
+        * (k[lo, 0] * q[lo, 0]).sum(-1)[:, None]
+    assert np.abs(out[lo, 0] - alone).max() < 1e-5
+
+
+@pytest.mark.parametrize("harsh", [False, True])
+def test_bfloat16_operands_stay_inside_their_rounding(harsh):
+    """What the program hands the kernel: ``q``, ``k``, ``v`` in
+    bfloat16. The levels' products then take ``k exp(.)`` rounded to
+    bfloat16; against the recurrence on the same (rounded) operands the
+    result stays within 2% of its spread."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    inputs = rule_inputs(4, 64, 2, harsh=harsh, dtype=jnp.bfloat16)
+    out = np.asarray(deltanet.channel_gated_delta_rule(
+        *inputs, jnp.asarray([True, False, False, True]), interpret=True))
+    want = np.concatenate([recurrence(inputs, 0, 3),
+                           recurrence(inputs, 3, 4)])
+    assert np.isfinite(out).all()
+    assert np.abs(out.reshape(want.shape) - want).max() < 0.02 * want.std()
+
+
+@pytest.mark.parametrize("qlen", [16, 128])
+def test_a_gate_alike_in_every_channel_is_the_scalar_rule(qlen):
+    """The two rules of ``ops/deltanet.py`` on the same operands: with a
+    head's ``log alpha`` the same in all its channels the vector gate's
+    kernel gives what ``gated_delta_rule`` gives (one value head a key
+    head)."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    rows = 3
+    q, k, v, log_alpha, beta = rule_inputs(rows, qlen, 2)
+    one = log_alpha[..., :1] * 10.0
+    row_first = jnp.asarray([True, False, True])
+    vector = np.asarray(deltanet.channel_gated_delta_rule(
+        q, k, v, jnp.broadcast_to(one, log_alpha.shape), beta, row_first,
+        interpret=True))
+    scalar = np.asarray(deltanet.gated_delta_rule(
+        q, k, v, one[..., 0], beta, row_first, interpret=True))
+    assert np.abs(vector - scalar).max() < 2e-5 * max(
+        1.0, np.abs(scalar).max())
+    # and another gate in one channel is another result
+    other = np.asarray(deltanet.channel_gated_delta_rule(
+        q, k, v, jnp.broadcast_to(one, log_alpha.shape)
+        .at[..., 0].multiply(3.0), beta, row_first, interpret=True))
+    assert np.abs(other - scalar).max() > 1e-3 * np.abs(scalar).max()
+
+
+def test_bfloat16_states_differ_by_one_rounding_a_row():
+    """The control's ``state_dtype``: a request's first row reads no
+    carried state, so it is the float32 rule's bit for bit; every later
+    row reads a state rounded once more, and differs by no more than
+    its roundings allow."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    inputs = rule_inputs(6, 16)
+
+    def rule(firsts, **how):
+        row_first = np.zeros(6, bool)
+        row_first[list(firsts)] = True
+        return np.asarray(deltanet.channel_gated_delta_rule(
+            *inputs, jnp.asarray(row_first), interpret=True, **how))
+    exact, rounded = rule((0, 4)), rule((0, 4), state_dtype=jnp.bfloat16)
+    scale = np.abs(exact).max()
+    for row in (0, 4):
+        assert np.array_equal(rounded[row], exact[row])
+    for row, roundings in ((1, 1), (2, 2), (3, 3), (5, 1)):
+        off = np.abs(rounded[row] - exact[row]).max()
+        assert 0 < off < roundings * 2.0 ** -7 * scale, (row, off)
+
+
+# -- latent attention without positions -----------------------------------
+
+
+def attention_only():
+    """A stack of two latent-attention layers (the first with the dense
+    feed-forward, the second with the experts) and no KDA layer:
+    whatever carries position is gone."""
+    return dict(TOY, num_hidden_layers=2, linear_attn_config=dict(
+        TOY["linear_attn_config"], kda_layers=[], full_attn_layers=[1, 2]))
+
+
+def test_latent_attention_rotates_no_column(toy):
+    """Two requests of equal tokens at different offsets in the pool
+    give equal logits through an attention-only stack, and the layer
+    knows no position: with a request's earlier tokens in another order
+    its last token reads the same set of keys and values and gives the
+    same result. With rotary turned on (the control arm) it does not."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.kimi_linear import checkpoint, network
+    from rnb_tpu.ops import rope
+    flat = attention_only()
+    cfg = network.KimiLinearConfig.from_published(flat)
+    params = checkpoint.make_params(cfg, SEED, HELD, toy["device"])
+    a, b = prompts_of([70, 5], seed=9)
+    logits, _, _ = run_program(toy, [b, a, a], 16, params=params, cfg=cfg)
+    assert np.array_equal(logits[1], logits[2])
+    want = np.asarray(run_reference(
+        toy, a, model=reference.Reference(flat),
+        read=checkpoint.reference_reader(cfg, SEED, toy["device"]))["logits"])
+    assert compare(logits[1][None], want[None], TOY_LIMIT)["ok"]
+    # the layer alone: a request of three rows, and the same with the
+    # tokens before its last in reverse order
+    rng = np.random.default_rng(8)
+    h = jnp.asarray(rng.normal(size=(3 * Q, 64)), jnp.bfloat16)
+    turned = jnp.concatenate([h[:-1][::-1], h[-1:]])
+    start = jnp.zeros(3, jnp.int32)
+    at = rope.pool_positions(start, Q)
+
+    def last(x, **how):
+        out, _ = network.latent_attention(
+            toy["cfg"], toy["params"]["l3"], x.reshape(3, Q, 64), start, at,
+            interpret=True, **how)
+        return np.asarray(out)[-1, -1]
+    plain = last(h)
+    assert np.abs(last(turned) - plain).max() < 0.02 * np.abs(plain).max()
+    rotated = last(h, rotary=True)
+    assert np.abs(last(turned, rotary=True) - rotated).max() \
+        > 0.2 * np.abs(rotated).max()
+
+
+def test_a_request_reads_no_other_requests_keys(toy):
+    """The latent attention over a packed pool against each request
+    alone: other requests' tokens, before or behind, change nothing."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.kimi_linear import network
+    from rnb_tpu.ops import rope
+    cfg, p = toy["cfg"], toy["params"]["l3"]
+    rng = np.random.default_rng(6)
+    h = jnp.asarray(rng.normal(size=(8, Q, 64)), jnp.bfloat16)
+    start = jnp.asarray([0, 0, 0, 3, 4, 4, 6, 7], jnp.int32)
+    packed, tiles = network.latent_attention(
+        cfg, p, h, start, rope.pool_positions(start, Q), interpret=True)
+    packed = np.asarray(packed)
+    for lo, hi in ((0, 3), (3, 4), (4, 6)):
+        own = jnp.zeros(hi - lo, jnp.int32)
+        alone, _ = network.latent_attention(
+            cfg, p, h[lo:hi], own, rope.pool_positions(own, Q),
+            interpret=True)
+        assert np.abs(packed[lo:hi] - np.asarray(alone)).max() \
+            < 1e-2 * np.abs(packed[lo:hi]).max()
+    assert int(tiles[0]) >= 1
+
+
+# -- the experts' shares --------------------------------------------------
+
+
+def test_the_shares_add_up_to_the_uncut_layer(toy):
+    """Experts 0-7 held here and 8-15 on the other chip: the two shares'
+    routed parts plus the shared expert, which both chips compute alike,
+    once, are the uncut reference's expert layer. In the reference, and
+    in the program with the slots of each share."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.kimi_linear import checkpoint, network
+    cfg, model, read = toy["cfg"], toy["reference"], toy["read"]
+    rng = np.random.default_rng(7)
+    hb = jnp.asarray(rng.normal(size=(3, Q, 64)), jnp.bfloat16)
+    h = hb.reshape(3 * Q, 64).astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        whole, ids, _, _, shared = model.experts(read, 1, h, range(16))
+        here = model.experts(read, 1, h, HELD)
+        there = model.experts(read, 1, h, OTHER)
+    whole, shared = np.asarray(whole), np.asarray(shared)
+    summed = np.asarray(here[3]) + np.asarray(there[3]) + shared
+    assert np.abs(summed - whole).max() < 1e-5 * np.abs(whole).max()
+    assert np.abs(np.asarray(here[0]) + np.asarray(there[0]) - shared
+                  - whole).max() < 1e-5 * np.abs(whole).max()
+    # the tokens' choices are spread over both shares
+    assert np.isin(np.asarray(ids), HELD).any() \
+        and np.isin(np.asarray(ids), OTHER).any()
+    # the program: each share's layer from its own stacks and slots
+    ok = jnp.ones((3, Q), bool)
+    outs = []
+    for share in (HELD, OTHER):
+        p = checkpoint.make_params(cfg, SEED, share, toy["device"],
+                                   groups=["l1"])["l1"]
+        out, chose, counts, _, _ = network.experts_ffn(
+            cfg, p, hb, ok, network.held_slots(cfg, share), interpret=True)
+        assert int(counts.sum()) == int(np.isin(np.asarray(chose),
+                                                share).sum())
+        outs.append(np.asarray(out).reshape(3 * Q, 64))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(model.experts(
+            read, 1, h, range(16), forced=jnp.asarray(chose))[0])
+    got = outs[0] + outs[1] - shared
+    assert np.abs(got - want).max() < 0.03 * want.std()
+
+
+# -- the recipe, the counts, the real configuration -----------------------
+
+
+def test_recipe_gives_program_and_reference_the_same_values(toy):
+    params, read = toy["params"], toy["read"]
+    for name, tensor in (("l0.in_qkv", params["l0"]["in_qkv"]),
+                         ("top.embed", params["embed"]),
+                         ("l3.kv_b", params["l3"]["kv_b"]),
+                         ("l2.a_log", params["l2"]["a_log"]),
+                         ("l2.dt_bias", params["l2"]["dt_bias"]),
+                         ("l1.b_corr", params["l1"]["b_corr"])):
+        assert np.array_equal(np.asarray(tensor, np.float32),
+                              np.asarray(read(name)))
+    # a routed expert's first matrices lie [held, inner, hidden]; the
+    # reference reads them as published, by global id
+    assert params["l1"]["gate"].shape == (8, 32, 64)
+    assert np.array_equal(
+        np.asarray(params["l1"]["gate"][3], np.float32).T,
+        np.asarray(read("l1.gate", [3]))[0])
+    # the dense layer's are plain matrices
+    assert params["l0"]["gate"].shape == (64, 128)
+    # the queries' weight lies heads first with zeros behind a head's
+    # 24 columns, up to whole lanes
+    stored = np.asarray(params["l3"]["q"], np.float32)
+    assert stored.shape == (4, 64, 128) and not stored[..., 24:].any()
+    assert np.array_equal(
+        stored[..., :24].transpose(1, 0, 2).reshape(64, 96),
+        np.asarray(read("l3.q")))
+    # A_log a head, dt_bias a channel
+    assert params["l0"]["a_log"].shape == (4,)
+    assert params["l0"]["dt_bias"].shape == (64,)
+    assert "q" not in params["l0"] and "in_qkv" not in params["l3"]
+    assert "router" not in params["l0"] and "b_corr" in params["l4"]
+
+
+def test_operation_counts_agree_with_the_family_file():
+    from rnb_tpu.models.kimi_linear import checkpoint, flops, network
+    family = mm.load_family("kimi_linear")
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.KimiLinearConfig.from_published(
+        family.published_keys(config))
+    assert family.LOW_RANK == checkpoint.LOW_RANK
+    assert flops.flops_per_token(cfg, 3000.0, 4.0) \
+        == family.flops_per_token(config, 3000.0, 4.0)
+    assert flops.kda_flops_per_token(cfg) \
+        == family.kda_flops_per_token(config)
+    assert flops.delta_rule_flops_per_token(cfg) == 7 * 32 * 128 * 128 \
+        == family.delta_rule_flops_per_token(config)
+    assert family.flops_per_row(config) == 128 * flops.flops_per_token(
+        cfg, family.mean_context(config), 4.0)
+    # ISSUE 49's arithmetic: 0.79 GFLOP a token of products and about
+    # 0.1 of attention's scores at the mix's mean context; a KDA mixer
+    # 39.52 M parameters, an MLA mixer 29.11 M
+    per_token = family.flops_per_row(config) / 128
+    assert 0.85e9 < per_token < 1.0e9
+    assert abs(family.kda_params(config) / 1e6 - 39.52) < 0.1
+    assert abs(family.attention_params(config) / 1e6 - 29.11) < 0.1
+    # both callers: scopes.py passes the held assignments, subscopes.py
+    # does not
+    ops, nbytes = family.mechanism_work(config, "deltarule", 1e6, 80.0)
+    assert ops == 4 * 1e6 * 7 * 32 * 128 * 128
+    assert nbytes == 4 * 1e6 * (2 * 3 * 4096 + 4 * 4096 + 4 * 32 + 4 * 4096)
+    assert family.mechanism_work(config, "deltanet", 1e6, 5e6, 80.0) \
+        == family.mechanism_work(config, "deltanet", 1e6, 80.0)
+    gmm_ops, _ = family.mechanism_work(config, "gmm", 1e6, 5e6, 80.0)
+    assert gmm_ops == 5e6 * flops.expert_flops(cfg)
+    flash_ops, _ = family.mechanism_work(config, "flash", 1e6, 5e6, 80.0)
+    assert flash_ops == 1e6 * 2 * family.mean_context(config) * 32 * 320
+    experts_ops, experts_bytes = family.mechanism_work(
+        config, "experts", 1e6, 5e6, 80.0)
+    assert experts_ops > gmm_ops and experts_bytes > 80 * 4 * 2 * 900e6
+
+
+def catalog_row():
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        for line in f:
+            row = json.loads(line)
+            if row["name"] == "Kimi-Linear-48B-A3B-Instruct":
+                return row
+    return None
+
+
+#: the catalog's ``config`` of Kimi-Linear-48B-A3B-Instruct
+PUBLISHED = {
+    "first_k_dense_replace": 1, "head_dim": 72, "hidden_act": "silu",
+    "hidden_size": 2304, "intermediate_size": 9216, "kv_lora_rank": 512,
+    "linear_attn_config": {
+        "full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 128,
+        "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                       21, 22, 23, 25, 26],
+        "num_heads": 32, "short_conv_kernel_size": 4},
+    "mla_use_nope": True, "model_max_length": 1048576,
+    "model_type": "kimi_linear", "moe_intermediate_size": 1024,
+    "moe_layer_freq": 1, "moe_renormalize": True,
+    "moe_router_activation_func": "sigmoid", "num_attention_heads": 32,
+    "num_expert_group": 1, "num_experts": 256, "num_experts_per_token": 8,
+    "num_hidden_layers": 27, "num_key_value_heads": 32,
+    "num_nextn_predict_layers": 0, "num_shared_experts": 1,
+    "q_lora_rank": None, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "rms_norm_eps": 1e-05, "rope_scaling": None, "rope_theta": 10000,
+    "routed_scaling_factor": 2.446, "tie_word_embeddings": False,
+    "topk_group": 1, "use_grouped_topk": True, "v_head_dim": 128,
+    "vocab_size": 163840}
+
+
+def test_real_configuration_keeps_the_published_sizes():
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    entry = mm.config_entry(mm.load(), "kimi-linear-l5-ep2")
+    assert entry["reduced"] == config["reduced"] \
+        == ["num_hidden_layers", "linear_attn_config", "num_experts"]
+    assert entry["source"] == config["source"]
+    for key, value in PUBLISHED.items():
+        if key in config["reduced"]:
+            assert config["published"][key] == value
+        else:
+            assert config[key] == value, key
+    assert config["num_hidden_layers"] == 5 and config["num_experts"] == 128
+    # the group that changed: the two lists cut, its widths as published
+    linear = config["linear_attn_config"]
+    assert linear["kda_layers"] == [1, 2, 3, 5] \
+        and linear["full_attn_layers"] == [4]
+    for key in ("num_heads", "head_dim", "short_conv_kernel_size"):
+        assert linear[key] == PUBLISHED["linear_attn_config"][key]
+    row = catalog_row()
+    if row is not None:
+        assert row["source_url"] == config["source"]
+        assert row["config"] == PUBLISHED
+    for key, text in config["assumed"].items():
+        assert text, key
+    for key in ("low_rank", "gate", "convolutions", "norms", "mla",
+                "router", "chunk_size"):
+        assert "NOT CHECKED against the modelling code" \
+            in config["assumed"][key], key
+    assert "two chips share each layer" in config["deployment"]
+    assert config["experts_held"] == {"first": 0, "count": 128}
+    assert 8 <= config["size_record"]["projected_gib"] <= 14
+    assert config["capacity_why"] and config["capacity_videos_per_chip_s"]
+    family = mm.load_family(config["family"])
+    assert family.check_config(config) == []
+    cell = mm.cell(mm.load(), CELL)
+    assert cell["config"] == "kimi-linear-l5-ep2" and cell["chips"] == 1 \
+        and cell["traffic"] == "bulk"
+    # the weights the file states, from the tensor list: ISSUE 49's
+    # 39.52 M and 29.11 M of mixers (the queries' stored pad columns
+    # 4.7 M more), 63.70 M of dense MLP, 7.078 M an expert, 754.97 M of
+    # embedding and head
+    from rnb_tpu.models.kimi_linear import checkpoint, network
+    cfg = network.KimiLinearConfig.from_published(
+        family.published_keys(config))
+    specs = checkpoint.tensor_specs(cfg, 128)
+    sizes = {group: sum(int(np.prod(spec.shape)) for spec in tensors.values())
+             for group, tensors in specs.items()}
+    assert abs(sizes["top"] / 1e6 - 754.98) < 0.1
+    assert abs(sizes["l0"] / 1e6 - (39.52 + 63.70)) < 0.1
+    assert abs(sizes["l3"] / 1e6 - (29.11 + 4.72 + 129 * 7.078 + 0.59)) < 0.2
+    held = sum(sizes.values())
+    assert abs(held / 1e9 - config["model"]["params_billions_held"]) < 0.01
+    assert abs(2 * held / 2 ** 30 - config["model"]["weights_gib"]) < 0.02
+    # qwen3-next-l4-ep2's dataset block to the letter
+    with open(os.path.join(
+            REPO, "benchmarks/configs/qwen3-next-l4-ep2.json")) as f:
+        sibling = json.load(f)
+    assert sibling["dataset"] == config["dataset"]
+    assert sibling["runtime_env"] == config["runtime_env"]
+    lengths = family.prompt_lengths(config)
+    assert min(lengths.values()) == 4096 and max(lengths.values()) <= 16384
+    # a held expert's tokens a full dispatch
+    assert 128 * 128 * config["num_experts_per_token"] \
+        // config["published"]["num_experts"] == 512
+
+
+# -- the control arms ---------------------------------------------------
+
+
+def toy_config():
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    lists = config["published"]["linear_attn_config"]
+    config.update(TOY)
+    config["published"] = dict(
+        TOY["published"], linear_attn_config=dict(
+            lists, **{k: TOY["linear_attn_config"][k]
+                      for k in ("num_heads", "head_dim")}))
+    config["experts_held"] = {"first": 0, "count": 8}
+    config["dataset"] = {"seed": 0, "long_every": 11,
+                         "short": {"count": 6, "median": 60, "sigma": 0.5,
+                                   "min": 20, "max": 100},
+                         "long": {"count": 2, "min": 100, "max": 128}}
+    config["capacity_videos_per_chip_s"] = 60
+    config["share_of_spread"] = TOY_LIMIT
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    loader.update(max_rows=8, chunk=16)
+    batcher.update(batch=8, shapes=[[8, 16], [8]], row_buckets=[4, 8])
+    prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
+                   sample_every=3, samples=8)
+    return config
+
+
+# -- the kernel for the chip ----------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_kernel_compiles_at_the_published_widths(one_chip):
+    """The rule of one layer over the largest row bucket, compiled for a
+    described v5e (nothing runs): one custom call under the kernel's
+    name, and no running sums, no ``Q x Q`` and no second ``Q x Dk``
+    array a head in the chip's memory beside the operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    rows = max(config["pipeline_config"]["pipeline"][-1]["row_buckets"])
+    q = config["chunk_size"]
+    heads = config["linear_attn_config"]["num_heads"]
+    dim = config["linear_attn_config"]["head_dim"]
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(deltanet.channel_gated_delta_rule).lower(
+        of((rows, q, heads, dim), jnp.bfloat16),
+        of((rows, q, heads, dim), jnp.bfloat16),
+        of((rows, q, heads, dim), jnp.bfloat16),
+        of((rows, q, heads, dim), jnp.float32),
+        of((rows, q, heads), jnp.float32), of((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert deltanet.KDA_KERNEL_NAME in text
+    assert deltanet.KDA_KERNEL_NAME != deltanet.KERNEL_NAME
+    assert "f32[%d,%d,%d,%d]" % (rows, heads, q, q) not in text
