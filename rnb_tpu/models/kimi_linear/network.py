@@ -11,15 +11,16 @@ request's last valid token. The norms store their weights plain.
 *Kimi Delta Attention* (Kimi Linear, arXiv:2510.26692): one product
 gives ``[q | k | v]`` (``H`` heads of ``D`` each), a causal depthwise
 convolution over each with zero history at a request's first token
-(``ops/ssd.segment_conv1d``: the three side by side), SiLU; ``q``, ``k``
-L2-normalised a head, ``q`` scaled by ``D ** -0.5``; **the gate is a
-vector**: ``log alpha = -exp(A_log[head]) softplus(f_b (f_a h) +
+(``ops/ssd.segment_conv1d``: the three side by side), SiLU; **the gate
+is a vector**: ``log alpha = -exp(A_log[head]) softplus(f_b (f_a h) +
 dt_bias)``, one decay a key channel, through a low-rank pair; ``beta =
-sigmoid(b h)`` one a head; the rule
-(``ops/deltanet.channel_gated_delta_rule``: one Pallas kernel a layer,
-which sums ``log alpha`` down a row itself and keeps every exponent at
-or under zero); an RMSNorm over each head's ``D`` columns times
-``sigmoid(g_b (g_a h))``, a second low-rank pair; the output product.
+sigmoid(b h)`` one a head; then one Pallas kernel a layer
+(``ops/deltanet.channel_gated_delta_rule``) from the convolution's
+result to the output product's operand: ``q``, ``k`` L2-normalised a
+head, ``q`` scaled by ``D ** -0.5``; the rule (the kernel sums ``log
+alpha`` down a row itself and keeps every exponent at or under zero);
+an RMSNorm over each head's ``D`` columns times ``sigmoid(g_b (g_a
+h))``, a second low-rank pair. Then the output product.
 
 *Latent attention* without positions (``mla_use_nope``): queries
 straight from the hidden state (no query latent), ``[c | k_r] = kv_a
@@ -212,10 +213,6 @@ def rms_norm(x, weight, eps: float, out_dtype):
     return (xf * weight.astype(jnp.float32)).astype(out_dtype)
 
 
-def l2_norm(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
-
-
 def _proj(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
@@ -232,32 +229,34 @@ def kda_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
     act = h.dtype
     heads, dim, width = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_dim
     qkv = _proj(h, p["in_qkv"]).astype(act)
-    # no bias; q and k are normalised in float32 behind the SiLU, so the
-    # kernel writes them in float32; v is rounded at once, by the kernel
+    # no bias; the rule's kernel normalises q and k in float32 behind the
+    # SiLU, so this kernel writes them in float32; v is rounded at once
     qk, vs = ssd.segment_conv1d(
         qkv, p["conv_w"], None, row_first, activation="silu",
         out_dtype=(jnp.float32, act), interpret=interpret,
         split=(2 * width, width))
-    qs = l2_norm(qk[..., :width].reshape(rows, q, heads, dim)) * dim ** -0.5
-    ks = l2_norm(qk[..., width:].reshape(rows, q, heads, dim))
-    vs = vs.reshape(rows, q, heads, dim)
     beta = jax.nn.sigmoid(_proj(h, p["in_b"]))
     with jax.named_scope("gate"):
         step = jax.nn.softplus(_low_rank(h, p["f_a"], p["f_b"])
                                + p["dt_bias"].astype(jnp.float32))
-        log_alpha = -jnp.exp(p["a_log"].astype(jnp.float32))[:, None] \
-            * step.reshape(rows, q, heads, dim)
+        # a head's rate over its channels: no array of a head axis
+        log_alpha = jnp.repeat(-jnp.exp(p["a_log"].astype(jnp.float32)),
+                               dim) * step
         if gate == "scalar":
             log_alpha = jnp.broadcast_to(
-                log_alpha.mean(-1, keepdims=True), log_alpha.shape)
+                log_alpha.reshape(rows, q, heads, dim)
+                .mean(-1, keepdims=True), (rows, q, heads, dim)) \
+                .reshape(rows, q, width)
+    z = _low_rank(h, p["g_a"], p["g_b"])
+    # the heads' L2 norms in front, the head norm and the gate behind are
+    # the kernel's: it reads the arrays above as they lie and writes
+    # ``o``'s operand
     with jax.named_scope("rule"):
         out = deltanet.channel_gated_delta_rule(
-            qs.astype(act), ks.astype(act), vs, log_alpha, beta, row_first,
-            state_dtype=state_dtype, interpret=interpret)
-    out = rms_norm(out, p["o_norm"], cfg.eps, jnp.float32) \
-        .reshape(rows, q, width)
-    out = out * jax.nn.sigmoid(_low_rank(h, p["g_a"], p["g_b"]))
-    return _proj(out.astype(act), p["o"])
+            qk, vs, log_alpha, beta, z, p["o_norm"], row_first, eps=cfg.eps,
+            activation="sigmoid", state_dtype=state_dtype,
+            interpret=interpret)
+    return _proj(out, p["o"])
 
 
 def latent_attention(cfg, p, h, row_start, positions, rotary=False,

@@ -12,16 +12,17 @@ norm stores them plain.
 heads of ``Dk`` twice, ``Hv`` value heads of ``Dv`` twice), one gives
 ``[b | a]`` (``Hv`` each); a causal depthwise convolution over ``[q | k
 | v]`` with zero history at a request's first token
-(``ops/ssd.segment_conv1d``), SiLU; ``q``, ``k`` L2-normalised a head,
-``q`` scaled by ``Dk ** -0.5``; ``beta = sigmoid(b)``, ``log alpha =
--exp(A_log) softplus(a + dt_bias)``; the gated delta rule
-(``ops/deltanet.py``: one Pallas kernel a layer, its grid (head group,
-row) with the rows innermost and in order; a grid step holds one row's
-scores, decay triangle, solve and updates and the head group's states
-in VMEM and carries the states in their sequential form, ``S <- exp(g_Q)
-S + (exp(g_Q - g) k)^T v_new``, zeroed where a request opens), value
-head h reading key head ``h // (Hv // Hk)``; an RMSNorm over each
-head's ``Dv`` columns times ``silu(z)``; the output product.
+(``ops/ssd.segment_conv1d``), SiLU; ``beta = sigmoid(b)``, ``log alpha
+= -exp(A_log) softplus(a + dt_bias)``; then one Pallas kernel a layer
+(``ops/deltanet.py``) from the convolution's result to the output
+product's operand: ``q``, ``k`` L2-normalised a head, ``q`` scaled by
+``Dk ** -0.5``; the gated delta rule (its grid (head group, row) with
+the rows innermost and in order; a grid step holds one row's scores,
+decay triangle, solve and updates and the head group's states in VMEM
+and carries the states in their sequential form, ``S <- exp(g_Q) S +
+(exp(g_Q - g) k)^T v_new``, zeroed where a request opens), value head h
+reading key head ``h // (Hv // Hk)``; an RMSNorm over each head's
+``Dv`` columns times ``silu(z)``. Then the output product.
 
 *Gated attention*: one product gives every head's ``[query | gate]``,
 one each keys and values; an RMSNorm over each head's columns on
@@ -168,16 +169,11 @@ def held_slots(cfg: Qwen3NextConfig, held: Sequence[int]):
     return moe.held_slots(cfg.router_experts, held)
 
 
-def rms_norm(x, weight, eps: float, out_dtype, centred: bool = True):
-    """``centred``: the weight is stored as its distance from one."""
+def rms_norm(x, weight, eps: float, out_dtype):
+    """The weight is stored as its distance from one."""
     xf = x.astype(jnp.float32)
     xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    w = weight.astype(jnp.float32)
-    return (xf * (1.0 + w if centred else w)).astype(out_dtype)
-
-
-def l2_norm(x, eps: float = 1e-6):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+    return (xf * (1.0 + weight.astype(jnp.float32))).astype(out_dtype)
 
 
 def _proj(x, w):
@@ -187,10 +183,8 @@ def _proj(x, w):
 def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
                    interpret=False):
     """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
-    rows, q, _ = h.shape
     act = h.dtype
-    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    hv = cfg.linear_num_value_heads
     # in_qkvz's columns as two products, qkv and z: of one float32 result
     # of 12,288 columns the convolution's kernel would read a rounded
     # slice, a pass of its own over them (PERF.md section 6, PR 48); its
@@ -198,26 +192,24 @@ def deltanet_mixer(cfg, p, h, row_first, state_dtype=jnp.float32,
     qkv = _proj(h, p["in_qkvz"][:, :cfg.conv_dim]).astype(act)
     z = _proj(h, p["in_qkvz"][:, cfg.conv_dim:])
     ba = _proj(h, p["in_ba"])
-    # no bias; q and k are normalised in float32 behind the SiLU, so the
-    # kernel writes them in float32; v is rounded at once, by the kernel
+    # no bias; the rule's kernel normalises q and k in float32 behind the
+    # SiLU, so this kernel writes them in float32; v is rounded at once
     qk, vs = ssd.segment_conv1d(
         qkv, p["conv_w"], None, row_first, activation="silu",
         out_dtype=(jnp.float32, act), interpret=interpret,
         split=(2 * cfg.key_dim, cfg.value_dim))
-    qs = l2_norm(qk[..., :cfg.key_dim].reshape(rows, q, hk, dk)) \
-        * dk ** -0.5
-    ks = l2_norm(qk[..., cfg.key_dim:].reshape(rows, q, hk, dk))
-    vs = vs.reshape(rows, q, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv])
     log_alpha = -jnp.exp(p["a_log"].astype(jnp.float32)) \
         * jax.nn.softplus(ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
+    # the heads' L2 norms in front, the head norm (its weight as stored)
+    # and the gate behind are the kernel's: it reads the arrays above as
+    # they lie and writes ``o``'s operand
     with jax.named_scope("rule"):
         out = deltanet.gated_delta_rule(
-            qs.astype(act), ks.astype(act), vs, log_alpha, beta,
-            row_first, state_dtype=state_dtype, interpret=interpret)
-    out = rms_norm(out, p["o_norm"], cfg.eps, jnp.float32, centred=False) \
-        .reshape(rows, q, cfg.value_dim)
-    return _proj((out * jax.nn.silu(z)).astype(act), p["o"])
+            qk, vs, log_alpha, beta, z, p["o_norm"], row_first,
+            key_heads=cfg.linear_num_key_heads, eps=cfg.eps,
+            activation="silu", state_dtype=state_dtype, interpret=interpret)
+    return _proj(out, p["o"])
 
 
 def rotate_front(cfg, x, positions):

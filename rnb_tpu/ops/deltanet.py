@@ -12,16 +12,16 @@ block, the unit-triangular system and its solve
 innermost and in order, the head group's states in a VMEM scratch that
 lives from one grid step to the next, ``row_first`` as a scalar-prefetch
 operand that zeroes the scratch where a request opens, float32 decays,
-steps, solve and states at ``highest`` precision, ``state_dtype`` for
-the control arm, and ``interpret`` for a device that is no TPU. *Where
+steps, solve and states at ``highest`` precision, the first and last
+lines around the rule (below), ``state_dtype`` for the control arm, and
+``interpret`` for a device that is no TPU. *Where
 they part*: the scalar rule multiplies a ``Q x Q`` decay triangle onto
 ``k k^T`` and ``q k^T`` *after* the products, and two value heads share
 one key head's scores; under the vector gate the decay stands *inside*
 the sum over the key channels, every head has its own ``q`` and ``k``,
 the running sums are ``(Q, Dk)`` a head and not ``(Q, 1)``, and the
 scores are assembled from pieces whose exponents are all at or under
-zero (below). The scalar rule's kernel and its callers are as PR 41
-left them.
+zero (below).
 
 A *row* is one chunk of ``Q`` consecutive tokens; a request occupies
 consecutive rows of the pool and ``row_first[r]`` says that row ``r``
@@ -79,6 +79,19 @@ independent chains of products, unrolled side by side so that the
 matrix unit has another head's product to run while one waits on its
 own result. Only the running sum ``g`` (a few bytes a token and head)
 is formed outside, in both the orientations the kernel reads it.
+
+*What stands around the rule in both mixers is the kernels' first and
+last lines*, on a head's ``(Q, D)`` slice while it is in VMEM. In
+front (:func:`_rounded_qk`): ``q`` and ``k`` are two column ranges of
+the float32 array the convolution wrote, un-normalised; the kernel
+divides a head's slice by its L2 norm in float32, scales ``q`` by
+``Dk^-1/2`` and rounds once to the activations' dtype (``v``'s). Behind
+(:func:`_gated_norm`): a head's float32 result through the head's RMS
+norm, times the output gate (``silu`` or ``sigmoid`` of a pre-activation
+read in float32 as its product wrote it), rounded once on the store:
+the kernel's result is the operand of the mixer's last product.
+Between the convolution and that product no array with a head axis
+exists in HBM.
 
 Decays, steps, ``T`` and states are float32; every product that reads
 or builds ``T`` or a state takes float32 operands at ``highest``
@@ -169,6 +182,35 @@ def _spread_columns(packed):
     return level
 
 
+#: the ``eps`` under the root of a head's L2 norm, as both families
+#: publish it
+_L2_EPS = 1e-6
+
+
+def _unit(x):
+    """A head's (Q, Dk) float32 slice over its L2 norm."""
+    return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _L2_EPS)
+
+
+def _rounded_qk(q_ref, k_ref, columns, act):
+    """The rule's first lines on one head: its ``columns`` of ``q_ref``
+    and ``k_ref`` (float32, as the convolution wrote them) over their L2
+    norms, ``q`` times ``Dk^-1/2``, each rounded once to ``act``."""
+    scale = (columns.stop - columns.start) ** -0.5
+    return ((_unit(q_ref[:, columns]) * scale).astype(act),
+            _unit(k_ref[:, columns]).astype(act))
+
+
+def _gated_norm(o, z, weight, eps: float, activation: str):
+    """The rule's last lines on one head: ``o`` (Q, Dv) float32 through
+    the head's RMS norm (``weight`` (1, Dv), as stored), times the
+    output gate, ``activation`` of the pre-activation ``z`` (Q, Dv), all
+    in float32."""
+    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) * weight
+    gate = jax.nn.sigmoid(z)
+    return o * (z * gate if activation == "silu" else gate)
+
+
 def unit_lower_inverse(lower):
     """``(I + lower)^-1`` for ``lower`` (C, C) float32, strictly lower
     triangular (zero on and over the diagonal); C a power of two. ->
@@ -207,15 +249,20 @@ def unit_lower_inverse(lower):
     return x
 
 
-def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, o_ref,
-            state_ref, *, per: int, dk: int, dv: int, state_dtype):
+def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, z_ref,
+            w_ref, o_ref, state_ref, *, per: int, dk: int, dv: int,
+            eps: float, activation: str, state_dtype):
     """One row of one head group. ``q_ref``, ``k_ref`` (Q, heads *
-    Dk), ``v_ref`` (Q, heads * per * Dv); ``g_ref``, ``b_ref`` (Q,
-    value heads) the running sums and the steps, a token a sublane;
+    Dk) float32, as the convolution wrote them; ``v_ref`` (Q, heads *
+    per * Dv) in the activations' dtype; ``g_ref``, ``b_ref`` (Q, value
+    heads) the running sums and the steps, a token a sublane;
     ``g_row_ref`` (value heads, Q) the sums again, a token a lane;
-    ``state_ref`` (value heads, Dk, Dv) float32, carried."""
+    ``z_ref`` (Q, heads * per * Dv) float32 the output gate before its
+    activation, ``w_ref`` (1, Dv) the head norm's weight; ``o_ref`` as
+    ``v_ref``; ``state_ref`` (value heads, Dk, Dv) float32, carried."""
     f32 = jnp.float32
     qlen = q_ref.shape[0]
+    act = v_ref.dtype
 
     @pl.when(first_ref[pl.program_id(1)] != 0)
     def _():
@@ -223,12 +270,14 @@ def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, o_ref,
 
     token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
     other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
+    weight = w_ref[...]
     for head in range(k_ref.shape[1] // dk):
-        k = k_ref[:, head * dk:(head + 1) * dk]
-        q = q_ref[:, head * dk:(head + 1) * dk]
+        q, k = _rounded_qk(q_ref, k_ref, slice(head * dk, (head + 1) * dk),
+                           act)
         kk, qk = _scores(k, k), _scores(q, k)
         kf, qf = k.astype(f32), q.astype(f32)
         for j in range(head * per, (head + 1) * per):
+            of_head = slice(j * dv, (j + 1) * dv)
             g = g_ref[:, j:j + 1]                            # (Q, 1)
             b = b_ref[:, j:j + 1]
             g_row = g_row_ref[j:j + 1, :]                    # (1, Q)
@@ -236,12 +285,14 @@ def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, o_ref,
             decay = jnp.exp(jnp.where(token >= other, g - g_row, -jnp.inf))
             t = unit_lower_inverse(
                 jnp.where(token > other, b * kk * decay, 0.0))
-            u = _dot(t, b * v_ref[:, j * dv:(j + 1) * dv].astype(f32))
+            u = _dot(t, b * v_ref[:, of_head].astype(f32))
             w = _dot(t, (b * jnp.exp(g)) * kf)
             state = state_ref[j]
             v_new = u - _dot(w, state)
-            o_ref[:, j * dv:(j + 1) * dv] = _dot(qf * jnp.exp(g), state) \
-                + _dot(qk * decay, v_new)
+            o = _dot(qf * jnp.exp(g), state) + _dot(qk * decay, v_new)
+            o_ref[:, of_head] = _gated_norm(
+                o, z_ref[:, of_head], weight, eps, activation) \
+                .astype(o_ref.dtype)
             state = jnp.exp(jnp.broadcast_to(end, (1, dv))) * state \
                 + lax.dot_general(
                     kf * jnp.exp(end - g), v_new, (((0,), (0,)), ((), ())),
@@ -252,22 +303,70 @@ def _kernel(first_ref, q_ref, k_ref, v_ref, g_ref, b_ref, g_row_ref, o_ref,
             state_ref[j] = state.astype(state_dtype).astype(f32)
 
 
-def gated_delta_rule(q, k, v, log_alpha, beta, row_first,
+def gated_delta_rule(qk, v, log_alpha, beta, z, norm_weight, row_first, *,
+                     key_heads: int, eps: float, activation: str,
                      state_dtype=jnp.float32, interpret: bool = False):
-    """The rule of one layer over a packed pool.
+    """The rule of one layer over a packed pool, from the convolution's
+    result to the operand of the mixer's last product.
 
-    ``q``, ``k`` (rows, Q, Hk, Dk), as the rule reads them (normalised,
-    ``q`` scaled); ``v`` (rows, Q, Hv, Dv), value head h reading key
+    ``qk`` (rows, Q, 2 Hk Dk) float32: every key head's ``q``, then
+    every key head's ``k``, as the convolution wrote them (the kernel
+    normalises, scales ``q`` and rounds: the module's docstring); ``v``
+    (rows, Q, Hv Dv) in the activations' dtype, value head h reading key
     head h // (Hv // Hk); ``log_alpha`` (rows, Q, Hv) float32, <= 0;
-    ``beta`` (rows, Q, Hv) float32; ``row_first`` (rows,) bool; Q a
-    power of two. -> float32 (rows, Q, Hv, Dv).
+    ``beta`` (rows, Q, Hv) float32; ``z`` (rows, Q, Hv Dv) float32, the
+    output gate before its ``activation`` ("silu" or "sigmoid");
+    ``norm_weight`` (Dv,) the head norm's, with ``eps``; ``row_first``
+    (rows,) bool; ``key_heads`` Hk; Q a power of two. -> (rows, Q, Hv
+    Dv) in ``v``'s dtype.
 
     ``state_dtype`` is the precision the states are carried in between
     rows: float32 in the program; the lower-precision control passes
     bfloat16. ``interpret`` runs the kernel in interpret mode (a device
     that is no TPU)."""
-    rows, qlen, hk, dk = k.shape
-    hv, dv = v.shape[2:]
+    assert activation in ("silu", "sigmoid"), activation
+    return _rule_call(qk, v, log_alpha, beta, z, norm_weight, row_first,
+                      key_heads=key_heads, eps=float(eps),
+                      activation=activation,
+                      state_dtype=jnp.dtype(state_dtype), interpret=interpret)
+
+
+def _cost(rows: int, qlen: int, heads: int, dk: int, dv: int, operands,
+          out) -> pl.CostEstimate:
+    """What a call costs, for the compiler that schedules around it: it
+    overlaps its own copies (the residual stream's prefetch in front of
+    ``o``'s product, for one) with a custom call only as far as it
+    knows how long the call runs. The ten float32 products a head and
+    row that both kernels share - the solve's merged levels, ``U``,
+    ``W``, the three against the state and ``qk v_new`` - at the six
+    passes ``highest`` takes; the single-pass score products and the
+    vector unit's work are left out, the exponentials one a pair of
+    tokens."""
+    levels = max(0, (qlen // _SOLVE_BASE).bit_length() - 1)
+    products = 2 * levels * qlen ** 3 + qlen * qlen * (dk + 2 * dv) \
+        + 3 * qlen * dk * dv
+    return pl.CostEstimate(
+        flops=2 * 6 * products * rows * heads,
+        transcendentals=rows * heads * qlen * qlen,
+        bytes_accessed=sum(x.size * x.dtype.itemsize
+                           for x in operands + (out,)))
+
+
+def _columns(qlen: int, width: int, offset: int = 0):
+    """Head group ``i``'s ``width`` columns of row ``r`` of a (rows, Q,
+    columns) array, ``offset`` blocks in."""
+    return pl.BlockSpec((None, qlen, width),
+                        lambda i, r, _: (r, 0, offset + i))
+
+
+# a function under ``jit`` of its own: a stack's layers call it with the
+# same shapes, and the kernel is traced and lowered once for all of them
+@functools.partial(jax.jit, static_argnames=(
+    "key_heads", "eps", "activation", "state_dtype", "interpret"))
+def _rule_call(qk, v, log_alpha, beta, z, norm_weight, row_first, *,
+               key_heads, eps, activation, state_dtype, interpret):
+    rows, qlen, hv = beta.shape
+    hk, dk, dv = key_heads, qk.shape[2] // (2 * key_heads), v.shape[2] // hv
     per = hv // hk
     heads = min(_KEY_HEADS, hk)
     groups, values = hk // heads, heads * per
@@ -278,30 +377,31 @@ def gated_delta_rule(q, k, v, log_alpha, beta, row_first,
     b = beta.astype(f32).reshape(rows, qlen, groups, values) \
         .transpose(0, 2, 1, 3)
 
-    def columns(width):
-        return pl.BlockSpec((None, qlen, width), lambda i, r, _: (r, 0, i))
-
     def of_group(*block):
         return pl.BlockSpec((None, None) + block,
                             lambda i, r, _: (r, i, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(_kernel, per=per, dk=dk, dv=dv,
-                          state_dtype=state_dtype),
+    operands = (qk, v, g, b, g.transpose(0, 1, 3, 2), z.astype(f32),
+                norm_weight.astype(f32)[None, :])
+    out = jax.ShapeDtypeStruct((rows, qlen, hv * dv), v.dtype)
+    return pl.pallas_call(
+        functools.partial(_kernel, per=per, dk=dk, dv=dv, eps=eps,
+                          activation=activation, state_dtype=state_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(groups, rows),
-            in_specs=[columns(heads * dk), columns(heads * dk),
-                      columns(values * dv), of_group(qlen, values),
-                      of_group(qlen, values), of_group(values, qlen)],
-            out_specs=columns(values * dv),
+            in_specs=[_columns(qlen, heads * dk),
+                      _columns(qlen, heads * dk, groups),
+                      _columns(qlen, values * dv), of_group(qlen, values),
+                      of_group(qlen, values), of_group(values, qlen),
+                      _columns(qlen, values * dv),
+                      pl.BlockSpec((1, dv), lambda i, r, _: (0, 0))],
+            out_specs=_columns(qlen, values * dv),
             scratch_shapes=[pltpu.VMEM((values, dk, dv), f32)]),
-        out_shape=jax.ShapeDtypeStruct((rows, qlen, hv * dv), f32),
+        out_shape=out,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=_cost(rows, qlen, hv, dk, dv, operands, out),
         interpret=interpret, name=KERNEL_NAME,
-    )(row_first.astype(jnp.int32), q.reshape(rows, qlen, hk * dk),
-      k.reshape(rows, qlen, hk * dk), v.reshape(rows, qlen, hv * dv),
-      g, b, g.transpose(0, 1, 3, 2))
-    return out.reshape(rows, qlen, hv, dv)
+    )(row_first.astype(jnp.int32), qk, *operands)
 
 
 # -- the gate a channel (Kimi Delta Attention) ----------------------------
@@ -366,15 +466,20 @@ def channel_scores(qf, kf, g, score_dtype):
     return kk, qk
 
 
-def _kda_kernel(first_ref, q_ref, k_ref, v_ref, a_ref, b_ref, o_ref,
-                state_ref, *, dk: int, dv: int, state_dtype):
-    """One row of one head group. ``q_ref``, ``k_ref`` (Q, heads * Dk),
-    ``v_ref`` (Q, heads * Dv), ``a_ref`` (Q, heads * Dk) float32 the
-    ``log alpha`` of every channel, ``b_ref`` (Q, heads) the steps;
+def _kda_kernel(first_ref, q_ref, k_ref, v_ref, a_ref, b_ref, z_ref, w_ref,
+                o_ref, state_ref, *, dk: int, dv: int, eps: float,
+                activation: str, state_dtype):
+    """One row of one head group. ``q_ref``, ``k_ref`` (Q, heads * Dk)
+    float32, as the convolution wrote them; ``v_ref`` (Q, heads * Dv) in
+    the activations' dtype; ``a_ref`` (Q, heads * Dk) float32 the ``log
+    alpha`` of every channel, ``b_ref`` (Q, heads) the steps; ``z_ref``
+    (Q, heads * Dv) float32 the output gate before its activation,
+    ``w_ref`` (1, Dv) the head norm's weight; ``o_ref`` as ``v_ref``;
     ``state_ref`` (heads, Dv, Dk) float32, carried, a head's state
     transposed."""
     f32 = jnp.float32
     qlen = q_ref.shape[0]
+    act = v_ref.dtype
 
     @pl.when(first_ref[pl.program_id(1)] != 0)
     def _():
@@ -383,75 +488,83 @@ def _kda_kernel(first_ref, q_ref, k_ref, v_ref, a_ref, b_ref, o_ref,
     token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
     other = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 1)
     ones = jnp.where(token >= other, 1.0, 0.0).astype(f32)
+    weight = w_ref[...]
     for head in range(k_ref.shape[1] // dk):
-        k = k_ref[:, head * dk:(head + 1) * dk]
-        kf = k.astype(f32)
-        qf = q_ref[:, head * dk:(head + 1) * dk].astype(f32)
-        g = _dot(ones, a_ref[:, head * dk:(head + 1) * dk])    # (Q, Dk)
+        of_keys = slice(head * dk, (head + 1) * dk)
+        of_values = slice(head * dv, (head + 1) * dv)
+        qf, kf = (x.astype(f32)
+                  for x in _rounded_qk(q_ref, k_ref, of_keys, act))
+        g = _dot(ones, a_ref[:, of_keys])                      # (Q, Dk)
         b = b_ref[:, head:head + 1]                            # (Q, 1)
         end = g[qlen - 1:qlen]                                 # (1, Dk)
-        kk, qk = channel_scores(qf, kf, g, k.dtype)
+        kk, qk = channel_scores(qf, kf, g, act)
         t = unit_lower_inverse(b * kk)
-        u = _dot(t, b * v_ref[:, head * dv:(head + 1) * dv].astype(f32))
+        u = _dot(t, b * v_ref[:, of_values].astype(f32))
         w = _dot(t, (b * jnp.exp(g)) * kf)
         state = state_ref[head]                                # (Dv, Dk)
         v_new = u - _dot_nt(w, state)
-        o_ref[:, head * dv:(head + 1) * dv] = \
-            _dot_nt(qf * jnp.exp(g), state) + _dot(qk, v_new)
+        o = _dot_nt(qf * jnp.exp(g), state) + _dot(qk, v_new)
+        o_ref[:, of_values] = _gated_norm(
+            o, z_ref[:, of_values], weight, eps, activation) \
+            .astype(o_ref.dtype)
         state = state * jnp.exp(end) + lax.dot_general(
             v_new, kf * jnp.exp(end - g), (((0,), (0,)), ((), ())),
             precision=_HIGHEST, preferred_element_type=f32)
         state_ref[head] = state.astype(state_dtype).astype(f32)
 
 
-def channel_gated_delta_rule(q, k, v, log_alpha, beta, row_first,
+def channel_gated_delta_rule(qk, v, log_alpha, beta, z, norm_weight,
+                             row_first, *, eps: float, activation: str,
                              state_dtype=jnp.float32,
                              interpret: bool = False):
-    """The rule of one layer over a packed pool, the gate a channel.
+    """The rule of one layer over a packed pool, the gate a channel,
+    from the convolution's result to the operand of the mixer's last
+    product.
 
-    ``q``, ``k`` (rows, Q, H, Dk), as the rule reads them (normalised,
-    ``q`` scaled); ``v`` (rows, Q, H, Dv); ``log_alpha`` (rows, Q, H,
-    Dk) float32, <= 0, a token's own (not summed); ``beta`` (rows, Q,
-    H) float32; ``row_first`` (rows,) bool; Q a power of two.
-    -> float32 (rows, Q, H, Dv).
-
+    ``qk`` (rows, Q, 2 H Dk) float32: every head's ``q``, then every
+    head's ``k``, as the convolution wrote them; ``v`` (rows, Q, H Dv)
+    in the activations' dtype; ``log_alpha`` (rows, Q, H Dk) float32, <=
+    0, a token's own (not summed); ``beta`` (rows, Q, H) float32; ``z``,
+    ``norm_weight``, ``eps``, ``activation``, ``row_first``,
     ``state_dtype`` and ``interpret`` as :func:`gated_delta_rule` takes
-    them."""
-    return _kda_call(q, k, v, log_alpha, beta, row_first,
+    them; Q a power of two. -> (rows, Q, H Dv) in ``v``'s dtype."""
+    assert activation in ("silu", "sigmoid"), activation
+    return _kda_call(qk, v, log_alpha, beta, z, norm_weight, row_first,
+                     eps=float(eps), activation=activation,
                      state_dtype=jnp.dtype(state_dtype), interpret=interpret)
 
 
-# a function under ``jit`` of its own: a stack's layers call it with the
-# same shapes, and the kernel is traced and lowered once for all of them
-@functools.partial(jax.jit, static_argnames=("state_dtype", "interpret"))
-def _kda_call(q, k, v, log_alpha, beta, row_first, *, state_dtype,
-              interpret):
-    rows, qlen, heads, dk = k.shape
-    dv = v.shape[3]
+@functools.partial(jax.jit, static_argnames=(
+    "eps", "activation", "state_dtype", "interpret"))
+def _kda_call(qk, v, log_alpha, beta, z, norm_weight, row_first, *, eps,
+              activation, state_dtype, interpret):
+    rows, qlen, heads = beta.shape
+    dk, dv = qk.shape[2] // (2 * heads), v.shape[2] // heads
     step = min(_KDA_HEADS, heads)
     groups = heads // step
     f32 = jnp.float32
     b = beta.astype(f32).reshape(rows, qlen, groups, step) \
         .transpose(0, 2, 1, 3)
-
-    def columns(width):
-        return pl.BlockSpec((None, qlen, width), lambda i, r, _: (r, 0, i))
-    out = pl.pallas_call(
-        functools.partial(_kda_kernel, dk=dk, dv=dv,
-                          state_dtype=state_dtype),
+    operands = (qk, v, log_alpha.astype(f32), b, z.astype(f32),
+                norm_weight.astype(f32)[None, :])
+    out = jax.ShapeDtypeStruct((rows, qlen, heads * dv), v.dtype)
+    return pl.pallas_call(
+        functools.partial(_kda_kernel, dk=dk, dv=dv, eps=eps,
+                          activation=activation, state_dtype=state_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(groups, rows),
-            in_specs=[columns(step * dk), columns(step * dk),
-                      columns(step * dv), columns(step * dk),
+            in_specs=[_columns(qlen, step * dk),
+                      _columns(qlen, step * dk, groups),
+                      _columns(qlen, step * dv), _columns(qlen, step * dk),
                       pl.BlockSpec((None, None, qlen, step),
-                                   lambda i, r, _: (r, i, 0, 0))],
-            out_specs=columns(step * dv),
+                                   lambda i, r, _: (r, i, 0, 0)),
+                      _columns(qlen, step * dv),
+                      pl.BlockSpec((1, dv), lambda i, r, _: (0, 0))],
+            out_specs=_columns(qlen, step * dv),
             scratch_shapes=[pltpu.VMEM((step, dv, dk), f32)]),
-        out_shape=jax.ShapeDtypeStruct((rows, qlen, heads * dv), f32),
+        out_shape=out,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=_cost(rows, qlen, heads, dk, dv, operands, out),
         interpret=interpret, name=KDA_KERNEL_NAME,
-    )(row_first.astype(jnp.int32), q.reshape(rows, qlen, heads * dk),
-      k.reshape(rows, qlen, heads * dk), v.reshape(rows, qlen, heads * dv),
-      log_alpha.astype(f32).reshape(rows, qlen, heads * dk), b)
-    return out.reshape(rows, qlen, heads, dv)
+    )(row_first.astype(jnp.int32), qk, *operands)

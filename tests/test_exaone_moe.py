@@ -984,7 +984,11 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     ``ops/ssd.segment_conv1d``, is one Pallas kernel with the SiLU
     inside, interpreted here, called once for q with k and once for v,
     and ``in_qkvz``'s columns are two products; this family runs no such
-    convolution and its text is the one PR 44 recorded)."""
+    convolution and its text is the one PR 44 recorded); PR 50 recorded
+    ``qwen3_next``'s again (the L2 norms in front of its delta rule and
+    the head norm and gate behind are the rule's kernel's first and
+    last lines, ``ops/deltanet.py``: the mixer reshapes nothing to a
+    head axis between the convolution and ``o``)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

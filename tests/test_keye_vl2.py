@@ -1132,7 +1132,9 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     kernel) and PR 48 ``nemotron_h``'s and ``qwen3_next``'s
     (``ops/ssd.segment_conv1d`` became one, with the callers' SiLU
     inside); the other four — the families whose cells bypass that
-    convolution — are the texts their PRs left."""
+    convolution — are the texts their PRs left. PR 50 recorded
+    ``qwen3_next``'s again (``ops/deltanet.py``'s kernel took the norms
+    and the gate around the rule in; no other family calls it)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

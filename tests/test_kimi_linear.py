@@ -1,9 +1,12 @@
 """The Kimi-Linear family against its plain reference, at a toy size on
 the CPU with weights from a seed: the vector gate's kernel
-(``ops/deltanet.channel_gated_delta_rule``, interpreted) against the
-token-by-token recurrence over packed pools at a mild and at a harsh
-draw of the decays, against the scalar rule under a gate that is the
-same in every channel, its states through bfloat16; the shares of the experts adding
+(``ops/deltanet.channel_gated_delta_rule``, interpreted) with its first
+and last lines against the plain composition - ``l2_norm``, the
+token-by-token recurrence, ``rms_norm`` times the gate - over packed
+pools at a mild and at a harsh draw of the decays, at toy heads and at
+the family's 32 of 128, against the scalar rule under a gate that is the
+same in every channel, its states through bfloat16; no float32 array of
+a head axis in the real 128-row program; the shares of the experts adding
 up to the uncut layer; latent attention that rotates nothing and reads
 no other request's keys; the recipe, the operation counts, the real
 configuration against the catalog's row, and the kernel compiled at the
@@ -13,6 +16,7 @@ command and the readers ``test_kimi_linear_cell.py``'s (one file is one
 worker's under ``--dist loadfile``, and each stays under two minutes).
 Nothing here needs the native decode library or a chip."""
 
+import functools
 import json
 import os
 import sys
@@ -26,6 +30,7 @@ sys.path.insert(0, REPO)
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import kimi_linear as reference  # noqa: E402
+from test_qwen3_next import first_token_alone, firsts_of  # noqa: E402
 
 REAL = "benchmarks/configs/kimi-linear-l5-ep2.json"
 CELL = "kimi-linear.bulk"
@@ -151,45 +156,74 @@ def through_float8(params):
 # -- the vector gate's kernel alone ---------------------------------------
 
 
+EPS = 1e-5
+
+
 def rule_inputs(rows, qlen, heads=2, dk=16, dv=16, harsh=False, seed=1,
-                dtype=None):
-    """Operands as the mixer hands them over. ``mild``: a channel's
-    ``log alpha`` is -0.0003 to -0.03 a token, so that it fades over
-    hundreds to thousands of tokens; ``harsh``: down to -25 a token, a
-    head's channels two orders apart, so that ``exp(-g)`` alone would
-    overflow float32 inside a row of 8 already."""
+                act=None):
+    """The kernel's operands as the mixer hands them over: ``qk`` as a
+    convolution wrote it (float32, every head's q then every head's k, a
+    token's length anything from a tenth to ten), ``v`` (in ``act``,
+    else float32), ``log alpha`` a channel, ``beta``, the output gate
+    before its sigmoid and the head norm's weight. ``mild``: a
+    channel's ``log alpha`` is -0.0003 to -0.03 a token, so that it
+    fades over hundreds to thousands of tokens; ``harsh``: down to -25 a
+    token, a head's channels two orders apart, so that ``exp(-g)`` alone
+    would overflow float32 inside a row of 8 already."""
     import jax
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
 
     def n(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
-    q, k = n(rows, qlen, heads, dk), n(rows, qlen, heads, dk)
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    qk = n(rows, qlen, 2 * heads, dk) \
+        * jnp.exp(jnp.log(10.0) * jnp.asarray(
+            rng.uniform(-1, 1, (rows, qlen, 2 * heads, 1)), jnp.float32))
     rate = jnp.asarray(rng.uniform(0.01, 1.0, size=(heads, dk)),
                        jnp.float32)
     token = jnp.asarray(rng.uniform(0.03, 1.0, size=(rows, qlen, heads, dk)),
                         jnp.float32)
     log_alpha = -(25.0 if harsh else 0.03) * rate * token
-    out = (q, k, n(rows, qlen, heads, dv), log_alpha,
-           jax.nn.sigmoid(n(rows, qlen, heads)))
-    if dtype is not None:
-        out = tuple(x.astype(dtype) for x in out[:3]) + out[3:]
-    return out
+    return (qk.reshape(rows, qlen, 2 * heads * dk),
+            n(rows, qlen, heads * dv).astype(act or jnp.float32),
+            log_alpha.reshape(rows, qlen, heads * dk),
+            jax.nn.sigmoid(n(rows, qlen, heads)), n(rows, qlen, heads * dv),
+            1.0 + 0.1 * n(dv))
 
 
-def recurrence(inputs, lo, hi):
-    """The plain reference's rule over rows ``lo`` to ``hi``, a request
-    of its own."""
+def rule(inputs, row_first, activation="sigmoid", **how):
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    return np.asarray(deltanet.channel_gated_delta_rule(
+        *inputs, jnp.asarray(row_first), eps=EPS, activation=activation,
+        interpret=True, **how).astype(jnp.float32))
+
+
+def composed(inputs, lo, hi):
+    """What the kernel replaces, the plain way, over rows ``lo`` to
+    ``hi`` as a request of its own: ``l2_norm`` a head and q's scale,
+    one rounding to the activations' dtype, the reference's recurrence
+    token by token, the head's ``rms_norm``, times ``sigmoid(z)``, one
+    rounding. -> (L, H Dv) float32."""
     import jax
     import jax.numpy as jnp
-    q, k, v, log_alpha, beta = (
-        x[lo:hi].reshape((-1,) + x.shape[2:]).astype(jnp.float32)
+    qk, v, log_alpha, beta, z, weight = (
+        x[lo:hi].reshape((-1,) + x.shape[2:]) if x.ndim > 1 else x
         for x in inputs)
+    act, heads = v.dtype, beta.shape[-1]
+    length, dk = qk.shape[0], qk.shape[1] // (2 * heads)
+    qk = qk.reshape(length, 2, heads, dk)
+    q = (reference.l2_norm(qk[:, 0]) * dk ** -0.5).astype(act)
+    k = reference.l2_norm(qk[:, 1]).astype(act)
     with jax.default_matmul_precision("highest"):
-        return np.asarray(reference.delta_rule(q, k, v, jnp.exp(log_alpha),
-                                               beta))
+        out = reference.delta_rule(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.reshape(length, heads, -1).astype(jnp.float32),
+            jnp.exp(log_alpha.reshape(length, heads, dk)), beta)
+    out = reference.rms_norm(out, weight, EPS).reshape(z.shape) \
+        * jax.nn.sigmoid(z)
+    return np.asarray(out.astype(act).astype(jnp.float32))
 
 
 #: (tokens a row, rows, the rows that open a request, heads, harsh): a
@@ -208,53 +242,77 @@ RULE_CASES = [
 @pytest.mark.parametrize("qlen,rows,firsts,heads,harsh", RULE_CASES)
 def test_the_kernel_matches_the_recurrence(qlen, rows, firsts, heads, harsh):
     """``channel_gated_delta_rule`` (interpreted) over a packed pool
-    against the recurrence token by token, a request at a time: finite
-    everywhere and within 2e-5 of the result's largest entry, float32
-    operands. At the harsh draw a channel's running sum passes -10 a
-    token: under -160 inside a row of 16 and -1,280 inside one of 128,
-    where float32 ends at exp(88)."""
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import deltanet
+    against the plain composition around the recurrence token by token,
+    a request at a time: finite everywhere and within 2e-5 of the
+    result's largest entry, float32 operands. At the harsh draw a
+    channel's running sum passes -10 a token: under -160 inside a row
+    of 16 and -1,280 inside one of 128, where float32 ends at
+    exp(88)."""
     inputs = rule_inputs(rows, qlen, heads, harsh=harsh)
-    row_first = np.zeros(rows, bool)
-    row_first[list(firsts)] = True
-    out = np.asarray(deltanet.channel_gated_delta_rule(
-        *inputs, jnp.asarray(row_first), interpret=True))
+    out = rule(inputs, firsts_of(rows, firsts))
     assert np.isfinite(out).all()
     if harsh:
-        assert float(np.asarray(inputs[3]).sum(1).min()) < -10.0 * qlen
+        assert float(np.asarray(inputs[2]).sum(1).min()) < -10.0 * qlen
     bounds = list(firsts) + [rows]
     for lo, hi in zip(bounds, bounds[1:]):
-        want = recurrence(inputs, lo, hi)
+        want = composed(inputs, lo, hi)
         got = out[lo:hi].reshape(want.shape)
         assert np.abs(got - want).max() < 2e-5 * max(
             1.0, np.abs(want).max())
-    # a state that did not restart would show: the second request's
-    # first token reads only its own write
+    # a state that did not restart would show at the second request's
+    # first token
     lo = firsts[1] if len(firsts) > 1 else 0
-    q, k, v, _, beta = (np.asarray(x, np.float64) for x in inputs)
-    alone = beta[lo, 0, :, None] * v[lo, 0] \
-        * (k[lo, 0] * q[lo, 0]).sum(-1)[:, None]
-    assert np.abs(out[lo, 0] - alone).max() < 1e-5
+    alone = first_token_alone(inputs, lo, heads, EPS,
+                              lambda z: 1.0 / (1.0 + np.exp(-z)))
+    assert np.abs(out[lo, 0].reshape(alone.shape) - alone).max() < 2e-5
+
+
+#: (heads, the activations' dtype, the limit as a share of the largest
+#: entry): Kimi-Linear's head geometry - 32 heads of 128, each with its
+#: own q and k, sixteen head groups - in float32 and in the program's
+#: bfloat16, where kernel and composition round at the same two places:
+#: the levels' products then take ``k exp(.)`` rounded once more (below)
+GEOMETRY_CASES = [(32, "float32", 2e-5), (32, "bfloat16", 0.02)]
+
+
+@pytest.mark.parametrize("heads,act,limit", GEOMETRY_CASES)
+def test_the_kernels_first_and_last_lines_are_the_mixers_norms(
+        heads, act, limit):
+    """The kernel with its prologue and epilogue against ``l2_norm`` ->
+    the sequential rule -> ``rms_norm`` x ``sigmoid(z)``, at the
+    family's head count and head size, over a pool of six rows that
+    holds a request of one row, a request of three rows whose first
+    lies mid-pool, a pad row (a request of its own) and a request's
+    first row at the pool's end."""
+    import jax.numpy as jnp
+    firsts = (0, 1, 4, 5)
+    inputs = rule_inputs(6, 16, heads, dk=128, dv=128, act=jnp.dtype(act))
+    # a pad row: the tokens the packer left empty read as zeros
+    inputs = tuple(x.at[4].set(0) if x.ndim == 3 else x for x in inputs)
+    out = rule(inputs, firsts_of(6, firsts))
+    assert np.isfinite(out).all()
+    for lo, hi in zip(firsts, firsts[1:] + (6,)):
+        want = composed(inputs, lo, hi)
+        got = out[lo:hi].reshape(want.shape)
+        assert np.abs(got - want).max() < limit * max(
+            1.0, np.abs(want).max()), (lo, hi)
 
 
 @pytest.mark.parametrize("harsh", [False, True])
 def test_bfloat16_operands_stay_inside_their_rounding(harsh):
-    """What the program hands the kernel: ``q``, ``k``, ``v`` in
-    bfloat16. The levels' products then take ``k exp(.)`` rounded to
-    bfloat16; against the recurrence on the same (rounded) operands the
-    result stays within 2% of its spread."""
+    """What the program hands the kernel: ``v`` in bfloat16, and ``q``
+    and ``k`` rounded to it behind their norms. The levels' products
+    then take ``k exp(.)`` rounded to bfloat16; against the composition
+    that rounds at the kernel's two places the result stays within 2% of
+    its spread and the one step of bfloat16 (2^-7 of the entry) that the
+    last rounding may fall to the other side by."""
     import jax.numpy as jnp
-
-    from rnb_tpu.ops import deltanet
-    inputs = rule_inputs(4, 64, 2, harsh=harsh, dtype=jnp.bfloat16)
-    out = np.asarray(deltanet.channel_gated_delta_rule(
-        *inputs, jnp.asarray([True, False, False, True]), interpret=True))
-    want = np.concatenate([recurrence(inputs, 0, 3),
-                           recurrence(inputs, 3, 4)])
+    inputs = rule_inputs(4, 64, 2, harsh=harsh, act=jnp.bfloat16)
+    out = rule(inputs, [True, False, False, True])
+    want = np.concatenate([composed(inputs, 0, 3), composed(inputs, 3, 4)])
     assert np.isfinite(out).all()
-    assert np.abs(out.reshape(want.shape) - want).max() < 0.02 * want.std()
+    assert (np.abs(out.reshape(want.shape) - want)
+            < 0.02 * want.std() + 2.0 ** -7 * np.abs(want)).all()
 
 
 @pytest.mark.parametrize("qlen", [16, 128])
@@ -262,25 +320,26 @@ def test_a_gate_alike_in_every_channel_is_the_scalar_rule(qlen):
     """The two rules of ``ops/deltanet.py`` on the same operands: with a
     head's ``log alpha`` the same in all its channels the vector gate's
     kernel gives what ``gated_delta_rule`` gives (one value head a key
-    head)."""
+    head), the first and last lines alike under either activation."""
     import jax.numpy as jnp
 
     from rnb_tpu.ops import deltanet
-    rows = 3
-    q, k, v, log_alpha, beta = rule_inputs(rows, qlen, 2)
-    one = log_alpha[..., :1] * 10.0
+    rows, heads, dk = 3, 2, 16
+    qk, v, log_alpha, beta, z, weight = rule_inputs(rows, qlen, heads, dk)
+    one = log_alpha.reshape(rows, qlen, heads, dk)[..., :1] * 10.0
+    alike = jnp.broadcast_to(one, (rows, qlen, heads, dk)) \
+        .reshape(log_alpha.shape)
     row_first = jnp.asarray([True, False, True])
-    vector = np.asarray(deltanet.channel_gated_delta_rule(
-        q, k, v, jnp.broadcast_to(one, log_alpha.shape), beta, row_first,
-        interpret=True))
-    scalar = np.asarray(deltanet.gated_delta_rule(
-        q, k, v, one[..., 0], beta, row_first, interpret=True))
-    assert np.abs(vector - scalar).max() < 2e-5 * max(
-        1.0, np.abs(scalar).max())
+    for activation in ("sigmoid", "silu"):
+        vector = rule((qk, v, alike, beta, z, weight), row_first, activation)
+        scalar = np.asarray(deltanet.gated_delta_rule(
+            qk, v, one[..., 0], beta, z, weight, row_first, key_heads=heads,
+            eps=EPS, activation=activation, interpret=True))
+        assert np.abs(vector - scalar).max() < 2e-5 * max(
+            1.0, np.abs(scalar).max())
     # and another gate in one channel is another result
-    other = np.asarray(deltanet.channel_gated_delta_rule(
-        q, k, v, jnp.broadcast_to(one, log_alpha.shape)
-        .at[..., 0].multiply(3.0), beta, row_first, interpret=True))
+    other = rule((qk, v, alike.at[..., 0].multiply(3.0), beta, z, weight),
+                 row_first, "silu")
     assert np.abs(other - scalar).max() > 1e-3 * np.abs(scalar).max()
 
 
@@ -290,16 +349,11 @@ def test_bfloat16_states_differ_by_one_rounding_a_row():
     row reads a state rounded once more, and differs by no more than
     its roundings allow."""
     import jax.numpy as jnp
-
-    from rnb_tpu.ops import deltanet
     inputs = rule_inputs(6, 16)
 
-    def rule(firsts, **how):
-        row_first = np.zeros(6, bool)
-        row_first[list(firsts)] = True
-        return np.asarray(deltanet.channel_gated_delta_rule(
-            *inputs, jnp.asarray(row_first), interpret=True, **how))
-    exact, rounded = rule((0, 4)), rule((0, 4), state_dtype=jnp.bfloat16)
+    def states(firsts, **how):
+        return rule(inputs, firsts_of(6, firsts), **how)
+    exact, rounded = states((0, 4)), states((0, 4), state_dtype=jnp.bfloat16)
     scale = np.abs(exact).max()
     for row in (0, 4):
         assert np.array_equal(rounded[row], exact[row])
@@ -635,6 +689,34 @@ def toy_config():
 # -- the kernel for the chip ----------------------------------------------
 
 
+def test_no_array_of_a_head_axis_between_convolution_and_output_product():
+    """The real configuration's 128-row program, lowered (nothing is
+    compiled or run; the kernels interpreted, their bodies a grid
+    step's): from ``segment_conv1d``'s call to ``o``'s product the KDA
+    mixer reshapes nothing to (tokens, heads, 128) - the heads' L2
+    norms, the head norm and the gate are the rule's kernel's, on slices
+    in VMEM, and ``log alpha`` is a head's rate repeated over its
+    channels - so the program holds no float32 array of 32 heads of 128
+    at all (latent attention's operands lie heads first). PR 49's tree
+    held four of ``128x128x32x128`` a KDA layer: q, k, the gate's steps
+    and the rule's result."""
+    from test_qwen3_next import head_axis_arrays, lowered_text
+
+    from rnb_tpu.models.kimi_linear import checkpoint, network
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    cfg = network.KimiLinearConfig.from_published(
+        mm.load_family(config["family"]).published_keys(config))
+    assert cfg.kda_head_dim == 128
+    text = lowered_text(checkpoint, network, cfg,
+                        range(config["num_experts"]), rows=128)
+    tokens = "128x%d" % cfg.chunk_size
+    # what the kernel reads and writes is there, as its neighbours wrote it
+    assert "tensor<%sx%dxf32>" % (tokens, 2 * cfg.kda_dim) in text
+    assert "tensor<%sx%dxbf16>" % (tokens, cfg.kda_dim) in text
+    assert head_axis_arrays(text, (cfg.kda_num_heads,), 128) == []
+
+
 @pytest.fixture(scope="module")
 def one_chip():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -670,14 +752,21 @@ def test_the_kernel_compiles_at_the_published_widths(one_chip):
 
     def of(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    compiled = jax.jit(deltanet.channel_gated_delta_rule).lower(
-        of((rows, q, heads, dim), jnp.bfloat16),
-        of((rows, q, heads, dim), jnp.bfloat16),
-        of((rows, q, heads, dim), jnp.bfloat16),
-        of((rows, q, heads, dim), jnp.float32),
-        of((rows, q, heads), jnp.float32), of((rows,), jnp.bool_)).compile()
+    compiled = jax.jit(functools.partial(
+        deltanet.channel_gated_delta_rule, eps=config["rms_norm_eps"],
+        activation="sigmoid")).lower(
+        of((rows, q, 2 * heads * dim), jnp.float32),
+        of((rows, q, heads * dim), jnp.bfloat16),
+        of((rows, q, heads * dim), jnp.float32),
+        of((rows, q, heads), jnp.float32),
+        of((rows, q, heads * dim), jnp.float32), of((dim,), jnp.bfloat16),
+        of((rows,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert deltanet.KDA_KERNEL_NAME in text
     assert deltanet.KDA_KERNEL_NAME != deltanet.KERNEL_NAME
     assert "f32[%d,%d,%d,%d]" % (rows, heads, q, q) not in text
+    # the result leaves the kernel rounded: ``o``'s operand
+    assert "bf16[%d,%d,%d]" % (rows, q, heads * dim) in text
+    # and the call tells the compiler's scheduler what it costs
+    assert "\"cost_estimate\":{\"flops\":\"" in text

@@ -2,17 +2,21 @@
 the CPU with weights from a seed: the packed prefill through dispatches
 with several requests, a pad row and a request that ends inside a row,
 the lower-precision controls, the gated delta rule's kernel
-(``ops/deltanet``, interpreted) against the token-by-token recurrence
-with state and convolution history reset at every request's first row,
+(``ops/deltanet``, interpreted) with its first and last lines against
+the plain composition - ``l2_norm``, the token-by-token recurrence,
+``rms_norm`` times the gate - with state and convolution history reset
+at every request's first row, at toy heads and at the family's 16 / 32,
 its states through bfloat16, the triangular solve alone, the shares of
 the experts adding up to the uncut layer, partial rotary, the stages and their counters, the operation counts,
 the cell through the one benchmark command, the four new readers on a
 run without their scope, the real configuration against the catalog's
-row, what the rule's kernel keeps out of the lowered program, the kernel
-compiled at the published widths for a described v5e, and the shared
+row, what the rule's kernel keeps out of the lowered program (a row's
+``Q x Q`` arrays; at the real widths any float32 array of a head axis),
+the kernel compiled at the published widths for a described v5e, and the shared
 code's StableHLO for the three older families.
 Nothing here needs the native decode library or a chip."""
 
+import functools
 import hashlib
 import json
 import os
@@ -199,19 +203,87 @@ def test_packing_is_invisible_and_state_and_positions_restart(toy):
 # -- the delta rule alone -------------------------------------------------------
 
 
-def rule_inputs(rows, qlen, hk=2, hv=4, dk=8, dv=8, seed=1):
+EPS = 1e-6
+
+
+def rule_inputs(rows, qlen, hk=2, hv=4, dk=8, dv=8, seed=1, act=None):
+    """The kernel's operands as the mixer hands them over: ``qk`` as a
+    convolution wrote it (float32, every key head's q then every key
+    head's k, a token's length anything from a tenth to ten), ``v`` (in
+    ``act``, else float32), ``log alpha``, ``beta``, the output gate
+    before its SiLU and the head norm's weight."""
     import jax
     import jax.numpy as jnp
     rng = np.random.default_rng(seed)
 
     def n(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
-    q, k = n(rows, qlen, hk, dk), n(rows, qlen, hk, dk)
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    return (q, k, n(rows, qlen, hv, dv),
+    qk = n(rows, qlen, 2 * hk, dk) \
+        * jnp.exp(jnp.log(10.0) * jnp.asarray(
+            rng.uniform(-1, 1, (rows, qlen, 2 * hk, 1)), jnp.float32))
+    return (qk.reshape(rows, qlen, 2 * hk * dk),
+            n(rows, qlen, hv * dv).astype(act or jnp.float32),
             -0.3 * jnp.exp(n(rows, qlen, hv)),
-            jax.nn.sigmoid(n(rows, qlen, hv)))
+            jax.nn.sigmoid(n(rows, qlen, hv)), n(rows, qlen, hv * dv),
+            1.0 + 0.1 * n(dv))
+
+
+def rule(inputs, row_first, hk, **how):
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    return np.asarray(deltanet.gated_delta_rule(
+        *inputs, jnp.asarray(row_first), key_heads=hk, eps=EPS,
+        activation="silu", interpret=True, **how).astype(jnp.float32))
+
+
+def composed(inputs, lo, hi, hk):
+    """What the kernel replaces, the plain way, over rows ``lo`` to
+    ``hi`` as a request of its own: ``l2_norm`` a head and q's scale,
+    one rounding to the activations' dtype, the reference's recurrence
+    token by token, the head's ``rms_norm``, times ``silu(z)``, one
+    rounding. -> (L, Hv Dv) float32."""
+    import jax
+    import jax.numpy as jnp
+    qk, v, log_alpha, beta, z, weight = (
+        x[lo:hi].reshape((-1,) + x.shape[2:]) if x.ndim > 1 else x
+        for x in inputs)
+    act, hv = v.dtype, beta.shape[-1]
+    length, dk = qk.shape[0], qk.shape[1] // (2 * hk)
+    qk = qk.reshape(length, 2, hk, dk)
+    q = (reference.l2_norm(qk[:, 0]) * dk ** -0.5).astype(act)
+    k = reference.l2_norm(qk[:, 1]).astype(act)
+    with jax.default_matmul_precision("highest"):
+        out = reference.delta_rule(
+            *(jnp.repeat(x.astype(jnp.float32), hv // hk, axis=1)
+              for x in (q, k)),
+            v.reshape(length, hv, -1).astype(jnp.float32),
+            jnp.exp(log_alpha), beta)
+    out = reference.rms_norm(out, weight, EPS, centred=False) \
+        .reshape(z.shape) * jax.nn.silu(z)
+    return np.asarray(out.astype(act).astype(jnp.float32))
+
+
+def firsts_of(rows, firsts):
+    row_first = np.zeros(rows, bool)
+    row_first[list(firsts)] = True
+    return row_first
+
+
+def first_token_alone(inputs, row, hk, eps, gate):
+    """What a request's first token gives, in float64: it reads only its
+    own write, beta v (k . q), behind the head's norm and ``gate`` of
+    ``z``. ``inputs`` as ``rule_inputs`` gives them, the request's first
+    row ``row``. -> (value heads, Dv)."""
+    qk, v, _, beta, z, weight = (np.asarray(x, np.float64) for x in inputs)
+    dv = weight.size
+    unit = qk[row, 0].reshape(2, hk, -1)
+    unit = unit / np.sqrt((unit * unit).sum(-1, keepdims=True) + 1e-6)
+    dots = (unit[0] * unit[1]).sum(-1) * unit.shape[-1] ** -0.5
+    v, z = (x[row, 0].reshape(-1, dv) for x in (v, z))
+    alone = (beta[row, 0] * np.repeat(dots, len(v) // hk))[:, None] * v
+    return alone / np.sqrt((alone * alone).mean(-1, keepdims=True) + eps) \
+        * weight * gate(z)
 
 
 #: (tokens a row, the rows that open a request, key heads): the first
@@ -229,37 +301,60 @@ RULE_CASES = [
 @pytest.mark.parametrize("qlen,firsts,hk", RULE_CASES)
 def test_the_blocked_rule_matches_the_recurrence(qlen, firsts, hk):
     """``gated_delta_rule`` (the kernel, interpreted) over a pool of six
-    rows (three at 128) against the plain reference's recurrence, token
-    by token, a request at a time: the state starts at zero at every
-    request's first row. Rows of 32, 64 and 128 tokens go through the
-    solve's merged levels; four key heads are two head groups."""
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import deltanet
+    rows (three at 128) against the plain composition around the
+    reference's recurrence, token by token, a request at a time: the
+    state starts at zero at every request's first row. Rows of 32, 64
+    and 128 tokens go through the solve's merged levels; four key heads
+    are two head groups."""
     rows = 3 if qlen == 128 else 6
     inputs = rule_inputs(rows, qlen, hk=hk, hv=2 * hk)
-    row_first = np.zeros(rows, bool)
-    row_first[list(firsts)] = True
-    out = np.asarray(deltanet.gated_delta_rule(
-        *inputs, jnp.asarray(row_first), interpret=True))
+    out = rule(inputs, firsts_of(rows, firsts), hk)
     bounds = list(firsts) + [rows]
     for lo, hi in zip(bounds, bounds[1:]):
-        q, k, v, log_alpha, beta = (
-            x[lo:hi].reshape((-1,) + x.shape[2:]) for x in inputs)
-        want = np.asarray(reference.delta_rule(
-            jnp.repeat(q, 2, axis=1), jnp.repeat(k, 2, axis=1), v,
-            jnp.exp(log_alpha), beta))
+        want = composed(inputs, lo, hi, hk)
         got = out[lo:hi].reshape(want.shape)
         assert np.abs(got - want).max() < 2e-5 * max(
             1.0, np.abs(want).max())
-    # a state that did not restart would show: the second request's
-    # first token reads only its own write
+    # a state that did not restart would show at the second request's
+    # first token
     if len(firsts) > 1:
         lo = firsts[1]
-        q, k, v, _, beta = (np.asarray(x, np.float64) for x in inputs)
-        alone = beta[lo, 0, :, None] * v[lo, 0] * np.repeat(
-            (k[lo, 0] * q[lo, 0]).sum(-1), 2)[:, None]
-        assert np.abs(out[lo, 0] - alone).max() < 1e-5
+        alone = first_token_alone(inputs, lo, hk, EPS,
+                                  lambda z: z / (1.0 + np.exp(-z)))
+        assert np.abs(out[lo, 0].reshape(alone.shape) - alone).max() < 2e-5
+
+
+#: (key heads, value heads, the activations' dtype, the limit as a share
+#: of the largest entry): Qwen3-Next's head geometry - 16 key heads, two
+#: value heads reading each, eight head groups - at heads of 128, in
+#: float32 and in the program's bfloat16, where kernel and composition
+#: round at the same two places and a sum that associates otherwise may
+#: move a value by one step of bfloat16, 2^-8
+GEOMETRY_CASES = [(16, 32, "float32", 2e-5), (16, 32, "bfloat16", 2.0 ** -7)]
+
+
+@pytest.mark.parametrize("hk,hv,act,limit", GEOMETRY_CASES)
+def test_the_kernels_first_and_last_lines_are_the_mixers_norms(
+        hk, hv, act, limit):
+    """The kernel with its prologue and epilogue against ``l2_norm`` ->
+    the sequential rule -> ``rms_norm`` x ``silu(z)``, at the family's
+    head counts and head size, over a pool of six rows that holds a
+    request of one row, a request of three rows whose first lies
+    mid-pool, a pad row (a request of its own) and a request's first
+    row at the pool's end."""
+    import jax.numpy as jnp
+    firsts = (0, 1, 4, 5)
+    inputs = rule_inputs(6, 16, hk=hk, hv=hv, dk=128, dv=128,
+                         act=jnp.dtype(act))
+    # a pad row: the tokens the packer left empty read as zeros
+    inputs = tuple(x.at[4].set(0) if x.ndim == 3 else x for x in inputs)
+    out = rule(inputs, firsts_of(6, firsts), hk)
+    assert np.isfinite(out).all()
+    for lo, hi in zip(firsts, firsts[1:] + (6,)):
+        want = composed(inputs, lo, hi, hk)
+        got = out[lo:hi].reshape(want.shape)
+        assert np.abs(got - want).max() < limit * max(
+            1.0, np.abs(want).max()), (lo, hi)
 
 
 def test_bfloat16_states_differ_by_one_rounding_a_row():
@@ -269,16 +364,11 @@ def test_bfloat16_states_differ_by_one_rounding_a_row():
     was dropped would read zero) by no more than its roundings, each
     2^-9 of the state, allow."""
     import jax.numpy as jnp
-
-    from rnb_tpu.ops import deltanet
     inputs = rule_inputs(6, 16)
 
-    def rule(firsts, **how):
-        row_first = np.zeros(6, bool)
-        row_first[list(firsts)] = True
-        return np.asarray(deltanet.gated_delta_rule(
-            *inputs, jnp.asarray(row_first), interpret=True, **how))
-    exact, rounded = rule((0, 4)), rule((0, 4), state_dtype=jnp.bfloat16)
+    def states(firsts, **how):
+        return rule(inputs, firsts_of(6, firsts), 2, **how)
+    exact, rounded = states((0, 4)), states((0, 4), state_dtype=jnp.bfloat16)
     scale = np.abs(exact).max()
     for row in (0, 4):
         assert np.array_equal(rounded[row], exact[row])
@@ -287,7 +377,8 @@ def test_bfloat16_states_differ_by_one_rounding_a_row():
         assert 0 < off < roundings * 2.0 ** -7 * scale, (row, off)
     # requests of one row each: nothing rounded is ever read
     alone = range(6)
-    assert np.array_equal(rule(alone, state_dtype=jnp.bfloat16), rule(alone))
+    assert np.array_equal(states(alone, state_dtype=jnp.bfloat16),
+                          states(alone))
 
 
 def solve(lower):
@@ -325,19 +416,26 @@ def test_keys_alike_do_not_break_the_solve():
     want = np.linalg.inv(np.eye(128) + lower[0].astype(np.float64))
     assert np.abs(got[0] - want).max() < 1e-5
     # one key for every token, steps of 0.99, no decay: ``lower`` above.
-    # The first row reads no state, so o = tril(q . k) T (beta v)
+    # The first row reads no state, so o = tril(q . k) T (beta v), with
+    # q and k over their lengths, then the head's norm and the gate
     rng = np.random.default_rng(0)
-    key = rng.normal(size=8)
-    k = np.broadcast_to(key / np.linalg.norm(key), (1, 128, 1, 8))
-    q = rng.normal(size=(1, 128, 1, 8))
-    v = rng.normal(size=(1, 128, 1, 8))
+    key = np.broadcast_to(3.0 * rng.normal(size=8), (1, 128, 8))
+    q = rng.normal(size=(1, 128, 8))
+    v = rng.normal(size=(1, 128, 8))
+    z = rng.normal(size=(1, 128, 8))
     out = np.asarray(deltanet.gated_delta_rule(
         *(jnp.asarray(x, jnp.float32) for x in (
-            q, k, v, np.zeros((1, 128, 1)), np.full((1, 128, 1), 0.99))),
-        jnp.asarray([True]), interpret=True))
-    scores = np.tril(q[0, :, 0] @ k[0, :, 0].T)
-    want = scores @ want @ (0.99 * v[0, :, 0])
-    assert np.abs(out[0, :, 0] - want).max() < 1e-4 * np.abs(want).max()
+            np.concatenate([q, key], -1), v, np.zeros((1, 128, 1)),
+            np.full((1, 128, 1), 0.99), z, np.ones(8))),
+        jnp.asarray([True]), key_heads=1, eps=EPS, activation="silu",
+        interpret=True))
+    unit = key[0] / np.linalg.norm(key[0], axis=-1, keepdims=True)
+    scaled = q[0] / np.linalg.norm(q[0], axis=-1, keepdims=True) * 8 ** -0.5
+    scores = np.tril(scaled @ unit.T)
+    want = scores @ want @ (0.99 * v[0])
+    want = want / np.sqrt((want * want).mean(-1, keepdims=True) + EPS) \
+        * z[0] / (1.0 + np.exp(-z[0]))
+    assert np.abs(out[0] - want).max() < 1e-4 * np.abs(want).max()
 
 
 def test_the_mixer_restarts_state_and_convolution_history(toy):
@@ -821,14 +919,61 @@ def test_the_rule_leaves_no_row_by_row_arrays_outside_the_kernel():
     rows, q = 8, cfg.chunk_size
     text = lowered_text(checkpoint, network, cfg, HELD, rows)
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    # the rule's result, which does leave the kernel, is in the text
-    assert "tensor<%dx%dx%dxf32>" % (
+    # the kernel's result, which does leave it, is in the text: the
+    # output product's operand
+    assert "tensor<%dx%dx%dxbf16>" % (
         rows, q, hv * cfg.linear_value_head_dim) in text
     for shape in ((rows, hv, q, q), (rows * hv, q, q),
                   (rows, hk, hv // hk, q, q),
                   (rows, hk, hv // hk, q, cfg.linear_key_head_dim)):
         assert "tensor<%sxf32>" % "x".join(map(str, shape)) not in text, \
             shape
+
+
+def real_stack():
+    """-> (the real configuration's ``cfg``, its held experts a layer)."""
+    from rnb_tpu.models.qwen3_next import network
+    with open(os.path.join(REPO, REAL)) as f:
+        config = json.load(f)
+    family = mm.load_family(config["family"])
+    return network.Qwen3NextConfig.from_published(
+        family.published_keys(config)), config["num_experts"]
+
+
+def head_axis_arrays(text: str, heads, dim: int):
+    """The float32 arrays of ``text`` (StableHLO) whose two minor axes
+    are one of ``heads`` and ``dim``: a token's heads side by side as an
+    axis of their own, which the device tiles (8, 128) with the axis in
+    front - a relayout of what a product or a kernel wrote as (tokens,
+    heads x dim)."""
+    import re
+    return sorted(set(re.findall(
+        r"tensor<[\dx]*x(?:%s)x%dxf32>" % ("|".join(map(str, heads)), dim),
+        text)))
+
+
+def test_no_array_of_a_head_axis_between_convolution_and_output_product():
+    """The real configuration's 128-row program, lowered (nothing is
+    compiled or run; the kernels interpreted, their bodies a grid
+    step's): from ``segment_conv1d``'s call to ``o``'s product the
+    DeltaNet mixer reshapes nothing to (tokens, heads, 128) - the heads'
+    L2 norms, the head norm and the gate are the rule's kernel's, on
+    slices in VMEM - so the program holds no float32 array of 16 or of
+    32 heads of 128 at all (the attention layer's heads are 256 wide).
+    PR 49's tree held two of ``128x128x16x128`` (q and k through
+    ``l2_norm``) and one of ``128x128x32x128`` (the rule's result
+    through ``rms_norm``) a layer."""
+    from rnb_tpu.models.qwen3_next import checkpoint, network
+    cfg, held = real_stack()
+    assert cfg.linear_key_head_dim == cfg.linear_value_head_dim == 128
+    text = lowered_text(checkpoint, network, cfg, range(held), rows=128)
+    tokens = "128x%d" % cfg.chunk_size
+    # what the kernel reads and writes is there, as its neighbours wrote it
+    assert "tensor<%sx%dxf32>" % (tokens, 2 * cfg.key_dim) in text
+    assert "tensor<%sx%dxbf16>" % (tokens, cfg.value_dim) in text
+    assert head_axis_arrays(
+        text, (cfg.linear_num_key_heads, cfg.linear_num_value_heads),
+        128) == []
 
 
 @pytest.fixture(scope="module")
@@ -866,15 +1011,25 @@ def test_the_kernel_compiles_at_the_published_widths(one_chip):
 
     def of(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    compiled = jax.jit(deltanet.gated_delta_rule).lower(
-        of((rows, q, hk, dk), jnp.bfloat16),
-        of((rows, q, hk, dk), jnp.bfloat16),
-        of((rows, q, hv, dv), jnp.bfloat16), of((rows, q, hv), jnp.float32),
-        of((rows, q, hv), jnp.float32), of((rows,), jnp.bool_)).compile()
+    compiled = jax.jit(functools.partial(
+        deltanet.gated_delta_rule, key_heads=hk, eps=config["rms_norm_eps"],
+        activation="silu")).lower(
+        of((rows, q, 2 * hk * dk), jnp.float32),
+        of((rows, q, hv * dv), jnp.bfloat16), of((rows, q, hv), jnp.float32),
+        of((rows, q, hv), jnp.float32), of((rows, q, hv * dv), jnp.float32),
+        of((dv,), jnp.bfloat16), of((rows,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert deltanet.KERNEL_NAME in text
     assert "f32[%d,%d,%d,%d]" % (rows, hv, q, q) not in text
+    # q and k reach the kernel as one array, the result leaves it rounded
+    assert "bf16[%d,%d,%d]" % (rows, q, hv * dv) in text
+    assert "bf16[%d,%d,%d]" % (rows, q, hk * dk) not in text
+    # the call tells the compiler what it costs, so that the scheduler
+    # overlaps its own copies with it (without, the stage program's
+    # temporaries stood 62 MiB over PR 49's: the stream's prefetch in
+    # front of ``o``'s product had nothing to hide behind)
+    assert "\"cost_estimate\":{\"flops\":\"" in text
 
 
 # -- the shared code, for the three older families -------------------------------------
