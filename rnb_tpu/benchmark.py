@@ -30,6 +30,7 @@ from dataclasses import dataclass, field
 from datetime import datetime
 from typing import Any, Dict, Optional
 
+from rnb_tpu import trace as trace_mod
 from rnb_tpu.arg_utils import nonnegative_int, positive_int
 
 BARRIER_TIMEOUT_S = 1800.0  # generous: first TPU compile can be slow
@@ -37,6 +38,13 @@ BARRIER_TIMEOUT_S = 1800.0  # generous: first TPU compile can be slow
 
 #: the checkout root: <root>/rnb_tpu/benchmark.py
 REPO_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+#: when an entry point first called enable_compilation_cache(): JAX and
+#: the accelerator runtime are up by then (benchmarks/run.py calls it
+#: right behind jax.devices()). The next run_benchmark takes the stamp
+#: into its record of set-up as the instant ``setup.entered``.
+_ENTERED: Optional[float] = None
 
 
 def enable_compilation_cache() -> str:
@@ -57,8 +65,14 @@ def enable_compilation_cache() -> str:
     ``op_name``s: the final stages write their scope table from the
     executable's text (rnb_tpu.hloscopes), so a cache shared with a
     checkout that names its scopes otherwise would hand them its
-    names."""
+    names.
+
+    Its first call also stamps the end of "imports and the runtime's
+    start" for the record of set-up (``_ENTERED``)."""
     import jax
+    global _ENTERED
+    if _ENTERED is None:
+        _ENTERED = time.time()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = os.path.join(REPO_DIR, ".jax_cache")
@@ -66,6 +80,128 @@ def enable_compilation_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir
+
+
+#: JAX's own time spans of a program's way to an executable, by the
+#: names they take in the record of set-up (jax/_src/dispatch.py)
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_JAX_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _listen_to_jax(tracer):
+    """Record ``jax.monitoring``'s time spans of tracing, lowering and
+    compiling into ``tracer`` as ``setup.jax.*`` spans, on the thread
+    that compiles (JAX stamps them with ``time.time()`` there, so they
+    nest under that thread's ``setup.s{step}.*`` spans); -> the
+    function that unregisters every listener again. A compile span
+    carries ``cache_hit`` and ``retrieval_s`` from the cache's event
+    and duration, which JAX records inside it on the same thread. A
+    ``jit`` traced inside another's trace or lowering (thousands a
+    program; the metrics add the two up) is in the outer one's span
+    and gets none of its own: JAX records a span's start as a scalar,
+    which is what tells the depth."""
+    from jax import monitoring
+    names = {
+        _JAX_TRACE: trace_mod.name("setup.jax.trace"),
+        _JAX_LOWER: trace_mod.name("setup.jax.lower"),
+        _JAX_COMPILE: trace_mod.name("setup.jax.compile"),
+    }
+    mine = threading.local()  # the compiling thread's open spans
+
+    def on_event(event, **_kwargs):
+        if event == _JAX_CACHE_HIT:
+            mine.hit = 1
+
+    def on_duration(event, duration, **_kwargs):
+        if event == _JAX_CACHE_READ:
+            mine.retrieval_s = duration
+
+    def on_scalar(event, _value, **_kwargs):
+        if event in (_JAX_TRACE, _JAX_LOWER):
+            mine.lowering = getattr(mine, "lowering", 0) + 1
+
+    def on_span(event, start, end, **kwargs):
+        event_name = names.get(event)
+        if event_name is None:
+            return
+        counts = {"fun_name": str(kwargs.get("fun_name", ""))}
+        if event == _JAX_COMPILE:
+            counts["cache_hit"] = mine.__dict__.pop("hit", 0)
+            if "retrieval_s" in mine.__dict__:
+                counts["retrieval_s"] = mine.__dict__.pop("retrieval_s")
+        else:
+            mine.lowering = getattr(mine, "lowering", 1) - 1
+            if mine.lowering > 0:
+                return
+        tracer.add_event(event_name, "X", start, end - start, None, counts)
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_scalar_listener(on_scalar)
+    monitoring.register_event_time_span_listener(on_span)
+
+    def stop():
+        monitoring.unregister_event_listener(on_event)
+        monitoring.unregister_event_duration_listener(on_duration)
+        monitoring.unregister_scalar_listener(on_scalar)
+        monitoring.unregister_event_time_span_listener(on_span)
+    return stop
+
+
+class _Setup:
+    """One job's record of set-up: a Tracer that collects from
+    run_benchmark's first line to the start barrier whatever the
+    ``trace`` key says, and JAX's compile listeners for as long. At
+    the barrier's release (its action: every party has arrived, none
+    runs yet) ``trace.ACTIVE`` becomes what the configuration asked
+    for and the listeners go, so the served window is traced, or not,
+    exactly as without this."""
+
+    def __init__(self):
+        self.run_start = time.time()
+        #: where no entry point stamped it, set-up enters here
+        self.entered = self.run_start if _ENTERED is None else _ENTERED
+        self.released: Optional[float] = None
+        self.tracer = None
+        self._configured = None
+        self._stop_listening = None
+
+    def open(self, configured) -> None:
+        """Start collecting: into the ``trace`` key's Tracer where
+        there is one, else into one of set-up's own (no sampler)."""
+        global _ENTERED
+        _ENTERED = None  # a later job of this process stamps its own
+        self._configured = configured
+        self.tracer = configured or trace_mod.Tracer(
+            trace_mod.TraceSettings(sample_hz=0.0))
+        trace_mod.ACTIVE = self.tracer
+        self._stop_listening = _listen_to_jax(self.tracer)
+        self.tracer.add_event(trace_mod.name("setup.entered"), "i",
+                              self.entered, 0.0, None, None)
+
+    def launched(self) -> None:
+        """Every thread of the job is started."""
+        self.tracer.add_event(trace_mod.name("setup.launch"), "X",
+                              self.run_start,
+                              time.time() - self.run_start, None, None)
+
+    def release(self) -> None:
+        """The start barrier's action; also run_benchmark's way out
+        where the barrier was never reached."""
+        if self._stop_listening is None:
+            return
+        self.released = time.time()
+        trace_mod.ACTIVE = self._configured
+        self._stop_listening()
+        self._stop_listening = None
+
+    def events(self):
+        """The Tracer's events up to the release."""
+        return [e for e in self.tracer.snapshot_events()
+                if e[2] <= self.released]
 
 
 @dataclass
@@ -247,6 +383,13 @@ class BenchmarkResult:
     #: per-step stage-construction wall seconds (weights + warmup
     #: compiles), summed over the step's instances
     warmup_s: Dict[str, float] = field(default_factory=dict)
+    #: set-up's record (rnb_tpu.benchmark._Setup): ``entered``,
+    #: ``run_start``, ``released`` in epoch seconds and the ``setup.*``
+    #: events ``(name, t0, dur, thread, counts)`` of every thread from
+    #: run_benchmark's first line to the start barrier's release —
+    #: telemetry.TRACE_EVENT_REGISTRY says what each spans; the same
+    #: events are logs/<job>/setup-trace.json
+    setup: Dict[str, Any] = field(default_factory=dict)
     #: device-resident handoff accounting (rnb_tpu.handoff), summed
     #: over every consumer executor; all zero without the root
     #: `handoff` config key. Every ring-payload take is one edge
@@ -327,6 +470,20 @@ def run_benchmark(config_path: str,
                   job_id: Optional[str] = None) -> BenchmarkResult:
     """Programmatic entry used by the CLI, the tests and the benchmark
     (``benchmarks/run.py``)."""
+    setup = _Setup()
+    try:
+        return _run_benchmark(setup, config_path, mean_interval_ms,
+                              batch_size, num_videos, queue_size,
+                              log_base, print_progress, seed, job_id)
+    finally:
+        setup.release()
+
+
+def _run_benchmark(setup: _Setup, config_path: str, mean_interval_ms: int,
+                   batch_size: int, num_videos: int, queue_size: int,
+                   log_base: str, print_progress: bool,
+                   seed: Optional[int],
+                   job_id: Optional[str]) -> BenchmarkResult:
     enable_compilation_cache()
     # multi-host: honor RNB_TPU_COORDINATOR/_NUM_PROCESSES/_PROCESS_ID
     # before the first backend touch — jax.distributed must initialize
@@ -336,7 +493,6 @@ def run_benchmark(config_path: str,
     from rnb_tpu.parallel.distributed import maybe_initialize
     keep_host_backend()
     maybe_initialize()
-    from rnb_tpu import trace as trace_mod
     from rnb_tpu.client import bulk_client, poisson_client
     from rnb_tpu.config import load_config
     from rnb_tpu.control import (ChannelFabric, FaultStats,
@@ -352,6 +508,14 @@ def run_benchmark(config_path: str,
     trace_mod.ACTIVE = None
 
     config = load_config(config_path)
+    # unified pipeline tracing (rnb_tpu.trace, root 'trace' config
+    # key): one per-job collector every thread role records spans
+    # into. Until the start barrier a Tracer collects in any case —
+    # this one, or one of set-up's own (_Setup)
+    trace_settings = trace_mod.TraceSettings.from_config(config.trace)
+    tracer = trace_mod.Tracer(trace_settings) \
+        if trace_settings is not None else None
+    setup.open(tracer)
     config.check_devices()
     # best-effort contention probe (reference benchmark.py:97-125
     # aborted here; we warn — see rnb_tpu.devices.probe_busy_devices)
@@ -378,7 +542,8 @@ def run_benchmark(config_path: str,
 
     num_runners = config.num_runners
     bar_total = num_runners + 2  # runners + client + this controller
-    sta_bar = threading.Barrier(bar_total, timeout=BARRIER_TIMEOUT_S)
+    sta_bar = threading.Barrier(bar_total, action=setup.release,
+                                timeout=BARRIER_TIMEOUT_S)
     fin_bar = threading.Barrier(bar_total, timeout=BARRIER_TIMEOUT_S)
     counter = InferenceCounter()
     termination = TerminationState()
@@ -546,15 +711,11 @@ def run_benchmark(config_path: str,
         effective_queue_size = (num_videos * seg_factor + num_runners
                                 + max(NUM_EXIT_MARKERS, num_runners) + 1)
     fabric = ChannelFabric(config, effective_queue_size)
-    # unified pipeline tracing (rnb_tpu.trace, root 'trace' config
-    # key): one per-job collector every thread role records spans
-    # into, plus a low-rate background sampler over the inter-stage
-    # queue depths (stage-owned sources — staging occupancy, in-flight
-    # decode counts — register in the runner via enable_trace)
-    tracer = None
-    trace_settings = trace_mod.TraceSettings.from_config(config.trace)
-    if trace_settings is not None:
-        tracer = trace_mod.Tracer(trace_settings)
+    # the `trace` key's Tracer also runs a low-rate background sampler
+    # over the inter-stage queue depths (stage-owned sources — staging
+    # occupancy, in-flight decode counts — register in the runner via
+    # enable_trace)
+    if tracer is not None:
         tracer.add_counter_source(
             trace_mod.name("queue.filename.depth"),
             fabric.get_filename_queue().qsize)
@@ -568,7 +729,6 @@ def run_benchmark(config_path: str,
                     trace_mod.name("queue.e%d.depth", edge_idx),
                     step_queues[q_idx].qsize)
                 edge_idx += 1
-        trace_mod.ACTIVE = tracer
 
     threads = []
     client_kwargs = dict(overload_policy=config.overload_policy,
@@ -692,16 +852,20 @@ def run_benchmark(config_path: str,
 
     for t in threads:
         t.start()
+    setup.launched()
 
     import resource
 
     from rnb_tpu.decode.native import DecodePool
     if tracer is not None:
         # occupancy sampling covers the measured window (plus the
-        # short drain); started here so warm-up/compile never lands
-        # in the timeline
+        # short drain): the sampler starts here, so its counter tracks
+        # hold nothing of warm-up; set-up's spans are in the timeline
         tracer.start_sampler()
     sta_bar.wait()
+    setup.tracer.add_event(trace_mod.name("setup.run"), "X",
+                           setup.run_start,
+                           setup.released - setup.run_start, None, None)
     ru_start = resource.getrusage(resource.RUSAGE_SELF)
     decode_start = DecodePool.shared_stats()
     time_start = time.time()
@@ -743,6 +907,16 @@ def run_benchmark(config_path: str,
             print("Trace: %d event(s) -> %s (%d dropped at the "
                   "max_events cap)"
                   % (trace_events, trace_path, trace_dropped))
+
+    # set-up's record: what the Tracer held when the barrier released
+    # (with the `trace` key, the same events are in trace.json too)
+    setup_events = setup.events()
+    trace_mod.export_events(
+        setup_events, 0,
+        os.path.join(logroot(job_id, base=log_base), "setup-trace.json"),
+        job_id)
+    setup_account = trace_mod.setup_account(
+        setup_events, setup.run_start, setup.released)
 
     # per-request phase attribution (rnb_tpu.trace): aggregated over
     # every final-step instance's steady-state records — surfaced only
@@ -1085,6 +1259,9 @@ def run_benchmark(config_path: str,
         if warmup_stats:
             f.write("Warmup: %s\n"
                     % json.dumps(warmup_stats, sort_keys=True))
+        if setup_account:
+            f.write("Setup: %s\n"
+                    % json.dumps(setup_account, sort_keys=True))
         if tracer is not None:
             # trace-export accounting: events written to trace.json
             # and events dropped at the max_events cap — parse_utils
@@ -1324,6 +1501,11 @@ def run_benchmark(config_path: str,
         pages=dict(pages_summary) if pages_summary else {},
         compile_signatures=compile_stats,
         warmup_s=warmup_stats,
+        setup={"entered": setup.entered, "run_start": setup.run_start,
+               "released": setup.released,
+               "events": [(e[0], e[2], e[3], e[4], e[6] or {})
+                          for e in setup_events
+                          if e[0].startswith("setup.")]},
         handoff_edges=handoff_stats["edges"] if handoff_stats else 0,
         handoff_d2d_edges=(handoff_stats["d2d_edges"]
                            if handoff_stats else 0),

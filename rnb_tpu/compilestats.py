@@ -21,11 +21,13 @@ the executor freezes it when the measured window opens
 mid-run recompile and is surfaced as ``steady_new`` in the
 ``Compiles:`` accounting (parse_utils --check fails on nonzero).
 
-Warmup wall-time rides the same sink: the executor times each stage's
-construction (weights + warmup compiles happen in ``__init__``) and
-the launcher writes the per-step ``Warmup:`` log-meta line — under
-ragged, collapsing the per-bucket warmup matrix to one compile is a
-measurable launch-latency win, and this is where it is measured.
+Warm-up wall time rides the signatures' sink, but is not measured
+here: it is the duration of the executor's ``setup.s{step}.construct``
+span around each stage's construction (weights + warmup compiles
+happen in ``__init__``; rnb_tpu.runner, rnb_tpu.trace), summed a step
+into the ``Warmup:`` log-meta line — under ragged, collapsing the
+per-bucket warmup matrix to one compile is a measurable launch-latency
+win, and the set-up spans under that one say where it went.
 """
 
 from __future__ import annotations

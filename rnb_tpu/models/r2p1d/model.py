@@ -235,10 +235,12 @@ def _shared_params(start: int, end: int, num_classes: int,
     with _cache_lock:
         params = _params_cache.get(key)
         if params is None:
-            variables = ckpt.load_or_init(
-                start, end, num_classes, layer_sizes, ckpt_path,
-                factored_shortcut=factored_shortcut)
-            params = jax.device_put(variables, device)
+            with trace.span(trace.name("setup.s%d.weights",
+                                       trace.building_step())):
+                variables = ckpt.load_or_init(
+                    start, end, num_classes, layer_sizes, ckpt_path,
+                    factored_shortcut=factored_shortcut)
+                params = jax.device_put(variables, device)
             _params_cache[key] = params
         return params
 
@@ -615,6 +617,11 @@ class R2P1DLoader(StageModel):
         #: stage requires; frozen by the executor at window start so
         #: any later new signature surfaces as a mid-run recompile
         self.compiles = None
+        # set-up's spans of the warm-up below, a warmed shape each (the
+        # launcher's Tracer collects them until the start barrier)
+        tr_program = trace.name("setup.s%d.program", trace.building_step())
+        tr_first_call = trace.name("setup.s%d.first_call",
+                                   trace.building_step())
         if self.raw_output or self.pixel_path in ("yuv420", "dct"):
             # raw mode: consumer normalizes on its mesh. yuv420/dct:
             # the network stage's jit owns the whole ingest; the
@@ -626,9 +633,11 @@ class R2P1DLoader(StageModel):
             for rows in self._warm_shapes():
                 dummy = np.zeros(self._batch_shape(rows),
                                  dtype=self._wire_dtype)
-                for _ in range(num_warmups):
-                    jax.block_until_ready(
-                        jax.device_put(dummy, self._jax_device))
+                with trace.span(tr_program, rows=rows), \
+                        trace.span(tr_first_call):
+                    for _ in range(num_warmups):
+                        jax.block_until_ready(
+                            jax.device_put(dummy, self._jax_device))
         elif self.ragged:
             # ragged ingest: ONE compiled executable serves every
             # batch composition — the rows_valid scalar is traced,
@@ -643,10 +652,12 @@ class R2P1DLoader(StageModel):
             # vocabulary declared even under num_warmups=0 (see the
             # runner's warmup loop)
             self.compiles.observe(dummy)
-            for _ in range(num_warmups):
-                jax.block_until_ready(self._preprocess_ragged(
-                    jax.device_put(dummy, self._jax_device),
-                    np.int32(self.pool_rows)))
+            with trace.span(tr_program, rows=self.pool_rows), \
+                    trace.span(tr_first_call):
+                for _ in range(num_warmups):
+                    jax.block_until_ready(self._preprocess_ragged(
+                        jax.device_put(dummy, self._jax_device),
+                        np.int32(self.pool_rows)))
         else:
             self._preprocess = _shared_preprocess(self._jax_device)
             self.compiles = SignatureTracker()
@@ -656,9 +667,11 @@ class R2P1DLoader(StageModel):
                 dummy = np.zeros(self._batch_shape(rows),
                                  dtype=np.uint8)
                 self.compiles.observe(dummy)
-                for _ in range(num_warmups):
-                    jax.block_until_ready(self._preprocess(
-                        jax.device_put(dummy, self._jax_device)))
+                with trace.span(tr_program, rows=rows), \
+                        trace.span(tr_first_call):
+                    for _ in range(num_warmups):
+                        jax.block_until_ready(self._preprocess(
+                            jax.device_put(dummy, self._jax_device)))
         # decode warm-up on real sample files (the reference warmed its
         # NVVL loader on 3 sample mp4s, models/r2p1d/model.py:133-138):
         # faults in file IO, header parse and the native pool so the
@@ -2706,6 +2719,12 @@ class R2P1DRunner(StageModel):
         #: the stage writes when it has drained (rnb_tpu.hloscopes)
         self._warmed_programs = []
         self._log_dir = None
+        # set-up's spans of a bucket's warm-up (the launcher's Tracer
+        # collects them until the start barrier)
+        step = trace.building_step()
+        tr_program = trace.name("setup.s%d.program", step)
+        tr_scopes = trace.name("setup.s%d.scopes", step)
+        tr_first_call = trace.name("setup.s%d.first_call", step)
         for rows in warm_rows:
             host = np.zeros((rows,) + self._steady_shape[1:],
                             warm_dtype)
@@ -2716,26 +2735,36 @@ class R2P1DRunner(StageModel):
             # compile of an unwarmed run
             self.compiles.observe(host)
             if num_warmups > 0:
-                if self._input_sharding is not None:
-                    dummy = jax.device_put(host, self._input_sharding)
-                else:
-                    dummy = jax.device_put(host, self._jax_device)
-                args = (self._variables, dummy) + (
-                    (np.int32(rows),) if self.ragged else ())
-                for _ in range(num_warmups):
-                    out = self._apply(*args)
-                    jax.block_until_ready(out)
-                    if self._merge is not None:
-                        # warm the merge collective too: its compile
-                        # must not land inside the measured window
-                        jax.block_until_ready(self._merge(out))
-                if self.end_index == NUM_LAYERS:
-                    # not a second compile: the same arguments find the
-                    # executable the call above made in jit's cache
-                    # (tests/test_r2p1d_scopes.py counts the backend's
-                    # compilations)
-                    self._warmed_programs.append(
-                        self._apply.lower(*args).compile())
+                with trace.span(tr_program, rows=rows):
+                    self._warm_bucket(rows, host, num_warmups,
+                                      tr_first_call, tr_scopes)
+
+    def _warm_bucket(self, rows: int, host, num_warmups: int,
+                     tr_first_call: str, tr_scopes: str) -> None:
+        """One row count's executable, from nothing to warmed."""
+        import jax
+        if self._input_sharding is not None:
+            dummy = jax.device_put(host, self._input_sharding)
+        else:
+            dummy = jax.device_put(host, self._jax_device)
+        args = (self._variables, dummy) + (
+            (np.int32(rows),) if self.ragged else ())
+        with trace.span(tr_first_call):
+            for _ in range(num_warmups):
+                out = self._apply(*args)
+                jax.block_until_ready(out)
+                if self._merge is not None:
+                    # warm the merge collective too: its compile
+                    # must not land inside the measured window
+                    jax.block_until_ready(self._merge(out))
+        if self.end_index == NUM_LAYERS:
+            # not a second compile: the same arguments find the
+            # executable the call above made in jit's cache
+            # (tests/test_r2p1d_scopes.py counts the backend's
+            # compilations)
+            with trace.span(tr_scopes):
+                self._warmed_programs.append(
+                    self._apply.lower(*args).compile())
 
     def input_shape(self):
         return (self._steady_shape,)

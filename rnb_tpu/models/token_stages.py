@@ -188,12 +188,19 @@ class PackedPrefill(StageModel):
         self.row_buckets = normalize_row_buckets(row_buckets,
                                                  self.max_rows, "max_rows")
         self._jax_device = device.resolve()
-        self._params = checkpoint.make_params(self.cfg, seed, held,
-                                              self._jax_device)
+        # set-up's spans (the launcher's Tracer collects them until the
+        # start barrier): the step is the one the executor bound
+        step = trace.building_step()
+        tr_program = trace.name("setup.s%d.program", step)
+        tr_scopes = trace.name("setup.s%d.scopes", step)
+        tr_first_call = trace.name("setup.s%d.first_call", step)
         self._slots = None
-        if "expert_served" in network.COUNTERS:
-            self._slots = jax.device_put(
-                network.held_slots(self.cfg, held), self._jax_device)
+        with trace.span(trace.name("setup.s%d.weights", step)):
+            self._params = checkpoint.make_params(self.cfg, seed, held,
+                                                  self._jax_device)
+            if "expert_served" in network.COUNTERS:
+                self._slots = jax.device_put(
+                    network.held_slots(self.cfg, held), self._jax_device)
         self._request_choices = getattr(network, "request_choices", None)
         cfg = self.cfg
         interpret = self._jax_device.platform != "tpu"
@@ -215,14 +222,17 @@ class PackedPrefill(StageModel):
             meta = dispatch_meta((0, rows), np.full(rows, self.chunk),
                                  rows, self.chunk)
             self.compiles.observe(tokens)
-            program = jax.jit(apply).lower(
-                self._params, self._slots, tokens, meta).compile()
-            self.hlo_scopes.update(
-                hloscopes.scopes_of_hlo(program.as_text()))
-            self._programs[rows] = program
-            for _ in range(int(num_warmups)):
-                jax.block_until_ready(program(
-                    self._params, self._slots, tokens, meta))
+            with trace.span(tr_program, rows=rows):
+                program = jax.jit(apply).lower(
+                    self._params, self._slots, tokens, meta).compile()
+                with trace.span(tr_scopes):
+                    self.hlo_scopes.update(
+                        hloscopes.scopes_of_hlo(program.as_text()))
+                self._programs[rows] = program
+                with trace.span(tr_first_call):
+                    for _ in range(int(num_warmups)):
+                        jax.block_until_ready(program(
+                            self._params, self._slots, tokens, meta))
         #: counters of the dispatches served: valid and shipped tokens
         #: (the Tokens: line) and the family's own, by the names of
         #: ``network.COUNTERS``, summed as they come back

@@ -694,10 +694,16 @@ def runner(ctx: RunnerContext) -> None:
         # warmup wall time: weights + warmup compiles all happen in the
         # stage constructor, before the start barrier — the launch cost
         # the `Warmup:` accounting surfaces (ragged collapses the
-        # per-bucket compile matrix here)
-        t_construct = time.monotonic()
-        model = model_class(ctx.device, **ctx.model_kwargs)
-        warmup_s = time.monotonic() - t_construct
+        # per-bucket compile matrix here). It is the construct span's
+        # duration: the launcher's Tracer of set-up collects it, and
+        # the stage's own setup.s{step}.* spans nest under it
+        trace.building_step(ctx.step_idx)
+        t_construct = time.time()
+        with trace.span(trace.name("setup.s%d.construct", ctx.step_idx),
+                        instance=ctx.instance_idx,
+                        device=ctx.device.label) as built:
+            model = model_class(ctx.device, **ctx.model_kwargs)
+        warmup_s = getattr(built, "dur", time.time() - t_construct)
         declared_shapes = model_class.output_shape_for(**ctx.model_kwargs)
 
         selector = None

@@ -245,7 +245,13 @@ META_LINE_REGISTRY = (
               "steady_new > 0 means a mid-run recompile"),
     StampSpec("Warmup:", "rnb_tpu/benchmark.py",
               "JSON per-step stage-construction wall seconds "
-              "(weights + warmup compiles)"),
+              "(weights + warmup compiles): the setup.s{step}."
+              "construct spans' durations"),
+    StampSpec("Setup:", "rnb_tpu/benchmark.py",
+              "JSON seconds by phase from run_benchmark's first line "
+              "to the start barrier's release, along the stage "
+              "instance whose constructor ended last "
+              "(rnb_tpu.trace.setup_account)"),
     StampSpec("Handoff:", "rnb_tpu/benchmark.py",
               "device-resident handoff counters: edge takes split "
               "d2d vs host with bytes each class moved "
@@ -440,6 +446,55 @@ TRACE_EVENT_REGISTRY = (
     StampSpec("queue.e{step}.depth", "rnb_tpu/benchmark.py",
               "counter (sampled): inter-stage queue depth, keyed by "
               "queue index"),
+    # -- set-up, from the process's start to the start barrier: kept by
+    # a Tracer the launcher holds until the barrier whatever the
+    # `trace` key says (BenchmarkResult.setup, setup-trace.json, the
+    # Setup: line), on time.time(); each names the benchmark metric
+    # that reads it (benchmarks/setup_account.py)
+    StampSpec("setup.entered", "rnb_tpu/benchmark.py",
+              "instant: enable_compilation_cache()'s first call, once "
+              "JAX and the accelerator runtime are up; setup_runtime_s "
+              "ends and setup_inputs_s starts here"),
+    StampSpec("setup.run", "rnb_tpu/benchmark.py",
+              "span: run_benchmark's first line to the start barrier's "
+              "release; setup_inputs_s ends where it opens, "
+              "setup_unnamed_s is what of it no span below names"),
+    StampSpec("setup.launch", "rnb_tpu/benchmark.py",
+              "span: run_benchmark's first line to the last runner "
+              "thread's start (configuration, queues, rings); "
+              "setup_unnamed_s"),
+    StampSpec("setup.s{step}.construct", "rnb_tpu/runner.py",
+              "span: one stage instance's constructor whole (instance, "
+              "device), on its runner thread: the Warmup: line's "
+              "seconds; the one that ends last is the instance every "
+              "setup_*_s metric follows, its self time setup_unnamed_s"),
+    StampSpec("setup.s{step}.weights", "rnb_tpu/models/token_stages.py",
+              "span: recipe or checkpoint to parameters handed to the "
+              "device; the draw's device work runs on behind it and "
+              "the first call's span carries that; self time "
+              "setup_weights_s"),
+    StampSpec("setup.s{step}.program", "rnb_tpu/models/token_stages.py",
+              "span: one row bucket's program from nothing to warmed "
+              "(rows); self time setup_unnamed_s"),
+    StampSpec("setup.s{step}.scopes", "rnb_tpu/models/token_stages.py",
+              "span: the executable's text and its scope table, inside "
+              "the bucket's program span; self time setup_unnamed_s"),
+    StampSpec("setup.s{step}.first_call", "rnb_tpu/models/token_stages.py",
+              "span: the bucket's warm-up calls to block_until_ready; "
+              "self time setup_first_call_s"),
+    StampSpec("setup.jax.trace", "rnb_tpu/benchmark.py",
+              "span: JAX's /jax/core/compile/jaxpr_trace_duration "
+              "(fun_name), on the tracing thread; setup_lower_s"),
+    StampSpec("setup.jax.lower", "rnb_tpu/benchmark.py",
+              "span: JAX's /jax/core/compile/"
+              "jaxpr_to_mlir_module_duration (fun_name); setup_lower_s"),
+    StampSpec("setup.jax.compile", "rnb_tpu/benchmark.py",
+              "span: JAX's /jax/core/compile/backend_compile_duration "
+              "(fun_name; cache_hit 1 where the persistent cache "
+              "answered, retrieval_s its read): the cache key, then "
+              "the cache's read and the executable's load or the "
+              "compiler; setup_compile_s, and setup_compiled_programs "
+              "counts those with cache_hit 0"),
 )
 
 

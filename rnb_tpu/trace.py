@@ -102,6 +102,20 @@ def _stats(rid: Optional[int], counts: dict) -> dict:
     return dict(counts, rid=rid) if rid is not None else counts
 
 
+#: the pipeline step whose stage this thread builds, for the stage's
+#: own ``setup.s{step}.*`` spans (a constructor is not told its step)
+_BUILDING = threading.local()
+
+
+def building_step(step: Optional[int] = None) -> int:
+    """The step of the stage under construction on this thread: the
+    executor binds it in front of the constructor, the constructor
+    reads it into its span names (0 where nobody bound one)."""
+    if step is not None:
+        _BUILDING.step = int(step)
+    return getattr(_BUILDING, "step", 0)
+
+
 def name(pattern: str, *args) -> str:
     """Format a registered event-name pattern once, ahead of a hot
     loop (``trace.name("exec%d.model_call", step)``). Call sites keep
@@ -184,7 +198,8 @@ class _Span:
     """One live span while a Tracer collects: the profiler annotation
     plus an event in the collector's buffer."""
 
-    __slots__ = ("tracer", "name", "rid", "counts", "annotation", "t0")
+    __slots__ = ("tracer", "name", "rid", "counts", "annotation", "t0",
+                 "dur")
 
     def __init__(self, tracer, event_name: str, rid: Optional[int],
                  counts: Optional[dict] = None):
@@ -201,9 +216,9 @@ class _Span:
         return self
 
     def __exit__(self, *exc):
-        t1 = time.time()
+        self.dur = time.time() - self.t0
         self.annotation.__exit__(*exc)
-        self.tracer.add_event(self.name, "X", self.t0, t1 - self.t0,
+        self.tracer.add_event(self.name, "X", self.t0, self.dur,
                               self.rid, self.counts or None)
         return False
 
@@ -531,6 +546,68 @@ def track_names(path: str) -> List[str]:
                   for ev in doc.get("traceEvents", [])
                   if ev.get("ph") == "M"
                   and ev.get("name") == "thread_name")
+
+
+# -- set-up's account ---------------------------------------------------
+#
+# Set-up is recorded with the spans above (``setup.*`` in the registry)
+# and JAX's own time spans of tracing, lowering and compiling, on one
+# clock (``time.time()``). Stages are built in parallel threads; what
+# a fresh replica waits for is the one whose constructor ended last.
+
+#: a ``setup.*`` span's phase on the ``Setup:`` line, by the end of its
+#: name; a span that ends otherwise (``construct``, ``program``) is
+#: ``other``
+SETUP_PHASES = {"weights": "weights", "jax.trace": "lower",
+                "jax.lower": "lower", "jax.compile": "compile",
+                "first_call": "first_call", "scopes": "scopes"}
+
+
+def self_times(spans: List[Tuple[float, float]]) -> List[float]:
+    """Each ``(t0, dur)`` span's duration less its children's, for
+    spans of one thread (which nest or follow each other), in the
+    order given."""
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][0], -spans[i][1]))
+    own = [dur for _t0, dur in spans]
+    open_: List[int] = []
+    for i in order:
+        t0, dur = spans[i]
+        while open_ and sum(spans[open_[-1]]) <= t0:
+            open_.pop()
+        if open_:
+            own[open_[-1]] -= dur
+        open_.append(i)
+    return own
+
+
+def setup_account(events: List[Tuple], run_start: float,
+                  released: float) -> Dict[str, object]:
+    """Seconds by phase from ``run_start`` to ``released`` along the
+    stage instance whose ``setup.s{step}.construct`` ended last:
+    ``launch`` in front of its constructor, the constructor's time by
+    :data:`SETUP_PHASES` (self times: a compile inside a first call is
+    ``compile``), ``barrier`` behind it. They add up to ``total``.
+    Empty where no constructor was recorded."""
+    built = [e for e in events if e[1] == "X"
+             and e[0].startswith("setup.s") and e[0].endswith(".construct")]
+    if not built:
+        return {}
+    last = max(built, key=lambda e: e[2] + e[3])
+    t0, t1, thread = last[2], last[2] + last[3], last[4]
+    inside = [e for e in events if e[1] == "X" and e[4] == thread
+              and e[0].startswith("setup.")
+              and t0 <= e[2] and e[2] + e[3] <= t1]
+    account = dict.fromkeys(SETUP_PHASES.values(), 0.0)
+    account.update(launch=t0 - run_start, other=0.0, barrier=released - t1)
+    for event, own in zip(inside, self_times([e[2:4] for e in inside])):
+        phase = next((p for end, p in SETUP_PHASES.items()
+                      if event[0].endswith("." + end)), "other")
+        account[phase] += own
+    account = {k: round(v, 6) for k, v in account.items()}
+    account["total"] = round(released - run_start, 6)
+    account["instance"] = thread
+    return account
 
 
 # -- deterministic phase attribution ----------------------------------

@@ -157,6 +157,11 @@ def parse_meta(job_dir: str) -> Dict[str, object]:
             # time (weights + warmup compiles)
             import json
             meta["warmup_s"] = json.loads(line.split(":", 1)[1])
+        elif line.startswith("Setup:"):
+            # JSON {phase: seconds} from run_benchmark's first line to
+            # the start barrier, along the stage instance built last
+            import json
+            meta["setup_account"] = json.loads(line.split(":", 1)[1])
         elif line.startswith("Trace:"):
             # "Trace: events=N dropped=M" — written only by
             # trace-enabled runs (rnb_tpu.trace); counts events
@@ -940,6 +945,18 @@ def check_job_detail(job_dir: str) -> Tuple[List[str], bool]:
                 "window (Compiles: steady_new) — warmup must cover "
                 "the full shape vocabulary"
                 % (step, int(sigs["steady_new"])))
+
+    # set-up's account (rnb_tpu.trace.setup_account): the phases
+    # along the instance built last partition run_benchmark's first
+    # line to the start barrier's release
+    account = meta.get("setup_account")
+    if account:
+        parts = sum(v for k, v in account.items()
+                    if k not in ("total", "instance"))
+        if abs(parts - account["total"]) > 1e-3:
+            problems.append(
+                "Setup: the phases give %.6f s of total %.6f"
+                % (parts, account["total"]))
 
     # self-healing accounting (rnb_tpu.health): lane transition paths
     # must be legal automaton walks, routing must never feed an open

@@ -162,3 +162,34 @@ class CountingPathIterator(VideoPathIterator):
         while True:
             yield "video-%d" % i
             i += 1
+
+
+#: the paths ProbingPathIterator hands out, and what it saw at each
+PROBE_PATHS: list = []
+PROBED: list = []
+
+
+def listener_counts() -> tuple:
+    """How many listeners of each kind ``jax.monitoring`` holds."""
+    from jax._src import monitoring
+    return (len(monitoring.get_event_listeners()),
+            len(monitoring.get_event_duration_listeners()),
+            len(monitoring.get_event_time_span_listeners()),
+            len(monitoring.get_scalar_listeners()))
+
+
+class ProbingPathIterator(VideoPathIterator):
+    """Cycles ``PROBE_PATHS`` and keeps in ``PROBED`` what the client's
+    thread saw of the tracing at each request. The client asks for its
+    first path behind the start barrier, so every entry is the state
+    the served window runs under."""
+
+    def __iter__(self):
+        import itertools
+
+        from rnb_tpu import trace
+        for path in itertools.cycle(list(PROBE_PATHS)):
+            PROBED.append({"active": trace.ACTIVE,
+                           "span": trace.span("client.enqueue"),
+                           "listeners": listener_counts()})
+            yield path

@@ -120,7 +120,9 @@ def test_the_accepted_readers_list_the_cell_last():
     by_name = {m["name"]: m for m in mm.load()["per_layer"]}
     for name in LISTED:
         assert by_name[name + ".bulk"]["workloads"][-1] == CELL, name
-    listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())}
+    # (PR 51's set-up metrics list every cell and move `setup_s`)
+    listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
+              and m["moves"] == "videos_per_s"}
     assert listed == {n + ".bulk" for n in LISTED} | set(NEW_READERS)
 
 

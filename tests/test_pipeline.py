@@ -70,8 +70,10 @@ def test_bulk_end_to_end(tmp_path):
 
 def test_cpu_accounting_and_no_span_artifacts(tmp_path):
     """The rusage window (always on) lands in the result, and a run
-    with no `trace` key and no profiler session leaves no span
-    artifact behind: the spans are profiler annotations only."""
+    with no `trace` key and no profiler session leaves no artifact of
+    the served window's spans behind (they are profiler annotations
+    only): set-up's record, kept up to the start barrier, is the one
+    span file (PR 51)."""
     cfg = _write_config(tmp_path, _two_step())
     # enough videos that the measured window exceeds the kernel's
     # CPU-time accounting granularity: with every jit cache warm from
@@ -84,7 +86,10 @@ def test_cpu_accounting_and_no_span_artifacts(tmp_path):
     assert res.host_cpu_s > 0
     files = os.listdir(res.log_dir)
     assert sorted(f for f in files if "group" not in f) \
-        == ["log-meta.txt", "pipeline.json"]
+        == ["log-meta.txt", "pipeline.json", "setup-trace.json"]
+    with open(os.path.join(res.log_dir, "setup-trace.json")) as f:
+        assert {e["name"].split(".")[0] for e in json.load(f)["traceEvents"]
+                if e["ph"] != "M"} == {"setup"}
     assert res.trace_events == 0
     # the tiny pipeline decodes nothing through the native pool
     assert res.decode_busy_s == 0 and res.decode_frames == 0

@@ -245,7 +245,7 @@ def test_a_cached_executable_is_not_handed_to_a_program_of_other_scopes(
 
     from rnb_tpu import hloscopes
     from rnb_tpu.benchmark import enable_compilation_cache
-    names = ("jax_compilation_cache_dir",
+    names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",
              "jax_persistent_cache_min_entry_size_bytes",
              "jax_compilation_cache_include_metadata_in_key")
@@ -262,6 +262,9 @@ def test_a_cached_executable_is_not_handed_to_a_program_of_other_scopes(
     try:
         enable_compilation_cache()
         compilation_cache.reset_cache()
+        # a described-v5e fixture of an earlier file on this worker may
+        # have left the cache off (they turn it off and never on again)
+        jax.config.update("jax_enable_compilation_cache", True)
         jax.config.update("jax_compilation_cache_dir", str(tmp_path))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -348,6 +348,7 @@ def test_unregistered_meta_line_triggers_t004(tmp_path):
                      'f.write("Hedge: fired=%d\\n" % hg)\n'
                      'f.write("Compiles: %s\\n" % c)\n'
                      'f.write("Warmup: %s\\n" % w)\n'
+                     'f.write("Setup: %s\\n" % su)\n'
                      'f.write("Pages: allocs=%d\\n" % pg)\n'
                      'f.write("Shard: steps=%d\\n" % sh)\n'
                      'f.write("Shard steps: %s\\n" % ss)\n'
