@@ -14,9 +14,11 @@ rotates queries and keys (``ops/rope.py``: halves rotated, plain
 frequencies, positions inside the request) and a query reads the
 ``sliding_window`` keys of its request that end with its own; a *full*
 layer reads every key of its request at or before the query and has no
-rotary at all ("global NoPE"). Both run through the pool's flash kernel
-(``ops/segattn.py``): the sliding layers with its local mask, a table
-cut by the window and tiles of their own.
+rotary at all ("global NoPE"). A full layer runs through the pool's
+flash kernel (``ops/segattn.py``); a sliding layer has a kernel of its
+own (``ops/banded.py``) that reads q, k and v as their products wrote
+them, norms and turns them as its first lines, and writes ``o``'s
+operand: a band of two key blocks a step, no table.
 
 *Feed-forward*: a SiLU-gated MLP where ``mlp_layer_types`` says
 ``dense`` (the first ``first_k_dense_replace`` layers), else sparse
@@ -51,11 +53,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from rnb_tpu.ops import moe, rope, segattn
+from rnb_tpu.ops import banded, moe, segattn
 
 #: what ``forward`` returns behind the logits and the router's choices
 #: (``models/token_stages.py``): ``attn_tiles`` counts the full layers,
-#: ``window_tiles`` the sliding ones, at their own tile sizes;
+#: ``window_tiles`` the sliding ones' steps, at their own tile sizes;
 #: ``pair_rows`` the pair rows ``held_experts``' buffers held and the
 #: tokens x k a layer has
 COUNTERS = ("expert_served", "group_tokens", "attn_tiles", "window_tiles",
@@ -187,35 +189,42 @@ def _proj(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
-def attention_mixer(cfg, p, x, row_start, positions, sliding: bool,
-                    interpret=False):
-    """``x`` (rows, Q, hidden), the stream as it is -> (float32 (rows,
-    Q, hidden) before the norm behind it, the flash kernel's tiles: run,
-    and on or under the diagonal)."""
+def attention_mixer(cfg, p, x, row_start, band=None, interpret=False):
+    """``x`` (rows, Q, hidden), the stream as it is; ``band``: a sliding
+    layer's ``ops/banded.band_tables``, None for a full layer ->
+    (float32 (rows, Q, hidden) before the norm behind it, the kernel's
+    tiles: run, and on or under the diagonal)."""
     rows, q, _ = x.shape
     act = x.dtype
     hq, hk, dim = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
+    tokens = rows * q
+    if band is not None:
+        # the kernel reads the three products' results as they are, and
+        # writes the fourth's operand
+        operands = (_proj(x, p["q"]).reshape(tokens, hq * dim),
+                    _proj(x, p["k"]).reshape(tokens, hk * dim),
+                    _proj(x, p["v"]).astype(act).reshape(tokens, hk * dim))
+        with jax.named_scope("kernel"):
+            out, tiles = banded.banded_attention(
+                *operands, p["q_norm"], p["k_norm"], band,
+                cfg.sliding_window, cfg.eps, interpret)
+        return _proj(out.reshape(rows, q, hq * dim), p["o"]), tiles
     qs = rms_norm(_proj(x, p["q"]).reshape(rows, q, hq, dim), p["q_norm"],
                   cfg.eps, jnp.float32)
     ks = rms_norm(_proj(x, p["k"]).reshape(rows, q, hk, dim), p["k_norm"],
                   cfg.eps, jnp.float32)
-    if sliding:
-        qs = rope.rotate(qs, positions, cfg.inv_freq())
-        ks = rope.rotate(ks, positions, cfg.inv_freq())
     # the scores' scale goes onto the float32 queries, before their one
     # rounding to the activations' dtype
     qs = (qs * dim ** -0.5).astype(act)
     vs = _proj(x, p["v"]).astype(act).reshape(rows, q, hk, dim)
-    tokens = rows * q
     operands = (
         segattn.heads_first(qs.reshape(tokens, hk, hq // hk, dim)),
         segattn.heads_first(ks.astype(act).reshape(tokens, hk, dim)),
         segattn.heads_first(vs.reshape(tokens, hk, dim)))
     with jax.named_scope("kernel"):
         out, tiles = segattn.heads_first_attention(
-            *operands, row_start, q, interpret,
-            cfg.sliding_window if sliding else None)
+            *operands, row_start, q, interpret)
     out = jnp.moveaxis(out, -2, 0)[:tokens, ..., :dim] \
         .reshape(rows, q, hq * dim)
     return _proj(out, p["o"]), tiles
@@ -283,14 +292,16 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
     valid tokens of each expert layer that sent the held experts
     anything (expert layers,) int32; the flash kernel's tiles in the
     full layers (full layers, 2) int32: those this dispatch's block
-    table let run, and those on or under the diagonal; the same of the
-    sliding layers (sliding layers, 2), at their own tile sizes; the pair
+    table let run, and those on or under the diagonal; the banded
+    kernel's steps in the sliding layers and the tiles of their size on
+    or under the diagonal (sliding layers, 2); the pair
     rows the held experts' buffers held and tokens x k (expert layers,
     2)).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
-    positions = rope.pool_positions(row_start, q)
+    # the sliding layers' rotary tables and requests' first tokens, once
+    band = banded.band_tables(row_start, q, cfg.inv_freq())
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
@@ -301,8 +312,9 @@ def forward(cfg: ExaoneMoeConfig, params, slots, tokens, row_tokens,
         sliding = cfg.is_sliding(i)
         with jax.named_scope("attn"):
             with jax.named_scope("window" if sliding else "full"):
-                out, ran = attention_mixer(cfg, p, x, row_start, positions,
-                                           sliding, interpret)
+                out, ran = attention_mixer(cfg, p, x, row_start,
+                                           band if sliding else None,
+                                           interpret)
             out = rms_norm(out, p["attn_norm"], cfg.eps, jnp.float32)
             x = (x.astype(jnp.float32) + out).astype(act)
             (window_tiles if sliding else full_tiles).append(ran)

@@ -16,7 +16,7 @@ elsewhere (CPU tests, interpret mode), so numerics are defined once.
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
 rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
-rnb_tpu.models.keye_vl2, rnb_tpu.models.kimi_linear: seven) add seven
+rnb_tpu.models.keye_vl2, rnb_tpu.models.kimi_linear: seven) add eight
 mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan — one Pallas kernel that walks the rows with a step's states in
@@ -37,9 +37,12 @@ by bit, and a Pallas flash kernel under the sets), ``segattn``
 (causal attention inside requests: JAX's Pallas splash kernel over the
 pool; values may be narrower than keys, latent attention's expanded
 form, with rotary keys (DeepSeek-V2) or with no positions at all
-(Kimi-Linear); with a window, a query reads the last so many keys of its request
-and the kernel walks a band of tiles, K-EXAONE's sliding layers), ``rope`` (rotary positions that restart at each request, YaRN's
-frequencies) and ``moe`` (routing over all experts by the family's rule
+(Kimi-Linear)), ``banded`` (attention under a window, K-EXAONE's
+sliding layers: a query reads the last so many keys of its request; one
+Pallas kernel with no table, a key-value head's query heads against the
+two key blocks of a band in one step, the head norms and the rotary its
+first lines, operands read as the products wrote them), ``rope``
+(rotary positions that restart at each request, YaRN's frequencies) and ``moe`` (routing over all experts by the family's rule
 and the held experts' part, plain or gated, whose grouped product is
 JAX's Pallas megablox kernel).
 """

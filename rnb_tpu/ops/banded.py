@@ -1,0 +1,309 @@
+"""Grouped-query attention under a *window* over a packed pool of rows:
+a query reads the ``window`` keys of its request that end with its own.
+One Pallas TPU kernel, K-EXAONE's sliding layers' own
+(``models/exaone_moe``), from the three products' results to the operand
+of the fourth.
+
+The pool holds ``rows`` of ``Q`` tokens; a request is a run of
+consecutive rows (``ops/segattn.py``'s text). Under a window of 128 keys
+and a context of 16k tokens the causal triangle is the wrong picture:
+what a block of queries may read is a *band*, as wide as the block and
+the window together, wherever the block lies. So the kernel has no
+table, no inner loop over key blocks and no running softmax.
+
+*A step* is (query block i, key-value head g), both grid axes
+``parallel``. The block is ``B`` tokens, ``B >= window - 1`` and ``B``
+dividing the pool (:func:`band_block`: 128 at the published window of
+128), so the queries of block i read two key blocks, their own and the
+one before it: ``k`` and ``v`` are passed twice, block ``max(i - 1, 0)``
+and block ``i``. A pair (query t, key s) is kept iff ``s <= t``, ``s > t
+- window`` and ``s >= start[t]``, the first token of t's request
+(``ops/indexed.token_table``'s form); ``s`` is counted from the real
+block index, so that in front of the pool's first block stands a block
+of negative positions that masks itself. Every query keeps its own key.
+The band is whole in one step: one plain softmax.
+
+*Eight query heads a step.* The ``Hq / Hk`` query heads that read key
+head g share the step: the block's keys are normed, turned and laid out
+once for the eight, 1,024 steps a layer. Each head's (B, D) slice has
+its own product for the scores, against 256 keys, and its own for the
+values, and the eight go through each line of the kernel together (the
+sweep has what the other orders cost).
+
+*Operands as the products wrote them.* ``q`` is the float32 ``(T, Hq
+D)`` result of the layer's first product, read by the block ``(B, (Hq /
+Hk) D)`` at column block g; ``k`` float32 ``(T, Hk D)``, ``v`` ``(T, Hk
+D)`` in the activations' dtype; the result is written ``(T, Hq D)`` in
+that dtype at q's column block: the last product's operand. A head is a
+lane slice (D = 128 is a lane tile). Between the products no array with
+a head axis exists in HBM: no pad, no transpose.
+
+*QK-norm and rotary are the kernel's first lines*, a head's ``(B, D)``
+slice at a time, in float32: ``x rsqrt(mean(x^2) + eps) weight``, then
+``x cos + roll(x, D / 2) sin`` with the tables ``[cos | cos]`` and
+``[-sin | sin]`` (:func:`band_tables`: ``ops/rope.turn_tables``'s
+numbers, built once a dispatch for all the layers and read a ``(B, D)``
+block a step: the same float32 numbers as ``ops/rope.rotate``'s
+half-split form), then ``D^-1/2`` on q and one rounding to the
+activations' dtype. Keys are normed and turned in both steps that read
+them (an eighth of q's work, twice). Scores and softmax are float32; the
+probabilities go into the values' product as float32, as splash handed
+them to its own (interpreted, the product then keeps them; compiled,
+Mosaic rounds them: the sweep below), and are summed in float32 beside
+it; the division stands behind the product.
+
+Off the TPU the same kernel runs in Pallas's interpret mode.
+
+**What it replaced** (PR 52). Until then a layer with a window ran
+``ops/segattn.py``'s kernel, splash, under its local mask with a table
+cut to the band (``window_table``: (query blocks, steps)), one query
+head a step. On the v5e, 128 rows of 128 tokens, 64 / 8 heads of 128, a
+window of 128, that kernel's call alone (my chip runs, PR 42; one, two
+and three requests in the pool read alike, within 0.2 ms): ms at
+(queries a tile, keys a tile, keys a step) and the steps a query block:
+(512, 512, 512) **6.2-6.3**, 2; (512, 256, 256) 7.2, 3; (256, 256, 256)
+7.5, 2; (256, 256, 128) 7.6; (512, 512, 256) 7.6; (1024, 512, 512) 7.7,
+3; (1024, 256, 256) 8.0, 5; (1024, 1024, 512) 8.6, 2; (512, 128, 128)
+9.8, 5; (256, 128, 128) 10.0, 3; (2048, 256, 256) 10.9; (1024, 128, 128)
+11.0, 9; (128, 128, 128) 11.6, 2: the smallest tiles computed least and
+lost to the grid's 16,384 steps of 0.7 us, the largest computed eight
+times the band. Under the causal table the same layer took 17.5 (three
+requests, 57 tiles), 21.4 (two, 76) and 33.9 ms (one, 136). Around that
+kernel the mixer made float32 passes for the norms, the rotary (a half
+materialised and concatenated), the scale and the cast, a pad and a
+transpose for each of q, k and v, and a transpose back: 6 ms a layer
+beside the kernel's 5.4 at the mix's mean dispatch (PERF.md section 5,
+PR 43). The window's own work (each query against 128 keys; q, k, v read
+and the result written once in bfloat16) is 0.74 ms of the chip's
+memory bandwidth.
+
+**The sweep** (my chip runs, PR 52; one TPU v5 lite, the same 128 rows
+and widths, this kernel's call alone with its first lines inside; ms).
+``scripts/banded_sweep.py`` reads the kernel as it stands at 128 and at
+256 queries a step and the passes it replaced; every other variant below
+was an edit of the kernel's body in a probe that is not in the tree (the
+chip tool's log of PR 52 holds its lines). *The first form* laid the
+eight heads' rows under each other, (head, query), for one product of
+1,024 x 128 against 256 keys and one for the values, and rounded the
+probabilities to bfloat16 in front of theirs: **2.95** at 128 queries a
+step, 3.63 at 256 (half the steps, twice the masked pairs, 64 MiB of
+VMEM allowed). On that form: ``q`` read as bfloat16 (a first product
+that rounds) 2.96 and 3.61: no faster, for a second rounding of q (the
+check's largest difference 0.0148 for 0.0096), so q stays float32 - the
+kernel is not bound by its bytes (1.04 GB a layer, 1.27 ms). The
+probabilities handed to the values' product in float32 3.02-3.04, the
+same result to the last bit: Mosaic's product at default precision
+rounds float32 operands to bfloat16 itself (a probe of 1,024 x 256 x 128
+read the same largest error 0.0658 for float32 operands as for bfloat16
+ones, and 6e-6 at ``highest``), so splash's float32 probabilities went
+into its product as bfloat16 too. They are float32 here for the
+interpreted kernel's sake, which the tests hold to the reference: there
+the product keeps what it is handed, as splash's did, and a cast would
+be a rounding the parent's tests never saw. The softmax over all heads'
+rows at once 2.99; the halves' swap as a product with a permutation at
+``highest`` in the place of the lane rotation 3.07, the same bits. Where
+the 2.95 went, by leaving a line out (wrong results, times only):
+without the rotary's lane rotation 2.00, without the norm's mean 2.19,
+without the row maximum 2.55, without the row sum 2.87, without the
+exponential 2.93, without the division 2.91; without rotation, mean,
+maximum, sum and exponential together 1.90, which is the copies, the two
+products and the plain multiplies. *The form that stands* takes the
+copies out: with float32 probabilities, the values' product a head and
+the scores' still one 3.01-3.03; both products a head, each head through
+all its lines before the next 3.12; **both products a head, the eight
+heads through each line together 2.59-2.60** (2.64-2.65 with the keys
+transposed once in front), the same bits as the first form in every
+case. What is left is bound by the unit that moves values across lanes
+(a step rotates 160 registers and reduces 416 along their lanes), not by
+bytes and not by the matrix unit. The passes this kernel replaced, as
+XLA runs them without a kernel between them (norms, rotary, scale and
+cast, three pads and transposes, one transpose back and one sum to hold
+them apart): 10.8.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from rnb_tpu.ops import rope
+
+#: the kernel's name in the device's trace and in the scope table
+KERNEL_NAME = "banded_attention"
+
+#: what a score outside the band is set to (splash's own)
+_MASKED = -0.7 * float(np.finfo(np.float32).max)
+
+#: a block's rows are whole sublane tiles of the activations' dtype
+_SUBLANES = 16
+
+
+def band_block(tokens: int, window: int) -> int:
+    """Queries a step for a pool of ``tokens``: the least count of whole
+    sublane tiles that divides the pool and is at least ``window - 1``
+    (two blocks then hold any query's band), else the whole pool."""
+    return next((b for b in range(_SUBLANES, tokens, _SUBLANES)
+                 if tokens % b == 0 and b >= window - 1), tokens)
+
+
+def band_tables(row_start, qlen: int, inv_freq):
+    """What the kernel reads beside a layer's q, k and v, the same for
+    every layer of a dispatch: ``row_start`` (rows,) int32, rows of
+    ``qlen`` tokens, ``inv_freq`` (D // 2,) the rotary frequencies.
+    -> (cos, sin, start): float32 (T, D) ``[cos | cos]`` and ``[-sin |
+    sin]`` of each token's position inside its request, so that
+    ``ops/rope.rotate(x)`` is ``x cos + roll(x, D / 2) sin``; int32 (T,
+    1) the first token of each token's request."""
+    half = len(inv_freq)
+    cos, sin = rope.turn_tables(
+        rope.pool_positions(row_start, qlen).reshape(-1), inv_freq, 0,
+        2 * half)
+    sign = np.repeat(np.float32([-1.0, 1.0]), half)
+    start = jnp.repeat(row_start.astype(jnp.int32) * qlen, qlen)
+    return cos, sin * sign, start[:, None]
+
+
+def band_tiles(tokens: int, block: int):
+    """(the steps a key-value head takes, the (``block``, 2 ``block``)
+    tiles on or under the diagonal: what a kernel that walked the causal
+    triangle at this kernel's tile sizes would run)."""
+    steps = tokens // block
+    return steps, int((np.arange(steps) // 2 + 1).sum())
+
+
+def _first_lines(x, weight, cos, sin, eps: float, act, scale=None):
+    """A head's (B, D) float32 slice as its product wrote it -> in ``act``, as
+    the kernel's products read it: through the head's RMS norm
+    (``weight`` (1, D) float32, with ``eps``), turned by its tokens'
+    positions (``cos``, ``sin`` (B, D): :func:`band_tables`'), times
+    ``scale`` where one is given, all in float32, and rounded once."""
+    x = x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+    x = x * weight
+    x = x * cos + pltpu.roll(x, x.shape[1] // 2, 1) * sin
+    return (x if scale is None else x * scale).astype(act)
+
+
+def _kernel(q_ref, k0_ref, k1_ref, v0_ref, v1_ref, cos0_ref, cos1_ref,
+            sin0_ref, sin1_ref, start_ref, qw_ref, kw_ref, o_ref, *,
+            window: int, dim: int, eps: float):
+    """One query block of one key-value head. ``q_ref`` (B, per * D)
+    float32 and ``k1_ref`` (B, D) float32 as their products wrote them,
+    ``v1_ref`` (B, D) in the activations' dtype, ``cos1_ref``,
+    ``sin1_ref`` (B, D) the block's rotary tables; ``k0_ref``,
+    ``v0_ref``, ``cos0_ref``, ``sin0_ref`` the same of the block before
+    it (of this block again at the pool's first); ``start_ref`` (B, 1)
+    the first token of each query's request; ``qw_ref``, ``kw_ref`` (1,
+    D) the norms' weights in float32; ``o_ref`` as ``q_ref``, in the
+    activations' dtype."""
+    i = pl.program_id(0)
+    block = q_ref.shape[0]
+    per = q_ref.shape[1] // dim
+    act = v1_ref.dtype
+
+    cos, sin = cos1_ref[...], sin1_ref[...]
+    q_weight, k_weight = qw_ref[...], kw_ref[...]
+    # the scores' scale goes onto the float32 queries, before their one
+    # rounding
+    q = [_first_lines(q_ref[:, h * dim:(h + 1) * dim], q_weight, cos, sin,
+                      eps, act, dim ** -0.5) for h in range(per)]
+    k = jnp.concatenate([
+        _first_lines(k0_ref[...], k_weight, cos0_ref[...], sin0_ref[...],
+                     eps, act),
+        _first_lines(k1_ref[...], k_weight, cos, sin, eps, act)], axis=0)
+    v = jnp.concatenate([v0_ref[...], v1_ref[...]], axis=0)
+    t = i * block + lax.broadcasted_iota(jnp.int32, (block, 2 * block), 0)
+    at = (i - 1) * block \
+        + lax.broadcasted_iota(jnp.int32, (block, 2 * block), 1)
+    keep = (at <= t) & (at > t - window) & (at >= start_ref[...])
+    # a head's two products are its own, and the heads go through each
+    # line together: the module's sweep has both choices' times
+    s = [lax.dot_general(of_head, k, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+         for of_head in q]
+    p, sums = [], []
+    for of_head in s:
+        of_head = jnp.where(keep, of_head, _MASKED)
+        p.append(jnp.exp(of_head - of_head.max(axis=-1, keepdims=True)))
+        sums.append(p[-1].sum(axis=-1, keepdims=True))
+    o = [jnp.dot(of_head, v, preferred_element_type=jnp.float32)
+         for of_head in p]
+    for h in range(per):
+        o_ref[:, h * dim:(h + 1) * dim] = (o[h] / sums[h]).astype(o_ref.dtype)
+
+
+def _cost(steps: int, heads: int, per: int, block: int, dim: int,
+          operands, out) -> pl.CostEstimate:
+    """What a call costs, for the compiler that schedules around it
+    (``ops/deltanet.py``'s ``_cost``): the two products of a step, an
+    exponential a score, and every operand's bytes as the steps read
+    them (what stands for the block before is read twice)."""
+    pairs = steps * heads * per * block * 2 * block
+    return pl.CostEstimate(
+        flops=2 * 2 * pairs * dim, transcendentals=pairs,
+        bytes_accessed=sum(x.size * x.dtype.itemsize
+                           for x in operands + (out,)))
+
+
+# a function under ``jit`` of its own: a stack's sliding layers call it
+# with the same shapes, and the kernel is traced and lowered once for all
+@functools.partial(jax.jit, static_argnames=("window", "eps", "interpret"))
+def _band_call(q, k, v, q_weight, k_weight, cos, sin, start, *, window,
+               eps, interpret):
+    tokens, dim = cos.shape
+    heads = k.shape[1] // dim
+    per = q.shape[1] // k.shape[1]
+    block = band_block(tokens, window)
+    steps = tokens // block
+
+    def spec(width, before=False, shared=False):
+        """``width`` columns of a block of tokens: this step's or the
+        block before it, key-value head g's or, of an array every head
+        reads, the only ones."""
+        def at(i, g):
+            return (jnp.maximum(i - 1, 0) if before else i,
+                    0 if shared else g)
+        return pl.BlockSpec((block, width), at)
+    weight = pl.BlockSpec((1, dim), lambda i, g: (0, 0))
+    f32 = jnp.float32
+    operands = (q, k, k, v, v, cos, cos, sin, sin, start,
+                q_weight.astype(f32)[None, :], k_weight.astype(f32)[None, :])
+    out = jax.ShapeDtypeStruct(q.shape, v.dtype)
+    return pl.pallas_call(
+        functools.partial(_kernel, window=window, dim=dim, eps=eps),
+        grid=(steps, heads),
+        in_specs=[spec(per * dim), spec(dim, True), spec(dim),
+                  spec(dim, True), spec(dim), spec(dim, True, True),
+                  spec(dim, shared=True), spec(dim, True, True),
+                  spec(dim, shared=True), spec(1, shared=True), weight,
+                  weight],
+        out_specs=spec(per * dim), out_shape=out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        cost_estimate=_cost(steps, heads, per, block, dim, operands, out),
+        interpret=interpret, name=KERNEL_NAME)(*operands)
+
+
+def banded_attention(q, k, v, q_weight, k_weight, tables, window: int,
+                     eps: float, interpret: bool = False):
+    """One layer's attention under a window, from the three products'
+    results to the fourth's operand (the module's text).
+
+    ``q`` (T, Hq D) and ``k`` (T, Hk D) float32, un-normalised, each key
+    head serving Hq // Hk query heads; ``v`` (T, Hk D) in the
+    activations' dtype; ``q_weight``, ``k_weight`` (D,) the head norms'
+    weights, with ``eps``; ``tables`` :func:`band_tables`' three;
+    ``window``: a query reads the so many keys of its request that end
+    with its own. -> ((T, Hq D) in ``v``'s dtype; int32 (2,):
+    :func:`band_tiles`, the steps the kernel ran a key-value head and
+    the tiles of its size on or under the diagonal)."""
+    out = _band_call(q, k, v, q_weight, k_weight, *tables,
+                     window=int(window), eps=float(eps),
+                     interpret=bool(interpret))
+    tiles = band_tiles(q.shape[0], band_block(q.shape[0], int(window)))
+    return out, jnp.asarray(tiles, jnp.int32)

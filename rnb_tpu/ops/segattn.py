@@ -46,30 +46,8 @@ forms agree, so one set of constants. (PR 28 read 4.6 ms for the whole
 triangle, and 5.1 to 63.8 ms for a ``jax.numpy`` loop over the
 distance between query row and key row.)
 
-A layer with a *window* (``window``: a query reads the so many keys of
-its request that end with its own) runs the same kernel under splash's
-local mask, and its table holds the band a row of tiles can run, not
-the row (:func:`window_table`): the kernel's grid is as wide as the
-table it is given, so the layer walks ``steps`` tiles a query block
-whatever the context, and its time does not follow the packing. Its
-tiles are its own. On the v5e, 128 rows of 128 tokens, 64 / 8 heads of
-128, a window of 128, the kernel's call alone (my chip runs, PR 42;
-one, two and three requests in the pool read alike, within 0.2 ms): ms
-at (queries a tile, keys a tile, keys a step) and the steps a query
-block: (512, 512, 512) **6.2-6.3**, 2; (512, 256, 256) 7.2, 3; (256,
-256, 256) 7.5, 2; (256, 256, 128) 7.6; (512, 512, 256) 7.6; (1024, 512,
-512) 7.7, 3; (1024, 256, 256) 8.0, 5; (1024, 1024, 512) 8.6, 2; (512,
-128, 128) 9.8, 5; (256, 128, 128) 10.0, 3; (2048, 256, 256) 10.9;
-(1024, 128, 128) 11.0, 9; (128, 128, 128) 11.6, 2: the smallest tiles
-compute least (a query block of 128 against 256 keys is the band
-itself) and lose to the grid's 16,384 steps of 0.7 us, the largest
-compute eight times the band. Under the causal table the same layer
-took 17.5 (three requests, 57 tiles), 21.4 (two, 76) and 33.9 ms (one,
-136). The window's own work (each query against 128 keys; queries,
-keys and values read and the result written once) is 0.74 ms of the
-chip's memory bandwidth: a kernel that stacks a key-value head's eight
-query heads into one step of 128 queries against 256 keys, with no
-table, has most of the 6.2 to win (PERF.md section 7).
+A layer whose queries read a *window* of keys has a kernel of its own,
+``ops/banded.py``: a band is no triangle.
 """
 
 from __future__ import annotations
@@ -87,15 +65,13 @@ _LANES = 128
 #: the fastest of those read on the chip for both callers' forms (the
 #: module's text)
 _BLOCK_Q, _BLOCK_KV, _BLOCK_COMPUTE = 1024, 1024, 512
-#: the same three for a layer with a window (the module's text)
-_WINDOW_BLOCK_Q, _WINDOW_BLOCK_KV, _WINDOW_BLOCK_COMPUTE = 512, 512, 512
 
 
 def _round_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def block_table(first, block_q: int, block_kv: int, window=None):
+def block_table(first, block_q: int, block_kv: int):
     """The two tables splash reads ahead of the data, for a pool of
     ``len(first)`` query blocks: ``first[i]`` is the first token of the
     request that owns query block i's first token, the earliest key any
@@ -103,9 +79,7 @@ def block_table(first, block_q: int, block_kv: int, window=None):
     -> (``run``, ``fetch``, causal) with the tables int32 (query blocks,
     key blocks): ``run`` is 1 where the tile executes, ``fetch`` the key
     block a step holds; ``causal`` is the tiles on or under the
-    diagonal, which a pool that one request fills runs all. With a
-    ``window`` the tables are :func:`window_table`'s, (query blocks,
-    steps).
+    diagonal, which a pool that one request fills runs all.
 
     Tile (i, j) runs iff key block j starts at or before block i's last
     query and ends after ``first[i]``: the blocks ``lo[i] .. hi[i]`` of
@@ -119,8 +93,6 @@ def block_table(first, block_q: int, block_kv: int, window=None):
     table is safe, not exact: a tile it lets run may hold no permitted
     pair, and the kernel's own causal and segment masks decide inside
     every tile."""
-    if window is not None:
-        return window_table(first, block_q, block_kv, window)
     nq = first.shape[0]
     hi = ((np.arange(nq, dtype=np.int32) + 1) * block_q - 1) // block_kv
     j = np.arange(hi[-1] + 1, dtype=np.int32)[None, :]
@@ -131,45 +103,10 @@ def block_table(first, block_q: int, block_kv: int, window=None):
     return run.astype(jnp.int32), fetch, int((j <= hi).sum())
 
 
-def window_table(first, block_q: int, block_kv: int, window: int):
-    """:func:`block_table` where a query reads the ``window`` keys that
-    end with its own: tile (i, j) runs iff it runs without a window
-    *and* key block j ends after block i's first query less ``window -
-    1``. What can run in a row of tiles is then a band whose width the
-    sizes alone decide, so the tables hold the band and not the row:
-    int32 (query blocks, steps), step s of row i holding key block
-    ``lo[i] + s``, where ``lo[i]`` is the later of the request's first
-    block and the window's, and running up to the diagonal's. The
-    kernel's grid is as wide as the tables: a window layer walks
-    ``steps`` tiles a query block whatever the context. ``causal`` is
-    the tiles on or under the diagonal at these sizes, what the layer
-    would walk without its window."""
-    nq = first.shape[0]
-    start = np.arange(nq, dtype=np.int32) * block_q
-    hi = (start + block_q - 1) // block_kv
-    reach = np.maximum(start - (window - 1), 0) // block_kv
-    steps = int((hi - reach).max()) + 1
-    lo = jnp.maximum(first.astype(jnp.int32) // block_kv, reach)[:, None]
-    j = lo + np.arange(steps, dtype=np.int32)[None, :]
-    run = j <= hi[:, None]
-    fetch = jnp.where(run, j, jnp.roll(lo, -1, axis=0))
-    return run.astype(jnp.int32), fetch, int((hi + 1).sum())
-
-
 def _blocks(tokens: int):
     """(queries a tile, keys a tile) for a pool of ``tokens``."""
     whole = _round_up(tokens, _LANES)
     return min(_BLOCK_Q, whole), min(_BLOCK_KV, whole)
-
-
-def _window_blocks(padded: int):
-    """(queries a tile, keys a tile, keys a step) of a layer with a
-    window, for a pool laid out as ``padded`` tokens: the module's
-    constants where they divide it (any pool of more than a causal
-    tile), else one lane-width."""
-    sizes = (_WINDOW_BLOCK_Q, _WINDOW_BLOCK_KV, _WINDOW_BLOCK_COMPUTE)
-    return sizes if not any(padded % n for n in sizes[:2]) \
-        else (_LANES,) * 3
 
 
 def pool_tokens(tokens: int) -> int:
@@ -191,42 +128,34 @@ def heads_first(x, columns=None):
 
 
 def heads_first_attention(q, k, v, row_start, qlen: int,
-                          interpret: bool = False, window=None):
+                          interpret: bool = False):
     """The kernel's own form: ``q`` (Hk, Hq // Hk, P, D), already
     scaled; ``k`` (Hk, P, D); ``v`` (Hk, P, Dv); P the
     ``pool_tokens`` of the ``len(row_start) * qlen`` the pool holds, D
     and Dv whole lanes (what lies past a head's own columns is zero in
-    ``k`` and ``v``); ``row_start`` (rows,) int32; ``window``: a query
-    reads the so many keys of its request that end with its own (None:
-    every key at or before it).
+    ``k`` and ``v``); ``row_start`` (rows,) int32.
     -> ((Hk, Hq // Hk, P, Dv) in q's dtype, int32 (2,): the tiles the
     kernel ran a head, and the tiles on or under the diagonal)."""
     per, padded = q.shape[1:3]
     tokens = row_start.shape[0] * qlen
+    block_q, block_kv = _blocks(tokens)
     if padded != pool_tokens(tokens):
         raise ValueError("%d tokens laid out as %d, not %d"
                          % (tokens, padded, pool_tokens(tokens)))
-    if window is None:
-        block_q, block_kv = _blocks(tokens)
-        compute = min(_BLOCK_COMPUTE, block_kv)
-        mask = masks.CausalMask((padded, padded))
-    else:
-        block_q, block_kv, compute = _window_blocks(padded)
-        mask = masks.LocalMask((padded, padded), (int(window) - 1, 0), 0)
     # a token's segment id is the first token of its request
     segment = jnp.concatenate([
         jnp.repeat(row_start.astype(jnp.int32) * qlen, qlen),
         jnp.arange(tokens, padded, dtype=jnp.int32)])
-    # the static mask brings the mask function, the queries' positions
-    # and the grid; which tiles of it run is this dispatch's
+    # the static causal mask brings the mask function, the queries'
+    # positions and the grid; which tiles of it run is this dispatch's
     kernel = splash.make_splash_mqa_single_device(
-        masks.MultiHeadMask([mask] * per),
+        masks.MultiHeadMask([masks.CausalMask((padded, padded))] * per),
         block_sizes=splash.BlockSizes(
-            block_q=block_q, block_kv=block_kv, block_kv_compute=compute),
+            block_q=block_q, block_kv=block_kv,
+            block_kv_compute=min(_BLOCK_COMPUTE, block_kv)),
         interpret=interpret)
     info = kernel.fwd_mask_info
-    run, fetch, causal = block_table(segment[::block_q], block_q, block_kv,
-                                     window)
+    run, fetch, causal = block_table(segment[::block_q], block_q, block_kv)
     kernel = splash.SplashAttentionKernel(
         info._replace(
             block_mask=run[None].astype(info.block_mask.dtype),
@@ -237,13 +166,11 @@ def heads_first_attention(q, k, v, row_start, qlen: int,
     return out, jnp.stack([run.sum(), jnp.int32(causal)])
 
 
-def packed_attention(q, k, v, row_start, interpret: bool = False,
-                     window=None):
+def packed_attention(q, k, v, row_start, interpret: bool = False):
     """``q`` (rows, Q, Hq, D), already scaled (``D ** -0.5``, or the
     family's own); ``k`` (rows, Q, Hk, D) and ``v`` (rows, Q, Hk, Dv),
     each kv head serving Hq // Hk query heads (Dv may differ from D:
-    latent attention's 192 / 128); ``row_start`` (rows,) int32;
-    ``window`` as :func:`heads_first_attention` takes it.
+    latent attention's 192 / 128); ``row_start`` (rows,) int32.
     -> ((rows, Q, Hq, Dv) in q's dtype, int32 (2,): the tiles the
     kernel ran a head, and the tiles on or under the diagonal).
 
@@ -258,6 +185,6 @@ def packed_attention(q, k, v, row_start, interpret: bool = False,
         heads_first(q.reshape(tokens, hk, hq // hk, dim)),
         heads_first(k.reshape(tokens, hk, dim)),
         heads_first(v.reshape(tokens, hk, dim_v)), row_start, qlen,
-        interpret, window)
+        interpret)
     out = jnp.moveaxis(out, -2, 0)[:tokens, ..., :dim_v]
     return out.reshape(rows, qlen, hq, dim_v), tiles

@@ -1047,8 +1047,11 @@ STAGE_COUNTERS = (
     StageCounter(
         "window_tiles", "Attention:", "",
         ("window_tiles_visited", "window_tiles_causal"),
-        "(window layers, 2): the same pair of a stack's layers with a "
-        "window, at their own tile sizes"),
+        "(window layers, 2), a stack's layers with a window, whose "
+        "kernel walks a band and no table (``ops/banded.py``): the "
+        "steps it ran a key-value head, and the tiles on or under the "
+        "diagonal at their own tile sizes (a step's queries by twice "
+        "as many keys)"),
 )
 
 

@@ -3,15 +3,15 @@ toy size on the CPU with weights from a seed: the packed prefill through
 dispatches with requests of unequal length, a pad row, a request longer
 than the window and one shorter; the lower-precision control; packing
 invisible; the eight shares of the experts adding up to the uncut layer;
-the window by itself (``ops/segattn`` with a window against an explicit
-mask, for windows under, at and over a tile) and its table never closing
-a tile that holds a permitted pair; rotary on the sliding layers alone;
+the full layer's kernel by itself against an explicit mask (the sliding
+layers' own kernel, ``ops/banded.py``, has ``tests/test_banded.py``);
+rotary on the sliding layers alone;
 the stages and their counters; the operation counts against a count by
 hand; the cell through the one benchmark command; the four new readers
 on a run without their scope; the real configuration against the
-catalog's row; the window kernel compiled at the published widths for a
-described v5e; and the shared code's StableHLO for the newest older
-family and for this one.
+catalog's row; the full layer's kernel compiled at the published widths
+for a described v5e; and the shared code's StableHLO for the newest
+older family, for this one and for its full layer alone.
 Nothing here needs the native decode library or a chip."""
 
 import hashlib
@@ -261,12 +261,12 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(toy):
     assert np.abs(got - want).max() < 0.06 * want.std()
 
 
-# -- the window by itself ---------------------------------------------------------
+# -- the full layer's kernel by itself ----------------------------------------------
 
 
-def explicit(q, k, v, row_start, window):
+def explicit(q, k, v, row_start):
     """Softmax attention under an explicit (T, T) mask: a key of the
-    query's request, at or before it, inside its window."""
+    query's request, at or before it."""
     rows, qlen, hq, dim = q.shape
     hk, tokens = k.shape[2], rows * qlen
     qf = np.asarray(q, np.float64).reshape(tokens, hq, dim)
@@ -274,120 +274,65 @@ def explicit(q, k, v, row_start, window):
     vf = np.asarray(v, np.float64).reshape(tokens, hk, -1)
     seg, at = np.repeat(np.asarray(row_start), qlen), np.arange(tokens)
     ok = (seg[:, None] == seg[None, :]) & (at[None, :] <= at[:, None])
-    if window is not None:
-        ok &= at[None, :] > at[:, None] - window
     out = np.zeros((tokens, hq, vf.shape[-1]))
     for h in range(hq):
         s = np.where(ok, qf[:, h] @ kf[:, h // (hq // hk)].T, -np.inf)
         p = np.exp(s - s.max(-1, keepdims=True))
         out[:, h] = (p / p.sum(-1, keepdims=True)) @ vf[:, h // (hq // hk)]
-    return out.reshape(rows, qlen, hq, -1), ok
+    return out.reshape(rows, qlen, hq, -1)
 
 
-#: (rows of 128 tokens, each row's request, the window): a pool of
-#: lane-wide tiles (3 rows) and one of the module's window tiles (16
-#: rows: 512 keys a tile), windows under, at and over a tile, requests
-#: that open inside a tile and a pad row
-WINDOWS = [
-    (3, [0, 0, 2], 40), (3, [0, 0, 2], 128), (3, [0, 0, 0], 200),
-    (16, [0] * 9 + [9] * 6 + [15], 100),
-    (16, [0] * 9 + [9] * 6 + [15], 512),
-    (16, [0] * 16, 600), (16, [0] * 5 + [5] * 11, None)]
-
-
-@pytest.mark.parametrize("rows,starts,window", WINDOWS)
-def test_the_windowed_kernel_equals_the_explicit_mask(rows, starts, window):
+def test_the_full_layers_kernel_equals_the_explicit_mask():
+    """16 rows of 128 tokens, two requests, the second opening inside a
+    tile: what the sliding layers' cases beside this one were held to
+    until PR 52 gave them ``ops/banded.py`` (``tests/test_banded.py``)."""
     import jax.numpy as jnp
 
     from rnb_tpu.ops import segattn
-    rng = np.random.default_rng(rows + (window or 0))
+    rows, starts = 16, [0] * 5 + [5] * 11
+    rng = np.random.default_rng(rows)
     hq, hk, dim = 4, 2, 128
     q = jnp.asarray(rng.normal(size=(rows, 128, hq, dim)) * dim ** -0.5,
                     jnp.float32)
     k = jnp.asarray(rng.normal(size=(rows, 128, hk, dim)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(rows, 128, hk, dim)), jnp.float32)
     out, tiles = segattn.packed_attention(
-        q, k, v, jnp.asarray(starts, jnp.int32), True, window)
-    want, _ = explicit(q, k, v, starts, window)
+        q, k, v, jnp.asarray(starts, jnp.int32), True)
     # float32 operands, the kernel's running softmax against one pass
-    assert np.abs(np.asarray(out) - want).max() < 5e-6
+    assert np.abs(np.asarray(out) - explicit(q, k, v, starts)).max() < 5e-6
     ran, causal = (int(n) for n in np.asarray(tiles))
     assert 0 < ran <= causal
-
-
-@pytest.mark.parametrize("block_q,block_kv,window", [
-    (128, 128, 128), (256, 256, 128), (256, 128, 128), (512, 256, 128),
-    (128, 256, 100), (256, 256, 300), (1024, 256, 128), (256, 512, 700)])
-def test_the_window_table_closes_no_tile_with_a_permitted_pair(
-        block_q, block_kv, window):
-    """Over random segment tables: every (query, key) pair the mask
-    permits lies in a tile the table runs, the step that runs it fetches
-    that tile's own key block, a step that does not run fetches a block
-    some row runs (no copy of keys nobody reads), and the table is as
-    wide as the band, not the row."""
-    from rnb_tpu.ops import segattn
-    tokens = 4096
-    rng = np.random.default_rng(block_q + block_kv + window)
-    for _ in range(5):
-        cuts = np.sort(rng.choice(np.arange(1, tokens // 128), 5,
-                                  replace=False)) * 128
-        segment = np.zeros(tokens, np.int32)
-        for cut in cuts:
-            segment[cut:] = cut
-        run, fetch, causal = segattn.block_table(
-            segment[::block_q], block_q, block_kv, window)
-        run, fetch = np.asarray(run), np.asarray(fetch)
-        nq, steps = run.shape
-        assert steps <= (block_q + window - 2) // block_kv + 2
-        assert causal == sum(((i + 1) * block_q - 1) // block_kv + 1
-                             for i in range(nq))
-        open_tiles = {(i, int(fetch[i, s])) for i in range(nq)
-                      for s in range(steps) if run[i, s]}
-        assert len(open_tiles) == int(run.sum())
-        at = np.arange(tokens)
-        ok = (segment[:, None] == segment[None, :]) \
-            & (at[None, :] <= at[:, None]) \
-            & (at[None, :] > at[:, None] - window)
-        needed = {(int(i), int(j)) for i, j in zip(
-            *np.nonzero(ok.reshape(nq, block_q, tokens // block_kv,
-                                   block_kv).any(axis=(1, 3))))}
-        assert needed <= open_tiles
-        fetched = {int(j) for j in fetch.reshape(-1)}
-        assert fetched <= {j for _, j in open_tiles}
 
 
 # -- rotary on the sliding layers alone ---------------------------------------------
 
 
 def test_rotary_turns_the_sliding_layers_and_not_the_full_one(toy):
-    """A full layer's mixer does not see positions at all; a sliding
-    layer's result moves with them. And the reference agrees on both
-    kinds, layer by layer."""
+    """A full layer's mixer is handed no positions at all; a sliding
+    layer's result moves with the angles its tables hold. And the
+    reference agrees on both kinds, layer by layer."""
     import jax
     import jax.numpy as jnp
 
     from rnb_tpu.models.exaone_moe import network
-    from rnb_tpu.ops import rope
+    from rnb_tpu.ops import banded
     cfg = toy["cfg"]
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(4, Q, 64)), jnp.bfloat16)
     start = jnp.zeros(4, jnp.int32)
-    here = rope.pool_positions(start, Q)
+    band = banded.band_tables(start, Q, cfg.inv_freq())
+    # every angle doubled: the keys' and the queries' positions apart (a
+    # shift of every position would be invisible to rotary scores)
+    apart = banded.band_tables(start, Q, 2 * cfg.inv_freq())
     for layer, sliding in ((3, False), (2, True)):
         assert cfg.is_sliding(layer) == sliding
         p = toy["params"]["l%d" % layer]
-        out, _ = network.attention_mixer(cfg, p, x, start, here, sliding,
-                                         interpret=True)
-        moved, _ = network.attention_mixer(cfg, p, x, start, here + 7,
-                                           sliding, interpret=True)
-        # a shift of every position is invisible to rotary scores too:
-        # turn the keys' and the queries' positions apart
-        apart, _ = network.attention_mixer(cfg, p, x, start, here * 2,
-                                           sliding, interpret=True)
-        assert np.array_equal(np.asarray(out), np.asarray(moved)) \
-            or sliding
-        assert np.array_equal(np.asarray(out), np.asarray(apart)) \
-            != sliding
+        out, _ = network.attention_mixer(
+            cfg, p, x, start, band if sliding else None, interpret=True)
+        if sliding:
+            moved, _ = network.attention_mixer(cfg, p, x, start, apart,
+                                               interpret=True)
+            assert not np.array_equal(np.asarray(out), np.asarray(moved))
         with jax.default_matmul_precision("highest"):
             want = reference.attention(
                 TOY, {t: toy["read"]("l%d.%s" % (layer, t))
@@ -396,6 +341,31 @@ def test_rotary_turns_the_sliding_layers_and_not_the_full_one(toy):
         # bfloat16 products against float32
         assert np.abs(np.asarray(out).reshape(4 * Q, 64)
                       - np.asarray(want)).max() < 0.05 * float(want.std())
+
+
+def test_the_kernel_scope_holds_the_kernel_alone(toy):
+    """``window_attn_roofline_pct.bulk`` divides by the device time under
+    ``attn/window/kernel``: a sliding layer's four products are traced
+    outside that scope, the banded kernel's call inside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import network
+    from rnb_tpu.ops import banded
+    cfg = toy["cfg"]
+    start = jnp.zeros(4, jnp.int32)
+    band = banded.band_tables(start, Q, cfg.inv_freq())
+    jaxpr = jax.make_jaxpr(lambda p, x: network.attention_mixer(
+        cfg, p, x, start, band, interpret=True))(
+        toy["params"]["l2"], jnp.zeros((4, Q, 64), jnp.bfloat16))
+    scoped = {str(eqn.source_info.name_stack): eqn.primitive.name
+              for eqn in jaxpr.eqns}
+    products = [stack for eqn in jaxpr.eqns
+                if eqn.primitive.name == "dot_general"
+                for stack in [str(eqn.source_info.name_stack)]]
+    assert len(products) == 4 and not any("kernel" in s for s in products)
+    assert any("kernel" in stack and name in ("pjit", "jit")
+               for stack, name in scoped.items()), scoped
 
 
 def test_recipe_gives_program_and_reference_the_same_values(toy):
@@ -462,12 +432,14 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert counters["expert_served"].shape == (4, 2)
     assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
     assert 0 < counters["group_tokens"] <= 4 * valid
-    # one full layer and four sliding ones, a pool of one tile
+    # one full layer, a pool of one tile; four sliding ones, whose
+    # kernel takes the pool's 128 tokens in four steps of 32 (a window
+    # of 24), where tiles of 32 x 64 under the diagonal are 1 1 2 2
     assert counters["attn_tiles"].tolist() == [1, 1]
-    assert counters["window_tiles"].tolist() == [4, 4]
+    assert counters["window_tiles"].tolist() == [16, 24]
     _, twice = stage_counter_report([counters, counters])
     assert (twice["window_tiles_visited"],
-            twice["window_tiles_causal"]) == (8, 8)
+            twice["window_tiles_causal"]) == (32, 48)
     # four expert layers of 8 rows x 16 tokens x 4 choices: too few
     # pairs for a capacity, so all of them move
     assert counters["pair_rows"].tolist() == [2048, 2048]
@@ -900,11 +872,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("window", [128, None])
-def test_the_kernel_compiles_at_the_published_widths(window, one_chip):
-    """Both kinds of layer's kernel over the largest row bucket, compiled
-    for a described v5e (nothing runs): one custom call a key-value head
-    batch, and a sliding layer's tables are a band of two steps."""
+def test_the_kernel_compiles_at_the_published_widths(one_chip):
+    """The full layer's kernel over the largest row bucket, compiled for
+    a described v5e (nothing runs): one custom call a key-value head
+    batch (the sliding layers' kernel: ``tests/test_banded.py``)."""
     import jax
     import jax.numpy as jnp
 
@@ -919,16 +890,12 @@ def test_the_kernel_compiles_at_the_published_widths(window, one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     compiled = jax.jit(
         lambda a, b, c, s: segattn.heads_first_attention(
-            a, b, c, s, q, False, window)).lower(
+            a, b, c, s, q, False)).lower(
         of((hk, hq // hk, pool, dim)), of((hk, pool, dim)),
         of((hk, pool, dim)), of((rows,), jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
     assert "splash_mqa_fwd_segmented_no_residuals" in text
-    if window is not None:
-        block = segattn._WINDOW_BLOCK_Q
-        assert "s32[%d,2]" % (pool // block) in text \
-            or "s8[1,%d,2]" % (pool // block) in text
 
 
 def test_the_second_grouped_product_compiles_at_the_published_widths(
@@ -988,7 +955,13 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     ``qwen3_next``'s again (the L2 norms in front of its delta rule and
     the head norm and gate behind are the rule's kernel's first and
     last lines, ``ops/deltanet.py``: the mixer reshapes nothing to a
-    head axis between the convolution and ``o``)."""
+    head axis between the convolution and ``o``); PR 52 recorded this
+    family's again (its sliding layers run ``ops/banded.py``'s kernel,
+    interpreted here, and ``ops/segattn.py`` lost the window it had
+    for them: ``qwen3_next``'s text, and the three older families' and
+    ``keye_vl2``'s in their own modules, are the ones they had) and
+    holds this family's *full* layer alone to the text PR 51's tree
+    gave (:func:`test_the_full_layer_lowers_to_the_parents_text`)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
@@ -998,6 +971,46 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
         pytest.skip("recorded under jax %s" % recorded["jax"])
     text = test_qwen3_next.stack_text(family)
     assert hashlib.sha256(text.encode()).hexdigest() == recorded[family]
+
+
+def full_layer_text(mixer) -> str:
+    """The StableHLO text of the toy stack's full layer's mixer (layer
+    3: the norms in front, ``ops/segattn.py``'s kernel interpreted, the
+    four products) over 8 rows; ``mixer(cfg, p, x, row_start)`` calls
+    ``network.attention_mixer`` for a full layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.exaone_moe import checkpoint, network
+    cfg = network.ExaoneMoeConfig.from_published(TOY)
+    assert not cfg.is_sliding(3)
+    p = {name: jax.ShapeDtypeStruct(spec.shape, getattr(jnp, spec.dtype))
+         for name, spec in
+         checkpoint.tensor_specs(cfg, len(HELD))["l3"].items()}
+    return jax.jit(lambda p, x, start: mixer(cfg, p, x, start)).lower(
+        p, jax.ShapeDtypeStruct((8, Q, cfg.hidden_size), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8,), jnp.int32)).as_text()
+
+
+def test_the_full_layer_lowers_to_the_parents_text():
+    """PR 52 took the window out of ``ops/segattn.py`` and the sliding
+    layers out of its callers: the full layer's mixer, which stays on
+    it, lowers to the StableHLO text it had (the SHA-256 of PR 51's
+    tree's, from a ``git archive`` of that commit, where the same
+    function took ``positions`` and ``sliding=False``)."""
+    with open(os.path.join(REPO, "tests", "recorded",
+                           "toy_stack_stablehlo.json")) as f:
+        recorded = json.load(f)
+    import jax
+
+    from rnb_tpu.models.exaone_moe import network
+    if recorded["jax"] != jax.__version__:
+        pytest.skip("recorded under jax %s" % recorded["jax"])
+    text = full_layer_text(
+        lambda cfg, p, x, start: network.attention_mixer(
+            cfg, p, x, start, None, interpret=True))
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == recorded["exaone_moe_full_layer"]
 
 
 # -- the held experts' buffers in the compiled program -------------------------------

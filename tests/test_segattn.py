@@ -103,31 +103,6 @@ def test_the_table_leaves_out_no_tile_that_holds_a_permitted_pair(
         assert (run == np.eye(nq, dtype=run.dtype)).all()
 
 
-@pytest.mark.parametrize("packing", sorted(PACKINGS))
-def test_without_a_window_the_tables_are_todays(packing):
-    """``window=None`` is the table of a call without the argument, to
-    the last entry and as wide as the row; a window as long as the pool
-    opens the same tiles, held as a band (query blocks, steps)."""
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import segattn
-    rows, sizes = PACKINGS[packing]
-    block = 256
-    padded = -(-rows * Q // block) * block
-    first = jnp.asarray(token_segments(row_starts(sizes, rows),
-                                       padded)[::block])
-    run, fetch, causal = segattn.block_table(first, block, block)
-    same = segattn.block_table(first, block, block, None)
-    assert np.array_equal(run, same[0]) and np.array_equal(fetch, same[1])
-    assert causal == same[2] and run.shape == (padded // block,) * 2
-    band, at, band_causal = segattn.block_table(first, block, block, padded)
-    assert band_causal == causal and band.shape == run.shape
-    band, at = np.asarray(band), np.asarray(at)
-    opened = {(int(i), int(at[i, s])) for i, s in zip(*np.nonzero(band))}
-    assert opened == {(int(i), int(j))
-                      for i, j in zip(*np.nonzero(np.asarray(run)))}
-
-
 FORMS = {
     # grouped queries: 16 query heads a key-value head
     "gqa": dict(hq=32, hk=2, dim=32, dim_v=32),
