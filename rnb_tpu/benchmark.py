@@ -320,6 +320,7 @@ class BenchmarkResult:
     #: counts it
     tokens_valid: int = 0
     tokens_shipped: int = 0
+    tokens_scan_resets: int = 0
     experts_assignments: int = 0
     experts_held: int = 0
     experts_max_per_expert: int = 0

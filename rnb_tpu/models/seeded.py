@@ -61,6 +61,10 @@ class TensorSpec:
     heads_first: Optional[Tuple[int, Tuple[Optional[int], ...]]] = None
     #: a ``dt_bias`` draw's (min, max, floor) of the time step
     steps: Tuple[float, ...] = ()
+    #: ((columns, factor), ...): the last axis in runs of columns, each
+    #: drawn at ``scale`` times its own factor (a product whose result
+    #: the model multiplies by a scalar a segment)
+    segments: Tuple[Tuple[int, float], ...] = ()
 
 
 def published_shape(spec: TensorSpec) -> Tuple[int, ...]:
@@ -135,6 +139,10 @@ def _drawer(spec: TensorSpec):
             x = dt + jnp.log(-jnp.expm1(-dt))      # inverse softplus
         else:
             raise ValueError("tensor kind %r" % (spec.kind,))
+        if spec.segments:
+            x = x * np.repeat(
+                np.asarray([f for _, f in spec.segments], np.float32),
+                [n for n, _ in spec.segments])
         x = x.astype(dtype)
         if spec.halves is not None:
             x = x[..., halves_order(spec)]

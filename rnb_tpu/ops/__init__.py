@@ -16,7 +16,8 @@ elsewhere (CPU tests, interpret mode), so numerics are defined once.
 The token-sequence families (rnb_tpu.models.nemotron_h,
 rnb_tpu.models.deepseek_v2, rnb_tpu.models.minicpm_sala,
 rnb_tpu.models.qwen3_next, rnb_tpu.models.exaone_moe,
-rnb_tpu.models.keye_vl2, rnb_tpu.models.kimi_linear: seven) add eight
+rnb_tpu.models.keye_vl2, rnb_tpu.models.kimi_linear,
+rnb_tpu.models.falcon_h1: eight) add eight
 mechanisms, each over a packed pool
 of rows with state confined to requests: ``ssd`` (the blocked Mamba-2
 scan — one Pallas kernel that walks the rows with a step's states in

@@ -74,8 +74,8 @@ that a state is never rounded to bfloat16 on its way through the matrix
 unit. The within-row products take their inputs in the activations'
 dtype and accumulate in float32.
 
-*The convolution* (``segment_conv1d``; in front of Nemotron-H's scan
-and of Qwen3-Next's delta rule): K taps a channel, a bias, the SiLU. One
+*The convolution* (``segment_conv1d``; in front of Nemotron-H's and
+Falcon-H1's scans and of Qwen3-Next's and Kimi-Linear's delta rules): K taps a channel, a bias, the SiLU. One
 kernel reads the activations once in their own dtype, forms the taps,
 the bias and the activation in float32 in VMEM and writes once, each of
 the arrays its caller takes (``split``: Nemotron-H's xs, B and C;
@@ -100,13 +100,32 @@ form: the vector unit's registers spill). A call a part, each over its
 own channels of ``x``, ran 0.345 | 1.050 at 8 x 1,024 (the store behind
 a branch on the part costs the body its straight line) and set a stage
 up 3 s slower, warm, for 1.5 s as one call: a stage's set-up grows with
-the kernel bodies its programs hold, three a block or one.
+the kernel bodies its programs hold, three a block or one. Falcon-H1's
+branch (64 rows of 5,120 channels in parts of 4,096, 512 and 512, a
+bias; my chip run, PR 53): the passes 1.932 ms, the kernel 0.346-0.347
+at 8 or 16 rows x 512 or 1,024 lanes a step (its bytes are 0.20 ms).
 
 Alone on the v5e (``scripts/ssd_sweep.py``, the device's time; my chip
 run, PR 47): Nemotron-H's block (64 rows, 64 heads of 64 in 8 groups)
 2.159 ms as XLA's fusions -> 0.893 ms, 0.758 of it the kernel and the
 rest the running sums; a lightning layer (128 rows, 32 heads of 128)
-7.291 -> 1.338 ms.
+7.291 -> 1.338 ms. Falcon-H1's branch (my chip run, PR 53: 64 rows, 32
+heads of 128 in 2 groups, N 256 — a *group* is 16 heads = 2,048 lanes,
+over ``_STEP_LANES``, so a grid step is one group: 16 heads unrolled, a
+state of 256 x 2,048 float32 = 2 MiB of scratch, ``C . B^T`` over 256
+columns, and the gated norm's mean over the step's whole 2,048 columns,
+which is why a group is not split over steps): 2.884 ms as the blocked
+``jnp`` form -> 1.292 ms, 1.180 of it the kernel (1.231 with the gated
+norm as its last lines), under the compiler's own scoped-VMEM limit of
+16 MiB, 1.0-2.7 s to compile and first run; both groups in one step
+(4,096 lanes) are refused there (17.14 MiB) and under a limit of 64 MiB
+(the sweep's ``--vmem-mib``) run 1.150 ms (-2.5%) for 5.4 s of compile:
+one group a step stays, under the compiler's own limit. The recurrence's own bytes (x, z, y in bfloat16, B, C, the steps)
+are 0.268 ms at the HBM's pace and its operations (5 P N a token and
+head) 0.218 ms at the matrix unit's: the kernel stands at 22% of that
+floor; what it spends is the two ``highest`` products of a row's state
+(``C S`` and ``B^T (x . decay)``: 2 x 256 x 128 x 2,048 multiply-adds a
+row and group in float32, six bfloat16 passes each).
 """
 
 from __future__ import annotations
@@ -131,7 +150,8 @@ KERNEL_NAME = "ssd_scan"
 #: PR 47), Nemotron-H's shapes | lightning's: 512 lanes 0.982 | 1.488 ms,
 #: 1024 0.893 | 1.338, 2048 0.868 | 1.293, 4096 0.846 | 1.272 — past 1024
 #: the kernel gains 3-5% for a compile time that doubles with the lanes
-#: (the body is unrolled a head: 0.9 s, 1.5 s, 3.2 s here)
+#: (the body is unrolled a head: 0.9 s, 1.5 s, 3.2 s here). A group
+#: wider than this (Falcon-H1's 16 heads of 128) is a step of its own
 _STEP_LANES = 1024
 
 #: the convolution's kernel in the device's trace

@@ -996,6 +996,12 @@ STAGE_COUNTERS = (
         "valid tokens / tokens shipped (rows x tokens a row) over every "
         "dispatch a stage of token rows served", _token_totals),
     StageCounter(
+        "scan_resets", "Tokens:", "tokens_", ("scan_resets",),
+        "(1,): the rows of a dispatch that open a request, where a "
+        "state-space scan zeroes its state and the convolution in front "
+        "of it its history (``ops/ssd.py``'s ``row_first``), pad rows "
+        "not counted: the requests a dispatch packed"),
+    StageCounter(
         "expert_served", "Experts:", "experts_",
         ("assignments", "held", "max_per_expert", "mean_per_expert"),
         "(expert layers, held): the assignments each held expert "

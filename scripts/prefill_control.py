@@ -28,7 +28,9 @@ whose delta rule is gated a channel and whose latent attention turns
 nothing (``kimi_linear``) has an arm for each: a head's mean ``log alpha``
 in the place of its channels', and rotary turned on; both must fail, as
 must float8, and the carried states through bfloat16 are recorded
-whether they do or not (the family file's ``CONTROL_MAY_PASS``).
+whether they do or not (the family file's ``CONTROL_MAY_PASS``;
+``falcon_h1`` records its scan's states through bfloat16 the same way
+and holds float8 to a failure).
 """
 
 import argparse
@@ -56,7 +58,7 @@ def arms_of(family: str):
         return [("as_stated", {}, None),
                 ("experts_float8", {}, lambda group, name: name in _FFN),
                 ("layers_float8", {}, lambda group, name: True)]
-    if family in ("minicpm_sala", "qwen3_next"):
+    if family in ("minicpm_sala", "qwen3_next", "falcon_h1"):
         return [("as_stated", {}, None),
                 ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None),
                 ("layers_float8", {}, lambda group, name: True)]
@@ -123,7 +125,7 @@ def main(argv=None) -> int:
     ref_model = reference.Reference(published)
     out = {"device": device.device_kind, "family": name, "limit": limit,
            "rows": rows, "lengths": [len(p) for p in prompts]}
-    arms = arms_of(name)
+    arms, kept = arms_of(name), None
     for arm, kwargs, rounded in arms:
         if rounded is not None:
             for group, block in params.items():
@@ -138,9 +140,13 @@ def main(argv=None) -> int:
                 interpret=device.platform != "tpu", **kwargs))(
             params, slots, tokens, meta)
         chosen = jax.tree.map(np.asarray, chosen)
+        # a family that chooses nothing is given nothing: its reference
+        # is the same for every arm, and is computed once
+        again = kept is None or any(
+            leaf.size for leaf in jax.tree.leaves(chosen))
         want, short, key_short = [], 0.0, None
         with jax.default_matmul_precision("highest"):
-            for prompt, first in zip(prompts, offsets):
+            for prompt, first in zip(prompts, offsets) if again else ():
                 # the request's own choices, as a sample keeps them
                 # (models/token_stages.py) and the run's check reads them
                 keep = getattr(network, "request_choices", None)
@@ -169,10 +175,17 @@ def main(argv=None) -> int:
                         key_short or 0.0,
                         float("inf") if strays else 0.0,
                         float(np.asarray(ref["key_shortfall"]).max()))
-        verdict = compare(np.asarray(logits)[:len(prompts)],
-                          np.stack(want), limit)
+        kept = np.stack(want) if again else kept
+        got, want = np.asarray(logits)[:len(prompts)], kept
+        # a family that holds the logits to more than the one limit
+        # brings its comparison (``falcon_h1``'s root mean square)
+        verdict = family.compare_logits(config, got, want) \
+            if hasattr(family, "compare_logits") \
+            else compare(got, want, limit)
         out[arm] = {"share_of_spread": verdict["share_of_spread"],
                     "ok": verdict["ok"], "route_shortfall_max": short}
+        if "rms_share_of_spread" in verdict:
+            out[arm]["rms_share_of_spread"] = verdict["rms_share_of_spread"]
         if key_short is not None:
             out[arm]["key_shortfall_max"] = key_short
             out[arm]["ok"] = bool(verdict["ok"]

@@ -1,7 +1,11 @@
-"""``rnb_tpu.ops.ssd.ssd_scan`` alone, on the chip, at both callers'
+"""``rnb_tpu.ops.ssd.ssd_scan`` alone, on the chip, at its callers'
 shapes (Nemotron-H's M block: 64 rows, 64 heads of 64 in 8 groups, N
 128, steps and a skip term; MiniCPM-SALA's lightning layer: 128 rows, 32
-heads of 128, a group a head, unit steps): a check of the kernel against
+heads of 128, a group a head, unit steps; Falcon-H1's state-space
+branch: 64 rows, 32 heads of 128 in 2 groups, N 256, steps and a skip
+term — a group is 2,048 lanes, so a step is one group whatever
+``_STEP_LANES`` says up to 2,048, and both at 4,096, which wants
+``--vmem-mib``): a check of the kernel against
 the blocked ``jax.numpy`` form it replaced (``tests/test_ssd_kernel.py``
 keeps it) on a pool of three requests and a pad row, then the time of
 that form and of the kernel at each count of lanes a grid step
@@ -10,10 +14,15 @@ the kernel's last lines: the device's own time from a profiler trace of
 ``REPEATS`` calls (the kernel's custom call, and every operation of the
 jitted scan: the running sums and their transposes are XLA's), and the
 host's clock around the calls, which at these sizes is mostly the launch.
-Then ``rnb_tpu.ops.ssd.segment_conv1d`` alone at its two callers' shapes
+Every kernel line says the scan's least time by its bytes and by its
+operations (``floor_ms``: the recurrence's own 5 P N a token and head, x,
+z and y in bfloat16, B, C and the steps once — what
+``benchmarks/families/falcon_h1.py`` counts for ``scan``).
+Then ``rnb_tpu.ops.ssd.segment_conv1d`` alone at its callers' shapes
 (Nemotron-H's M block: 64 rows of 6,144 channels, a bias, xs, B and C
 as three bfloat16 arrays; Qwen3-Next's DeltaNet layer: 128 rows of
-8,192, no bias, q with k in float32 and v in bfloat16; both four taps
+8,192, no bias, q with k in float32 and v in bfloat16; Falcon-H1's 64
+rows of 5,120, a bias, parts of 4,096, 512 and 512; all four taps
 and the SiLU): the ``jax.numpy`` passes it replaced
 (``tests/test_segment_conv.py`` keeps them) with the caller's SiLU,
 slices and rounding behind them, against the kernel at each count of
@@ -21,18 +30,20 @@ rows and of lanes a grid step and of lanes the body holds at once (``ssd._CONV_R
 Lines go to stdout and to ``chiprun_out/ssd_sweep/sweep.jsonl``.
 
     chiprun -- python3 scripts/ssd_sweep.py [--only=scan|conv] [--rows=N]
-        [--lanes=512,1024] [--conv-rows=4,8] [--conv-lanes=512,1024]
-        [--conv-chunk=128,256]
+        [--callers=falcon_h1] [--lanes=512,1024] [--vmem-mib=64]
+        [--conv-rows=4,8] [--conv-lanes=512,1024] [--conv-chunk=128,256]
 
 Off the TPU the kernel runs in Pallas's interpret mode, which at these
 sizes is of no use (``--rows=4`` is a dry run of the control flow).
 """
+import functools
 import itertools
 import json
 import os
 import sys
 import tempfile
 import time
+import types
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -42,7 +53,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks import xplane  # noqa: E402
+from benchmarks import peaks, xplane  # noqa: E402
 from rnb_tpu.ops import ssd  # noqa: E402
 
 OUT = os.path.join(REPO, "chiprun_out", "ssd_sweep")
@@ -59,8 +70,22 @@ def option(name, default):
 
 #: caller -> (rows, heads, groups, P, N, steps and a skip term?)
 CALLERS = {"nemotron_h": (64, 64, 8, 64, 128, True),
-           "lightning": (128, 32, 32, 128, 128, False)}
+           "lightning": (128, 32, 32, 128, 128, False),
+           "falcon_h1": (64, 32, 2, 128, 256, True)}
+ONLY_CALLERS = [c for c in option("--callers", "").split(",") if c]
 LANES = [int(v) for v in option("--lanes", "512,1024,2048,4096").split(",")]
+#: the scoped-VMEM limit of this sweep's kernels, MiB (0: the compiler's
+#: own, 16 MiB on the v5e, under which every caller runs): no option of
+#: ``ops/ssd.py`` — the sweep wraps the ``CompilerParams`` that module
+#: builds, to try a step the default refuses
+VMEM_MIB = int(option("--vmem-mib", "0"))
+if VMEM_MIB:
+    ssd.pltpu = types.SimpleNamespace(**dict(
+        vars(ssd.pltpu), CompilerParams=functools.partial(
+            ssd.pltpu.CompilerParams,
+            vmem_limit_bytes=VMEM_MIB * 2 ** 20)))
+#: the v5e's published peaks: a floor is a statement about that chip
+V5E = peaks.peak_for("TPU v5 lite")
 
 CONV_ROWS = [int(v) for v in option("--conv-rows", "1,2,4,8,16").split(",")]
 CONV_LANES = [int(v) for v in
@@ -133,6 +158,8 @@ def conv_sweep():
     from test_segment_conv import CALLERS as channels_of, REAL_ROWS, \
         SPLITS, passes
     for caller, rows in REAL_ROWS.items():
+        if ONLY_CALLERS and caller not in ONLY_CALLERS:
+            continue
         channels, biased, _ = channels_of[caller]
         parts = SPLITS[caller][2]
         rows = int(option("--rows", rows))
@@ -194,10 +221,24 @@ def main():
         conv_sweep()
 
 
+def scan_floor_ms(shape, rows):
+    """The least time of one call by the recurrence's own operations and
+    bytes: (by operations, by bytes), ms."""
+    _, heads, groups, p, n, full = shape
+    tokens = rows * QLEN
+    ops = tokens * heads * (5 * p * n + 2 * p)
+    nbytes = tokens * (2 * (2 + full) * heads * p + 2 * 2 * groups * n
+                       + 4 * heads * full)
+    return (round(1e3 * ops / V5E["bf16_flops_per_s"], 4),
+            round(1e3 * nbytes / V5E["hbm_bytes_per_s"], 4))
+
+
 def scan_sweep():
     from test_ssd_kernel import blocked
     chosen = ssd._STEP_LANES
     for caller, shape in CALLERS.items():
+        if ONLY_CALLERS and caller not in ONLY_CALLERS:
+            continue
         rows = int(option("--rows", shape[0]))
         args = operands(caller, rows, 47)
         want, times = timed(scan_of(caller, blocked), *args)
@@ -205,15 +246,26 @@ def scan_sweep():
         want = np.asarray(want)
         for lanes in LANES:
             ssd._STEP_LANES = lanes
-            got, times = timed(
-                scan_of(caller, ssd.ssd_scan, interpret=INTERPRET), *args)
+            t0 = time.perf_counter()
+            try:
+                got, times = timed(
+                    scan_of(caller, ssd.ssd_scan, interpret=INTERPRET),
+                    *args)
+            except Exception as e:   # a step the compiler refuses
+                say({"caller": caller, "rows": rows, "form": "kernel",
+                     "step_lanes": lanes, "vmem_mib": VMEM_MIB,
+                     "refused": str(e)[-300:]})
+                continue
             worst = float(np.abs(np.asarray(got) - want).max()
                           / (1.0 + np.abs(want).max()))
             say({"caller": caller, "rows": rows, "form": "kernel",
-                 "step_lanes": lanes,
+                 "step_lanes": lanes, "vmem_mib": VMEM_MIB,
                  "groups_a_step": ssd._groups_a_step(shape[2],
                                                      shape[1] // shape[2],
                                                      shape[3]),
+                 "first_call_and_trace_s": round(time.perf_counter() - t0,
+                                                 1),
+                 "floor_ms": scan_floor_ms(shape, rows),
                  **times, "worst_vs_blocked": worst})
             assert worst < 5e-3, worst
         ssd._STEP_LANES = chosen
@@ -228,6 +280,7 @@ def scan_sweep():
                                      gated_norm=gated_norm), *args)
             say({"caller": caller, "rows": rows,
                  "form": "kernel with the gated norm", "step_lanes": chosen,
+                 "vmem_mib": VMEM_MIB, "floor_ms": scan_floor_ms(shape, rows),
                  **times})
 
 

@@ -116,10 +116,10 @@ LISTED = (
     "hbm_peak_gib")
 
 
-def test_the_accepted_readers_list_the_cell_last():
+def test_the_accepted_readers_list_the_cell():
     by_name = {m["name"]: m for m in mm.load()["per_layer"]}
     for name in LISTED:
-        assert by_name[name + ".bulk"]["workloads"][-1] == CELL, name
+        assert CELL in by_name[name + ".bulk"]["workloads"], name
     # (PR 51's set-up metrics list every cell and move `setup_s`)
     listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
               and m["moves"] == "videos_per_s"}

@@ -108,6 +108,9 @@ FORMS = {
     "gqa": dict(hq=32, hk=2, dim=32, dim_v=32),
     # latent attention expanded: 192 / 128, one query head a key head
     "mla": dict(hq=2, hk=2, dim=192, dim_v=128),
+    # five query heads a key-value head (Falcon-H1's 20 / 4): no power
+    # of two
+    "gqa_five": dict(hq=10, hk=2, dim=32, dim_v=32),
 }
 
 
@@ -172,11 +175,13 @@ def test_the_kernel_under_the_table_equals_every_causal_tile_bit_for_bit(
     assert (np.abs(got - want) <= 2.0 ** -7 * np.abs(want) + 2.0 ** -9).all()
 
 
-#: the two callers' real head counts and widths, over a pool of two
-#: blocks: Nemotron-H's grouped queries, DeepSeek-V2's latent attention
+#: the callers' real head counts and widths, over a pool of two blocks:
+#: Nemotron-H's grouped queries, DeepSeek-V2's latent attention,
+#: Falcon-H1's five query heads a key-value head
 ENTRIES = {
     "gqa-32-over-2-of-128": dict(hq=32, hk=2, dim=128, dim_v=128),
     "mla-128-of-192-and-128": dict(hq=128, hk=128, dim=192, dim_v=128),
+    "gqa-20-over-4-of-128": dict(hq=20, hk=4, dim=128, dim_v=128),
 }
 
 

@@ -32,7 +32,8 @@ POOLS = {
 #: caller -> (channels, a bias?, the dtype the kernel writes: the
 #: activations' (None) or float32)
 CALLERS = {"nemotron_h": (6144, True, None),
-           "qwen3_next": (8192, False, "float32")}
+           "qwen3_next": (8192, False, "float32"),
+           "falcon_h1": (5120, True, None)}
 
 
 def first_of(pool):
@@ -111,8 +112,8 @@ def close(got, want, out_dtype):
 @pytest.mark.parametrize("pool", sorted(POOLS))
 @pytest.mark.parametrize("caller", sorted(CALLERS))
 def test_the_kernel_is_the_passes_it_replaced(caller, pool, dtype):
-    """Both callers' forms at their channel counts (twelve and sixteen lane
-    tiles of 512): the history is zero at ``row_first`` and nowhere
+    """The callers' forms at their channel counts (twelve, sixteen and
+    ten lane tiles of 512): the history is zero at ``row_first`` and nowhere
     else, whether the row lies first in the pool, inside a grid step or
     first in a step, and a pad row after a pad row reads nothing."""
     channels, biased, out_dtype = CALLERS[caller]
@@ -166,6 +167,8 @@ SPLITS = {
     "nemotron_h": (6144, 16, ((4096, "bfloat16"), (1024, "bfloat16"),
                               (1024, "bfloat16"))),
     "qwen3_next": (8192, 16, ((4096, "float32"), (4096, "bfloat16"))),
+    "falcon_h1": (5120, 16, ((4096, "bfloat16"), (512, "bfloat16"),
+                             (512, "bfloat16"))),
     "toy_nemotron_h": (128, 16, ((64, "bfloat16"), (32, "bfloat16"),
                                  (32, "bfloat16"))),
     "toy_qwen3_next": (128, 32, ((64, "float32"), (64, "bfloat16"))),
@@ -258,7 +261,7 @@ def one_chip():
 
 
 #: caller -> the rows of its largest dispatch
-REAL_ROWS = {"nemotron_h": 64, "qwen3_next": 128}
+REAL_ROWS = {"nemotron_h": 64, "qwen3_next": 128, "falcon_h1": 64}
 
 
 @pytest.mark.parametrize("caller", sorted(CALLERS))

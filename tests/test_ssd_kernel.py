@@ -5,9 +5,10 @@ eight heads of 64 a group; lightning's: unit steps, a group a head of
 128), pools whose requests span rows, share a pool and are parted by a
 pad row, packing, the carried state's precision, the blocked
 ``jax.numpy`` form the kernel replaced (kept here as an oracle), and
-the M block's gated norm as the kernel's last lines. Then,
-where a v5e can be described, the compile of the kernel at both
-callers' real shapes (the topology inside a fixture)."""
+the M block's gated norm as the kernel's last lines, and Falcon-H1's
+shape (32 heads of 128 in 2 groups, a state of 256). Then, where a v5e
+can be described, the compile of the kernel at the callers' real shapes
+(the topology inside a fixture)."""
 
 import os
 import sys
@@ -24,6 +25,10 @@ Q, N = 16, 16
 FORMS = {"mamba": (8, 1, 64, True), "mamba_two_groups": (16, 2, 64, True),
          "lightning": (2, 2, 128, False), "narrow_heads": (8, 2, 8, True)}
 
+#: Falcon-H1's own shape, (heads, groups, P, N): a group is 16 heads of
+#: 128 = 2,048 lanes, one group a grid step, a state of 256 x 2,048
+FALCON_H1 = (32, 2, 128, 256)
+
 #: name -> the rows that open a request (a pad row opens its own)
 POOLS = {"one_row": [1], "a_request_over_rows": [1, 0, 0, 0],
          "two_requests": [1, 0, 0, 1, 0], "a_pad_row_between": [1, 0, 1, 1, 0],
@@ -33,11 +38,14 @@ POOLS = {"one_row": [1], "a_request_over_rows": [1, 0, 0, 0],
 def inputs(form, rows, seed=0, dtype="bfloat16"):
     """-> (xs, dt | None, a, b, c, d | None) as ``ssd_scan`` takes them."""
     import jax.numpy as jnp
-    heads, groups, p, full = FORMS[form]
+    if form == "falcon_h1":
+        (heads, groups, p, n), full = FALCON_H1, True
+    else:
+        (heads, groups, p, full), n = FORMS[form], N
     rng = np.random.default_rng(seed)
     xs, b, c = (jnp.asarray(rng.standard_normal(shape), dtype)
-                for shape in ((rows, Q, heads, p), (rows, Q, groups, N),
-                              (rows, Q, groups, N)))
+                for shape in ((rows, Q, heads, p), (rows, Q, groups, n),
+                              (rows, Q, groups, n)))
     a = jnp.asarray(-rng.uniform(0.05, 1.0, heads), jnp.float32)
     if not full:
         return xs, None, a, b, c, None
@@ -152,6 +160,45 @@ def test_the_kernel_is_the_recurrence(form, pool):
     got = scan(args, first)
     assert got.dtype == np.float32 and got.shape == args[0].shape
     assert worst(got, recurrence(*args, first)) < 2e-2
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated_norm"])
+@pytest.mark.parametrize("pool", ["a_request_over_rows", "a_pad_row_between"])
+def test_falcon_h1s_shape_is_the_recurrence(pool, gated):
+    """32 heads of 128 in 2 groups at a state of 256: a grid step is one
+    group's 16 heads (``_groups_a_step``: a group is wider than
+    ``_STEP_LANES``), its state 256 x 2,048 — against the recurrence
+    token by token, without the gated norm and with it (the mean over a
+    group's 2,048 columns)."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import ssd
+    heads, groups, p, n = FALCON_H1
+    assert ssd._groups_a_step(groups, heads // groups, p) == 1
+    assert ssd._lane_tile(heads // groups, p) == (1, 128)
+    first = POOLS[pool]
+    args = inputs("falcon_h1", len(first), seed=53)
+    assert args[3].shape == (len(first), Q, groups, n)
+    want = recurrence(*args, first)
+    if not gated:
+        got = scan(args, first)
+        assert got.dtype == np.float32
+        assert worst(got, want) < 2e-2
+        return
+    rows, width = len(first), heads * p
+    rng = np.random.default_rng(2)
+    z = jnp.asarray(rng.standard_normal((rows, Q, width)), jnp.float32)
+    weight = jnp.asarray(rng.uniform(0.5, 1.5, width), jnp.bfloat16)
+    got = scan(args, first, gated_norm=(z, weight, 1e-5))
+    assert got.dtype == args[0].dtype
+    g = (want.reshape(rows, Q, width) * np.asarray(jax.nn.silu(z))) \
+        .reshape(rows, Q, groups, -1)
+    g = g / np.sqrt(np.mean(g * g, -1, keepdims=True) + 1e-5)
+    want = g.reshape(rows, Q, width) * np.asarray(weight.astype(jnp.float32))
+    got = np.asarray(got.astype(jnp.float32)).reshape(want.shape)
+    # the within-row scores' rounding to bfloat16 and the result's own
+    assert np.abs(got - want).max() < 3e-2 * np.abs(want).max()
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))
@@ -276,7 +323,8 @@ def one_chip():
 
 #: name -> (rows, Q, heads, groups, P, N, steps and skip term?)
 REAL = {"nemotron_h": (64, 128, 64, 8, 64, 128, True),
-        "lightning": (128, 128, 32, 32, 128, 128, False)}
+        "lightning": (128, 128, 32, 32, 128, 128, False),
+        "falcon_h1": (64, 128) + FALCON_H1[:3] + (256, True)}
 
 
 @pytest.mark.parametrize("caller", sorted(REAL))

@@ -21,7 +21,7 @@ from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAGE_COUNTERS,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
-            "exaone_moe", "keye_vl2")
+            "exaone_moe", "keye_vl2", "falcon_h1")
 
 #: what the dispatches of one stage summed to, as ``network.forward``
 #: hands each counter back: the layers that count first
@@ -34,6 +34,7 @@ RAW = {
     "gmm_rows": [128, 256],
     "sparse": [[20, 12, 90, 60], [20, 8, 70, 50]],
     "index_tiles": [[3, 4], [2, 4]],
+    "scan_resets": [21],
 }
 
 TOKENS = "Tokens: valid=10 shipped=16"
@@ -55,6 +56,7 @@ GOLDEN = {
     "keye_vl2": [TOKENS, EXPERTS + " gmm_rows=384",
                  "Sparse: queries=40 selecting=20 causal_keys=160 "
                  "chosen_keys=110 tiles_chosen=5 tiles_causal=8"],
+    "falcon_h1": [TOKENS + " scan_resets=21", ATTENTION],
 }
 
 
@@ -102,7 +104,8 @@ def test_a_familys_counters_give_the_lines_the_launcher_wrote(family,
 
 
 @pytest.mark.parametrize("line,keys", [
-    (TOKENS, {"tokens_valid": 10, "tokens_shipped": 16}),
+    (TOKENS + " scan_resets=21",
+     {"tokens_valid": 10, "tokens_shipped": 16, "tokens_scan_resets": 21}),
     (EXPERTS + " group_tokens=13 pair_rows_moved=22 pair_rows_all=80 "
                "gmm_rows=384",
      {"experts_assignments": 60, "experts_held": 17,
