@@ -25,7 +25,8 @@ bfloat16 operands, over the keys of t's request at or before it. A
 query reads all of them while it has ``topk`` or fewer, else the
 ``topk`` with the largest ``I`` (a tie to the lower key), the same set
 for every head (``ops/indexed.py``: scores as sort keys, a threshold a
-query, a flash kernel under the sets; exact).
+query, a flash kernel under the sets that holds all the heads of a
+(query tile, key tile) pair in a step; exact).
 
 *Experts*: a softmax router over all the model's experts in float32,
 the ``num_experts_per_tok`` largest renormalised (``ops/moe.route``),
@@ -42,8 +43,10 @@ float32.
 The named scopes are ``embed``, ``attn`` (inside it ``attn/select``:
 everything that decides the sets, with ``attn/select/index`` the
 indexer's three products, its norm, rotary and scores; and
-``attn/kernel``: the attention kernel's call, which writes the sets as
-bits beside its result, and the copies that lay its operands out), ``experts`` and ``head``.
+``attn/kernel``: the attention kernel's call alone, from q as its
+product wrote it — the head norm, the rotary, the scale and the rounding
+of q are the kernel's first lines — to ``o``'s operand, with the sets
+as bits beside it), ``experts`` and ``head``.
 """
 
 from __future__ import annotations
@@ -55,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from rnb_tpu.ops import indexed, moe, rope
+from rnb_tpu.ops import banded, indexed, moe, rope
 
 #: what ``forward`` returns behind the logits and the two kinds of
 #: choice (``models/token_stages.py``); ``sparse``: the four of the
@@ -202,9 +205,10 @@ def recency_keys(start):
     return jnp.where(mine, at[None, :], indexed.LOWEST)
 
 
-def attention_mixer(cfg, p, h, row_start, row_tokens, positions,
+def attention_mixer(cfg, p, h, row_start, row_tokens, positions, tables,
                     interpret=False, index_bits=None, select=None):
-    """``h`` (rows, Q, hidden), normed -> (float32 (rows, Q, hidden);
+    """``h`` (rows, Q, hidden), normed; ``tables``: the dispatch's
+    ``ops/banded.band_tables`` -> (float32 (rows, Q, hidden);
     the sets as bits (T, T // 32) uint32; int32 (4,): valid queries,
     those that choose (more than ``topk`` keys to read), the keys those
     could read, the keys they chose; int32 (2,): the attention kernel's
@@ -228,21 +232,20 @@ def attention_mixer(cfg, p, h, row_start, row_tokens, positions,
                     *index_operands(cfg, p, h, positions, index_bits),
                     start, interpret)
         tau, cut = indexed.thresholds(keys, at, topk, interpret)
-    qs = rms_norm(_proj(h, p["q"]).reshape(rows, q, hq, dim), p["q_norm"],
-                  cfg.eps, jnp.float32)
+    # q goes to the kernel as its product wrote it: its norm, rotary,
+    # scale and rounding are the kernel's first lines. A key tile is
+    # read by up to 64 query tiles, so k's stay here, on an eighth of
+    # q's columns
+    qs = _proj(h, p["q"]).reshape(tokens, hq * dim)
     ks = rms_norm(_proj(h, p["k"]).reshape(rows, q, hk, dim), p["k_norm"],
                   cfg.eps, jnp.float32)
-    # the scores' scale goes onto the float32 queries, before their one
-    # rounding to the activations' dtype
-    qs = (rope.rotate(qs, positions, cfg.inv_freq()) * dim ** -0.5) \
-        .astype(act)
-    ks = rope.rotate(ks, positions, cfg.inv_freq()).astype(act)
-    vs = _proj(h, p["v"]).astype(act)
+    ks = rope.rotate(ks, positions, cfg.inv_freq()).astype(act) \
+        .reshape(tokens, hk * dim)
+    vs = _proj(h, p["v"]).astype(act).reshape(tokens, hk * dim)
     with jax.named_scope("kernel"):
-        out, sets = indexed.masked_attention(
-            qs.reshape(tokens, hk, hq // hk, dim),
-            ks.reshape(tokens, hk, dim), vs.reshape(tokens, hk, dim),
-            keys, tau, cut, start, interpret)
+        out, sets = indexed.indexed_attention(
+            qs, ks, vs, keys, tau, cut, p["q_norm"], tables, cfg.eps,
+            interpret)
     chose, reached = indexed.count_sets(
         sets, indexed.attention_tiles(tokens)[0])
     chooses = valid & (at + 1 > cfg.topk)
@@ -308,6 +311,8 @@ def forward(cfg: KeyeVL2Config, params, slots, tokens, row_tokens,
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
     positions = rope.pool_positions(row_start, q)
+    # what the attention kernel turns q by, the same in every layer
+    tables = banded.band_tables(row_start, q, cfg.inv_freq())
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
@@ -318,8 +323,8 @@ def forward(cfg: KeyeVL2Config, params, slots, tokens, row_tokens,
         with jax.named_scope("attn"):
             h = rms_norm(x, p["attn_norm"], cfg.eps, act)
             out, sets, counts, ran = attention_mixer(
-                cfg, p, h, row_start, row_tokens, positions, interpret,
-                index_bits, select)
+                cfg, p, h, row_start, row_tokens, positions, tables,
+                interpret, index_bits, select)
             x = (x.astype(jnp.float32) + out).astype(act)
             key_sets.append(sets)
             sparse.append(counts)

@@ -36,25 +36,99 @@ or (key[t, s] == tau[t] and s <= cut[t])}`` among the keys it may read:
 exactly ``min(t + 1, topk)`` of them. A query with ``topk`` keys or
 fewer has ``tau`` the smallest int32: everything.
 
-**Attention** (:func:`masked_attention`, the kernel
-``indexed_attention``) is a flash kernel: a tile of queries times the
-query heads of one key-value head against a tile of keys, scores and
-running maximum and sum in VMEM in float32; the tile's mask is three
-integer comparisons of the sort keys with ``tau`` and ``cut``, the same
-for every head. A query tile walks the key tiles from its first
-request's first to the diagonal's (two numbers a query tile in scalar
-memory); inside them it skips nothing: under seeded random weights a
-query's 2,048 keys lie all over its request and no causal tile of a
-request is ever free of them (:func:`count_sets` counts the tiles that
-hold a chosen key, for the day that changes).
+**Attention** (:func:`indexed_attention`, the kernel of that name) is a
+flash kernel from q's product to ``o``'s operand. A grid step is a
+(query tile, key tile) pair for *all* the heads: it reads q as the
+``(tile_q, Hq D)`` block of the float32 array the layer's first product
+wrote, k and v as ``(tile_k, Hk D)`` blocks in the activations' dtype
+(k normed and turned by XLA, on an eighth of q's columns: a key tile is
+read by up to 64 query tiles, and its first lines would be redone at
+each), the pair's tile of sort keys, and writes the ``(tile_q, Hq D)``
+block the last product reads. Between the two products no array with a
+head axis exists in HBM. *At a query tile's first key step* each head's
+``(tile_q, D)`` slice goes through the head's RMS norm, the rotary (``x
+cos + roll(x, D / 2) sin`` under ``ops/banded.band_tables``' tables, a
+dispatch's), the scores' scale and one rounding — ``ops/banded.py``'s
+``_first_lines``, the same float32 operations in the same order as
+``rms_norm`` + ``ops/rope.rotate`` + the scale + the cast — into VMEM
+scratch, a head in front, for the tile's key steps. *At every step* the
+mask is built once — three integer comparisons of the sort keys with
+``tau`` and ``cut``, the same for every head —, the sets' bits are
+written from it, and it becomes an additive float32 tile (0 or -1e30:
+the sum is the ``where``'s value to the bit); then the eight query
+heads of a key-value head, their rows under each other, take one
+product for the scores against the head's ``(tile_k, D)`` lanes of the
+block, the tile added under each head by a leading axis (nothing is
+concatenated), the running maximum and sum in float32, the
+probabilities rounded to the activations' dtype into the values'
+product. The running maximum and sum lie in scratch *under each of the
+D lanes* (``(Hq, tile_q, D)``: a row's number 128 times), so that ``s -
+m`` is ``pltpu.repeat`` and a plain subtraction and ``alpha acc`` a
+plain product: as a ``(tile_q, 1)`` column, the form the kernel had
+until PR 54, the maximum's way from the lane reduction into the scores'
+subtraction was half the kernel's time (the sweep below). A query tile
+walks the key tiles from its first request's first to the diagonal's
+(two numbers a query tile in scalar memory); inside them it skips
+nothing: under seeded random weights a query's 2,048 keys lie all over
+its request and no causal tile of a request is ever free of them
+(:func:`count_sets` counts the tiles that hold a chosen key, for the
+day that changes).
 
 **What a sample keeps** is the kernel's second result: the sets as bits,
 (T, keys a tile) uint32, key tile b being bit b of a word — bit b of
 word w of query t stands for key ``b * (keys a tile) + w`` — written
-once a query tile while the first head group walks its key tiles (two
-operations an element and no pass of its own: as two passes of XLA's
-over the matrix, bits and tiles took 6.3 ms a layer, my chip runs, PR
-46); :func:`unpack_sets` is its inverse on the host.
+once a step from the step's one mask (two operations an element and no
+pass of its own: as two passes of XLA's over the matrix, bits and tiles
+took 6.3 ms a layer, my chip runs, PR 46); :func:`unpack_sets` is its
+inverse on the host.
+
+**What the attention kernel replaced** (PR 54). From PR 46 to PR 53 a
+step held *one* key-value head (grid (query tile, 4, key tile)): it
+read the same tile of sort keys and built the same mask for each of the
+four, laid the mask under itself eight times (``concatenate``) for the
+``where`` over the (8 x 256, 512) scores, and kept maximum and sum as
+(2,048, 1) columns. Around it the mixer made float32 passes over q
+(``rms_norm``, ``rope.rotate`` with a half materialised and
+concatenated, the scale, the cast), a copy into (key-value head, query
+tile, (head, query), D) and a copy back (``tests/keye_parent.py`` keeps
+that form for the bit-for-bit tests).
+
+**The sweep** (my chip runs, PR 54; one TPU v5 lite, 128 rows of 128
+tokens as one / two / three requests, 32 / 4 heads of 128;
+``scripts/indexed_sweep.py``; ms a layer, by the host's clock around a
+jitted call). *The parent*: its kernel alone **25.7 / 15.1 / 12.1**
+(PR 46 read 25.4 / 15.1 / 12.1), the passes around it alone, as XLA
+runs them, 7.6, both in one program 31.2 / 21.0 / 17.8. *This kernel's
+first form* — all heads a step, one mask, q's first lines inside, but
+maximum and sum still columns — read 29.5 / 16.6 / 12.8: a step of 32
+heads took 25.7 us where the parent's four took 21 (fitted over the
+three pools: the fixed part fell from 4.5 ms to 2.7). A probe with a
+line left out (wrong results, times only; one request; not in the tree)
+said why: without the first lines 29.0, without the mask's sum 29.3,
+without the sets' bits 29.4 — the mask and its copies had been no
+cost worth the name — but without the row maximum **15.3**, with the
+maximum computed and *not subtracted* 17.7, without the row sum 23.7;
+with no softmax line at all 15.0, and with no product either 6.7. The
+same kernel with the two statistics under every lane: **16.6**, the
+same bits; that is the form that stands. On it (the forms beside
+the standing one are in this record alone, not in the tree): the heads
+of a key-value head as one product, which stands, 16.3 / 9.7 / 7.9, two heads a
+product 16.4 / 9.9 / 7.9, a head a product 16.7 / 9.9 / 8.1 (18.5 /
+10.0 / 8.0 at a second reading in the same call; it lowers in 0.55 s a
+row bucket for 0.30 s, five buckets a run); the mask as a
+``where`` under a broadcast in the place of the sum 16.4 / 9.8 / 7.9
+(at a head a product 16.6 / 10.0 / 8.0); k handed over transposed once
+in front 16.4 / 9.8 / 7.9 (16.8 / 9.9 / 8.1); the heads' products in a
+loop the compiler keeps rolled 20.5 / 12.1 / 9.5 (two heads a turn 18.5
+/ 11.0 / 8.8; compiled here for a described v5e it takes 2.6 s for
+10.7); 128 queries a tile 17.0 /
+10.5 / 8.6 with eight heads a product and 22.9 / 13.5 / 10.7 with one
+(17.3 with the heads' lines staged, all scores, then all softmaxes, then
+all values' products: at 256 queries that order reads 16.4 for 16.6);
+512 queries a tile does not fit VMEM. Every form gives the parent's
+values and sets to the last bit, compiled as interpreted. The step's
+two products are 11.5 ms a layer of one request at the matrix unit's
+peak: the kernel stands at 71% of it.
 """
 
 from __future__ import annotations
@@ -68,14 +142,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from rnb_tpu.ops.banded import _first_lines
+
 #: the indexer's scores: queries and keys a tile
 _SCORE_TILE_Q, _SCORE_TILE_K = 512, 1024
 #: the thresholds: queries a step (their whole rows of sort keys lie in
 #: VMEM: 2 MiB at 16,384 keys), and the keys a count looks at together
 #: (a chunk wholly over the step's diagonal is not counted)
 _SELECT_TILE_Q, _SELECT_CHUNK = 32, 2048
-#: the attention kernel: queries and keys a tile (the query tile times
-#: the heads of a group is the matrix's rows: 2,048 at 8 heads)
+#: the attention kernel: queries and keys a tile
 _TILE_Q, _TILE_K = 256, 512
 _MASKED = -1e30
 LOWEST = np.iinfo(np.int32).min
@@ -321,67 +396,12 @@ def count_sets(packed, tile_q: int):
 # -- attention under the sets ---------------------------------------------
 
 
-def _attention_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, keys_ref, tau_ref,
-                      cut_ref, start_ref, o_ref, sets_ref, m_ref, l_ref,
-                      acc_ref, *, per: int):
-    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    steps = pl.num_programs(2)
-    tile_q, tile_k = keys_ref.shape
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when((j == 0) & (g == 0))
-    def _():
-        sets_ref[...] = jnp.zeros(sets_ref.shape, jnp.int32)
-
-    # a tile that holds a pair a query may read: from its first
-    # request's first key block to the diagonal's
-    @pl.when((j >= lo_ref[i]) & (j <= hi_ref[i]))
-    def _():
-        s = lax.dot_general(q_ref[0, 0], k_ref[0],
-                            (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        keys, tau = keys_ref[...], tau_ref[...]
-        q_at = i * tile_q + lax.broadcasted_iota(
-            jnp.int32, (tile_q, tile_k), 0)
-        k_at = j * tile_k + lax.broadcasted_iota(
-            jnp.int32, (tile_q, tile_k), 1)
-        chosen = ((keys > tau) | ((keys == tau) & (k_at <= cut_ref[...]))) \
-            & (k_at <= q_at) & (k_at >= start_ref[...])
-
-        # the sets as bits, once (the heads share them): key tile j is
-        # bit j of a word
-        @pl.when(g == 0)
-        def _():
-            sets_ref[...] = sets_ref[...] | (chosen.astype(jnp.int32) << j)
-        # rows are (head, query): the same set for every head
-        s = jnp.where(jnp.concatenate([chosen] * per, axis=0), s, _MASKED)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # a row that has met no chosen key yet holds sums of exp(0);
-        # the first chosen key's maximum wipes them (alpha = 0)
-        p = jnp.exp(s - m_next)
-        alpha = jnp.exp(m_prev - m_next)
-        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0],
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_next
-
-    @pl.when(j == steps - 1)
-    def _():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-
 def attention_tiles(tokens: int):
     """(queries a tile, keys a tile) of the attention kernel for a pool
     of ``tokens``; a key tile is a bit of the sets' words, so a pool is
     32 of them at most."""
-    tile_q, tile_k = _tile(_TILE_Q, tokens), _tile(_TILE_K, tokens)
+    tile_q = _tile(_TILE_Q, tokens)
+    tile_k = _tile(_TILE_K, tokens)
     if tokens > 32 * tile_k:
         raise ValueError("%d tokens are more than 32 key tiles of %d"
                          % (tokens, tile_k))
@@ -396,60 +416,168 @@ def causal_tiles(tokens: int) -> int:
                 // tile_k + 1).sum())
 
 
-def masked_attention(q, k, v, keys, tau, cut, start,
-                     interpret: bool = False):
-    """``q`` (T, Hk, per, D) scaled; ``k``, ``v`` (T, Hk, D); ``keys``,
-    ``tau``, ``cut`` the sets (:func:`index_keys`, :func:`thresholds`).
-    -> ((T, Hk, per, D) in q's dtype: softmax attention of each query
-    over the keys of its set; the sets as bits (T, keys a tile) uint32,
-    bit b of word w standing for key ``b * (keys a tile) + w``:
-    :func:`unpack_sets` reads them, :func:`count_sets` counts them)."""
-    tokens, groups, per, dim = q.shape
+def _attention_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, keys_ref, tau_ref,
+                      cut_ref, start_ref, w_ref, cos_ref, sin_ref, o_ref,
+                      sets_ref, qs_ref, m_ref, l_ref, acc_ref, *, groups: int,
+                      eps: float):
+    """One (query tile, key tile) pair for every head. ``q_ref``
+    (tile_q, Hq D) float32 as its product wrote it; ``k_ref``, ``v_ref``
+    (tile_k, Hk D) in the activations' dtype; ``keys_ref`` the pair's
+    sort keys; ``tau_ref``, ``cut_ref``, ``start_ref`` (tile_q, 1);
+    ``w_ref`` (1, D) the
+    query norm's weight, ``cos_ref``, ``sin_ref`` (tile_q, D) the rotary
+    tables; ``o_ref`` as ``q_ref`` in the activations' dtype;
+    ``sets_ref`` the query tile's words. Scratch, a head in front:
+    ``qs_ref`` the queries as the products read them, ``m_ref``,
+    ``l_ref``, ``acc_ref`` the running maximum, sum and result, the
+    first two a row's number under each of the D lanes."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    heads, tile_q, dim = qs_ref.shape
+    tile_k = keys_ref.shape[1]
+    per = heads // groups
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        sets_ref[...] = jnp.zeros(sets_ref.shape, jnp.int32)
+        # a head's norm, rotary, scale and one rounding: what the mixer
+        # made five passes of, once a query tile for all its key steps
+        weight, cos, sin = w_ref[...], cos_ref[...], sin_ref[...]
+        for h in range(heads):
+            qs_ref[h] = _first_lines(q_ref[:, h * dim:(h + 1) * dim], weight,
+                                     cos, sin, eps, qs_ref.dtype, dim ** -0.5)
+
+    # a tile that holds a pair a query may read: from its first
+    # request's first key block to the diagonal's
+    @pl.when((j >= lo_ref[i]) & (j <= hi_ref[i]))
+    def _():
+        keys, tau = keys_ref[...], tau_ref[...]
+        q_at = i * tile_q + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 0)
+        k_at = j * tile_k + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 1)
+        # the pair's mask, once: every head reads the same set
+        chosen = ((keys > tau) | ((keys == tau) & (k_at <= cut_ref[...]))) \
+            & (k_at <= q_at) & (k_at >= start_ref[...])
+        # the sets as bits: key tile j is bit j of a word
+        sets_ref[...] = sets_ref[...] | (chosen.astype(jnp.int32) << j)
+        bias = jnp.where(chosen, 0.0, _MASKED)[None]
+        for g in range(groups):
+            # a key-value head's query heads, their rows under each
+            # other, in one product
+            of = pl.ds(g * per, per)
+            k = k_ref[:, g * dim:(g + 1) * dim]
+            v = v_ref[:, g * dim:(g + 1) * dim]
+            s = lax.dot_general(
+                qs_ref[of].reshape(per * tile_q, dim), k,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) \
+                .reshape(per, tile_q, tile_k) + bias
+            m_prev = m_ref[of]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # a row that has met no chosen key yet holds sums of exp(0);
+            # the first chosen key's maximum wipes them (alpha = 0)
+            p = jnp.exp(s - pltpu.repeat(m_next, tile_k // dim, 2))
+            alpha = jnp.exp(m_prev - m_next)
+            l_ref[of] = alpha * l_ref[of] + p.sum(axis=-1, keepdims=True)
+            acc_ref[of] = alpha * acc_ref[of] + jnp.dot(
+                p.astype(v.dtype).reshape(per * tile_q, tile_k), v,
+                preferred_element_type=jnp.float32) \
+                .reshape(per, tile_q, dim)
+            m_ref[of] = m_next
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        for h in range(heads):
+            o_ref[:, h * dim:(h + 1) * dim] = \
+                (acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
+
+
+def _cost(pairs: int, dim: int, arrays) -> pl.CostEstimate:
+    """What a call costs, for the compiler that schedules around it
+    (``ops/banded.py``'s ``_cost``): the two products of each of the
+    ``pairs`` (query, key, head) a step holds over the causal tiles, an
+    exponential a score, every operand's and result's bytes once (a key
+    tile's more often, which this leaves out)."""
+    return pl.CostEstimate(
+        flops=2 * 2 * pairs * dim, transcendentals=pairs,
+        bytes_accessed=sum(x.size * x.dtype.itemsize for x in arrays))
+
+
+# a function under ``jit`` of its own: a stack's layers call it with the
+# same shapes, and the kernel is traced and lowered once for all
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+def _attention_call(q, k, v, keys, tau, cut, weight, cos, sin, start, *,
+                    eps, interpret):
+    tokens, dim = cos.shape
+    heads, groups = q.shape[1] // dim, k.shape[1] // dim
     tile_q, tile_k = attention_tiles(tokens)
     nq, nk = tokens // tile_q, tokens // tile_k
-    rows = per * tile_q
-    # a query tile as one matrix, rows (head, query)
-    q_tiles = q.reshape(nq, tile_q, groups, per, dim) \
-        .transpose(2, 0, 3, 1, 4).reshape(groups, nq, rows, dim)
     # the key tiles a query tile walks: from the block that holds the
     # first key of its first query's request to the diagonal's. A step
     # outside them names the nearest of them and moves nothing
-    lo = (start[::tile_q] // tile_k).astype(jnp.int32)
+    lo = (start[::tile_q, 0] // tile_k).astype(jnp.int32)
     hi = jnp.asarray((np.arange(nq) * tile_q + tile_q - 1) // tile_k,
                      jnp.int32)
 
     def walked(j, i, lo, hi):
         return jnp.clip(j, lo[i], hi[i])
-    one = pl.BlockSpec((tile_q, 1), lambda i, g, j, *_: (i, 0))
+
+    def mine(width):
+        return pl.BlockSpec((tile_q, width), lambda i, j, *_: (i, 0))
+
+    def theirs(width):
+        return pl.BlockSpec((tile_k, width), lambda i, j, lo, hi:
+                            (walked(j, i, lo, hi), 0))
+    operands = (q, k, v, keys, tau[:, None],
+                cut[:, None], start, weight.astype(jnp.float32)[None, :],
+                cos, sin)
+    outs = (jax.ShapeDtypeStruct(q.shape, v.dtype),
+            jax.ShapeDtypeStruct((tokens, tile_k), jnp.int32))
     out, sets = pl.pallas_call(
-        functools.partial(_attention_kernel, per=per),
+        functools.partial(_attention_kernel, groups=groups, eps=eps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(nq, groups, nk),
+            num_scalar_prefetch=2, grid=(nq, nk),
             in_specs=[
-                pl.BlockSpec((1, 1, rows, dim),
-                             lambda i, g, j, *_: (g, i, 0, 0)),
-                pl.BlockSpec((1, tile_k, dim), lambda i, g, j, lo, hi:
-                             (g, walked(j, i, lo, hi), 0)),
-                pl.BlockSpec((1, tile_k, dim), lambda i, g, j, lo, hi:
-                             (g, walked(j, i, lo, hi), 0)),
-                pl.BlockSpec((tile_q, tile_k), lambda i, g, j, lo, hi:
+                mine(heads * dim), theirs(groups * dim), theirs(groups * dim),
+                pl.BlockSpec((tile_q, tile_k), lambda i, j, lo, hi:
                              (i, walked(j, i, lo, hi))),
-                one, one, one],
-            out_specs=[pl.BlockSpec((1, 1, rows, dim),
-                                    lambda i, g, j, *_: (g, i, 0, 0)),
-                       pl.BlockSpec((tile_q, tile_k),
-                                    lambda i, g, j, *_: (i, 0))],
-            scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, 1), jnp.float32),
-                            pltpu.VMEM((rows, dim), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((groups, nq, rows, dim), q.dtype),
-                   jax.ShapeDtypeStruct((tokens, tile_k), jnp.int32)],
+                mine(1), mine(1), mine(1),
+                pl.BlockSpec((1, dim), lambda i, j, *_: (0, 0)),
+                mine(dim), mine(dim)],
+            out_specs=[mine(heads * dim), mine(tile_k)],
+            scratch_shapes=[pltpu.VMEM((heads, tile_q, dim), v.dtype)]
+            + [pltpu.VMEM((heads, tile_q, dim), jnp.float32)] * 3),
+        out_shape=outs,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
+        cost_estimate=_cost(
+            causal_tiles(tokens) * tile_q * tile_k * heads, dim,
+            operands + outs),
         interpret=interpret, name=ATTENTION_KERNEL,
-    )(lo, hi, q_tiles, k.transpose(1, 0, 2), v.transpose(1, 0, 2), keys,
-      tau[:, None], cut[:, None], start[:, None])
-    out = out.reshape(groups, nq, per, tile_q, dim) \
-        .transpose(1, 3, 0, 2, 4).reshape(tokens, groups, per, dim)
+    )(lo, hi, *operands)
     return out, lax.bitcast_convert_type(sets, jnp.uint32)
+
+
+def indexed_attention(q, k, v, keys, tau, cut, q_weight, tables, eps: float,
+                      interpret: bool = False):
+    """One layer's attention under the sets, from the products' results
+    to the last product's operand (the module's text).
+
+    ``q`` (T, Hq D) float32 as its product wrote it: the head norm
+    (``q_weight`` (D,), ``eps``), the rotary, the scale and the rounding
+    are the kernel's first lines; ``k`` normed and turned and ``v``, (T,
+    Hk D) in the activations' dtype; ``keys``, ``tau``, ``cut`` the sets
+    (:func:`index_keys`, :func:`thresholds`); ``tables``
+    ``ops/banded.band_tables``' three, a dispatch's. -> ((T, Hq D) in v's
+    dtype:
+    softmax attention of each query over the keys of its set; the sets
+    as bits (T, keys a tile) uint32, bit b of word w standing for key
+    ``b * (keys a tile) + w``: :func:`unpack_sets` reads them,
+    :func:`count_sets` counts them)."""
+    cos, sin, start = tables
+    return _attention_call(q, k, v, keys, tau, cut, q_weight, cos, sin,
+                           start, eps=float(eps), interpret=bool(interpret))

@@ -4,7 +4,10 @@ dispatches that mix requests under and over ``topk``, with a pad row; the
 sets by themselves (``ops/indexed``: exactly ``min(t + 1, topk)`` keys,
 none of the future or of another request, equal scores to the lower key,
 against a sort in NumPy) and the attention kernel under them against an
-explicit mask; a query with ``topk`` keys or fewer against plain causal
+explicit mask, at the toy's heads and at 32 / 4, and against the passes
+and the kernel it replaced (``tests/keye_parent.py``) bit for bit, in
+every form the sweep times and through the whole toy stack; a query with
+``topk`` keys or fewer against plain causal
 attention, bit for bit; the reference's multimodal rotary against
 ``ops/rope.rotate``; every expert held; the counters against a NumPy
 count; a sample's two kinds of choice and the check's refusals (a
@@ -12,9 +15,10 @@ tampered set, the float8 indexer, both attention controls, every matrix
 through float8); the stages; the operation counts against a count by
 hand; the cell through the one benchmark command; the five new readers
 on a run without their scope; the real configuration against the
-catalog's row; the kernels compiled at the published widths for a
-described v5e; and the shared code's StableHLO for the five older
-families and for this one.
+catalog's row; the mixer's ``attn/kernel`` scope and the real 128-row
+program's arrays between q's product and ``o``'s; the kernels compiled
+at the published widths for a described v5e; and the shared code's
+StableHLO for the five older families and for this one.
 Nothing here needs the native decode library or a chip."""
 
 import functools
@@ -269,55 +273,160 @@ def test_a_set_is_the_topk_keys_of_a_sort(topk):
         assert (np.asarray(cut) < tokens).any()
 
 
-@pytest.mark.parametrize("rows", [8, 64])
-def test_the_attention_kernel_reads_the_sets_alone(rows):
-    """``indexed_attention`` against softmax over an explicit mask, and
-    the sets it writes as bits beside its result against the mask: the
-    same sets, their sizes and the tiles they reach (a pool of one tile;
-    one of 8 x 4 tiles of 256 x 512, with requests that start inside
-    a key tile)."""
+def inv_freq(dim, theta=1e7):
+    return (theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)) \
+        .astype(np.float32)
+
+
+def a_pool(rows, firsts, hq, hk, dim, topk=40, qlen=32):
+    """A seeded pool of ``rows`` rows of ``qlen`` tokens, a request from
+    each of ``firsts``, with equal index scores planted: -> (what
+    ``indexed.indexed_attention`` takes, in its order; the explicit mask
+    of the sets; each token's index inside its request)."""
     import jax.numpy as jnp
 
-    from rnb_tpu.ops import indexed
-    rng = np.random.default_rng(rows)
-    tokens, topk = rows * 32, 40
-    firsts = [0, 3, 5, 7] if rows == 8 else [0, 27, 28]
+    from rnb_tpu.ops import banded, indexed
+    rng = np.random.default_rng(rows + hq)
+    tokens = rows * qlen
     row_start = jnp.asarray([max(f for f in firsts if f <= r)
                              for r in range(rows)], jnp.int32)
     start, _ = indexed.token_table(
-        row_start, jnp.full((rows,), 32, jnp.int32), 32)
+        row_start, jnp.full((rows,), qlen, jnp.int32), qlen)
     keys = indexed.index_keys(*planted(rng, tokens), start, interpret=True)
     position = jnp.arange(tokens, dtype=jnp.int32) - start
     tau, cut = indexed.thresholds(keys, position, topk, interpret=True)
     mask = np.asarray(indexed.chosen_mask(keys, tau, cut, start))
-    q = jnp.asarray(rng.normal(size=(tokens, 2, 2, 16)), jnp.bfloat16)
-    k, v = (jnp.asarray(rng.normal(size=(tokens, 2, 16)), jnp.bfloat16)
+    # q as its product writes it: float32, far from unit norm
+    q = jnp.asarray(rng.normal(size=(tokens, hq * dim)) * 3, jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(tokens, hk * dim)), jnp.bfloat16)
             for _ in range(2))
-    out, sets = indexed.masked_attention(q, k, v, keys, tau, cut, start,
-                                         interpret=True)
-    s = np.einsum("tgpd,sgd->tgps", np.asarray(q, np.float32),
-                  np.asarray(k, np.float32))
+    weight = jnp.asarray(1 + 0.2 * rng.normal(size=dim), jnp.bfloat16)
+    tables = banded.band_tables(row_start, qlen, inv_freq(dim))
+    return (q, k, v, keys, tau, cut, weight, tables, 1e-6, True), mask, \
+        np.asarray(position)
+
+
+#: (rows of 32 tokens, the rows that open a request, query heads,
+#: key-value heads, their width): PR 46's two pools at the toy's heads (a
+#: pool of one tile; one of 8 x 4 tiles of 256 x 512, with requests that
+#: start inside a key tile), and 32 / 4 heads over one, two and three
+#: requests with pad rows behind (a pad row is a request of its own) and
+#: at the published head width
+POOLS = {"toy_one_tile": (8, [0, 3, 5, 7], 4, 2, 16),
+         "toy_8x4_tiles": (64, [0, 27, 28], 4, 2, 16),
+         "32_4_one_request": (16, [0, 15], 32, 4, 16),
+         "32_4_two_requests": (16, [0, 9, 14, 15], 32, 4, 16),
+         "32_4_three_requests": (32, [0, 9, 21, 30, 31], 32, 4, 16),
+         "32_4_of_128": (8, [0, 5, 7], 32, 4, 128)}
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_the_attention_kernel_reads_the_sets_alone(pool):
+    """``indexed_attention`` against softmax over an explicit mask (of q
+    through the passes the kernel's first lines replaced), and the sets
+    it writes as bits beside its result against the mask: the same sets,
+    their sizes and the tiles they reach."""
+    import jax.numpy as jnp
+
+    import keye_parent
+    from rnb_tpu.ops import indexed
+    rows, firsts, hq, hk, dim = POOLS[pool]
+    operands, mask, position = a_pool(rows, firsts, hq, hk, dim)
+    q, k, v, _, _, cut, weight, tables = operands[:8]
+    tokens, topk = rows * 32, 40
+    out, sets = indexed.indexed_attention(*operands)
+    assert out.shape == (tokens, hq * dim) and out.dtype == jnp.bfloat16
+    qs = keye_parent.replaced_passes(
+        q.reshape(rows, 32, -1), weight,
+        jnp.asarray(position.reshape(rows, 32)), inv_freq(dim), 1e-6,
+        jnp.bfloat16)
+    s = np.einsum("tgpd,sgd->tgps",
+                  np.asarray(qs, np.float32).reshape(tokens, hk, -1, dim),
+                  np.asarray(k, np.float32).reshape(tokens, hk, dim))
     s = np.where(mask[:, None, None, :], s, -np.inf)
     p = np.exp(s - s.max(-1, keepdims=True))
     want = np.einsum("tgps,sgd->tgpd", p / p.sum(-1, keepdims=True),
-                     np.asarray(v, np.float32))
-    assert np.abs(np.asarray(out, np.float32) - want).max() < 0.02
+                     np.asarray(v, np.float32).reshape(tokens, hk, dim))
+    assert np.abs(np.asarray(out, np.float32)
+                  - want.reshape(tokens, hq * dim)).max() < 0.02
     tile_q, tile_k = indexed.attention_tiles(tokens)
     assert sets.shape == (tokens, tile_k) and sets.dtype == jnp.uint32
     assert (indexed.unpack_sets(sets)[:, :tokens] == mask).all()
     assert not indexed.unpack_sets(sets)[:, tokens:].any()
     chose, reached = indexed.count_sets(sets, tile_q)
-    assert (np.asarray(chose)
-            == np.minimum(np.asarray(position) + 1, topk)).all()
+    assert (np.asarray(chose) == np.minimum(position + 1, topk)).all()
     tiles = mask.reshape(tokens // tile_q, tile_q, tokens // tile_k,
                          tile_k).any(axis=(1, 3))
     assert int(reached) == int(tiles.sum())
     assert int(tiles.sum()) <= indexed.causal_tiles(tokens) \
-        == {8: 1, 64: 20}[rows]
+        == {8: 1, 16: 2, 32: 6, 64: 20}[rows]
     if rows == 64:
         # the last request's later query tiles do not reach the first
         # key tile: another request's keys
         assert not tiles[4:, 0].any() and 12 <= int(tiles.sum()) < 20
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_the_kernel_is_the_parents_passes_and_kernel_to_the_bit(pool):
+    """From q as its product wrote it to ``o``'s operand: q's first
+    lines inside the kernel are ``rms_norm`` + ``ops/rope.rotate`` + the
+    scale + the rounding, and a step's one mask under each head's own
+    scores is the parent's (a key-value head a step, the mask laid under
+    itself a head: ``tests/keye_parent.py``) — the same float32
+    operations in the same order, so the same bits in the result and in
+    the sets, on pools with equal scores at some query's cut."""
+    import jax
+
+    import keye_parent
+    from rnb_tpu.ops import indexed
+    rows, firsts, hq, hk, dim = POOLS[pool]
+    operands, _, _ = a_pool(rows, firsts, hq, hk, dim)
+    assert (np.asarray(operands[5]) < rows * 32).any()
+    # under one ``jit`` each: in one program the CPU's compiler has a
+    # multiply next to an add to contract, on both sides alike
+    q, k, v, keys, tau, cut, weight, tables = operands[:8]
+    want, want_sets = jax.jit(lambda *a: keye_parent.indexed_attention(
+        *a, tables, 1e-6, True, qlen=32, inv_freq=inv_freq(dim)))(
+        q, k, v, keys, tau, cut, weight)
+    got, sets = jax.jit(lambda *a: indexed.indexed_attention(
+        *a, tables, 1e-6, True))(q, k, v, keys, tau, cut, weight)
+    assert got.dtype == want.dtype and sets.dtype == want_sets.dtype
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(want, np.float32))
+    assert np.array_equal(np.asarray(sets), np.asarray(want_sets))
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCHES))
+def test_the_toy_stack_serves_the_parents_logits_to_the_bit(
+        toy, case, monkeypatch):
+    """The whole toy stack with PR 54's kernel and with the parent's
+    passes and kernel in its place: the same logits, the same sets, the
+    same experts and the same counters, bit for bit."""
+    import jax
+
+    import keye_parent
+    from rnb_tpu.models.keye_vl2 import network
+    from rnb_tpu.ops import indexed
+    prompts = prompts_of(DISPATCHES[case], seed=len(case))
+    logits, kept, counts = run_program(toy, prompts, 32)
+    monkeypatch.setattr(indexed, "indexed_attention", functools.partial(
+        keye_parent.indexed_attention, qlen=Q,
+        inv_freq=toy["cfg"].inv_freq()))
+    tokens, meta, _ = pack(prompts, 32)
+    want, chosen, *want_counts = jax.jit(lambda p, s, t, m: network.forward(
+        toy["cfg"], p, s, t, m[0], m[1], m[2], interpret=True))(
+        toy["params"], toy["slots"], tokens, meta)
+    assert np.array_equal(logits, np.asarray(want)[:len(prompts)])
+    for got, (offset, prompt) in zip(kept, zip(pack(prompts, 32)[2],
+                                              prompts)):
+        theirs = network.request_choices(
+            toy["cfg"], tuple(np.asarray(c) for c in chosen), offset * Q,
+            len(prompt))
+        assert sorted(got) == sorted(theirs)
+        for name in got:
+            assert np.array_equal(got[name], theirs[name]), name
+    for got, theirs in zip(counts, want_counts):
+        assert np.array_equal(got, np.asarray(theirs))
 
 
 def test_under_topk_the_reference_is_plain_causal_attention(toy):
@@ -1028,6 +1137,67 @@ def test_real_configuration_keeps_the_published_sizes():
         // config["num_experts"] == 1024
 
 
+# -- the lowered program ---------------------------------------------------------------
+
+
+def test_the_kernel_scope_holds_the_kernel_alone(toy):
+    """``indexed_attn_roofline_pct.bulk`` divides by the device time
+    under ``attn/kernel``: the mixer's seven products (q, k, v, ``o`` and
+    the indexer's three) are traced outside that scope, and inside it
+    stands one call, the attention kernel's own ``jit``."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.keye_vl2 import network
+    from rnb_tpu.ops import banded, rope
+    cfg = toy["cfg"]
+    start = jnp.zeros(32, jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, x: network.attention_mixer(
+        cfg, p, x, start, jnp.full(32, Q, jnp.int32),
+        rope.pool_positions(start, Q),
+        banded.band_tables(start, Q, cfg.inv_freq()), interpret=True))(
+        toy["params"]["l1"], jnp.zeros((32, Q, 64), jnp.bfloat16))
+    stacks = [(str(eqn.source_info.name_stack), eqn.primitive.name)
+              for eqn in jaxpr.eqns]
+    products = [stack for stack, name in stacks if name == "dot_general"]
+    assert len(products) == 7 and not any("kernel" in s for s in products)
+    assert [name for stack, name in stacks if "kernel" in stack] \
+        in (["pjit"], ["jit"]), stacks
+
+
+def test_no_array_of_a_head_axis_between_qs_product_and_os():
+    """The real configuration's 128-row program, lowered (nothing is
+    compiled or run; the kernels interpreted, their bodies a grid
+    step's): q goes from its product to the attention kernel as
+    (tokens, 32 x 128) float32 and the kernel's result to ``o``'s
+    product as (tokens, 32 x 128) bfloat16 — the head norm, the rotary,
+    the scale and the rounding are the kernel's, on slices in VMEM — so
+    the program holds no array whose minor axes are 32 or 8 heads of 128
+    at all. PR 53's tree held, a layer, q as ``128x128x32x128`` float32
+    three times over (``rms_norm``, ``rope.rotate``, the scale) and as
+    ``16384x4x8x128`` bfloat16 on both sides of the kernel. (k keeps its
+    4 heads' axis through its norm and rotary: an eighth of q's.)"""
+    import re
+
+    import test_qwen3_next
+    from rnb_tpu.models.keye_vl2 import checkpoint, network
+    config = real_config()
+    cfg = network.KeyeVL2Config.from_published(
+        mm.load_family("keye_vl2").published_keys(config))
+    text = test_qwen3_next.lowered_text(
+        checkpoint, network, cfg, range(config["num_experts"]), rows=128)
+    tokens, width = 128 * cfg.chunk_size, \
+        cfg.num_attention_heads * cfg.head_dim
+    # what the kernel reads and writes is there, as its neighbours
+    # wrote and read it
+    assert "tensor<%dx%dxf32>" % (tokens, width) in text
+    assert "tensor<%dx%dxbf16>" % (tokens, width) in text
+    per = cfg.num_attention_heads // cfg.num_key_value_heads
+    assert sorted(set(re.findall(
+        r"tensor<[\dx]*x(?:%d|%d)x%dx(?:f32|bf16)>"
+        % (cfg.num_attention_heads, per, cfg.head_dim), text))) == []
+
+
 # -- compiled for the chip -----------------------------------------------------------
 
 
@@ -1079,17 +1249,32 @@ def test_the_kernels_compile_at_the_published_widths(piece, rows, one_chip):
             k, p, sa["topk"])).lower(keys, column)
         names = [indexed.THRESHOLD_KERNEL, indexed.TIE_KERNEL]
     else:
-        lowered = jax.jit(indexed.masked_attention).lower(
-            of((tokens, hk, hq // hk, head), jnp.bfloat16),
-            of((tokens, hk, head), jnp.bfloat16),
-            of((tokens, hk, head), jnp.bfloat16), keys, column, column,
-            column)
+        lowered = jax.jit(
+            lambda q, k, v, keys, tau, cut, w, cos, sin, start:
+            indexed.indexed_attention(q, k, v, keys, tau, cut, w,
+                                      (cos, sin, start),
+                                      config["rms_norm_eps"])).lower(
+            of((tokens, hq * head), jnp.float32),
+            of((tokens, hk * head), jnp.bfloat16),
+            of((tokens, hk * head), jnp.bfloat16), keys, column, column,
+            of((head,), jnp.bfloat16), of((tokens, head), jnp.float32),
+            of((tokens, head), jnp.float32), of((tokens, 1)))
         names = [indexed.ATTENTION_KERNEL]
     text = lowered.compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") \
         == len(names)
     for name in names:
         assert name in text, name
+    if piece == "attention":
+        # from q's product to ``o``'s operand: nothing laid out in front
+        # of the kernel or behind it
+        assert "f32[%d,%d]" % (tokens, hq * head) in text
+        assert "bf16[%d,%d]" % (tokens, hq * head) in text
+        # (the 64 numbers of a query tile's first key tile come out of
+        # a strided slice, an int32 transpose of nothing)
+        import re
+        assert not re.search(r"(?:f32|bf16)\[[\d,]*\]\S* transpose\(", text)
+        assert "pad(" not in text
 
 
 def test_the_grouped_products_compile_at_the_published_widths(one_chip):
@@ -1134,7 +1319,13 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     inside); the other four — the families whose cells bypass that
     convolution — are the texts their PRs left. PR 50 recorded
     ``qwen3_next``'s again (``ops/deltanet.py``'s kernel took the norms
-    and the gate around the rule in; no other family calls it)."""
+    and the gate around the rule in; no other family calls it). PR 54
+    recorded this family's again (``ops/indexed.py``'s attention kernel
+    holds all the heads of a tile pair a step and took q's norm, rotary,
+    scale and rounding in; nothing else imports it: the five older
+    families' texts are the ones they had, and
+    :func:`test_the_toy_stack_serves_the_parents_logits_to_the_bit`
+    holds the new text's values to the old one's)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
