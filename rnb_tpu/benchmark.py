@@ -339,6 +339,8 @@ class BenchmarkResult:
     attention_tiles_causal: int = 0
     window_tiles_visited: int = 0
     window_tiles_causal: int = 0
+    window_keys_kept: int = 0
+    window_keys_causal: int = 0
     #: ragged row-pool dispatch accounting (rnb_tpu.ops.ragged),
     #: summed over every ragged stage instance; all zero without the
     #: `ragged` root config key. rows = valid rows shipped across all

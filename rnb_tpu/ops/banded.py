@@ -2,7 +2,12 @@
 a query reads the ``window`` keys of its request that end with its own.
 One Pallas TPU kernel, K-EXAONE's sliding layers' own
 (``models/exaone_moe``), from the three products' results to the operand
-of the fourth.
+of the fourth; and, since PR 55, a second one at the end of the module
+for *latent* attention under a window (``latent_banded_attention``:
+dots3-note's sliding layers, ``models/dots3_note`` — every head its own
+key of 256 lanes and value of 128, a window of 513, no norm, nothing to
+turn; its text and sweep stand at the function). K-EXAONE's kernel is
+as PR 52 left it.
 
 The pool holds ``rows`` of ``Q`` tokens; a request is a run of
 consecutive rows (``ops/segattn.py``'s text). Under a window of 128 keys
@@ -132,7 +137,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from rnb_tpu.ops import rope
+from rnb_tpu.ops import latent, rope
 
 #: the kernel's name in the device's trace and in the scope table
 KERNEL_NAME = "banded_attention"
@@ -306,4 +311,146 @@ def banded_attention(q, k, v, q_weight, k_weight, tables, window: int,
                      window=int(window), eps=float(eps),
                      interpret=bool(interpret))
     tiles = band_tiles(q.shape[0], band_block(q.shape[0], int(window)))
+    return out, jnp.asarray(tiles, jnp.int32)
+
+
+# -- latent attention (MLA, expanded) under a window ----------------------
+#
+# dots3-note's sliding layers (``models/dots3_note``): every head has a
+# key of its own, wider than its value, no head norm, and q comes rotated,
+# scaled and rounded from ``ops/mla.queries``. The module's text above is
+# K-EXAONE's kernel's; what differs here is said at
+# :func:`latent_banded_attention`.
+
+LATENT_KERNEL_NAME = "latent_banded_attention"
+#: heads a step of the latent kernel (the sweep at
+#: :func:`latent_banded_attention`)
+_LATENT_HEADS = 16
+
+
+def _latent_kernel(q_ref, kv0_ref, kv1_ref, ks0_ref, ks1_ref, start_ref,
+                   gate_ref, o_ref, *, window: int, own: int, value: int):
+    """One query block of ``heads`` heads. ``q_ref`` (heads, B, lanes);
+    ``kv1_ref`` (B, heads (own + value)) a head's ``[own key | value]``
+    as their product wrote them, ``ks1_ref`` the block's shared key;
+    ``kv0_ref``, ``ks0_ref`` the same of the block before it;
+    ``start_ref`` (B, 1); ``gate_ref`` (1, B, heads) float32 the heads'
+    output gates; ``o_ref`` (B, heads value)."""
+    i = pl.program_id(0)
+    heads, block, _ = q_ref.shape
+    wide = own + value
+    t = i * block + lax.broadcasted_iota(jnp.int32, (block, 2 * block), 0)
+    at = (i - 1) * block \
+        + lax.broadcasted_iota(jnp.int32, (block, 2 * block), 1)
+    keep = (at <= t) & (at > t - window) & (at >= start_ref[...])
+    shared = jnp.concatenate([ks0_ref[...], ks1_ref[...]], axis=0)
+    gate = gate_ref[0]
+    for h in range(heads):
+        k = jnp.concatenate([kv0_ref[:, h * wide:h * wide + own],
+                             kv1_ref[:, h * wide:h * wide + own]], axis=0)
+        v = jnp.concatenate([kv0_ref[:, h * wide + own:(h + 1) * wide],
+                             kv1_ref[:, h * wide + own:(h + 1) * wide]],
+                            axis=0)
+        s = jnp.where(keep, latent.scores(q_ref[h], k, shared, own),
+                      _MASKED)
+        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+        o = jnp.dot(p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+        o_ref[:, h * value:(h + 1) * value] = (
+            o / p.sum(axis=-1, keepdims=True) * gate[:, h:h + 1]) \
+            .astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "window", "nope", "value", "per", "interpret"))
+def _latent_band_call(q, kv, shared, gate, start, *, window, nope, value,
+                      per, interpret):
+    heads, tokens, lanes = q.shape
+    own = latent.key_lanes(nope, lanes)
+    wide = own + value
+    block_rows = band_block(tokens, window)
+    steps = tokens // block_rows
+
+    def block(width, before=False, shared=False):
+        """``width`` columns of a block of tokens: this step's or the
+        block before it, the step's heads' or every head's."""
+        return pl.BlockSpec((block_rows, width), lambda i, g: (
+            jnp.maximum(i - 1, 0) if before else i, 0 if shared else g))
+    operands = (q, kv, kv, shared, shared, start,
+                latent.gate_groups(gate, per))
+    out = jax.ShapeDtypeStruct((tokens, heads * value), kv.dtype)
+    pairs = steps * heads * block_rows * 2 * block_rows
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, window=window, own=own,
+                          value=value),
+        grid=(steps, heads // per),
+        in_specs=[
+            pl.BlockSpec((per, block_rows, lanes), lambda i, g: (g, i, 0)),
+            block(per * wide, before=True), block(per * wide),
+            block(shared.shape[1], True, True),
+            block(shared.shape[1], shared=True), block(1, shared=True),
+            pl.BlockSpec((1, block_rows, per), lambda i, g: (g, i, 0))],
+        out_specs=block(per * value),
+        out_shape=out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=96 * 2 ** 20),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (lanes + value), transcendentals=pairs,
+            bytes_accessed=sum(x.size * x.dtype.itemsize
+                               for x in operands + (out,))),
+        interpret=interpret, name=LATENT_KERNEL_NAME)(*operands)
+
+
+def latent_banded_attention(q, kv, k_pe, gate, start, window: int,
+                            nope: int, value: int,
+                            interpret: bool = False):
+    """Latent attention in its expanded form under a window, from
+    ``ops/mla.queries``' result to the output product's operand;
+    dots3-note's sliding layers (``models/dots3_note/network.py``) are
+    the caller.
+
+    ``q`` (heads, T, lanes) as ``ops/mla.queries`` wrote it: a head's
+    ``[q_nope | q_pe rotated | 0]``, scaled and rounded; ``kv`` (T,
+    heads (own + value)) as the key-value latent's product wrote it, a
+    head's ``[own key | value]`` with ``own``
+    ``ops/latent.key_lanes``; ``k_pe`` (T, rotary) the one rotary key
+    all heads share, rotated; ``gate`` (T, heads) float32, a head's
+    result is multiplied by it; ``start`` (T, 1) int32
+    (:func:`band_tables`' third); a query reads the ``window`` keys of
+    its request that end with its own. -> ((T, heads value) in ``kv``'s
+    dtype, int32 (2,): :func:`band_tiles`).
+
+    What differs from K-EXAONE's kernel above: a step is (query block,
+    ``per`` heads) and every head has *its own* key and value, so a
+    step's operands are ``per`` times a head's and nothing is shared
+    but the rotary key, which is not copied under the heads in HBM: it
+    is added to the own key's empty columns in VMEM (or, where the own
+    key is whole lane tiles, has a product of its own); no norm, no
+    rotation: q's are ``ops/mla.queries``', the keys' are XLA's on 64
+    columns a token; the head's output gate is the kernel's last line.
+    The window of 513 makes the block 512 and a step two key blocks of
+    512: 1,024 keys computed where at most 513 are kept.
+
+    **The sweep** (my chip runs, PR 55; one TPU v5 lite, 128 rows of 128
+    tokens as one / two / three requests, 64 heads of 192 + 64 / 128, a
+    window of 513; ``scripts/indexed_sweep.py --shape=latent``, which
+    sets ``_LATENT_HEADS`` for each count; ms a layer; every form gives
+    the explicit mask's values, largest difference 0.0073 at a spread of
+    0.169). Heads a step: 16 **5.00 / 5.25 / 5.00**; 8 5.13 /
+    5.46 / 5.51; 4 5.57 / 5.86 / 5.87; 2 6.02 / 6.00 / 6.05. The two
+    products over the band's two blocks are 4.19 ms at the matrix unit's
+    peak (over the pairs the window keeps 2.05; q, ``kv`` and the result
+    once are 1.80 ms of the memory's bandwidth): at 16 heads a step the
+    kernel stands at 84% of what it computes and 41% of what the window
+    asks for. Walking the causal triangle instead (the full layers'
+    kernel's 42-92 ms a layer at twice the heads) would be ten times
+    that."""
+    heads, tokens, lanes = q.shape
+    per = min(_LATENT_HEADS, heads)
+    out = _latent_band_call(
+        q, kv, latent.shared_key(k_pe, nope, lanes), gate, start,
+        window=int(window), nope=int(nope), value=int(value), per=int(per),
+        interpret=bool(interpret))
+    tiles = band_tiles(tokens, band_block(tokens, int(window)))
     return out, jnp.asarray(tiles, jnp.int32)

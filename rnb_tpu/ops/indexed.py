@@ -6,7 +6,14 @@ keys of its own request at or before it with the largest scores (all of
 them while it has ``topk`` or fewer; a tie goes to the lower key), and
 every head attends, causally, to those keys only. Queries that choose
 and queries that read everything share one pool and the same three
-kernels. Nothing is approximated: every query gets exactly its own set.
+kernels. Nothing is approximated: every query gets exactly its own set. Two
+families call the module: ``models/keye_vl2`` (grouped queries, 16 index
+heads of 64: the text below) and, since PR 55, ``models/dots3_note``,
+whose full layers run *latent* attention under the sets: the same
+scores kernel at 64 index heads of 128 with queries from the query
+latent, the same thresholds, and a kernel of its own under the sets
+(``latent_indexed_attention``, at the end of the module with its text
+and sweep); Keye-VL's attention kernel is as PR 54 left it.
 
 The pool holds ``rows`` of ``Q`` tokens; a request is a run of
 consecutive rows (``row_start[r]``: the first row of row r's request; a
@@ -142,6 +149,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from rnb_tpu.ops import latent
 from rnb_tpu.ops.banded import _first_lines
 
 #: the indexer's scores: queries and keys a tile
@@ -159,6 +167,10 @@ SCORES_KERNEL = "index_scores"
 THRESHOLD_KERNEL = "index_threshold"
 TIE_KERNEL = "index_tie_cutoff"
 ATTENTION_KERNEL = "indexed_attention"
+LATENT_KERNEL = "latent_indexed_attention"
+#: the latent kernel: queries a tile, keys a tile, heads a step (the
+#: sweep at :func:`latent_indexed_attention`)
+_LATENT_TILES = (512, 512, 16)
 
 
 def token_table(row_start, row_tokens, qlen: int):
@@ -411,7 +423,10 @@ def attention_tiles(tokens: int):
 def causal_tiles(tokens: int) -> int:
     """The attention kernel's (query tile, key tile) pairs on or under
     the diagonal of a pool of ``tokens``."""
-    tile_q, tile_k = attention_tiles(tokens)
+    return _tiles_under_diagonal(tokens, *attention_tiles(tokens))
+
+
+def _tiles_under_diagonal(tokens: int, tile_q: int, tile_k: int) -> int:
     return int(((np.arange(tokens // tile_q) * tile_q + tile_q - 1)
                 // tile_k + 1).sum())
 
@@ -581,3 +596,224 @@ def indexed_attention(q, k, v, keys, tau, cut, q_weight, tables, eps: float,
     cos, sin, start = tables
     return _attention_call(q, k, v, keys, tau, cut, q_weight, cos, sin,
                            start, eps=float(eps), interpret=bool(interpret))
+
+
+# -- latent attention (MLA, expanded) under the sets ----------------------
+
+
+def latent_tiles(tokens: int):
+    """(queries a tile, keys a tile, heads a step) of the latent kernel
+    for a pool of ``tokens``: the module's, the first two cut to the
+    pool; a key tile is a bit of the sets' words."""
+    tile_q, tile_k, per = _LATENT_TILES
+    tile_q, tile_k = _tile(tile_q, tokens), _tile(tile_k, tokens)
+    if tokens > 32 * tile_k:
+        raise ValueError("%d tokens are more than 32 key tiles of %d"
+                         % (tokens, tile_k))
+    return tile_q, tile_k, per
+
+
+def latent_causal_tiles(tokens: int) -> int:
+    """The latent kernel's (query tile, key tile) pairs on or under the
+    diagonal of a pool of ``tokens``."""
+    return _tiles_under_diagonal(tokens, *latent_tiles(tokens)[:2])
+
+
+def _latent_kernel(lo_ref, hi_ref, q_ref, kv_ref, ks_ref, keys_ref, tau_ref,
+                   cut_ref, start_ref, gate_ref, o_ref, sets_ref, m_ref,
+                   l_ref, acc_ref, *, own: int, value: int):
+    """One (query tile, group of heads, key tile). ``q_ref`` (heads,
+    tile_q, lanes) as ``ops/mla.queries`` wrote it; ``kv_ref`` (tile_k,
+    heads (own + value)) a head's ``[own key | value]`` as their
+    product wrote them; ``ks_ref`` the key tile's shared rotary key
+    (``ops/latent.shared_key``); ``keys_ref`` the pair's sort
+    keys; ``tau_ref``, ``cut_ref``, ``start_ref`` (tile_q, 1);
+    ``gate_ref`` (1, tile_q, heads) float32; ``o_ref`` (tile_q, heads
+    value); ``sets_ref`` the query tile's words, written by the first
+    group. Scratch, a head in front: the running maximum, sum and
+    result, the first two a row's number under each of the value's
+    lanes."""
+    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    heads, tile_q, _ = q_ref.shape
+    tile_k = keys_ref.shape[1]
+    wide = own + value
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _MASKED, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when((j == 0) & (g == 0))
+    def _():
+        sets_ref[...] = jnp.zeros(sets_ref.shape, jnp.int32)
+
+    @pl.when((j >= lo_ref[i]) & (j <= hi_ref[i]))
+    def _():
+        keys, tau = keys_ref[...], tau_ref[...]
+        q_at = i * tile_q + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 0)
+        k_at = j * tile_k + lax.broadcasted_iota(
+            jnp.int32, (tile_q, tile_k), 1)
+        # the pair's mask: every head reads the same set; a group
+        # builds it again (three integer comparisons: the attention
+        # kernel's sweep found them no cost beside a head's products)
+        chosen = ((keys > tau) | ((keys == tau) & (k_at <= cut_ref[...]))) \
+            & (k_at <= q_at) & (k_at >= start_ref[...])
+
+        @pl.when(g == 0)
+        def _():
+            sets_ref[...] = sets_ref[...] | (chosen.astype(jnp.int32) << j)
+        bias = jnp.where(chosen, 0.0, _MASKED)
+        shared = ks_ref[...]
+        for h in range(heads):
+            k = kv_ref[:, h * wide:h * wide + own]
+            v = kv_ref[:, h * wide + own:(h + 1) * wide]
+            s = latent.scores(q_ref[h], k, shared, own) + bias
+            m_prev = m_ref[h]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - pltpu.repeat(m_next, tile_k // value, 1))
+            alpha = jnp.exp(m_prev - m_next)
+            l_ref[h] = alpha * l_ref[h] + p.sum(axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_next
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        gate = gate_ref[0]
+        for h in range(heads):
+            o_ref[:, h * value:(h + 1) * value] = \
+                (acc_ref[h] / l_ref[h] * gate[:, h:h + 1]) \
+                .astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("nope", "value", "tiles",
+                                             "interpret"))
+def _latent_call(q, kv, shared, gate, keys, tau, cut, start, *, nope, value,
+                 tiles, interpret):
+    heads, tokens, lanes = q.shape
+    own = latent.key_lanes(nope, lanes)
+    wide = own + value
+    tile_q, tile_k, per = tiles
+    per = min(per, heads)
+    nq, nk = tokens // tile_q, tokens // tile_k
+    lo = (start[::tile_q, 0] // tile_k).astype(jnp.int32)
+    hi = jnp.asarray((np.arange(nq) * tile_q + tile_q - 1) // tile_k,
+                     jnp.int32)
+
+    def walked(j, i, lo, hi):
+        return jnp.clip(j, lo[i], hi[i])
+
+    def mine(width):
+        return pl.BlockSpec((tile_q, width), lambda i, g, j, *_: (i, 0))
+    operands = (q, kv, shared, keys, tau[:, None], cut[:, None], start,
+                latent.gate_groups(gate, per))
+    outs = (jax.ShapeDtypeStruct((tokens, heads * value), kv.dtype),
+            jax.ShapeDtypeStruct((tokens, tile_k), jnp.int32))
+    pairs = _tiles_under_diagonal(tokens, tile_q, tile_k) \
+        * tile_q * tile_k * heads
+    out, sets = pl.pallas_call(
+        functools.partial(_latent_kernel, own=own, value=value),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(nq, heads // per, nk),
+            in_specs=[
+                pl.BlockSpec((per, tile_q, lanes),
+                             lambda i, g, j, *_: (g, i, 0)),
+                pl.BlockSpec((tile_k, per * wide), lambda i, g, j, lo, hi:
+                             (walked(j, i, lo, hi), g)),
+                pl.BlockSpec((tile_k, shared.shape[1]),
+                             lambda i, g, j, lo, hi:
+                             (walked(j, i, lo, hi), 0)),
+                pl.BlockSpec((tile_q, tile_k), lambda i, g, j, lo, hi:
+                             (i, walked(j, i, lo, hi))),
+                mine(1), mine(1), mine(1),
+                pl.BlockSpec((1, tile_q, per),
+                             lambda i, g, j, *_: (g, i, 0))],
+            out_specs=[
+                pl.BlockSpec((tile_q, per * value),
+                             lambda i, g, j, *_: (i, g)),
+                mine(tile_k)],
+            scratch_shapes=[pltpu.VMEM((per, tile_q, value),
+                                       jnp.float32)] * 3),
+        out_shape=outs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 2 ** 20),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * pairs * (lanes + value), transcendentals=pairs,
+            bytes_accessed=sum(x.size * x.dtype.itemsize
+                               for x in operands + outs)),
+        interpret=interpret, name=LATENT_KERNEL,
+    )(lo, hi, *operands)
+    return out, lax.bitcast_convert_type(sets, jnp.uint32)
+
+
+def latent_indexed_attention(q, kv, k_pe, gate, keys, tau, cut, start,
+                             nope: int, value: int,
+                             interpret: bool = False):
+    """Latent attention (MLA) in its expanded form under the sets, from
+    ``ops/mla.queries``' result to the output product's operand;
+    dots3-note's full layers (``models/dots3_note/network.py``) are the
+    caller.
+
+    ``q`` (heads, T, lanes) as ``ops/mla.queries`` wrote it: a head's
+    ``[q_nope | q_pe rotated | 0]``, scaled and rounded; ``kv`` (T,
+    heads (own + value)) as the key-value latent's product wrote it, a
+    head's ``[own key | value]`` (``ops/latent.key_lanes``);
+    ``k_pe`` (T, rotary) the one rotary key all heads share, rotated;
+    ``gate`` (T, heads) float32, a head's result is multiplied by it;
+    ``keys``, ``tau``, ``cut`` the sets; ``start`` (T, 1) int32.
+    -> ((T, heads value) in ``kv``'s dtype; the sets as bits (T, keys a
+    tile) uint32 as :func:`indexed_attention` writes them).
+
+    **Why a kernel beside** :func:`indexed_attention`. That one is a
+    grouped-query kernel: eight query heads share a key head's 128
+    lanes, all 32 heads' queries of a 256-query tile are 4 MiB, and the
+    mask is built once a (query tile, key tile) pair for all of them.
+    Here every one of 128 heads has a key of its own (192 columns, 256
+    lanes with the shared rotary key's) and a value of 128: a head's
+    keys and values are read once a *query tile*, so the operations a
+    byte of them are the queries a tile (256 queries a tile stand at
+    the v5e's ridge of 240 operations a byte; 1,024 are four times
+    over it), and all heads of such a tile are 64 MiB of q: a step
+    holds ``per`` heads, a query tile walks its key tiles once a group
+    of heads, and the group builds the mask again from the same sort
+    keys (the sets' bits are the first group's to write). Keye-VL's
+    kernel is left as PR 54 left it, to the bit. Nothing with a head
+    axis is copied in HBM: q is the queries' product's own result,
+    ``kv`` the key-value latent's, the output the last product's
+    operand; the shared rotary key is one (T, 128) array whose product
+    with q's lanes behind the own key stands beside the own key's.
+
+    **The sweep** (my chip runs, PR 55; one TPU v5 lite, 128 rows of 128
+    tokens as one / two / three requests, 128 heads of 128 + 64 / 128;
+    ``scripts/indexed_sweep.py --shape=latent``, which sets the module's
+    ``_LATENT_TILES`` for each form: the kernel takes no tile argument;
+    ms a layer by the host's clock around a jitted call; every form
+    gives the dense form's values, largest difference 0.0079 at a spread
+    of 0.174, and its sets to the bit). At
+    (queries a tile, keys a tile, heads a step): (512, 512, 16) **84.7 /
+    47.4 / 38.0**, which stands; (256, 512, 16) 89.2 / 51.3 / 40.1;
+    (512, 512, 8) 91.9 / 52.2 / 42.2; (1024, 512, 8) 91.7 / 52.1 / 43.6;
+    (256, 512, 8) 98.7 / 58.1 / 46.2; (1024, 512, 4) 106.0 / 60.7 / 51.1;
+    (512, 512, 4) 108.2 / 63.0 / 51.6; (1024, 512, 2) 131.9 / 77.3 /
+    65.9; (2048, 512, 2) 133.2 / 79.2 / 72.4; (1024, 512, 16) runs out
+    of VMEM: the
+    heads a step decide, the queries a tile hardly — a step's fixed part
+    (the pair's sort keys, 1 MiB at 512 x 512, its three comparisons, the
+    mask as a float tile) is paid once a group, so it is the *groups* a
+    (query tile, key tile) pair has that cost, not its bytes of keys and
+    values. The two products of every head over the causal pairs are
+    55.0 / 27.5 / 18.3 ms at the matrix unit's peak (over the tiles the
+    kernel visits in a pool one request fills, 57.6): the kernel stands
+    at 65% of the causal pairs' floor for one request, 58% for two and
+    48% for three (more diagonal tiles and more first tiles a request).
+    Beside it ``index_keys`` at 64 heads of 128 reads 17.5 / 10.9 / 9.4
+    ms (its operations' floor 11.0 / 5.5 / 3.7) and ``thresholds`` 10.8
+    whatever the pool holds."""
+    heads, tokens, lanes = q.shape
+    return _latent_call(
+        q, kv, latent.shared_key(k_pe, nope, lanes), gate, keys, tau, cut,
+        start, nope=int(nope), value=int(value),
+        tiles=latent_tiles(tokens), interpret=bool(interpret))

@@ -14,10 +14,22 @@ halves ``[x1 | x2]`` the product brings ``[-x2 | x1]`` beside them, a
 lane roll lays it under the halves, and ``x cos + turned sin`` is the
 half-split rotation of ``ops/rope.py`` in the same float32 numbers.
 
-*Which callers rotate.* ``models/deepseek_v2/network.py`` is the one
-caller: its queries come from a latent (``q_lora_rank``) and 64 of a
-head's 192 columns are rotary, so the kernel requires room for them
-twice. ``models/kimi_linear/network.py`` runs the same expanded form
+*Which callers rotate.* ``models/deepseek_v2/network.py`` (its queries
+come from a latent, ``q_lora_rank``, and 64 of a head's 192 columns are
+rotary, so the kernel requires room for them twice) and, since PR 55,
+``models/dots3_note/network.py`` at two geometries in one stack, both
+from a latent of 1,024: 128 heads of 128 + 64 (the same columns as
+DeepSeek-V2's) and 64 heads of 192 + 64, whose rotary columns begin in
+the *middle* of the second lane tile (``whole`` 128, ``first`` 64: the
+last two of three lane tiles are rolled as one, by 192) and whose third
+lane tile holds nothing but the turned copies: ``out_columns`` 256
+leaves it unwritten (a quarter of a GiB a layer at 16,384 tokens). That
+caller builds the rotary tables once a dispatch a layer type
+(:func:`turn_tables`: two bases in one stack) and hands them to every
+layer's call; its output's reader is no splash kernel but
+``ops/indexed.latent_indexed_attention`` and
+``ops/banded.latent_banded_attention``, which read ``(heads, tokens,
+lanes)`` as it lies. ``models/kimi_linear/network.py`` runs the same expanded form
 **without** a query latent and **without** positions
 (``mla_use_nope``): nothing is turned, so it does not call this kernel;
 it stores its query weight heads-first and whole lanes wide too and one
@@ -60,17 +72,39 @@ def _kernel(x_ref, w_ref, cos_ref, sin_ref, o_ref, *, whole, turn, scale):
         o_ref[0, :, :whole] = (acc[:, :whole] * scale).astype(o_ref.dtype)
     last = acc[:, whole:]
     last = last * cos_ref[...] + pltpu.roll(last, turn, 1) * sin_ref[...]
+    kept = o_ref.shape[2] - whole
+    if kept < last.shape[1]:
+        # the lane tiles behind the rotated columns held what the
+        # rotation read: nothing a caller reads
+        last = last[:, :kept]
     o_ref[0, :, whole:] = (last * scale).astype(o_ref.dtype)
 
 
+def turn_tables(positions, inv_freq, nope: int, mscale: float = 1.0):
+    """The (cos, sin) :func:`queries` turns a head's rotary columns by
+    (``ops/rope.turn_tables`` over the lane tiles from the one they
+    begin in): a caller whose layers share positions and frequencies
+    builds them once a dispatch and hands them to every layer's call."""
+    whole = nope // _LANES * _LANES
+    return rope.turn_tables(
+        positions, inv_freq, nope - whole,
+        query_lanes(nope, 2 * len(inv_freq)) - whole, mscale)
+
+
 def queries(latent, weight, positions, inv_freq, nope: int, scale: float,
-            mscale: float = 1.0, interpret: bool = False):
+            mscale: float = 1.0, interpret: bool = False, tables=None,
+            out_columns=None):
     """``latent`` (tokens, rank), normed; ``weight`` (heads, rank,
     ``query_lanes``) in the stored order; ``positions`` (tokens,).
     -> (heads, tokens, ``query_lanes``) in the latent's dtype: a head's
     ``[q_nope | q_pe rotated | 0]`` times ``scale``, the rotation (its
     cos and sin times ``mscale``) and the scale on the float32
-    product."""
+    product. ``tables``: :func:`turn_tables`' pair where the caller has
+    built it (``positions``, ``inv_freq`` and ``mscale`` are then not
+    read but for the rotary's width); ``out_columns``: the whole lanes
+    of a head that are written, where the last lane tiles hold nothing
+    but what the rotation read (192 + 64 + 64 columns are three lane
+    tiles of which the first two hold the head)."""
     rotary = 2 * len(inv_freq)
     columns = weight.shape[2]
     # the lane tiles in front of the one the rotary columns begin in
@@ -80,7 +114,12 @@ def queries(latent, weight, positions, inv_freq, nope: int, scale: float,
     if first + 2 * rotary > width:
         raise ValueError("no room for %d rotary columns twice behind %d "
                          "in %d" % (rotary, nope, columns))
-    cos, sin = rope.turn_tables(positions, inv_freq, first, width, mscale)
+    written = columns if out_columns is None else int(out_columns)
+    if written % _LANES or not nope + rotary <= written <= columns:
+        raise ValueError("%d columns written of %d, %d of them a head's"
+                         % (written, columns, nope + rotary))
+    cos, sin = tables if tables is not None else rope.turn_tables(
+        positions, inv_freq, first, width, mscale)
     tokens, rank = latent.shape
     heads = weight.shape[0]
     step = min(_TOKENS, tokens)
@@ -94,8 +133,8 @@ def queries(latent, weight, positions, inv_freq, nope: int, scale: float,
                   pl.BlockSpec((1, rank, columns), lambda i, h: (h, 0, 0)),
                   pl.BlockSpec((step, width), lambda i, h: (i, 0)),
                   pl.BlockSpec((step, width), lambda i, h: (i, 0))],
-        out_specs=pl.BlockSpec((1, step, columns), lambda i, h: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((heads, tokens, columns),
+        out_specs=pl.BlockSpec((1, step, written), lambda i, h: (h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((heads, tokens, written),
                                        latent.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),

@@ -1058,6 +1058,13 @@ STAGE_COUNTERS = (
         "steps it ran a key-value head, and the tiles on or under the "
         "diagonal at their own tile sizes (a step's queries by twice "
         "as many keys)"),
+    StageCounter(
+        "window_keys", "Attention:", "",
+        ("window_keys_kept", "window_keys_causal"),
+        "(window layers, 2), a stack's layers with a window: the (valid "
+        "query, key) pairs the window keeps, ``min(position + 1, "
+        "window)`` a query, and the causal pairs a layer without one "
+        "would read"),
 )
 
 

@@ -30,14 +30,32 @@ in the place of its channels', and rotary turned on; both must fail, as
 must float8, and the carried states through bfloat16 are recorded
 whether they do or not (the family file's ``CONTROL_MAY_PASS``;
 ``falcon_h1`` records its scan's states through bfloat16 the same way
-and holds float8 to a failure).
+and holds float8 to a failure). ``dots3_note`` (latent attention under
+an indexer's sets in its full layers, under a window in its sliding
+ones) has ``keye_vl2``'s lower-precision arm, the indexer's operands
+through float8, which must fail the key slack, every stored matrix
+through float8, and :func:`dots3_note_faults`: each changes what the
+program is *given* — a tensor of its parameter tree scaled, a field of
+its configuration, a function of its module replaced — and nothing in
+the program, and must fail one of the family's limits: the head-wise
+gates' matrices at zero (every gate a half), the latents' norm weights
+over the rescale (the rescale left out), the window a key short, the
+rotary bases of the two layer types swapped, the indexer's rotary left
+out (that one fails the key slack). ``old_draw`` is a witness and no
+fault: program *and* reference with the latents' norm weights at one,
+the draw the cell's first chip run read 24% of the spread under
+(``models/dots3_note/checkpoint.py``); it fails as that run did.
+``--arms`` names the ones to run where a chip's minutes are counted.
 """
 
 import argparse
+import contextlib
+import dataclasses
 import importlib
 import json
 import os
 import sys
+from unittest import mock
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -75,7 +93,94 @@ def arms_of(family: str):
                 ("scalar_gate", {"gate": "scalar"}, None),
                 ("rotary_on", {"rotary": True}, None),
                 ("layers_float8", {}, lambda group, name: True)]
+    if family == "dots3_note":
+        from rnb_tpu.models.dots3_note.network import FLOAT8_BITS
+        return [("as_stated", {}, None),
+                ("index_float8", {"index_bits": FLOAT8_BITS}, None)] \
+            + [(name, {}, None) for name in (
+                "flat_gates", "no_rescale", "window_a_key_short",
+                "theta_swapped", "index_no_rotary", "old_draw")] \
+            + [("layers_float8", {}, lambda group, name: True)]
     raise ValueError("no control arms for family %r" % (family,))
+
+
+def dots3_note_faults(cfg):
+    """{arm: fault} for a ``Dots3NoteConfig``. A fault is what the arm's
+    program is given in the place of the stated one: ``scale`` {tensor:
+    factor} on the parameter tree (and, with ``reference_too``, on what
+    the reference reads), ``cfg`` another configuration, ``patch``
+    attributes of ``models/dots3_note/network`` replaced while the
+    arm's program is traced. ``network.forward`` itself has no switch
+    for any of them."""
+    from rnb_tpu.models.dots3_note.checkpoint import LATENT_SPREAD
+
+    def scaled(tensor, factor, sliding=None):
+        return {"l%d.%s" % (i, tensor): factor(cfg.geometry(i))
+                for i in range(cfg.num_hidden_layers)
+                if sliding in (None, cfg.is_sliding(i))}
+    faults = {}
+    for kind, sliding in (("full", False), ("sliding", True)):
+        faults["gate_flat_" + kind] = {
+            "scale": scaled("attn_gate", lambda geo: 0.0, sliding)}
+        for at, (latent, norm) in enumerate((("q", "q_a_norm"),
+                                             ("kv", "kv_a_norm"))):
+            faults["rho_%s_%s" % (latent, kind)] = {"scale": scaled(
+                norm, lambda geo, at=at: 1.0 / cfg.rescales(geo)[at],
+                sliding)}
+
+    def together(*names):
+        return {"scale": {k: v for n in names
+                          for k, v in faults[n]["scale"].items()}}
+    faults["flat_gates"] = together("gate_flat_full", "gate_flat_sliding")
+    faults["no_rescale"] = together("rho_q_full", "rho_kv_full",
+                                    "rho_q_sliding", "rho_kv_sliding")
+    faults["window_a_key_short"] = {"cfg": dataclasses.replace(
+        cfg, sliding_window_size=cfg.sliding_window_size - 1)}
+    faults["theta_swapped"] = {"cfg": dataclasses.replace(
+        cfg, full=dataclasses.replace(cfg.full, theta=cfg.sliding.theta),
+        sliding=dataclasses.replace(cfg.sliding, theta=cfg.full.theta))}
+    faults["index_no_rotary"] = {"patch": {
+        "rotate_front": lambda x, positions, inv_freq: x}}
+    faults["old_draw"] = {"reference_too": True, "scale": {
+        k: v for at, norm in enumerate(("q_a_norm", "kv_a_norm"))
+        for k, v in scaled(norm, lambda geo, at=at: cfg.rescales(geo)[at]
+                           / LATENT_SPREAD).items()}}
+    return faults
+
+
+def scale_tensor(w, factor):
+    """``w`` times ``factor`` in float32, rounded as ``w`` is stored."""
+    import jax.numpy as jnp
+    return (w.astype(jnp.float32) * factor).astype(w.dtype)
+
+
+def planted(params, fault):
+    """The parameter tree with the fault's ``scale`` on it (the groups
+    touched are copies: ``params`` stays as stated)."""
+    out = dict(params)
+    for name, factor in fault.get("scale", {}).items():
+        group, tensor = name.split(".", 1)
+        out[group] = dict(out[group])
+        out[group][tensor] = scale_tensor(out[group][tensor], factor)
+    return out
+
+
+def reads_planted(read, fault):
+    """The reference's reader with the fault's ``scale`` on what it
+    reads, rounded as the program stores it, where the fault says
+    ``reference_too``; else ``read``."""
+    import jax.numpy as jnp
+    if not fault.get("reference_too"):
+        return read
+    scale = fault["scale"]
+
+    def scaled(name, expert_ids=None):
+        w = read(name, expert_ids)
+        if name not in scale:
+            return w
+        return scale_tensor(w.astype(jnp.bfloat16), scale[name]) \
+            .astype(jnp.float32)
+    return scaled
 
 
 def main(argv=None) -> int:
@@ -86,6 +191,9 @@ def main(argv=None) -> int:
     parser.add_argument("--lengths", default=None, help="prompt lengths, "
                         "comma-separated (default: the family file's "
                         "CONTROL_LENGTHS, else 300,1190,700,2400)")
+    parser.add_argument("--arms", default=None, help="the arms to run "
+                        "behind as_stated, comma-separated (default: all "
+                        "of the family's)")
     args = parser.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -126,7 +234,16 @@ def main(argv=None) -> int:
     out = {"device": device.device_kind, "family": name, "limit": limit,
            "rows": rows, "lengths": [len(p) for p in prompts]}
     arms, kept = arms_of(name), None
+    if args.arms is not None:
+        wanted = args.arms.split(",")
+        unknown = set(wanted) - {arm for arm, _, _ in arms}
+        if unknown:
+            parser.error("no such arm of %s: %s" % (name, sorted(unknown)))
+        arms = [a for a in arms if a[0] == "as_stated" or a[0] in wanted]
+    faults = dots3_note_faults(cfg) if name == "dots3_note" else {}
+    programs = {}
     for arm, kwargs, rounded in arms:
+        fault = faults.get(arm, {})
         if rounded is not None:
             for group, block in params.items():
                 if isinstance(block, dict):
@@ -134,15 +251,24 @@ def main(argv=None) -> int:
                         if w.ndim >= 2 and rounded(group, tensor):
                             block[tensor] = w.astype(
                                 jnp.float8_e4m3fn).astype(w.dtype)
-        logits, chosen, *_ = jax.jit(
-            lambda p, s, t, m: network.forward(
-                cfg, p, s, t, m[0], m[1], m[2],
-                interpret=device.platform != "tpu", **kwargs))(
-            params, slots, tokens, meta)
+        arm_cfg = fault.get("cfg", cfg)
+        # arms that differ in the weights alone share one program
+        own = arm if kwargs or "cfg" in fault or "patch" in fault else None
+        if own not in programs:
+            programs[own] = jax.jit(
+                lambda p, s, t, m, arm_cfg=arm_cfg, kwargs=kwargs:
+                network.forward(
+                    arm_cfg, p, s, t, m[0], m[1], m[2],
+                    interpret=device.platform != "tpu", **kwargs))
+        with mock.patch.multiple(network, **fault["patch"]) \
+                if "patch" in fault else contextlib.nullcontext():
+            logits, chosen, *_ = programs[own](
+                planted(params, fault), slots, tokens, meta)
+        arm_read = reads_planted(read, fault)
         chosen = jax.tree.map(np.asarray, chosen)
         # a family that chooses nothing is given nothing: its reference
         # is the same for every arm, and is computed once
-        again = kept is None or any(
+        again = kept is None or fault.get("reference_too") or any(
             leaf.size for leaf in jax.tree.leaves(chosen))
         want, short, key_short = [], 0.0, None
         with jax.default_matmul_precision("highest"):
@@ -163,7 +289,7 @@ def main(argv=None) -> int:
                     forced, sets, strays = forced
                     if "select" not in kwargs:
                         given = {"forced_sets": sets}
-                ref = ref_model.forward(read, prompt, held=held,
+                ref = ref_model.forward(arm_read, prompt, held=held,
                                         forced=forced, **given)
                 want.append(np.asarray(ref["logits"]))
                 short = max([short] + [
@@ -184,13 +310,23 @@ def main(argv=None) -> int:
             else compare(got, want, limit)
         out[arm] = {"share_of_spread": verdict["share_of_spread"],
                     "ok": verdict["ok"], "route_shortfall_max": short}
-        if "rms_share_of_spread" in verdict:
-            out[arm]["rms_share_of_spread"] = verdict["rms_share_of_spread"]
+        # the root mean square beside the worst logit: a limit of
+        # ``falcon_h1``'s, a record for the others
+        out[arm]["rms_share_of_spread"] = verdict.get(
+            "rms_share_of_spread", float(np.sqrt(np.mean(
+                (got.astype(np.float64) - want) ** 2)) / want.std()))
         if key_short is not None:
             out[arm]["key_shortfall_max"] = key_short
             out[arm]["ok"] = bool(verdict["ok"]
                                   and key_short <= float(config.get(
                                       "key_slack", family.KEY_SLACK)))
+            if hasattr(family, "held_to_the_limits"):
+                # every limit of the run's own check, the router's too
+                out[arm]["ok"] = family.held_to_the_limits(
+                    config, dict(verdict), {
+                        "key_bad": int(key_short == float("inf")),
+                        "route_shortfall_max": short,
+                        "key_shortfall_max": key_short})["ok"]
         print("[control] %s %s" % (arm, out[arm]), file=sys.stderr,
               flush=True)
     # a family that says so holds every control to a failure (or every

@@ -289,3 +289,75 @@ def test_the_kernel_compiles_at_the_published_widths(one_chip):
     assert banded.KERNEL_NAME in text
     assert "bf16[%d,%d]" % (tokens, hq * dim) in text
     assert "transpose(" not in text and "pad(" not in text
+
+
+# -- latent attention under a window (dots3-note's sliding layers) ---------------
+
+#: (rows, tokens a row, the rows that open a request, window, heads,
+#: nope, rotary, value, heads a step): an odd window over one block (the
+#: whole pool) and over several, every head its own key, keys wider than
+#: values, the own key padded and whole lane tiles
+LATENT_WINDOWS = [
+    (4, 16, [0, 0, 2, 2], 37, 2, 24, 8, 16, 2),
+    (16, 32, [0, 0, 0, 3, 3, 3, 3, 3, 8, 8, 8, 8, 8, 8, 14, 15], 37, 4,
+     24, 8, 16, 2),
+    (16, 32, [0, 0, 0, 3, 3, 3, 3, 3, 8, 8, 8, 8, 8, 8, 14, 15], 65, 2,
+     128, 16, 32, 1),
+    (8, 32, [0] * 8, 513, 2, 24, 8, 16, 2)]
+
+
+@pytest.mark.parametrize(
+    "rows,qlen,starts,window,heads,nope,rotary,value,per", LATENT_WINDOWS)
+def test_the_latent_banded_kernel_equals_the_explicit_mask(
+        rows, qlen, starts, window, heads, nope, rotary, value, per,
+        monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded, latent
+    monkeypatch.setattr(banded, "_LATENT_HEADS", per)
+    rng = np.random.default_rng(rows + window)
+    tokens = rows * qlen
+    lanes = -(-(nope + rotary) // 128) * 128
+    own = latent.key_lanes(nope, lanes)
+    assert own == (nope if nope % 128 == 0 else lanes)
+    q = np.zeros((heads, tokens, lanes), np.float32)
+    q[..., :nope + rotary] = rng.normal(
+        size=(heads, tokens, nope + rotary)) * 2 * (nope + rotary) ** -0.5
+    kv = np.zeros((tokens, heads, own + value), np.float32)
+    kv[..., :nope] = rng.normal(size=(tokens, heads, nope))
+    kv[..., own:] = rng.normal(size=(tokens, heads, value))
+    k_pe = rng.normal(size=(tokens, rotary))
+    gate = np.asarray(jax.nn.sigmoid(jnp.asarray(
+        rng.normal(size=(tokens, heads)), jnp.float32)))
+    bf = jnp.bfloat16
+    q, kv, k_pe = (jnp.asarray(x, bf) for x in (q, kv.reshape(tokens, -1),
+                                                k_pe))
+    start = np.repeat(np.asarray(starts) * qlen, qlen)
+    out, tiles = banded.latent_banded_attention(
+        q, kv, k_pe, jnp.asarray(gate), jnp.asarray(start, jnp.int32)[:, None],
+        window, nope, value, interpret=True)
+    assert out.shape == (tokens, heads * value) and out.dtype == bf
+    t = np.arange(tokens)
+    keep = (t[None, :] <= t[:, None]) & (t[:, None] - t[None, :] < window) \
+        & (t[None, :] >= start[:, None])
+    qf = np.asarray(q, np.float32)
+    kvf = np.asarray(kv, np.float32).reshape(tokens, heads, own + value)
+    s = np.einsum("htd,shd->hts", qf[..., :nope], kvf[..., :nope]) \
+        + np.einsum("htd,sd->hts", qf[..., nope:nope + rotary],
+                    np.asarray(k_pe, np.float32))
+    s = np.where(keep[None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("hts,shd->thd", p / p.sum(-1, keepdims=True),
+                     kvf[..., own:]) * gate[:, :, None]
+    assert np.abs(np.asarray(out, np.float32)
+                  - want.reshape(tokens, -1)).max() < 0.03
+    block = banded.band_block(tokens, window)
+    assert tiles.tolist() == list(banded.band_tiles(tokens, block))
+    # one key fewer in the window is another result
+    short, _ = banded.latent_banded_attention(
+        q, kv, k_pe, jnp.asarray(gate), jnp.asarray(start, jnp.int32)[:, None],
+        window - 1, nope, value, interpret=True)
+    binds = bool((keep.sum(1) == window).any())
+    assert (np.abs(np.asarray(short, np.float32)
+                   - np.asarray(out, np.float32)).max() > 0.03) == binds

@@ -1119,3 +1119,51 @@ def test_the_queries_reach_the_kernel_from_their_product(stage_program):
     assert queries == ["bf16[%d,%d,%d]" % (heads, tokens, mla.query_lanes(
         cfg.qk_nope_head_dim, cfg.qk_rope_head_dim))] * 5
     assert len(passes) <= 25, passes
+
+
+# -- ``mla.queries`` at dots3-note's sliding geometry ---------------------------
+
+
+@pytest.mark.parametrize("nope,rotary,cut", [(192, 64, True), (192, 64, False),
+                                            (128, 64, False), (24, 8, True)])
+def test_the_queries_kernel_turns_columns_that_start_inside_a_lane_tile(
+        nope, rotary, cut):
+    """``mla.queries`` at nope 192 (``models/dots3_note``'s sliding
+    layers): the rotary columns begin in the middle of the second lane
+    tile, the turned copies fill the third, and ``out_columns`` writes
+    the two tiles that hold the head; against ``ops/rope.rotate`` on the
+    float32 product, with the tables handed over or built inside."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import mla, rope
+    rng = np.random.default_rng(nope)
+    tokens, rank, heads, scale = 64, 32, 3, 0.25
+    lanes = mla.query_lanes(nope, rotary)
+    half = rotary // 2
+    w = rng.normal(size=(heads, rank, nope + rotary)) / np.sqrt(rank)
+    stored = np.zeros((heads, rank, lanes), np.float32)
+    stored[..., :nope + rotary] = w
+    stored[..., nope + rotary:nope + rotary + half] = -w[..., nope + half:]
+    stored[..., nope + rotary + half:nope + 2 * rotary] = \
+        w[..., nope:nope + half]
+    latent = jnp.asarray(rng.normal(size=(tokens, rank)), jnp.bfloat16)
+    stored = jnp.asarray(stored, jnp.bfloat16)
+    positions = jnp.asarray(rng.integers(0, 5000, tokens), jnp.int32)
+    inv_freq = (5e4 ** (-np.arange(0, rotary, 2) / rotary)).astype(np.float32)
+    written = -(-(nope + rotary) // 128) * 128 if cut else None
+    tables = mla.turn_tables(positions, inv_freq, nope) if cut else None
+    got = mla.queries(latent, stored, positions, inv_freq, nope, scale,
+                      interpret=True, tables=tables, out_columns=written)
+    assert got.shape == (heads, tokens, written or lanes)
+    product = jnp.einsum("tr,hrc->htc", latent.astype(jnp.float32),
+                         stored.astype(jnp.float32)[..., :nope + rotary])
+    turned = rope.rotate(product[..., nope:].transpose(1, 0, 2)[None],
+                         positions[None], inv_freq)[0].transpose(1, 0, 2)
+    want = np.concatenate([np.asarray(product[..., :nope]),
+                           np.asarray(turned)], -1) * scale
+    got = np.asarray(got, np.float32)
+    assert np.abs(got[..., :nope + rotary] - want).max() < 0.02
+    assert not got[..., nope + rotary:].any()
+    with pytest.raises(ValueError, match="columns written"):
+        mla.queries(latent, stored, positions, inv_freq, nope, scale,
+                    interpret=True, out_columns=128 if nope > 64 else 64)

@@ -674,7 +674,10 @@ def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
     scope: the seconds under it."""
     module = mm.load_layer_metric(name)
     entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
-    assert entry and entry[0]["workloads"] == [CELL]
+    # PR 55's cell counts its banded kernel's steps in the same pair
+    joined = ["dots3-note.bulk"] if name == "window_tile_visit_pct.bulk" \
+        else []
+    assert entry and entry[0]["workloads"] == [CELL] + joined
     assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == "packed attention"
 

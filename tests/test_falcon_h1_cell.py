@@ -176,7 +176,8 @@ def test_the_parent_fails_on_the_cell_before_jax_starts(tmp_path):
     with pytest.raises(SystemExit, match="falcon_h1"):
         family.build(str(tmp_path))
     family.build(REPO)
-    parents = dict(mm.load(), workloads=mm.load()["workloads"][:-1])
+    parents = dict(mm.load(), workloads=[
+        w for w in mm.load()["workloads"] if w["name"] != CELL])
     with pytest.raises(KeyError, match=CELL):
         mm.cell(parents, CELL)
 
@@ -201,8 +202,14 @@ LISTED = (
 
 def test_the_accepted_readers_list_the_cell_last():
     by_name = {m["name"]: m for m in mm.load()["per_layer"]}
+
+    def last_of_its_pr(workloads):
+        """The cell stands last but for the cells later PRs appended
+        (PR 55's ``dots3-note.bulk``)."""
+        behind = workloads[workloads.index(CELL) + 1:]
+        return set(behind) <= {"dots3-note.bulk"}
     for name in LISTED:
-        assert by_name[name + ".bulk"]["workloads"][-1] == CELL, name
+        assert last_of_its_pr(by_name[name + ".bulk"]["workloads"]), name
     listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
               and m["moves"] == "videos_per_s"}
     assert listed == {n + ".bulk" for n in LISTED} | set(NEW_READERS)
@@ -213,7 +220,7 @@ def test_the_accepted_readers_list_the_cell_last():
     at = names.index(next(iter(NEW_READERS)))
     assert names[at:at + len(NEW_READERS)] == list(NEW_READERS)
     assert by_name[names[at - 1]]["moves"] == "setup_s"
-    assert by_name[names[at - 1]]["workloads"][-1] == CELL
+    assert last_of_its_pr(by_name[names[at - 1]]["workloads"])
     # a reader that gives a dense family nothing does not list it
     # nor the three idle shares: the cell's spans paired under
     # ``hostspans.PAIR_RADIUS_NS`` in two of three traced runs only

@@ -21,7 +21,7 @@ from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAGE_COUNTERS,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
-            "exaone_moe", "keye_vl2", "falcon_h1")
+            "exaone_moe", "keye_vl2", "falcon_h1", "dots3_note")
 
 #: what the dispatches of one stage summed to, as ``network.forward``
 #: hands each counter back: the layers that count first
@@ -35,6 +35,7 @@ RAW = {
     "sparse": [[20, 12, 90, 60], [20, 8, 70, 50]],
     "index_tiles": [[3, 4], [2, 4]],
     "scan_resets": [21],
+    "window_keys": [[30, 70], [30, 70]],
 }
 
 TOKENS = "Tokens: valid=10 shipped=16"
@@ -57,6 +58,12 @@ GOLDEN = {
                  "Sparse: queries=40 selecting=20 causal_keys=160 "
                  "chosen_keys=110 tiles_chosen=5 tiles_causal=8"],
     "falcon_h1": [TOKENS + " scan_resets=21", ATTENTION],
+    "dots3_note": [TOKENS, EXPERTS + " pair_rows_moved=22 pair_rows_all=80 "
+                                     "gmm_rows=384",
+                   "Sparse: queries=40 selecting=20 causal_keys=160 "
+                   "chosen_keys=110 tiles_chosen=5 tiles_causal=8",
+                   "Attention: window_tiles_visited=3 window_tiles_causal=4 "
+                   "window_keys_kept=60 window_keys_causal=140"],
 }
 
 
