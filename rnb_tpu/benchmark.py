@@ -335,6 +335,8 @@ class BenchmarkResult:
     sparse_chosen_keys: int = 0
     sparse_tiles_chosen: int = 0
     sparse_tiles_causal: int = 0
+    sparse_chunks_walked: int = 0
+    sparse_chunks_to_diagonal: int = 0
     attention_tiles_visited: int = 0
     attention_tiles_causal: int = 0
     window_tiles_visited: int = 0

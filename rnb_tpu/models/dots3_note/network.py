@@ -80,9 +80,10 @@ from rnb_tpu.ops import banded, indexed, latent, mla, moe, rope
 #: count the full layers (``models/keye_vl2``'s pair), ``window_tiles``
 #: and ``window_keys`` the sliding ones: the banded kernel's steps and
 #: the tiles of their size on or under the diagonal; the pairs the
-#: window keeps and the causal pairs
+#: window keeps and the causal pairs; ``index_chunks`` the full layers'
+#: thresholds (``models/keye_vl2``'s)
 COUNTERS = ("expert_served", "gmm_rows", "pair_rows", "sparse",
-            "index_tiles", "window_tiles", "window_keys")
+            "index_tiles", "window_tiles", "window_keys", "index_chunks")
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: the lower-precision control's rounding of the indexer's operands
 #: (``models/keye_vl2``'s): float8 e4m3's exponent and mantissa bits
@@ -309,8 +310,9 @@ def latent_mixer(cfg, layer: int, p, h, at, start, valid, turn,
     dispatch's. -> (float32 (rows, Q, hidden), and of a full layer: the
     sets as bits (T, keys a tile) uint32, the ``Sparse:`` line's four
     int32 (4,), the kernel's tiles with a chosen key and on or under the
-    diagonal int32 (2,); of a sliding layer: the banded kernel's steps
-    and causal tiles int32 (2,))."""
+    diagonal int32 (2,), the thresholds' chunk visits and those of a
+    walk from key 0 int32 (2,); of a sliding layer: the banded kernel's
+    steps and causal tiles int32 (2,))."""
     rows, q, hidden = h.shape
     act = h.dtype
     sliding = cfg.is_sliding(layer)
@@ -364,7 +366,8 @@ def latent_mixer(cfg, layer: int, p, h, at, start, valid, turn,
             valid.sum(), chooses.sum(), jnp.where(chooses, at + 1, 0).sum(),
             jnp.where(chooses, chose, 0).sum()]).astype(jnp.int32)
         extras = (sets, counts, jnp.stack(
-            [reached, jnp.int32(indexed.latent_causal_tiles(tokens))]))
+            [reached, jnp.int32(indexed.latent_causal_tiles(tokens))]),
+            indexed.chunk_visits(at, cfg.index_topk))
     with jax.named_scope("mla_proj"):
         out = _proj(out.reshape(rows, q, geo.heads * geo.value), p["o"])
     return (out,) + extras
@@ -432,7 +435,9 @@ def forward(cfg: Dots3NoteConfig, params, slots, tokens, row_tokens,
     (full layers, 4); the full layers' kernel's tiles with a chosen key
     and on or under the diagonal (full layers, 2); the banded kernel's
     steps and causal tiles (sliding layers, 2); the pairs the window
-    keeps and the causal pairs of valid queries (sliding layers, 2)).
+    keeps and the causal pairs of valid queries (sliding layers, 2);
+    the thresholds' chunk visits and those of a walk from key 0 (full
+    layers, 2)).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
@@ -450,6 +455,7 @@ def forward(cfg: Dots3NoteConfig, params, slots, tokens, row_tokens,
     act = x.dtype
     chosen, served, gmm_rows, pair_rows = [], [], [], []
     key_sets, sparse, index_tiles, window_tiles, kept = [], [], [], [], []
+    index_chunks = []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         sliding = cfg.is_sliding(i)
@@ -466,6 +472,7 @@ def forward(cfg: Dots3NoteConfig, params, slots, tokens, row_tokens,
                 key_sets.append(extras[0])
                 sparse.append(extras[1])
                 index_tiles.append(extras[2])
+                index_chunks.append(extras[3])
         with jax.named_scope("experts"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)
             if cfg.is_dense(i):
@@ -485,4 +492,4 @@ def forward(cfg: Dots3NoteConfig, params, slots, tokens, row_tokens,
     return logits, (jnp.stack(chosen), jnp.stack(key_sets)), \
         jnp.stack(served), jnp.stack(gmm_rows), jnp.stack(pair_rows), \
         jnp.stack(sparse), jnp.stack(index_tiles), \
-        jnp.stack(window_tiles), jnp.stack(kept)
+        jnp.stack(window_tiles), jnp.stack(kept), jnp.stack(index_chunks)

@@ -64,8 +64,11 @@ from rnb_tpu.ops import banded, indexed, moe, rope
 #: choice (``models/token_stages.py``); ``sparse``: the four of the
 #: ``Sparse:`` line, a (query, layer) once, for the heads share a set;
 #: ``index_tiles``: the attention kernel's (query tile, key tile) pairs
-#: in which any query chose any key, and those on or under the diagonal
-COUNTERS = ("expert_served", "gmm_rows", "sparse", "index_tiles")
+#: in which any query chose any key, and those on or under the diagonal;
+#: ``index_chunks``: the (query step, key chunk) visits of a count of
+#: the thresholds, and those of a walk from key 0 to the diagonal
+COUNTERS = ("expert_served", "gmm_rows", "sparse", "index_tiles",
+            "index_chunks")
 #: the lower-precision control's rounding of the indexer's operands
 #: (``scripts/prefill_control.py``): float8 e4m3's exponent and mantissa
 #: bits, through ``lax.reduce_precision`` (a pair of conversions the
@@ -213,7 +216,8 @@ def attention_mixer(cfg, p, h, row_start, row_tokens, positions, tables,
     those that choose (more than ``topk`` keys to read), the keys those
     could read, the keys they chose; int32 (2,): the attention kernel's
     tiles in which any query chose any key, and those on or under the
-    diagonal). ``select`` is a control's: ``"causal"`` reads every key
+    diagonal; int32 (2,): ``ops/indexed.chunk_visits``, the thresholds'
+    walk). ``select`` is a control's: ``"causal"`` reads every key
     a query may read, ``"recent"`` the latest ``topk``."""
     rows, q, _ = h.shape
     act = h.dtype
@@ -254,7 +258,7 @@ def attention_mixer(cfg, p, h, row_start, row_tokens, positions, tables,
         jnp.where(chooses, chose, 0).sum()]).astype(jnp.int32)
     tiles = jnp.stack([reached, jnp.int32(indexed.causal_tiles(tokens))])
     return _proj(out.reshape(rows, q, hq * dim), p["o"]), sets, counts, \
-        tiles
+        tiles, indexed.chunk_visits(at, topk)
 
 
 def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
@@ -306,7 +310,8 @@ def forward(cfg: KeyeVL2Config, params, slots, tokens, row_tokens,
     tokens only; the rows the first grouped product multiplied
     (layers,) int32; the ``Sparse:`` line's four (layers, 4) int32; the
     attention kernel's tiles with a chosen key and on or under the
-    diagonal (layers, 2) int32).
+    diagonal (layers, 2) int32; the thresholds' chunk visits and those
+    of a walk from key 0 (layers, 2) int32).
     """
     rows, q = tokens.shape
     token_ok = jnp.arange(q)[None, :] < row_tokens[:, None]
@@ -316,19 +321,20 @@ def forward(cfg: KeyeVL2Config, params, slots, tokens, row_tokens,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     act = x.dtype
-    chosen, key_sets, served, gmm_rows, sparse, tiles = \
-        [], [], [], [], [], []
+    chosen, key_sets, served, gmm_rows, sparse, tiles, chunks = \
+        [], [], [], [], [], [], []
     for i in range(cfg.num_hidden_layers):
         p = params["l%d" % i]
         with jax.named_scope("attn"):
             h = rms_norm(x, p["attn_norm"], cfg.eps, act)
-            out, sets, counts, ran = attention_mixer(
+            out, sets, counts, ran, walked = attention_mixer(
                 cfg, p, h, row_start, row_tokens, positions, tables,
                 interpret, index_bits, select)
             x = (x.astype(jnp.float32) + out).astype(act)
             key_sets.append(sets)
             sparse.append(counts)
             tiles.append(ran)
+            chunks.append(walked)
         with jax.named_scope("experts"):
             h = rms_norm(x, p["ffn_norm"], cfg.eps, act)
             out, ids, counts, multiplied = experts_ffn(
@@ -343,4 +349,4 @@ def forward(cfg: KeyeVL2Config, params, slots, tokens, row_tokens,
         logits = _proj(last, params["head"])
     return logits, (jnp.stack(chosen), jnp.stack(key_sets)), \
         jnp.stack(served), jnp.stack(gmm_rows), jnp.stack(sparse), \
-        jnp.stack(tiles)
+        jnp.stack(tiles), jnp.stack(chunks)

@@ -32,16 +32,63 @@ pair a query may not read (the future, another request). (T, T) int32:
 **The choice** (:func:`thresholds`, the kernels ``index_threshold`` and
 ``index_tie_cutoff``) is a threshold a query, not a sort: the largest
 ``tau[t]`` with at least ``topk`` keys at or over it, built bit by bit
-from the top (32 counts over the query's row of sort keys, which lies in
-VMEM: a sort of 16,384 floats a query is 105 compare-exchange passes,
-and a rank count 2.7 x 10^8 comparisons), and, where more keys than
-``topk`` lie at or over it — equal scores at the cut —, the position
-``cut[t]`` up to which the keys that *equal* ``tau[t]`` are kept, found
-the same way over the positions' bits in the tiles that hold such a
-query and nowhere else. Query t's set is then ``{s : key[t, s] > tau[t]
-or (key[t, s] == tau[t] and s <= cut[t])}`` among the keys it may read:
-exactly ``min(t + 1, topk)`` of them. A query with ``topk`` keys or
-fewer has ``tau`` the smallest int32: everything.
+from the top (the sign and 31 bits: 32 counts over the query's row of
+sort keys, which lies in VMEM: a sort of 16,384 floats a query is 105
+compare-exchange passes, and a rank count 2.7 x 10^8 comparisons), and,
+where more keys than ``topk`` lie at or over it — equal scores at the
+cut —, the position ``cut[t]`` up to which the keys that *equal*
+``tau[t]`` are kept, found the same way over the positions' bits in the
+steps that hold such a query and nowhere else. Query t's set is then
+``{s : key[t, s] > tau[t] or (key[t, s] == tau[t] and s <= cut[t])}``
+among the keys it may read: exactly ``min(t + 1, topk)`` of them. A
+query with fewer keys than ``topk`` has ``tau`` the smallest int32:
+everything (with exactly ``topk``, its lowest key's: the same set).
+
+A grid step holds 128 queries and their whole rows of sort keys (8 MiB
+at 16,384 keys). *A count* (``_count``) is one ``lax.fori_loop`` over
+the chunks of 1,024 keys from the one that holds the first key of the
+step's first request to the one that holds the step's last query
+(:func:`select_walk`, two numbers a step in scalar memory: the keys in
+front are other requests', all the smallest int32, and those behind the
+future's); inside it every 128 lanes are loaded, compared and added
+*under the lanes* into a (128, 128) int32 carry, a load and three
+vector operations a register with nothing between them, and the lanes
+are summed once, behind the loop. A step none of whose queries has
+``topk`` keys to read counts nothing and moves no rows. The two counts
+a tie needs fall out of the search: the keys at or over ``tau`` are the
+count of the last candidate taken, those over it the count of the last
+one refused (``tau + 1`` is that candidate).
+
+**What the walk replaced, and its sweep** (PR 56; from PR 46 to PR 55 a
+step held 32 queries, a count was a Python loop over eight chunks of
+2,048 keys from key 0 of the pool, each a ``lax.cond`` around a load, a
+compare, a cast and a lane reduction, and two further counts behind the
+search gave the tie's numbers: 34 counts; ``tests/keye_parent.py`` keeps
+that form for the bit-for-bit tests). My chip runs, PR 56, one TPU v5
+lite, 128 rows of 128 tokens as one / two / three requests, ``topk``
+2,048, ``scripts/indexed_sweep.py --only=thresholds``, ms a layer by
+the host's clock around a jitted call, every form's ``tau`` and ``cut``
+the parent's to the bit. *The parent* **11.7 / 11.7 / 11.7**: it walks
+from key 0 whatever the pool holds. *The walk* at (queries a step, keys
+a chunk): (128, 1,024), which stands, **4.99 / 3.26 / 2.76**; (128,
+2,048) 4.99 / 3.33 / 2.73; (128, 512) 5.09 / 3.45 / 2.63; (128, 256)
+5.48 / 3.47 / 2.81; (128, 4,096) 5.41 / 3.89 / 3.72 (before the steps
+that count nothing stopped moving their rows, which took (128, 2,048)
+from 5.10 / 3.70 / 3.31; 80 rows are no whole chunks of 4,096); (64,
+1,024) 5.25 / 3.41 / 2.84; (64, 2,048) 5.27 / 3.85 / 3.47 and (32,
+2,048) 6.07 / 4.50 / 3.89 (likewise before); (256, 512) 7.24 / 4.36 /
+3.53 and (256, 2,048) 6.99 / 4.57 / 4.15: a carry of 32 registers
+beside 32 of candidates no longer fits the register file. Probes (times
+only, not in the tree; (128, 2,048), one / three requests): the kernel
+with its rows never moved 4.97 / 2.81; moving the rows and counting
+nothing **2.5**: 1 GiB a layer arrives at 430 GB/s, not at the 819 of
+the data sheet, and lies under every pool's time since; every count
+with an empty loop 2.5 as well (hidden behind the rows). Over the
+pools the walk's own time is 0.72 cycles a register visited (a load and
+three operations on four vector slots need 0.75) and 1.4 ms a layer of
+fixed cost, 330 cycles a count: the lane sum, the candidate's way back
+under the lanes and the loop's start, once a count where the parent
+paid them once a chunk.
 
 **Attention** (:func:`indexed_attention`, the kernel of that name) is a
 flash kernel from q's product to ``o``'s operand. A grid step is a
@@ -141,6 +188,7 @@ peak: the kernel stands at 71% of it.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -155,9 +203,10 @@ from rnb_tpu.ops.banded import _first_lines
 #: the indexer's scores: queries and keys a tile
 _SCORE_TILE_Q, _SCORE_TILE_K = 512, 1024
 #: the thresholds: queries a step (their whole rows of sort keys lie in
-#: VMEM: 2 MiB at 16,384 keys), and the keys a count looks at together
-#: (a chunk wholly over the step's diagonal is not counted)
-_SELECT_TILE_Q, _SELECT_CHUNK = 32, 2048
+#: VMEM: 8 MiB at 16,384 keys), and the keys a turn of a count's loop
+#: looks at: a count walks the chunks from the step's first request's
+#: first key to its diagonal (the sweep in the module's text)
+_SELECT_TILE_Q, _SELECT_CHUNK = 128, 1024
 #: the attention kernel: queries and keys a tile
 _TILE_Q, _TILE_K = 256, 512
 _MASKED = -1e30
@@ -262,63 +311,199 @@ def index_keys(q, k, w, start, interpret: bool = False):
 # -- the choice -----------------------------------------------------------
 
 
-def _count(keys_ref, test, last):
+def select_form(tokens: int):
+    """(queries a step, keys a chunk) of the thresholds for a pool of
+    ``tokens``: the module's, cut to the pool."""
+    return _tile(_SELECT_TILE_Q, tokens), _tile(_SELECT_CHUNK, tokens)
+
+
+def select_walk(position, topk: int, form=None):
+    """A count's walk over a pool, from the pool's layout alone:
+    ``position`` (T,) int32, a token's index inside its request. -> (lo,
+    hi), (query steps,) int32 each: the first and the last key chunk a
+    count of the step visits; ``lo = hi + 1`` is a step that counts
+    nothing. The thresholds' kernels take both as they come from here,
+    and :func:`chunk_visits` counts them.
+
+    A step walks from the chunk that holds the first key of its *first*
+    query's request (requests lie in the pool in order, so no query of
+    the step may read a key in front of it: all ``LOWEST``) to the chunk
+    that holds its last query (the diagonal). A step none of whose
+    queries has ``topk`` keys to read (``position + 1 < topk``: no
+    candidate over ``LOWEST`` can reach ``topk`` keys) walks nothing."""
+    tokens = position.shape[0]
+    tile, chunk = form or select_form(tokens)
+    # the chunk that holds each step's last query
+    hi = jnp.asarray((np.arange(tokens // tile) * tile + tile - 1) // chunk,
+                     jnp.int32)
+    first = (jnp.arange(tokens, dtype=jnp.int32) - position)[::tile]
+    counts = (position + 1 >= topk).reshape(-1, tile).any(axis=1)
+    return jnp.where(counts, first // chunk, hi + 1), hi
+
+
+def chunk_visits(position, topk: int):
+    """int32 (2,): the (query step, key chunk) visits a count makes on
+    :func:`select_walk`'s walk over the pool, and those of a walk from
+    key 0 to every step's diagonal (the thresholds' until PR 55), both
+    at the module's queries a step and keys a chunk."""
+    lo, hi = select_walk(position, topk)
+    return jnp.stack([(hi + 1 - lo).sum(), (hi + 1).sum()])
+
+
+def _lanes(chunk: int) -> int:
+    """The lanes a count's sums lie under: a register's 128, fewer in a
+    toy pool."""
+    return math.gcd(chunk, 128)
+
+
+def _count(keys_ref, lo, hi, chunk: int, test):
     """(tile, 1) int32: over a step's rows of sort keys, the keys with
-    ``test(keys, their positions)``, a chunk at a time; a chunk that
-    begins behind position ``last`` holds nothing a query may read."""
-    tile, tokens = keys_ref.shape
-    chunk = min(_SELECT_CHUNK, tokens)
-    total = jnp.zeros((tile, 1), jnp.int32)
-    for lo in range(0, tokens, chunk):
-        def some(lo=lo):
-            at = lo + lax.broadcasted_iota(jnp.int32, (tile, chunk), 1)
-            hit = test(keys_ref[:, lo:lo + chunk], at)
-            return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
-        total = total + lax.cond(lo <= last, some,
-                                 lambda: jnp.zeros((tile, 1), jnp.int32))
-    return total
+    ``test(keys, the pool position of the first of them)`` in the chunks
+    ``lo`` to ``hi`` of ``chunk`` keys. One loop over the chunks; inside
+    it the hits of each 128 lanes are added under the lanes, and the
+    lanes are summed once, behind the loop."""
+    tile, lanes = keys_ref.shape[0], _lanes(chunk)
+
+    def some(c, total):
+        for at in range(0, chunk, lanes):
+            first = pl.multiple_of(c * chunk + at, lanes)
+            total = total + test(keys_ref[:, pl.ds(first, lanes)],
+                                 first).astype(jnp.int32)
+        return total
+    total = lax.fori_loop(lo, hi + 1, some,
+                          jnp.zeros((tile, lanes), jnp.int32))
+    return jnp.sum(total, axis=1, keepdims=True)
 
 
-def _threshold_kernel(keys_ref, tau_ref, over_ref, reach_ref, *, topk: int):
+def _under_lanes(column, chunk: int):
+    """A (tile, 1) column as :func:`_count`'s test reads it: a row's
+    number under each of a register's lanes."""
+    return jnp.broadcast_to(column, (column.shape[0], _lanes(chunk)))
+
+
+def _threshold_kernel(lo_ref, hi_ref, fetch_ref, keys_ref, tau_ref, over_ref,
+                      reach_ref, *, topk: int, chunk: int):
+    i = pl.program_id(0)
     tile = keys_ref.shape[0]
-    last = pl.program_id(0) * tile + tile - 1
+    lo, hi = lo_ref[i], hi_ref[i]
 
-    def at_least(cand):
-        return _count(keys_ref, lambda keys, _: keys >= cand, last)
-    # the sign first, then the 31 bits under it from the top: the
-    # largest value that topk keys reach
-    tau = jnp.where(at_least(jnp.zeros((tile, 1), jnp.int32)) >= topk,
-                    0, LOWEST).astype(jnp.int32)
+    @pl.when(lo > hi)
+    def _():
+        # no query here has topk keys to read: everything, no tie
+        tau_ref[...] = jnp.full((tile, 1), LOWEST, jnp.int32)
+        over_ref[...] = jnp.zeros((tile, 1), jnp.int32)
+        reach_ref[...] = jnp.zeros((tile, 1), jnp.int32)
 
-    def step(n, tau):
-        cand = tau | (jnp.int32(1) << (30 - n))
-        return jnp.where(at_least(cand) >= topk, cand, tau)
-    tau = lax.fori_loop(0, 31, step, tau)
-    tau_ref[...] = tau
-    over_ref[...] = _count(keys_ref, lambda keys, _: keys > tau, last)
-    reach_ref[...] = at_least(tau)
+    @pl.when(lo <= hi)
+    def _():
+        def accept(cand, tau, over, reach):
+            wide = _under_lanes(cand, chunk)
+            count = _count(keys_ref, lo, hi, chunk,
+                           lambda keys, _: keys >= wide)
+            ok = count >= topk
+            # ``reach``, the keys at or over tau, is the count of the
+            # last candidate taken; ``over``, the keys over tau, that of
+            # the last one refused: tau + 1 is tau with its lowest 0 bit
+            # set and the 1s under it cleared, the candidate of that
+            # bit's turn, and every later one was taken (tau with no 0
+            # under the sign: the sign's own turn, or none, and no key
+            # lies over the largest int32)
+            return jnp.where(ok, cand, tau), jnp.where(ok, over, count), \
+                jnp.where(ok, count, reach)
+        # the sign first, then the 31 bits under it from the top: the
+        # largest value that topk keys reach. At or over LOWEST lies
+        # every key walked
+        zero = jnp.zeros((tile, 1), jnp.int32)
+        carry = accept(zero, jnp.full((tile, 1), LOWEST, jnp.int32), zero,
+                       jnp.full((tile, 1), (hi + 1 - lo) * chunk, jnp.int32))
+
+        def step(n, carry):
+            return accept(carry[0] | (jnp.int32(1) << (30 - n)), *carry)
+        tau_ref[...], over_ref[...], reach_ref[...] = lax.fori_loop(
+            0, 31, step, carry)
 
 
-def _tie_kernel(fetch_ref, tied_ref, keys_ref, tau_ref, want_ref, cut_ref,
-                *, bits: int):
+def _tie_kernel(lo_ref, hi_ref, fetch_ref, tied_ref, keys_ref, tau_ref,
+                want_ref, cut_ref, *, bits: int, chunk: int):
     i = pl.program_id(0)
     tile, tokens = keys_ref.shape
-    last = i * tile + tile - 1
     cut_ref[...] = jnp.full((tile, 1), tokens, jnp.int32)
 
     @pl.when(tied_ref[i] != 0)
     def _():
-        tau, want = tau_ref[...], want_ref[...]
+        tau, want = _under_lanes(tau_ref[...], chunk), want_ref[...]
+        lane = lax.broadcasted_iota(jnp.int32, tau.shape, 1)
 
         def step(n, cut):
             cand = cut | (jnp.int32(1) << (bits - 1 - n))
-            before = _count(keys_ref, lambda keys, at:
-                            (keys == tau) & (at < cand), last)
+            before = _count(keys_ref, lo_ref[i], hi_ref[i], chunk,
+                            lambda keys, first:
+                            (keys == tau) & (lane < cand - first))
             return jnp.where(before < want, cand, cut)
         # the largest position with fewer than ``want`` equal keys in
         # front of it: where the want-th of them lies
         cut_ref[...] = lax.fori_loop(
             0, bits, step, jnp.zeros((tile, 1), jnp.int32))
+
+
+# under a ``jit`` of its own, like the attention kernel's call: a
+# stack's layers call it with the same shapes, and the two kernels are
+# traced and lowered once for all (``form`` is a key of that cache: the
+# sweep sets the module's constants)
+@functools.partial(jax.jit, static_argnames=("topk", "form", "interpret"))
+def _thresholds_call(keys, position, *, topk, form, interpret):
+    tokens = keys.shape[0]
+    tile, chunk = form
+    steps = tokens // tile
+    lo, hi = select_walk(position, topk, form)
+
+    def held(runs):
+        # a step that does not run moves no rows: it names the block
+        # the last step that ran held
+        return lax.cummax(jnp.where(runs, jnp.arange(steps), 0), axis=0) \
+            .astype(jnp.int32)
+    rows = pl.BlockSpec((tile, tokens),
+                        lambda i, lo, hi, fetch, *_: (fetch[i], 0))
+    one = pl.BlockSpec((tile, 1), lambda i, *_: (i, 0))
+    column = jax.ShapeDtypeStruct((tokens, 1), jnp.int32)
+    tau, over, reach = pl.pallas_call(
+        functools.partial(_threshold_kernel, topk=topk, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(steps,), in_specs=[rows],
+            out_specs=[one, one, one]),
+        out_shape=[column, column, column],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=THRESHOLD_KERNEL)(lo, hi, held(lo <= hi),
+                                                    keys)
+    # a query that chooses, with more keys at or over tau than topk:
+    # equal scores at the cut. Its set takes the first ``want`` of the
+    # keys that equal tau: topk less those over it. Why the keys the
+    # walk leaves out (in front of the step's first request: LOWEST,
+    # every one) cannot change ``tied`` or ``cut``: a score's sort key
+    # is never LOWEST (that is one NaN's bits and no float's), so a
+    # query with over topk keys to read has a tau over LOWEST, and no
+    # left-out key is at or over it, over it, or equal to it: ``reach``,
+    # ``over`` and the tie kernel's counts are what a walk from key 0
+    # gives. A query whose tau is LOWEST has topk keys or fewer and is
+    # not tied whatever its ``reach`` (every key walked) reads
+    tied = (position[:, None] + 1 > topk) & (reach > topk)
+    tile_tied = tied.reshape(steps, tile).any(axis=1)
+    cut = pl.pallas_call(
+        functools.partial(_tie_kernel, chunk=chunk,
+                          bits=max(1, int(tokens - 1).bit_length())),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(steps,),
+            in_specs=[rows, one, one], out_specs=one),
+        out_shape=column,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=TIE_KERNEL,
+    )(lo, hi, held(tile_tied), tile_tied.astype(jnp.int32), keys, tau,
+      topk - over)
+    return tau[:, 0], jnp.where(tied, cut, tokens)[:, 0]
 
 
 def thresholds(keys, position, topk: int, interpret: bool = False):
@@ -327,45 +512,9 @@ def thresholds(keys, position, topk: int, interpret: bool = False):
     each: query t's set is the keys it may read with ``key > tau[t]``,
     or ``key == tau[t]`` at a pool position ``<= cut[t]``; exactly
     ``min(position + 1, topk)`` keys."""
-    tokens = keys.shape[0]
-    tile = _tile(_SELECT_TILE_Q, tokens)
-    steps = tokens // tile
-    one = pl.BlockSpec((tile, 1), lambda i, *_: (i, 0))
-    column = jax.ShapeDtypeStruct((tokens, 1), jnp.int32)
-    tau, over, reach = pl.pallas_call(
-        functools.partial(_threshold_kernel, topk=topk),
-        grid=(steps,),
-        in_specs=[pl.BlockSpec((tile, tokens), lambda i: (i, 0))],
-        out_specs=[one, one, one], out_shape=[column, column, column],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name=THRESHOLD_KERNEL)(keys)
-    # a query that chooses, with more keys at or over tau than topk:
-    # equal scores at the cut. Its set takes the first ``want`` of the
-    # keys that equal tau: topk less those over it
-    tied = (position[:, None] + 1 > topk) & (reach > topk)
-    tile_tied = tied.reshape(steps, tile).any(axis=1)
-    # a step with no such query moves no rows: it names the block the
-    # step before it held
-    fetch = lax.cummax(jnp.where(tile_tied, jnp.arange(steps), 0), axis=0)
-    cut = pl.pallas_call(
-        functools.partial(_tie_kernel,
-                          bits=max(1, int(tokens - 1).bit_length())),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(steps,),
-            in_specs=[pl.BlockSpec((tile, tokens),
-                                   lambda i, fetch, _: (fetch[i], 0)),
-                      one, one],
-            out_specs=one),
-        out_shape=column,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name=TIE_KERNEL,
-    )(fetch.astype(jnp.int32), tile_tied.astype(jnp.int32), keys, tau,
-      topk - over)
-    return tau[:, 0], jnp.where(tied, cut, tokens)[:, 0]
+    return _thresholds_call(keys, position, topk=int(topk),
+                            form=select_form(keys.shape[0]),
+                            interpret=bool(interpret))
 
 
 # -- the sets, as a mask and as bits --------------------------------------

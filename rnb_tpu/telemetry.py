@@ -1043,6 +1043,15 @@ STAGE_COUNTERS = (
         "tile) pairs in which any query chose any key, and those on or "
         "under the diagonal, at its own tile sizes"),
     StageCounter(
+        "index_chunks", "Sparse:", "sparse_",
+        ("chunks_walked", "chunks_to_diagonal"),
+        "(sparse layers, 2), the thresholds under an indexer's scores "
+        "(``ops/indexed.chunk_visits``): the (query step, key chunk) "
+        "visits a count's walk makes, from the chunk of the step's "
+        "first request's first key to the diagonal's and none in a "
+        "step no query of which has ``topk`` keys to read, and those "
+        "of a walk from key 0 to every step's diagonal"),
+    StageCounter(
         "attn_tiles", "Attention:", "attention_",
         ("tiles_visited", "tiles_causal"),
         "(attention layers, 2), the packed flash kernel "

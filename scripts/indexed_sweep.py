@@ -11,9 +11,15 @@ both) and the attention kernel as it stands (the forms PR 54 timed
 beside it — queries a tile, the heads that share a product, the mask as
 a ``where``, k transposed, the heads' loop rolled — are a record in
 ``ops/indexed.py``'s text: none was faster, and the kernel keeps one).
+The thresholds' time stands beside the parent's (``tests/keye_parent.py``:
+PR 46's, a ``lax.cond`` and a lane reduction a chunk from key 0), with
+``tau`` and ``cut`` compared to the bit and the walk's chunk visits;
+``--only=thresholds`` is that line alone, at each (queries a step, keys a
+chunk) of ``--select=32-2048,128-2048,...`` (the module's constants are
+set for each: the kernels take no such argument).
 Lines go to stdout and to ``chiprun_out/indexed_sweep/sweep.jsonl``.
 
-    chiprun -- python3 scripts/indexed_sweep.py [--rows=128]
+    chiprun -- python3 scripts/indexed_sweep.py [--rows=128] [--only=thresholds [--select=...]]
 
 ``--shape=latent`` (PR 55) is the same module at dots3-note's shapes,
 with ``ops/banded.py``'s latent kernel beside it: the scores at 64 index
@@ -482,11 +488,58 @@ def latent_main():
                          tokens * 2 * block, SLIDING, tokens)[0]})
 
 
+def thresholds_line(keys, position, parent=None):
+    """The thresholds as they stand, timed, beside the parent's
+    (``parent``: its results and time, computed here where None):
+    -> ((tau, cut), the parent's pair, the line's fields)."""
+    def call(f):
+        return jax.jit(lambda keys, p: f(keys, p, TOPK, INTERPRET))
+    (tau, cut), ms = timed(call(indexed.thresholds), keys, position)
+    (want_tau, want_cut), parent_ms = parent or timed(
+        call(keye_parent.thresholds), keys, position)
+    walked, to_diagonal = (int(n) for n in
+                           indexed.chunk_visits(position, TOPK))
+    return (tau, cut), ((want_tau, want_cut), parent_ms), {
+        "thresholds_ms": ms, "parent_thresholds_ms": parent_ms,
+        "queries_a_step": indexed._SELECT_TILE_Q,
+        "keys_a_chunk": indexed._SELECT_CHUNK,
+        "chunks_walked": walked, "chunks_to_diagonal": to_diagonal,
+        "tau_differ": int((np.asarray(tau) != np.asarray(want_tau)).sum()),
+        "cut_differ": int((np.asarray(cut) != np.asarray(want_cut)).sum())}
+
+
+def thresholds_main():
+    """The thresholds alone over one, two and three requests, at each
+    (queries a step, keys a chunk) of ``--select``."""
+    rng = np.random.default_rng(46)
+    tokens = ROWS * QLEN
+    q, k, w = operands(rng, tokens)
+    standing = "%d-%d" % (indexed._SELECT_TILE_Q, indexed._SELECT_CHUNK)
+    forms = [tuple(int(n) for n in form.split("-"))
+             for form in option("select", standing).split(",")]
+    for requests in (1, 2, 3):
+        _, start, position = pool(ROWS, requests)
+        keys = jax.jit(lambda q, k, w, s: indexed.index_keys(
+            q, k, w, s, INTERPRET))(q, k, w, start)
+        parent = None
+        for form in forms:
+            indexed._SELECT_TILE_Q, indexed._SELECT_CHUNK = form
+            try:
+                _, parent, line = thresholds_line(keys, position, parent)
+            except Exception as e:                          # VMEM, mostly
+                say({"requests": requests, "form": form,
+                     "failed": str(e)[:300]})
+                continue
+            say(dict(line, requests=requests, tokens=tokens))
+
+
 def main():
     if option("shape", "keye") == "latent":
         return latent_main()
-    rng = np.random.default_rng(46)
     say({"device": DEVICE.device_kind, "rows": ROWS})
+    if option("only", "") == "thresholds":
+        return thresholds_main()
+    rng = np.random.default_rng(46)
     check(rng)
     tokens = ROWS * QLEN
     q, k, w = operands(rng, tokens)
@@ -499,10 +552,8 @@ def main():
             lambda q, k, w, s: indexed.index_keys(q, k, w, s,
                                                   INTERPRET)),
             q, k, w, start)
-        (tau, cut), line["thresholds_ms"] = timed(jax.jit(
-            lambda keys, p: indexed.thresholds(keys, p, TOPK,
-                                               INTERPRET)),
-            keys, position)
+        (tau, cut), _, fields = thresholds_line(keys, position)
+        line.update(fields)
         positions = rope.pool_positions(row_start, QLEN)
         tables = banded.band_tables(row_start, QLEN, INV_FREQ)
         (out, _), line["parent_ms"] = timed(

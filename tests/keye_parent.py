@@ -4,8 +4,12 @@ the tests that hold PR 54's kernel to it bit for bit and for
 q in front of the kernel (head norm, rotary, scale, rounding, the copy
 into (key-value head, query tile, (head, query), D)) and the flash
 kernel of one key-value head a grid step, which built its mask once a
-key-value head and laid it under itself once a query head. Nothing in
-``rnb_tpu`` imports this."""
+key-value head and laid it under itself once a query head. And, at the
+end, the thresholds as the tree ran them from PR 46 to PR 55
+(:func:`thresholds`: 32 queries a step, a ``lax.cond`` and a lane
+reduction a chunk of 2,048 keys from key 0 to the diagonal, 34 counts),
+for the tests that hold PR 56's walk to them bit for bit and for the
+sweep's timings beside it. Nothing in ``rnb_tpu`` imports this."""
 
 import functools
 
@@ -18,9 +22,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from rnb_tpu.models.keye_vl2.network import rms_norm
 from rnb_tpu.ops import rope
-from rnb_tpu.ops.indexed import _MASKED, _VMEM_LIMIT, attention_tiles
+from rnb_tpu.ops.indexed import (LOWEST, _MASKED, _VMEM_LIMIT, _tile,
+                                 attention_tiles)
 
 KERNEL = "indexed_attention_one_head"
+#: the thresholds' queries a step and keys a chunk, PR 46's; the tests
+#: set them small beside ``ops/indexed.py``'s
+SELECT_TILE_Q, SELECT_CHUNK = 32, 2048
 
 
 def _attention_kernel(lo_ref, hi_ref, q_ref, k_ref, v_ref, keys_ref, tau_ref,
@@ -165,3 +173,105 @@ def indexed_attention(q, k, v, keys, tau, cut, q_weight, tables, eps,
         qs.reshape(tokens, groups, -1, dim), k.reshape(tokens, groups, dim),
         v.reshape(tokens, groups, dim), keys, tau, cut, start, interpret)
     return out.reshape(tokens, -1), sets
+
+
+# -- the thresholds, PR 46 to PR 55 ----------------------------------------
+
+
+def _count(keys_ref, test, last):
+    """(tile, 1) int32: over a step's rows of sort keys, the keys with
+    ``test(keys, their positions)``, a chunk at a time; a chunk that
+    begins behind position ``last`` holds nothing a query may read."""
+    tile, tokens = keys_ref.shape
+    chunk = min(SELECT_CHUNK, tokens)
+    total = jnp.zeros((tile, 1), jnp.int32)
+    for lo in range(0, tokens, chunk):
+        def some(lo=lo):
+            at = lo + lax.broadcasted_iota(jnp.int32, (tile, chunk), 1)
+            hit = test(keys_ref[:, lo:lo + chunk], at)
+            return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+        total = total + lax.cond(lo <= last, some,
+                                 lambda: jnp.zeros((tile, 1), jnp.int32))
+    return total
+
+
+def _threshold_kernel(keys_ref, tau_ref, over_ref, reach_ref, *, topk: int):
+    tile = keys_ref.shape[0]
+    last = pl.program_id(0) * tile + tile - 1
+
+    def at_least(cand):
+        return _count(keys_ref, lambda keys, _: keys >= cand, last)
+    # the sign first, then the 31 bits under it from the top: the
+    # largest value that topk keys reach
+    tau = jnp.where(at_least(jnp.zeros((tile, 1), jnp.int32)) >= topk,
+                    0, LOWEST).astype(jnp.int32)
+
+    def step(n, tau):
+        cand = tau | (jnp.int32(1) << (30 - n))
+        return jnp.where(at_least(cand) >= topk, cand, tau)
+    tau = lax.fori_loop(0, 31, step, tau)
+    tau_ref[...] = tau
+    over_ref[...] = _count(keys_ref, lambda keys, _: keys > tau, last)
+    reach_ref[...] = at_least(tau)
+
+
+def _tie_kernel(fetch_ref, tied_ref, keys_ref, tau_ref, want_ref, cut_ref,
+                *, bits: int):
+    i = pl.program_id(0)
+    tile, tokens = keys_ref.shape
+    last = i * tile + tile - 1
+    cut_ref[...] = jnp.full((tile, 1), tokens, jnp.int32)
+
+    @pl.when(tied_ref[i] != 0)
+    def _():
+        tau, want = tau_ref[...], want_ref[...]
+
+        def step(n, cut):
+            cand = cut | (jnp.int32(1) << (bits - 1 - n))
+            before = _count(keys_ref, lambda keys, at:
+                            (keys == tau) & (at < cand), last)
+            return jnp.where(before < want, cand, cut)
+        # the largest position with fewer than ``want`` equal keys in
+        # front of it: where the want-th of them lies
+        cut_ref[...] = lax.fori_loop(
+            0, bits, step, jnp.zeros((tile, 1), jnp.int32))
+
+
+def thresholds(keys, position, topk: int, interpret: bool = False):
+    """``ops/indexed.thresholds``' arguments and results as the tree
+    computed them until PR 55 (a query is ``tied`` where its ``cut`` lies
+    under the pool's size)."""
+    tokens = keys.shape[0]
+    tile = _tile(SELECT_TILE_Q, tokens)
+    steps = tokens // tile
+    one = pl.BlockSpec((tile, 1), lambda i, *_: (i, 0))
+    column = jax.ShapeDtypeStruct((tokens, 1), jnp.int32)
+    tau, over, reach = pl.pallas_call(
+        functools.partial(_threshold_kernel, topk=topk),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((tile, tokens), lambda i: (i, 0))],
+        out_specs=[one, one, one], out_shape=[column, column, column],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name="index_threshold_parent")(keys)
+    tied = (position[:, None] + 1 > topk) & (reach > topk)
+    tile_tied = tied.reshape(steps, tile).any(axis=1)
+    fetch = lax.cummax(jnp.where(tile_tied, jnp.arange(steps), 0), axis=0)
+    cut = pl.pallas_call(
+        functools.partial(_tie_kernel,
+                          bits=max(1, int(tokens - 1).bit_length())),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(steps,),
+            in_specs=[pl.BlockSpec((tile, tokens),
+                                   lambda i, fetch, _: (fetch[i], 0)),
+                      one, one],
+            out_specs=one),
+        out_shape=column,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name="index_tie_cutoff_parent",
+    )(fetch.astype(jnp.int32), tile_tied.astype(jnp.int32), keys, tau,
+      topk - over)
+    return tau[:, 0], jnp.where(tied, cut, tokens)[:, 0]

@@ -378,6 +378,15 @@ def test_the_counters_are_a_numpy_count(toy):
     # alone hold its chosen keys
     assert counts["index_tiles"][:, 1].tolist() == [10, 10]
     assert (counts["index_tiles"][:, 0] <= 10).all()
+    # the thresholds' walk, by hand from the pool's layout: 4 query
+    # steps of 128 over one chunk of 512 keys, none counted in a step
+    # no query of which has topk keys to read
+    row_start = np.asarray(pack(prompts, 16)[1][1])
+    position = np.arange(16 * Q) - np.repeat(row_start * Q, Q)
+    walked = sum(int((position[i:i + 128] + 1 >= TOPK).any())
+                 for i in range(0, 16 * Q, 128))
+    assert counts["index_chunks"].tolist() == [[walked, 4]] * 2
+    assert 0 < walked <= 4
     assert counts["expert_served"].shape == (4, 8)
     assert counts["pair_rows"].shape == (4, 2) \
         and counts["gmm_rows"].shape == (4,)

@@ -105,7 +105,9 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert [line.split(":")[0] for line in lines] \
         == ["Tokens", "Experts", "Sparse", "Attention"]
     assert " pair_rows_moved=" in lines[1] and " gmm_rows=" in lines[1]
-    assert " tiles_chosen=" in lines[2]
+    assert " tiles_chosen=" in lines[2] \
+        and " chunks_walked=" in lines[2] \
+        and lines[2].endswith(" chunks_to_diagonal=%d" % (2 * 2 * 2))
     assert lines[3].startswith("Attention: window_tiles_visited=24 ") \
         and " window_keys_kept=" in lines[3]
     assert fields["window_keys_causal"] == 6 * int(at.sum())
@@ -161,6 +163,7 @@ def run_the_cell(trace, tmp_path):
     meta = (out / "run" / "log-meta.txt").read_text()
     for name in ("Tokens: valid=", "Experts:", " gmm_rows=",
                  " pair_rows_moved=", "Sparse: queries=", " tiles_chosen=",
+                 " chunks_walked=", " chunks_to_diagonal=",
                  "Attention: window_tiles_visited=", " window_keys_kept="):
         assert name in meta, name
     samples = sorted((out / "run").glob("prefill-sample-*.npz"))
@@ -183,6 +186,7 @@ def run_the_cell(trace, tmp_path):
         assert 0 < metrics["sparse_query_pct.bulk"]["value"] < 100
         assert 0 < metrics["selected_key_pct.bulk"]["value"] < 100
         assert 0 < metrics["chosen_tile_pct.bulk"]["value"] <= 100
+        assert 0 < metrics["select_chunk_walk_pct.bulk"]["value"] <= 100
         assert 0 < metrics["window_key_pct.bulk"]["value"] < 100
         assert 0 < metrics["gmm_row_fill_pct.bulk"]["value"] <= 100
         assert 0 < metrics["pair_rows_moved_pct.bulk"]["value"] <= 100
@@ -278,8 +282,9 @@ def test_the_cell_joins_the_accepted_metrics_its_readers_serve():
               if CELL in m["workloads"]]
     # ISSUE 55's 23 and the eight of set-up, the two accepted readers
     # that find this family's scope and counter (the indexer's, the
-    # window's tiles), and the cell's own seven
-    assert len(joined) == 23 + 8 + 2 + 7
+    # window's tiles), the cell's own seven, and PR 56's reader of the
+    # thresholds' walk, which came with both cells that run it
+    assert len(joined) == 23 + 8 + 2 + 7 + 1
     assert {"indexer_ms_per_dispatch.bulk",
             "window_tile_visit_pct.bulk"} <= set(joined)
     # readers of another family's kernel or scope by name stay as they were

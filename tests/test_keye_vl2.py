@@ -273,6 +273,177 @@ def test_a_set_is_the_topk_keys_of_a_sort(topk):
         assert (np.asarray(cut) < tokens).any()
 
 
+#: pools for the thresholds' walk at 8 queries a step and 128 keys a
+#: chunk, rows of 4 tokens: (rows, the rows that open a request, pad rows
+#: behind, ``topk``, repeated index keys (copy, of)). Requests begin
+#: inside a chunk and inside a query step (row 75: token 300; row 161:
+#: token 644), pad rows are requests of their own, and the repeats put
+#: equal scores at some query's cut in every request
+WALKS = {
+    "one_request": (128, [0], 0, 40,
+                    ((70, 65), (66, 65), (90, 65), (200, 130), (131, 130))),
+    "two_requests": (256, [0, 75], 6, 40,
+                     ((70, 65), (66, 65), (90, 65), (420, 400), (401, 400),
+                      (900, 650), (651, 650))),
+    "three_requests": (256, [0, 33, 161], 3, 24,
+                       ((70, 65), (66, 65), (300, 210), (211, 210),
+                        (800, 700), (701, 700), (760, 700))),
+    "over_topk_nowhere": (128, [0, 20, 50, 90], 8, 300, ((70, 65),)),
+}
+
+
+def a_walk(name):
+    """-> (sort keys (T, T), each token's request's first token, its
+    index inside the request, ``topk``)."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import indexed
+    rows, firsts, pads, topk, repeats = WALKS[name]
+    firsts = firsts + list(range(rows - pads, rows))
+    rng = np.random.default_rng(len(name))
+    tokens = rows * 4
+    row_start = jnp.asarray([max(f for f in firsts if f <= r)
+                             for r in range(rows)], jnp.int32)
+    row_tokens = jnp.asarray([4] * (rows - pads) + [0] * pads, jnp.int32)
+    start, _ = indexed.token_table(row_start, row_tokens, 4)
+    q = jnp.asarray(rng.normal(size=(tokens, 4, 16)), jnp.bfloat16)
+    k = np.asarray(rng.normal(size=(tokens, 16)), np.float32)
+    for copy, of in repeats:
+        k[copy] = k[of]
+    w = jnp.asarray(rng.normal(size=(tokens, 4)), jnp.float32)
+    keys = indexed.index_keys(q, jnp.asarray(k, jnp.bfloat16), w, start,
+                              interpret=True)
+    return keys, np.asarray(start), np.arange(tokens) - np.asarray(start), \
+        topk
+
+
+def small_steps(monkeypatch):
+    """8 queries a step and 128 keys a chunk for the walk, 32 and 256
+    for the parent's: pools of a few hundred tokens are many of each."""
+    import keye_parent
+    from rnb_tpu.ops import indexed
+    monkeypatch.setattr(indexed, "_SELECT_TILE_Q", 8)
+    monkeypatch.setattr(indexed, "_SELECT_CHUNK", 128)
+    monkeypatch.setattr(keye_parent, "SELECT_TILE_Q", 32)
+    monkeypatch.setattr(keye_parent, "SELECT_CHUNK", 256)
+
+
+def visits_by_hand(start, position, topk, tile, chunk):
+    """(the chunks a count visits, those from key 0 to the diagonal),
+    a query step at a time."""
+    walked = whole = 0
+    for first in range(0, len(start), tile):
+        last = first + tile - 1
+        whole += last // chunk + 1
+        if (position[first:first + tile] + 1 >= topk).any():
+            walked += last // chunk - start[first] // chunk + 1
+    return [walked, whole]
+
+
+@pytest.mark.parametrize("pool", sorted(WALKS))
+def test_the_walk_gives_the_parents_thresholds_to_the_bit(pool,
+                                                          monkeypatch):
+    """One loop from the chunk of the step's first request's first key
+    to the diagonal's, sums under the lanes, ``over`` and ``reach`` the
+    counts of the last candidate refused and taken, nothing counted in
+    a step no query of which has ``topk`` keys: ``tau`` and ``cut``,
+    and so which queries are tied, are the parent's (a ``lax.cond`` a
+    chunk from key 0, 34 counts: ``tests/keye_parent.py``) bit for
+    bit; the sets are a sort's; the walk's chunk visits are a count by
+    hand."""
+    import jax.numpy as jnp
+
+    import keye_parent
+    from rnb_tpu.ops import indexed
+    small_steps(monkeypatch)
+    keys, start, position, topk = a_walk(pool)
+    tokens = len(start)
+    at = jnp.asarray(position, jnp.int32)
+    tau, cut = (np.asarray(x) for x in indexed.thresholds(
+        keys, at, topk, interpret=True))
+    want_tau, want_cut = (np.asarray(x) for x in keye_parent.thresholds(
+        keys, at, topk, interpret=True))
+    assert tau.dtype == want_tau.dtype and cut.dtype == want_cut.dtype
+    assert np.array_equal(tau, want_tau) and np.array_equal(cut, want_cut)
+    # the sets: a stable sort by descending key keeps the lower position
+    # of equal keys first, and what may not be read (LOWEST) last
+    order = np.argsort(-np.asarray(keys, np.int64), axis=1, kind="stable")
+    want = np.zeros((tokens, tokens), bool)
+    for t in range(tokens):
+        want[t, order[t, :min(position[t] + 1, topk)]] = True
+    mask = np.asarray(indexed.chosen_mask(
+        keys, jnp.asarray(tau), jnp.asarray(cut), jnp.asarray(start)))
+    assert (mask == want).all()
+    lo, hi = (np.asarray(x) for x in indexed.select_walk(at, topk))
+    assert np.asarray(indexed.chunk_visits(at, topk)).tolist() \
+        == [int((hi + 1 - lo).sum()), int((hi + 1).sum())] \
+        == visits_by_hand(start, position, topk, 8, 128)
+    tied = cut < tokens
+    if pool == "over_topk_nowhere":
+        # every query reads everything: no step counts, none is tied
+        assert (lo == hi + 1).all() and (tau == indexed.LOWEST).all() \
+            and not tied.any()
+        return
+    # steps that count nothing, steps that begin behind key 0, a step
+    # that holds the end of a request and the head of the next, a query
+    # with exactly topk keys (its tau is its lowest key's), ties in
+    # every request
+    assert (lo == hi + 1).any() and (tau[position + 1 < topk]
+                                     == indexed.LOWEST).all()
+    assert (tau[position + 1 == topk] > indexed.LOWEST).all()
+    requests = np.unique(start[position + 1 > topk])
+    assert len(requests) == len(WALKS[pool][1])
+    assert all(tied[start == first].any() for first in requests)
+    if len(requests) > 1:
+        assert ((lo > 0) & (lo <= hi)).any()
+        straddles = (start[::8] != start[7::8]) & (lo <= hi)
+        assert straddles.any()
+
+
+@pytest.mark.parametrize("case", ["few_values", "all_equal", "sign_only"])
+def test_the_walk_under_keys_that_are_mostly_ties(case, monkeypatch):
+    """Sort keys made by hand, most of them equal: few values (every
+    query past ``topk`` is tied), one value (``tau`` is it, the cut is
+    the ``topk``-th position), the values -1 and the largest int32 (a
+    ``tau`` with no 0 bit under its sign: ``over`` is then the sign's
+    own count, or none's) — over two requests, the second from inside a
+    chunk, with a step whose queries all read everything and steps
+    whose walk begins behind key 0. ``tau``, ``cut`` and which queries
+    are tied are the parent's."""
+    import jax.numpy as jnp
+
+    import keye_parent
+    from rnb_tpu.ops import indexed
+    small_steps(monkeypatch)
+    tokens, second, topk = 512, 200, 24
+    rng = np.random.default_rng(len(case))
+    at = np.arange(tokens)
+    start = np.where(at < second, 0, second)
+    mine = (at[None, :] <= at[:, None]) & (at[None, :] >= start[:, None])
+    values = {"few_values": rng.integers(-3, 4, (tokens, tokens)),
+              "all_equal": np.full((tokens, tokens), 7),
+              "sign_only": rng.choice([-1, np.iinfo(np.int32).max],
+                                      (tokens, tokens))}[case]
+    keys = jnp.asarray(np.where(mine, values, indexed.LOWEST), jnp.int32)
+    position = jnp.asarray(at - start, jnp.int32)
+    tau, cut = (np.asarray(x) for x in indexed.thresholds(
+        keys, position, topk, interpret=True))
+    want_tau, want_cut = (np.asarray(x) for x in keye_parent.thresholds(
+        keys, position, topk, interpret=True))
+    assert np.array_equal(tau, want_tau) and np.array_equal(cut, want_cut)
+    chooses = at - start + 1 > topk
+    assert ((cut < tokens) == (want_cut < tokens)).all() \
+        and (cut < tokens)[chooses].mean() > 0.9 \
+        and not (cut < tokens)[~chooses].any()
+    lo, hi = (np.asarray(x) for x in indexed.select_walk(position, topk))
+    assert (lo == hi + 1).sum() == 4 and lo[second // 8 + 3:].min() == 1
+    if case == "all_equal":
+        assert (tau[chooses] == 7).all() \
+            and (cut[chooses] == start[chooses] + topk - 1).all()
+    if case == "sign_only":
+        assert set(tau[chooses].tolist()) <= {-1, np.iinfo(np.int32).max}
+
+
 def inv_freq(dim, theta=1e7):
     return (theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)) \
         .astype(np.float32)
@@ -515,7 +686,8 @@ def test_every_expert_is_held_and_the_shares_add_up(toy):
     assert np.abs(np.asarray(whole)
                   - np.asarray(halves[0] + halves[1])).max() < 1e-5
     assert cfg.num_experts_per_tok == 2 and network.COUNTERS == (
-        "expert_served", "gmm_rows", "sparse", "index_tiles")
+        "expert_served", "gmm_rows", "sparse", "index_tiles",
+        "index_chunks")
 
 
 # -- the counters ---------------------------------------------------------------------
@@ -534,6 +706,14 @@ def test_the_counters_are_a_numpy_count(toy):
     # the kernel's tiles: a pool of 512 tokens is 2 x 1 tiles of 256 x
     # 512, both on or under the diagonal, both with a chosen key
     assert tiles.tolist() == [[2, 2]] * 3
+    # the thresholds' walk: the pool's layout, a count by hand (at the
+    # module's 128 queries a step a pool of 512 tokens is 4 steps over
+    # one chunk, a query with topk keys to read in each)
+    tokens, meta, _ = pack(prompts, 32)
+    start = np.repeat(np.asarray(meta[1]) * Q, Q)
+    assert counts[4].tolist() == [visits_by_hand(
+        start, np.arange(32 * Q) - start, TOPK, 128, 512)] * 3 \
+        == [[4, 4]] * 3
     chosen = sum(int(sets_of(k, n)[0][TOPK:].sum())
                  for k, n in zip(kept, lengths))
     assert chosen == want[3]
@@ -541,6 +721,8 @@ def test_the_counters_are_a_numpy_count(toy):
     # all and says so
     _, kept_all, counts_all = run_program(toy, prompts, 32, select="causal")
     assert counts_all[2][0].tolist() == want[:3] + [want[2]]
+    # and its thresholds count nothing: no query has the pool's 512 keys
+    assert counts_all[4].tolist() == [[0, 4]] * 3
     assert (sets_of(kept_all[2], 230)[0]
             == np.tril(np.ones((230, 230), bool))).all()
     _, kept_recent, _ = run_program(toy, prompts, 32, select="recent")
@@ -643,6 +825,9 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert counters["expert_served"].sum() == 3 * 2 * valid
     assert counters["sparse"].tolist()[:2] == [3 * valid, 3 * (102 + 182)]
     assert counters["index_tiles"].tolist() == [6, 6]
+    # 4 query steps of 128 over one chunk of 512 keys, a query with
+    # topk keys in each: 3 layers
+    assert counters["index_chunks"].tolist() == [12, 12]
     lines, fields = stage_counter_report([counters, counters])
     assert lines[0] == "Tokens: valid=%d shipped=%d" % (2 * valid,
                                                         2 * 32 * Q)
@@ -650,9 +835,12 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
                                % (12 * valid, 12 * valid))
     assert lines[2].startswith("Sparse: queries=%d selecting=%d "
                                % (6 * valid, 6 * 284))
-    assert lines[2].endswith(" tiles_chosen=12 tiles_causal=12")
+    assert lines[2].endswith(" tiles_chosen=12 tiles_causal=12"
+                             " chunks_walked=24 chunks_to_diagonal=24")
     assert (fields["sparse_tiles_chosen"],
             fields["sparse_tiles_causal"]) == (12, 12)
+    assert (fields["sparse_chunks_walked"],
+            fields["sparse_chunks_to_diagonal"]) == (24, 24)
     assert fields["experts_held"] == fields["experts_assignments"]
     for scope in ("/attn/", "/attn/select/", "/attn/select/index/",
                   "/attn/kernel/", "/experts/", "/head/", "/embed/"):
@@ -855,7 +1043,8 @@ def test_the_cell_through_the_benchmark_command(trace, tmp_path):
     assert line["attempted"] > 0
     meta = (out / "run" / "log-meta.txt").read_text()
     for name in ("Tokens: valid=", "Experts:", " gmm_rows=",
-                 "Sparse: queries=", " tiles_chosen="):
+                 "Sparse: queries=", " tiles_chosen=", " chunks_walked=",
+                 " chunks_to_diagonal="):
         assert name in meta, name
     samples = sorted((out / "run").glob("prefill-sample-*.npz"))
     assert len(samples) == 8
@@ -875,6 +1064,7 @@ def test_the_cell_through_the_benchmark_command(trace, tmp_path):
         assert 0 < metrics["sparse_query_pct.bulk"]["value"] < 100
         assert 0 < metrics["selected_key_pct.bulk"]["value"] < 100
         assert 0 < metrics["chosen_tile_pct.bulk"]["value"] <= 100
+        assert 0 < metrics["select_chunk_walk_pct.bulk"]["value"] <= 100
         assert 0 < metrics["gmm_row_fill_pct.bulk"]["value"] <= 100
         # what stands against the chip's peak, or comes from the
         # device's trace, does not come from a CPU
@@ -926,13 +1116,17 @@ def test_the_control_script_runs_the_familys_arms(tmp_path):
     assert out["recent_keys"]["share_of_spread"] > 0.2
 
 
-# -- the five new readers -------------------------------------------------------------
+# -- the five new readers, and PR 56's ------------------------------------------------
 
+#: a reader's scope path, or the two fields of the result it divides
 NEW_READERS = {"indexer_ms_per_dispatch.bulk": "attn/select/index",
                "select_roofline_pct.bulk": "attn/select",
                "indexed_attn_ms_per_dispatch.bulk": "attn/kernel",
                "indexed_attn_roofline_pct.bulk": "attn/kernel",
-               "chosen_tile_pct.bulk": None}
+               "chosen_tile_pct.bulk": ("sparse_tiles_chosen",
+                                        "sparse_tiles_causal"),
+               "select_chunk_walk_pct.bulk": ("sparse_chunks_walked",
+                                              "sparse_chunks_to_diagonal")}
 
 
 @pytest.mark.parametrize("name", sorted(NEW_READERS))
@@ -944,10 +1138,12 @@ def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
     module = mm.load_layer_metric(name)
     entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
     # PR 55's cell, an indexer's sets under latent attention, joined the
-    # three that read what both families write
+    # three that read what both families write; PR 56's reader came
+    # with both
     joined = ["dots3-note.bulk"] if name in (
         "select_roofline_pct.bulk", "chosen_tile_pct.bulk",
-        "indexer_ms_per_dispatch.bulk") else []
+        "indexer_ms_per_dispatch.bulk", "select_chunk_walk_pct.bulk") \
+        else []
     assert entry and entry[0]["workloads"] == [CELL] + joined
     assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == "packed attention"
@@ -966,11 +1162,12 @@ def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
         device_kind = "TPU v5 lite"
     assert module.read(Facts) is None
     path = NEW_READERS[name]
-    if path is None:
-        Result.sparse_tiles_chosen, Result.sparse_tiles_causal = 0, 0
-        assert module.read(Facts) is None
-        Result.sparse_tiles_chosen, Result.sparse_tiles_causal = 30, 40
-        assert module.read(Facts) == 75.0
+    if isinstance(path, tuple):
+        # the parent's result has neither field: nothing, not a raise
+        for part, whole in ((0, 0), (30, 40)):
+            setattr(Result, path[0], part)
+            setattr(Result, path[1], whole)
+            assert module.read(Facts) == (75.0 if whole else None)
         return
 
     class Trace:
@@ -1496,7 +1693,11 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     scale and rounding in; nothing else imports it: the five older
     families' texts are the ones they had, and
     :func:`test_the_toy_stack_serves_the_parents_logits_to_the_bit`
-    holds the new text's values to the old one's)."""
+    holds the new text's values to the old one's). PR 56 recorded it
+    once more (the thresholds' counts walk a step's own keys in one
+    loop; ``ops/indexed.py`` alone, which no older family imports;
+    :func:`test_the_walk_gives_the_parents_thresholds_to_the_bit` holds
+    the values)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

@@ -626,12 +626,14 @@ def test_the_sparse_line_is_declared_and_parsed(tmp_path):
     from rnb_tpu import telemetry
     sparse = [row for row in telemetry.STAGE_COUNTERS
               if row.line == "Sparse:"]
-    # behind the four: the tiles of a learned indexer's kernel (PR 46),
-    # which this family does not count
+    # behind the four: the tiles of a learned indexer's kernel (PR 46)
+    # and the chunk visits of its thresholds (PR 56), which this family
+    # does not count
     assert [(row.counter, row.keys) for row in sparse] == [
         ("sparse", ("queries", "selecting", "causal_keys",
                     "chosen_keys")),
-        ("index_tiles", ("tiles_chosen", "tiles_causal"))]
+        ("index_tiles", ("tiles_chosen", "tiles_causal")),
+        ("index_chunks", ("chunks_walked", "chunks_to_diagonal"))]
     (tmp_path / "log-meta.txt").write_text(
         "Tokens: valid=10 shipped=16\n"
         "Sparse: queries=20 selecting=12 causal_keys=90 chosen_keys=60\n")

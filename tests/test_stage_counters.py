@@ -34,6 +34,7 @@ RAW = {
     "gmm_rows": [128, 256],
     "sparse": [[20, 12, 90, 60], [20, 8, 70, 50]],
     "index_tiles": [[3, 4], [2, 4]],
+    "index_chunks": [[9, 16], [9, 16]],
     "scan_resets": [21],
     "window_keys": [[30, 70], [30, 70]],
 }
@@ -56,12 +57,14 @@ GOLDEN = {
                                "window_tiles_causal=4"],
     "keye_vl2": [TOKENS, EXPERTS + " gmm_rows=384",
                  "Sparse: queries=40 selecting=20 causal_keys=160 "
-                 "chosen_keys=110 tiles_chosen=5 tiles_causal=8"],
+                 "chosen_keys=110 tiles_chosen=5 tiles_causal=8 "
+                 "chunks_walked=18 chunks_to_diagonal=32"],
     "falcon_h1": [TOKENS + " scan_resets=21", ATTENTION],
     "dots3_note": [TOKENS, EXPERTS + " pair_rows_moved=22 pair_rows_all=80 "
                                      "gmm_rows=384",
                    "Sparse: queries=40 selecting=20 causal_keys=160 "
-                   "chosen_keys=110 tiles_chosen=5 tiles_causal=8",
+                   "chosen_keys=110 tiles_chosen=5 tiles_causal=8 "
+                   "chunks_walked=18 chunks_to_diagonal=32",
                    "Attention: window_tiles_visited=3 window_tiles_causal=4 "
                    "window_keys_kept=60 window_keys_causal=140"],
 }
@@ -122,7 +125,8 @@ def test_a_familys_counters_give_the_lines_the_launcher_wrote(family,
     (GOLDEN["keye_vl2"][2],
      {"sparse_queries": 40, "sparse_selecting": 20,
       "sparse_causal_keys": 160, "sparse_chosen_keys": 110,
-      "sparse_tiles_chosen": 5, "sparse_tiles_causal": 8}),
+      "sparse_tiles_chosen": 5, "sparse_tiles_causal": 8,
+      "sparse_chunks_walked": 18, "sparse_chunks_to_diagonal": 32}),
     (GOLDEN["exaone_moe"][2],
      {"attention_tiles_visited": 9, "attention_tiles_causal": 15,
       "attention_window_tiles_visited": 3,
@@ -194,7 +198,9 @@ def test_the_most_loaded_expert_is_taken_after_the_sum():
     (("expert_served", "attn_tiles"),
      ("Sparse:", "group_tokens", "pair_rows", "gmm_rows", "window_")),
     (("sparse",), ("tiles_chosen",)),
-], ids=["tokens-only", "no-experts", "no-tails", "no-index-tiles"])
+    (("sparse", "index_tiles"), ("chunks_",)),
+], ids=["tokens-only", "no-experts", "no-tails", "no-index-tiles",
+        "no-index-chunks"])
 def test_what_no_stage_counts_is_not_written(names, absent):
     lines, fields = stage_counter_report([snapshot_of(names)])
     text = "\n".join(lines)
