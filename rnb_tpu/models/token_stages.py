@@ -1,6 +1,6 @@
-"""The pipeline stages of the token families (``nemotron_h``,
-``deepseek_v2``, ``minicpm_sala``, ``qwen3_next``, ``exaone_moe``,
-``keye_vl2``, ``kimi_linear``, ``falcon_h1``, ``dots3_note``): a first stage whose
+"""The pipeline stages of the token families (the packages under
+``rnb_tpu/models/`` that bring ``checkpoint`` and ``network``;
+``tests/family_contract.py``'s ``FAMILIES`` lists them): a first stage whose
 request is a prompt file, and a final stage that runs a family's stack
 over a packed pool of rows. Between them stands ``rnb_tpu.batcher.Batcher``
 (``segments: true``), which fuses requests into row buckets up to the

@@ -26,6 +26,9 @@ import pytest
 jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# the token families' shared assertions live in a module that is not
+# collected: its ``assert``s report what they compared all the same
+pytest.register_assert_rewrite("family_contract")
 
 
 @pytest.fixture(autouse=True)

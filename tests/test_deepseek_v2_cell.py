@@ -1,0 +1,20 @@
+"""``deepseek-v2.bulk`` through the one benchmark command, traced and
+untraced, and the final stage serving the family, at a toy size on the
+CPU, by ``family_contract.py``; the record is ``test_deepseek_v2.py``'s.
+A file of its own because one file is one worker's under ``--dist
+loadfile`` and a run takes most of a minute."""
+
+import pytest
+
+import family_contract as contract
+
+FAMILY = contract.record("deepseek_v2")
+
+
+@pytest.mark.parametrize("trace", FAMILY.traces)
+def test_the_cell_through_the_benchmark_command(trace, tmp_path):
+    contract.run_the_cell(FAMILY, trace, tmp_path)
+
+
+def test_the_prefill_stage_serves_the_family(tmp_path):
+    contract.stage_serves(FAMILY, tmp_path)

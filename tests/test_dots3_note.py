@@ -13,8 +13,10 @@ makes the model: two geometries with different head counts, key widths
 and ranks, keys wider than values (a full layer's own key whole lane
 tiles, a sliding layer's padded), a window that is no multiple of a tile
 and shorter than the prompts, a top-k smaller than the prompts, 8 experts
-a share of 64. ``test_dots3_note_cell.py`` drives the stages and the one
-benchmark command over the same toy."""
+a share of 64. Then the family's record for ``family_contract.py``, by
+which ``test_dots3_note_cell.py`` drives the stages, the control script
+and the one benchmark command over the same toy, and the seven new
+readers on a run without their kernel or counter."""
 
 import contextlib
 import functools
@@ -30,6 +32,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [REPO, os.path.join(REPO, "scripts")]
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import dots3_note as reference  # noqa: E402
@@ -135,14 +138,17 @@ def run_program(toy, prompts, rows, fault=None, **arm):
     import prefill_control
     from rnb_tpu.models.dots3_note import network
     tokens, meta, offsets = pack(prompts, rows)
-    if fault is None:
-        program, params, patch = _program(**arm), toy["params"], {}
-    else:
+    fault = fault or {}
+    patch = fault.get("patch", {})
+    if "cfg" in fault or patch:
         cfg = fault.get("cfg", toy["cfg"])
         program = jax.jit(lambda p, s, t, m: network.forward(
             cfg, p, s, t, m[0], m[1], m[2], interpret=True, **arm))
-        params = prefill_control.planted(toy["params"], fault)
-        patch = fault.get("patch", {})
+    else:
+        # a fault of the parameter tree alone is the stated program
+        # given other values: what a test before it compiled
+        program = _program(**arm)
+    params = prefill_control.planted(toy["params"], fault)
     with mock.patch.multiple(network, **patch) if patch \
             else contextlib.nullcontext():
         logits, chosen, *counts = program(params, toy["slots"], tokens,
@@ -530,3 +536,198 @@ def test_the_toy_keeps_what_makes_the_model():
     assert real.sliding.key_lanes == real.sliding.lanes == 256
     assert WINDOW % 16 and TOPK < min(DISPATCHES["under_and_over"][:2])
     assert cfg.rescales(full) == (2 ** 0.5, (64 / 24) ** 0.5)
+
+
+# -- the family's record for ``family_contract.py`` -----------------------
+
+
+def toy_config():
+    config = real_config()
+    config.update(TOY)
+    config["experts_held"] = {"first": 0, "count": 8}
+    config["vocab_held"] = {"first": 0, "count": TOY["vocab_size"]}
+    config["model"] = dict(config["model"], layers=5)
+    config["dataset"] = {"seed": 0, "long_every": 11,
+                         "short": {"count": 6, "median": 110, "sigma": 0.5,
+                                   "min": 30, "max": 200},
+                         "long": {"count": 2, "min": 200, "max": 256}}
+    config["capacity_videos_per_chip_s"] = 60
+    config["share_of_spread"] = TOY_LIMIT
+    config["key_slack"] = TOY_KEY_SLACK
+    config["ref_pad"] = 64
+    loader, batcher, prefill = config["pipeline_config"]["pipeline"]
+    loader.update(max_rows=8, chunk=Q)
+    batcher.update(batch=8, shapes=[[8, Q], [8]], row_buckets=[8])
+    prefill.update(max_rows=8, chunk=Q, row_buckets=[8],
+                   sample_every=3, samples=8)
+    return config
+
+
+def the_stage_counts_what_the_four_lines_carry(served):
+    """``family_contract.stage_serves``'s entry for this family: what
+    the four log-meta lines carry, the kernels' names, and both kinds of
+    choice in a sample."""
+    from rnb_tpu.ops import banded, indexed
+    from rnb_tpu.telemetry import stage_counter_report
+    stage, valid = served.stage, served.valid
+    counters = stage.stage_counters()
+    at = np.concatenate([np.arange(len(p)) for p in served.prompts]) + 1
+    # two full layers, two dispatches
+    assert counters["sparse"].tolist() == [
+        4 * valid, 4 * int((at > TOPK).sum()),
+        4 * int(at[at > TOPK].sum()), 4 * int((at > TOPK).sum()) * TOPK]
+    # three sliding layers, two dispatches: the pairs a window of 37 keeps
+    assert counters["window_keys"].tolist() == [
+        6 * int(np.minimum(at, 37).sum()), 6 * int(at.sum())]
+    assert counters["window_tiles"].tolist() == [6 * 4, 6 * 6]
+    assert counters["index_tiles"].tolist()[1] == 4
+    assert counters["expert_served"].shape == (4, 8)
+    lines, fields = stage_counter_report([counters])
+    assert [line.split(":")[0] for line in lines] \
+        == ["Tokens", "Experts", "Sparse", "Attention"]
+    assert " pair_rows_moved=" in lines[1] and " gmm_rows=" in lines[1]
+    assert " tiles_chosen=" in lines[2] \
+        and " chunks_walked=" in lines[2] \
+        and lines[2].endswith(" chunks_to_diagonal=%d" % (2 * 2 * 2))
+    assert lines[3].startswith("Attention: window_tiles_visited=24 ") \
+        and " window_keys_kept=" in lines[3]
+    assert fields["window_keys_causal"] == 6 * int(at.sum())
+    kernels = " ".join(stage.hlo_scopes)
+    for kernel in (indexed.LATENT_KERNEL, banded.LATENT_KERNEL_NAME,
+                   indexed.SCORES_KERNEL, "mla_queries"):
+        assert kernel in kernels or stage._jax_device.platform != "tpu"
+    first = stage._samples[0]
+    assert first["key_sets"].shape[:2] == (2, 120) and first["first"] == 0
+    assert first["logits"].shape == (TOY["vocab_size"],)
+
+
+def the_controls_read(out):
+    # the witness is recorded either way: the toy's rescale is 1.4 and
+    # 1.6, not the published 2.2 and 3.2, and its old draw is no sharper
+    assert "old_draw" in mm.load_family("dots3_note").CONTROL_MAY_PASS
+    assert "no_rescale" not in out
+
+
+#: ``tests/test_dots3_note_cell.py`` runs it
+CONTRACT = contract.Family(
+    name="dots3_note", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED, HELD),
+    meta=("Tokens: valid=", "Experts:", " gmm_rows=", " pair_rows_moved=",
+          "Sparse: queries=", " tiles_chosen=", " chunks_walked=",
+          " chunks_to_diagonal=", "Attention: window_tiles_visited=",
+          " window_keys_kept="),
+    scopes=("/attn/select/index/", "/attn/full/", "/attn/window/",
+            "/attn/mla_proj/", "/attn/gate/"),
+    sample_fields=("tokens", "logits", "chosen", "key_sets", "first"),
+    sample_shapes={"key_sets": (2,)},      # the full layers'
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "held_assignment_pct.bulk": "(0, 100)",
+        "expert_load_max_over_mean.bulk": "[1, inf)",
+        "sparse_query_pct.bulk": "(0, 100)",
+        "selected_key_pct.bulk": "(0, 100)",
+        "chosen_tile_pct.bulk": "(0, 100]",
+        "select_chunk_walk_pct.bulk": "(0, 100]",
+        "window_key_pct.bulk": "(0, 100)",
+        "gmm_row_fill_pct.bulk": "(0, 100]",
+        "pair_rows_moved_pct.bulk": "(0, 100]"},
+    not_from_a_cpu="roofline|util|_ms_per_|busy_pct",
+    stage=contract.Stage(
+        lengths=(120, 70, 30), row_buckets=(8,), dispatches=2,
+        scopes=("/embed/", "/attn/", "/attn/mla_proj/", "/attn/gate/",
+                "/attn/select/", "/attn/select/index/", "/attn/full/",
+                "/attn/window/", "/experts/", "/head/"),
+        chosen_shape=(4, 120, 8),
+        also=the_stage_counts_what_the_four_lines_carry),
+    # as stated inside the limit and both slacks, the arms asked for
+    # outside one of them
+    control=contract.Control(
+        lengths="150,30,230",
+        arms="index_float8,flat_gates,old_draw,layers_float8",
+        outside=("index_float8", "flat_gates", "layers_float8"),
+        reads={("as_stated", "key_shortfall_max"):
+               "(-inf, %r)" % TOY_KEY_SLACK,
+               ("old_draw", "share_of_spread"): "(0, inf)",
+               ("index_float8", "key_shortfall_max"):
+               "(%r, inf)" % TOY_KEY_SLACK,
+               ("flat_gates", "share_of_spread"): "(0.2, inf)"},
+        also=the_controls_read))
+
+
+# -- the seven new readers ------------------------------------------------
+
+NEW_READERS = {
+    "mla_index_scores_roofline_pct.bulk": "index_scores",
+    "mla_indexed_attn_ms_per_dispatch.bulk": "latent_indexed_attention",
+    "mla_indexed_attn_roofline_pct.bulk": "latent_indexed_attention",
+    "mla_window_attn_ms_per_dispatch.bulk": "latent_banded_attention",
+    "mla_window_attn_roofline_pct.bulk": "latent_banded_attention",
+    "mla_latent_proj_ms_per_dispatch.bulk": "attn/mla_proj",
+    "window_key_pct.bulk": None}
+
+
+@pytest.mark.parametrize("name", sorted(NEW_READERS))
+def test_a_new_reader_reads_nothing_on_a_run_without_its_kernel(
+        name, tmp_path):
+    """No trace, or a counter that counted nothing (the parent's
+    program): None, not a raise. The kernels' names are the program's."""
+    from rnb_tpu.ops import banded, indexed
+    module = mm.load_layer_metric(name)
+    entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
+    assert entry and entry[0]["workloads"] == [CELL]
+    assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
+    assert module.LAYER == "packed attention"
+
+    class Result:
+        log_dir = str(tmp_path)
+        tokens_valid = 100
+        pad_emissions = 2
+
+    class Facts:
+        trace = None
+        result = Result
+        family = mm.load_family("dots3_note")
+        config = real_config()
+        peak_flops_per_s = 1.97e14
+        device_kind = "TPU v5 lite"
+    assert module.read(Facts) is None
+    if NEW_READERS[name] is None:
+        Result.window_keys_kept, Result.window_keys_causal = 0, 0
+        assert module.read(Facts) is None
+        Result.window_keys_kept, Result.window_keys_causal = 9, 100
+        assert module.read(Facts) == 9.0
+        return
+    if "/" in NEW_READERS[name]:
+        # a reader of a scope the family names, not of a kernel
+        assert not hasattr(module, "KERNEL")
+        assert '"%s"' % NEW_READERS[name] in inspect.getsource(module.read)
+        return
+    assert module.KERNEL == NEW_READERS[name] and module.KERNEL in (
+        indexed.SCORES_KERNEL, indexed.LATENT_KERNEL,
+        banded.LATENT_KERNEL_NAME)
+    # another family's file counts none of these mechanisms: no raise
+    Facts.family = mm.load_family("keye_vl2")
+    assert module.read(Facts) is None
+
+
+def test_the_cell_joins_the_accepted_metrics_its_readers_serve():
+    per_layer = {m["name"]: m for m in mm.load()["per_layer"]}
+    joined = [name for name, m in per_layer.items()
+              if CELL in m["workloads"]]
+    # ISSUE 55's 23 and the eight of set-up, the two accepted readers
+    # that find this family's scope and counter (the indexer's, the
+    # window's tiles), the cell's own seven, and PR 56's reader of the
+    # thresholds' walk, which came with both cells that run it
+    assert len(joined) == 23 + 8 + 2 + 7 + 1
+    assert {"indexer_ms_per_dispatch.bulk",
+            "window_tile_visit_pct.bulk"} <= set(joined)
+    # readers of another family's kernel or scope by name stay as they were
+    for name in ("mla_proj_ms_per_dispatch.bulk", "flash_roofline_pct.bulk",
+                 "indexed_attn_roofline_pct.bulk",
+                 "window_attn_roofline_pct.bulk"):
+        assert CELL not in per_layer[name]["workloads"]
+    for m in per_layer.values():
+        assert m["workloads"].count(CELL) <= 1
+        if CELL in m["workloads"] and len(m["workloads"]) > 1:
+            assert m["workloads"][-1] == CELL

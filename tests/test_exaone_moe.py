@@ -17,7 +17,6 @@ Nothing here needs the native decode library or a chip."""
 import hashlib
 import json
 import os
-import subprocess
 import sys
 import types
 
@@ -27,6 +26,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import exaone_moe as reference  # noqa: E402
@@ -71,7 +71,7 @@ def toy():
     from rnb_tpu.models.exaone_moe import checkpoint, network
     cfg = network.ExaoneMoeConfig.from_published(TOY)
     device = jax.devices()[0]
-    return {"cfg": cfg, "device": device,
+    return {"cfg": cfg, "device": device, "programs": {},
             "params": checkpoint.make_params(cfg, SEED, HELD, device),
             "slots": network.held_slots(cfg, HELD),
             "read": checkpoint.reference_reader(cfg, SEED, device),
@@ -89,17 +89,27 @@ def pack(prompts, rows):
     return token_stages.pack_prompts(prompts, rows, Q)
 
 
-def run_program(toy, prompts, rows, params=None):
-    """-> (logits a prompt, each prompt's router choices (expert layers,
-    tokens, k), the counters)."""
+def program_of(toy, **arm):
+    """The toy stack jitted once an arm of ``forward``, kept on the
+    module's ``toy``: a test that runs it at rows another has run traces
+    and compiles nothing."""
     import jax
 
     from rnb_tpu.models.exaone_moe import network
+    key = tuple(sorted(arm.items()))
+    if key not in toy["programs"]:
+        toy["programs"][key] = jax.jit(
+            lambda p, t, m: network.forward(
+                toy["cfg"], p, toy["slots"], t, m[0], m[1], m[2],
+                interpret=True, **arm))
+    return toy["programs"][key]
+
+
+def run_program(toy, prompts, rows, params=None):
+    """-> (logits a prompt, each prompt's router choices (expert layers,
+    tokens, k), the counters)."""
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, *counts = jax.jit(
-        lambda p, t, m: network.forward(
-            toy["cfg"], p, toy["slots"], t, m[0], m[1], m[2],
-            interpret=True))(
+    logits, chosen, *counts = program_of(toy)(
         toy["params"] if params is None else params, tokens, meta)
     chosen = np.asarray(chosen)
     per_prompt = [chosen[:, o * Q:o * Q + len(p)]
@@ -397,37 +407,12 @@ def test_recipe_gives_program_and_reference_the_same_values(toy):
 # -- the stages -----------------------------------------------------------------
 
 
-def test_the_prefill_stage_serves_the_family(tmp_path):
-    """The final stage learns the family from the recipe, counts the
-    held experts' assignments and both kinds of layer's tiles, names the
-    scopes the readers look for and keeps the router's choices."""
-    from rnb_tpu.devices import DeviceSpec
-    from rnb_tpu.models import token_stages
-    from rnb_tpu.models.exaone_moe import checkpoint
-    from rnb_tpu.stage import PaddedBatch
+def the_stage_counts_both_kinds_of_layer(served):
+    """``family_contract.stage_serves``'s entry for this family: the
+    held experts' assignments, both kinds of layer's tiles and the pair
+    rows that moved."""
     from rnb_tpu.telemetry import stage_counter_report
-    recipe = str(tmp_path / "toy.recipe.json")
-    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
-    stage = token_stages.PackedPrefill(
-        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
-        row_buckets=[4, 8], family="exaone_moe", sample_every=1, samples=2)
-    assert stage.family == "exaone_moe" and stage._slots is not None
-    prompts = prompts_of([80, 9, 30], seed=2)
-    tokens, meta, offsets = pack(prompts, 8)
-    batch = PaddedBatch(tokens, offsets[-1])
-    batch.segment_offsets = tuple(offsets)
-
-    class Card:
-        def __init__(self, rid):
-            self.id = rid
-
-    class Cards:
-        time_cards = [Card(0), Card(1), Card(2)]
-    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, Cards())
-    counters = stage.stage_counters()
-    valid = sum(len(p) for p in prompts)
-    assert counters["tokens_valid"] == valid
-    assert counters["tokens_shipped"] == 8 * Q
+    counters, valid = served.stage.stage_counters(), served.valid
     assert counters["experts_per_token"] == 4
     assert counters["expert_served"].shape == (4, 2)
     assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
@@ -448,20 +433,6 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     lines, _ = stage_counter_report([counters])
     assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
     assert lines[1].startswith("Experts: ")
-    for scope in ("/attn/", "/attn/window/", "/attn/full/",
-                  "/attn/window/kernel/", "/attn/full/kernel/",
-                  "/experts/", "/head/", "/embed/"):
-        assert any(scope in name + "/"
-                   for name in stage.hlo_scopes.values()), scope
-    # a sample's arrays start for the host behind the next launch and
-    # are read behind the one after
-    assert len(stage._sampled) == 2 and not stage._samples
-    stage._send_samples()
-    stage._collect_samples()
-    assert len(stage._samples) == 2
-    first = stage._samples[0]
-    assert first["tokens"].tolist() == prompts[0].tolist()
-    assert first["chosen"].shape == (4, 80, 4)
 
 
 def test_the_attention_line_carries_the_window_pair(tmp_path):
@@ -565,7 +536,8 @@ def toy_config():
                          "short": {"count": 6, "median": 60, "sigma": 0.5,
                                    "min": 20, "max": 100},
                          "long": {"count": 2, "min": 100, "max": 128}}
-    config["capacity_videos_per_chip_s"] = 300
+    # sized to what a CPU serves: ``family_contract.py``, "The backlog"
+    config["capacity_videos_per_chip_s"] = 130
     config["share_of_spread"] = TOY_LIMIT
     loader, batcher, prefill = config["pipeline_config"]["pipeline"]
     loader.update(max_rows=8, chunk=16)
@@ -575,87 +547,34 @@ def toy_config():
     return config
 
 
-def toy_tree(tmp_path):
-    """The real manifest's new cell over a toy-width copy of its
-    configuration: the same family, stages, mix and readers."""
-    os.makedirs(tmp_path / "benchmarks" / "configs")
-    with open(tmp_path / REAL, "w") as f:
-        json.dump(toy_config(), f)
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(mm.load(), f)
-    return str(tmp_path / "BENCHMARK.json")
-
-
-@pytest.mark.parametrize("trace", [0, 1])
-def test_the_cell_through_the_benchmark_command(trace, tmp_path):
-    out = tmp_path / "out"
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
-         "--manifest", toy_tree(tmp_path), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
-         "--platform", "cpu", "--out", str(out)],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0, \
-        done.stderr[-3000:]
-    assert line["attempted"] > 0
-    meta = (out / "run" / "log-meta.txt").read_text()
-    for name in ("Tokens: valid=", "Experts:", "Attention: tiles_visited=",
-                 " window_tiles_visited=", " pair_rows_moved="):
-        assert name in meta, name
-    assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
-    with open(out / "run" / "hlo-scopes.json") as f:
-        scopes = list(json.load(f).values())
-    for scope in ("/attn/window/kernel/", "/attn/full/kernel/"):
-        assert any(scope in name + "/" for name in scopes), scope
-    metrics = line["metrics"]
-    if trace:
-        assert metrics["tokens_per_s.bulk"]["value"] > 0
-        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
-        assert 0 < metrics["held_assignment_pct.bulk"]["value"] < 100
-        assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
-        assert 0 < metrics["flash_tile_visit_pct.bulk"]["value"] <= 100
-        assert 0 < metrics["window_tile_visit_pct.bulk"]["value"] <= 100
+#: ``tests/test_exaone_moe_cell.py`` runs it
+CONTRACT = contract.Family(
+    name="exaone_moe", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED, HELD),
+    meta=("Tokens: valid=", "Experts:", "Attention: tiles_visited=",
+          " window_tiles_visited=", " pair_rows_moved="),
+    scopes=("/attn/window/kernel/", "/attn/full/kernel/"),
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "held_assignment_pct.bulk": "(0, 100)",
+        "expert_load_max_over_mean.bulk": "[1, inf)",
+        "flash_tile_visit_pct.bulk": "(0, 100]",
+        "window_tile_visit_pct.bulk": "(0, 100]",
         # 8 rows of 16 tokens: no capacity, all pairs move
-        assert metrics["pair_rows_moved_pct.bulk"]["value"] == 100
-        # what stands against the chip's peak, or comes from the
-        # device's trace, does not come from a CPU
-        assert not any("roofline" in n or "util" in n or "_ms_per_" in n
-                       or "busy_pct" in n for n in metrics)
-    else:
-        assert metrics["videos_per_s"]["value"] > 0
-        assert metrics["setup_s"]["value"] > 0
-
-
-def test_the_parent_fails_on_the_cell_before_jax_starts(tmp_path):
-    """A checkout whose program lacks the family (the parent of PR 42,
-    given this PR's benchmark files): the family file's ``build`` says
-    so and exits, no result line."""
-    family = mm.load_family("exaone_moe")
-    os.makedirs(tmp_path / "rnb_tpu" / "models")
-    with pytest.raises(SystemExit, match="exaone_moe"):
-        family.build(str(tmp_path))
-    family.build(REPO)
-
-
-def test_the_control_script_takes_the_family_from_the_recipe(tmp_path):
-    """``scripts/prefill_control.py`` over a toy-width copy of the
-    configuration's file: as stated inside the limit, the float8 arms
-    outside it."""
-    path = tmp_path / "toy.json"
-    path.write_text(json.dumps(toy_config()))
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
-         "--config", str(path), "--lengths", "37,120,70"],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    out = json.loads(done.stdout.strip().splitlines()[-1])
-    assert out["family"] == "exaone_moe" and out["ok"]
-    assert out["as_stated"]["ok"] and not out["layers_float8"]["ok"]
-    assert "share_of_spread" in out["experts_float8"]
+        "pair_rows_moved_pct.bulk": "[100, 100]"},
+    not_from_a_cpu="roofline|util|_ms_per_|busy_pct",
+    stage=contract.Stage(
+        lengths=(80, 9, 30), row_buckets=(4, 8),
+        scopes=("/attn/", "/attn/window/", "/attn/full/",
+                "/attn/window/kernel/", "/attn/full/kernel/", "/experts/",
+                "/head/", "/embed/"),
+        chosen_shape=(4, 80, 4),
+        also=the_stage_counts_both_kinds_of_layer),
+    # as stated inside the limit, the float8 arms outside it
+    control=contract.Control(
+        lengths="37,120,70", outside=("layers_float8",),
+        reads={("experts_float8", "share_of_spread"): "[0, inf)"}))
 
 
 # -- the four new readers -------------------------------------------------------------

@@ -1,14 +1,14 @@
-"""``falcon-h1.bulk`` through the one benchmark command, traced and
-untraced, the control script's arms and the final stage serving the
-family, at a toy size on the CPU, by ``family_contract.py``; the record
-is ``test_falcon_h1.py``'s. A file of its own because one file is one
+"""``k-exaone.bulk`` through the one benchmark command, the control
+script's arms and the final stage serving the family, at a toy size on
+the CPU, by ``family_contract.py``; the record is
+``test_exaone_moe.py``'s. A file of its own because one file is one
 worker's under ``--dist loadfile`` and a run takes over a minute."""
 
 import pytest
 
 import family_contract as contract
 
-FAMILY = contract.record("falcon_h1")
+FAMILY = contract.record("exaone_moe")
 
 
 @pytest.mark.parametrize("trace", FAMILY.traces)

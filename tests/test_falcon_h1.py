@@ -10,9 +10,11 @@ spreads; the operation counts and the issue's 430,120,032 parameters a
 layer; the real configuration against the catalog's row. The toy keeps
 what makes the shape: 5 query heads a key-value head, 2 groups of 16
 scan heads, a state twice a head's width, all multipliers as published.
-The stage, the control script and the cell are
-``test_falcon_h1_cell.py``'s (one file is one worker's under ``--dist
-loadfile``). Nothing here needs the native decode library or a chip."""
+Then the family's record for ``family_contract.py``, by which
+``test_falcon_h1_cell.py`` runs the stage, the control script and the
+cell (one file is one worker's under ``--dist loadfile``), and the six
+new readers on a run without their scope, kernel or counter. Nothing
+here needs the native decode library or a chip."""
 
 import json
 import os
@@ -24,6 +26,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import falcon_h1 as reference  # noqa: E402
@@ -505,7 +508,8 @@ def toy_config():
                          "short": {"count": 6, "median": 60, "sigma": 0.5,
                                    "min": 20, "max": 100},
                          "long": {"count": 2, "min": 100, "max": 128}}
-    config["capacity_videos_per_chip_s"] = 60
+    # sized to what a CPU serves: ``family_contract.py``, "The backlog"
+    config["capacity_videos_per_chip_s"] = 40
     config["share_of_spread"] = TOY_LIMIT
     loader, batcher, prefill = config["pipeline_config"]["pipeline"]
     loader.update(max_rows=8, chunk=16)
@@ -513,3 +517,197 @@ def toy_config():
     prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
                    sample_every=3, samples=8)
     return config
+
+
+def the_stage_counts_tiles_and_resets(served):
+    """``family_contract.stage_serves``'s entry for this family: the
+    flash kernel's tiles and the rows that open a request, and no
+    expert."""
+    from rnb_tpu.telemetry import stage_counter_report
+    counters, valid = served.stage.stage_counters(), served.valid
+    # four layers' tiles, two dispatches; three requests a dispatch
+    assert counters["attn_tiles"].tolist() == [8, 8]
+    assert counters["scan_resets"].tolist() == [6]
+    lines, fields = stage_counter_report([counters])
+    assert lines == [
+        "Tokens: valid=%d shipped=%d scan_resets=6" % (2 * valid, 16 * Q),
+        "Attention: tiles_visited=8 tiles_causal=8"]
+    assert fields["tokens_scan_resets"] == 6
+    assert served.stage._samples[0]["logits"].shape \
+        == (TOY["vocab_size"],)
+
+
+#: ``tests/test_falcon_h1_cell.py`` runs it. One of the two families
+#: that keep the untraced run (``family_contract.py``'s docstring): the
+#: dense one
+CONTRACT = contract.Family(
+    name="falcon_h1", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED),
+    meta=("Tokens: valid=", " scan_resets=", "Attention:"),
+    meta_absent=("Experts:",),
+    scopes=("/ssd/scan/", "/ssd/conv/", "/attn/", "/mlp/", "/head/"),
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "flash_tile_visit_pct.bulk": "(0, 100]",
+        "scan_resets_per_dispatch.bulk": "[1, 8]"},
+    not_from_a_cpu="roofline|util|busy_pct|ms_per_dispatch",
+    traces=(0, 1),
+    stage=contract.Stage(
+        lengths=(80, 9, 30), row_buckets=(8,), dispatches=2,
+        scopes=("/embed/", "/norm/", "/ssd/", "/ssd/conv/", "/ssd/scan/",
+                "/attn/", "/mlp/", "/head/"),
+        chosen_shape=(0, 80), also=the_stage_counts_tiles_and_resets),
+    # as stated inside the limit, every layer's matrices through float8
+    # outside it, the scan's states through bfloat16 reported and free
+    # to pass
+    control=contract.Control(
+        lengths="120,37,70", outside=("layers_float8",),
+        reads={("state_bfloat16", "share_of_spread"): "[0, 0.2)"},
+        may_pass=("state_bfloat16",)))
+
+
+# -- the six new readers --------------------------------------------------
+
+NEW_READERS = {
+    "ssm_branch_roofline_pct.bulk": "state-space scan",
+    "ssd_kernel_roofline_pct.bulk": "state-space scan",
+    "hybrid_flash_roofline_pct.bulk": "packed attention",
+    "mlp_roofline_pct.bulk": "network",
+    "attn_branch_ms_per_dispatch.bulk": "packed attention",
+    "scan_resets_per_dispatch.bulk": "state-space scan"}
+#: the accepted readers whose lists gained the cell
+LISTED = (
+    "host_cores_busy", "rows_per_dispatch", "pad_row_pct",
+    "net_flops_util_pct", "net_roofline_pct", "device_idle_pct",
+    "hbm_peak_gib", "pad_row_traced_pct", "tokens_per_s", "pad_token_pct",
+    "flash_tile_visit_pct", "ssd_busy_pct", "attn_busy_pct", "mlp_busy_pct",
+    "ssd_scan_ms_per_dispatch", "segment_conv_ms_per_dispatch")
+
+
+def test_the_accepted_readers_list_the_cell_last():
+    by_name = {m["name"]: m for m in mm.load()["per_layer"]}
+
+    def last_of_its_pr(workloads):
+        """The cell stands last but for the cells later PRs appended
+        (PR 55's ``dots3-note.bulk``)."""
+        behind = workloads[workloads.index(CELL) + 1:]
+        return set(behind) <= {"dots3-note.bulk"}
+    for name in LISTED:
+        assert last_of_its_pr(by_name[name + ".bulk"]["workloads"]), name
+    listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
+              and m["moves"] == "videos_per_s"}
+    assert listed == {n + ".bulk" for n in LISTED} | set(NEW_READERS)
+    # this PR's six stand behind the eight set-up metrics, appended in
+    # the order of ``NEW_READERS``; the eight themselves are the
+    # harness's test's (``tests/harness/test_harness_setup_account.py``)
+    names = [m["name"] for m in mm.load()["per_layer"]]
+    at = names.index(next(iter(NEW_READERS)))
+    assert names[at:at + len(NEW_READERS)] == list(NEW_READERS)
+    assert by_name[names[at - 1]]["moves"] == "setup_s"
+    assert last_of_its_pr(by_name[names[at - 1]]["workloads"])
+    # a reader that gives a dense family nothing does not list it
+    # nor the three idle shares: the cell's spans paired under
+    # ``hostspans.PAIR_RADIUS_NS`` in two of three traced runs only
+    # (PERF.md section 6)
+    for name in ("flash_roofline_pct.bulk", "ssd_roofline_pct.bulk",
+                 "experts_busy_pct.bulk", "idle_starved_pct.bulk",
+                 "idle_launch_pct.bulk", "idle_host_loop_pct.bulk"):
+        assert CELL not in by_name[name]["workloads"], name
+
+
+class Result:
+    tokens_valid = 100
+    pad_emissions = 2
+    tokens_scan_resets = 0
+
+
+def facts_of(tmp_path, family="falcon_h1"):
+    class Facts:
+        trace = None
+        result = type("R", (Result,), {"log_dir": str(tmp_path)})
+        config = json.load(open(os.path.join(REPO, REAL)))
+        peak_flops_per_s = 1.97e14
+        device_kind = "TPU v5 lite"
+    Facts.family = mm.load_family(family)
+    return Facts
+
+
+@pytest.mark.parametrize("name", sorted(NEW_READERS))
+def test_a_new_reader_reads_nothing_on_a_run_without_its_source(
+        name, tmp_path):
+    """No trace, no counter (the parent's programs have neither the
+    scopes nor the counter): None, not a raise; and the manifest repeats
+    what the file declares."""
+    module = mm.load_layer_metric(name)
+    entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
+    assert entry and entry[0]["workloads"] == [CELL]
+    assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
+    assert module.LAYER == NEW_READERS[name]
+    assert module.read(facts_of(tmp_path)) is None
+    # an older family's file counts by another signature, or no such
+    # mechanism: nothing, not a raise
+    assert module.read(facts_of(tmp_path, "nemotron_h")) is None
+    assert module.read(facts_of(tmp_path, "minicpm_sala")) is None
+
+
+def test_the_readers_read_a_run_that_has_their_sources(tmp_path,
+                                                       monkeypatch):
+    """A trace reduced to two instructions and a kernel's call: the
+    shares are the family's work over those seconds, the counter its
+    rows a dispatch."""
+    from benchmarks import scopes, subscopes
+    facts = facts_of(tmp_path)
+
+    class Trace:
+        path = str(tmp_path / "none.xplane.pb")
+        host_span = (0.0, 1.0)
+    facts.trace = Trace
+    facts.result.tokens_scan_resets = 14
+    subscopes._CACHE[Trace.path] = {"%fusion.1 f32[8,8]": 0.5,
+                                    "%fusion.2 f32[8,8]": 0.25,
+                                    "%fusion.3 f32[8,8]": 0.125}
+    (tmp_path / "hlo-scopes.json").write_text(json.dumps({
+        "%fusion.1 f32[8,8]": "jit(apply)/jit(main)/mlp/dot",
+        "%fusion.2 f32[8,8]": "jit(apply)/jit(main)/ssd/scan/ssd_scan",
+        "%fusion.3 f32[8,8]": "jit(apply)/jit(main)/attn/dot"}))
+    subscopes._op_names.cache_clear()
+    tokens, dispatches = 16384.0, 2.0
+    monkeypatch.setattr(scopes, "traced_tokens", lambda facts: tokens)
+    monkeypatch.setattr(scopes, "kernel_seconds",
+                        lambda facts, kernel: 0.01)
+    try:
+        family, config = facts.family, facts.config
+
+        def least(mechanism):
+            ops, nbytes = family.mechanism_work(config, mechanism, tokens,
+                                                tokens * 2 / 100)
+            return max(ops / 1.97e14, nbytes / 8.19e11)
+        read = {name: mm.load_layer_metric(name).read(facts)
+                for name in NEW_READERS}
+        assert read["mlp_roofline_pct.bulk"] \
+            == pytest.approx(100 * least("mlp") / 0.5)
+        assert read["ssm_branch_roofline_pct.bulk"] \
+            == pytest.approx(100 * least("ssm") / 0.25)
+        assert read["ssd_kernel_roofline_pct.bulk"] \
+            == pytest.approx(100 * least("scan") / 0.01)
+        assert read["hybrid_flash_roofline_pct.bulk"] \
+            == pytest.approx(100 * least("flash") / 0.01)
+        assert read["attn_branch_ms_per_dispatch.bulk"] \
+            == pytest.approx(1e3 * 0.125 / (tokens * 2 / 100))
+        assert read["scan_resets_per_dispatch.bulk"] == 7.0
+    finally:
+        del subscopes._CACHE[Trace.path]
+        subscopes._op_names.cache_clear()
+
+
+def test_the_kernels_names_are_the_readers():
+    from rnb_tpu.ops import ssd
+    assert mm.load_layer_metric("ssd_kernel_roofline_pct.bulk").KERNEL \
+        == ssd.KERNEL_NAME
+    # the flash kernel's calls are the ones ``flash_roofline_pct.bulk``
+    # reads for the expert families
+    with open(os.path.join(mm.LAYER_METRICS_DIR,
+                           "flash_roofline_pct.bulk.py")) as f:
+        assert '"%s"' % mm.load_layer_metric(
+            "hybrid_flash_roofline_pct.bulk").KERNEL in f.read()

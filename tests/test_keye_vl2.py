@@ -12,12 +12,14 @@ attention, bit for bit; the reference's multimodal rotary against
 ``ops/rope.rotate``; every expert held; the counters against a NumPy
 count; a sample's two kinds of choice and the check's refusals (a
 tampered set, the float8 indexer, both attention controls, every matrix
-through float8); the stages; the operation counts against a count by
-hand; the cell through the one benchmark command; the five new readers
-on a run without their scope; the real configuration against the
+through float8); the family's record for ``family_contract.py``, by
+which ``test_keye_vl2_cell.py`` runs the stage, the control script and
+the cell; the operation counts against a count by hand; the five new
+readers on a run without their scope; the real configuration against the
 catalog's row; the mixer's ``attn/kernel`` scope and the real 128-row
 program's arrays between q's product and ``o``'s; the kernels compiled
-at the published widths for a described v5e; and the shared code's
+at the published widths for a described v5e (dots3-note's, under latent
+attention, in ``test_indexed_latent.py``); and the shared code's
 StableHLO for the five older families and for this one.
 Nothing here needs the native decode library or a chip."""
 
@@ -25,7 +27,6 @@ import functools
 import hashlib
 import json
 import os
-import subprocess
 import sys
 import types
 
@@ -35,6 +36,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import keye_vl2 as reference  # noqa: E402
@@ -734,17 +736,21 @@ def test_the_counters_are_a_numpy_count(toy):
 # -- the stages, a sample's choices and the check ---------------------------------
 
 
-def toy_config():
+def toy_config(layers=4):
+    """A toy-width copy of the real configuration's file, of four
+    layers: the floor of the family file's ``check_config``. The stage
+    below serves ``TOY``'s three, and is checked against three."""
     with open(os.path.join(REPO, REAL)) as f:
         config = json.load(f)
-    config.update(TOY)
+    config.update(TOY, num_hidden_layers=layers)
     config["published"] = {"num_hidden_layers": 48}
-    config["model"] = dict(config["model"], layers=3)
+    config["model"] = dict(config["model"], layers=layers)
     config["dataset"] = {"seed": 0, "long_every": 11,
                          "short": {"count": 6, "median": 80, "sigma": 0.5,
                                    "min": 20, "max": 120},
                          "long": {"count": 2, "min": 100, "max": 128}}
-    config["capacity_videos_per_chip_s"] = 300
+    # sized to what a CPU serves: ``family_contract.py``, "The backlog"
+    config["capacity_videos_per_chip_s"] = 110
     config["share_of_spread"] = TOY_LIMIT
     config["key_slack"] = TOY_KEY_SLACK
     config["ref_pad"] = 64
@@ -756,42 +762,30 @@ def toy_config():
     return config
 
 
-class Card:
-    def __init__(self, rid):
-        self.id = rid
-
-
-def serve(tmp_path, prompts, **arm):
-    """The final stage over one 32-row dispatch of ``prompts``, a sample
-    a request, written under ``tmp_path``: -> (the stage, what
+def serve(tmp_path, **arm):
+    """``family_contract.serve``'s one 32-row dispatch under an arm of
+    ``network.forward``, a sample a request, the prompts written as the
+    run's request files: -> (the stage, its recipe, what
     ``check_outputs`` is handed)."""
-    from rnb_tpu.devices import DeviceSpec
-    from rnb_tpu.models import token_stages
-    from rnb_tpu.models.keye_vl2 import checkpoint, network
-    from rnb_tpu.stage import PaddedBatch
+    from rnb_tpu.models.keye_vl2 import network
     os.makedirs(tmp_path, exist_ok=True)
-    recipe = os.path.join(tmp_path, "toy.recipe.json")
-    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
     forward = network.forward
     if arm:
         network.forward = functools.partial(forward, **arm)
     try:
-        stage = token_stages.PackedPrefill(
-            DeviceSpec(-1), ckpt_path=recipe, max_rows=32, chunk=Q,
-            row_buckets=[32], family="keye_vl2", sample_every=1, samples=8)
+        served = contract.serve(CONTRACT, tmp_path)
     finally:
         network.forward = forward
-    tokens, meta, offsets = pack(prompts, 32)
-    batch = PaddedBatch(tokens, offsets[-1])
-    batch.segment_offsets = tuple(offsets)
-    cards = types.SimpleNamespace(
-        time_cards=[Card(i) for i in range(len(prompts))])
-    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, cards)
+    return served.stage, served.recipe, request_files(served)
+
+
+def request_files(served):
     files = []
-    for i, prompt in enumerate(prompts):
-        files.append(os.path.join(tmp_path, "short-%03d.npy" % i))
+    for i, prompt in enumerate(served.prompts):
+        files.append(os.path.join(os.path.dirname(served.recipe),
+                                  "short-%03d.npy" % i))
         np.save(files[-1], prompt)
-    return stage, recipe, {"short_files": files, "long_files": []}
+    return {"short_files": files, "long_files": []}
 
 
 def checked(tmp_path, stage, recipe, inputs):
@@ -800,26 +794,19 @@ def checked(tmp_path, stage, recipe, inputs):
     stage.finalize()
     family = mm.load_family("keye_vl2")
     return family.check_outputs(
-        toy_config(), None, None, recipe, SEED, inputs, jax.devices(),
+        toy_config(layers=TOY["num_hidden_layers"]), None, None, recipe,
+        SEED, inputs, jax.devices(),
         types.SimpleNamespace(log_dir=str(tmp_path)))
 
 
-def test_the_prefill_stage_serves_the_family(tmp_path):
-    """The final stage learns the family from the recipe, counts the
-    experts' assignments, the sets and the kernel's tiles, names the
-    scopes the readers look for, and a sample keeps both kinds of
-    choice: the check holds them to the reference."""
+def the_stage_keeps_both_kinds_of_choice(served):
+    """``family_contract.stage_serves``'s entry for this family: the
+    stage counts the experts' assignments, the sets and the kernel's
+    tiles, and a sample keeps both kinds of choice: the check holds them
+    to the reference."""
     from rnb_tpu.telemetry import stage_counter_report
-    prompts = prompts_of([150, 30, 230], seed=2)
-    stage, recipe, inputs = serve(tmp_path, prompts)
-    assert stage.family == "keye_vl2" and stage._slots is not None
-    # the samples' arrays wait on the device for the next launch; the
-    # counters wait for no one
-    assert len(stage._sampled) == 3 and not stage._samples
+    stage, valid = served.stage, served.valid
     counters = stage.stage_counters()
-    valid = sum(len(p) for p in prompts)
-    assert counters["tokens_valid"] == valid
-    assert counters["tokens_shipped"] == 32 * Q
     assert counters["experts_per_token"] == 2
     assert counters["expert_served"].shape == (3, 8)
     assert counters["expert_served"].sum() == 3 * 2 * valid
@@ -842,16 +829,11 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert (fields["sparse_chunks_walked"],
             fields["sparse_chunks_to_diagonal"]) == (24, 24)
     assert fields["experts_held"] == fields["experts_assignments"]
-    for scope in ("/attn/", "/attn/select/", "/attn/select/index/",
-                  "/attn/kernel/", "/experts/", "/head/", "/embed/"):
-        assert any(scope in name + "/"
-                   for name in stage.hlo_scopes.values()), scope
-    verdict = checked(tmp_path, stage, recipe, inputs)
+    verdict = checked(os.path.dirname(served.recipe), stage, served.recipe,
+                      request_files(served))
     assert len(stage._samples) == 3 and not stage._fetching \
         and not stage._sampled
     first = stage._samples[0]
-    assert first["tokens"].tolist() == prompts[0].tolist()
-    assert first["chosen"].shape == (3, 150, 2)
     assert first["key_sets"].shape == (3, 150, 32 * Q)
     assert first["key_sets"].dtype == np.uint32 and first["first"] == 0
     assert int(stage._samples[2]["first"]) == 12 * Q
@@ -859,6 +841,45 @@ def test_the_prefill_stage_serves_the_family(tmp_path):
     assert verdict["samples"] == 3 and verdict["key_bad"] == 0
     assert 0 < verdict["key_shortfall_max"] < TOY_KEY_SLACK
     assert verdict["key_differ"] > 0
+
+
+#: ``tests/test_keye_vl2_cell.py`` runs it
+CONTRACT = contract.Family(
+    name="keye_vl2", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED, HELD),
+    meta=("Tokens: valid=", "Experts:", " gmm_rows=", "Sparse: queries=",
+          " tiles_chosen=", " chunks_walked=", " chunks_to_diagonal="),
+    scopes=("/attn/select/index/", "/attn/kernel/"),
+    sample_fields=("tokens", "logits", "chosen", "key_sets", "first"),
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "held_assignment_pct.bulk": "[100, 100]",
+        "expert_load_max_over_mean.bulk": "[1, inf)",
+        "sparse_query_pct.bulk": "(0, 100)",
+        "selected_key_pct.bulk": "(0, 100)",
+        "chosen_tile_pct.bulk": "(0, 100]",
+        "select_chunk_walk_pct.bulk": "(0, 100]",
+        "gmm_row_fill_pct.bulk": "(0, 100]"},
+    not_from_a_cpu="roofline|util|_ms_per_|busy_pct",
+    stage=contract.Stage(
+        lengths=(150, 30, 230), rows=32, row_buckets=(32,), samples=8,
+        scopes=("/attn/", "/attn/select/", "/attn/select/index/",
+                "/attn/kernel/", "/experts/", "/head/", "/embed/"),
+        chosen_shape=(3, 150, 2),
+        also=the_stage_keeps_both_kinds_of_choice),
+    # as stated inside the limit and both slacks, each of the four
+    # controls outside one of them
+    control=contract.Control(
+        lengths="150,30,230",
+        outside=("index_float8", "all_causal_keys", "recent_keys",
+                 "layers_float8"),
+        reads={("as_stated", "key_shortfall_max"):
+               "(-inf, %r)" % TOY_KEY_SLACK,
+               ("index_float8", "key_shortfall_max"):
+               "(%r, inf)" % TOY_KEY_SLACK,
+               ("all_causal_keys", "share_of_spread"): "(0.2, inf)",
+               ("recent_keys", "share_of_spread"): "(0.2, inf)"}))
 
 
 TAMPERINGS = {
@@ -893,16 +914,29 @@ def _set(sets, layer, query, key):
     return sets
 
 
-@pytest.mark.parametrize("how", sorted(TAMPERINGS))
-def test_the_check_refuses_a_tampered_set(how, tmp_path):
-    prompts = prompts_of([150, 30, 230], seed=2)
-    stage, recipe, inputs = serve(tmp_path, prompts)
+@pytest.fixture(scope="module")
+def served_as_stated(tmp_path_factory):
+    """The stage served once for the tampered sets below, its samples
+    collected: -> (their directory, the stage, its recipe, the run's
+    request files)."""
+    tmp_path = tmp_path_factory.mktemp("served")
+    stage, recipe, inputs = serve(tmp_path)
     stage._send_samples()
     stage._collect_samples()
+    return tmp_path, stage, recipe, inputs
+
+
+@pytest.mark.parametrize("how", sorted(TAMPERINGS))
+def test_the_check_refuses_a_tampered_set(how, served_as_stated):
+    tmp_path, stage, recipe, inputs = served_as_stated
     sample = stage._samples[2]
-    sample["key_sets"] = TAMPERINGS[how](sample["key_sets"],
+    as_served = sample["key_sets"]
+    sample["key_sets"] = TAMPERINGS[how](as_served.copy(),
                                          int(sample["first"]))
-    verdict = checked(tmp_path, stage, recipe, inputs)
+    try:
+        verdict = checked(tmp_path, stage, recipe, inputs)
+    finally:
+        sample["key_sets"] = as_served
     assert not verdict["ok"] and verdict["key_bad"] >= 1, verdict
     assert "another size" in verdict["why"]
 
@@ -917,8 +951,7 @@ def test_the_check_refuses_the_attention_controls(arm, why, tmp_path):
     the run's own check and is refused: all causal keys are sets of
     another size, the latest ``topk`` and a float8 indexer's are sets
     whose weakest key lies far under the reference's ``topk``-th."""
-    prompts = prompts_of([150, 30, 230], seed=2)
-    verdict = checked(tmp_path, *serve(tmp_path, prompts, **arm))
+    verdict = checked(tmp_path, *serve(tmp_path, **arm))
     assert not verdict["ok"] and why in verdict["why"], verdict
 
 
@@ -1010,110 +1043,6 @@ def test_operation_counts_agree_with_a_count_by_hand():
     assert experts_ops == gmm_ops + layers * 1e6 * 2 * 2048 * 128
     with pytest.raises(ValueError):
         family.mechanism_work(config, "flash", 1e6, 8e6, 80.0)
-
-
-# -- through the one benchmark command ------------------------------------------
-
-
-def toy_tree(tmp_path):
-    """The real manifest's new cell over a toy-width copy of its
-    configuration: the same family, stages, mix and readers."""
-    os.makedirs(tmp_path / "benchmarks" / "configs")
-    with open(tmp_path / REAL, "w") as f:
-        json.dump(toy_config(), f)
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(mm.load(), f)
-    return str(tmp_path / "BENCHMARK.json")
-
-
-@pytest.mark.parametrize("trace", [0, 1])
-def test_the_cell_through_the_benchmark_command(trace, tmp_path):
-    out = tmp_path / "out"
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
-         "--manifest", toy_tree(tmp_path), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
-         "--platform", "cpu", "--out", str(out)],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0, \
-        done.stderr[-3000:]
-    assert line["attempted"] > 0
-    meta = (out / "run" / "log-meta.txt").read_text()
-    for name in ("Tokens: valid=", "Experts:", " gmm_rows=",
-                 "Sparse: queries=", " tiles_chosen=", " chunks_walked=",
-                 " chunks_to_diagonal="):
-        assert name in meta, name
-    samples = sorted((out / "run").glob("prefill-sample-*.npz"))
-    assert len(samples) == 8
-    with np.load(samples[0]) as sample:
-        assert {"tokens", "logits", "chosen", "key_sets", "first"} \
-            <= set(sample.files)
-    with open(out / "run" / "hlo-scopes.json") as f:
-        scopes = list(json.load(f).values())
-    for scope in ("/attn/select/index/", "/attn/kernel/"):
-        assert any(scope in name + "/" for name in scopes), scope
-    metrics = line["metrics"]
-    if trace:
-        assert metrics["tokens_per_s.bulk"]["value"] > 0
-        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
-        assert metrics["held_assignment_pct.bulk"]["value"] == 100
-        assert metrics["expert_load_max_over_mean.bulk"]["value"] >= 1
-        assert 0 < metrics["sparse_query_pct.bulk"]["value"] < 100
-        assert 0 < metrics["selected_key_pct.bulk"]["value"] < 100
-        assert 0 < metrics["chosen_tile_pct.bulk"]["value"] <= 100
-        assert 0 < metrics["select_chunk_walk_pct.bulk"]["value"] <= 100
-        assert 0 < metrics["gmm_row_fill_pct.bulk"]["value"] <= 100
-        # what stands against the chip's peak, or comes from the
-        # device's trace, does not come from a CPU
-        assert not any("roofline" in n or "util" in n or "_ms_per_" in n
-                       or "busy_pct" in n for n in metrics)
-    else:
-        assert metrics["videos_per_s"]["value"] > 0
-        assert metrics["setup_s"]["value"] > 0
-
-
-def test_the_parent_fails_on_the_cell_before_jax_starts(tmp_path):
-    """A checkout whose program lacks the family (the parent of PR 46,
-    given this PR's benchmark files): the family file's ``build`` says
-    so and exits, no result line; and the parent's own manifest has no
-    such cell: ``manifest.cell`` raises at once."""
-    family = mm.load_family("keye_vl2")
-    os.makedirs(tmp_path / "rnb_tpu" / "models")
-    with pytest.raises(SystemExit, match="keye_vl2"):
-        family.build(str(tmp_path))
-    family.build(REPO)
-    parents = dict(mm.load())
-    parents["workloads"] = [w for w in parents["workloads"]
-                            if w["name"] != CELL]
-    with pytest.raises(KeyError, match="no workload 'keye-vl2.bulk'"):
-        mm.cell(parents, CELL)
-
-
-def test_the_control_script_runs_the_familys_arms(tmp_path):
-    """``scripts/prefill_control.py`` over a toy-width copy of the
-    configuration's file: as stated inside the limit and both slacks,
-    each of the four controls outside one of them."""
-    path = tmp_path / "toy.json"
-    path.write_text(json.dumps(toy_config()))
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
-         "--config", str(path), "--lengths", "150,30,230"],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    out = json.loads(done.stdout.strip().splitlines()[-1])
-    assert out["family"] == "keye_vl2" and out["ok"]
-    assert out["as_stated"]["ok"]
-    assert out["as_stated"]["key_shortfall_max"] < TOY_KEY_SLACK
-    for arm in ("index_float8", "all_causal_keys", "recent_keys",
-                "layers_float8"):
-        assert not out[arm]["ok"], (arm, out[arm])
-    assert out["index_float8"]["key_shortfall_max"] > TOY_KEY_SLACK
-    assert out["all_causal_keys"]["share_of_spread"] > 0.2
-    assert out["recent_keys"]["share_of_spread"] > 0.2
 
 
 # -- the five new readers, and PR 56's ------------------------------------------------
@@ -1400,108 +1329,6 @@ def test_no_array_of_a_head_axis_between_qs_product_and_os():
         % (cfg.num_attention_heads, per, cfg.head_dim), text))) == []
 
 
-# -- the sets under latent attention (dots3-note's full layers) -----------------
-
-
-def latent_operands(rng, tokens, heads, nope, rotary, value):
-    """(q, kv, k_pe, gate) as ``models/dots3_note`` hands them to the
-    latent kernels: q heads-first ``[q_nope | q_pe | 0]`` to whole lanes
-    with the scale in it, a head's ``[own key | value]`` in ``kv``, the
-    one rotary key, the heads' gates."""
-    import jax
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import latent
-    lanes = -(-(nope + rotary) // 128) * 128
-    own = latent.key_lanes(nope, lanes)
-    q = np.zeros((heads, tokens, lanes), np.float32)
-    q[..., :nope + rotary] = rng.normal(
-        size=(heads, tokens, nope + rotary)) * 2 * (nope + rotary) ** -0.5
-    kv = np.zeros((tokens, heads, own + value), np.float32)
-    kv[..., :nope] = rng.normal(size=(tokens, heads, nope))
-    kv[..., own:] = rng.normal(size=(tokens, heads, value))
-    return (jnp.asarray(q, jnp.bfloat16),
-            jnp.asarray(kv.reshape(tokens, -1), jnp.bfloat16),
-            jnp.asarray(rng.normal(size=(tokens, rotary)), jnp.bfloat16),
-            jax.nn.sigmoid(jnp.asarray(rng.normal(size=(tokens, heads)),
-                                       jnp.float32)))
-
-
-def latent_dense(q, kv, k_pe, gate, mask, nope, rotary, value):
-    """Every head's softmax under the explicit ``mask``, in numpy."""
-    heads, tokens, _ = q.shape
-    q = np.asarray(q, np.float32)
-    kv = np.asarray(kv, np.float32).reshape(tokens, heads, -1)
-    s = np.einsum("htd,shd->hts", q[..., :nope], kv[..., :nope]) \
-        + np.einsum("htd,sd->hts", q[..., nope:nope + rotary],
-                    np.asarray(k_pe, np.float32))
-    s = np.where(mask[None], s, -np.inf)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    out = np.einsum("hts,shd->thd", p / p.sum(-1, keepdims=True),
-                    kv[..., -value:])
-    return (out * np.asarray(gate)[:, :, None]).reshape(tokens, -1)
-
-
-#: (rows of 32 tokens, the rows that open a request, heads, nope, rotary,
-#: value, (queries, keys, heads) a step): the MLA shape in both forms of
-#: the own key — whole lane tiles with the rotary key's product beside
-#: it, and padded with the rotary key added under it —, keys wider than
-#: values, pools of several tiles with requests that start inside one
-LATENT_POOLS = {
-    "own_key_whole_lanes": (16, [0, 9, 14, 15], 4, 128, 16, 32,
-                            (128, 128, 2)),
-    "own_key_padded": (16, [0, 9, 14, 15], 4, 24, 8, 16, (128, 128, 4)),
-    "one_tile_one_group": (8, [0, 5, 7], 2, 128, 16, 32, (1024, 512, 4)),
-    "queries_over_keys_tiles": (32, [0, 9, 21, 30, 31], 4, 24, 8, 16,
-                                (256, 128, 2))}
-
-
-@pytest.mark.parametrize("pool", sorted(LATENT_POOLS))
-def test_the_latent_kernel_reads_the_sets_alone(pool, monkeypatch):
-    """``latent_indexed_attention`` against ``chosen_mask``'s dense form:
-    every head its own key and value, the one rotary key shared, the
-    gate on the result; the sets it writes as bits are the mask."""
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import indexed
-    rows, firsts, heads, nope, rotary, value, tiles = LATENT_POOLS[pool]
-    monkeypatch.setattr(indexed, "_LATENT_TILES", tiles)
-    operands, mask, position = a_pool(rows, firsts, 4, 2, 16)
-    keys, tau, cut = operands[3:6]
-    start = operands[7][2]
-    tokens = rows * 32
-    rng = np.random.default_rng(rows + nope)
-    q, kv, k_pe, gate = latent_operands(rng, tokens, heads, nope, rotary,
-                                        value)
-    out, sets = indexed.latent_indexed_attention(
-        q, kv, k_pe, gate, keys, tau, cut, start, nope, value,
-        interpret=True)
-    assert out.shape == (tokens, heads * value) \
-        and out.dtype == jnp.bfloat16
-    want = latent_dense(q, kv, k_pe, gate, mask, nope, rotary, value)
-    assert np.abs(np.asarray(out, np.float32) - want).max() < 0.03
-    tile_q, tile_k, _ = indexed.latent_tiles(tokens)
-    assert sets.shape == (tokens, tile_k) and sets.dtype == jnp.uint32
-    assert (indexed.unpack_sets(sets)[:, :tokens] == mask).all()
-    chose, reached = indexed.count_sets(sets, tile_q)
-    assert (np.asarray(chose) == np.minimum(position + 1, 40)).all()
-    reach = mask.reshape(tokens // tile_q, tile_q, tokens // tile_k,
-                         tile_k).any(axis=(1, 3))
-    assert int(reached) == int(reach.sum()) \
-        <= indexed.latent_causal_tiles(tokens)
-
-
-def test_more_than_32_key_tiles_are_refused(monkeypatch):
-    from rnb_tpu.ops import indexed
-    assert indexed.latent_tiles(16384) == indexed._LATENT_TILES
-    monkeypatch.setattr(indexed, "_LATENT_TILES", (1024, 256, 4))
-    with pytest.raises(ValueError, match="more than 32 key tiles"):
-        indexed.latent_tiles(16384)
-    monkeypatch.setattr(indexed, "_LATENT_TILES", (1024, 512, 4))
-    assert indexed.latent_causal_tiles(16384) \
-        == sum(2 * (i + 1) for i in range(16))
-
-
 # -- compiled for the chip -----------------------------------------------------------
 
 
@@ -1579,70 +1406,6 @@ def test_the_kernels_compile_at_the_published_widths(piece, rows, one_chip):
         import re
         assert not re.search(r"(?:f32|bf16)\[[\d,]*\]\S* transpose\(", text)
         assert "pad(" not in text
-
-
-@pytest.mark.parametrize("rows", [128, 80])
-@pytest.mark.parametrize("piece", ["scores", "full", "window", "queries"])
-def test_the_latent_kernels_compile_at_the_published_widths(piece, rows,
-                                                            one_chip):
-    """dots3-note's shapes for a described v5e (nothing runs): the
-    scores at 64 index heads of 128, the latent kernel under the sets
-    (128 heads of 128 + 64 / 128), the latent banded kernel (64 heads of
-    192 + 64 / 128, window 513) and ``ops/mla.queries`` at nope 192
-    from a latent of 1,024; from the products' results to ``o``'s
-    operand with no transpose and no pad of an array with a head axis."""
-    import re
-
-    import jax
-    import jax.numpy as jnp
-
-    from rnb_tpu.ops import banded, indexed, mla
-    tokens = rows * 128
-    bf, f32 = jnp.bfloat16, jnp.float32
-
-    def of(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    if piece == "scores":
-        lowered = jax.jit(indexed.index_keys).lower(
-            of((tokens, 64, 128), bf), of((tokens, 128), bf),
-            of((tokens, 64), f32), of((tokens,)))
-        name = indexed.SCORES_KERNEL
-    elif piece == "full":
-        lowered = jax.jit(
-            lambda q, kv, k_pe, gate, keys, tau, cut, start:
-            indexed.latent_indexed_attention(
-                q, kv, k_pe, gate, keys, tau, cut, start, 128, 128)).lower(
-            of((128, tokens, 256), bf), of((tokens, 128 * 256), bf),
-            of((tokens, 64), bf), of((tokens, 128), f32),
-            of((tokens, tokens)), of((tokens,)), of((tokens,)),
-            of((tokens, 1)))
-        name = indexed.LATENT_KERNEL
-    elif piece == "window":
-        lowered = jax.jit(
-            lambda q, kv, k_pe, gate, start:
-            banded.latent_banded_attention(
-                q, kv, k_pe, gate, start, 513, 192, 128)).lower(
-            of((64, tokens, 256), bf), of((tokens, 64 * 384), bf),
-            of((tokens, 64), bf), of((tokens, 64), f32), of((tokens, 1)))
-        name = banded.LATENT_KERNEL_NAME
-    else:
-        inv = np.ones(32, np.float32)
-        lowered = jax.jit(lambda c, w, at: mla.queries(
-            c, w, at, inv, 192, 0.0625, out_columns=256)).lower(
-            of((tokens, 1024), bf), of((64, 1024, 384), bf), of((tokens,)))
-        name = mla.KERNEL_NAME
-    text = lowered.compile().as_text()
-    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
-    assert name in text
-    if piece in ("full", "window"):
-        heads = 128 if piece == "full" else 64
-        assert "bf16[%d,%d]" % (tokens, heads * 128) in text
-        # nothing with a head axis is laid out in front of the kernel or
-        # behind it: the gates' (T, heads) float32 alone is regrouped
-        assert not re.search(r"bf16\[[\d,]*\]\S* transpose\(", text)
-        assert not re.search(r"bf16\[\d+,\d+,\d+\]\S* pad\(", text)
-    if piece == "queries":
-        assert "bf16[64,%d,256]" % tokens in text
 
 
 def test_the_grouped_products_compile_at_the_published_widths(one_chip):

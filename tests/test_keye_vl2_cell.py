@@ -1,14 +1,14 @@
-"""``falcon-h1.bulk`` through the one benchmark command, traced and
-untraced, the control script's arms and the final stage serving the
-family, at a toy size on the CPU, by ``family_contract.py``; the record
-is ``test_falcon_h1.py``'s. A file of its own because one file is one
+"""``keye-vl2.bulk`` through the one benchmark command, the control
+script's arms and the final stage serving the family, at a toy size on
+the CPU, by ``family_contract.py``; the record is
+``test_keye_vl2.py``'s. A file of its own because one file is one
 worker's under ``--dist loadfile`` and a run takes over a minute."""
 
 import pytest
 
 import family_contract as contract
 
-FAMILY = contract.record("falcon_h1")
+FAMILY = contract.record("keye_vl2")
 
 
 @pytest.mark.parametrize("trace", FAMILY.traces)

@@ -10,10 +10,11 @@ a head axis in the real 128-row program; the shares of the experts adding
 up to the uncut layer; latent attention that rotates nothing and reads
 no other request's keys; the recipe, the operation counts, the real
 configuration against the catalog's row, and the kernel compiled at the
-published widths for a described v5e. The packed prefill and the stage
-are ``test_kimi_linear_stack.py``'s, the cell through the benchmark
-command and the readers ``test_kimi_linear_cell.py``'s (one file is one
-worker's under ``--dist loadfile``, and each stays under two minutes).
+published widths for a described v5e; the family's record for
+``family_contract.py`` and the two new readers. The packed prefill is
+``test_kimi_linear_stack.py``'s; the cell through the benchmark command,
+the control script and the stage are ``test_kimi_linear_cell.py``'s (one
+file is one worker's under ``--dist loadfile``).
 Nothing here needs the native decode library or a chip."""
 
 import functools
@@ -27,6 +28,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import kimi_linear as reference  # noqa: E402
@@ -676,7 +678,8 @@ def toy_config():
                          "short": {"count": 6, "median": 60, "sigma": 0.5,
                                    "min": 20, "max": 100},
                          "long": {"count": 2, "min": 100, "max": 128}}
-    config["capacity_videos_per_chip_s"] = 60
+    # sized to what a CPU serves: ``family_contract.py``, "The backlog"
+    config["capacity_videos_per_chip_s"] = 42
     config["share_of_spread"] = TOY_LIMIT
     loader, batcher, prefill = config["pipeline_config"]["pipeline"]
     loader.update(max_rows=8, chunk=16)
@@ -684,6 +687,52 @@ def toy_config():
     prefill.update(max_rows=8, chunk=16, row_buckets=[4, 8],
                    sample_every=3, samples=8)
     return config
+
+
+def the_stage_counts_experts_and_tiles(served):
+    """``family_contract.stage_serves``'s entry for this family: the
+    held experts' assignments and the flash kernel's tiles."""
+    from rnb_tpu.telemetry import stage_counter_report
+    counters, valid = served.stage.stage_counters(), served.valid
+    assert counters["experts_per_token"] == 4
+    # four expert layers behind the dense one, one attention layer
+    assert counters["expert_served"].shape == (4, 8)
+    assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
+    assert 0 < counters["group_tokens"] <= 4 * valid
+    assert counters["attn_tiles"].tolist() == [1, 1]
+    lines, _ = stage_counter_report([counters])
+    assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
+    assert lines[1].startswith("Experts: ")
+
+
+#: ``tests/test_kimi_linear_cell.py`` runs it
+CONTRACT = contract.Family(
+    name="kimi_linear", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED, HELD),
+    meta=("Tokens: valid=", "Experts:", "Attention:"),
+    scopes=("/deltanet/rule/", "/deltanet/gate/", "/deltanet/conv/"),
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "held_assignment_pct.bulk": "(0, 100)",
+        "expert_load_max_over_mean.bulk": "[1, inf)",
+        "flash_tile_visit_pct.bulk": "(0, 100]",
+        "gmm_row_fill_pct.bulk": "(0, 100]"},
+    not_from_a_cpu="roofline|util|deltarule|busy_pct|^kda_",
+    stage=contract.Stage(
+        lengths=(80, 9, 30), row_buckets=(8,),
+        scopes=("/deltanet/", "/deltanet/conv/", "/deltanet/gate/",
+                "/deltanet/rule/", "/attn/", "/experts/", "/head/",
+                "/embed/"),
+        chosen_shape=(4, 80, 4), also=the_stage_counts_experts_and_tiles),
+    # as stated inside the limit; the float8, the scalar-gate and the
+    # rotary arm outside it; the bfloat16 states reported, and free to
+    # pass
+    control=contract.Control(
+        lengths="120,120",
+        outside=("layers_float8", "scalar_gate", "rotary_on"),
+        reads={("state_bfloat16", "share_of_spread"): "[0, 0.2)"},
+        may_pass=("state_bfloat16",)))
 
 
 # -- the kernel for the chip ----------------------------------------------
@@ -770,3 +819,92 @@ def test_the_kernel_compiles_at_the_published_widths(one_chip):
     assert "bf16[%d,%d,%d]" % (rows, q, heads * dim) in text
     # and the call tells the compiler's scheduler what it costs
     assert "\"cost_estimate\":{\"flops\":\"" in text
+
+
+# -- the two new readers --------------------------------------------------
+
+NEW_READERS = ("kda_kernel_roofline_pct.bulk", "kda_gate_ms_per_dispatch.bulk")
+#: the accepted readers whose lists gained the cell
+LISTED = (
+    "deltanet_busy_pct", "deltanet_roofline_pct", "deltarule_roofline_pct",
+    "deltarule_ms_per_dispatch", "segment_conv_ms_per_dispatch",
+    "attn_busy_pct", "flash_roofline_pct", "flash_tile_visit_pct",
+    "mla_proj_ms_per_dispatch", "experts_busy_pct", "experts_roofline_pct",
+    "gmm_roofline_pct", "gmm_row_fill_pct", "held_assignment_pct",
+    "expert_load_max_over_mean", "net_flops_util_pct", "net_roofline_pct",
+    "tokens_per_s", "pad_token_pct", "pad_row_pct", "pad_row_traced_pct",
+    "rows_per_dispatch", "host_cores_busy", "device_idle_pct",
+    "hbm_peak_gib")
+
+
+def test_the_accepted_readers_list_the_cell():
+    by_name = {m["name"]: m for m in mm.load()["per_layer"]}
+    for name in LISTED:
+        assert CELL in by_name[name + ".bulk"]["workloads"], name
+    # (PR 51's set-up metrics list every cell and move `setup_s`)
+    listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
+              and m["moves"] == "videos_per_s"}
+    assert listed == {n + ".bulk" for n in LISTED} | set(NEW_READERS)
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
+        name, tmp_path):
+    """No trace, a trace whose run wrote no scope table, and a family
+    whose file counts no ``deltarule`` (the parent's programs have
+    neither the scope nor the kernel): None, not a raise."""
+    module = mm.load_layer_metric(name)
+    entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
+    assert entry and entry[0]["workloads"] == [CELL]
+    assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
+    assert module.LAYER == "gated delta rule"
+
+    class Result:
+        log_dir = str(tmp_path)
+        tokens_valid = 100
+        pad_emissions = 2
+
+    class Facts:
+        trace = None
+        result = Result
+        family = mm.load_family("kimi_linear")
+        config = {}
+        peak_flops_per_s = 1.97e14
+        device_kind = "TPU v5 lite"
+    assert module.read(Facts) is None
+
+    class Trace:
+        path = str(tmp_path / "none.xplane.pb")
+        host_span = (0.0, 1.0)
+    from benchmarks import subscopes
+    subscopes._CACHE[Trace.path] = {"%fusion.1 f32[8,8]": 0.5}
+    Facts.trace = Trace
+    try:
+        assert subscopes.seconds_under(Facts, "deltanet/gate") is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]": "jit(apply)/jit(main)/deltanet/rule/dot"}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, "deltanet/gate") is None
+        (tmp_path / "hlo-scopes.json").write_text(json.dumps(
+            {"%fusion.1 f32[8,8]":
+             "jit(apply)/jit(main)/deltanet/gate/dot"}))
+        subscopes._op_names.cache_clear()
+        assert subscopes.seconds_under(Facts, "deltanet/gate") == 0.5
+        assert subscopes.seconds_under(Facts, "deltanet") == 0.5
+    finally:
+        del subscopes._CACHE[Trace.path]
+        subscopes._op_names.cache_clear()
+
+
+def test_the_kernels_name_is_the_readers():
+    from rnb_tpu.ops import deltanet
+    reader = mm.load_layer_metric("kda_kernel_roofline_pct.bulk")
+    assert reader.KERNEL == deltanet.KDA_KERNEL_NAME
+    # an older family's file counts no such mechanism and raises: the
+    # reader says None there
+    with open(os.path.join(
+            REPO, "benchmarks/configs/deepseek-v2-ep8.json")) as f:
+        older = json.load(f)
+    with pytest.raises(ValueError):
+        mm.load_family("deepseek_v2").mechanism_work(
+            older, "deltarule", 1.0, 1.0, 1.0)

@@ -2,14 +2,11 @@
 the CPU with weights from a seed: the packed prefill through dispatches
 with several requests, a pad row and a request that ends inside a row,
 the router's choices within the slack; packing that is invisible; the
-KDA mixer's state and convolution history restarting at every request;
-the final stage serving the family from its recipe with the scopes
-and counters the readers look for; and the control script's arms over a
-toy-width copy of the configuration's file. The kernel and the configuration
-are ``test_kimi_linear.py``'s, the cell ``test_kimi_linear_cell.py``'s
-(one file is one worker's under ``--dist loadfile``)."""
+KDA mixer's state and convolution history restarting at every request.
+The kernel and the configuration are ``test_kimi_linear.py``'s, the cell,
+the stage and the control script ``test_kimi_linear_cell.py``'s (one file
+is one worker's under ``--dist loadfile``)."""
 
-import json
 import os
 import sys
 
@@ -22,8 +19,7 @@ from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import kimi_linear as reference  # noqa: E402
 from test_kimi_linear import (  # noqa: E402,F401
-    HELD, Q, REPO, SEED, TOY, TOY_LIMIT, pack, prompts_of, run_program,
-    run_reference, toy, toy_config)
+    Q, TOY, TOY_LIMIT, prompts_of, run_program, run_reference, toy)
 
 
 # -- the whole stack ----------------------------------------------------------
@@ -92,80 +88,3 @@ def test_the_mixer_restarts_state_and_convolution_history(toy):
             TOY, w, h[:3].reshape(3 * Q, 64).astype(jnp.float32)))
     assert np.abs(packed[:3].reshape(want.shape) - want).max() \
         < 0.03 * want.std()
-
-
-def test_the_prefill_stage_serves_the_family(tmp_path):
-    """The final stage learns the family from the recipe, counts the
-    held experts' assignments and the flash kernel's tiles, names the
-    scopes the readers look for and keeps the router's choices."""
-    from rnb_tpu.devices import DeviceSpec
-    from rnb_tpu.models import token_stages
-    from rnb_tpu.models.kimi_linear import checkpoint
-    from rnb_tpu.stage import PaddedBatch
-    from rnb_tpu.telemetry import stage_counter_report
-    recipe = str(tmp_path / "toy.recipe.json")
-    checkpoint.save_recipe(recipe, TOY, SEED, HELD)
-    stage = token_stages.PackedPrefill(
-        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
-        row_buckets=[8], family="kimi_linear", sample_every=1, samples=2)
-    assert stage.family == "kimi_linear" and stage._slots is not None
-    prompts = prompts_of([80, 9, 30], seed=2)
-    tokens, meta, offsets = pack(prompts, 8)
-    batch = PaddedBatch(tokens, offsets[-1])
-    batch.segment_offsets = tuple(offsets)
-
-    class Card:
-        def __init__(self, rid):
-            self.id = rid
-
-    class Cards:
-        time_cards = [Card(0), Card(1), Card(2)]
-    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, Cards())
-    counters = stage.stage_counters()
-    valid = sum(len(p) for p in prompts)
-    assert counters["tokens_valid"] == valid
-    assert counters["tokens_shipped"] == 8 * Q
-    assert counters["experts_per_token"] == 4
-    # four expert layers behind the dense one, one attention layer
-    assert counters["expert_served"].shape == (4, 8)
-    assert 0 < counters["expert_served"].sum() < 4 * 4 * valid
-    assert 0 < counters["group_tokens"] <= 4 * valid
-    assert counters["attn_tiles"].tolist() == [1, 1]
-    lines, _ = stage_counter_report([counters])
-    assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
-    assert lines[1].startswith("Experts: ")
-    for scope in ("/deltanet/", "/deltanet/conv/", "/deltanet/gate/",
-                  "/deltanet/rule/", "/attn/", "/experts/", "/head/",
-                  "/embed/"):
-        assert any(scope in name + "/"
-                   for name in stage.hlo_scopes.values()), scope
-    stage._send_samples()
-    stage._collect_samples()
-    assert len(stage._samples) == 2
-    first = stage._samples[0]
-    assert first["tokens"].tolist() == prompts[0].tolist()
-    assert first["chosen"].shape == (4, 80, 4)
-
-
-def test_the_control_script_takes_the_family_from_the_recipe(tmp_path):
-    """``scripts/prefill_control.py`` over a toy-width copy of the
-    configuration's file: as stated inside the limit; the float8, the
-    scalar-gate and the rotary arm outside it; the bfloat16 states
-    reported, and free to pass."""
-    import subprocess
-    path = tmp_path / "toy.json"
-    path.write_text(json.dumps(toy_config()))
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
-         "--config", str(path), "--lengths", "120,120"],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    out = json.loads(done.stdout.strip().splitlines()[-1])
-    assert out["family"] == "kimi_linear" and out["ok"]
-    assert out["as_stated"]["ok"]
-    for arm in ("layers_float8", "scalar_gate", "rotary_on"):
-        assert not out[arm]["ok"], arm
-    assert out["state_bfloat16"]["share_of_spread"] < 0.2
-    assert mm.load_family("kimi_linear").CONTROL_MAY_PASS \
-        == ("state_bfloat16",)

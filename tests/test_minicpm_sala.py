@@ -13,7 +13,6 @@ decode library or a described chip (the last test but one may)."""
 
 import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -22,6 +21,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import family_contract as contract  # noqa: E402
 from benchmarks import manifest as mm  # noqa: E402
 from benchmarks.references import compare  # noqa: E402
 from benchmarks.references import minicpm_sala as reference  # noqa: E402
@@ -66,7 +66,7 @@ def toy():
     from rnb_tpu.models.minicpm_sala import checkpoint, network
     cfg = network.MinicpmSalaConfig.from_published(TOY)
     device = jax.devices()[0]
-    return {"cfg": cfg, "device": device,
+    return {"cfg": cfg, "device": device, "programs": {},
             "params": checkpoint.make_params(cfg, SEED, (), device),
             "read": checkpoint.reference_reader(cfg, SEED, device),
             "reference": reference.Reference(TOY)}
@@ -83,18 +83,29 @@ def pack(prompts, rows):
     return token_stages.pack_prompts(prompts, rows, Q)
 
 
-def run_program(toy, prompts, rows, params=None, **kwargs):
-    """-> (logits a prompt, each prompt's chosen blocks (sparse layers,
-    tokens, Hk, its blocks) bool, the counts (sparse layers, 4))."""
+def program_of(toy, **arm):
+    """The toy stack jitted once an arm of ``forward``, kept on the
+    module's ``toy``: a test that runs it at rows another has run traces
+    and compiles nothing."""
     import jax
 
     from rnb_tpu.models.minicpm_sala import network
+    key = tuple(sorted(arm.items()))
+    if key not in toy["programs"]:
+        toy["programs"][key] = jax.jit(
+            lambda p, t, m: network.forward(
+                toy["cfg"], p, None, t, m[0], m[1], m[2], interpret=True,
+                **arm))
+    return toy["programs"][key]
+
+
+def run_program(toy, prompts, rows, params=None, **kwargs):
+    """-> (logits a prompt, each prompt's chosen blocks (sparse layers,
+    tokens, Hk, its blocks) bool, the counts (sparse layers, 4))."""
+    from rnb_tpu.models.minicpm_sala import network
     family = mm.load_family("minicpm_sala")
     tokens, meta, offsets = pack(prompts, rows)
-    logits, chosen, counts = jax.jit(
-        lambda p, t, m: network.forward(
-            toy["cfg"], p, None, t, m[0], m[1], m[2], interpret=True,
-            **kwargs))(
+    logits, chosen, counts = program_of(toy, **kwargs)(
         toy["params"] if params is None else params, tokens, meta)
     per_prompt = [family.unpack_choices(TOY, network.request_choices(
         toy["cfg"], chosen, o * Q, len(p)), len(p))
@@ -547,46 +558,15 @@ def test_recipe_gives_program_and_reference_the_same_values(toy):
     assert params["l0"]["k"].shape == (64, 32)
 
 
-def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
-    """The final stage learns the family from the recipe, makes no
-    slots, counts no expert and keeps each sampled request's chosen
+def serves_a_family_without_experts(served):
+    """``family_contract.stage_serves``'s entry for this family: the
+    stage counts no expert and keeps each sampled request's chosen
     blocks in the place of router choices."""
-    from rnb_tpu.devices import DeviceSpec
-    from rnb_tpu.models import token_stages
-    from rnb_tpu.models.minicpm_sala import checkpoint
-    from rnb_tpu.stage import PaddedBatch
     from rnb_tpu.telemetry import stage_counter_report
     family = mm.load_family("minicpm_sala")
-    recipe = str(tmp_path / "toy.recipe.json")
-    checkpoint.save_recipe(recipe, TOY, SEED)
-    with pytest.raises(ValueError, match="names family"):
-        token_stages.PackedPrefill(DeviceSpec(-1), ckpt_path=recipe,
-                                   max_rows=8, chunk=Q, row_buckets=[8],
-                                   family="deepseek_v2")
-    stage = token_stages.PackedPrefill(
-        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=Q,
-        row_buckets=[4, 8], family="minicpm_sala", sample_every=1,
-        samples=2)
-    assert stage.family == "minicpm_sala" and stage._slots is None
-    assert set(stage.stage_counters()) == {"tokens_valid", "tokens_shipped"}
-    prompts = prompts_of([80, 9, 30], seed=2)
-    tokens, meta, offsets = pack(prompts, 8)
-    batch = PaddedBatch(tokens, offsets[-1])
-    batch.segment_offsets = tuple(offsets)
-
-    class Card:
-        def __init__(self, rid):
-            self.id = rid
-
-    class Cards:
-        time_cards = [Card(0), Card(1), Card(2)]
-    stage((batch, PaddedBatch(meta[0], offsets[-1])), None, Cards())
+    stage, valid = served.stage, served.valid
     counters = stage.stage_counters()
-    valid = sum(len(p) for p in prompts)
-    assert counters["tokens_valid"] == valid
-    assert counters["tokens_shipped"] == 8 * Q
-    assert "expert_served" not in counters \
-        and "experts_per_token" not in counters
+    assert "experts_per_token" not in counters
     assert counters["sparse"].tolist()[:2] == [2 * valid, 2 * 80]
     lines, _ = stage_counter_report([counters])
     assert lines[0] == "Tokens: valid=%d shipped=%d" % (valid, 8 * Q)
@@ -598,20 +578,8 @@ def test_the_prefill_stage_serves_a_family_without_experts(tmp_path):
     assert stage_counter_report([{"tokens_valid": 1,
                                   "tokens_shipped": 1}])[0] \
         == ["Tokens: valid=1 shipped=1"]
-    for scope in ("/attn/", "/attn/select/", "/ssd/", "/mlp/", "/head/",
-                  "/embed/"):
-        assert any(scope in name + "/"
-                   for name in stage.hlo_scopes.values()), scope
     # the samples: the first two requests, each with its own blocks
-    # a sample's arrays start for the host behind the next launch and
-    # are read behind the one after
-    assert len(stage._sampled) == 2 and not stage._samples
-    stage._send_samples()
-    stage._collect_samples()
-    assert len(stage._samples) == 2
-    first = stage._samples[0]
-    assert first["tokens"].tolist() == prompts[0].tolist()
-    own = family.unpack_choices(TOY, first["chosen"], 80)
+    own = family.unpack_choices(TOY, stage._samples[0]["chosen"], 80)
     assert own.shape == (1, 80, 2, 10)
     assert (own[0, 79].sum(-1) == SPARSE["topk"]).all()
     assert own[0, 0].sum(-1).tolist() == [1, 1]
@@ -684,7 +652,8 @@ def toy_config():
                          "short": {"count": 6, "median": 60, "sigma": 0.5,
                                    "min": 20, "max": 100},
                          "long": {"count": 2, "min": 100, "max": 128}}
-    config["capacity_videos_per_chip_s"] = 300
+    # sized to what a CPU serves: ``family_contract.py``, "The backlog"
+    config["capacity_videos_per_chip_s"] = 240
     config["share_of_spread"] = TOY_LIMIT
     loader, batcher, prefill = config["pipeline_config"]["pipeline"]
     loader.update(max_rows=8, chunk=16)
@@ -694,67 +663,27 @@ def toy_config():
     return config
 
 
-def toy_tree(tmp_path):
-    """The real manifest's new cell over a toy-width copy of its
-    configuration: the same family, stages, mix and readers."""
-    os.makedirs(tmp_path / "benchmarks" / "configs")
-    with open(tmp_path / REAL, "w") as f:
-        json.dump(toy_config(), f)
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(mm.load(), f)
-    return str(tmp_path / "BENCHMARK.json")
-
-
-@pytest.mark.parametrize("trace", [0, 1])
-def test_the_cell_through_the_benchmark_command(trace, tmp_path):
-    out = tmp_path / "out"
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
-         "--manifest", toy_tree(tmp_path), "--workload", CELL,
-         "--seed", "3000000019", "--seconds", "3", "--trace", str(trace),
-         "--platform", "cpu", "--out", str(out)],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0, \
-        done.stderr[-3000:]
-    assert line["attempted"] > 0
-    meta = (out / "run" / "log-meta.txt").read_text()
-    assert "Tokens: valid=" in meta and "Sparse: queries=" in meta
-    assert "Experts:" not in meta
-    assert len(list((out / "run").glob("prefill-sample-*.npz"))) == 8
-    metrics = line["metrics"]
-    if trace:
-        assert metrics["tokens_per_s.bulk"]["value"] > 0
-        assert 0 < metrics["pad_token_pct.bulk"]["value"] < 100
-        assert 0 < metrics["sparse_query_pct.bulk"]["value"] < 100
-        assert 0 < metrics["selected_key_pct.bulk"]["value"] < 100
-        assert metrics["rows_per_dispatch.bulk"]["value"] > 0
-        # what stands against the chip's peak, or comes from the
-        # device's trace, does not come from a CPU
-        assert not any("roofline" in n or "util" in n or "select_ms" in n
-                       or "busy_pct" in n for n in metrics)
-    else:
-        assert metrics["videos_per_s"]["value"] > 0
-        assert metrics["setup_s"]["value"] > 0
-
-
-def test_the_control_script_takes_the_family_from_the_recipe(tmp_path):
-    """``scripts/prefill_control.py`` over a toy-width copy of the
-    configuration's file: as stated inside the limit, the float8 arm
-    outside it."""
-    path = tmp_path / "toy.json"
-    path.write_text(json.dumps(toy_config()))
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "prefill_control.py"),
-         "--config", str(path), "--lengths", "37,120,70"],
-        capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert done.returncode == 0, done.stderr[-3000:]
-    out = json.loads(done.stdout.strip().splitlines()[-1])
-    assert out["family"] == "minicpm_sala" and out["ok"]
-    assert out["as_stated"]["ok"] and not out["layers_float8"]["ok"]
+#: ``tests/test_minicpm_sala_cell.py`` runs it
+CONTRACT = contract.Family(
+    name="minicpm_sala", cell=CELL, real=REAL, toy_config=toy_config,
+    recipe=(TOY, SEED),
+    meta=("Tokens: valid=", "Sparse: queries="), meta_absent=("Experts:",),
+    traced={
+        "tokens_per_s.bulk": "(0, inf)",
+        "pad_token_pct.bulk": "(0, 100)",
+        "sparse_query_pct.bulk": "(0, 100)",
+        "selected_key_pct.bulk": "(0, 100)",
+        "rows_per_dispatch.bulk": "(0, inf)"},
+    not_from_a_cpu="roofline|util|select_ms|busy_pct",
+    stage=contract.Stage(
+        lengths=(80, 9, 30), row_buckets=(4, 8),
+        scopes=("/attn/", "/attn/select/", "/ssd/", "/mlp/", "/head/",
+                "/embed/"),
+        # the blocks packed as bits: ``unpack_choices`` below
+        chosen_shape=(1, 80, 2, 2), also=serves_a_family_without_experts),
+    # as stated inside the limit, the float8 arm outside it
+    control=contract.Control(lengths="37,120,70",
+                             outside=("layers_float8",)))
 
 
 # -- the real configuration -----------------------------------------------------
