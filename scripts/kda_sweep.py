@@ -13,16 +13,22 @@ out of its body (``_unit`` and ``_gated_norm`` replaced by functions that
 pass their operand through; the operands still cross HBM), beside the
 figures of the kernels that read q and k normalised and rounded and
 wrote float32 (``PR49_MS``), and the ``jnp`` passes the lines replaced
-in the mixers, as XLA runs them. Then the vector gate's kernel at each
-choice it has — the block inside which pairs are formed on the vector
-unit (``_PAIR_BASE``), the heads a grid step (``_KDA_HEADS``). (The
-levels' score products in float32 at ``highest`` in the place of the
+in the mixers, as XLA runs them. Then *where each kernel's time is*
+(``PROBES``): the kernel again with one part of its body left out at a
+time - the solve's merges, its substitution, the levels, the offsets,
+the running sums, the score products, the products behind the solve -
+each replaced by the cheapest stand-in of its shape, so that the times
+say what the part costs and the results say nothing (PERF.md section 6,
+PR 58, has the table before and after that PR). Then the vector gate's
+kernel at each choice it has — the block inside which pairs are formed
+on the vector unit (``_PAIR_BASE``), the heads a grid step
+(``_KDA_HEADS``). (The levels' score products in float32 at ``highest`` in the place of the
 activations' bfloat16 read 18.90 ms for 16.12 at 8 and 2 and left the
 tree: my chip run, PR 49.) The host's clock around ``REPEATS`` calls: a
 call is tens of milliseconds, the launch a few tenths of one. Lines go
 to stdout and to ``chiprun_out/kda_sweep/sweep.jsonl``.
 
-    chiprun -- python3 scripts/kda_sweep.py [--rows=N]
+    chiprun -- python3 scripts/kda_sweep.py [--rows=N] [--only=probes]
 
 Off the TPU the kernel runs in Pallas's interpret mode, which at these
 sizes is of no use (``--rows=4`` is a dry run of the control flow).
@@ -190,6 +196,86 @@ def the_lines(emit, rows, operands, row_first):
                       weight)})
 
 
+def stand_in(a, b, dims=deltanet._NN):
+    """In a product's place: one addition of its operands' first parts
+    (every shape of the sweep is 128 x 128)."""
+    return a[0].astype(jnp.float32) + b[0].astype(jnp.float32)
+
+
+def probes():
+    """What to leave out -> the module's functions to replace, and the
+    kernels it is a part of."""
+    product = deltanet._product
+    both = (deltanet.KERNEL_NAME, deltanet.KDA_KERNEL_NAME)
+
+    def with_the_products(function):
+        def run(*operands):
+            replaced, deltanet._product = deltanet._product, product
+            try:
+                return function(*operands)
+            finally:
+                deltanet._product = replaced
+        return run
+
+    def products_of(parts):
+        """Stand-ins for the products of ``parts`` (both operands one
+        part or not) outside the merges and the levels."""
+        return {
+            "_product": lambda a, b, dims=deltanet._NN: (
+                stand_in if (len(a) == len(b) == 1) == parts else product)(
+                    a, b, dims),
+            "_merged": with_the_products(deltanet._merged),
+            "_level": with_the_products(deltanet._level)}
+
+    def zeros(rows):
+        # made inside the kernel's body: it may capture no array
+        return lambda *_: (jnp.zeros((rows, QLEN), jnp.float32),) * 2
+    return {
+        "nothing": ({}, both),
+        "the merges": ({"_merged": lambda x, xs, lowers, block: (x, xs)},
+                       both),
+        "the substitution": ({"_substituted": lambda packed: packed}, both),
+        "the solve": ({"unit_lower_inverses": lambda lowers: lowers}, both),
+        "the four levels": ({"_level": zeros(QLEN // 2)}, both[1:]),
+        "the seven offsets": ({"_offsets": zeros(QLEN)}, both[1:]),
+        "the running sums": ({"_running_sums": lambda x: x}, both[1:]),
+        "the two score products": (products_of(True), both[:1]),
+        "the six products behind the solve": (products_of(False), both),
+        "the first and last lines": ({
+            # the operands are drawn near unit length, so that the rule
+            # without its norms still reads numbers of the rule's size
+            "_unit": lambda x: x * DIM ** -0.5,
+            "_gated_norm": lambda o, z, weight, eps, activation: o}, both),
+    }
+
+
+def the_parts(emit, rows, operands, row_first):
+    """Each kernel with one part left out at a time: times only."""
+    qk, v, log_alpha, beta, z, weight = operands
+    one = log_alpha.reshape(rows, QLEN, HEADS, DIM)[..., 0]
+    kernels = {
+        deltanet.KERNEL_NAME: (
+            deltanet._rule_call, lambda: deltanet.gated_delta_rule(
+                qk, v, one, beta, z, weight, row_first, key_heads=HEADS,
+                eps=EPS, activation="silu", interpret=INTERPRET)),
+        deltanet.KDA_KERNEL_NAME: (
+            deltanet._kda_call, lambda: kda(*operands, row_first))}
+    for left_out, (replaced, of) in probes().items():
+        for name in of:
+            jitted, call = kernels[name]
+            kept = {attr: getattr(deltanet, attr) for attr in replaced}
+            for attr, function in replaced.items():
+                setattr(deltanet, attr, function)
+            jitted.clear_cache()
+            try:
+                emit({"kernel": name, "rows": rows, "left_out": left_out,
+                      "ms": timed(call)})
+            finally:
+                for attr, function in kept.items():
+                    setattr(deltanet, attr, function)
+                jitted.clear_cache()
+
+
 def main():
     os.makedirs(OUT, exist_ok=True)
     lines = open(os.path.join(OUT, "sweep.jsonl"), "w")
@@ -199,11 +285,17 @@ def main():
         print(json.dumps(record), flush=True)
         lines.write(json.dumps(record) + "\n")
         lines.flush()
-    check(emit)
+    only = option("--only", "")
+    if not only:
+        check(emit)
     rows = int(option("--rows", 128))
     operands = draw(rows, HEADS, False, seed=1)
     row_first = jnp.asarray(np.arange(rows) % 40 == 0)
-    the_lines(emit, rows, operands, row_first)
+    if not only:
+        the_lines(emit, rows, operands, row_first)
+    the_parts(emit, rows, operands, row_first)
+    if only:
+        return
     for base, heads in itertools.product((8, 16), (1, 2, 4)):
         deltanet._PAIR_BASE, deltanet._KDA_HEADS = base, heads
         deltanet._kda_call.clear_cache()

@@ -883,7 +883,10 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     for them: ``qwen3_next``'s text, and the three older families' and
     ``keye_vl2``'s in their own modules, are the ones they had) and
     holds this family's *full* layer alone to the text PR 51's tree
-    gave (:func:`test_the_full_layer_lowers_to_the_parents_text`)."""
+    gave (:func:`test_the_full_layer_lowers_to_the_parents_text`); PR
+    58 recorded ``qwen3_next``'s again (the products in the bodies of
+    ``ops/deltanet.py``'s kernels go part by part; no other family of
+    this file calls it, and their texts are the ones they had)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

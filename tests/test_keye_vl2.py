@@ -1460,7 +1460,10 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     once more (the thresholds' counts walk a step's own keys in one
     loop; ``ops/indexed.py`` alone, which no older family imports;
     :func:`test_the_walk_gives_the_parents_thresholds_to_the_bit` holds
-    the values)."""
+    the values). PR 58 recorded ``qwen3_next``'s again (the products in
+    the bodies of ``ops/deltanet.py``'s kernels go part by part, no
+    other family here calls it; its kernel against the recurrence is
+    ``tests/test_qwen3_next.py``'s)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

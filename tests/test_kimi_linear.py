@@ -345,6 +345,108 @@ def test_a_gate_alike_in_every_channel_is_the_scalar_rule(qlen):
     assert np.abs(other - scalar).max() > 1e-3 * np.abs(scalar).max()
 
 
+@pytest.mark.parametrize("qlen,harsh", [(16, False), (16, True),
+                                        (128, False), (128, True)])
+def test_the_scores_are_the_sum_pair_by_pair(qlen, harsh):
+    """``deltanet.channel_scores`` - the levels over their own rows, the
+    offsets inside blocks of 8 - against ``sum_d x_id k_jd exp(g_id -
+    g_jd)`` taken pair by pair in float64: every value finite, zero
+    where no pair is (``kk`` on and over the diagonal, ``qk`` over it).
+    Rows of 16: one level; of 128: four."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    qk, _, log_alpha, _, _, _ = rule_inputs(1, qlen, 1, harsh=harsh)
+    dk = log_alpha.shape[-1]
+    unit = np.asarray(qk[0], np.float64).reshape(qlen, 2, dk)
+    unit = unit / np.linalg.norm(unit, axis=-1, keepdims=True)
+    q, k = unit[:, 0] * dk ** -0.5, unit[:, 1]
+    g = np.cumsum(np.asarray(log_alpha[0], np.float64), axis=0)
+    if harsh:
+        assert g.min() < -10.0 * qlen
+    kk, qk = (np.asarray(x) for x in jax.jit(
+        deltanet.channel_scores, static_argnums=3)(
+        *(jnp.asarray(x, jnp.float32) for x in (q, k, g)), jnp.float32))
+    assert np.isfinite(kk).all() and np.isfinite(qk).all()
+    later = np.tril(np.ones((qlen, qlen), bool))
+    # exp of a difference under -745 is zero in float64 too: no overflow
+    decay = np.exp(np.where(later[:, :, None], g[:, None] - g[None], -np.inf))
+    for got, x, pairs in ((kk, k, np.tril(later, -1)), (qk, q, later)):
+        want = np.where(pairs, np.einsum("id,jd,ijd->ij", x, k, decay), 0.0)
+        assert np.array_equal(got[~pairs], np.zeros_like(got[~pairs]))
+        assert np.abs(got - want).max() < (5e-5 if harsh else 1e-6)
+
+
+def matrix_unit_work(jaxpr) -> int:
+    """The multiply-adds of every ``dot_general`` of a jaxpr and of the
+    jaxprs inside it (a kernel's body under ``pallas_call``, a
+    ``pl.when``'s branches)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract_a, _), _ = eqn.params["dimension_numbers"]
+            a, b = (v.aval for v in eqn.invars)
+            # a pass of the matrix unit: one part by one part
+            assert a.dtype == b.dtype == np.dtype("bfloat16"), eqn
+            total += int(np.prod(a.shape)) * int(np.prod(b.shape)) \
+                // int(np.prod([a.shape[i] for i in contract_a]))
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += matrix_unit_work(inner)
+    return total
+
+
+#: (the vector gate, the activations' dtype, value heads a key head,
+#: the most passes of 128^3 a value head and row): ISSUE 58's counts -
+#: 74 and 61 before it - and with float32 operands (these tests') six
+#: passes a product, none dropped
+PASS_CASES = [(True, "bfloat16", 1, 52), (False, "bfloat16", 2, 40),
+              (True, "float32", 1, None), (False, "float32", 2, None)]
+
+
+@pytest.mark.parametrize("channel,act,per,most", PASS_CASES)
+def test_the_matrix_units_passes_are_counted_from_the_kernels(
+        channel, act, per, most):
+    """``deltanet._passes``, which ``_cost`` reads, at the cells' sizes
+    (Q = Dk = Dv = 128): no more passes than the issue allows with
+    bfloat16 activations, six for every product of two float32
+    operands - and the same work as the ``dot_general``s of the
+    kernel's own traced body, so that the table cannot leave it."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    size = 128
+    table = deltanet._passes(size, size, size, jnp.dtype(act),
+                             channel=channel, per=per)
+    counted = sum(work * passes for work, passes in table.values())
+    if most is None:
+        assert {passes for _, passes in table.values()} == {6}
+    else:
+        assert counted <= most * size ** 3
+        assert table["solve"] == (2 * size ** 3, 6)     # two levels' halves
+    heads = 2
+    rows, f32 = 1, jnp.float32
+    operands = (
+        jnp.zeros((rows, size, 2 * heads * size), f32),
+        jnp.zeros((rows, size, heads * per * size), jnp.dtype(act)),
+        jnp.zeros((rows, size, heads * (size if channel else per)), f32),
+        jnp.zeros((rows, size, heads * per), f32),
+        jnp.zeros((rows, size, heads * per * size), f32),
+        jnp.ones((size,), f32), jnp.ones((rows,), bool))
+    call = functools.partial(
+        deltanet.channel_gated_delta_rule, eps=EPS, activation="sigmoid") \
+        if channel else functools.partial(
+            deltanet.gated_delta_rule, key_heads=heads, eps=EPS,
+            activation="silu")
+    traced = matrix_unit_work(jax.make_jaxpr(call)(*operands).jaxpr)
+    assert traced == counted * heads * per
+
+
 def test_bfloat16_states_differ_by_one_rounding_a_row():
     """The control's ``state_dtype``: a request's first row reads no
     carried state, so it is the float32 rule's bit for bit; every later

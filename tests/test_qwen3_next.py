@@ -402,7 +402,9 @@ def solve(lower):
         [deltanet.unit_lower_inverse(x) for x in batch]))(lower))
 
 
-@pytest.mark.parametrize("size", [8, 16, 32, 128])
+#: 8 to 32: the substitution alone; 64 and 128: one and two levels of
+#: merges over the odd blocks' rows
+@pytest.mark.parametrize("size", [8, 16, 32, 64, 128])
 def test_the_triangular_solve_is_the_inverse(size):
     rng = np.random.default_rng(size)
     lower = np.tril(0.2 * rng.normal(size=(5, size, size)), -1) \
@@ -411,6 +413,89 @@ def test_the_triangular_solve_is_the_inverse(size):
     want = np.linalg.inv(np.eye(size) + lower.astype(np.float64))
     assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
     assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+
+#: the contractions the kernels take (``a b``, ``a b^T``, ``a^T b``) by
+#: the operands' dtypes: both float32 (six passes), one bfloat16 (three)
+PRODUCT_CASES = [(dims, left, right)
+                 for dims in ("_NN", "_NT", "_TN")
+                 for left, right in (("float32", "float32"),
+                                     ("float32", "bfloat16"),
+                                     ("bfloat16", "float32"))]
+
+
+def product_operands(rng, dims, left, right, whole):
+    """(a, b) float64 for a contraction over 32 terms, a (24, 40)
+    result, ``left`` and ``right`` the dtypes they will be given in.
+    ``whole``: integers of 12 bits (two bfloat16 parts) in float32 and
+    of 6 (one part) in bfloat16, so that every sum of 32 products is a
+    float32 to the bit."""
+    def draw(shape, dtype):
+        if whole:
+            top = 4095 if dtype == "float32" else 63
+            return rng.integers(-top, top + 1, shape).astype(np.float64)
+        return rng.normal(size=shape) \
+            * np.exp(rng.uniform(-3, 3, (shape[0], 1)))
+    return (draw((32, 24) if dims == "_TN" else (24, 32), left),
+            draw((40, 32) if dims == "_NT" else (32, 40), right))
+
+
+@pytest.mark.parametrize("dims,left,right", PRODUCT_CASES)
+def test_a_product_keeps_every_part_its_operands_hold(dims, left, right):
+    """``deltanet._product`` over ``_parts``: against ``highest`` on the
+    same operands to 2^-21 of the result's scale, whatever the dtypes;
+    and where an operand is bfloat16 (one part, three passes) *exact*:
+    on integers whose sums float32 holds to the bit, the float64
+    product's value, which no dropped part would give."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from rnb_tpu.ops import deltanet
+    how = getattr(deltanet, dims)
+    rng = np.random.default_rng(len(dims + left + right))
+
+    def both(whole):
+        a, b = product_operands(rng, dims, left, right, whole)
+        a, b = jnp.asarray(a, jnp.dtype(left)), jnp.asarray(b,
+                                                            jnp.dtype(right))
+        got = deltanet._product(deltanet._parts(a), deltanet._parts(b), how)
+        assert got.dtype == jnp.float32
+        f32 = jnp.float32
+        want = lax.dot_general(a.astype(f32), b.astype(f32), how,
+                               precision=lax.Precision.HIGHEST)
+        exact = np.einsum(
+            {"_NN": "ik,kj->ij", "_NT": "ik,jk->ij", "_TN": "ki,kj->ij"}[
+                dims], np.asarray(a, np.float64), np.asarray(b, np.float64))
+        return np.asarray(got, np.float64), np.asarray(want), exact
+    got, want, exact = both(False)
+    assert np.abs(got - want).max() <= 2.0 ** -21 * np.abs(want).max()
+    assert np.abs(got - exact).max() <= 2.0 ** -21 * np.abs(exact).max()
+    passes = len(deltanet._terms(*(
+        len(deltanet._parts(jnp.zeros((), jnp.dtype(x))))
+        for x in (left, right))))
+    if "bfloat16" not in (left, right):
+        assert passes == 6
+        return
+    assert passes == 3
+    # the float32 side's 12 bits lie in two parts: one part alone is off
+    got, _, exact = both(True)
+    assert np.abs(exact).max() < 2 ** 24
+    assert np.array_equal(got, exact)
+
+
+def test_a_float32_operand_is_its_three_parts_to_the_bit():
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import deltanet
+    rng = np.random.default_rng(0)
+    x = (rng.normal(size=(64, 64)) * np.exp(rng.uniform(-20, 20, (64, 64)))) \
+        .astype(np.float32)
+    parts = deltanet._parts(jnp.asarray(x))
+    assert [p.dtype for p in parts] == [jnp.bfloat16] * 3
+    assert np.array_equal(
+        sum(np.asarray(p, np.float64) for p in parts), x.astype(np.float64))
+    one = deltanet._parts(jnp.asarray(x, jnp.bfloat16))
+    assert len(one) == 1 and one[0].dtype == jnp.bfloat16
 
 
 def test_keys_alike_do_not_break_the_solve():
