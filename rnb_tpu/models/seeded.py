@@ -49,7 +49,8 @@ import numpy as np
 class TensorSpec:
     shape: Tuple[int, ...]
     dtype: str          # "bfloat16" | "float32"
-    kind: str           # normal | ones (x scale) | a_log | dt_bias | uniform
+    kind: str           # normal | ones (x scale) | a_log | a_log_states |
+                        # dt_bias | uniform
     scale: float = 1.0
     per_expert: bool = False   # leading axis = routed experts
     #: ``shape`` is the stored one: the published orientation, in which
@@ -130,6 +131,10 @@ def _drawer(spec: TensorSpec):
         elif spec.kind == "a_log":
             x = jnp.log(jax.random.uniform(key, shape, jnp.float32,
                                            1.0, 16.0))
+        elif spec.kind == "a_log_states":
+            # Mamba-1's own: log(1 .. N) along the state axis, a channel
+            x = jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[-1] + 1, dtype=jnp.float32)), shape)
         elif spec.kind == "dt_bias":
             t_min, t_max, t_floor = spec.steps
             u = jax.random.uniform(key, shape, jnp.float32)

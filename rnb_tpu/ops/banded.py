@@ -174,6 +174,14 @@ def band_tables(row_start, qlen: int, inv_freq):
     return cos, sin * sign, start[:, None]
 
 
+def band_start(row_start, qlen: int):
+    """int32 (T, 1): the first token of each token's request
+    (:func:`band_tables`' third, alone: a layer that turns nothing reads
+    no other table)."""
+    start = jnp.repeat(row_start.astype(jnp.int32) * qlen, qlen)
+    return start[:, None]
+
+
 def band_tiles(tokens: int, block: int):
     """(the steps a key-value head takes, the (``block``, 2 ``block``)
     tiles on or under the diagonal: what a kernel that walked the causal
@@ -194,33 +202,62 @@ def _first_lines(x, weight, cos, sin, eps: float, act, scale=None):
     return (x if scale is None else x * scale).astype(act)
 
 
-def _kernel(q_ref, k0_ref, k1_ref, v0_ref, v1_ref, cos0_ref, cos1_ref,
-            sin0_ref, sin1_ref, start_ref, qw_ref, kw_ref, o_ref, *,
-            window: int, dim: int, eps: float):
+def _kernel(*refs, window: int, dim: int, eps, pair_eps=None):
     """One query block of one key-value head. ``q_ref`` (B, per * D)
-    float32 and ``k1_ref`` (B, D) float32 as their products wrote them,
-    ``v1_ref`` (B, D) in the activations' dtype, ``cos1_ref``,
-    ``sin1_ref`` (B, D) the block's rotary tables; ``k0_ref``,
-    ``v0_ref``, ``cos0_ref``, ``sin0_ref`` the same of the block before
-    it (of this block again at the pool's first); ``start_ref`` (B, 1)
-    the first token of each query's request; ``qw_ref``, ``kw_ref`` (1,
-    D) the norms' weights in float32; ``o_ref`` as ``q_ref``, in the
-    activations' dtype."""
+    and ``k1_ref`` (B, D) as their products wrote them, ``v1_ref`` (B,
+    D) in the activations' dtype; ``k0_ref``, ``v0_ref`` the same of
+    the block before it (of this block again at the pool's first);
+    ``start_ref`` (B, 1) the first token of each query's request;
+    ``o_ref`` as ``q_ref``, in the activations' dtype.
+
+    With a head norm (``eps`` not None: K-EXAONE's) ``q_ref`` and the
+    keys are float32, ``cos1_ref``, ``sin1_ref`` (B, D) are the block's
+    rotary tables and ``cos0_ref``, ``sin0_ref`` the block before's,
+    ``qw_ref``, ``kw_ref`` (1, D) the norms' weights in float32:
+    :func:`_first_lines`. Without one q comes scaled and rounded, and
+    nothing is turned.
+
+    With ``pair_eps`` (differential attention, Phi-4-mini-flash's:
+    :func:`differential_banded_attention`) a "head" of ``D`` lanes is a
+    *pair* of heads of ``D / 2`` — ``[q1 | q2]``, ``[k1 | k2]``, ``[v1 |
+    v2]`` — with two softmaxes, ``q1 . k1`` and ``q2 . k2``, over the
+    one value of ``D`` columns; the last lines are ``P1 V - lambda P2
+    V``, the RMS norm over the ``D`` columns and its weight:
+    ``lam_ref`` (1, D) float32 ``lambda`` over the lanes, ``sub_ref``
+    (1, D) the norm's weight times the layer's scale."""
+    if eps is not None:
+        (q_ref, k0_ref, k1_ref, v0_ref, v1_ref, cos0_ref, cos1_ref,
+         sin0_ref, sin1_ref, start_ref, qw_ref, kw_ref, o_ref) = refs
+    else:
+        (q_ref, k0_ref, k1_ref, v0_ref, v1_ref, start_ref, lam_ref,
+         sub_ref, o_ref) = refs
     i = pl.program_id(0)
     block = q_ref.shape[0]
     per = q_ref.shape[1] // dim
     act = v1_ref.dtype
 
-    cos, sin = cos1_ref[...], sin1_ref[...]
-    q_weight, k_weight = qw_ref[...], kw_ref[...]
-    # the scores' scale goes onto the float32 queries, before their one
-    # rounding
-    q = [_first_lines(q_ref[:, h * dim:(h + 1) * dim], q_weight, cos, sin,
-                      eps, act, dim ** -0.5) for h in range(per)]
-    k = jnp.concatenate([
-        _first_lines(k0_ref[...], k_weight, cos0_ref[...], sin0_ref[...],
-                     eps, act),
-        _first_lines(k1_ref[...], k_weight, cos, sin, eps, act)], axis=0)
+    if eps is not None:
+        cos, sin = cos1_ref[...], sin1_ref[...]
+        q_weight, k_weight = qw_ref[...], kw_ref[...]
+        # the scores' scale goes onto the float32 queries, before their
+        # one rounding
+        q = [_first_lines(q_ref[:, h * dim:(h + 1) * dim], q_weight, cos,
+                          sin, eps, act, dim ** -0.5) for h in range(per)]
+        k = jnp.concatenate([
+            _first_lines(k0_ref[...], k_weight, cos0_ref[...],
+                         sin0_ref[...], eps, act),
+            _first_lines(k1_ref[...], k_weight, cos, sin, eps, act)],
+            axis=0)
+    else:
+        q = [q_ref[:, h * dim:(h + 1) * dim] for h in range(per)]
+        k = jnp.concatenate([k0_ref[...], k1_ref[...]], axis=0)
+    if pair_eps is not None:
+        # a pair's two heads against the pair's keys whole, the other
+        # head's lanes zeroed: the matrix unit's columns are 128 either
+        # way (``ops/ssd.py``'s lanes), and nothing is shifted along them
+        first = lax.broadcasted_iota(jnp.int32, (block, dim), 1) < dim // 2
+        q = [jnp.where(first == mine, pair, jnp.zeros_like(pair))
+             for pair in q for mine in (True, False)]
     v = jnp.concatenate([v0_ref[...], v1_ref[...]], axis=0)
     t = i * block + lax.broadcasted_iota(jnp.int32, (block, 2 * block), 0)
     at = (i - 1) * block \
@@ -238,6 +275,16 @@ def _kernel(q_ref, k0_ref, k1_ref, v0_ref, v1_ref, cos0_ref, cos1_ref,
         sums.append(p[-1].sum(axis=-1, keepdims=True))
     o = [jnp.dot(of_head, v, preferred_element_type=jnp.float32)
          for of_head in p]
+    if pair_eps is not None:
+        lam, sub = lam_ref[...], sub_ref[...]
+        for h in range(per):
+            pair = o[2 * h] / sums[2 * h] \
+                - lam * (o[2 * h + 1] / sums[2 * h + 1])
+            pair = pair * lax.rsqrt(
+                jnp.mean(pair * pair, -1, keepdims=True) + pair_eps)
+            o_ref[:, h * dim:(h + 1) * dim] = (pair * sub) \
+                .astype(o_ref.dtype)
+        return
     for h in range(per):
         o_ref[:, h * dim:(h + 1) * dim] = (o[h] / sums[h]).astype(o_ref.dtype)
 
@@ -311,6 +358,99 @@ def banded_attention(q, k, v, q_weight, k_weight, tables, window: int,
                      window=int(window), eps=float(eps),
                      interpret=bool(interpret))
     tiles = band_tiles(q.shape[0], band_block(q.shape[0], int(window)))
+    return out, jnp.asarray(tiles, jnp.int32)
+
+
+# -- differential attention under a window --------------------------------
+
+DIFFERENTIAL_KERNEL_NAME = "differential_banded_attention"
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "window", "pairs", "dim", "eps", "interpret"))
+def _differential_call(qkv, lam, sub, start, *, window, pairs, dim, eps,
+                       interpret):
+    tokens = qkv.shape[0]
+    query_pairs, key_pairs = pairs
+    per = query_pairs // key_pairs
+    block = band_block(tokens, window)
+    steps = tokens // block
+    # q's, k's and v's columns in the one array their product wrote:
+    # pair g's start at these blocks of ``per * dim`` and of ``dim``
+    k_at, v_at = query_pairs, query_pairs + key_pairs
+
+    def spec(width, first, before=False):
+        return pl.BlockSpec((block, width), lambda i, g: (
+            jnp.maximum(i - 1, 0) if before else i, first + g))
+    row = pl.BlockSpec((1, dim), lambda i, g: (0, 0))
+    f32 = jnp.float32
+    operands = (qkv, qkv, qkv, qkv, qkv, start,
+                jnp.broadcast_to(lam.astype(f32), (1, dim)),
+                sub.astype(f32)[None, :])
+    out = jax.ShapeDtypeStruct((tokens, query_pairs * dim), qkv.dtype)
+    # two score tiles a query pair, each with its product with the value
+    scored = steps * key_pairs * 2 * per * block * 2 * block
+    return pl.pallas_call(
+        functools.partial(_kernel, window=window, dim=dim, eps=None,
+                          pair_eps=eps),
+        grid=(steps, key_pairs),
+        in_specs=[spec(per * dim, 0), spec(dim, k_at, True),
+                  spec(dim, k_at), spec(dim, v_at, True), spec(dim, v_at),
+                  pl.BlockSpec((block, 1), lambda i, g: (i, 0)), row, row],
+        out_specs=spec(per * dim, 0), out_shape=out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=96 * 2 ** 20),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * 2 * scored * dim, transcendentals=scored,
+            bytes_accessed=2 * qkv.size * qkv.dtype.itemsize),
+        interpret=interpret, name=DIFFERENTIAL_KERNEL_NAME)(*operands)
+
+
+def differential_banded_attention(qkv, lam, sub_weight, start, window: int,
+                                  heads, eps: float,
+                                  interpret: bool = False):
+    """One layer's *differential* attention under a window, from the
+    layer's first product to its last one's operand; Phi-4-mini-flash's
+    sliding layers (``models/phi4_flash/network.py``) are the caller.
+    The kernel is K-EXAONE's above without its first lines and with two
+    more last ones (:func:`_kernel`'s text): what follows is what
+    differs.
+
+    ``qkv`` (T, (Hq + 2 Hk) d) in the activations' dtype, the one array
+    the layer's first product wrote, ``[Q | K | V]`` with the bias added
+    and Q *scaled* before its one rounding (``d ** -0.5`` = 1 / 8 at the
+    published 64: exact in any dtype), heads of ``d`` side by side;
+    ``heads`` (Hq, Hk). Heads pair by stripes: query pair j is heads 2j
+    (``q1``) and 2j + 1 (``q2``), key-value pair g likewise, and query
+    pair j reads pair ``g = j // (Hq / Hk)`` — so a pair is ``D = 2 d``
+    = 128 adjacent lanes of each of Q, K and V, and the kernel reads
+    them where they lie: the array is passed five times, no slice, pad
+    or transpose in HBM. ``lam`` () float32 the layer's ``lambda``;
+    ``sub_weight`` (D,) the sub-layer norm's weight times the layer's
+    scale ``1 - lambda_init``, ``eps`` the norm's; ``start`` (T, 1)
+    int32 (:func:`band_start`); a query reads the ``window`` keys of its
+    request that end with its own.
+    -> ((T, Hq d) in ``qkv``'s dtype: per pair ``RMSNorm(P1 [v1 | v2] -
+    lambda P2 [v1 | v2]) sub_weight``; int32 (2,): :func:`band_tiles`).
+
+    A step is (query block, key-value pair): its ``Hq / Hk`` query pairs
+    are ``2 Hq / Hk`` score tiles, each a pair's lanes with the other
+    head's zeroed against the pair's keys whole — a product over 128
+    lanes costs the matrix unit what one over 64 does, and no lane is
+    shifted; each softmax has its product with the 128-wide value (a
+    pair's scores are computed once for both halves of the value), and
+    the subtraction, the norm and the weight run on the (B, 128) results.
+    The window of 512 makes the block 512 and a step two key blocks:
+    1,024 keys computed where at most 512 are kept, four float32 (512,
+    1,024) score tiles a step (the limit on VMEM is raised for them)."""
+    hq, hk = heads
+    dim = 2 * (qkv.shape[1] // (hq + 2 * hk))
+    out = _differential_call(
+        qkv, lam, sub_weight, start, window=int(window),
+        pairs=(hq // 2, hk // 2), dim=int(dim), eps=float(eps),
+        interpret=bool(interpret))
+    tiles = band_tiles(qkv.shape[0], band_block(qkv.shape[0], int(window)))
     return out, jnp.asarray(tiles, jnp.int32)
 
 

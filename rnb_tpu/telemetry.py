@@ -1002,6 +1002,12 @@ STAGE_COUNTERS = (
         "of it its history (``ops/ssd.py``'s ``row_first``), pad rows "
         "not counted: the requests a dispatch packed"),
     StageCounter(
+        "cross_lines", "Tokens:", "tokens_", ("cross_lines",),
+        "(1,): the lines a dispatch sends through a cross-decoder, of a "
+        "stack whose second half reads the first half's keys, values and "
+        "scan memory (``models/phi4_flash``): one a request with the "
+        "prefill exit, one a valid token without it"),
+    StageCounter(
         "expert_served", "Experts:", "experts_",
         ("assignments", "held", "max_per_expert", "mean_per_expert"),
         "(expert layers, held): the assignments each held expert "

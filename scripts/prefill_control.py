@@ -45,6 +45,15 @@ out (that one fails the key slack). ``old_draw`` is a witness and no
 fault: program *and* reference with the latents' norm weights at one,
 the draw the cell's first chip run read 24% of the spread under
 (``models/dots3_note/checkpoint.py``); it fails as that run did.
+``phi4_flash`` (Mamba-1 scans, differential attention under a window and
+in one full layer, a cross-decoder on one line a request) has its scans'
+states through bfloat16, recorded either way, every stored matrix
+through float8, and :func:`phi4_flash_faults`, functions of its module
+replaced, each of which must fail: ``one_softmax`` (``lambda`` at zero:
+``lambda P2 V`` dropped in all sixteen attention layers),
+``window_off`` (the sliding layers read the whole context) and
+``memory_gated`` (the Gated Memory Units read layer 16's *gated*
+output). ``own_keys`` is no arm: a cross layer has no keys of its own.
 ``--arms`` names the ones to run where a chip's minutes are counted.
 """
 
@@ -101,7 +110,35 @@ def arms_of(family: str):
                 "flat_gates", "no_rescale", "window_a_key_short",
                 "theta_swapped", "index_no_rotary", "old_draw")] \
             + [("layers_float8", {}, lambda group, name: True)]
+    if family == "phi4_flash":
+        return [("as_stated", {}, None),
+                ("state_bfloat16", {"state_dtype": jnp.bfloat16}, None)] \
+            + [(name, {}, None) for name in (
+                "one_softmax", "window_off", "memory_gated")] \
+            + [("layers_float8", {}, lambda group, name: name.split(
+                ".")[-1] in PHI4_FLASH_MATRICES)]
     raise ValueError("no control arms for family %r" % (family,))
+
+
+#: the stored matrices of a Phi-4-mini-flash layer (a stacked group's
+#: vectors have two axes too: the rounding goes by name)
+PHI4_FLASH_MATRICES = ("gate_up", "down", "in_proj", "x_proj", "dt_proj",
+                       "out_proj", "qkv", "q", "o", "g_in", "g_out")
+
+
+def phi4_flash_faults():
+    """{arm: fault} of ``models/phi4_flash/network``: attributes of the
+    module replaced while the arm's program is traced
+    (:func:`dots3_note_faults`' ``patch``); ``network.forward`` has no
+    switch for any of them."""
+    from rnb_tpu.models.phi4_flash import network
+    return {
+        "one_softmax": {"patch": {
+            "lambda_of": lambda p, lambda_init: 0.0 * lambda_init}},
+        "window_off": {"patch": {
+            "window_attention": network.full_attention}},
+        "memory_gated": {"patch": {
+            "scan_memory": lambda gated, y: gated}}}
 
 
 def dots3_note_faults(cfg):
@@ -240,7 +277,8 @@ def main(argv=None) -> int:
         if unknown:
             parser.error("no such arm of %s: %s" % (name, sorted(unknown)))
         arms = [a for a in arms if a[0] == "as_stated" or a[0] in wanted]
-    faults = dots3_note_faults(cfg) if name == "dots3_note" else {}
+    faults = dots3_note_faults(cfg) if name == "dots3_note" \
+        else phi4_flash_faults() if name == "phi4_flash" else {}
     programs = {}
     for arm, kwargs, rounded in arms:
         fault = faults.get(arm, {})

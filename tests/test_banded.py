@@ -361,3 +361,176 @@ def test_the_latent_banded_kernel_equals_the_explicit_mask(
     binds = bool((keep.sum(1) == window).any())
     assert (np.abs(np.asarray(short, np.float32)
                    - np.asarray(out, np.float32)).max() > 0.03) == binds
+
+
+# -- differential attention under a window (Phi-4-mini-flash's) ------------------
+
+
+def differential_explicit(qkv, lam, sub, starts, qlen, window, hq, hk, eps):
+    """Two softmaxes a head pair over the pair's one value under an
+    explicit (T, T) mask, in plain float64: ``RMSNorm(P1 [v1 | v2] -
+    lambda P2 [v1 | v2]) sub``; query pair j = heads 2j, 2j + 1 reads
+    key-value pair ``j // (hq / hk)``; q comes scaled."""
+    x = np.asarray(qkv, np.float64)
+    tokens = x.shape[0]
+    d = x.shape[1] // (hq + 2 * hk)
+    q = x[:, :hq * d].reshape(tokens, hq, d)
+    k = x[:, hq * d:(hq + hk) * d].reshape(tokens, hk, d)
+    v = x[:, (hq + hk) * d:].reshape(tokens, hk // 2, 2 * d)
+    seg, at = np.repeat(np.asarray(starts), qlen), np.arange(tokens)
+    ok = (seg[:, None] == seg[None, :]) & (at[None, :] <= at[:, None]) \
+        & (at[None, :] > at[:, None] - window)
+
+    def softmax(s):
+        s = np.where(ok, s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        return p / p.sum(-1, keepdims=True)
+    out = np.zeros((tokens, hq // 2, 2 * d))
+    for j in range(hq // 2):
+        g = j // (hq // hk)
+        o = softmax(q[:, 2 * j] @ k[:, 2 * g].T) @ v[:, g] \
+            - lam * (softmax(q[:, 2 * j + 1] @ k[:, 2 * g + 1].T) @ v[:, g])
+        out[:, j] = o / np.sqrt((o * o).mean(-1, keepdims=True) + eps) \
+            * np.asarray(sub, np.float64)
+    return out.reshape(tokens, -1), ok
+
+
+def differential_draw(seed, tokens, hq, hk, d, dtype):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    qkv = rng.normal(size=(tokens, (hq + 2 * hk) * d))
+    qkv[:, :hq * d] *= 3.0 * d ** -0.5          # q: scaled
+    return (jnp.asarray(qkv, dtype),
+            jnp.asarray(1.0 + 0.2 * rng.normal(size=2 * d), jnp.float32))
+
+
+#: (rows, tokens a row, each row's request, the window, query heads, key
+#: heads, a head's width): the published pairing (two query pairs a
+#: key-value pair, heads of 64) under a window under, at and over a
+#: block, requests that open inside a band, a pad row; the toy stack's
+#: rows of 16
+DIFFERENTIAL_WINDOWS = [
+    (3, 128, [0, 0, 2], 40, 4, 2, 64),
+    (6, 128, [0, 0, 0, 0, 4, 5], 128, 8, 4, 64),
+    (6, 128, [0] * 6, 200, 4, 2, 64),
+    (8, 16, [0, 0, 0, 3, 3, 5, 6, 7], 24, 4, 2, 64),
+    (3, 16, [0, 0, 2], 24, 8, 2, 64),
+]
+
+
+@pytest.mark.parametrize("rows,qlen,starts,window,hq,hk,d",
+                         DIFFERENTIAL_WINDOWS)
+def test_two_softmaxes_over_one_value_equal_plain_jnp(rows, qlen, starts,
+                                                      window, hq, hk, d):
+    """Float32 values: the kernel's last lines (``P1 V - lambda P2 V``,
+    the norm over a pair's 128 columns, its weight) against the explicit
+    mask's."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded
+    qkv, sub = differential_draw(rows + window, rows * qlen, hq, hk, d,
+                                 jnp.float32)
+    out, tiles = banded.differential_banded_attention(
+        qkv, jnp.float32(0.37), sub,
+        banded.band_start(jnp.asarray(starts, jnp.int32), qlen), window,
+        (hq, hk), EPS, True)
+    want, _ = differential_explicit(qkv, 0.37, sub, starts, qlen, window,
+                                    hq, hk, EPS)
+    assert out.shape == (rows * qlen, hq * d) and out.dtype == jnp.float32
+    assert np.abs(np.asarray(out) - want).max() < 2e-5
+    ran, causal = (int(n) for n in np.asarray(tiles))
+    assert 0 < ran <= causal
+
+
+def test_a_query_reads_key_t_minus_511_and_not_t_minus_512():
+    """The published window: 512 keys that end with the query's own. A
+    value planted at key ``t - 511`` moves query t's result, one at ``t -
+    512`` does not; nor does one across a request boundary inside the
+    band."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded
+    rows, qlen, hq, hk, d, window = 12, 128, 4, 2, 64, 512
+    starts = [0] * 9 + [9] * 3
+    assert banded.band_block(rows * qlen, window) == 512
+    qkv, sub = differential_draw(9, rows * qlen, hq, hk, d, jnp.float32)
+    start = banded.band_start(jnp.asarray(starts, jnp.int32), qlen)
+
+    def run(qkv):
+        out, _ = banded.differential_banded_attention(
+            qkv, jnp.float32(0.3), sub, start, window, (hq, hk), EPS, True)
+        return np.asarray(out)
+    base = run(qkv)
+    value = (hq + hk) * d               # V's first column
+    t = 1100                            # of the first request (rows 0-8)
+    for key, moves in ((t - 511, True), (t - 512, False)):
+        moved = np.abs(run(qkv.at[key, value:].add(50.0)) - base).max(-1)
+        assert (moved[t] > 1e-3) == moves, (key, moved[t])
+        # the first query past the window's reach of that key is clean
+        assert moved[key + 512] < 1e-6 and moved[key + 511] > 1e-3
+    # the second request's first queries lie within 512 of the first
+    # request's last keys and read none of them
+    boundary = 9 * qlen
+    moved = np.abs(run(qkv.at[boundary - 1, value:].add(50.0)) - base).max(-1)
+    assert moved[boundary - 1] > 1e-3
+    assert moved[boundary:].max() < 1e-6
+    want, ok = differential_explicit(qkv, 0.3, sub, starts, qlen, window,
+                                     hq, hk, EPS)
+    assert ok[t, t - 511] and not ok[t, t - 512] and not ok[boundary,
+                                                           boundary - 1]
+    assert np.abs(base - want).max() < 2e-5
+
+
+def test_the_differential_kernel_rounds_once_in_bfloat16():
+    """bfloat16 values, as the layer's first product wrote them: the
+    explicit mask reads the same rounded operands; the result is rounded
+    on the store."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded
+    starts = [0, 0, 2, 2, 2, 5]
+    qkv, sub = differential_draw(11, 6 * 128, 4, 2, 64, jnp.bfloat16)
+    out, _ = banded.differential_banded_attention(
+        qkv, jnp.float32(0.6), sub,
+        banded.band_start(jnp.asarray(starts, jnp.int32), 128), 128, (4, 2),
+        EPS, True)
+    want, _ = differential_explicit(qkv, 0.6, sub, starts, 128, 128, 4, 2,
+                                    EPS)
+    assert out.dtype == jnp.bfloat16
+    assert np.abs(np.asarray(out, np.float64) - want).max() \
+        < 2 ** -7 * np.abs(want).max()
+
+
+def test_the_differential_kernel_compiles_at_the_published_widths(one_chip):
+    """A sliding layer's kernel over the largest row bucket, from the
+    first product's one array to ``o``'s operand, compiled for a
+    described v5e (nothing runs): one custom call under its own name,
+    and nothing beside it — no slice of Q, K or V, no pad, no
+    transpose."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded
+    with open(os.path.join(
+            REPO, "benchmarks/configs/phi4-mini-flash.json")) as f:
+        config = json.load(f)
+    rows = max(config["pipeline_config"]["pipeline"][-1]["row_buckets"])
+    hq, hk = config["num_attention_heads"], config["num_key_value_heads"]
+    d = config["hidden_size"] // hq
+    tokens = rows * config["chunk_size"]
+    assert banded.band_block(tokens, config["sliding_window"]) == 512
+
+    def of(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda qkv, lam, sub, start: banded.differential_banded_attention(
+            qkv, lam, sub, start, config["sliding_window"], (hq, hk),
+            config["layer_norm_eps"])).lower(
+        of((tokens, (hq + 2 * hk) * d)), of((), jnp.float32),
+        of((2 * d,), jnp.float32), of((tokens, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert banded.DIFFERENTIAL_KERNEL_NAME in text
+    assert "bf16[%d,%d]" % (tokens, hq * d) in text
+    assert "transpose(" not in text and "pad(" not in text \
+        and " slice(" not in text

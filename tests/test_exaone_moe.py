@@ -594,8 +594,13 @@ def test_a_new_reader_reads_nothing_on_a_run_without_its_scope(
     module = mm.load_layer_metric(name)
     entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
     # PR 55's cell counts its banded kernel's steps in the same pair
+    # and PR 59's, differential attention under a window of 512 and in
+    # one full layer, runs under the same scopes
     joined = ["dots3-note.bulk"] if name == "window_tile_visit_pct.bulk" \
-        else []
+        else ["phi4-flash.bulk"] if name in (
+            "window_attn_ms_per_dispatch.bulk",
+            "full_attn_ms_per_dispatch.bulk",
+            "window_attn_roofline_pct.bulk") else []
     assert entry and entry[0]["workloads"] == [CELL] + joined
     assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == "packed attention"

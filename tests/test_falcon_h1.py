@@ -590,9 +590,9 @@ def test_the_accepted_readers_list_the_cell_last():
 
     def last_of_its_pr(workloads):
         """The cell stands last but for the cells later PRs appended
-        (PR 55's ``dots3-note.bulk``)."""
+        (PR 55's ``dots3-note.bulk``, PR 59's ``phi4-flash.bulk``)."""
         behind = workloads[workloads.index(CELL) + 1:]
-        return set(behind) <= {"dots3-note.bulk"}
+        return set(behind) <= {"dots3-note.bulk", "phi4-flash.bulk"}
     for name in LISTED:
         assert last_of_its_pr(by_name[name + ".bulk"]["workloads"]), name
     listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())
@@ -641,7 +641,11 @@ def test_a_new_reader_reads_nothing_on_a_run_without_its_source(
     what the file declares."""
     module = mm.load_layer_metric(name)
     entry = [m for m in mm.load()["per_layer"] if m["name"] == name]
-    assert entry and entry[0]["workloads"] == [CELL]
+    # PR 59's cell, a dense MLP at a second width and a scan's resets,
+    # joined two
+    joined = ["phi4-flash.bulk"] if name in (
+        "mlp_roofline_pct.bulk", "scan_resets_per_dispatch.bulk") else []
+    assert entry and entry[0]["workloads"] == [CELL] + joined
     assert mm.describe(module) == {k: entry[0][k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == NEW_READERS[name]
     assert module.read(facts_of(tmp_path)) is None

@@ -1,0 +1,24 @@
+"""``phi4-flash.bulk`` through the one benchmark command, traced, the
+control script's arms and the final stage serving the family, at a toy
+size on the CPU, by ``family_contract.py``; the record is
+``test_phi4_flash.py``'s. A file of its own because one file is one
+worker's under ``--dist loadfile`` and a run takes over a minute."""
+
+import pytest
+
+import family_contract as contract
+
+FAMILY = contract.record("phi4_flash")
+
+
+@pytest.mark.parametrize("trace", FAMILY.traces)
+def test_the_cell_through_the_benchmark_command(trace, tmp_path):
+    contract.run_the_cell(FAMILY, trace, tmp_path)
+
+
+def test_the_control_script_runs_the_familys_arms(tmp_path):
+    contract.run_the_control(FAMILY, tmp_path)
+
+
+def test_the_prefill_stage_serves_the_family(tmp_path):
+    contract.stage_serves(FAMILY, tmp_path)

@@ -33,7 +33,8 @@ POOLS = {
 #: activations' (None) or float32)
 CALLERS = {"nemotron_h": (6144, True, None),
            "qwen3_next": (8192, False, "float32"),
-           "falcon_h1": (5120, True, None)}
+           "falcon_h1": (5120, True, None),
+           "phi4_flash": (5120, True, None)}
 
 
 def first_of(pool):
@@ -169,6 +170,7 @@ SPLITS = {
     "qwen3_next": (8192, 16, ((4096, "float32"), (4096, "bfloat16"))),
     "falcon_h1": (5120, 16, ((4096, "bfloat16"), (512, "bfloat16"),
                              (512, "bfloat16"))),
+    "phi4_flash": (5120, 16, ((5120, "bfloat16"),)),
     "toy_nemotron_h": (128, 16, ((64, "bfloat16"), (32, "bfloat16"),
                                  (32, "bfloat16"))),
     "toy_qwen3_next": (128, 32, ((64, "float32"), (64, "bfloat16"))),
@@ -261,7 +263,8 @@ def one_chip():
 
 
 #: caller -> the rows of its largest dispatch
-REAL_ROWS = {"nemotron_h": 64, "qwen3_next": 128, "falcon_h1": 64}
+REAL_ROWS = {"nemotron_h": 64, "qwen3_next": 128, "falcon_h1": 64,
+             "phi4_flash": 128}
 
 
 @pytest.mark.parametrize("caller", sorted(CALLERS))

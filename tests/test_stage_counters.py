@@ -21,7 +21,8 @@ from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAGE_COUNTERS,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
-            "exaone_moe", "keye_vl2", "falcon_h1", "dots3_note")
+            "exaone_moe", "keye_vl2", "falcon_h1", "dots3_note",
+            "phi4_flash")
 
 #: what the dispatches of one stage summed to, as ``network.forward``
 #: hands each counter back: the layers that count first
@@ -37,6 +38,7 @@ RAW = {
     "index_chunks": [[9, 16], [9, 16]],
     "scan_resets": [21],
     "window_keys": [[30, 70], [30, 70]],
+    "cross_lines": [21],
 }
 
 TOKENS = "Tokens: valid=10 shipped=16"
@@ -60,6 +62,9 @@ GOLDEN = {
                  "chosen_keys=110 tiles_chosen=5 tiles_causal=8 "
                  "chunks_walked=18 chunks_to_diagonal=32"],
     "falcon_h1": [TOKENS + " scan_resets=21", ATTENTION],
+    "phi4_flash": [TOKENS + " scan_resets=21 cross_lines=21",
+                   ATTENTION + " window_keys_kept=60 "
+                               "window_keys_causal=140"],
     "dots3_note": [TOKENS, EXPERTS + " pair_rows_moved=22 pair_rows_all=80 "
                                      "gmm_rows=384",
                    "Sparse: queries=40 selecting=20 causal_keys=160 "
@@ -116,6 +121,9 @@ def test_a_familys_counters_give_the_lines_the_launcher_wrote(family,
 @pytest.mark.parametrize("line,keys", [
     (TOKENS + " scan_resets=21",
      {"tokens_valid": 10, "tokens_shipped": 16, "tokens_scan_resets": 21}),
+    (GOLDEN["phi4_flash"][0],
+     {"tokens_valid": 10, "tokens_shipped": 16, "tokens_scan_resets": 21,
+      "tokens_cross_lines": 21}),
     (EXPERTS + " group_tokens=13 pair_rows_moved=22 pair_rows_all=80 "
                "gmm_rows=384",
      {"experts_assignments": 60, "experts_held": 17,
@@ -131,7 +139,7 @@ def test_a_familys_counters_give_the_lines_the_launcher_wrote(family,
      {"attention_tiles_visited": 9, "attention_tiles_causal": 15,
       "attention_window_tiles_visited": 3,
       "attention_window_tiles_causal": 4}),
-], ids=["Tokens", "Experts", "Sparse", "Attention"])
+], ids=["Tokens", "Tokens-lines", "Experts", "Sparse", "Attention"])
 def test_a_line_parses_to_the_keys_it_always_had(line, keys, tmp_path):
     meta = parse(tmp_path, line)
     assert meta == keys
