@@ -16,7 +16,8 @@ v_t``, ``o_t = q_t S_t / sqrt(d)`` with per-head RMSNorm and rotary
 (positions inside the request, ``ops/rope.py``) on queries and keys, the
 state zero at a request's first token, ``lambda_h = exp(-2^(-8 (h + 1)
 / H))``; an RMSNorm over all heads' outputs, a sigmoid gate. It runs
-through ``ops/ssd.ssd_scan`` with unit steps.
+through ``ops/ssd.ssd_scan`` with unit steps, every line between the
+five products inside that kernel.
 
 A *row* is ``chunk_size`` tokens; a request is a run of consecutive
 rows with its tail padded. Weights and activations are bfloat16; the
@@ -41,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from rnb_tpu.ops import blocksparse, moe, rope, ssd
+from rnb_tpu.ops import banded, blocksparse, moe, ssd
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
 #: what ``forward`` returns behind the logits and the choices
@@ -174,28 +175,37 @@ def sparse_mixer(cfg, p, h, row_start, row_tokens, interpret=False):
     return _proj(out, p["o"]), chosen, counts
 
 
-def lightning_mixer(cfg, p, h, row_first, positions,
+def rotary_tables(cfg, row_start, qlen: int):
+    """The lightning layers' rotary (cos, sin), float32 (rows, Q, dim),
+    of each token's position inside its request, the sign in the sine
+    (``ops/banded.band_tables``): one pair a dispatch for every layer."""
+    rows = row_start.shape[0]
+    return [t.reshape(rows, qlen, -1) for t in banded.band_tables(
+        row_start, qlen, cfg.inv_freq())[:2]]
+
+
+def lightning_mixer(cfg, p, h, row_first, tables,
                     state_dtype=jnp.float32, interpret=False):
-    """``h`` (rows, Q, hidden), normed -> float32 (rows, Q, hidden)."""
+    """``h`` (rows, Q, hidden), normed; ``tables``: :func:`rotary_tables`
+    -> float32 (rows, Q, hidden). Between the five
+    products nothing with a head axis is written but what the scan's
+    kernel reads and writes: the head norms, the rotation, the scale and
+    the rounding of q and k are its first lines, the output norm and the
+    gate its last (``ops/ssd.py``)."""
     rows, q, _ = h.shape
-    act = h.dtype
     heads, dim = cfg.lightning_nh, cfg.lightning_head_dim
-    inv_freq = cfg.inv_freq()
-    qs = rope.rotate(_heads(cfg, h, p["q"], p["q_norm"], heads, dim),
-                     positions, inv_freq)
-    ks = rope.rotate(_heads(cfg, h, p["k"], p["k_norm"], heads, dim),
-                     positions, inv_freq)
-    vs = _heads(cfg, h, p["v"], None, heads, dim).astype(act)
+
+    def of_heads(name):
+        return _proj(h, p[name]).reshape(rows, q, heads, dim)
     # the Mamba-2 scan with unit steps: xs = v, B = k, C = q / sqrt(d),
     # one group a head, no skip term
-    out = ssd.ssd_scan(vs, None, jnp.asarray(cfg.log_decay()),
-                       ks.astype(act), (qs * dim ** -0.5).astype(act), None,
-                       row_first, state_dtype=state_dtype,
-                       interpret=interpret)
-    out = rms_norm(out.reshape(rows, q, heads * dim), p["o_norm"], cfg.eps,
-                   jnp.float32)
-    out = (out * jax.nn.sigmoid(_proj(h, p["gate"]))).astype(act)
-    return _proj(out, p["o"])
+    out = ssd.ssd_scan(
+        of_heads("v").astype(h.dtype), None, jnp.asarray(cfg.log_decay()),
+        of_heads("k"), of_heads("q"), None, row_first,
+        state_dtype=state_dtype, interpret=interpret,
+        head_norm=(p["k_norm"], p["q_norm"], cfg.eps, dim ** -0.5, *tables),
+        out_norm=(_proj(h, p["gate"]), p["o_norm"], cfg.eps))
+    return _proj(out.reshape(rows, q, heads * dim), p["o"])
 
 
 def forward(cfg: MinicpmSalaConfig, params, slots, tokens, row_tokens,
@@ -219,7 +229,7 @@ def forward(cfg: MinicpmSalaConfig, params, slots, tokens, row_tokens,
     del slots
     rows, q = tokens.shape
     row_first = row_start == jnp.arange(rows)
-    positions = rope.pool_positions(row_start, q)
+    tables = rotary_tables(cfg, row_start, q)
     scale = cfg.residual_scale
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -239,7 +249,7 @@ def forward(cfg: MinicpmSalaConfig, params, slots, tokens, row_tokens,
         else:
             with jax.named_scope("ssd"):
                 h = rms_norm(x, p["attn_norm"], cfg.eps, act)
-                out = lightning_mixer(cfg, p, h, row_first, positions,
+                out = lightning_mixer(cfg, p, h, row_first, tables,
                                       state_dtype, interpret)
                 x = (x.astype(jnp.float32) + scale * out).astype(act)
         with jax.named_scope("mlp"):

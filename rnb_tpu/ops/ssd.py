@@ -68,6 +68,36 @@ fusions behind a kernel that wrote float32 the same lines cost four
 passes over a float32 (rows, Q, H P) array and a transposing copy of it
 (11.3 ms of a 146 ms dispatch; my chip run, PR 47).
 
+*Lightning attention's lines* (``head_norm``, ``out_norm``,
+MiniCPM-SALA's mixer; PR 60): q and k come float32 as their products
+wrote them and a step's first lines are, a group's (Q, N) slice at a
+time in VMEM, the head's RMS norm, the rotation ``x cos + roll(x, N /
+2) sin`` (the tables (rows, Q, N) float32, one pair a dispatch for
+every layer), the scale on q and the one rounding to the activations'
+dtype — ``ops/banded.py``'s ``_first_lines``, the float32 operations
+of ``rms_norm`` and ``ops/rope.rotate`` in their order — so that ``C .
+B^T`` and both state products read the values they read when XLA's
+passes wrote them. The output norm's mean spans all the heads and a
+step holds eight, so for this caller the grid is (row, step): every
+step's groups keep states of their own in the scratch (32 heads: 2
+MiB), a step leaves its float32 ``y`` and the sigmoid of its gate block
+in two more (2 MiB each), and the row's last step forms ``y
+rsqrt(mean(y^2) + eps) w sigmoid(gate)`` over the row's (Q, H P) and
+writes it once in the activations' dtype: ``y`` is never rounded before
+the norm and never reaches HBM. A caller that hands neither (the two
+Mamba-2 callers) lowers to the program it had. Alone on the v5e
+(``scripts/ssd_sweep.py --only=lightning``, the device's time a layer
+at 128 rows from q, k and the gate in float32 to ``o``'s operand; my
+chip runs, PR 60): the passes around the kernel 11.818 ms, 1.337 of it
+the kernel; the lines inside under the (row, step) grid 2.405 ms, all
+of it the kernel (its bytes, 8 MB a row, are 1.31 ms), 324 of 67 M
+bfloat16 outputs another value, none by more than one step; the other
+sound form — the (step, row) grid as it was, the kernel writing float32
+``y`` and each head's sum of squares, one fused pass behind it for the
+norm and the gate — 3.150 ms, 2.193 of it the kernel (a variant of this
+module measured once and not kept: ``y``'s float32 round trip is 0.65
+ms at the HBM's rate). 1.6-1.9 s to compile and first run either way.
+
 Decays, cumulative sums and states are float32; the products that read
 or build a state take float32 operands at ``highest`` precision, so
 that a state is never rounded to bfloat16 on its way through the matrix
@@ -138,6 +168,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from rnb_tpu.ops.banded import _first_lines
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -352,7 +384,7 @@ def _of_tile(columns, first: int, count: int, p: int, shape):
 
 
 def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
-            skip: bool, eps, state_dtype):
+            skip: bool, eps, state_dtype, turn=None, out_eps=None):
     """One row of one step's groups. ``x_ref`` (Q, heads * P); ``b_ref``,
     ``c_ref`` (Q, groups * N); ``cs_ref`` (Q, heads) the running sums of
     the log decays, a token a sublane, ``cs_row_ref`` (heads, Q) the
@@ -362,7 +394,19 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
     over its lanes; with a gated norm (``eps`` not None) ``z_ref`` (Q,
     heads * P) float32 and ``w_ref`` (1, heads * P) the norm's weight;
     ``state_ref`` (groups, N, per * P) float32, carried: a head's state
-    transposed, ``S^T``."""
+    transposed, ``S^T``.
+
+    With ``turn`` ((eps, scale): ``ssd_scan``'s ``head_norm``) ``b_ref``
+    and ``c_ref`` are float32 as their products wrote them, ``bw_ref``,
+    ``cw_ref`` (1, N) the head norms' weights in float32 and ``cos_ref``,
+    ``sin_ref`` (Q, N) the row's rotary tables: ``ops/banded.py``'s
+    :func:`_first_lines` on each group's slice. With ``out_eps``
+    (``ssd_scan``'s ``out_norm``) the grid is (row, step): ``g_ref`` (Q,
+    heads * P) float32 the step's gate, ``ow_ref`` (1, all heads * P) the
+    norm's weight, ``o_ref`` (Q, all heads * P) the row whole,
+    ``state_ref`` every step's groups, ``y_ref`` and ``gate_ref``
+    (steps, Q, heads * P) float32 the row's ``y`` and the gate's sigmoid
+    as the steps leave them; the row's last step norms and writes."""
     refs = iter(refs)
     x_ref, b_ref, c_ref, cs_ref, cs_row_ref, end_ref = (
         next(refs) for _ in range(6))
@@ -371,15 +415,28 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
     d_ref = next(refs) if skip else None
     z_ref, w_ref = (next(refs), next(refs)) if eps is not None \
         else (None, None)
-    o_ref, state_ref = refs
+    if turn is not None:
+        bw_ref, cw_ref, cos_ref, sin_ref = (next(refs) for _ in range(4))
+    if out_eps is not None:
+        g_ref, ow_ref, o_ref, state_ref, y_ref, gate_ref = refs
+    else:
+        o_ref, state_ref = refs
     f32 = jnp.float32
     qlen = x_ref.shape[0]
     act = x_ref.dtype
-    row = pl.program_id(1)
+    # with an output norm over all the heads a row's steps are the inner
+    # axis, and each step's groups keep states of their own
+    row = pl.program_id(1 if out_eps is None else 0)
+    step = None if out_eps is None else pl.program_id(1)
+    step_groups = b_ref.shape[1] // n
 
     @pl.when((row == 0) | (first_ref[row] != 0))
     def _():
-        state_ref[...] = jnp.zeros_like(state_ref)
+        if step is None:
+            state_ref[...] = jnp.zeros_like(state_ref)
+        else:
+            state_ref[pl.ds(step * step_groups, step_groups)] = jnp.zeros(
+                (step_groups,) + state_ref.shape[1:], f32)
 
     tile_heads, lanes = _lane_tile(per, p)
     token = lax.broadcasted_iota(jnp.int32, (qlen, qlen), 0)
@@ -389,9 +446,14 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
     def side_by_side(parts):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
 
-    for group in range(b_ref.shape[1] // n):
+    for group in range(step_groups):
         b = b_ref[:, group * n:(group + 1) * n]
         c = c_ref[:, group * n:(group + 1) * n]
+        if turn is not None:
+            b = _first_lines(b, bw_ref[...], cos_ref[...], sin_ref[...],
+                             turn[0], act)
+            c = _first_lines(c, cw_ref[...], cos_ref[...], sin_ref[...],
+                             turn[0], act, turn[1])
         cb = _scores(c, b)                                   # (Q, Q)
         heads = range(group * per, (group + 1) * per)
         tiles = range(heads.start, heads.stop, tile_heads)
@@ -418,7 +480,8 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
                 y = part if y is None else y + part
             ys.append(y)
         xf = x.astype(f32)
-        state = state_ref[group]                             # (N, per P)
+        slot = group if step is None else step * step_groups + group
+        state = state_ref[slot]                              # (N, per P)
         since = side_by_side([_of_tile(cs, at, tile_heads, p, (qlen, lanes))
                               for at in tiles])
         last = end_ref[:, of_group]                          # (1, per P)
@@ -431,7 +494,10 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
             y = y * (z * jax.nn.sigmoid(z))
             y = y * lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps) \
                 * w_ref[:, of_group]
-        o_ref[:, of_group] = y.astype(o_ref.dtype)
+        if out_eps is None:
+            o_ref[:, of_group] = y.astype(o_ref.dtype)
+        else:
+            y_ref[step, :, of_group] = y
         # the carry: one multiply-add of the state a row
         to_end = jnp.exp(last - since)
         if steps_dt:
@@ -442,11 +508,27 @@ def _kernel(first_ref, *refs, per: int, p: int, n: int, steps_dt: bool,
         state = jnp.exp(last) * state + _dot(b.astype(f32).T, xf * to_end)
         # inside the kernel the pair of conversions is Mosaic's to lower,
         # and it keeps both (``ops/deltanet.py``)
-        state_ref[group] = state.astype(state_dtype).astype(f32)
+        state_ref[slot] = state.astype(state_dtype).astype(f32)
+
+    if out_eps is None:
+        return
+    gate_ref[step] = jax.nn.sigmoid(g_ref[...])
+
+    @pl.when(step == y_ref.shape[0] - 1)
+    def _():
+        width = y_ref.shape[2]
+        ys = [y_ref[s] for s in range(y_ref.shape[0])]
+        squares = sum(jnp.sum(y * y, -1, keepdims=True) for y in ys)
+        scale = lax.rsqrt(squares / o_ref.shape[1] + out_eps)
+        for s, y in enumerate(ys):
+            lanes = slice(s * width, (s + 1) * width)
+            o_ref[:, lanes] = (y * scale * ow_ref[:, lanes]
+                               * gate_ref[s]).astype(o_ref.dtype)
 
 
 def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
-             interpret: bool = False, gated_norm=None):
+             interpret: bool = False, gated_norm=None, head_norm=None,
+             out_norm=None):
     """The scan of one block over a packed pool.
 
     ``xs`` (rows, Q, H, P); ``dt`` (rows, Q, H) float32, after its
@@ -461,6 +543,25 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
     with the mean over each group's ``H / G * P`` columns, in float32,
     -> ``xs``'s dtype. ``z`` (rows, Q, H P) float32; ``weight`` (H P,).
 
+    ``head_norm``: None, or (``b_weight``, ``c_weight``, ``eps``,
+    ``c_scale``, ``cos``, ``sin``), lightning attention's lines in front
+    of the scan as the kernel's first: ``b`` and ``c`` come float32 as
+    their products wrote them, and a step's block goes, in VMEM, through
+    each group's RMS norm over its ``N`` columns (weights (N,)), the
+    rotation ``x cos + roll(x, N / 2) sin`` (``cos``, ``sin`` float32
+    (rows, Q, N), the sign in the table: ``ops/banded.band_tables``),
+    ``c`` times ``c_scale``, and one rounding to ``xs``'s dtype — the
+    float32 operations of an RMS norm and ``ops/rope.rotate`` in their
+    order.
+
+    ``out_norm``: None, or (``gate``, ``weight``, ``eps``), lightning
+    attention's lines behind the scan as the kernel's last: ``y
+    rsqrt(mean(y^2) + eps) weight sigmoid(gate)`` with the mean over all
+    ``H P`` columns, in float32 on the float32 ``y``, -> ``xs``'s dtype.
+    ``gate`` (rows, Q, H P) float32; ``weight`` (H P,). The mean spans
+    every step's heads, so the grid is (row, step): a row's ``y`` and
+    every step's states stay in VMEM and the row's last step writes it.
+
     ``state_dtype`` is the precision the states are carried in
     between rows: float32 in the program; the lower-precision control
     of the tests passes bfloat16. ``interpret`` runs the kernel in
@@ -469,6 +570,7 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
     groups, n = b.shape[2:]
     per = heads // groups
     f32 = jnp.float32
+    by_row = out_norm is not None
     with jax.named_scope("scan"):
         together = _groups_a_step(groups, per, p)
         steps, mine = groups // together, together * per
@@ -479,12 +581,18 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
             x = x.reshape(x.shape[0], q, steps, mine).transpose(0, 2, 1, 3)
             return x, x.transpose(0, 1, 3, 2)
 
+        def at(index):
+            """A block's index from (step, row) under the grid's order."""
+            if by_row:
+                return lambda r, i, _: index(i, r)
+            return lambda i, r, _: index(i, r)
+
         def columns(width):
-            return pl.BlockSpec((None, q, width), lambda i, r, _: (r, 0, i))
+            return pl.BlockSpec((None, q, width), at(lambda i, r: (r, 0, i)))
 
         def small(*block, row=lambda r: r):
             return pl.BlockSpec((None, None) + block,
-                                lambda i, r, _: (row(r), i, 0, 0))
+                                at(lambda i, r: (row(r), i, 0, 0)))
         # log decay a token; with unit steps the same in every row: one
         # block a step, which the pipeline fetches once
         la = jnp.broadcast_to(a.astype(f32), (1, q, heads)) if dt is None \
@@ -502,27 +610,51 @@ def ssd_scan(xs, dt, a, b, c, d, row_first, state_dtype=jnp.float32,
         if dt is not None:
             operands += of_step(dt.astype(f32))
             specs += [small(q, mine), small(mine, q)]
-        of_heads = pl.BlockSpec((1, mine * p), lambda i, r, _: (0, i))
+        of_heads = pl.BlockSpec((1, mine * p), at(lambda i, r: (0, i)))
         if d is not None:
             operands.append(jnp.repeat(d.astype(f32), p)[None, :])
             specs.append(of_heads)
-        eps = None
+        eps = turn = out_eps = None
         if gated_norm is not None:
             z, weight, eps = gated_norm
             operands += [z, weight.astype(f32)[None, :]]
             specs += [columns(mine * p), of_heads]
+        if head_norm is not None:
+            b_weight, c_weight, head_eps, c_scale, cos, sin = head_norm
+            turn = (head_eps, c_scale)
+            operands += [b_weight.astype(f32)[None, :],
+                         c_weight.astype(f32)[None, :], cos, sin]
+            specs += [pl.BlockSpec((1, n), at(lambda i, r: (0, 0)))] * 2 \
+                + [pl.BlockSpec((None, q, n), at(lambda i, r: (r, 0, 0)))] * 2
+        out_specs, scratch = columns(mine * p), [
+            pltpu.VMEM((together, n, per * p), f32)]
+        out_shape = jax.ShapeDtypeStruct(
+            (rows, q, heads * p),
+            f32 if eps is None and not by_row else xs.dtype)
+        if by_row:
+            gate, weight, out_eps = out_norm
+            operands += [gate, weight.astype(f32)[None, :]]
+            specs += [columns(mine * p), pl.BlockSpec(
+                (1, heads * p), at(lambda i, r: (0, 0)))]
+            out_specs = pl.BlockSpec((None, q, heads * p),
+                                     at(lambda i, r: (r, 0, 0)))
+            scratch = [pltpu.VMEM((groups, n, per * p), f32),
+                       pltpu.VMEM((steps, q, mine * p), f32),
+                       pltpu.VMEM((steps, q, mine * p), f32)]
         out = pl.pallas_call(
             functools.partial(_kernel, per=per, p=p, n=n,
                               steps_dt=dt is not None, skip=d is not None,
-                              eps=eps, state_dtype=state_dtype),
+                              eps=eps, state_dtype=state_dtype,
+                              turn=turn, out_eps=out_eps),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(steps, rows),
-                in_specs=specs, out_specs=columns(mine * p),
-                scratch_shapes=[pltpu.VMEM((together, n, per * p), f32)]),
-            out_shape=jax.ShapeDtypeStruct(
-                (rows, q, heads * p), f32 if eps is None else xs.dtype),
+                num_scalar_prefetch=1,
+                grid=(rows, steps) if by_row else (steps, rows),
+                in_specs=specs, out_specs=out_specs,
+                scratch_shapes=scratch),
+            out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("arbitrary", "arbitrary") if by_row
+                else ("parallel", "arbitrary")),
             interpret=interpret, name=KERNEL_NAME,
         )(row_first.astype(jnp.int32), *operands)
     return out.reshape(rows, q, heads, p)

@@ -18,6 +18,13 @@ Every kernel line says the scan's least time by its bytes and by its
 operations (``floor_ms``: the recurrence's own 5 P N a token and head, x,
 z and y in bfloat16, B, C and the steps once — what
 ``benchmarks/families/falcon_h1.py`` counts for ``scan``).
+Then (``--only=lightning`` for it alone) a lightning layer between its
+products and ``o``, from q, k and the gate in float32 and v in bfloat16
+to ``o``'s bfloat16 operand: the head norms, ``rope.rotate``, the scale
+and the rounding in front of the kernel and the output norm and the gate
+behind it as XLA's passes, as MiniCPM-SALA's mixer ran them until PR 60,
+against the kernel with those lines inside (``head_norm``,
+``out_norm``), with the count of bfloat16 outputs that differ.
 Then ``rnb_tpu.ops.ssd.segment_conv1d`` alone at its callers' shapes
 (Nemotron-H's M block: 64 rows of 6,144 channels, a bias, xs, B and C
 as three bfloat16 arrays; Qwen3-Next's DeltaNet layer: 128 rows of
@@ -29,7 +36,7 @@ slices and rounding behind them, against the kernel at each count of
 rows and of lanes a grid step and of lanes the body holds at once (``ssd._CONV_ROWS``, ``ssd._CONV_LANES``, ``ssd._CONV_CHUNK``).
 Lines go to stdout and to ``chiprun_out/ssd_sweep/sweep.jsonl``.
 
-    chiprun -- python3 scripts/ssd_sweep.py [--only=scan|conv] [--rows=N]
+    chiprun -- python3 scripts/ssd_sweep.py [--only=scan|lightning|conv] [--rows=N]
         [--callers=falcon_h1] [--lanes=512,1024] [--vmem-mib=64]
         [--conv-rows=4,8] [--conv-lanes=512,1024] [--conv-chunk=128,256]
 
@@ -215,9 +222,11 @@ def conv_sweep():
 def main():
     say({"device": DEVICE.device_kind, "platform": DEVICE.platform})
     only = option("--only", "")
-    if only != "conv":
+    if only in ("", "scan"):
         scan_sweep()
-    if only != "scan":
+    if only in ("", "lightning"):
+        lightning_sweep()
+    if only in ("", "conv"):
         conv_sweep()
 
 
@@ -283,6 +292,74 @@ def scan_sweep():
                  "vmem_mib": VMEM_MIB, "floor_ms": scan_floor_ms(shape, rows),
                  **times})
 
+
+def lightning_sweep():
+    """A lightning layer between its products and ``o``, from q, k and
+    the gate in float32 as their products write them and v in bfloat16 to
+    ``o``'s bfloat16 operand: the passes around the kernel as the mixer
+    ran them until PR 60 (head norms, ``rope.rotate``, scale and
+    rounding in front; the output norm and the gate behind a float32
+    ``y``) against the kernel with those lines inside. The rotary tables
+    of the kernel's form are made outside the timed call, once a dispatch
+    in the program; the passes form their angles in every call, as the
+    program's did."""
+    from rnb_tpu.models.minicpm_sala.network import rms_norm
+    from rnb_tpu.ops import banded, rope
+    rows, heads, _, p, n, _ = CALLERS["lightning"]
+    rows = int(option("--rows", rows))
+    rng = np.random.default_rng(60)
+    f32, act, eps, width = jnp.float32, jnp.bfloat16, 1e-6, heads * p
+    first = np.zeros(rows, bool)
+    first[[0, rows // 3, max(rows - 2, 0), rows - 1]] = True
+    row_start = jnp.asarray(np.maximum.accumulate(
+        np.where(first, np.arange(rows), 0)), jnp.int32)
+    inv_freq = (10000.0 ** (-np.arange(0, n, 2) / n)).astype(np.float32)
+    log_decay = jnp.asarray(-2.0 ** (-8.0 * np.arange(1, heads + 1) / heads),
+                            f32)
+    q, k, gate = (jnp.asarray(rng.standard_normal((rows, QLEN, width)), f32)
+                  for _ in range(3))
+    v = jnp.asarray(rng.standard_normal((rows, QLEN, width)), act)
+    qw, kw = (jnp.asarray(rng.uniform(0.5, 1.5, n), act) for _ in range(2))
+    ow = jnp.asarray(rng.uniform(0.5, 1.5, width), act)
+    tables = [t.reshape(rows, QLEN, n) for t in
+              banded.band_tables(row_start, QLEN, inv_freq)[:2]]
+    args = (q, k, v, gate, jnp.asarray(first))
+
+    def of_heads(x):
+        return x.reshape(rows, QLEN, heads, -1)
+
+    def passes(q, k, v, gate, first):
+        positions = rope.pool_positions(row_start, QLEN)
+        qs = rope.rotate(rms_norm(of_heads(q), qw, eps, f32), positions,
+                         inv_freq)
+        ks = rope.rotate(rms_norm(of_heads(k), kw, eps, f32), positions,
+                         inv_freq)
+        y = ssd.ssd_scan(of_heads(v), None, log_decay, ks.astype(act),
+                         (qs * n ** -0.5).astype(act), None, first,
+                         interpret=INTERPRET)
+        y = rms_norm(y.reshape(rows, QLEN, width), ow, eps, f32)
+        return (y * jax.nn.sigmoid(gate)).astype(act)
+
+    def inside(q, k, v, gate, first, cos, sin):
+        return ssd.ssd_scan(
+            of_heads(v), None, log_decay, of_heads(k), of_heads(q), None,
+            first, interpret=INTERPRET,
+            head_norm=(kw, qw, eps, n ** -0.5, cos, sin),
+            out_norm=(gate, ow, eps)).reshape(rows, QLEN, width)
+    want, times = timed(jax.jit(passes), *args)
+    say({"caller": "lightning", "rows": rows,
+         "form": "passes around the kernel", **times})
+    want = np.asarray(want.astype(f32))
+    t0 = time.perf_counter()
+    got, times = timed(jax.jit(inside), *args, *tables)
+    got = np.asarray(got.astype(f32))
+    say({"caller": "lightning", "rows": rows,
+         "form": "lines inside the kernel",
+         "first_call_and_trace_s": round(time.perf_counter() - t0, 1),
+         **times, "elements_apart": int((got != want).sum()),
+         "of": int(got.size),
+         "worst_apart": float(np.abs(got - want).max())})
+    assert np.abs(got - want).max() <= 2.0 ** -6 * np.abs(want).max()
 
 if __name__ == "__main__":
     main()

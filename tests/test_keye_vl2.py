@@ -1463,7 +1463,11 @@ def test_the_toy_stacks_lower_to_the_recorded_text(family):
     the values). PR 58 recorded ``qwen3_next``'s again (the products in
     the bodies of ``ops/deltanet.py``'s kernels go part by part, no
     other family here calls it; its kernel against the recurrence is
-    ``tests/test_qwen3_next.py``'s)."""
+    ``tests/test_qwen3_next.py``'s). PR 60 recorded ``minicpm_sala``'s
+    again (the lightning mixer's lines around the scan are
+    ``ops/ssd.py``'s kernel's first and last, for the caller that hands
+    it raw q and k and an output norm: ``nemotron_h``, the kernel's
+    other caller here, lowers to the text it had)."""
     import test_qwen3_next
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:

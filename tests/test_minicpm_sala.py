@@ -434,13 +434,13 @@ def test_lightning_through_the_scan_matches_the_recurrence(toy, lengths):
     import jax.numpy as jnp
 
     from rnb_tpu.models.minicpm_sala import network
-    from rnb_tpu.ops import rope
     h, row_start, firsts, weights = lightning_inputs(toy, lengths)
     rows = h.shape[0]
     got = network.lightning_mixer(
         toy["cfg"], toy["params"]["l1"], h,
         jnp.asarray(row_start) == jnp.arange(rows),
-        rope.pool_positions(jnp.asarray(row_start), Q), interpret=True)
+        network.rotary_tables(toy["cfg"], jnp.asarray(row_start), Q),
+        interpret=True)
     got = np.asarray(got).reshape(rows * Q, -1)
     flat = h.reshape(rows * Q, -1).astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -470,7 +470,11 @@ def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
     the array PR 34's tree gave on the same inputs, recorded under
     ``tests/recorded``, is that output.
     The lightning form — unit steps, a constant decay a head, no skip
-    term — equals the recurrence written out token by token."""
+    term — equals the recurrence written out token by token, and with
+    the operands the mixer hands it since PR 60 (``head_norm``: q and k
+    as their products wrote them, the head norms' weights, the rotary
+    tables and the scale; ``out_norm``: the gate and the norm over all
+    the heads) the same recurrence between those lines in float64."""
     import jax
     import jax.numpy as jnp
 
@@ -533,6 +537,43 @@ def test_the_scans_generalisation_keeps_both_callers(toy, monkeypatch):
                              state)
             assert np.abs(got[r, t] - want).max() < 2e-3 * (
                 1.0 + np.abs(want).max())
+
+    # the same scan from the operands the mixer hands it
+    from rnb_tpu.models.minicpm_sala.network import rotary_tables
+    eps = 1e-6
+    kw, qw = (jnp.asarray(rng.uniform(0.5, 1.5, dim), jnp.float32)
+              for _ in range(2))
+    ow = jnp.asarray(rng.uniform(0.5, 1.5, heads * dim), jnp.float32)
+    gate = jnp.asarray(rng.standard_normal((rows, Q, heads * dim)),
+                       jnp.float32)
+    row_start = jnp.asarray([0, 0, 0, 3, 3, 5], jnp.int32)
+    cos, sin = rotary_tables(toy["cfg"], row_start, Q)
+    got = np.asarray(ssd.ssd_scan(
+        v, None, log_decay, k, q, None, first, interpret=True,
+        head_norm=(kw, qw, eps, dim ** -0.5, cos, sin),
+        out_norm=(gate, ow, eps))).reshape(rows, Q, heads * dim)
+
+    def lines(x, w):
+        x = np.asarray(x, np.float64)
+        x = x / np.sqrt(np.mean(x * x, -1, keepdims=True) + eps) \
+            * np.asarray(w, np.float64)
+        return x * np.asarray(cos, np.float64)[:, :, None] \
+            + np.roll(x, dim // 2, -1) * np.asarray(sin, np.float64)[:, :, None]
+    kn, qn = lines(k, kw), lines(q, qw) * dim ** -0.5
+    state = np.zeros((heads, dim, dim))
+    want = np.zeros((rows, Q, heads, dim))
+    for r in range(rows):
+        if first[r]:
+            state[:] = 0.0
+        for t in range(Q):
+            state = lam * state + np.einsum(
+                "hd,hv->hdv", kn[r, t], np.asarray(v[r, t], np.float64))
+            want[r, t] = np.einsum("hd,hdv->hv", qn[r, t], state)
+    want = want.reshape(rows, Q, heads * dim)
+    want = want / np.sqrt(np.mean(want * want, -1, keepdims=True) + eps) \
+        * np.asarray(ow, np.float64) / (1.0 + np.exp(-np.asarray(
+            gate, np.float64)))
+    assert np.abs(got - want).max() < 2e-3 * (1.0 + np.abs(want).max())
 
 
 # -- the recipe, the stages, the counters ---------------------------------------

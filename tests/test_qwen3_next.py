@@ -1080,7 +1080,7 @@ def stack_text(family: str) -> str:
 
 
 @pytest.mark.parametrize("family", ["nemotron_h", "deepseek_v2",
-                                    "minicpm_sala"])
+                                    "minicpm_sala", "falcon_h1"])
 def test_the_older_families_lower_to_the_text_the_parent_gave(family):
     """PR 39 left ``ops/moe.py``, ``ops/segattn.py``, ``ops/rope.py`` and
     ``ops/ssd.py`` as they were: each older family's toy stack lowers to
@@ -1095,7 +1095,14 @@ def test_the_older_families_lower_to_the_text_the_parent_gave(family):
     48 recorded ``nemotron_h``'s again (``ops/ssd.segment_conv1d`` is one
     Pallas kernel with the SiLU and the rounding inside, interpreted
     here; ``minicpm_sala``, which calls ``ssd_scan`` alone, and
-    ``deepseek_v2`` lower to the texts they had)."""
+    ``deepseek_v2`` lower to the texts they had); PR 60 recorded
+    ``minicpm_sala``'s again (its lightning mixer hands ``ssd_scan`` q
+    and k as their products wrote them and the gate: the head norms, the
+    rotation, the output norm and the gate are the kernel's first and
+    last lines, under a (row, step) grid) and holds both Mamba-2 callers
+    of that kernel to the parent's program: ``nemotron_h``'s text is the
+    one PR 48 recorded, and ``falcon_h1``'s, which had no entry, is
+    recorded from a ``git archive`` of the parent ``461cc79``."""
     with open(os.path.join(REPO, "tests", "recorded",
                            "toy_stack_stablehlo.json")) as f:
         recorded = json.load(f)

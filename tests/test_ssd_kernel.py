@@ -6,7 +6,11 @@ eight heads of 64 a group; lightning's: unit steps, a group a head of
 pad row, packing, the carried state's precision, the blocked
 ``jax.numpy`` form the kernel replaced (kept here as an oracle), and
 the M block's gated norm as the kernel's last lines, and Falcon-H1's
-shape (32 heads of 128 in 2 groups, a state of 256). Then, where a v5e
+shape (32 heads of 128 in 2 groups, a state of 256), and lightning's
+form with the mixer's lines inside (``lightning_raw``: q and k as their
+products wrote them, the head norms, the rotation, the scale and the
+rounding the kernel's first lines, the output norm over all the heads
+and the gate its last, two grid steps a row). Then, where a v5e
 can be described, the compile of the kernel at the callers' real shapes
 (the topology inside a fixture)."""
 
@@ -301,6 +305,176 @@ def test_the_gated_norm_is_the_kernels_last_lines(form, pool):
     assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max() + 1e-6
 
 
+# -- lightning's form with the mixer's lines inside -------------------------------
+
+#: (heads, P): 16 heads of 128 lanes are two grid steps of eight, so the
+#: output norm's mean spans steps; q and k are ``N`` wide
+RAW = (16, 128)
+RAW_EPS = 1e-6
+
+
+def raw_inputs(first, seed=0, dtype="bfloat16"):
+    """What a lightning mixer hands the scan (PR 60): v in the
+    activations' dtype, k, q and the gate float32 as their products
+    wrote them, the three norms' weights, the decays, and the rotary
+    tables of ``first``'s requests, positions restarting at each."""
+    import jax.numpy as jnp
+
+    from rnb_tpu.ops import banded
+    heads, p = RAW
+    rows = len(first)
+    rng = np.random.default_rng(seed)
+    row_start = np.maximum.accumulate(
+        np.where(np.asarray(first, bool), np.arange(rows), 0))
+    row_start[0] = 0
+    inv_freq = (10000.0 ** (-np.arange(0, N, 2) / N)).astype(np.float32)
+    cos, sin, _ = banded.band_tables(jnp.asarray(row_start, jnp.int32), Q,
+                                     inv_freq)
+    return dict(
+        v=jnp.asarray(rng.standard_normal((rows, Q, heads, p)), dtype),
+        k=jnp.asarray(rng.standard_normal((rows, Q, heads, N)) * 3.0,
+                      jnp.float32),
+        q=jnp.asarray(rng.standard_normal((rows, Q, heads, N)) * 0.3,
+                      jnp.float32),
+        gate=jnp.asarray(rng.standard_normal((rows, Q, heads * p)),
+                         jnp.float32),
+        a=jnp.asarray(-2.0 ** (-8.0 * np.arange(1, heads + 1) / heads),
+                      jnp.float32),
+        kw=jnp.asarray(rng.uniform(0.5, 1.5, N), dtype),
+        qw=jnp.asarray(rng.uniform(0.5, 1.5, N), dtype),
+        ow=jnp.asarray(rng.uniform(0.5, 1.5, heads * p), dtype),
+        row_start=jnp.asarray(row_start, jnp.int32), inv_freq=inv_freq,
+        cos=cos.reshape(rows, Q, N), sin=sin.reshape(rows, Q, N))
+
+
+def raw_scan(x, first, lines="both", **kwargs):
+    """The scan with the mixer's first lines, its last or both inside
+    the kernel, the others as the mixer wrote them until PR 60:
+    ``network.rms_norm``, ``rope.rotate``, the scale, the rounding; the
+    norm over all the heads and the gate on the float32 ``y``."""
+    import jax
+    import jax.numpy as jnp
+
+    from rnb_tpu.models.minicpm_sala.network import rms_norm
+    from rnb_tpu.ops import rope, ssd
+    f32, act = jnp.float32, x["v"].dtype
+    rows, q, heads, p = x["v"].shape
+    k, c, head_norm, out_norm = x["k"], x["q"], None, None
+    if lines in ("first", "both"):
+        head_norm = (x["kw"], x["qw"], RAW_EPS, N ** -0.5, x["cos"],
+                     x["sin"])
+    else:
+        positions = rope.pool_positions(x["row_start"], q)
+        k = rope.rotate(rms_norm(k, x["kw"], RAW_EPS, f32), positions,
+                        x["inv_freq"]).astype(act)
+        c = (rope.rotate(rms_norm(c, x["qw"], RAW_EPS, f32), positions,
+                         x["inv_freq"]) * N ** -0.5).astype(act)
+    if lines in ("last", "both"):
+        out_norm = (x["gate"], x["ow"], RAW_EPS)
+    y = ssd.ssd_scan(x["v"], None, x["a"], k, c, None,
+                     jnp.asarray(first, bool), interpret=True,
+                     head_norm=head_norm, out_norm=out_norm, **kwargs)
+    if out_norm is None:
+        assert y.dtype == f32
+        y = rms_norm(y.reshape(rows, q, heads * p), x["ow"], RAW_EPS, f32)
+        y = (y * jax.nn.sigmoid(x["gate"])).astype(act)
+    assert y.dtype == act
+    return np.asarray(y.astype(f32)).reshape(rows, q, heads * p)
+
+
+def raw_recurrence(x, first):
+    """The same lines in float64 around the recurrence token by token,
+    from q and k as they come: nothing rounded on the way."""
+    def normed(v, w):
+        v = np.asarray(v, np.float64)
+        v = v / np.sqrt(np.mean(v * v, -1, keepdims=True) + RAW_EPS)
+        return v * np.asarray(w.astype("float32"), np.float64)
+
+    def turned(v):
+        cos, sin = (np.asarray(x[t], np.float64)[:, :, None, :]
+                    for t in ("cos", "sin"))
+        return v * cos + np.roll(v, N // 2, -1) * sin
+    import jax.numpy as jnp
+    k = turned(normed(x["k"], x["kw"]))
+    c = turned(normed(x["q"], x["qw"])) * N ** -0.5
+    y = recurrence(x["v"], None, x["a"], jnp.asarray(k, jnp.float32),
+                   jnp.asarray(c, jnp.float32), None, first)
+    y = y.reshape(y.shape[:2] + (-1,))
+    y = y / np.sqrt(np.mean(y * y, -1, keepdims=True) + RAW_EPS)
+    gate = np.asarray(x["gate"], np.float64)
+    return y * np.asarray(x["ow"].astype("float32"), np.float64) \
+        / (1.0 + np.exp(-gate))
+
+
+@pytest.mark.parametrize("lines", ["first", "last", "both"])
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_the_mixers_lines_inside_are_the_lines_outside(pool, lines):
+    """``head_norm`` and ``out_norm``: the head norms, the rotation, the
+    scale and the one rounding of q and k in front of the scan, the norm
+    over all the heads (two grid steps here) and the gate behind it, are
+    inside the kernel the float32 operations they were outside, in
+    their order — a reduction's order apart (a head's mean of squares,
+    the mean over every head's columns), which is an ulp of a float32
+    and moves a bfloat16 value only across a rounding edge: an output
+    itself by one bfloat16 step, or an element of q or k by one, which
+    moves its token's outputs by less than that step at the top. No
+    output further apart than 2^-7 of the largest, and no more than one
+    in a hundred moved at all. Over the pools: a request that starts
+    mid-pool restarts its positions and its state, a pad row lies
+    between two."""
+    first = POOLS[pool]
+    x = raw_inputs(first, seed=60 + len(first))
+    want = raw_scan(x, first, lines="none")
+    got = raw_scan(x, first, lines=lines)
+    assert np.abs(got - want).max() <= 2.0 ** -7 * np.abs(want).max()
+    assert (got != want).mean() < 0.01
+    if lines != "both":
+        return
+    # and the whole is the recurrence, with the scores' and the result's
+    # roundings to bfloat16
+    exact = raw_recurrence(x, first)
+    assert np.abs(got - exact).max() < 3e-2 * np.abs(exact).max()
+
+
+def test_float32_lines_inside_meet_the_recurrence_closely():
+    """With float32 activations the first lines round nothing: the
+    kernel with both sets of lines inside, to float32's last digits."""
+    first = POOLS["two_requests"]
+    x = raw_inputs(first, seed=7, dtype="float32")
+    exact = raw_recurrence(x, first)
+    assert worst(raw_scan(x, first), exact) < 2e-5
+
+
+def test_packing_is_invisible_to_the_lines_inside():
+    """A request alone equals the same request packed behind another and
+    a pad row, bit for bit: its positions restart with its tables, its
+    state at ``row_first``, and the output norm is a token's own."""
+    import jax.numpy as jnp
+    alone, packed = raw_inputs([1, 0], seed=11), raw_inputs(
+        [1, 0, 1, 1, 0], seed=12)
+    for name in ("v", "k", "q", "gate"):
+        packed[name] = jnp.concatenate([packed[name][:3], alone[name]])
+    for name in ("kw", "qw", "ow"):
+        packed[name] = alone[name]
+    assert np.array_equal(np.asarray(packed["cos"][3:]),
+                          np.asarray(alone["cos"]))
+    assert np.array_equal(raw_scan(packed, [1, 0, 1, 1, 0])[3:],
+                          raw_scan(alone, [1, 0]))
+
+
+def test_a_bfloat16_state_moves_the_lines_inside():
+    """``state_dtype`` rounds every step's carried states under the
+    (row, step) grid too, and only where a state was carried."""
+    import jax.numpy as jnp
+    first = [1, 0, 0, 1]
+    x = raw_inputs(first, seed=3)
+    exact = raw_scan(x, first)
+    rounded = raw_scan(x, first, state_dtype=jnp.bfloat16)
+    for row in (0, 3):
+        assert np.array_equal(exact[row], rounded[row])
+    assert 1e-4 < worst(rounded[1:3], exact[1:3]) < 5e-2
+
+
 # -- the real shapes, compiled for a described v5e ------------------------------
 
 
@@ -324,6 +498,7 @@ def one_chip():
 #: name -> (rows, Q, heads, groups, P, N, steps and skip term?)
 REAL = {"nemotron_h": (64, 128, 64, 8, 64, 128, True),
         "lightning": (128, 128, 32, 32, 128, 128, False),
+        "lightning_raw": (128, 128, 32, 32, 128, 128, False),
         "falcon_h1": (64, 128) + FALCON_H1[:3] + (256, True)}
 
 
@@ -331,7 +506,11 @@ REAL = {"nemotron_h": (64, 128, 64, 8, 64, 128, True),
 def test_the_kernel_compiles_at_a_callers_shapes(one_chip, caller):
     """Mosaic takes the kernel at the real widths (nothing runs), and
     nothing of ``Q x Q`` a head or of a state is left for XLA: the
-    program's temporaries are the running sums' few bytes a token."""
+    program's temporaries are the running sums' few bytes a token.
+    ``lightning_raw`` is the form MiniCPM-SALA's mixer calls since PR
+    60: q, k and the gate float32 as their products wrote them, a row's
+    ``y``, the gate's sigmoid and all 32 heads' states in VMEM under
+    the compiler's own limit, one bfloat16 array out."""
     import jax
     import jax.numpy as jnp
 
@@ -342,6 +521,23 @@ def test_the_kernel_compiles_at_a_callers_shapes(one_chip, caller):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     per_token = of((rows, q, heads), jnp.float32)
     per_head = of((heads,), jnp.float32)
+    out_bytes = rows * q * heads * p * 4
+    if caller == "lightning_raw":
+        wide = of((rows, q, heads * p), jnp.float32)
+        table = of((rows, q, n), jnp.float32)
+        compiled = jax.jit(
+            lambda xs, a, b, c, first, bw, cw, cos, sin, gate, w:
+            ssd.ssd_scan(
+                xs.reshape(rows, q, heads, p), None, a,
+                b.reshape(rows, q, groups, n), c.reshape(rows, q, groups, n),
+                None, first, head_norm=(bw, cw, 1e-6, n ** -0.5, cos, sin),
+                out_norm=(gate, w, 1e-6)).reshape(rows, q, heads * p)).lower(
+            of((rows, q, heads * p)), per_head, wide, wide,
+            of((rows,), jnp.bool_), of((n,)), of((n,)), table, table, wide,
+            of((heads * p,))).compile()
+        assert ssd.KERNEL_NAME in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < out_bytes // 8
+        return
     # the heads' lanes side by side on both sides, as the callers hold
     # them: the reshapes are the compiler's to cancel
     # (Nemotron-H's with its gated norm)
@@ -357,5 +553,4 @@ def test_the_kernel_compiles_at_a_callers_shapes(one_chip, caller):
         of((rows, q, heads * p), jnp.float32) if full else None,
         of((heads * p,)) if full else None).compile()
     assert ssd.KERNEL_NAME in compiled.as_text()
-    out_bytes = rows * q * heads * p * 4
     assert compiled.memory_analysis().temp_size_in_bytes < out_bytes // 8
