@@ -1,23 +1,32 @@
 """``rnb_tpu.ops.selective_scan.selective_scan`` alone, on the chip, at
 Phi-4-mini-flash's published shape (5,120 channels, 16 states, rows of
 128 tokens) and the cell's row buckets: a check of the kernel against
-the token-by-token recurrence (``selective_scan.recurrence``) on a pool
-of three requests and a pad row at the draw's extremes, then its time a
-call at each count of channels a grid step and of tokens the loop's body
-holds (``selective_scan._STEP_CHANNELS``, ``_UNROLL``): the device's own
-time from a profiler trace of ``REPEATS`` calls — ``kernel_ms`` the
-custom call alone, ``device_ms`` every operation of the jitted call (the
-relayouts of x, dt and z in front of the kernel and of y behind it are
-XLA's) — and the host's clock around the calls. Every line says the
-scan's least time by its operations at the matrix unit's bf16 peak and
-by its bytes at the HBM's rate (``floor_ms``: what
+the token-by-token recurrence (``selective_scan.recurrence``) and, to
+the bit, against the slab form it replaced (``tests/
+selective_scan_slabs.py``: PR 59's kernel behind XLA's copies into
+``(rows, Q, C / 128, 128)``) on a pool of three requests and a pad row
+at the draw's extremes, then its time a call at each count of channels
+a grid step and of tokens a turn (``selective_scan._STEP_CHANNELS``,
+``_UNROLL``: the token loop's body holds one turn) beside the slab
+form's: the device's own time from a profiler trace of ``REPEATS`` calls
+— ``kernel_ms`` the custom call alone, ``device_ms`` every operation of
+the jitted call (the slab form's relayouts of x, dt and z in front of
+the kernel and of y behind it are XLA's; the kernel that reads the
+pool's layout has none) — and the host's clock around the calls. Every
+line says the scan's least time by its operations at the matrix unit's
+bf16 peak and by its bytes at the HBM's rate (``floor_ms``: what
 ``benchmarks/families/phi4_flash.py`` counts for ``selective_scan`` —
 the peaks' table has no vector-unit rate, ROADMAP D10).
 Lines go to stdout and to ``chiprun_out/selective_scan_sweep/sweep.jsonl``.
 
     chiprun -- python3 scripts/selective_scan_sweep.py [--rows=64,128]
-        [--channels=1024,2048] [--unroll=4,8,16] [--check-rows=8]
+        [--channels=1024,5120] [--unroll=8,16] [--check-rows=8]
+        [--vmem-mib=64] [--slabs=0]
 
+``--channels`` takes what divides 5,120 in whole registers: 1,024 or all
+5,120 (2,048 does not divide them, and ``step_channels`` then takes them
+all: what PR 59's table called 2,048 was 5,120 a step); ``--vmem-mib``
+puts another limit on scoped VMEM in place of the kernel's own.
 Off the TPU the kernel runs in Pallas's interpret mode, which at these
 sizes is of no use (``--rows=2 --check-rows=2`` is a dry run of the
 control flow).
@@ -30,12 +39,13 @@ import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+sys.path[:0] = [REPO, os.path.join(REPO, "tests")]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+import selective_scan_slabs as slabs  # noqa: E402
 from benchmarks import peaks, xplane  # noqa: E402
 from rnb_tpu.ops import selective_scan as ss  # noqa: E402
 
@@ -56,9 +66,11 @@ def ints(name, default):
 
 
 ROWS = ints("--rows", "64,80,96,112,128")
-STEP_CHANNELS = ints("--channels", "1024")
+STEP_CHANNELS = ints("--channels", "5120")
 UNROLL = ints("--unroll", "8")
 CHECK_ROWS = int(option("--check-rows", "8"))
+VMEM_MIB = int(option("--vmem-mib", "0"))
+SLABS = bool(int(option("--slabs", "1")))
 #: the v5e's published peaks: a floor is a statement about that chip
 V5E = peaks.peak_for("TPU v5 lite")
 
@@ -84,6 +96,7 @@ def timed(f, *args):
         ops = [op for plane in xplane.device_ops(
             xplane.find_xplane(trace_dir)).values() for op in plane]
         for key, mine in (("device_ms", ops), ("kernel_ms", [
+                # the slab form's name begins with the kernel's
                 op for op in ops if ss.KERNEL_NAME in op[2]])):
             times[key] = round(sum(end - start for start, end, _ in mine)
                                / REPEATS / 1e6, 4)
@@ -126,8 +139,14 @@ def run(*args):
     return ss.selective_scan(*args, memory=True, interpret=INTERPRET)
 
 
+def run_slabs(*args):
+    return slabs.selective_scan(*args, memory=True, interpret=INTERPRET)
+
+
 def main():
     say({"device": DEVICE.device_kind, "platform": DEVICE.platform})
+    if VMEM_MIB:
+        ss._VMEM_LIMIT = VMEM_MIB << 20
     for extreme in (None, (-16.0, 0.1), (-1.0, 0.001)):
         args = operands(CHECK_ROWS, 59, extreme)
         want = jax.jit(ss.recurrence)(*args)
@@ -135,27 +154,36 @@ def main():
         worst = [float(np.abs(np.asarray(g, np.float32) - np.asarray(w)).max()
                        / (1.0 + np.abs(np.asarray(w)).max()))
                  for g, w in zip(got, want)]
+        equal = [bool(np.array_equal(np.asarray(g, np.float32),
+                                     np.asarray(s, np.float32)))
+                 for g, s in zip(got, jax.jit(run_slabs)(*args))]
         say({"check": "extreme %s" % (extreme,), "rows": CHECK_ROWS,
-             "worst_vs_recurrence": worst})
-        assert max(worst) < 5e-3, worst
+             "worst_vs_recurrence": worst, "equal_to_slabs": equal})
+        # off the chip XLA's CPU fusions round the two bodies apart
+        # (``tests/test_selective_scan.py`` compares them unfused)
+        assert max(worst) < 5e-3 and (INTERPRET or all(equal)), (worst, equal)
     chosen = ss._STEP_CHANNELS, ss._UNROLL
+    forms = [("pool", run) + form
+             for form in itertools.product(STEP_CHANNELS, UNROLL)] \
+        + [("slabs", run_slabs) + chosen] * SLABS
     for rows in ROWS:
         args = operands(rows, 60)
-        for form in itertools.product(STEP_CHANNELS, UNROLL):
-            ss._STEP_CHANNELS, ss._UNROLL = form
+        for layout, call, channels, unroll in forms:
+            ss._STEP_CHANNELS, ss._UNROLL = channels, unroll
             # read while the call traces, and jit keeps a trace a function
             ss._scan_call.clear_cache()
+            line = {"rows": rows, "layout": layout,
+                    "step_channels": ss.step_channels(CHANNELS),
+                    "unroll": unroll}
             t0 = time.perf_counter()
             try:
-                _, times = timed(jax.jit(lambda *a: run(*a)), *args)
+                _, times = timed(jax.jit(lambda *a: call(*a)), *args)
             except Exception as e:   # a step the compiler refuses
-                say({"rows": rows, "step_channels": form[0],
-                     "unroll": form[1], "refused": str(e)[-300:]})
+                say({**line, "refused": str(e)[-300:]})
                 continue
-            say({"rows": rows, "step_channels": form[0], "unroll": form[1],
-                 "first_call_and_trace_s": round(time.perf_counter() - t0,
-                                                 1),
-                 "floor_ms": floor_ms(rows), **times})
+            say({**line, "first_call_and_trace_s": round(
+                time.perf_counter() - t0, 1), "floor_ms": floor_ms(rows),
+                **times})
     ss._STEP_CHANNELS, ss._UNROLL = chosen
     ss._scan_call.clear_cache()
 

@@ -547,7 +547,10 @@ def toy_config():
                                    "min": 20, "max": 100},
                          "long": {"count": 2, "min": 100, "max": 128}}
     # sized to what a CPU serves: ``family_contract.py``, "The backlog"
-    config["capacity_videos_per_chip_s"] = 12
+    # (``left_share`` 0.58 on an idle machine, PR 61: a scan step of all
+    # the channels is a fifth of the interpreter's grid steps, and the
+    # 12 of PR 59 was gone before the window opened)
+    config["capacity_videos_per_chip_s"] = 40
     config["share_of_spread"] = TOY_LIMIT
     loader, batcher, prefill = config["pipeline_config"]["pipeline"]
     loader.update(max_rows=8, chunk=16)
