@@ -322,6 +322,8 @@ class BenchmarkResult:
     tokens_shipped: int = 0
     tokens_scan_resets: int = 0
     tokens_cross_lines: int = 0
+    tokens_mixes: int = 0
+    tokens_res_defect_e9: int = 0
     experts_assignments: int = 0
     experts_held: int = 0
     experts_max_per_expert: int = 0

@@ -30,6 +30,20 @@ rotary projections' columns are stored de-interleaved
 The named scopes are the ones the benchmark's reduction knows:
 ``embed``, ``attn``, ``experts`` (a layer's feed-forward, the dense
 first layer's too), ``head``.
+
+*Two callers since PR 62.* ``models/xing4/network.py`` runs the same
+layer on another residual path and imports :func:`latent_attention` and
+:func:`experts_ffn` (and the config's fields: ``Xing4Config`` extends
+:class:`DeepseekV2Config` through ``published_fields``).
+:func:`latent_attention`'s ``h`` is then no norm of ``x``: it is the
+norm of the sublayer's input that caller's mappings mix out of its four
+streams; nothing in the function reads the stream. :func:`experts_ffn`
+hands ``ops/moe.route`` the layer's ``b_corr`` where the parameters
+hold one (DeepSeek-V3's rule: the bias moves the choice, never the
+weights) and ``cfg.route_scale``: a caller with a correction bias and
+``norm_topk_prob`` gets sigmoid scores renormalised *and* scaled; this
+file's own stack has no ``b_corr`` and lowers to the text it had
+(``tests/test_qwen3_next.py``: the recorded StableHLO of the toy stack).
 """
 
 from __future__ import annotations
@@ -88,15 +102,23 @@ class DeepseekV2Config:
         """From a configuration file's keys: the published ones, with
         ``num_hidden_layers`` the layers held here and
         ``published.n_routed_experts`` the width of the router."""
+        if config["topk_method"] != "group_limited_greedy":
+            raise ValueError("topk_method: not the DeepSeek-V2 this "
+                             "network implements")
+        return DeepseekV2Config(**DeepseekV2Config.published_fields(config))
+
+    @staticmethod
+    def published_fields(config: Mapping) -> dict:
+        """The fields, by name, from a configuration file's keys (a
+        stack that shares the layer, ``models/xing4``, adds its own)."""
         published = config.get("published", {})
         layers = int(config["num_hidden_layers"])
         yarn = config["rope_scaling"]
-        if yarn.get("type") != "yarn" or config["moe_layer_freq"] != 1 \
-                or config["topk_method"] != "group_limited_greedy":
-            raise ValueError("rope_scaling.type, moe_layer_freq or "
-                             "topk_method: not the DeepSeek-V2 this "
-                             "network implements")
-        return DeepseekV2Config(
+        if yarn.get("type") != "yarn" or config["moe_layer_freq"] != 1:
+            raise ValueError("rope_scaling.type or moe_layer_freq: not "
+                             "the latent-attention stack this network "
+                             "implements")
+        return dict(
             num_hidden_layers=layers,
             published_layers=int(published.get("num_hidden_layers",
                                                layers)),
@@ -141,6 +163,13 @@ class DeepseekV2Config:
     @property
     def shared_intermediate_size(self) -> int:
         return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def route_scale(self) -> float:
+        """What the chosen scores are multiplied by: the published code
+        scales them where it does not renormalise them, and the other
+        way round."""
+        return 1.0 if self.norm_topk_prob else self.routed_scaling_factor
 
     @property
     def softmax_scale(self) -> float:
@@ -229,12 +258,9 @@ def experts_ffn(cfg, p, h, token_ok, slots, interpret=False):
     rows, q, hidden = h.shape
     flat = h.reshape(rows * q, hidden)
     ok = token_ok.reshape(-1)
-    # the published code scales the chosen scores where it does not
-    # renormalise them, and the other way round
     ids, weights = moe.route(
-        flat, p["router"], None, cfg.num_experts_per_tok,
-        1.0 if cfg.norm_topk_prob else cfg.routed_scaling_factor,
-        score=cfg.scoring_func, n_group=cfg.n_group,
+        flat, p["router"], p.get("b_corr"), cfg.num_experts_per_tok,
+        cfg.route_scale, score=cfg.scoring_func, n_group=cfg.n_group,
         topk_group=cfg.topk_group, renormalise=cfg.norm_topk_prob)
     routed, counts, gmm_rows = moe.held_experts(
         flat, ids, weights, ok, slots, p["up"], p["down"],

@@ -66,6 +66,9 @@ class TensorSpec:
     #: drawn at ``scale`` times its own factor (a product whose result
     #: the model multiplies by a scalar a segment)
     segments: Tuple[Tuple[int, float], ...] = ()
+    #: added to the draw along the last axis, an entry a column (a bias
+    #: drawn around a mean of its own: ``B_res = 3 I + N(0, 1)``)
+    offsets: Tuple[float, ...] = ()
 
 
 def published_shape(spec: TensorSpec) -> Tuple[int, ...]:
@@ -148,6 +151,8 @@ def _drawer(spec: TensorSpec):
             x = x * np.repeat(
                 np.asarray([f for _, f in spec.segments], np.float32),
                 [n for n, _ in spec.segments])
+        if spec.offsets:
+            x = x + np.asarray(spec.offsets, np.float32)
         x = x.astype(dtype)
         if spec.halves is not None:
             x = x[..., halves_order(spec)]

@@ -55,6 +55,7 @@ from rnb_tpu.telemetry import STAGE_COUNTERS
 
 MAX_ROWS = 64
 CHUNK = 128
+_COUNTER_ROWS = {row.counter: row for row in STAGE_COUNTERS}
 
 
 def rows_of_tokens(num_tokens: int, chunk: int = CHUNK) -> int:
@@ -298,8 +299,11 @@ class PackedPrefill(StageModel):
             count = self._counted.get(row.counter)
             if count is None:
                 continue
-            counters[row.counter] = count.copy() if row.reduce \
-                else count.reshape(-1, len(row.keys)).sum(axis=0)
+            if row.reduce or row.maxima:
+                counters[row.counter] = count.copy()
+            else:
+                counters[row.counter] = count.reshape(
+                    -1, len(row.keys)).sum(axis=0)
         if "expert_served" in counters:
             counters["experts_per_token"] = int(
                 self.cfg.num_experts_per_tok)
@@ -315,8 +319,8 @@ class PackedPrefill(StageModel):
     def _count(self, pending) -> None:
         counts, valid, rows = pending
         for name, count in zip(self._counter_names, counts):
-            self._counted[name] = self._counted.get(name, 0) \
-                + np.asarray(count, np.int64)
+            self._counted[name] = _COUNTER_ROWS[name].merge(
+                self._counted.get(name), np.asarray(count, np.int64))
         self.tokens_valid += valid
         self.tokens_shipped += rows * self.chunk
 

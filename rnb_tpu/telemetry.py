@@ -968,17 +968,33 @@ class StageCounter(NamedTuple):
     stand there, and ``prefix`` + key the ``BenchmarkResult`` field of
     each. ``reduce`` None: a vector of ``len(keys)`` a snapshot, summed
     over the snapshots; else the row's own ``reduce(snapshots, row)``
-    over the counter as the stage counted it, whole."""
+    over the counter as the stage counted it, whole. ``maxima``: the
+    keys that are a largest value and no count: over dispatches and
+    snapshots their maximum is taken (:meth:`merge`)."""
     counter: str
     line: str
     prefix: str
     keys: Sequence[str]
     doc: str
     reduce: Optional[Callable] = None
+    maxima: Sequence[str] = ()
 
     @property
     def fields(self):
         return tuple(self.prefix + key for key in self.keys)
+
+    def merge(self, counted, count):
+        """``counted`` (None before the first) and one more ``count`` of
+        this row, arrays of one shape with the keys last -> both as
+        one."""
+        import numpy as np
+        count = np.asarray(count, np.int64)
+        if counted is None:
+            return count
+        if not self.maxima:
+            return counted + count
+        return np.where([key in self.maxima for key in self.keys],
+                        np.maximum(counted, count), counted + count)
 
 
 #: every counter a stage may hand the launcher through
@@ -1007,6 +1023,14 @@ STAGE_COUNTERS = (
         "stack whose second half reads the first half's keys, values and "
         "scan memory (``models/phi4_flash``): one a request with the "
         "prefill exit, one a valid token without it"),
+    StageCounter(
+        "stream_mix", "Tokens:", "tokens_", ("mixes", "res_defect_e9"),
+        "(2,), a residual stream several wide under per-token mappings "
+        "(``ops/hyper.py``): the (valid token, sublayer) mixings a "
+        "dispatch made, and the largest distance of a row or column sum "
+        "of any valid token's ``H_res`` from 1, in units of 1e-9 (the "
+        "largest of the run, not a sum): fewer Sinkhorn steps show here",
+        maxima=("res_defect_e9",)),
     StageCounter(
         "expert_served", "Experts:", "experts_",
         ("assignments", "held", "max_per_expert", "mean_per_expert"),
@@ -1094,10 +1118,13 @@ def stage_counter_report(snapshots):
         if row.reduce is not None:
             values = row.reduce(snapshots, row)
         else:
-            counted = [np.asarray(snap[row.counter], np.int64).reshape(-1)
-                       for snap in snapshots
-                       if snap.get(row.counter) is not None]
-            values = [int(v) for v in sum(counted)] if counted else None
+            values = None
+            for snap in snapshots:
+                if snap.get(row.counter) is not None:
+                    values = row.merge(values, np.asarray(
+                        snap[row.counter], np.int64).reshape(-1))
+            if values is not None:
+                values = [int(v) for v in values]
         if values is None:
             continue
         fields.update(zip(row.fields, values))

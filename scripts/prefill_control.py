@@ -54,6 +54,16 @@ replaced, each of which must fail: ``one_softmax`` (``lambda`` at zero:
 ``window_off`` (the sliding layers read the whole context) and
 ``memory_gated`` (the Gated Memory Units read layer 16's *gated*
 output). ``own_keys`` is no arm: a cross layer has no keys of its own.
+``xing4`` (a residual stream four wide under per-token mappings) has
+:func:`xing4_faults` — ``no_dynamic_term`` (every ``alpha`` at zero),
+``one_stream_read`` (the head reads one stream, not their sum),
+``sinkhorn_5`` and ``sinkhorn_19`` (five and nineteen steps for twenty)
+and ``mappings_bfloat16`` (the sigmoids, ``exp`` and Sinkhorn steps in
+bfloat16) — and the feed-forwards' and every stored matrix through
+float8; every arm goes through the family's whole verdict
+(``families/xing4.held_to_the_limits``: the logits, the router's slack,
+each (sublayer, token)'s ``H_res`` defect against the reference's own)
+and every one must fail.
 ``--arms`` names the ones to run where a chip's minutes are counted.
 """
 
@@ -117,7 +127,62 @@ def arms_of(family: str):
                 "one_softmax", "window_off", "memory_gated")] \
             + [("layers_float8", {}, lambda group, name: name.split(
                 ".")[-1] in PHI4_FLASH_MATRICES)]
+    if family == "xing4":
+        return [("as_stated", {}, None)] \
+            + [(name, {}, None) for name in (
+                "no_dynamic_term", "one_stream_read", "sinkhorn_5",
+                "sinkhorn_19", "mappings_bfloat16")] \
+            + [("experts_float8", {}, lambda group, name: name in _FFN),
+               ("layers_float8", {}, lambda group, name: True)]
     raise ValueError("no control arms for family %r" % (family,))
+
+
+def xing4_faults(cfg):
+    """{arm: fault} for a ``Xing4Config``, planted from outside the
+    program as :func:`dots3_note_faults`' are: every mapping's ``alpha``
+    at zero (the dynamic term ``alpha (x^ phi)`` dropped: the mappings
+    no longer depend on the token), the head reading one stream in the
+    place of their sum, five Sinkhorn steps for twenty and nineteen
+    (twenty means twenty: one step short is refused), and the
+    mappings' coefficients — the sigmoids, ``exp`` and every Sinkhorn
+    step, what ``hyper/maps`` spends its time on — in bfloat16. (The
+    kernel's share of the float32 arithmetic, the statistic, the
+    projection's scaling and both mixings' sums, cannot be lowered on
+    this chip: Mosaic refuses ``hyper_mix`` in bfloat16 for a v5e, a
+    ``vector.broadcast`` of a float32 scalar to bfloat16 lanes in the
+    logistic. ``tests/test_xing4.py`` lowers all of ``ops/hyper.COMPUTE``
+    under the interpreter.)"""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from rnb_tpu.ops import hyper
+
+    def step_by_step(m, iters, hc_eps):
+        # under a loop each step's result is rounded where it lies;
+        # written out, XLA's CPU compiler also takes eleven seconds a
+        # sublayer for them in bfloat16
+        return lax.fori_loop(0, iters, lambda _, m: hyper.sinkhorn_step(
+            m, hc_eps), m)
+    as_given = hyper.coefficients_of
+
+    def in_bfloat16(logits, *sizes):
+        # ``coefficients_of`` works in the logits' dtype
+        return as_given(logits.astype(jnp.bfloat16), *sizes)
+    return {
+        "no_dynamic_term": {"scale": {
+            "l%d.%s_hc_alpha" % (i, sub): 0.0
+            for i in range(cfg.num_hidden_layers)
+            for sub in ("attn", "ffn")}},
+        "one_stream_read": {"patch": {
+            "merge_streams": lambda last, n: last.astype(
+                jnp.float32)[:, :last.shape[1] // n]}},
+        "sinkhorn_5": {"cfg": dataclasses.replace(
+            cfg, hc_sinkhorn_iters=5)},
+        "sinkhorn_19": {"cfg": dataclasses.replace(
+            cfg, hc_sinkhorn_iters=19)},
+        "mappings_bfloat16": {"patch": {"coefficients_of": in_bfloat16,
+                                        "sinkhorn": step_by_step},
+                              "patched": hyper}}
 
 
 #: the stored matrices of a Phi-4-mini-flash layer (a stacked group's
@@ -278,7 +343,8 @@ def main(argv=None) -> int:
             parser.error("no such arm of %s: %s" % (name, sorted(unknown)))
         arms = [a for a in arms if a[0] == "as_stated" or a[0] in wanted]
     faults = dots3_note_faults(cfg) if name == "dots3_note" \
-        else phi4_flash_faults() if name == "phi4_flash" else {}
+        else phi4_flash_faults() if name == "phi4_flash" \
+        else xing4_faults(cfg) if name == "xing4" else {}
     programs = {}
     for arm, kwargs, rounded in arms:
         fault = faults.get(arm, {})
@@ -298,7 +364,8 @@ def main(argv=None) -> int:
                 network.forward(
                     arm_cfg, p, s, t, m[0], m[1], m[2],
                     interpret=device.platform != "tpu", **kwargs))
-        with mock.patch.multiple(network, **fault["patch"]) \
+        with mock.patch.multiple(fault.get("patched", network),
+                                 **fault["patch"]) \
                 if "patch" in fault else contextlib.nullcontext():
             logits, chosen, *_ = programs[own](
                 planted(params, fault), slots, tokens, meta)
@@ -308,17 +375,18 @@ def main(argv=None) -> int:
         # is the same for every arm, and is computed once
         again = kept is None or fault.get("reference_too") or any(
             leaf.size for leaf in jax.tree.leaves(chosen))
-        want, short, key_short = [], 0.0, None
+        want, short, key_short, defects = [], 0.0, None, ([], [])
         with jax.default_matmul_precision("highest"):
             for prompt, first in zip(prompts, offsets) if again else ():
                 # the request's own choices, as a sample keeps them
                 # (models/token_stages.py) and the run's check reads them
                 keep = getattr(network, "request_choices", None)
+                sample = None if keep is None else keep(
+                    cfg, chosen, first * chunk, len(prompt))
                 forced = chosen[:, first * chunk:
                                 first * chunk + len(prompt)] \
                     if keep is None else family.unpack_choices(
-                        config, keep(cfg, chosen, first * chunk,
-                                     len(prompt)), len(prompt))
+                        config, sample, len(prompt))
                 given = {}
                 if isinstance(forced, tuple):
                     # two kinds of choice: the router's and the keys
@@ -334,6 +402,11 @@ def main(argv=None) -> int:
                     float(ref[key].max())
                     for key in ("shortfall", "group_shortfall")
                     if key in ref])
+                if "res_defect" in ref:
+                    # each (sublayer, token)'s defect, as a sample keeps
+                    # them, and the reference's own
+                    defects[0].append(sample["res_defect"])
+                    defects[1].append(np.asarray(ref["res_defect"]))
                 if given:
                     key_short = max(
                         key_short or 0.0,
@@ -353,18 +426,21 @@ def main(argv=None) -> int:
         out[arm]["rms_share_of_spread"] = verdict.get(
             "rms_share_of_spread", float(np.sqrt(np.mean(
                 (got.astype(np.float64) - want) ** 2)) / want.std()))
+        worst = {"route_shortfall_max": short}
         if key_short is not None:
             out[arm]["key_shortfall_max"] = key_short
             out[arm]["ok"] = bool(verdict["ok"]
                                   and key_short <= float(config.get(
                                       "key_slack", family.KEY_SLACK)))
-            if hasattr(family, "held_to_the_limits"):
-                # every limit of the run's own check, the router's too
-                out[arm]["ok"] = family.held_to_the_limits(
-                    config, dict(verdict), {
-                        "key_bad": int(key_short == float("inf")),
-                        "route_shortfall_max": short,
-                        "key_shortfall_max": key_short})["ok"]
+            worst.update(key_bad=int(key_short == float("inf")),
+                         key_shortfall_max=key_short)
+        if defects[0]:
+            out[arm]["res_defect_apart"] = worst["res_defect_apart"] \
+                = family.defects_apart(*defects)
+        if hasattr(family, "held_to_the_limits"):
+            # every limit of the run's own check, the router's too
+            out[arm]["ok"] = family.held_to_the_limits(
+                config, dict(verdict), worst)["ok"]
         print("[control] %s %s" % (arm, out[arm]), file=sys.stderr,
               flush=True)
     # a family that says so holds every control to a failure (or every

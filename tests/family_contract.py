@@ -61,7 +61,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: ``tests/test_<family>.py``
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
             "exaone_moe", "keye_vl2", "kimi_linear", "falcon_h1",
-            "dots3_note", "phi4_flash")
+            "dots3_note", "phi4_flash", "xing4")
 
 
 @dataclasses.dataclass(frozen=True)

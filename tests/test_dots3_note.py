@@ -732,6 +732,6 @@ def test_the_cell_joins_the_accepted_metrics_its_readers_serve():
     for m in per_layer.values():
         assert m["workloads"].count(CELL) <= 1
         if CELL in m["workloads"] and len(m["workloads"]) > 1:
-            # last but for the cells later PRs appended (PR 59's)
+            # last but for the cells later PRs appended (PR 59's, PR 62's)
             behind = m["workloads"][m["workloads"].index(CELL) + 1:]
-            assert set(behind) <= {"phi4-flash.bulk"}
+            assert set(behind) <= {"phi4-flash.bulk", "xing4.bulk"}

@@ -590,9 +590,11 @@ def test_the_accepted_readers_list_the_cell_last():
 
     def last_of_its_pr(workloads):
         """The cell stands last but for the cells later PRs appended
-        (PR 55's ``dots3-note.bulk``, PR 59's ``phi4-flash.bulk``)."""
+        (PR 55's ``dots3-note.bulk``, PR 59's ``phi4-flash.bulk``, PR
+        62's ``xing4.bulk``)."""
         behind = workloads[workloads.index(CELL) + 1:]
-        return set(behind) <= {"dots3-note.bulk", "phi4-flash.bulk"}
+        return set(behind) <= {"dots3-note.bulk", "phi4-flash.bulk",
+                               "xing4.bulk"}
     for name in LISTED:
         assert last_of_its_pr(by_name[name + ".bulk"]["workloads"]), name
     listed = {n for n, m in by_name.items() if CELL in m.get("workloads", ())

@@ -280,11 +280,12 @@ def test_the_readers_entry_in_the_manifest():
     module = mm.load_layer_metric("gmm_row_fill_pct.bulk")
     entry, = [m for m in mm.load()["per_layer"]
               if m["name"] == "gmm_row_fill_pct.bulk"]
-    # PR 46's, PR 49's and PR 55's cells joined the list behind the
-    # three it was written for
+    # PR 46's, PR 49's, PR 55's and PR 62's cells joined the list behind
+    # the three it was written for
     assert entry["workloads"] == ["nemotron3-nano.bulk", "deepseek-v2.bulk",
                                   "qwen3-next.bulk", "keye-vl2.bulk",
-                                  "kimi-linear.bulk", "dots3-note.bulk"]
+                                  "kimi-linear.bulk", "dots3-note.bulk",
+                                  "xing4.bulk"]
     assert mm.describe(module) == {k: entry[k] for k in mm.METRIC_FIELDS}
     assert module.LAYER == "sparse experts" and module.BETTER == "higher"
     assert module.MOVES == "videos_per_s"

@@ -22,7 +22,7 @@ from rnb_tpu.telemetry import (META_LINE_REGISTRY, STAGE_COUNTERS,
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("nemotron_h", "deepseek_v2", "minicpm_sala", "qwen3_next",
             "exaone_moe", "keye_vl2", "falcon_h1", "dots3_note",
-            "phi4_flash")
+            "phi4_flash", "xing4")
 
 #: what the dispatches of one stage summed to, as ``network.forward``
 #: hands each counter back: the layers that count first
@@ -39,6 +39,7 @@ RAW = {
     "scan_resets": [21],
     "window_keys": [[30, 70], [30, 70]],
     "cross_lines": [21],
+    "stream_mix": [120, 31000000],
 }
 
 TOKENS = "Tokens: valid=10 shipped=16"
@@ -65,6 +66,8 @@ GOLDEN = {
     "phi4_flash": [TOKENS + " scan_resets=21 cross_lines=21",
                    ATTENTION + " window_keys_kept=60 "
                                "window_keys_causal=140"],
+    "xing4": [TOKENS + " mixes=120 res_defect_e9=31000000",
+              EXPERTS + " gmm_rows=384", ATTENTION],
     "dots3_note": [TOKENS, EXPERTS + " pair_rows_moved=22 pair_rows_all=80 "
                                      "gmm_rows=384",
                    "Sparse: queries=40 selecting=20 causal_keys=160 "
