@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import importlib
 import os
+import queue
 import threading
 from typing import Optional
 
@@ -161,6 +162,13 @@ class PackedPrefill(StageModel):
     is launched; a sampled one's arrays are fetched from then on by a
     thread of their own."""
 
+    #: rnb-lint's contract (rnb_tpu.analysis.concurrency, RNB-C002)
+    READ_ONLY_ROLES = {
+        "prefill-load": "the constructor's worker reads the stage's "
+                        "weights and hands what it makes back through "
+                        "its arguments; the constructor alone, behind "
+                        "its join, writes the stage"}
+
     def __init__(self, device, ckpt_path: Optional[str] = None,
                  max_rows: int = MAX_ROWS, chunk: int = CHUNK,
                  row_buckets=None, num_warmups: int = 1,
@@ -190,11 +198,14 @@ class PackedPrefill(StageModel):
                                                  self.max_rows, "max_rows")
         self._jax_device = device.resolve()
         # set-up's spans (the launcher's Tracer collects them until the
-        # start barrier): the step is the one the executor bound
+        # start barrier): the step is the one the executor bound, to
+        # this thread, so the worker's names are made here too
         step = trace.building_step()
         tr_program = trace.name("setup.s%d.program", step)
-        tr_scopes = trace.name("setup.s%d.scopes", step)
-        tr_first_call = trace.name("setup.s%d.first_call", step)
+        tr_load_wait = trace.name("setup.s%d.load_wait", step)
+        tr_worker = (trace.name("setup.s%d.load", step),
+                     trace.name("setup.s%d.scopes", step),
+                     trace.name("setup.s%d.first_call", step))
         self._slots = None
         with trace.span(trace.name("setup.s%d.weights", step)):
             self._params = checkpoint.make_params(self.cfg, seed, held,
@@ -213,27 +224,46 @@ class PackedPrefill(StageModel):
             # one value a request goes back to the executor, which
             # waits on it as on any stage's output
             return logits[:, 0], logits, chosen, tuple(counts)
-        # one program a row bucket, compiled ahead: its text says which
-        # named scope each instruction came from (hlo_scopes)
+        # one program a row bucket, made ahead, two buckets at a time:
+        # this thread traces and lowers them in turn (Python, the GIL
+        # held) while one worker turns each lowered program into its
+        # loaded executable, reads its text, which says which named scope
+        # each instruction came from (hlo_scopes), and makes its first
+        # calls (the compiler or the cache's read and the load onto the
+        # chip: C++ that lets the GIL go)
         self._programs = {}
         self.hlo_scopes = {}
         self.compiles = SignatureTracker()
-        for rows in self.row_buckets:
-            tokens = np.zeros((rows, self.chunk), np.int32)
-            meta = dispatch_meta((0, rows), np.full(rows, self.chunk),
-                                 rows, self.chunk)
-            self.compiles.observe(tokens)
-            with trace.span(tr_program, rows=rows):
-                program = jax.jit(apply).lower(
-                    self._params, self._slots, tokens, meta).compile()
-                with trace.span(tr_scopes):
-                    self.hlo_scopes.update(
-                        hloscopes.scopes_of_hlo(program.as_text()))
-                self._programs[rows] = program
-                with trace.span(tr_first_call):
-                    for _ in range(int(num_warmups)):
-                        jax.block_until_ready(program(
-                            self._params, self._slots, tokens, meta))
+        lowered, loaded, failed = queue.SimpleQueue(), [], []
+        worker = threading.Thread(
+            target=self._load_programs, name="prefill-load", daemon=True,
+            args=(lowered, loaded, failed, tr_worker, int(num_warmups)))
+        worker.start()
+        try:
+            for rows in self.row_buckets:
+                if failed:
+                    break
+                tokens = np.zeros((rows, self.chunk), np.int32)
+                meta = dispatch_meta((0, rows), np.full(rows, self.chunk),
+                                     rows, self.chunk)
+                self.compiles.observe(tokens)
+                with trace.span(tr_program, rows=rows):
+                    lowered.put((rows, tokens, meta, jax.jit(apply).lower(
+                        self._params, self._slots, tokens, meta)))
+        except BaseException as e:
+            failed.append(e)    # the worker drops what is still queued
+            raise
+        finally:
+            lowered.put(None)
+            with trace.span(tr_load_wait):
+                worker.join()
+        if failed:
+            raise failed[0]
+        # in the buckets' order: a later bucket's scopes overwrite an
+        # earlier one's under the same key
+        for rows, program, scopes in loaded:
+            self._programs[rows] = program
+            self.hlo_scopes.update(scopes)
         #: counters of the dispatches served: valid and shipped tokens
         #: (the Tokens: line) and the family's own, by the names of
         #: ``network.COUNTERS``, summed as they come back
@@ -256,6 +286,31 @@ class PackedPrefill(StageModel):
         #: are read once the next dispatch is launched (the executor has
         #: waited for this one by then)
         self._pending = None
+
+    def _load_programs(self, lowered, loaded, failed, names,
+                       num_warmups: int) -> None:
+        """The constructor's worker: each ``(rows, tokens, meta, lowered
+        program)`` of ``lowered``, up to the None that ends it, becomes
+        ``(rows, executable, its scope table)`` in ``loaded``, warmed by
+        ``num_warmups`` calls. What it raises goes to ``failed`` for the
+        constructor to raise, and what is still queued is dropped."""
+        import jax
+        tr_load, tr_scopes, tr_first_call = names
+        for rows, tokens, meta, program in iter(lowered.get, None):
+            if failed:
+                continue
+            try:
+                with trace.span(tr_load, rows=rows):
+                    program = program.compile()
+                    with trace.span(tr_scopes):
+                        scopes = hloscopes.scopes_of_hlo(program.as_text())
+                    with trace.span(tr_first_call):
+                        for _ in range(num_warmups):
+                            jax.block_until_ready(program(
+                                self._params, self._slots, tokens, meta))
+                loaded.append((rows, program, scopes))
+            except BaseException as e:     # raised by the constructor
+                failed.append(e)
 
     def bind_log_dir(self, log_dir: str) -> None:
         self._log_dir = log_dir

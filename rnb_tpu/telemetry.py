@@ -474,14 +474,31 @@ TRACE_EVENT_REGISTRY = (
               "the first call's span carries that; self time "
               "setup_weights_s"),
     StampSpec("setup.s{step}.program", "rnb_tpu/models/token_stages.py",
-              "span: one row bucket's program from nothing to warmed "
-              "(rows); self time setup_unnamed_s"),
+              "span: one row bucket's program on the constructor's "
+              "thread (rows): in a token stage its tracing and lowering "
+              "and the hand to the worker, in R(2+1)D's from nothing to "
+              "warmed; self time setup_unnamed_s"),
+    StampSpec("setup.s{step}.load", "rnb_tpu/models/token_stages.py",
+              "span: a token stage's worker thread (prefill-load) on "
+              "one lowered program (rows): the compiler or the cache's "
+              "read and the executable's load, its scope table, its "
+              "first calls, while the constructor lowers the next "
+              "bucket; the sum less load_wait's ran behind a lowering; "
+              "no metric reads another thread than the constructor's"),
+    StampSpec("setup.s{step}.load_wait", "rnb_tpu/models/token_stages.py",
+              "span: the token stage's constructor waits for its worker "
+              "to end, behind the last bucket's lowering: the tail of "
+              "the pipeline; self time setup_unnamed_s"),
     StampSpec("setup.s{step}.scopes", "rnb_tpu/models/token_stages.py",
               "span: the executable's text and its scope table, inside "
-              "the bucket's program span; self time setup_unnamed_s"),
+              "the bucket's load span (R(2+1)D: program span); self "
+              "time setup_unnamed_s where the constructor's thread runs "
+              "it"),
     StampSpec("setup.s{step}.first_call", "rnb_tpu/models/token_stages.py",
-              "span: the bucket's warm-up calls to block_until_ready; "
-              "self time setup_first_call_s"),
+              "span: the bucket's warm-up calls to block_until_ready, "
+              "in a token stage on the worker inside the load span; "
+              "self time setup_first_call_s where the constructor's "
+              "thread runs it"),
     StampSpec("setup.jax.trace", "rnb_tpu/benchmark.py",
               "span: JAX's /jax/core/compile/jaxpr_trace_duration "
               "(fun_name), on the tracing thread; setup_lower_s"),

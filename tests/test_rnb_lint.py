@@ -507,6 +507,49 @@ def test_contract_registry_names_the_core_classes():
         assert expected in classes, expected
 
 
+TOKEN_STAGES = os.path.join(REPO, "rnb_tpu", "models", "token_stages.py")
+
+
+def test_the_token_stages_loader_thread_is_a_declared_read_only_role():
+    """PR 63's worker (``prefill-load``: a row bucket's executable made
+    while the constructor lowers the next) is an entry point the
+    analyzer sees, under a role the class declares read-only."""
+    from rnb_tpu.analysis import concurrency
+    from rnb_tpu.analysis.findings import parse_py
+    (cls,) = [c for c in concurrency._classes_of(parse_py(TOKEN_STAGES))
+              if c.name == "PackedPrefill"]
+    info = concurrency._extract_contracts(cls, "token_stages.py")
+    assert info.entry_roles["_load_programs"] == "prefill-load"
+    assert set(info.read_only_roles) == {"prefill-load"}
+    assert not info.locks and not info.guarded
+    assert "PackedPrefill" in {
+        cls for _, cls, _, _ in concurrency.contract_registry()}
+    assert concurrency.check_file(TOKEN_STAGES, root=REPO) == []
+
+
+@pytest.mark.parametrize("write", [
+    "self._programs[rows] = program",
+    "self.hlo_scopes = scopes",
+    "self._note(rows)"])
+def test_a_write_on_the_loader_thread_triggers_c002(tmp_path, write):
+    """... and a stage attribute written there, directly or through a
+    method of the stage, is a finding."""
+    from rnb_tpu.analysis.concurrency import check_file
+    with open(TOKEN_STAGES) as f:
+        source = f.read()
+    at = "                loaded.append((rows, program, scopes))\n"
+    assert source.count(at) == 1
+    source = source.replace(at, at + "                %s\n" % write)
+    source += ("\n    def _note(self, rows):\n"
+               "        self._noted = rows\n")
+    path = tmp_path / "token_stages.py"
+    path.write_text(source)
+    findings = check_file(str(path), root=str(tmp_path))
+    assert {f.rule for f in findings} == {"RNB-C002"}, \
+        [f.render() for f in findings]
+    assert all("prefill-load" in f.render() for f in findings)
+
+
 def test_rnb_lint_concurrency_family_runs_without_jax(tmp_path):
     """Acceptance: `--family concurrency` must not import jax (the
     analyzer is pure-AST, budgeted at seconds not minutes) — a

@@ -15,6 +15,7 @@ import json
 import logging
 import os
 import re
+import threading
 
 import numpy as np
 import pytest
@@ -237,46 +238,109 @@ def test_setup_trace_json_validates_and_holds_the_record(run):
 # -- the stages' spans ----------------------------------------------------
 
 
+#: the two threads a token stage's set-up lives on since PR 63: the
+#: runner's, which builds the stage, and the constructor's one worker
+BUILDER, LOADER = "runner-s2-g0-i0", "prefill-load"
+
+
+def inside(event, outer) -> bool:
+    return outer[1] <= event[1] \
+        and event[1] + event[2] <= outer[1] + outer[2]
+
+
 def test_the_final_token_stage_names_its_phases(token_run):
-    names = token_run.names("runner-s2-g0-i0")
+    names = token_run.names(BUILDER)
     assert names.count("setup.s2.construct") == 1
     assert names.count("setup.s2.weights") == 1
-    for kind in ("program", "scopes", "first_call"):
-        assert names.count("setup.s2.%s" % kind) == 2, kind
-    rows = [e[4]["rows"] for e in token_run.events
-            if e[0] == "setup.s2.program"]
-    assert rows == [4, 8]
+    assert names.count("setup.s2.program") == 2
+    assert names.count("setup.s2.load_wait") == 1
+    # what turns a lowered program into a warmed executable is the
+    # worker's, a load span a row bucket
+    loader = token_run.names(LOADER)
+    for kind in ("load", "scopes", "first_call"):
+        assert loader.count("setup.s2.%s" % kind) == 2, kind
+        assert "setup.s2.%s" % kind not in names, kind
+    assert not [n for n in loader if n.startswith("setup.s2.")
+                and n.split(".")[2] not in ("load", "scopes", "first_call")]
+    for kind in ("program", "load"):
+        rows = [e[4]["rows"] for e in token_run.events
+                if e[0] == "setup.s2.%s" % kind]
+        assert rows == [4, 8], kind
     # the stages in front of it build in threads of their own
     assert token_run.names("runner-s0-g0-i0") == ["setup.s0.construct"]
     assert token_run.names("runner-s1-g0-i0") == ["setup.s1.construct"]
-    assert token_run.setup_line()["instance"] == "runner-s2-g0-i0"
+    assert token_run.setup_line()["instance"] == BUILDER
 
 
 def test_jax_own_spans_nest_under_the_stage(token_run):
-    """A program is traced, lowered and compiled once, on the thread
-    that builds the stage, inside its program span."""
+    """A program is traced and lowered once on the thread that builds
+    the stage, inside its program span, and compiled once on the
+    worker, inside its load span, with its scopes and first call."""
     spans = {e[0]: [] for e in token_run.events}
     for e in token_run.events:
         spans[e[0]].append(e)
-    programs = spans["setup.s2.program"]
-    for kind in ("trace", "lower", "compile"):
+    programs, loads = spans["setup.s2.program"], spans["setup.s2.load"]
+    for kind, thread, outers in (("trace", BUILDER, programs),
+                                 ("lower", BUILDER, programs),
+                                 ("compile", LOADER, loads)):
         mine = [e for e in spans["setup.jax." + kind]
                 if e[4]["fun_name"].endswith("apply")
                 or e[4]["fun_name"] == "jit(apply)"]
         assert len(mine) == 2, (kind, [e[4] for e in
                                        spans["setup.jax." + kind]])
-        for event, program in zip(mine, programs):
-            assert event[3] == "runner-s2-g0-i0"
-            assert program[1] <= event[1]
-            assert event[1] + event[2] <= program[1] + program[2]
+        for event, outer in zip(mine, outers):
+            assert event[3] == thread and outer[3] == thread
+            assert inside(event, outer)
+    for kind in ("scopes", "first_call"):
+        for event, load in zip(spans["setup.s2." + kind], loads):
+            assert event[3] == LOADER and inside(event, load)
     for e in spans["setup.jax.compile"]:
         assert e[4]["cache_hit"] in (0, 1)
         assert ("retrieval_s" in e[4]) == bool(e[4]["cache_hit"])
     # a trace inside a trace or a lowering has no span of its own
     lowering = sorted((e[1], e[1] + e[2]) for e in
                       spans["setup.jax.trace"] + spans["setup.jax.lower"]
-                      if e[3] == "runner-s2-g0-i0")
+                      if e[3] == BUILDER)
     assert all(a[1] <= b[0] for a, b in zip(lowering, lowering[1:]))
+
+
+def test_a_load_starts_behind_its_lowering_and_ends_before_the_join(
+        token_run):
+    """The pipeline's order: bucket i's load opens once its program span
+    has closed, the loads follow each other on the one worker, and the
+    constructor's wait closes behind the last of them; both threads'
+    spans nest or follow, which the account's self times assume."""
+    at = {kind: [e for e in token_run.events
+                 if e[0] == "setup.s2.%s" % kind]
+          for kind in ("program", "load", "load_wait", "construct")}
+    for program, load in zip(at["program"], at["load"]):
+        assert program[1] + program[2] <= load[1]
+    first, second = at["load"]
+    assert first[1] + first[2] <= second[1]
+    (wait,), (construct,) = at["load_wait"], at["construct"]
+    assert at["program"][-1][1] + at["program"][-1][2] <= wait[1]
+    assert second[1] + second[2] <= wait[1] + wait[2]
+    assert inside(wait, construct)
+    for thread in (BUILDER, LOADER):
+        mine = sorted((e[1], -e[2]) for e in token_run.events
+                      if e[3] == thread and e[2])
+        open_ = []
+        for t0, neg in mine:
+            while open_ and open_[-1] <= t0:
+                open_.pop()
+            assert not open_ or t0 - neg <= open_[-1] + 1e-9, thread
+            open_.append(t0 - neg)
+
+
+def test_load_and_load_wait_are_registered_names():
+    patterns = {spec.pattern: spec for spec in TRACE_EVENT_REGISTRY}
+    for kind in ("load", "load_wait"):
+        spec = patterns["setup.s{step}.%s" % kind]
+        assert spec.producer == "rnb_tpu/models/token_stages.py"
+        assert REGISTERED.match("setup.s2.%s" % kind)
+    # neither is a phase of the Setup: line: on the constructor's
+    # thread the wait is `other`
+    assert not [end for end in trace.SETUP_PHASES if "load" in end]
 
 
 def test_no_program_compiles_twice_because_of_a_span(token_run, r2p1d_run):
@@ -303,6 +367,96 @@ def test_the_r2p1d_stages_name_their_phases(r2p1d_run):
     assert loader.count("setup.s0.program") \
         == loader.count("setup.s0.first_call") >= 1
     assert "setup.s0.weights" not in loader
+
+
+# -- the constructor's pipeline, on a stage made by hand -------------------
+
+
+def toy_stage(tmp_path):
+    from rnb_tpu.devices import DeviceSpec
+    from rnb_tpu.models import token_stages
+    from rnb_tpu.models.nemotron_h import checkpoint
+    recipe = str(tmp_path / "toy.recipe.json")
+    checkpoint.save_recipe(recipe, TOY, 11, HELD)
+    return token_stages.PackedPrefill(
+        DeviceSpec(-1), ckpt_path=recipe, max_rows=8, chunk=16,
+        row_buckets=[4, 8])
+
+
+def loaders():
+    return [t for t in threading.enumerate() if t.name == LOADER]
+
+
+def test_each_buckets_program_is_the_one_made_in_turn(tmp_path, monkeypatch):
+    """What the worker loads is what ``jax.jit(apply).lower(...)
+    .compile()`` gives a bucket at a time on one thread: the same text,
+    in the buckets' order, and the scope table filled in that order."""
+    import jax
+
+    from rnb_tpu import hloscopes
+    from rnb_tpu.models.token_stages import dispatch_meta
+    jit, applied = jax.jit, []
+
+    def keeping(fun, *args, **kwargs):
+        if getattr(fun, "__name__", "") == "apply":
+            applied.append(fun)
+        return jit(fun, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "jit", keeping)
+    stage = toy_stage(tmp_path)
+    monkeypatch.undo()
+    assert len(applied) == 2 and applied[0] is applied[1]
+    assert list(stage._programs) == list(stage.row_buckets) == [4, 8]
+    assert not loaders()
+    scopes = {}
+    for rows in stage.row_buckets:
+        tokens = np.zeros((rows, 16), np.int32)
+        meta = dispatch_meta((0, rows), np.full(rows, 16), rows, 16)
+        text = jax.jit(applied[0]).lower(
+            stage._params, stage._slots, tokens, meta).compile().as_text()
+        assert stage._programs[rows].as_text() == text, rows
+        scopes.update(hloscopes.scopes_of_hlo(text))
+    assert stage.hlo_scopes == scopes
+    assert list(stage.hlo_scopes) == list(scopes)
+
+
+@pytest.mark.parametrize("bucket", [0, 1])
+def test_what_the_worker_raises_the_constructor_raises(tmp_path,
+                                                       monkeypatch, bucket):
+    """The same error, from whichever bucket's load, with the worker
+    gone and nothing loaded behind the failure."""
+    from rnb_tpu import hloscopes
+
+    class Broken(RuntimeError):
+        pass
+
+    raised, tables = [], []
+
+    def scopes_of_hlo(text):
+        tables.append(threading.current_thread().name)
+        if len(tables) == bucket + 1:
+            raised.append(Broken("bucket %d" % bucket))
+            raise raised[0]
+        return {}
+
+    monkeypatch.setattr(hloscopes, "scopes_of_hlo", scopes_of_hlo)
+    with pytest.raises(Broken) as caught:
+        toy_stage(tmp_path)
+    assert caught.value is raised[0]
+    assert tables == [LOADER] * (bucket + 1)
+    assert not loaders()
+
+
+def test_what_the_lowering_raises_leaves_no_worker(tmp_path, monkeypatch):
+    from rnb_tpu.models.nemotron_h import network
+
+    def forward(*_args, **_kwargs):
+        raise ZeroDivisionError("no stack")
+
+    monkeypatch.setattr(network, "forward", forward)
+    with pytest.raises(ZeroDivisionError, match="no stack"):
+        toy_stage(tmp_path)
+    assert not loaders()
 
 
 # -- behind the barrier ---------------------------------------------------
